@@ -30,7 +30,7 @@
 #                   error-level finding (tools/program_audit.py)
 #   make test     — full suite on the virtual 8-device CPU mesh
 #   make dryrun   — compile+run one training step per parallelism mode
-#   make bench    — the benchmark (real chip when present, CPU fallback)
+#   make bench    — the benchmark (one process, on a TPU; exits non-zero without one)
 #   make bench-fit — step-loop overlap bench (prefetch / dispatch-ahead /
 #                    multi-step dispatch) on the e2e MLP; one JSON line
 #   make bench-pipe — pipeline schedule/engine bench (host GPipe vs 1F1B

@@ -1,0 +1,709 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+``python chip_smoke.py`` (no arguments, one process, from the repo root)
+drives the two main paths through the entry points a user calls, at the
+published widths of GPT-2-medium (``gpt2-medium`` config.json: 24 layers,
+hidden 1024, 16 heads, MLP 4096, vocab 50257, 1024 positions), with
+random weights made from a seed:
+
+* **kernels** — every Pallas kernel compiled by Mosaic and compared with
+  its ``jnp`` reference at ``highest`` matmul precision, forward and
+  backward: flash attention at the train phase's own shape, and
+  ``moe_dispatch``/``moe_combine`` at the zoo MoE's shape;
+* **serve** — ``GenerationInstance`` → scheduler → ``PagedDecoder``
+  answering eight overlapping greedy requests, and the paged prefill's
+  logits against ``ff.compiled.raw_forward`` for one prompt;
+* **train** — builder API → ``FFModel.compile`` → ``fit`` for two epochs
+  in bfloat16 with Adam; on more than one device the same phase runs
+  twice over a mesh of all devices, once data-parallel and once with the
+  Unity search on, and their first losses must agree.
+
+It exits 0 only if every phase passed on a TPU, and prints as its last
+line ``{"ok": true, "device": {...}}``. With any other backend it names
+what it found and exits non-zero before building a model; there is no
+flag, environment variable or code path by which it passes without a
+chip. The phases are functions of :class:`SmokeSizes` so that
+``tests/test_chip_smoke.py`` drives the same control flow at toy size on
+the CPU mesh; the command line takes no size.
+
+Phases run smallest first, so each line's peak device memory (high-water
+marks of the process, which the backend cannot reset) is that phase's
+own. Everything the run writes goes under ``chiprun_out/``, apart
+from the compilation cache (``JAX_COMPILATION_CACHE_DIR`` if set, else
+``.jax_cache/`` in the checkout — flexflow_tpu/utils/compile_cache.py).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import gc
+import json
+import math
+import os
+import sys
+import time
+from typing import Dict, List, Optional, Tuple
+
+import numpy as np
+
+_ROOT = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(_ROOT, "chiprun_out", "chip_smoke")
+
+
+@dataclasses.dataclass(frozen=True)
+class SmokeSizes:
+    """Everything about the run that has a size. ``GPT2_MEDIUM`` is what
+    ``main`` runs; ``TOY`` is what the CPU test runs."""
+
+    # the language model (models/gpt.py GPTConfig)
+    vocab: int
+    positions: int
+    hidden: int
+    heads: int
+    layers: int
+    mlp_ratio: int
+    # train: sequence length, steps per epoch (two epochs), Adam's alpha,
+    # and the per-device batch (None = the largest power of two that the
+    # device's memory holds, train_batch_that_fits)
+    seq: int
+    steps_per_epoch: int
+    lr: float
+    batch_per_device: Optional[int]
+    # serve: the instance's max_length and the request mix (prompt and
+    # answer lengths drawn from a seed within these bounds)
+    max_length: int
+    requests: int
+    prompt_len: Tuple[int, int]
+    new_tokens: Tuple[int, int]
+    # kernels: the MoE shape (tokens, d_in, d_out, experts, picks, alpha);
+    # flash attention runs at (train batch, seq, heads, hidden // heads)
+    moe: Tuple[int, int, int, int, int, float]
+
+
+GPT2_MEDIUM = SmokeSizes(
+    vocab=50257, positions=1024, hidden=1024, heads=16, layers=24,
+    mlp_ratio=4, seq=1024, steps_per_epoch=4, lr=2e-4,
+    batch_per_device=None, max_length=1024, requests=8,
+    prompt_len=(32, 512), new_tokens=(32, 64),
+    # models/moe.py MoeConfig at FFConfig's default batch of 64
+    moe=(64, 784, 64, 5, 2, 2.0))
+
+TOY = SmokeSizes(
+    vocab=96, positions=64, hidden=32, heads=4, layers=2, mlp_ratio=4,
+    seq=32, steps_per_epoch=2, lr=1e-2, batch_per_device=1, max_length=32,
+    requests=8, prompt_len=(3, 16), new_tokens=(2, 5),
+    moe=(16, 12, 8, 4, 2, 2.0))
+
+
+class SmokeFailure(AssertionError):
+    """A phase's check did not hold."""
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+# --------------------------------------------------------------------------
+# bookkeeping shared by the phases
+# --------------------------------------------------------------------------
+
+def _peak_bytes() -> Dict[str, Optional[int]]:
+    """High-water marks of device 0. On a TPU a running program's
+    temporaries are counted under ``peak_bytes_reserved``, beside the
+    live arrays of ``peak_bytes_in_use``; the two regions share the
+    device's memory."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}  # None on the CPU backend
+    return {k: stats.get(k)
+            for k in ("peak_bytes_in_use", "peak_bytes_reserved")}
+
+
+class _Phase:
+    """Times one phase and reads what the compiler did during it."""
+
+    def __init__(self, name: str):
+        from flexflow_tpu.utils.compile_cache import compile_stats
+
+        self.name = name
+        self._stats = compile_stats
+        self._c0 = compile_stats()
+        self._t0 = time.perf_counter()
+        # set by built(): the wall time of building the model and its
+        # programs, and the compile seconds already inside it
+        self._build_s = self._build_compile_s = 0.0
+
+    def built(self) -> None:
+        """The phase has built its model; what follows is the workload."""
+        self._build_s = time.perf_counter() - self._t0
+        self._build_compile_s = self._stats()["compile_s"] - self._c0["compile_s"]
+
+    def report(self, **facts) -> Dict:
+        c1 = self._stats()
+        d = {k: c1[k] - self._c0[k] for k in c1}
+        rec = {
+            "phase": self.name,
+            "wall_s": round(time.perf_counter() - self._t0, 2),
+            # set-up = the build (graph, search, parameter init, tracing
+            # and whatever it compiled) + the seconds JAX spent in XLA
+            # compile requests after it (the first dispatch of each
+            # program), persistent-cache reads included
+            "setup_s": round(self._build_s + d["compile_s"]
+                             - self._build_compile_s, 2),
+            "xla_compile_s": round(d["compile_s"], 2),
+            "compiles": int(d["compiles"]),
+            "cache_hits": int(d["cache_hits"]),
+            "cache_misses": int(d["cache_misses"]),
+            **_peak_bytes(),
+            **facts,
+        }
+        print("[chip_smoke] " + " ".join(f"{k}={v}" for k, v in rec.items()),
+              flush=True)
+        return rec
+
+
+def _gpt_config(sizes: SmokeSizes):
+    from flexflow_tpu.models.gpt import GPTConfig
+
+    return GPTConfig(vocab_size=sizes.vocab, max_positions=sizes.positions,
+                     hidden_size=sizes.hidden, num_heads=sizes.heads,
+                     num_layers=sizes.layers, mlp_ratio=sizes.mlp_ratio)
+
+
+def _ff_config(**kw):
+    """FFConfig for a smoke phase: no strategy cache, and the run ledger
+    under the output directory instead of the cwd's .ffcache/."""
+    from flexflow_tpu import FFConfig
+
+    return FFConfig(seed=0, compute_dtype="bfloat16", search_cache="off",
+                    ledger_dir=os.path.join(OUT_DIR, "ledger"), **kw)
+
+
+def gpt_param_count(sizes: SmokeSizes) -> int:
+    h, v = sizes.hidden, sizes.vocab
+    block = (4 * h * h + 4 * h            # attention q, k, v, o + biases
+             + 2 * sizes.mlp_ratio * h * h + (sizes.mlp_ratio + 1) * h
+             + 4 * h)                     # two LayerNorms
+    return (v * h + sizes.positions * h + sizes.layers * block + 2 * h
+            + h * v)
+
+
+def train_bytes_estimate(sizes: SmokeSizes, batch: int) -> int:
+    """Estimated peak training footprint of one device holding ``batch``
+    samples, in bytes:
+
+    * state: 12 per parameter (float32 weight, Adam m and v; each
+      gradient is consumed by its update and never held beside them) —
+      4.9 GB for GPT-2-medium with the zoo model's untied vocabulary head
+      (406 M parameters);
+    * per sample, the step program's temporaries: the unfused attention
+      probabilities in bfloat16, ``layers * heads * seq^2 * 2`` (0.8 GB);
+      the logits three times over in float32 (the logits, their
+      log-softmax, its gradient: 0.6 GB); two ``seq * hidden`` bfloat16
+      activations per block.
+
+    The per-block count is fitted, not derived: on a v5e the train phase
+    measured 8.3 GB at batch 2 and 11.3 GB at batch 4
+    (``peak_bytes_in_use + peak_bytes_reserved``; my chip run, PR 21)
+    against 7.9 and 11.0 from this estimate. The phase prints the peak
+    it measured beside the estimate."""
+    per_sample = (sizes.layers * sizes.heads * sizes.seq ** 2 * 2
+                  + 3 * sizes.seq * sizes.vocab * 4
+                  + sizes.layers * 2 * sizes.seq * sizes.hidden * 2)
+    return 12 * gpt_param_count(sizes) + batch * per_sample
+
+
+def train_batch_that_fits(sizes: SmokeSizes, hbm_bytes: int) -> int:
+    """Largest power-of-two per-device batch whose
+    :func:`train_bytes_estimate` fits nine tenths of ``hbm_bytes`` (the
+    rest is left to XLA's own temporaries)."""
+    batch = 1
+    if train_bytes_estimate(sizes, batch) > 0.9 * hbm_bytes:
+        raise SmokeFailure(
+            f"one sample does not fit: an estimated "
+            f"{train_bytes_estimate(sizes, 1) / 1e9:.2f} GB > 0.9 * "
+            f"{hbm_bytes / 1e9:.2f} GB")
+    while train_bytes_estimate(sizes, 2 * batch) <= 0.9 * hbm_bytes:
+        batch *= 2
+    return batch
+
+
+def resolve_batch_per_device(sizes: SmokeSizes) -> int:
+    if sizes.batch_per_device is not None:
+        return sizes.batch_per_device
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    _require(stats is not None and "bytes_limit" in stats,
+             "the device reports no memory limit to size the batch from")
+    return train_batch_that_fits(sizes, int(stats["bytes_limit"]))
+
+
+# --------------------------------------------------------------------------
+# kernels
+# --------------------------------------------------------------------------
+
+# Tolerances, kernel against the float32 reference at `highest` precision.
+# Flash attention: the largest error of each output over the largest
+# magnitude of its reference. Mosaic's default contract precision, like
+# XLA's, feeds the MXU bfloat16 passes even for float32 operands, so every
+# product carries one bfloat16 rounding of an operand (2^-8 = 0.4 % of its
+# value) whatever the input dtype; bfloat16 inputs add the rounding of the
+# outputs themselves (2^-9). Measured on a v5e at (4, 1024, 16, 64): 0.20 %
+# to 0.73 % of range (the largest: dk with float32 inputs); the bound is
+# under three times that.
+FLASH_RANGE_TOL = 2e-2
+# The MoE kernels copy rows and form k-term weighted sums in float32
+# (measured: exact to 2e-6). The gate gradient is the exception: the
+# kernels gather its rows, then a jnp einsum contracts them at the
+# backend's default matmul precision (measured 1.2e-4 at 256-wide rows).
+MOE_TOL = dict(rtol=1e-5, atol=1e-5)
+MOE_DGATE_TOL = dict(rtol=1e-3, atol=1e-3)
+
+
+def _assert_mosaic(jitted, *args) -> None:
+    """The lowered program must hold a Mosaic custom call: an interpreted
+    kernel lowers to plain HLO and would pass a numeric check unnoticed."""
+    text = jitted.lower(*args).as_text()
+    _require("tpu_custom_call" in text,
+             "kernel did not lower to a Mosaic tpu_custom_call")
+
+
+def check_flash_attention(shape, causal: bool, dtype: str,
+                          mosaic: bool) -> Dict[str, float]:
+    """Flash attention (forward, dq, dk/dv) against
+    ``single_device_attention`` in float32 at ``highest`` precision, on
+    the same inputs. Returns each output's largest error as a share of
+    its reference's largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.flash_attention import flash_attention, supported
+    from flexflow_tpu.parallel.ring_attention import single_device_attention
+
+    _require(supported(shape, shape, causal),
+             f"flash_attention.supported() refuses {shape}")
+    b, s, h, d = shape
+    rng = np.random.default_rng(0)
+    q, k, v, w = (jnp.asarray(rng.normal(size=shape).astype(np.float32),
+                              dtype) for _ in range(4))
+    scale = d ** -0.5
+
+    def kernel_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, scale=scale)
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32)), out
+
+    def ref_loss(q, k, v):
+        out = single_device_attention(q, k, v, causal, scale)
+        return jnp.sum(out * w.astype(jnp.float32)), out
+
+    got_fn = jax.jit(jax.value_and_grad(kernel_loss, argnums=(0, 1, 2),
+                                        has_aux=True))
+    if mosaic:
+        _assert_mosaic(got_fn, q, k, v)
+    (_, out), grads = got_fn(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (_, out_ref), grads_ref = jax.jit(jax.value_and_grad(
+            ref_loss, argnums=(0, 1, 2), has_aux=True))(
+                *(a.astype(jnp.float32) for a in (q, k, v)))
+    errs = {}
+    for name, a, r in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                          (out_ref, *grads_ref)):
+        a = np.asarray(a.astype(jnp.float32))
+        r = np.asarray(r)
+        _require(np.isfinite(a).all(), f"flash {name}: non-finite values")
+        errs[name] = float(np.max(np.abs(a - r)) / np.max(np.abs(r)))
+        _require(errs[name] <= FLASH_RANGE_TOL,
+                 f"flash {name} ({dtype}, {shape}): max error "
+                 f"{errs[name]:.2e} of range > {FLASH_RANGE_TOL}")
+    return errs
+
+
+def check_moe_kernels(moe, mosaic: bool) -> Dict[str, float]:
+    """``moe_dispatch`` / ``moe_combine`` and their gradients against the
+    one-hot einsum formulation (ops/moe_ops.py) at ``highest`` precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.moe_kernels import moe_combine, moe_dispatch
+    from flexflow_tpu.ops.moe_ops import expert_capacity, moe_dispatch_mask
+
+    tokens, d_in, d_out, n, k, alpha = moe
+    cap = expert_capacity(tokens, k, n, alpha)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(tokens, d_in)).astype(np.float32))
+    rows_in = jnp.asarray(rng.normal(size=(n, cap, d_out)).astype(np.float32))
+    assign = jnp.asarray(rng.integers(0, n, size=(tokens, k)), jnp.int32)
+    gate = jnp.asarray(rng.uniform(0.1, 1.0, size=(tokens, k))
+                       .astype(np.float32))
+
+    def ref_dispatch(x):
+        disp = moe_dispatch_mask(assign, n, cap)
+        return jnp.einsum("tnc,tf->ncf", disp, jnp.repeat(x, k, axis=0))
+
+    def ref_combine(rows, gate):
+        disp = moe_dispatch_mask(assign, n, cap)
+        out = jnp.einsum("tnc,ncf->tf",
+                         disp * gate.reshape(-1)[:, None, None], rows)
+        return out.reshape(tokens, k, -1).sum(axis=1)
+
+    def loss(dispatch, combine):
+        def f(x, rows, gate):
+            return (jnp.sum(dispatch(x) ** 2)
+                    + jnp.sum(combine(rows, gate) ** 2))
+        return f
+
+    dispatch = jax.jit(lambda x: moe_dispatch(x, assign, n, cap))
+    combine = jax.jit(lambda rows, gate: moe_combine(rows, assign, gate))
+    grad = jax.jit(jax.grad(loss(lambda x: moe_dispatch(x, assign, n, cap),
+                                 lambda r, g: moe_combine(r, assign, g)),
+                            argnums=(0, 1, 2)))
+    if mosaic:
+        _assert_mosaic(dispatch, x)
+        _assert_mosaic(combine, rows_in, gate)
+        _assert_mosaic(grad, x, rows_in, gate)
+    got = (dispatch(x), combine(rows_in, gate), *grad(x, rows_in, gate))
+    with jax.default_matmul_precision("highest"):
+        want = (jax.jit(ref_dispatch)(x), jax.jit(ref_combine)(rows_in, gate),
+                *jax.jit(jax.grad(loss(ref_dispatch, ref_combine),
+                                  argnums=(0, 1, 2)))(x, rows_in, gate))
+    errs = {}
+    for name, a, r in zip(("dispatch", "combine", "dx", "drows", "dgate"),
+                          got, want):
+        a, r = np.asarray(a), np.asarray(r)
+        _require(np.isfinite(a).all(), f"moe {name}: non-finite values")
+        errs[name] = float(np.max(np.abs(a - r)))
+        np.testing.assert_allclose(
+            a, r, err_msg=f"moe {name}",
+            **(MOE_DGATE_TOL if name == "dgate" else MOE_TOL))
+    return errs
+
+
+def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
+    from flexflow_tpu.kernels import pallas_mode
+
+    mode = pallas_mode()
+    _require(mode is not None, "Pallas kernels are off (FLEXFLOW_TPU_PALLAS)")
+    mosaic = mode == "compiled"
+    ph = _Phase("kernels")
+    shape = (batch, sizes.seq, sizes.heads, sizes.hidden // sizes.heads)
+    errs = {}
+    for dtype in ("float32", "bfloat16"):
+        e = check_flash_attention(shape, True, dtype, mosaic)
+        errs.update({f"flash_{dtype}_{k}": f"{v:.1e}" for k, v in e.items()})
+    errs.update({f"moe_{k}": f"{v:.1e}"
+                 for k, v in check_moe_kernels(sizes.moe, mosaic).items()})
+    return ph.report(interpret=not mosaic, flash_shape=shape,
+                     moe_shape=sizes.moe, **errs)
+
+
+# --------------------------------------------------------------------------
+# serve
+# --------------------------------------------------------------------------
+
+# Paged prefill against raw_forward, last prompt position, max |Δlogit|.
+# Both sides run the same bfloat16 weights and activations; they differ in
+# how attention is written (the prefill scales the scores after the
+# product and masks with -1e30, single_device_attention scales q first and
+# masks with -inf), so every block rounds a little differently to
+# bfloat16 (2^-9 relative) and the differences add up over the depth:
+# 0.014 measured on a v5e over 24 blocks. The logits of a freshly
+# initialised model span several units, and a wrong block table, mask or
+# position moves them by that much.
+SERVE_LOGIT_ATOL = 0.05
+KV_DTYPE = "bfloat16"
+
+
+def phase_serve(sizes: SmokeSizes) -> Dict:
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu import FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.models.gpt import build_gpt
+    from flexflow_tpu.obs.metrics import metrics_registry
+    from flexflow_tpu.serving import GenerationInstance
+
+    ph = _Phase("serve")
+    reg = metrics_registry()
+    watched = ("serving.errors", "serving.shed", "serving.kv_dtype_fallbacks",
+               "serving.worker_crashes")
+    before = {n: reg.counter(n).value for n in watched}
+
+    slots = 4
+    ff = FFModel(_ff_config(batch_size=slots,
+                            computation_mode=CompMode.INFERENCE))
+    build_gpt(ff, slots, sizes.max_length, _gpt_config(sizes))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    inst = GenerationInstance(ff, decode_slots=slots,
+                              max_length=sizes.max_length, kv_dtype=KV_DTYPE)
+    ph.built()
+    try:
+        rng = np.random.default_rng(0)
+        reqs = []
+        for _ in range(sizes.requests):
+            n = int(rng.integers(sizes.prompt_len[0], sizes.prompt_len[1] + 1))
+            m = int(rng.integers(sizes.new_tokens[0], sizes.new_tokens[1] + 1))
+            reqs.append((rng.integers(0, sizes.vocab, n).astype(np.int32), m))
+        # all submitted at once into fewer slots than requests: the later
+        # ones are prefilled between the earlier ones' decode steps
+        futures = [inst.generate_async(p, m, temperature=0.0)
+                   for p, m in reqs]
+        # the scheduler turns an exception into a failed request, not a
+        # dead process: result() re-raises it here
+        outs = [f.result(timeout=900) for f in futures]
+        for (prompt, m), out in zip(reqs, outs):
+            _require(out.shape == (prompt.size + m,)
+                     and np.array_equal(out[:prompt.size], prompt)
+                     and int(out.min()) >= 0 and int(out.max()) < sizes.vocab,
+                     f"request of {prompt.size}+{m} tokens returned "
+                     f"{out.shape}")
+        st = inst.stats()
+        _require(st["completed"] == sizes.requests
+                 and st["prefill_prompts"] == sizes.requests,
+                 f"completed {st['completed']} of {sizes.requests}")
+        _require(st["decode_dispatches"] == st["decode_steps"] > 0,
+                 f"decode dispatches {st['decode_dispatches']} != steps "
+                 f"{st['decode_steps']}")
+        _require(st["shed"] == 0 and st["deadline_rejects"] == 0,
+                 f"shed {st['shed']}, deadline rejects "
+                 f"{st['deadline_rejects']}")
+        moved = {n: reg.counter(n).value - before[n] for n in watched}
+        _require(not any(moved.values()), f"serving counters moved: {moved}")
+        arena = next(iter(inst.decoder.pool.kv.values()))[0]
+        _require(st["kv"]["kv_dtype"] == KV_DTYPE
+                 and arena.dtype == jnp.dtype(KV_DTYPE)
+                 and not st["kv"].get("quant_fallback", False),
+                 f"asked for {KV_DTYPE} KV, got {st['kv']['kv_dtype']} "
+                 f"arenas of {arena.dtype}")
+
+        # one prompt (the shortest: the cheapest reference to compile):
+        # paged prefill logits against the dense forward. The scheduler is
+        # idle now, so the pool is ours to donate through.
+        prompt = min((p for p, _ in reqs), key=lambda p: p.size)
+        table = inst.decoder.pool.try_admit(prompt.size + 1)
+        try:
+            paged = inst.decoder.prefill(prompt, table)
+        finally:
+            inst.decoder.pool.free(table)
+        pos = np.arange(prompt.size, dtype=np.int32)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(ff.compiled.raw_forward)(
+                ff.compiled.params, prompt[None, :], pos[None, :])
+        ref = np.asarray(ref)[0, -1]
+        _require(paged.shape == (sizes.vocab,) and np.isfinite(paged).all(),
+                 "paged prefill logits: wrong shape or non-finite")
+        err = float(np.max(np.abs(paged - ref)))
+        _require(err <= SERVE_LOGIT_ATOL,
+                 f"paged prefill vs raw_forward: max |dlogit| {err:.3e} > "
+                 f"{SERVE_LOGIT_ATOL} (logit range {ref.min():.2f}.."
+                 f"{ref.max():.2f})")
+    finally:
+        inst.stop()
+    return ph.report(
+        requests=sizes.requests, tokens=st["tokens"],
+        decode_steps=st["decode_steps"],
+        prefill_dispatches=st["prefill_dispatches"],
+        kv_dtype=st["kv"]["kv_dtype"],
+        kv_divergence=st["kv"].get("divergence"),
+        prefill_vs_raw_forward=f"{err:.2e}", logit_atol=SERVE_LOGIT_ATOL,
+        ttft_p50_s=(st["phases"]["ttft"] or {}).get("p50"))
+
+
+# --------------------------------------------------------------------------
+# train
+# --------------------------------------------------------------------------
+
+# First (epoch-0 mean) loss against ln(vocab): a freshly initialised model
+# spreads its probability almost evenly, so its loss starts within a few
+# hundredths of ln(vocab) and the first steps move it down from there; a
+# label shift, a wrong vocabulary or a broken loss lands far outside.
+FIRST_LOSS_BAND = 1.0
+# Data-parallel against the searched plan, epoch-0 mean loss: the same
+# seed, data and global batch, summed in another order in bfloat16.
+PLAN_LOSS_ATOL = 0.05
+
+
+def _train_data(sizes: SmokeSizes, n: int):
+    """A seeded, fixed, learnable dataset: tokens drawn from a Zipf law
+    over the vocabulary, so that a few Adam steps lower the loss by
+    learning the unigram distribution."""
+    rng = np.random.default_rng(0)
+    p = 1.0 / np.arange(1, sizes.vocab + 1)
+    tok = rng.choice(sizes.vocab, size=(n, sizes.seq + 1),
+                     p=p / p.sum()).astype(np.int32)
+    pos = np.broadcast_to(np.arange(sizes.seq, dtype=np.int32),
+                          (n, sizes.seq)).copy()
+    return tok[:, :-1].copy(), pos, tok[:, 1:].copy()
+
+
+def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
+    """``plan``: ``single`` (one device), ``dp`` (every device, data
+    parallel) or ``searched`` (every device, the Unity search's choice)."""
+    import jax
+
+    from flexflow_tpu import AdamOptimizer, FFModel, LossType, MetricsType
+    from flexflow_tpu.models.gpt import build_gpt
+    from flexflow_tpu.obs.metrics import metrics_registry
+
+    ph = _Phase(f"train[{plan}]")
+    devices = jax.devices()
+    n_dev = len(devices)
+    _require((plan == "single") == (n_dev == 1),
+             f"plan {plan!r} on {n_dev} device(s)")
+    batch = batch_per_device * n_dev
+    reg = metrics_registry()
+    paths0 = {p: reg.counter(f"attention.path.{p}").value
+              for p in ("flash", "xla", "ring", "ulysses")}
+
+    ff = FFModel(_ff_config(batch_size=batch, epochs=2,
+                            only_data_parallel=plan != "searched",
+                            search_budget=8 if plan == "searched" else 0))
+    build_gpt(ff, batch, sizes.seq, _gpt_config(sizes))
+    ff.compile(optimizer=AdamOptimizer(alpha=sizes.lr),
+               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+               metrics=[MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY])
+    ph.built()
+    cm = ff.compiled
+
+    tokens, positions, labels = _train_data(
+        sizes, batch * sizes.steps_per_epoch)
+    history = ff.fit([tokens, positions], labels, verbose=False)
+    losses = [pm.sparse_cce_loss / max(1, pm.train_all) for pm in history]
+    paths = sorted(p for p, v0 in paths0.items()
+                   if reg.counter(f"attention.path.{p}").value > v0)
+
+    _require(len(losses) == 2 and all(math.isfinite(x) for x in losses),
+             f"epoch losses {losses}")
+    ln_v = math.log(sizes.vocab)
+    _require(abs(losses[0] - ln_v) <= FIRST_LOSS_BAND,
+             f"first loss {losses[0]:.3f} outside ln({sizes.vocab})="
+             f"{ln_v:.3f} +- {FIRST_LOSS_BAND}")
+    _require(losses[1] < losses[0],
+             f"loss did not fall: {losses[0]:.4f} -> {losses[1]:.4f}")
+    epochs = ff.fit_profile["epochs"]
+    _require([e["steps"] for e in epochs] == [sizes.steps_per_epoch] * 2,
+             f"steps per epoch {[e['steps'] for e in epochs]}")
+    _require(epochs[1]["compiles"] == 0,
+             f"{epochs[1]['compiles']} compile(s) after the first epoch")
+
+    # where the state and the batch actually are
+    leaves = jax.tree_util.tree_leaves((cm.params, cm.opt_state))
+    on = set().union(*(leaf.sharding.device_set for leaf in leaves))
+    _require(on == set(devices),
+             f"parameters and optimizer state sit on {len(on)} of {n_dev} "
+             f"devices")
+    for sh in (*cm.input_shardings, cm.label_sharding):
+        _require(sh.device_set == set(devices),
+                 f"batch sharding {sh} covers {len(sh.device_set)} of "
+                 f"{n_dev} devices")
+    if plan == "dp" and n_dev > 1:
+        _require(cm.input_shardings[0].spec[0] == "data",
+                 f"data-parallel batch is not split: "
+                 f"{cm.input_shardings[0].spec}")
+    facts = {}
+    if plan == "searched":
+        sp = ff.search_profile
+        facts = dict(mesh=sp["mesh_shape"], candidates=sp["candidates"],
+                     search_workers=sp["workers"],
+                     search_s=round(sp["search_time_s"], 2),
+                     sharded_layers=sum(
+                         1 for s in ff._search_strategies.values() if s),
+                     pipelined=ff.pipelined is not None)
+    return ph.report(
+        batch=batch, batch_per_device=batch_per_device, devices=n_dev,
+        state_on_devices=len(on),
+        batch_on_devices=len(cm.input_shardings[0].device_set),
+        batch_spec=tuple(cm.input_shardings[0].spec),
+        seq=sizes.seq, params=gpt_param_count(sizes),
+        estimated_bytes=train_bytes_estimate(sizes, batch_per_device),
+        steps=2 * sizes.steps_per_epoch, attention_path="+".join(paths),
+        loss_epoch0=round(losses[0], 4), loss_epoch1=round(losses[1], 4),
+        epoch_wall_s=[e["wall_s"] for e in epochs],
+        compiles_by_epoch=[e["compiles"] for e in epochs], **facts)
+
+
+# --------------------------------------------------------------------------
+# the run
+# --------------------------------------------------------------------------
+
+def _release() -> None:
+    """Drop a finished phase's device buffers before the next one."""
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+
+
+def run(sizes: SmokeSizes) -> List[Dict]:
+    """Every phase at ``sizes`` on whatever backend JAX has; raises on the
+    first check that does not hold. Returns one record per phase."""
+    import jax
+
+    from flexflow_tpu import native_bridge
+    from flexflow_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()  # before the first jit of the process
+    os.makedirs(OUT_DIR, exist_ok=True)
+    print(f"[chip_smoke] native={native_bridge.status()!r} "
+          f"compile_cache={jax.config.jax_compilation_cache_dir!r} "
+          f"(JAX_COMPILATION_CACHE_DIR "
+          f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'})",
+          flush=True)
+
+    batch = resolve_batch_per_device(sizes)
+    records = [phase_kernels(sizes, batch)]
+    _release()
+    records.append(phase_serve(sizes))
+    _release()
+    if len(jax.devices()) == 1:
+        records.append(phase_train(sizes, "single", batch))
+    else:
+        dp = phase_train(sizes, "dp", batch)
+        _release()
+        searched = phase_train(sizes, "searched", batch)
+        records += [dp, searched]
+        gap = abs(dp["loss_epoch0"] - searched["loss_epoch0"])
+        _require(gap <= PLAN_LOSS_ATOL,
+                 f"first losses disagree: dp {dp['loss_epoch0']}, "
+                 f"searched {searched['loss_epoch0']}")
+        print(f"[chip_smoke] plans agree: |first loss dp - searched| = "
+              f"{gap:.2e} <= {PLAN_LOSS_ATOL}", flush=True)
+    return records
+
+
+def main() -> int:
+    import jax
+
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    print(f"[chip_smoke] platform={device['platform']} "
+          f"device_kind={device['kind']!r} count={device['count']}",
+          flush=True)
+    if jax.default_backend() != "tpu":
+        print(f"[chip_smoke] FAIL: jax.default_backend() is "
+              f"{jax.default_backend()!r}, not 'tpu'; nothing was built",
+              file=sys.stderr, flush=True)
+        return 2
+    # the kernels phase is about Mosaic, and the train phase reads no
+    # tune cache: on the chip neither is up to the environment
+    for var in ("FLEXFLOW_TPU_PALLAS", "FLEXFLOW_FA_TUNE_CACHE",
+                "FLEXFLOW_FA_BLOCK_Q"):
+        os.environ.pop(var, None)
+    # the tree may be a copy whose file times mean nothing: rebuild the
+    # native library from native/src (run() says which one it got)
+    from flexflow_tpu import native_bridge
+
+    native_bridge.rebuild()
+    records = run(GPT2_MEDIUM)
+    with open(os.path.join(OUT_DIR, "result.json"), "w") as f:
+        json.dump({"device": device, "phases": records}, f, indent=1,
+                  default=str)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -80,7 +80,8 @@ DEFAULT_DONATE_BYTES = 1 << 20  # AUD002: args below this are not worth it
 _CALLBACK_PRIMS = {
     "pure_callback": "jax.pure_callback",
     "io_callback": "jax.experimental.io_callback",
-    "debug_callback": "jax.debug.print/callback",
+    "debug_callback": "jax.debug.callback",
+    "debug_print": "jax.debug.print",
 }
 # collectives that synchronize across an axis — the set whose cross-rank
 # ORDER must agree, or a multi-process mesh deadlocks
@@ -143,7 +144,7 @@ def _frame(eqn) -> Tuple[Optional[str], Optional[int]]:
     try:
         from jax._src import source_info_util as _siu
 
-        fr = _siu.user_frame(eqn.source_info)
+        fr = _siu.user_frame(eqn.source_info.traceback)
         if fr is not None:
             return fr.file_name, fr.start_line
     except Exception:

@@ -12,9 +12,17 @@ Layout: public entry takes (B, S, H, D) — the framework's bshd convention
 Compute is float32 on the MXU regardless of input dtype; outputs are cast
 back.
 
-VMEM budget: one (S, D) K/V panel plus a (block_q, S) logits tile; fits
-~16 MB VMEM for S·D ≤ ~1M, i.e. any shape short enough not to want ring
-attention (parallel/ring_attention.py) anyway.
+VMEM: each kernel holds one whole (S, D) panel per full operand plus
+(block, S) float32 logits temporaries; :func:`_vmem_bytes` counts that
+working set the way the compiler allocates it (pipeline double buffers,
+float32 copies, lane padding) and :func:`supported` refuses what does not
+fit, so longer sequences take the jnp path or ring attention
+(parallel/ring_attention.py) instead of failing in Mosaic.
+
+The log-sum-exp travels as (B*H, S, 1): a (block_q, 1) column is what
+the row reductions produce and what the backward broadcasts against, so
+no kernel moves data between sublanes and lanes, and a block's last dim
+is the array's full last dim whatever ``block_q`` is.
 """
 
 from __future__ import annotations
@@ -24,14 +32,22 @@ import math
 from typing import Optional
 
 import jax
-from ..utils.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import pallas_mode
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/max() NaN-free
+
+# The scoped-VMEM limit handed to Mosaic for these kernels (a v5e core
+# has 128 MiB; the compiler's default scope is 16 MiB), and the share of
+# it supported() lets the counted working set take — the rest is room
+# for what the count cannot see (compiler temporaries, relayouts).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+VMEM_BUDGET_BYTES = 48 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _causal_mask(block_q: int, skv: int, q_offset):
@@ -40,20 +56,30 @@ def _causal_mask(block_q: int, skv: int, q_offset):
     return qpos >= kpos
 
 
+# dot_general dimension numbers for a @ b.T and a.T @ b: the MXU takes
+# either operand transposed, so no kernel materializes a transpose
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale           # (block_q, D)
     k = k_ref[0].astype(jnp.float32)                   # (Skv, D)
     v = v_ref[0].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (block_q, Skv)
+    s = _dot(q, k, _NT)                                # (block_q, Skv)
     if causal:
         s = jnp.where(_causal_mask(block_q, k.shape[0], qi * block_q), s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.dot(p, v, preferred_element_type=jnp.float32) / l
-    o_ref[0] = o.astype(o_ref.dtype)
-    lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+    o_ref[0] = (_dot(p, v) / l).astype(o_ref.dtype)
+    lse_ref[0] = m + jnp.log(l)                        # (block_q, 1)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, dq_ref,
@@ -64,16 +90,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, dq_ref,
     v = v_ref[0].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
     o = o_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                 # (block_q,)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+    s = _dot(q, k, _NT)
     if causal:
         s = jnp.where(_causal_mask(block_q, k.shape[0], qi * block_q), s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                       # softmax probabilities
-    dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
+    p = jnp.exp(s - lse_ref[0])                         # softmax probabilities
+    dp = _dot(g, v, _NT)
     delta = jnp.sum(g * o, axis=-1, keepdims=True)      # rowsum(dO ∘ O)
     ds = p * (dp - delta)
-    dq_ref[0] = (jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
-                 ).astype(dq_ref.dtype)
+    dq_ref[0] = (_dot(ds, k) * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, dk_ref, dv_ref,
@@ -84,20 +108,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, dk_ref, dv_ref,
     v = v_ref[0].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
     o = o_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                 # (Sq,)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (Sq, block_k)
+    s = _dot(q, k, _NT)                                 # (Sq, block_k)
     if causal:
         sq = q.shape[0]
         qpos = jax.lax.broadcasted_iota(jnp.int32, (sq, block_k), 0)
         kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (sq, block_k), 1)
         s = jnp.where(qpos >= kpos, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
-    dv_ref[0] = jnp.dot(p.T, g, preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
+    p = jnp.exp(s - lse_ref[0])                         # lse: (Sq, 1)
+    dv_ref[0] = _dot(p, g, _TN).astype(dv_ref.dtype)
+    dp = _dot(g, v, _NT)
     delta = jnp.sum(g * o, axis=-1, keepdims=True)
     ds = p * (dp - delta)
-    dk_ref[0] = (jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-                 ).astype(dk_ref.dtype)  # q already carries `scale`
+    dk_ref[0] = _dot(ds, q, _TN).astype(dk_ref.dtype)   # q already carries `scale`
 
 
 def _pick_block(s: int, pref: int) -> Optional[int]:
@@ -208,6 +230,8 @@ def autotune(shape=(4, 512, 8, 64), candidates=(64, 128, 256, 512),
 
     import numpy as np
 
+    from . import pallas_forced
+
     b, s, h, d = shape
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(b * h, s, d)).astype(np.float32))
@@ -219,7 +243,7 @@ def autotune(shape=(4, 512, 8, 64), candidates=(64, 128, 256, 512),
             continue  # shape can't tile at this size
         # VMEM gate shared with supported(): don't let one oversized
         # candidate's Mosaic failure discard the other timings
-        if _fwd_vmem_bytes(s, cand, d) > VMEM_BUDGET_BYTES:
+        if _vmem_bytes(s, s, cand, cand, d) > VMEM_BUDGET_BYTES:
             continue
         fn = jax.jit(functools.partial(
             _flash, causal=causal, scale=d ** -0.5, block_q=cand,
@@ -232,6 +256,8 @@ def autotune(shape=(4, 512, 8, 64), candidates=(64, 128, 256, 512),
                 out = fn(q, q, q)
             jax.block_until_ready(out)
         except Exception:  # compile/alloc failure: skip this candidate
+            if pallas_forced():
+                raise  # forced Mosaic: a refusal is the finding, not a skip
             continue
         results[cand] = (time.perf_counter() - t0) / iters
     if results:
@@ -279,7 +305,8 @@ def autotune(shape=(4, 512, 8, 64), candidates=(64, 128, 256, 512),
             t_xla = _median_time(ref_fn, q4)
             xla_ratio = round(t_xla / t_kernel, 4)
         except Exception:
-            pass
+            if pallas_forced():
+                raise
         _TUNE_CACHE[(s, s, d, bool(causal))] = {
             "block_q": best, "xla_ratio": xla_ratio}
         path = cache_path or os.environ.get("FLEXFLOW_FA_TUNE_CACHE")
@@ -348,12 +375,14 @@ def _flash_fwd(q, k, v, causal, scale, block_q, interpret):
         functools.partial(_fwd_kernel, scale=scale, causal=causal, block_q=block_q),
         grid=grid,
         in_specs=[qspec, kvspec, kvspec],
-        out_specs=[qspec, pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))],
+        out_specs=[qspec, pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, (q, k, v, out, lse)
 
@@ -365,18 +394,20 @@ def _flash_bwd(causal, scale, block_q, interpret, res, g):
     block_k = _pick_block(skv, block_q)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
     kvfull = pl.BlockSpec((1, skv, d), lambda b, i: (b, 0, 0))
-    lspec = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
+    lspec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, block_q=block_q),
         grid=(bh, sq // block_q),
         in_specs=[qspec, kvfull, kvfull, qspec, qspec, lspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, out, g, lse)
     qfull = pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))
-    lfull = pl.BlockSpec((1, 1, sq), lambda b, i: (b, 0, 0))
+    lfull = pl.BlockSpec((1, sq, 1), lambda b, i: (b, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal, block_k=block_k),
         grid=(bh, skv // block_k),
@@ -386,7 +417,9 @@ def _flash_bwd(causal, scale, block_q, interpret, res, g):
             jax.ShapeDtypeStruct((bh, skv, d), k.dtype),
             jax.ShapeDtypeStruct((bh, skv, d), v.dtype),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, out, g, lse)
     return dq, dk, dv
 
@@ -394,14 +427,41 @@ def _flash_bwd(causal, scale, block_q, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # leave headroom under the ~16 MB core
+def _vmem_bytes(sq: int, skv: int, block_q: int, block_k: int, d: int) -> int:
+    """Largest VMEM working set of the three kernels for float32 inputs
+    (the widest), counted the way it is allocated: every input and output
+    block twice (the Pallas pipeline double-buffers them), a float32 copy
+    of every loaded operand, the (rows, cols) float32 temporaries of the
+    softmax recomputation (s, p, and in the backward dp, ds), all with
+    the last dim padded to 128 lanes — so one log-sum-exp element costs a
+    whole 512-byte lane row. Shared by supported() and autotune()."""
+    def lanes(n):
+        return -(-n // 128) * 128
+
+    dd = lanes(d)
+    lse_row = 4 * 128
+    fwd = (2 * 4 * (2 * block_q + 2 * skv) * dd            # q, o; k, v panels
+           + 2 * block_q * lse_row
+           + 4 * (block_q + 2 * skv) * dd                  # f32 q, k, v
+           + 4 * 3 * block_q * lanes(skv))                 # s, p, mask
+    dq = (2 * 4 * (4 * block_q + 2 * skv) * dd             # q, o, g, dq; k, v
+          + 2 * block_q * lse_row
+          + 4 * (3 * block_q + 2 * skv) * dd
+          + 4 * 5 * block_q * lanes(skv))                  # s, p, dp, ds, mask
+    dkv = (2 * 4 * (3 * sq + 4 * block_k) * dd             # q, o, g; k, v, dk, dv
+           + 2 * sq * lse_row
+           + 4 * (3 * sq + 2 * block_k) * dd
+           + 4 * 5 * sq * lanes(block_k))
+    return max(fwd, dq, dkv)
 
 
 def supported(q_shape, k_shape, causal: bool = False) -> bool:
     """Whether the kernel path handles these (B, S, H, D) shapes.
 
-    Checks block divisibility and the VMEM working set (K/V panels +
-    per-tile q/o/g and logits, float32); longer sequences fall back to the
+    Checks that the sequence lengths tile (blocks are multiples of 16
+    rows — the bf16 sublane tile — or the whole sequence) and that the
+    working set :func:`_vmem_bytes` counts fits the budget; longer
+    sequences fall back to the
     jnp path / ring attention rather than failing at Mosaic compile.
     Budgets with the SAME block the kernel will resolve (env/tuned/128) —
     a tuned 512 tile must not pass a gate computed for 128.
@@ -418,16 +478,9 @@ def supported(q_shape, k_shape, causal: bool = False) -> bool:
     bk = _pick_block(skv, pref)
     if bq is None or bk is None:
         return False
-    # worst case is the dkv backward: full q/g/o panels + one k/v tile +
-    # the (sq, block_k) logits tile, all float32
-    working = 4 * (3 * sq * d + 2 * bk * d + 2 * sq * bk)
-    return max(working, _fwd_vmem_bytes(skv, bq, d)) <= VMEM_BUDGET_BYTES
-
-
-def _fwd_vmem_bytes(skv: int, block_q: int, d: int) -> int:
-    """Forward tile working set, float32: K/V panels + q/o/lse tiles +
-    the (block_q, Skv) logits tile. Shared by supported() and autotune()."""
-    return 4 * (2 * skv * d + 3 * block_q * d + 2 * block_q * skv)
+    if (bq % 16 and bq != sq) or (bk % 16 and bk != skv):
+        return False
+    return _vmem_bytes(sq, skv, bq, bk, d) <= VMEM_BUDGET_BYTES
 
 
 def sharded_supported(q_shape, k_shape, mesh, batch_axis, heads_axis,

@@ -38,6 +38,22 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_mode
 
 
+# One v5e core has 1 MiB of SMEM, and Mosaic refuses a program whose
+# scalar-prefetch operands do not fit it. Every kernel call here prefetches
+# two 4-byte-per-element operands, one element per pick (tokens * k) or
+# per slot (experts * capacity); the budget leaves a quarter to the
+# compiler.
+SMEM_BUDGET_BYTES = 768 * 1024
+
+
+def supported(tokens: int, k: int, n: int, capacity: int) -> bool:
+    """Whether :func:`moe_dispatch` / :func:`moe_combine` and their
+    backward passes compile at this routing shape: the larger of the
+    per-pick and per-slot operand pairs must fit the SMEM budget. Callers
+    fall back to the one-hot einsum formulation (ops/moe_ops.py)."""
+    return 2 * 4 * max(tokens * k, n * capacity) <= SMEM_BUDGET_BYTES
+
+
 def _row_gather_kernel(idx_ref, scale_ref, x_ref, out_ref):
     i = pl.program_id(0)
     out_ref[...] = (scale_ref[i] * x_ref[...].astype(jnp.float32)
@@ -67,20 +83,22 @@ def row_gather(x: jax.Array, idx: jax.Array, scale: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r_out, 1, d), x.dtype),
         interpret=interpret,
+        name="moe_row_gather",
     )(idx.astype(jnp.int32), scale.astype(jnp.float32), x[:, None, :])
     return out[:, 0, :]
 
 
 def _row_gather_sum_kernel(idx_ref, w_ref, x_ref, out_ref, acc_ref):
     b, j = pl.program_id(0), pl.program_id(1)
+    k = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += w_ref[b, j] * x_ref[0].astype(jnp.float32)  # (1, d)
+    acc_ref[...] += w_ref[b * k + j] * x_ref[0].astype(jnp.float32)  # (1, d)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == k - 1)
     def _():
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
@@ -89,7 +107,10 @@ def row_gather_sum(x: jax.Array, idx: jax.Array, w: jax.Array,
                    interpret: bool = False) -> jax.Array:
     """out[b, :] = sum_j w[b, j] * x[idx[b, j], :]   (idx: (B, k) int32).
 
-    Same (R, 1, d) layout trick as :func:`row_gather`.
+    Same (R, 1, d) layout trick as :func:`row_gather`. The scalar-prefetch
+    operands go to SMEM flattened to (B*k,): SMEM pads the last dim of a
+    2-D array to 128 words, so a (B, k) operand would take B*512 bytes
+    and stop fitting at a few thousand tokens.
     """
     bsz, k = idx.shape
     d = x.shape[1]
@@ -97,7 +118,8 @@ def row_gather_sum(x: jax.Array, idx: jax.Array, w: jax.Array,
         num_scalar_prefetch=2,
         grid=(bsz, k),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, j, idx_ref, w_ref: (idx_ref[b, j], 0, 0)),
+            pl.BlockSpec((1, 1, d),
+                         lambda b, j, idx_ref, w_ref: (idx_ref[b * k + j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda b, j, idx_ref, w_ref: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
@@ -107,7 +129,9 @@ def row_gather_sum(x: jax.Array, idx: jax.Array, w: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, 1, d), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), w.astype(jnp.float32), x[:, None, :])
+        name="moe_row_gather_sum",
+    )(idx.astype(jnp.int32).reshape(-1), w.astype(jnp.float32).reshape(-1),
+      x[:, None, :])
     return out[:, 0, :]
 
 

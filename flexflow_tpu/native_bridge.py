@@ -13,7 +13,8 @@ include/flexflow/flexflow_c.h). The native library
 
 Every entry point has a pure-Python caller-side fallback (the callers check
 :func:`available`), so the framework works without a C++ toolchain; with
-one, the library is auto-built on first import.
+one, the library is auto-built on first import. :func:`status` says which
+of the two a process got and, for the Python twins, why.
 """
 
 from __future__ import annotations
@@ -32,6 +33,8 @@ _LIB_PATH = os.path.join(_REPO, "flexflow_tpu", "native",
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# why the Python twins were selected (None while the library is in use)
+_why_python: Optional[str] = None
 
 
 def _stale() -> bool:
@@ -50,9 +53,13 @@ def _stale() -> bool:
     return False
 
 
-def _build() -> bool:
+def _build(force: bool = False) -> bool:
+    """``make`` the library under the build lock; records why it could
+    not in ``_why_python``. ``force`` rebuilds whatever the mtimes say."""
+    global _why_python
     makefile_dir = os.path.join(_REPO, "native")
     if not os.path.isdir(makefile_dir):
+        _why_python = f"no native sources at {makefile_dir}"
         return False
     try:
         # serialize concurrent builders (pytest-xdist, multi-process
@@ -63,24 +70,41 @@ def _build() -> bool:
         with open(lock_path, "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
             try:
-                if not _stale():  # a peer finished the build while we waited
-                    return True
-                subprocess.run(["make", "-C", makefile_dir, "-s"], check=True,
-                               capture_output=True, timeout=120)
+                if not force and not _stale():
+                    return True  # a peer finished the build while we waited
+                subprocess.run(
+                    ["make", "-C", makefile_dir, "-s"] + (["-B"] if force else []),
+                    check=True, capture_output=True, timeout=120)
             finally:
                 fcntl.flock(lock, fcntl.LOCK_UN)
         return os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        _why_python = ("make failed: "
+                       + (e.stderr or b"").decode(errors="replace")[-300:])
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _why_python = f"make did not run: {type(e).__name__}: {e}"
+    return False
+
+
+def rebuild() -> bool:
+    """Rebuild the library from ``native/src`` unconditionally. For a tree
+    whose file times cannot be trusted (a copy scrambles them, and
+    :func:`_stale` compares mtimes). Call before anything loads the
+    library; returns whether a library now exists."""
+    if _lib is not None:
+        raise RuntimeError("native library already loaded; rebuild() must "
+                           "run before its first use")
+    return _build(force=True)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _why_python
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         if os.environ.get("FLEXFLOW_TPU_NATIVE", "auto") == "off":
+            _why_python = "FLEXFLOW_TPU_NATIVE=off"
             return None
         # rebuild only when a native source is newer than the .so
         # (stale-symbol safety without forking make in every process)
@@ -88,8 +112,10 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        except OSError as e:
+            _why_python = f"dlopen failed: {e}"
             return None
+        _why_python = None
         i32p = ctypes.POINTER(ctypes.c_int32)
         f64p = ctypes.POINTER(ctypes.c_double)
         lib.fftpu_version.restype = ctypes.c_int
@@ -143,6 +169,14 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """``"native library"`` or ``"python twins (<why>)"`` — which
+    implementation this process's callers get."""
+    if available():
+        return "native library"
+    return f"python twins ({_why_python})"
 
 
 def _i32(a) -> np.ndarray:

@@ -306,6 +306,10 @@ class EpochThroughput:
         self._t0 = time.perf_counter()
         self.prefix = prefix  # registry series + trace span name prefix
         r = _REGISTRY
+        # XLA compile requests so far (utils/compile_cache.py feeds the
+        # counter): finish() reports how many fell inside this epoch —
+        # a steady-state epoch should show none
+        self._compiles0 = r.counter("jax.compiles").value
         self._m_wait = r.histogram(f"{prefix}.input_wait_s")
         self._m_depth = r.histogram(f"{prefix}.queue_depth")
         self._m_inflight = r.histogram(f"{prefix}.inflight_steps")
@@ -362,6 +366,8 @@ class EpochThroughput:
                 self.input_bytes / wall / 2**20, 3) if wall > 0 else 0.0,
             "queue_depth_hist": dict(sorted(self.depth_hist.items())),
             "dispatch_ahead_occupancy": round(occ, 3),
+            "compiles": max(0, int(_REGISTRY.counter("jax.compiles").value
+                                   - self._compiles0)),
         }
         tokens = getattr(self, "_tokens", None)
         if tokens is not None:

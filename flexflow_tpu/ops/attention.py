@@ -136,6 +136,7 @@ class MultiHeadAttention(Op):
             from ..parallel.ring_attention import ulysses_attention
 
             sp = ulysses_attention if self.seq_mode == "a2a" else ring_attention
+            path = "ulysses" if self.seq_mode == "a2a" else "ring"
             ctxv = sp(
                 qh, kh, vh, ctx.mesh, self.seq_axis,
                 causal=self.causal, scale=scale,
@@ -173,10 +174,18 @@ class MultiHeadAttention(Op):
                         ctxv = fa.sharded_flash_attention(
                             qh, kh, vh, mesh, batch_ax, heads_ax,
                             causal=self.causal, scale=scale)
+            path = "flash"
             if ctxv is None:
+                path = "xla"
                 ctxv = single_device_attention(
                     qh, kh, vh, self.causal, scale, drop, ctx.rng
                 )
+        # which implementation this lowering took, counted once per trace:
+        # on `auto` a missing tune entry selects the jnp path without a
+        # word, and a chip run has to be able to say which one it timed
+        from ..obs.metrics import metrics_registry
+
+        metrics_registry().counter(f"attention.path.{path}").inc()
         out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
         if self.use_bias:
             out = out + weights["bo"]

@@ -30,8 +30,8 @@ import math
 from typing import List, Tuple
 
 import jax
-from ..utils.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 
 from ..ffconst import ActiMode, DataType, OpType
 from ..core.op import Op, register_op
@@ -61,10 +61,13 @@ def expert_capacity(batch: int, k: int, n: int, alpha: float) -> int:
     return int(math.ceil(alpha * k / n * batch))
 
 
-def _use_pallas(ctx) -> bool:
+def _use_pallas(ctx, tokens: int, k: int, n: int, capacity: int) -> bool:
+    """Single-device lowering with kernels on, at a routing shape the
+    kernels' SMEM operands fit (kernels/moe_kernels.supported)."""
     from ..kernels import use_pallas
+    from ..kernels.moe_kernels import supported
 
-    return use_pallas(ctx)
+    return use_pallas(ctx) and supported(tokens, k, n, capacity)
 
 
 def moe_dispatch_mask(assign: jnp.ndarray, n: int, capacity: int) -> jnp.ndarray:
@@ -92,7 +95,7 @@ def _dispatch_rows(ctx, x, assign, n: int, capacity: int, k: int):
     reference: group_by.cu)."""
     feat = x.shape[1:]
     xf = x.reshape(x.shape[0], -1)
-    if _use_pallas(ctx):
+    if _use_pallas(ctx, x.shape[0], k, n, capacity):
         from ..kernels.moe_kernels import moe_dispatch
 
         rows = moe_dispatch(xf, assign, n, capacity)
@@ -149,7 +152,8 @@ class _AggregateBase(Op):
         arrays, not compile-time shapes — the pipeline engine (and any
         microbatching caller) feeds fractions of the compiled batch, and a
         static reshape would silently mis-fold tokens into features."""
-        if ctx is not None and _use_pallas(ctx):
+        if ctx is not None and _use_pallas(
+                ctx, assign.shape[0], self.k, self.n, self.capacity):
             from ..kernels.moe_kernels import moe_combine
 
             return moe_combine(stacked, assign,
@@ -325,10 +329,13 @@ class GroupByStacked(Op):
             from ..kernels import pallas_mode
             from ..parallel.collectives import expert_all_to_all
 
+            from ..kernels.moe_kernels import supported as kernel_fits
+
             ax, deg = ep
             c_loc = self.capacity // deg
             n, k = self.n, self.k
-            use_kernel = pallas_mode() is not None
+            use_kernel = pallas_mode() is not None and kernel_fits(
+                x.shape[0] // deg, k, n, c_loc)
 
             def body(x_loc, assign_loc):
                 # per-shard dispatch (reference: group_by.cu scatter)
@@ -462,10 +469,13 @@ class AggregateStacked(_AggregateBase):
             from ..kernels import pallas_mode
             from ..parallel.collectives import experts_to_tokens
 
+            from ..kernels.moe_kernels import supported as kernel_fits
+
             ax, deg = ep
             c_loc = self.capacity // deg
             n, k = self.n, self.k
-            use_kernel = pallas_mode() is not None
+            use_kernel = pallas_mode() is not None and kernel_fits(
+                assign.shape[0] // deg, k, n, c_loc)
             # expert outputs back to the token-owning shards (inverse a2a)
             rows = experts_to_tokens(
                 stacked.reshape(self.n, self.capacity, -1), ctx.mesh, ax)

@@ -17,8 +17,8 @@ import functools
 from typing import Optional
 
 import jax
-from ..utils.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -79,7 +79,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.machine import DATA_AXIS, mesh_axis_sizes
@@ -793,8 +793,8 @@ class CompiledPipelinedModel(PipelinedModel):
         if with_metrics:
             out_specs = out_specs + (
                 P("pipe", None, DATA_AXIS) if dp > 1 else P("pipe"),)
-        fn = shard_map(shard_body, self._pmesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = shard_map(shard_body, mesh=self._pmesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
         return jax.jit(fn, donate_argnums=(0, 1))
 
     # ----------------------------------------------------------- audit

@@ -26,8 +26,9 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from ..utils.compat import pcast, shard_map
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -62,6 +62,11 @@ _METRICS_FROM_STRING = {
 
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
+        # every path that jits (fit, eval, serving) builds an FFModel
+        # first: place the persistent compilation cache here, before it
+        from ..utils.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
         self.config = config or FFConfig()
         self.layers: List[Layer] = []
         self.input_tensors: List[Tensor] = []

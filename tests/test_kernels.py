@@ -340,3 +340,14 @@ def test_flash_autotune_records_xla_ratio(monkeypatch, tmp_path):
     assert fa._TUNE_CACHE[(128, 128, 8, False)] == {"block_q": 64,
                                                     "xla_ratio": None}
     fa._TUNE_CACHE.clear()
+
+
+def test_moe_kernels_supported_counts_smem_operands():
+    """The kernels prefetch two 4-byte operands per pick or per slot into
+    a 1 MiB SMEM: the zoo MoE fits; 32k tokens (refused by Mosaic on a
+    v5e) do not, and take the einsum path instead."""
+    from flexflow_tpu.kernels.moe_kernels import supported
+
+    assert supported(64, 2, 5, 52)
+    assert supported(24576, 2, 8, 12288)          # 98,304 slots: the edge
+    assert not supported(32768, 2, 8, 16384)

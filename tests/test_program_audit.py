@@ -30,7 +30,7 @@ from flexflow_tpu.analysis.program_audit import (ExecutableSpec,
                                                  audit_spec, audit_traced,
                                                  lint_donated_reuse)
 from flexflow_tpu.models import build_mlp
-from flexflow_tpu.utils.compat import shard_map
+from jax import shard_map
 
 BS = 32
 F32 = jnp.float32

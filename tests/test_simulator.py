@@ -321,3 +321,32 @@ def test_per_op_family_backward_factors():
     from flexflow_tpu.sim.cost_model import BWD_FACTORS
     assert BWD_FACTORS[OpType.MULTIHEAD_ATTENTION] == 2.5
     assert BWD_FACTORS[OpType.CONV2D] == 2.0
+
+
+def test_detect_machine_model_keys_on_reported_device_kind(monkeypatch):
+    """The v5e reports device_kind "TPU v5 lite": that string, not a
+    fall-through, selects the v5e preset; a kind with no preset raises;
+    and no environment variable moves the preset's step_overhead."""
+    import types
+
+    import jax
+
+    from flexflow_tpu.sim import detect_machine_model
+    from flexflow_tpu.sim.machine_model import CHIP_PRESETS
+
+    def devices_of(kind, n=4):
+        return lambda *a, **k: [types.SimpleNamespace(
+            platform="tpu", device_kind=kind)] * n
+
+    monkeypatch.setattr(jax, "devices", devices_of("TPU v5 lite"))
+    m = detect_machine_model()
+    assert m.chip == CHIP_PRESETS["v5e"] and m.num_devices() == 4
+    for platforms in ("", "tpu", "cpu", "proxy", "proxy,cpu"):
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        assert (detect_machine_model(1).chip.step_overhead
+                == CHIP_PRESETS["v5e"].step_overhead)
+    monkeypatch.setattr(jax, "devices", devices_of("TPU v5"))
+    assert detect_machine_model().chip == CHIP_PRESETS["v5p"]
+    monkeypatch.setattr(jax, "devices", devices_of("TPU v9 mega"))
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        detect_machine_model()
