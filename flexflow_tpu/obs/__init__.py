@@ -6,12 +6,14 @@ predictions stay observable at runtime; cf. "A Learned Performance
 Model for Tensor Processing Units", arXiv:2008.01040, and FlexFlow's
 ``--profiling``/Legion Prof per-op device timing, arXiv:1807.05358):
 
-* :mod:`.trace` — thread-safe ring-buffered **span tracer** emitting
-  Chrome/Perfetto trace-event JSON. ~Free when disabled
-  (``config.trace=off``, the default); spans cover compile (search,
-  validation, lowering, cache hit/miss), the fit/eval step loop
-  (dispatch, input wait, host sync, recompile checks), the pipeline
-  engines, and serving (one span tree per request).
+* :mod:`.trace` — the **span tracer**: every span is a
+  ``jax.profiler`` annotation, so it is on the device trace's clock
+  under any profile, and with ``config.trace=on`` also an event of a
+  thread-safe ring exported as Chrome/Perfetto trace-event JSON. Spans
+  cover compile (search, validation, lowering, cache hit/miss), the
+  fit/eval step loop (dispatch, input wait, host sync, recompile
+  checks), the pipeline engines, and serving (the scheduler thread's
+  ``serving.loop.*`` phases; one span tree per request in the ring).
 * :mod:`.metrics` — named counters / gauges / histograms in one
   process-wide **registry** with JSON and Prometheus-text export, fed
   by the Prefetcher, the dispatch-ahead window, the strategy cache,

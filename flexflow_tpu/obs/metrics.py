@@ -20,6 +20,7 @@ cumulative series for the scrape.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import re
 import threading
@@ -28,6 +29,14 @@ from typing import Dict, List, Optional
 
 # quantiles exported for every histogram (Prometheus summary convention)
 _QUANTILES = (0.5, 0.9, 0.99)
+
+# upper bounds of every histogram's buckets: four a doubling from 0.1 ms
+# to 105 s (81 bounds; a last bucket takes what lies above). Fixed, so
+# two snapshots, or two processes' histograms, subtract and add bucket
+# by bucket. In JSON a bucket is keyed by its bound (``_BUCKET_KEYS``).
+BUCKET_BOUNDS = tuple(1e-4 * 2.0 ** (i / 4.0) for i in range(81))
+_BUCKET_KEYS = tuple(f"{b:.6g}" for b in BUCKET_BOUNDS) + ("inf",)
+_BUCKET_INDEX = {k: i for i, k in enumerate(_BUCKET_KEYS)}
 
 
 def nearest_rank_percentile(xs, q: float) -> float:
@@ -82,9 +91,14 @@ class Histogram:
     """count/sum/min/max plus a bounded reservoir of the most recent
     samples for percentile estimation (latency p50/p90/p99). The
     reservoir keeps the RECENT window — the flight-recorder convention,
-    matched to the tracer's ring buffer."""
+    matched to the tracer's ring buffer.
 
-    __slots__ = ("count", "sum", "min", "max", "_recent")
+    Beside it, counts per bucket at the fixed ``BUCKET_BOUNDS``, never
+    reset: the difference of two ``to_json()["buckets"]`` snapshots is
+    the distribution of exactly the observations between them, which a
+    reservoir of the last 1024 cannot give (:func:`bucket_delta`)."""
+
+    __slots__ = ("count", "sum", "min", "max", "_recent", "_buckets")
 
     def __init__(self, reservoir: int = 1024):
         self.count = 0
@@ -92,6 +106,7 @@ class Histogram:
         self.min = float("inf")
         self.max = float("-inf")
         self._recent: collections.deque = collections.deque(maxlen=reservoir)
+        self._buckets = [0] * len(_BUCKET_KEYS)
 
     def observe(self, v: float) -> None:
         v = float(v)
@@ -102,6 +117,7 @@ class Histogram:
         if v > self.max:
             self.max = v  # concurrency: race-ok (lock-free by design, see count)
         self._recent.append(v)
+        self._buckets[bisect.bisect_left(BUCKET_BOUNDS, v)] += 1  # concurrency: race-ok (lock-free by design, see count)
 
     def percentile(self, q: float) -> float:
         xs = sorted(self._recent)
@@ -123,6 +139,9 @@ class Histogram:
             "min": self.min,
             "max": self.max,
             **{f"p{int(q * 100)}": self.percentile(q) for q in _QUANTILES},
+            # {upper bound: count}, the buckets that hold something
+            "buckets": {k: n for k, n in zip(_BUCKET_KEYS, self._buckets)
+                        if n},
         }
 
     def merge(self, other: "Histogram") -> None:
@@ -130,6 +149,8 @@ class Histogram:
         self.sum += other.sum  # concurrency: race-ok (merge folds quiesced registries, see count)
         self.min = min(self.min, other.min)  # concurrency: race-ok (see count)
         self.max = max(self.max, other.max)  # concurrency: race-ok (see count)
+        self._buckets = [a + b for a, b in  # concurrency: race-ok (see count)
+                         zip(self._buckets, other._buckets)]
         # reservoir merge: appending ALL of other's window into the
         # maxlen-bounded deque would evict every one of self's samples
         # whenever other has >= maxlen entries — merged percentiles would
@@ -146,6 +167,16 @@ class Histogram:
             a, b = _strided(a, na), _strided(b, cap - na)
         self._recent = collections.deque(  # concurrency: race-ok (see count)
             _interleave(a, b), maxlen=cap)
+
+
+def bucket_delta(before: Optional[Dict], after: Dict) -> List[tuple]:
+    """``[(upper bound, count), ...]``, ascending: the observations
+    between two ``Histogram.to_json()`` snapshots of one histogram
+    (``before`` may be None or empty: everything up to ``after``)."""
+    b0 = (before or {}).get("buckets") or {}
+    rows = [(float(k), n - b0.get(k, 0))
+            for k, n in (after.get("buckets") or {}).items()]
+    return sorted((b, n) for b, n in rows if n)
 
 
 def _strided(xs: List[float], n: int) -> List[float]:
@@ -251,8 +282,9 @@ class MetricsRegistry:
     @staticmethod
     def from_json(doc: Dict) -> "MetricsRegistry":
         """Rebuild a registry from :meth:`to_json` output (histograms
-        keep count/sum/min/max — the reservoir, hence percentiles, is
-        not serialized). Types round-trip by JSON representation:
+        keep count/sum/min/max and the bucket counts — the reservoir,
+        hence percentiles, is not serialized). Types round-trip by JSON
+        representation:
         gauges always serialize as floats (``3.0``) and counters as
         ints when integral (``3``), so an integral-valued gauge still
         rebuilds as a Gauge and merges cleanly with a live registry.
@@ -267,6 +299,8 @@ class MetricsRegistry:
                 h.sum = float(v.get("sum", 0.0))
                 h.min = float(v.get("min", float("inf")))
                 h.max = float(v.get("max", float("-inf")))
+                for k, n in (v.get("buckets") or {}).items():
+                    h._buckets[_BUCKET_INDEX[k]] = int(n)
             elif isinstance(v, float):
                 reg.gauge(name).set(v)
             else:
@@ -314,7 +348,6 @@ class EpochThroughput:
         self._m_depth = r.histogram(f"{prefix}.queue_depth")
         self._m_inflight = r.histogram(f"{prefix}.inflight_steps")
         self._m_steps = r.counter(f"{prefix}.steps")
-        self._m_bytes = r.counter(f"{prefix}.input_bytes")
 
     def record_wait(self, seconds: float) -> None:
         """Time the consumer spent blocked on host batch assembly/transfer
@@ -338,7 +371,6 @@ class EpochThroughput:
         self.steps += n
         self.input_bytes += nbytes
         self._m_steps.inc(n)
-        self._m_bytes.inc(nbytes)
 
     def record_tokens(self, valid: int, total: int) -> None:
         """Token accounting for dynamic-shape epochs (runtime/buckets.py
@@ -379,5 +411,5 @@ class EpochThroughput:
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "EpochThroughput",
-    "metrics_registry",
+    "metrics_registry", "BUCKET_BOUNDS", "bucket_delta",
 ]

@@ -1,26 +1,33 @@
-"""Span tracer: ring-buffered Chrome/Perfetto trace-event recording.
+"""Span tracer: profiler annotations first, ring-buffered
+Chrome/Perfetto trace events when ``trace="on"``.
 
-Design constraints (the reasons this is not ``jax.profiler``):
+One kind of span (:func:`span`), two sinks:
 
-* **~free when disabled** — the hot step loop calls :func:`span` per
-  step; with tracing off that is one attribute read returning a shared
-  no-op context manager, no allocation, no lock. The existing
-  ``jax.profiler`` path (:func:`..runtime.profiling.trace`) stays for
-  XLA-level traces; this tracer covers the HOST-side control plane the
-  XLA trace can't see (search, cache, batcher queues, schedule replay).
-* **thread-safe** — serving workers, the Prefetcher worker, and the fit
-  loop all record concurrently. Events append to a bounded ``deque``
-  (GIL-atomic append; the ring bound makes an always-on tracer safe in
-  a long-lived serving process).
-* **standard output format** — ``export()`` writes Chrome trace-event
-  JSON (the ``{"traceEvents": [...]}`` object form), loadable in
-  ``chrome://tracing`` and https://ui.perfetto.dev. Spans are complete
-  ("ph": "X") events with microsecond ``ts``/``dur``; markers are
-  instant ("ph": "i") events.
+* **always a profiler annotation** — every span enters a
+  ``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` where it
+  is given a ``step_num``), so whenever a ``jax.profiler`` trace is
+  running the span lands on the ``/host:CPU`` plane of the
+  ``.xplane.pb``, on the device trace's own clock, as a line of the
+  thread that ran it. That is how an idle gap of the device gets the
+  name of what the host was doing in it. With no profiler running an
+  annotation is a flag test (about a microsecond a span to enter and
+  leave, its Python object included), so no knob turns the spans of
+  the hot loops off.
+* **a ring event when the tracer is enabled** — ``config.trace="on"`` /
+  ``--trace`` (:func:`configure_tracer`) also records the span as a
+  complete ("X") event on ``time.perf_counter`` into a bounded
+  ``deque`` (GIL-atomic append; the ring bound makes an always-on
+  tracer safe in a long-lived serving process). ``export()`` writes
+  Chrome trace-event JSON (the ``{"traceEvents": [...]}`` object form),
+  loadable in ``chrome://tracing`` and https://ui.perfetto.dev, with
+  microsecond ``ts``/``dur``; markers are instant ("ph": "i") events.
 
-One process-wide tracer (:func:`tracer`); ``config.trace="on"`` /
-``--trace`` flips it on at compile/fit/serve time
-(:func:`configure_tracer`).
+Spans written after the fact from stored stamps (:meth:`Tracer.complete`:
+the per-request serving trees on virtual tracks, the pipeline's schedule
+replay) exist only in the ring: an annotation cannot be back-dated.
+
+Thread-safe: serving workers, the Prefetcher worker and the fit loop
+all record concurrently. One process-wide tracer (:func:`tracer`).
 """
 
 from __future__ import annotations
@@ -32,47 +39,49 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 # Virtual thread-id base for per-request serving span trees: each request
 # renders on its own track so request spans never partially overlap real
 # threads' spans (serving/engine.py).
 VIRTUAL_TID_BASE = 1 << 20
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager returned while tracing is off."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopSpan()
-
-
 class _Span:
-    """A live span: records one complete ("X") event on exit."""
+    """A live span: a profiler annotation for as long as it is open,
+    and one complete ("X") ring event on exit if the tracer was enabled
+    when it was entered."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
-        self._t0 = 0.0
+        self._t0 = None
+        # a span that numbers its steps groups the device's work by
+        # step in the profile's viewers
+        self._ann = (StepTraceAnnotation if "step_num" in args
+                     else TraceAnnotation)(name, **args)
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the span: what it found to do."""
+        self._args.update(args)
+        self._ann.set_metadata(**args)
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer.complete(self._name, self._t0, t1 - self._t0,
-                              cat=self._cat, args=self._args or None)
+        if self._t0 is not None:
+            t1 = time.perf_counter()
+            self._tracer.complete(self._name, self._t0, t1 - self._t0,
+                                  cat=self._cat, args=self._args or None)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -105,10 +114,8 @@ class Tracer:
         return time.perf_counter()
 
     def span(self, name: str, cat: str = "", **args):
-        """Context manager timing a code region into one "X" event.
-        Returns the shared no-op when disabled — the fast path."""
-        if not self.enabled:
-            return _NOOP
+        """Context manager around a code region: a profiler annotation
+        ``name`` with ``args`` and, when enabled, one "X" event."""
         return _Span(self, name, cat, args)
 
     def complete(self, name: str, t0: float, dur_s: float, cat: str = "",
@@ -222,8 +229,6 @@ def trace_enabled() -> bool:
 
 def span(name: str, cat: str = "", **args):
     """Module-level convenience over the global tracer's :meth:`span`."""
-    if not _TRACER.enabled:
-        return _NOOP
     return _Span(_TRACER, name, cat, args)
 
 

@@ -34,7 +34,7 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..obs.trace import tracer
+from ..obs.trace import span
 from ..obs.watchdog import beat as _wd_beat
 from ..obs.watchdog import watch as _wd_watch
 from .buckets import PackingSpec, build_epoch_plan, plan_token_stats
@@ -492,7 +492,6 @@ class Prefetcher:
                     f"plan's item boundaries (prefix sums {plan[:idx]})")
             self.group.skip_batches(skip)
             plan = plan[idx:]
-        tr = tracer()
         # span name/cat track whichever loop drives us (fit vs eval) so
         # the trace agrees with the registry series the stats feed
         pfx = self.stats.prefix if self.stats is not None else "fit"
@@ -512,16 +511,15 @@ class Prefetcher:
                         section.__enter__()
                     elif i > 1:
                         _wd_beat(f"{pfx}.loop")
-                    t0 = time.perf_counter()
-                    host = self.group.assemble_host(k)
-                    wait = time.perf_counter() - t0
+                    with span(f"{pfx}.input_wait", cat=pfx, k=k,
+                              mode="serial"):
+                        t0 = time.perf_counter()
+                        host = self.group.assemble_host(k)
+                        wait = time.perf_counter() - t0
                     if self.stats is not None:
                         # serial mode: the whole inline assembly IS the wait
                         self.stats.record_wait(wait)
                         self.stats.record_depth(0)
-                    if tr.enabled:
-                        tr.complete(f"{pfx}.input_wait", t0, wait, cat=pfx,
-                                    args={"k": k, "mode": "serial"})
                     yield k, self.group.place(host, k)
             finally:
                 if section is not None:
@@ -562,9 +560,13 @@ class Prefetcher:
                 elif i > 1:
                     _wd_beat(f"{pfx}.loop")
                 depth_sample = chan.depth()
-                t0 = time.perf_counter()
-                item = chan.get()
-                wait = time.perf_counter() - t0
+                # (the wait for the end-of-epoch sentinel is a span too:
+                # the loop thread did wait there)
+                with span(f"{pfx}.input_wait", cat=pfx, depth=depth_sample,
+                          mode="prefetch"):
+                    t0 = time.perf_counter()
+                    item = chan.get()
+                    wait = time.perf_counter() - t0
                 if item is _DONE or item is _CLOSED:
                     return
                 if isinstance(item, _WorkerError):
@@ -574,10 +576,6 @@ class Prefetcher:
                     # not an input wait)
                     self.stats.record_depth(depth_sample)
                     self.stats.record_wait(wait)
-                if tr.enabled:
-                    tr.complete(f"{pfx}.input_wait", t0, wait, cat=pfx,
-                                args={"depth": depth_sample,
-                                      "mode": "prefetch"})
                 k, host = item
                 yield k, self.group.place(host, k)
         finally:

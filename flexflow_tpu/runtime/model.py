@@ -2023,7 +2023,7 @@ class FFModel:
         the lax.scan multi-step executable. Per-epoch throughput counters
         land in ``self.fit_profile``."""
         assert self.compiled is not None, "call compile() first"
-        _tr = configure_tracer(self.config)
+        configure_tracer(self.config)
         from ..obs.attribution import attribution_mode
         from ..obs.costcorpus import corpus_mode
         from ..obs.divergence import divergence_mode
@@ -2109,9 +2109,12 @@ class FFModel:
             inflight = collections.deque()
             steps_in_epoch = skip_steps if epoch == start_epoch else 0
             for nk, batch in pf.epoch(skip=steps_in_epoch):
-                # span per step: host-side dispatch + window control time
-                # (one flag check when tracing is off)
-                _ts = _tr.now() if _tr.enabled else 0.0
+                # span per step: host-side dispatch + window control
+                # time, numbered for the profiler. Entered and left by
+                # hand (the body is long and an exception ends the fit)
+                _step = span("fit.step", cat="fit", step_num=cm.iteration,
+                             k=nk)
+                _step.__enter__()
                 if self.pipelined is not None:
                     loss, bm = self.pipelined.train_step(
                         self._next_rng(), batch[:-1], batch[-1]
@@ -2252,9 +2255,7 @@ class FFModel:
                     if fired:
                         cm = self.compiled
                 prev_loss = loss
-                if _tr.enabled:
-                    _tr.complete("fit.step", _ts, _tr.now() - _ts,
-                                 cat="fit", args={"k": nk})
+                _step.__exit__(None, None, None)
             with span("fit.host_sync", cat="fit", epoch=epoch):
                 pm.flush()  # the epoch-boundary host sync (device-side accum)
             if dyn:
@@ -2372,7 +2373,7 @@ class FFModel:
         device-side metric accumulation with one sync at the end; the
         throughput record lands in ``self.eval_profile``."""
         assert self.compiled is not None
-        _tr = configure_tracer(self.config)
+        configure_tracer(self.config)
         from ..obs.ledger import ledger_mode
         from ..obs.watchdog import beat as _wd_beat
         from ..obs.watchdog import configure_watchdog
@@ -2392,7 +2393,8 @@ class FFModel:
         pm = PerfMetrics()
         inflight = collections.deque()
         for _nk, batch in pf.epoch(reshuffle=False):
-            _ts = _tr.now() if _tr.enabled else 0.0
+            _step = span("eval.step", cat="eval")
+            _step.__enter__()
             sl = self.iter_config.seq_length
             if dyn:
                 rows, sl = batch[-1].shape[0], batch[-1].shape[1]
@@ -2406,8 +2408,7 @@ class FFModel:
             self._advance_window(stats, inflight, loss, 1, batch_nbytes,
                                  max_inflight)
             _wd_beat("eval.loop")  # watchdog heartbeat (no-op when off)
-            if _tr.enabled:
-                _tr.complete("eval.step", _ts, _tr.now() - _ts, cat="eval")
+            _step.__exit__(None, None, None)
         with span("eval.host_sync", cat="eval"):
             pm.flush()
         if dyn:

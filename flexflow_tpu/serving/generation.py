@@ -56,6 +56,7 @@ import jax.numpy as jnp
 
 from ..ffconst import OpType
 from ..core.op import LowerCtx
+from ..obs.trace import span
 from .kv_cache import NULL_BLOCK, PagedKVPool
 
 
@@ -647,6 +648,9 @@ class PagedDecoder(_DecodeGraph):
         self._prefill_fns: Dict[Tuple[int, int], object] = {}
         self.decode_dispatches = 0
         self.decode_steps = 0
+        # called between a jitted call's return and the fetch of its
+        # logits: where the scheduler's clock divides dispatch from fetch
+        self.on_dispatched = None
         self.audit_report = None
         self.exec_telemetry = None
         # KVQ001 state: measured max-abs logit divergence of the
@@ -854,10 +858,11 @@ class PagedDecoder(_DecodeGraph):
             tabs[i, :t.shape[0]] = t
             lengths[i] = lens[i]
         fn = self._prefill_fn(bucket, width)
-        logits, self.pool.kv = fn(
-            self._exec_params(), jnp.asarray(toks), self.pool.kv,
-            jnp.asarray(tabs), jnp.asarray(lengths))
-        out = np.asarray(logits)
+        with span("serving.loop.dispatch", cat="serving"):
+            logits, self.pool.kv = fn(
+                self._exec_params(), jnp.asarray(toks), self.pool.kv,
+                jnp.asarray(tabs), jnp.asarray(lengths))
+        out = self._fetch(logits)
         rows = np.arange(len(arrs))
         return out[rows, np.asarray(lens) - 1]
 
@@ -867,13 +872,14 @@ class PagedDecoder(_DecodeGraph):
         many are active). Returns (slots, vocab) float32 logits."""
         self.decode_steps += 1
         self.decode_dispatches += 1
-        logits, self.pool.kv = self._decode(
-            self._exec_params(),
-            jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
-            self.pool.kv,
-            jnp.asarray(np.asarray(tables, np.int32)),
-            jnp.asarray(np.asarray(seq_lens, np.int32)))
-        return np.asarray(logits)
+        with span("serving.loop.dispatch", cat="serving"):
+            logits, self.pool.kv = self._decode(
+                self._exec_params(),
+                jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
+                self.pool.kv,
+                jnp.asarray(np.asarray(tables, np.int32)),
+                jnp.asarray(np.asarray(seq_lens, np.int32)))
+        return self._fetch(logits)
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
                seq_lens: np.ndarray) -> np.ndarray:
@@ -891,11 +897,21 @@ class PagedDecoder(_DecodeGraph):
             self._verify_fns[w] = fn
         self.decode_steps += 1
         self.decode_dispatches += 1
-        logits, self.pool.kv = fn(
-            self._exec_params(), jnp.asarray(tokens), self.pool.kv,
-            jnp.asarray(np.asarray(tables, np.int32)),
-            jnp.asarray(np.asarray(seq_lens, np.int32)))
-        return np.asarray(logits)
+        with span("serving.loop.dispatch", cat="serving"):
+            logits, self.pool.kv = fn(
+                self._exec_params(), jnp.asarray(tokens), self.pool.kv,
+                jnp.asarray(np.asarray(tables, np.int32)),
+                jnp.asarray(np.asarray(seq_lens, np.int32)))
+        return self._fetch(logits)
+
+    def _fetch(self, logits) -> np.ndarray:
+        """The other half of a dispatch: wait for the device and copy
+        the logits to the host."""
+        if self.on_dispatched is not None:
+            self.on_dispatched()
+        with span("serving.loop.fetch", cat="serving",
+                  bytes=logits.size * logits.dtype.itemsize):
+            return np.asarray(logits)
 
     # ---- KV quantization gate (KVQ001) -------------------------------------
     def _dense_reference_logits(self, tokens: np.ndarray) -> np.ndarray:

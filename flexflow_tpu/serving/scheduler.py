@@ -46,8 +46,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..obs.metrics import metrics_registry
-from ..obs.trace import VIRTUAL_TID_BASE, tracer
+from ..obs.metrics import Histogram, metrics_registry
+from ..obs.trace import VIRTUAL_TID_BASE, span, tracer
 from ..obs.watchdog import watch as _wd_watch
 from ..runtime.faults import InjectedFault, TransientFault
 from ..runtime.faults import fire as _fault_fire
@@ -97,6 +97,92 @@ def _percentiles(xs) -> Optional[Dict]:
             "p99": nearest_rank_percentile(xs, 0.99)}
 
 
+# what the scheduler's thread can be doing; every moment of its life is
+# charged to exactly one of these
+LOOP_PHASES = ("wait", "admit", "prefill", "inputs", "dispatch", "fetch",
+               "sample", "other")
+
+
+class _LoopClock:
+    """The scheduler thread's time by phase, always on. The thread
+    calls :meth:`enter` at every boundary: one clock read, which closes
+    the phase it was in and opens the next, so the phases telescope —
+    their sum is the time since the loop first started. ``steps``
+    counts the passes that ran a decode step or a speculative round
+    and ``step_wall`` holds their wall times; ``token_gap`` the time
+    between a request's consecutive tokens."""
+
+    def __init__(self):
+        self._lock = threading.Lock()  # enter() against snapshot()
+        self.phase_s = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.phase: Optional[str] = None  # None: the loop is not running
+        self.t = 0.0  # the last boundary
+        self.t_start: Optional[float] = None  # the first
+        self.steps = 0
+        self.step_wall = Histogram()
+        self.token_gap = Histogram()
+
+    def enter(self, phase: Optional[str]):
+        """The thread is in ``phase`` from now on. Returns the boundary
+        and the phase it closed."""
+        with self._lock:
+            now = time.perf_counter()
+            was = self.phase
+            if was is not None:
+                self.phase_s[was] += now - self.t
+            elif self.t_start is None:
+                self.t_start = now
+            else:  # a respawned worker: the gap since the crash
+                self.phase_s["other"] += now - self.t
+            self.phase = phase
+            self.t = now
+        return now, was
+
+    def count_step(self, wall_s: float) -> None:
+        with self._lock:
+            self.steps += 1
+        self.step_wall.observe(wall_s)
+
+    def snapshot(self) -> Dict:
+        """``stats()["loop"]``; the phase that is open is charged up to
+        this moment, so two snapshots subtract to what lay between."""
+        with self._lock:
+            now = time.perf_counter()  # under the lock: not before self.t
+            phase_s = dict(self.phase_s)
+            if self.phase is not None:
+                phase_s[self.phase] += now - self.t
+            else:
+                now = self.t  # the loop has ended, or never began
+            steps = self.steps
+            elapsed = 0.0 if self.t_start is None else now - self.t_start
+        return {"steps": steps, "elapsed_s": elapsed, "phase_s": phase_s,
+                "step_wall": self.step_wall.to_json(),
+                "token_gap": self.token_gap.to_json()}
+
+
+class _Phase:
+    """``with`` block of the scheduler's thread: a ``serving.loop.<name>``
+    span, during which the clock charges ``phase``; on exit the phase
+    that the block interrupted resumes. ``t0``/``t1``: its two ends."""
+
+    __slots__ = ("_clock", "_phase", "_outer", "span", "t0", "t1")
+
+    def __init__(self, clock: _LoopClock, name: str, phase: str, args: Dict):
+        self._clock = clock
+        self._phase = phase
+        self.span = span("serving.loop." + name, cat="serving", **args)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0, self._outer = self._clock.enter(self._phase)
+        return self
+
+    def __exit__(self, *exc):
+        self.t1, _ = self._clock.enter(self._outer)
+        self.span.__exit__(*exc)
+        return False
+
+
 class GenerationRequest:
     """One queued/in-flight generation request. The ``future`` resolves
     to the full (prompt + generated) int32 token array — exactly
@@ -106,8 +192,8 @@ class GenerationRequest:
                  "seed", "eos_id", "deadline_s", "t_enqueue", "future",
                  # scheduler-thread-only runtime state
                  "table", "seq_len", "tokens", "rng", "t_admit",
-                 "t_prefill_done", "t_first_token", "decode_t0",
-                 "decode_steps")
+                 "t_prefill_done", "t_first_token", "t_last_token",
+                 "decode_t0", "decode_steps")
 
     def __init__(self, request_id: int, prompt: np.ndarray,
                  max_new_tokens: int, temperature: float, seed: int,
@@ -128,6 +214,7 @@ class GenerationRequest:
         self.t_admit = None
         self.t_prefill_done = None
         self.t_first_token = None
+        self.t_last_token = None
         self.decode_t0 = None
         self.decode_steps = 0
 
@@ -234,11 +321,19 @@ class ContinuousBatchingScheduler:
         self._breaker_open_until = 0.0
         self._tokens_total = 0
         self._t_first_activity: Optional[float] = None
-        # per-phase latency windows for the session ledger record
+        # per-phase latency windows for the session ledger record, and
+        # beside each the session's whole count and sum (the window
+        # saturates at _PHASE_WINDOW; these do not)
         self._lat: Dict[str, collections.deque] = {
             k: collections.deque(maxlen=_PHASE_WINDOW)
             for k in ("queue_wait", "prefill", "decode", "ttft",
                       "per_token", "e2e")}
+        self._lat_total: Dict[str, List[float]] = {
+            k: [0, 0.0] for k in self._lat}
+        self._clock = _LoopClock()
+        for dec in filter(None, (self.decoder, self.draft)):
+            dec.on_dispatched = self._on_dispatched
+        self._publish_due = False
         self._shed = 0
         self._deadline_rejects = 0
         self._completed = 0
@@ -391,37 +486,66 @@ class ContinuousBatchingScheduler:
             if not r.future.done():
                 r.future.set_exception(wrapped)
 
+    def _phase(self, name: str, phase: Optional[str] = None,
+               **args) -> _Phase:
+        """The loop thread's next phase: the span ``serving.loop.<name>``
+        and the clock's ``phase`` (``name`` unless given)."""
+        return _Phase(self._clock, name, phase or name, args)
+
     def _loop(self) -> None:
+        self._clock.enter("other")  # a respawned worker resumes the clock
+        try:
+            self._loop_passes()
+        finally:
+            self._clock.enter(None)
+
+    def _loop_passes(self) -> None:
         import contextlib
+
+        def idle() -> bool:
+            return (not self._queue
+                    and not any(r is not None for r in self._slots))
 
         first_step = True
         while True:
             with self._mu:
-                while (not self._closed and not self._queue
-                       and not any(r is not None for r in self._slots)):
-                    self._mu.wait()
-                if (self._closed and not self._queue
-                        and not any(r is not None for r in self._slots)):
+                if not self._closed and idle():
+                    with self._phase("wait"):
+                        while not self._closed and idle():
+                            self._mu.wait()
+                if self._closed and idle():
                     return
                 closed = self._closed
-            # fault site: decode-worker crash — state stays on the
-            # scheduler, so the respawned worker resumes every request
-            rule = _fault_fire("serving.worker")
-            if rule is not None:
-                raise InjectedFault(
-                    f"injected fault at site 'serving.worker' ({rule})")
-            self._admit(closed)
-            with self._mu:
-                active = any(r is not None for r in self._slots)
-            if not active:
-                continue
-            # watchdog: only ACTIVE decode work is watched; the first
-            # step runs unwatched through the cold XLA compile
-            ctx = (contextlib.nullcontext() if first_step
-                   else _wd_watch(f"serving.gen.{self.name}"))
-            first_step = False
-            with ctx:
-                self._decode_once()
+                queued = len(self._queue)
+                active = sum(1 for r in self._slots if r is not None)
+            # one pass: admit, then one decode step or speculative
+            # round; whatever of it no inner span covers is "other"
+            step = self._phase("step", "other", step=self._clock.steps,
+                               active=active, queued=queued)
+            stepped = False
+            with step:
+                # fault site: decode-worker crash — state stays on the
+                # scheduler, so the respawned worker resumes every request
+                rule = _fault_fire("serving.worker")
+                if rule is not None:
+                    raise InjectedFault(
+                        f"injected fault at site 'serving.worker' ({rule})")
+                self._admit(closed)
+                with self._mu:
+                    active = any(r is not None for r in self._slots)
+                if active:
+                    # watchdog: only ACTIVE decode work is watched; the
+                    # first step runs unwatched through the cold XLA compile
+                    ctx = (contextlib.nullcontext() if first_step
+                           else _wd_watch(f"serving.gen.{self.name}"))
+                    first_step = False
+                    with ctx:
+                        stepped = self._decode_once()
+                if self._publish_due:
+                    self._publish_due = False  # hotpath: lock-ok (flag of the loop thread alone)
+                    self._publish_attribution()
+            if stepped:
+                self._clock.count_step(step.t1 - step.t0)
 
     # ---- admission between decode steps ------------------------------------
     def _admit(self, closed: bool) -> None:
@@ -432,8 +556,15 @@ class ContinuousBatchingScheduler:
         prefilled per call, bounding the decode stall a prompt burst can
         cause. With ``prefill_token_budget`` set the stall bound is
         token-native instead: see :meth:`_admit_batched`."""
-        if self.prefill_token_budget > 0:
-            return self._admit_batched(closed)
+        with self._phase("admit") as ph:
+            if self.prefill_token_budget > 0:
+                n = self._admit_batched(closed)
+            else:
+                n = self._admit_single(closed)
+            ph.span.set(admitted=n)
+
+    def _admit_single(self, closed: bool) -> int:
+        """One prefill dispatch a prompt; returns how many it admitted."""
         reg = metrics_registry()
         with self._mu:
             active = any(r is not None for r in self._slots)
@@ -443,7 +574,7 @@ class ContinuousBatchingScheduler:
         while admitted < budget:
             with self._mu:
                 if not self._queue:
-                    return
+                    return admitted
                 req = self._queue.popleft()
             if closed:
                 if not req.future.done():
@@ -470,7 +601,7 @@ class ContinuousBatchingScheduler:
             if slot is None:
                 with self._mu:
                     self._queue.appendleft(req)
-                return
+                return admitted
             table = self.decoder.pool.try_admit(
                 req.prompt.size + req.max_new_tokens)
             if table is None:
@@ -478,11 +609,11 @@ class ContinuousBatchingScheduler:
                 # retirement (bounded — actives free their worst case)
                 with self._mu:
                     self._queue.appendleft(req)
-                return
+                return admitted
             with self._mu:
                 req.table = table
                 req.t_admit = now
-                self._lat["queue_wait"].append(now - req.t_enqueue)
+                self._observe_lat("queue_wait", now - req.t_enqueue)
             reg.histogram("serving.gen_queue_wait_s").observe(
                 now - req.t_enqueue)
             try:
@@ -498,8 +629,16 @@ class ContinuousBatchingScheduler:
                 continue
             with self._mu:
                 self._slots[slot] = req
+        return admitted
 
-    def _admit_batched(self, closed: bool) -> None:
+    def _observe_lat(self, phase: str, seconds: float) -> None:
+        """One sample of a request phase; the caller holds ``_mu``."""
+        self._lat[phase].append(seconds)
+        total = self._lat_total[phase]
+        total[0] += 1  # hotpath: lock-ok (the caller holds _mu)
+        total[1] += seconds  # hotpath: lock-ok (the caller holds _mu)
+
+    def _admit_batched(self, closed: bool) -> int:
         """Token-budget admission: the same deadline/slot/pool gates as
         the one-per-dispatch path, but admitted prompts are grouped by
         prefill bucket and each group runs through ONE batched prefill
@@ -507,7 +646,8 @@ class ContinuousBatchingScheduler:
         prompts. While decodes are active, collection stops once the
         group's padded prefill tokens would pass the budget — the
         decode-stall bound is measured in tokens, which is what the
-        stall actually costs, instead of prompt count."""
+        stall actually costs, instead of prompt count. Returns how many
+        prompts it sent to prefill."""
         reg = metrics_registry()
         with self._mu:
             active = any(r is not None for r in self._slots)
@@ -562,14 +702,14 @@ class ContinuousBatchingScheduler:
             with self._mu:
                 req.table = table
                 req.t_admit = now
-                self._lat["queue_wait"].append(now - req.t_enqueue)
+                self._observe_lat("queue_wait", now - req.t_enqueue)
             reg.histogram("serving.gen_queue_wait_s").observe(
                 now - req.t_enqueue)
             reserved.add(slot)
             spent += bucket
             batch.append((slot, req, bucket))
         if not batch:
-            return
+            return 0
         groups: Dict[int, List] = {}
         for slot, req, bucket in batch:
             groups.setdefault(bucket, []).append((slot, req))
@@ -577,27 +717,31 @@ class ContinuousBatchingScheduler:
             members = groups[bucket]
             cap = max(1, self.prefill_token_budget // bucket)
             for i in range(0, len(members), cap):
-                self._prefill_group(members[i:i + cap])
+                self._prefill_group(members[i:i + cap], bucket)
+        return len(batch)
 
-    def _prefill_group(self, members: List) -> None:
+    def _prefill_group(self, members: List, bucket: int) -> None:
         """ONE batched prefill dispatch for same-bucket requests; a
         dispatch failure fails exactly the group's requests (their
         blocks free), mirroring the single-prefill error contract."""
         reg = metrics_registry()
         reqs = [r for _, r in members]
-        t0 = time.perf_counter()
         try:
-            logits = _DECODE_RETRY.call(
-                self.decoder.prefill_many,
-                [r.prompt for r in reqs], [r.table for r in reqs])
-            if self.draft is not None:
-                # prime the draft's arenas through the SAME block
-                # tables (its prefill logits are unused — the first
-                # generated token is sampled from the target, exactly
-                # like non-speculative serving)
-                _DECODE_RETRY.call(
-                    self.draft.prefill_many,
+            with self._phase(
+                    "prefill", bucket=bucket,
+                    request_ids=",".join(str(r.request_id) for r in reqs),
+                    tokens=sum(int(r.prompt.size) for r in reqs)) as ph:
+                logits = _DECODE_RETRY.call(
+                    self.decoder.prefill_many,
                     [r.prompt for r in reqs], [r.table for r in reqs])
+                if self.draft is not None:
+                    # prime the draft's arenas through the SAME block
+                    # tables (its prefill logits are unused — the first
+                    # generated token is sampled from the target,
+                    # exactly like non-speculative serving)
+                    _DECODE_RETRY.call(
+                        self.draft.prefill_many,
+                        [r.prompt for r in reqs], [r.table for r in reqs])
         except Exception as e:  # noqa: BLE001 — fail the group only
             reg.counter("serving.errors").inc()
             for _, req in members:
@@ -605,7 +749,7 @@ class ContinuousBatchingScheduler:
                 if not req.future.done():
                     req.future.set_exception(e)
             return
-        t_done = time.perf_counter()
+        t0, t_done = ph.t0, ph.t1
         with self._mu:
             self._prefill_dispatches += 1
             self._prefill_prompts += len(reqs)
@@ -613,113 +757,158 @@ class ContinuousBatchingScheduler:
                 req.t_prefill_done = t_done
                 req.seq_len = req.prompt.size
                 req.rng = np.random.default_rng(req.seed)
-                self._lat["prefill"].append(t_done - t0)
+                self._observe_lat("prefill", t_done - t0)
         reg.histogram("serving.prefill_s").observe(t_done - t0)
-        for i, (slot, req) in enumerate(members):
-            self._append_token(req, logits[i])
-            if req.future.done():  # single-token request retired here
-                continue
-            with self._mu:
-                self._slots[slot] = req
+        with self._phase("sample", tokens=len(members)):
+            for i, (slot, req) in enumerate(members):
+                self._append_token(req, logits[i])
+                if req.future.done():  # single-token request retired here
+                    continue
+                with self._mu:
+                    self._slots[slot] = req
 
     def _prefill(self, req: GenerationRequest) -> None:
-        t0 = time.perf_counter()
-        logits = _DECODE_RETRY.call(self.decoder.prefill, req.prompt,
-                                    req.table)
-        if self.draft is not None:
-            # prime the draft's arenas through the SAME block table
-            # (its prefill logits are unused)
-            _DECODE_RETRY.call(self.draft.prefill, req.prompt, req.table)
-        t_done = time.perf_counter()
+        with self._phase("prefill", request_id=req.request_id,
+                         bucket=self.decoder.bucket_for(req.prompt.size),
+                         tokens=int(req.prompt.size)) as ph:
+            logits = _DECODE_RETRY.call(self.decoder.prefill, req.prompt,
+                                        req.table)
+            if self.draft is not None:
+                # prime the draft's arenas through the SAME block table
+                # (its prefill logits are unused)
+                _DECODE_RETRY.call(self.draft.prefill, req.prompt,
+                                   req.table)
+        t0, t_done = ph.t0, ph.t1
         with self._mu:
             self._prefill_dispatches += 1
             self._prefill_prompts += 1
             req.t_prefill_done = t_done
             req.seq_len = req.prompt.size
             req.rng = np.random.default_rng(req.seed)
-            self._lat["prefill"].append(t_done - t0)
+            self._observe_lat("prefill", t_done - t0)
         metrics_registry().histogram("serving.prefill_s").observe(
             t_done - t0)
-        self._append_token(req, logits)
+        with self._phase("sample", tokens=1):
+            self._append_token(req, logits)
 
     # ---- decode ------------------------------------------------------------
-    def _decode_once(self) -> None:
-        if self.spec_k > 0 and self.draft is not None:
-            return self._spec_once()
+    def _step_inputs(self):
+        """What a decode step or speculative round starts from: the
+        live slots, with each one's last token, block table and cached
+        length in slot-width arrays; None where no slot is live."""
         reg = metrics_registry()
-        now = time.perf_counter()
-        with self._mu:
-            slots = list(self._slots)
-        # deadline gate: expired in-flight requests are rejected BEFORE
-        # their next decode step (their remaining tokens would be served
-        # to nobody); their blocks free immediately
-        expired = set()
-        for i, req in enumerate(slots):
-            if req is not None and req.expired(now):
-                expired.add(i)
-                with self._mu:
-                    self._slots[i] = None
-                    self._deadline_rejects += 1
-                reg.counter("serving.deadline_rejects").inc()
-                self.decoder.pool.free(req.table)
-                if not req.future.done():
-                    req.future.set_exception(DeadlineExceeded(
-                        f"request {req.request_id} exceeded its deadline "
-                        f"{req.deadline_s:.3f}s mid-decode "
-                        f"({len(req.tokens)}/{req.max_new_tokens} tokens)"))
-        active = [(i, r) for i, r in enumerate(slots)
-                  if r is not None and i not in expired]
-        if not active:
-            return
-        n_slots = len(slots)
-        tokens = np.zeros(n_slots, np.int32)
-        tables = np.zeros(
-            (n_slots, self.decoder.max_blocks_per_request), np.int32)
-        seq_lens = np.zeros(n_slots, np.int32)
-        with self._mu:
-            for i, req in active:
-                tokens[i] = req.tokens[-1]
-                tables[i] = req.table
-                seq_lens[i] = req.seq_len
-                if req.decode_t0 is None:
-                    req.decode_t0 = time.perf_counter()
-        t0 = time.perf_counter()
+        with self._phase("inputs") as ph:
+            now = ph.t0
+            with self._mu:
+                slots = list(self._slots)
+            # deadline gate: expired in-flight requests are rejected
+            # BEFORE their next decode step (their remaining tokens would
+            # be served to nobody); their blocks free immediately
+            expired = set()
+            for i, req in enumerate(slots):
+                if req is not None and req.expired(now):
+                    expired.add(i)
+                    with self._mu:
+                        self._slots[i] = None
+                        self._deadline_rejects += 1
+                    reg.counter("serving.deadline_rejects").inc()
+                    self.decoder.pool.free(req.table)
+                    if not req.future.done():
+                        req.future.set_exception(DeadlineExceeded(
+                            f"request {req.request_id} exceeded its "
+                            f"deadline {req.deadline_s:.3f}s mid-decode "
+                            f"({len(req.tokens)}/{req.max_new_tokens} "
+                            f"tokens)"))
+            active = [(i, r) for i, r in enumerate(slots)
+                      if r is not None and i not in expired]
+            if not active:
+                return None
+            n_slots = len(slots)
+            tokens = np.zeros(n_slots, np.int32)
+            tables = np.zeros(
+                (n_slots, self.decoder.max_blocks_per_request), np.int32)
+            seq_lens = np.zeros(n_slots, np.int32)
+            with self._mu:
+                for i, req in active:
+                    tokens[i] = req.tokens[-1]
+                    tables[i] = req.table
+                    seq_lens[i] = req.seq_len
+                    if req.decode_t0 is None:
+                        req.decode_t0 = now
+        return active, tokens, tables, seq_lens
+
+    def _dispatch(self, fn, *args):
+        """One jitted call and the fetch of its logits, on the clock:
+        ``dispatch`` until the call returns, ``fetch`` (entered by
+        :meth:`_on_dispatched`, between the decoder's two spans) until
+        the logits are on the host. Returns the logits and the pair's
+        two ends."""
+        t0, outer = self._clock.enter("dispatch")
         try:
-            logits = _DECODE_RETRY.call(self.decoder.decode, tokens,
-                                        tables, seq_lens)
-        except Exception as e:  # noqa: BLE001 — fail the step's requests
-            reg.counter("serving.errors").inc()
-            for i, req in active:
-                with self._mu:
-                    self._slots[i] = None
-                self.decoder.pool.free(req.table)
-                if not req.future.done():
-                    req.future.set_exception(e)
-            if self.breaker_threshold:
-                with self._mu:
-                    self._consec_failures += 1
-                    # transition-only (==): repeated failures behind an
-                    # open breaker must not re-extend the cooldown
-                    opened = (self._consec_failures
-                              == self.breaker_threshold)
-                    if opened:
-                        self._breaker_open_until = (
-                            time.monotonic() + self.breaker_cooldown_s)
-                if opened:
-                    reg.counter("serving.breaker_opens").inc()
-            return
-        dt = time.perf_counter() - t0
-        reg.histogram("serving.decode_step_s").observe(dt)
+            logits = _DECODE_RETRY.call(fn, *args)
+        finally:
+            t1, _ = self._clock.enter(outer)
+        return logits, t0, t1
+
+    def _on_dispatched(self) -> None:
+        """A decoder's jitted call has returned and its fetch begins. In
+        a prefill the clock stays in ``prefill``, which covers both."""
+        if self._clock.phase == "dispatch":
+            self._clock.enter("fetch")
+
+    def _fail_step(self, active, e: Exception) -> None:
+        """A step's dispatch failed: fail its requests, free their
+        blocks, and count the failure towards the breaker."""
+        reg = metrics_registry()
+        reg.counter("serving.errors").inc()
         for i, req in active:
             with self._mu:
-                req.seq_len += 1
-                req.decode_steps += 1
-            self._append_token(req, logits[i])
+                self._slots[i] = None
+            self.decoder.pool.free(req.table)
+            if not req.future.done():
+                req.future.set_exception(e)
         if self.breaker_threshold:
-            with self._mu:  # a served step closes the failure streak
-                self._consec_failures = 0
+            with self._mu:
+                self._consec_failures += 1
+                # transition-only (==): repeated failures behind an
+                # open breaker must not re-extend the cooldown
+                opened = (self._consec_failures
+                          == self.breaker_threshold)
+                if opened:
+                    self._breaker_open_until = (
+                        time.monotonic() + self.breaker_cooldown_s)
+            if opened:
+                reg.counter("serving.breaker_opens").inc()
 
-    def _spec_once(self) -> None:
+    def _decode_once(self) -> bool:
+        """One decode step for every live slot (one speculative round
+        with speculation on). True where the step was served."""
+        if self.spec_k > 0 and self.draft is not None:
+            return self._spec_once()
+        inputs = self._step_inputs()
+        if inputs is None:
+            return False
+        active, tokens, tables, seq_lens = inputs
+        try:
+            logits, t0, t1 = self._dispatch(self.decoder.decode, tokens,
+                                            tables, seq_lens)
+        except Exception as e:  # noqa: BLE001 — fail the step's requests
+            self._fail_step(active, e)
+            return False
+        metrics_registry().histogram("serving.decode_step_s").observe(
+            t1 - t0)
+        with self._phase("sample", tokens=len(active)):
+            for i, req in active:
+                with self._mu:
+                    req.seq_len += 1
+                    req.decode_steps += 1
+                self._append_token(req, logits[i])
+            if self.breaker_threshold:
+                with self._mu:  # a served step closes the failure streak
+                    self._consec_failures = 0
+        return True
+
+    def _spec_once(self) -> bool:
         """One speculative round: ``spec_k`` draft proposals per live
         slot (k+1 draft dispatches — the extra one writes the last
         proposal's K/V so the draft cache stays position-complete for
@@ -749,142 +938,102 @@ class ContinuousBatchingScheduler:
         Rejected suffixes never touch other slots: acceptance is pure
         per-row host bookkeeping over the shared dispatch."""
         reg = metrics_registry()
-        now = time.perf_counter()
-        with self._mu:
-            slots = list(self._slots)
-        expired = set()
-        for i, req in enumerate(slots):
-            if req is not None and req.expired(now):
-                expired.add(i)
-                with self._mu:
-                    self._slots[i] = None
-                    self._deadline_rejects += 1
-                reg.counter("serving.deadline_rejects").inc()
-                self.decoder.pool.free(req.table)
-                if not req.future.done():
-                    req.future.set_exception(DeadlineExceeded(
-                        f"request {req.request_id} exceeded its deadline "
-                        f"{req.deadline_s:.3f}s mid-decode "
-                        f"({len(req.tokens)}/{req.max_new_tokens} tokens)"))
-        active = [(i, r) for i, r in enumerate(slots)
-                  if r is not None and i not in expired]
-        if not active:
-            return
+        inputs = self._step_inputs()
+        if inputs is None:
+            return False
+        active, base_tokens, tables, seq_lens = inputs
         k = self.spec_k
-        n_slots = len(slots)
-        base_tokens = np.zeros(n_slots, np.int32)
-        tables = np.zeros(
-            (n_slots, self.decoder.max_blocks_per_request), np.int32)
-        seq_lens = np.zeros(n_slots, np.int32)
-        with self._mu:
-            for i, req in active:
-                base_tokens[i] = req.tokens[-1]
-                tables[i] = req.table
-                seq_lens[i] = req.seq_len
-                if req.decode_t0 is None:
-                    req.decode_t0 = time.perf_counter()
-        t0 = time.perf_counter()
+        n_slots = len(base_tokens)
+        t0 = None  # the round's first dispatch
         proposals = np.zeros((n_slots, k), np.int32)
         qdists: List[Optional[List[np.ndarray]]] = [None] * n_slots
         try:
             cur = base_tokens.copy()
             lens = seq_lens.copy()
             for j in range(k + 1):
-                dlogits = _DECODE_RETRY.call(self.draft.decode, cur,
-                                             tables, lens)
+                dlogits, tj, _ = self._dispatch(self.draft.decode, cur,
+                                                tables, lens)
+                t0 = tj if t0 is None else t0
                 lens = lens + 1
                 if j == k:
                     break  # cache-sync dispatch: writes d_k, logits unused
                 nxt = np.zeros(n_slots, np.int32)
-                for i, req in active:
-                    if req.temperature > 0:
-                        q = _temp_softmax(dlogits[i], req.temperature)
-                        if qdists[i] is None:
-                            qdists[i] = []  # hotpath: lock-ok (round-local list, never shared)
-                        qdists[i].append(q)
-                        nxt[i] = int(req.rng.choice(q.shape[-1], p=q))  # hotpath: lock-ok (round-local array)
-                    else:
-                        nxt[i] = int(dlogits[i].argmax(-1))  # hotpath: lock-ok (round-local array)
-                proposals[:, j] = nxt  # hotpath: lock-ok (round-local array)
+                with self._phase("sample", tokens=len(active)):
+                    for i, req in active:
+                        if req.temperature > 0:
+                            q = _temp_softmax(dlogits[i], req.temperature)
+                            if qdists[i] is None:
+                                qdists[i] = []  # hotpath: lock-ok (round-local list, never shared)
+                            qdists[i].append(q)
+                            nxt[i] = int(req.rng.choice(q.shape[-1], p=q))  # hotpath: lock-ok (round-local array)
+                        else:
+                            nxt[i] = int(dlogits[i].argmax(-1))  # hotpath: lock-ok (round-local array)
+                    proposals[:, j] = nxt  # hotpath: lock-ok (round-local array)
                 cur = nxt
             window = np.zeros((n_slots, k + 1), np.int32)
             window[:, 0] = base_tokens  # hotpath: lock-ok (round-local array)
             window[:, 1:] = proposals  # hotpath: lock-ok (round-local array)
-            vlogits = _DECODE_RETRY.call(self.decoder.verify, window,
-                                         tables, seq_lens)
+            vlogits, _, t1 = self._dispatch(self.decoder.verify, window,
+                                            tables, seq_lens)
         except Exception as e:  # noqa: BLE001 — fail the step's requests
-            reg.counter("serving.errors").inc()
+            self._fail_step(active, e)
+            return False
+        reg.histogram("serving.decode_step_s").observe(t1 - t0)
+        with self._phase("sample", tokens=len(active)):
             for i, req in active:
-                with self._mu:
-                    self._slots[i] = None
-                self.decoder.pool.free(req.table)
-                if not req.future.done():
-                    req.future.set_exception(e)
-            if self.breaker_threshold:
-                with self._mu:
-                    self._consec_failures += 1
-                    opened = (self._consec_failures
-                              == self.breaker_threshold)
-                    if opened:
-                        self._breaker_open_until = (
-                            time.monotonic() + self.breaker_cooldown_s)
-                if opened:
-                    reg.counter("serving.breaker_opens").inc()
-            return
-        dt = time.perf_counter() - t0
-        reg.histogram("serving.decode_step_s").observe(dt)
-        for i, req in active:
-            matched = 0
-            emitted = 0
-            done = False
-            accepted = True
-            for j in range(k):
-                row = np.asarray(vlogits[i, j])
-                d = int(proposals[i, j])
-                if req.temperature > 0:
-                    p = _temp_softmax(row, req.temperature)
-                    q = qdists[i][j]
-                    u = req.rng.uniform()
-                    if q[d] > 0 and u < min(1.0, float(p[d]) / float(q[d])):
-                        tok = d
-                        accepted = True
+                matched = 0
+                emitted = 0
+                done = False
+                accepted = True
+                for j in range(k):
+                    row = np.asarray(vlogits[i, j])
+                    d = int(proposals[i, j])
+                    if req.temperature > 0:
+                        p = _temp_softmax(row, req.temperature)
+                        q = qdists[i][j]
+                        u = req.rng.uniform()
+                        if q[d] > 0 and u < min(
+                                1.0, float(p[d]) / float(q[d])):
+                            tok = d
+                            accepted = True
+                        else:
+                            resid = np.maximum(p - q, 0.0)
+                            tot = resid.sum()
+                            tok = (int(req.rng.choice(
+                                       resid.shape[-1], p=resid / tot))
+                                   if tot > 0 else
+                                   int(req.rng.choice(p.shape[-1], p=p)))
+                            accepted = False
                     else:
-                        resid = np.maximum(p - q, 0.0)
-                        tot = resid.sum()
-                        tok = (int(req.rng.choice(
-                                   resid.shape[-1], p=resid / tot))
-                               if tot > 0 else
-                               int(req.rng.choice(p.shape[-1], p=p)))
-                        accepted = False
-                else:
-                    tok = int(row.argmax(-1))
-                    accepted = tok == d
-                emitted += 1
-                done = self._commit_token(req, tok, advance_seq=True)
-                if done or not accepted:
-                    break
-                matched += 1
-            if accepted and not done and matched == k:
-                # every proposal accepted: the bonus token rides the
-                # last verify row for free
-                tok = sample_next_token(np.asarray(vlogits[i, k]),
-                                        req.temperature, req.rng)
-                emitted += 1
-                self._commit_token(req, tok, advance_seq=True)
-            with self._mu:
-                req.decode_steps += 1
-                self._spec_slot_rounds += 1
-                self._spec_proposed += k
-                self._spec_matched += matched
-                self._spec_emitted += emitted
-            reg.histogram("serving.spec_accept_rate").observe(matched / k)
-            reg.histogram("serving.spec_tokens_per_dispatch").observe(
-                emitted)
-        with self._mu:  # one verify dispatch served this whole round
-            self._spec_rounds += 1
-        if self.breaker_threshold:
-            with self._mu:  # a served step closes the failure streak
-                self._consec_failures = 0
+                        tok = int(row.argmax(-1))
+                        accepted = tok == d
+                    emitted += 1
+                    done = self._commit_token(req, tok, advance_seq=True)
+                    if done or not accepted:
+                        break
+                    matched += 1
+                if accepted and not done and matched == k:
+                    # every proposal accepted: the bonus token rides the
+                    # last verify row for free
+                    tok = sample_next_token(np.asarray(vlogits[i, k]),
+                                            req.temperature, req.rng)
+                    emitted += 1
+                    self._commit_token(req, tok, advance_seq=True)
+                with self._mu:
+                    req.decode_steps += 1
+                    self._spec_slot_rounds += 1
+                    self._spec_proposed += k
+                    self._spec_matched += matched
+                    self._spec_emitted += emitted
+                reg.histogram("serving.spec_accept_rate").observe(matched / k)
+                reg.histogram("serving.spec_tokens_per_dispatch").observe(
+                    emitted)
+            with self._mu:  # one verify dispatch served this whole round
+                self._spec_rounds += 1
+            if self.breaker_threshold:
+                with self._mu:  # a served step closes the failure streak
+                    self._consec_failures = 0
+        return True
 
     def _append_token(self, req: GenerationRequest, row_logits) -> None:
         """Sample the next token for one request (mask-aware: only
@@ -910,13 +1059,20 @@ class ContinuousBatchingScheduler:
             if req.t_first_token is None:
                 req.t_first_token = now
                 ttft = now - req.t_enqueue
-                self._lat["ttft"].append(ttft)
+                self._observe_lat("ttft", ttft)
             self._tokens_total += 1
             total = self._tokens_total
             t_start = self._t_first_activity
+            gap = (None if req.t_last_token is None
+                   else now - req.t_last_token)
+            req.t_last_token = now
         if ttft is not None:
             metrics_registry().histogram("serving.ttft_s").observe(ttft)
-        metrics_registry().counter("serving.gen_tokens").inc()
+        if gap is not None:
+            # every token after a request's first. A speculative round
+            # commits its tokens at one instant: the round's gap once,
+            # then gaps of microseconds, which land in the lowest bucket
+            self._clock.token_gap.observe(gap)
         if t_start is not None and now > t_start:
             metrics_registry().gauge("serving.tokens_per_s").set(
                 total / (now - t_start))
@@ -939,24 +1095,25 @@ class ContinuousBatchingScheduler:
         n = len(req.tokens)
         e2e = now - req.t_enqueue
         with self._mu:  # stats() snapshots these under the same lock
-            self._lat["e2e"].append(e2e)
-            self._lat["per_token"].append(e2e / n)
+            self._observe_lat("e2e", e2e)
+            self._observe_lat("per_token", e2e / n)
             if req.decode_t0 is not None:
-                self._lat["decode"].append(now - req.decode_t0)
+                self._observe_lat("decode", now - req.decode_t0)
         reg.histogram("serving.gen_e2e_s").observe(e2e)
         reg.histogram("serving.per_token_s").observe(e2e / n)
         reg.counter("serving.batches").inc()
         self._record_request_spans(req, now)
         req.future.set_result(out)
-        # publish AFTER the future resolves (telemetry must not ride the
-        # client-visible latency) and throttled: the first retirement
-        # arms the /attribution surface immediately, then every
-        # _PUBLISH_EVERY-th refreshes it; stop() publishes the final
-        # table either way — eventual freshness, not per-request sorts
+        # publish AFTER the future resolves and after the pass's other
+        # slots have their tokens (the loop does it at the end of the
+        # pass: telemetry must not ride the client-visible latency), and
+        # throttled: the first retirement arms the /attribution surface,
+        # then every _PUBLISH_EVERY-th refreshes it; stop() publishes
+        # the final table either way — eventual freshness
         with self._mu:
             completed = self._completed
         if completed % _PUBLISH_EVERY == 1:
-            self._publish_attribution()
+            self._publish_due = True  # hotpath: lock-ok (flag of the loop thread alone)
 
     # ---- observability -----------------------------------------------------
     def _record_request_spans(self, req: GenerationRequest,
@@ -998,7 +1155,12 @@ class ContinuousBatchingScheduler:
             completed = self._completed
             prefill_dispatches = self._prefill_dispatches
             prefill_prompts = self._prefill_prompts
+            # the window's percentiles ("count" is the window's length,
+            # at most _PHASE_WINDOW) beside the session's true totals
             phases = {k: _percentiles(v) for k, v in self._lat.items()}
+            for k, (n, sum_s) in self._lat_total.items():
+                if phases[k] is not None:
+                    phases[k].update(n=n, sum_s=sum_s)
             spec_rounds = self._spec_rounds
             spec_slot_rounds = self._spec_slot_rounds
             spec_proposed = self._spec_proposed
@@ -1022,6 +1184,7 @@ class ContinuousBatchingScheduler:
             "shed": shed,
             "deadline_rejects": deadline,
             "phases": phases,
+            "loop": self._clock.snapshot(),
             "kv": kv,
             "decode_steps": self.decoder.decode_steps,
             "decode_dispatches": self.decoder.decode_dispatches,

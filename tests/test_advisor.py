@@ -641,9 +641,12 @@ def test_child_fit_subprocess_smoke():
 
 
 # ------------------------------------------- /advice + serving attribution
-def test_advice_endpoint_404_then_report():
+def test_advice_endpoint_404_then_report(monkeypatch):
+    import flexflow_tpu.obs.server as obs_server_mod
     from flexflow_tpu.obs.server import (ObsServer, publish_advice)
 
+    # a serving session that ran earlier in this process published one
+    monkeypatch.setattr(obs_server_mod, "_LATEST_ADVICE", None)
     srv = ObsServer(port=0)
     port = srv.start()
     try:

@@ -625,3 +625,206 @@ def test_request_span_tree(gpt):
         assert decode[-1]["args"]["steps"] == 3
     finally:
         configure_tracer(enabled=False)
+
+
+# --------------------------------------------- the loop's phases (PR 25)
+def _loop_sched(gpt, mode, **kw):
+    """A scheduler on the plain or the speculative path."""
+    if mode == "spec":
+        from flexflow_tpu.serving.generation import build_draft_model
+
+        kw.update(draft_ff=build_draft_model(gpt, "self:1"), spec_k=2)
+    return ContinuousBatchingScheduler(gpt, max_length=32, decode_slots=2,
+                                       block_size=8, **kw)
+
+
+def _run_three(sched, new_tokens=6):
+    futs = [sched.submit(np.arange(1, n + 1, dtype=np.int32), new_tokens)
+            for n in (2, 3, 4)]
+    return [f.result(timeout=300) for f in futs]
+
+
+def test_loop_spans_reach_the_profile_nested_in_a_step(gpt, tmp_path):
+    """A CPU profile around a toy session holds every ``serving.loop.*``
+    span on ``/host:CPU``, on the scheduler's thread, read back the way
+    the benchmark reads a trace; every span but ``wait`` lies inside a
+    ``serving.loop.step``, and ``dispatch`` and ``fetch`` of a prefill
+    inside its ``serving.loop.prefill``. The ring is off throughout."""
+    from flexflow_tpu.obs.trace import configure_tracer, tracer
+    from test_obs import _host_spans, _profile
+
+    was = tracer().enabled
+    configure_tracer(enabled=False)  # an earlier test may have armed it
+    before = tracer().event_count()
+    sched = _loop_sched(gpt, "plain")
+    sched.generate(np.zeros(3, np.int32), 3)  # compile outside the profile
+    with _profile(tmp_path):
+        _run_three(sched)
+        time.sleep(0.05)  # the loop goes back to waiting ...
+        sched.generate(np.zeros(3, np.int32), 3)  # ... and is woken
+    sched.stop()
+    assert tracer().event_count() == before
+    configure_tracer(enabled=was)
+    (evs,) = _host_spans(tmp_path, "serving.loop.").values()  # one thread
+    names = {n for n, _, _ in evs}
+    assert names == {"serving.loop." + k for k in (
+        "wait", "step", "admit", "prefill", "inputs", "dispatch", "fetch",
+        "sample")}
+    steps = [(s, e) for n, s, e in evs if n == "serving.loop.step"]
+    prefills = [(s, e) for n, s, e in evs if n == "serving.loop.prefill"]
+
+    def inside(spans, s, e):
+        return any(s0 <= s and e <= e0 for s0, e0 in spans)
+
+    for n, s, e in evs:
+        if n == "serving.loop.wait":
+            assert not inside(steps, s, e)
+        elif n != "serving.loop.step":
+            assert inside(steps, s, e), n
+    n_disp = sum(n == "serving.loop.dispatch" for n, _, _ in evs)
+    n_fetch = sum(n == "serving.loop.fetch" for n, _, _ in evs)
+    assert n_disp == n_fetch >= len(prefills) == 4
+    assert sum(inside(prefills, s, e) for n, s, e in evs
+               if n == "serving.loop.dispatch") == 4
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec"])
+def test_loop_phases_telescope_and_steps_count_dispatches(gpt, mode):
+    """``stats()["loop"]``: the phases sum to the loop's lifetime (within
+    1 %; in fact to rounding), a step is counted for every decode step
+    or speculative round, and its wall time is observed once."""
+    sched = _loop_sched(gpt, mode)
+    assert sched.stats()["loop"]["elapsed_s"] == 0.0  # never started
+    t_before = time.perf_counter()
+    _run_three(sched)
+    st = sched.stats()
+    t_after = time.perf_counter()
+    loop = st["loop"]
+    assert set(loop["phase_s"]) == {"wait", "admit", "prefill", "inputs",
+                                    "dispatch", "fetch", "sample", "other"}
+    assert all(v >= 0 for v in loop["phase_s"].values())
+    total = sum(loop["phase_s"].values())
+    assert 0 < loop["elapsed_s"] <= t_after - t_before
+    assert abs(total - loop["elapsed_s"]) <= 0.01 * loop["elapsed_s"]
+    assert loop["steps"] == st["decode_steps"] > 0
+    assert loop["step_wall"]["count"] == loop["steps"]
+    assert sum(loop["step_wall"]["buckets"].values()) == loop["steps"]
+    for k in ("prefill", "dispatch", "fetch", "sample"):
+        assert loop["phase_s"][k] > 0, k
+    # steps' wall time is loop time: no step is longer than the whole
+    assert loop["step_wall"]["sum"] <= total - loop["phase_s"]["wait"]
+    sched.stop()
+    # a stopped loop's clock stands still
+    a = sched.stats()["loop"]
+    time.sleep(0.02)
+    assert sched.stats()["loop"] == a
+    assert abs(sum(a["phase_s"].values()) - a["elapsed_s"]) < 1e-6
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec"])
+def test_token_gap_counts_tokens_less_first_tokens(gpt, mode):
+    """Every token after a request's first is stamped: ``token_gap``
+    holds one gap a token, less one a request."""
+    sched = _loop_sched(gpt, mode)
+    outs = _run_three(sched, new_tokens=5)
+    sched.generate(np.zeros(2, np.int32), 1)  # a first token and no other
+    st = sched.stats()
+    sched.stop()
+    assert [len(o) for o in outs] == [7, 8, 9]
+    assert st["tokens"] == 16 and st["completed"] == 4
+    gap = st["loop"]["token_gap"]
+    assert gap["count"] == st["tokens"] - st["completed"] == 12
+    assert sum(gap["buckets"].values()) == 12 and gap["min"] >= 0
+
+
+def test_loop_spans_in_the_ring_keep_the_trace_valid(gpt):
+    """With the ring enabled the loop's spans are ring events too, on the
+    scheduler's own thread, and the exported trace still nests."""
+    from flexflow_tpu.obs.trace import (configure_tracer, tracer,
+                                        validate_chrome_trace)
+
+    configure_tracer(enabled=True)
+    tracer().clear()
+    try:
+        sched = _loop_sched(gpt, "plain")
+        _run_three(sched)
+        sched.stop()
+        events = tracer().events()
+    finally:
+        configure_tracer(enabled=False)
+        tracer().clear()
+    loop = [e for e in events if e["name"].startswith("serving.loop.")]
+    assert {e["name"].rsplit(".", 1)[1] for e in loop} >= {
+        "step", "admit", "prefill", "inputs", "dispatch", "fetch", "sample"}
+    assert len({e["tid"] for e in loop}) == 1
+    admits = [e for e in loop if e["name"] == "serving.loop.admit"]
+    assert sum(e["args"]["admitted"] for e in admits) == 3
+    steps = [e for e in loop if e["name"] == "serving.loop.step"]
+    assert [e["args"]["step"] for e in steps] == sorted(
+        e["args"]["step"] for e in steps)
+    assert validate_chrome_trace({"traceEvents": events}) == []
+
+
+def test_phase_totals_outlive_the_percentile_window(gpt, monkeypatch):
+    """``stats()["phases"][k]["count"]`` is the window's length and
+    saturates; ``n`` and ``sum_s`` are the session's."""
+    from flexflow_tpu.serving import scheduler as sched_mod
+
+    monkeypatch.setattr(sched_mod, "_PHASE_WINDOW", 2)
+    sched = _loop_sched(gpt, "plain")
+    _run_three(sched)
+    sched.generate(np.zeros(2, np.int32), 2)
+    ph = sched.stats()["phases"]
+    sched.stop()
+    for k in ("queue_wait", "prefill", "decode", "ttft", "per_token", "e2e"):
+        assert ph[k]["count"] == 2 and ph[k]["n"] == 4, k
+        assert ph[k]["sum_s"] >= ph[k]["count"] * ph[k]["mean"] > 0, k
+
+
+def test_loop_snapshots_stay_whole_under_concurrent_readers(gpt):
+    """``stats()`` is read from other threads while the loop's thread
+    moves the clock: every snapshot still telescopes (a torn one, taken
+    between a phase's two updates, would not) and no phase runs
+    backwards. More readers than cores, a short switch interval."""
+    import os
+    import sys
+    import threading
+
+    sched = _loop_sched(gpt, "plain")
+    sched.generate(np.zeros(3, np.int32), 2)  # compiled before the race
+    stop = threading.Event()
+    bad = []
+
+    def reader():
+        last = None
+        while not stop.is_set():
+            loop = sched.stats()["loop"]
+            total = sum(loop["phase_s"].values())
+            if abs(total - loop["elapsed_s"]) > 1e-6:
+                bad.append(("torn", total, loop["elapsed_s"]))
+            if last is not None and any(
+                    loop["phase_s"][k] < v - 1e-9
+                    for k, v in last["phase_s"].items()):
+                bad.append(("backwards", last["phase_s"], loop["phase_s"]))
+            last = loop
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    readers = [threading.Thread(target=reader)
+               for _ in range((os.cpu_count() or 2) + 2)]
+    try:
+        for t in readers:
+            t.start()
+        deadline = time.perf_counter() + 20
+        for _ in range(3):
+            if time.perf_counter() < deadline:
+                _run_three(sched, new_tokens=8)
+    finally:
+        stop.set()
+        for t in readers:
+            t.join(timeout=30)
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in readers)
+    sched.stop()
+    assert bad == []
+    assert sched.stats()["loop"]["steps"] == sched.stats()["decode_steps"]

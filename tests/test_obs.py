@@ -10,7 +10,9 @@ import pytest
 
 from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.models.mlp import build_mlp
-from flexflow_tpu.obs.metrics import MetricsRegistry, metrics_registry
+from flexflow_tpu.obs.metrics import (BUCKET_BOUNDS, Histogram,
+                                      MetricsRegistry, bucket_delta,
+                                      metrics_registry)
 from flexflow_tpu.obs.trace import (VIRTUAL_TID_BASE, Tracer,
                                     configure_tracer, span, tracer,
                                     validate_chrome_trace)
@@ -26,6 +28,17 @@ def armed_tracer():
     tr.clear()
     yield tr
     tr.clear()
+    tr.enabled = was
+
+
+@pytest.fixture()
+def disarmed_tracer():
+    """The global tracer DISABLED for a test, whatever an earlier test of
+    this process left it at (``trace="on"`` only ever ratchets it on)."""
+    tr = tracer()
+    was = tr.enabled
+    tr.enabled = False
+    yield tr
     tr.enabled = was
 
 
@@ -46,9 +59,8 @@ def _data(n=64):
 
 
 # ------------------------------------------------------------------ tracer
-def test_disabled_tracer_records_nothing_and_is_cheap():
-    tr = tracer()
-    assert not tr.enabled  # the process default
+def test_disabled_tracer_records_nothing_and_is_cheap(disarmed_tracer):
+    tr = disarmed_tracer
     before = tr.event_count()
     t0 = time.perf_counter()
     for _ in range(100_000):
@@ -56,10 +68,93 @@ def test_disabled_tracer_records_nothing_and_is_cheap():
             pass
     elapsed = time.perf_counter() - t0
     assert tr.event_count() == before
-    # ~free: one flag check + a shared no-op context manager. 100k calls
-    # in far under a second even on a loaded CI host (loose bound — the
-    # point is no per-call allocation/locking, not a precise number).
+    # cheap: with no profiler running a span is an inactive
+    # ``TraceAnnotation`` (a flag test in C++) plus one small Python
+    # object; about a microsecond a span, so 100k take some 0.1 s here
+    # (loose bound — the point is no locking and no ring append, not a
+    # precise number).
     assert elapsed < 2.0, f"disabled span() too slow: {elapsed:.3f}s"
+
+
+def _host_spans(trace_dir, prefix):
+    """``{thread line: [(name, start_ns, end_ns), ...]}`` of the spans on
+    ``/host:CPU`` whose names start with ``prefix``, read the way the
+    benchmark reads a trace."""
+    from benchmark import reduce
+
+    trace = reduce.load_xplane(reduce.find_xplane(str(trace_dir)))
+    out = {}
+    for plane in trace["planes"]:
+        if plane["name"] != reduce.HOST_PLANE:
+            continue
+        for line in plane["lines"]:
+            evs = [(n, s, s + d) for n, s, d in line["events"]
+                   if n.startswith(prefix)]
+            if evs:
+                out[line["name"]] = evs
+    return out
+
+
+def _profile(trace_dir):
+    """A CPU profile with the host tracer on and Python's off: what a
+    ``--trace 1`` run of the benchmark takes, less the device."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 2
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(trace_dir), profiler_options=opts)
+
+
+def test_span_is_a_profiler_annotation_with_the_ring_off(disarmed_tracer,
+                                                         tmp_path):
+    """A span needs no knob to reach the profiler's trace: with the ring
+    disabled it still lands on ``/host:CPU``, from a worker thread, with
+    its arguments kept out of its name; the ring stays empty."""
+    import threading
+
+    tr = disarmed_tracer
+    before = tr.event_count()
+
+    def work():
+        with span("obs.test.outer", cat="test", step=3) as sp:
+            with span("obs.test.inner", cat="test"):
+                time.sleep(0.002)
+            sp.set(found=1)
+
+    with _profile(tmp_path):
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    assert tr.event_count() == before
+    (evs,) = _host_spans(tmp_path, "obs.test.").values()
+    by_name = {n: (s, e) for n, s, e in evs}
+    assert set(by_name) == {"obs.test.outer", "obs.test.inner"}
+    (o0, o1), (i0, i1) = by_name["obs.test.outer"], by_name["obs.test.inner"]
+    assert o0 <= i0 and i1 <= o1 and i1 - i0 >= 2_000_000
+
+
+def test_span_set_adds_arguments_to_the_ring_event(armed_tracer):
+    with span("obs.test.set", cat="test", a=1) as sp:
+        sp.set(b=2)
+    (ev,) = [e for e in armed_tracer.events()
+             if e["name"] == "obs.test.set"]
+    assert ev["args"] == {"a": 1, "b": 2}
+
+
+def test_fit_step_and_input_wait_reach_the_profile(disarmed_tracer,
+                                                   tmp_path):
+    """``fit.step`` and ``fit.input_wait`` are spans of the loop thread
+    itself, so a profile of a fit holds them with tracing off."""
+    ff = _mlp()
+    x, y = _data()
+    ff.fit(x, y, epochs=1, verbose=False)  # compile outside the profile
+    with _profile(tmp_path):
+        ff.fit(x, y, epochs=1, verbose=False)
+    names = [n for evs in _host_spans(tmp_path, "fit.").values()
+             for n, _, _ in evs]
+    assert names.count("fit.step") == 4  # 64 rows in batches of 16
+    assert names.count("fit.input_wait") >= 4
 
 
 def test_span_events_have_required_fields_and_nest(armed_tracer, tmp_path):
@@ -169,6 +264,57 @@ def test_registry_counter_gauge_histogram_round_trip():
     assert "# TYPE flexflow_a_gauge gauge" in text
     assert 'flexflow_a_lat{quantile="0.5"}' in text
     assert "flexflow_a_lat_count 10" in text
+
+
+def test_histogram_snapshots_subtract_to_the_observations_between():
+    """The buckets are cumulative in time at fixed bounds, so the
+    difference of two snapshots is the distribution of exactly what was
+    observed between them, however many samples the reservoir dropped."""
+    h = Histogram(reservoir=4)
+    for v in (0.001, 0.002, 0.5):
+        h.observe(v)
+    s0 = json.loads(json.dumps(h.to_json()))
+    between = [0.098] * 470 + [2.5] + [0.00005, 1e4]
+    for v in between:
+        h.observe(v)
+    s1 = json.loads(json.dumps(h.to_json()))
+    delta = bucket_delta(s0, s1)
+    assert sum(n for _, n in delta) == len(between)
+    assert s1["count"] - s0["count"] == len(between)
+    got = dict(delta)
+    # each observation sits in the bucket whose bound is the first at or
+    # above it; what lies past the last bound is in the bucket "inf"
+    le_98ms = min(b for b in BUCKET_BOUNDS if b >= 0.098)
+    le_2500ms = min(b for b in BUCKET_BOUNDS if b >= 2.5)
+
+    def key(bound):  # a bucket is keyed by its bound at six digits
+        return float(f"{bound:.6g}")
+
+    assert got[key(le_98ms)] == 470
+    assert got[key(le_2500ms)] == 1
+    assert got[key(BUCKET_BOUNDS[0])] == 1
+    assert got[float("inf")] == 1
+    # four bounds a doubling: a 2.5 s stall and a 98 ms step lie 19 apart
+    assert (BUCKET_BOUNDS.index(le_2500ms)
+            - BUCKET_BOUNDS.index(le_98ms)) == 19
+    assert le_98ms / 0.098 < 2 ** 0.25
+    # no earlier snapshot: everything up to the later one
+    assert sum(n for _, n in bucket_delta(None, s1)) == s1["count"]
+
+
+def test_histogram_merge_adds_buckets_and_json_keeps_them():
+    a, b = MetricsRegistry(), MetricsRegistry()
+    for v in (0.01, 0.01, 3.0):
+        a.histogram("lat").observe(v)
+    for v in (0.01, 40.0):
+        b.histogram("lat").observe(v)
+    a.merge(MetricsRegistry.from_json(json.loads(json.dumps(b.to_json()))))
+    doc = a.to_json()["lat"]
+    assert doc["count"] == 5 and sum(doc["buckets"].values()) == 5
+    rows = bucket_delta(None, doc)
+    assert [n for _, n in rows] == [3, 1, 1]
+    assert rows[0][0] >= 0.01 > rows[0][0] / 2 ** 0.25
+    assert Histogram().to_json() == {"count": 0}
 
 
 def test_registry_merge():
