@@ -260,6 +260,11 @@ FLASH_RANGE_TOL = 2e-2
 # backend's default matmul precision (measured 1.2e-4 at 256-wide rows).
 MOE_TOL = dict(rtol=1e-5, atol=1e-5)
 MOE_DGATE_TOL = dict(rtol=1e-3, atol=1e-3)
+# Paged attention: the largest error over the largest magnitude of the
+# reference, as for flash attention and for the same reason (one bfloat16
+# rounding of an operand a product, and with bfloat16 arenas the
+# probabilities rounded to bfloat16 before they meet V).
+PAGED_RANGE_TOL = 2e-2
 
 
 def _assert_mosaic(jitted, *args) -> None:
@@ -380,6 +385,64 @@ def check_moe_kernels(moe, mosaic: bool) -> Dict[str, float]:
     return errs
 
 
+def check_paged_attention(slots: int, heads: int, head_dim: int,
+                          block_size: int, max_blocks: int, dtype: str,
+                          mosaic: bool, window: int = 1) -> float:
+    """The paged-attention decode kernel against the gather-and-softmax
+    it replaces, in float32 at ``highest`` precision over the same
+    arenas: slots of ragged lengths (one inactive, one full), tables
+    over shuffled blocks, garbage in the null block. Returns the largest
+    error as a share of the reference's largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.paged_attention import (paged_attention_decode,
+                                                      supported)
+
+    hd = heads * head_dim
+    nb = slots * max_blocks + 1
+    q_shape = (slots, window, heads, head_dim)
+    _require(supported(q_shape, (nb, block_size, hd), dtype, max_blocks),
+             f"paged_attention.supported() refuses {q_shape} over "
+             f"{(nb, block_size, hd)} {dtype}")
+    rng = np.random.default_rng(0)
+    length = max_blocks * block_size
+    lens = rng.integers(1, length - window, size=slots).astype(np.int32)
+    lens[0], lens[-1] = 0, length - window
+    tables = rng.permutation(np.arange(1, nb)).astype(np.int32).reshape(
+        slots, max_blocks)
+    tables[0] = 0
+    k, v = (jnp.asarray(rng.normal(size=(nb, block_size, hd))
+                        .astype(np.float32), dtype) for _ in range(2))
+    k, v = k.at[0].set(1e4), v.at[0].set(-1e4)
+    q = jnp.asarray(rng.normal(size=q_shape).astype(np.float32), dtype)
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    got_fn = jax.jit(paged_attention_decode)
+    if mosaic:
+        _assert_mosaic(got_fn, q, k, v, tables, lens)
+    got = np.asarray(got_fn(q, k, v, tables, lens), np.float32)
+
+    def reference(q, k, v):
+        kk, vv = (a[tables].reshape(slots, length, heads, head_dim)
+                  for a in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * head_dim ** -0.5
+        pos = lens[:, None] + jnp.arange(window)[None, :]
+        seen = jnp.arange(length)[None, None, :] <= pos[:, :, None]
+        p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(reference)(
+            *(a.astype(jnp.float32) for a in (q, k, v))))
+    _require(np.isfinite(got).all(), "paged attention: non-finite values")
+    # the inactive slot attends to the null block alone: left out
+    err = float(np.max(np.abs(got[1:] - want[1:])) / np.max(np.abs(want[1:])))
+    _require(err <= PAGED_RANGE_TOL,
+             f"paged attention ({dtype}, {q_shape}, {nb} blocks): max error "
+             f"{err:.2e} of range > {PAGED_RANGE_TOL}")
+    return err
+
+
 def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     from flexflow_tpu.kernels import pallas_mode
 
@@ -394,6 +457,11 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
         errs.update({f"flash_{dtype}_{k}": f"{v:.1e}" for k, v in e.items()})
     errs.update({f"moe_{k}": f"{v:.1e}"
                  for k, v in check_moe_kernels(sizes.moe, mosaic).items()})
+    if sizes.hidden % 128 == 0:  # the toy's rows are no whole lane tiles
+        for window in (1, 4):
+            errs[f"paged_w{window}"] = "%.1e" % check_paged_attention(
+                4, sizes.heads, sizes.hidden // sizes.heads, 16,
+                sizes.max_length // 16, KV_DTYPE, mosaic, window)
     return ph.report(interpret=not mosaic, flash_shape=shape,
                      moe_shape=sizes.moe, **errs)
 
@@ -471,6 +539,11 @@ def phase_serve(sizes: SmokeSizes) -> Dict:
                  f"{st['deadline_rejects']}")
         moved = {n: reg.counter(n).value - before[n] for n in watched}
         _require(not any(moved.values()), f"serving counters moved: {moved}")
+        # no silent fall-back: on the chip the decode step's attention
+        # reads the pool in place, through the paged-attention kernel
+        path = st["kv"]["attention_path"]["decode"]
+        _require(path == "kernel" or jax.default_backend() != "tpu",
+                 f"the decode step's attention took the {path!r} path")
         arena = next(iter(inst.decoder.pool.kv.values()))[0]
         _require(st["kv"]["kv_dtype"] == KV_DTYPE
                  and arena.dtype == jnp.dtype(KV_DTYPE)
@@ -505,7 +578,10 @@ def phase_serve(sizes: SmokeSizes) -> Dict:
         requests=sizes.requests, tokens=st["tokens"],
         decode_steps=st["decode_steps"],
         prefill_dispatches=st["prefill_dispatches"],
-        kv_dtype=st["kv"]["kv_dtype"],
+        kv_dtype=st["kv"]["kv_dtype"], attention_path=path,
+        kv_blocks_read_share=round(
+            st["kv"]["blocks_read"] / max(1, st["kv"]["blocks_in_tables"]),
+            4),
         kv_divergence=st["kv"].get("divergence"),
         prefill_vs_raw_forward=f"{err:.2e}", logit_atol=SERVE_LOGIT_ATOL,
         ttft_p50_s=(st["phases"]["ttft"] or {}).get("p50"))

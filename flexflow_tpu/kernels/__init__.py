@@ -7,6 +7,12 @@ that keep the working set in VMEM and feed the MXU directly:
 * :mod:`flash_attention` — fused scaled-dot-product attention that never
   materializes the (S, S) logits in HBM (reference: src/ops/attention.cu
   uses cuDNN MultiHeadAttn for the same reason).
+* :mod:`paged_attention` — the paged decode step's attention over a
+  ``PagedKVPool`` arena read in place: block tables by scalar prefetch,
+  a slot's live blocks only, all heads of a chunk in one matrix product
+  (serving/generation.py falls back to its jnp gather for what
+  ``supported()`` refuses: int8 arenas, widths that are no whole lane
+  tiles, the CPU).
 * :mod:`moe_kernels` — row gather / weighted row-gather-sum with
   scalar-prefetched indices, realizing the MoE dispatch/combine data
   movement (reference: src/ops/group_by.cu, aggregate.cu scatter kernels)

@@ -1,0 +1,305 @@
+"""Paged-attention decode as a Pallas TPU kernel: the KV pool read in place.
+
+The paged decode step (serving/generation.py) attends W new tokens a slot
+(W = 1 for a decode step, k+1 for a speculative verify window) over what
+the slot has cached in a :class:`~flexflow_tpu.serving.kv_cache
+.PagedKVPool` arena. The jnp path gathers every slot's *whole* block table
+into a ``(slots, max_length, H, D)`` copy first, which costs a pass over
+an arena's worth of bytes whatever is cached. This kernel reads K and V
+from the arena where they lie:
+
+* the arena is ``(num_blocks, block_size, H*D)``: one block is
+  ``block_size`` rows of ``H*D`` lanes, so it tiles the TPU's
+  (sublane, 128) layout with no padding when ``H*D`` is a multiple of
+  128, whatever the head width (GPT-2's 64 is half a lane tile), and a
+  block is one contiguous DMA;
+* block tables and ``seq_lens`` arrive by scalar prefetch; for each slot
+  (one grid step) the kernel walks ``ceil((seq_len + W) / block_size)``
+  blocks of its table and no more, ``pages_per_chunk`` blocks a loop
+  iteration, with the next chunk's DMAs (the next slot's first chunk
+  after a slot's last) in flight behind the current chunk's math;
+* all heads of a chunk are scored by ONE matrix product: the slot's
+  query row is spread into a block-diagonal ``(H_pad, H*D)`` operand
+  (row h keeps head h's 64 lanes, zeros elsewhere), so
+  ``q_bd @ K_chunk^T`` is the ``(H_pad, tokens)`` score matrix and
+  ``P @ V_chunk`` an ``(H_pad, H*D)`` accumulator whose diagonal blocks
+  are the heads' outputs. No lane slice at a half tile, no per-head
+  loop; the MXU has the headroom (the step is bound by the bytes);
+* scores, the running maximum and sum, and the weighted sum are float32;
+  K, V and the probabilities fed to the MXU are in the arena's dtype,
+  which is what the jnp path feeds it.
+
+Masking is by position, as in the jnp path: column ``t`` of a slot counts
+for window row ``w`` iff ``t <= seq_len + w``, so stale rows after a
+speculative roll-back, the null block's contents and blocks that are
+reserved but not yet written never reach a result. They must be finite
+(the pool's contract): a masked probability is an exact 0.0, and
+0 * finite = 0. For the same reason the chunk buffers are zeroed once
+before the first DMA: a block that is not live is not fetched, and what
+a buffer holds in its place is then old arena data or zeros.
+
+The jnp path (``serving.generation._entry_read`` and the einsums after
+it) is this kernel's reference and takes every entry :func:`supported`
+refuses: int8 arenas, widths Mosaic does not tile, and the CPU.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_mode
+from .flash_attention import NEG_INF, VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES, _NT, _dot
+from .moe_kernels import SMEM_BUDGET_BYTES
+
+# tokens a loop iteration scores: a multiple of the 128 lanes the score
+# matrix has them on; 256 measured best of 128/256/512 on the v5e at the
+# benchmark's shapes (PERF.md section 6, PR 26)
+CHUNK_TOKENS = 256
+# rows of the block-diagonal query operand: W windows of H_pad heads
+MAX_QUERY_ROWS = 256
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _sublanes(dtype) -> int:
+    return 32 // jnp.dtype(dtype).itemsize     # 8 for f32, 16 for bf16
+
+
+def _pages_per_chunk(block_size: int, max_blocks: int) -> int:
+    per = max(1, CHUNK_TOKENS // block_size)
+    # no chunk wider than a table: the smallest multiple of a lane tile
+    # of tokens that covers it
+    lane_pages = max(1, 128 // block_size)
+    return min(per, _round_up(max_blocks, lane_pages))
+
+
+def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
+                block_size: int, max_blocks: int, dtype) -> int:
+    """The kernel's VMEM working set as it is allocated: q and o whole
+    (float32, double-buffered by the pipeline), both chunk buffers of K
+    and V, the float32 accumulator, and the (rows, chunk) float32
+    temporaries of one iteration (s, p, mask) beside the block-diagonal
+    operand and its mask."""
+    hd = heads * head_dim
+    m = window * _round_up(heads, 16)
+    chunk = _pages_per_chunk(block_size, max_blocks) * block_size
+    item = jnp.dtype(dtype).itemsize
+    return (2 * 2 * 4 * rows * hd              # q, o
+            + 2 * 2 * chunk * hd * item        # K, V chunks, two buffers
+            + 4 * m * hd * 3                   # acc, q_bd, its f32 source
+            + 4 * m * 128 * 2                  # m, l columns (lane padded)
+            + 4 * 3 * m * chunk)               # s, p, mask
+
+
+def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
+    """Whether the kernel takes this call. ``q_shape``: (slots, W, H, D);
+    ``arena_shape``: (num_blocks, block_size, H*D). Refuses what Mosaic
+    would: rows that do not fill whole 128-lane tiles, blocks that are
+    not whole sublane tiles of the arena's dtype or do not divide a lane
+    tile of tokens, dtypes other than float32 and bfloat16 (an int8
+    entry is not a ``(k, v)`` pair at all and never gets here), more
+    query rows than one score matrix should hold, tables that do not fit
+    SMEM, a working set over the VMEM budget. Callers take the jnp path
+    then."""
+    if pallas_mode() is None:
+        return False
+    n, w, heads, head_dim = q_shape
+    _, block_size, hd = arena_shape
+    dtype = jnp.dtype(arena_dtype)
+    if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False
+    if hd != heads * head_dim or hd % 128:
+        return False
+    if block_size % _sublanes(dtype) or (128 % block_size
+                                         and block_size % 128):
+        return False
+    if w * _round_up(heads, 16) > MAX_QUERY_ROWS:
+        return False
+    if 4 * (n * max_blocks + n) > SMEM_BUDGET_BYTES:
+        return False
+    rows = _round_up(n * w, 8)
+    return _vmem_bytes(rows, w, heads, head_dim, block_size, max_blocks,
+                       dtype) <= VMEM_BUDGET_BYTES
+
+
+def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
+            q_ref, k_hbm, v_hbm,             # inputs
+            o_ref,                           # output
+            kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref,
+            *, scale, window, heads, head_dim, block_size, max_blocks,
+            pages, slots):
+    b = pl.program_id(0)
+    hd = heads * head_dim
+    hpad = _round_up(heads, 16)
+    chunk = pages * block_size
+
+    def live_blocks(slot):
+        # blocks that hold a position some window row may see
+        return jnp.minimum(
+            (lens_ref[slot] + window + block_size - 1) // block_size,
+            max_blocks)
+
+    def copies(slot, i, buf, wait):
+        """Start (or wait for) the DMAs of chunk ``i`` of ``slot`` into
+        chunk buffer ``buf``: its live blocks only."""
+        live = live_blocks(slot)
+        for j in range(pages):
+            g = i * pages + j
+
+            @pl.when(g < live)
+            def _():
+                page = tables_ref[slot * max_blocks + g]
+                for hbm, vmem, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[page], vmem.at[buf, j], sems.at[s, buf])
+                    if wait:
+                        cp.wait()
+                    else:
+                        cp.start()
+
+    @pl.when(b == 0)
+    def _():
+        cur_ref[0] = 0
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        copies(0, 0, 0, wait=False)
+
+    # row h of a group keeps head h's lanes: the block-diagonal operand,
+    # and at the end the diagonal blocks of the accumulator
+    r = jax.lax.broadcasted_iota(jnp.int32, (hpad, hd), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (hpad, hd), 1)
+    diag = (c >= r * head_dim) & (c < r * head_dim + head_dim)
+    q_bd = jnp.concatenate(
+        [jnp.where(diag, jnp.broadcast_to(
+            q_ref[pl.ds(b * window + w, 1), :], (hpad, hd)), 0.0)
+         for w in range(window)], axis=0).astype(kbuf.dtype)  # (M, HD)
+    m_rows = window * hpad
+    # the last position each row may see: seq_len + its window index
+    row = jax.lax.broadcasted_iota(jnp.int32, (m_rows, chunk), 0)
+    limit = jnp.full((m_rows, chunk), lens_ref[b], jnp.int32)
+    for w in range(1, window):
+        limit = limit + (row >= w * hpad).astype(jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (m_rows, chunk), 1)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    n_chunks = (live_blocks(b) + pages - 1) // pages          # >= 1
+
+    def body(i, carry):
+        cur = cur_ref[0]
+        last = i + 1 >= n_chunks
+        nxt_slot = jnp.where(last, b + 1, b)
+        nxt_i = jnp.where(last, 0, i + 1)
+
+        @pl.when(nxt_slot < slots)
+        def _():
+            copies(nxt_slot, nxt_i, 1 - cur, wait=False)
+
+        copies(b, i, cur, wait=True)
+        k = kbuf[cur].reshape(chunk, hd)
+        v = vbuf[cur].reshape(chunk, hd)
+        s = _dot(q_bd, k, _NT) * scale                        # (M, chunk)
+        s = jnp.where(i * chunk + col <= limit, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + _dot(p.astype(v.dtype), v)
+        m_ref[...] = m_new
+        cur_ref[0] = 1 - cur
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, body, None)
+
+    out = acc_ref[...] / l_ref[...]                           # (M, HD)
+    for w in range(window):
+        o_ref[pl.ds(b * window + w, 1), :] = jnp.sum(
+            jnp.where(diag, out[w * hpad:(w + 1) * hpad], 0.0),
+            axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
+def _paged_attention(q, k_arena, v_arena, tables, seq_lens, *, scale, pages,
+                     interpret):
+    n, window, heads, head_dim = q.shape
+    _, block_size, hd = k_arena.shape
+    max_blocks = tables.shape[1]
+    rows = _round_up(n * window, 8)
+    q2 = q.astype(jnp.float32).reshape(n * window, hd)
+    if rows != n * window:
+        q2 = jnp.pad(q2, ((0, rows - n * window), (0, 0)))
+    m_rows = window * _round_up(heads, 16)
+    whole = pl.BlockSpec((rows, hd), lambda b, lens, tabs: (0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[whole,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=whole,
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, block_size, hd), k_arena.dtype),
+            pltpu.VMEM((2, pages, block_size, hd), v_arena.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((m_rows, 1), jnp.float32),
+            pltpu.VMEM((m_rows, 1), jnp.float32),
+            pltpu.VMEM((m_rows, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, scale=scale, window=window, heads=heads,
+            head_dim=head_dim, block_size=block_size,
+            max_blocks=max_blocks, pages=pages, slots=n),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="paged_attention_decode",
+    )(seq_lens.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1),
+      q2, k_arena, v_arena)
+    return out[:n * window].reshape(n, window, heads, head_dim)
+
+
+def paged_attention_decode(q, k_arena, v_arena, tables, seq_lens,
+                           scale: Optional[float] = None,
+                           pages_per_chunk: Optional[int] = None
+                           ) -> jax.Array:
+    """Attention of W new tokens a slot over the slot's cached K/V, read
+    through its block table from the arenas in place.
+
+    ``q``: (slots, W, H, D); ``k_arena``/``v_arena``: (num_blocks,
+    block_size, H*D), already holding the window's own rows; ``tables``:
+    (slots, max_blocks) int32; ``seq_lens``: (slots,) int32, the tokens
+    cached before the window. Row w of a slot sees positions
+    ``0 .. seq_len + w``. ``pages_per_chunk`` (blocks a loop iteration;
+    times ``block_size`` a multiple of 128) is for tests and tuning.
+    Returns (slots, W, H, D) float32. Callers check :func:`supported`
+    first."""
+    head_dim = q.shape[-1]
+    block_size = k_arena.shape[1]
+    scale = float(scale) if scale is not None else head_dim ** -0.5
+    pages = (int(pages_per_chunk) if pages_per_chunk
+             else _pages_per_chunk(block_size, tables.shape[1]))
+    if (pages * block_size) % 128:
+        raise ValueError(f"a chunk of {pages} blocks of {block_size} "
+                         f"tokens is no multiple of 128 lanes")
+    return _paged_attention(q, k_arena, v_arena, tables, seq_lens,
+                            scale=scale, pages=pages,
+                            interpret=pallas_mode() == "interpret")
+
+
+__all__ = ["paged_attention_decode", "supported"]

@@ -23,20 +23,24 @@ Two cache layouts share the graph walk:
   the bit-compared reference for the paged path);
 * :class:`PagedDecoder` — the continuous-batching layout: a
   :class:`~flexflow_tpu.serving.kv_cache.PagedKVPool` of
-  ``(num_blocks, block_size, H, D)`` arenas plus per-request block
-  tables. Decode attention gathers K/V **through the block table**; the
-  compiled decode program's shape depends only on (decode slots, pool
-  geometry), so one program serves every in-flight request mix, and
-  prompts run through a separate **bucketed prefill executable**
-  (pad-to-bucket ladder, per-bucket compile cached and counted) whose
-  K/V is scattered into the pool in the same dispatch.
+  ``(num_blocks, block_size, H*D)`` arenas plus per-request block
+  tables. Decode attention reads K/V **through the block table**: in
+  place, by the paged-attention kernel (kernels/paged_attention.py),
+  where its ``supported()`` admits the entry, and otherwise by a gather
+  of each slot's table (the jnp path: int8 entries, widths Mosaic
+  refuses, the CPU). The compiled decode program's shape depends only
+  on (decode slots, pool geometry), so one program serves every
+  in-flight request mix, and prompts run through a separate **bucketed
+  prefill executable** (pad-to-bucket ladder, per-bucket compile cached
+  and counted) whose K/V is scattered into the pool in the same
+  dispatch.
 
-The two layouts are bit-identical per request (tests/test_continuous_
-batching.py asserts it per zoo causal-LM model): the paged gather
-reconstructs exactly the dense cache rows for written positions, and
-every unwritten/foreign lane is masked to -1e30 before softmax, where
-``exp`` underflows to exactly 0.0 — adding exact zeros never perturbs
-the valid lanes' accumulation.
+The two layouts compute the same sums per request
+(tests/test_continuous_batching.py holds them to float32 reordering
+per zoo causal-LM model): the paged read reconstructs exactly the dense
+cache rows for written positions, and every unwritten/foreign lane is
+masked to -1e30 before softmax, where ``exp`` underflows to exactly
+0.0 — adding exact zeros never perturbs the valid lanes' accumulation.
 
 Works for any builder graph whose attention ops are causal
 self-attention (models/gpt.py; an imported HF decoder fits the same
@@ -56,6 +60,7 @@ import jax.numpy as jnp
 
 from ..ffconst import OpType
 from ..core.op import LowerCtx
+from ..kernels import paged_attention
 from ..obs.trace import span
 from .kv_cache import NULL_BLOCK, PagedKVPool
 
@@ -110,46 +115,56 @@ def _entry_write(entry, flat, kh, vh):
     """Scatter T new K/V rows (``kh``/``vh``: (T, H, D)) into a pool
     arena entry at flat token slots ``flat`` (T,), quantizing when the
     entry is an int8 6-tuple (values + scale/zero sidecars share the
-    same flat addressing). Returns the updated entry."""
+    same flat addressing). An arena is ``(num_blocks, block_size,
+    H*D)``, so a token is one row of its ``(num_blocks*block_size,
+    H*D)`` view — a reshape that moves nothing under the TPU's tiling —
+    and the scatter updates the donated buffer in place. Returns the
+    updated entry."""
+    t = kh.shape[0]
+
+    def put(arena, rows):
+        nb, bs = arena.shape[:2]
+        flat_arena = arena.reshape((nb * bs,) + arena.shape[2:])
+        return flat_arena.at[flat].set(
+            rows.astype(arena.dtype)).reshape(arena.shape)
+
     if len(entry) == 2:
         k, v = entry
-        nb, bs, h, d = k.shape
-        kf = k.reshape(nb * bs, h, d).at[flat].set(kh.astype(k.dtype))
-        vf = v.reshape(nb * bs, h, d).at[flat].set(vh.astype(v.dtype))
-        return (kf.reshape(k.shape), vf.reshape(v.shape))
+        return (put(k, kh.reshape(t, -1)), put(v, vh.reshape(t, -1)))
     kq, vq, ks, kz, vs, vz = entry
-    nb, bs, h, d = kq.shape
     qk, sk, zk = _quant_rows(kh)
     qv, sv, zv = _quant_rows(vh)
-    return (
-        kq.reshape(nb * bs, h, d).at[flat].set(qk).reshape(kq.shape),
-        vq.reshape(nb * bs, h, d).at[flat].set(qv).reshape(vq.shape),
-        ks.reshape(nb * bs, h).at[flat].set(sk).reshape(ks.shape),
-        kz.reshape(nb * bs, h).at[flat].set(zk).reshape(kz.shape),
-        vs.reshape(nb * bs, h).at[flat].set(sv).reshape(vs.shape),
-        vz.reshape(nb * bs, h).at[flat].set(zv).reshape(vz.shape))
+    return (put(kq, qk.reshape(t, -1)), put(vq, qv.reshape(t, -1)),
+            put(ks, sk), put(kz, zk), put(vs, sv), put(vz, zv))
 
 
-def _entry_read(entry, tables):
+def _entry_read(entry, tables, heads):
     """Gather each slot's logical (max_blocks*block_size, H, D) K/V
     view through its block table, dequantizing int8 entries to f32
     INSIDE the dispatch (the arena stays quantized; only the gathered
-    working set pays the f32 width)."""
+    working set pays the f32 width). The jnp path: what the paged-
+    attention kernel is checked against, and what runs where the kernel
+    does not (int8 entries, widths Mosaic refuses, the CPU)."""
     n = tables.shape[0]
+
+    def view(arena):
+        return arena[tables].reshape(n, -1, heads, arena.shape[-1] // heads)
+
     if len(entry) == 2:
         k, v = entry
-        nb, bs, h, d = k.shape
-        return (k[tables].reshape(n, -1, h, d),
-                v[tables].reshape(n, -1, h, d))
+        return view(k), view(v)
     kq, vq, ks, kz, vs, vz = entry
-    nb, bs, h, d = kq.shape
-    k = (kq[tables].reshape(n, -1, h, d).astype(jnp.float32)
-         * ks[tables].reshape(n, -1, h)[..., None]
-         + kz[tables].reshape(n, -1, h)[..., None])
-    v = (vq[tables].reshape(n, -1, h, d).astype(jnp.float32)
-         * vs[tables].reshape(n, -1, h)[..., None]
-         + vz[tables].reshape(n, -1, h)[..., None])
+    k = (view(kq).astype(jnp.float32) * view(ks) + view(kz))
+    v = (view(vq).astype(jnp.float32) * view(vs) + view(vz))
     return k, v
+
+
+def _kernel_reads(entry, q_shape, max_blocks) -> bool:
+    """Whether the paged-attention kernel reads this entry in place for
+    a (slots, W, H, D) query: a ``(k, v)`` pair of a shape and dtype
+    its ``supported()`` admits, on a backend where Pallas kernels run."""
+    return len(entry) == 2 and paged_attention.supported(
+        q_shape, entry[0].shape, entry[0].dtype, max_blocks)
 
 
 def _attn_with_paged_cache(op, weights, x, entry, tables, seq_lens):
@@ -167,12 +182,16 @@ def _attn_with_paged_cache(op, weights, x, entry, tables, seq_lens):
     whose tables are all :data:`~flexflow_tpu.serving.kv_cache
     .NULL_BLOCK`, write into the null block — harmless by construction;
     positions past the table's span are redirected there too), then
-    gathers each slot's logical ``(max_blocks*block_size)`` cache view
-    through its table and masks per query position exactly like the
-    dense path — so window position j's output is bit-identical to the
-    dense cache decode at absolute position ``seq_lens + j`` (the
-    window's own future K/V rows are masked to -1e30, where exp
-    underflows to exact 0.0).
+    reads each slot's cache through its table — the kernel walks the
+    slot's live blocks in the arena, the jnp path gathers its logical
+    ``(max_blocks*block_size)`` view — and masks per query position
+    exactly like the dense path, so window position j's output is the
+    dense cache decode at absolute position ``seq_lens + j``: the
+    window's own future K/V rows, stale rows after a speculative
+    roll-back and the null block's garbage are masked to -1e30, where
+    exp underflows to exact 0.0. Which reader runs is decided on what
+    the trace can see (the entry's structure and dtype, W, the head and
+    block sizes, the backend: :func:`_kernel_reads`).
     """
     qh = jnp.einsum("bse,ehd->bshd", x, weights["wq"])
     kh = jnp.einsum("bse,ehd->bshd", x, weights["wk"])
@@ -181,8 +200,8 @@ def _attn_with_paged_cache(op, weights, x, entry, tables, seq_lens):
         qh = qh + weights["bq"]
         kh = kh + weights["bk"]
         vh = vh + weights["bv"]
-    nb, bs, heads, hdim = entry[0].shape
-    n, w = x.shape[0], x.shape[1]
+    bs = entry[0].shape[1]
+    n, w, heads, hdim = qh.shape
     mb = tables.shape[1]
     pos = seq_lens[:, None] + jax.lax.iota(jnp.int32, w)[None, :]  # (n, W)
     blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, mb - 1),
@@ -196,15 +215,21 @@ def _attn_with_paged_cache(op, weights, x, entry, tables, seq_lens):
     entry = _entry_write(entry, flat.reshape(-1),
                          kh.reshape(n * w, heads, hdim),
                          vh.reshape(n * w, heads, hdim))
-    # gather each slot's logical view: (n, MB, BS, H, D) -> (n, L, H, D)
-    k, v = _entry_read(entry, tables)
     scale = 1.0 / math.sqrt(op.head_dim)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", qh, k) * scale       # (n,H,W,L)
-    kpos = jax.lax.iota(jnp.int32, k.shape[1])                  # (L,)
-    mask = kpos[None, None, :] <= pos[:, :, None]               # (n, W, L)
-    scores = jnp.where(mask[:, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    if _kernel_reads(entry, qh.shape, mb):
+        # the kernel walks each slot's live blocks in the arena itself
+        ctxv = paged_attention.paged_attention_decode(
+            qh, entry[0], entry[1], tables, seq_lens,
+            scale=scale).astype(qh.dtype)
+    else:
+        # gather each slot's logical view: (n, MB, BS, HD) -> (n, L, H, D)
+        k, v = _entry_read(entry, tables, heads)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qh, k) * scale   # (n,H,W,L)
+        kpos = jax.lax.iota(jnp.int32, k.shape[1])              # (L,)
+        mask = kpos[None, None, :] <= pos[:, :, None]           # (n, W, L)
+        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
     if op.use_bias:
         out = out + weights["bo"]
@@ -645,6 +670,11 @@ class PagedDecoder(_DecodeGraph):
         # one verify executable per window width W=k+1 (spec_k is a
         # session knob, so in practice this holds one entry)
         self._verify_fns: Dict[int, object] = {}
+        # how each program's attention reads the pool, fixed when the
+        # program is built: "kernel" (paged attention, in place) or
+        # "gather" (the jnp path); "verify" appears with its program
+        self.attention_path: Dict[str, str] = {
+            "decode": self._attention_path(1)}
         self._prefill_fns: Dict[Tuple[int, int], object] = {}
         self.decode_dispatches = 0
         self.decode_steps = 0
@@ -767,6 +797,16 @@ class PagedDecoder(_DecodeGraph):
 
         logits = self._forward_block(params, acts, attn)
         return logits, new_pool
+
+    def _attention_path(self, window: int) -> str:
+        """What a W-token step's attention does with the pool as it is
+        now: "kernel" where every attention op's entry is read in
+        place, else "gather"."""
+        return "kernel" if all(
+            _kernel_reads(self.pool.kv[op.name],
+                          (self.decode_slots, window, op.num_heads,
+                           op.head_dim), self.max_blocks_per_request)
+            for op in self._attn_ops) else "gather"
 
     def _prefill_fn(self, bucket: int, width: int = 1):
         """The (bucket, row-width) executable — the seen-set is the
@@ -895,6 +935,7 @@ class PagedDecoder(_DecodeGraph):
         if fn is None:
             fn = jax.jit(self._verify_step, donate_argnums=(2,))
             self._verify_fns[w] = fn
+            self.attention_path["verify"] = self._attention_path(w)
         self.decode_steps += 1
         self.decode_dispatches += 1
         with span("serving.loop.dispatch", cat="serving"):
@@ -1020,6 +1061,7 @@ class PagedDecoder(_DecodeGraph):
             num_blocks=self.pool.num_blocks, block_size=self.block_size,
             max_blocks_per_request=self.max_blocks_per_request, dtype=dt,
             kv_dtype="float32")
+        self.attention_path["decode"] = self._attention_path(1)
 
 
 def build_draft_model(ff, spec: str):

@@ -4,11 +4,12 @@ The dense generator (:class:`serving.generation.Generator`) reserves a
 ``(B, max_length, H, D)`` rectangle per attention op — every request
 pays the worst-case sequence length for its whole lifetime, so the
 number of co-resident requests is fixed at compile time. The paged pool
-is the vLLM-style alternative: one ``(num_blocks, block_size, H, D)``
-arena per attention op, carved into fixed-size blocks, with a
-per-request **block table** mapping logical token positions to physical
-blocks. Requests allocate their worst case (prompt + ``max_new_tokens``,
-rounded up to blocks) at admission and free it at retirement, so
+is the vLLM-style alternative: one ``(num_blocks, block_size, H*D)``
+arena per attention op (the shape is the next section's subject), carved
+into fixed-size blocks, with a per-request **block table** mapping
+logical token positions to physical blocks. Requests allocate their
+worst case (prompt + ``max_new_tokens``, rounded up to blocks) at
+admission and free it at retirement, so
 
 * pool memory is bounded by construction — admission **sheds**
   (:class:`KVPoolExhausted`, a :class:`ShedError`) instead of OOMing
@@ -24,16 +25,36 @@ inactive decode slots and prompt padding, and the gather source for
 unreserved block-table entries. Its contents are arbitrary-but-finite;
 every read through it is masked out by position before softmax.
 
+**The arena's shape** is chosen for the one reader that cannot take
+another: the paged-attention kernel (``kernels/paged_attention.py``),
+whose operand has one fixed layout. A token is ONE row of ``H*D`` values,
+all heads side by side, and a block is ``block_size`` such rows. On a TPU
+an array lives in (sublane, 128-lane) tiles of its two minor dimensions:
+``(…, H, D) = (…, 20, 64)`` in bf16 would pad to (32, 128) tiles, 3.2
+times the bytes, and a head-major ``(H, num_blocks, block_size, 64)``
+pads its 64 lanes to 128, twice the bytes; ``(block_size, H*D) = (16,
+1280)`` tiles exactly whenever ``H*D`` is a multiple of 128 and
+``block_size`` of the sublane tile (16 rows in bf16, 8 in float32), so
+the bytes below are the bytes on the device, a block is one contiguous
+DMA, and the ``(num_blocks*block_size, H*D)`` view the writers scatter
+rows into is the same buffer, not a copy. Prefill's scatter, decode's
+scatter and both readers (the kernel in place; the jnp gather, which
+reshapes the gathered view to ``(…, H, D)``) share this one shape, and
+the donated arena is only ever updated in place.
+
 Memory math (per attention op): ``2 * num_blocks * block_size * heads *
 head_dim * dtype_bytes`` — e.g. 256 blocks x 16 tokens x 8 heads x 64
 dims in bf16 = 2 * 256*16*8*64 * 2B = 8 MiB per layer, serving up to
-``(num_blocks-1) // blocks_per_request`` concurrent worst-case requests.
+``(num_blocks-1) // blocks_per_request`` concurrent worst-case requests;
+GPT-2 large at 16 slots of 1024 tokens: 1025 x 16 x 1280 x 2 B = 42 MB an
+arena, 72 arenas, 3.0 GB.
 
 **Quantized arenas** (``kv_dtype``): the pool can store its arenas in
 ``"bfloat16"`` (cast-in/cast-out) or ``"int8"`` — asymmetric per-token
 per-head quantization, with the f32 scale and zero-point stored in
-sidecar arrays indexed by the same (block, slot, head) coordinates so
-the scatter/gather path never needs a second addressing scheme. int8
+``(num_blocks, block_size, H)`` sidecar arrays indexed by the same
+(block, slot) coordinates so the scatter/gather path never needs a
+second addressing scheme. int8
 per-token bytes per head are ``head_dim + 8`` (values + scale + zero)
 vs f32's ``4 * head_dim`` — half the bytes at head_dim 8, a quarter at
 head_dim 64 — so worst-case admission at a fixed byte budget doubles
@@ -111,7 +132,8 @@ class PagedKVPool:
         # compiled programs ever see
         self.kv: Dict[str, Tuple[jnp.ndarray, ...]] = {}
         for name, (heads, head_dim) in self.specs.items():
-            shape = (self.num_blocks, self.block_size, heads, head_dim)
+            # one row a token, all heads side by side (module docstring)
+            shape = (self.num_blocks, self.block_size, heads * head_dim)
             if kv_dtype == "int8":
                 side = (self.num_blocks, self.block_size, heads)
                 self.kv[name] = (

@@ -31,8 +31,9 @@ steps**:
 Determinism contract: sampling is per-request — each request draws from
 ``np.random.default_rng(seed)`` in its own token order through the
 shared :func:`~flexflow_tpu.serving.generation.sample_next_token` — and
-the paged decode is bit-identical to the dense cache, so the engine
-produces exactly the tokens sequential static-batch serving would.
+the paged decode computes the dense cache's sums (to float32
+reordering), so the engine produces the tokens sequential static-batch
+serving would.
 """
 
 from __future__ import annotations
@@ -292,6 +293,9 @@ class ContinuousBatchingScheduler:
                 num_blocks=self.decoder.pool.num_blocks,
                 prefill_buckets=self.decoder.prefill_buckets,
                 kv_dtype=self.decoder.kv_dtype, calibrate=False)
+        # running sums over decode steps, read by stats()["kv"]
+        self._blocks_read = 0
+        self._blocks_in_tables = 0
         self._spec_rounds = 0
         self._spec_slot_rounds = 0
         self._spec_proposed = 0
@@ -828,6 +832,7 @@ class ContinuousBatchingScheduler:
             tables = np.zeros(
                 (n_slots, self.decoder.max_blocks_per_request), np.int32)
             seq_lens = np.zeros(n_slots, np.int32)
+            bs = self.decoder.block_size
             with self._mu:
                 for i, req in active:
                     tokens[i] = req.tokens[-1]
@@ -835,6 +840,10 @@ class ContinuousBatchingScheduler:
                     seq_lens[i] = req.seq_len
                     if req.decode_t0 is None:
                         req.decode_t0 = now
+                    # the share of its table the step reads: the blocks
+                    # of the slot's cached tokens and the row it writes
+                    self._blocks_read += (req.seq_len + bs) // bs
+                self._blocks_in_tables += len(active) * tables.shape[1]
         return active, tokens, tables, seq_lens
 
     def _dispatch(self, fn, *args):
@@ -1166,10 +1175,15 @@ class ContinuousBatchingScheduler:
             spec_proposed = self._spec_proposed
             spec_matched = self._spec_matched
             spec_emitted = self._spec_emitted
+            blocks_read = self._blocks_read
+            blocks_in_tables = self._blocks_in_tables
         now = time.perf_counter()
         tps = (tokens / (now - t_start)
                if t_start is not None and now > t_start else 0.0)
         kv = self.decoder.pool.stats()
+        kv["attention_path"] = dict(self.decoder.attention_path)
+        kv["blocks_read"] = blocks_read
+        kv["blocks_in_tables"] = blocks_in_tables
         if self.decoder.kv_divergence is not None:
             kv["divergence"] = self.decoder.kv_divergence
             kv["quant_fallback"] = self.decoder.kv_quant_report is not None

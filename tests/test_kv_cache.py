@@ -22,7 +22,8 @@ def test_pool_geometry_and_arenas():
     assert p.capacity_blocks == 8  # block 0 reserved
     assert set(p.kv) == {"attn0", "attn1"}
     k, v = p.kv["attn0"]
-    assert k.shape == (9, 4, 2, 8) and v.shape == (9, 4, 2, 8)
+    # one row a token, heads side by side: (blocks, block_size, H*D)
+    assert k.shape == (9, 4, 16) and v.shape == (9, 4, 16)
     assert k.dtype == jnp.float32
     # memory math: 2 arenas/op x 2 ops x 9*4 slots x 2*8 x 4B
     assert p.memory_bytes() == 2 * 2 * 9 * 4 * 2 * 8 * 4

@@ -1,0 +1,226 @@
+"""The paged-attention decode kernel against the jnp path it replaces,
+through the Pallas interpreter (tier-1 has no chip): same arena, same
+tables, same answer. The compiled kernel at the benchmark cell's shapes
+is in tests_tpu/test_compiled_kernels.py."""
+
+import math
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from flexflow_tpu.kernels import paged_attention
+from flexflow_tpu.serving.generation import _attn_with_paged_cache
+from flexflow_tpu.serving.kv_cache import NULL_BLOCK
+
+HEAD_DIM, BLOCK, MAX_BLOCKS = 64, 16, 20
+MAX_LENGTH = BLOCK * MAX_BLOCKS          # 320 tokens: more than one chunk
+HUGE = 3.0e4                             # "large finite": garbage to mask
+
+# The largest difference allowed, as a share of the largest output (a
+# slot's output is a convex mix of V rows, about 4 at most here).
+# float32 arenas: both paths do float32 arithmetic in different orders (a
+# running softmax over chunks against one softmax over the table), which
+# moves the last bits: 3e-7 of the largest read; 5e-6 leaves room and is
+# a thousandth of what one bfloat16 rounding would move. bfloat16
+# arenas: both outputs are rounded to bfloat16 on their way out, and the
+# jnp path rounds scores and probabilities to it as well where the kernel
+# keeps them float32 until the matrix product, so they differ by a last
+# place of bfloat16 (2**-7 of a value just over a power of two: 0.03125
+# read at an output of 4.1) and never by two: 2**-6.
+TOLERANCE = {"float32": 5e-6, "bfloat16": 2.0 ** -6}
+
+
+class _Op:
+    """The attention op's face as ``_attn_with_paged_cache`` sees it,
+    with identity projections so the test drives q, k and v directly."""
+    use_bias = False
+    head_dim = HEAD_DIM
+
+    def __init__(self, heads):
+        self.num_heads = heads
+
+
+def _case(heads, dtype, window, seed=0):
+    """A pool whose null block and every row past a slot's window hold
+    large finite garbage, tables over shuffled blocks, and ragged
+    lengths: an inactive slot (0, all-null table), one exactly on a
+    block boundary, one a block minus one, one that ends at the table's
+    last row, and one mid-block across the chunk boundary."""
+    rng = np.random.default_rng(seed)
+    hd = heads * HEAD_DIM
+    lens = np.array([0, 3 * BLOCK, 5 * BLOCK - 1, MAX_LENGTH - window,
+                     267], np.int32)
+    n = lens.size
+    nb = n * MAX_BLOCKS + 1
+    k = np.full((nb, BLOCK, hd), HUGE, np.float32)
+    v = np.full((nb, BLOCK, hd), -HUGE, np.float32)
+    tables = np.full((n, MAX_BLOCKS), NULL_BLOCK, np.int32)
+    perm = rng.permutation(np.arange(1, nb))
+    for i, length in enumerate(lens):
+        if length == 0:
+            continue
+        tables[i] = perm[i * MAX_BLOCKS:(i + 1) * MAX_BLOCKS]
+        for pos in range(length):      # what earlier steps cached
+            blk, off = tables[i, pos // BLOCK], pos % BLOCK
+            k[blk, off] = rng.normal(size=hd)
+            v[blk, off] = rng.normal(size=hd)
+    x = rng.normal(size=(n, window, hd)).astype(np.float32)
+    return (jnp.asarray(x, dtype), (jnp.asarray(k, dtype),
+                                    jnp.asarray(v, dtype)),
+            jnp.asarray(tables), jnp.asarray(lens))
+
+
+def _identity_weights(heads, dtype):
+    hd = heads * HEAD_DIM
+    eye = np.eye(hd, dtype=np.float32).reshape(hd, heads, HEAD_DIM)
+    w = jnp.asarray(eye, dtype)
+    return {"wq": w, "wk": w, "wv": w,
+            "wo": jnp.asarray(eye.reshape(heads, HEAD_DIM, hd), dtype)}
+
+
+def _run(monkeypatch, mode, heads, dtype, window):
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    x, entry, tables, lens = _case(heads, dtype, window)
+    out, new_entry = _attn_with_paged_cache(
+        _Op(heads), _identity_weights(heads, dtype), x, entry, tables, lens)
+    return np.asarray(out, np.float32), new_entry, np.asarray(lens)
+
+
+@pytest.mark.parametrize("window", [1, 3])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [16, 20])
+def test_kernel_matches_jnp_path(monkeypatch, heads, dtype, window):
+    """Heads of 64 at GPT-2 medium's and large's counts, both arena
+    dtypes, the decode step and a verify window: the kernel's output is
+    the jnp path's within the dtype's tolerance, for active and inactive
+    slots alike, and the garbage never shows."""
+    q_shape = (5, window, heads, HEAD_DIM)
+    arena = (5 * MAX_BLOCKS + 1, BLOCK, heads * HEAD_DIM)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert paged_attention.supported(q_shape, arena, dtype, MAX_BLOCKS)
+    got, got_entry, lens = _run(monkeypatch, "interpret", heads, dtype,
+                                window)
+    want, want_entry, _ = _run(monkeypatch, "off", heads, dtype, window)
+    assert np.isfinite(got).all()
+    active = lens > 0
+    worst = float(np.abs(got[active] - want[active]).max())
+    bound = TOLERANCE[dtype] * float(np.abs(want[active]).max())
+    assert worst <= bound, f"off by {worst:.3e} > {bound:.3e}"
+    assert float(np.abs(got[active]).max()) < 10.0, "garbage leaked"
+    # both paths wrote the same rows into the same arenas
+    for a, b in zip(got_entry, want_entry):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+
+
+def test_kernel_reads_live_blocks_only(monkeypatch):
+    """A block past a slot's last live one is never fetched: NaN there
+    (which no mask could hide from a matrix product) changes nothing."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    heads = 16
+    x, (k, v), tables, lens = _case(heads, "float32", 1)
+    q = x.reshape(x.shape[0], 1, heads, HEAD_DIM)
+    # chunks of 128 tokens: the long slots walk two and three of them
+    clean = np.asarray(paged_attention.paged_attention_decode(
+        q, k, v, tables, lens, pages_per_chunk=8))
+    live = {NULL_BLOCK}
+    for row, length in zip(np.asarray(tables), np.asarray(lens)):
+        live.update(row[:math.ceil((length + 1) / BLOCK)].tolist())
+    dead = np.array(sorted(set(range(k.shape[0])) - live))
+    assert dead.size > 0
+    k = k.at[dead].set(jnp.nan)
+    v = v.at[dead].set(jnp.nan)
+    dirty = np.asarray(paged_attention.paged_attention_decode(
+        q, k, v, tables, lens, pages_per_chunk=8))
+    assert np.array_equal(clean, dirty)
+
+
+@pytest.mark.parametrize("why,heads,head_dim,block,dtype,window", [
+    ("head width 8: rows are no whole lane tiles", 4, 8, 8, "float32", 1),
+    ("blocks of 8 are half a bfloat16 sublane tile", 16, 64, 8,
+     "bfloat16", 1),
+    ("int8 arenas are dequantised by the jnp path", 16, 64, 16, "int8", 1),
+    ("a window of 16 x 32 padded heads is too many rows", 20, 64, 16,
+     "bfloat16", 16),
+])
+def test_supported_refuses(monkeypatch, why, heads, head_dim, block, dtype,
+                           window):
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert not paged_attention.supported(
+        (4, window, heads, head_dim), (33, block, heads * head_dim), dtype,
+        8), why
+
+
+def test_refused_entries_take_the_jnp_path(monkeypatch):
+    """What ``supported()`` refuses still decodes, through the gather:
+    the zoo's toy width (heads of 8, blocks of 8) and an int8 entry
+    give the same logits with kernels on as with kernels off, and
+    ``attention_path`` says which path a decoder's programs took."""
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.models.gpt import GPTConfig, build_gpt
+    from flexflow_tpu.serving.generation import PagedDecoder
+
+    ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    build_gpt(ff, 2, 6, GPTConfig(vocab_size=48, max_positions=32,
+                                  hidden_size=32, num_heads=4,
+                                  num_layers=1))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    prompt = np.arange(5, dtype=np.int32)
+    logits = {}
+    for mode in ("interpret", "off"):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        for kv_dtype in ("float32", "int8"):
+            dec = PagedDecoder(ff, max_length=32, decode_slots=2,
+                               block_size=8, kv_dtype=kv_dtype,
+                               kv_divergence_budget=10.0)
+            assert dec.attention_path == {"decode": "gather"}
+            table = dec.pool.try_admit(8)
+            dec.prefill(prompt, table)
+            tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
+            tables[0] = table
+            logits[mode, kv_dtype] = dec.decode(
+                np.array([7, 0], np.int32), tables,
+                np.array([5, 0], np.int32))[0]
+    for kv_dtype in ("float32", "int8"):
+        assert np.array_equal(logits["interpret", kv_dtype],
+                              logits["off", kv_dtype])
+
+
+def test_decoder_reports_the_kernel_path(monkeypatch):
+    """A model of GPT-2's head width takes the kernel for its decode
+    step and its verify window, says so in ``attention_path``, and
+    decodes what the jnp path decodes."""
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.models.gpt import GPTConfig, build_gpt
+    from flexflow_tpu.serving.generation import PagedDecoder
+
+    ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    build_gpt(ff, 2, 6, GPTConfig(vocab_size=48, max_positions=64,
+                                  hidden_size=128, num_heads=2,
+                                  num_layers=1))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    prompt = np.arange(21, dtype=np.int32) % 48
+    out = {}
+    for mode, path in (("interpret", "kernel"), ("off", "gather")):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        dec = PagedDecoder(ff, max_length=64, decode_slots=2, block_size=16)
+        assert dec.attention_path == {"decode": path}
+        table = dec.pool.try_admit(40)
+        dec.prefill(prompt, table)
+        tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
+        tables[0] = table
+        lens = np.array([21, 0], np.int32)
+        step = dec.decode(np.array([7, 0], np.int32), tables, lens)[0]
+        window = dec.verify(np.array([[7, 9, 11], [0, 0, 0]], np.int32),
+                            tables, lens)[0]
+        assert dec.attention_path == {"decode": path, "verify": path}
+        out[mode] = (step, window)
+    for got, want in zip(out["interpret"], out["off"]):
+        assert float(np.abs(got - want).max()) <= 1e-4 * float(
+            np.abs(want).max())

@@ -11,7 +11,8 @@ phase (one process holds the chip, so the smoke calls them in-process
 instead of running this suite): each asserts that its program lowered to
 a Mosaic custom call and compares with the float32 ``jnp`` reference at
 ``highest`` matmul precision, at the tolerances written beside
-``chip_smoke.FLASH_RANGE_TOL`` / ``MOE_TOL`` with their reasons. Without a TPU
+``chip_smoke.FLASH_RANGE_TOL`` / ``MOE_TOL`` / ``PAGED_RANGE_TOL`` with
+their reasons. Without a TPU
 every test here fails (conftest.py); none skips.
 """
 
@@ -34,6 +35,16 @@ def test_flash_attention_compiled(shape, causal, dtype):
     "moe", [chip_smoke.GPT2_MEDIUM.moe, (64, 32, 32, 8, 2, 2.0)])
 def test_moe_kernels_compiled(moe):
     chip_smoke.check_moe_kernels(moe, mosaic=True)
+
+
+@pytest.mark.parametrize("window", [1, 4])
+@pytest.mark.parametrize("heads", [20, 16])
+def test_paged_attention_compiled(heads, window):
+    """The offline cell's pool (16 slots, 1025 blocks of 16 tokens, 20
+    heads of 64, bfloat16) and GPT-2 medium's heads, the decode step
+    and a verify window."""
+    chip_smoke.check_paged_attention(16, heads, 64, 16, 64, "bfloat16",
+                                     mosaic=True, window=window)
 
 
 def test_flash_autotune_on_chip(monkeypatch):
