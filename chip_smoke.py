@@ -443,6 +443,68 @@ def check_paged_attention(slots: int, heads: int, head_dim: int,
     return err
 
 
+def check_latent_attention(slots: int, heads: int, rank: int, rope: int,
+                           block_size: int, max_blocks: int, dtype: str,
+                           mosaic: bool) -> float:
+    """The latent-attention decode kernel against the gather-and-softmax
+    it replaces, in float32 at ``highest`` precision over the same arena
+    of rows ``[c | k_rope | 0...]``: slots of ragged lengths (one
+    inactive, one full), tables over shuffled blocks, garbage in the null
+    block. Returns the largest error as a share of the reference's
+    largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.latent_attention import (
+        latent_attention_decode, supported)
+    from flexflow_tpu.serving.kv_cache import latent_row_lanes
+
+    row = latent_row_lanes(rank + rope)
+    nb = slots * max_blocks + 1
+    q_shape = (slots, heads, row)
+    _require(supported(q_shape, (nb, block_size, row), dtype, max_blocks,
+                       rank),
+             f"latent_attention.supported() refuses {q_shape} over "
+             f"{(nb, block_size, row)} {dtype}")
+    rng = np.random.default_rng(0)
+    length = max_blocks * block_size
+    lens = rng.integers(1, length - 1, size=slots).astype(np.int32)
+    lens[0], lens[-1] = 0, length - 1
+    tables = rng.permutation(np.arange(1, nb)).astype(np.int32).reshape(
+        slots, max_blocks)
+    tables[0] = 0
+    pad = np.zeros((1, 1, row), np.float32)
+    pad[..., :rank + rope] = 1.0          # the arena's padding lanes are 0
+    arena = jnp.asarray(rng.normal(size=(nb, block_size, row))
+                        .astype(np.float32) * pad, dtype).at[0].set(1e4)
+    q = jnp.asarray(rng.normal(size=q_shape).astype(np.float32) * pad[0],
+                    dtype)
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    scale = (rank + rope) ** -0.5
+    got_fn = jax.jit(lambda q, a, t, l: latent_attention_decode(
+        q, a, t, l, scale=scale, out_width=rank))
+    if mosaic:
+        _assert_mosaic(got_fn, q, arena, tables, lens)
+    got = np.asarray(got_fn(q, arena, tables, lens), np.float32)
+
+    def reference(q, arena):
+        view = arena[tables].reshape(slots, length, row)
+        s = jnp.einsum("nhr,nlr->nhl", q, view) * scale
+        seen = jnp.arange(length)[None, :] <= lens[:, None]
+        p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("nhl,nlc->nhc", p, view[..., :rank])
+
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(reference)(q.astype(jnp.float32),
+                                             arena.astype(jnp.float32)))
+    _require(np.isfinite(got).all(), "latent attention: non-finite values")
+    err = float(np.max(np.abs(got[1:] - want[1:])) / np.max(np.abs(want[1:])))
+    _require(err <= PAGED_RANGE_TOL,
+             f"latent attention ({dtype}, {q_shape}, {nb} blocks): max "
+             f"error {err:.2e} of range > {PAGED_RANGE_TOL}")
+    return err
+
+
 def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     from flexflow_tpu.kernels import pallas_mode
 
@@ -462,6 +524,10 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
             errs[f"paged_w{window}"] = "%.1e" % check_paged_attention(
                 4, sizes.heads, sizes.hidden // sizes.heads, 16,
                 sizes.max_length // 16, KV_DTYPE, mosaic, window)
+    # a latent cache's rows at the benchmark's widths (64 heads over rows
+    # of 512 + 64, padded to 640 lanes), beside paged_attention_decode
+    errs["latent"] = "%.1e" % check_latent_attention(
+        4, 64, 512, 64, 16, sizes.max_length // 16, KV_DTYPE, mosaic)
     return ph.report(interpret=not mosaic, flash_shape=shape,
                      moe_shape=sizes.moe, **errs)
 

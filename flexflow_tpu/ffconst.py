@@ -47,6 +47,7 @@ class ActiMode(enum.Enum):
     SIGMOID = 12
     TANH = 13
     GELU = 14
+    SILU = 15
 
 
 class AggrMode(enum.Enum):
@@ -144,6 +145,7 @@ class OpType(enum.Enum):
     SOFTMAX = "softmax"
     BATCHNORM = "batch_norm"
     LAYERNORM = "layer_norm"
+    RMS_NORM = "rms_norm"
     CONCAT = "concat"
     SPLIT = "split"
     EMBEDDING = "embedding"
@@ -171,6 +173,14 @@ class OpType(enum.Enum):
     CAST = "cast"
     TOPK = "topk"
     MULTIHEAD_ATTENTION = "multihead_attention"
+    # causal self-attention whose keys and values are up-projections of
+    # one low-rank latent row a token, with rotary positions (MLA)
+    LATENT_ATTENTION = "latent_attention"
+    # x -> (act(x W_gate) * (x W_up)) W_down
+    GATED_MLP = "gated_mlp"
+    # dropless top-k routing over n experts, of which this op holds a
+    # contiguous share and computes only those
+    ROUTED_EXPERTS = "routed_experts"
     # recurrent ops (reference: the legacy NMT engine's LSTM/RNN cells,
     # /root/reference/nmt/{rnn.h,lstm.cu} — predating FFModel; first-class
     # ops here)

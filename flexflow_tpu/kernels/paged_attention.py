@@ -73,8 +73,9 @@ def _sublanes(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize     # 8 for f32, 16 for bf16
 
 
-def _pages_per_chunk(block_size: int, max_blocks: int) -> int:
-    per = max(1, CHUNK_TOKENS // block_size)
+def _pages_per_chunk(block_size: int, max_blocks: int,
+                     chunk_tokens: int = CHUNK_TOKENS) -> int:
+    per = max(1, chunk_tokens // block_size)
     # no chunk wider than a table: the smallest multiple of a lane tile
     # of tokens that covers it
     lane_pages = max(1, 128 // block_size)

@@ -13,6 +13,7 @@ from .xdl import build_xdl, XDLConfig
 from .candle_uno import build_candle_uno, CandleUnoConfig
 from .nmt import build_nmt, NMTConfig
 from .gpt import build_gpt, GPTConfig
+from .latent_moe import build_latent_moe_lm, LatentMoEConfig
 
 
 def zoo_smoke_builders():
@@ -65,6 +66,19 @@ def zoo_smoke_builders():
             vocab_size=128, max_positions=64, hidden_size=32,
             num_heads=4, num_layers=2))
 
+    def latent_moe(ff, bs):
+        build_latent_moe_lm(ff, bs, 16, LatentMoEConfig(
+            vocab_size=128, max_positions=64, hidden_size=32, num_layers=2,
+            num_heads=4, q_lora_rank=24, kv_lora_rank=16,
+            qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+            rope_scaling={"type": "yarn", "factor": 4,
+                          "original_max_position_embeddings": 16,
+                          "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                          "mscale_all_dim": 1},
+            first_dense=1, dense_width=64, expert_width=16, n_routed=8,
+            experts_per_token=2, n_group=4, topk_group=2,
+            routed_scale=2.5))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -78,4 +92,5 @@ def zoo_smoke_builders():
         "candle_uno": candle_uno,
         "nmt": nmt,
         "gpt": gpt,
+        "latent_moe": latent_moe,
     }

@@ -1,0 +1,113 @@
+"""Decoder-only causal LM with latent attention and routed experts.
+
+No reference analog. The block of the DeepSeek-V2/V3 line of models:
+token embedding (positions are rotary, inside the attention), pre-norm
+blocks ``h = x + latent_attention(rms_norm(x)); y = h + ffn(rms_norm(h))``
+where ``ffn`` is a dense gated MLP in the first ``first_dense`` layers
+and, after them, routed experts plus one shared gated MLP; a final
+RMSNorm and an untied vocabulary head.
+
+One builder serves the whole model and one holder's share of it:
+``experts_held = (first, count)`` makes every expert layer route over all
+``n_routed`` experts and compute only the held ones (the shared expert,
+attention, router and dense layers are whole on every holder), and
+``vocab_size`` is whatever slice of the vocabulary the holder keeps.
+
+Built on the builder API, so the graph compiles, is priced by the search
+and the simulator, and drives ``serving.GenerationInstance`` (a paged
+latent cache, one row a token). ``param_dtype`` is the dtype the graph's
+weights are STORED in: float32 masters by default, ``BFLOAT16`` for an
+inference graph that holds its matrices once. With ``draw_weights=False``
+the weights are declared and not drawn (``DeclaredInitializer``), for a
+graph that is loaded before it runs.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Optional, Tuple
+
+from ..ffconst import DataType
+from ..runtime.initializer import DeclaredInitializer
+
+
+@dataclasses.dataclass
+class LatentMoEConfig:
+    vocab_size: int = 32000
+    max_positions: int = 4096
+    hidden_size: int = 512
+    num_layers: int = 4
+    num_heads: int = 8
+    q_lora_rank: int = 192
+    kv_lora_rank: int = 64
+    qk_nope_head_dim: int = 32
+    qk_rope_head_dim: int = 16
+    v_head_dim: int = 32
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[Dict[str, Any]] = None
+    rms_eps: float = 1e-6
+    first_dense: int = 1
+    dense_width: int = 1536
+    expert_width: int = 256
+    n_routed: int = 16
+    experts_per_token: int = 2
+    n_group: int = 1
+    topk_group: int = 1
+    scoring: str = "sigmoid"
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    n_shared: int = 1
+    experts_held: Optional[Tuple[int, int]] = None
+    param_dtype: DataType = DataType.FLOAT
+    draw_weights: bool = True
+
+
+def build_latent_moe_lm(ff, batch_size: int, seq_length: int,
+                        cfg: LatentMoEConfig = LatentMoEConfig()):
+    """Returns (tokens, positions, logits); ``logits`` (B, S, vocab)."""
+    init = None if cfg.draw_weights else DeclaredInitializer()
+    tokens = ff.create_tensor((batch_size, seq_length), DataType.INT32,
+                              name="tokens")
+    positions = ff.create_tensor((batch_size, seq_length), DataType.INT32,
+                                 name="positions")
+    h = ff.embedding(tokens, cfg.vocab_size, cfg.hidden_size,
+                     dtype=cfg.param_dtype, kernel_initializer=init,
+                     name="embed")
+    for i in range(cfg.num_layers):
+        n1 = ff.rms_norm(h, eps=cfg.rms_eps, kernel_initializer=init,
+                         name=f"block{i}_norm1")
+        attn = ff.latent_attention(
+            n1, positions, num_heads=cfg.num_heads,
+            q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim, max_positions=cfg.max_positions,
+            rope_theta=cfg.rope_theta, rope_scaling=cfg.rope_scaling,
+            eps=cfg.rms_eps, kernel_initializer=init, gain_initializer=init,
+            name=f"block{i}_attn")
+        h = ff.add(h, attn, name=f"block{i}_res1")
+        n2 = ff.rms_norm(h, eps=cfg.rms_eps, kernel_initializer=init,
+                         name=f"block{i}_norm2")
+        if i < cfg.first_dense:
+            m = ff.gated_mlp(n2, cfg.dense_width, kernel_initializer=init,
+                             name=f"block{i}_mlp")
+        else:
+            m = ff.routed_experts(
+                n2, n_routed=cfg.n_routed,
+                experts_per_token=cfg.experts_per_token,
+                width=cfg.expert_width, n_group=cfg.n_group,
+                topk_group=cfg.topk_group, scoring=cfg.scoring,
+                norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
+                experts_held=cfg.experts_held, kernel_initializer=init,
+                name=f"block{i}_experts")
+            if cfg.n_shared:
+                shared = ff.gated_mlp(
+                    n2, cfg.n_shared * cfg.expert_width,
+                    kernel_initializer=init, name=f"block{i}_shared")
+                m = ff.add(m, shared, name=f"block{i}_ffn")
+        h = ff.add(h, m, name=f"block{i}_res2")
+    h = ff.rms_norm(h, eps=cfg.rms_eps, kernel_initializer=init,
+                    name="norm_f")
+    logits = ff.dense(h, cfg.vocab_size, use_bias=False,
+                      kernel_initializer=init, name="lm_head")
+    return tokens, positions, logits

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from ..ffconst import DataType, OpType
 from ..core.op import Op, WeightSpec, register_op
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
-from ..runtime.initializer import DefaultWeightInitializer, ZeroInitializer
+from ..runtime.initializer import (ConstantInitializer,
+                                   DefaultWeightInitializer, ZeroInitializer)
 
 
 @register_op
@@ -229,3 +230,193 @@ class MultiHeadAttention(Op):
         proj = 2.0 * b * s * e * h * d * 4  # q,k,v,o projections
         attn = 2.0 * b * h * s * s * d * 2  # logits + context
         return proj + attn
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA)
+# ---------------------------------------------------------------------------
+
+def rotary_inv_freq(dim: int, theta: float, scaling=None):
+    """The ``dim // 2`` rotary frequencies ``theta^(-2i/dim)``; with a
+    YaRN dict (``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``; Peng et al. 2023) each is blended with
+    itself divided by ``factor`` along a linear ramp between the
+    dimensions that make ``beta_fast`` and ``beta_slow`` turns over the
+    original positions: fast dimensions keep their frequency, slow ones
+    are interpolated."""
+    import numpy as np
+
+    i = np.arange(0, dim, 2, dtype=np.float64)
+    extra = 1.0 / (float(theta) ** (i / dim))
+    if not scaling:
+        return extra.astype(np.float32)
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def turns_dim(turns):
+        return (dim * math.log(orig / (turns * 2 * math.pi))
+                / (2 * math.log(float(theta))))
+
+    low = max(math.floor(turns_dim(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(turns_dim(float(scaling.get("beta_slow", 1)))),
+               dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rotary(x, positions, inv_freq):
+    """Rotate the pairs ``(x[i], x[i + d/2])`` of the last axis by
+    ``positions * inv_freq[i]``. ``x``: (..., S, [H,] d) with
+    ``positions`` (..., S); float32 inside, the input's dtype out."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    if x.ndim == ang.ndim + 1:                   # a heads axis before d
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@register_op
+class LatentAttention(Op):
+    """Causal self-attention over a low-rank latent (multi-head latent
+    attention, DeepSeek-V2 2024; no reference analog). Per token:
+
+    * ``cq = norm(x W_qa)``; ``[q_nope | q_rope] = cq W_qb`` per head;
+    * ``[ckv | kr] = x W_kva``; ``c = norm(ckv)``; one ``k_rope =
+      rope(kr)`` for all heads, ``q_rope = rope(q_rope)``;
+    * ``[k_nope | v] = c W_kvb`` per head; scores ``(q_nope . k_nope +
+      q_rope . k_rope) * scale``, causal softmax, ``sum p v``, ``W_o``.
+
+    What a cache has to keep of a token is the row ``[c | k_rope]``
+    (:meth:`latent_rows`), not keys and values: serving/generation.py
+    prefills in this expanded form and decodes in the absorbed one
+    (``W_kvb`` folded into the query and the output). Inputs: the
+    activations (B, S, E) and the graph's int32 positions (B, S).
+    Matrices keep 2-D shapes, heads side by side in the columns, so a
+    loader can hand them over as stored."""
+
+    op_type = OpType.LATENT_ATTENTION
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        a = self.attrs
+        self.embed_dim: int = input_shapes[0].sizes[-1]
+        self.num_heads: int = int(a["num_heads"])
+        self.q_rank: int = int(a["q_lora_rank"])
+        self.kv_rank: int = int(a["kv_lora_rank"])
+        self.nope_dim: int = int(a["qk_nope_head_dim"])
+        self.rope_dim: int = int(a["qk_rope_head_dim"])
+        self.v_dim: int = int(a["v_head_dim"])
+        self.eps = float(a.get("eps", 1e-6))
+        self.max_positions = int(a["max_positions"])
+        scaling = a.get("rope_scaling") or None
+        self.inv_freq = rotary_inv_freq(self.rope_dim,
+                                        float(a.get("rope_theta", 10000.0)),
+                                        scaling)
+        self.scale = (self.nope_dim + self.rope_dim) ** -0.5
+        if scaling and float(scaling.get("mscale_all_dim", 0)):
+            m = yarn_mscale(float(scaling["factor"]),
+                            float(scaling["mscale_all_dim"]))
+            self.scale *= m * m
+        # the row a cache keeps of a token
+        self.row_width = self.kv_rank + self.rope_dim
+        self.causal = True
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self) -> List[WeightSpec]:
+        dt = self.input_shapes[0].dtype
+        init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        gain = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
+        e, h = self.embed_dim, self.num_heads
+        return [
+            WeightSpec("wq_a", (e, self.q_rank), dt, init),
+            WeightSpec("q_norm", (self.q_rank,), dt, gain, weight_decay=False),
+            WeightSpec("wq_b", (self.q_rank,
+                                h * (self.nope_dim + self.rope_dim)), dt, init),
+            WeightSpec("wkv_a", (e, self.kv_rank + self.rope_dim), dt, init),
+            WeightSpec("kv_norm", (self.kv_rank,), dt, gain,
+                       weight_decay=False),
+            WeightSpec("wkv_b", (self.kv_rank,
+                                 h * (self.nope_dim + self.v_dim)), dt, init),
+            WeightSpec("wo", (h * self.v_dim, e), dt, init),
+        ]
+
+    # ---- the pieces serving composes ------------------------------------
+    def queries_and_rows(self, weights, x, positions):
+        """``x`` (B, S, E), ``positions`` (B, S) -> ``q_nope`` (B, S, H,
+        nope), ``q_rope`` (B, S, H, rope) rotated, and the latent rows
+        (B, S, kv_rank + rope): ``[c | k_rope]``."""
+        from .norm import rms_norm
+
+        b, s, _ = x.shape
+        h = self.num_heads
+        cq = rms_norm(_mm(x, weights["wq_a"]), weights["q_norm"], self.eps)
+        q = _mm(cq, weights["wq_b"]).reshape(
+            b, s, h, self.nope_dim + self.rope_dim)
+        q_nope, q_rope = q[..., :self.nope_dim], q[..., self.nope_dim:]
+        kva = _mm(x, weights["wkv_a"])
+        c = rms_norm(kva[..., :self.kv_rank], weights["kv_norm"], self.eps)
+        k_rope = apply_rotary(kva[..., self.kv_rank:], positions,
+                              self.inv_freq)
+        q_rope = apply_rotary(q_rope, positions, self.inv_freq)
+        return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+
+    def kvb_heads(self, weights):
+        """``W_kvb`` as (kv_rank, H, nope + v): its key part is
+        ``[..., :nope]``, its value part ``[..., nope:]``."""
+        return weights["wkv_b"].reshape(self.kv_rank, self.num_heads,
+                                        self.nope_dim + self.v_dim)
+
+    def attend_expanded(self, weights, q_nope, q_rope, rows, mask):
+        """Attention in the expanded form over the rows given (queries
+        (B, Sq, H, ·), rows (B, Sk, width), ``mask`` (Sq, Sk) or (B, Sq,
+        Sk) True where a query sees a key); returns (B, Sq, E)."""
+        c, k_rope = rows[..., :self.kv_rank], rows[..., self.kv_rank:]
+        kv = jnp.einsum("bkc,chd->bkhd", c, self.kvb_heads(weights),
+                        preferred_element_type=jnp.float32).astype(c.dtype)
+        k_nope, v = kv[..., :self.nope_dim], kv[..., self.nope_dim:]
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                               preferred_element_type=jnp.float32)
+                  ) * self.scale
+        mask = mask[None, None] if mask.ndim == 2 else mask[:, None]
+        probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+        ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32).astype(v.dtype)
+        b, sq = ctxv.shape[:2]
+        return _mm(ctxv.reshape(b, sq, self.num_heads * self.v_dim),
+                   weights["wo"])
+
+    def forward(self, ctx, inputs, weights):
+        x, positions = inputs
+        q_nope, q_rope, rows = self.queries_and_rows(weights, x, positions)
+        s = x.shape[1]
+        pos = jax.lax.iota(jnp.int32, s)
+        return [self.attend_expanded(weights, q_nope, q_rope, rows,
+                                     pos[None, :] <= pos[:, None])]
+
+    def flops(self) -> float:
+        b, s = self.input_shapes[0].sizes[:2]
+        e, h = self.embed_dim, self.num_heads
+        qk = self.nope_dim + self.rope_dim
+        proj = 2.0 * b * s * (
+            e * self.q_rank + self.q_rank * h * qk
+            + e * (self.kv_rank + self.rope_dim)
+            + self.kv_rank * h * (self.nope_dim + self.v_dim)
+            + h * self.v_dim * e)
+        return proj + 2.0 * b * h * s * s * (qk + self.v_dim)
+
+
+def _mm(x, w):
+    """A product accumulated in float32, in the activations' dtype out."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)

@@ -37,6 +37,10 @@ def apply_activation(x: jnp.ndarray, mode: ActiMode) -> jnp.ndarray:
         import jax.nn
 
         return jax.nn.gelu(x, approximate=False)
+    if mode is ActiMode.SILU:
+        import jax.nn
+
+        return jax.nn.silu(x)
     raise ValueError(mode)
 
 
@@ -145,3 +149,53 @@ class Linear(Op):
 
     def input_contraction_dims(self):
         return [(0, len(self.input_shapes[0].dims) - 1, "kernel", 0)]
+
+
+@register_op
+class GatedMLP(Op):
+    """``(act(x W_gate) * (x W_up)) W_down`` — the gated feed-forward
+    block (Shazeer 2020, "GLU variants"), one op with three matrices of
+    shapes (in, width), (in, width), (width, in). The products accumulate
+    in float32 and the gate is applied there; the output keeps the
+    input's dtype."""
+
+    op_type = OpType.GATED_MLP
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        self.width: int = int(layer.attrs["width"])
+        self.activation: ActiMode = layer.attrs.get("activation",
+                                                    ActiMode.SILU)
+        self.in_dim: int = input_shapes[0].sizes[-1]
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self) -> List[WeightSpec]:
+        dt = self.input_shapes[0].dtype
+        init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        return [WeightSpec("gate", (self.in_dim, self.width), dt, init),
+                WeightSpec("up", (self.in_dim, self.width), dt, init),
+                WeightSpec("down", (self.width, self.in_dim), dt, init)]
+
+    def forward(self, ctx: LowerCtx, inputs, weights):
+        (x,) = inputs
+        return [gated_mlp(x, weights["gate"], weights["up"], weights["down"],
+                          self.activation)]
+
+    def flops(self) -> float:
+        batch = 1
+        for s in self.input_shapes[0].sizes[:-1]:
+            batch *= s
+        return 6.0 * batch * self.in_dim * self.width
+
+    def input_contraction_dims(self):
+        last = len(self.input_shapes[0].dims) - 1
+        return [(0, last, "gate", 0), (0, last, "up", 0)]
+
+
+def gated_mlp(x, gate, up, down, activation: ActiMode = ActiMode.SILU):
+    g = jnp.dot(x, gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, up, preferred_element_type=jnp.float32)
+    h = (apply_activation(g, activation) * u).astype(x.dtype)
+    return jnp.dot(h, down, preferred_element_type=jnp.float32).astype(x.dtype)

@@ -524,3 +524,145 @@ class Cache(Op):
 
     def forward(self, ctx, inputs, weights):
         return [inputs[0]]
+
+
+@register_op
+class RoutedExperts(Op):
+    """Dropless top-k routing over ``n_routed`` experts, of which this op
+    holds ``experts_held = (first, count)`` (no reference analog; the
+    formulation of DeepSeek-V3 2024). Per token ``u``:
+
+    * scores ``s = sigmoid(float32(u) W_router)``, in float32 whatever
+      the activations' dtype;
+    * with ``n_group`` > 1 the experts are ``n_group`` groups of equal
+      size, a group scores the sum of its two highest ``s``, the
+      ``topk_group`` highest groups stay;
+    * ``T`` = the ``experts_per_token`` highest ``s`` among what stays;
+      ``g_e = s_e / sum_{T} s`` (``norm_topk``) times ``routed_scale``;
+    * output ``sum_{e in T, e held} g_e MLP_e(u)``, a gated MLP of width
+      ``width`` each.
+
+    It routes over ALL ``n_routed`` experts and computes only the pairs
+    whose expert it holds, adding nothing for the others: the sum over
+    the holders of all shares is the whole layer's routed part. Nothing
+    is dropped, and an expert's weights are read once a call however few
+    rows it gets (:meth:`apply`). Weights: ``router`` (E, n_routed),
+    ``w_gate``/``w_up`` (count, E, width), ``w_down`` (count, width, E).
+    """
+
+    op_type = OpType.ROUTED_EXPERTS
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        a = self.attrs
+        self.in_dim: int = input_shapes[0].sizes[-1]
+        self.n_routed = int(a["n_routed"])
+        self.k = int(a["experts_per_token"])
+        self.width = int(a["width"])
+        self.n_group = int(a.get("n_group") or 1)
+        self.topk_group = int(a.get("topk_group") or self.n_group)
+        self.scoring = a.get("scoring", "sigmoid")
+        self.norm_topk = bool(a.get("norm_topk", True))
+        self.routed_scale = float(a.get("routed_scale", 1.0))
+        first, count = a.get("experts_held") or (0, self.n_routed)
+        self.first, self.count = int(first), int(count)
+        if self.scoring != "sigmoid":
+            raise ValueError(f"scoring {self.scoring!r}: only sigmoid "
+                             f"scores are built")
+        if self.n_routed % self.n_group:
+            raise ValueError(f"{self.n_routed} experts are not {self.n_group}"
+                             f" equal groups")
+        if not (0 <= self.first and self.count >= 1
+                and self.first + self.count <= self.n_routed):
+            raise ValueError(f"experts_held {(self.first, self.count)} is "
+                             f"not a share of {self.n_routed} experts")
+        if self.k > self.topk_group * (self.n_routed // self.n_group):
+            raise ValueError("fewer experts stay than a token takes")
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self):
+        from ..core.op import WeightSpec
+        from ..runtime.initializer import DefaultWeightInitializer
+
+        dt = self.input_shapes[0].dtype
+        init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        e, w, c = self.in_dim, self.width, self.count
+        return [WeightSpec("router", (e, self.n_routed), dt, init),
+                WeightSpec("w_gate", (c, e, w), dt, init),
+                WeightSpec("w_up", (c, e, w), dt, init),
+                WeightSpec("w_down", (c, w, e), dt, init)]
+
+    # ---- the two halves (serving reads the first's ids) -------------------
+    def route(self, weights, x2d, ids=None):
+        """``x2d`` (T, E) -> expert ids (T, k) int32 and their weights
+        (T, k) float32, over all ``n_routed`` experts. With ``ids`` given
+        the selection is skipped: those experts are taken, weighted by
+        this op's own scores of them (a comparison that has to follow
+        another program's routing)."""
+        logits = jnp.dot(x2d.astype(jnp.float32),
+                         weights["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        choice = s
+        if ids is not None:
+            ids = ids.astype(jnp.int32)
+        elif self.n_group > 1 and self.topk_group < self.n_group:
+            t = s.shape[0]
+            per = self.n_routed // self.n_group
+            grouped = s.reshape(t, self.n_group, per)
+            gscore = jax.lax.top_k(grouped, min(2, per))[0].sum(-1)
+            _, gidx = jax.lax.top_k(gscore, self.topk_group)
+            keep = jnp.zeros((t, self.n_group), bool).at[
+                jnp.arange(t)[:, None], gidx].set(True)
+            choice = jnp.where(keep[:, :, None], grouped, -1.0).reshape(
+                t, self.n_routed)
+        if ids is None:
+            _, ids = jax.lax.top_k(choice, self.k)
+        g = jnp.take_along_axis(s, ids, axis=-1)
+        if self.norm_topk:
+            g = g / (g.sum(-1, keepdims=True) + 1e-20)
+        return ids.astype(jnp.int32), g * self.routed_scale
+
+    def held_hits(self, ids):
+        """``ids`` (T, k) -> (T, k, count) bool: which held expert, if
+        any, each pick names."""
+        return ((ids - self.first)[..., None]
+                == jnp.arange(self.count, dtype=jnp.int32))
+
+    def apply(self, weights, x2d, ids, gates):
+        """The held experts' part of the layer for the routing given:
+        (T, E) in the activations' dtype. Every token passes through
+        every held expert and is weighted by its gate, 0 where the token
+        did not take the expert: nothing is dropped, the shapes do not
+        depend on the routing, each matrix is read once a call, and the
+        weighted sum over the experts is part of the down product. That
+        is ``n_routed / k`` times the rows the routing names; for a
+        holder of a dozen experts at a decode step's few rows the
+        matrices' bytes decide, not the rows (PERF.md section 6, PR 27:
+        pairs sorted by expert and ``jax.lax.ragged_dot`` read 3,683
+        tokens/s where this reads 5,012)."""
+        w = (self.held_hits(ids) * gates[..., None]).sum(1)     # (T, count)
+        g = jnp.einsum("te,cef->ctf", x2d, weights["w_gate"],
+                       preferred_element_type=jnp.float32)
+        u = jnp.einsum("te,cef->ctf", x2d, weights["w_up"],
+                       preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u * w.T[:, :, None]).astype(x2d.dtype)
+        return jnp.einsum("ctf,cfe->te", h, weights["w_down"],
+                          preferred_element_type=jnp.float32
+                          ).astype(x2d.dtype)
+
+    def forward(self, ctx, inputs, weights):
+        (x,) = inputs
+        x2d = x.reshape(-1, x.shape[-1])
+        ids, gates = self.route(weights, x2d)
+        return [self.apply(weights, x2d, ids, gates).reshape(x.shape)]
+
+    def flops(self) -> float:
+        t = 1
+        for s in self.input_shapes[0].sizes[:-1]:
+            t *= s
+        # what apply() computes: every token through every held expert
+        return (2.0 * t * self.in_dim * self.n_routed
+                + 6.0 * t * self.count * self.in_dim * self.width)

@@ -1,4 +1,4 @@
-"""LayerNorm operator.
+"""LayerNorm and RMSNorm operators.
 
 TPU-native equivalent of the reference's LayerNorm
 (reference: src/ops/layer_norm.cc + .cu — custom Welford kernels; builder
@@ -54,3 +54,43 @@ class LayerNorm(Op):
                 shape[a] = x.shape[a]
             y = y * weights["scale"].reshape(shape) + weights["bias"].reshape(shape)
         return [y]
+
+
+@register_op
+class RMSNorm(Op):
+    """Root-mean-square norm over the last axis with a learned gain:
+    ``x / sqrt(mean(x^2) + eps) * scale`` (Zhang & Sennrich 2019; no
+    reference analog). The statistics are taken in float32 whatever the
+    activations' dtype; the output keeps the input's."""
+
+    op_type = OpType.RMS_NORM
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        self.eps = float(self.attrs.get("eps", 1e-6))
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self):
+        return [WeightSpec(
+            "scale", (self.input_shapes[0].sizes[-1],),
+            self.input_shapes[0].dtype,
+            self.attrs.get("kernel_initializer") or ConstantInitializer(1.0),
+            weight_decay=False)]
+
+    def forward(self, ctx, inputs, weights):
+        (x,) = inputs
+        return [rms_norm(x, weights["scale"], self.eps)]
+
+    def flops(self) -> float:
+        n = 1
+        for s in self.input_shapes[0].sizes:
+            n *= s
+        return 4.0 * n
+
+
+def rms_norm(x, scale, eps: float):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)

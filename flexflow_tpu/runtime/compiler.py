@@ -42,6 +42,7 @@ from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 from ..core.tensor import Tensor
 from .loss import compute_loss
 from .metrics import compute_batch_metrics
+from .initializer import DeclaredInitializer
 from .optimizer import Optimizer
 
 
@@ -253,6 +254,13 @@ def init_params(
             sh = _named_sharding(mesh, op.weight_shapes[ws.name])
             jdtype = dtype_override or ws.dtype.to_jnp()
             init_fn = ws.initializer
+            if isinstance(init_fn, DeclaredInitializer):
+                # declared, not drawn: the loader puts the array here
+                params[op.name][ws.name] = jax.ShapeDtypeStruct(
+                    ws.shape, jdtype, sharding=sh)
+                shardings[op.name][ws.name] = sh
+                wd_mask[op.name][ws.name] = ws.weight_decay
+                continue
 
             @functools.partial(jax.jit, out_shardings=sh)
             def _init(key, _fn=init_fn, _shape=ws.shape, _dt=jdtype):

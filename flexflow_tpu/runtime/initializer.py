@@ -23,6 +23,20 @@ class Initializer:
         raise NotImplementedError
 
 
+class DeclaredInitializer(Initializer):
+    """A weight that is declared and not drawn: ``init_params`` leaves a
+    ``jax.ShapeDtypeStruct`` (shape, dtype, sharding) in the parameter
+    tree where the array would be, for a graph whose weights are loaded
+    from elsewhere before anything runs. A model of billions of
+    parameters then never holds a random copy beside the loaded one.
+    Running the graph before its weights are in place fails at the first
+    op that reads one."""
+
+    def __call__(self, key, shape, dtype):
+        raise RuntimeError("a declared weight was asked for its values: "
+                           "load the graph's parameters first")
+
+
 class GlorotUniformInitializer(Initializer):
     """reference: initializer.h GlorotUniform; matches fan computation of
     initializer_kernel.cu (fan_in/fan_out over first two dims, receptive

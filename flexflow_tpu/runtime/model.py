@@ -249,6 +249,74 @@ class FFModel:
         attrs = dict(axes=tuple(axes), elementwise_affine=elementwise_affine, eps=eps)
         return self._infer_and_add(OpType.LAYERNORM, [input], attrs, name)
 
+    def rms_norm(self, input: Tensor, eps: float = 1e-6,
+                 kernel_initializer=None,
+                 name: Optional[str] = None) -> Tensor:
+        """Root-mean-square norm over the last axis with a learned gain
+        (ops/norm.py RMSNorm)."""
+        return self._infer_and_add(
+            OpType.RMS_NORM, [input],
+            dict(eps=float(eps), kernel_initializer=kernel_initializer), name)
+
+    def gated_mlp(self, input: Tensor, width: int,
+                  activation: ActiMode = ActiMode.SILU,
+                  kernel_initializer=None,
+                  name: Optional[str] = None) -> Tensor:
+        """``(act(x W_gate) * (x W_up)) W_down`` (ops/linear.py
+        GatedMLP)."""
+        return self._infer_and_add(
+            OpType.GATED_MLP, [input],
+            dict(width=int(width), activation=activation,
+                 kernel_initializer=kernel_initializer), name)
+
+    def latent_attention(self, input: Tensor, positions: Tensor, *,
+                         num_heads: int, q_lora_rank: int, kv_lora_rank: int,
+                         qk_nope_head_dim: int, qk_rope_head_dim: int,
+                         v_head_dim: int, max_positions: int,
+                         rope_theta: float = 10000.0,
+                         rope_scaling: Optional[Dict[str, Any]] = None,
+                         eps: float = 1e-6, kernel_initializer=None,
+                         gain_initializer=None,
+                         name: Optional[str] = None) -> Tensor:
+        """Causal self-attention over a low-rank latent with rotary
+        positions (ops/attention.py LatentAttention). ``positions`` is
+        the graph's int32 positions input; ``rope_scaling`` an optional
+        YaRN dict."""
+        attrs = dict(
+            num_heads=int(num_heads), q_lora_rank=int(q_lora_rank),
+            kv_lora_rank=int(kv_lora_rank),
+            qk_nope_head_dim=int(qk_nope_head_dim),
+            qk_rope_head_dim=int(qk_rope_head_dim),
+            v_head_dim=int(v_head_dim), max_positions=int(max_positions),
+            rope_theta=float(rope_theta),
+            rope_scaling=dict(rope_scaling) if rope_scaling else None,
+            eps=float(eps), kernel_initializer=kernel_initializer,
+            gain_initializer=gain_initializer)
+        return self._infer_and_add(OpType.LATENT_ATTENTION,
+                                   [input, positions], attrs, name)
+
+    def routed_experts(self, input: Tensor, *, n_routed: int,
+                       experts_per_token: int, width: int,
+                       n_group: int = 1, topk_group: Optional[int] = None,
+                       scoring: str = "sigmoid", norm_topk: bool = True,
+                       routed_scale: float = 1.0,
+                       experts_held: Optional[Tuple[int, int]] = None,
+                       kernel_initializer=None,
+                       name: Optional[str] = None) -> Tensor:
+        """Dropless top-k routed experts of which this op holds
+        ``experts_held = (first, count)`` (default: all of them)
+        (ops/moe_ops.py RoutedExperts)."""
+        attrs = dict(
+            n_routed=int(n_routed), experts_per_token=int(experts_per_token),
+            width=int(width), n_group=int(n_group),
+            topk_group=int(topk_group or n_group), scoring=scoring,
+            norm_topk=bool(norm_topk), routed_scale=float(routed_scale),
+            experts_held=(tuple(int(v) for v in experts_held)
+                          if experts_held else None),
+            kernel_initializer=kernel_initializer)
+        return self._infer_and_add(OpType.ROUTED_EXPERTS, [input], attrs,
+                                   name)
+
     # ---- elementwise --------------------------------------------------- #
     def _binary(self, op_type, x, y, name=None, inplace_a=False):
         return self._infer_and_add(op_type, [x, y], {}, name)

@@ -44,12 +44,20 @@ masked to -1e30 before softmax, where ``exp`` underflows to exactly
 
 Works for any builder graph whose attention ops are causal
 self-attention (models/gpt.py; an imported HF decoder fits the same
-contract).
+contract) or latent attention (models/latent_moe.py): a latent op's
+cache is ONE row a token, ``[c | k_rope]`` — a 1-tuple entry in either
+layout — which prefill attends in the expanded form and the paged decode
+step in the absorbed one (:func:`_latent_attn_paged`; in place by
+kernels/latent_attention.py where its ``supported()`` admits the entry).
+Routed-experts ops run inside the same programs; the paged ones keep the
+expert ids they chose (``PagedDecoder.last_routing``) and count their
+load on the device (``PagedDecoder.expert_stats``).
 """
 
 from __future__ import annotations
 
 import math
+import threading
 import weakref
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -60,7 +68,7 @@ import jax.numpy as jnp
 
 from ..ffconst import OpType
 from ..core.op import LowerCtx
-from ..kernels import paged_attention
+from ..kernels import latent_attention, paged_attention
 from ..obs.trace import span
 from .kv_cache import NULL_BLOCK, PagedKVPool
 
@@ -128,6 +136,12 @@ def _entry_write(entry, flat, kh, vh):
         return flat_arena.at[flat].set(
             rows.astype(arena.dtype)).reshape(arena.shape)
 
+    if len(entry) == 1:
+        # a latent entry: ``kh`` is the (T, width) rows, padded with
+        # zeros to the arena's whole lane tiles; ``vh`` is unused
+        lanes = entry[0].shape[-1]
+        return (put(entry[0], jnp.pad(kh, ((0, 0),
+                                           (0, lanes - kh.shape[-1])))),)
     if len(entry) == 2:
         k, v = entry
         return (put(k, kh.reshape(t, -1)), put(v, vh.reshape(t, -1)))
@@ -234,6 +248,131 @@ def _attn_with_paged_cache(op, weights, x, entry, tables, seq_lens):
     if op.use_bias:
         out = out + weights["bo"]
     return out, entry
+
+
+def _latent_kernel_reads(op, entry, slots: int, max_blocks: int) -> bool:
+    """Whether the latent-attention kernel reads this 1-tuple entry in
+    place for a one-token step of ``slots`` slots."""
+    arena = entry[0]
+    return latent_attention.supported(
+        (slots, op.num_heads, arena.shape[-1]), arena.shape, arena.dtype,
+        max_blocks, op.kv_rank)
+
+
+def _latent_attn_with_cache(op, weights, x, positions, rows_cache, offset):
+    """The dense-rectangle form of latent attention: the block's rows
+    written at ``offset`` into a (B, max_length, width) cache, attention
+    in the expanded form over the whole static length, masked by
+    position (the sibling of :func:`_attn_with_cache`)."""
+    q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
+    rows_cache = jax.lax.dynamic_update_slice(
+        rows_cache, rows.astype(rows_cache.dtype), (0, offset, 0))
+    qpos = offset + jax.lax.iota(jnp.int32, x.shape[1])
+    kpos = jax.lax.iota(jnp.int32, rows_cache.shape[1])
+    out = op.attend_expanded(weights, q_nope, q_rope,
+                             rows_cache.astype(x.dtype),
+                             kpos[None, :] <= qpos[:, None])
+    return out, rows_cache
+
+
+def _latent_attn_paged(op, weights, x, positions, entry, tables, seq_lens):
+    """One new token a slot through a paged latent cache, in the
+    absorbed form: ``x`` (n, 1, E) at positions ``seq_lens``. Writes the
+    token's row ``[c | k_rope]`` at the slot's position (inactive slots
+    into the null block), then attends the slot's cached rows through
+    its table: per head the query over a row's lanes is ``q_nope`` folded
+    through the key half of ``W_kvb`` beside ``q_rope``, the weighted sum
+    of the rows' latent part is unfolded through the value half. The
+    kernel reads the arena in place over live blocks only; the jnp path
+    gathers each slot's logical view (its reference, and what runs where
+    its ``supported()`` says no). Masked lanes are exact zeros, as in
+    :func:`_attn_with_paged_cache`."""
+    n, w, _ = x.shape
+    if w != 1:
+        raise ValueError(
+            f"{op.name}: a latent cache entry takes one new token a slot "
+            f"(speculative verify windows are not built for it), got {w}")
+    arena = entry[0]
+    bs, lanes = arena.shape[1], arena.shape[2]
+    mb = tables.shape[1]
+    q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
+    blk = jnp.take_along_axis(
+        tables, jnp.clip(seq_lens[:, None] // bs, 0, mb - 1), axis=1)[:, 0]
+    flat = jnp.where(seq_lens < mb * bs, blk * bs + seq_lens % bs,
+                     NULL_BLOCK * bs)
+    entry = _entry_write(entry, flat, rows[:, 0], None)
+    arena = entry[0]
+    wkvb = op.kvb_heads(weights)                      # (rank, H, nope + v)
+    q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0], wkvb[..., :op.nope_dim],
+                       preferred_element_type=jnp.float32)
+    q_full = jnp.concatenate(
+        [q_lat.astype(arena.dtype), q_rope[:, 0].astype(arena.dtype),
+         jnp.zeros((n, op.num_heads, lanes - op.row_width), arena.dtype)],
+        axis=-1)                                      # (n, H, lanes)
+    if _latent_kernel_reads(op, entry, n, mb):
+        with jax.named_scope("latent_attention_decode"):
+            ctxv = latent_attention.latent_attention_decode(
+                q_full, arena, tables, seq_lens, scale=op.scale,
+                out_width=op.kv_rank)
+    else:
+        view = arena[tables].reshape(n, mb * bs, lanes)      # (n, L, lanes)
+        scores = jnp.einsum("nhr,nlr->nhl", q_full, view,
+                            preferred_element_type=jnp.float32) * op.scale
+        kpos = jax.lax.iota(jnp.int32, mb * bs)
+        scores = jnp.where((kpos[None, :] <= seq_lens[:, None])[:, None, :],
+                           scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        ctxv = jnp.einsum("nhl,nlc->nhc", probs.astype(arena.dtype),
+                          view[..., :op.kv_rank],
+                          preferred_element_type=jnp.float32)
+    o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
+                   wkvb[..., op.nope_dim:],
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+    out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim), weights["wo"],
+                  preferred_element_type=jnp.float32).astype(x.dtype)
+    return out, entry
+
+
+def _latent_attn_prefill(op, weights, x, positions, entry, tables, lengths):
+    """A group of prompts through latent attention in the expanded form
+    (keys and values up-projected from the prompt's own rows, dense
+    causal attention), the rows scattered into the pool through each
+    prompt's block table with padding positions sent to the null block:
+    the sibling of the attention closure of ``_prefill_step``."""
+    b, s_blk, _ = x.shape
+    bs = entry[0].shape[1]
+    q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
+    pos = jax.lax.iota(jnp.int32, s_blk)
+    with jax.named_scope("latent_attention_prefill"):
+        out = op.attend_expanded(weights, q_nope, q_rope, rows,
+                                 pos[None, :] <= pos[:, None])
+    blk = tables[:, pos // bs]
+    flat = jnp.where(pos[None, :] < lengths[:, None],
+                     blk * bs + (pos % bs)[None, :], NULL_BLOCK * bs)
+    entry = _entry_write(entry, flat.reshape(-1),
+                         rows.reshape(b * s_blk, -1), None)
+    return out, entry
+
+
+def _expert_counts(op, ids, active):
+    """What one decode step adds to an expert op's counters: ``[1, pairs
+    routed, pairs held, held experts that got no row, rows of each held
+    expert ...]`` over the active slots' tokens. ``ids`` (T, k),
+    ``active`` (T,) bool."""
+    hit = op.held_hits(ids) & active[:, None, None]
+    rows = hit.sum((0, 1)).astype(jnp.uint32)                 # (count,)
+    head = jnp.stack([jnp.uint32(1),
+                      (active.sum() * ids.shape[1]).astype(jnp.uint32),
+                      rows.sum(), (rows == 0).sum().astype(jnp.uint32)])
+    return jnp.concatenate([head, rows])
+
+
+def _count_up(acc, add):
+    """``acc`` (2, n) uint32, low words over high words: a 64-bit count
+    in two words, so that a server that never restarts does not wrap
+    (1,024 pairs a step fill 32 bits in 4 M steps)."""
+    low = acc[0] + add
+    return jnp.stack([low, acc[1] + (low < acc[0]).astype(jnp.uint32)])
 
 
 def sample_next_token(row_logits: np.ndarray, temperature: float,
@@ -376,15 +515,30 @@ class _DecodeGraph:
             raise ValueError("compile() the model before generating")
         self._cm = cm
         self.max_length = int(max_length)
-        self._attn_ops = [op for op in cm.ops
-                          if op.op_type is OpType.MULTIHEAD_ATTENTION]
+        self._attn_ops = [op for op in cm.ops if op.op_type in (
+            OpType.MULTIHEAD_ATTENTION, OpType.LATENT_ATTENTION)]
+        self._token_id = cm.input_tensors[0]
+        self._pos_id = cm.input_tensors[1]
         for op in self._attn_ops:
+            if op.op_type is OpType.LATENT_ATTENTION:
+                # its second input is the graph's positions (rotary,
+                # inside the op), not a learned table's
+                if op.layer.inputs[1].tensor_id != self._pos_id.tensor_id:
+                    raise ValueError(
+                        f"{op.name}: latent attention has to take the "
+                        f"graph's positions input")
+                if self.max_length > op.max_positions:
+                    raise ValueError(
+                        f"max_length {self.max_length} exceeds the "
+                        f"positions {op.name} was built for "
+                        f"({op.max_positions})")
+                continue
             ids = {t.tensor_id for t in op.layer.inputs}
             if len(ids) != 1 or not op.causal:
                 raise ValueError(
                     f"{op.name}: generation needs causal SELF-attention")
-        self._token_id = cm.input_tensors[0]
-        self._pos_id = cm.input_tensors[1]
+        self._expert_ops = [op for op in cm.ops
+                            if op.op_type is OpType.ROUTED_EXPERTS]
         # the position-embedding table bounds how far the MODEL can decode;
         # jnp.take clamps out-of-range ids silently, so enforce it here
         pos_tid = self._pos_id.tensor_id
@@ -414,10 +568,13 @@ class _DecodeGraph:
         invalidates automatically)."""
         self._params_cache.invalidate()
 
-    def _forward_block(self, params, acts, attn):
+    def _forward_block(self, params, acts, attn, experts=None):
         """Walk the op graph over the activations in ``acts``; ``attn``
-        handles each causal self-attention op (cache layout specific).
-        Returns the (B, S, vocab) float32 logits."""
+        handles each causal self-attention op (cache layout specific;
+        a latent-attention op is handed the positions too) and
+        ``experts``, where given, each routed-experts op (the paged
+        programs keep the routing they chose). Returns the (B, S, vocab)
+        float32 logits."""
         ctx = LowerCtx(mesh=None, training=False, aux_losses=[],
                        compute_dtype=None)
         for op in self._cm.ops:
@@ -425,6 +582,10 @@ class _DecodeGraph:
             p = params.get(op.name, {})
             if op.op_type is OpType.MULTIHEAD_ATTENTION:
                 outs = [attn(op, p, ins[0])]
+            elif op.op_type is OpType.LATENT_ATTENTION:
+                outs = [attn(op, p, ins[0], ins[1])]
+            elif op.op_type is OpType.ROUTED_EXPERTS and experts is not None:
+                outs = [experts(op, p, ins[0])]
             else:
                 outs = op.forward(ctx, ins, p)
             for out, t in zip(outs, op.layer.outputs):
@@ -470,9 +631,8 @@ class Generator(_DecodeGraph):
         params_sds = jax.tree_util.tree_map(_sds, self._cm.params)
         tokens_sds = jax.ShapeDtypeStruct((self.batch_size, 1), jnp.int32)
         cache_sds = {
-            op.name: tuple(jax.ShapeDtypeStruct(
-                (self.batch_size, self.max_length, op.num_heads,
-                 op.head_dim), cache_dt) for _ in range(2))
+            op.name: tuple(jax.ShapeDtypeStruct(shape, cache_dt)
+                           for shape in self._cache_shapes(op))
             for op in self._attn_ops}
         offset_sds = jax.ShapeDtypeStruct((), jnp.int32)
         self.audit_report, self.exec_telemetry = _audit_serving_program(
@@ -480,14 +640,20 @@ class Generator(_DecodeGraph):
             (params_sds, tokens_sds, cache_sds, offset_sds), cfg)
 
     # ---- cache ------------------------------------------------------------
-    def init_cache(self) -> Dict[str, Tuple[jnp.ndarray, jnp.ndarray]]:
-        cache = {}
+    def _cache_shapes(self, op) -> Tuple[Tuple[int, ...], ...]:
+        """The dense cache of one attention op: a (k, v) pair of
+        (B, max_length, H, D), or a latent op's one (B, max_length,
+        width) array of rows."""
+        if op.op_type is OpType.LATENT_ATTENTION:
+            return ((self.batch_size, self.max_length, op.row_width),)
+        shape = (self.batch_size, self.max_length, op.num_heads, op.head_dim)
+        return (shape, shape)
+
+    def init_cache(self) -> Dict[str, Tuple[jnp.ndarray, ...]]:
         dt = self._compute_dtype() or jnp.float32
-        for op in self._attn_ops:
-            shape = (self.batch_size, self.max_length, op.num_heads,
-                     op.head_dim)
-            cache[op.name] = (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-        return cache
+        return {op.name: tuple(jnp.zeros(shape, dt)
+                               for shape in self._cache_shapes(op))
+                for op in self._attn_ops}
 
     # ---- one block step (prefill: S=prompt, decode: S=1) -----------------
     def _block_step(self, params, tokens, cache, offset):
@@ -498,7 +664,12 @@ class Generator(_DecodeGraph):
                 self._pos_id.tensor_id: positions}
         new_cache = dict(cache)
 
-        def attn(op, p, x):
+        def attn(op, p, x, pos=None):
+            if pos is not None:
+                out, rows = _latent_attn_with_cache(
+                    op, p, x, pos, new_cache[op.name][0], offset)
+                new_cache[op.name] = (rows,)
+                return out
             k, v = new_cache[op.name]
             out, k, v = _attn_with_cache(op, p, x, k, v, offset)
             new_cache[op.name] = (k, v)
@@ -655,18 +826,31 @@ class PagedDecoder(_DecodeGraph):
         dt = self._compute_dtype() or jnp.float32
         self.kv_dtype = str(kv_dtype)
         self.pool = PagedKVPool(
-            {op.name: (op.num_heads, op.head_dim)
-             for op in self._attn_ops},
-            num_blocks=int(num_blocks), block_size=self.block_size,
+            self._pool_specs(), num_blocks=int(num_blocks),
+            block_size=self.block_size,
             max_blocks_per_request=self.max_blocks_per_request, dtype=dt,
             kv_dtype=self.kv_dtype)
+        # one small accumulator for each routed-experts op (_count_up),
+        # donated to the decode program beside the pool and returned by
+        # it: counted on the device, fetched only by expert_stats(). The
+        # lock covers the moment between a dispatch that donates them and
+        # the assignment of what it returns.
+        self._expert_acc: Dict[str, jax.Array] = {
+            op.name: jnp.zeros((2, 4 + op.count), jnp.uint32)
+            for op in self._expert_ops}
+        self._expert_acc_lock = threading.Lock()
+        # the expert ids the last prefill or decode call chose, {routed-
+        # experts op name: (rows..., k) int32 device array}: kept for
+        # whoever asks (a comparison with a reference), never fetched by
+        # the scheduler's loop
+        self.last_routing: Dict[str, jax.Array] = {}
         if prefill_buckets is None:
             prefill_buckets = default_prefill_buckets(self.max_length)
         self.prefill_buckets = sorted(
             {min(int(bkt), self.max_length) for bkt in prefill_buckets})
         if self.prefill_buckets[-1] < self.max_length:
             self.prefill_buckets.append(self.max_length)
-        self._decode = jax.jit(self._decode_step, donate_argnums=(2,))
+        self._decode = jax.jit(self._decode_step, donate_argnums=(2, 5))
         # one verify executable per window width W=k+1 (spec_k is a
         # session knob, so in practice this holds one entry)
         self._verify_fns: Dict[int, object] = {}
@@ -694,23 +878,41 @@ class PagedDecoder(_DecodeGraph):
             self._calibrate_kv_quant(kv_divergence_budget)
 
     # ---- compiled programs -------------------------------------------------
-    def _decode_step(self, params, tokens, pool, tables, seq_lens):
+    def _decode_step(self, params, tokens, pool, tables, seq_lens,
+                     expert_acc):
         """One decode step for all slots: tokens (slots, 1) int32, pool
         {op: arena entry} donated, tables (slots, MB) int32, seq_lens
-        (slots,) int32. Returns ((slots, vocab) float32 logits, new
-        pool)."""
+        (slots,) int32, expert_acc {routed-experts op: counters}
+        donated. Returns ((slots, vocab) float32 logits, new pool, the
+        expert ids chosen, new counters)."""
         positions = seq_lens[:, None]                           # (slots, 1)
         acts = {self._token_id.tensor_id: tokens,
                 self._pos_id.tensor_id: positions}
         new_pool = dict(pool)
+        new_acc = dict(expert_acc)
+        routed: Dict[str, jax.Array] = {}
+        # a slot with no block reserved is idle: its token is padding
+        active = tables[:, 0] != NULL_BLOCK
 
-        def attn(op, p, x):
+        def attn(op, p, x, pos=None):
+            if pos is not None:
+                out, new_pool[op.name] = _latent_attn_paged(
+                    op, p, x, pos, new_pool[op.name], tables, seq_lens)
+                return out
             out, new_pool[op.name] = _attn_with_paged_cache(
                 op, p, x, new_pool[op.name], tables, seq_lens)
             return out
 
-        logits = self._forward_block(params, acts, attn)
-        return logits[:, -1, :], new_pool
+        def experts(op, p, x):
+            x2d = x.reshape(-1, x.shape[-1])
+            ids, gates = op.route(p, x2d)
+            routed[op.name] = ids
+            new_acc[op.name] = _count_up(
+                new_acc[op.name], _expert_counts(op, ids, active))
+            return op.apply(p, x2d, ids, gates).reshape(x.shape)
+
+        logits = self._forward_block(params, acts, attn, experts)
+        return logits[:, -1, :], new_pool, routed, new_acc
 
     def _verify_step(self, params, tokens, pool, tables, seq_lens):
         """Speculative verify: tokens (slots, W) int32 — each slot's
@@ -739,7 +941,7 @@ class PagedDecoder(_DecodeGraph):
             return out
 
         logits = self._forward_block(params, acts, attn)
-        return logits, new_pool
+        return logits, new_pool, {}
 
     def _prefill_step(self, params, tokens, pool, tables, lengths):
         """Bucketed prefill for a GROUP of requests: tokens (P, Sb)
@@ -750,8 +952,11 @@ class PagedDecoder(_DecodeGraph):
         scattered through its own block table with padding positions
         redirected into the null block — so one multi-prompt dispatch
         computes exactly what P single-prompt dispatches would, in one
-        XLA program. Returns ((P, Sb, vocab) float32 logits, new
-        pool)."""
+        XLA program. Returns ((P, vocab) float32 logits of each row's
+        last prompt position, new pool): that row is all a caller
+        reads, and the other Sb - 1 never leave the device (fetched
+        whole they were 63-84 MB a prefill at 20480 wide, a tenth of a
+        serving loop's time: PERF.md section 6, PR 27)."""
         b, s_blk = tokens.shape
         positions = jnp.broadcast_to(
             jax.lax.iota(jnp.int32, s_blk)[None, :], (b, s_blk))
@@ -759,8 +964,19 @@ class PagedDecoder(_DecodeGraph):
                 self._pos_id.tensor_id: positions}
         new_pool = dict(pool)
         bs = self.block_size
+        routed: Dict[str, jax.Array] = {}
 
-        def attn(op, p, x):
+        def experts(op, p, x):
+            x2d = x.reshape(-1, x.shape[-1])
+            ids, gates = op.route(p, x2d)
+            routed[op.name] = ids.reshape(b, s_blk, -1)
+            return op.apply(p, x2d, ids, gates).reshape(x.shape)
+
+        def attn(op, p, x, pos=None):
+            if pos is not None:
+                out, new_pool[op.name] = _latent_attn_prefill(
+                    op, p, x, pos, new_pool[op.name], tables, lengths)
+                return out
             qh = jnp.einsum("bse,ehd->bshd", x, p["wq"])
             kh = jnp.einsum("bse,ehd->bshd", x, p["wk"])
             vh = jnp.einsum("bse,ehd->bshd", x, p["wv"])
@@ -795,18 +1011,56 @@ class PagedDecoder(_DecodeGraph):
                 vh.reshape(b * s_blk, heads, hdim))
             return out
 
-        logits = self._forward_block(params, acts, attn)
-        return logits, new_pool
+        logits = self._forward_block(params, acts, attn, experts)
+        last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
+        return last, new_pool, routed
+
+    def _pool_specs(self) -> Dict[str, Tuple[int, ...]]:
+        """What a token's row is for each attention op (kv_cache.py)."""
+        return {op.name: ((op.row_width,)
+                          if op.op_type is OpType.LATENT_ATTENTION
+                          else (op.num_heads, op.head_dim))
+                for op in self._attn_ops}
+
+    def expert_stats(self) -> Dict[str, Dict]:
+        """Per routed-experts op, counted on the device over the decode
+        steps' active slots: ``steps``, ``pairs_routed`` (tokens x picks),
+        ``pairs_held`` (those whose expert this op holds),
+        ``idle_held_experts`` (held experts that got no row, summed over
+        steps), ``rows_per_held_expert`` (count,). One fetch of a few
+        hundred bytes, which waits for a decode step in flight; {} for a
+        graph with no such op."""
+        if not self._expert_ops:
+            return {}
+        with self._expert_acc_lock:
+            fetched = jax.device_get(self._expert_acc)
+        out = {}
+        for op in self._expert_ops:
+            acc = fetched[op.name].astype(np.uint64)
+            acc = [int(v) for v in (acc[1] << np.uint64(32)) | acc[0]]
+            out[op.name] = {
+                "held": [op.first, op.count], "n_routed": op.n_routed,
+                "steps": acc[0], "pairs_routed": acc[1],
+                "pairs_held": acc[2], "idle_held_experts": acc[3],
+                "rows_per_held_expert": acc[4:]}
+        return out
 
     def _attention_path(self, window: int) -> str:
         """What a W-token step's attention does with the pool as it is
         now: "kernel" where every attention op's entry is read in
         place, else "gather"."""
-        return "kernel" if all(
-            _kernel_reads(self.pool.kv[op.name],
-                          (self.decode_slots, window, op.num_heads,
-                           op.head_dim), self.max_blocks_per_request)
-            for op in self._attn_ops) else "gather"
+        def in_place(op):
+            entry = self.pool.kv[op.name]
+            if op.op_type is OpType.LATENT_ATTENTION:
+                return window == 1 and _latent_kernel_reads(
+                    op, entry, self.decode_slots,
+                    self.max_blocks_per_request)
+            return _kernel_reads(
+                entry, (self.decode_slots, window, op.num_heads,
+                        op.head_dim), self.max_blocks_per_request)
+
+        return "kernel" if all(in_place(op)
+                               for op in self._attn_ops) else "gather"
 
     def _prefill_fn(self, bucket: int, width: int = 1):
         """The (bucket, row-width) executable — the seen-set is the
@@ -850,9 +1104,12 @@ class PagedDecoder(_DecodeGraph):
         tables_sds = jax.ShapeDtypeStruct(
             (self.decode_slots, self.max_blocks_per_request), jnp.int32)
         lens_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.int32)
+        acc_sds = {name: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                   for name, a in self._expert_acc.items()}
         self.audit_report, self.exec_telemetry = _audit_serving_program(
             "serving.paged_decode_step", self._decode,
-            (params_sds, tokens_sds, pool_sds, tables_sds, lens_sds), cfg)
+            (params_sds, tokens_sds, pool_sds, tables_sds, lens_sds,
+             acc_sds), cfg)
 
     # ---- host API (the scheduler's surface) --------------------------------
     def prefill(self, prompt: np.ndarray, table: np.ndarray) -> np.ndarray:
@@ -899,12 +1156,10 @@ class PagedDecoder(_DecodeGraph):
             lengths[i] = lens[i]
         fn = self._prefill_fn(bucket, width)
         with span("serving.loop.dispatch", cat="serving"):
-            logits, self.pool.kv = fn(
+            logits, self.pool.kv, self.last_routing = fn(
                 self._exec_params(), jnp.asarray(toks), self.pool.kv,
                 jnp.asarray(tabs), jnp.asarray(lengths))
-        out = self._fetch(logits)
-        rows = np.arange(len(arrs))
-        return out[rows, np.asarray(lens) - 1]
+        return self._fetch(logits)[:len(arrs)]
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
                seq_lens: np.ndarray) -> np.ndarray:
@@ -912,13 +1167,16 @@ class PagedDecoder(_DecodeGraph):
         many are active). Returns (slots, vocab) float32 logits."""
         self.decode_steps += 1
         self.decode_dispatches += 1
-        with span("serving.loop.dispatch", cat="serving"):
-            logits, self.pool.kv = self._decode(
+        with span("serving.loop.dispatch", cat="serving"), \
+                self._expert_acc_lock:
+            (logits, self.pool.kv, self.last_routing,
+             self._expert_acc) = self._decode(
                 self._exec_params(),
                 jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
                 self.pool.kv,
                 jnp.asarray(np.asarray(tables, np.int32)),
-                jnp.asarray(np.asarray(seq_lens, np.int32)))
+                jnp.asarray(np.asarray(seq_lens, np.int32)),
+                self._expert_acc)
         return self._fetch(logits)
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
@@ -931,6 +1189,7 @@ class PagedDecoder(_DecodeGraph):
         distribution after window position j."""
         tokens = np.asarray(tokens, np.int32)
         w = int(tokens.shape[1])
+        self._refuse_verify_over_latent()
         fn = self._verify_fns.get(w)
         if fn is None:
             fn = jax.jit(self._verify_step, donate_argnums=(2,))
@@ -939,11 +1198,22 @@ class PagedDecoder(_DecodeGraph):
         self.decode_steps += 1
         self.decode_dispatches += 1
         with span("serving.loop.dispatch", cat="serving"):
-            logits, self.pool.kv = fn(
+            logits, self.pool.kv, self.last_routing = fn(
                 self._exec_params(), jnp.asarray(tokens), self.pool.kv,
                 jnp.asarray(np.asarray(tables, np.int32)),
                 jnp.asarray(np.asarray(seq_lens, np.int32)))
         return self._fetch(logits)
+
+    def _refuse_verify_over_latent(self) -> None:
+        """Speculative verify (W > 1 tokens a slot) is not built for a
+        latent cache entry: refuse, loudly, rather than fall back."""
+        latent = [op.name for op in self._attn_ops
+                  if op.op_type is OpType.LATENT_ATTENTION]
+        if latent:
+            raise ValueError(
+                f"speculative verify over a latent cache entry is not "
+                f"built ({latent[0]} and {len(latent) - 1} more): serve "
+                f"this model with spec_k=0")
 
     def _fetch(self, logits) -> np.ndarray:
         """The other half of a dispatch: wait for the device and copy
@@ -955,10 +1225,13 @@ class PagedDecoder(_DecodeGraph):
             return np.asarray(logits)
 
     # ---- KV quantization gate (KVQ001) -------------------------------------
-    def _dense_reference_logits(self, tokens: np.ndarray) -> np.ndarray:
+    def _dense_reference_logits(self, tokens: np.ndarray,
+                                routing=None) -> np.ndarray:
         """Eager (un-jitted) dense causal forward over one full
         sequence — the cache-free reference the quantized pool is
-        calibrated against. Returns (S, vocab) float32 logits."""
+        calibrated against. ``routing`` ({routed-experts op name: (S, k)
+        expert ids}) makes the expert layers take those experts. Returns
+        (S, vocab) float32 logits."""
         tokens = np.asarray(tokens, np.int32)
         s = tokens.shape[0]
         acts = {
@@ -966,7 +1239,9 @@ class PagedDecoder(_DecodeGraph):
             self._pos_id.tensor_id:
                 jnp.asarray(np.arange(s, dtype=np.int32)[None, :])}
 
-        def attn(op, p, x):
+        def attn(op, p, x, pos=None):
+            if pos is not None:     # latent attention's own dense forward
+                return op.forward(None, [x, pos], p)[0]
             qh = jnp.einsum("bse,ehd->bshd", x, p["wq"])
             kh = jnp.einsum("bse,ehd->bshd", x, p["wk"])
             vh = jnp.einsum("bse,ehd->bshd", x, p["wv"])
@@ -986,7 +1261,13 @@ class PagedDecoder(_DecodeGraph):
                 out = out + p["bo"]
             return out
 
-        logits = self._forward_block(self._exec_params(), acts, attn)
+        def experts(op, p, x):
+            x2d = x.reshape(-1, x.shape[-1])
+            ids, gates = op.route(p, x2d, jnp.asarray(routing[op.name]))
+            return op.apply(p, x2d, ids, gates).reshape(x.shape)
+
+        logits = self._forward_block(self._exec_params(), acts, attn,
+                                     experts if routing else None)
         return np.asarray(logits[0], np.float32)
 
     def _calibrate_kv_quant(self, budget: Optional[float]) -> None:
@@ -1013,8 +1294,6 @@ class PagedDecoder(_DecodeGraph):
         # reference: dense cache-free forward, then one more position
         ref = self._dense_reference_logits(prompt)
         nxt = int(ref[-1].argmax(-1))
-        ref_row = self._dense_reference_logits(
-            np.concatenate([prompt, [nxt]]))[-1]
         # quantized path: the exact programs serving will dispatch
         table = self.pool.try_admit(prompt_len + 1)
         if table is None:  # pragma: no cover — fresh pool always fits
@@ -1022,6 +1301,8 @@ class PagedDecoder(_DecodeGraph):
                                "fresh pool")
         try:
             self.prefill(prompt, table)
+            routed = {k: [np.asarray(v)[0, :prompt_len]]
+                      for k, v in self.last_routing.items()}
             toks = np.zeros(self.decode_slots, np.int32)
             toks[0] = nxt
             tabs = np.full((self.decode_slots, self.max_blocks_per_request),
@@ -1030,8 +1311,18 @@ class PagedDecoder(_DecodeGraph):
             lens = np.zeros(self.decode_slots, np.int32)
             lens[0] = prompt_len
             q_row = self.decode(toks, tabs, lens)[0]
+            for k, v in self.last_routing.items():
+                routed[k].append(np.asarray(v)[:1])
         finally:
             self.pool.free(table)
+        # a routed layer is discontinuous: two programs a rounding apart
+        # may take different experts, and the logits then differ by a
+        # whole expert's output, which says nothing about the cache. The
+        # reference therefore follows the routing the paged programs
+        # chose (its own scores for the weights).
+        ref_row = self._dense_reference_logits(
+            np.concatenate([prompt, [nxt]]),
+            {k: np.concatenate(v) for k, v in routed.items()})[-1]
         self.kv_divergence = float(np.max(np.abs(q_row - ref_row)))
         if self.kv_divergence <= budget:
             return
@@ -1055,12 +1346,12 @@ class PagedDecoder(_DecodeGraph):
               file=sys.stderr)
         self.kv_dtype = "float32"
         dt = self._compute_dtype() or jnp.float32
-        self.pool = PagedKVPool(
-            {op.name: (op.num_heads, op.head_dim)
-             for op in self._attn_ops},
-            num_blocks=self.pool.num_blocks, block_size=self.block_size,
+        fallback = PagedKVPool(
+            self._pool_specs(), num_blocks=self.pool.num_blocks,
+            block_size=self.block_size,
             max_blocks_per_request=self.max_blocks_per_request, dtype=dt,
             kv_dtype="float32")
+        self.pool = fallback  # concurrency: race-ok (calibration runs inside __init__, before the scheduler's thread or any stats() reader exists)
         self.attention_path["decode"] = self._attention_path(1)
 
 

@@ -87,11 +87,21 @@ NULL_BLOCK = 0  # reserved scatter/gather sink; never allocated
 KV_DTYPES = ("float32", "bfloat16", "int8")
 
 
+def latent_row_lanes(width: int) -> int:
+    """A latent row's width in the arena: whole 128-lane tiles."""
+    return -(-int(width) // 128) * 128
+
+
 class PagedKVPool:
     """Block pool + allocator for one model's attention ops.
 
-    ``specs``: ``{attention op name: (num_heads, head_dim)}`` — one
-    (k, v) arena pair per op, all sharing the same block geometry and
+    ``specs``: ``{attention op name: spec}`` says what a token's row is
+    for each op: ``(num_heads, head_dim)`` — one (k, v) arena pair — or
+    the 1-tuple ``(row_width,)`` of a latent-attention op, whose cache
+    is ONE row a token (its normalized latent and its rotary key, which
+    keys and values are both read from): a 1-tuple entry
+    ``(num_blocks, block_size, row_lanes)``, the width padded up to whole
+    128-lane tiles with zeros. All ops share the same block geometry and
     allocator (a token occupies one slot in EVERY layer's arena, so one
     block id spans all layers — the allocator hands out block ids, not
     per-layer storage).
@@ -103,7 +113,7 @@ class PagedKVPool:
     thread, capacity introspection on callers' threads.
     """
 
-    def __init__(self, specs: Dict[str, Tuple[int, int]], *,
+    def __init__(self, specs: Dict[str, Tuple[int, ...]], *,
                  num_blocks: int, block_size: int,
                  max_blocks_per_request: int, dtype=jnp.float32,
                  kv_dtype: str = "float32"):
@@ -125,13 +135,25 @@ class PagedKVPool:
         self.dtype = dtype
         self.kv_dtype = kv_dtype
         self.specs = dict(specs)
-        # arena entry per op: (k, v) for float/bf16 storage, or the
-        # 6-tuple (k_q, v_q, k_scale, k_zero, v_scale, v_zero) for int8
-        # — the generation helpers dispatch on the tuple length, so the
-        # donated pytree structure is the only quantization "flag" the
-        # compiled programs ever see
+        # arena entry per op: (k, v) for float/bf16 storage, the
+        # 6-tuple (k_q, v_q, k_scale, k_zero, v_scale, v_zero) for int8,
+        # or the 1-tuple (rows,) of a latent op — the generation helpers
+        # dispatch on the tuple length, so the donated pytree structure
+        # is the only "flag" the compiled programs ever see
         self.kv: Dict[str, Tuple[jnp.ndarray, ...]] = {}
-        for name, (heads, head_dim) in self.specs.items():
+        for name, spec in self.specs.items():
+            if len(spec) == 1:
+                if kv_dtype == "int8":
+                    raise ValueError(
+                        f"{name}: a latent cache entry has no int8 form "
+                        f"(kv_dtype='int8' quantizes per head, and a "
+                        f"latent row has no heads); use 'bfloat16'")
+                store = jnp.bfloat16 if kv_dtype == "bfloat16" else dtype
+                self.kv[name] = (jnp.zeros(
+                    (self.num_blocks, self.block_size,
+                     latent_row_lanes(spec[0])), store),)
+                continue
+            heads, head_dim = spec
             # one row a token, all heads side by side (module docstring)
             shape = (self.num_blocks, self.block_size, heads * head_dim)
             if kv_dtype == "int8":
@@ -174,7 +196,8 @@ class PagedKVPool:
             return self.num_blocks * self.block_size * per_tok
         item = (2 if self.kv_dtype == "bfloat16"
                 else jnp.dtype(self.dtype).itemsize)
-        per_tok = sum(2 * h * d for h, d in self.specs.values())
+        per_tok = sum(latent_row_lanes(s[0]) if len(s) == 1
+                      else 2 * s[0] * s[1] for s in self.specs.values())
         return self.num_blocks * self.block_size * per_tok * item
 
     # ---- allocator ---------------------------------------------------------
@@ -251,7 +274,21 @@ class PagedKVPool:
             "high_water": hw,
             "memory_bytes": int(self.memory_bytes()),
             "kv_dtype": self.kv_dtype,
+            # what a token's row is: "pair" (k, v), "int8" (values and
+            # sidecars) or "latent" (one row, its width as cached and as
+            # the arena pads it)
+            **self._entry_stats(),
         }
 
+    def _entry_stats(self) -> Dict:
+        latent = [s[0] for s in self.specs.values() if len(s) == 1]
+        if not latent:
+            return {"entry": "int8" if self.kv_dtype == "int8" else "pair"}
+        if len(latent) != len(self.specs):
+            return {"entry": "mixed"}
+        return {"entry": "latent", "row_width": int(latent[0]),
+                "row_lanes": latent_row_lanes(latent[0])}
 
-__all__ = ["KV_DTYPES", "NULL_BLOCK", "PagedKVPool", "KVPoolExhausted"]
+
+__all__ = ["KV_DTYPES", "NULL_BLOCK", "PagedKVPool", "KVPoolExhausted",
+           "latent_row_lanes"]

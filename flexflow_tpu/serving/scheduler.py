@@ -262,6 +262,7 @@ class ContinuousBatchingScheduler:
         self.spec_k = max(0, int(spec_k))
         self.draft: Optional[PagedDecoder] = None
         if self.spec_k > 0:
+            self.decoder._refuse_verify_over_latent()
             if draft_ff is None:
                 raise ValueError(
                     f"{name!r}: spec_k={self.spec_k} needs a draft model "
@@ -1187,7 +1188,9 @@ class ContinuousBatchingScheduler:
         if self.decoder.kv_divergence is not None:
             kv["divergence"] = self.decoder.kv_divergence
             kv["quant_fallback"] = self.decoder.kv_quant_report is not None
+        moe = self.decoder.expert_stats()
         return {
+            **({"moe": moe} if moe else {}),
             "serving_engine": "continuous",
             "model": self.name,
             "queued": queued,

@@ -81,7 +81,9 @@ def serving_kv_pool_bytes(specs, num_blocks: int, block_size: int,
                       for h, d in dict(specs).values())
         return int(num_blocks) * int(block_size) * per_tok
     item = 2 if kv_dtype == "bfloat16" else int(dtype_bytes)
-    per_tok = sum(2 * h * d for h, d in dict(specs).values())
+    # a latent op's 1-tuple spec: one row a token, padded to 128 lanes
+    per_tok = sum(-(-s[0] // 128) * 128 if len(s) == 1 else 2 * s[0] * s[1]
+                  for s in dict(specs).values())
     return int(num_blocks) * int(block_size) * per_tok * item
 
 

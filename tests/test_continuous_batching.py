@@ -85,9 +85,10 @@ def test_paged_decode_bit_identical_per_zoo_causal_lm():
                                  computation_mode=CompMode.INFERENCE,
                                  ledger="off"))
         build(probe, 4)
-        if not any(layer.op_type is OpType.MULTIHEAD_ATTENTION
-                   and layer.attrs.get("causal")
-                   and len({t.tensor_id for t in layer.inputs}) == 1
+        if not any((layer.op_type is OpType.MULTIHEAD_ATTENTION
+                    and layer.attrs.get("causal")
+                    and len({t.tensor_id for t in layer.inputs}) == 1)
+                   or layer.op_type is OpType.LATENT_ATTENTION
                    for layer in probe.layers):
             continue  # not a causal LM — the generator would reject it
         probe.compile(optimizer=None, loss_type=None, metrics=[])
@@ -132,7 +133,8 @@ def test_paged_decode_bit_identical_per_zoo_causal_lm():
                 nxt = int(dense.argmax())
             dec.pool.free(table)
         covered.append(name)
-    assert "gpt" in covered, f"zoo causal-LM sweep covered {covered}"
+    assert {"gpt", "latent_moe"} <= set(covered), \
+        f"zoo causal-LM sweep covered {covered}"
 
 
 def test_paged_decoder_audit_clean_with_donated_pool(gpt):
@@ -276,6 +278,25 @@ def test_prefill_many_bit_identical_to_single_path(gpt):
     # unseen shape a counted compile miss
     assert all(w > 1 for (_b, w) in many._prefill_fns)
     assert all(w == 1 for (_b, w) in one._prefill_fns)
+
+
+def test_prefill_fetches_each_rows_last_position_only(gpt, monkeypatch):
+    """What a prefill hands the host is (rows, vocab) — each prompt's
+    last position, which is all a caller reads — not the bucket's
+    (rows, bucket, vocab); and it is the dense forward's row."""
+    dec = PagedDecoder(gpt, max_length=32, decode_slots=4, block_size=8)
+    fetched = []
+    fetch = dec._fetch
+    monkeypatch.setattr(dec, "_fetch",
+                        lambda a: fetched.append(a.shape) or fetch(a))
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, V, (n,)).astype(np.int32) for n in (5, 9, 3)]
+    tabs = [dec.pool.try_admit(p.size + 1) for p in prompts]
+    rows = dec.prefill_many(prompts, tabs)
+    assert fetched == [(4, V)] and rows.shape == (3, V)
+    for p, row in zip(prompts, rows):
+        want = dec._dense_reference_logits(p)[-1]
+        np.testing.assert_allclose(row, want, rtol=2e-5, atol=2e-5)
 
 
 def test_token_budget_scheduler_batches_prefills_same_tokens(gpt):

@@ -47,6 +47,15 @@ def test_paged_attention_compiled(heads, window):
                                      mosaic=True, window=window)
 
 
+@pytest.mark.parametrize("slots,max_blocks", [(128, 256), (4, 64)])
+def test_latent_attention_compiled(slots, max_blocks):
+    """The reasoning cell's pool (128 slots of 256 blocks of 16 tokens,
+    64 heads over rows of 512 + 64 padded to 640 lanes, bfloat16: 131 KB
+    of block tables in SMEM) and a small one."""
+    chip_smoke.check_latent_attention(slots, 64, 512, 64, 16, max_blocks,
+                                      "bfloat16", mosaic=True)
+
+
 def test_flash_autotune_on_chip(monkeypatch):
     """Compiled-mode autotune at the bench shape; under forced compiled
     mode a Mosaic refusal of any candidate raises instead of being
