@@ -610,6 +610,15 @@ def phase_serve(sizes: SmokeSizes) -> Dict:
         path = st["kv"]["attention_path"]["decode"]
         _require(path == "kernel" or jax.default_backend() != "tpu",
                  f"the decode step's attention took the {path!r} path")
+        # nor a silent synchronous loop: every request here is greedy,
+        # so each step but a run's first is dispatched while the one
+        # before it is still unread
+        ran = st["loop"]["ahead"]
+        ahead_share = ran["steps_ahead"] / max(
+            1, ran["steps_ahead"] + ran["steps_sync"])
+        _require(ahead_share > 0.9,
+                 f"{ran['steps_ahead']} steps ran ahead, "
+                 f"{ran['steps_sync']} were waited for")
         arena = next(iter(inst.decoder.pool.kv.values()))[0]
         _require(st["kv"]["kv_dtype"] == KV_DTYPE
                  and arena.dtype == jnp.dtype(KV_DTYPE)
@@ -645,6 +654,7 @@ def phase_serve(sizes: SmokeSizes) -> Dict:
         decode_steps=st["decode_steps"],
         prefill_dispatches=st["prefill_dispatches"],
         kv_dtype=st["kv"]["kv_dtype"], attention_path=path,
+        steps_ahead_share=round(ahead_share, 4),
         kv_blocks_read_share=round(
             st["kv"]["blocks_read"] / max(1, st["kv"]["blocks_in_tables"]),
             4),

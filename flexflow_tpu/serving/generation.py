@@ -65,6 +65,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ffconst import OpType
 from ..core.op import LowerCtx
@@ -803,6 +804,13 @@ class PagedDecoder(_DecodeGraph):
       ``serving.prefill_bucket_compiles``) that compute the prompt's
       K/V, scatter it into the pool through the block table, and return
       the full-prompt logits — one dispatch per prefill.
+    * the decode program keeps the greedy token's loop on the device: it
+      returns each row's ``argmax`` as int32 ids beside the logits and
+      takes the step before's ids back (``prev_ids``, ``take_prev``).
+      :meth:`decode` dispatches a step, waits, and returns its numpy
+      logits; :meth:`decode_ahead` dispatches the same executable and
+      returns the ids on the device without waiting, which is how the
+      scheduler runs one step ahead of what it has read.
     """
 
     def __init__(self, ff, max_length: int, *, decode_slots: int = 4,
@@ -839,6 +847,14 @@ class PagedDecoder(_DecodeGraph):
             op.name: jnp.zeros((2, 4 + op.count), jnp.uint32)
             for op in self._expert_ops}
         self._expert_acc_lock = threading.Lock()
+        # the greedy ids the last decode step chose, (slots,) int32 on
+        # the device: the next step's ``prev_ids``. Placed as the
+        # program places what it returns (replicated over the model's
+        # mesh, committed), so that the first step's signature is every
+        # later step's and costs no compile of its own.
+        self._ids: jax.Array = jax.device_put(
+            np.zeros((self.decode_slots,), np.int32),
+            NamedSharding(self._cm.mesh, PartitionSpec()))
         # the expert ids the last prefill or decode call chose, {routed-
         # experts op name: (rows..., k) int32 device array}: kept for
         # whoever asks (a comparison with a reference), never fetched by
@@ -879,12 +895,19 @@ class PagedDecoder(_DecodeGraph):
 
     # ---- compiled programs -------------------------------------------------
     def _decode_step(self, params, tokens, pool, tables, seq_lens,
-                     expert_acc):
-        """One decode step for all slots: tokens (slots, 1) int32, pool
+                     expert_acc, prev_ids, take_prev):
+        """One decode step for all slots: tokens (slots,) int32, pool
         {op: arena entry} donated, tables (slots, MB) int32, seq_lens
         (slots,) int32, expert_acc {routed-experts op: counters}
-        donated. Returns ((slots, vocab) float32 logits, new pool, the
-        expert ids chosen, new counters)."""
+        donated, prev_ids (slots,) int32 the ids the step before
+        returned (not donated: the host may still be fetching them),
+        take_prev (slots,) bool. Slot i's token is ``prev_ids[i]`` where
+        ``take_prev[i]``, else ``tokens[i]``: a greedy token goes from
+        one step to the next without leaving the device. Returns
+        ((slots, vocab) float32 logits, new pool, the expert ids
+        chosen, new counters, (slots,) int32 ids: each row's first
+        maximum, what ``np.argmax`` of the fetched row gives)."""
+        tokens = jnp.where(take_prev, prev_ids, tokens)[:, None]
         positions = seq_lens[:, None]                           # (slots, 1)
         acts = {self._token_id.tensor_id: tokens,
                 self._pos_id.tensor_id: positions}
@@ -911,8 +934,9 @@ class PagedDecoder(_DecodeGraph):
                 new_acc[op.name], _expert_counts(op, ids, active))
             return op.apply(p, x2d, ids, gates).reshape(x.shape)
 
-        logits = self._forward_block(params, acts, attn, experts)
-        return logits[:, -1, :], new_pool, routed, new_acc
+        logits = self._forward_block(params, acts, attn, experts)[:, -1, :]
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return logits, new_pool, routed, new_acc, ids
 
     def _verify_step(self, params, tokens, pool, tables, seq_lens):
         """Speculative verify: tokens (slots, W) int32 — each slot's
@@ -1097,19 +1121,20 @@ class PagedDecoder(_DecodeGraph):
             return jax.ShapeDtypeStruct(a.shape, dt)
 
         params_sds = jax.tree_util.tree_map(_sds, self._cm.params)
-        tokens_sds = jax.ShapeDtypeStruct((self.decode_slots, 1), jnp.int32)
         pool_sds = {name: tuple(jax.ShapeDtypeStruct(k.shape, k.dtype)
                                 for k in kv)
                     for name, kv in self.pool.kv.items()}
         tables_sds = jax.ShapeDtypeStruct(
             (self.decode_slots, self.max_blocks_per_request), jnp.int32)
+        # tokens, seq_lens and prev_ids: one (slots,) int32 each
         lens_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.int32)
         acc_sds = {name: jax.ShapeDtypeStruct(a.shape, a.dtype)
                    for name, a in self._expert_acc.items()}
+        take_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.bool_)
         self.audit_report, self.exec_telemetry = _audit_serving_program(
             "serving.paged_decode_step", self._decode,
-            (params_sds, tokens_sds, pool_sds, tables_sds, lens_sds,
-             acc_sds), cfg)
+            (params_sds, lens_sds, pool_sds, tables_sds, lens_sds,
+             acc_sds, lens_sds, take_sds), cfg)
 
     # ---- host API (the scheduler's surface) --------------------------------
     def prefill(self, prompt: np.ndarray, table: np.ndarray) -> np.ndarray:
@@ -1164,20 +1189,43 @@ class PagedDecoder(_DecodeGraph):
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
                seq_lens: np.ndarray) -> np.ndarray:
         """One decode step for all slots (ONE dispatch regardless of how
-        many are active). Returns (slots, vocab) float32 logits."""
+        many are active), waited for. Returns (slots, vocab) float32
+        logits."""
+        return self._fetch(self._dispatch_decode(tokens, tables, seq_lens,
+                                                 None)[0])
+
+    def decode_ahead(self, tokens: np.ndarray, tables: np.ndarray,
+                     seq_lens: np.ndarray,
+                     take_prev: np.ndarray) -> jax.Array:
+        """The same step through the same executable, dispatched and
+        not waited for. Slot i's token is the greedy one the step
+        before chose (still on the device) where ``take_prev[i]``, else
+        ``tokens[i]``. Returns this step's (slots,) int32 greedy ids as
+        a device array: ``np.asarray`` of it is the wait for the step;
+        the logits are never fetched."""
+        return self._dispatch_decode(tokens, tables, seq_lens, take_prev)[1]
+
+    def _dispatch_decode(self, tokens, tables, seq_lens, take_prev):
+        """The decode program's one call site, so that a step waited for
+        and a step run ahead pass the same signature (a second one
+        would be a second compile): (logits, ids), both on the device.
+        ``take_prev`` None: every slot takes ``tokens``."""
         self.decode_steps += 1
         self.decode_dispatches += 1
+        if take_prev is None:
+            take_prev = np.zeros(self.decode_slots, bool)
         with span("serving.loop.dispatch", cat="serving"), \
                 self._expert_acc_lock:
-            (logits, self.pool.kv, self.last_routing,
-             self._expert_acc) = self._decode(
+            (logits, self.pool.kv, self.last_routing, self._expert_acc,
+             self._ids) = self._decode(
                 self._exec_params(),
-                jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
+                jnp.asarray(np.asarray(tokens, np.int32)),
                 self.pool.kv,
                 jnp.asarray(np.asarray(tables, np.int32)),
                 jnp.asarray(np.asarray(seq_lens, np.int32)),
-                self._expert_acc)
-        return self._fetch(logits)
+                self._expert_acc, self._ids,
+                jnp.asarray(np.asarray(take_prev, bool)))
+        return logits, self._ids
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
                seq_lens: np.ndarray) -> np.ndarray:

@@ -12,6 +12,11 @@ steps**:
   :class:`~flexflow_tpu.serving.generation.PagedDecoder`); the decode
   loop issues one dispatch per step regardless of how many slots are
   live;
+* a greedy session's loop runs **one step ahead**: a pass dispatches
+  step n+1 (each slot's token taken from step n's ids on the device)
+  and only then reads step n's ids, so the host's work hides behind the
+  chip's; a sampled slot or speculation makes the pass the synchronous
+  one (:meth:`ContinuousBatchingScheduler._decode_once`);
 * prompts run through the separate bucketed prefill executable, their
   K/V scattered straight into the pool; at most
   ``max_prefills_per_step`` prefills are interleaved between decode
@@ -111,7 +116,12 @@ class _LoopClock:
     their sum is the time since the loop first started. ``steps``
     counts the passes that ran a decode step or a speculative round
     and ``step_wall`` holds their wall times; ``token_gap`` the time
-    between a request's consecutive tokens."""
+    between a request's consecutive tokens; ``ahead`` how the steps met
+    the host: ``steps_ahead`` were dispatched while the step before was
+    still unread, ``steps_sync`` had their logits waited for, and
+    ``rows_dropped`` rows were computed for a request that had already
+    ended (a step dispatched with nothing before it unread, and read a
+    pass later, is in neither count)."""
 
     def __init__(self):
         self._lock = threading.Lock()  # enter() against snapshot()
@@ -122,6 +132,7 @@ class _LoopClock:
         self.steps = 0
         self.step_wall = Histogram()
         self.token_gap = Histogram()
+        self.ahead = {"steps_ahead": 0, "steps_sync": 0, "rows_dropped": 0}
 
     def enter(self, phase: Optional[str]):
         """The thread is in ``phase`` from now on. Returns the boundary
@@ -144,6 +155,11 @@ class _LoopClock:
             self.steps += 1
         self.step_wall.observe(wall_s)
 
+    def count(self, key: str, n: int = 1) -> None:
+        """``n`` more of ``ahead[key]``."""
+        with self._lock:
+            self.ahead[key] += n
+
     def snapshot(self) -> Dict:
         """``stats()["loop"]``; the phase that is open is charged up to
         this moment, so two snapshots subtract to what lay between."""
@@ -155,8 +171,10 @@ class _LoopClock:
             else:
                 now = self.t  # the loop has ended, or never began
             steps = self.steps
+            ahead = dict(self.ahead)
             elapsed = 0.0 if self.t_start is None else now - self.t_start
         return {"steps": steps, "elapsed_s": elapsed, "phase_s": phase_s,
+                "ahead": ahead,
                 "step_wall": self.step_wall.to_json(),
                 "token_gap": self.token_gap.to_json()}
 
@@ -184,6 +202,12 @@ class _Phase:
         return False
 
 
+# a decode step dispatched and not yet read: ``rows`` [(slot, request)]
+# it carries, ``ids`` its (slots,) greedy tokens on the device, ``t0``
+# the moment of its dispatch
+_Step = collections.namedtuple("_Step", "rows ids t0")
+
+
 class GenerationRequest:
     """One queued/in-flight generation request. The ``future`` resolves
     to the full (prompt + generated) int32 token array — exactly
@@ -192,7 +216,7 @@ class GenerationRequest:
     __slots__ = ("request_id", "prompt", "max_new_tokens", "temperature",
                  "seed", "eos_id", "deadline_s", "t_enqueue", "future",
                  # scheduler-thread-only runtime state
-                 "table", "seq_len", "tokens", "rng", "t_admit",
+                 "table", "seq_len", "in_flight", "tokens", "rng", "t_admit",
                  "t_prefill_done", "t_first_token", "t_last_token",
                  "decode_t0", "decode_steps")
 
@@ -209,7 +233,8 @@ class GenerationRequest:
         self.t_enqueue = time.perf_counter()
         self.future: Future = Future()
         self.table = None
-        self.seq_len = 0
+        self.seq_len = 0  # rows cached, those of steps in flight included
+        self.in_flight = 0  # tokens dispatched for and not yet read
         self.tokens: List[int] = []
         self.rng = None
         self.t_admit = None
@@ -336,6 +361,12 @@ class ContinuousBatchingScheduler:
         self._lat_total: Dict[str, List[float]] = {
             k: [0, 0.0] for k in self._lat}
         self._clock = _LoopClock()
+        # decode steps dispatched and not yet read, oldest first: one
+        # between two passes of a greedy session, two between a pass's
+        # dispatch and its fetch. The loop's thread alone touches it; it
+        # lives here and not in that thread's frame, so a respawned
+        # worker reads what the dead one dispatched
+        self._in_flight: collections.deque = collections.deque()
         for dec in filter(None, (self.decoder, self.draft)):
             dec.on_dispatched = self._on_dispatched
         self._publish_due = False
@@ -480,6 +511,7 @@ class ContinuousBatchingScheduler:
             self._queue.clear()
             active = [r for r in self._slots if r is not None]
             self._slots = [None] * len(self._slots)
+        self._in_flight.clear()  # their rows' requests fail with the rest
         metrics_registry().counter("serving.abandoned_failed").inc(
             len(pending) + len(active))
         wrapped = RuntimeError(
@@ -508,7 +540,7 @@ class ContinuousBatchingScheduler:
         import contextlib
 
         def idle() -> bool:
-            return (not self._queue
+            return (not self._queue and not self._in_flight
                     and not any(r is not None for r in self._slots))
 
         first_step = True
@@ -527,7 +559,7 @@ class ContinuousBatchingScheduler:
             # round; whatever of it no inner span covers is "other"
             step = self._phase("step", "other", step=self._clock.steps,
                                active=active, queued=queued)
-            stepped = False
+            stepped = ahead = False
             with step:
                 # fault site: decode-worker crash — state stays on the
                 # scheduler, so the respawned worker resumes every request
@@ -538,14 +570,15 @@ class ContinuousBatchingScheduler:
                 self._admit(closed)
                 with self._mu:
                     active = any(r is not None for r in self._slots)
-                if active:
+                if active or self._in_flight:
                     # watchdog: only ACTIVE decode work is watched; the
                     # first step runs unwatched through the cold XLA compile
                     ctx = (contextlib.nullcontext() if first_step
                            else _wd_watch(f"serving.gen.{self.name}"))
                     first_step = False
                     with ctx:
-                        stepped = self._decode_once()
+                        stepped, ahead = self._decode_once()
+                step.span.set(ahead=int(ahead))
                 if self._publish_due:
                     self._publish_due = False  # hotpath: lock-ok (flag of the loop thread alone)
                     self._publish_attribution()
@@ -799,8 +832,11 @@ class ContinuousBatchingScheduler:
     # ---- decode ------------------------------------------------------------
     def _step_inputs(self):
         """What a decode step or speculative round starts from: the
-        live slots, with each one's last token, block table and cached
-        length in slot-width arrays; None where no slot is live."""
+        slots it carries, with each one's last token, block table and
+        cached length in slot-width arrays; None where it would carry
+        none. A live slot is left out where the token it has in flight
+        is the last its request may have: the row would be computed for
+        nobody."""
         reg = metrics_registry()
         with self._phase("inputs") as ph:
             now = ph.t0
@@ -825,7 +861,8 @@ class ContinuousBatchingScheduler:
                             f"({len(req.tokens)}/{req.max_new_tokens} "
                             f"tokens)"))
             active = [(i, r) for i, r in enumerate(slots)
-                      if r is not None and i not in expired]
+                      if r is not None and i not in expired
+                      and len(r.tokens) + r.in_flight < r.max_new_tokens]
             if not active:
                 return None
             n_slots = len(slots)
@@ -848,17 +885,17 @@ class ContinuousBatchingScheduler:
         return active, tokens, tables, seq_lens
 
     def _dispatch(self, fn, *args):
-        """One jitted call and the fetch of its logits, on the clock:
-        ``dispatch`` until the call returns, ``fetch`` (entered by
-        :meth:`_on_dispatched`, between the decoder's two spans) until
-        the logits are on the host. Returns the logits and the pair's
-        two ends."""
+        """One jitted call and, where ``fn`` waits for them, the fetch
+        of its logits, on the clock: ``dispatch`` until the call
+        returns, ``fetch`` (entered by :meth:`_on_dispatched`, between
+        the decoder's two spans) until the logits are on the host.
+        Returns what ``fn`` returns and the two ends."""
         t0, outer = self._clock.enter("dispatch")
         try:
-            logits = _DECODE_RETRY.call(fn, *args)
+            out = _DECODE_RETRY.call(fn, *args)
         finally:
             t1, _ = self._clock.enter(outer)
-        return logits, t0, t1
+        return out, t0, t1
 
     def _on_dispatched(self) -> None:
         """A decoder's jitted call has returned and its fetch begins. In
@@ -867,12 +904,22 @@ class ContinuousBatchingScheduler:
             self._clock.enter("fetch")
 
     def _fail_step(self, active, e: Exception) -> None:
-        """A step's dispatch failed: fail its requests, free their
-        blocks, and count the failure towards the breaker."""
+        """A step failed, at its dispatch or (a step run ahead) at the
+        fetch of its ids a dispatch later: fail its requests and those
+        of every step in flight, each once, free their blocks, and
+        count ONE failure towards the breaker. A row whose request has
+        already ended (its slot holds another, or nothing) has nothing
+        left to fail."""
         reg = metrics_registry()
         reg.counter("serving.errors").inc()
-        for i, req in active:
+        rows = dict(active)
+        for step in self._in_flight:
+            rows.update(step.rows)
+        self._in_flight.clear()
+        for i, req in rows.items():
             with self._mu:
+                if self._slots[i] is not req:
+                    continue
                 self._slots[i] = None
             self.decoder.pool.free(req.table)
             if not req.future.done():
@@ -890,21 +937,115 @@ class ContinuousBatchingScheduler:
             if opened:
                 reg.counter("serving.breaker_opens").inc()
 
-    def _decode_once(self) -> bool:
-        """One decode step for every live slot (one speculative round
-        with speculation on). True where the step was served."""
+    def _decode_once(self):
+        """One pass's decode work for the live slots. Returns
+        ``(stepped, ahead)``: whether a step (or speculative round) was
+        dispatched and served, and whether it was dispatched while the
+        step before it was still unread.
+
+        Where every live slot is greedy the pass **runs one step
+        ahead**: it builds step n+1's inputs, dispatches it through
+        :meth:`PagedDecoder.decode_ahead` (a slot that was in step n
+        takes its token from n's ids on the device; a slot admitted
+        since, from the host), and only then reads step n's ids,
+        commits them and retires, so the wait for the chip falls while
+        the chip already holds its next program. What n+1 needs without
+        n's tokens the host has: ``seq_len`` (advanced at dispatch),
+        the block table, and whether n's token is the request's last
+        (:meth:`_step_inputs` leaves the slot out). An ``eos_id`` hit it
+        learns one step late: the row that n+1 computed for the slot is
+        dropped when it arrives, and its write went to a row of the
+        request's own blocks, behind every later owner's writes in the
+        device's order and masked by ``seq_len`` until overwritten (the
+        argument :meth:`_spec_once` makes for rejected suffixes).
+
+        A sampled slot (``temperature > 0``) or speculation makes the
+        pass the synchronous one: read what is in flight, dispatch,
+        wait for the logits, :func:`sample_next_token` from the
+        request's own stream. The choice is read from the slots, pass
+        by pass; both ways run the one decode executable."""
         if self.spec_k > 0 and self.draft is not None:
-            return self._spec_once()
+            stepped = self._spec_once()
+            if stepped:
+                self._clock.count("steps_sync")
+            return stepped, False
+        with self._mu:
+            greedy = all(r is None or r.temperature == 0
+                         for r in self._slots)
+        if not greedy:
+            self._read_steps(keep=0)  # this step starts from their tokens
         inputs = self._step_inputs()
         if inputs is None:
-            return False
+            self._read_steps(keep=0)
+            return False, False
         active, tokens, tables, seq_lens = inputs
+        if not greedy:
+            return self._decode_sync(active, tokens, tables, seq_lens), False
+        take_prev = np.zeros(len(tokens), bool)
+        for i, req in active:
+            take_prev[i] = req.in_flight > 0  # hotpath: lock-ok (pass-local array)
+        try:
+            ids, t0, _ = self._dispatch(self.decoder.decode_ahead, tokens,
+                                        tables, seq_lens, take_prev)
+        except Exception as e:  # noqa: BLE001 — fail the steps' requests
+            self._fail_step(active, e)
+            return False, False
+        ahead = bool(self._in_flight)
+        with self._mu:
+            for _, req in active:
+                req.seq_len += 1
+                req.in_flight += 1
+        self._in_flight.append(_Step(active, ids, t0))
+        if ahead:
+            self._clock.count("steps_ahead")
+        self._read_steps(keep=1)
+        return True, ahead
+
+    def _read_steps(self, keep: int) -> None:
+        """Read the steps in flight, oldest first, until ``keep`` are
+        left: wait for a step's ids (``fetch``: 4 bytes a slot), commit
+        each row's token to its request and retire what it finishes
+        (``sample``). A row whose request has ended since the dispatch
+        (an ``eos_id`` hit, a deadline, a failure) is dropped."""
+        while len(self._in_flight) > keep:
+            step = self._in_flight[0]
+            try:
+                with self._phase("fetch", bytes=step.ids.nbytes) as ph:
+                    ids = np.asarray(step.ids)
+            except Exception as e:  # noqa: BLE001 — fail the steps' requests
+                self._fail_step((), e)
+                return
+            self._in_flight.popleft()
+            metrics_registry().histogram("serving.decode_step_s").observe(
+                ph.t1 - step.t0)
+            dropped = 0
+            with self._phase("sample", tokens=len(step.rows)):
+                for i, req in step.rows:
+                    with self._mu:
+                        live = self._slots[i] is req
+                        if live:
+                            req.in_flight -= 1
+                            req.decode_steps += 1
+                    if live:
+                        self._commit_token(req, int(ids[i]))
+                    else:
+                        dropped += 1
+                if self.breaker_threshold:
+                    with self._mu:  # a served step closes the failure streak
+                        self._consec_failures = 0
+            if dropped:
+                self._clock.count("rows_dropped", dropped)
+
+    def _decode_sync(self, active, tokens, tables, seq_lens) -> bool:
+        """The synchronous step: dispatch, wait for the logits, sample
+        each row on the host. True where the step was served."""
         try:
             logits, t0, t1 = self._dispatch(self.decoder.decode, tokens,
                                             tables, seq_lens)
         except Exception as e:  # noqa: BLE001 — fail the step's requests
             self._fail_step(active, e)
             return False
+        self._clock.count("steps_sync")
         metrics_registry().histogram("serving.decode_step_s").observe(
             t1 - t0)
         with self._phase("sample", tokens=len(active)):

@@ -461,9 +461,10 @@ def test_breaker_opens_on_consecutive_decode_failures(gpt, monkeypatch):
                                         breaker_threshold=2,
                                         breaker_cooldown_s=30.0,
                                         worker_retry_budget=0)
-    monkeypatch.setattr(sched.decoder, "decode",
-                        lambda *a, **k: (_ for _ in ()).throw(
-                            RuntimeError("wedged device")))
+    for way in ("decode", "decode_ahead"):  # the pass's two ways to step
+        monkeypatch.setattr(sched.decoder, way,
+                            lambda *a, **k: (_ for _ in ()).throw(
+                                RuntimeError("wedged device")))
     futs = [sched.submit(np.zeros(3, np.int32), 4) for _ in range(2)]
     for f in futs:
         with pytest.raises(RuntimeError, match="wedged"):
@@ -893,3 +894,238 @@ def test_loop_snapshots_stay_whole_under_concurrent_readers(gpt):
     sched.stop()
     assert bad == []
     assert sched.stats()["loop"]["steps"] == sched.stats()["decode_steps"]
+
+
+# ----------------------------------- the loop runs one step ahead (PR 29)
+_MIX = [(3, 6), (6, 2), (2, 9), (5, 1), (4, 7), (2, 3), (3, 5), (6, 4)]
+
+
+def _mix_reqs():
+    rng = np.random.default_rng(7)
+    return [(rng.integers(0, V, (n,)).astype(np.int32), m) for n, m in _MIX]
+
+
+def _serve_mix(gpt, temperature=0.0, rider=False):
+    """The 8-request ragged mix through a fresh scheduler of 4 slots;
+    ``rider``: one sampled request that outlives the mix rides along in
+    the first slot. Returns the mix's outputs and the last ``stats()``."""
+    sched = ContinuousBatchingScheduler(gpt, max_length=32,
+                                        decode_slots=4, block_size=8)
+    ride = (sched.submit(np.array([1, 2], np.int32), 30, temperature=0.8,
+                         seed=5) if rider else None)
+    futs = []
+    for i, (prompt, m) in enumerate(_mix_reqs()):
+        futs.append(sched.submit(prompt, m, temperature=temperature,
+                                 seed=1000 + i))
+        if i % 3 == 2:
+            time.sleep(0.002)  # ragged arrival
+    outs = [f.result(timeout=120) for f in futs]
+    if ride is not None:
+        assert ride.result(timeout=120).shape == (32,)
+    st = sched.stats()
+    sched.stop()
+    return outs, st
+
+
+def test_greedy_tokens_same_running_ahead_and_forced_through_sync(gpt):
+    """The greedy mix runs every step but a run's first ahead; one
+    sampled request riding along forces every step through the
+    synchronous pass. The mix's tokens are the same, and sequential
+    serving's."""
+    ahead, st_a = _serve_mix(gpt)
+    sync, st_s = _serve_mix(gpt, rider=True)
+    ref = _reference_rows(gpt, _mix_reqs(), 0.0)
+    for a, s, r in zip(ahead, sync, ref):
+        np.testing.assert_array_equal(a, r)
+        np.testing.assert_array_equal(s, r)
+    ran = st_a["loop"]["ahead"]
+    assert ran["steps_ahead"] > 0 and ran["steps_sync"] == 0, ran
+    assert ran["rows_dropped"] == 0  # no eos_id: nothing ends unforeseen
+    assert ran["steps_ahead"] <= st_a["decode_steps"] == st_a["loop"]["steps"]
+    forced = st_s["loop"]["ahead"]
+    assert forced["steps_ahead"] == 0, forced
+    assert forced["steps_sync"] == st_s["decode_steps"] > 0
+    assert st_a["decode_steps"] == st_a["decode_dispatches"]
+
+
+@pytest.mark.parametrize("mode", ["sampled", "spec"])
+def test_sampled_and_speculative_sessions_never_run_ahead(gpt, mode):
+    if mode == "sampled":
+        _, st = _serve_mix(gpt, temperature=0.8)
+    else:
+        sched = _loop_sched(gpt, "spec")
+        _run_three(sched)
+        st = sched.stats()
+        sched.stop()
+    ran = st["loop"]["ahead"]
+    assert ran["steps_ahead"] == 0 and ran["rows_dropped"] == 0, ran
+    assert ran["steps_sync"] == st["loop"]["steps"] == st["decode_steps"] > 0
+
+
+def test_eos_learnt_a_step_late_drops_one_row(gpt):
+    """An ``eos_id`` hit in mid-generation, another slot live: the step
+    after the hit was dispatched before the hit was read, so its row
+    for the ended request is dropped, never committed or counted. The
+    blocks the hit freed admit a waiting request, and every request's
+    tokens are sequential serving's."""
+    gen = Generator(gpt, max_length=32)
+    rng = np.random.default_rng(21)
+    for _ in range(50):
+        a = rng.integers(0, V, (4,)).astype(np.int32)
+        new = gen.generate(a[None, :], 8)[0][a.size:].tolist()
+        hits = [k for k in range(2, 6) if new[k] not in new[:k]]
+        if hits:
+            k = hits[0]
+            break
+    else:
+        pytest.fail("no prompt whose greedy run has a fresh token in 2..5")
+    b, c = (rng.integers(0, V, (4,)).astype(np.int32) for _ in range(2))
+    sched = ContinuousBatchingScheduler(
+        gpt, max_length=32, decode_slots=2, block_size=8,
+        num_blocks=5)  # two requests of 12 tokens at a time
+    fa = sched.submit(a, 8, eos_id=new[k])
+    fb = sched.submit(b, 8)
+    fc = sched.submit(c, 8)  # waits for the blocks the eos frees
+    outs = [f.result(timeout=120) for f in (fa, fb, fc)]
+    st = sched.stats()
+    sched.stop()
+    assert outs[0].tolist() == a.tolist() + new[:k + 1]
+    for out, ref in zip(outs[1:], _reference_rows(gpt, [(b, 8), (c, 8)],
+                                                  0.0)):
+        np.testing.assert_array_equal(out, ref)
+    ran = st["loop"]["ahead"]
+    assert ran["rows_dropped"] == 1 and ran["steps_sync"] == 0, ran
+    assert st["tokens"] == sum(len(o) - 4 for o in outs) == k + 1 + 16
+    assert st["decode_steps"] == st["decode_dispatches"] == st["loop"]["steps"]
+    assert sched.decoder.pool.in_use() == 0
+
+
+def test_session_after_the_benchmarks_warm_up_compiles_nothing(gpt):
+    """``benchmark/serving.py`` ``warm_up``, word for word: the prefill
+    of each bucket and ``decode(*idle)`` twice, until a round compiles
+    nothing. A session after it, running ahead, compiles nothing: the
+    loop's way into the decode program is the executable ``decode()``
+    compiled, and the loop does no device arithmetic of its own."""
+    from flexflow_tpu.utils.compile_cache import compile_stats
+
+    buckets = [8, 16]
+    sched = ContinuousBatchingScheduler(gpt, max_length=32, decode_slots=3,
+                                        block_size=8, prefill_buckets=buckets)
+    dec = sched.decoder
+    slots = dec.decode_slots
+    idle = (np.zeros(slots, np.int32),
+            np.zeros((slots, dec.max_blocks_per_request), np.int32),
+            np.zeros(slots, np.int32))
+    for round_ in range(4):
+        before = compile_stats()["compiles"]
+        for b in buckets + buckets[:1]:
+            table = dec.pool.try_admit(b + 1)
+            try:
+                dec.prefill(np.arange(b, dtype=np.int32) % V, table)
+            finally:
+                dec.pool.free(table)
+            dec.decode(*idle)
+            dec.decode(*idle)
+        if compile_stats()["compiles"] == before:
+            break
+    else:
+        pytest.fail("still compiling after four rounds of warm-up")
+    before = compile_stats()["compiles"]
+    rng = np.random.default_rng(9)
+    futs = [sched.submit(rng.integers(0, V, (n,)).astype(np.int32), m)
+            for n, m in [(5, 9), (12, 6), (8, 12), (3, 4), (16, 7)]]
+    for f in futs:
+        f.result(timeout=120)
+    st = sched.stats()
+    sched.stop()
+    assert compile_stats()["compiles"] == before
+    assert st["loop"]["ahead"]["steps_ahead"] > 0
+    assert st["loop"]["ahead"]["steps_sync"] == 0
+
+
+class _Unreadable:
+    """Ids whose fetch fails: a step that fails on the device."""
+
+    nbytes = 8
+
+    def __array__(self, *a, **k):
+        raise RuntimeError("wedged device")
+
+
+@pytest.mark.parametrize("fails_at", ["dispatch", "fetch"])
+def test_failure_with_a_step_in_flight_fails_both_steps_once(
+        gpt, monkeypatch, fails_at):
+    """The third step fails, at its dispatch or at the fetch of its ids
+    (a dispatch later, the fourth step by then in flight): the requests
+    of every step in flight fail, each once, every block comes back,
+    and the breaker counts one failure."""
+    sched = ContinuousBatchingScheduler(gpt, max_length=32, decode_slots=2,
+                                        block_size=8, breaker_threshold=5,
+                                        worker_retry_budget=0)
+    real = sched.decoder.decode_ahead
+    calls = []  # the steps in flight at each dispatch
+
+    def decode_ahead(*args):
+        calls.append(len(sched._in_flight))
+        if len(calls) == 3:
+            if fails_at == "dispatch":
+                raise RuntimeError("wedged device")
+            real(*args)
+            return _Unreadable()
+        return real(*args)
+
+    monkeypatch.setattr(sched.decoder, "decode_ahead", decode_ahead)
+    errors = metrics_registry().counter("serving.errors").value
+    futs = [sched.submit(np.arange(1, n + 1, dtype=np.int32), 10)
+            for n in (3, 4)]
+    for f in futs:
+        with pytest.raises(RuntimeError, match="wedged"):
+            f.result(timeout=120)
+    # the loop serves on: the failure took no block and no slot with it
+    out = sched.generate(np.arange(1, 4, dtype=np.int32), 5)
+    st = sched.stats()
+    with sched._mu:
+        streak = sched._consec_failures
+    sched.stop()
+    assert calls[2] == 1  # the failing step had one before it unread
+    assert metrics_registry().counter("serving.errors").value == errors + 1
+    assert streak == 0 and out.shape == (8,)  # a served step closed it
+    assert st["completed"] == 1 and st["tokens"] >= 5
+    assert sched.decoder.pool.in_use() == 0
+    assert not sched._in_flight
+
+
+def test_worker_crash_with_a_step_in_flight_loses_no_token(gpt, monkeypatch):
+    """The step in flight lives on the scheduler: the worker that the
+    ``serving.worker`` fault kills leaves it there, and the respawned
+    one reads it. Every token is sequential serving's and counted
+    once."""
+    plan = {"schema": 1, "sites": {"serving.worker":
+                                   {"at_step": 4, "max_fires": 1}}}
+    faults.configure_faults(FFConfig(fault_plan=plan))
+    sched = ContinuousBatchingScheduler(gpt, max_length=32, decode_slots=2,
+                                        block_size=8, worker_retry_budget=2)
+    at_crash = []
+    passes = sched._loop_passes
+
+    def loop_passes():
+        try:
+            passes()
+        except Exception:
+            at_crash.append(len(sched._in_flight))
+            raise
+
+    monkeypatch.setattr(sched, "_loop_passes", loop_passes)
+    rng = np.random.default_rng(17)
+    reqs = [(rng.integers(0, V, (n,)).astype(np.int32), m)
+            for n, m in [(3, 8), (4, 6), (2, 7)]]
+    futs = [sched.submit(p, m) for p, m in reqs]
+    outs = [f.result(timeout=120) for f in futs]
+    st = sched.stats()
+    sched.stop()
+    assert at_crash == [1]
+    for out, ref in zip(outs, _reference_rows(gpt, reqs, 0.0)):
+        np.testing.assert_array_equal(out, ref)
+    assert st["tokens"] == 8 + 6 + 7
+    assert st["loop"]["ahead"]["rows_dropped"] == 0
+    assert st["decode_steps"] == st["decode_dispatches"] == st["loop"]["steps"]
