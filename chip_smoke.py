@@ -457,7 +457,7 @@ def check_latent_attention(slots: int, heads: int, rank: int, rope: int,
 
     from flexflow_tpu.kernels.latent_attention import (
         latent_attention_decode, supported)
-    from flexflow_tpu.serving.kv_cache import latent_row_lanes
+    from flexflow_tpu.serving.cache_entry import latent_row_lanes
 
     row = latent_row_lanes(rank + rope)
     nb = slots * max_blocks + 1

@@ -22,7 +22,7 @@ applied afterwards). So keys and values are the same bytes, read once:
 * scores, running maximum and sum, and the accumulator are float32; the
   rows and the probabilities fed to the MXU are in the arena's dtype.
 
-The jnp path (``serving.generation._latent_attn_paged``'s gather) is the
+The jnp path (``serving.cache_entry.LatentEntry.step``'s gather) is the
 kernel's reference and takes every call :func:`supported` refuses.
 """
 

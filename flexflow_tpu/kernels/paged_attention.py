@@ -38,7 +38,7 @@ reserved but not yet written never reach a result. They must be finite
 before the first DMA: a block that is not live is not fetched, and what
 a buffer holds in its place is then old arena data or zeros.
 
-The jnp path (``serving.generation._entry_read`` and the einsums after
+The jnp path (``serving.cache_entry.PairEntry.read`` and the attend after
 it) is this kernel's reference and takes every entry :func:`supported`
 refuses: int8 arenas, widths Mosaic does not tile, and the CPU.
 """

@@ -115,17 +115,33 @@ class MultiHeadAttention(Op):
             ]
         return specs
 
-    def forward(self, ctx, inputs, weights):
-        q, k, v = inputs
-        # (B, S, E) x (E, H, D) -> (B, S, H, D)
-        qh = jnp.einsum("bse,ehd->bshd", q, weights["wq"])
-        kh = jnp.einsum("bse,ehd->bshd", k, weights["wk"])
-        vh = jnp.einsum("bse,ehd->bshd", v, weights["wv"])
+    # ---- the pieces serving composes (serving/cache_entry.py) -------------
+    @property
+    def scale(self) -> float:
+        return 1.0 / math.sqrt(self.head_dim)
+
+    def project_qkv(self, weights, q_in, k_in, v_in):
+        """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries, keys and
+        values, biases added."""
+        qh = jnp.einsum("bse,ehd->bshd", q_in, weights["wq"])
+        kh = jnp.einsum("bse,ehd->bshd", k_in, weights["wk"])
+        vh = jnp.einsum("bse,ehd->bshd", v_in, weights["wv"])
         if self.use_bias:
             qh = qh + weights["bq"]
             kh = kh + weights["bk"]
             vh = vh + weights["bv"]
-        scale = 1.0 / math.sqrt(self.head_dim)
+        return qh, kh, vh
+
+    def project_out(self, weights, ctxv):
+        """The attended (B, S, H, D) values -> (B, S, E)."""
+        out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
+        if self.use_bias:
+            out = out + weights["bo"]
+        return out
+
+    def forward(self, ctx, inputs, weights):
+        qh, kh, vh = self.project_qkv(weights, *inputs)
+        scale = self.scale
         drop = self.dropout if (ctx.training and ctx.rng is not None) else 0.0
         from ..parallel.ring_attention import ring_attention, single_device_attention
 
@@ -187,10 +203,7 @@ class MultiHeadAttention(Op):
         from ..obs.metrics import metrics_registry
 
         metrics_registry().counter(f"attention.path.{path}").inc()
-        out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
-        if self.use_bias:
-            out = out + weights["bo"]
-        return [out]
+        return [self.project_out(weights, ctxv)]
 
     def propagate(self, input_shapes, strategy):
         out_shapes, weight_shapes = super().propagate(input_shapes, strategy)
@@ -295,10 +308,10 @@ class LatentAttention(Op):
       q_rope . k_rope) * scale``, causal softmax, ``sum p v``, ``W_o``.
 
     What a cache has to keep of a token is the row ``[c | k_rope]``
-    (:meth:`latent_rows`), not keys and values: serving/generation.py
-    prefills in this expanded form and decodes in the absorbed one
-    (``W_kvb`` folded into the query and the output). Inputs: the
-    activations (B, S, E) and the graph's int32 positions (B, S).
+    (:meth:`queries_and_rows`), not keys and values:
+    serving/cache_entry.py prefills in this expanded form and decodes in
+    the absorbed one (``W_kvb`` folded into the query and the output).
+    Inputs: the activations (B, S, E) and the graph's int32 positions (B, S).
     Matrices keep 2-D shapes, heads side by side in the columns, so a
     loader can hand them over as stored."""
 

@@ -1,0 +1,418 @@
+"""The cache entry of an attention op: what a layer keeps for a token.
+
+One decision lives here and nowhere else: what one token's row is, how
+it is allocated and what it weighs, how a step of W new tokens and a
+prefill write it and read it, what its dense rectangle is, and what it
+cannot do. An :class:`EntryKind` answers, :data:`KINDS` names one per
+attention op type, and everything else asks: the pool (kv_cache.py)
+allocates what a kind describes and counts its bytes, the programs
+(generation.py) hand every attention op to its kind, the scheduler reads
+its limits. A new kind is a class and a line in :data:`KINDS`; those
+three modules do not change.
+
+An entry is the tuple of arrays its kind allocates, donated through the
+programs; the kind is static Python beside it. Every reader masks by
+position: a query at absolute position ``p`` sees keys at positions
+``<= p``, everything else (a window's own future rows, stale rows after
+a speculative roll-back, the null block's garbage) is set to -1e30
+before the softmax, where ``exp`` underflows to exactly 0.0, so the
+paged forms compute the dense rectangle's sums.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ffconst import OpType
+from ..kernels import latent_attention, paged_attention
+from .kv_cache import NULL_BLOCK
+
+
+def _iota(n):
+    return jax.lax.iota(jnp.int32, n)
+
+
+def _scores(q, k, scale):
+    """(B, Sq, H, D) queries against (B, Sk, H, D) keys -> (B, H, Sq, Sk)."""
+    return jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+
+
+def _weigh(scores, mask, v):
+    """The masked softmax of ``scores`` over the (B, Sk, H, D) values;
+    ``mask`` broadcasts against (B, H, Sq, Sk), True where a query sees a
+    key. With :func:`_scores`, the one copy of the attend every (k, v)
+    form shares."""
+    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _put(arena, flat, rows):
+    """Scatter ``rows`` (T, width) into an arena at flat token slots
+    ``flat`` (T,). A token is one row of the arena's ``(num_blocks *
+    block_size, width)`` view, a reshape that moves nothing under the
+    TPU's tiling, so the donated buffer is updated in place."""
+    nb, bs = arena.shape[:2]
+    flat_arena = arena.reshape((nb * bs,) + arena.shape[2:])
+    return flat_arena.at[flat].set(rows.astype(arena.dtype)).reshape(
+        arena.shape)
+
+
+def _prefill_slots(tables, lengths, pos, bs):
+    """Where a group of prompts' rows go: row i's position p lands in
+    block ``tables[i, p // bs]`` at offset ``p % bs``; padding positions
+    (``p >= lengths[i]``) go to the null block. (P, Sb) flat slots."""
+    blk = tables[:, pos // bs]
+    return jnp.where(pos[None, :] < lengths[:, None],
+                     blk * bs + (pos % bs)[None, :], NULL_BLOCK * bs)
+
+
+def _quant_rows(x):
+    """Asymmetric int8 per-(token, head) quantization over head_dim.
+    ``x``: (T, H, D) -> (q int8, scale f32 (T, H), zero f32 (T, H)).
+    Zero-point at the range midpoint, scale spanning [-127, 127], so
+    dequantization is ``q * scale + zero``."""
+    x = x.astype(jnp.float32)
+    hi = x.max(-1)
+    lo = x.min(-1)
+    zero = 0.5 * (hi + lo)
+    scale = jnp.maximum((hi - lo) / 254.0, 1e-8)
+    q = jnp.clip(jnp.round((x - zero[..., None]) / scale[..., None]),
+                 -127, 127)
+    return q.astype(jnp.int8), scale, zero
+
+
+def latent_row_lanes(width: int) -> int:
+    """A latent row's width in the arena: whole 128-lane tiles."""
+    return -(-int(width) // 128) * 128
+
+
+class EntryKind:
+    """What one attention op keeps for one token, and everything that
+    depends on it. ``op`` is the attention op, ``weights`` its
+    parameters, ``x`` (B, S, E) its input, ``positions`` (B, S) the
+    graph's; an ``entry`` is the tuple of arenas :meth:`arenas`
+    describes, ``tables`` (B, max_blocks) the slots' block tables. A
+    kind defines:
+
+    * ``for_op(op, positions_id, max_length)`` (a classmethod): the kind
+      of ``op`` in a graph whose positions input has that tensor id,
+      decoded up to ``max_length``; raises what the op cannot serve;
+    * ``arenas(num_blocks, block_size, dtype)``: one op's arenas, a
+      ``jax.ShapeDtypeStruct`` each;
+    * ``write(entry, flat, *rows)``: (T, ...) rows into flat token slots
+      (T,); returns the entry;
+    * ``reads_in_place(op, entry, slots, window, max_blocks)``: whether a
+      ``window``-token step reads ``entry`` by a kernel, in place;
+    * ``step(op, weights, x, positions, entry, tables, seq_lens)``: W new
+      tokens a slot at positions ``seq_lens .. seq_lens + W - 1``, their
+      rows written through the tables (an idle slot's, and positions past
+      a table's span, into the null block), then each slot's cache
+      attended through its table; returns (out, entry);
+    * ``whole(op, weights, x, positions)``: dense causal attention of
+      whole sequences over their own rows, cache-free (prefill's half,
+      and what the KV calibration gate compares the paged programs
+      with); returns (out, the rows ``write`` takes, the (S,) positions);
+    * ``dense_shapes(batch, max_length)``, the dense rectangle's arrays,
+      and ``dense_step(op, weights, x, positions, cache, offset)``: a
+      block of tokens written at ``offset`` and attended over the whole
+      static length; returns (out, cache)."""
+
+    name = ""                          # what stats()["kv"]["entry"] says
+    max_window: Optional[int] = None   # new tokens a slot a step; None: any
+    int8_form: Optional["EntryKind"] = None
+
+    def stats(self) -> Dict:
+        return {"entry": self.name}
+
+    def token_bytes(self, dtype) -> int:
+        """Bytes one token takes in one op's arenas stored as ``dtype``:
+        plain arithmetic on :meth:`arenas`, nothing is allocated."""
+        return sum(math.prod(a.shape) * a.dtype.itemsize
+                   for a in self.arenas(1, 1, dtype))
+
+    def prefill(self, op, weights, x, positions, entry, tables, lengths):
+        """A group of prompts padded to one bucket, of true ``lengths``:
+        :meth:`whole`, and the rows scattered through each prompt's table
+        (padding into the null block). Returns (out, entry)."""
+        out, rows, pos = self.whole(op, weights, x, positions)
+        flat = _prefill_slots(tables, lengths, pos, entry[0].shape[1])
+        return out, self.write(entry, flat.reshape(-1), *(
+            r.reshape((-1,) + r.shape[2:]) for r in rows))
+
+
+@dataclasses.dataclass(frozen=True)
+class PairEntry(EntryKind):
+    """Keys and values, ``heads * head_dim`` numbers each a token."""
+
+    heads: int
+    head_dim: int
+    name = "pair"
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        if len({t.tensor_id for t in op.layer.inputs}) != 1 or not op.causal:
+            raise ValueError(
+                f"{op.name}: generation needs causal SELF-attention")
+        return cls(op.num_heads, op.head_dim)
+
+    @property
+    def int8_form(self):
+        return Int8PairEntry(self.heads, self.head_dim)
+
+    def arenas(self, num_blocks, block_size, dtype):
+        a = jax.ShapeDtypeStruct(
+            (num_blocks, block_size, self.heads * self.head_dim), dtype)
+        return (a, a)
+
+    def write(self, entry, flat, kh, vh):
+        """T new (T, H, D) keys and values at flat token slots (T,)."""
+        t = kh.shape[0]
+        k, v = entry
+        return (_put(k, flat, kh.reshape(t, -1)),
+                _put(v, flat, vh.reshape(t, -1)))
+
+    def read(self, entry, tables):
+        """Each slot's logical (max_blocks * block_size, H, D) keys and
+        values, gathered through its table: what the kernel is checked
+        against, and what runs where it does not."""
+        return tuple(self._view(a, tables) for a in entry)
+
+    def _view(self, arena, tables):
+        return arena[tables].reshape(tables.shape[0], -1, self.heads,
+                                     arena.shape[-1] // self.heads)
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return paged_attention.supported(
+            (slots, window, self.heads, self.head_dim), entry[0].shape,
+            entry[0].dtype, max_blocks)
+
+    def step(self, op, weights, x, positions, entry, tables, seq_lens):
+        qh, kh, vh = op.project_qkv(weights, x, x, x)
+        bs = entry[0].shape[1]
+        n, w, heads, hdim = qh.shape
+        mb = tables.shape[1]
+        pos = seq_lens[:, None] + _iota(w)[None, :]                # (n, W)
+        blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, mb - 1),
+                                  axis=1)
+        # past the table's span (a verify window overrunning a request's
+        # worst case) is the null block, never a clamped real block
+        flat = jnp.where(pos < mb * bs, blk * bs + pos % bs,
+                         NULL_BLOCK * bs)
+        entry = self.write(entry, flat.reshape(-1),
+                           kh.reshape(n * w, heads, hdim),
+                           vh.reshape(n * w, heads, hdim))
+        if self.reads_in_place(op, entry, n, w, mb):
+            # the kernel walks each slot's live blocks in the arena itself
+            ctxv = paged_attention.paged_attention_decode(
+                qh, entry[0], entry[1], tables, seq_lens,
+                scale=op.scale).astype(qh.dtype)
+        else:
+            k, v = self.read(entry, tables)                 # (n, L, H, D)
+            scores = _scores(qh, k, op.scale)
+            mask = _iota(k.shape[1])[None, None, :] <= pos[:, :, None]
+            ctxv = _weigh(scores, mask[:, None, :, :], v)
+        return op.project_out(weights, ctxv), entry
+
+    def whole(self, op, weights, x, positions):
+        qh, kh, vh = op.project_qkv(weights, x, x, x)
+        scores = _scores(qh, kh, op.scale)
+        pos = _iota(x.shape[1])
+        mask = pos[None, :] <= pos[:, None]
+        ctxv = _weigh(scores, mask[None, None, :, :], vh)
+        return op.project_out(weights, ctxv), (kh, vh), pos
+
+    def dense_shapes(self, batch, max_length):
+        shape = (batch, max_length, self.heads, self.head_dim)
+        return (shape, shape)
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        qh, kh, vh = op.project_qkv(weights, x, x, x)
+        kcache, vcache = cache
+        # dynamic_update_slice keeps the shape static; unwritten and
+        # future positions are masked by position comparison
+        kcache = jax.lax.dynamic_update_slice(kcache, kh, (0, offset, 0, 0))
+        vcache = jax.lax.dynamic_update_slice(vcache, vh, (0, offset, 0, 0))
+        scores = _scores(qh, kcache, op.scale)
+        qpos = offset + _iota(x.shape[1])
+        mask = _iota(kcache.shape[1])[None, :] <= qpos[:, None]
+        ctxv = _weigh(scores, mask[None, None, :, :], vcache)
+        return op.project_out(weights, ctxv), (kcache, vcache)
+
+
+@dataclasses.dataclass(frozen=True)
+class Int8PairEntry(PairEntry):
+    """The pair quantized: ``(k_q, v_q, k_scale, k_zero, v_scale,
+    v_zero)``. ``head_dim + 8`` bytes a head a token against float32's
+    ``4 * head_dim``, so a byte budget admits twice the requests or more;
+    only the gathered working set pays the float32 width (the kernel's
+    ``supported()`` takes no int8 arena, so a step always gathers)."""
+
+    name = "int8"
+    int8_form = None
+
+    def arenas(self, num_blocks, block_size, dtype):
+        a = jax.ShapeDtypeStruct(
+            (num_blocks, block_size, self.heads * self.head_dim), jnp.int8)
+        s = jax.ShapeDtypeStruct((num_blocks, block_size, self.heads),
+                                 jnp.float32)
+        return (a, a, s, s, s, s)
+
+    def write(self, entry, flat, kh, vh):
+        t = kh.shape[0]
+        kq, vq, ks, kz, vs, vz = entry
+        qk, sk, zk = _quant_rows(kh)
+        qv, sv, zv = _quant_rows(vh)
+        return (_put(kq, flat, qk.reshape(t, -1)),
+                _put(vq, flat, qv.reshape(t, -1)),
+                _put(ks, flat, sk), _put(kz, flat, zk),
+                _put(vs, flat, sv), _put(vz, flat, zv))
+
+    def read(self, entry, tables):
+        kq, vq, ks, kz, vs, vz = entry
+        view = lambda a: self._view(a, tables)  # noqa: E731
+        return (view(kq).astype(jnp.float32) * view(ks) + view(kz),
+                view(vq).astype(jnp.float32) * view(vs) + view(vz))
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentEntry(EntryKind):
+    """A latent-attention op's one row a token: its normalized latent and
+    its rotary key, which keys and values are both read from."""
+
+    row_width: int
+    name = "latent"
+    max_window = 1
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        # its second input is the graph's positions (rotary, inside the
+        # op), not a learned table's
+        if op.layer.inputs[1].tensor_id != positions_id:
+            raise ValueError(f"{op.name}: latent attention has to take the "
+                             f"graph's positions input")
+        if max_length > op.max_positions:
+            raise ValueError(
+                f"max_length {max_length} exceeds the positions {op.name} "
+                f"was built for ({op.max_positions})")
+        return cls(op.row_width)
+
+    def arenas(self, num_blocks, block_size, dtype):
+        return (jax.ShapeDtypeStruct(
+            (num_blocks, block_size, latent_row_lanes(self.row_width)),
+            dtype),)
+
+    def stats(self):
+        return {"entry": self.name, "row_width": self.row_width,
+                "row_lanes": latent_row_lanes(self.row_width)}
+
+    def write(self, entry, flat, rows):
+        """(T, width) rows, padded with zeros to the arena's lanes."""
+        lanes = entry[0].shape[-1]
+        return (_put(entry[0], flat,
+                     jnp.pad(rows, ((0, 0), (0, lanes - rows.shape[-1])))),)
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        arena = entry[0]
+        return window == 1 and latent_attention.supported(
+            (slots, op.num_heads, arena.shape[-1]), arena.shape,
+            arena.dtype, max_blocks, op.kv_rank)
+
+    def step(self, op, weights, x, positions, entry, tables, seq_lens):
+        """The absorbed form: per head the query over a row's lanes is
+        ``q_nope`` folded through the key half of ``W_kvb`` beside
+        ``q_rope``, and the weighted sum of the rows' latent part is
+        unfolded through the value half."""
+        n, w, _ = x.shape                # w is 1: ``max_window``
+        arena = entry[0]
+        bs, lanes = arena.shape[1], arena.shape[2]
+        mb = tables.shape[1]
+        q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(seq_lens[:, None] // bs, 0, mb - 1), axis=1)[:, 0]
+        flat = jnp.where(seq_lens < mb * bs, blk * bs + seq_lens % bs,
+                         NULL_BLOCK * bs)
+        entry = self.write(entry, flat, rows[:, 0])
+        arena = entry[0]
+        wkvb = op.kvb_heads(weights)                  # (rank, H, nope + v)
+        q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0],
+                           wkvb[..., :op.nope_dim],
+                           preferred_element_type=jnp.float32)
+        q_full = jnp.concatenate(
+            [q_lat.astype(arena.dtype), q_rope[:, 0].astype(arena.dtype),
+             jnp.zeros((n, op.num_heads, lanes - op.row_width), arena.dtype)],
+            axis=-1)                                  # (n, H, lanes)
+        if self.reads_in_place(op, entry, n, w, mb):
+            with jax.named_scope("latent_attention_decode"):
+                ctxv = latent_attention.latent_attention_decode(
+                    q_full, arena, tables, seq_lens, scale=op.scale,
+                    out_width=op.kv_rank)
+        else:
+            view = arena[tables].reshape(n, mb * bs, lanes)  # (n, L, lanes)
+            scores = jnp.einsum("nhr,nlr->nhl", q_full, view,
+                                preferred_element_type=jnp.float32) * op.scale
+            mask = _iota(mb * bs)[None, :] <= seq_lens[:, None]
+            probs = jax.nn.softmax(
+                jnp.where(mask[:, None, :], scores, -1e30), axis=-1)
+            ctxv = jnp.einsum("nhl,nlc->nhc", probs.astype(arena.dtype),
+                              view[..., :op.kv_rank],
+                              preferred_element_type=jnp.float32)
+        o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
+                       wkvb[..., op.nope_dim:],
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+        out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim), weights["wo"],
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+        return out, entry
+
+    def whole(self, op, weights, x, positions):
+        """The expanded form: keys and values up-projected from the
+        sequences' own rows."""
+        q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
+        pos = _iota(x.shape[1])
+        with jax.named_scope("latent_attention_prefill"):
+            out = op.attend_expanded(weights, q_nope, q_rope, rows,
+                                     pos[None, :] <= pos[:, None])
+        return out, (rows,), pos
+
+    def dense_shapes(self, batch, max_length):
+        return ((batch, max_length, self.row_width),)
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
+        rows_cache = jax.lax.dynamic_update_slice(
+            cache[0], rows.astype(cache[0].dtype), (0, offset, 0))
+        qpos = offset + _iota(x.shape[1])
+        kpos = _iota(rows_cache.shape[1])
+        out = op.attend_expanded(weights, q_nope, q_rope,
+                                 rows_cache.astype(x.dtype),
+                                 kpos[None, :] <= qpos[:, None])
+        return out, (rows_cache,)
+
+
+# the kind of each attention op type: ``for_op`` as :meth:`EntryKind.for_op`
+KINDS: Dict[OpType, Callable[..., EntryKind]] = {
+    OpType.MULTIHEAD_ATTENTION: PairEntry.for_op,
+    OpType.LATENT_ATTENTION: LatentEntry.for_op,
+}
+
+
+def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
+    """The entry kind of ``op``, or None for an op that keeps nothing; an
+    attention op type with no kind raises, naming the op, rather than
+    run its ``forward`` over one step's tokens alone."""
+    make = KINDS.get(op.op_type)
+    if make is not None:
+        return make(op, positions_id, max_length)
+    if "ATTENTION" in op.op_type.name:
+        raise ValueError(
+            f"{op.name}: no cache entry kind is registered for "
+            f"{op.op_type.name} (serving/cache_entry.py KINDS)")
+    return None
+
+
+__all__ = ["EntryKind", "Int8PairEntry", "KINDS", "LatentEntry", "PairEntry",
+           "kind_for", "latent_row_lanes"]

@@ -4,51 +4,33 @@ No reference analog (the reference predates LLM serving; its triton/
 prototype served batch CNN inference) — this is the modern-completeness
 piece on top of the serving engine. TPU-native design:
 
-* the decode step is ONE jitted function per block length (prefill length
-  and 1), produced by walking the compiled model's op graph — every op
-  runs its ordinary shape-polymorphic ``forward`` on the (B, S_blk, ·)
-  activations EXCEPT self-attention, which reads/writes a static-shape
-  KV cache via ``lax.dynamic_update_slice`` (XLA-friendly: no growing
-  shapes, position masking instead of shape change);
-* the cache is a pytree {attention op name: (k, v)} of
-  (B, max_length, H, D) arrays, donated through the decode step so XLA
-  updates it in place;
-* sampling (greedy / temperature) happens on host between steps, like
-  every production TPU decode loop.
+* each program is ONE jitted function produced by walking the compiled
+  model's op graph — every op runs its ordinary shape-polymorphic
+  ``forward`` on the (B, S_blk, ·) activations EXCEPT the attention ops,
+  which are handed to their entry kind
+  (:mod:`~flexflow_tpu.serving.cache_entry`: what an op keeps for a
+  token, and how each program here writes and reads it, is the kind's;
+  this module knows no more than that every such op has one);
+* the cache is a pytree {attention op name: entry} of static shape,
+  donated through the program so XLA updates it in place;
+* sampling (greedy / temperature) happens on host between steps, except
+  the greedy token, which the paged decode program picks itself.
 
 Two cache layouts share the graph walk:
 
-* :class:`Generator` — the dense rectangle: ``(B, max_length, H, D)``
-  per op, one fixed batch decoded in lockstep (offline/batch use, and
-  the bit-compared reference for the paged path);
+* :class:`Generator` — the dense rectangle: ``(B, max_length, ·)`` per
+  op, one fixed batch decoded in lockstep (offline/batch use, and the
+  reference the paged path is held to: tests/test_continuous_batching.py,
+  to float32 reordering per zoo causal-LM model);
 * :class:`PagedDecoder` — the continuous-batching layout: a
-  :class:`~flexflow_tpu.serving.kv_cache.PagedKVPool` of
-  ``(num_blocks, block_size, H*D)`` arenas plus per-request block
-  tables. Decode attention reads K/V **through the block table**: in
-  place, by the paged-attention kernel (kernels/paged_attention.py),
-  where its ``supported()`` admits the entry, and otherwise by a gather
-  of each slot's table (the jnp path: int8 entries, widths Mosaic
-  refuses, the CPU). The compiled decode program's shape depends only
-  on (decode slots, pool geometry), so one program serves every
+  :class:`~flexflow_tpu.serving.kv_cache.PagedKVPool` of arenas plus
+  per-request block tables. The compiled decode program's shape depends
+  only on (decode slots, pool geometry), so one program serves every
   in-flight request mix, and prompts run through a separate **bucketed
   prefill executable** (pad-to-bucket ladder, per-bucket compile cached
-  and counted) whose K/V is scattered into the pool in the same
+  and counted) whose rows are scattered into the pool in the same
   dispatch.
 
-The two layouts compute the same sums per request
-(tests/test_continuous_batching.py holds them to float32 reordering
-per zoo causal-LM model): the paged read reconstructs exactly the dense
-cache rows for written positions, and every unwritten/foreign lane is
-masked to -1e30 before softmax, where ``exp`` underflows to exactly
-0.0 — adding exact zeros never perturbs the valid lanes' accumulation.
-
-Works for any builder graph whose attention ops are causal
-self-attention (models/gpt.py; an imported HF decoder fits the same
-contract) or latent attention (models/latent_moe.py): a latent op's
-cache is ONE row a token, ``[c | k_rope]`` — a 1-tuple entry in either
-layout — which prefill attends in the expanded form and the paged decode
-step in the absorbed one (:func:`_latent_attn_paged`; in place by
-kernels/latent_attention.py where its ``supported()`` admits the entry).
 Routed-experts ops run inside the same programs; the paged ones keep the
 expert ids they chose (``PagedDecoder.last_routing``) and count their
 load on the device (``PagedDecoder.expert_stats``).
@@ -69,290 +51,9 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ffconst import OpType
 from ..core.op import LowerCtx
-from ..kernels import latent_attention, paged_attention
 from ..obs.trace import span
+from .cache_entry import kind_for
 from .kv_cache import NULL_BLOCK, PagedKVPool
-
-
-def _attn_with_cache(op, weights, x, kcache, vcache, offset):
-    """Causal self-attention over [cache ∪ current block].
-
-    ``offset``: traced scalar — absolute position of the block's first
-    token. Scores span the FULL static cache length; future/unwritten
-    positions are masked by position comparison (static shapes, jit-safe).
-    """
-    qh = jnp.einsum("bse,ehd->bshd", x, weights["wq"])
-    kh = jnp.einsum("bse,ehd->bshd", x, weights["wk"])
-    vh = jnp.einsum("bse,ehd->bshd", x, weights["wv"])
-    if op.use_bias:
-        qh = qh + weights["bq"]
-        kh = kh + weights["bk"]
-        vh = vh + weights["bv"]
-    kcache = jax.lax.dynamic_update_slice(kcache, kh, (0, offset, 0, 0))
-    vcache = jax.lax.dynamic_update_slice(vcache, vh, (0, offset, 0, 0))
-    scale = 1.0 / math.sqrt(op.head_dim)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", qh, kcache) * scale
-    s_blk = x.shape[1]
-    qpos = offset + jax.lax.iota(jnp.int32, s_blk)             # (S_blk,)
-    kpos = jax.lax.iota(jnp.int32, kcache.shape[1])            # (max_len,)
-    mask = kpos[None, :] <= qpos[:, None]                      # causal+written
-    scores = jnp.where(mask[None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs, vcache)
-    out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
-    if op.use_bias:
-        out = out + weights["bo"]
-    return out, kcache, vcache
-
-
-def _quant_rows(x):
-    """Asymmetric int8 per-(token, head) quantization over head_dim.
-    ``x``: (T, H, D) -> (q int8, scale f32 (T, H), zero f32 (T, H)).
-    Zero-point at the range midpoint, scale spanning [-127, 127], so
-    dequantization is ``q * scale + zero``."""
-    x = x.astype(jnp.float32)
-    hi = x.max(-1)
-    lo = x.min(-1)
-    zero = 0.5 * (hi + lo)
-    scale = jnp.maximum((hi - lo) / 254.0, 1e-8)
-    q = jnp.clip(jnp.round((x - zero[..., None]) / scale[..., None]),
-                 -127, 127)
-    return q.astype(jnp.int8), scale, zero
-
-
-def _entry_write(entry, flat, kh, vh):
-    """Scatter T new K/V rows (``kh``/``vh``: (T, H, D)) into a pool
-    arena entry at flat token slots ``flat`` (T,), quantizing when the
-    entry is an int8 6-tuple (values + scale/zero sidecars share the
-    same flat addressing). An arena is ``(num_blocks, block_size,
-    H*D)``, so a token is one row of its ``(num_blocks*block_size,
-    H*D)`` view — a reshape that moves nothing under the TPU's tiling —
-    and the scatter updates the donated buffer in place. Returns the
-    updated entry."""
-    t = kh.shape[0]
-
-    def put(arena, rows):
-        nb, bs = arena.shape[:2]
-        flat_arena = arena.reshape((nb * bs,) + arena.shape[2:])
-        return flat_arena.at[flat].set(
-            rows.astype(arena.dtype)).reshape(arena.shape)
-
-    if len(entry) == 1:
-        # a latent entry: ``kh`` is the (T, width) rows, padded with
-        # zeros to the arena's whole lane tiles; ``vh`` is unused
-        lanes = entry[0].shape[-1]
-        return (put(entry[0], jnp.pad(kh, ((0, 0),
-                                           (0, lanes - kh.shape[-1])))),)
-    if len(entry) == 2:
-        k, v = entry
-        return (put(k, kh.reshape(t, -1)), put(v, vh.reshape(t, -1)))
-    kq, vq, ks, kz, vs, vz = entry
-    qk, sk, zk = _quant_rows(kh)
-    qv, sv, zv = _quant_rows(vh)
-    return (put(kq, qk.reshape(t, -1)), put(vq, qv.reshape(t, -1)),
-            put(ks, sk), put(kz, zk), put(vs, sv), put(vz, zv))
-
-
-def _entry_read(entry, tables, heads):
-    """Gather each slot's logical (max_blocks*block_size, H, D) K/V
-    view through its block table, dequantizing int8 entries to f32
-    INSIDE the dispatch (the arena stays quantized; only the gathered
-    working set pays the f32 width). The jnp path: what the paged-
-    attention kernel is checked against, and what runs where the kernel
-    does not (int8 entries, widths Mosaic refuses, the CPU)."""
-    n = tables.shape[0]
-
-    def view(arena):
-        return arena[tables].reshape(n, -1, heads, arena.shape[-1] // heads)
-
-    if len(entry) == 2:
-        k, v = entry
-        return view(k), view(v)
-    kq, vq, ks, kz, vs, vz = entry
-    k = (view(kq).astype(jnp.float32) * view(ks) + view(kz))
-    v = (view(vq).astype(jnp.float32) * view(vs) + view(vz))
-    return k, v
-
-
-def _kernel_reads(entry, q_shape, max_blocks) -> bool:
-    """Whether the paged-attention kernel reads this entry in place for
-    a (slots, W, H, D) query: a ``(k, v)`` pair of a shape and dtype
-    its ``supported()`` admits, on a backend where Pallas kernels run."""
-    return len(entry) == 2 and paged_attention.supported(
-        q_shape, entry[0].shape, entry[0].dtype, max_blocks)
-
-
-def _attn_with_paged_cache(op, weights, x, entry, tables, seq_lens):
-    """W-token causal self-attention through a paged KV pool.
-
-    ``x``: (n, W, E) — W new tokens per decode slot at absolute
-    positions ``seq_lens .. seq_lens + W - 1`` (W=1 is the plain decode
-    step; W=k+1 is the speculative verify window). ``entry``: the pool
-    arena entry for this op — (k, v) arenas, or the int8 6-tuple with
-    scale/zero sidecars. ``tables``: (n, max_blocks) int32 per-slot
-    block tables. ``seq_lens``: (n,) int32 — tokens already cached per
-    slot, i.e. the window's first absolute position.
-
-    Writes the W new K/V rows at each slot's positions (inactive slots,
-    whose tables are all :data:`~flexflow_tpu.serving.kv_cache
-    .NULL_BLOCK`, write into the null block — harmless by construction;
-    positions past the table's span are redirected there too), then
-    reads each slot's cache through its table — the kernel walks the
-    slot's live blocks in the arena, the jnp path gathers its logical
-    ``(max_blocks*block_size)`` view — and masks per query position
-    exactly like the dense path, so window position j's output is the
-    dense cache decode at absolute position ``seq_lens + j``: the
-    window's own future K/V rows, stale rows after a speculative
-    roll-back and the null block's garbage are masked to -1e30, where
-    exp underflows to exact 0.0. Which reader runs is decided on what
-    the trace can see (the entry's structure and dtype, W, the head and
-    block sizes, the backend: :func:`_kernel_reads`).
-    """
-    qh = jnp.einsum("bse,ehd->bshd", x, weights["wq"])
-    kh = jnp.einsum("bse,ehd->bshd", x, weights["wk"])
-    vh = jnp.einsum("bse,ehd->bshd", x, weights["wv"])
-    if op.use_bias:
-        qh = qh + weights["bq"]
-        kh = kh + weights["bk"]
-        vh = vh + weights["bv"]
-    bs = entry[0].shape[1]
-    n, w, heads, hdim = qh.shape
-    mb = tables.shape[1]
-    pos = seq_lens[:, None] + jax.lax.iota(jnp.int32, w)[None, :]  # (n, W)
-    blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, mb - 1),
-                              axis=1)                           # (n, W)
-    # positions past the table span (a verify window overrunning a
-    # request's worst case) land in the null block, never a clamped
-    # real block — by then the request has retired, so the rows are
-    # write-only garbage like every other masked lane
-    flat = jnp.where(pos < mb * bs, blk * bs + pos % bs,
-                     NULL_BLOCK * bs)                           # (n, W)
-    entry = _entry_write(entry, flat.reshape(-1),
-                         kh.reshape(n * w, heads, hdim),
-                         vh.reshape(n * w, heads, hdim))
-    scale = 1.0 / math.sqrt(op.head_dim)
-    if _kernel_reads(entry, qh.shape, mb):
-        # the kernel walks each slot's live blocks in the arena itself
-        ctxv = paged_attention.paged_attention_decode(
-            qh, entry[0], entry[1], tables, seq_lens,
-            scale=scale).astype(qh.dtype)
-    else:
-        # gather each slot's logical view: (n, MB, BS, HD) -> (n, L, H, D)
-        k, v = _entry_read(entry, tables, heads)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", qh, k) * scale   # (n,H,W,L)
-        kpos = jax.lax.iota(jnp.int32, k.shape[1])              # (L,)
-        mask = kpos[None, None, :] <= pos[:, :, None]           # (n, W, L)
-        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
-    if op.use_bias:
-        out = out + weights["bo"]
-    return out, entry
-
-
-def _latent_kernel_reads(op, entry, slots: int, max_blocks: int) -> bool:
-    """Whether the latent-attention kernel reads this 1-tuple entry in
-    place for a one-token step of ``slots`` slots."""
-    arena = entry[0]
-    return latent_attention.supported(
-        (slots, op.num_heads, arena.shape[-1]), arena.shape, arena.dtype,
-        max_blocks, op.kv_rank)
-
-
-def _latent_attn_with_cache(op, weights, x, positions, rows_cache, offset):
-    """The dense-rectangle form of latent attention: the block's rows
-    written at ``offset`` into a (B, max_length, width) cache, attention
-    in the expanded form over the whole static length, masked by
-    position (the sibling of :func:`_attn_with_cache`)."""
-    q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
-    rows_cache = jax.lax.dynamic_update_slice(
-        rows_cache, rows.astype(rows_cache.dtype), (0, offset, 0))
-    qpos = offset + jax.lax.iota(jnp.int32, x.shape[1])
-    kpos = jax.lax.iota(jnp.int32, rows_cache.shape[1])
-    out = op.attend_expanded(weights, q_nope, q_rope,
-                             rows_cache.astype(x.dtype),
-                             kpos[None, :] <= qpos[:, None])
-    return out, rows_cache
-
-
-def _latent_attn_paged(op, weights, x, positions, entry, tables, seq_lens):
-    """One new token a slot through a paged latent cache, in the
-    absorbed form: ``x`` (n, 1, E) at positions ``seq_lens``. Writes the
-    token's row ``[c | k_rope]`` at the slot's position (inactive slots
-    into the null block), then attends the slot's cached rows through
-    its table: per head the query over a row's lanes is ``q_nope`` folded
-    through the key half of ``W_kvb`` beside ``q_rope``, the weighted sum
-    of the rows' latent part is unfolded through the value half. The
-    kernel reads the arena in place over live blocks only; the jnp path
-    gathers each slot's logical view (its reference, and what runs where
-    its ``supported()`` says no). Masked lanes are exact zeros, as in
-    :func:`_attn_with_paged_cache`."""
-    n, w, _ = x.shape
-    if w != 1:
-        raise ValueError(
-            f"{op.name}: a latent cache entry takes one new token a slot "
-            f"(speculative verify windows are not built for it), got {w}")
-    arena = entry[0]
-    bs, lanes = arena.shape[1], arena.shape[2]
-    mb = tables.shape[1]
-    q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
-    blk = jnp.take_along_axis(
-        tables, jnp.clip(seq_lens[:, None] // bs, 0, mb - 1), axis=1)[:, 0]
-    flat = jnp.where(seq_lens < mb * bs, blk * bs + seq_lens % bs,
-                     NULL_BLOCK * bs)
-    entry = _entry_write(entry, flat, rows[:, 0], None)
-    arena = entry[0]
-    wkvb = op.kvb_heads(weights)                      # (rank, H, nope + v)
-    q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0], wkvb[..., :op.nope_dim],
-                       preferred_element_type=jnp.float32)
-    q_full = jnp.concatenate(
-        [q_lat.astype(arena.dtype), q_rope[:, 0].astype(arena.dtype),
-         jnp.zeros((n, op.num_heads, lanes - op.row_width), arena.dtype)],
-        axis=-1)                                      # (n, H, lanes)
-    if _latent_kernel_reads(op, entry, n, mb):
-        with jax.named_scope("latent_attention_decode"):
-            ctxv = latent_attention.latent_attention_decode(
-                q_full, arena, tables, seq_lens, scale=op.scale,
-                out_width=op.kv_rank)
-    else:
-        view = arena[tables].reshape(n, mb * bs, lanes)      # (n, L, lanes)
-        scores = jnp.einsum("nhr,nlr->nhl", q_full, view,
-                            preferred_element_type=jnp.float32) * op.scale
-        kpos = jax.lax.iota(jnp.int32, mb * bs)
-        scores = jnp.where((kpos[None, :] <= seq_lens[:, None])[:, None, :],
-                           scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctxv = jnp.einsum("nhl,nlc->nhc", probs.astype(arena.dtype),
-                          view[..., :op.kv_rank],
-                          preferred_element_type=jnp.float32)
-    o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
-                   wkvb[..., op.nope_dim:],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim), weights["wo"],
-                  preferred_element_type=jnp.float32).astype(x.dtype)
-    return out, entry
-
-
-def _latent_attn_prefill(op, weights, x, positions, entry, tables, lengths):
-    """A group of prompts through latent attention in the expanded form
-    (keys and values up-projected from the prompt's own rows, dense
-    causal attention), the rows scattered into the pool through each
-    prompt's block table with padding positions sent to the null block:
-    the sibling of the attention closure of ``_prefill_step``."""
-    b, s_blk, _ = x.shape
-    bs = entry[0].shape[1]
-    q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
-    pos = jax.lax.iota(jnp.int32, s_blk)
-    with jax.named_scope("latent_attention_prefill"):
-        out = op.attend_expanded(weights, q_nope, q_rope, rows,
-                                 pos[None, :] <= pos[:, None])
-    blk = tables[:, pos // bs]
-    flat = jnp.where(pos[None, :] < lengths[:, None],
-                     blk * bs + (pos % bs)[None, :], NULL_BLOCK * bs)
-    entry = _entry_write(entry, flat.reshape(-1),
-                         rows.reshape(b * s_blk, -1), None)
-    return out, entry
 
 
 def _expert_counts(op, ids, active):
@@ -505,10 +206,10 @@ def _audit_serving_program(program_name: str, jitted, sds_args, cfg):
 
 
 class _DecodeGraph:
-    """The shared compiled-graph contract both cache layouts walk:
-    validated causal self-attention ops, the (tokens, positions) input
-    binding, the position-embedding capacity bound, and the exec-params
-    cast cache."""
+    """The shared compiled-graph contract both cache layouts walk: the
+    attention ops and the entry kind of each, the (tokens, positions)
+    input binding, the position-embedding capacity bound, and the
+    exec-params cast cache."""
 
     def __init__(self, ff, max_length: int):
         cm = ff.compiled
@@ -516,33 +217,18 @@ class _DecodeGraph:
             raise ValueError("compile() the model before generating")
         self._cm = cm
         self.max_length = int(max_length)
-        self._attn_ops = [op for op in cm.ops if op.op_type in (
-            OpType.MULTIHEAD_ATTENTION, OpType.LATENT_ATTENTION)]
         self._token_id = cm.input_tensors[0]
         self._pos_id = cm.input_tensors[1]
-        for op in self._attn_ops:
-            if op.op_type is OpType.LATENT_ATTENTION:
-                # its second input is the graph's positions (rotary,
-                # inside the op), not a learned table's
-                if op.layer.inputs[1].tensor_id != self._pos_id.tensor_id:
-                    raise ValueError(
-                        f"{op.name}: latent attention has to take the "
-                        f"graph's positions input")
-                if self.max_length > op.max_positions:
-                    raise ValueError(
-                        f"max_length {self.max_length} exceeds the "
-                        f"positions {op.name} was built for "
-                        f"({op.max_positions})")
-                continue
-            ids = {t.tensor_id for t in op.layer.inputs}
-            if len(ids) != 1 or not op.causal:
-                raise ValueError(
-                    f"{op.name}: generation needs causal SELF-attention")
+        pos_tid = self._pos_id.tensor_id
+        # what each attention op keeps for a token, chosen once
+        kinds = {op.name: kind_for(op, pos_tid, self.max_length)
+                 for op in cm.ops}
+        self._kinds = {name: k for name, k in kinds.items() if k is not None}
+        self._attn_ops = [op for op in cm.ops if op.name in self._kinds]
         self._expert_ops = [op for op in cm.ops
                             if op.op_type is OpType.ROUTED_EXPERTS]
         # the position-embedding table bounds how far the MODEL can decode;
         # jnp.take clamps out-of-range ids silently, so enforce it here
-        pos_tid = self._pos_id.tensor_id
         for op in cm.ops:
             if (op.op_type is OpType.EMBEDDING
                     and op.layer.inputs[0].tensor_id == pos_tid):
@@ -563,6 +249,15 @@ class _DecodeGraph:
         version — see :class:`_ExecParamsCache`)."""
         return self._params_cache.get(self._cm, self._compute_dtype())
 
+    def _params_sds(self):
+        """The shapes of :meth:`_exec_params`, for tracing a program
+        without running it."""
+        cdt = self._compute_dtype()
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, cdt if cdt is not None and jnp.issubdtype(
+                    a.dtype, jnp.floating) else a.dtype), self._cm.params)
+
     def invalidate_params_cache(self) -> None:
         """Drop the cast copy after mutating ``cm.params`` leaves in
         place (replacing the tree, or bumping ``cm.params_version``,
@@ -571,8 +266,8 @@ class _DecodeGraph:
 
     def _forward_block(self, params, acts, attn, experts=None):
         """Walk the op graph over the activations in ``acts``; ``attn``
-        handles each causal self-attention op (cache layout specific;
-        a latent-attention op is handed the positions too) and
+        handles each attention op, given ``(op, weights, x, positions)``
+        (it calls the op's entry kind in the program's cache layout) and
         ``experts``, where given, each routed-experts op (the paged
         programs keep the routing they chose). Returns the (B, S, vocab)
         float32 logits."""
@@ -581,10 +276,8 @@ class _DecodeGraph:
         for op in self._cm.ops:
             ins = [acts[t.tensor_id] for t in op.layer.inputs]
             p = params.get(op.name, {})
-            if op.op_type is OpType.MULTIHEAD_ATTENTION:
-                outs = [attn(op, p, ins[0])]
-            elif op.op_type is OpType.LATENT_ATTENTION:
-                outs = [attn(op, p, ins[0], ins[1])]
+            if op.name in self._kinds:
+                outs = [attn(op, p, ins[0], acts[self._pos_id.tensor_id])]
             elif op.op_type is OpType.ROUTED_EXPERTS and experts is not None:
                 outs = [experts(op, p, ins[0])]
             else:
@@ -620,41 +313,22 @@ class Generator(_DecodeGraph):
         self._maybe_audit()
 
     def _maybe_audit(self) -> None:
-        cfg = self._cm.config
-        cdt = self._compute_dtype()
-        cache_dt = cdt or jnp.float32
-
-        def _sds(a):
-            dt = (cache_dt if cdt is not None
-                  and jnp.issubdtype(a.dtype, jnp.floating) else a.dtype)
-            return jax.ShapeDtypeStruct(a.shape, dt)
-
-        params_sds = jax.tree_util.tree_map(_sds, self._cm.params)
         tokens_sds = jax.ShapeDtypeStruct((self.batch_size, 1), jnp.int32)
-        cache_sds = {
-            op.name: tuple(jax.ShapeDtypeStruct(shape, cache_dt)
-                           for shape in self._cache_shapes(op))
-            for op in self._attn_ops}
-        offset_sds = jax.ShapeDtypeStruct((), jnp.int32)
         self.audit_report, self.exec_telemetry = _audit_serving_program(
             "serving.decode_step", self._step,
-            (params_sds, tokens_sds, cache_sds, offset_sds), cfg)
+            (self._params_sds(), tokens_sds, jax.eval_shape(self.init_cache),
+             jax.ShapeDtypeStruct((), jnp.int32)), self._cm.config)
 
     # ---- cache ------------------------------------------------------------
-    def _cache_shapes(self, op) -> Tuple[Tuple[int, ...], ...]:
-        """The dense cache of one attention op: a (k, v) pair of
-        (B, max_length, H, D), or a latent op's one (B, max_length,
-        width) array of rows."""
-        if op.op_type is OpType.LATENT_ATTENTION:
-            return ((self.batch_size, self.max_length, op.row_width),)
-        shape = (self.batch_size, self.max_length, op.num_heads, op.head_dim)
-        return (shape, shape)
-
     def init_cache(self) -> Dict[str, Tuple[jnp.ndarray, ...]]:
+        """The dense rectangle of each attention op, as its kind shapes
+        it: a (k, v) pair of (B, max_length, H, D), or a latent op's one
+        (B, max_length, width) array of rows."""
         dt = self._compute_dtype() or jnp.float32
-        return {op.name: tuple(jnp.zeros(shape, dt)
-                               for shape in self._cache_shapes(op))
-                for op in self._attn_ops}
+        return {name: tuple(jnp.zeros(shape, dt) for shape in
+                            kind.dense_shapes(self.batch_size,
+                                              self.max_length))
+                for name, kind in self._kinds.items()}
 
     # ---- one block step (prefill: S=prompt, decode: S=1) -----------------
     def _block_step(self, params, tokens, cache, offset):
@@ -665,15 +339,9 @@ class Generator(_DecodeGraph):
                 self._pos_id.tensor_id: positions}
         new_cache = dict(cache)
 
-        def attn(op, p, x, pos=None):
-            if pos is not None:
-                out, rows = _latent_attn_with_cache(
-                    op, p, x, pos, new_cache[op.name][0], offset)
-                new_cache[op.name] = (rows,)
-                return out
-            k, v = new_cache[op.name]
-            out, k, v = _attn_with_cache(op, p, x, k, v, offset)
-            new_cache[op.name] = (k, v)
+        def attn(op, p, x, pos):
+            out, new_cache[op.name] = self._kinds[op.name].dense_step(
+                op, p, x, pos, new_cache[op.name], offset)
             return out
 
         logits = self._forward_block(params, acts, attn)
@@ -831,13 +499,8 @@ class PagedDecoder(_DecodeGraph):
             # plus the reserved null block
             num_blocks = (self.decode_slots * self.max_blocks_per_request
                           + 1)
-        dt = self._compute_dtype() or jnp.float32
         self.kv_dtype = str(kv_dtype)
-        self.pool = PagedKVPool(
-            self._pool_specs(), num_blocks=int(num_blocks),
-            block_size=self.block_size,
-            max_blocks_per_request=self.max_blocks_per_request, dtype=dt,
-            kv_dtype=self.kv_dtype)
+        self.pool = self._new_pool(int(num_blocks))
         # one small accumulator for each routed-experts op (_count_up),
         # donated to the decode program beside the pool and returned by
         # it: counted on the device, fetched only by expert_stats(). The
@@ -917,13 +580,9 @@ class PagedDecoder(_DecodeGraph):
         # a slot with no block reserved is idle: its token is padding
         active = tables[:, 0] != NULL_BLOCK
 
-        def attn(op, p, x, pos=None):
-            if pos is not None:
-                out, new_pool[op.name] = _latent_attn_paged(
-                    op, p, x, pos, new_pool[op.name], tables, seq_lens)
-                return out
-            out, new_pool[op.name] = _attn_with_paged_cache(
-                op, p, x, new_pool[op.name], tables, seq_lens)
+        def attn(op, p, x, pos):
+            out, new_pool[op.name] = self.pool.kinds[op.name].step(
+                op, p, x, pos, new_pool[op.name], tables, seq_lens)
             return out
 
         def experts(op, p, x):
@@ -941,7 +600,7 @@ class PagedDecoder(_DecodeGraph):
     def _verify_step(self, params, tokens, pool, tables, seq_lens):
         """Speculative verify: tokens (slots, W) int32 — each slot's
         last accepted token followed by W-1 draft proposals, at absolute
-        positions ``seq_lens .. seq_lens + W - 1``. Writes K/V for ALL
+        positions ``seq_lens .. seq_lens + W - 1``. Writes the rows of ALL
         W positions through the block tables and returns the full
         ((slots, W, vocab) float32 logits, new pool) in ONE dispatch:
         row j is the target's distribution for the token AFTER window
@@ -959,9 +618,9 @@ class PagedDecoder(_DecodeGraph):
                 self._pos_id.tensor_id: positions}
         new_pool = dict(pool)
 
-        def attn(op, p, x):
-            out, new_pool[op.name] = _attn_with_paged_cache(
-                op, p, x, new_pool[op.name], tables, seq_lens)
+        def attn(op, p, x, pos):
+            out, new_pool[op.name] = self.pool.kinds[op.name].step(
+                op, p, x, pos, new_pool[op.name], tables, seq_lens)
             return out
 
         logits = self._forward_block(params, acts, attn)
@@ -971,13 +630,12 @@ class PagedDecoder(_DecodeGraph):
         """Bucketed prefill for a GROUP of requests: tokens (P, Sb)
         int32 (each prompt padded to the bucket), pool donated, tables
         (P, MB) int32, lengths (P,) int32 true prompt lengths. Rows
-        are independent — batched dense causal attention (padding keys
-        are causally masked for every valid query row), each row's K/V
-        scattered through its own block table with padding positions
-        redirected into the null block — so one multi-prompt dispatch
-        computes exactly what P single-prompt dispatches would, in one
-        XLA program. Returns ((P, vocab) float32 logits of each row's
-        last prompt position, new pool): that row is all a caller
+        are independent (padding keys are causally masked for every
+        valid query row, padding rows go to the null block), so one
+        multi-prompt dispatch computes exactly what P single-prompt
+        dispatches would, in one XLA program. Returns ((P, vocab)
+        float32 logits of each row's last prompt position, new pool,
+        the expert ids chosen): that row is all a caller
         reads, and the other Sb - 1 never leave the device (fetched
         whole they were 63-84 MB a prefill at 20480 wide, a tenth of a
         serving loop's time: PERF.md section 6, PR 27)."""
@@ -987,7 +645,6 @@ class PagedDecoder(_DecodeGraph):
         acts = {self._token_id.tensor_id: tokens,
                 self._pos_id.tensor_id: positions}
         new_pool = dict(pool)
-        bs = self.block_size
         routed: Dict[str, jax.Array] = {}
 
         def experts(op, p, x):
@@ -996,55 +653,25 @@ class PagedDecoder(_DecodeGraph):
             routed[op.name] = ids.reshape(b, s_blk, -1)
             return op.apply(p, x2d, ids, gates).reshape(x.shape)
 
-        def attn(op, p, x, pos=None):
-            if pos is not None:
-                out, new_pool[op.name] = _latent_attn_prefill(
-                    op, p, x, pos, new_pool[op.name], tables, lengths)
-                return out
-            qh = jnp.einsum("bse,ehd->bshd", x, p["wq"])
-            kh = jnp.einsum("bse,ehd->bshd", x, p["wk"])
-            vh = jnp.einsum("bse,ehd->bshd", x, p["wv"])
-            if op.use_bias:
-                qh = qh + p["bq"]
-                kh = kh + p["bk"]
-                vh = vh + p["bv"]
-            scale = 1.0 / math.sqrt(op.head_dim)
-            scores = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) * scale
-            pos = jax.lax.iota(jnp.int32, s_blk)
-            mask = pos[None, :] <= pos[:, None]                 # causal
-            scores = jnp.where(mask[None, None, :, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs, vh)
-            out = jnp.einsum("bqhd,hde->bqe", ctxv, p["wo"])
-            if op.use_bias:
-                out = out + p["bo"]
-            # scatter each row's prompt K/V into the pool: row i's
-            # position p lands in block tables[i, p // bs] at offset
-            # p % bs; padding positions (p >= lengths[i]) are
-            # redirected into the null block (real positions never
-            # collide — each row owns its blocks). _entry_write
-            # quantizes on the way in for int8 arenas.
-            blk = tables[:, pos // bs]                          # (P, Sb)
-            flat = jnp.where(pos[None, :] < lengths[:, None],
-                             blk * bs + (pos % bs)[None, :],
-                             NULL_BLOCK * bs)                   # (P, Sb)
-            heads, hdim = kh.shape[2], kh.shape[3]
-            new_pool[op.name] = _entry_write(
-                new_pool[op.name], flat.reshape(-1),
-                kh.reshape(b * s_blk, heads, hdim),
-                vh.reshape(b * s_blk, heads, hdim))
+        def attn(op, p, x, pos):
+            out, new_pool[op.name] = self.pool.kinds[op.name].prefill(
+                op, p, x, pos, new_pool[op.name], tables, lengths)
             return out
 
         logits = self._forward_block(params, acts, attn, experts)
         last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
         return last, new_pool, routed
 
-    def _pool_specs(self) -> Dict[str, Tuple[int, ...]]:
-        """What a token's row is for each attention op (kv_cache.py)."""
-        return {op.name: ((op.row_width,)
-                          if op.op_type is OpType.LATENT_ATTENTION
-                          else (op.num_heads, op.head_dim))
-                for op in self._attn_ops}
+    def _new_pool(self, num_blocks: int) -> PagedKVPool:
+        """A pool of the attention ops' entries stored as ``kv_dtype``
+        says, and with it the kinds the programs call
+        (``pool.kinds``): the one place either is made, so a kind cannot
+        outlive its arenas."""
+        return PagedKVPool(
+            self._kinds, num_blocks=num_blocks, block_size=self.block_size,
+            max_blocks_per_request=self.max_blocks_per_request,
+            dtype=self._compute_dtype() or jnp.float32,
+            kv_dtype=self.kv_dtype)
 
     def expert_stats(self) -> Dict[str, Dict]:
         """Per routed-experts op, counted on the device over the decode
@@ -1073,18 +700,11 @@ class PagedDecoder(_DecodeGraph):
         """What a W-token step's attention does with the pool as it is
         now: "kernel" where every attention op's entry is read in
         place, else "gather"."""
-        def in_place(op):
-            entry = self.pool.kv[op.name]
-            if op.op_type is OpType.LATENT_ATTENTION:
-                return window == 1 and _latent_kernel_reads(
-                    op, entry, self.decode_slots,
-                    self.max_blocks_per_request)
-            return _kernel_reads(
-                entry, (self.decode_slots, window, op.num_heads,
-                        op.head_dim), self.max_blocks_per_request)
-
-        return "kernel" if all(in_place(op)
-                               for op in self._attn_ops) else "gather"
+        return "kernel" if all(
+            self.pool.kinds[op.name].reads_in_place(
+                op, self.pool.kv[op.name], self.decode_slots, window,
+                self.max_blocks_per_request)
+            for op in self._attn_ops) else "gather"
 
     def _prefill_fn(self, bucket: int, width: int = 1):
         """The (bucket, row-width) executable — the seen-set is the
@@ -1111,30 +731,18 @@ class PagedDecoder(_DecodeGraph):
 
     # ---- audit -------------------------------------------------------------
     def _maybe_audit(self) -> None:
-        cfg = self._cm.config
-        cdt = self._compute_dtype()
-        cache_dt = cdt or jnp.float32
-
-        def _sds(a):
-            dt = (cache_dt if cdt is not None
-                  and jnp.issubdtype(a.dtype, jnp.floating) else a.dtype)
-            return jax.ShapeDtypeStruct(a.shape, dt)
-
-        params_sds = jax.tree_util.tree_map(_sds, self._cm.params)
-        pool_sds = {name: tuple(jax.ShapeDtypeStruct(k.shape, k.dtype)
-                                for k in kv)
-                    for name, kv in self.pool.kv.items()}
+        pool_sds, acc_sds = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            (self.pool.kv, self._expert_acc))
         tables_sds = jax.ShapeDtypeStruct(
             (self.decode_slots, self.max_blocks_per_request), jnp.int32)
         # tokens, seq_lens and prev_ids: one (slots,) int32 each
         lens_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.int32)
-        acc_sds = {name: jax.ShapeDtypeStruct(a.shape, a.dtype)
-                   for name, a in self._expert_acc.items()}
         take_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.bool_)
         self.audit_report, self.exec_telemetry = _audit_serving_program(
             "serving.paged_decode_step", self._decode,
-            (params_sds, lens_sds, pool_sds, tables_sds, lens_sds,
-             acc_sds, lens_sds, take_sds), cfg)
+            (self._params_sds(), lens_sds, pool_sds, tables_sds, lens_sds,
+             acc_sds, lens_sds, take_sds), self._cm.config)
 
     # ---- host API (the scheduler's surface) --------------------------------
     def prefill(self, prompt: np.ndarray, table: np.ndarray) -> np.ndarray:
@@ -1237,7 +845,7 @@ class PagedDecoder(_DecodeGraph):
         distribution after window position j."""
         tokens = np.asarray(tokens, np.int32)
         w = int(tokens.shape[1])
-        self._refuse_verify_over_latent()
+        self.check_window(w)
         fn = self._verify_fns.get(w)
         if fn is None:
             fn = jax.jit(self._verify_step, donate_argnums=(2,))
@@ -1252,15 +860,17 @@ class PagedDecoder(_DecodeGraph):
                 jnp.asarray(np.asarray(seq_lens, np.int32)))
         return self._fetch(logits)
 
-    def _refuse_verify_over_latent(self) -> None:
-        """Speculative verify (W > 1 tokens a slot) is not built for a
-        latent cache entry: refuse, loudly, rather than fall back."""
-        latent = [op.name for op in self._attn_ops
-                  if op.op_type is OpType.LATENT_ATTENTION]
-        if latent:
+    def check_window(self, window: int) -> None:
+        """Refuse, loudly, a step of ``window`` new tokens a slot (a
+        speculative verify of ``spec_k + 1``) that an entry kind of this
+        model does not take, rather than fall back."""
+        narrow = [name for name, kind in self._kinds.items()
+                  if kind.max_window is not None and window > kind.max_window]
+        if narrow:
             raise ValueError(
-                f"speculative verify over a latent cache entry is not "
-                f"built ({latent[0]} and {len(latent) - 1} more): serve "
+                f"speculative verify over a "
+                f"{self._kinds[narrow[0]].name} cache entry is not "
+                f"built ({narrow[0]} and {len(narrow) - 1} more): serve "
                 f"this model with spec_k=0")
 
     def _fetch(self, logits) -> np.ndarray:
@@ -1287,27 +897,8 @@ class PagedDecoder(_DecodeGraph):
             self._pos_id.tensor_id:
                 jnp.asarray(np.arange(s, dtype=np.int32)[None, :])}
 
-        def attn(op, p, x, pos=None):
-            if pos is not None:     # latent attention's own dense forward
-                return op.forward(None, [x, pos], p)[0]
-            qh = jnp.einsum("bse,ehd->bshd", x, p["wq"])
-            kh = jnp.einsum("bse,ehd->bshd", x, p["wk"])
-            vh = jnp.einsum("bse,ehd->bshd", x, p["wv"])
-            if op.use_bias:
-                qh = qh + p["bq"]
-                kh = kh + p["bk"]
-                vh = vh + p["bv"]
-            scale = 1.0 / math.sqrt(op.head_dim)
-            scores = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) * scale
-            pos = jax.lax.iota(jnp.int32, s)
-            mask = pos[None, :] <= pos[:, None]
-            scores = jnp.where(mask[None, None, :, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs, vh)
-            out = jnp.einsum("bqhd,hde->bqe", ctxv, p["wo"])
-            if op.use_bias:
-                out = out + p["bo"]
-            return out
+        def attn(op, p, x, pos):
+            return self._kinds[op.name].whole(op, p, x, pos)[0]
 
         def experts(op, p, x):
             x2d = x.reshape(-1, x.shape[-1])
@@ -1393,13 +984,7 @@ class PagedDecoder(_DecodeGraph):
         print(f"[serving] KVQ001: {report.warnings[0].message}",
               file=sys.stderr)
         self.kv_dtype = "float32"
-        dt = self._compute_dtype() or jnp.float32
-        fallback = PagedKVPool(
-            self._pool_specs(), num_blocks=self.pool.num_blocks,
-            block_size=self.block_size,
-            max_blocks_per_request=self.max_blocks_per_request, dtype=dt,
-            kv_dtype="float32")
-        self.pool = fallback  # concurrency: race-ok (calibration runs inside __init__, before the scheduler's thread or any stats() reader exists)
+        self.pool = self._new_pool(self.pool.num_blocks)  # concurrency: race-ok (calibration runs inside __init__, before the scheduler's thread or any stats() reader exists)
         self.attention_path["decode"] = self._attention_path(1)
 
 

@@ -50,17 +50,12 @@ GPT-2 large at 16 slots of 1024 tokens: 1025 x 16 x 1280 x 2 B = 42 MB an
 arena, 72 arenas, 3.0 GB.
 
 **Quantized arenas** (``kv_dtype``): the pool can store its arenas in
-``"bfloat16"`` (cast-in/cast-out) or ``"int8"`` — asymmetric per-token
-per-head quantization, with the f32 scale and zero-point stored in
-``(num_blocks, block_size, H)`` sidecar arrays indexed by the same
-(block, slot) coordinates so the scatter/gather path never needs a
-second addressing scheme. int8
-per-token bytes per head are ``head_dim + 8`` (values + scale + zero)
-vs f32's ``4 * head_dim`` — half the bytes at head_dim 8, a quarter at
-head_dim 64 — so worst-case admission at a fixed byte budget doubles
-or better. Dequantization happens inside the decode/verify dispatch
-(:func:`~flexflow_tpu.serving.generation._attn_with_paged_cache`);
-the numerics gate (``serving_kv_divergence_budget``, KVQ001) lives in
+``"bfloat16"`` (cast-in/cast-out) or ``"int8"``: each op's entry in its
+kind's int8 form (:class:`~flexflow_tpu.serving.cache_entry
+.Int8PairEntry`: values quantized per token and head, float32 sidecars
+under the same (block, slot) addresses, so worst-case admission at a
+fixed byte budget doubles or better). The numerics gate
+(``serving_kv_divergence_budget``, KVQ001) lives in
 :class:`~flexflow_tpu.serving.generation.PagedDecoder`, which
 calibrates at construction and falls back loudly to f32.
 """
@@ -69,7 +64,7 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -77,6 +72,9 @@ import jax.numpy as jnp
 
 from ..obs.metrics import metrics_registry
 from .errors import KVPoolExhausted
+
+if TYPE_CHECKING:
+    from .cache_entry import EntryKind
 
 NULL_BLOCK = 0  # reserved scatter/gather sink; never allocated
 
@@ -87,21 +85,43 @@ NULL_BLOCK = 0  # reserved scatter/gather sink; never allocated
 KV_DTYPES = ("float32", "bfloat16", "int8")
 
 
-def latent_row_lanes(width: int) -> int:
-    """A latent row's width in the arena: whole 128-lane tiles."""
-    return -(-int(width) // 128) * 128
+def stored_as(name: str, kind, kv_dtype: str, dtype):
+    """The (kind, dtype) an op's arenas are stored as under ``kv_dtype``:
+    its own kind in bfloat16 or the pool's ``dtype``, or under ``"int8"``
+    its int8 form (which it has to have, and which states its arenas'
+    dtypes itself)."""
+    if kv_dtype != "int8":
+        return kind, jnp.bfloat16 if kv_dtype == "bfloat16" else dtype
+    if kind.int8_form is None:
+        raise ValueError(
+            f"{name}: a {kind.name} cache entry has no int8 form "
+            f"(kv_dtype='int8' quantizes the heads of a (k, v) pair); "
+            f"use 'bfloat16'")
+    return kind.int8_form, dtype
+
+
+def pool_bytes(specs, num_blocks: int, block_size: int,
+               kv_dtype: str = "float32", dtype=jnp.float32) -> int:
+    """Arena bytes of a pool of ``specs`` (``{attention op name: entry
+    kind}``) across all ops, sidecars included: plain arithmetic, which
+    :meth:`PagedKVPool.memory_bytes` and the sim's capacity planning both
+    call. ``dtype`` is what the ``"float32"`` mode stores in (the pool's
+    compute dtype, which may itself be bf16)."""
+    per_tok = 0
+    for name, spec in dict(specs).items():
+        kind, store = stored_as(name, spec, kv_dtype, dtype)
+        per_tok += kind.token_bytes(store)
+    return int(num_blocks) * int(block_size) * per_tok
 
 
 class PagedKVPool:
     """Block pool + allocator for one model's attention ops.
 
-    ``specs``: ``{attention op name: spec}`` says what a token's row is
-    for each op: ``(num_heads, head_dim)`` — one (k, v) arena pair — or
-    the 1-tuple ``(row_width,)`` of a latent-attention op, whose cache
-    is ONE row a token (its normalized latent and its rotary key, which
-    keys and values are both read from): a 1-tuple entry
-    ``(num_blocks, block_size, row_lanes)``, the width padded up to whole
-    128-lane tiles with zeros. All ops share the same block geometry and
+    ``specs``: ``{attention op name: entry kind}`` says what a token's
+    row is for each op (:mod:`~flexflow_tpu.serving.cache_entry`): the
+    pool allocates the arenas the kind describes, as :attr:`kv`'s tuple
+    of arrays for that op, and keeps the kind they are stored as in
+    :attr:`kinds` (the op's own, or its int8 form). All ops share the same block geometry and
     allocator (a token occupies one slot in EVERY layer's arena, so one
     block id spans all layers — the allocator hands out block ids, not
     per-layer storage).
@@ -113,7 +133,7 @@ class PagedKVPool:
     thread, capacity introspection on callers' threads.
     """
 
-    def __init__(self, specs: Dict[str, Tuple[int, ...]], *,
+    def __init__(self, specs: Dict[str, "EntryKind"], *,
                  num_blocks: int, block_size: int,
                  max_blocks_per_request: int, dtype=jnp.float32,
                  kv_dtype: str = "float32"):
@@ -135,37 +155,16 @@ class PagedKVPool:
         self.dtype = dtype
         self.kv_dtype = kv_dtype
         self.specs = dict(specs)
-        # arena entry per op: (k, v) for float/bf16 storage, the
-        # 6-tuple (k_q, v_q, k_scale, k_zero, v_scale, v_zero) for int8,
-        # or the 1-tuple (rows,) of a latent op — the generation helpers
-        # dispatch on the tuple length, so the donated pytree structure
-        # is the only "flag" the compiled programs ever see
+        # arena entry per op: the tuple of arrays its kind describes,
+        # donated through the programs; the kind stays beside it
+        self.kinds: Dict[str, "EntryKind"] = {}
         self.kv: Dict[str, Tuple[jnp.ndarray, ...]] = {}
         for name, spec in self.specs.items():
-            if len(spec) == 1:
-                if kv_dtype == "int8":
-                    raise ValueError(
-                        f"{name}: a latent cache entry has no int8 form "
-                        f"(kv_dtype='int8' quantizes per head, and a "
-                        f"latent row has no heads); use 'bfloat16'")
-                store = jnp.bfloat16 if kv_dtype == "bfloat16" else dtype
-                self.kv[name] = (jnp.zeros(
-                    (self.num_blocks, self.block_size,
-                     latent_row_lanes(spec[0])), store),)
-                continue
-            heads, head_dim = spec
-            # one row a token, all heads side by side (module docstring)
-            shape = (self.num_blocks, self.block_size, heads * head_dim)
-            if kv_dtype == "int8":
-                side = (self.num_blocks, self.block_size, heads)
-                self.kv[name] = (
-                    jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                    jnp.zeros(side, jnp.float32), jnp.zeros(side, jnp.float32),
-                    jnp.zeros(side, jnp.float32), jnp.zeros(side, jnp.float32))
-            else:
-                store = jnp.bfloat16 if kv_dtype == "bfloat16" else dtype
-                self.kv[name] = (jnp.zeros(shape, store),
-                                 jnp.zeros(shape, store))
+            kind, store = stored_as(name, spec, kv_dtype, dtype)
+            self.kinds[name] = kind
+            self.kv[name] = tuple(
+                jnp.zeros(a.shape, a.dtype) for a in kind.arenas(
+                    self.num_blocks, self.block_size, store))
         # LIFO free list: freshly freed blocks are reused first (their
         # stale contents are masked by position either way)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
@@ -184,21 +183,11 @@ class PagedKVPool:
         return max(1, math.ceil(int(tokens) / self.block_size))
 
     def memory_bytes(self) -> int:
-        """Total arena bytes across all ops (k and v), dtype-aware:
-        int8 pools count their f32 scale/zero-point sidecars too (the
-        honest admission-doubling denominator). Pinned byte-for-byte to
-        the sim's :func:`~flexflow_tpu.sim.simulator
-        .serving_kv_pool_bytes` by a parity test."""
-        if self.kv_dtype == "int8":
-            # per token: k+v int8 values plus (scale, zero) f32 per head
-            per_tok = sum(2 * h * d + 2 * 2 * h * 4
-                          for h, d in self.specs.values())
-            return self.num_blocks * self.block_size * per_tok
-        item = (2 if self.kv_dtype == "bfloat16"
-                else jnp.dtype(self.dtype).itemsize)
-        per_tok = sum(latent_row_lanes(s[0]) if len(s) == 1
-                      else 2 * s[0] * s[1] for s in self.specs.values())
-        return self.num_blocks * self.block_size * per_tok * item
+        """Total arena bytes across all ops, dtype-aware: int8 pools
+        count their f32 scale/zero-point sidecars too (the honest
+        admission-doubling denominator)."""
+        return pool_bytes(self.specs, self.num_blocks, self.block_size,
+                          self.kv_dtype, self.dtype)
 
     # ---- allocator ---------------------------------------------------------
     def in_use(self) -> int:
@@ -274,21 +263,17 @@ class PagedKVPool:
             "high_water": hw,
             "memory_bytes": int(self.memory_bytes()),
             "kv_dtype": self.kv_dtype,
-            # what a token's row is: "pair" (k, v), "int8" (values and
-            # sidecars) or "latent" (one row, its width as cached and as
-            # the arena pads it)
+            # what a token's row is, in its kind's words: "pair" (k, v),
+            # "int8" (values and sidecars) or "latent" (one row, its
+            # width as cached and as the arena pads it)
             **self._entry_stats(),
         }
 
     def _entry_stats(self) -> Dict:
-        latent = [s[0] for s in self.specs.values() if len(s) == 1]
-        if not latent:
-            return {"entry": "int8" if self.kv_dtype == "int8" else "pair"}
-        if len(latent) != len(self.specs):
-            return {"entry": "mixed"}
-        return {"entry": "latent", "row_width": int(latent[0]),
-                "row_lanes": latent_row_lanes(latent[0])}
+        said = [kind.stats() for kind in self.kinds.values()]
+        return said[0] if all(s == said[0] for s in said) else {
+            "entry": "mixed"}
 
 
 __all__ = ["KV_DTYPES", "NULL_BLOCK", "PagedKVPool", "KVPoolExhausted",
-           "latent_row_lanes"]
+           "pool_bytes", "stored_as"]

@@ -287,7 +287,7 @@ class ContinuousBatchingScheduler:
         self.spec_k = max(0, int(spec_k))
         self.draft: Optional[PagedDecoder] = None
         if self.spec_k > 0:
-            self.decoder._refuse_verify_over_latent()
+            self.decoder.check_window(self.spec_k + 1)
             if draft_ff is None:
                 raise ValueError(
                     f"{name!r}: spec_k={self.spec_k} needs a draft model "

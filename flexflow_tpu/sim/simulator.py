@@ -66,25 +66,18 @@ class MemoryUsage:
 def serving_kv_pool_bytes(specs, num_blocks: int, block_size: int,
                           kv_dtype: str = "float32",
                           dtype_bytes: int = 4) -> int:
-    """Dtype-aware paged-KV pool arena bytes — the sim-side mirror of
-    ``PagedKVPool.memory_bytes`` (a parity test pins the two byte-for-
-    byte, so capacity planning and the advisor's admission math can
-    never drift from the real allocation).
+    """Dtype-aware paged-KV pool arena bytes for capacity planning and
+    the advisor's admission math: the pool's own arithmetic
+    (``serving.kv_cache.pool_bytes``, which ``PagedKVPool.memory_bytes``
+    calls too), so the two cannot drift. ``specs``: ``{attention op
+    name: entry kind}``, as ``PagedKVPool.specs``; ``dtype_bytes``: the
+    item size of the ``"float32"`` mode's compute dtype."""
+    import numpy as np
 
-    ``specs``: ``{attention op name: (num_heads, head_dim)}``. Per
-    token per op: k+v at the storage width, plus — for ``"int8"`` —
-    the f32 scale/zero-point sidecar pair per head for each of k and v.
-    ``dtype_bytes`` is the ``"float32"`` mode's item size (that mode
-    stores in the pool's compute dtype, which may itself be bf16)."""
-    if kv_dtype == "int8":
-        per_tok = sum(2 * h * d + 2 * 2 * h * 4
-                      for h, d in dict(specs).values())
-        return int(num_blocks) * int(block_size) * per_tok
-    item = 2 if kv_dtype == "bfloat16" else int(dtype_bytes)
-    # a latent op's 1-tuple spec: one row a token, padded to 128 lanes
-    per_tok = sum(-(-s[0] // 128) * 128 if len(s) == 1 else 2 * s[0] * s[1]
-                  for s in dict(specs).values())
-    return int(num_blocks) * int(block_size) * per_tok * item
+    from ..serving.kv_cache import pool_bytes
+
+    return pool_bytes(specs, num_blocks, block_size, kv_dtype,
+                      np.dtype(f"f{int(dtype_bytes)}"))
 
 
 def _collective_axes(op: Op) -> Tuple[List[Tuple[str, int, str]], int]:

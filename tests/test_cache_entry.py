@@ -1,0 +1,183 @@
+"""The seam of serving/cache_entry.py: a new entry kind is a class and a
+line in ``KINDS`` (generation.py, kv_cache.py and scheduler.py serve it
+unedited), and each real kind's arenas, bytes, names and limits are the
+kind's own answers."""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from flexflow_tpu import FFConfig, FFModel
+from flexflow_tpu.ffconst import CompMode, OpType
+from flexflow_tpu.models import (GPTConfig, LatentMoEConfig, build_gpt,
+                                 build_latent_moe_lm)
+from flexflow_tpu.serving import (ContinuousBatchingScheduler,
+                                  GenerationInstance, Generator,
+                                  PagedDecoder, PagedKVPool)
+from flexflow_tpu.serving import cache_entry
+from flexflow_tpu.serving.cache_entry import (Int8PairEntry, LatentEntry,
+                                              PairEntry)
+from flexflow_tpu.sim import serving_kv_pool_bytes
+
+V = 50
+NB, BS = 9, 8
+
+
+def _model(build, *args):
+    ff = FFModel(FFConfig(batch_size=4, seed=0, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    build(ff, 4, *args)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    return ff
+
+
+@pytest.fixture(scope="module")
+def gpt():
+    return _model(build_gpt, 6, GPTConfig(
+        vocab_size=V, max_positions=32, hidden_size=32, num_heads=4,
+        num_layers=2))
+
+
+@pytest.fixture(scope="module")
+def latent_lm():
+    return _model(build_latent_moe_lm, 8, LatentMoEConfig(
+        vocab_size=V, max_positions=32, hidden_size=32, num_layers=2,
+        num_heads=4, q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, dense_width=32, expert_width=16,
+        n_routed=4, experts_per_token=2))
+
+
+# ---- (a) a kind the package has never seen -----------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _FusedEntry(PairEntry):
+    """Keys and values of a token side by side in ONE arena row."""
+
+    name = "fused"
+
+    def arenas(self, num_blocks, block_size, dtype):
+        return (jax.ShapeDtypeStruct(
+            (num_blocks, block_size, 2 * self.heads * self.head_dim), dtype),)
+
+    def write(self, entry, flat, kh, vh):
+        t = kh.shape[0]
+        rows = jnp.concatenate([kh.reshape(t, -1), vh.reshape(t, -1)], -1)
+        return (cache_entry._put(entry[0], flat, rows),)
+
+    def read(self, entry, tables):
+        n, hd = tables.shape[0], self.heads * self.head_dim
+        rows = entry[0][tables].reshape(n, -1, 2 * hd)
+        shape = (n, rows.shape[1], self.heads, self.head_dim)
+        return rows[..., :hd].reshape(shape), rows[..., hd:].reshape(shape)
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return False
+
+
+def test_a_new_kind_serves_through_unmodified_decoder_and_scheduler(
+        gpt, monkeypatch):
+    rng = np.random.default_rng(7)
+    reqs = [(rng.integers(0, V, (n,)).astype(np.int32), m)
+            for n, m in [(3, 6), (6, 2), (2, 9), (5, 4)]]
+    gen = Generator(gpt, max_length=32)
+    want = [gen.generate(p[None, :], m)[0] for p, m in reqs]
+    monkeypatch.setitem(cache_entry.KINDS, OpType.MULTIHEAD_ATTENTION,
+                        _FusedEntry.for_op)
+    sched = ContinuousBatchingScheduler(gpt, max_length=32, decode_slots=3,
+                                        block_size=8)
+    try:
+        futs = [sched.submit(p, m) for p, m in reqs]
+        got = [f.result(timeout=120) for f in futs]
+        stats = sched.stats()
+    finally:
+        sched.stop()
+    for out, ref in zip(got, want):
+        np.testing.assert_array_equal(out, ref)
+    pool = sched.decoder.pool
+    assert stats["kv"]["entry"] == "fused"
+    assert stats["kv"]["attention_path"]["decode"] == "gather"
+    assert all(isinstance(k, _FusedEntry) for k in pool.kinds.values())
+    assert {tuple(a.shape for a in e) for e in pool.kv.values()} == {
+        ((pool.num_blocks, 8, 2 * 32),)}
+    # a speculative verify window goes through the same kind
+    dec = PagedDecoder(gpt, 32, decode_slots=2, block_size=8)
+    table = dec.pool.try_admit(12)
+    dec.prefill(reqs[0][0], table)
+    tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
+    tables[0] = table
+    window = np.zeros((2, 3), np.int32)
+    window[0] = want[0][3:6]
+    logits = dec.verify(window, tables, np.array([3, 0], np.int32))
+    assert logits[0].argmax(-1).tolist() == want[0][4:7].tolist()
+
+
+def test_an_attention_op_without_a_kind_is_refused_by_name(gpt, monkeypatch):
+    monkeypatch.delitem(cache_entry.KINDS, OpType.MULTIHEAD_ATTENTION)
+    with pytest.raises(ValueError, match=r"block0_attn.*no cache entry kind "
+                                         r".*MULTIHEAD_ATTENTION"):
+        PagedDecoder(gpt, 32, decode_slots=2, block_size=8)
+
+
+# ---- (b) the three real kinds ------------------------------------------------
+
+CASES = {
+    "pair": (PairEntry(4, 8), "float32",
+             [((NB, BS, 32), "float32")] * 2, {"entry": "pair"}, None),
+    "pair-bf16": (PairEntry(4, 8), "bfloat16",
+                  [((NB, BS, 32), "bfloat16")] * 2, {"entry": "pair"}, None),
+    "int8": (PairEntry(4, 8), "int8",
+             [((NB, BS, 32), "int8")] * 2 + [((NB, BS, 4), "float32")] * 4,
+             {"entry": "int8"}, None),
+    "latent": (LatentEntry(24), "bfloat16", [((NB, BS, 128), "bfloat16")],
+               {"entry": "latent", "row_width": 24, "row_lanes": 128}, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_kind_answers_for_its_arenas_bytes_names_and_limits(case):
+    kind, kv_dtype, arenas, said, max_window = CASES[case]
+    pool = PagedKVPool({"a": kind, "b": kind}, num_blocks=NB, block_size=BS,
+                       max_blocks_per_request=4, kv_dtype=kv_dtype)
+    stored = pool.kinds["a"]
+    assert isinstance(stored, Int8PairEntry) == (kv_dtype == "int8")
+    for entry in pool.kv.values():
+        assert [(a.shape, str(a.dtype)) for a in entry] == arenas
+    store = jnp.bfloat16 if kv_dtype == "bfloat16" else jnp.float32
+    held = sum(a.nbytes for entry in pool.kv.values() for a in entry)
+    assert 2 * NB * BS * stored.token_bytes(store) == held
+    assert pool.memory_bytes() == held == serving_kv_pool_bytes(
+        pool.specs, NB, BS, kv_dtype)
+    st = pool.stats()
+    assert {k: st[k] for k in said} == said and st["memory_bytes"] == held
+    assert stored.max_window == max_window
+    assert len(stored.dense_shapes(2, 16)) == (1 if case == "latent" else 2)
+
+
+def test_a_latent_row_has_no_int8_form():
+    assert LatentEntry(24).int8_form is None
+    assert PairEntry(4, 8).int8_form == Int8PairEntry(4, 8)
+    with pytest.raises(ValueError, match="attn: a latent cache entry has "
+                                         "no int8 form"):
+        PagedKVPool({"attn": LatentEntry(24)}, num_blocks=4, block_size=8,
+                    max_blocks_per_request=2, kv_dtype="int8")
+
+
+# ---- (c) limits reach the public path ----------------------------------------
+
+def test_spec_k_over_a_latent_model_is_refused_at_construction(latent_lm,
+                                                               gpt):
+    dec = PagedDecoder(latent_lm, 32, decode_slots=2, block_size=8)
+    assert {type(k) for k in dec.pool.kinds.values()} == {LatentEntry}
+    dec.check_window(1)
+    with pytest.raises(
+            ValueError,
+            match=r"speculative verify over a latent cache entry is not "
+                  r"built \(\w+ and 1 more\): serve this model with "
+                  r"spec_k=0"):
+        GenerationInstance(latent_lm, decode_slots=2, block_size=8,
+                           max_length=32, spec_k=2, draft_ff=latent_lm)
+    PagedDecoder(gpt, 32, decode_slots=2, block_size=8).check_window(5)

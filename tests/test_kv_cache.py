@@ -7,12 +7,13 @@ import pytest
 import jax.numpy as jnp
 
 from flexflow_tpu.obs.metrics import metrics_registry
+from flexflow_tpu.serving.cache_entry import PairEntry
 from flexflow_tpu.serving.errors import KVPoolExhausted, ShedError
 from flexflow_tpu.serving.kv_cache import NULL_BLOCK, PagedKVPool
 
 
 def _pool(num_blocks=9, block_size=4, max_blocks=4, **kw):
-    return PagedKVPool({"attn0": (2, 8), "attn1": (2, 8)},
+    return PagedKVPool({"attn0": PairEntry(2, 8), "attn1": PairEntry(2, 8)},
                        num_blocks=num_blocks, block_size=block_size,
                        max_blocks_per_request=max_blocks, **kw)
 

@@ -30,6 +30,7 @@ from flexflow_tpu.kernels import latent_attention  # noqa: E402
 from flexflow_tpu.models import LatentMoEConfig, build_latent_moe_lm  # noqa: E402
 from flexflow_tpu.serving.generation import (  # noqa: E402
     Generator, PagedDecoder, _ExecParamsCache)
+from flexflow_tpu.serving.cache_entry import LatentEntry  # noqa: E402
 from flexflow_tpu.serving.kv_cache import NULL_BLOCK, PagedKVPool  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark", "tests", "data", "configs",
@@ -373,7 +374,7 @@ def test_declared_weights_are_shapes_until_loaded():
 
 def test_int8_and_verify_over_a_latent_entry_refuse():
     with pytest.raises(ValueError, match="no int8 form"):
-        PagedKVPool({"attn": (24,)}, num_blocks=4, block_size=8,
+        PagedKVPool({"attn": LatentEntry(24)}, num_blocks=4, block_size=8,
                     max_blocks_per_request=2, kv_dtype="int8")
     ff, _ = _program(TOY)
     with pytest.raises(ValueError, match="no int8 form"):

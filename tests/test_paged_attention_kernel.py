@@ -11,7 +11,8 @@ import pytest
 import jax.numpy as jnp
 
 from flexflow_tpu.kernels import paged_attention
-from flexflow_tpu.serving.generation import _attn_with_paged_cache
+from flexflow_tpu.ops.attention import MultiHeadAttention
+from flexflow_tpu.serving.cache_entry import PairEntry
 from flexflow_tpu.serving.kv_cache import NULL_BLOCK
 
 HEAD_DIM, BLOCK, MAX_BLOCKS = 64, 16, 20
@@ -33,13 +34,14 @@ TOLERANCE = {"float32": 5e-6, "bfloat16": 2.0 ** -6}
 
 
 class _Op:
-    """The attention op's face as ``_attn_with_paged_cache`` sees it,
-    with identity projections so the test drives q, k and v directly."""
+    """The attention op's face as ``PairEntry.step`` sees it (the op's
+    own projections), with identity weights so the test drives q, k and
+    v directly."""
     use_bias = False
     head_dim = HEAD_DIM
-
-    def __init__(self, heads):
-        self.num_heads = heads
+    scale = MultiHeadAttention.scale
+    project_qkv = MultiHeadAttention.project_qkv
+    project_out = MultiHeadAttention.project_out
 
 
 def _case(heads, dtype, window, seed=0):
@@ -83,8 +85,8 @@ def _identity_weights(heads, dtype):
 def _run(monkeypatch, mode, heads, dtype, window):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
     x, entry, tables, lens = _case(heads, dtype, window)
-    out, new_entry = _attn_with_paged_cache(
-        _Op(heads), _identity_weights(heads, dtype), x, entry, tables, lens)
+    out, new_entry = PairEntry(heads, HEAD_DIM).step(
+        _Op(), _identity_weights(heads, dtype), x, None, entry, tables, lens)
     return np.asarray(out, np.float32), new_entry, np.asarray(lens)
 
 

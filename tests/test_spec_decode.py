@@ -34,6 +34,7 @@ from flexflow_tpu.serving import (ContinuousBatchingScheduler,
                                   DeadlineExceeded, InferenceEngine,
                                   PagedDecoder, PagedKVPool,
                                   build_draft_model)
+from flexflow_tpu.serving.cache_entry import LatentEntry, PairEntry
 from flexflow_tpu.sim import serving_kv_pool_bytes
 
 V = 50
@@ -319,7 +320,7 @@ def test_admission_doubles_at_fixed_pool_bytes():
     """The tentpole's capacity claim, as arithmetic: pick the largest
     int8 pool that fits the float32 pool's byte budget — it must admit
     >= 2x the worst-case requests."""
-    specs = {"a": (4, 8), "b": (4, 8)}
+    specs = {"a": PairEntry(4, 8), "b": PairEntry(4, 8)}
     bs, max_len = 8, 32
     n_f32 = 13
     budget = serving_kv_pool_bytes(specs, n_f32, bs, "float32")
@@ -347,14 +348,18 @@ def test_admission_doubles_at_fixed_pool_bytes():
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
 def test_pool_bytes_parity_with_sim(dtype):
-    """PagedKVPool.memory_bytes() and the sim's serving memory math
-    must agree byte-for-byte — the capacity planner prices admission
-    off the sim numbers."""
-    specs = {"l0": (4, 8), "l1": (2, 16)}
+    """PagedKVPool.memory_bytes() and the sim's serving memory math are
+    one arithmetic (the capacity planner prices admission off the sim
+    numbers), and it counts the bytes of the arenas the pool holds."""
+    specs = {"l0": PairEntry(4, 8), "l1": PairEntry(2, 16),
+             "l2": LatentEntry(24)}
+    if dtype == "int8":
+        del specs["l2"]             # a latent row has no int8 form
     pool = PagedKVPool(specs, num_blocks=9, block_size=8,
                        max_blocks_per_request=4, kv_dtype=dtype)
     assert pool.memory_bytes() == serving_kv_pool_bytes(
-        specs, 9, 8, dtype)
+        specs, 9, 8, dtype) == sum(a.nbytes for entry in pool.kv.values()
+                                   for a in entry)
     if dtype == "int8":
         # scale/zero sidecars included, still at most half of f32
         assert pool.memory_bytes() <= serving_kv_pool_bytes(
