@@ -287,8 +287,8 @@ def check_flash_attention(shape, causal: bool, dtype: str,
     from flexflow_tpu.kernels.flash_attention import flash_attention, supported
     from flexflow_tpu.parallel.ring_attention import single_device_attention
 
-    _require(supported(shape, shape, causal),
-             f"flash_attention.supported() refuses {shape}")
+    _require(supported(shape, shape, causal, dtype),
+             f"flash_attention.supported() refuses {shape} in {dtype}")
     b, s, h, d = shape
     rng = np.random.default_rng(0)
     q, k, v, w = (jnp.asarray(rng.normal(size=shape).astype(np.float32),
@@ -725,6 +725,13 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
     losses = [pm.sparse_cce_loss / max(1, pm.train_all) for pm in history]
     paths = sorted(p for p, v0 in paths0.items()
                    if reg.counter(f"attention.path.{p}").value > v0)
+    # no silent fall-back: on the chip, at this shape, the step's
+    # attention is the fused kernels (no (S, S) array in HBM), taken by
+    # the shapes alone — main() pops every variable that could force it
+    _require(paths == ["flash"] or jax.default_backend() != "tpu",
+             f"the train step's attention took the {'+'.join(paths)!r} "
+             f"path at sequence {sizes.seq}, {sizes.heads} heads of "
+             f"{sizes.hidden // sizes.heads}")
 
     _require(len(losses) == 2 and all(math.isfinite(x) for x in losses),
              f"epoch losses {losses}")
@@ -839,11 +846,10 @@ def main() -> int:
               f"{jax.default_backend()!r}, not 'tpu'; nothing was built",
               file=sys.stderr, flush=True)
         return 2
-    # the kernels phase is about Mosaic, and the train phase reads no
-    # tune cache: on the chip neither is up to the environment
-    for var in ("FLEXFLOW_TPU_PALLAS", "FLEXFLOW_FA_TUNE_CACHE",
-                "FLEXFLOW_FA_BLOCK_Q"):
-        os.environ.pop(var, None)
+    # the kernels phase is about Mosaic, and the train phase about which
+    # attention the shapes alone choose: on the chip neither is up to
+    # the environment
+    os.environ.pop("FLEXFLOW_TPU_PALLAS", None)
     # the tree may be a copy whose file times mean nothing: rebuild the
     # native library from native/src (run() says which one it got)
     from flexflow_tpu import native_bridge

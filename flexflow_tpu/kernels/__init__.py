@@ -4,9 +4,11 @@ The reference implements these as handwritten CUDA kernels (SURVEY.md §2.2:
 attention.cu, group_by.cu, aggregate.cu); here they are Pallas TPU kernels
 that keep the working set in VMEM and feed the MXU directly:
 
-* :mod:`flash_attention` — fused scaled-dot-product attention that never
-  materializes the (S, S) logits in HBM (reference: src/ops/attention.cu
-  uses cuDNN MultiHeadAttn for the same reason).
+* :mod:`flash_attention` — fused scaled-dot-product attention, forward
+  and backward, blocked over keys with a running softmax: no array with
+  two sequence axes reaches HBM (reference: src/ops/attention.cu uses
+  cuDNN MultiHeadAttn for the same reason). Taken by shape
+  (``engaged``), in training, eval and forward programs.
 * :mod:`paged_attention` — the paged decode step's attention over a
   ``PagedKVPool`` arena read in place: block tables by scalar prefetch,
   a slot's live blocks only, all heads of a chunk in one matrix product
@@ -47,9 +49,9 @@ def pallas_mode() -> str | None:
 def pallas_forced() -> bool:
     """True when the operator EXPLICITLY forced compiled kernels on
     (``FLEXFLOW_TPU_PALLAS=compiled``) — as opposed to ``pallas_mode()``
-    returning "compiled" merely because the backend is a TPU. The flash
-    win-or-off policy needs the distinction; the env contract lives here
-    so it is parsed in one module."""
+    returning "compiled" merely because the backend is a TPU. Flash
+    attention's rule over shapes (``flash_attention.engaged``) yields to
+    it; the env contract lives here so it is parsed in one module."""
     return os.environ.get("FLEXFLOW_TPU_PALLAS") == "compiled"
 
 

@@ -1,35 +1,60 @@
-"""Fused (flash-style) attention as a Pallas TPU kernel.
+"""Fused (flash-style) attention as Pallas TPU kernels, forward and backward.
 
 Replaces the reference's cuDNN MultiHeadAttn device path
-(reference: src/ops/attention.cu:35-128) with a TPU kernel that tiles
-queries into ``block_q`` rows, holds K/V for one (batch, head) in VMEM, and
-computes softmax(QKᵀ)V per tile without ever writing the (S, S) logits to
-HBM. The backward pass is the standard two-kernel flash recomputation
-(dq over q-tiles; dk/dv over k-tiles) using the saved log-sum-exp.
+(reference: src/ops/attention.cu:35-128). No array with two sequence
+axes is written to HBM: every kernel walks (query block, key block)
+tiles of the scores, and what crosses a tile's edge is a running
+softmax (forward) or the saved log-sum-exp (backward).
 
-Layout: public entry takes (B, S, H, D) — the framework's bshd convention
-(ops/attention.py) — and transposes to (B*H, S, D) for the kernel grid.
-Compute is float32 on the MXU regardless of input dtype; outputs are cast
-back.
+Layout. The kernels take q, k, v as ``(B, S, H*D)``: what a projection
+``(B, S, E) x (E, H*D)`` writes and what the output projection reads,
+so nothing is transposed through HBM on either side. A grid step owns
+one *lane tile* of that last axis: ``W = max(128, D)`` lanes, which is
+``G = W // D`` heads side by side (two heads of 64). A head's products
+are taken over the whole tile with the other heads' lanes of K and V
+zeroed: the MXU contracts over 128 lanes either way, so a head of 64
+costs what it would alone, and q, o and the gradients stay whole tiles.
 
-VMEM: each kernel holds one whole (S, D) panel per full operand plus
-(block, S) float32 logits temporaries; :func:`_vmem_bytes` counts that
-working set the way the compiler allocates it (pipeline double buffers,
-float32 copies, lane padding) and :func:`supported` refuses what does not
-fit, so longer sequences take the jnp path or ring attention
-(parallel/ring_attention.py) instead of failing in Mosaic.
+Every tile is computed *transposed*: keys on sublanes, queries on
+lanes. What a softmax keeps for a query (running maximum, sum,
+log-sum-exp, ``delta``) is then a lane-dense (1, block_q) row that
+broadcasts along sublanes, and a reduction over keys is elementwise
+over vregs with one short sublane reduce at its end: no cross-lane
+reduction and no (block_q, 1) column anywhere (a first version with
+queries on sublanes spent more on those than on its products: PERF.md
+section 6, PR 31). The three kernels, by their ``pallas_call`` names:
 
-The log-sum-exp travels as (B*H, S, 1): a (block_q, 1) column is what
-the row reductions produce and what the backward broadcasts against, so
-no kernel moves data between sublanes and lanes, and a block's last dim
-is the array's full last dim whatever ``block_q`` is.
+* ``flash_attention_fwd`` — grid (B, H/G, query blocks, key blocks), the
+  key axis innermost; m, l and the transposed output accumulator
+  (W, block_q) live in VMEM scratch across a query block's key blocks
+  (the running softmax), so VMEM does not grow with the sequence. The
+  accumulator is transposed back once a query block.
+* ``flash_attention_dq`` — the same grid; dQ^T accumulates over key
+  blocks.
+* ``flash_attention_dkv`` — grid (B, H/G, key blocks, query blocks), the
+  query axis innermost; dK and dV accumulate over query blocks, already
+  the right way round.
+
+Precision: MXU operands in the dtype the op was given, float32
+accumulation; scores, row maximum, sum, log-sum-exp and
+``delta = rowsum(dO * O)`` in float32; P and dS are rounded to the
+operand dtype only as they enter a product.
+
+Causal attention (top-left aligned, as ``single_device_attention``'s
+``tril``) skips the key blocks above the diagonal: the body under
+``pl.when``, and the DMA by clamping the block index to the last block
+needed (an unchanged index is not fetched again). Only blocks the
+diagonal crosses pay for the mask.
+
+The log-sum-exp and ``delta`` travel as ``(B, H, 1, S)`` float32: a
+lane-dense row a head, as the kernels use them.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,14 +72,16 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/max() NaN-free
 # for what the count cannot see (compiler temporaries, relayouts).
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 VMEM_BUDGET_BYTES = 48 * 1024 * 1024
-_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
-
-def _causal_mask(block_q: int, skv: int, q_offset):
-    qpos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (block_q, skv), 0)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (block_q, skv), 1)
-    return qpos >= kpos
-
+LANES = 128
+# block sizes tried in this order (the first that divides the sequence);
+# the chip's measurements behind the order: PERF.md section 6, PR 31
+BLOCKS = (512, 256, 128)
+# sequences from which the kernels beat XLA's softmax(QK^T)V forward and
+# backward on a v5e (bf16, batch x heads 64, d 64 and 128: PERF.md
+# section 6, PR 31); below it the (S, S) tile is small enough that XLA's
+# fusions win and the `xla` path stays
+MIN_SEQ = 1024
 
 # dot_general dimension numbers for a @ b.T and a.T @ b: the MXU takes
 # either operand transposed, so no kernel materializes a transpose
@@ -67,424 +94,451 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale           # (block_q, D)
-    k = k_ref[0].astype(jnp.float32)                   # (Skv, D)
-    v = v_ref[0].astype(jnp.float32)
-    s = _dot(q, k, _NT)                                # (block_q, Skv)
-    if causal:
-        s = jnp.where(_causal_mask(block_q, k.shape[0], qi * block_q), s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o_ref[0] = (_dot(p, v) / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)                        # (block_q, 1)
+# -- a lane tile's heads ------------------------------------------------------
 
-
-def _dq_kernel(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, dq_ref,
-               *, scale, causal, block_q):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    g = g_ref[0].astype(jnp.float32)
-    o = o_ref[0].astype(jnp.float32)
-    s = _dot(q, k, _NT)
-    if causal:
-        s = jnp.where(_causal_mask(block_q, k.shape[0], qi * block_q), s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0])                         # softmax probabilities
-    dp = _dot(g, v, _NT)
-    delta = jnp.sum(g * o, axis=-1, keepdims=True)      # rowsum(dO ∘ O)
-    ds = p * (dp - delta)
-    dq_ref[0] = (_dot(ds, k) * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, g_ref, lse_ref, dk_ref, dv_ref,
-                *, scale, causal, block_k):
-    ki = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale            # (Sq, D)
-    k = k_ref[0].astype(jnp.float32)                    # (block_k, D)
-    v = v_ref[0].astype(jnp.float32)
-    g = g_ref[0].astype(jnp.float32)
-    o = o_ref[0].astype(jnp.float32)
-    s = _dot(q, k, _NT)                                 # (Sq, block_k)
-    if causal:
-        sq = q.shape[0]
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (sq, block_k), 0)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (sq, block_k), 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0])                         # lse: (Sq, 1)
-    dv_ref[0] = _dot(p, g, _TN).astype(dv_ref.dtype)
-    dp = _dot(g, v, _NT)
-    delta = jnp.sum(g * o, axis=-1, keepdims=True)
-    ds = p * (dp - delta)
-    dk_ref[0] = _dot(ds, q, _TN).astype(dk_ref.dtype)   # q already carries `scale`
-
-
-def _pick_block(s: int, pref: int) -> Optional[int]:
-    for b in (pref, 256, 128, 64, 32, 16, 8):
-        if b <= s and s % b == 0:
-            return b
+def _tile_width(heads: int, d: int) -> Optional[int]:
+    """Lanes a grid step owns of the (B, S, H*D) operands: whole heads,
+    whole 128-lane tiles, or the whole axis. None where no such width
+    exists (a head that straddles tiles)."""
+    if d % LANES == 0:
+        return d
+    if LANES % d == 0 and heads % (LANES // d) == 0:
+        return LANES
+    if heads * d < LANES:
+        return heads * d
     return None
 
 
-# -- block-size tuning --------------------------------------------------------
-# Round-2 measurement on a real v5e showed the default tile a hair SLOWER
-# than XLA's fused attention at the bench shape; the right block_q depends
-# on seq/head_dim and the chip. Resolution order: the FLEXFLOW_FA_BLOCK_Q
-# env override, then a per-shape autotune cache (populated by autotune(),
-# persisted to FLEXFLOW_FA_TUNE_CACHE if set), then 128.
-_TUNE_CACHE: dict = {}
-_CACHE_FILE_LOADED: Optional[str] = None  # path last loaded successfully
+def _head_masks(width: int, d: int, axis: int):
+    """For each head of a tile, the mask of its lanes (``axis`` 1: a
+    (1, width) mask) or, for a transposed (width, n) array, of its rows
+    (``axis`` 0: (width, 1)); None for a head that fills the tile."""
+    if width == d:
+        return [None]
+    at = jax.lax.broadcasted_iota(
+        jnp.int32, (1, width) if axis else (width, 1), axis)
+    return [(at >= c * d) & (at < (c + 1) * d) for c in range(width // d)]
 
 
-def _ensure_cache_loaded() -> None:
-    """Load FLEXFLOW_FA_TUNE_CACHE into the process cache once per path:
-    a missing file retries (it may appear later), a present-but-bad file
-    does not (one parse attempt, not one per attention call). A path
-    CHANGE drops the previous file's winners first — they were tuned for
-    something else."""
-    import os
-
-    global _CACHE_FILE_LOADED
-    path = os.environ.get("FLEXFLOW_FA_TUNE_CACHE")
-    if path and _CACHE_FILE_LOADED != path and os.path.exists(path):
-        _TUNE_CACHE.clear()
-        try:
-            load_tune_cache(path)
-        except (OSError, ValueError):
-            pass
-        _CACHE_FILE_LOADED = path
+def _only(lanes, x):
+    """``x`` with the other heads' lanes zeroed."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
 
 
-def tune_entry(sq: int, skv: int, d: int,
-               causal: bool = False) -> Optional[dict]:
-    """Public accessor for one tune-cache record
-    (``{"block_q": int, "xla_ratio": float|None}``), loading the
-    persisted cache first. The key/entry format is private to this
-    module — consumers (bench.py) must come through here."""
-    _ensure_cache_loaded()
-    return _TUNE_CACHE.get((sq, skv, d, bool(causal)))
-
-
-def default_block_q(sq: int, skv: int, d: int,
-                    causal: bool = False) -> int:
-    import os
-
-    env = os.environ.get("FLEXFLOW_FA_BLOCK_Q")
-    if env:
-        try:
-            v = int(env)
-        except ValueError as e:
-            raise ValueError(
-                f"FLEXFLOW_FA_BLOCK_Q={env!r} is not an integer") from e
-        if v < 8 or v % 8 != 0:
-            raise ValueError(
-                f"FLEXFLOW_FA_BLOCK_Q={v} must be a positive multiple of 8")
-        return v
-    entry = tune_entry(sq, skv, d, causal)
-    return entry["block_q"] if entry else 128
-
-
-def proven(sq: int, skv: int, d: int, causal: bool = False) -> bool:
-    """True iff a recorded autotune shows the kernel MATCHING OR BEATING
-    XLA's fused attention at this shape (``xla_ratio >= 1.0``)."""
-    entry = tune_entry(sq, skv, d, causal)
-    return bool(entry) and (entry.get("xla_ratio") or 0.0) >= 1.0
-
-
-def engaged(sq: int, skv: int, d: int, causal: bool = False) -> bool:
-    """Dispatch policy for the flash kernel (win-or-off, round 5): the
-    only measured comparison (round 2, real v5e) had the kernel at 0.98x
-    vs XLA's fused attention — losing to the thing it exists to beat —
-    so on the default ``auto`` setting the kernel engages ONLY at shapes
-    where a recorded autotune proves a >=1.0x ratio (``proven``).
-    ``FLEXFLOW_TPU_PALLAS=compiled`` forces it on everywhere (autotune /
-    benchmarking); ``interpret`` keeps engaging it for numerics tests;
-    ``off`` wins over everything. Rationale: PARITY.md §flash-attention."""
-    from . import pallas_forced
-
-    mode = pallas_mode()
-    if mode is None:
-        return False
-    if mode == "interpret":
-        return True
-    if pallas_forced():
-        return True  # explicitly forced, not auto-on-TPU
-    return proven(sq, skv, d, causal)
-
-
-def autotune(shape=(4, 512, 8, 64), candidates=(64, 128, 256, 512),
-             causal: bool = False, iters: int = 10,
-             cache_path: Optional[str] = None) -> dict:
-    """Time the forward kernel per candidate block_q on the CURRENT
-    backend and remember the winner for this (seq, seq, head_dim).
-
-    Run once on real hardware (tests_tpu/ has a gated smoke); results are
-    process-cached and optionally persisted as JSON. Returns
-    {block_q: seconds} for inspection."""
-    import json
-    import os
-    import time
-
-    import numpy as np
-
-    from . import pallas_forced
-
-    b, s, h, d = shape
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(b * h, s, d)).astype(np.float32))
-    interpret = pallas_mode() == "interpret"
-    results = {}
-    for cand in candidates:
-        bq = _pick_block(s, cand)
-        if bq != cand:
-            continue  # shape can't tile at this size
-        # VMEM gate shared with supported(): don't let one oversized
-        # candidate's Mosaic failure discard the other timings
-        if _vmem_bytes(s, s, cand, cand, d) > VMEM_BUDGET_BYTES:
-            continue
-        fn = jax.jit(functools.partial(
-            _flash, causal=causal, scale=d ** -0.5, block_q=cand,
-            interpret=interpret))
-        try:
-            out = fn(q, q, q)
-            jax.block_until_ready(out)
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = fn(q, q, q)
-            jax.block_until_ready(out)
-        except Exception:  # compile/alloc failure: skip this candidate
-            if pallas_forced():
-                raise  # forced Mosaic: a refusal is the finding, not a skip
-            continue
-        results[cand] = (time.perf_counter() - t0) / iters
-    if results:
-        best = min(results, key=results.get)
-        # time XLA's own fused attention at the same shape: the engage
-        # policy (``engaged``) only turns the kernel on where this ratio
-        # proves a win (>= 1.0). This measurement DECIDES dispatch, so
-        # both sides use the median of 3 windows — a single transient
-        # stall must not persist a wrong on/off decision into the cache
-        xla_ratio = None
-        scale = d ** -0.5
-
-        def _median_time(fn, arg) -> float:
-            out = fn(arg, arg, arg)
-            jax.block_until_ready(out)  # warmup/compile
-            windows = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                for _ in range(iters):
-                    out = fn(arg, arg, arg)
-                jax.block_until_ready(out)
-                windows.append((time.perf_counter() - t0) / iters)
-            return sorted(windows)[1]
-
-        try:
-            # the baseline is the EXACT implementation dispatch falls
-            # back to when the kernel is off (ops/attention.py →
-            # single_device_attention), on its own (b, s, h, d) layout —
-            # not a re-derivation that XLA might compile differently.
-            # BOTH sides time the full (B, S, H, D) entry: the kernel
-            # side goes through the public flash_attention so the
-            # bshd↔(B*H,S,D) transposes the production dispatch pays are
-            # inside the measured ratio — a kernel that wins only on the
-            # pre-transposed layout must not record a >=1.0 and engage
-            from ..parallel.ring_attention import single_device_attention
-
-            q4 = jnp.asarray(np.random.default_rng(0).normal(
-                size=(b, s, h, d)).astype(np.float32))
-            best_fn = jax.jit(functools.partial(
-                flash_attention, causal=causal, scale=scale,
-                block_q=best))
-            t_kernel = _median_time(best_fn, q4)
-            ref_fn = jax.jit(lambda q_, k_, v_: single_device_attention(
-                q_, k_, v_, causal, scale))
-            t_xla = _median_time(ref_fn, q4)
-            xla_ratio = round(t_xla / t_kernel, 4)
-        except Exception:
-            if pallas_forced():
-                raise
-        _TUNE_CACHE[(s, s, d, bool(causal))] = {
-            "block_q": best, "xla_ratio": xla_ratio}
-        path = cache_path or os.environ.get("FLEXFLOW_FA_TUNE_CACHE")
-        # multi-host: only process 0 persists (all processes tuned the
-        # same shapes); write-temp + os.replace keeps readers from ever
-        # seeing a truncated file
-        if path and jax.process_index() == 0:
-            try:
-                import fcntl
-
-                # lock the read-merge-replace so two processes tuning
-                # different shapes can't lose each other's entries
-                # (same pattern as native_bridge._build)
-                with open(f"{path}.lock", "w") as lk:
-                    fcntl.flock(lk, fcntl.LOCK_EX)
-                    data = {}
-                    if os.path.exists(path):
-                        with open(path) as f:
-                            data = json.load(f)
-                    data[f"{s}x{s}x{d}x{int(bool(causal))}"] = {
-                        "block_q": best, "xla_ratio": xla_ratio}
-                    tmp = f"{path}.tmp.{os.getpid()}"
-                    with open(tmp, "w") as f:
-                        json.dump(data, f)
-                    os.replace(tmp, path)
-            except (OSError, ValueError):  # incl. a corrupt existing file
-                pass
-    return results
-
-
-def load_tune_cache(path: str) -> int:
-    """Load a persisted autotune cache; returns entries loaded."""
-    import json
-
-    with open(path) as f:
-        data = json.load(f)
-    n = 0
-    for k, v in data.items():
-        parts = [int(x) for x in k.split("x")]
-        if len(parts) == 3:  # pre-causal-key format
-            parts.append(0)
-        s1, s2, d, c = parts
-        if isinstance(v, dict):
-            entry = {"block_q": int(v["block_q"]),
-                     "xla_ratio": v.get("xla_ratio")}
-        else:  # legacy bare-int format: block size only, no win evidence
-            entry = {"block_q": int(v), "xla_ratio": None}
-        _TUNE_CACHE[(s1, s2, d, bool(c))] = entry
-        n += 1
-    return n
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, interpret):
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, interpret)
+def _spread(rows, masks):
+    """Per-head (1, n) rows -> one (width, n) array, each head's rows
+    (``masks``: :func:`_head_masks` over axis 0) holding its own."""
+    out = rows[0]
+    for row, m in zip(rows[1:], masks[1:]):
+        out = jnp.where(m, row, out)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, interpret):
-    bh, sq, d = q.shape
-    skv = k.shape[1]
-    grid = (bh, sq // block_q)
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
-    kvspec = pl.BlockSpec((1, skv, d), lambda b, i: (b, 0, 0))
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, block_q=block_q),
-        grid=grid,
-        in_specs=[qspec, kvspec, kvspec],
-        out_specs=[qspec, pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-        ],
+def _prescale(q, scale: float):
+    """``(q * scale, 1.0)`` where the product is exact in q's dtype (a
+    power of two: heads of 16, 64, 256), so the scores need no pass of
+    their own; else ``(q, scale)`` and the caller scales the float32
+    scores, because rounding ``q * scale`` would be a second rounding
+    of an operand."""
+    if math.frexp(scale)[0] == 0.5:
+        return q * jnp.asarray(scale, q.dtype), 1.0
+    return q, scale
+
+
+def _keep(keys: int, queries: int, q0, k0):
+    """Causal mask of a (keys, queries) tile whose first query is ``q0``
+    and first key ``k0``: True where the query sees the key."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (keys, queries), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (keys, queries), 1)
+    return q0 + c >= k0 + r
+
+
+def _causal_tiles(causal: bool, q0, k0, block_q: int, block_k: int, tile):
+    """Run ``tile(masked, keys, queries)`` over the block at (q0, k0),
+    ``keys`` and ``queries`` the slices of the block it covers: not at
+    all where no query sees a key, unmasked where every query sees every
+    key. With equal blocks the one block the diagonal crosses is the
+    aligned one, whose quarter of late keys and early queries is all
+    masked: it runs as the early keys against every query and the late
+    keys against the late queries, three quarters of the work."""
+    whole = (slice(0, block_k), slice(0, block_q))
+    if not causal:
+        tile(False, *whole)
+        return
+    needed = k0 <= q0 + block_q - 1
+    full = k0 + block_k - 1 <= q0
+    pl.when(needed & full)(lambda: tile(False, *whole))
+
+    @pl.when(needed & jnp.logical_not(full))
+    def _():
+        half = block_q // 2
+        if block_q == block_k and half % 8 == 0 and (
+                half % LANES == 0 or pallas_mode() == "interpret"):
+            tile(True, slice(0, half), slice(0, block_q))
+            tile(True, slice(half, block_k), slice(half, block_q))
+        else:
+            tile(True, *whole)
+
+
+def _size(sl: slice) -> int:
+    return sl.stop - sl.start
+
+
+# -- kernels ------------------------------------------------------------------
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                *, scale, causal, d, block_q, block_k):
+    i, kk, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    width = q_ref.shape[-1]
+    lanes, rows = _head_masks(width, d, 1), _head_masks(width, d, 0)
+
+    @pl.when(kk == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def tile(masked, ks, qs):
+        k, v = k_ref[0, ks], v_ref[0, ks]
+        q, post = _prescale(q_ref[0, qs], scale)
+        if masked:
+            keep = _keep(_size(ks), _size(qs), i * block_q + qs.start,
+                         kk * block_k + ks.start)
+        alphas, pv = [], None
+        for c, mine in enumerate(lanes):
+            st = _dot(_only(mine, k), q, _NT)              # (keys, queries)
+            if post != 1.0:
+                st = st * post
+            if masked:
+                st = jnp.where(keep, st, NEG_INF)
+            m_prev = m_ref[c, :, qs]                       # (1, queries)
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            pt = jnp.exp(st - m_new)
+            l_ref[c, :, qs] = (alpha * l_ref[c, :, qs]
+                               + jnp.sum(pt, axis=0, keepdims=True))
+            m_ref[c, :, qs] = m_new
+            x = _dot(_only(mine, v), pt.astype(v.dtype), _TN)  # (width, queries)
+            pv = x if pv is None else pv + x               # this head's rows
+            alphas.append(alpha)
+        acc_ref[:, qs] = acc_ref[:, qs] * _spread(alphas, rows) + pv
+
+    _causal_tiles(causal, i * block_q, kk * block_k, block_q, block_k, tile)
+
+    @pl.when(kk == nk - 1)
+    def _():
+        ls = [l_ref[c] for c in range(len(lanes))]
+        o_ref[0] = (acc_ref[...] / _spread(ls, rows)).T.astype(o_ref.dtype)
+        for c, l in enumerate(ls):
+            lse_ref[0, c] = m_ref[c] + jnp.log(l)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+               acc_ref, *, scale, causal, d, block_q, block_k):
+    i, kk, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    lanes = _head_masks(q_ref.shape[-1], d, 1)
+
+    @pl.when(kk == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def tile(masked, ks, qs):
+        k, v, g = k_ref[0, ks], v_ref[0, ks], g_ref[0, qs]
+        q, post = _prescale(q_ref[0, qs], scale)
+        if masked:
+            keep = _keep(_size(ks), _size(qs), i * block_q + qs.start,
+                         kk * block_k + ks.start)
+        dq = None
+        for c, mine in enumerate(lanes):
+            kc = _only(mine, k)
+            st = _dot(kc, q, _NT)                          # (keys, queries)
+            if post != 1.0:
+                st = st * post
+            if masked:
+                st = jnp.where(keep, st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, c, :, qs])
+            dpt = _dot(_only(mine, v), g, _NT)
+            dst = pt * (dpt - delta_ref[0, c, :, qs])
+            x = _dot(kc, dst.astype(k.dtype), _TN)         # (width, queries)
+            dq = x if dq is None else dq + x               # this head's rows
+        acc_ref[:, qs] += dq
+
+    _causal_tiles(causal, i * block_q, kk * block_k, block_q, block_k, tile)
+
+    @pl.when(kk == nk - 1)
+    def _():
+        dq_ref[0] = (acc_ref[...] * scale).T.astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_acc, dv_acc,
+                *, scale, causal, d, block_q, block_k):
+    kk, i, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    lanes = _head_masks(q_ref.shape[-1], d, 1)
+
+    @pl.when(i == 0)
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    def tile(masked, ks, qs):
+        q, g, k, v = q_ref[0, qs], g_ref[0, qs], k_ref[0, ks], v_ref[0, ks]
+        q_scaled, post = _prescale(q, scale)
+        if masked:
+            keep = _keep(_size(ks), _size(qs), i * block_q + qs.start,
+                         kk * block_k + ks.start)
+        dk = dv = None
+        for c, mine in enumerate(lanes):
+            st = _dot(_only(mine, k), q_scaled, _NT)       # (keys, queries)
+            if post != 1.0:
+                st = st * post
+            if masked:
+                st = jnp.where(keep, st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, c, :, qs])        # rows broadcast
+            dpt = _dot(_only(mine, v), g, _NT)
+            dst = pt * (dpt - delta_ref[0, c, :, qs])
+            xv = _dot(pt.astype(g.dtype), g)               # every head's lanes
+            xk = _dot(dst.astype(q.dtype), q)
+            # head 0's product fills the tile; each later head takes its lanes
+            dv = xv if dv is None else jnp.where(mine, xv, dv)
+            dk = xk if dk is None else jnp.where(mine, xk, dk)
+        dv_acc[ks] += dv
+        dk_acc[ks] += dk
+
+    _causal_tiles(causal, i * block_q, kk * block_k, block_q, block_k, tile)
+
+    @pl.when(i == nq - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# -- the calls ----------------------------------------------------------------
+
+# batch, lane tile and the outer block axis are independent; the inner
+# block axis carries the accumulators
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _last_key_block(i, block_q: int, block_k: int, nk: int):
+    """The last key block a causal query block ``i`` needs."""
+    return jnp.minimum(((i + 1) * block_q - 1) // block_k, nk - 1)
+
+
+def _first_query_block(kk, block_q: int, block_k: int, nq: int):
+    """The first query block that sees causal key block ``kk``."""
+    return jnp.minimum((kk * block_k) // block_q, nq - 1)
+
+
+def _specs(causal, width, heads_per_tile, block_q, block_k, nq, nk,
+           queries_inner: bool):
+    """Block specs of a query-side operand, a key-side operand and a
+    statistics row. Grid (b, j, i, kk), or (b, j, kk, i) with
+    ``queries_inner``; the inner index is clamped to the blocks a causal
+    tile row (or column) needs, so a skipped step fetches nothing."""
+    if queries_inner:
+        def qi(kk, i):
+            return (jnp.maximum(i, _first_query_block(kk, block_q, block_k,
+                                                      nq)) if causal else i)
+
+        qmap = lambda b, j, kk, i: (b, qi(kk, i), j)
+        kmap = lambda b, j, kk, i: (b, kk, j)
+        smap = lambda b, j, kk, i: (b, j, 0, qi(kk, i))
+    else:
+        def ki(i, kk):
+            return (jnp.minimum(kk, _last_key_block(i, block_q, block_k, nk))
+                    if causal else kk)
+
+        qmap = lambda b, j, i, kk: (b, i, j)
+        kmap = lambda b, j, i, kk: (b, ki(i, kk), j)
+        smap = lambda b, j, i, kk: (b, j, 0, i)
+    return (pl.BlockSpec((1, block_q, width), qmap),
+            pl.BlockSpec((1, block_k, width), kmap),
+            pl.BlockSpec((1, heads_per_tile, 1, block_q), smap))
+
+
+# The calls are jitted on their own: a model has one attention a layer,
+# all alike, and an inner jit is traced once and lowered to one function
+# that every layer calls. Left inline, each layer's three kernels were
+# traced and lowered to Mosaic again in every program that holds them,
+# which no compile cache spares (its key is the lowered text): 20 s a
+# program of 24 layers on the chip's host (PERF.md section 6, PR 31).
+_STATIC = ("heads", "causal", "scale", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _forward(q, k, v, *, heads, causal, scale, block_q, block_k, interpret):
+    """-> (out (B, Sq, H*D), lse (B, H, 1, Sq))."""
+    b, sq, f = q.shape
+    skv, d = k.shape[1], f // heads
+    width = _tile_width(heads, d)
+    g, nq, nk = width // d, sq // block_q, skv // block_k
+    qspec, kspec, sspec = _specs(causal, width, g, block_q, block_k, nq, nk,
+                                 queries_inner=False)
+    row = pltpu.VMEM((g, 1, block_q), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, d=d,
+                          block_q=block_q, block_k=block_k),
+        grid=(b, f // width, nq, nk),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, sspec],
+        out_shape=[jax.ShapeDtypeStruct((b, sq, f), q.dtype),
+                   jax.ShapeDtypeStruct((b, heads, 1, sq), jnp.float32)],
+        scratch_shapes=[row, row,
+                        pltpu.VMEM((width, block_q), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_attention_fwd",
     )(q, k, v)
-    return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, interpret, res, g):
-    q, k, v, out, lse = res
-    bh, sq, d = q.shape
-    skv = k.shape[1]
-    block_k = _pick_block(skv, block_q)
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
-    kvfull = pl.BlockSpec((1, skv, d), lambda b, i: (b, 0, 0))
-    lspec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _backward(q, k, v, out, lse, g_out, *, heads, causal, scale, block_q,
+              block_k, interpret):
+    """-> (dq, dk, dv)."""
+    b, sq, f = q.shape
+    skv, d = k.shape[1], f // heads
+    width = _tile_width(heads, d)
+    g, nq, nk = width // d, sq // block_q, skv // block_k
+    # delta = rowsum(dO * O), once, as lane-dense rows like the lse
+    delta = jnp.sum((g_out.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(b, sq, heads, d), axis=-1)
+    delta = delta.transpose(0, 2, 1)[:, :, None, :]
+    kw = dict(scale=scale, causal=causal, d=d, block_q=block_q,
+              block_k=block_k)
+    qspec, kspec, sspec = _specs(causal, width, g, block_q, block_k, nq, nk,
+                                 queries_inner=False)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, block_q=block_q),
-        grid=(bh, sq // block_q),
-        in_specs=[qspec, kvfull, kvfull, qspec, qspec, lspec],
+        functools.partial(_dq_kernel, **kw),
+        grid=(b, f // width, nq, nk),
+        in_specs=[qspec, kspec, kspec, qspec, sspec, sspec],
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, sq, f), q.dtype),
+        scratch_shapes=[pltpu.VMEM((width, block_q), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_attention_dq",
-    )(q, k, v, out, g, lse)
-    qfull = pl.BlockSpec((1, sq, d), lambda b, i: (b, 0, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))
-    lfull = pl.BlockSpec((1, sq, 1), lambda b, i: (b, 0, 0))
+    )(q, k, v, g_out, lse, delta)
+    qspec, kspec, sspec = _specs(causal, width, g, block_q, block_k, nq, nk,
+                                 queries_inner=True)
+    acc = pltpu.VMEM((block_k, width), jnp.float32)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal, block_k=block_k),
-        grid=(bh, skv // block_k),
-        in_specs=[qfull, kspec, kspec, qfull, qfull, lfull],
+        functools.partial(_dkv_kernel, **kw),
+        grid=(b, f // width, nk, nq),
+        in_specs=[qspec, kspec, kspec, qspec, sspec, sspec],
         out_specs=[kspec, kspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, skv, d), v.dtype),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((b, skv, f), k.dtype),
+                   jax.ShapeDtypeStruct((b, skv, f), v.dtype)],
+        scratch_shapes=[acc, acc],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_attention_dkv",
-    )(q, k, v, out, g, lse)
+    )(q, k, v, g_out, lse, delta)
     return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, static):
+    return _forward(q, k, v, **dict(static))[0]
+
+
+def _flash_fwd(q, k, v, static):
+    out, lse = _forward(q, k, v, **dict(static))
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd(static, res, g_out):
+    return _backward(*res, g_out, **dict(static))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _vmem_bytes(sq: int, skv: int, block_q: int, block_k: int, d: int) -> int:
+# -- who takes the kernels, and with what blocks ------------------------------
+
+def _pick_block(s: int) -> Optional[int]:
+    """The first of :data:`BLOCKS` that divides ``s``. Under the
+    interpreter, which has no tiles to respect, smaller ones too and at
+    last the whole sequence."""
+    prefs = BLOCKS
+    if pallas_mode() == "interpret":
+        prefs += (64, 32, 16, 8, s)
+    for blk in prefs:
+        if blk <= s and s % blk == 0:
+            return blk
+    return None
+
+
+def _vmem_bytes(block_q: int, block_k: int, width: int, d: int) -> int:
     """Largest VMEM working set of the three kernels for float32 inputs
-    (the widest), counted the way it is allocated: every input and output
-    block twice (the Pallas pipeline double-buffers them), a float32 copy
-    of every loaded operand, the (rows, cols) float32 temporaries of the
-    softmax recomputation (s, p, and in the backward dp, ds), all with
-    the last dim padded to 128 lanes — so one log-sum-exp element costs a
-    whole 512-byte lane row. Shared by supported() and autotune()."""
-    def lanes(n):
-        return -(-n // 128) * 128
-
-    dd = lanes(d)
-    lse_row = 4 * 128
-    fwd = (2 * 4 * (2 * block_q + 2 * skv) * dd            # q, o; k, v panels
-           + 2 * block_q * lse_row
-           + 4 * (block_q + 2 * skv) * dd                  # f32 q, k, v
-           + 4 * 3 * block_q * lanes(skv))                 # s, p, mask
-    dq = (2 * 4 * (4 * block_q + 2 * skv) * dd             # q, o, g, dq; k, v
-          + 2 * block_q * lse_row
-          + 4 * (3 * block_q + 2 * skv) * dd
-          + 4 * 5 * block_q * lanes(skv))                  # s, p, dp, ds, mask
-    dkv = (2 * 4 * (3 * sq + 4 * block_k) * dd             # q, o, g; k, v, dk, dv
-           + 2 * sq * lse_row
-           + 4 * (3 * sq + 2 * block_k) * dd
-           + 4 * 5 * sq * lanes(block_k))
-    return max(fwd, dq, dkv)
+    (the widest), counted the way it is allocated: every input and
+    output block twice (the Pallas pipeline double-buffers them), the
+    float32 accumulators, the statistics rows (a sublane tile each), the
+    zeroed copies of K and V a head takes, and the (block_k, block_q)
+    float32 temporaries of one tile (s, p, dp, ds, the mask) for each
+    head of the lane tile. It does not grow with the sequence."""
+    w = -(-width // LANES) * LANES
+    blocks = 2 * 4 * w * (3 * block_q + 4 * block_k)    # q, g, dq; k, v, dk, dv
+    acc = 4 * w * (block_q + 2 * block_k)
+    stats = 4 * 8 * block_q * (2 * 2 + 2) * (width // d)
+    copies = 4 * w * 4 * max(block_q, block_k)
+    tiles = 4 * 5 * (width // d) * block_q * block_k
+    return blocks + acc + stats + copies + tiles
 
 
-def supported(q_shape, k_shape, causal: bool = False) -> bool:
-    """Whether the kernel path handles these (B, S, H, D) shapes.
-
-    Checks that the sequence lengths tile (blocks are multiples of 16
-    rows — the bf16 sublane tile — or the whole sequence) and that the
-    working set :func:`_vmem_bytes` counts fits the budget; longer
-    sequences fall back to the
-    jnp path / ring attention rather than failing at Mosaic compile.
-    Budgets with the SAME block the kernel will resolve (env/tuned/128) —
-    a tuned 512 tile must not pass a gate computed for 128.
-    """
+def supported(q_shape, k_shape, causal: bool = False, dtype=None) -> bool:
+    """Whether the kernels take these (B, S, H, D) shapes: a float32 or
+    bfloat16 operand, heads that fill whole lane tiles (128 % d == 0
+    with an even split of the heads, or d a multiple of 128), sequences
+    a block of :data:`BLOCKS` divides, and a working set inside the VMEM
+    budget. Callers take ``single_device_attention`` (or ring attention)
+    otherwise. Independent of the sequence length: no operand is held
+    whole."""
+    del causal  # the same tiles either way
     if pallas_mode() is None:
         return False
-    sq, skv = q_shape[1], k_shape[1]
-    d = q_shape[3]
-    try:
-        pref = default_block_q(sq, skv, d, causal)
-    except ValueError:
-        return False  # malformed env override: fall back to the jnp path
-    bq = _pick_block(sq, pref)
-    bk = _pick_block(skv, pref)
+    if dtype is not None and jnp.dtype(dtype) not in (
+            jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False
+    (_, sq, heads, d), skv = q_shape, k_shape[1]
+    width = _tile_width(heads, d)
+    if width is None:
+        return False
+    if pallas_mode() != "interpret" and width % LANES:
+        return False
+    bq, bk = _pick_block(sq), _pick_block(skv)
     if bq is None or bk is None:
         return False
-    if (bq % 16 and bq != sq) or (bk % 16 and bk != skv):
+    return _vmem_bytes(bq, bk, width, d) <= VMEM_BUDGET_BYTES
+
+
+def engaged(sq: int, skv: int, d: int, causal: bool = False,
+            dtype=jnp.bfloat16) -> bool:
+    """Whether attention at these sizes takes the kernels: a rule over
+    what the op traces, no file and no knob. On the chip (``auto``):
+    both sequences at least :data:`MIN_SEQ`, from where the kernels beat
+    XLA's softmax(QK^T)V forward and backward at d 64 and 128 in
+    bfloat16 and in float32 (table: PERF.md section 6, PR 31; PARITY.md
+    "Flash-attention dispatch policy"). Shorter sequences keep the `xla`
+    path: its (S, S) tile is a few hundred KB a head and XLA's fusions
+    win. ``FLEXFLOW_TPU_PALLAS=compiled`` forces the kernels on wherever
+    :func:`supported` allows, ``interpret`` runs them in the
+    interpreter for the numerics tests, ``off`` wins over everything."""
+    from . import pallas_forced
+
+    del d, causal, dtype  # measured: the crossover is the same for all
+    mode = pallas_mode()
+    if mode is None:
         return False
-    return _vmem_bytes(sq, skv, bq, bk, d) <= VMEM_BUDGET_BYTES
+    if mode == "interpret" or pallas_forced():
+        return True
+    return min(sq, skv) >= MIN_SEQ
 
 
 def sharded_supported(q_shape, k_shape, mesh, batch_axis, heads_axis,
-                      causal: bool = False) -> bool:
+                      causal: bool = False, dtype=None) -> bool:
     """Whether the shard_map-wrapped kernel handles these GLOBAL (B,S,H,D)
     shapes on this mesh: batch/heads must divide by their axis sizes and
     the per-shard block must satisfy :func:`supported`."""
@@ -498,13 +552,12 @@ def sharded_supported(q_shape, k_shape, mesh, batch_axis, heads_axis,
         return False
     lq = (b // ddeg, sq, h // hdeg, d)
     lk = (k_shape[0] // ddeg, k_shape[1], k_shape[2] // hdeg, d)
-    return supported(lq, lk, causal)
+    return supported(lq, lk, causal, dtype)
 
 
 def sharded_flash_attention(q, k, v, mesh, batch_axis, heads_axis,
                             causal: bool = False,
-                            scale: Optional[float] = None,
-                            block_q: Optional[int] = None) -> jax.Array:
+                            scale: Optional[float] = None) -> jax.Array:
     """Flash attention composed with SPMD sharding via shard_map.
 
     Attention is independent across batch and heads, so each device runs
@@ -517,40 +570,126 @@ def sharded_flash_attention(q, k, v, mesh, batch_axis, heads_axis,
     from jax.sharding import PartitionSpec
 
     spec = PartitionSpec(batch_axis, None, heads_axis, None)
-    fn = functools.partial(flash_attention, causal=causal, scale=scale,
-                           block_q=block_q)
+    fn = functools.partial(flash_attention, causal=causal, scale=scale)
     return shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
 
 
-def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None,
-                    block_q: Optional[int] = None) -> jax.Array:
-    """Fused attention. q/k/v: (B, S, H, D) (framework bshd convention).
+def flash_attention_packed(q, k, v, heads: int, causal: bool = False,
+                           scale: Optional[float] = None,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None) -> jax.Array:
+    """Fused attention over q/k/v as (B, S, H*D), the kernels' own
+    layout: what ``(B, S, E) x (E, H*D)`` writes, so a caller that
+    projects that way moves nothing through HBM. Returns (B, S, H*D).
 
     Differentiable (custom VJP). Caller is responsible for checking
     :func:`supported` and falling back to
     ``parallel.ring_attention.single_device_attention`` otherwise (e.g.
     with attention dropout, which this kernel does not implement).
+    ``block_q`` / ``block_k`` override the blocks the shapes choose
+    (tests, :func:`autotune`).
     """
+    sq, skv, d = q.shape[1], k.shape[1], q.shape[2] // heads
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    bq = block_q or _pick_block(sq)
+    bk = block_k or _pick_block(skv)
+    if (_tile_width(heads, d) is None or bq is None or bk is None
+            or sq % bq or skv % bk):
+        raise ValueError(
+            f"flash_attention: no blocks for sequences ({sq}, {skv}) and "
+            f"{heads} heads of {d}; check supported() and fall back to "
+            f"single_device_attention")
+    return _flash(q, k, v, tuple(zip(_STATIC, (
+        heads, causal, float(scale), bq, bk, pallas_mode() == "interpret"))))
+
+
+def flash_attention(q, k, v, causal: bool = False,
+                    scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None) -> jax.Array:
+    """:func:`flash_attention_packed` for q/k/v as (B, S, H, D) (the
+    framework's bshd convention); returns (B, S, H, D)."""
     b, sq, h, d = q.shape
     skv = k.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    if block_q is None:
-        block_q = default_block_q(sq, skv, d, causal)
-    bq = _pick_block(sq, block_q)
-    if bq is None or _pick_block(skv, block_q) is None:
-        raise ValueError(
-            f"flash_attention: seq lengths ({sq}, {skv}) have no valid "
-            f"block size (must be divisible by 8); check supported() and "
-            f"fall back to single_device_attention"
-        )
-    interpret = pallas_mode() == "interpret"
-    # (B, S, H, D) -> (B*H, S, D)
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, skv, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, skv, d)
-    ot = _flash(qt, kt, vt, causal, scale, bq, interpret)
-    return ot.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    out = flash_attention_packed(
+        q.reshape(b, sq, h * d), k.reshape(b, skv, h * d),
+        v.reshape(b, skv, h * d), h, causal, scale, block_q, block_k)
+    return out.reshape(b, sq, h, d)
+
+
+# -- measuring it -------------------------------------------------------------
+
+def autotune(shape=(4, 1024, 16, 64),
+             candidates: Sequence[Tuple[int, int]] = ((512, 512), (256, 256),
+                                                      (128, 128)),
+             causal: bool = True, dtype=jnp.bfloat16,
+             iters: int = 5, layers: int = 8) -> Dict:
+    """Time what training runs at ``shape`` (B, S, H, D) on the CURRENT
+    backend: the forward and backward passes together, in ``dtype``,
+    through the public :func:`flash_attention` for each ``(block_q,
+    block_k)`` candidate, and through ``single_device_attention``, the
+    path dispatch falls back to. One program runs ``layers`` such
+    passes one after the other (each on the last one's gradients, so
+    none can be dropped), which keeps the host's dispatch out of a
+    short kernel's time. Returns ``{"blocks": {(bq, bk): seconds a
+    pass}, "best": (bq, bk), "xla_s": seconds, "xla_ratio": xla_s /
+    best}``. It measures and decides nothing: :func:`engaged` is a rule
+    over shapes, and its constants are edited from tables this function
+    produced on the chip (tools/flash_crossover.py)."""
+    import time
+
+    import numpy as np
+
+    from . import pallas_forced
+    from ..parallel.ring_attention import single_device_attention
+
+    _, s, h, d = shape
+    rng = np.random.default_rng(0)
+    qkv = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32), dtype)
+                for _ in range(3))
+    scale = d ** -0.5
+
+    def both_passes(attend):
+        grads = jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2))
+
+        def layer(xs, _):
+            return tuple(x + 1e-3 * g for x, g in zip(xs, grads(*xs))), None
+
+        return jax.jit(lambda xs: jax.lax.scan(layer, xs, None,
+                                               length=layers)[0])
+
+    def median_time(fn) -> float:
+        jax.block_until_ready(fn(qkv))                  # warm-up, compile
+        windows = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                out = fn(qkv)
+            jax.block_until_ready(out)
+            windows.append((time.perf_counter() - t0) / (iters * layers))
+        return sorted(windows)[1]
+
+    blocks = {}
+    for bq, bk in candidates:
+        if s % bq or s % bk:
+            continue  # the sequence does not tile at this size
+        if _vmem_bytes(bq, bk, _tile_width(h, d) or LANES,
+                       d) > VMEM_BUDGET_BYTES:
+            continue
+        try:
+            blocks[(bq, bk)] = median_time(both_passes(functools.partial(
+                flash_attention, causal=causal, scale=scale, block_q=bq,
+                block_k=bk)))
+        except Exception:  # compile/alloc failure: skip this candidate
+            if pallas_forced():
+                raise  # forced Mosaic: a refusal is the finding, not a skip
+    xla_s = median_time(both_passes(
+        lambda q, k, v: single_device_attention(q, k, v, causal, scale)))
+    best = min(blocks, key=blocks.get) if blocks else None
+    return {"blocks": blocks, "best": best, "xla_s": xla_s,
+            "xla_ratio": (round(xla_s / blocks[best], 4) if blocks else None)}

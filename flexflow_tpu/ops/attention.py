@@ -139,9 +139,58 @@ class MultiHeadAttention(Op):
             out = out + weights["bo"]
         return out
 
-    def forward(self, ctx, inputs, weights):
+    def _fused(self, ctx, inputs, weights):
+        """The op through the fused kernels (kernels/flash_attention.py:
+        no array with two sequence axes reaches HBM), or None where the
+        shapes it traces keep the `xla` path: short sequences
+        (``fa.engaged``), heads that fill no lane tile, a dtype or a
+        mesh the kernels do not take (``fa.supported``)."""
+        from ..kernels import flash_attention as fa
+
+        q_in, k_in, v_in = inputs
+        h, d = self.num_heads, self.head_dim
+        q_shape = q_in.shape[:2] + (h, d)
+        k_shape = k_in.shape[:2] + (h, d)
+        if not fa.engaged(q_shape[1], k_shape[1], d, self.causal, q_in.dtype):
+            return None
+        mesh = ctx.mesh
+        if mesh is None or mesh.size == 1:
+            if not fa.supported(q_shape, k_shape, self.causal, q_in.dtype):
+                return None
+            # the kernels' own layout, heads side by side on the last
+            # axis: the projections write it and read it as they are, so
+            # q, k, v, o and their gradients are never transposed
+
+            def packed(x, w, b):
+                y = jnp.einsum("bse,ef->bsf", x,
+                               weights[w].reshape(x.shape[-1], h * d))
+                return y + weights[b].reshape(h * d) if self.use_bias else y
+
+            ctxv = fa.flash_attention_packed(
+                packed(q_in, "wq", "bq"), packed(k_in, "wk", "bk"),
+                packed(v_in, "wv", "bv"), h, causal=self.causal,
+                scale=self.scale)
+            out = jnp.einsum("bqf,fe->bqe", ctxv,
+                             weights["wo"].reshape(h * d, self.embed_dim))
+            return out + weights["bo"] if self.use_bias else out
+        # multi-device: shard_map the kernels over the batch / heads mesh
+        # axes (attention is independent across both), so dp x tp
+        # configs run them too
+        bdim = self.input_shapes[0].dims[0]
+        batch_ax = bdim.axis if bdim.is_partitioned else None
+        wq = self.weight_shapes.get("wq")
+        hdim = wq.dims[1] if wq is not None else None
+        heads_ax = (hdim.axis if hdim is not None and hdim.is_partitioned
+                    else None)
+        if not fa.sharded_supported(q_shape, k_shape, mesh, batch_ax,
+                                    heads_ax, self.causal, q_in.dtype):
+            return None
         qh, kh, vh = self.project_qkv(weights, *inputs)
-        scale = self.scale
+        return self.project_out(weights, fa.sharded_flash_attention(
+            qh, kh, vh, mesh, batch_ax, heads_ax, causal=self.causal,
+            scale=self.scale))
+
+    def forward(self, ctx, inputs, weights):
         drop = self.dropout if (ctx.training and ctx.rng is not None) else 0.0
         from ..parallel.ring_attention import ring_attention, single_device_attention
 
@@ -154,56 +203,28 @@ class MultiHeadAttention(Op):
 
             sp = ulysses_attention if self.seq_mode == "a2a" else ring_attention
             path = "ulysses" if self.seq_mode == "a2a" else "ring"
-            ctxv = sp(
-                qh, kh, vh, ctx.mesh, self.seq_axis,
-                causal=self.causal, scale=scale,
+            out = self.project_out(weights, sp(
+                *self.project_qkv(weights, *inputs), ctx.mesh, self.seq_axis,
+                causal=self.causal, scale=self.scale,
                 dropout_rate=drop, rng=ctx.rng,
-            )
+            ))
         else:
-            from ..kernels import flash_attention as fa
-
-            ctxv = None
-            # win-or-off policy: on `auto` the kernel engages only at
-            # shapes where a recorded autotune beat XLA fused
-            # (fa.engaged; PARITY.md §flash-attention)
-            if drop == 0.0 and fa.engaged(
-                    qh.shape[1], kh.shape[1], qh.shape[-1], self.causal):
-                mesh = ctx.mesh
-                if mesh is None or mesh.size == 1:
-                    if fa.supported(qh.shape, kh.shape, self.causal):
-                        # Pallas fused attention: (S,S) logits never
-                        # touch HBM.
-                        ctxv = fa.flash_attention(
-                            qh, kh, vh, causal=self.causal, scale=scale)
-                else:
-                    # multi-device: shard_map the kernel over the batch /
-                    # heads mesh axes (attention is independent across
-                    # both), so dp x tp configs run the fused kernel too
-                    bdim = self.input_shapes[0].dims[0]
-                    batch_ax = bdim.axis if bdim.is_partitioned else None
-                    wq = self.weight_shapes.get("wq")
-                    hdim = wq.dims[1] if wq is not None else None
-                    heads_ax = (hdim.axis if hdim is not None and
-                                hdim.is_partitioned else None)
-                    if fa.sharded_supported(qh.shape, kh.shape, mesh,
-                                            batch_ax, heads_ax,
-                                            self.causal):
-                        ctxv = fa.sharded_flash_attention(
-                            qh, kh, vh, mesh, batch_ax, heads_ax,
-                            causal=self.causal, scale=scale)
+            # attention dropout keeps the `xla` path: the kernels do not
+            # implement it
+            out = self._fused(ctx, inputs, weights) if drop == 0.0 else None
             path = "flash"
-            if ctxv is None:
+            if out is None:
                 path = "xla"
-                ctxv = single_device_attention(
-                    qh, kh, vh, self.causal, scale, drop, ctx.rng
-                )
+                out = self.project_out(weights, single_device_attention(
+                    *self.project_qkv(weights, *inputs), self.causal,
+                    self.scale, drop, ctx.rng))
         # which implementation this lowering took, counted once per trace:
-        # on `auto` a missing tune entry selects the jnp path without a
-        # word, and a chip run has to be able to say which one it timed
+        # the rule is over shapes, and a chip run has to be able to say
+        # which one it timed
         from ..obs.metrics import metrics_registry
 
         metrics_registry().counter(f"attention.path.{path}").inc()
-        return [self.project_out(weights, ctxv)]
+        return [out]
 
     def propagate(self, input_shapes, strategy):
         out_shapes, weight_shapes = super().propagate(input_shapes, strategy)

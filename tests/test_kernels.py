@@ -228,118 +228,233 @@ def test_moe_model_trains_with_pallas_kernels():
     assert hist[-1].accuracy > 0.4, hist[-1].accuracy
 
 
-def test_flash_autotune_mechanics(monkeypatch):
-    """autotune() picks a block size, caches it per shape, persists and
-    reloads (interpret mode here; the TPU-gated smoke in tests_tpu/ runs
-    it compiled)."""
-    import json
+def _attention_errors(q_shape, k_shape, causal, dtype, block_q, block_k):
+    """The kernels' output and three gradients against
+    ``single_device_attention`` in float32 on the same (rounded) inputs,
+    each as its largest error over the reference's largest magnitude."""
+    from flexflow_tpu.kernels.flash_attention import flash_attention
+    from flexflow_tpu.parallel.ring_attention import single_device_attention
 
+    rng = np.random.default_rng(3)
+    q, w = (jnp.asarray(rng.normal(size=q_shape), dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=k_shape), dtype) for _ in range(2))
+    scale = q_shape[-1] ** -0.5
+
+    def got(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, scale=scale,
+                              block_q=block_q, block_k=block_k)
+        assert out.dtype == dtype and out.shape == q_shape
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32)), out
+
+    def want(q, k, v):
+        out = single_device_attention(q, k, v, causal, scale)
+        return jnp.sum(out * w.astype(jnp.float32)), out
+
+    (_, out), grads = jax.value_and_grad(got, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    (_, out_ref), grads_ref = jax.value_and_grad(
+        want, argnums=(0, 1, 2), has_aux=True)(
+            *(a.astype(jnp.float32) for a in (q, k, v)))
+    errs = {}
+    for name, a, r in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                          (out_ref, *grads_ref)):
+        assert a.dtype == dtype, name
+        a = np.asarray(a.astype(jnp.float32))
+        assert np.isfinite(a).all(), name
+        errs[name] = float(np.max(np.abs(a - np.asarray(r)))
+                           / np.max(np.abs(np.asarray(r))))
+    return errs
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads,d", [(2, 64), (1, 128)])
+def test_flash_blocked_kernels_match_reference(heads, d, causal, dtype, tol):
+    """The key-blocked kernels, forward and all three gradients, over
+    more than one key block and more than one query block: two heads of
+    64 side by side in a lane tile and one head of 128, causal and not,
+    float32 operands and bfloat16 ones (float32 statistics either
+    way)."""
+    shape = (1, 256, heads, d)
+    errs = _attention_errors(shape, shape, causal, dtype, 64, 128)
+    assert max(errs.values()) <= tol, errs
+
+
+@pytest.mark.parametrize("sq,skv", [(256, 128), (128, 256), (192, 64)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_blocked_kernels_unequal_sequences(sq, skv, causal):
+    """sq != skv, both ways round: the causal mask is
+    ``single_device_attention``'s top-left ``tril``, so a long query's
+    late rows see every key and a long key's late blocks are never read
+    (their dK, dV are zero)."""
+    errs = _attention_errors((2, sq, 4, 32), (2, skv, 4, 32), causal,
+                             jnp.float32, 64, 64)
+    assert max(errs.values()) <= 1e-5, errs
+
+
+def _two_sequence_axes(closed_jaxpr, seq: int):
+    """Shapes of every array in the jaxpr (nested calls, loops and
+    custom derivatives included; a ``pallas_call``'s body, which lives
+    in VMEM, excluded) that has ``seq`` on two axes or more."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            for var in list(eqn.invars) + list(eqn.outvars):
+                shape = getattr(getattr(var, "aval", None), "shape", ())
+                if sum(1 for n in shape if n == seq) >= 2:
+                    found.append((eqn.primitive.name, tuple(shape)))
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(closed_jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("mode,expect_ss", [("interpret", False),
+                                            ("off", True)])
+def test_toy_gpt_step_writes_no_two_sequence_axes_array(monkeypatch, mode,
+                                                        expect_ss):
+    """One training step of a toy GPT: on the fused path its jaxpr holds
+    no array with two sequence-length axes, forward or backward (and
+    the attention counter says `flash`); with the kernels off the same
+    step holds the (B, H, S, S) scores, which is what the search for
+    them must be able to find."""
+    from flexflow_tpu import (AdamOptimizer, FFConfig, FFModel, LossType,
+                              MetricsType)
+    from flexflow_tpu.models.gpt import GPTConfig, build_gpt
+    from flexflow_tpu.obs.metrics import metrics_registry
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    seq, batch = 96, 2          # 96 is no other dimension of the model
+    reg = metrics_registry()
+    before = {p: reg.counter(f"attention.path.{p}").value
+              for p in ("flash", "xla")}
+    ff = FFModel(FFConfig(batch_size=batch, seed=0, search_cache="off"))
+    build_gpt(ff, batch, seq, GPTConfig(vocab_size=61, max_positions=128,
+                                        hidden_size=64, num_heads=2,
+                                        num_layers=2))
+    ff.compile(optimizer=AdamOptimizer(alpha=1e-3),
+               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+               metrics=[MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY])
+    cm = ff.compiled
+    tok = jnp.zeros((batch, seq), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, s, rng, tok, pos, lab: cm.train_step(p, s, rng, tok, pos,
+                                                       lab))(
+        cm.params, cm.opt_state, jax.random.key(0), tok, tok, tok)
+    taken = {p for p, v in before.items()
+             if reg.counter(f"attention.path.{p}").value > v}
+    assert taken == ({"xla"} if expect_ss else {"flash"})
+    found = _two_sequence_axes(jaxpr, seq)
+    assert bool(found) == expect_ss, found[:5]
+
+
+def test_flash_autotune_mechanics(monkeypatch):
+    """autotune() times what training runs — forward and backward, in
+    the dtype given — for each (block_q, block_k) that tiles the
+    sequence, names the fastest, and decides nothing: the rule answers
+    as before it ran (interpret mode here; tools/flash_crossover.py
+    runs it compiled on the chip)."""
     from flexflow_tpu.kernels import flash_attention as fa
 
-    # isolate from the developer's real tuning env: interpret-mode winners
-    # must never leak into a hardware cache file
-    monkeypatch.delenv("FLEXFLOW_FA_TUNE_CACHE", raising=False)
-    monkeypatch.delenv("FLEXFLOW_FA_BLOCK_Q", raising=False)
-
-    results = fa.autotune(shape=(1, 64, 1, 8), candidates=(16, 32, 64),
-                          iters=1)
-    assert results and set(results) <= {16, 32, 64}
-    best = min(results, key=results.get)
-    assert fa.default_block_q(64, 64, 8) == best
-    import tempfile, os
-    with tempfile.TemporaryDirectory() as d:
-        p = os.path.join(d, "tune.json")
-        fa.autotune(shape=(1, 64, 1, 8), candidates=(16, 32), iters=1,
-                    cache_path=p)
-        fa._TUNE_CACHE.clear()
-        assert fa.load_tune_cache(p) == 1
-        assert fa.default_block_q(64, 64, 8) in (16, 32)
-    fa._TUNE_CACHE.clear()
+    before = [fa.engaged(s, s, 8) for s in (64, 1024)]
+    r = fa.autotune(shape=(1, 64, 2, 8), candidates=((16, 16), (32, 64),
+                                                     (48, 48)),
+                    causal=True, dtype=jnp.bfloat16, iters=1, layers=2)
+    assert set(r["blocks"]) == {(16, 16), (32, 64)}   # 48 does not tile 64
+    assert all(t > 0 for t in r["blocks"].values())
+    assert r["best"] == min(r["blocks"], key=r["blocks"].get)
+    assert [fa.engaged(s, s, 8) for s in (64, 1024)] == before
+    assert not hasattr(fa, "load_tune_cache")          # no file, no cache
 
 
 def test_flash_env_block_override(monkeypatch):
+    """Blocks follow the shapes: the first of ``BLOCKS`` that divides a
+    sequence, whatever ``FLEXFLOW_FA_BLOCK_Q`` or
+    ``FLEXFLOW_FA_TUNE_CACHE`` say (neither is read any more); only the
+    explicit arguments override, and the result does not depend on
+    them."""
     from flexflow_tpu.kernels import flash_attention as fa
 
-    monkeypatch.setenv("FLEXFLOW_FA_BLOCK_Q", "32")
-    assert fa.default_block_q(512, 512, 64) == 32
-
-
-def test_flash_win_or_off_policy(monkeypatch):
-    """Round-5 dispatch policy (PARITY.md §flash-attention): on `auto`
-    the kernel engages only at shapes where a recorded autotune beat XLA
-    fused; `compiled` forces it; `off` wins over everything; legacy
-    bare-int cache entries carry no win evidence."""
-    from flexflow_tpu.kernels import flash_attention as fa
-
-    monkeypatch.delenv("FLEXFLOW_FA_TUNE_CACHE", raising=False)
-    monkeypatch.delenv("FLEXFLOW_FA_BLOCK_Q", raising=False)
-    fa._TUNE_CACHE.clear()
-
-    # no evidence: auto-on-TPU must NOT engage (pretend we're on TPU by
-    # forcing mode through the env is 'compiled' which is force — so
-    # check the auto path on this CPU host where pallas_mode() is None)
-    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "auto")
-    assert not fa.engaged(512, 512, 64)
-
-    # interpret mode: numerics tests keep exercising the kernel
-    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
-    assert fa.engaged(512, 512, 64)
-
-    # forced: engages regardless of evidence
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
-    assert fa.engaged(512, 512, 64)
-
-    # off beats forced-adjacent states
-    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
-    assert not fa.engaged(512, 512, 64)
-
-    # proven(): ratio >= 1.0 required; legacy int entries prove nothing
-    fa._TUNE_CACHE[(512, 512, 64, False)] = {"block_q": 128,
-                                             "xla_ratio": 1.07}
-    assert fa.proven(512, 512, 64)
-    fa._TUNE_CACHE[(512, 512, 64, False)] = {"block_q": 128,
-                                             "xla_ratio": 0.98}
-    assert not fa.proven(512, 512, 64)
-    fa._TUNE_CACHE[(512, 512, 64, False)] = {"block_q": 128,
-                                             "xla_ratio": None}
-    assert not fa.proven(512, 512, 64)
-    fa._TUNE_CACHE.clear()
+    monkeypatch.setenv("FLEXFLOW_FA_BLOCK_Q", "32")
+    monkeypatch.setenv("FLEXFLOW_FA_TUNE_CACHE", "/nonexistent/tune.json")
+    assert [fa._pick_block(s) for s in (1024, 768, 384, 192, 2048)] == [
+        512, 256, 128, None, 512]
+    assert not fa.supported((1, 192, 16, 64), (1, 192, 16, 64))
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert fa._pick_block(192) == 64        # the interpreter has no tiles
+    q, k, v = _qkv(b=1, s=64, h=2, d=8, seed=4)
+    a = fa.flash_attention(q, k, v, causal=True)
+    b = fa.flash_attention(q, k, v, causal=True, block_q=16, block_k=32)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               rtol=2e-6, atol=2e-6)
 
 
-def test_flash_autotune_records_xla_ratio(monkeypatch, tmp_path):
-    """autotune() times XLA fused at the same shape and persists the
-    ratio; load_tune_cache round-trips both new-dict and legacy-int
-    formats."""
-    import json
-
+@pytest.mark.parametrize("mode,on_tpu,want", [
+    ("auto", False, (False, False)),     # the CPU: every lowering the jnp one
+    ("interpret", False, (True, True)),  # numerics tests keep the kernels
+    ("compiled", False, (True, True)),   # forced on wherever supported
+    ("off", True, (False, False)),       # off wins over everything
+    ("auto", True, (True, False)),       # the chip: the rule over shapes
+])
+def test_flash_win_or_off_policy(monkeypatch, mode, on_tpu, want):
+    """Who takes the kernels is read from the shapes (PARITY.md
+    "Flash-attention dispatch policy"): on a TPU, sequences from
+    ``MIN_SEQ`` up, the fit cell's (1024, 1024, 64) among them;
+    shorter ones keep the `xla` path. No file, no recorded tuning."""
     from flexflow_tpu.kernels import flash_attention as fa
 
-    monkeypatch.delenv("FLEXFLOW_FA_TUNE_CACHE", raising=False)
-    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
-    fa._TUNE_CACHE.clear()
-    p = str(tmp_path / "tune.json")
-    fa.autotune(shape=(1, 64, 1, 8), candidates=(16, 32), iters=1,
-                cache_path=p)
-    entry = fa._TUNE_CACHE[(64, 64, 8, False)]
-    assert entry["block_q"] in (16, 32)
-    # recorded-fields assertion, NOT a wall-clock comparison: asserting
-    # the interpret-mode kernel loses to XLA (< 1.0) was timing-flaky
-    # under full-suite load on a saturated host. What matters is that
-    # the ratio was measured and persisted, and that engagement asks
-    # proven() (which needs ratio >= 1.0) rather than mere presence.
-    assert isinstance(entry["xla_ratio"], float) and entry["xla_ratio"] > 0
-    assert fa.proven(64, 64, 8) == (entry["xla_ratio"] >= 1.0)
-    with open(p) as f:
-        data = json.load(f)
-    data["128x128x8x0"] = 64  # legacy bare-int entry
-    with open(p, "w") as f:
-        json.dump(data, f)
-    fa._TUNE_CACHE.clear()
-    assert fa.load_tune_cache(p) == 2
-    assert fa._TUNE_CACHE[(64, 64, 8, False)]["block_q"] == entry["block_q"]
-    assert fa._TUNE_CACHE[(128, 128, 8, False)] == {"block_q": 64,
-                                                    "xla_ratio": None}
-    fa._TUNE_CACHE.clear()
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    if on_tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert (fa.engaged(1024, 1024, 64, True, jnp.bfloat16),
+            fa.engaged(128, 128, 64, True, jnp.bfloat16)) == want
+
+
+@pytest.mark.parametrize("q_shape,k_shape,dtype,want", [
+    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.bfloat16, True),   # the cell
+    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.float32, True),
+    ((1, 8192, 8, 128), (1, 8192, 8, 128), jnp.bfloat16, True),   # S7
+    ((1, 65536, 8, 128), (1, 65536, 8, 128), jnp.bfloat16, True),
+    ((2, 512, 20, 64), (2, 2048, 20, 64), jnp.bfloat16, True),    # sq != skv
+    ((2, 1024, 3, 64), (2, 1024, 3, 64), jnp.bfloat16, False),    # odd heads
+    ((2, 1024, 4, 80), (2, 1024, 4, 80), jnp.bfloat16, False),    # straddles
+    ((2, 1024, 4, 16), (2, 1024, 4, 16), jnp.bfloat16, False),    # 64 lanes
+    ((2, 192, 16, 64), (2, 192, 16, 64), jnp.bfloat16, False),    # no block
+    ((2, 1024, 16, 64), (2, 1024, 16, 64), jnp.float16, False),
+])
+def test_flash_supported_follows_shapes_and_dtype(monkeypatch, q_shape,
+                                                  k_shape, dtype, want):
+    """What Mosaic would refuse, ``supported()`` refuses first: heads
+    that fill no whole lane tile, sequences no block divides, a dtype
+    the MXU path does not take. Its VMEM count no longer grows with the
+    sequence (d 128 at 8192 and beyond: ROADMAP S7)."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    assert fa.supported(q_shape, k_shape, True, dtype) is want
+
+
+def test_flash_autotune_records_xla_ratio(monkeypatch):
+    """autotune() times the `xla` path (``single_device_attention``,
+    both passes, the same dtype) at the same shape and reports the
+    ratio the crossover table is made of."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    r = fa.autotune(shape=(1, 64, 1, 8), candidates=((16, 32),),
+                    causal=False, dtype=jnp.float32, iters=1, layers=1)
+    # recorded-fields assertion, NOT a wall-clock comparison: the
+    # interpreted kernel against XLA:CPU says nothing about either
+    assert r["xla_s"] > 0 and r["best"] == (16, 32)
+    assert r["xla_ratio"] == round(r["xla_s"] / r["blocks"][(16, 32)], 4)
 
 
 def test_moe_kernels_supported_counts_smem_operands():

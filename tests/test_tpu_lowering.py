@@ -1,0 +1,81 @@
+"""The fused attention kernels compiled for a described (not attached)
+TPU v5e at real widths: what Mosaic refuses — a block shape off the
+tiling, an unsupported relayout, too much VMEM — fails here, on the CPU,
+before any chip time is spent. Nothing runs, so nothing here says
+anything about results or speed.
+
+One file, and the topology only inside a fixture: one process at a time
+may load the TPU's library, and pytest-xdist workers all import every
+test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A program compiled for a described chip cannot be read back from
+    the persistent cache without one: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+CASES = [
+    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.bfloat16, True),  # the fit cell
+    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.float32, True),
+    ((1, 2048, 8, 128), (1, 2048, 8, 128), jnp.bfloat16, True),
+    ((2, 256, 20, 64), (2, 768, 20, 64), jnp.bfloat16, False),
+]
+
+
+def test_flash_attention_compiles_for_v5e(monkeypatch, one_chip,
+                                          no_compile_cache):
+    """Forward and backward at the blocks the shapes choose: three
+    Mosaic custom calls, by their names, and no (S, S) buffer in the
+    program. One test over all the cases, so that one process (the one
+    that holds the TPU's library) compiles them all, whatever the
+    number of workers."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    for q_shape, k_shape, dtype, causal in CASES:
+        assert fa.supported(q_shape, k_shape, causal, dtype)
+        q = jax.ShapeDtypeStruct(q_shape, dtype, sharding=one_chip)
+        k = jax.ShapeDtypeStruct(k_shape, dtype, sharding=one_chip)
+
+        def loss(q, k, v):
+            return jnp.sum(fa.flash_attention(q, k, v, causal=causal)
+                           .astype(jnp.float32))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, k).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 3
+        for name in ("flash_attention_fwd", "flash_attention_dq",
+                     "flash_attention_dkv"):
+            assert name in text, (q_shape, name)
+        (b, sq, h, _), skv = q_shape, k_shape[1]
+        assert f"[{b},{h},{sq},{skv}]" not in text  # the scores of a head

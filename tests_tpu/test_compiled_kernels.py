@@ -25,7 +25,8 @@ import chip_smoke
 @pytest.mark.parametrize(
     "shape,causal",
     [((2, 128, 2, 64), False), ((2, 512, 16, 64), True),
-     ((2, 1024, 16, 64), True), ((1, 1024, 8, 128), True)],
+     ((4, 1024, 16, 64), True),       # the fit cell's own shape
+     ((1, 1024, 8, 128), True), ((1, 2048, 20, 64), False)],
 )
 def test_flash_attention_compiled(shape, causal, dtype):
     chip_smoke.check_flash_attention(shape, causal, dtype, mosaic=True)
@@ -57,16 +58,20 @@ def test_latent_attention_compiled(slots, max_blocks):
 
 
 def test_flash_autotune_on_chip(monkeypatch):
-    """Compiled-mode autotune at the bench shape; under forced compiled
+    """Compiled-mode autotune at the fit cell's shape: what training
+    runs (bfloat16, causal, forward and backward) through every block
+    size that tiles it and through the `xla` path; under forced compiled
     mode a Mosaic refusal of any candidate raises instead of being
-    skipped. Persists the winner where FLEXFLOW_FA_TUNE_CACHE points."""
+    skipped. The rule takes the kernels at this shape, and they win."""
+    import jax.numpy as jnp
+
     from flexflow_tpu.kernels import flash_attention as fa
 
+    assert fa.engaged(1024, 1024, 64, True, jnp.bfloat16)   # on `auto`
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
-    results = fa.autotune(shape=(4, 512, 8, 64),
-                          candidates=(64, 128, 256, 512), iters=5)
-    assert results
-    best = min(results, key=results.get)
-    print("flash autotune:", {k: round(v * 1e3, 3) for k, v in results.items()},
-          "best:", best)
-    assert fa.default_block_q(512, 512, 64) == best
+    r = fa.autotune(shape=(4, 1024, 16, 64),
+                    candidates=((512, 512), (256, 256), (128, 128)))
+    print("flash autotune:",
+          {k: round(v * 1e3, 3) for k, v in r["blocks"].items()},
+          "xla ms:", round(r["xla_s"] * 1e3, 3), "ratio:", r["xla_ratio"])
+    assert len(r["blocks"]) == 3 and r["xla_ratio"] > 1.0
