@@ -505,6 +505,66 @@ def check_latent_attention(slots: int, heads: int, rank: int, rope: int,
     return err
 
 
+# The state-update kernel is float32 elementwise work and sums down 96
+# sublanes, no matrix unit: it differs from its jnp form (float32 products
+# at `highest`) by the order of a sum.
+GATED_DELTA_RANGE_TOL = 1e-4
+
+
+def check_gated_delta(slots: int, heads: int, key_dim: int, value_dim: int,
+                      mosaic: bool) -> float:
+    """The gated-delta-rule decode kernel against its jnp form
+    (``kernels/gated_delta.py`` ``gated_delta_step``) over the same arena
+    of states: every slot on a row of its own but two idle ones on the
+    null row, decays in (0.9, 1), beta in (0, 2). Returns the largest
+    error of the outputs and of the live rows' new states, each as a
+    share of its reference's largest magnitude; rows no slot names must
+    come back bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels import gated_delta as gd
+
+    rows_n = slots + 1
+    shape = (rows_n, key_dim, heads * value_dim)
+    _require(gd.supported(slots, heads, key_dim, value_dim, shape,
+                          jnp.float32),
+             f"gated_delta.supported() refuses {slots} slots over {shape}")
+    rng = np.random.default_rng(0)
+    arena = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    rows = rng.permutation(np.arange(1, rows_n)).astype(np.int32)
+    rows[[1, slots - 1]] = 0
+    q = rng.normal(size=(slots, heads, key_dim)).astype(np.float32)
+    k = rng.normal(size=(slots, heads, key_dim)).astype(np.float32)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * key_dim ** 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.normal(size=(slots, heads, value_dim)).astype(np.float32)
+    alpha = rng.uniform(0.9, 1.0, size=(slots, heads)).astype(np.float32)
+    beta = rng.uniform(0.0, 2.0, size=(slots, heads)).astype(np.float32)
+    args = tuple(map(jnp.asarray, (rows, q, k, v, alpha, beta)))
+    got_fn = jax.jit(gd.gated_delta_decode)
+    if mosaic:
+        _assert_mosaic(got_fn, arena, *args)
+    o, new = got_fn(arena, *args)
+    o_ref, new_ref = jax.jit(gd.gated_delta_step)(arena, *args)
+    live = rows != 0
+    held = rows[live]
+    untouched = np.setdiff1d(np.arange(1, rows_n), held)
+    _require(np.array_equal(np.asarray(new)[untouched],
+                            np.asarray(arena)[untouched]),
+             "gated delta: a row no slot names was written")
+    _require(np.isfinite(np.asarray(new)).all(),
+             "gated delta: non-finite state")
+    err = 0.0
+    for a, r in ((np.asarray(o)[live], np.asarray(o_ref)[live]),
+                 (np.asarray(new)[held], np.asarray(new_ref)[held])):
+        err = max(err, float(np.max(np.abs(a - r)) / np.max(np.abs(r))))
+    _require(err <= GATED_DELTA_RANGE_TOL,
+             f"gated delta ({slots} slots, {shape}): max error {err:.2e} "
+             f"of range > {GATED_DELTA_RANGE_TOL}")
+    return err
+
+
 def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     from flexflow_tpu.kernels import pallas_mode
 
@@ -528,6 +588,10 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     # of 512 + 64, padded to 640 lanes), beside paged_attention_decode
     errs["latent"] = "%.1e" % check_latent_attention(
         4, 64, 512, 64, 16, sizes.max_length // 16, KV_DTYPE, mosaic)
+    # a hybrid model's states at the benchmark's widths (30 heads, keys of
+    # 96, values of 192) and its 32 slots; 4 under the interpreter
+    errs["gated_delta"] = "%.1e" % check_gated_delta(
+        32 if mosaic else 4, 30, 96, 192, mosaic)
     return ph.report(interpret=not mosaic, flash_shape=shape,
                      moe_shape=sizes.moe, **errs)
 

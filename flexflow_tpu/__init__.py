@@ -43,6 +43,7 @@ from .ops import (  # noqa: F401
     element_unary,
     embedding,
     fused,
+    gated_delta,
     linear,
     moe_ops,
     norm,

@@ -176,6 +176,9 @@ class OpType(enum.Enum):
     # causal self-attention whose keys and values are up-projections of
     # one low-rank latent row a token, with rotary positions (MLA)
     LATENT_ATTENTION = "latent_attention"
+    # linear attention by the gated delta rule: a state of fixed size a
+    # sequence, a short causal convolution before it (Gated DeltaNet)
+    GATED_DELTA_NET = "gated_delta_net"
     # x -> (act(x W_gate) * (x W_up)) W_down
     GATED_MLP = "gated_mlp"
     # dropless top-k routing over n experts, of which this op holds a

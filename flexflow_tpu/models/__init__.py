@@ -14,6 +14,7 @@ from .candle_uno import build_candle_uno, CandleUnoConfig
 from .nmt import build_nmt, NMTConfig
 from .gpt import build_gpt, GPTConfig
 from .latent_moe import build_latent_moe_lm, LatentMoEConfig
+from .hybrid import build_hybrid_lm, HybridLMConfig
 
 
 def zoo_smoke_builders():
@@ -79,6 +80,11 @@ def zoo_smoke_builders():
             experts_per_token=2, n_group=4, topk_group=2,
             routed_scale=2.5))
 
+    def hybrid(ff, bs):
+        build_hybrid_lm(ff, bs, 16, HybridLMConfig(
+            vocab_size=128, hidden_size=32, num_heads=4, linear_heads=4,
+            linear_key_dim=8, linear_value_dim=16, mlp_width=64))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -93,4 +99,5 @@ def zoo_smoke_builders():
         "nmt": nmt,
         "gpt": gpt,
         "latent_moe": latent_moe,
+        "hybrid": hybrid,
     }

@@ -88,6 +88,10 @@ class MultiHeadAttention(Op):
         self.k_in = input_shapes[1].sizes[-1]
         self.v_in = input_shapes[2].sizes[-1]
         self.causal = bool(a.get("causal", False))
+        # an RMSNorm with a gain over the whole projected q, and one over
+        # the whole projected k, before the heads are split
+        self.qk_norm = bool(a.get("qk_norm", False))
+        self.norm_eps = float(a.get("norm_eps", 1e-6))
         # set by propagate when the strategy sequence-shards this op
         self.seq_axis: str | None = None
         self.seq_mode: str = "ring"  # "ring" | "a2a" (Ulysses)
@@ -113,6 +117,13 @@ class MultiHeadAttention(Op):
                 WeightSpec("bv", (h, d), dt, ZeroInitializer(), weight_decay=False),
                 WeightSpec("bo", (self.embed_dim,), dt, ZeroInitializer(), weight_decay=False),
             ]
+        if self.qk_norm:
+            gain = (self.attrs.get("gain_initializer")
+                    or ConstantInitializer(1.0))
+            specs += [
+                WeightSpec("q_norm", (h, d), dt, gain, weight_decay=False),
+                WeightSpec("k_norm", (h, d), dt, gain, weight_decay=False),
+            ]
         return specs
 
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
@@ -130,7 +141,18 @@ class MultiHeadAttention(Op):
             qh = qh + weights["bq"]
             kh = kh + weights["bk"]
             vh = vh + weights["bv"]
+        if self.qk_norm:
+            qh = self._normed(qh, weights["q_norm"])
+            kh = self._normed(kh, weights["k_norm"])
         return qh, kh, vh
+
+    def _normed(self, x, gain):
+        """RMSNorm over all heads' values of a position: ``x`` (..., H, D)
+        or packed (..., H D), ``gain`` (H, D)."""
+        from .norm import rms_norm
+
+        flat = x.reshape(x.shape[:2] + (-1,))
+        return rms_norm(flat, gain.reshape(-1), self.norm_eps).reshape(x.shape)
 
     def project_out(self, weights, ctxv):
         """The attended (B, S, H, D) values -> (B, S, E)."""
@@ -166,9 +188,12 @@ class MultiHeadAttention(Op):
                                weights[w].reshape(x.shape[-1], h * d))
                 return y + weights[b].reshape(h * d) if self.use_bias else y
 
+            qp, kp = packed(q_in, "wq", "bq"), packed(k_in, "wk", "bk")
+            if self.qk_norm:
+                qp = self._normed(qp, weights["q_norm"])
+                kp = self._normed(kp, weights["k_norm"])
             ctxv = fa.flash_attention_packed(
-                packed(q_in, "wq", "bq"), packed(k_in, "wk", "bk"),
-                packed(v_in, "wv", "bv"), h, causal=self.causal,
+                qp, kp, packed(v_in, "wv", "bv"), h, causal=self.causal,
                 scale=self.scale)
             out = jnp.einsum("bqf,fe->bqe", ctxv,
                              weights["wo"].reshape(h * d, self.embed_dim))
@@ -236,7 +261,7 @@ class MultiHeadAttention(Op):
                 for wn in ("wq", "wk", "wv"):
                     weight_shapes[wn] = weight_shapes[wn].partitioned(1, deg, ax)
                 weight_shapes["wo"] = weight_shapes["wo"].partitioned(0, deg, ax)
-                for bn in ("bq", "bk", "bv"):
+                for bn in ("bq", "bk", "bv", "q_norm", "k_norm"):
                     if bn in weight_shapes:
                         weight_shapes[bn] = weight_shapes[bn].partitioned(0, deg, ax)
         sax = strategy.get("seq")

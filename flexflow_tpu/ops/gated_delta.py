@@ -1,0 +1,273 @@
+"""GatedDeltaNet: a linear-attention layer whose memory is a state of
+fixed size a sequence (Gated DeltaNet, Yang, Kautz & Hatamizadeh 2024;
+no reference analog).
+
+``x`` is (B, S, E); H heads, key width ``d_k``, value width ``d_v``.
+
+* ``q = x W_q``, ``k = x W_k`` (each ``H d_k`` wide), ``v = x W_v``
+  (``H d_v``). Each channel ``c`` of ``[q | k | v]`` goes through a causal
+  depthwise convolution of ``K`` taps and SiLU: ``u_t[c] = silu(sum_{j <
+  K} w[j, c] in_{t-K+1+j}[c])``, zeros before the sequence. Per head, q
+  and k are L2-normalised over ``d_k`` and q is scaled by ``d_k^-1/2``.
+* ``beta_t = sigmoid(x_t W_b)`` per head, doubled where
+  ``allow_neg_eigval`` (beta in (0, 2)); ``g_t = -exp(A_log) *
+  softplus(x_t W_a + dt_bias)`` per head, ``alpha_t = exp(g_t)``.
+* the state ``S`` of a head is ``(d_k, d_v)``, zero before the sequence::
+
+      S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
+      o_t = S_t^T q_t
+
+  that is ``S_t = alpha_t (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t
+  v_t^T``.
+* out: ``y_t = (RMSNorm_{d_v}(o_t) * silu(x_t W_g)) W_o``, the norm's gain
+  of width ``d_v`` shared by the heads.
+
+What a sequence keeps of this layer is ``S`` (``H d_k d_v`` numbers) and
+the last ``K - 1`` inputs of the convolution: one row a REQUEST, not a
+row a token (serving/cache_entry.py ``StateEntry``).
+
+Two forms compute the recurrence. :func:`chunked_delta_rule` takes whole
+sequences, ``CHUNK`` tokens at a time: within a chunk everything is a
+matrix product (the chunk's updates ``u_t = beta_t (v_t - alpha_t
+S_{t-1}^T k_t)`` solve a unit lower-triangular system that does not
+involve the incoming state: the WY form), between chunks the state is
+carried by ``lax.scan``. ``kernels/gated_delta.py`` takes one token
+(``delta_rule_step`` in jnp, ``gated_delta_decode`` the kernel). Both
+are float32 with products at ``highest``; the projections around them are
+in the activations' dtype with float32 accumulation.
+"""
+
+from __future__ import annotations
+
+from typing import List
+
+import jax
+import jax.numpy as jnp
+
+from ..core.op import Op, WeightSpec, register_op
+from ..ffconst import OpType
+from ..runtime.initializer import (ConstantInitializer,
+                                   DefaultWeightInitializer, ZeroInitializer)
+from .attention import _mm
+from .norm import rms_norm
+
+CHUNK = 64  # tokens a step of the whole-sequence form's scan
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _unit_lower_inverse(low):
+    """``(I + low)^-1`` for strictly lower-triangular ``low`` (..., C, C),
+    by forward substitution a row at a time: row ``i`` of the inverse is
+    ``e_i - low[i, :i] @ inverse[:i]``."""
+    c = low.shape[-1]
+    eye = jnp.eye(c, dtype=low.dtype)
+
+    def row(i, inv):
+        li = jax.lax.dynamic_index_in_dim(low, i, axis=-2, keepdims=False)
+        new = eye[i] - jnp.einsum("...j,...jc->...c", li, inv, precision=_HI)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, i, axis=-2)
+
+    # rows below i are still zero when row i is made, and low[i, j >= i] is
+    # zero anyway
+    return jax.lax.fori_loop(0, c, row, jnp.zeros_like(low))
+
+
+def chunked_delta_rule(q, k, v, g, beta, state):
+    """The gated delta rule over whole sequences. ``q``, ``k`` (B, S, H,
+    d_k), ``v`` (B, S, H, d_v), ``g`` = log alpha and ``beta`` (B, S, H),
+    ``state`` (B, H, d_k, d_v) the state before the sequence, all float32.
+    A position with ``g = 0`` and ``beta = 0`` leaves the state as it was.
+    Returns (o (B, S, H, d_v), the state after position S - 1)."""
+    b, s, h, dk = q.shape
+    pad = -s % CHUNK
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                                    (a.ndim - 2)) for a in (q, k, v, g, beta))
+    n = (s + pad) // CHUNK
+
+    def chunks(a):                    # (B, n C, H, ...) -> (n, B, H, C, ...)
+        a = a.reshape((b, n, CHUNK) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-1)                              # (n, B, H, C)
+    idx = jax.lax.iota(jnp.int32, CHUNK)
+    lower = idx[:, None] >= idx[None, :]
+    # decay[t, i] = prod_{i < j <= t} alpha_j, for i <= t
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    kk = jnp.einsum("...td,...id->...ti", k, k, precision=_HI)
+    low = beta[..., None] * kk * jnp.where(idx[:, None] > idx[None, :],
+                                           decay, 0.0)
+    inv = _unit_lower_inverse(low)
+    since = jnp.exp(gc)               # the decay since the chunk's start
+    # u_t = uv_t - w_t S_0: what position t adds to the state as k_t u_t^T
+    uv = jnp.einsum("...ti,...id->...td", inv, beta[..., None] * v,
+                    precision=_HI)
+    w = jnp.einsum("...ti,...id->...td", inv, (beta * since)[..., None] * k,
+                   precision=_HI)
+    qk = jnp.einsum("...td,...id->...ti", q, k, precision=_HI) * decay
+    q_in = since[..., None] * q                              # against S_0
+    k_out = jnp.exp(gc[..., -1:] - gc)[..., None] * k        # up to the end
+    a_end = since[..., -1][..., None, None]
+
+    def step(st, xs):
+        uv_c, w_c, qk_c, q_c, k_c, a_c = xs
+        u = uv_c - jnp.einsum("...td,...dv->...tv", w_c, st, precision=_HI)
+        o = (jnp.einsum("...td,...dv->...tv", q_c, st, precision=_HI)
+             + jnp.einsum("...ti,...iv->...tv", qk_c, u, precision=_HI))
+        st = a_c * st + jnp.einsum("...td,...tv->...dv", k_c, u,
+                                   precision=_HI)
+        return st, o
+
+    state, o = jax.lax.scan(step, state, (uv, w, qk, q_in, k_out, a_end))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)            # (B, n, C, H, dv)
+    return o.reshape(b, n * CHUNK, h, -1)[:, :s], state
+
+
+@register_op
+class GatedDeltaNet(Op):
+    """The layer of the module's docstring. Matrices keep 2-D shapes,
+    heads side by side in the columns; ``conv`` is (taps, 2 H d_k + H
+    d_v), the channels in the order ``[q | k | v]``."""
+
+    op_type = OpType.GATED_DELTA_NET
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        a = self.attrs
+        self.embed_dim: int = input_shapes[0].sizes[-1]
+        self.num_heads = int(a["num_heads"])
+        self.key_dim = int(a["key_dim"])
+        self.value_dim = int(a["value_dim"])
+        self.conv_taps = int(a.get("conv_taps", 4))
+        self.neg_eigval = bool(a.get("allow_neg_eigval", False))
+        self.eps = float(a.get("eps", 1e-6))
+        self.qk_width = self.num_heads * self.key_dim
+        self.v_width = self.num_heads * self.value_dim
+        self.channels = 2 * self.qk_width + self.v_width
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self) -> List[WeightSpec]:
+        dt = self.input_shapes[0].dtype
+        init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        gain = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
+        # A_log = 0 and dt_bias = 0 give alpha = exp(-softplus(.)); a
+        # loader or a family's draw puts the published ranges in
+        gate = self.attrs.get("gate_initializer") or ZeroInitializer()
+        e, h = self.embed_dim, self.num_heads
+        return [
+            WeightSpec("wq", (e, self.qk_width), dt, init),
+            WeightSpec("wk", (e, self.qk_width), dt, init),
+            WeightSpec("wv", (e, self.v_width), dt, init),
+            WeightSpec("wg", (e, self.v_width), dt, init),
+            WeightSpec("wa", (e, h), dt, init),
+            WeightSpec("wb", (e, h), dt, init),
+            WeightSpec("conv", (self.conv_taps, self.channels), dt, init),
+            WeightSpec("a_log", (h,), dt, gate, weight_decay=False),
+            WeightSpec("dt_bias", (h,), dt, gate, weight_decay=False),
+            WeightSpec("norm", (self.value_dim,), dt, gain,
+                       weight_decay=False),
+            WeightSpec("wo", (self.v_width, e), dt, init),
+        ]
+
+    # ---- the pieces serving composes (serving/cache_entry.py) -------------
+    def conv_inputs(self, weights, x):
+        """(B, S, E) -> the convolution's inputs ``[q | k | v]`` (B, S,
+        channels), in the activations' dtype: what the tail keeps."""
+        return jnp.concatenate([_mm(x, weights[w])
+                                for w in ("wq", "wk", "wv")], axis=-1)
+
+    def convolve(self, weights, window):
+        """``window`` (B, K - 1 + S, channels): each position's inputs
+        behind the ``K - 1`` before it. The sum of K shifted products,
+        then SiLU; (B, S, channels) float32."""
+        s = window.shape[1] - (self.conv_taps - 1)
+        w = weights["conv"].astype(jnp.float32)
+        window = window.astype(jnp.float32)
+        acc = sum(w[j] * window[:, j:j + s] for j in range(self.conv_taps))
+        return jax.nn.silu(acc)
+
+    def heads(self, u):
+        """The convolved (B, S, channels) -> q, k (B, S, H, d_k), each
+        L2-normalised, q scaled by ``d_k^-1/2``, and v (B, S, H, d_v)."""
+        b, s, _ = u.shape
+        h, dk = self.num_heads, self.key_dim
+        q = u[..., :self.qk_width].reshape(b, s, h, dk)
+        k = u[..., self.qk_width:2 * self.qk_width].reshape(b, s, h, dk)
+        v = u[..., 2 * self.qk_width:].reshape(b, s, h, self.value_dim)
+
+        def unit(a):
+            return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+        return unit(q) * dk ** -0.5, unit(k), v
+
+    def gates(self, weights, x):
+        """(B, S, E) -> ``g`` = log alpha and beta, (B, S, H) float32."""
+        f32 = jnp.float32
+        a = jnp.dot(x, weights["wa"], preferred_element_type=f32)
+        bl = jnp.dot(x, weights["wb"], preferred_element_type=f32)
+        g = -jnp.exp(weights["a_log"].astype(f32)) * jax.nn.softplus(
+            a + weights["dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(bl)
+        return g, beta * 2.0 if self.neg_eigval else beta
+
+    def finish(self, weights, x, o):
+        """The recurrence's (B, S, H, d_v) float32 outputs -> (B, S, E):
+        RMSNorm over ``d_v``, times ``silu(x W_g)``, through ``W_o``."""
+        b, s = o.shape[:2]
+        z = jnp.dot(x, weights["wg"], preferred_element_type=jnp.float32)
+        y = rms_norm(o, weights["norm"], self.eps).reshape(b, s, self.v_width)
+        return _mm((y * jax.nn.silu(z)).astype(x.dtype), weights["wo"])
+
+    def run(self, weights, x, state, tail, lengths=None):
+        """A block of S tokens a row behind ``state`` (B, H, d_k, d_v)
+        float32 and ``tail`` (B, K - 1, channels), the last inputs of the
+        convolution before the block; ``lengths`` (B,) the tokens of each
+        row that count (None: all S). Positions past a row's length leave
+        its state as it was (alpha = 1, beta = 0 there) and the new tail
+        is taken at the true length. Returns (y (B, S, E), state, tail)."""
+        b, s, _ = x.shape
+        taps = self.conv_taps
+        if lengths is None:
+            lengths = jnp.full((b,), s, jnp.int32)
+        with jax.named_scope("gated_delta_prefill"):
+            window = jnp.concatenate(
+                [tail.astype(x.dtype), self.conv_inputs(weights, x)], axis=1)
+            q, k, v = self.heads(self.convolve(weights, window))
+            g, beta = self.gates(weights, x)
+            live = (jax.lax.iota(jnp.int32, s)[None, :]
+                    < lengths[:, None])[..., None]
+            o, state = chunked_delta_rule(q, k, v, jnp.where(live, g, 0.0),
+                                          jnp.where(live, beta, 0.0), state)
+            # window position p is block position p - (K - 1): the K - 1
+            # inputs before position ``length`` start at ``length``
+            tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+                w, n, taps - 1, axis=0))(window, lengths)
+            return self.finish(weights, x, o), state, tail
+
+    def whole(self, weights, x, lengths=None):
+        """Whole sequences from an empty state: :meth:`run` behind zeros."""
+        b = x.shape[0]
+        return self.run(
+            weights, x,
+            jnp.zeros((b, self.num_heads, self.key_dim, self.value_dim),
+                      jnp.float32),
+            jnp.zeros((b, self.conv_taps - 1, self.channels), x.dtype),
+            lengths)
+
+    def forward(self, ctx, inputs, weights):
+        return [self.whole(weights, inputs[0])[0]]
+
+    def flops(self) -> float:
+        b, s = self.input_shapes[0].sizes[:2]
+        e, h, dk, dv = (self.embed_dim, self.num_heads, self.key_dim,
+                        self.value_dim)
+        proj = 2.0 * b * s * e * (2 * self.qk_width + 3 * self.v_width + 2 * h)
+        # a chunk of C tokens: k k^T, q k^T and the inverse's two products
+        # over (C, C); w S, q S, k^T u and (q k^T) u against the state
+        chunk = 2.0 * b * s * h * (CHUNK * (2 * dk + 2 * (dk + dv))
+                                   + 3 * dk * dv)
+        return proj + chunk + 2.0 * b * s * self.conv_taps * self.channels

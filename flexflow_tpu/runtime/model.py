@@ -295,6 +295,26 @@ class FFModel:
         return self._infer_and_add(OpType.LATENT_ATTENTION,
                                    [input, positions], attrs, name)
 
+    def gated_delta_net(self, input: Tensor, *, num_heads: int, key_dim: int,
+                        value_dim: int, conv_taps: int = 4,
+                        allow_neg_eigval: bool = False, eps: float = 1e-6,
+                        kernel_initializer=None, gain_initializer=None,
+                        gate_initializer=None,
+                        name: Optional[str] = None) -> Tensor:
+        """Linear attention by the gated delta rule over a state of fixed
+        size a sequence (ops/gated_delta.py GatedDeltaNet): ``num_heads``
+        heads of ``key_dim`` keys and ``value_dim`` values, a causal
+        depthwise convolution of ``conv_taps`` taps before them."""
+        attrs = dict(
+            num_heads=int(num_heads), key_dim=int(key_dim),
+            value_dim=int(value_dim), conv_taps=int(conv_taps),
+            allow_neg_eigval=bool(allow_neg_eigval), eps=float(eps),
+            kernel_initializer=kernel_initializer,
+            gain_initializer=gain_initializer,
+            gate_initializer=gate_initializer)
+        return self._infer_and_add(OpType.GATED_DELTA_NET, [input], attrs,
+                                   name)
+
     def routed_experts(self, input: Tensor, *, n_routed: int,
                        experts_per_token: int, width: int,
                        n_group: int = 1, topk_group: Optional[int] = None,
@@ -485,10 +505,16 @@ class FFModel:
         causal: bool = False,
         name=None,
         strategy: Optional[Dict[str, str]] = None,
+        qk_norm: bool = False,
+        norm_eps: float = 1e-6,
+        gain_initializer=None,
     ) -> Tensor:
         """reference: FFModel::multihead_attention (model.h:542,
         src/ops/attention.cc — cuDNN multihead attention). ``causal`` is a
-        TPU-native extension (the reference has no causal masking)."""
+        TPU-native extension (the reference has no causal masking), as is
+        ``qk_norm``: an RMSNorm with a learned gain over the whole
+        projected q and over the whole projected k, before the heads are
+        split."""
         attrs = dict(
             embed_dim=embed_dim,
             num_heads=num_heads,
@@ -501,6 +527,9 @@ class FFModel:
             kernel_initializer=kernel_initializer,
             causal=causal,
         )
+        if qk_norm:
+            attrs.update(qk_norm=True, norm_eps=float(norm_eps),
+                         gain_initializer=gain_initializer)
         if strategy:
             attrs["strategy"] = strategy
         return self._infer_and_add(

@@ -1,14 +1,20 @@
-"""The cache entry of an attention op: what a layer keeps for a token.
+"""The cache entry of a sequence-mixing op: what a layer keeps for a
+token, or for a request.
 
-One decision lives here and nowhere else: what one token's row is, how
-it is allocated and what it weighs, how a step of W new tokens and a
-prefill write it and read it, what its dense rectangle is, and what it
-cannot do. An :class:`EntryKind` answers, :data:`KINDS` names one per
-attention op type, and everything else asks: the pool (kv_cache.py)
+One decision lives here and nowhere else: what one token's row (or one
+request's state) is, how it is allocated and what it weighs, how a step
+of W new tokens and a prefill write it and read it, what its dense form
+is, and what it cannot do. An :class:`EntryKind` answers, :data:`KINDS`
+names one per op type, and everything else asks: the pool (kv_cache.py)
 allocates what a kind describes and counts its bytes, the programs
-(generation.py) hand every attention op to its kind, the scheduler reads
-its limits. A new kind is a class and a line in :data:`KINDS`; those
-three modules do not change.
+(generation.py) hand every such op to its kind, the scheduler reads its
+limits. A new kind is a class and a line in :data:`KINDS`; those three
+modules do not change.
+
+A kind keeps a row a TOKEN, addressed through the slots' block tables, or
+(``per_request``) a row a REQUEST, addressed by the slots' rows; the
+programs pass both as one :class:`~flexflow_tpu.serving.kv_cache
+.Addresses` and a kind takes the half that is its own.
 
 An entry is the tuple of arrays its kind allocates, donated through the
 programs; the kind is static Python beside it. Every reader masks by
@@ -29,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ffconst import OpType
-from ..kernels import latent_attention, paged_attention
+from ..kernels import gated_delta, latent_attention, paged_attention
 from .kv_cache import NULL_BLOCK
 
 
@@ -92,55 +98,65 @@ def latent_row_lanes(width: int) -> int:
 
 
 class EntryKind:
-    """What one attention op keeps for one token, and everything that
-    depends on it. ``op`` is the attention op, ``weights`` its
-    parameters, ``x`` (B, S, E) its input, ``positions`` (B, S) the
-    graph's; an ``entry`` is the tuple of arenas :meth:`arenas`
-    describes, ``tables`` (B, max_blocks) the slots' block tables. A
-    kind defines:
+    """What one op keeps for one token (or one request), and everything
+    that depends on it. ``op`` is the op, ``weights`` its parameters,
+    ``x`` (B, S, E) its input, ``positions`` (B, S) the graph's; an
+    ``entry`` is the tuple of arenas :meth:`arenas` describes, ``addr``
+    the slots' :class:`~flexflow_tpu.serving.kv_cache.Addresses` (block
+    tables (B, max_blocks) and rows (B,)). A kind defines:
 
     * ``for_op(op, positions_id, max_length)`` (a classmethod): the kind
       of ``op`` in a graph whose positions input has that tensor id,
       decoded up to ``max_length``; raises what the op cannot serve;
-    * ``arenas(num_blocks, block_size, dtype)``: one op's arenas, a
-      ``jax.ShapeDtypeStruct`` each;
-    * ``write(entry, flat, *rows)``: (T, ...) rows into flat token slots
-      (T,); returns the entry;
+    * ``arenas(n, block_size, dtype)``: one op's arenas, a
+      ``jax.ShapeDtypeStruct`` each, of ``n`` blocks (of ``n`` rows, for a
+      ``per_request`` kind);
     * ``reads_in_place(op, entry, slots, window, max_blocks)``: whether a
       ``window``-token step reads ``entry`` by a kernel, in place;
-    * ``step(op, weights, x, positions, entry, tables, seq_lens)``: W new
+    * ``step(op, weights, x, positions, entry, addr, seq_lens)``: W new
       tokens a slot at positions ``seq_lens .. seq_lens + W - 1``, their
       rows written through the tables (an idle slot's, and positions past
       a table's span, into the null block), then each slot's cache
-      attended through its table; returns (out, entry);
-    * ``whole(op, weights, x, positions)``: dense causal attention of
-      whole sequences over their own rows, cache-free (prefill's half,
-      and what the KV calibration gate compares the paged programs
-      with); returns (out, the rows ``write`` takes, the (S,) positions);
-    * ``dense_shapes(batch, max_length)``, the dense rectangle's arrays,
-      and ``dense_step(op, weights, x, positions, cache, offset)``: a
-      block of tokens written at ``offset`` and attended over the whole
-      static length; returns (out, cache)."""
+      attended through its table; a per-request kind updates each slot's
+      row (an idle slot's is the null row); returns (out, entry);
+    * ``prefill(op, weights, x, positions, entry, addr, lengths)``: a
+      group of prompts padded to one bucket, of true ``lengths``, from
+      nothing: what they leave is written where ``addr`` says (padding
+      into the null block, padding rows into the null row); returns
+      (out, entry);
+    * ``whole(op, weights, x, positions)``: whole sequences, cache-free
+      (what the KV calibration gate compares the paged programs with);
+      returns (out, ...), the rest the kind's own business;
+    * ``dense_shapes(batch, max_length, dtype)``, the dense form's arrays
+      (a ``jax.ShapeDtypeStruct`` each), and ``dense_step(op, weights, x,
+      positions, cache, offset)``: a block of tokens at ``offset``,
+      behind what the cache holds; returns (out, cache).
+
+    A kind of a row a token defines ``write(entry, flat, *rows)`` ((T,
+    ...) rows into flat token slots (T,)) and a ``whole`` that returns
+    (out, the rows ``write`` takes, the (S,) positions), and inherits
+    ``prefill``."""
 
     name = ""                          # what stats()["kv"]["entry"] says
     max_window: Optional[int] = None   # new tokens a slot a step; None: any
     int8_form: Optional["EntryKind"] = None
+    per_request = False                # a row a request, not a row a token
 
     def stats(self) -> Dict:
         return {"entry": self.name}
 
     def token_bytes(self, dtype) -> int:
-        """Bytes one token takes in one op's arenas stored as ``dtype``:
-        plain arithmetic on :meth:`arenas`, nothing is allocated."""
+        """Bytes one token (one request, for a ``per_request`` kind) takes
+        in one op's arenas stored as ``dtype``: plain arithmetic on
+        :meth:`arenas`, nothing is allocated."""
         return sum(math.prod(a.shape) * a.dtype.itemsize
                    for a in self.arenas(1, 1, dtype))
 
-    def prefill(self, op, weights, x, positions, entry, tables, lengths):
-        """A group of prompts padded to one bucket, of true ``lengths``:
-        :meth:`whole`, and the rows scattered through each prompt's table
-        (padding into the null block). Returns (out, entry)."""
+    def prefill(self, op, weights, x, positions, entry, addr, lengths):
+        """:meth:`whole`, and the rows scattered through each prompt's
+        table (padding into the null block)."""
         out, rows, pos = self.whole(op, weights, x, positions)
-        flat = _prefill_slots(tables, lengths, pos, entry[0].shape[1])
+        flat = _prefill_slots(addr.tables, lengths, pos, entry[0].shape[1])
         return out, self.write(entry, flat.reshape(-1), *(
             r.reshape((-1,) + r.shape[2:]) for r in rows))
 
@@ -191,10 +207,11 @@ class PairEntry(EntryKind):
             (slots, window, self.heads, self.head_dim), entry[0].shape,
             entry[0].dtype, max_blocks)
 
-    def step(self, op, weights, x, positions, entry, tables, seq_lens):
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
         qh, kh, vh = op.project_qkv(weights, x, x, x)
         bs = entry[0].shape[1]
         n, w, heads, hdim = qh.shape
+        tables = addr.tables
         mb = tables.shape[1]
         pos = seq_lens[:, None] + _iota(w)[None, :]                # (n, W)
         blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, mb - 1),
@@ -226,9 +243,10 @@ class PairEntry(EntryKind):
         ctxv = _weigh(scores, mask[None, None, :, :], vh)
         return op.project_out(weights, ctxv), (kh, vh), pos
 
-    def dense_shapes(self, batch, max_length):
-        shape = (batch, max_length, self.heads, self.head_dim)
-        return (shape, shape)
+    def dense_shapes(self, batch, max_length, dtype):
+        a = jax.ShapeDtypeStruct(
+            (batch, max_length, self.heads, self.head_dim), dtype)
+        return (a, a)
 
     def dense_step(self, op, weights, x, positions, cache, offset):
         qh, kh, vh = op.project_qkv(weights, x, x, x)
@@ -322,7 +340,7 @@ class LatentEntry(EntryKind):
             (slots, op.num_heads, arena.shape[-1]), arena.shape,
             arena.dtype, max_blocks, op.kv_rank)
 
-    def step(self, op, weights, x, positions, entry, tables, seq_lens):
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
         """The absorbed form: per head the query over a row's lanes is
         ``q_nope`` folded through the key half of ``W_kvb`` beside
         ``q_rope``, and the weighted sum of the rows' latent part is
@@ -330,6 +348,7 @@ class LatentEntry(EntryKind):
         n, w, _ = x.shape                # w is 1: ``max_window``
         arena = entry[0]
         bs, lanes = arena.shape[1], arena.shape[2]
+        tables = addr.tables
         mb = tables.shape[1]
         q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
         blk = jnp.take_along_axis(
@@ -378,8 +397,9 @@ class LatentEntry(EntryKind):
                                      pos[None, :] <= pos[:, None])
         return out, (rows,), pos
 
-    def dense_shapes(self, batch, max_length):
-        return ((batch, max_length, self.row_width),)
+    def dense_shapes(self, batch, max_length, dtype):
+        return (jax.ShapeDtypeStruct((batch, max_length, self.row_width),
+                                     dtype),)
 
     def dense_step(self, op, weights, x, positions, cache, offset):
         q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
@@ -393,10 +413,98 @@ class LatentEntry(EntryKind):
         return out, (rows_cache,)
 
 
-# the kind of each attention op type: ``for_op`` as :meth:`EntryKind.for_op`
+@dataclasses.dataclass(frozen=True)
+class StateEntry(EntryKind):
+    """A gated-delta-rule op's one row a REQUEST: the float32 state of
+    its heads, ``(d_k, H d_v)`` with the heads side by side on the lanes
+    (``kernels/gated_delta.py``: the tiles pad nothing), and the last
+    ``taps - 1`` inputs of its convolution, flat. The state is float32
+    whatever ``kv_dtype`` says (rounded each step it would drift for a
+    request's whole life; ``stats()`` says so); the convolution's tail is
+    stored as the per-token kinds' rows are. A state cannot be rolled
+    back, so a step takes one token a slot."""
+
+    heads: int
+    key_dim: int
+    value_dim: int
+    tail: int          # positions of the convolution's inputs kept
+    channels: int
+    name = "state"
+    max_window = 1
+    per_request = True
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        return cls(op.num_heads, op.key_dim, op.value_dim, op.conv_taps - 1,
+                   op.channels)
+
+    def arenas(self, rows, block_size, dtype):
+        return (jax.ShapeDtypeStruct(
+                    (rows, self.key_dim, self.heads * self.value_dim),
+                    jnp.float32),
+                jax.ShapeDtypeStruct((rows, self.tail * self.channels),
+                                     dtype))
+
+    def stats(self):
+        return {"entry": self.name, "state_dtype": "float32"}
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return window == 1 and gated_delta.supported(
+            slots, self.heads, self.key_dim, self.value_dim, entry[0].shape,
+            entry[0].dtype)
+
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        n = x.shape[0]                   # one token a slot: ``max_window``
+        state, tails = entry
+        window = jnp.concatenate(
+            [tails[addr.rows].reshape(n, self.tail, self.channels),
+             op.conv_inputs(weights, x).astype(tails.dtype)], axis=1)
+        q, k, v = op.heads(op.convolve(weights, window))
+        g, beta = op.gates(weights, x)
+        tails = tails.at[addr.rows].set(window[:, 1:].reshape(n, -1))
+        update = (gated_delta.gated_delta_decode
+                  if self.reads_in_place(op, entry, n, 1, 0)
+                  else gated_delta.gated_delta_step)
+        o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
+                          jnp.exp(g[:, 0]), beta[:, 0])
+        return op.finish(weights, x, o[:, None]), (state, tails)
+
+    def prefill(self, op, weights, x, positions, entry, addr, lengths):
+        """The op's chunked whole-sequence form from an empty state: what
+        a prompt of its TRUE length leaves, whatever the bucket, written
+        over the request's row (padding rows over the null row)."""
+        out, state, tail = op.whole(weights, x, lengths)
+        return out, self._put(entry, addr.rows, state, tail)
+
+    def _put(self, entry, rows, state, tail):
+        n = state.shape[0]
+        lanes = jnp.moveaxis(state, 1, 2).reshape(n, self.key_dim, -1)
+        return (entry[0].at[rows].set(lanes),
+                entry[1].at[rows].set(
+                    tail.reshape(n, -1).astype(entry[1].dtype)))
+
+    def whole(self, op, weights, x, positions):
+        out, state, tail = op.whole(weights, x)
+        return out, (state, tail), None
+
+    def dense_shapes(self, batch, max_length, dtype):
+        return (jax.ShapeDtypeStruct(
+                    (batch, self.heads, self.key_dim, self.value_dim),
+                    jnp.float32),
+                jax.ShapeDtypeStruct((batch, self.tail, self.channels),
+                                     dtype))
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        out, state, tail = op.run(weights, x, *cache)
+        return out, (state, tail.astype(cache[1].dtype))
+
+
+# the kind of each op type that keeps something for a sequence: ``for_op``
+# as :meth:`EntryKind.for_op`
 KINDS: Dict[OpType, Callable[..., EntryKind]] = {
     OpType.MULTIHEAD_ATTENTION: PairEntry.for_op,
     OpType.LATENT_ATTENTION: LatentEntry.for_op,
+    OpType.GATED_DELTA_NET: StateEntry.for_op,
 }
 
 
@@ -415,4 +523,4 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
 
 
 __all__ = ["EntryKind", "Int8PairEntry", "KINDS", "LatentEntry", "PairEntry",
-           "kind_for", "latent_row_lanes"]
+           "StateEntry", "kind_for", "latent_row_lanes"]

@@ -6,11 +6,13 @@ piece on top of the serving engine. TPU-native design:
 
 * each program is ONE jitted function produced by walking the compiled
   model's op graph — every op runs its ordinary shape-polymorphic
-  ``forward`` on the (B, S_blk, ·) activations EXCEPT the attention ops,
-  which are handed to their entry kind
-  (:mod:`~flexflow_tpu.serving.cache_entry`: what an op keeps for a
-  token, and how each program here writes and reads it, is the kind's;
-  this module knows no more than that every such op has one);
+  ``forward`` on the (B, S_blk, ·) activations EXCEPT the ops that keep
+  something for a sequence (attention, a recurrent state), which are
+  handed to their entry kind (:mod:`~flexflow_tpu.serving.cache_entry`:
+  what an op keeps for a token or for a request, and how each program
+  here writes and reads it, is the kind's; this module knows no more
+  than that every such op has one, and hands each what addresses it:
+  block tables and state rows together, as one ``Addresses``);
 * the cache is a pytree {attention op name: entry} of static shape,
   donated through the program so XLA updates it in place;
 * sampling (greedy / temperature) happens on host between steps, except
@@ -53,7 +55,7 @@ from ..ffconst import OpType
 from ..core.op import LowerCtx
 from ..obs.trace import span
 from .cache_entry import kind_for
-from .kv_cache import NULL_BLOCK, PagedKVPool
+from .kv_cache import NULL_BLOCK, Addresses, PagedKVPool
 
 
 def _expert_counts(op, ids, active):
@@ -218,8 +220,11 @@ class _DecodeGraph:
         self._cm = cm
         self.max_length = int(max_length)
         self._token_id = cm.input_tensors[0]
-        self._pos_id = cm.input_tensors[1]
-        pos_tid = self._pos_id.tensor_id
+        # a graph none of whose layers reads positions (its order is in
+        # its recurrent layers) has no such input
+        self._pos_id = (cm.input_tensors[1] if len(cm.input_tensors) > 1
+                        else None)
+        pos_tid = None if self._pos_id is None else self._pos_id.tensor_id
         # what each attention op keeps for a token, chosen once
         kinds = {op.name: kind_for(op, pos_tid, self.max_length)
                  for op in cm.ops}
@@ -264,6 +269,13 @@ class _DecodeGraph:
         invalidates automatically)."""
         self._params_cache.invalidate()
 
+    def _inputs(self, tokens, positions) -> Dict:
+        """The graph's input activations, by tensor id."""
+        acts = {self._token_id.tensor_id: tokens}
+        if self._pos_id is not None:
+            acts[self._pos_id.tensor_id] = positions
+        return acts
+
     def _forward_block(self, params, acts, attn, experts=None):
         """Walk the op graph over the activations in ``acts``; ``attn``
         handles each attention op, given ``(op, weights, x, positions)``
@@ -273,11 +285,13 @@ class _DecodeGraph:
         float32 logits."""
         ctx = LowerCtx(mesh=None, training=False, aux_losses=[],
                        compute_dtype=None)
+        positions = (None if self._pos_id is None
+                     else acts[self._pos_id.tensor_id])
         for op in self._cm.ops:
             ins = [acts[t.tensor_id] for t in op.layer.inputs]
             p = params.get(op.name, {})
             if op.name in self._kinds:
-                outs = [attn(op, p, ins[0], acts[self._pos_id.tensor_id])]
+                outs = [attn(op, p, ins[0], positions)]
             elif op.op_type is OpType.ROUTED_EXPERTS and experts is not None:
                 outs = [experts(op, p, ins[0])]
             else:
@@ -321,13 +335,14 @@ class Generator(_DecodeGraph):
 
     # ---- cache ------------------------------------------------------------
     def init_cache(self) -> Dict[str, Tuple[jnp.ndarray, ...]]:
-        """The dense rectangle of each attention op, as its kind shapes
-        it: a (k, v) pair of (B, max_length, H, D), or a latent op's one
-        (B, max_length, width) array of rows."""
+        """The dense form of each op's entry, as its kind shapes it: a
+        (k, v) pair of (B, max_length, H, D), a latent op's one (B,
+        max_length, width) array of rows, a state and its convolution's
+        tail a row."""
         dt = self._compute_dtype() or jnp.float32
-        return {name: tuple(jnp.zeros(shape, dt) for shape in
+        return {name: tuple(jnp.zeros(a.shape, a.dtype) for a in
                             kind.dense_shapes(self.batch_size,
-                                              self.max_length))
+                                              self.max_length, dt))
                 for name, kind in self._kinds.items()}
 
     # ---- one block step (prefill: S=prompt, decode: S=1) -----------------
@@ -335,8 +350,7 @@ class Generator(_DecodeGraph):
         b, s_blk = tokens.shape
         positions = offset + jax.lax.iota(jnp.int32, s_blk)[None, :]
         positions = jnp.broadcast_to(positions, (b, s_blk))
-        acts = {self._token_id.tensor_id: tokens,
-                self._pos_id.tensor_id: positions}
+        acts = self._inputs(tokens, positions)
         new_cache = dict(cache)
 
         def attn(op, p, x, pos):
@@ -557,10 +571,11 @@ class PagedDecoder(_DecodeGraph):
             self._calibrate_kv_quant(kv_divergence_budget)
 
     # ---- compiled programs -------------------------------------------------
-    def _decode_step(self, params, tokens, pool, tables, seq_lens,
+    def _decode_step(self, params, tokens, pool, addr, seq_lens,
                      expert_acc, prev_ids, take_prev):
         """One decode step for all slots: tokens (slots,) int32, pool
-        {op: arena entry} donated, tables (slots, MB) int32, seq_lens
+        {op: arena entry} donated, addr the slots' ``Addresses`` (tables
+        (slots, MB) int32 and, over a pool of states, rows), seq_lens
         (slots,) int32, expert_acc {routed-experts op: counters}
         donated, prev_ids (slots,) int32 the ids the step before
         returned (not donated: the host may still be fetching them),
@@ -572,17 +587,16 @@ class PagedDecoder(_DecodeGraph):
         maximum, what ``np.argmax`` of the fetched row gives)."""
         tokens = jnp.where(take_prev, prev_ids, tokens)[:, None]
         positions = seq_lens[:, None]                           # (slots, 1)
-        acts = {self._token_id.tensor_id: tokens,
-                self._pos_id.tensor_id: positions}
+        acts = self._inputs(tokens, positions)
         new_pool = dict(pool)
         new_acc = dict(expert_acc)
         routed: Dict[str, jax.Array] = {}
         # a slot with no block reserved is idle: its token is padding
-        active = tables[:, 0] != NULL_BLOCK
+        active = addr.tables[:, 0] != NULL_BLOCK
 
         def attn(op, p, x, pos):
             out, new_pool[op.name] = self.pool.kinds[op.name].step(
-                op, p, x, pos, new_pool[op.name], tables, seq_lens)
+                op, p, x, pos, new_pool[op.name], addr, seq_lens)
             return out
 
         def experts(op, p, x):
@@ -597,7 +611,7 @@ class PagedDecoder(_DecodeGraph):
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits, new_pool, routed, new_acc, ids
 
-    def _verify_step(self, params, tokens, pool, tables, seq_lens):
+    def _verify_step(self, params, tokens, pool, addr, seq_lens):
         """Speculative verify: tokens (slots, W) int32 — each slot's
         last accepted token followed by W-1 draft proposals, at absolute
         positions ``seq_lens .. seq_lens + W - 1``. Writes the rows of ALL
@@ -614,24 +628,25 @@ class PagedDecoder(_DecodeGraph):
         w = tokens.shape[1]
         positions = (seq_lens[:, None]
                      + jax.lax.iota(jnp.int32, w)[None, :])     # (slots, W)
-        acts = {self._token_id.tensor_id: tokens,
-                self._pos_id.tensor_id: positions}
+        acts = self._inputs(tokens, positions)
         new_pool = dict(pool)
 
         def attn(op, p, x, pos):
             out, new_pool[op.name] = self.pool.kinds[op.name].step(
-                op, p, x, pos, new_pool[op.name], tables, seq_lens)
+                op, p, x, pos, new_pool[op.name], addr, seq_lens)
             return out
 
         logits = self._forward_block(params, acts, attn)
         return logits, new_pool, {}
 
-    def _prefill_step(self, params, tokens, pool, tables, lengths):
+    def _prefill_step(self, params, tokens, pool, addr, lengths):
         """Bucketed prefill for a GROUP of requests: tokens (P, Sb)
-        int32 (each prompt padded to the bucket), pool donated, tables
-        (P, MB) int32, lengths (P,) int32 true prompt lengths. Rows
+        int32 (each prompt padded to the bucket), pool donated, addr the
+        prompts' ``Addresses`` (tables (P, MB) int32 and, over a pool of
+        states, rows), lengths (P,) int32 true prompt lengths. Rows
         are independent (padding keys are causally masked for every
-        valid query row, padding rows go to the null block), so one
+        valid query row, padding rows go to the null block, a state
+        stops at its prompt's true length), so one
         multi-prompt dispatch computes exactly what P single-prompt
         dispatches would, in one XLA program. Returns ((P, vocab)
         float32 logits of each row's last prompt position, new pool,
@@ -642,8 +657,7 @@ class PagedDecoder(_DecodeGraph):
         b, s_blk = tokens.shape
         positions = jnp.broadcast_to(
             jax.lax.iota(jnp.int32, s_blk)[None, :], (b, s_blk))
-        acts = {self._token_id.tensor_id: tokens,
-                self._pos_id.tensor_id: positions}
+        acts = self._inputs(tokens, positions)
         new_pool = dict(pool)
         routed: Dict[str, jax.Array] = {}
 
@@ -655,7 +669,7 @@ class PagedDecoder(_DecodeGraph):
 
         def attn(op, p, x, pos):
             out, new_pool[op.name] = self.pool.kinds[op.name].prefill(
-                op, p, x, pos, new_pool[op.name], tables, lengths)
+                op, p, x, pos, new_pool[op.name], addr, lengths)
             return out
 
         logits = self._forward_block(params, acts, attn, experts)
@@ -663,15 +677,25 @@ class PagedDecoder(_DecodeGraph):
         return last, new_pool, routed
 
     def _new_pool(self, num_blocks: int) -> PagedKVPool:
-        """A pool of the attention ops' entries stored as ``kv_dtype``
-        says, and with it the kinds the programs call
-        (``pool.kinds``): the one place either is made, so a kind cannot
-        outlive its arenas."""
+        """A pool of the ops' entries stored as ``kv_dtype`` says, and
+        with it the kinds the programs call (``pool.kinds``): the one
+        place either is made, so a kind cannot outlive its arenas. Of
+        per-request entries it holds a row a decode slot and the null
+        row: a request is admitted into a free slot."""
         return PagedKVPool(
             self._kinds, num_blocks=num_blocks, block_size=self.block_size,
             max_blocks_per_request=self.max_blocks_per_request,
             dtype=self._compute_dtype() or jnp.float32,
-            kv_dtype=self.kv_dtype)
+            kv_dtype=self.kv_dtype, num_rows=self.decode_slots + 1)
+
+    def _addresses(self, tables: np.ndarray) -> Addresses:
+        """The programs' one address argument for the requests of
+        ``tables`` (N, MB): the tables and, over a pool of states, the
+        row each one's request holds."""
+        tables = np.asarray(tables, np.int32)
+        rows = self.pool.rows_of(tables)
+        return Addresses(jnp.asarray(tables),
+                         None if rows is None else jnp.asarray(rows))
 
     def expert_stats(self) -> Dict[str, Dict]:
         """Per routed-experts op, counted on the device over the decode
@@ -697,9 +721,9 @@ class PagedDecoder(_DecodeGraph):
         return out
 
     def _attention_path(self, window: int) -> str:
-        """What a W-token step's attention does with the pool as it is
-        now: "kernel" where every attention op's entry is read in
-        place, else "gather"."""
+        """What a W-token step does with the pool as it is now: "kernel"
+        where every op's entry, of whatever kind, is read in place, else
+        "gather"."""
         return "kernel" if all(
             self.pool.kinds[op.name].reads_in_place(
                 op, self.pool.kv[op.name], self.decode_slots, window,
@@ -734,14 +758,16 @@ class PagedDecoder(_DecodeGraph):
         pool_sds, acc_sds = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self.pool.kv, self._expert_acc))
-        tables_sds = jax.ShapeDtypeStruct(
-            (self.decode_slots, self.max_blocks_per_request), jnp.int32)
-        # tokens, seq_lens and prev_ids: one (slots,) int32 each
+        # tokens, seq_lens, prev_ids and the rows: one (slots,) int32 each
         lens_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.int32)
+        addr_sds = Addresses(
+            jax.ShapeDtypeStruct(
+                (self.decode_slots, self.max_blocks_per_request), jnp.int32),
+            lens_sds if self.pool.num_rows else None)
         take_sds = jax.ShapeDtypeStruct((self.decode_slots,), jnp.bool_)
         self.audit_report, self.exec_telemetry = _audit_serving_program(
             "serving.paged_decode_step", self._decode,
-            (self._params_sds(), lens_sds, pool_sds, tables_sds, lens_sds,
+            (self._params_sds(), lens_sds, pool_sds, addr_sds, lens_sds,
              acc_sds, lens_sds, take_sds), self._cm.config)
 
     # ---- host API (the scheduler's surface) --------------------------------
@@ -791,7 +817,7 @@ class PagedDecoder(_DecodeGraph):
         with span("serving.loop.dispatch", cat="serving"):
             logits, self.pool.kv, self.last_routing = fn(
                 self._exec_params(), jnp.asarray(toks), self.pool.kv,
-                jnp.asarray(tabs), jnp.asarray(lengths))
+                self._addresses(tabs), jnp.asarray(lengths))
         return self._fetch(logits)[:len(arrs)]
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
@@ -828,8 +854,7 @@ class PagedDecoder(_DecodeGraph):
              self._ids) = self._decode(
                 self._exec_params(),
                 jnp.asarray(np.asarray(tokens, np.int32)),
-                self.pool.kv,
-                jnp.asarray(np.asarray(tables, np.int32)),
+                self.pool.kv, self._addresses(tables),
                 jnp.asarray(np.asarray(seq_lens, np.int32)),
                 self._expert_acc, self._ids,
                 jnp.asarray(np.asarray(take_prev, bool)))
@@ -856,7 +881,7 @@ class PagedDecoder(_DecodeGraph):
         with span("serving.loop.dispatch", cat="serving"):
             logits, self.pool.kv, self.last_routing = fn(
                 self._exec_params(), jnp.asarray(tokens), self.pool.kv,
-                jnp.asarray(np.asarray(tables, np.int32)),
+                self._addresses(tables),
                 jnp.asarray(np.asarray(seq_lens, np.int32)))
         return self._fetch(logits)
 
@@ -892,10 +917,9 @@ class PagedDecoder(_DecodeGraph):
         (S, vocab) float32 logits."""
         tokens = np.asarray(tokens, np.int32)
         s = tokens.shape[0]
-        acts = {
-            self._token_id.tensor_id: jnp.asarray(tokens[None, :]),
-            self._pos_id.tensor_id:
-                jnp.asarray(np.arange(s, dtype=np.int32)[None, :])}
+        acts = self._inputs(
+            jnp.asarray(tokens[None, :]),
+            jnp.asarray(np.arange(s, dtype=np.int32)[None, :]))
 
         def attn(op, p, x, pos):
             return self._kinds[op.name].whole(op, p, x, pos)[0]

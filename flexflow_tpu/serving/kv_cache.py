@@ -49,6 +49,18 @@ dims in bf16 = 2 * 256*16*8*64 * 2B = 8 MiB per layer, serving up to
 GPT-2 large at 16 slots of 1024 tokens: 1025 x 16 x 1280 x 2 B = 42 MB an
 arena, 72 arenas, 3.0 GB.
 
+**Per-request entries.** A layer may keep, instead of a row a token, a
+state of fixed size a request (a gated-delta-rule op:
+:class:`~flexflow_tpu.serving.cache_entry.StateEntry`). Such a kind says
+``per_request``, and the pool gives its arenas ``num_rows`` rows in place
+of ``num_blocks`` blocks: row 0 is the **null row**, what idle slots and a
+prefill's padding rows name, as block 0 is for tokens. Admission reserves
+a request's blocks AND a row, or neither; the block table stays the
+request's one handle (:meth:`PagedKVPool.rows_of` finds the row of a
+table's request by its first block, which no other live request holds),
+:meth:`PagedKVPool.free` returns both, and the bytes count both. Inside
+the programs the two travel together as :class:`Addresses`.
+
 **Quantized arenas** (``kv_dtype``): the pool can store its arenas in
 ``"bfloat16"`` (cast-in/cast-out) or ``"int8"``: each op's entry in its
 kind's int8 form (:class:`~flexflow_tpu.serving.cache_entry
@@ -64,7 +76,7 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -77,6 +89,18 @@ if TYPE_CHECKING:
     from .cache_entry import EntryKind
 
 NULL_BLOCK = 0  # reserved scatter/gather sink; never allocated
+NULL_ROW = 0    # the same for per-request arenas
+
+
+class Addresses(NamedTuple):
+    """What addresses the entries of a program's slots (or of a prefill's
+    prompts): ``tables`` (N, max_blocks) int32, each one's block table,
+    for the kinds that keep a row a token, and ``rows`` (N,) int32, each
+    one's row of the per-request arenas (None in a pool that has none:
+    the programs of a model with no such kind take no such argument)."""
+
+    tables: "jnp.ndarray"
+    rows: Optional["jnp.ndarray"] = None
 
 # arena storage modes: "float32" stores in the pool's compute dtype
 # (the historical behavior — ``dtype`` may itself be bf16 under a
@@ -101,17 +125,24 @@ def stored_as(name: str, kind, kv_dtype: str, dtype):
 
 
 def pool_bytes(specs, num_blocks: int, block_size: int,
-               kv_dtype: str = "float32", dtype=jnp.float32) -> int:
-    """Arena bytes of a pool of ``specs`` (``{attention op name: entry
-    kind}``) across all ops, sidecars included: plain arithmetic, which
-    :meth:`PagedKVPool.memory_bytes` and the sim's capacity planning both
-    call. ``dtype`` is what the ``"float32"`` mode stores in (the pool's
-    compute dtype, which may itself be bf16)."""
-    per_tok = 0
+               kv_dtype: str = "float32", dtype=jnp.float32,
+               num_rows: int = 0) -> int:
+    """Arena bytes of a pool of ``specs`` (``{op name: entry kind}``)
+    across all ops, sidecars included: a term a token (``num_blocks *
+    block_size`` of them) for the kinds that keep a row a token and a term
+    a request (``num_rows``) for those that keep a state. Plain
+    arithmetic, which :meth:`PagedKVPool.memory_bytes` and the sim's
+    capacity planning both call. ``dtype`` is what the ``"float32"`` mode
+    stores in (the pool's compute dtype, which may itself be bf16)."""
+    per_tok = per_row = 0
     for name, spec in dict(specs).items():
         kind, store = stored_as(name, spec, kv_dtype, dtype)
-        per_tok += kind.token_bytes(store)
-    return int(num_blocks) * int(block_size) * per_tok
+        if kind.per_request:
+            per_row += kind.token_bytes(store)
+        else:
+            per_tok += kind.token_bytes(store)
+    return (int(num_blocks) * int(block_size) * per_tok
+            + int(num_rows) * per_row)
 
 
 class PagedKVPool:
@@ -136,7 +167,7 @@ class PagedKVPool:
     def __init__(self, specs: Dict[str, "EntryKind"], *,
                  num_blocks: int, block_size: int,
                  max_blocks_per_request: int, dtype=jnp.float32,
-                 kv_dtype: str = "float32"):
+                 kv_dtype: str = "float32", num_rows: int = 0):
         if num_blocks < 2:
             raise ValueError(f"num_blocks {num_blocks} < 2: block 0 is the "
                              f"reserved null block, so a usable pool needs "
@@ -158,16 +189,31 @@ class PagedKVPool:
         # arena entry per op: the tuple of arrays its kind describes,
         # donated through the programs; the kind stays beside it
         self.kinds: Dict[str, "EntryKind"] = {}
+        stored = {name: stored_as(name, spec, kv_dtype, dtype)
+                  for name, spec in self.specs.items()}
+        # rows of each per-request arena, the null row among them; 0 in a
+        # pool none of whose kinds keeps a state
+        has_state = any(kind.per_request for kind, _ in stored.values())
+        self.num_rows = int(num_rows) if has_state else 0
+        if has_state and self.num_rows < 2:
+            raise ValueError(f"num_rows {num_rows} < 2: row 0 is the "
+                             f"reserved null row, so a pool of per-request "
+                             f"entries needs at least one more")
         self.kv: Dict[str, Tuple[jnp.ndarray, ...]] = {}
-        for name, spec in self.specs.items():
-            kind, store = stored_as(name, spec, kv_dtype, dtype)
+        for name, (kind, store) in stored.items():
             self.kinds[name] = kind
             self.kv[name] = tuple(
                 jnp.zeros(a.shape, a.dtype) for a in kind.arenas(
-                    self.num_blocks, self.block_size, store))
+                    self.num_rows if kind.per_request else self.num_blocks,
+                    self.block_size, store))
         # LIFO free list: freshly freed blocks are reused first (their
         # stale contents are masked by position either way)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        # the same for rows (a prefill overwrites the whole row), and the
+        # row each live request holds, by the request's first block
+        self._free_rows: List[int] = list(range(self.num_rows - 1, 0, -1))
+        self._row_of_block = np.zeros(self.num_blocks, np.int32)
+        self._rows_high_water = 0
         self._mu = threading.Lock()
         self._high_water = 0
         self._gauge()
@@ -187,7 +233,7 @@ class PagedKVPool:
         count their f32 scale/zero-point sidecars too (the honest
         admission-doubling denominator)."""
         return pool_bytes(self.specs, self.num_blocks, self.block_size,
-                          self.kv_dtype, self.dtype)
+                          self.kv_dtype, self.dtype, self.num_rows)
 
     # ---- allocator ---------------------------------------------------------
     def in_use(self) -> int:
@@ -201,10 +247,12 @@ class PagedKVPool:
 
     def try_admit(self, total_tokens: int) -> Optional[np.ndarray]:
         """Reserve the worst case for a request of ``total_tokens``
-        (prompt + max_new_tokens). Returns a padded block table
-        ``(max_blocks_per_request,)`` int32 (unused tail entries =
-        :data:`NULL_BLOCK`), or None when the pool is currently too
-        full — the caller waits for retirements and retries.
+        (prompt + max_new_tokens): its blocks and, in a pool of
+        per-request entries, a row; both or neither. Returns a padded
+        block table ``(max_blocks_per_request,)`` int32 (unused tail
+        entries = :data:`NULL_BLOCK`), the request's handle for both, or
+        None when the pool is currently too full — the caller waits for
+        retirements and retries.
 
         Raises :class:`KVPoolExhausted` when the request can NEVER fit
         (worst case exceeds total pool capacity) — that is a shed, not
@@ -221,12 +269,18 @@ class PagedKVPool:
                 f"tokens) exceeds the whole pool "
                 f"({self.capacity_blocks} allocatable blocks)")
         with self._mu:
-            if need > len(self._free):
+            if need > len(self._free) or (self.num_rows
+                                          and not self._free_rows):
                 return None
             blocks = [self._free.pop() for _ in range(need)]
             used = self.capacity_blocks - len(self._free)
             if used > self._high_water:
                 self._high_water = used
+            if self.num_rows:
+                self._row_of_block[blocks[0]] = self._free_rows.pop()
+                self._rows_high_water = max(
+                    self._rows_high_water,
+                    self.num_rows - 1 - len(self._free_rows))
         self._gauge()
         table = np.full(self.max_blocks_per_request, NULL_BLOCK, np.int32)
         table[:need] = blocks
@@ -234,16 +288,34 @@ class PagedKVPool:
 
     def free(self, table: np.ndarray) -> None:
         """Return a request's reserved blocks (every non-null table
-        entry) to the pool."""
+        entry) and its row to the pool."""
         blocks = [int(b) for b in np.asarray(table).ravel()
                   if int(b) != NULL_BLOCK]
         with self._mu:
+            if self.num_rows and blocks:
+                row = int(self._row_of_block[blocks[0]])
+                if row == NULL_ROW:
+                    raise RuntimeError(
+                        f"double free: the request of block {blocks[0]} "
+                        f"holds no state row")
+                self._row_of_block[blocks[0]] = NULL_ROW
+                self._free_rows.append(row)
             self._free.extend(blocks)
             if len(self._free) > self.capacity_blocks:
                 raise RuntimeError(
                     f"double free: {len(self._free)} free blocks > "
                     f"capacity {self.capacity_blocks}")
         self._gauge()
+
+    def rows_of(self, tables: np.ndarray) -> Optional[np.ndarray]:
+        """The row each table's request holds in the per-request arenas,
+        (N,) int32 for ``tables`` (N, max_blocks): found by the request's
+        first block; an idle slot's all-null table gives the null row.
+        None in a pool that has no such arena."""
+        if not self.num_rows:
+            return None
+        with self._mu:
+            return self._row_of_block[np.asarray(tables)[:, 0]]
 
     def _gauge(self) -> None:
         metrics_registry().gauge("serving.kv_blocks_in_use").set(
@@ -254,6 +326,13 @@ class PagedKVPool:
         with self._mu:
             used = self.capacity_blocks - len(self._free)
             hw = self._high_water
+            state = {"state": {
+                "rows": self.num_rows,
+                "in_use": self.num_rows - 1 - len(self._free_rows),
+                "high_water": self._rows_high_water,
+                # a request's row over all the ops that keep one, as stored
+                "row_bytes": pool_bytes(self.specs, 0, 0, self.kv_dtype,
+                                        self.dtype, 1)}} if self.num_rows else {}
         return {
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,
@@ -263,17 +342,28 @@ class PagedKVPool:
             "high_water": hw,
             "memory_bytes": int(self.memory_bytes()),
             "kv_dtype": self.kv_dtype,
-            # what a token's row is, in its kind's words: "pair" (k, v),
-            # "int8" (values and sidecars) or "latent" (one row, its
-            # width as cached and as the arena pads it)
+            # what an op keeps, in its kind's words: "pair" (k, v),
+            # "int8" (values and sidecars), "latent" (one row, its width
+            # as cached and as the arena pads it) or "state" (a row a
+            # request)
             **self._entry_stats(),
+            **state,
         }
 
     def _entry_stats(self) -> Dict:
+        """One kind's own words where every op says the same; else each
+        kind's name with its count of ops, beside what the kinds say
+        besides."""
         said = [kind.stats() for kind in self.kinds.values()]
-        return said[0] if all(s == said[0] for s in said) else {
-            "entry": "mixed"}
+        if all(s == said[0] for s in said):
+            return said[0]
+        out: Dict = {}
+        for s in said:
+            out.update(s)
+        out["entry"] = {name: sum(s["entry"] == name for s in said)
+                        for name in dict.fromkeys(s["entry"] for s in said)}
+        return out
 
 
-__all__ = ["KV_DTYPES", "NULL_BLOCK", "PagedKVPool", "KVPoolExhausted",
-           "pool_bytes", "stored_as"]
+__all__ = ["Addresses", "KV_DTYPES", "NULL_BLOCK", "NULL_ROW", "PagedKVPool",
+           "KVPoolExhausted", "pool_bytes", "stored_as"]

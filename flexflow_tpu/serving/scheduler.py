@@ -319,9 +319,15 @@ class ContinuousBatchingScheduler:
                 num_blocks=self.decoder.pool.num_blocks,
                 prefill_buckets=self.decoder.prefill_buckets,
                 kv_dtype=self.decoder.kv_dtype, calibrate=False)
+            # a draft's rejected tokens are rolled back as the target's are
+            self.draft.check_window(self.spec_k + 1)
         # running sums over decode steps, read by stats()["kv"]
         self._blocks_read = 0
         self._blocks_in_tables = 0
+        # active slots x the ops that keep a state a request
+        self._rows_stepped = 0
+        self._state_ops = sum(
+            k.per_request for k in self.decoder.pool.kinds.values())
         self._spec_rounds = 0
         self._spec_slot_rounds = 0
         self._spec_proposed = 0
@@ -882,6 +888,7 @@ class ContinuousBatchingScheduler:
                     # of the slot's cached tokens and the row it writes
                     self._blocks_read += (req.seq_len + bs) // bs
                 self._blocks_in_tables += len(active) * tables.shape[1]
+                self._rows_stepped += len(active) * self._state_ops
         return active, tokens, tables, seq_lens
 
     def _dispatch(self, fn, *args):
@@ -1319,6 +1326,7 @@ class ContinuousBatchingScheduler:
             spec_emitted = self._spec_emitted
             blocks_read = self._blocks_read
             blocks_in_tables = self._blocks_in_tables
+            rows_stepped = self._rows_stepped
         now = time.perf_counter()
         tps = (tokens / (now - t_start)
                if t_start is not None and now > t_start else 0.0)
@@ -1326,6 +1334,8 @@ class ContinuousBatchingScheduler:
         kv["attention_path"] = dict(self.decoder.attention_path)
         kv["blocks_read"] = blocks_read
         kv["blocks_in_tables"] = blocks_in_tables
+        if "state" in kv:
+            kv["state"]["rows_stepped"] = rows_stepped
         if self.decoder.kv_divergence is not None:
             kv["divergence"] = self.decoder.kv_divergence
             kv["quant_fallback"] = self.decoder.kv_quant_report is not None

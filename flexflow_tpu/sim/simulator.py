@@ -65,19 +65,21 @@ class MemoryUsage:
 
 def serving_kv_pool_bytes(specs, num_blocks: int, block_size: int,
                           kv_dtype: str = "float32",
-                          dtype_bytes: int = 4) -> int:
+                          dtype_bytes: int = 4, num_rows: int = 0) -> int:
     """Dtype-aware paged-KV pool arena bytes for capacity planning and
     the advisor's admission math: the pool's own arithmetic
     (``serving.kv_cache.pool_bytes``, which ``PagedKVPool.memory_bytes``
-    calls too), so the two cannot drift. ``specs``: ``{attention op
-    name: entry kind}``, as ``PagedKVPool.specs``; ``dtype_bytes``: the
-    item size of the ``"float32"`` mode's compute dtype."""
+    calls too), so the two cannot drift. ``specs``: ``{op name: entry
+    kind}``, as ``PagedKVPool.specs``; ``dtype_bytes``: the item size of
+    the ``"float32"`` mode's compute dtype; ``num_rows``: the rows (one a
+    decode slot and the null row) of the kinds that keep a state a
+    request, the per-request term."""
     import numpy as np
 
     from ..serving.kv_cache import pool_bytes
 
     return pool_bytes(specs, num_blocks, block_size, kv_dtype,
-                      np.dtype(f"f{int(dtype_bytes)}"))
+                      np.dtype(f"f{int(dtype_bytes)}"), num_rows)
 
 
 def _collective_axes(op: Op) -> Tuple[List[Tuple[str, int, str]], int]:

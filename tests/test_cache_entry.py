@@ -20,7 +20,7 @@ from flexflow_tpu.serving import (ContinuousBatchingScheduler,
                                   PagedDecoder, PagedKVPool)
 from flexflow_tpu.serving import cache_entry
 from flexflow_tpu.serving.cache_entry import (Int8PairEntry, LatentEntry,
-                                              PairEntry)
+                                              PairEntry, StateEntry)
 from flexflow_tpu.sim import serving_kv_pool_bytes
 
 V = 50
@@ -154,7 +154,49 @@ def test_a_kind_answers_for_its_arenas_bytes_names_and_limits(case):
     st = pool.stats()
     assert {k: st[k] for k in said} == said and st["memory_bytes"] == held
     assert stored.max_window == max_window
-    assert len(stored.dense_shapes(2, 16)) == (1 if case == "latent" else 2)
+    dense = stored.dense_shapes(2, 16, jnp.float32)
+    assert len(dense) == (1 if case == "latent" else 2)
+    assert all(a.shape[:2] == (2, 16) and a.dtype == jnp.float32
+               for a in dense)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16"])
+def test_a_state_kind_answers_for_its_rows_bytes_names_and_limits(kv_dtype):
+    """Beside a pair kind in one pool: arenas by rows, the state float32
+    whatever ``kv_dtype`` says, the bytes a term a token and a term a
+    request, each kind named with its count of ops."""
+    state, pair = StateEntry(4, 8, 16, 3, 128), PairEntry(4, 8)
+    pool = PagedKVPool({"s0": state, "a": pair, "s1": state}, num_blocks=NB,
+                       block_size=BS, max_blocks_per_request=4,
+                       kv_dtype=kv_dtype, num_rows=5)
+    store = jnp.bfloat16 if kv_dtype == "bfloat16" else jnp.float32
+    for name in ("s0", "s1"):
+        assert [(a.shape, a.dtype) for a in pool.kv[name]] == [
+            ((5, 8, 64), jnp.float32), ((5, 3 * 128), store)]
+    assert pool.kv["a"][0].shape == (NB, BS, 32)
+    held = sum(a.nbytes for entry in pool.kv.values() for a in entry)
+    row = 8 * 64 * 4 + 3 * 128 * jnp.dtype(store).itemsize
+    assert state.token_bytes(store) == row and state.per_request
+    assert pool.memory_bytes() == held == (
+        NB * BS * pair.token_bytes(store) + 5 * 2 * row)
+    assert held == serving_kv_pool_bytes(pool.specs, NB, BS, kv_dtype,
+                                         num_rows=5)
+    st = pool.stats()
+    assert st["entry"] == {"state": 2, "pair": 1}
+    assert st["state_dtype"] == "float32"
+    assert st["state"] == {"rows": 5, "in_use": 0, "high_water": 0,
+                           "row_bytes": 2 * row}
+    assert state.max_window == 1 and state.int8_form is None
+    dense = state.dense_shapes(2, 16, store)
+    assert [(a.shape, a.dtype) for a in dense] == [
+        ((2, 4, 8, 16), jnp.float32), ((2, 3, 128), store)]
+    with pytest.raises(ValueError, match="s0: a state cache entry has no "
+                                         "int8 form"):
+        PagedKVPool({"s0": state}, num_blocks=4, block_size=8,
+                    max_blocks_per_request=2, kv_dtype="int8", num_rows=3)
+    with pytest.raises(ValueError, match="num_rows 1 < 2"):
+        PagedKVPool({"s0": state}, num_blocks=4, block_size=8,
+                    max_blocks_per_request=2, num_rows=1)
 
 
 def test_a_latent_row_has_no_int8_form():

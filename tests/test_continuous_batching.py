@@ -88,7 +88,8 @@ def test_paged_decode_bit_identical_per_zoo_causal_lm():
         if not any((layer.op_type is OpType.MULTIHEAD_ATTENTION
                     and layer.attrs.get("causal")
                     and len({t.tensor_id for t in layer.inputs}) == 1)
-                   or layer.op_type is OpType.LATENT_ATTENTION
+                   or layer.op_type in (OpType.LATENT_ATTENTION,
+                                        OpType.GATED_DELTA_NET)
                    for layer in probe.layers):
             continue  # not a causal LM — the generator would reject it
         probe.compile(optimizer=None, loss_type=None, metrics=[])
@@ -97,6 +98,9 @@ def test_paged_decode_bit_identical_per_zoo_causal_lm():
         gen = Generator(probe, max_length=max_len, batch_size=4)
         dec = PagedDecoder(probe, max_length=max_len, decode_slots=4,
                            block_size=8)
+        # a step over a per-request state advances it: that program run
+        # twice is two steps, not one step twice
+        repeats = not any(k.per_request for k in dec.pool.kinds.values())
         rng = np.random.default_rng(1)
         prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
                    for n in (3, 6, 2, 5)]
@@ -127,13 +131,14 @@ def test_paged_decode_bit_identical_per_zoo_causal_lm():
                     dense, paged, f"{name}: decode step {step} logits")
                 # one program, twice: the step rewrites the row it
                 # wrote, so the pool and the logits repeat bit for bit
-                again = dec.decode(toks, tables, seq_lens)[0]
-                assert np.array_equal(paged, again), \
-                    f"{name}: decode step {step} does not repeat"
+                if repeats:
+                    again = dec.decode(toks, tables, seq_lens)[0]
+                    assert np.array_equal(paged, again), \
+                        f"{name}: decode step {step} does not repeat"
                 nxt = int(dense.argmax())
             dec.pool.free(table)
         covered.append(name)
-    assert {"gpt", "latent_moe"} <= set(covered), \
+    assert {"gpt", "latent_moe", "hybrid"} <= set(covered), \
         f"zoo causal-LM sweep covered {covered}"
 
 
@@ -184,6 +189,50 @@ def test_engine_tokens_equal_sequential_static_batch(gpt, temperature):
     eng.stop()
     for out, ref in zip(outs, _reference_rows(gpt, reqs, temperature)):
         np.testing.assert_array_equal(out, ref)
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """The zoo's toy of gated-delta-rule layers among full-attention
+    ones: a state a request beside the (k, v) rows a token."""
+    ff = FFModel(FFConfig(batch_size=4, seed=0, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    zoo_smoke_builders()["hybrid"](ff, 4)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    return ff
+
+
+@pytest.mark.parametrize("temperature,group", [(0.0, 1), (0.8, 1),
+                                                (0.0, 4)])
+def test_engine_over_a_state_kind_equals_sequential(hybrid, temperature,
+                                                    group):
+    """The same promise over a mixed pool: eight requests churn three
+    slots (each slot's row serves several requests in turn), greedy and
+    sampled, admitted one prompt a dispatch and in padded groups of up
+    to four (whose dummy rows land on the null row)."""
+    vocab = int(hybrid.compiled.logits_tensor.dims[-1])
+    rng = np.random.default_rng(17)
+    reqs = [(rng.integers(0, vocab, (n,)).astype(np.int32), m)
+            for n, m in [(3, 6), (6, 2), (2, 9), (5, 1), (4, 7), (2, 3),
+                         (3, 5), (6, 4)]]
+    sched = ContinuousBatchingScheduler(
+        hybrid, max_length=32, decode_slots=3, block_size=8,
+        max_prefills_per_step=group, prefill_token_budget=32 * group)
+    futs = [sched.submit(p, m, temperature=temperature, seed=1000 + i)
+            for i, (p, m) in enumerate(reqs)]
+    outs = [f.result(timeout=120) for f in futs]
+    stats = sched.stats()
+    sched.stop()
+    for out, ref in zip(outs, _reference_rows(hybrid, reqs, temperature)):
+        np.testing.assert_array_equal(out, ref)
+    state = stats["kv"]["state"]
+    assert state["in_use"] == 0 and 1 <= state["high_water"] <= 3
+    assert stats["kv"]["in_use"] == 0
+    assert state["rows_stepped"] == 3 * stats["kv"]["blocks_in_tables"] \
+        // sched.decoder.max_blocks_per_request
+    assert stats["decode_steps"] == stats["decode_dispatches"]
+    ahead = stats["loop"]["ahead"]
+    assert (ahead["steps_ahead"] > 0) == (temperature == 0.0)
 
 
 def test_eos_retires_early(gpt):

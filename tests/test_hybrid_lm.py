@@ -1,0 +1,363 @@
+"""A causal LM of gated-delta-rule and full-attention layers
+(models/hybrid.py) through the serving path: prefill then decode through
+the mixed pool (a (k, v) pair a token beside a state a request) against
+the plain reference's full forward, what a slot's second request sees of
+the first, what idle slots touch, what the state kind refuses, and that
+the models without such a layer lower to the programs they had."""
+
+import hashlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.reference import olmo_hybrid as reference
+from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.ffconst import CompMode, DataType, LossType, MetricsType
+from flexflow_tpu.models import (HybridLMConfig, build_hybrid_lm,
+                                 zoo_smoke_builders)
+from flexflow_tpu.serving import (GenerationInstance, Generator,
+                                  PagedDecoder)
+from flexflow_tpu.serving.cache_entry import PairEntry, StateEntry
+
+LINEAR, FULL = "linear_attention", "full_attention"
+CONFIG = {
+    "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
+    "num_hidden_layers": 4, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "rms_norm_eps": 1e-6,
+    "layer_types": [LINEAR, LINEAR, FULL, LINEAR],
+    "linear_num_key_heads": 4, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True}
+SEED = 2 ** 31 + 32
+
+
+def _build(slots=3, seq=16, config=CONFIG, **ffkw):
+    ff = FFModel(FFConfig(batch_size=slots, ledger="off", seed=0,
+                          computation_mode=CompMode.INFERENCE, **ffkw))
+    build_hybrid_lm(ff, slots, seq, HybridLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        layer_types=tuple(config["layer_types"]),
+        num_heads=config["num_attention_heads"],
+        linear_heads=config["linear_num_value_heads"],
+        linear_key_dim=config["linear_key_head_dim"],
+        linear_value_dim=config["linear_value_head_dim"],
+        mlp_width=config["intermediate_size"]))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    return ff
+
+
+def _load(ff, config=CONFIG, seed=SEED):
+    """The reference's seeded weights, in float32, into the program."""
+    from benchmark.families import olmo_hybrid as family  # noqa: F401
+
+    weights = {k: v.astype(jnp.float32)
+               for k, v in reference.init_weights(config, seed).items()}
+    cm = ff.compiled
+    cm.params = jax.tree_util.tree_map(
+        jax.device_put, family.to_program(weights, config),
+        cm.param_shardings)
+    cm.bump_params_version()
+    return weights
+
+
+@pytest.fixture(scope="module")
+def model():
+    ff = _build()
+    return ff, _load(ff)
+
+
+def _serve(dec, prompt, steps, slot=1):
+    """A request's prefill and ``steps`` greedy decode steps in ``slot``,
+    the other slots idle: the logits of each, and the tokens."""
+    n = len(prompt)
+    table = dec.pool.try_admit(n + steps + 1)
+    rows, toks = [dec.prefill(prompt, table)], list(prompt)
+    slots = dec.decode_slots
+    for k in range(steps):
+        toks.append(int(rows[-1].argmax()))
+        tokens = np.zeros(slots, np.int32)
+        tables = np.zeros((slots, dec.max_blocks_per_request), np.int32)
+        lens = np.zeros(slots, np.int32)
+        tokens[slot], lens[slot], tables[slot] = toks[-1], n + k, table
+        rows.append(dec.decode(tokens, tables, lens)[slot])
+    return np.stack(rows), np.asarray(toks, np.int32), table
+
+
+def _reference_rows(weights, toks, n_rows, config=CONFIG):
+    logits = reference.forward_jit(weights, jnp.asarray(toks[None, :]),
+                                   config, "float32")
+    return np.asarray(logits)[0, len(toks) - n_rows:]
+
+
+def test_graph_and_kinds(model):
+    ff, _ = model
+    cm = ff.compiled
+    assert len(cm.input_tensors) == 1       # no positions input
+    dec = PagedDecoder(ff, 128, decode_slots=3, block_size=8)
+    kinds = dec.pool.kinds
+    assert [type(kinds[f"block{i}_mixer"]) for i in range(4)] == [
+        StateEntry, StateEntry, PairEntry, StateEntry]
+    state, tail = dec.pool.kv["block0_mixer"]
+    assert state.shape == (4, 8, 4 * 16) and state.dtype == jnp.float32
+    assert tail.shape == (4, 3 * (2 * 4 * 8 + 4 * 16))
+    k, _ = dec.pool.kv["block2_mixer"]
+    assert k.shape == (dec.pool.num_blocks, 8, 32)
+    # no model's name beyond the builder: the kinds come from the op types
+    assert dec.pool.num_rows == dec.decode_slots + 1
+
+
+@pytest.mark.parametrize("prompt_len,bucket", [(70, 96), (96, 96), (5, 16)])
+def test_prefill_then_decode_is_the_references_forward(model, prompt_len,
+                                                       bucket):
+    """Through both caches: a prompt that ends inside a chunk and inside
+    its bucket (70 of 96), one that fills its bucket, one shorter than a
+    chunk; then six decode steps."""
+    ff, weights = model
+    dec = PagedDecoder(ff, 128, decode_slots=3, block_size=8,
+                       prefill_buckets=[16, 96, 128])
+    assert dec.bucket_for(prompt_len) == bucket
+    prompt = np.random.default_rng(prompt_len).integers(
+        0, 96, size=prompt_len).astype(np.int32)
+    rows, toks, table = _serve(dec, prompt, 6)
+    dec.pool.free(table)
+    want = _reference_rows(weights, toks, len(rows))
+    spread = np.linalg.norm(want - want.mean(-1, keepdims=True), axis=-1)
+    err = np.linalg.norm(rows - want, axis=-1) / spread
+    assert err.max() < 2e-4, err
+
+
+def test_generate_equals_the_dense_generator_and_slots_are_reused(model):
+    """Seven requests over three slots: every slot serves a second and a
+    third request, each equal to the request decoded alone through the
+    dense generator (whose cache is a state and a tail a row)."""
+    ff, _ = model
+    rng = np.random.default_rng(9)
+    reqs = [(rng.integers(0, 96, (n,)).astype(np.int32), m)
+            for n, m in [(3, 6), (20, 4), (9, 9), (70, 5), (2, 3), (33, 7),
+                         (12, 8)]]
+    gen = Generator(ff, max_length=128, batch_size=1)
+    want = [gen.generate(p[None, :], m)[0] for p, m in reqs]
+    inst = GenerationInstance(ff, decode_slots=3, block_size=8,
+                              max_length=128, prefill_buckets=[16, 96, 128])
+    try:
+        futs = [inst.generate_async(p, m, temperature=0.0) for p, m in reqs]
+        got = [f.result(timeout=300) for f in futs]
+        stats = inst.stats()
+    finally:
+        inst.stop()
+    for out, ref in zip(got, want):
+        np.testing.assert_array_equal(out, ref)
+    kv = stats["kv"]
+    assert kv["entry"] == {"state": 3, "pair": 1}
+    assert kv["state_dtype"] == "float32"
+    st = kv["state"]
+    assert st["rows"] == 4 and st["in_use"] == 0 and st["high_water"] == 3
+    assert st["row_bytes"] == 3 * (8 * 64 * 4 + 3 * 128 * 4)
+    # active slots x the three state layers, summed over the steps
+    assert st["rows_stepped"] == 3 * (sum(m - 1 for _, m in reqs))
+    assert kv["in_use"] == 0
+
+
+def test_a_slots_second_request_sees_nothing_of_the_first(model):
+    ff, _ = model
+    dec = PagedDecoder(ff, 128, decode_slots=3, block_size=8,
+                       prefill_buckets=[16, 96])
+    rng = np.random.default_rng(2)
+    second = rng.integers(0, 96, size=11).astype(np.int32)
+    fresh, _, table = _serve(dec, second, 3)
+    dec.pool.free(table)
+    # a long first request leaves its state in the row the second takes
+    first = rng.integers(0, 96, size=60).astype(np.int32)
+    _, _, table = _serve(dec, first, 4)
+    row = int(dec.pool.rows_of(table[None])[0])
+    dec.pool.free(table)
+    again, _, table = _serve(dec, second, 3)
+    assert int(dec.pool.rows_of(table[None])[0]) == row
+    dec.pool.free(table)
+    assert np.array_equal(fresh, again)
+
+
+def test_idle_slots_touch_only_the_null_row(model):
+    ff, _ = model
+    dec = PagedDecoder(ff, 128, decode_slots=3, block_size=8,
+                       prefill_buckets=[16])
+    prompts = [np.arange(1, 8, dtype=np.int32),
+               np.arange(20, 30, dtype=np.int32)]
+    tables = [dec.pool.try_admit(24) for _ in prompts]
+    for p, t in zip(prompts, tables):
+        dec.prefill(p, t)
+    rows = dec.pool.rows_of(np.stack(tables))
+    assert sorted(rows) == [1, 2]
+    before = jax.device_get(dec.pool.kv["block0_mixer"])
+    # slot 0 carries the first request; slots 1 and 2 idle
+    tabs = np.zeros((3, dec.max_blocks_per_request), np.int32)
+    tabs[0] = tables[0]
+    assert dec.pool.rows_of(tabs).tolist() == [rows[0], 0, 0]
+    dec.decode(np.array([5, 0, 0], np.int32), tabs,
+               np.array([7, 0, 0], np.int32))
+    after = jax.device_get(dec.pool.kv["block0_mixer"])
+    other, free = int(rows[1]), 3
+    for a, b in zip(before, after):
+        assert np.array_equal(a[other], b[other])   # the waiting request
+        assert np.array_equal(a[free], b[free])     # the row nobody holds
+        assert not np.array_equal(a[rows[0]], b[rows[0]])
+        assert np.isfinite(b[0]).all()
+    for t in tables:
+        dec.pool.free(t)
+
+
+def test_state_kind_refuses_rollback_and_int8_by_name(model):
+    ff, _ = model
+    with pytest.raises(
+            ValueError,
+            match=r"speculative verify over a state cache entry is not "
+                  r"built \(block0_mixer and 2 more\): serve this model "
+                  r"with spec_k=0"):
+        GenerationInstance(ff, decode_slots=2, block_size=8, max_length=64,
+                           spec_k=2, draft_ff=ff)
+    with pytest.raises(ValueError, match=r"block0_mixer: a state cache "
+                                         r"entry has no int8 form"):
+        PagedDecoder(ff, 64, decode_slots=2, block_size=8, kv_dtype="int8")
+    assert StateEntry(4, 8, 16, 3, 128).max_window == 1
+    assert StateEntry(4, 8, 16, 3, 128).int8_form is None
+
+
+def test_calibration_runs_over_the_mixed_pool_and_the_state_stays_float32():
+    ff = _build()
+    _load(ff)
+    dec = PagedDecoder(ff, 64, decode_slots=2, block_size=8,
+                       kv_dtype="bfloat16", kv_divergence_budget=0.5)
+    assert dec.kv_dtype == "bfloat16" and dec.kv_quant_report is None
+    assert 0.0 < dec.kv_divergence < 0.1
+    state, tail = dec.pool.kv["block0_mixer"]
+    assert state.dtype == jnp.float32 and tail.dtype == jnp.bfloat16
+    assert dec.pool.kv["block2_mixer"][0].dtype == jnp.bfloat16
+    assert dec.pool.stats()["state"]["in_use"] == 0   # its row came back
+
+
+def test_decode_takes_both_kernels_where_both_are_supported(monkeypatch):
+    """A toy whose heads fill lane tiles for both readers: the paged
+    attention kernel over the pairs and the state kernel over the states,
+    under the interpreter, to the gather's logits."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    config = dict(CONFIG, hidden_size=128, num_attention_heads=2,
+                  num_key_value_heads=2, linear_num_key_heads=2,
+                  linear_num_value_heads=2, linear_value_head_dim=64,
+                  layer_types=[LINEAR, FULL], num_hidden_layers=2)
+
+    def run():
+        ff = _build(slots=2, config=config)
+        _load(ff, config)
+        dec = PagedDecoder(ff, 64, decode_slots=2, block_size=8,
+                           prefill_buckets=[16])
+        rows, _, table = _serve(dec, np.arange(3, 14, dtype=np.int32), 3,
+                                slot=0)
+        dec.pool.free(table)
+        return dec.attention_path["decode"], rows
+
+    path, got = run()
+    assert path == "kernel"
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    path, want = run()
+    assert path == "gather"
+    assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+
+
+def test_bfloat16_weights_are_held_once_and_declared():
+    ff = FFModel(FFConfig(batch_size=2, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    build_hybrid_lm(ff, 2, 16, HybridLMConfig(
+        vocab_size=64, hidden_size=32, num_heads=4, linear_heads=4,
+        linear_key_dim=8, linear_value_dim=16, mlp_width=64,
+        param_dtype=DataType.BFLOAT16, draw_weights=False))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    leaves = jax.tree_util.tree_leaves(ff.compiled.params)
+    assert all(isinstance(a, jax.ShapeDtypeStruct)
+               and a.dtype == jnp.bfloat16 for a in leaves)
+
+
+def test_unknown_layer_type_is_refused():
+    ff = FFModel(FFConfig(batch_size=2, ledger="off"))
+    with pytest.raises(ValueError, match="layer 1: 'sliding'"):
+        build_hybrid_lm(ff, 2, 16, HybridLMConfig(
+            layer_types=(LINEAR, "sliding")))
+
+
+# ---- the models without such a layer keep their programs ------------------------
+
+# sha256 of the lowered text (``jit(...).lower(...).as_text()``) of each
+# program, recorded on the commit before the state kind (543e5ce): the new
+# argument (``Addresses`` with no rows), ``qk_norm`` and the optional
+# positions leave GPT-2's and the latent model's programs letter for
+# letter what they were. After a change that is meant to move one of
+# them, print the new ones: ``python tests/test_hybrid_lm.py``.
+RECORDED = {
+    "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
+    "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
+    "gpt.train": "170e41925d7e8ba1d46f71f4f6274b2560128cbf591b30200fc1cea3b172dc38",
+    "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
+    "latent_moe.prefill": "43e30c908af85f9f65457976171f1f762c7a454a0f807318b4c7e2a3e4a6dbd4",
+    "latent_moe.train": "71272d740bed1d84a16644e19412122af40d6a7251a94171bc5aab6e2c515ebe",
+}
+
+
+def _lowered(name: str) -> str:
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    model, program = name.split(".")
+    build = zoo_smoke_builders()[model]
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    if program == "train":
+        ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
+                              search_cache="off"))
+        build(ff, 2)
+        ff.compile(optimizer=AdamOptimizer(alpha=1e-3),
+                   loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+                   metrics=[MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY])
+        spec = [s for s in ff.compiled.audit_exec
+                if s.name == "train_step"][0]
+        args = list(spec.args)           # labels a token, not a row
+        args[-1] = jax.ShapeDtypeStruct(
+            tuple(ff.compiled.input_tensors[0].dims), jnp.int32)
+        return spec.fn.lower(*args).as_text()
+    ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
+                          search_cache="off",
+                          computation_mode=CompMode.INFERENCE))
+    build(ff, 2)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    dec = PagedDecoder(ff, 32, decode_slots=2, block_size=8,
+                       prefill_buckets=[16])
+    lens = jax.ShapeDtypeStruct((2,), jnp.int32)
+
+    def addr(n):
+        return Addresses(jax.ShapeDtypeStruct(
+            (n, dec.max_blocks_per_request), jnp.int32), None)
+
+    if program == "decode":
+        return dec._decode.lower(
+            dec._params_sds(), lens, sds(dec.pool.kv), addr(2), lens,
+            sds(dec._expert_acc), lens,
+            jax.ShapeDtypeStruct((2,), jnp.bool_)).as_text()
+    return dec._prefill_fn(16, 1).lower(
+        dec._params_sds(), jax.ShapeDtypeStruct((1, 16), jnp.int32),
+        sds(dec.pool.kv), addr(1),
+        jax.ShapeDtypeStruct((1,), jnp.int32)).as_text()
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_programs_without_a_state_lower_to_the_text_they_had(name):
+    assert hashlib.sha256(_lowered(name).encode()).hexdigest() \
+        == RECORDED[name]
+
+
+if __name__ == "__main__":
+    for name in sorted(RECORDED):
+        print(f'    "{name}": '
+              f'"{hashlib.sha256(_lowered(name).encode()).hexdigest()}",')

@@ -7,7 +7,7 @@ import pytest
 import jax.numpy as jnp
 
 from flexflow_tpu.obs.metrics import metrics_registry
-from flexflow_tpu.serving.cache_entry import PairEntry
+from flexflow_tpu.serving.cache_entry import PairEntry, StateEntry
 from flexflow_tpu.serving.errors import KVPoolExhausted, ShedError
 from flexflow_tpu.serving.kv_cache import NULL_BLOCK, PagedKVPool
 
@@ -104,3 +104,72 @@ def test_double_free_is_loud():
     p.free(t)
     with pytest.raises(RuntimeError, match="double free"):
         p.free(t)
+
+
+# ---- per-request rows beside the blocks ------------------------------------------
+
+def _mixed(num_rows=3, **kw):
+    return PagedKVPool({"attn": PairEntry(2, 8),
+                        "mix": StateEntry(2, 8, 64, 3, 160)},
+                       num_blocks=9, block_size=4, max_blocks_per_request=4,
+                       num_rows=num_rows, **kw)
+
+
+def test_admission_reserves_blocks_and_a_row_or_neither():
+    p = _mixed()                         # two rows beside the null row
+    a, b = p.try_admit(8), p.try_admit(4)
+    assert sorted(p.rows_of(np.stack([a, b])).tolist()) == [1, 2]
+    assert p.stats()["state"]["in_use"] == 2
+    used = p.in_use()
+    # blocks are left (5 of 8) and no row is: nothing is taken
+    assert p.try_admit(4) is None
+    assert p.in_use() == used and p.stats()["state"]["in_use"] == 2
+    p.free(a)
+    assert p.in_use() == used - 2 and p.stats()["state"]["in_use"] == 1
+    # a row is left and too few blocks are: nothing is taken either
+    c = p.try_admit(16)
+    assert p.in_use() == 1 + 4
+    assert p.try_admit(16) is None
+    assert p.stats()["state"]["in_use"] == 2
+    p.free(b)
+    p.free(c)
+    st = p.stats()
+    assert st["in_use"] == 0 and st["state"]["in_use"] == 0
+    assert st["state"]["high_water"] == 2 and st["state"]["rows"] == 3
+
+
+def test_an_idle_table_names_the_null_row_and_a_freed_one_too():
+    p = _mixed()
+    t = p.try_admit(8)
+    idle = np.zeros_like(t)
+    assert p.rows_of(np.stack([idle, t, idle])).tolist() == [0, 1, 0]
+    p.free(t)
+    assert p.rows_of(t[None]).tolist() == [0]
+    # a pool without per-request kinds has no rows to name
+    assert _pool().rows_of(t[None]) is None and _pool().num_rows == 0
+    assert "state" not in _pool().stats()
+
+
+def test_double_free_of_a_row_is_loud_and_takes_nothing():
+    p = _mixed()
+    t = p.try_admit(8)
+    p.free(t)
+    with pytest.raises(RuntimeError, match="double free: the request of "
+                                           "block .* holds no state row"):
+        p.free(t)
+    assert p.in_use() == 0 and p.stats()["state"]["in_use"] == 0
+    # both rows can still be taken, once each
+    assert sorted(p.rows_of(np.stack([p.try_admit(4),
+                                      p.try_admit(4)])).tolist()) == [1, 2]
+
+
+def test_pool_bytes_has_a_term_a_token_and_a_term_a_request():
+    from flexflow_tpu.serving.kv_cache import pool_bytes
+
+    p = _mixed(num_rows=5, kv_dtype="bfloat16")
+    per_token = 2 * 2 * 8 * 2
+    per_row = 8 * 2 * 64 * 4 + 3 * 160 * 2     # the state stays float32
+    assert p.memory_bytes() == 9 * 4 * per_token + 5 * per_row
+    assert pool_bytes(p.specs, 9, 4, "bfloat16", num_rows=5) \
+        == p.memory_bytes()
+    assert pool_bytes(p.specs, 9, 4, "bfloat16") == 9 * 4 * per_token

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from flexflow_tpu.kernels import paged_attention
 from flexflow_tpu.ops.attention import MultiHeadAttention
 from flexflow_tpu.serving.cache_entry import PairEntry
-from flexflow_tpu.serving.kv_cache import NULL_BLOCK
+from flexflow_tpu.serving.kv_cache import NULL_BLOCK, Addresses
 
 HEAD_DIM, BLOCK, MAX_BLOCKS = 64, 16, 20
 MAX_LENGTH = BLOCK * MAX_BLOCKS          # 320 tokens: more than one chunk
@@ -38,6 +38,7 @@ class _Op:
     own projections), with identity weights so the test drives q, k and
     v directly."""
     use_bias = False
+    qk_norm = False
     head_dim = HEAD_DIM
     scale = MultiHeadAttention.scale
     project_qkv = MultiHeadAttention.project_qkv
@@ -86,7 +87,8 @@ def _run(monkeypatch, mode, heads, dtype, window):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
     x, entry, tables, lens = _case(heads, dtype, window)
     out, new_entry = PairEntry(heads, HEAD_DIM).step(
-        _Op(), _identity_weights(heads, dtype), x, None, entry, tables, lens)
+        _Op(), _identity_weights(heads, dtype), x, None, entry,
+        Addresses(tables), lens)
     return np.asarray(out, np.float32), new_entry, np.asarray(lens)
 
 

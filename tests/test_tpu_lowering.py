@@ -79,3 +79,35 @@ def test_flash_attention_compiles_for_v5e(monkeypatch, one_chip,
             assert name in text, (q_shape, name)
         (b, sq, h, _), skv = q_shape, k_shape[1]
         assert f"[{b},{h},{sq},{skv}]" not in text  # the scores of a head
+
+
+def test_gated_delta_decode_compiles_for_v5e(monkeypatch, one_chip,
+                                             no_compile_cache):
+    """The state-update kernel at the published widths of the hybrid
+    configuration (30 heads, keys of 96, values of 192: pairs of heads
+    over three lane tiles), 32 slots over 33 rows: one Mosaic custom
+    call, the arena aliased through it, nothing beside it on the device
+    but what it is given."""
+    from flexflow_tpu.kernels import gated_delta as gd
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    n, rows, h, dk, dv = 32, 33, 30, 96, 192
+    assert gd.supported(n, h, dk, dv, (rows, dk, h * dv), jnp.float32)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(arena, slot_rows, q, k, v, alpha, beta):
+        return gd.gated_delta_decode(arena, slot_rows, q, k, v, alpha, beta)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        sds((rows, dk, h * dv)), sds((n,), jnp.int32), sds((n, h, dk)),
+        sds((n, h, dk)), sds((n, h, dv)), sds((n, h)), sds((n, h))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gated_delta_decode" in text
+    mem = compiled.memory_analysis()
+    arena_bytes = rows * dk * h * dv * 4
+    # the donated arena goes in and comes out as one buffer
+    assert mem.alias_size_in_bytes >= arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes // 8
