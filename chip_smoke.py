@@ -565,6 +565,59 @@ def check_gated_delta(slots: int, heads: int, key_dim: int, value_dim: int,
     return err
 
 
+# The whole-sequence kernel's products are float32 at float32 contract
+# precision, as its jnp form's are at `highest`; they differ by the order
+# of sums and by how each inverts a chunk's unit-lower system (doubling
+# blocks against rows). bfloat16 operands would read some 1e-2.
+GATED_DELTA_CHUNKS_RANGE_TOL = 1e-4
+
+
+def check_gated_delta_chunks(seq: int, heads: int, key_dim: int,
+                             value_dim: int, mosaic: bool) -> Dict[str, float]:
+    """What a gated-delta-rule layer runs between its convolution and
+    its gate, the kernel's way (``ops/gated_delta.py`` ``fused_rule``:
+    unit q and k, the whole-sequence recurrence, RMSNorm, one kernel)
+    against the jnp way (``scan_rule``, around ``chunked_delta_rule``)
+    over one row of ``seq`` tokens behind a state that is not zero:
+    decays in (0.8, 1), beta in (0, 2). Returns the largest error of the
+    normalised o and of the state after the sequence, each as a share of
+    its reference's largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels import gated_delta as gd
+    from flexflow_tpu.ops.gated_delta import fused_rule, scan_rule
+
+    _require(gd.chunks_supported(seq, heads, key_dim, value_dim, jnp.float32),
+             f"gated_delta.chunks_supported() refuses {seq} tokens of "
+             f"{heads} heads of {key_dim} and {value_dim}")
+    rng = np.random.default_rng(0)
+    q, k = (rng.normal(size=(1, seq, heads * key_dim)).astype(np.float32)
+            for _ in range(2))
+    v = rng.normal(size=(1, seq, heads * value_dim)).astype(np.float32)
+    g = np.log(rng.uniform(0.8, 1.0, size=(1, seq, heads))).astype(np.float32)
+    beta = rng.uniform(0.0, 2.0, size=(1, seq, heads)).astype(np.float32)
+    state = 0.1 * rng.normal(
+        size=(1, heads, key_dim, value_dim)).astype(np.float32)
+    gain = rng.uniform(0.5, 1.5, size=(value_dim,)).astype(np.float32)
+    args = tuple(map(jnp.asarray, (q, k, v, g, beta, state, gain)))
+    got_fn = jax.jit(lambda *a: fused_rule(1e-6, *a))
+    if mosaic:
+        _assert_mosaic(got_fn, *args)
+    got = got_fn(*args)
+    want = jax.jit(lambda *a: scan_rule(1e-6, *a))(*args)
+    errs = {}
+    for name, a, r in zip(("o", "state"), got, want):
+        a, r = np.asarray(a), np.asarray(r)
+        _require(np.isfinite(a).all(), f"gated delta chunks: non-finite {name}")
+        errs[name] = float(np.max(np.abs(a - r)) / np.max(np.abs(r)))
+        _require(errs[name] <= GATED_DELTA_CHUNKS_RANGE_TOL,
+                 f"gated delta chunks ({seq} tokens, {heads} heads): {name} "
+                 f"max error {errs[name]:.2e} of range > "
+                 f"{GATED_DELTA_CHUNKS_RANGE_TOL}")
+    return errs
+
+
 def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     from flexflow_tpu.kernels import pallas_mode
 
@@ -592,6 +645,11 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     # 96, values of 192) and its 32 slots; 4 under the interpreter
     errs["gated_delta"] = "%.1e" % check_gated_delta(
         32 if mosaic else 4, 30, 96, 192, mosaic)
+    # and its prefill at the cell's widest bucket (a chunk and a partial
+    # one of a group and a half of heads under the interpreter)
+    e = check_gated_delta_chunks(*((1536, 30) if mosaic else (150, 6)),
+                                 96, 192, mosaic)
+    errs.update({f"gated_delta_chunks_{k}": f"{v:.1e}" for k, v in e.items()})
     return ph.report(interpret=not mosaic, flash_shape=shape,
                      moe_shape=sizes.moe, **errs)
 
@@ -611,6 +669,54 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
 # position moves them by that much.
 SERVE_LOGIT_ATOL = 0.05
 KV_DTYPE = "bfloat16"
+
+
+def serve_hybrid() -> Dict[str, str]:
+    """A small hybrid of one gated-delta-rule and one full-attention
+    layer (4 linear heads of 32 and 64, 2 heads of 128: widths both
+    kinds' kernels take) through ``GenerationInstance``: three greedy
+    requests over two slots and three prefill buckets. Returns how its
+    programs ran: on the chip the prefills must take the whole-sequence
+    kernel and the decode step read both caches in place."""
+    import jax
+
+    from flexflow_tpu import FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.models import HybridLMConfig, build_hybrid_lm
+    from flexflow_tpu.serving import GenerationInstance
+
+    slots, vocab, max_length = 2, 512, 288
+    ff = FFModel(_ff_config(batch_size=slots,
+                            computation_mode=CompMode.INFERENCE))
+    build_hybrid_lm(ff, slots, max_length, HybridLMConfig(
+        vocab_size=vocab, hidden_size=256, num_heads=2, linear_heads=4,
+        layer_types=("linear_attention", "full_attention"),
+        linear_key_dim=32, linear_value_dim=64, mlp_width=512))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    inst = GenerationInstance(ff, decode_slots=slots, max_length=max_length,
+                              kv_dtype=KV_DTYPE,
+                              prefill_buckets=[96, 160, max_length])
+    try:
+        rng = np.random.default_rng(1)
+        reqs = [(rng.integers(0, vocab, n).astype(np.int32), m)
+                for n, m in ((20, 6), (150, 5), (200, 4))]
+        futures = [inst.generate_async(p, m, temperature=0.0)
+                   for p, m in reqs]
+        for (prompt, m), fut in zip(reqs, futures):
+            out = fut.result(timeout=900)
+            _require(out.shape == (prompt.size + m,)
+                     and np.array_equal(out[:prompt.size], prompt),
+                     f"hybrid request of {prompt.size}+{m} tokens returned "
+                     f"{out.shape}")
+        kv = inst.stats()["kv"]
+    finally:
+        inst.stop()
+    paths = {"hybrid_prefill_path": kv["state"]["prefill_path"],
+             "hybrid_attention_path": kv["attention_path"]["decode"]}
+    _require(set(paths.values()) == {"kernel"}
+             or jax.default_backend() != "tpu",
+             f"the hybrid's programs took {paths}")
+    return paths
 
 
 def phase_serve(sizes: SmokeSizes) -> Dict:
@@ -714,6 +820,7 @@ def phase_serve(sizes: SmokeSizes) -> Dict:
     finally:
         inst.stop()
     return ph.report(
+        **serve_hybrid(),
         requests=sizes.requests, tokens=st["tokens"],
         decode_steps=st["decode_steps"],
         prefill_dispatches=st["prefill_dispatches"],

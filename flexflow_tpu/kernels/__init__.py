@@ -15,6 +15,12 @@ that keep the working set in VMEM and feed the MXU directly:
   (serving/generation.py falls back to its jnp gather for what
   ``supported()`` refuses: int8 arenas, widths that are no whole lane
   tiles, the CPU).
+* :mod:`gated_delta` — the gated delta rule of a linear-attention layer:
+  the decode step over a pool's per-request states in place, and the
+  whole-sequence form of a prefill as one kernel a layer, the state in
+  VMEM from the first chunk to the last (``ops/gated_delta.py`` keeps
+  the jnp forms, taken where ``supported()`` / ``chunks_supported()``
+  refuse).
 * :mod:`moe_kernels` — row gather / weighted row-gather-sum with
   scalar-prefetched indices, realizing the MoE dispatch/combine data
   movement (reference: src/ops/group_by.cu, aggregate.cu scatter kernels)

@@ -1,5 +1,6 @@
-"""The gated delta rule's decode step as a Pallas TPU kernel: every
-active slot's state read once, updated, and written back in place.
+"""The gated delta rule as Pallas TPU kernels: the decode step (every
+active slot's state read once, updated, and written back in place) and,
+further down, the whole-sequence form a prefill runs.
 
 A gated-delta-rule op (ops/gated_delta.py ``GatedDeltaNet``) keeps, for a
 request, one float32 state ``S`` of ``(d_k, d_v)`` a head. The serving
@@ -31,11 +32,53 @@ memory bounds it. The kernel:
 :func:`gated_delta_step` is the jnp form (:func:`delta_rule_step` over
 gathered rows, scattered back): the kernel's reference, and what runs
 where :func:`supported` refuses.
+
+:func:`gated_delta_chunks` is the whole-sequence form
+(``ops/gated_delta.py`` ``chunked_delta_rule`` is its jnp form, its
+reference and what runs where :func:`chunks_supported` refuses). It is
+matrix products, ``ROWS`` tokens at a time: within a chunk the updates
+``u_t = beta_t (v_t - alpha_t S_{t-1}^T k_t)`` solve a unit
+lower-triangular system that does not involve the incoming state, and
+between chunks the state is carried. The kernel:
+
+* grids over (row of the batch, group of heads, chunk), the chunks in
+  turn, the group's states in a float32 VMEM scratch from the first chunk
+  (read from the incoming state) to the last (written out once): the
+  state never goes through HBM between chunks;
+* takes q, k, v as ``(B, S, H d)``, heads side by side on the lanes as
+  the projections and the convolution write them, and writes o the same
+  way: no transpose through HBM. A group is the fewest heads whose keys
+  AND values fill whole lane tiles (4 at widths 96 and 192); a head's
+  window of whole tiles is loaded, rotated to lane 0 and masked, so
+  every product sees whole tiles with zeros in the padding. Where the
+  heads are no multiple of the group the last grid step's block hangs
+  over the arrays' edge: what it reads there is never selected and what
+  it writes there is dropped;
+* makes what a chunk needs in VMEM from that chunk's q, k, v, beta and
+  log-decay (summed from the chunk's start by XLA before the call: (B, S,
+  H), the one thing not made here): the decay of every pair of
+  positions, ``k k^T``, ``q k^T``, the system's inverse, u, o and the new
+  state. The inverse is built by block substitution in doubling blocks
+  (:func:`_unit_lower_inverses`), never by a series in powers of the
+  system; the 0/1 tiles that pick a level's blocks are the same for
+  every chunk and head, so XLA makes them once a call
+  (:func:`_tile_masks`) and they stay in VMEM;
+* goes through a group's heads stage by stage, not head by head: a head
+  is a chain of dependent products, and four chains side by side keep the
+  matrix unit fed where one leaves it waiting;
+* can make the two norms the op computes around the recurrence (unit q
+  and k a head, RMSNorm of o a head) on the head's tile, where each is a
+  lane sum; XLA reduces over a head's lanes of a ``(B, S, H d)`` array by
+  transposing the whole array and back;
+* computes in float32 throughout, every product at float32 contract
+  precision (``highest``): Mosaic's default for float32 operands is bf16
+  passes.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +86,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import pallas_mode
-from .flash_attention import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
+from .flash_attention import _NT, VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
 from .moe_kernels import SMEM_BUDGET_BYTES
+from .paged_attention import _round_up
 
 LANES = 128
 
@@ -162,6 +206,324 @@ def gated_delta_decode(arena, rows, q, k, v, alpha, beta):
     return o.reshape(n, heads, value_dim), arena
 
 
+# ---- the whole-sequence form: a chunk of tokens a grid step -----------------
+
+# tokens a grid step takes: one unit-lower system of ROWS x ROWS a head.
+# 128 fills the matrix unit's tiles where ops/gated_delta.py's CHUNK of
+# 64 half-fills them (PERF.md section 6, PR 33: the table on the chip)
+ROWS = 128
+# sequences from which the kernel beats the jnp scan on a v5e: one chunk
+# of the scan's (64 tokens) or less is one short loop there and one
+# padded chunk of ROWS here (PERF.md section 6, PR 33: the table)
+MIN_SEQ = 65
+# the body unrolls a group of heads; past this many no group is built
+MAX_GROUP = 8
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _whole_tiles(width: int) -> int:
+    """Heads of ``width`` lanes that fill whole lane tiles side by side."""
+    return LANES // math.gcd(width, LANES)
+
+
+def _chunk_group(key_dim: int, value_dim: int) -> int:
+    """Heads a grid step takes: the fewest whose keys AND values fill
+    whole lane tiles of the (B, S, H d) operands (4 at 96 and 192)."""
+    return math.lcm(_whole_tiles(key_dim), _whole_tiles(value_dim))
+
+
+def _chunks_vmem_bytes(group: int, key_dim: int, value_dim: int) -> int:
+    """The working set of a grid step: q, k, v in and o out and the
+    chunk's constant tiles twice (the pipeline's two buffers), the
+    states in, out and in the scratch, and each head's (ROWS, ROWS) and
+    (2 ROWS, lanes) temporaries (the heads go stage by stage, so all of
+    them live at once), all float32."""
+    kp, vp = _round_up(key_dim, LANES), _round_up(value_dim, LANES)
+    blocks = 2 * ROWS * group * (2 * key_dim + 2 * value_dim)
+    masks = 2 * (ROWS.bit_length() + 1) * ROWS * ROWS
+    states = group * (4 * key_dim * value_dim + kp * vp)
+    temps = group * (8 * ROWS * ROWS + 6 * ROWS * (kp + vp))
+    return 4 * (blocks + masks + states + temps)
+
+
+def chunks_supported(seq: int, heads: int, key_dim: int, value_dim: int,
+                     dtype) -> bool:
+    """Whether :func:`gated_delta_chunks` takes these shapes: more than
+    one chunk of the jnp scan's, float32, keys that fill whole sublane
+    tiles of a state, heads that group into whole lane tiles (values in
+    whole groups: a tile two heads share is written by both), a group
+    the body can unroll, a working set within the VMEM budget."""
+    if pallas_mode() is None or seq < MIN_SEQ:
+        return False
+    if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
+        return False
+    if key_dim % 8 or heads % _whole_tiles(value_dim):
+        return False
+    group = _chunk_group(key_dim, value_dim)
+    if group > MAX_GROUP:
+        return False
+    return _chunks_vmem_bytes(group, key_dim, value_dim) <= VMEM_BUDGET_BYTES
+
+
+def _mm(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A float32 product at float32 contract precision (Mosaic's default
+    is bf16 passes even for float32 operands)."""
+    return jax.lax.dot_general(a, b, dims, precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _take(ref, start: int, width: int):
+    """Lanes ``[start, start + width)`` of a (rows, lanes) ref as whole
+    lane tiles: moved to lane 0, zeros after them. The head's window of
+    whole tiles is loaded, rotated, cut and masked (a select, so that
+    what lies beside the head, another head or a boundary block's
+    padding, does not reach the result)."""
+    lo, hi = start // LANES * LANES, _round_up(start + width, LANES)
+    x = ref[:, lo:hi]
+    if start != lo:
+        x = pltpu.roll(x, (hi - lo) - (start - lo), 1)
+    x = x[:, :_round_up(width, LANES)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane < width, x, 0.0)
+
+
+def _put(tiles, x, start: int, width: int):
+    """:func:`_take` backwards: ``x`` (rows, whole tiles; zeros past
+    ``width``) added into the lane tiles ``tiles`` (a list) at lanes
+    ``[start, start + width)``."""
+    lo, hi = start // LANES * LANES, _round_up(start + width, LANES)
+    if hi - lo > x.shape[1]:
+        x = jnp.concatenate(
+            [x, jnp.zeros((x.shape[0], hi - lo - x.shape[1]), x.dtype)], 1)
+    if start != lo:
+        x = pltpu.roll(x, start - lo, 1)
+    for n in range((hi - lo) // LANES):
+        part, at = x[:, n * LANES:(n + 1) * LANES], lo // LANES + n
+        tiles[at] = part if tiles[at] is None else tiles[at] + part
+
+
+def _tile_masks(rows: int):
+    """The constant 0/1 tiles of a chunk, float32 (3 + levels, rows,
+    rows) over (t, i): ``t >= i``; ``t > i``; ``t`` and ``i`` in one pair;
+    then for each level l >= 1 the entries that join two diagonal blocks
+    of 2^l into one of 2^(l+1) (same block of 2^(l+1), other block of
+    2^l). Made once a call by XLA and held in VMEM for the whole grid: a
+    mask made in the kernel is integer work on every head of every
+    chunk."""
+    t = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+    masks = [t >= i, t > i, (t >> 1) == (i >> 1)]
+    for level in range(1, rows.bit_length() - 1):
+        masks.append(((t >> level + 1) == (i >> level + 1))
+                     & ((t >> level) != (i >> level)))
+    return jnp.stack(masks).astype(jnp.float32)
+
+
+def _unit_lower_inverses(lows, m_ref):
+    """``(I + low)^-1`` for each strictly lower-triangular tile of
+    ``lows``, by block substitution in doubling blocks: with ``T`` the
+    inverse of the diagonal blocks of size s and ``B`` the part of
+    ``low`` that joins two neighbours into one block of 2 s, the inverse
+    of those blocks is ``T - T B T`` (the 2 x 2 block formula). Blocks of
+    2 need no product. Only the later half of each block of 2 s changes,
+    so from s = 8 (a sublane tile) the two products run over those rows
+    alone, gathered tile by tile. Every step is a substitution, so
+    nothing is lost where a series in powers of ``low`` cancels (keys
+    nearly parallel, beta near 2). The tiles are independent chains of
+    dependent products: they go level by level side by side, so that one
+    tile's products fill the matrix unit while another's drain."""
+    rows = lows[0].shape[0]
+    eye = m_ref[0] - m_ref[1]
+    invs = [eye - low * m_ref[2] for low in lows]
+    for level in range(1, rows.bit_length() - 1):
+        s, joins = 1 << level, m_ref[2 + level]
+        if s < 8:
+            invs = [inv - _mm(inv, _mm(low * joins, inv))
+                    for low, inv in zip(lows, invs)]
+            continue
+        later = range(s, rows, 2 * s)
+        gather = lambda x: jnp.concatenate(  # noqa: E731
+            [x[p:p + s] for p in later], axis=0)
+        zeros = jnp.zeros((s, rows), jnp.float32)
+        through = [_mm(gather(low * joins), inv)          # (rows / 2, rows)
+                   for low, inv in zip(lows, invs)]
+        change = [_mm(gather(inv), jnp.concatenate(
+            [part for n in range(len(later))
+             for part in (zeros, thr[n * s:(n + 1) * s])], axis=0))
+            for inv, thr in zip(invs, through)]
+        invs = [jnp.concatenate(
+            [part for n, p in enumerate(later)
+             for part in (inv[p - s:p],
+                          inv[p:p + s] - chg[n * s:(n + 1) * s])], axis=0)
+            for inv, chg in zip(invs, change)]
+    return invs
+
+
+def _chunks_kernel(q_ref, k_ref, v_ref, gb_ref, gt_ref, m_ref, s0_ref, *rest,
+                   group, key_dim, value_dim, unit_eps, norm_eps):
+    gain_ref = rest[0] if norm_eps is not None else None
+    o_ref, s_out, s_scr = rest[-3:]
+    c = pl.program_id(2)
+    rows = q_ref.shape[0]
+    kp = s_scr.shape[1]
+    heads = range(group)
+
+    @pl.when(c == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+        for j in heads:
+            s_scr[j, :key_dim, :value_dim] = s0_ref[j]
+
+    # stage by stage over the group's heads, not head by head: the heads
+    # are independent, and each stage of one is a chain the next waits for
+    qs = [_take(q_ref, j * key_dim, key_dim) for j in heads]     # (rows, kp)
+    ks = [_take(k_ref, j * key_dim, key_dim) for j in heads]
+    vs = [_take(v_ref, j * value_dim, value_dim) for j in heads]  # (rows, vp)
+    if unit_eps is not None:      # GatedDeltaNet.heads: unit q and k a head
+        unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(a * a, axis=1, keepdims=True) + unit_eps)
+        qs = [unit(q) * key_dim ** -0.5 for q in qs]
+        ks = [unit(k) for k in ks]
+    # gc: the cumulative log-decay from the chunk's start, down the
+    # sublanes and along the lanes; beta down the sublanes
+    gcs = [gb_ref[:, j:j + 1] for j in heads]                     # (rows, 1)
+    betas = [gb_ref[:, group + j:group + j + 1] for j in heads]
+    # decay[t, i] = prod_{i < j <= t} alpha_j, for i <= t (0 above)
+    decays = [jnp.exp(jnp.minimum(gc - gt_ref[j:j + 1, :], 0.0)) * m_ref[0]
+              for j, gc in zip(heads, gcs)]
+    kqs = [_mm(jnp.concatenate([k, q], axis=0), k, _NT)       # (2 rows, rows)
+           for k, q in zip(ks, qs)]
+    invs = _unit_lower_inverses(
+        [beta * kq[:rows] * decay * m_ref[1]
+         for beta, kq, decay in zip(betas, kqs, decays)], m_ref)
+    sinces = [jnp.exp(gc) for gc in gcs]    # the decay since the chunk's start
+    # u_t = uv_t - w_t S_0: what position t adds to the state as k_t u_t^T
+    wus = [_mm(inv, jnp.concatenate([(beta * since) * k, beta * v], axis=1))
+           for inv, beta, since, k, v in zip(invs, betas, sinces, ks, vs)]
+    states = [s_scr[j] for j in heads]
+    againsts = [_mm(jnp.concatenate([wu[:, :kp], since * q], axis=0), state)
+                for wu, since, q, state in zip(wus, sinces, qs, states)]
+    us = [wu[:, kp:] - against[:rows] for wu, against in zip(wus, againsts)]
+    # the whole chunk's log-decay, (1, 1): summed out of the lanes' form
+    # (a slice of the sublanes' form sits on sublane 7, and Mosaic
+    # broadcasts along one of sublanes and lanes at a time)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) == rows - 1
+    ends = [jnp.sum(jnp.where(last, gt_ref[j:j + 1, :], 0.0), axis=1,
+                    keepdims=True) for j in heads]
+    throughs = [_mm(jnp.concatenate(
+        [kq[rows:] * decay, (jnp.exp(end - gc) * k).T], axis=0), u)
+        for kq, decay, end, gc, k, u in zip(kqs, decays, ends, gcs, ks, us)]
+    tiles = [None] * (group * value_dim // LANES)
+    for j in heads:
+        s_scr[j] = jnp.exp(ends[j]) * states[j] + throughs[j][rows:]
+        o = againsts[j][rows:] + throughs[j][:rows]
+        if norm_eps is not None:  # GatedDeltaNet.finish: RMSNorm a head
+            o = o * jax.lax.rsqrt(jnp.sum(o * o, axis=1, keepdims=True)
+                                  * (1.0 / value_dim) + norm_eps) * gain_ref[...]
+        _put(tiles, o, j * value_dim, value_dim)
+    for n, tile in enumerate(tiles):
+        o_ref[:, n * LANES:(n + 1) * LANES] = tile
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        for j in heads:
+            s_out[j] = s_scr[j, :key_dim, :value_dim]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "rows", "unit_eps", "norm_eps", "interpret"))
+def _gated_delta_chunks(q, k, v, g, beta, state, gain=None, *, heads, rows,
+                        unit_eps=None, norm_eps=None, interpret):
+    b, s, _ = q.shape
+    key_dim, value_dim = q.shape[-1] // heads, v.shape[-1] // heads
+    group = _chunk_group(key_dim, value_dim)
+    groups = -(-heads // group)
+    pad = -s % rows
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                            for a in (q, k, v, g, beta))
+    n = (s + pad) // rows
+    # the log-decay summed from each chunk's start: (B, S, H), the one
+    # thing of a chunk made before the call (its (rows, rows) decay, its
+    # system and the system's inverse are made in VMEM)
+    gc = jnp.cumsum(g.reshape(b, n, rows, heads), axis=2)
+
+    def grouped(a):               # (B, n, rows, H) -> (B, groups, n, rows, group)
+        a = jnp.pad(a, ((0, 0),) * 3 + ((0, groups * group - heads),))
+        return a.reshape(b, n, rows, groups, group).transpose(0, 3, 1, 2, 4)
+
+    gc = grouped(gc)
+    lanes = lambda d: pl.BlockSpec(  # noqa: E731
+        (None, rows, group * d), lambda bi, gi, ci: (bi, ci, gi))
+    gates = lambda *blk: pl.BlockSpec(  # noqa: E731
+        (None, None, None) + blk, lambda bi, gi, ci: (bi, gi, ci, 0, 0))
+    masks = _tile_masks(rows)
+    states = pl.BlockSpec((None, group, key_dim, value_dim),
+                          lambda bi, gi, ci: (bi, gi, 0, 0))
+    vp = _round_up(value_dim, LANES)
+    normed = () if norm_eps is None else (jnp.pad(
+        gain.astype(jnp.float32), (0, vp - value_dim)).reshape(1, vp),)
+    o, state = pl.pallas_call(
+        functools.partial(_chunks_kernel, group=group, key_dim=key_dim,
+                          value_dim=value_dim, unit_eps=unit_eps,
+                          norm_eps=norm_eps),
+        grid=(b, groups, n),
+        in_specs=[lanes(key_dim), lanes(key_dim), lanes(value_dim),
+                  gates(rows, 2 * group), gates(group, rows),
+                  pl.BlockSpec(masks.shape, lambda bi, gi, ci: (0, 0, 0)),
+                  states] + [pl.BlockSpec((1, vp), lambda bi, gi, ci: (0, 0))
+                             for _ in normed],
+        out_specs=[lanes(value_dim), states],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM(
+            (group, _round_up(key_dim, LANES), vp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="gated_delta_chunks",
+    )(q, k, v, jnp.concatenate(
+        [gc, grouped(beta.reshape(b, n, rows, heads))], axis=-1),
+      gc.transpose(0, 1, 2, 4, 3), masks, state, *normed)
+    return o[:, :s], state
+
+
+def gated_delta_chunks(q, k, v, g, beta, state, *, unit_eps=None, norm=None):
+    """The gated delta rule over whole sequences, as
+    ``ops/gated_delta.py`` ``chunked_delta_rule`` takes and returns them
+    (``q``, ``k`` (B, S, H, d_k), ``v`` (B, S, H, d_v), ``g`` = log alpha
+    and ``beta`` (B, S, H), ``state`` (B, H, d_k, d_v), float32), one
+    kernel call: grid over (row, group of heads, chunk of ``ROWS``
+    tokens), the chunks in turn, the group's states in a VMEM scratch
+    from the first chunk to the last. q, k, v may as well come flat, (B,
+    S, H d) as the projections and the convolution wrote them, heads side
+    by side on the lanes, which is how the kernel reads them; o comes
+    back in v's form. The chunk's decay, its unit-lower system and the
+    system's inverse exist only in VMEM.
+
+    Two things the op does around the recurrence reduce over a head's
+    lanes, which XLA does by transposing the whole (B, S, H d) array
+    and back; on a head's tile in VMEM they are a lane sum. ``unit_eps``:
+    q and k arrive as the convolution wrote them and each head's is
+    L2-normalised here, ``a * rsqrt(sum(a^2) + unit_eps)``, q then scaled
+    by ``d_k^-1/2`` (``GatedDeltaNet.heads``). ``norm`` = (gain (d_v,),
+    eps): o leaves RMS-normalised over each head's d_v, times the gain
+    (``GatedDeltaNet.finish``'s first step).
+
+    Callers check :func:`chunks_supported` first. The call is jitted on
+    its own, so that the layers of a model trace it once a shape."""
+    b, s, heads = g.shape
+    f32 = jnp.float32
+    flat = lambda a: a.astype(f32).reshape(b, s, -1)  # noqa: E731
+    gain, norm_eps = (None, None) if norm is None else norm
+    o, state = _gated_delta_chunks(
+        flat(q), flat(k), flat(v), g.astype(f32), beta.astype(f32),
+        state.astype(f32), gain, heads=heads, rows=ROWS, unit_eps=unit_eps,
+        norm_eps=norm_eps, interpret=pallas_mode() == "interpret")
+    return o.reshape(v.shape), state
+
+
 def delta_rule_step(state, q, k, v, alpha, beta):
     """The step in jnp, one token a row. ``state`` (N, d_k, H d_v)
     float32, laid out as an arena row; the rest as
@@ -185,5 +547,5 @@ def gated_delta_step(arena, rows, q, k, v, alpha, beta):
     return o, arena.at[rows].set(new)
 
 
-__all__ = ["delta_rule_step", "gated_delta_decode", "gated_delta_step",
-           "supported"]
+__all__ = ["chunks_supported", "delta_rule_step", "gated_delta_chunks",
+           "gated_delta_decode", "gated_delta_step", "supported"]

@@ -31,14 +31,20 @@ sequences, ``CHUNK`` tokens at a time: within a chunk everything is a
 matrix product (the chunk's updates ``u_t = beta_t (v_t - alpha_t
 S_{t-1}^T k_t)`` solve a unit lower-triangular system that does not
 involve the incoming state: the WY form), between chunks the state is
-carried by ``lax.scan``. ``kernels/gated_delta.py`` takes one token
-(``delta_rule_step`` in jnp, ``gated_delta_decode`` the kernel). Both
-are float32 with products at ``highest``; the projections around them are
-in the activations' dtype with float32 accumulation.
+carried by ``lax.scan``. A layer runs it, between the norms on either
+side of it, by the path the shapes choose: :func:`fused_rule`
+(``kernels/gated_delta.py`` ``gated_delta_chunks``, one kernel a call)
+where :func:`delta_rule_path` says ``"kernel"``, else :func:`scan_rule`,
+the jnp form, which stays as the kernel's reference.
+``kernels/gated_delta.py`` also takes one token (``delta_rule_step`` in
+jnp, ``gated_delta_decode`` the kernel). All are float32 with products
+at ``highest``; the projections around them are in the activations'
+dtype with float32 accumulation.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
@@ -46,6 +52,7 @@ import jax.numpy as jnp
 
 from ..core.op import Op, WeightSpec, register_op
 from ..ffconst import OpType
+from ..kernels import gated_delta as kernel
 from ..runtime.initializer import (ConstantInitializer,
                                    DefaultWeightInitializer, ZeroInitializer)
 from .attention import _mm
@@ -125,6 +132,61 @@ def chunked_delta_rule(q, k, v, g, beta, state):
     return o.reshape(b, n * CHUNK, h, -1)[:, :s], state
 
 
+UNIT_EPS = 1e-6  # under the root of a head's L2 norm of q and of k
+
+
+def unit_heads(q, k, v):
+    """q, k (B, S, H, d_k) and v (B, S, H, d_v) as the convolution wrote
+    them -> q and k each head's L2-normalised, q scaled by ``d_k^-1/2``,
+    and v."""
+    def unit(a):
+        return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + UNIT_EPS)
+
+    return unit(q) * q.shape[-1] ** -0.5, unit(k), v
+
+
+def scan_rule(eps: float, q, k, v, g, beta, state, gain):
+    """What a layer does between its convolution and its gate, in jnp:
+    ``q``, ``k`` (B, S, H d_k) and ``v`` (B, S, H d_v) as the convolution
+    wrote them; unit q and k a head, :func:`chunked_delta_rule`, then
+    RMSNorm over each head's d_v times ``gain`` (d_v,). Returns (y (B, S,
+    H d_v) float32, the state after the sequence)."""
+    b, s, h = g.shape
+    o, state = chunked_delta_rule(
+        *unit_heads(*(a.reshape(b, s, h, -1) for a in (q, k, v))), g, beta,
+        state)
+    return rms_norm(o, gain, eps).reshape(v.shape), state
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def fused_rule(eps, q, k, v, g, beta, state, gain):
+    """:func:`scan_rule` as one kernel call, the norms on either side of
+    the recurrence made on a head's tile in VMEM."""
+    return kernel.gated_delta_chunks(q, k, v, g, beta, state,
+                                     unit_eps=UNIT_EPS, norm=(gain, eps))
+
+
+def _fused_fwd(eps, *args):
+    return fused_rule(eps, *args), args
+
+
+def _fused_bwd(eps, args, cotangents):
+    # nothing trains through the kernel yet: the jnp form's gradients
+    return jax.vjp(functools.partial(scan_rule, eps), *args)[1](cotangents)
+
+
+fused_rule.defvjp(_fused_fwd, _fused_bwd)
+
+
+def delta_rule_path(seq: int, heads: int, key_dim: int, value_dim: int,
+                    dtype=jnp.float32) -> str:
+    """How a layer computes its recurrence over these shapes:
+    ``"kernel"`` (:func:`fused_rule`) or ``"scan"`` (:func:`scan_rule`).
+    A rule over what a trace sees, the backend among it; no knob."""
+    return "kernel" if kernel.chunks_supported(
+        seq, heads, key_dim, value_dim, dtype) else "scan"
+
+
 @register_op
 class GatedDeltaNet(Op):
     """The layer of the module's docstring. Matrices keep 2-D shapes,
@@ -190,19 +252,23 @@ class GatedDeltaNet(Op):
         acc = sum(w[j] * window[:, j:j + s] for j in range(self.conv_taps))
         return jax.nn.silu(acc)
 
+    def split(self, u):
+        """The convolved (B, S, channels) -> its q, k and v channels."""
+        return (u[..., :self.qk_width],
+                u[..., self.qk_width:2 * self.qk_width],
+                u[..., 2 * self.qk_width:])
+
     def heads(self, u):
         """The convolved (B, S, channels) -> q, k (B, S, H, d_k), each
         L2-normalised, q scaled by ``d_k^-1/2``, and v (B, S, H, d_v)."""
         b, s, _ = u.shape
         h, dk = self.num_heads, self.key_dim
+        # :meth:`split`'s slices, each reshaped as it is cut: the order
+        # the decode program's lowered text has had
         q = u[..., :self.qk_width].reshape(b, s, h, dk)
         k = u[..., self.qk_width:2 * self.qk_width].reshape(b, s, h, dk)
         v = u[..., 2 * self.qk_width:].reshape(b, s, h, self.value_dim)
-
-        def unit(a):
-            return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
-
-        return unit(q) * dk ** -0.5, unit(k), v
+        return unit_heads(q, k, v)
 
     def gates(self, weights, x):
         """(B, S, E) -> ``g`` = log alpha and beta, (B, S, H) float32."""
@@ -214,12 +280,15 @@ class GatedDeltaNet(Op):
         beta = jax.nn.sigmoid(bl)
         return g, beta * 2.0 if self.neg_eigval else beta
 
-    def finish(self, weights, x, o):
+    def finish(self, weights, x, o, normed=False):
         """The recurrence's (B, S, H, d_v) float32 outputs -> (B, S, E):
-        RMSNorm over ``d_v``, times ``silu(x W_g)``, through ``W_o``."""
+        RMSNorm over ``d_v`` (``normed``: made already, by
+        :func:`fused_rule` or :func:`scan_rule`), times ``silu(x W_g)``,
+        through ``W_o``."""
         b, s = o.shape[:2]
         z = jnp.dot(x, weights["wg"], preferred_element_type=jnp.float32)
-        y = rms_norm(o, weights["norm"], self.eps).reshape(b, s, self.v_width)
+        y = o if normed else rms_norm(o, weights["norm"], self.eps)
+        y = y.reshape(b, s, self.v_width)
         return _mm((y * jax.nn.silu(z)).astype(x.dtype), weights["wo"])
 
     def run(self, weights, x, state, tail, lengths=None):
@@ -236,17 +305,23 @@ class GatedDeltaNet(Op):
         with jax.named_scope("gated_delta_prefill"):
             window = jnp.concatenate(
                 [tail.astype(x.dtype), self.conv_inputs(weights, x)], axis=1)
-            q, k, v = self.heads(self.convolve(weights, window))
+            q, k, v = self.split(self.convolve(weights, window))
             g, beta = self.gates(weights, x)
             live = (jax.lax.iota(jnp.int32, s)[None, :]
                     < lengths[:, None])[..., None]
-            o, state = chunked_delta_rule(q, k, v, jnp.where(live, g, 0.0),
-                                          jnp.where(live, beta, 0.0), state)
+            # by the path the shapes choose; the kernel's gradients are
+            # the jnp form's
+            rule = (fused_rule if delta_rule_path(
+                s, self.num_heads, self.key_dim, self.value_dim, q.dtype)
+                == "kernel" else scan_rule)
+            y, state = rule(self.eps, q, k, v, jnp.where(live, g, 0.0),
+                            jnp.where(live, beta, 0.0), state,
+                            weights["norm"])
             # window position p is block position p - (K - 1): the K - 1
             # inputs before position ``length`` start at ``length``
             tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
                 w, n, taps - 1, axis=0))(window, lengths)
-            return self.finish(weights, x, o), state, tail
+            return self.finish(weights, x, y, normed=True), state, tail
 
     def whole(self, weights, x, lengths=None):
         """Whole sequences from an empty state: :meth:`run` behind zeros."""

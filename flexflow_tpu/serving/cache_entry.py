@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from ..ffconst import OpType
 from ..kernels import gated_delta, latent_attention, paged_attention
+from ..ops.gated_delta import delta_rule_path
 from .kv_cache import NULL_BLOCK
 
 
@@ -144,6 +145,11 @@ class EntryKind:
 
     def stats(self) -> Dict:
         return {"entry": self.name}
+
+    def prefill_path(self, bucket: int) -> Optional[str]:
+        """How :meth:`prefill` computes a ``bucket`` of tokens, for a kind
+        whose prefill has more than one form; None for one that has one."""
+        return None
 
     def token_bytes(self, dtype) -> int:
         """Bytes one token (one request, for a ``per_request`` kind) takes
@@ -468,6 +474,12 @@ class StateEntry(EntryKind):
         o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
                           jnp.exp(g[:, 0]), beta[:, 0])
         return op.finish(weights, x, o[:, None]), (state, tails)
+
+    def prefill_path(self, bucket):
+        """``"kernel"`` (the fused whole-sequence kernel) or ``"scan"``
+        (the jnp form), by the rule the op's lowering asks."""
+        return delta_rule_path(bucket, self.heads, self.key_dim,
+                               self.value_dim)
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """The op's chunked whole-sequence form from an empty state: what

@@ -553,6 +553,12 @@ class PagedDecoder(_DecodeGraph):
         self.attention_path: Dict[str, str] = {
             "decode": self._attention_path(1)}
         self._prefill_fns: Dict[Tuple[int, int], object] = {}
+        # how the prefill programs run the recurrence of the ops that
+        # keep a state, fixed when a program is built, from the rule its
+        # lowering asks: "kernel" where every program built so far takes
+        # the fused whole-sequence kernel (the widest bucket speaks
+        # until one is), else "scan"; None in a pool that keeps no state
+        self.prefill_path = self._prefill_path(self.prefill_buckets[-1])
         self.decode_dispatches = 0
         self.decode_steps = 0
         # called between a jitted call's return and the fetch of its
@@ -730,6 +736,11 @@ class PagedDecoder(_DecodeGraph):
                 self.max_blocks_per_request)
             for op in self._attn_ops) else "gather"
 
+    def _prefill_path(self, bucket: int) -> Optional[str]:
+        said = {kind.prefill_path(bucket) for kind in self.pool.kinds.values()}
+        said.discard(None)
+        return ("kernel" if said == {"kernel"} else "scan") if said else None
+
     def _prefill_fn(self, bucket: int, width: int = 1):
         """The (bucket, row-width) executable — the seen-set is the
         dict itself, so ``serving.prefill_bucket_compiles`` counts
@@ -739,6 +750,8 @@ class PagedDecoder(_DecodeGraph):
         if fn is None:
             fn = jax.jit(self._prefill_step, donate_argnums=(2,))
             self._prefill_fns[key] = fn
+            if self._prefill_path(bucket) == "scan":
+                self.prefill_path = "scan"
             from ..obs.metrics import metrics_registry
 
             metrics_registry().counter(

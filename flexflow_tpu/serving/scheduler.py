@@ -1336,6 +1336,7 @@ class ContinuousBatchingScheduler:
         kv["blocks_in_tables"] = blocks_in_tables
         if "state" in kv:
             kv["state"]["rows_stepped"] = rows_stepped
+            kv["state"]["prefill_path"] = self.decoder.prefill_path
         if self.decoder.kv_divergence is not None:
             kv["divergence"] = self.decoder.kv_divergence
             kv["quant_fallback"] = self.decoder.kv_quant_report is not None

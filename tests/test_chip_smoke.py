@@ -36,6 +36,10 @@ def test_phases_run_to_completion_at_toy_size(monkeypatch, tmp_path):
     assert [r["phase"] for r in records] == [
         "kernels", "serve", "train[dp]", "train[searched]"]
     assert records[0]["interpret"] is True
+    assert float(records[0]["gated_delta_chunks_o"]) < 1e-4
+    # the small hybrid's prefills took the whole-sequence kernel
+    assert records[1]["hybrid_prefill_path"] == "kernel"
+    assert records[1]["hybrid_attention_path"] == "kernel"
     assert records[2]["devices"] == 8 and records[2]["attention_path"] == "flash"
     assert records[3]["compiles_by_epoch"][1] == 0
     assert os.path.isdir(tmp_path / "ledger")  # nothing under the cwd

@@ -1,8 +1,10 @@
-"""The gated-delta-rule op (ops/gated_delta.py) and its decode kernel
+"""The gated-delta-rule op (ops/gated_delta.py) and its kernels
 (kernels/gated_delta.py): the chunked whole-sequence form, the one-token
 form and the reference's token-by-token recurrence give the same sums; a
-padded prefill stops at the true length; the kernel, interpreted, is its
-jnp form; and ``qk_norm`` on multi-head attention."""
+padded prefill stops at the true length; each kernel, interpreted, is its
+jnp form (the whole-sequence one on an ill-conditioned chunk too, where
+a series in powers of the system would not be); and ``qk_norm`` on
+multi-head attention."""
 
 import numpy as np
 import pytest
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.ffconst import CompMode, DataType
 from flexflow_tpu.kernels import gated_delta as gd
+from flexflow_tpu.ops import gated_delta as op_mod
 from flexflow_tpu.ops.gated_delta import CHUNK, chunked_delta_rule
 
 H, DK, DV, E = 3, 8, 16, 24
@@ -28,10 +31,11 @@ def _qkv(rng, b, s, h=H, dk=DK, dv=DV):
     return q, k, v, g, beta
 
 
-def _by_hand(q, k, v, g, beta):
+def _by_hand(q, k, v, g, beta, state=None):
     """The recurrence as the module's docstring writes it, in float64."""
     b, s, h, dk = q.shape
-    state = np.zeros((b, h, dk, v.shape[-1]))
+    state = (np.zeros((b, h, dk, v.shape[-1])) if state is None
+             else np.asarray(state, np.float64))
     out = np.zeros(v.shape)
     for t in range(s):
         state = np.exp(g[:, t])[..., None, None] * state
@@ -204,6 +208,222 @@ def test_kernel_refuses_what_it_does_not_build(monkeypatch):
     assert not gd.supported(4, 4, 8, 64, (9, 8, 128), jnp.float32)
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
     assert not gd.supported(*ok)
+
+
+# ---- the whole-sequence kernel ------------------------------------------------
+
+def _hard(rng, b, s, h, dk, dv):
+    """Keys of a chunk nearly parallel, beta 1.9-2.0, alpha 0.99-1.0: the
+    unit-lower system's strict part has entries near 2 all over."""
+    q, k, v, g, beta = _qkv(rng, b, s, h, dk, dv)
+    k = rng.normal(size=(b, 1, h, dk)) + 0.05 * k
+    k = (k / np.linalg.norm(k, axis=-1, keepdims=True)).astype(np.float32)
+    g = np.log(rng.uniform(0.99, 1.0, size=(b, s, h))).astype(np.float32)
+    beta = rng.uniform(1.9, 2.0, size=(b, s, h)).astype(np.float32)
+    return q, k, v, g, beta
+
+
+# (batch, tokens, heads, d_k, d_v, a state before the sequence, the draw)
+CHUNKS_CASES = {
+    "published-widths": (1, 128, 30, 96, 192, False, _qkv),
+    "no-multiple-of-64": (1, 150, 4, 32, 64, False, _qkv),
+    "behind-a-state": (1, 200, 6, 96, 192, True, _qkv),  # a group and a half
+    "two-rows": (2, 70, 2, 64, 128, True, _qkv),
+    "ill-conditioned": (1, 256, 4, 32, 64, True, _hard),
+}
+
+
+@pytest.mark.parametrize("case", CHUNKS_CASES)
+def test_chunks_kernel_interpreted_is_the_scan_and_the_recurrence(
+        monkeypatch, case):
+    """The whole-sequence kernel against ``chunked_delta_rule`` and
+    against the recurrence token by token (float64, and the one-token
+    jnp form in float32); on the ill-conditioned draw it stays as close
+    to the recurrence as the scan's row substitution does."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    b, s, h, dk, dv, behind, draw = CHUNKS_CASES[case]
+    rng = np.random.default_rng(len(case))
+    q, k, v, g, beta = draw(rng, b, s, h, dk, dv)
+    state = (rng.normal(size=(b, h, dk, dv)).astype(np.float32) if behind
+             else np.zeros((b, h, dk, dv), np.float32))
+    assert gd.chunks_supported(s, h, dk, dv, jnp.float32)
+    args = tuple(map(jnp.asarray, (q, k, v, g, beta, state)))
+    got, end = gd.gated_delta_chunks(*args)
+    scan, scan_end = chunked_delta_rule(*args)
+    want, want_end = _by_hand(q, k, v, g, beta, state)
+    scale, end_scale = np.abs(want).max(), np.abs(want_end).max()
+    tol = 2e-4 if draw is _hard else 2e-5
+    assert np.abs(got - want).max() < tol * scale
+    assert np.abs(end - want_end).max() < tol * end_scale
+    assert np.abs(got - scan).max() < tol * scale
+    assert np.abs(end - scan_end).max() < tol * end_scale
+    # no further from the truth than a few times the scan's own distance
+    assert np.abs(got - want).max() < max(
+        4 * np.abs(scan - want).max(), 2e-6 * scale)
+    # the one-token form over the arena's layout, float32
+    lanes = jnp.moveaxis(args[5], 1, 2).reshape(b, dk, h * dv)
+    for t in range(min(s, 70)):
+        o, lanes = gd.delta_rule_step(lanes, q[:, t], k[:, t], v[:, t],
+                                      np.exp(g[:, t]), beta[:, t])
+        assert np.abs(o - got[:, t]).max() < tol * scale, t
+
+
+@pytest.mark.parametrize("b,s,h,dk,dv", [(1, 128, 30, 96, 192),
+                                        (2, 150, 4, 32, 64)])
+def test_fused_rule_makes_the_norms_on_either_side_as_the_scan_rule_does(
+        monkeypatch, b, s, h, dk, dv):
+    """What a layer runs between its convolution and its gate: q and k
+    as the convolution wrote them, flat, made unit a head in the kernel,
+    and o RMS-normalised a head times the gain on its way out, against
+    the same in jnp around ``chunked_delta_rule``."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    rng = np.random.default_rng(s)
+    q, k, v, g, beta = _qkv(rng, b, s, h, dk, dv)
+    q, k = 3.0 * q, 0.3 * k * rng.uniform(0.5, 2.0, size=(b, s, h, 1))
+    flat = [jnp.asarray(a.reshape(b, s, -1), jnp.float32) for a in (q, k, v)]
+    rest = (jnp.asarray(g), jnp.asarray(beta),
+            jnp.asarray(rng.normal(size=(b, h, dk, dv)).astype(np.float32)),
+            jnp.asarray(rng.uniform(0.5, 1.5, size=(dv,)).astype(np.float32)))
+    got, end = op_mod.fused_rule(1e-6, *flat, *rest)
+    want, want_end = op_mod.scan_rule(1e-6, *flat, *rest)
+    assert got.shape == want.shape == (b, s, h * dv)
+    assert np.abs(got - want).max() < 2e-5 * np.abs(want).max()
+    assert np.abs(end - want_end).max() < 2e-5 * np.abs(want_end).max()
+
+
+def test_a_series_in_powers_of_the_system_fails_where_the_kernel_does_not():
+    """Why the kernel substitutes: ``(I - L)(I + L^2)(I + L^4)...`` is
+    ``(I + L)^-1`` on paper and, on the ill-conditioned draw, float32
+    noise; the kernel's doubling blocks invert the same tile to float32's
+    accuracy."""
+    rng = np.random.default_rng(7)
+    _, k, _, g, beta = _hard(rng, 1, gd.ROWS, 1, 32, 64)
+    k, g, beta = k[0, :, 0], g[0, :, 0], beta[0, :, 0]
+    gc = np.cumsum(g.astype(np.float64))
+    idx = np.arange(gd.ROWS)
+    low = np.where(idx[:, None] > idx[None, :], beta[:, None]
+                   * (k.astype(np.float64) @ k.T)
+                   * np.exp(gc[:, None] - gc[None, :]), 0.0)
+    want = np.linalg.inv(np.eye(gd.ROWS) + low)
+    got, = gd._unit_lower_inverses([jnp.asarray(low, jnp.float32)],
+                                   gd._tile_masks(gd.ROWS))
+    assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+    l32 = low.astype(np.float32)
+    series, power = np.eye(gd.ROWS, dtype=np.float32) - l32, l32 @ l32
+    with np.errstate(over="ignore", invalid="ignore"):
+        while np.abs(power).max() > 0 and np.isfinite(power).all():
+            series, power = series @ (np.eye(gd.ROWS, dtype=np.float32)
+                                      + power), power @ power
+    assert not np.abs(series - want).max() < 1.0 * np.abs(want).max()
+
+
+def test_chunks_kernel_carries_its_state_into_the_next_call(monkeypatch):
+    """Whole = the first half, then the second from the state it left."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    rng = np.random.default_rng(9)
+    args = tuple(map(jnp.asarray, _qkv(rng, 1, 300, 2, 64, 128)))
+    zero = jnp.zeros((1, 2, 64, 128))
+    whole, end = gd.gated_delta_chunks(*args, zero)
+    cut = 137
+    first, mid = gd.gated_delta_chunks(*(a[:, :cut] for a in args), zero)
+    second, end2 = gd.gated_delta_chunks(*(a[:, cut:] for a in args), mid)
+    both = jnp.concatenate([first, second], axis=1)
+    assert np.abs(both - whole).max() < 2e-5 * np.abs(whole).max()
+    assert np.abs(end - end2).max() < 2e-5 * np.abs(end).max()
+
+
+def _wide_op(batch=2, seq=150):
+    """An op at widths the whole-sequence kernel takes (4 heads of 32 and
+    64: one group), with gates in the published range."""
+    ff = FFModel(FFConfig(batch_size=batch, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    x = ff.create_tensor((batch, seq, E), DataType.FLOAT, name="x")
+    ff.gated_delta_net(x, num_heads=4, key_dim=32, value_dim=64,
+                       allow_neg_eigval=True, name="gdn")
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    cm = ff.compiled
+    op = [o for o in cm.ops if o.name == "gdn"][0]
+    rng = np.random.default_rng(11)
+    w = {k: jnp.asarray(rng.normal(size=v.shape).astype(np.float32)
+                        * (0.3 if v.ndim > 1 else 1.0))
+         for k, v in cm.params["gdn"].items()}
+    return ff, op, w
+
+
+def test_op_through_the_kernel_stops_each_row_at_its_true_length(monkeypatch):
+    """``GatedDeltaNet.run`` by the path the shapes choose: under the
+    interpreter the kernel, whose rows of 70 and of 150 tokens in one
+    block of 150 leave the states, tails and outputs the scan leaves
+    (``g = 0`` and ``beta = 0`` past a row's length)."""
+    _, op, w = _wide_op()
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(2, 150, E)).astype(np.float32))
+    lengths = jnp.asarray([70, 150], jnp.int32)
+    assert op_mod.delta_rule_path(150, 4, 32, 64) == "scan"
+    y0, state0, tail0 = op.whole(w, x, lengths)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert op_mod.delta_rule_path(150, 4, 32, 64) == "kernel"
+    y, state, tail = op.whole(w, x, lengths)
+    assert np.abs(y[0, :70] - y0[0, :70]).max() < 1e-5 * np.abs(y0).max()
+    assert np.abs(y[1] - y0[1]).max() < 1e-5 * np.abs(y0).max()
+    assert np.abs(state - state0).max() < 1e-5 * np.abs(state0).max()
+    assert np.array_equal(tail, tail0)
+    alone, alone_state, _ = op.whole(w, x[:1, :70])
+    assert np.abs(state[0] - alone_state[0]).max() \
+        < 1e-5 * np.abs(state0).max()
+    assert np.abs(y[0, :70] - alone[0]).max() < 1e-5 * np.abs(y0).max()
+
+
+def test_gradient_through_the_kernel_is_the_scans(monkeypatch):
+    """``jax.grad`` through ``GatedDeltaNet.forward`` where the kernel is
+    taken: the custom VJP's backward is the jnp form's, so the gradients
+    are those of the scan path (to the forward's rounding), and a bare
+    ``pallas_call``'s refusal to differentiate never shows."""
+    ff, op, w = _wide_op(batch=1, seq=130)
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(1, 130, E))
+                    .astype(np.float32))
+
+    def loss(w, x):
+        return jnp.sum(jnp.square(op.forward(None, [x], w)[0]))
+
+    want = jax.grad(loss, argnums=(0, 1))(w, x)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    got = jax.grad(loss, argnums=(0, 1))(w, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.abs(a - b).max() <= 1e-4 * np.abs(b).max() + 1e-6
+
+
+# what the whole-sequence kernel takes and what it refuses, and why
+SUPPORTED = {
+    "the cell's widths": ((1536, 30, 96, 192, jnp.float32), True),
+    "one chunk": ((128, 30, 96, 192, jnp.float32), True),
+    "one chunk of the scan's: its loop is shorter than a padded chunk": (
+        (64, 30, 96, 192, jnp.float32), False),
+    "the KV calibration's prompt": ((12, 30, 96, 192, jnp.float32), False),
+    "heads that fill a tile each": ((256, 3, 128, 128, jnp.float32), True),
+    "pairs of heads a tile": ((256, 4, 64, 64, jnp.float32), True),
+    "bfloat16: the recurrence is float32": (
+        (1536, 30, 96, 192, jnp.bfloat16), False),
+    "keys off the sublane tiling": ((256, 4, 36, 64, jnp.float32), False),
+    "an odd head shares its value tile with nobody": (
+        (256, 3, 64, 64, jnp.float32), False),
+    "a group too long to unroll (16 heads of 8)": (
+        (256, 16, 8, 64, jnp.float32), False),
+    "the toy widths of this file": ((150, H, DK, DV, jnp.float32), False),
+}
+
+
+@pytest.mark.parametrize("why", SUPPORTED)
+def test_chunks_kernel_takes_by_shape(monkeypatch, why):
+    args, takes = SUPPORTED[why]
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert gd.chunks_supported(*args) is takes
+    assert op_mod.delta_rule_path(*args) == ("kernel" if takes else "scan")
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    assert not gd.chunks_supported(*args)
+    monkeypatch.delenv("FLEXFLOW_TPU_PALLAS")
+    assert not gd.chunks_supported(*args)        # the CPU: the scan
 
 
 # ---- qk_norm on multi-head attention -------------------------------------------

@@ -158,6 +158,7 @@ def test_generate_equals_the_dense_generator_and_slots_are_reused(model):
     assert st["row_bytes"] == 3 * (8 * 64 * 4 + 3 * 128 * 4)
     # active slots x the three state layers, summed over the steps
     assert st["rows_stepped"] == 3 * (sum(m - 1 for _, m in reqs))
+    assert st["prefill_path"] == "scan"          # the CPU: the jnp form
     assert kv["in_use"] == 0
 
 
@@ -266,6 +267,43 @@ def test_decode_takes_both_kernels_where_both_are_supported(monkeypatch):
     assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
 
 
+def test_prefill_takes_the_chunks_kernel_and_generates_the_scans_tokens(
+        monkeypatch):
+    """A toy whose linear layers the whole-sequence kernel takes (4 heads
+    of 32 and 64: one group): under the interpreter every prefill
+    program runs it, ``stats()`` says so, and the greedy outputs of five
+    requests over two slots and two buckets are, token for token, those
+    of the jnp form."""
+    config = dict(CONFIG, hidden_size=128, num_attention_heads=2,
+                  num_key_value_heads=2, linear_key_head_dim=32,
+                  linear_value_head_dim=64)
+    rng = np.random.default_rng(4)
+    reqs = [(rng.integers(0, 96, (n,)).astype(np.int32), m)
+            for n, m in [(5, 6), (130, 5), (40, 7), (97, 4), (80, 6)]]
+
+    def run():
+        ff = _build(slots=2, config=config)
+        _load(ff, config)
+        inst = GenerationInstance(ff, decode_slots=2, block_size=8,
+                                  max_length=144,
+                                  prefill_buckets=[80, 144])
+        try:
+            futs = [inst.generate_async(p, m, temperature=0.0)
+                    for p, m in reqs]
+            return ([f.result(timeout=600) for f in futs],
+                    inst.stats()["kv"]["state"]["prefill_path"])
+        finally:
+            inst.stop()
+
+    want, path = run()
+    assert path == "scan"
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    got, path = run()
+    assert path == "kernel"
+    for out, ref in zip(got, want):
+        np.testing.assert_array_equal(out, ref)
+
+
 def test_bfloat16_weights_are_held_once_and_declared():
     ff = FFModel(FFConfig(batch_size=2, ledger="off",
                           computation_mode=CompMode.INFERENCE))
@@ -294,10 +332,14 @@ def test_unknown_layer_type_is_refused():
 # positions leave GPT-2's and the latent model's programs letter for
 # letter what they were. After a change that is meant to move one of
 # them, print the new ones: ``python tests/test_hybrid_lm.py``.
+# ``hybrid.decode`` was recorded on 0f38e32, before the whole-sequence
+# kernel: what changes a prefill's recurrence must not reach the decode
+# program.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
     "gpt.train": "170e41925d7e8ba1d46f71f4f6274b2560128cbf591b30200fc1cea3b172dc38",
+    "hybrid.decode": "4d24aefa5c08257e468bf6e0099721acafe48a58f593be9809c3c206eedcec7f",
     "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
     "latent_moe.prefill": "43e30c908af85f9f65457976171f1f762c7a454a0f807318b4c7e2a3e4a6dbd4",
     "latent_moe.train": "71272d740bed1d84a16644e19412122af40d6a7251a94171bc5aab6e2c515ebe",
@@ -337,8 +379,10 @@ def _lowered(name: str) -> str:
     lens = jax.ShapeDtypeStruct((2,), jnp.int32)
 
     def addr(n):
-        return Addresses(jax.ShapeDtypeStruct(
-            (n, dec.max_blocks_per_request), jnp.int32), None)
+        return Addresses(
+            jax.ShapeDtypeStruct((n, dec.max_blocks_per_request), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.int32)
+            if dec.pool.num_rows else None)
 
     if program == "decode":
         return dec._decode.lower(

@@ -1,5 +1,5 @@
-"""The fused attention kernels compiled for a described (not attached)
-TPU v5e at real widths: what Mosaic refuses — a block shape off the
+"""The fused attention and gated-delta-rule kernels compiled for a
+described (not attached) TPU v5e at real widths: what Mosaic refuses — a block shape off the
 tiling, an unsupported relayout, too much VMEM — fails here, on the CPU,
 before any chip time is spent. Nothing runs, so nothing here says
 anything about results or speed.
@@ -111,3 +111,56 @@ def test_gated_delta_decode_compiles_for_v5e(monkeypatch, one_chip,
     # the donated arena goes in and comes out as one buffer
     assert mem.alias_size_in_bytes >= arena_bytes
     assert mem.temp_size_in_bytes < arena_bytes // 8
+
+
+def test_gated_delta_prefill_compiles_for_v5e(monkeypatch, one_chip,
+                                              no_compile_cache):
+    """One linear layer's prefill (``GatedDeltaNet.whole``: projections,
+    convolution, the recurrence, norm and gate, from bfloat16 x and
+    weights) at the hybrid configuration's widths and each of its cell's
+    three buckets: one Mosaic custom call, named ``gated_delta_chunks``;
+    no ``while`` (the scan's loops) anywhere in the program, no
+    ``(n, 1, 30, 64, 64)`` buffer (the scan's decay, system and inverse a
+    chunk), temporaries under 128 MB where the scan's program takes 228
+    at the widest bucket. And twelve such layers trace the kernel once:
+    the lowered text holds one function that all of them call."""
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import DataType, OpType
+    from flexflow_tpu.ops import gated_delta as op_mod
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    e, h, dk, dv = 3840, 30, 96, 192
+    layer = Layer(OpType.GATED_DELTA_NET, "gdn", attrs=dict(
+        num_heads=h, key_dim=dk, value_dim=dv, conv_taps=4,
+        allow_neg_eigval=True))
+    op = op_mod.GatedDeltaNet(layer, [ParallelTensorShape.unpartitioned(
+        (1, 1536, e), DataType.FLOAT)])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    weights = {ws.name: sds(ws.shape) for ws in op.weight_specs()}
+    lengths = sds((1,), jnp.int32)
+    for bucket in (768, 1024, 1536):
+        assert op_mod.delta_rule_path(bucket, h, dk, dv) == "kernel"
+        compiled = jax.jit(op.whole).lower(
+            weights, sds((1, bucket, e)), lengths).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1, bucket
+        assert "gated_delta_chunks" in text
+        assert "gated_delta_decode" not in text
+        assert " while(" not in text, bucket
+        assert ",1,30,64,64]" not in text, bucket
+        assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+
+    def twelve(weights, x, lengths):
+        for _ in range(12):
+            x = op.whole(weights, x, lengths)[0]
+        return x
+
+    text = jax.jit(twelve).lower(weights, sds((1, 768, e)),
+                                 lengths).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert text.count("func.func private @_gated_delta_chunks(") == 1
+    assert text.count("call @_gated_delta_chunks(") == 12
