@@ -1,0 +1,102 @@
+"""``kind: serve_closed_chunked`` — ``serve_closed``'s closed loop for
+prompts longer than any prefill bucket, served by a model that selects
+the key blocks it reads: the same load and counters, the instance built
+with the mix's ``prefill_chunk`` (``traffic.bucket_for`` refuses a prompt
+above the largest bucket, so ``serving.build`` cannot carry chunks), the
+chunk programs warmed up, and the two-part comparison of
+``benchmark/selected.py`` (selection, then logits under the program's
+selection) in ``serving.compare_paged``'s place.
+
+``kinds/serve_closed.py`` is not this PR's to edit, so its ``run`` is
+copied here with those three calls changed (and the window's chunk
+counters kept beside its other facts); everything else is imported.
+"""
+
+from __future__ import annotations
+
+import queue
+import time
+from typing import Dict
+
+from benchmark import selected, serving, traffic
+
+
+def run(ctx) -> Dict:
+    mix, cfg = ctx.mix, ctx.config
+    # the schedule is the closed kind's own
+    reqs = traffic.schedule(dict(mix, kind="serve_closed"))
+    ff, inst, weights = selected.build(ctx)
+    selected.warm_up(ctx, inst)
+    selected.compare_paged(ctx, inst, weights, ctx.checks)
+    del weights
+    vocab = int(cfg["vocab_size"])
+    done: "queue.Queue" = queue.Queue()
+    sent, finished, failed = 0, 0, 0
+    bad_shape = 0
+
+    def send() -> None:
+        nonlocal sent
+        i = sent % len(reqs)  # the list goes round when it runs out
+        r = reqs[i]
+        sent += 1
+        fut = inst.generate_async(
+            traffic.token_ids(ctx.seed, i, r.prompt_len, vocab),
+            r.answer_len, temperature=0.0)
+        fut.add_done_callback(lambda f, i=i: done.put((i, f)))
+
+    def collect(until: float) -> None:
+        """Refill the slots as jobs complete, until ``until``."""
+        nonlocal finished, failed, bad_shape
+        while True:
+            left = until - time.perf_counter()
+            if left <= 0:
+                return
+            try:
+                i, fut = done.get(timeout=left)
+            except queue.Empty:
+                return
+            finished += 1
+            if fut.exception() is not None:
+                failed += 1
+            elif fut.result().shape != (reqs[i].prompt_len
+                                        + reqs[i].answer_len,):
+                bad_shape += 1
+            send()
+
+    before = serving.counters()
+    with ctx.span("lead_in"):
+        for _ in range(int(mix["clients"])):
+            send()
+        collect(time.perf_counter() + float(mix["lead_in_s"]))
+    s0 = inst.stats()
+    t0 = ctx.window_opens()
+    finished0 = finished
+    if ctx.profiler.enabled:
+        ctx.profiler.start()
+        with ctx.span("window"):
+            collect(t0 + min(ctx.trace_seconds, ctx.seconds))
+        ctx.profiler.stop()
+    collect(t0 + ctx.seconds)
+    s1 = inst.stats()
+    t1 = time.perf_counter()
+    ctx.window_closed(t1)
+    serving.finish_checks(ctx, inst, before, ctx.checks)
+    ctx.checks.equal("serve.wrong_length_outputs", bad_shape, 0)
+    tokens = s1["tokens"] - s0["tokens"]
+    ctx.facts.update(stats0=s0, stats1=s1, window_s=t1 - t0, tokens=tokens,
+                     jobs_finished_in_window=finished - finished0,
+                     decode_steps_in_window=(s1["decode_steps"]
+                                             - s0["decode_steps"]),
+                     prefills_in_window=(s1["prefill_prompts"]
+                                         - s0["prefill_prompts"]),
+                     chunks_in_window=(s1["loop"]["prefill_chunks"]
+                                       - s0["loop"]["prefill_chunks"]),
+                     prompt_tokens_in_window=(s1["loop"]["prefill_tokens"]
+                                              - s0["loop"]["prefill_tokens"]),
+                     clients_decoding_at_open=s0["prefill_prompts"],
+                     prompt_lens=[reqs[i % len(reqs)].prompt_len
+                                  for i in range(sent)])
+    # the jobs still in their slots are not waited for
+    return {"attempted": sent, "failed": failed,
+            "end_to_end": {"serve_tokens_per_s": tokens / (t1 - t0)},
+            "abandon_threads": True}

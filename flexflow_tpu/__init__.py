@@ -37,6 +37,7 @@ from .core.layer import Layer
 # import op modules for registration side effects
 from .ops import (  # noqa: F401
     attention,
+    block_sparse_attention,
     conv,
     dropout,
     element_binary,
@@ -44,6 +45,7 @@ from .ops import (  # noqa: F401
     embedding,
     fused,
     gated_delta,
+    lightning_attention,
     linear,
     moe_ops,
     norm,

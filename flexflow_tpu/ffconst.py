@@ -179,6 +179,13 @@ class OpType(enum.Enum):
     # linear attention by the gated delta rule: a state of fixed size a
     # sequence, a short causal convolution before it (Gated DeltaNet)
     GATED_DELTA_NET = "gated_delta_net"
+    # causal attention with grouped key-value heads that, past a context
+    # length, reads only the key blocks a score over mean-pooled keys
+    # selects (InfLLM v2), and an output gate
+    BLOCK_SPARSE_ATTENTION = "block_sparse_attention"
+    # linear attention with a fixed decay a head over a state of fixed
+    # size a sequence, rotary positions (Lightning Attention)
+    LIGHTNING_ATTENTION = "lightning_attention"
     # x -> (act(x W_gate) * (x W_up)) W_down
     GATED_MLP = "gated_mlp"
     # dropless top-k routing over n experts, of which this op holds a

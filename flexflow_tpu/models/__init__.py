@@ -15,6 +15,7 @@ from .nmt import build_nmt, NMTConfig
 from .gpt import build_gpt, GPTConfig
 from .latent_moe import build_latent_moe_lm, LatentMoEConfig
 from .hybrid import build_hybrid_lm, HybridLMConfig
+from .sparse_hybrid import build_sparse_hybrid_lm, SparseHybridConfig
 
 
 def zoo_smoke_builders():
@@ -85,6 +86,13 @@ def zoo_smoke_builders():
             vocab_size=128, hidden_size=32, num_heads=4, linear_heads=4,
             linear_key_dim=8, linear_value_dim=16, mlp_width=64))
 
+    def sparse_hybrid(ff, bs):
+        build_sparse_hybrid_lm(ff, bs, 32, SparseHybridConfig(
+            vocab_size=128, hidden_size=32, num_heads=4, num_kv_heads=2,
+            head_dim=8, linear_heads=4, linear_head_dim=8, mlp_width=64,
+            selection=dict(kernel=4, stride=2, block=4, window=4,
+                           dense_len=16, init_blocks=1, topk=3)))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -100,4 +108,5 @@ def zoo_smoke_builders():
         "gpt": gpt,
         "latent_moe": latent_moe,
         "hybrid": hybrid,
+        "sparse_hybrid": sparse_hybrid,
     }

@@ -315,6 +315,46 @@ class FFModel:
         return self._infer_and_add(OpType.GATED_DELTA_NET, [input], attrs,
                                    name)
 
+    def block_sparse_attention(self, input: Tensor, *, num_heads: int,
+                               num_kv_heads: int, head_dim: int,
+                               selection: Optional[Dict[str, int]] = None,
+                               eps: float = 1e-6, kernel_initializer=None,
+                               gain_initializer=None,
+                               name: Optional[str] = None) -> Tensor:
+        """Causal attention with grouped key-value heads, an output gate
+        and, past ``selection["dense_len"]`` positions, a selection of the
+        key blocks it reads (ops/block_sparse_attention.py
+        BlockSparseAttention); ``selection`` holds the sizes of
+        ``Selection``."""
+        attrs = dict(
+            num_heads=int(num_heads), num_kv_heads=int(num_kv_heads),
+            head_dim=int(head_dim),
+            selection={k: int(v) for k, v in (selection or {}).items()},
+            eps=float(eps), kernel_initializer=kernel_initializer,
+            gain_initializer=gain_initializer)
+        return self._infer_and_add(OpType.BLOCK_SPARSE_ATTENTION, [input],
+                                   attrs, name)
+
+    def lightning_attention(self, input: Tensor, positions: Tensor, *,
+                            num_heads: int, head_dim: int, layer_index: int,
+                            num_layers: int, rope_theta: float = 10000.0,
+                            eps: float = 1e-6, kernel_initializer=None,
+                            gain_initializer=None,
+                            name: Optional[str] = None) -> Tensor:
+        """Linear attention with a fixed decay a head over a state of
+        fixed size a sequence (ops/lightning_attention.py
+        LightningAttention); the decay is that of layer ``layer_index``
+        of ``num_layers``; ``positions`` is the graph's int32 positions
+        input (rotary)."""
+        attrs = dict(
+            num_heads=int(num_heads), head_dim=int(head_dim),
+            layer_index=int(layer_index), num_layers=int(num_layers),
+            rope_theta=float(rope_theta), eps=float(eps),
+            kernel_initializer=kernel_initializer,
+            gain_initializer=gain_initializer)
+        return self._infer_and_add(OpType.LIGHTNING_ATTENTION,
+                                   [input, positions], attrs, name)
+
     def routed_experts(self, input: Tensor, *, n_routed: int,
                        experts_per_token: int, width: int,
                        n_group: int = 1, topk_group: Optional[int] = None,

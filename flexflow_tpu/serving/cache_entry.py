@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from ..ffconst import OpType
 from ..kernels import gated_delta, latent_attention, paged_attention
+from ..ops import block_sparse_attention as bsa
 from ..ops.gated_delta import delta_rule_path
 from .kv_cache import NULL_BLOCK
 
@@ -136,15 +137,37 @@ class EntryKind:
     A kind of a row a token defines ``write(entry, flat, *rows)`` ((T,
     ...) rows into flat token slots (T,)) and a ``whole`` that returns
     (out, the rows ``write`` takes, the (S,) positions), and inherits
-    ``prefill``."""
+    ``prefill``.
+
+    A kind that is ``chunked`` also defines ``chunk(op, weights, x,
+    positions, entry, addr, offsets, lengths)``: a block of S tokens a
+    prompt at positions ``offsets .. offsets + lengths - 1`` (``offsets``
+    (P,) multiples of the block size), BEHIND what the chunks before it
+    left where ``addr`` says; a chunk at offset 0 starts from nothing.
+    Only such kinds serve a prompt in chunks
+    (``PagedDecoder(prefill_chunk=...)``). ``step`` and ``chunk`` may
+    return a third value, the ids of what the op chose to read (a
+    selection of blocks), which the programs keep for whoever asks."""
 
     name = ""                          # what stats()["kv"]["entry"] says
     max_window: Optional[int] = None   # new tokens a slot a step; None: any
     int8_form: Optional["EntryKind"] = None
     per_request = False                # a row a request, not a row a token
+    chunked = False                    # defines ``chunk``
 
     def stats(self) -> Dict:
         return {"entry": self.name}
+
+    def blocks_read(self, length: int) -> Optional[int]:
+        """Blocks of a request's table a step behind ``length`` cached
+        tokens reads, for a kind that reads a selection of them; None for
+        one that reads them all."""
+        return None
+
+    def side_rows(self, length: int) -> int:
+        """Rows a request of ``length`` cached tokens holds beside its
+        row a token (pooled keys)."""
+        return 0
 
     def prefill_path(self, bucket: int) -> Optional[str]:
         """How :meth:`prefill` computes a ``bucket`` of tokens, for a kind
@@ -160,7 +183,11 @@ class EntryKind:
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """:meth:`whole`, and the rows scattered through each prompt's
-        table (padding into the null block)."""
+        table (padding into the null block); for a ``chunked`` kind, the
+        prompts' first chunk."""
+        if self.chunked:
+            return self.chunk(op, weights, x, positions, entry, addr,
+                              jnp.zeros_like(lengths), lengths)
         out, rows, pos = self.whole(op, weights, x, positions)
         flat = _prefill_slots(addr.tables, lengths, pos, entry[0].shape[1])
         return out, self.write(entry, flat.reshape(-1), *(
@@ -511,12 +538,291 @@ class StateEntry(EntryKind):
         return out, (state, tail.astype(cache[1].dtype))
 
 
+@dataclasses.dataclass(frozen=True)
+class SparseEntry(EntryKind):
+    """A block-sparse attention op's rows: keys and values of its ``Hkv``
+    key-value heads a token, head-major inside a block (``(blocks, Hkv,
+    block_size, D)``: a head's block is one contiguous read, which is
+    what a step gathers ``topk`` of), and beside them one pooled key (a
+    **kernel**) every ``stride`` tokens, ``(blocks * per_block, Hkv D)``,
+    addressed through the same block tables: kernel i of a request lies
+    in its block ``i // per_block``. The pool's blocks have to be the
+    selection's. A step takes one token a slot (the selection is the
+    last position's)."""
+
+    kv_heads: int
+    head_dim: int
+    geom: bsa.Selection
+    name = "sparse"
+    max_window = 1
+    chunked = True
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        return cls(op.kv_heads, op.head_dim, op.geom)
+
+    def arenas(self, num_blocks, block_size, dtype):
+        if block_size != self.geom.block:
+            raise ValueError(
+                f"a sparse cache entry selects blocks of {self.geom.block} "
+                f"tokens: the pool's block_size {block_size} has to be that")
+        a = jax.ShapeDtypeStruct(
+            (num_blocks, self.kv_heads, block_size, self.head_dim), dtype)
+        return (a, a, jax.ShapeDtypeStruct(
+            (num_blocks * self.geom.per_block, self.kv_heads * self.head_dim),
+            dtype))
+
+    def token_bytes(self, dtype) -> int:
+        row = self.kv_heads * self.head_dim * jnp.dtype(dtype).itemsize
+        return 2 * row + row // self.geom.stride
+
+    def stats(self):
+        return {"entry": self.name, "kernels_per_block": self.geom.per_block}
+
+    def blocks_read(self, length):
+        return self.geom.blocks_read(length)
+
+    def side_rows(self, length):
+        return self.geom.kernels_in(length)
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return False                    # no kernel yet: a step gathers
+
+    # ---- addressing -------------------------------------------------------
+    @staticmethod
+    def _where(tables, idx, live, per: int):
+        """(block, offset) of items ``idx`` (N, W), ``per`` of them a
+        block, in the requests of ``tables``: the null block where not
+        ``live``, before the request or past its table."""
+        span = tables.shape[1]
+        blk = jnp.take_along_axis(tables, jnp.clip(idx // per, 0, span - 1),
+                                  axis=1)
+        ok = live & (idx >= 0) & (idx < span * per)
+        return jnp.where(ok, blk, NULL_BLOCK), idx % per
+
+    def _write(self, entry, tables, pos, live, k, v):
+        """``k``, ``v`` (N, W, Hkv, D) at positions ``pos`` (N, W)."""
+        keys, values, kernels = entry
+        blk, off = self._where(tables, pos, live, keys.shape[2])
+        h = _iota(self.kv_heads)
+        at = (blk[..., None], h, off[..., None])
+        return (keys.at[at].set(k.astype(keys.dtype)),
+                values.at[at].set(v.astype(values.dtype)), kernels)
+
+    def _read(self, arena, tables, pos):
+        """The rows at positions ``pos`` (N, W): (N, W, Hkv, D)."""
+        blk, off = self._where(tables, pos, True, arena.shape[2])
+        return arena[blk[..., None], _iota(self.kv_heads), off[..., None]]
+
+    def _write_kernels(self, entry, tables, first, pooled, keep):
+        """``pooled`` (N, J, Hkv, D): kernels ``first + j`` of each
+        request, written where ``keep`` (N, J)."""
+        r = self.geom.per_block
+        idx = first[:, None] + _iota(pooled.shape[1])[None, :]
+        blk, off = self._where(tables, idx, keep, r)
+        arena = entry[2]
+        rows = pooled.reshape(pooled.shape[:2] + (-1,)).astype(arena.dtype)
+        return entry[:2] + (arena.at[blk * r + off].set(rows),)
+
+    def _kernels(self, entry, tables):
+        """Each request's kernels through its table, (N, max_blocks *
+        per_block, Hkv, D)."""
+        r = self.geom.per_block
+        rows = (tables[:, :, None] * r + _iota(r)).reshape(tables.shape[0], -1)
+        return entry[2][rows].reshape(rows.shape + (self.kv_heads,
+                                                    self.head_dim))
+
+    # ---- the programs' forms ----------------------------------------------
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        g = self.geom
+        tables = addr.tables
+        n, mb = tables.shape
+        qg, k, v = op.project(weights, x)             # one token a slot
+        pos = seq_lens[:, None]
+        active = tables[:, 0] != NULL_BLOCK
+        entry = self._write(entry, tables, pos, active[:, None], k, v)
+        # the kernel whose last key this one is, if it is one's
+        back = pos - (g.kernel - 1) + _iota(g.kernel)[None, :]
+        pooled = self._read(entry[0], tables, back).astype(
+            jnp.float32).mean(1, keepdims=True)
+        begun = seq_lens - (g.kernel - 1)
+        entry = self._write_kernels(
+            entry, tables, begun // g.stride, pooled,
+            (active & (begun >= 0) & (begun % g.stride == 0))[:, None])
+        keys, values, _ = entry
+        with jax.named_scope("sparse_select"):
+            score = bsa.block_scores(bsa.kernel_scores(
+                qg, self._kernels(entry, tables), pos, g, op.scale),
+                pos, g)[:, :, 0]                      # (n, Hkv, mb)
+        below = seq_lens < g.dense_len
+        narrow = min(g.topk, mb)
+
+        def attend(count):
+            ids = jax.lax.top_k(score, count)[1].astype(jnp.int32)
+            counted = (_iota(count) < g.topk) | below[:, None, None]
+            phys = tables[_iota(n)[:, None, None], ids]
+            h = _iota(self.kv_heads)[None, :, None]
+            kg, vg = keys[phys, h], values[phys, h]   # (n, Hkv, count, bs, D)
+            s = jnp.einsum("nhgd,nhcbd->nhgcb", qg[:, 0], kg,
+                           preferred_element_type=jnp.float32) * op.scale
+            kpos = ids[..., None] * g.block + _iota(g.block)
+            see = counted[..., None] & (kpos <= seq_lens[:, None, None, None])
+            s = jnp.where(see[:, :, None], s, bsa.NEG)
+            p = jax.nn.softmax(s.reshape(s.shape[:3] + (-1,)), axis=-1)
+            o = jnp.einsum("nhgcb,nhcbd->nhgd",
+                           p.reshape(s.shape).astype(vg.dtype), vg,
+                           preferred_element_type=jnp.float32)
+            return o.astype(x.dtype), ids[..., :narrow]
+
+        # a slot below dense_len reads every block it has, up to dense_len
+        # / block of them; a step none of whose slots is gathers topk
+        wide = g.widest_read(mb)
+        with jax.named_scope("sparse_attend"):
+            if wide == narrow:
+                o, ids = attend(narrow)
+            else:
+                o, ids = jax.lax.cond(jnp.any(active & below),
+                                      lambda: attend(wide),
+                                      lambda: attend(narrow))
+        out = op.finish(weights, x, o.reshape(n, 1, -1, self.head_dim))
+        return out, entry, ids[:, :, None]
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        g = self.geom
+        tables = addr.tables
+        n, s, _ = x.shape
+        mb = tables.shape[1]
+        qg, k, v = op.project(weights, x)
+        pos = offsets[:, None] + _iota(s)[None, :]
+        live = _iota(s)[None, :] < lengths[:, None]
+        entry = self._write(entry, tables, pos, live, k, v)
+        # the kernels that end inside the chunk: the first of them begins
+        # kernel - stride keys before it
+        lap = g.kernel - g.stride
+        before = self._read(entry[0], tables,
+                            offsets[:, None] - lap + _iota(lap)[None, :])
+        pad = -s % g.stride
+        pooled = bsa.pool_keys(jnp.concatenate(
+            [before.astype(k.dtype), jnp.pad(k, ((0, 0), (0, pad), (0, 0),
+                                                 (0, 0)))], axis=1), g)
+        first = (offsets - lap) // g.stride
+        idx = first[:, None] + _iota(pooled.shape[1])[None, :]
+        done = idx * g.stride + g.kernel <= (offsets + lengths)[:, None]
+        entry = self._write_kernels(entry, tables, first, pooled, done)
+        kernels = self._kernels(entry, tables)
+        # selection, a few queries at a time: the kernels' scores are
+        # (heads, queries, kernels)
+        count = min(g.topk, mb)
+        span_blocks = max(1, 512 // g.block)
+        width = -(-mb // span_blocks) * span_blocks
+        qb = 256 if s % 256 == 0 else s
+
+        def pick(part):
+            q_part, pos_part = part
+            ids = bsa.select(q_part, kernels, pos_part, g, op.scale, count)
+            seen = bsa.picked_blocks(ids, width) | (
+                pos_part < g.dense_len)[:, None, :, None]
+            return ids, seen
+
+        def parts(a):                  # (N, S, ...) -> (S / qb, N, qb, ...)
+            return jnp.moveaxis(a.reshape((n, s // qb, qb) + a.shape[2:]),
+                                1, 0)
+
+        ids, seen = jax.lax.map(pick, (parts(qg), parts(pos)))
+        ids, seen = (jnp.moveaxis(a, 0, 2).reshape(
+            (n, self.kv_heads, s, a.shape[-1])) for a in (ids, seen))
+        padded = jnp.pad(tables, ((0, 0), (0, width - mb)),
+                         constant_values=NULL_BLOCK)
+        keys, values, _ = entry
+
+        def read(j):
+            blocks = jax.lax.dynamic_slice_in_dim(padded, j * span_blocks,
+                                                  span_blocks, axis=1)
+            return keys[blocks], values[blocks]
+
+        span = span_blocks * g.block
+        spans = jnp.maximum((jnp.max(offsets + lengths) + span - 1) // span,
+                            1)
+        o = bsa.attend_blocked(qg, read, seen, pos, spans, g, span_blocks,
+                               op.scale)
+        return op.finish(weights, x, o), entry, ids
+
+    def whole(self, op, weights, x, positions):
+        return op.whole(weights, x)
+
+    def dense_shapes(self, batch, max_length, dtype):
+        blocks = -(-max_length // self.geom.block)
+        a = jax.ShapeDtypeStruct(
+            (batch, blocks * self.geom.block, self.kv_heads, self.head_dim),
+            dtype)
+        return (a, a)
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        return op.whole(weights, x, offset, cache)[:2]
+
+
+@dataclasses.dataclass(frozen=True)
+class DecayStateEntry(EntryKind):
+    """A lightning-attention op's one row a REQUEST: the float32 state of
+    its heads, ``(H, D, D)``, in the rows the pool hands out to every
+    ``per_request`` kind. float32 whatever ``kv_dtype`` says and one
+    token a slot a step, for :class:`StateEntry`'s reasons."""
+
+    heads: int
+    head_dim: int
+    name = "decay_state"
+    max_window = 1
+    per_request = True
+    chunked = True
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        if op.layer.inputs[1].tensor_id != positions_id:
+            raise ValueError(f"{op.name}: lightning attention has to take "
+                             f"the graph's positions input")
+        return cls(op.num_heads, op.head_dim)
+
+    def arenas(self, rows, block_size, dtype):
+        return (jax.ShapeDtypeStruct(
+            (rows, self.heads, self.head_dim, self.head_dim), jnp.float32),)
+
+    def stats(self):
+        return {"entry": self.name, "state_dtype": "float32"}
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return False
+
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        out, arena = op.step(weights, x, positions, entry[0], addr.rows)
+        return out, (arena,)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        """A row holds what the request before left: a first chunk starts
+        from zeros, not from it."""
+        state = jnp.where((offsets > 0)[:, None, None, None],
+                          entry[0][addr.rows], 0.0)
+        out, state = op.run(weights, x, positions, state, lengths)
+        return out, (entry[0].at[addr.rows].set(state),)
+
+    def whole(self, op, weights, x, positions):
+        return op.run(weights, x, positions, op.empty_state(x.shape[0]))
+
+    def dense_shapes(self, batch, max_length, dtype):
+        return self.arenas(batch, 0, dtype)
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        out, state = op.run(weights, x, positions, cache[0])
+        return out, (state,)
+
+
 # the kind of each op type that keeps something for a sequence: ``for_op``
 # as :meth:`EntryKind.for_op`
 KINDS: Dict[OpType, Callable[..., EntryKind]] = {
     OpType.MULTIHEAD_ATTENTION: PairEntry.for_op,
     OpType.LATENT_ATTENTION: LatentEntry.for_op,
     OpType.GATED_DELTA_NET: StateEntry.for_op,
+    OpType.BLOCK_SPARSE_ATTENTION: SparseEntry.for_op,
+    OpType.LIGHTNING_ATTENTION: DecayStateEntry.for_op,
 }
 
 
@@ -534,5 +840,6 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
     return None
 
 
-__all__ = ["EntryKind", "Int8PairEntry", "KINDS", "LatentEntry", "PairEntry",
-           "StateEntry", "kind_for", "latent_row_lanes"]
+__all__ = ["DecayStateEntry", "EntryKind", "Int8PairEntry", "KINDS",
+           "LatentEntry", "PairEntry", "SparseEntry", "StateEntry", "kind_for",
+           "latent_row_lanes"]

@@ -276,13 +276,20 @@ class _DecodeGraph:
             acts[self._pos_id.tensor_id] = positions
         return acts
 
-    def _forward_block(self, params, acts, attn, experts=None):
+    def _forward_block(self, params, acts, attn, experts=None, tail=None):
         """Walk the op graph over the activations in ``acts``; ``attn``
         handles each attention op, given ``(op, weights, x, positions)``
         (it calls the op's entry kind in the program's cache layout) and
         ``experts``, where given, each routed-experts op (the paged
         programs keep the routing they chose). Returns the (B, S, vocab)
-        float32 logits."""
+        float32 logits.
+
+        ``tail`` says what to do behind the last op that keeps something
+        for a sequence, where every op left works a position at a time:
+        ``"skip"`` ends the walk there (a chunk that is not a prompt's
+        last writes its entries and needs nothing more; returns None), a
+        (B,) int32 array keeps that one position of each row, so that the
+        head is computed for it alone (returns (B, 1, vocab))."""
         ctx = LowerCtx(mesh=None, training=False, aux_losses=[],
                        compute_dtype=None)
         positions = (None if self._pos_id is None
@@ -298,6 +305,12 @@ class _DecodeGraph:
                 outs = op.forward(ctx, ins, p)
             for out, t in zip(outs, op.layer.outputs):
                 acts[t.tensor_id] = out
+            if tail is not None and op is self._attn_ops[-1]:
+                if isinstance(tail, str):
+                    return None
+                acts = {tid: jnp.take_along_axis(
+                    a, tail.reshape((-1, 1) + (1,) * (a.ndim - 2)), axis=1)
+                    for tid, a in acts.items()}
         logits = acts[self._cm.logits_tensor.tensor_id]
         return logits.astype(jnp.float32)
 
@@ -498,6 +511,7 @@ class PagedDecoder(_DecodeGraph):
     def __init__(self, ff, max_length: int, *, decode_slots: int = 4,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  prefill_buckets: Optional[Sequence[int]] = None,
+                 prefill_chunk: Optional[int] = None,
                  kv_dtype: str = "float32",
                  kv_divergence_budget: Optional[float] = None,
                  calibrate: bool = True):
@@ -506,6 +520,21 @@ class PagedDecoder(_DecodeGraph):
             raise ValueError(f"decode_slots {decode_slots} < 1")
         self.decode_slots = int(decode_slots)
         self.block_size = int(block_size)
+        # prompts prefilled as chunks of this many tokens, each continuing
+        # from what the chunks before left (None: a whole prompt a bucket)
+        self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
+        if self.prefill_chunk:
+            whole = [name for name, k in self._kinds.items() if not k.chunked]
+            if whole:
+                raise ValueError(
+                    f"prefill_chunk: a {self._kinds[whole[0]].name} cache "
+                    f"entry prefills a prompt whole, it does not continue "
+                    f"from a chunk ({whole[0]} and {len(whole) - 1} more)")
+            if self.prefill_chunk % self.block_size:
+                raise ValueError(
+                    f"prefill_chunk {self.prefill_chunk} is not a multiple "
+                    f"of block_size {self.block_size}")
+            prefill_buckets = [self.prefill_chunk]
         self.max_blocks_per_request = max(
             1, math.ceil(self.max_length / self.block_size))
         if num_blocks is None:
@@ -532,17 +561,21 @@ class PagedDecoder(_DecodeGraph):
         self._ids: jax.Array = jax.device_put(
             np.zeros((self.decode_slots,), np.int32),
             NamedSharding(self._cm.mesh, PartitionSpec()))
-        # the expert ids the last prefill or decode call chose, {routed-
-        # experts op name: (rows..., k) int32 device array}: kept for
-        # whoever asks (a comparison with a reference), never fetched by
-        # the scheduler's loop
+        # the ids the last prefill or decode call's ops chose, {op name:
+        # int32 device array}: a routed-experts op's experts (rows..., k),
+        # a selecting attention op's key blocks (rows, key-value heads,
+        # positions, picks); kept for whoever asks (a comparison with a
+        # reference), never fetched by the scheduler's loop
         self.last_routing: Dict[str, jax.Array] = {}
         if prefill_buckets is None:
             prefill_buckets = default_prefill_buckets(self.max_length)
         self.prefill_buckets = sorted(
             {min(int(bkt), self.max_length) for bkt in prefill_buckets})
-        if self.prefill_buckets[-1] < self.max_length:
+        if (self.prefill_buckets[-1] < self.max_length
+                and not self.prefill_chunk):
             self.prefill_buckets.append(self.max_length)
+        # the chunk programs, by whether they compute the head
+        self._chunk_fns: Dict[bool, object] = {}
         self._decode = jax.jit(self._decode_step, donate_argnums=(2, 5))
         # one verify executable per window width W=k+1 (spec_k is a
         # session knob, so in practice this holds one entry)
@@ -601,8 +634,9 @@ class PagedDecoder(_DecodeGraph):
         active = addr.tables[:, 0] != NULL_BLOCK
 
         def attn(op, p, x, pos):
-            out, new_pool[op.name] = self.pool.kinds[op.name].step(
+            out, new_pool[op.name], *picked = self.pool.kinds[op.name].step(
                 op, p, x, pos, new_pool[op.name], addr, seq_lens)
+            routed.update({op.name: ids for ids in picked})
             return out
 
         def experts(op, p, x):
@@ -667,20 +701,59 @@ class PagedDecoder(_DecodeGraph):
         new_pool = dict(pool)
         routed: Dict[str, jax.Array] = {}
 
+        def attn(op, p, x, pos):
+            out, new_pool[op.name], *picked = self.pool.kinds[
+                op.name].prefill(op, p, x, pos, new_pool[op.name], addr,
+                                 lengths)
+            routed.update({op.name: ids for ids in picked})
+            return out
+
+        logits = self._forward_block(params, acts, attn,
+                                     self._routing_kept(routed))
+        last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
+        return last, new_pool, routed
+
+    def _chunk_step(self, params, tokens, pool, addr, offsets, lengths, *,
+                    head: bool):
+        """One chunk of a prompt a row: tokens (P, C) int32 at positions
+        ``offsets .. offsets + C - 1`` (offsets (P,) int32, multiples of
+        the chunk), of which the first ``lengths`` (P,) count, BEHIND what
+        the chunks before wrote where ``addr`` says: the blocks and, over
+        a pool of states, the rows. Every op that keeps something
+        continues from it (its kind's ``chunk``). ``head`` (static): the
+        chunk is its prompts' last, and the program returns the (P, vocab)
+        float32 logits of each row's last position, the head computed for
+        that position alone; else the walk ends behind the last such op
+        and it returns None in their place. Also returns the new pool and
+        the ids the ops chose (experts, selected blocks)."""
+        positions = offsets[:, None] + jax.lax.iota(
+            jnp.int32, tokens.shape[1])[None, :]
+        acts = self._inputs(tokens, positions)
+        new_pool = dict(pool)
+        routed: Dict[str, jax.Array] = {}
+
+        def attn(op, p, x, pos):
+            out, new_pool[op.name], *picked = self.pool.kinds[op.name].chunk(
+                op, p, x, pos, new_pool[op.name], addr, offsets, lengths)
+            routed.update({op.name: ids for ids in picked})
+            return out
+
+        logits = self._forward_block(
+            params, acts, attn, self._routing_kept(routed),
+            tail=jnp.maximum(lengths - 1, 0) if head else "skip")
+        return (logits[:, 0] if head else None), new_pool, routed
+
+    @staticmethod
+    def _routing_kept(routed: Dict[str, jax.Array]):
+        """What a prompt program does with a routed-experts op: route,
+        keep the (rows, positions, k) expert ids in ``routed``, apply."""
         def experts(op, p, x):
             x2d = x.reshape(-1, x.shape[-1])
             ids, gates = op.route(p, x2d)
-            routed[op.name] = ids.reshape(b, s_blk, -1)
+            routed[op.name] = ids.reshape(x.shape[:2] + (-1,))
             return op.apply(p, x2d, ids, gates).reshape(x.shape)
 
-        def attn(op, p, x, pos):
-            out, new_pool[op.name] = self.pool.kinds[op.name].prefill(
-                op, p, x, pos, new_pool[op.name], addr, lengths)
-            return out
-
-        logits = self._forward_block(params, acts, attn, experts)
-        last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
-        return last, new_pool, routed
+        return experts
 
     def _new_pool(self, num_blocks: int) -> PagedKVPool:
         """A pool of the ops' entries stored as ``kv_dtype`` says, and
@@ -805,6 +878,9 @@ class PagedDecoder(_DecodeGraph):
         if not prompts or len(prompts) != len(tables):
             raise ValueError("prefill group needs matching non-empty "
                              "prompt/table lists")
+        if self.prefill_chunk:
+            return np.stack([self._prefill_in_chunks(p, t)
+                             for p, t in zip(prompts, tables)])
         arrs = [np.asarray(p, np.int32).ravel() for p in prompts]
         lens = [int(a.shape[0]) for a in arrs]
         if min(lens) < 1:
@@ -832,6 +908,51 @@ class PagedDecoder(_DecodeGraph):
                 self._exec_params(), jnp.asarray(toks), self.pool.kv,
                 self._addresses(tabs), jnp.asarray(lengths))
         return self._fetch(logits)[:len(arrs)]
+
+    def _prefill_in_chunks(self, prompt, table) -> np.ndarray:
+        """A whole prompt, chunk after chunk; its last position's logits."""
+        prompt = np.asarray(prompt, np.int32).ravel()
+        if not 1 <= prompt.shape[0] <= self.max_length:
+            raise ValueError(f"prompt of {prompt.shape[0]} tokens: expected "
+                             f"1 .. max_length {self.max_length}")
+        for at in range(0, prompt.shape[0], self.prefill_chunk):
+            logits = self.prefill_chunk_at(prompt, table, at)
+        return logits
+
+    def prefill_chunk_at(self, prompt: np.ndarray, table: np.ndarray,
+                         offset: int) -> Optional[np.ndarray]:
+        """The chunk of ``prompt`` (S,) that starts at ``offset`` (a
+        multiple of ``prefill_chunk``; the chunks before it have run),
+        written through ``table``. Where it is the prompt's last, waits
+        and returns the last position's (vocab,) float32 logits; else
+        returns None without waiting for the device."""
+        c = self.prefill_chunk
+        part = prompt[offset:offset + c]
+        last = offset + c >= prompt.shape[0]
+        fn = self._chunk_fns.get(last)
+        if fn is None:
+            def program(*args):
+                return self._chunk_step(*args, head=last)
+
+            # the name a device trace shows the program under
+            program.__name__ = "_chunk_step_head" if last else "_chunk_step"
+            fn = jax.jit(program, donate_argnums=(2,))
+            self._chunk_fns[last] = fn
+            from ..obs.metrics import metrics_registry
+
+            metrics_registry().counter(
+                "serving.prefill_bucket_compiles").inc()
+        toks = np.zeros((1, c), np.int32)
+        toks[0, :part.shape[0]] = part
+        tabs = np.full((1, self.max_blocks_per_request), NULL_BLOCK, np.int32)
+        table = np.asarray(table, np.int32).ravel()
+        tabs[0, :table.shape[0]] = table
+        with span("serving.loop.dispatch", cat="serving"):
+            logits, self.pool.kv, self.last_routing = fn(
+                self._exec_params(), jnp.asarray(toks), self.pool.kv,
+                self._addresses(tabs), jnp.asarray([offset], jnp.int32),
+                jnp.asarray([part.shape[0]], jnp.int32))
+        return self._fetch(logits)[0] if last else None
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
                seq_lens: np.ndarray) -> np.ndarray:
@@ -977,8 +1098,11 @@ class PagedDecoder(_DecodeGraph):
                                "fresh pool")
         try:
             self.prefill(prompt, table)
-            routed = {k: [np.asarray(v)[0, :prompt_len]]
-                      for k, v in self.last_routing.items()}
+            # the experts' routing alone: a selecting attention op's
+            # picks are its own business
+            experts = [op.name for op in self._expert_ops]
+            routed = {k: [np.asarray(self.last_routing[k])[0, :prompt_len]]
+                      for k in experts}
             toks = np.zeros(self.decode_slots, np.int32)
             toks[0] = nxt
             tabs = np.full((self.decode_slots, self.max_blocks_per_request),
@@ -987,8 +1111,8 @@ class PagedDecoder(_DecodeGraph):
             lens = np.zeros(self.decode_slots, np.int32)
             lens[0] = prompt_len
             q_row = self.decode(toks, tabs, lens)[0]
-            for k, v in self.last_routing.items():
-                routed[k].append(np.asarray(v)[:1])
+            for k in experts:
+                routed[k].append(np.asarray(self.last_routing[k])[:1])
         finally:
             self.pool.free(table)
         # a routed layer is discontinuous: two programs a rounding apart

@@ -22,6 +22,12 @@ steps**:
   ``max_prefills_per_step`` prefills are interleaved between decode
   steps while requests are active, so a long prompt burst cannot stall
   in-flight decodes unboundedly;
+* with ``prefill_chunk`` a prompt is prefilled as chunks of that many
+  tokens, each continuing from the blocks and states the chunks before
+  it wrote (the entry kinds' ``chunk``): a request takes its slot and
+  its blocks at admission, and the loop runs at most ONE chunk between
+  two decode steps, so a decoding slot's gap is a step plus a chunk
+  however long the prompts are;
 * admission control degrades gracefully (PR 11 semantics): a queue past
   ``admission_limit`` sheds (:class:`ShedError`), a request whose worst
   case (prompt + ``max_new_tokens``) can never fit the pool sheds
@@ -133,6 +139,8 @@ class _LoopClock:
         self.step_wall = Histogram()
         self.token_gap = Histogram()
         self.ahead = {"steps_ahead": 0, "steps_sync": 0, "rows_dropped": 0}
+        # prompts prefilled in chunks: the chunks run, and their tokens
+        self.chunks = {"prefill_chunks": 0, "prefill_tokens": 0}
 
     def enter(self, phase: Optional[str]):
         """The thread is in ``phase`` from now on. Returns the boundary
@@ -160,6 +168,11 @@ class _LoopClock:
         with self._lock:
             self.ahead[key] += n
 
+    def count_chunk(self, tokens: int) -> None:
+        with self._lock:
+            self.chunks["prefill_chunks"] += 1
+            self.chunks["prefill_tokens"] += tokens
+
     def snapshot(self) -> Dict:
         """``stats()["loop"]``; the phase that is open is charged up to
         this moment, so two snapshots subtract to what lay between."""
@@ -172,9 +185,10 @@ class _LoopClock:
                 now = self.t  # the loop has ended, or never began
             steps = self.steps
             ahead = dict(self.ahead)
+            chunks = dict(self.chunks)
             elapsed = 0.0 if self.t_start is None else now - self.t_start
         return {"steps": steps, "elapsed_s": elapsed, "phase_s": phase_s,
-                "ahead": ahead,
+                "ahead": ahead, **chunks,
                 "step_wall": self.step_wall.to_json(),
                 "token_gap": self.token_gap.to_json()}
 
@@ -216,7 +230,8 @@ class GenerationRequest:
     __slots__ = ("request_id", "prompt", "max_new_tokens", "temperature",
                  "seed", "eos_id", "deadline_s", "t_enqueue", "future",
                  # scheduler-thread-only runtime state
-                 "table", "seq_len", "in_flight", "tokens", "rng", "t_admit",
+                 "table", "seq_len", "in_flight", "prefill_left", "tokens",
+                 "rng", "t_admit",
                  "t_prefill_done", "t_first_token", "t_last_token",
                  "decode_t0", "decode_steps")
 
@@ -235,6 +250,7 @@ class GenerationRequest:
         self.table = None
         self.seq_len = 0  # rows cached, those of steps in flight included
         self.in_flight = 0  # tokens dispatched for and not yet read
+        self.prefill_left = 0  # prompt tokens its chunks have yet to take
         self.tokens: List[int] = []
         self.rng = None
         self.t_admit = None
@@ -265,6 +281,7 @@ class ContinuousBatchingScheduler:
                  decode_slots: int = 4, block_size: int = 16,
                  num_blocks: Optional[int] = None,
                  prefill_buckets: Optional[Sequence[int]] = None,
+                 prefill_chunk: Optional[int] = None,
                  max_prefills_per_step: int = 1,
                  prefill_token_budget: int = 0,
                  admission_limit: Optional[int] = None,
@@ -282,8 +299,8 @@ class ContinuousBatchingScheduler:
         self.decoder = PagedDecoder(
             ff, max_length, decode_slots=decode_slots,
             block_size=block_size, num_blocks=num_blocks,
-            prefill_buckets=prefill_buckets, kv_dtype=kv_dtype,
-            kv_divergence_budget=kv_divergence_budget)
+            prefill_buckets=prefill_buckets, prefill_chunk=prefill_chunk,
+            kv_dtype=kv_dtype, kv_divergence_budget=kv_divergence_budget)
         self.spec_k = max(0, int(spec_k))
         self.draft: Optional[PagedDecoder] = None
         if self.spec_k > 0:
@@ -318,6 +335,7 @@ class ContinuousBatchingScheduler:
                 block_size=self.decoder.block_size,
                 num_blocks=self.decoder.pool.num_blocks,
                 prefill_buckets=self.decoder.prefill_buckets,
+                prefill_chunk=self.decoder.prefill_chunk,
                 kv_dtype=self.decoder.kv_dtype, calibrate=False)
             # a draft's rejected tokens are rolled back as the target's are
             self.draft.check_window(self.spec_k + 1)
@@ -326,8 +344,17 @@ class ContinuousBatchingScheduler:
         self._blocks_in_tables = 0
         # active slots x the ops that keep a state a request
         self._rows_stepped = 0
-        self._state_ops = sum(
-            k.per_request for k in self.decoder.pool.kinds.values())
+        kinds = list(self.decoder.pool.kinds.values())
+        self._state_ops = sum(k.per_request for k in kinds)
+        # a kind whose steps read a selection of a request's blocks (its
+        # ops share one geometry): what they read, beside the live blocks
+        self._selecting = next(
+            (k for k in kinds if k.blocks_read(0) is not None), None)
+        self._selected_read = 0
+        self._selected_live = 0
+        # requests admitted whose prompts are still being prefilled in
+        # chunks, oldest first (the loop's thread alone touches it)
+        self._prefilling: collections.deque = collections.deque()
         self._spec_rounds = 0
         self._spec_slot_rounds = 0
         self._spec_proposed = 0
@@ -601,24 +628,24 @@ class ContinuousBatchingScheduler:
         cause. With ``prefill_token_budget`` set the stall bound is
         token-native instead: see :meth:`_admit_batched`."""
         with self._phase("admit") as ph:
-            if self.prefill_token_budget > 0:
+            if self.decoder.prefill_chunk:
+                n = self._admit_chunked(closed)
+            elif self.prefill_token_budget > 0:
                 n = self._admit_batched(closed)
             else:
                 n = self._admit_single(closed)
             ph.span.set(admitted=n)
+        if self._prefilling:
+            self._prefill_next_chunk()
 
-    def _admit_single(self, closed: bool) -> int:
-        """One prefill dispatch a prompt; returns how many it admitted."""
-        reg = metrics_registry()
-        with self._mu:
-            active = any(r is not None for r in self._slots)
-            n_slots = len(self._slots)
-        budget = self.max_prefills_per_step if active else n_slots
-        admitted = 0
-        while admitted < budget:
+    def _pop_live(self, closed: bool) -> Optional[GenerationRequest]:
+        """The queue's next request that is still wanted, or None where
+        the queue is empty: a request met after ``stop()`` fails with
+        "engine stopped", one past its deadline is rejected."""
+        while True:
             with self._mu:
                 if not self._queue:
-                    return admitted
+                    return None
                 req = self._queue.popleft()
             if closed:
                 if not req.future.done():
@@ -629,37 +656,125 @@ class ContinuousBatchingScheduler:
             if req.expired(now):
                 with self._mu:
                     self._deadline_rejects += 1
-                reg.counter("serving.deadline_rejects").inc()
+                metrics_registry().counter("serving.deadline_rejects").inc()
                 if not req.future.done():
                     req.future.set_exception(DeadlineExceeded(
                         f"request {req.request_id} waited "
                         f"{now - req.t_enqueue:.3f}s > deadline "
                         f"{req.deadline_s:.3f}s"))
                 continue
-            slot = None
+            return req
+
+    def _reserve(self, req: GenerationRequest, taken=()) -> Optional[int]:
+        """A free decode slot (not among ``taken``) and the request's
+        worst case in the pool, or the request goes back to the head of
+        the queue (a retirement frees both: bounded). Returns the slot,
+        with ``req.table`` and its queue wait set; else None."""
+        slot = None
+        with self._mu:
+            for i, r in enumerate(self._slots):
+                if r is None and i not in taken:
+                    slot = i
+                    break
+        table = None if slot is None else self.decoder.pool.try_admit(
+            req.prompt.size + req.max_new_tokens)
+        if table is None:
+            with self._mu:
+                self._queue.appendleft(req)
+            return None
+        now = time.perf_counter()
+        with self._mu:
+            req.table = table
+            req.t_admit = now
+            self._observe_lat("queue_wait", now - req.t_enqueue)
+        metrics_registry().histogram("serving.gen_queue_wait_s").observe(
+            now - req.t_enqueue)
+        return slot
+
+    def _admit_chunked(self, closed: bool) -> int:
+        """Admission where prompts are prefilled in chunks: a queued
+        request that finds a free slot and its worst case in the pool
+        takes both at once, which costs the device nothing; its prompt
+        is then prefilled a chunk a pass (:meth:`_prefill_next_chunk`),
+        oldest request first, while the other slots decode."""
+        admitted = 0
+        while True:
+            req = self._pop_live(closed)
+            if req is None:
+                return admitted
+            slot = self._reserve(req)
+            if slot is None:
+                return admitted
+            with self._mu:
+                req.prefill_left = int(req.prompt.size)
+                self._slots[slot] = req
+            self._prefilling.append(req)
+            admitted += 1
+
+    def _prefill_next_chunk(self) -> None:
+        """The next chunk of the oldest prompt still being prefilled:
+        ONE chunk between two decode steps, so a decoding slot waits a
+        step and a chunk at most. A chunk that is not its prompt's last
+        is dispatched and not waited for; the last one's logits are
+        fetched and its request's first token sampled. A failure fails
+        that request alone."""
+        while self._prefilling and self._prefilling[0].future.done():
+            self._prefilling.popleft()  # expired or failed since admitted
+        if not self._prefilling:
+            return
+        req = self._prefilling[0]
+        at = int(req.prompt.size) - req.prefill_left
+        n = min(self.decoder.prefill_chunk, req.prefill_left)
+        last = n == req.prefill_left
+        try:
+            with self._phase("chunk", "prefill", request_id=req.request_id,
+                             offset=at, tokens=n, last=int(last)) as ph:
+                logits = _DECODE_RETRY.call(self.decoder.prefill_chunk_at,
+                                            req.prompt, req.table, at)
+        except Exception as e:  # noqa: BLE001 — fail THIS request only
+            metrics_registry().counter("serving.errors").inc()
+            self._prefilling.popleft()
             with self._mu:
                 for i, r in enumerate(self._slots):
-                    if r is None:
-                        slot = i
-                        break
+                    if r is req:
+                        self._slots[i] = None
+            self.decoder.pool.free(req.table)
+            if not req.future.done():
+                req.future.set_exception(e)
+            return
+        self._clock.count_chunk(n)
+        with self._mu:
+            self._prefill_dispatches += 1
+            if last:
+                self._prefill_prompts += 1
+                req.t_prefill_done = ph.t1
+                req.seq_len = int(req.prompt.size)
+                req.rng = np.random.default_rng(req.seed)
+                self._observe_lat("prefill", ph.t1 - req.t_admit)
+            req.prefill_left -= n
+        if not last:
+            return
+        self._prefilling.popleft()
+        metrics_registry().histogram("serving.prefill_s").observe(
+            ph.t1 - req.t_admit)
+        with self._phase("sample", tokens=1):
+            self._append_token(req, logits)
+
+    def _admit_single(self, closed: bool) -> int:
+        """One prefill dispatch a prompt; returns how many it admitted."""
+        reg = metrics_registry()
+        with self._mu:
+            active = any(r is not None for r in self._slots)
+            n_slots = len(self._slots)
+        budget = self.max_prefills_per_step if active else n_slots
+        admitted = 0
+        while admitted < budget:
+            req = self._pop_live(closed)
+            if req is None:
+                return admitted
+            slot = self._reserve(req)
             if slot is None:
-                with self._mu:
-                    self._queue.appendleft(req)
                 return admitted
-            table = self.decoder.pool.try_admit(
-                req.prompt.size + req.max_new_tokens)
-            if table is None:
-                # pool momentarily full: head of line waits for a
-                # retirement (bounded — actives free their worst case)
-                with self._mu:
-                    self._queue.appendleft(req)
-                return admitted
-            with self._mu:
-                req.table = table
-                req.t_admit = now
-                self._observe_lat("queue_wait", now - req.t_enqueue)
-            reg.histogram("serving.gen_queue_wait_s").observe(
-                now - req.t_enqueue)
             try:
                 self._prefill(req)
             except Exception as e:  # noqa: BLE001 — fail THIS request only
@@ -692,7 +807,6 @@ class ContinuousBatchingScheduler:
         decode-stall bound is measured in tokens, which is what the
         stall actually costs, instead of prompt count. Returns how many
         prompts it sent to prefill."""
-        reg = metrics_registry()
         with self._mu:
             active = any(r is not None for r in self._slots)
             n_slots = len(self._slots)
@@ -700,55 +814,18 @@ class ContinuousBatchingScheduler:
         reserved: set = set()
         spent = 0
         while len(batch) < n_slots:
-            with self._mu:
-                if not self._queue:
-                    break
-                req = self._queue.popleft()
-            if closed:
-                if not req.future.done():
-                    req.future.set_exception(
-                        RuntimeError("engine stopped"))
-                continue
-            now = time.perf_counter()
-            if req.expired(now):
-                with self._mu:
-                    self._deadline_rejects += 1
-                reg.counter("serving.deadline_rejects").inc()
-                if not req.future.done():
-                    req.future.set_exception(DeadlineExceeded(
-                        f"request {req.request_id} waited "
-                        f"{now - req.t_enqueue:.3f}s > deadline "
-                        f"{req.deadline_s:.3f}s"))
-                continue
+            req = self._pop_live(closed)
+            if req is None:
+                break
             bucket = self.decoder.bucket_for(req.prompt.size)
             if active and batch and spent + bucket > \
                     self.prefill_token_budget:
                 with self._mu:
                     self._queue.appendleft(req)
                 break
-            slot = None
-            with self._mu:
-                for i, r in enumerate(self._slots):
-                    if r is None and i not in reserved:
-                        slot = i
-                        break
+            slot = self._reserve(req, reserved)
             if slot is None:
-                with self._mu:
-                    self._queue.appendleft(req)
                 break
-            table = self.decoder.pool.try_admit(
-                req.prompt.size + req.max_new_tokens)
-            if table is None:
-                # pool momentarily full: head of line keeps its place
-                with self._mu:
-                    self._queue.appendleft(req)
-                break
-            with self._mu:
-                req.table = table
-                req.t_admit = now
-                self._observe_lat("queue_wait", now - req.t_enqueue)
-            reg.histogram("serving.gen_queue_wait_s").observe(
-                now - req.t_enqueue)
             reserved.add(slot)
             spent += bucket
             batch.append((slot, req, bucket))
@@ -868,6 +945,7 @@ class ContinuousBatchingScheduler:
                             f"tokens)"))
             active = [(i, r) for i, r in enumerate(slots)
                       if r is not None and i not in expired
+                      and not r.prefill_left
                       and len(r.tokens) + r.in_flight < r.max_new_tokens]
             if not active:
                 return None
@@ -887,6 +965,10 @@ class ContinuousBatchingScheduler:
                     # the share of its table the step reads: the blocks
                     # of the slot's cached tokens and the row it writes
                     self._blocks_read += (req.seq_len + bs) // bs
+                    if self._selecting is not None:
+                        self._selected_read += self._selecting.blocks_read(
+                            req.seq_len)
+                        self._selected_live += (req.seq_len + bs) // bs
                 self._blocks_in_tables += len(active) * tables.shape[1]
                 self._rows_stepped += len(active) * self._state_ops
         return active, tokens, tables, seq_lens
@@ -1327,6 +1409,9 @@ class ContinuousBatchingScheduler:
             blocks_read = self._blocks_read
             blocks_in_tables = self._blocks_in_tables
             rows_stepped = self._rows_stepped
+            selected = {"blocks_read": self._selected_read,
+                        "blocks_live": self._selected_live}
+            lengths = [r.seq_len for r in self._slots if r is not None]
         now = time.perf_counter()
         tps = (tokens / (now - t_start)
                if t_start is not None and now > t_start else 0.0)
@@ -1334,6 +1419,13 @@ class ContinuousBatchingScheduler:
         kv["attention_path"] = dict(self.decoder.attention_path)
         kv["blocks_read"] = blocks_read
         kv["blocks_in_tables"] = blocks_in_tables
+        if self._selecting is not None:
+            # what the steps of the ops that select blocks read, beside
+            # the live blocks of the slots they carried, and the pooled
+            # keys the requests in their slots hold now (one op's)
+            kv["selected"] = selected
+            kv["kernel_rows"] = sum(self._selecting.side_rows(n)
+                                    for n in lengths)
         if "state" in kv:
             kv["state"]["rows_stepped"] = rows_stepped
             kv["state"]["prefill_path"] = self.decoder.prefill_path
@@ -1384,6 +1476,8 @@ class ContinuousBatchingScheduler:
                 "num_blocks": self.decoder.pool.num_blocks,
                 "max_length": self.decoder.max_length,
                 "max_prefills_per_step": self.max_prefills_per_step,
+                **({"prefill_chunk": self.decoder.prefill_chunk}
+                   if self.decoder.prefill_chunk else {}),
                 **({"prefill_token_budget": self.prefill_token_budget}
                    if self.prefill_token_budget > 0 else {}),
                 **({"spec_k": self.spec_k} if self.spec_k > 0 else {}),

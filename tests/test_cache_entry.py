@@ -223,3 +223,113 @@ def test_spec_k_over_a_latent_model_is_refused_at_construction(latent_lm,
         GenerationInstance(latent_lm, decode_slots=2, block_size=8,
                            max_length=32, spec_k=2, draft_ff=latent_lm)
     PagedDecoder(gpt, 32, decode_slots=2, block_size=8).check_window(5)
+
+
+# ---- (c) the selecting kind and the decay's state ------------------------------
+
+GEOM = dict(kernel=8, stride=4, block=16, window=20, dense_len=64,
+            init_blocks=1, topk=4)
+
+
+@pytest.fixture(scope="module")
+def sparse_lm():
+    from flexflow_tpu.models import (SparseHybridConfig,
+                                     build_sparse_hybrid_lm)
+
+    return _model(build_sparse_hybrid_lm, 96, SparseHybridConfig(
+        vocab_size=V, hidden_size=32, num_heads=4, num_kv_heads=2,
+        head_dim=8, linear_heads=4, linear_head_dim=8, mlp_width=64,
+        selection=GEOM))
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16"])
+def test_the_sparse_and_decay_kinds_answer_for_their_arenas_and_limits(
+        kv_dtype):
+    """One pool, three arenas a sparse op (keys and values head-major in a
+    block, a pooled key every stride tokens) and one a decay op (float32
+    whatever ``kv_dtype`` says): shapes, bytes a token and a request,
+    names, limits, and what a step reads."""
+    from flexflow_tpu.ops.block_sparse_attention import Selection
+    from flexflow_tpu.serving.cache_entry import DecayStateEntry, SparseEntry
+
+    sparse = SparseEntry(2, 8, Selection(**GEOM))
+    decay = DecayStateEntry(4, 8)
+    pool = PagedKVPool({"d0": decay, "a": sparse, "d1": decay},
+                       num_blocks=NB, block_size=16,
+                       max_blocks_per_request=6, kv_dtype=kv_dtype,
+                       num_rows=4)
+    store = jnp.bfloat16 if kv_dtype == "bfloat16" else jnp.float32
+    assert [(a.shape, a.dtype) for a in pool.kv["a"]] == [
+        ((NB, 2, 16, 8), store), ((NB, 2, 16, 8), store),
+        ((NB * 4, 16), store)]
+    assert [(a.shape, a.dtype) for a in pool.kv["d0"]] == [
+        ((4, 4, 8, 8), jnp.float32)]
+    held = sum(a.nbytes for entry in pool.kv.values() for a in entry)
+    item = jnp.dtype(store).itemsize
+    assert sparse.token_bytes(store) == (2 * 16 + 16 // 4) * item
+    assert decay.token_bytes(store) == 4 * 8 * 8 * 4 and decay.per_request
+    assert pool.memory_bytes() == held == serving_kv_pool_bytes(
+        pool.specs, NB, 16, kv_dtype, num_rows=4)
+    st = pool.stats()
+    assert st["entry"] == {"decay_state": 2, "sparse": 1}
+    assert st["kernels_per_block"] == 4 and st["state_dtype"] == "float32"
+    assert st["state"]["row_bytes"] == 2 * 4 * 8 * 8 * 4
+    for kind in (sparse, decay):
+        assert kind.max_window == 1 and kind.int8_form is None
+        assert kind.chunked
+    # below dense_len every live block, past it topk of them
+    assert [sparse.blocks_read(n) for n in (0, 15, 16, 63, 64, 200)] == [
+        1, 1, 2, 4, 4, 4]
+    assert decay.blocks_read(200) is None and PairEntry(4, 8).blocks_read(
+        200) is None
+    # whole kernels over n keys: (n - 8) // 4 + 1
+    assert [sparse.side_rows(n) for n in (0, 7, 8, 11, 12, 100)] == [
+        0, 0, 1, 1, 2, 24]
+    assert [(a.shape, a.dtype) for a in sparse.dense_shapes(2, 40, store)] \
+        == [((2, 48, 2, 8), store)] * 2
+    with pytest.raises(ValueError, match="a: a sparse cache entry has no "
+                                         "int8 form"):
+        PagedKVPool({"a": sparse}, num_blocks=4, block_size=16,
+                    max_blocks_per_request=2, kv_dtype="int8")
+    with pytest.raises(ValueError, match="block_size 8 has to be that"):
+        PagedKVPool({"a": sparse}, num_blocks=4, block_size=8,
+                    max_blocks_per_request=2)
+
+
+@pytest.mark.parametrize("op_type,layer", [
+    (OpType.BLOCK_SPARSE_ATTENTION, "block1_mixer"),
+    (OpType.LIGHTNING_ATTENTION, "block0_mixer")])
+def test_the_new_kinds_are_a_class_and_a_line_in_kinds(sparse_lm, monkeypatch,
+                                                       op_type, layer):
+    """Taken out of ``KINDS`` the op is refused by name; a subclass
+    defined here and put in its place serves through the unedited decoder
+    and scheduler, in chunks, what the package's kind serves."""
+    own = cache_entry.KINDS[op_type]
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(0, V, (n,)).astype(np.int32), 6)
+            for n in (70, 20, 90)]
+    gen = Generator(sparse_lm, max_length=96, batch_size=1)
+    want = [gen.generate(p[None, :], m)[0] for p, m in reqs]
+
+    @dataclasses.dataclass(frozen=True)
+    class _Renamed(own.__self__):
+        name = "renamed"
+
+    monkeypatch.setitem(cache_entry.KINDS, op_type, _Renamed.for_op)
+    sched = ContinuousBatchingScheduler(sparse_lm, max_length=96,
+                                        decode_slots=2, block_size=16,
+                                        prefill_chunk=32)
+    try:
+        got = [f.result(timeout=300)
+               for f in [sched.submit(p, m) for p, m in reqs]]
+        stats = sched.stats()
+    finally:
+        sched.stop()
+    for out, ref in zip(got, want):
+        np.testing.assert_array_equal(out, ref)
+    assert isinstance(sched.decoder.pool.kinds[layer], _Renamed)
+    assert stats["kv"]["entry"]["renamed"] >= 1
+    monkeypatch.delitem(cache_entry.KINDS, op_type)
+    with pytest.raises(ValueError, match=rf"{layer}.*no cache entry kind "
+                                         rf".*{op_type.name}"):
+        PagedDecoder(sparse_lm, 96, decode_slots=2, block_size=16)

@@ -164,3 +164,103 @@ def test_gated_delta_prefill_compiles_for_v5e(monkeypatch, one_chip,
     assert text.count("tpu_custom_call") == 1
     assert text.count("func.func private @_gated_delta_chunks(") == 1
     assert text.count("call @_gated_delta_chunks(") == 12
+
+
+@pytest.fixture(scope="module")
+def sparse_hybrid_programs(one_chip):
+    """The decode step and both chunk programs of a sparse and a linear
+    layer at MiniCPM-SALA's published widths (contexts to 33,792, chunks
+    of 2,048, the whole vocabulary), compiled for the described chip:
+    {name: (the compiled text, its memory analysis)}."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import minicpm_sala as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "minicpm-sala-pp2.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=2,
+                  mixer_types=config["mixer_types"][:2])
+    slots, max_length, chunk = 4, 33792, 2048
+    ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                          ledger="off", search_cache="off",
+                          computation_mode=CompMode.INFERENCE))
+    family.build(ff, config, slots, max_length)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    dec = PagedDecoder(ff, max_length, decode_slots=slots, block_size=64,
+                       prefill_chunk=chunk, kv_dtype="bfloat16",
+                       calibrate=False)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def ints(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+    pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+    mb = dec.max_blocks_per_request
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        out = {}
+        for head in (False, True):
+            def program(*args, head=head):
+                return dec._chunk_step(*args, head=head)
+
+            compiled = jax.jit(program, donate_argnums=(2,)).lower(
+                params, ints(1, chunk), pool, Addresses(ints(1, mb), ints(1)),
+                ints(1), ints(1)).compile()
+            out["chunk_head" if head else "chunk"] = (
+                compiled.as_text(), compiled.memory_analysis())
+        compiled = dec._decode.lower(
+            params, ints(slots), pool,
+            Addresses(ints(slots, mb), ints(slots)), ints(slots), {},
+            ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+        out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head", "decode"])
+def test_sparse_hybrid_programs_hold_no_square_and_no_vocabulary_rows(
+        sparse_hybrid_programs, name):
+    """No buffer with two sequence axes (a chunk's 2,048 queries against
+    the 33,792 positions, or against themselves for all heads), none of
+    (tokens, vocabulary) beyond a row a request, and little beside the
+    weights and the pool."""
+    import re
+
+    text, mem = sparse_hybrid_programs[name]
+    shapes = set(re.findall(r"\[([0-9,]+)\]", text))
+    for dims in shapes:
+        dims = [int(d) for d in dims.split(",")]
+        assert not (2048 in dims and 33792 in dims), dims
+        assert dims.count(2048) < 2, dims
+        if 73448 in dims:                  # the head's matrix, or one row
+            rest = [d for d in dims if d != 73448]
+            assert rest in ([], [4096], [1], [1, 1], [4], [4, 1]), dims
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_sparse_hybrid_decode_loops_over_no_slots(sparse_hybrid_programs):
+    """The decode step's states are updated where they lie and its blocks
+    gathered by one gather: no ``while`` (a gather of rows of 2 MB lowers
+    to a sequential loop over the slots), and the chunk programs' loops
+    are at most the three they were written with (the linear layer's scan
+    over sub-chunks, the selection a few queries at a time, the key
+    spans)."""
+    assert " while(" not in sparse_hybrid_programs["decode"][0]
+    for name in ("chunk", "chunk_head"):
+        assert 1 <= sparse_hybrid_programs[name][0].count(" while(") <= 3
