@@ -861,6 +861,17 @@ def _train_data(sizes: SmokeSizes, n: int):
     return tok[:, :-1].copy(), pos, tok[:, 1:].copy()
 
 
+def _path_counts(reg, family: str, names) -> Dict[str, int]:
+    """The ``<family>.path.<name>`` counters: which implementation a
+    lowering took, counted once per trace."""
+    return {n: reg.counter(f"{family}.path.{n}").value for n in names}
+
+
+def _paths_taken(reg, family: str, before: Dict[str, int]) -> List[str]:
+    now = _path_counts(reg, family, before)
+    return sorted(n for n, v0 in before.items() if now[n] > v0)
+
+
 def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
     """``plan``: ``single`` (one device), ``dp`` (every device, data
     parallel) or ``searched`` (every device, the Unity search's choice)."""
@@ -877,8 +888,10 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
              f"plan {plan!r} on {n_dev} device(s)")
     batch = batch_per_device * n_dev
     reg = metrics_registry()
-    paths0 = {p: reg.counter(f"attention.path.{p}").value
-              for p in ("flash", "xla", "ring", "ulysses")}
+    paths0 = _path_counts(reg, "attention",
+                          ("flash", "xla", "ring", "ulysses"))
+    forms0 = _path_counts(reg, "loss",
+                          ("one_pass", "log_softmax", "probabilities"))
 
     ff = FFModel(_ff_config(batch_size=batch, epochs=2,
                             only_data_parallel=plan != "searched",
@@ -894,8 +907,8 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
         sizes, batch * sizes.steps_per_epoch)
     history = ff.fit([tokens, positions], labels, verbose=False)
     losses = [pm.sparse_cce_loss / max(1, pm.train_all) for pm in history]
-    paths = sorted(p for p, v0 in paths0.items()
-                   if reg.counter(f"attention.path.{p}").value > v0)
+    paths = _paths_taken(reg, "attention", paths0)
+    forms = _paths_taken(reg, "loss", forms0)
     # no silent fall-back: on the chip, at this shape, the step's
     # attention is the fused kernels (no (S, S) array in HBM), taken by
     # the shapes alone — main() pops every variable that could force it
@@ -903,6 +916,10 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
              f"the train step's attention took the {'+'.join(paths)!r} "
              f"path at sequence {sizes.seq}, {sizes.heads} heads of "
              f"{sizes.hidden // sizes.heads}")
+    # sparse labels on raw logits: the loss reads the head's logits where
+    # the head wrote them, by the loss type alone
+    _require(forms == ["one_pass"],
+             f"the train step's loss took the {'+'.join(forms)!r} form")
 
     _require(len(losses) == 2 and all(math.isfinite(x) for x in losses),
              f"epoch losses {losses}")
@@ -949,6 +966,7 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
         seq=sizes.seq, params=gpt_param_count(sizes),
         estimated_bytes=train_bytes_estimate(sizes, batch_per_device),
         steps=2 * sizes.steps_per_epoch, attention_path="+".join(paths),
+        loss_path="+".join(forms),
         loss_epoch0=round(losses[0], 4), loss_epoch1=round(losses[1], 4),
         epoch_wall_s=[e["wall_s"] for e in epochs],
         compiles_by_epoch=[e["compiles"] for e in epochs], **facts)

@@ -40,7 +40,7 @@ from ..core.machine import DATA_AXIS, make_mesh, mesh_axis_sizes
 from ..core.op import LowerCtx, Op, create_op
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 from ..core.tensor import Tensor
-from .loss import compute_loss
+from .loss import compute_loss, sparse_ce_from_logits
 from .metrics import compute_batch_metrics
 from .initializer import DeclaredInitializer
 from .optimizer import Optimizer
@@ -512,6 +512,20 @@ def compile_model(
         # loss/metrics always in float32, whatever the compute dtype
         return x.astype(jnp.float32) if cdt is not None else x
 
+    # sparse CE on raw logits reads them in the dtype the head wrote and
+    # does its float32 arithmetic on the fly: a float32 copy of a
+    # (tokens, vocabulary) array is most of what such a loss costs
+    one_pass = (loss_type is LossType.SPARSE_CATEGORICAL_CROSSENTROPY
+                and from_logits)
+
+    def _loss(acts, y):
+        """The loss and, on the one-pass path, the per-position
+        log-likelihoods it is the mean of (the metrics read them too)."""
+        if one_pass:
+            return sparse_ce_from_logits(acts[logits_id], y, mask_pad)
+        return compute_loss(loss_type, _f32(acts[logits_id]), y,
+                            from_logits, mask_pad), None
+
     # ---- train step --------------------------------------------------------
     # ``seq_length`` is a leading STATIC argument on every step function:
     # each distinct value compiles its own executable (bucketed compile) —
@@ -531,9 +545,7 @@ def compile_model(
                 ops, mesh, params, dict(zip(input_ids, xs)), True, rng,
                 seq_length, cdt,
             )
-            logits = _f32(acts[logits_id])
-            loss = compute_loss(loss_type, logits, y, from_logits,
-                                mask_pad)
+            loss, ll = _loss(acts, y)
             for a in aux:
                 loss = loss + _f32(a)
             # weight regularizers (keras frontend: kernel_regularizer attr;
@@ -544,13 +556,13 @@ def compile_model(
                 if reg is not None and hasattr(reg, "penalty") \
                         and op.name in params and "kernel" in params[op.name]:
                     loss = loss + reg.penalty(params[op.name]["kernel"])
-            return loss, (logits, updates)
+            return loss, (_f32(acts[logits_id]), ll, updates)
 
         vag = jax.value_and_grad(loss_fn, has_aux=True)
         if accum == 1:
-            (loss, (logits, updates)), grads = vag(params, xs, y, rng)
+            (loss, (logits, ll, updates)), grads = vag(params, xs, y, rng)
             batch_metrics = compute_batch_metrics(
-                metrics, loss_type, logits, y, from_logits, mask_pad)
+                metrics, loss_type, logits, y, from_logits, mask_pad, ll)
         else:
             # gradient accumulation: split the batch into K microbatches,
             # run them through a lax.scan (ONE compiled body, K x less
@@ -568,9 +580,10 @@ def compile_model(
             rngs = jax.random.split(rng, accum)
 
             def one(xs_i, y_i, rng_i):
-                (li, (lgi, updi)), gi = vag(params, xs_i, y_i, rng_i)
+                (li, (lgi, lli, updi)), gi = vag(params, xs_i, y_i, rng_i)
                 bmi = compute_batch_metrics(
-                    metrics, loss_type, lgi, y_i, from_logits, mask_pad)
+                    metrics, loss_type, lgi, y_i, from_logits, mask_pad,
+                    lli)
                 return li, gi, bmi, updi
 
             def micro(carry, mb):
@@ -665,8 +678,7 @@ def compile_model(
                 ops, mesh, params, dict(zip(input_ids, xs)), True, rng,
                 seq_length, cdt,
             )
-            loss = compute_loss(loss_type, _f32(acts[logits_id]), y,
-                                from_logits, mask_pad)
+            loss, _ll = _loss(acts, y)
             for a in aux:
                 loss = loss + _f32(a)
             return loss
@@ -680,10 +692,9 @@ def compile_model(
         acts, _, _ = _forward_graph(ops, mesh, params, dict(zip(input_ids, xs)),
                                     False, None, seq_length, cdt)
         logits = _f32(acts[logits_id])
-        loss = (compute_loss(loss_type, logits, y, from_logits, mask_pad)
-                if loss_type else jnp.zeros(()))
+        loss, ll = _loss(acts, y) if loss_type else (jnp.zeros(()), None)
         return loss, logits, compute_batch_metrics(
-            metrics, loss_type, logits, y, from_logits, mask_pad)
+            metrics, loss_type, logits, y, from_logits, mask_pad, ll)
 
     def forward_fn(params, *xs, seq_length: int = -1):
         acts, _, _ = _forward_graph(ops, mesh, params, dict(zip(input_ids, xs)),

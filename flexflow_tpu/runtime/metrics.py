@@ -12,12 +12,13 @@ by jax's async dispatch.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..ffconst import LossType, MetricsType
+from .loss import label_positions, masked_row_sums, sparse_log_likelihood
 
 
 @dataclasses.dataclass
@@ -122,6 +123,7 @@ def compute_batch_metrics(
     labels: jnp.ndarray,
     from_logits: bool = False,
     mask_padding: bool = False,
+    log_likelihood: Optional[jnp.ndarray] = None,
 ) -> Dict[str, jnp.ndarray]:
     """Per-batch metric computation (reference: Metrics::compute kernels,
     src/metrics_functions/metrics_functions.cu). Runs inside jit.
@@ -129,30 +131,39 @@ def compute_batch_metrics(
     in a softmax. ``mask_padding`` mirrors compute_loss's masked
     token-level path: ``-1``-labelled positions drop out of count /
     correct / cce sums exactly, with the same row-major two-stage
-    reduction so bucket widths fold bit-identically."""
+    reduction so bucket widths fold bit-identically.
+    ``log_likelihood``: the per-position terms of a sparse loss on raw
+    logits where the caller's loss already made them
+    (``loss.sparse_ce_from_logits``); without them they are made here by
+    the same function."""
     sparse = loss_type is LossType.SPARSE_CATEGORICAL_CROSSENTROPY
+    want_scce = (sparse
+                 and MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY in metrics)
+    lab = label_positions(logits, labels) if sparse else None
+    ll = None
+    if want_scce:
+        if from_logits:
+            ll = (log_likelihood if log_likelihood is not None
+                  else sparse_log_likelihood(logits, lab))
+        else:
+            ll = jnp.take_along_axis(
+                jnp.log(jnp.clip(logits, 1e-10, 1.0)),
+                (jnp.maximum(lab, 0) if mask_padding else lab)[..., None],
+                axis=-1)[..., 0]
     if sparse and logits.ndim >= 3 and mask_padding:
-        lab = labels.reshape(logits.shape[:-1]).astype(jnp.int32)
         valid = lab >= 0
         out: Dict[str, jnp.ndarray] = {"count": jnp.sum(valid)}
         if MetricsType.ACCURACY in metrics:
             pred = jnp.argmax(logits, axis=-1)
             out["correct"] = jnp.sum(
                 jnp.sum(valid & (pred == lab), axis=-1))
-        if MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY in metrics:
-            logp = (jax.nn.log_softmax(logits, axis=-1) if from_logits
-                    else jnp.log(jnp.clip(logits, 1e-10, 1.0)))
-            ll = jnp.take_along_axis(
-                logp, jnp.where(valid, lab, 0)[..., None], axis=-1)[..., 0]
-            out["sparse_cce_loss"] = -jnp.sum(
-                jnp.sum(jnp.where(valid, ll, 0.0), axis=-1))
+        if want_scce:
+            out["sparse_cce_loss"] = -jnp.sum(masked_row_sums(ll, valid))
         return out
-    if sparse and logits.ndim >= 3:
-        # token-level metrics (seq2seq/NMT): positions flatten into the
-        # batch, matching compute_loss's rank-3 path (runtime/loss.py)
-        logits = logits.reshape(-1, logits.shape[-1])
-        labels = labels.reshape(-1, 1)
-    out: Dict[str, jnp.ndarray] = {"count": jnp.asarray(logits.shape[0])}
+    # token-level metrics (seq2seq/NMT): positions count as the batch,
+    # matching compute_loss's rank-3 path (runtime/loss.py)
+    out: Dict[str, jnp.ndarray] = {
+        "count": jnp.asarray(lab.size if sparse else logits.shape[0])}
 
     def _logp():
         if from_logits:
@@ -161,16 +172,11 @@ def compute_batch_metrics(
 
     if MetricsType.ACCURACY in metrics:
         pred = jnp.argmax(logits, axis=-1)
-        if sparse:
-            true = labels.reshape(labels.shape[0], -1)[:, 0].astype(pred.dtype)
-        else:
-            true = jnp.argmax(labels, axis=-1)
+        true = lab.astype(pred.dtype) if sparse \
+            else jnp.argmax(labels, axis=-1)
         out["correct"] = jnp.sum(pred == true)
-    if MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY in metrics and sparse:
-        lab = labels.reshape(labels.shape[0], -1)[:, 0].astype(jnp.int32)
-        out["sparse_cce_loss"] = -jnp.sum(
-            jnp.take_along_axis(_logp(), lab[:, None], axis=-1)
-        )
+    if want_scce:
+        out["sparse_cce_loss"] = -jnp.sum(ll)
     if MetricsType.CATEGORICAL_CROSSENTROPY in metrics and not sparse:
         out["cce_loss"] = -jnp.sum(labels * _logp())
     if MetricsType.MEAN_SQUARED_ERROR in metrics:

@@ -334,15 +334,18 @@ def test_unknown_layer_type_is_refused():
 # them, print the new ones: ``python tests/test_hybrid_lm.py``.
 # ``hybrid.decode`` was recorded on 0f38e32, before the whole-sequence
 # kernel: what changes a prefill's recurrence must not reach the decode
-# program.
+# program. The two ``train`` programs were recorded again with the
+# one-pass sparse cross-entropy (``runtime/loss.py``
+# ``sparse_log_likelihood``), which is meant to move them and nothing
+# else: the five serving programs kept their digests through it.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
-    "gpt.train": "170e41925d7e8ba1d46f71f4f6274b2560128cbf591b30200fc1cea3b172dc38",
+    "gpt.train": "b910d8faaa4dc59157d5baffb29bd2f5ec466eb49c9b8bed2e2fb14878d1376f",
     "hybrid.decode": "4d24aefa5c08257e468bf6e0099721acafe48a58f593be9809c3c206eedcec7f",
     "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
     "latent_moe.prefill": "43e30c908af85f9f65457976171f1f762c7a454a0f807318b4c7e2a3e4a6dbd4",
-    "latent_moe.train": "71272d740bed1d84a16644e19412122af40d6a7251a94171bc5aab6e2c515ebe",
+    "latent_moe.train": "ec35820380d8c0377a8b07e79d3248e5c9af80e3156d4e7f49d29b7f78eb7b8f",
 }
 
 

@@ -9,6 +9,8 @@ may load the TPU's library, and pytest-xdist workers all import every
 test file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -240,8 +242,6 @@ def test_sparse_hybrid_programs_hold_no_square_and_no_vocabulary_rows(
     the 33,792 positions, or against themselves for all heads), none of
     (tokens, vocabulary) beyond a row a request, and little beside the
     weights and the pool."""
-    import re
-
     text, mem = sparse_hybrid_programs[name]
     shapes = set(re.findall(r"\[([0-9,]+)\]", text))
     for dims in shapes:
@@ -264,3 +264,77 @@ def test_sparse_hybrid_decode_loops_over_no_slots(sparse_hybrid_programs):
     assert " while(" not in sparse_hybrid_programs["decode"][0]
     for name in ("chunk", "chunk_head"):
         assert 1 <= sparse_hybrid_programs[name][0].count(" while(") <= 3
+
+
+def _buffers(text):
+    """The instructions of an optimised HLO module that own a buffer:
+    those of every computation that is no fusion's body and no reducer
+    (what is inside a fusion lives in registers and VMEM)."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        if re.match(r"^(ENTRY )?%?[\w.\-]+ \(.*\) -> .* \{$", ln):
+            cur = comps.setdefault(ln.split(" (", 1)[0].split("%")[-1], [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in ln:
+            cur.append(ln.strip())
+    inner = set()
+    for lines in comps.values():
+        for ln in lines:
+            inner.update(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", ln))
+    return [ln for name, lines in comps.items() if name not in inner
+            for ln in lines]
+
+
+def test_train_step_holds_no_float32_vocabulary_array(monkeypatch, one_chip,
+                                                      no_compile_cache):
+    """The fit cell's step program at two layers (hidden 1024, 4 x 1024
+    tokens, vocabulary 50,257, bfloat16, Adam, the fused attention
+    kernels): sparse cross-entropy reads the head's logits where the head
+    wrote them. The logits exist once, as bfloat16, and no float32 array
+    of (tokens, vocabulary) is a buffer of the program — the log_softmax
+    path held three (the float32 copy, the log-probabilities, and the
+    scattered cotangent's reduction read them back)."""
+    from flexflow_tpu import (AdamOptimizer, FFConfig, FFModel, LossType,
+                              MetricsType, make_mesh)
+    from flexflow_tpu.models.gpt import GPTConfig, build_gpt
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    batch, seq, vocab = 4, 1024, 50257
+    ff = FFModel(FFConfig(batch_size=batch, seed=0, search_cache="off",
+                          ledger="off", compute_dtype="bfloat16",
+                          only_data_parallel=True, search_budget=0))
+    build_gpt(ff, batch, seq, GPTConfig(vocab_size=vocab, max_positions=seq,
+                                        hidden_size=1024, num_heads=16,
+                                        num_layers=2))
+    ff.compile(optimizer=AdamOptimizer(alpha=2e-4),
+               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+               metrics=[MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY],
+               mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
+    spec = ff.compiled.audit_exec[0]
+    assert spec.name == "train_step"
+
+    def on_chip(a):  # the optimizer's hyperparameters are plain floats
+        return (jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                if hasattr(a, "shape") else a)
+
+    seq_length, *args = spec.args
+    args = jax.tree_util.tree_map(on_chip, args)
+    args[-1] = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                    sharding=one_chip)  # a label a position
+    text = spec.fn.lower(seq_length, *args).compile().as_text()
+    assert "flash_attention_fwd" in text
+    made = []  # (what an instruction's output is, the instruction)
+    for ln in _buffers(text):
+        # `%name = <type, or a tuple of them> opcode(operands), ...`
+        m = re.search(r"[})] ([a-z][\w\-]*)\(", ln)
+        if m.group(1) not in ("get-tuple-element", "bitcast", "parameter"):
+            made.append((ln[:m.start() + 1], ln))
+
+    def producers(shape):
+        return [ln for out, ln in made if shape in out]
+
+    assert not producers(f"f32[{batch},{seq},{vocab}]")
+    assert not producers(f"f32[{batch * seq},{vocab}]")
+    assert len(producers(f"bf16[{batch},{seq},{vocab}]")) == 1
+    assert not producers(f"bf16[{batch * seq},{vocab}]")
