@@ -1,0 +1,15 @@
+"""Exclusive device milliseconds per execution of the paged decode program
+(``jit__decode_step``)
+that lie under the ops of the group ``matmul`` (the dense layer, the shared
+and the routed experts, the head), from the owner table of the
+traced window (``benchmark/owners.py``: an operation's duration less
+what is nested inside it, by the scope in its ``op_name`` path). None
+where the profile holds no such scope. Layer: Expert layer."""
+
+from benchmark import owners
+
+PROGRAM = r"_decode_step"
+
+
+def read(run):
+    return owners.device_ms(run, PROGRAM, group="matmul")

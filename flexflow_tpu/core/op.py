@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Type
+from urllib.parse import quote, unquote
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,101 @@ class LowerCtx:
     # stop_gradient (their grads are zero anyway: training never reads
     # them). None = caller doesn't track state (eval / pipeline stages).
     state_updates: Optional[dict] = None
+
+
+# ---------------------------------------------------------------------------
+# scopes: whose work a lowered operation is. A scope is metadata on the HLO
+# (the ``op_name`` path a device trace carries), entered at trace time only.
+# ---------------------------------------------------------------------------
+SCOPE_PREFIX = "ff."
+# the fixed scopes of what a step does outside any graph op
+FIXED_SCOPES = ("loss", "metrics", "optimizer", "sample", "tail", "counters")
+# the pieces of work inside an op that different changes aim at, the same
+# word in every entry kind (serving/cache_entry.py)
+SUB_SCOPES = ("project", "write", "attend", "select", "conv", "rule",
+              "chunks")
+# the group a metric sums an op type under; a type not named is "other"
+OP_GROUPS: Dict[str, Tuple[OpType, ...]] = {
+    # the types a serving program gives a pair, latent or sparse entry kind
+    "attention": (OpType.MULTIHEAD_ATTENTION, OpType.LATENT_ATTENTION,
+                  OpType.BLOCK_SPARSE_ATTENTION),
+    # the types that keep a state a request
+    "state": (OpType.GATED_DELTA_NET, OpType.LIGHTNING_ATTENTION),
+    "matmul": (OpType.LINEAR, OpType.GATED_MLP, OpType.EXPERT_LINEAR,
+               OpType.ROUTED_EXPERTS),
+}
+_GROUP_OF = {t.name: g for g, types in OP_GROUPS.items() for t in types}
+
+
+def op_scope(op: "Op"):
+    """The scope an op lowers under: ``jax.named_scope`` of
+    ``ff.<OpType name>.<op name>``, e.g. ``ff.LINEAR.h3.mlp.fc``.
+
+    The grammar, which :func:`parse_scope` inverts from the string alone.
+    One component of an HLO ``op_name`` path (components are joined by
+    ``/`` and wrapped by JAX's transforms, ``transpose(jvp(...))``) is
+    this package's where it starts with ``ff.``. An OpType's name is
+    upper case with no dot, so the second dot ends it and what follows,
+    dots included, is the op's name percent-encoded (``urllib.parse.quote``
+    with nothing safe: a path splits on ``/``, transforms wrap in ``(``
+    and ``)``; letters, digits and ``_.-~`` stay as they are). A lower-case word and no second dot is one of
+    :data:`FIXED_SCOPES` (``ff.loss``). Of the components behind the scope,
+    those that are :data:`SUB_SCOPES` name the pieces of work inside the
+    op, in order (``attend``; ``conv``, ``project``; a piece inside a loop
+    stands behind JAX's ``while/body``): no primitive or transform of
+    JAX's has one of those words for its whole name. A scope inside a
+    scope (a fused op's members, ``ff.counters`` inside an expert layer,
+    a piece inside a piece) owns what is under it: the innermost
+    speaks."""
+    return jax.named_scope(
+        f"{SCOPE_PREFIX}{op.op_type.name}.{quote(op.name, safe='')}")
+
+
+def _word_scope(word: str, vocabulary: Tuple[str, ...], prefix: str = ""):
+    if word not in vocabulary:
+        raise ValueError(f"no scope {word!r} among {vocabulary}")
+    return jax.named_scope(prefix + word)
+
+
+def fixed_scope(what: str):
+    """``ff.<what>``, for what a step does outside any graph op: one of
+    :data:`FIXED_SCOPES`."""
+    return _word_scope(what, FIXED_SCOPES, SCOPE_PREFIX)
+
+
+def sub_scope(piece: str):
+    """A piece of work inside an op's scope, one of :data:`SUB_SCOPES`."""
+    return _word_scope(piece, SUB_SCOPES)
+
+
+def parse_scope(op_name_path: str):
+    """``(type, name, sub-scopes, phase)`` of an HLO ``op_name`` path, or
+    None where no component is this package's. ``type`` is the OpType's
+    name, or the word of a fixed scope with ``name`` ``""``; ``phase`` is
+    ``"bwd"`` where the path holds ``transpose(``, else ``"fwd"``."""
+    parts = op_name_path.split("/")
+    found = None
+    for i, part in enumerate(parts):
+        inner = part[part.rfind("(") + 1:].rstrip(")")
+        if inner.startswith(SCOPE_PREFIX):
+            found = (i, inner[len(SCOPE_PREFIX):])
+    if found is None:
+        return None
+    i, body = found
+    kind, dot, name = body.partition(".")
+    if dot and kind.isupper():
+        name = unquote(name)
+    else:
+        kind, name = body, ""
+    subs = tuple(part for part in parts[i + 1:] if part in SUB_SCOPES)
+    phase = "bwd" if "transpose(" in op_name_path else "fwd"
+    return kind, name, subs, phase
+
+
+def scope_group(kind: str) -> str:
+    """The group of :data:`OP_GROUPS` an OpType's name falls in, else
+    ``"other"`` (a new op type, until someone says otherwise)."""
+    return _GROUP_OF.get(kind, "other")
 
 
 class Op:

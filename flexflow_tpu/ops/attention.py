@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ffconst import DataType, OpType
-from ..core.op import Op, WeightSpec, register_op
+from ..core.op import Op, WeightSpec, register_op, sub_scope
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 from ..runtime.initializer import (ConstantInitializer,
                                    DefaultWeightInitializer, ZeroInitializer)
@@ -131,6 +131,7 @@ class MultiHeadAttention(Op):
     def scale(self) -> float:
         return 1.0 / math.sqrt(self.head_dim)
 
+    @sub_scope("project")
     def project_qkv(self, weights, q_in, k_in, v_in):
         """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries, keys and
         values, biases added."""
@@ -154,6 +155,7 @@ class MultiHeadAttention(Op):
         flat = x.reshape(x.shape[:2] + (-1,))
         return rms_norm(flat, gain.reshape(-1), self.norm_eps).reshape(x.shape)
 
+    @sub_scope("project")
     def project_out(self, weights, ctxv):
         """The attended (B, S, H, D) values -> (B, S, E)."""
         out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
@@ -188,16 +190,19 @@ class MultiHeadAttention(Op):
                                weights[w].reshape(x.shape[-1], h * d))
                 return y + weights[b].reshape(h * d) if self.use_bias else y
 
-            qp, kp = packed(q_in, "wq", "bq"), packed(k_in, "wk", "bk")
-            if self.qk_norm:
-                qp = self._normed(qp, weights["q_norm"])
-                kp = self._normed(kp, weights["k_norm"])
-            ctxv = fa.flash_attention_packed(
-                qp, kp, packed(v_in, "wv", "bv"), h, causal=self.causal,
-                scale=self.scale)
-            out = jnp.einsum("bqf,fe->bqe", ctxv,
-                             weights["wo"].reshape(h * d, self.embed_dim))
-            return out + weights["bo"] if self.use_bias else out
+            with sub_scope("project"):
+                qp, kp = packed(q_in, "wq", "bq"), packed(k_in, "wk", "bk")
+                if self.qk_norm:
+                    qp = self._normed(qp, weights["q_norm"])
+                    kp = self._normed(kp, weights["k_norm"])
+                vp = packed(v_in, "wv", "bv")
+            with sub_scope("attend"):
+                ctxv = fa.flash_attention_packed(
+                    qp, kp, vp, h, causal=self.causal, scale=self.scale)
+            with sub_scope("project"):
+                out = jnp.einsum("bqf,fe->bqe", ctxv,
+                                 weights["wo"].reshape(h * d, self.embed_dim))
+                return out + weights["bo"] if self.use_bias else out
         # multi-device: shard_map the kernels over the batch / heads mesh
         # axes (attention is independent across both), so dp x tp
         # configs run them too
@@ -211,9 +216,11 @@ class MultiHeadAttention(Op):
                                     heads_ax, self.causal, q_in.dtype):
             return None
         qh, kh, vh = self.project_qkv(weights, *inputs)
-        return self.project_out(weights, fa.sharded_flash_attention(
-            qh, kh, vh, mesh, batch_ax, heads_ax, causal=self.causal,
-            scale=self.scale))
+        with sub_scope("attend"):
+            ctxv = fa.sharded_flash_attention(
+                qh, kh, vh, mesh, batch_ax, heads_ax, causal=self.causal,
+                scale=self.scale)
+        return self.project_out(weights, ctxv)
 
     def forward(self, ctx, inputs, weights):
         drop = self.dropout if (ctx.training and ctx.rng is not None) else 0.0
@@ -228,11 +235,11 @@ class MultiHeadAttention(Op):
 
             sp = ulysses_attention if self.seq_mode == "a2a" else ring_attention
             path = "ulysses" if self.seq_mode == "a2a" else "ring"
-            out = self.project_out(weights, sp(
-                *self.project_qkv(weights, *inputs), ctx.mesh, self.seq_axis,
-                causal=self.causal, scale=self.scale,
-                dropout_rate=drop, rng=ctx.rng,
-            ))
+            qkv = self.project_qkv(weights, *inputs)
+            with sub_scope("attend"):
+                ctxv = sp(*qkv, ctx.mesh, self.seq_axis, causal=self.causal,
+                          scale=self.scale, dropout_rate=drop, rng=ctx.rng)
+            out = self.project_out(weights, ctxv)
         else:
             # attention dropout keeps the `xla` path: the kernels do not
             # implement it
@@ -240,9 +247,11 @@ class MultiHeadAttention(Op):
             path = "flash"
             if out is None:
                 path = "xla"
-                out = self.project_out(weights, single_device_attention(
-                    *self.project_qkv(weights, *inputs), self.causal,
-                    self.scale, drop, ctx.rng))
+                qkv = self.project_qkv(weights, *inputs)
+                with sub_scope("attend"):
+                    ctxv = single_device_attention(
+                        *qkv, self.causal, self.scale, drop, ctx.rng)
+                out = self.project_out(weights, ctxv)
         # which implementation this lowering took, counted once per trace:
         # the rule is over shapes, and a chip run has to be able to say
         # which one it timed
@@ -410,6 +419,7 @@ class LatentAttention(Op):
         ]
 
     # ---- the pieces serving composes ------------------------------------
+    @sub_scope("project")
     def queries_and_rows(self, weights, x, positions):
         """``x`` (B, S, E), ``positions`` (B, S) -> ``q_nope`` (B, S, H,
         nope), ``q_rope`` (B, S, H, rope) rotated, and the latent rows
@@ -435,6 +445,7 @@ class LatentAttention(Op):
         return weights["wkv_b"].reshape(self.kv_rank, self.num_heads,
                                         self.nope_dim + self.v_dim)
 
+    @sub_scope("attend")
     def attend_expanded(self, weights, q_nope, q_rope, rows, mask):
         """Attention in the expanded form over the rows given (queries
         (B, Sq, H, ·), rows (B, Sk, width), ``mask`` (Sq, Sk) or (B, Sq,

@@ -39,7 +39,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 
-from ..core.op import Op, WeightSpec, register_op
+from ..core.op import Op, WeightSpec, register_op, sub_scope
 from ..ffconst import OpType
 from ..runtime.initializer import ConstantInitializer, DefaultWeightInitializer
 from .attention import _mm
@@ -151,7 +151,7 @@ def select(qg, kernels, qpos, geom: Selection, scale: float, count: int):
     ids (B, Hkv, Sq, count) int32, best first. Below ``dense_len`` every
     block up to the query's own is kept, so ``count`` has to cover them
     there."""
-    with jax.named_scope("sparse_select"):
+    with sub_scope("select"):
         score = block_scores(kernel_scores(qg, kernels, qpos, geom, scale),
                              qpos, geom)
         return jax.lax.top_k(score, count)[1].astype(jnp.int32)
@@ -193,7 +193,7 @@ def attend_blocked(qg, read, picked, qpos, spans, geom: Selection,
             v, preferred_element_type=jnp.float32)
         return m_new, l * fade + p.sum(-1), acc * fade[..., None] + pv
 
-    with jax.named_scope("sparse_attend"):
+    with sub_scope("attend"):
         init = (jnp.full((b, hkv, g, sq), NEG, jnp.float32),
                 jnp.zeros((b, hkv, g, sq), jnp.float32),
                 jnp.zeros((b, hkv, g, sq, d), jnp.float32))
@@ -246,6 +246,7 @@ class BlockSparseAttention(Op):
         ]
 
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
+    @sub_scope("project")
     def project(self, weights, x):
         """(B, S, E) -> the queries grouped by key-value head (B, S, Hkv,
         G, D) and the keys and values (B, S, Hkv, D), q and k normed."""
@@ -258,6 +259,7 @@ class BlockSparseAttention(Op):
                      weights["k_norm"], self.eps)
         return q, k, _mm(x, weights["wv"]).reshape(b, s, self.kv_heads, d)
 
+    @sub_scope("project")
     def finish(self, weights, x, o):
         """The attended (B, S, H, D) values -> (B, S, E): the output gate,
         then ``W_o``."""
@@ -281,17 +283,18 @@ class BlockSparseAttention(Op):
                                     (0, 0), (0, 0)))
         count = g.widest_read(nb)
         ids = select(qg, kernels, qpos, g, self.scale, count)
-        # of what a query past dense_len picked, the first topk count
-        rank = jax.lax.iota(jnp.int32, count) < g.topk
-        counted = rank | (qpos < g.dense_len)[:, None, :, None]
-        see = picked_blocks(jnp.where(counted, ids, nb), nb)
-        see = jnp.repeat(see, g.block, axis=-1) & (
-            jax.lax.iota(jnp.int32, length) <= qpos[:, None, :, None])
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys,
-                       preferred_element_type=jnp.float32) * self.scale
-        p = jax.nn.softmax(jnp.where(see[:, :, None], s, NEG), axis=-1)
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(values.dtype), values,
-                       preferred_element_type=jnp.float32)
+        with sub_scope("attend"):
+            # of what a query past dense_len picked, the first topk count
+            rank = jax.lax.iota(jnp.int32, count) < g.topk
+            counted = rank | (qpos < g.dense_len)[:, None, :, None]
+            see = picked_blocks(jnp.where(counted, ids, nb), nb)
+            see = jnp.repeat(see, g.block, axis=-1) & (
+                jax.lax.iota(jnp.int32, length) <= qpos[:, None, :, None])
+            s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, keys,
+                           preferred_element_type=jnp.float32) * self.scale
+            p = jax.nn.softmax(jnp.where(see[:, :, None], s, NEG), axis=-1)
+            o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(values.dtype),
+                           values, preferred_element_type=jnp.float32)
         return (o.reshape(b, sq, self.num_heads, -1).astype(qg.dtype),
                 ids[..., :min(count, g.topk)])
 

@@ -23,7 +23,7 @@ from typing import Dict, List, Set
 
 from ..ffconst import OpType
 from ..core.layer import Layer
-from ..core.op import Op, create_op, register_op
+from ..core.op import Op, create_op, op_scope, register_op
 
 FUSIBLE = {
     OpType.RELU, OpType.IDENTITY, OpType.SIGMOID, OpType.TANH, OpType.ELU,
@@ -64,7 +64,8 @@ class FusedOp(Op):
             # mask (matches the per-op fold_in in the unfused graph)
             ctx.rng = (jax.random.fold_in(base_rng, i)
                        if base_rng is not None else None)
-            (x,) = op.forward(ctx, [x], {})
+            with op_scope(op):      # a member keeps its own name
+                (x,) = op.forward(ctx, [x], {})
         ctx.rng = base_rng
         return [x]
 

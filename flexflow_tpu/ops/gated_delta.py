@@ -50,7 +50,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 
-from ..core.op import Op, WeightSpec, register_op
+from ..core.op import Op, WeightSpec, register_op, sub_scope
 from ..ffconst import OpType
 from ..kernels import gated_delta as kernel
 from ..runtime.initializer import (ConstantInitializer,
@@ -236,12 +236,14 @@ class GatedDeltaNet(Op):
         ]
 
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
+    @sub_scope("project")
     def conv_inputs(self, weights, x):
         """(B, S, E) -> the convolution's inputs ``[q | k | v]`` (B, S,
         channels), in the activations' dtype: what the tail keeps."""
         return jnp.concatenate([_mm(x, weights[w])
                                 for w in ("wq", "wk", "wv")], axis=-1)
 
+    @sub_scope("conv")
     def convolve(self, weights, window):
         """``window`` (B, K - 1 + S, channels): each position's inputs
         behind the ``K - 1`` before it. The sum of K shifted products,
@@ -270,6 +272,7 @@ class GatedDeltaNet(Op):
         v = u[..., 2 * self.qk_width:].reshape(b, s, h, self.value_dim)
         return unit_heads(q, k, v)
 
+    @sub_scope("project")
     def gates(self, weights, x):
         """(B, S, E) -> ``g`` = log alpha and beta, (B, S, H) float32."""
         f32 = jnp.float32
@@ -280,6 +283,7 @@ class GatedDeltaNet(Op):
         beta = jax.nn.sigmoid(bl)
         return g, beta * 2.0 if self.neg_eigval else beta
 
+    @sub_scope("project")
     def finish(self, weights, x, o, normed=False):
         """The recurrence's (B, S, H, d_v) float32 outputs -> (B, S, E):
         RMSNorm over ``d_v`` (``normed``: made already, by
@@ -302,11 +306,12 @@ class GatedDeltaNet(Op):
         taps = self.conv_taps
         if lengths is None:
             lengths = jnp.full((b,), s, jnp.int32)
-        with jax.named_scope("gated_delta_prefill"):
+        with sub_scope("conv"):
             window = jnp.concatenate(
                 [tail.astype(x.dtype), self.conv_inputs(weights, x)], axis=1)
             q, k, v = self.split(self.convolve(weights, window))
-            g, beta = self.gates(weights, x)
+        g, beta = self.gates(weights, x)
+        with sub_scope("rule"):
             live = (jax.lax.iota(jnp.int32, s)[None, :]
                     < lengths[:, None])[..., None]
             # by the path the shapes choose; the kernel's gradients are
@@ -317,11 +322,12 @@ class GatedDeltaNet(Op):
             y, state = rule(self.eps, q, k, v, jnp.where(live, g, 0.0),
                             jnp.where(live, beta, 0.0), state,
                             weights["norm"])
+        with sub_scope("conv"):
             # window position p is block position p - (K - 1): the K - 1
             # inputs before position ``length`` start at ``length``
             tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
                 w, n, taps - 1, axis=0))(window, lengths)
-            return self.finish(weights, x, y, normed=True), state, tail
+        return self.finish(weights, x, y, normed=True), state, tail
 
     def whole(self, weights, x, lengths=None):
         """Whole sequences from an empty state: :meth:`run` behind zeros."""

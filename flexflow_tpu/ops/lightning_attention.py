@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.op import Op, WeightSpec, register_op
+from ..core.op import Op, WeightSpec, register_op, sub_scope
 from ..ffconst import OpType
 from ..runtime.initializer import ConstantInitializer, DefaultWeightInitializer
 from .attention import _mm, apply_rotary, rotary_inv_freq
@@ -163,6 +163,7 @@ class LightningAttention(Op):
                 + [WeightSpec("wo", (w, e), dt, init)])
 
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
+    @sub_scope("project")
     def heads(self, weights, x, positions):
         """(B, S, E) -> q (normed, rotated, scaled), k (normed, rotated)
         and v, (B, S, H, D) float32."""
@@ -179,6 +180,7 @@ class LightningAttention(Op):
         return (head("wq", "q_norm") * self.head_dim ** -0.5,
                 head("wk", "k_norm"), head("wv"))
 
+    @sub_scope("project")
     def finish(self, weights, x, o):
         """The recurrence's (B, S, H, D) float32 outputs -> (B, S, E)."""
         b, s = o.shape[:2]
@@ -192,8 +194,8 @@ class LightningAttention(Op):
         (None: all S): positions past a row's length leave its state as
         it was. Returns (y (B, S, E), state)."""
         b, s, _ = x.shape
-        with jax.named_scope("lightning_chunks"):
-            q, k, v = self.heads(weights, x, positions)
+        q, k, v = self.heads(weights, x, positions)
+        with sub_scope("chunks"):
             g = jnp.broadcast_to(-jnp.asarray(self.slopes), (b, s,
                                                              self.num_heads))
             if lengths is not None:
@@ -202,17 +204,17 @@ class LightningAttention(Op):
                 g = jnp.where(live, g, 0.0)
                 k = jnp.where(live[..., None], k, 0.0)
             o, state = chunked_decay_rule(q, k, v, g, state)
-            return self.finish(weights, x, o), state
+        return self.finish(weights, x, o), state
 
     def step(self, weights, x, positions, arena, rows):
         """One token a slot: ``x`` (N, 1, E), slot n's state row
         ``rows[n]`` of ``arena`` (R, H, D, D). Returns (y (N, 1, E), the
         new arena)."""
-        with jax.named_scope("lightning_step"):
-            q, k, v = self.heads(weights, x, positions)
+        q, k, v = self.heads(weights, x, positions)
+        with sub_scope("rule"):
             o, arena = decay_step_rows(arena, rows, q[:, 0], k[:, 0], v[:, 0],
                                        jnp.exp(-jnp.asarray(self.slopes)))
-            return self.finish(weights, x, o[:, None]), arena
+        return self.finish(weights, x, o[:, None]), arena
 
     def empty_state(self, batch: int):
         return jnp.zeros((batch, self.num_heads, self.head_dim,

@@ -67,7 +67,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.machine import DATA_AXIS, PIPE_AXIS, mesh_axis_sizes
-from ..core.op import LowerCtx
+from ..core.op import LowerCtx, op_scope
 from .schedule import (Action, PipelineSchedule, build_schedule,
                        check_schedule, schedule_summary)
 
@@ -375,11 +375,12 @@ class PipelinedModel:
                 ctx.rng = (jax.random.fold_in(rng, oi)
                            if rng is not None else None)
                 ins = [acts[t.tensor_id] for t in op.layer.inputs]
-                p = cast_op_params(cast, op, chunk_params.get(op.name, {}),
-                                   cdt)
-                outs = op.forward(ctx, ins, p)
-                for out, t in zip(outs, op.layer.outputs):
-                    acts[t.tensor_id] = cast(out)
+                with op_scope(op):
+                    p = cast_op_params(cast, op,
+                                       chunk_params.get(op.name, {}), cdt)
+                    outs = op.forward(ctx, ins, p)
+                    for out, t in zip(outs, op.layer.outputs):
+                        acts[t.tensor_id] = cast(out)
             out_acts = {k: v for k, v in acts.items() if k in needed}
             aux = ctx.aux_losses or []
             # aux as a summed scalar so the vjp cotangent is one scalar;

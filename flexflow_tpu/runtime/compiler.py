@@ -37,7 +37,7 @@ from ..ffconst import CompMode, DataType, LossType, MetricsType, OpType
 from ..config import FFConfig
 from ..core.layer import Layer
 from ..core.machine import DATA_AXIS, make_mesh, mesh_axis_sizes
-from ..core.op import LowerCtx, Op, create_op
+from ..core.op import LowerCtx, Op, create_op, fixed_scope, op_scope
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 from ..core.tensor import Tensor
 from .loss import compute_loss, sparse_ce_from_logits
@@ -367,16 +367,19 @@ def _forward_graph(
     for oi, op in enumerate(ops):
         ins = [acts[t.tensor_id] for t in op.layer.inputs]
         ctx.rng = jax.random.fold_in(rng, oi) if rng is not None else None
-        p = cast_op_params(cast, op, params.get(op.name, {}), compute_dtype)
-        outs = op.forward(ctx, ins, p)
-        for out, t, ps in zip(outs, op.layer.outputs, op.output_shapes):
-            out = cast(out)
-            if mesh is not None and (
-                any(d.is_partitioned for d in ps.dims)
-                or getattr(op, "force_constraint", False)
-            ):
-                out = jax.lax.with_sharding_constraint(out, _named_sharding(mesh, ps))
-            acts[t.tensor_id] = out
+        with op_scope(op):
+            p = cast_op_params(cast, op, params.get(op.name, {}),
+                               compute_dtype)
+            outs = op.forward(ctx, ins, p)
+            for out, t, ps in zip(outs, op.layer.outputs, op.output_shapes):
+                out = cast(out)
+                if mesh is not None and (
+                    any(d.is_partitioned for d in ps.dims)
+                    or getattr(op, "force_constraint", False)
+                ):
+                    out = jax.lax.with_sharding_constraint(
+                        out, _named_sharding(mesh, ps))
+                acts[t.tensor_id] = out
     return acts, ctx.aux_losses, ctx.state_updates or {}
 
 
@@ -545,24 +548,27 @@ def compile_model(
                 ops, mesh, params, dict(zip(input_ids, xs)), True, rng,
                 seq_length, cdt,
             )
-            loss, ll = _loss(acts, y)
-            for a in aux:
-                loss = loss + _f32(a)
-            # weight regularizers (keras frontend: kernel_regularizer attr;
-            # reference keras/regularizers.py) — differentiable penalties on
-            # the fp32 master weights
-            for op in ops:
-                reg = op.attrs.get("kernel_regularizer")
-                if reg is not None and hasattr(reg, "penalty") \
-                        and op.name in params and "kernel" in params[op.name]:
-                    loss = loss + reg.penalty(params[op.name]["kernel"])
+            with fixed_scope("loss"):
+                loss, ll = _loss(acts, y)
+                for a in aux:
+                    loss = loss + _f32(a)
+                # weight regularizers (keras frontend: kernel_regularizer
+                # attr; reference keras/regularizers.py) — differentiable
+                # penalties on the fp32 master weights
+                for op in ops:
+                    reg = op.attrs.get("kernel_regularizer")
+                    if reg is not None and hasattr(reg, "penalty") \
+                            and op.name in params \
+                            and "kernel" in params[op.name]:
+                        loss = loss + reg.penalty(params[op.name]["kernel"])
             return loss, (_f32(acts[logits_id]), ll, updates)
 
         vag = jax.value_and_grad(loss_fn, has_aux=True)
         if accum == 1:
             (loss, (logits, ll, updates)), grads = vag(params, xs, y, rng)
-            batch_metrics = compute_batch_metrics(
-                metrics, loss_type, logits, y, from_logits, mask_pad, ll)
+            with fixed_scope("metrics"):
+                batch_metrics = compute_batch_metrics(
+                    metrics, loss_type, logits, y, from_logits, mask_pad, ll)
         else:
             # gradient accumulation: split the batch into K microbatches,
             # run them through a lax.scan (ONE compiled body, K x less
@@ -581,9 +587,10 @@ def compile_model(
 
             def one(xs_i, y_i, rng_i):
                 (li, (lgi, lli, updi)), gi = vag(params, xs_i, y_i, rng_i)
-                bmi = compute_batch_metrics(
-                    metrics, loss_type, lgi, y_i, from_logits, mask_pad,
-                    lli)
+                with fixed_scope("metrics"):
+                    bmi = compute_batch_metrics(
+                        metrics, loss_type, lgi, y_i, from_logits, mask_pad,
+                        lli)
                 return li, gi, bmi, updi
 
             def micro(carry, mb):
@@ -611,17 +618,19 @@ def compile_model(
             grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
             updates = {k: v / accum for k, v in upd_sum.items()}
             loss = loss_sum / accum
-        new_params, new_opt_state = optimizer.update(
-            params, grads, opt_state, wd_mask, hyper)
-        if opt_state_shardings is not None:
-            # keep ZeRO state sharded across updates: GSPMD reduce-scatters
-            # the grad into the sharded moment update and all-gathers only
-            # the weight delta
-            td, shards = opt_state_shardings
-            ls = td.flatten_up_to(new_opt_state)
-            new_opt_state = td.unflatten([
-                jax.lax.with_sharding_constraint(l, s) if s is not None else l
-                for l, s in zip(ls, shards)])
+        with fixed_scope("optimizer"):
+            new_params, new_opt_state = optimizer.update(
+                params, grads, opt_state, wd_mask, hyper)
+            if opt_state_shardings is not None:
+                # keep ZeRO state sharded across updates: GSPMD
+                # reduce-scatters the grad into the sharded moment update
+                # and all-gathers only the weight delta
+                td, shards = opt_state_shardings
+                ls = td.flatten_up_to(new_opt_state)
+                new_opt_state = td.unflatten([
+                    jax.lax.with_sharding_constraint(l, s)
+                    if s is not None else l
+                    for l, s in zip(ls, shards)])
         # non-trainable state (BatchNorm running stats) written after the
         # optimizer update — reference: cuDNN BN forward-training updates
         # the running averages in the same pass (batch_norm.cu)

@@ -34,6 +34,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core.op import sub_scope
 from ..ffconst import OpType
 from ..kernels import gated_delta, latent_attention, paged_attention
 from ..ops import block_sparse_attention as bsa
@@ -218,6 +219,7 @@ class PairEntry(EntryKind):
             (num_blocks, block_size, self.heads * self.head_dim), dtype)
         return (a, a)
 
+    @sub_scope("write")
     def write(self, entry, flat, kh, vh):
         """T new (T, H, D) keys and values at flat token slots (T,)."""
         t = kh.shape[0]
@@ -256,24 +258,27 @@ class PairEntry(EntryKind):
         entry = self.write(entry, flat.reshape(-1),
                            kh.reshape(n * w, heads, hdim),
                            vh.reshape(n * w, heads, hdim))
-        if self.reads_in_place(op, entry, n, w, mb):
-            # the kernel walks each slot's live blocks in the arena itself
-            ctxv = paged_attention.paged_attention_decode(
-                qh, entry[0], entry[1], tables, seq_lens,
-                scale=op.scale).astype(qh.dtype)
-        else:
-            k, v = self.read(entry, tables)                 # (n, L, H, D)
-            scores = _scores(qh, k, op.scale)
-            mask = _iota(k.shape[1])[None, None, :] <= pos[:, :, None]
-            ctxv = _weigh(scores, mask[:, None, :, :], v)
+        with sub_scope("attend"):
+            if self.reads_in_place(op, entry, n, w, mb):
+                # the kernel walks each slot's live blocks in the arena
+                # itself
+                ctxv = paged_attention.paged_attention_decode(
+                    qh, entry[0], entry[1], tables, seq_lens,
+                    scale=op.scale).astype(qh.dtype)
+            else:
+                k, v = self.read(entry, tables)             # (n, L, H, D)
+                scores = _scores(qh, k, op.scale)
+                mask = _iota(k.shape[1])[None, None, :] <= pos[:, :, None]
+                ctxv = _weigh(scores, mask[:, None, :, :], v)
         return op.project_out(weights, ctxv), entry
 
     def whole(self, op, weights, x, positions):
         qh, kh, vh = op.project_qkv(weights, x, x, x)
-        scores = _scores(qh, kh, op.scale)
-        pos = _iota(x.shape[1])
-        mask = pos[None, :] <= pos[:, None]
-        ctxv = _weigh(scores, mask[None, None, :, :], vh)
+        with sub_scope("attend"):
+            scores = _scores(qh, kh, op.scale)
+            pos = _iota(x.shape[1])
+            mask = pos[None, :] <= pos[:, None]
+            ctxv = _weigh(scores, mask[None, None, :, :], vh)
         return op.project_out(weights, ctxv), (kh, vh), pos
 
     def dense_shapes(self, batch, max_length, dtype):
@@ -284,14 +289,18 @@ class PairEntry(EntryKind):
     def dense_step(self, op, weights, x, positions, cache, offset):
         qh, kh, vh = op.project_qkv(weights, x, x, x)
         kcache, vcache = cache
-        # dynamic_update_slice keeps the shape static; unwritten and
-        # future positions are masked by position comparison
-        kcache = jax.lax.dynamic_update_slice(kcache, kh, (0, offset, 0, 0))
-        vcache = jax.lax.dynamic_update_slice(vcache, vh, (0, offset, 0, 0))
-        scores = _scores(qh, kcache, op.scale)
-        qpos = offset + _iota(x.shape[1])
-        mask = _iota(kcache.shape[1])[None, :] <= qpos[:, None]
-        ctxv = _weigh(scores, mask[None, None, :, :], vcache)
+        with sub_scope("write"):
+            # dynamic_update_slice keeps the shape static; unwritten and
+            # future positions are masked by position comparison
+            kcache = jax.lax.dynamic_update_slice(kcache, kh,
+                                                  (0, offset, 0, 0))
+            vcache = jax.lax.dynamic_update_slice(vcache, vh,
+                                                  (0, offset, 0, 0))
+        with sub_scope("attend"):
+            scores = _scores(qh, kcache, op.scale)
+            qpos = offset + _iota(x.shape[1])
+            mask = _iota(kcache.shape[1])[None, :] <= qpos[:, None]
+            ctxv = _weigh(scores, mask[None, None, :, :], vcache)
         return op.project_out(weights, ctxv), (kcache, vcache)
 
 
@@ -313,6 +322,7 @@ class Int8PairEntry(PairEntry):
                                  jnp.float32)
         return (a, a, s, s, s, s)
 
+    @sub_scope("write")
     def write(self, entry, flat, kh, vh):
         t = kh.shape[0]
         kq, vq, ks, kz, vs, vz = entry
@@ -361,6 +371,7 @@ class LatentEntry(EntryKind):
         return {"entry": self.name, "row_width": self.row_width,
                 "row_lanes": latent_row_lanes(self.row_width)}
 
+    @sub_scope("write")
     def write(self, entry, flat, rows):
         """(T, width) rows, padded with zeros to the arena's lanes."""
         lanes = entry[0].shape[-1]
@@ -390,34 +401,38 @@ class LatentEntry(EntryKind):
                          NULL_BLOCK * bs)
         entry = self.write(entry, flat, rows[:, 0])
         arena = entry[0]
-        wkvb = op.kvb_heads(weights)                  # (rank, H, nope + v)
-        q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0],
-                           wkvb[..., :op.nope_dim],
-                           preferred_element_type=jnp.float32)
-        q_full = jnp.concatenate(
-            [q_lat.astype(arena.dtype), q_rope[:, 0].astype(arena.dtype),
-             jnp.zeros((n, op.num_heads, lanes - op.row_width), arena.dtype)],
-            axis=-1)                                  # (n, H, lanes)
-        if self.reads_in_place(op, entry, n, w, mb):
-            with jax.named_scope("latent_attention_decode"):
+        with sub_scope("project"):
+            wkvb = op.kvb_heads(weights)              # (rank, H, nope + v)
+            q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0],
+                               wkvb[..., :op.nope_dim],
+                               preferred_element_type=jnp.float32)
+            q_full = jnp.concatenate(
+                [q_lat.astype(arena.dtype), q_rope[:, 0].astype(arena.dtype),
+                 jnp.zeros((n, op.num_heads, lanes - op.row_width),
+                           arena.dtype)], axis=-1)    # (n, H, lanes)
+        with sub_scope("attend"):
+            if self.reads_in_place(op, entry, n, w, mb):
                 ctxv = latent_attention.latent_attention_decode(
                     q_full, arena, tables, seq_lens, scale=op.scale,
                     out_width=op.kv_rank)
-        else:
-            view = arena[tables].reshape(n, mb * bs, lanes)  # (n, L, lanes)
-            scores = jnp.einsum("nhr,nlr->nhl", q_full, view,
-                                preferred_element_type=jnp.float32) * op.scale
-            mask = _iota(mb * bs)[None, :] <= seq_lens[:, None]
-            probs = jax.nn.softmax(
-                jnp.where(mask[:, None, :], scores, -1e30), axis=-1)
-            ctxv = jnp.einsum("nhl,nlc->nhc", probs.astype(arena.dtype),
-                              view[..., :op.kv_rank],
-                              preferred_element_type=jnp.float32)
-        o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
-                       wkvb[..., op.nope_dim:],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-        out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim), weights["wo"],
-                      preferred_element_type=jnp.float32).astype(x.dtype)
+            else:
+                view = arena[tables].reshape(n, mb * bs, lanes)  # (n, L, ·)
+                scores = jnp.einsum(
+                    "nhr,nlr->nhl", q_full, view,
+                    preferred_element_type=jnp.float32) * op.scale
+                mask = _iota(mb * bs)[None, :] <= seq_lens[:, None]
+                probs = jax.nn.softmax(
+                    jnp.where(mask[:, None, :], scores, -1e30), axis=-1)
+                ctxv = jnp.einsum("nhl,nlc->nhc", probs.astype(arena.dtype),
+                                  view[..., :op.kv_rank],
+                                  preferred_element_type=jnp.float32)
+        with sub_scope("project"):
+            o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
+                           wkvb[..., op.nope_dim:],
+                           preferred_element_type=jnp.float32).astype(x.dtype)
+            out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim),
+                          weights["wo"],
+                          preferred_element_type=jnp.float32).astype(x.dtype)
         return out, entry
 
     def whole(self, op, weights, x, positions):
@@ -425,9 +440,8 @@ class LatentEntry(EntryKind):
         sequences' own rows."""
         q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
         pos = _iota(x.shape[1])
-        with jax.named_scope("latent_attention_prefill"):
-            out = op.attend_expanded(weights, q_nope, q_rope, rows,
-                                     pos[None, :] <= pos[:, None])
+        out = op.attend_expanded(weights, q_nope, q_rope, rows,
+                                 pos[None, :] <= pos[:, None])
         return out, (rows,), pos
 
     def dense_shapes(self, batch, max_length, dtype):
@@ -436,8 +450,9 @@ class LatentEntry(EntryKind):
 
     def dense_step(self, op, weights, x, positions, cache, offset):
         q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
-        rows_cache = jax.lax.dynamic_update_slice(
-            cache[0], rows.astype(cache[0].dtype), (0, offset, 0))
+        with sub_scope("write"):
+            rows_cache = jax.lax.dynamic_update_slice(
+                cache[0], rows.astype(cache[0].dtype), (0, offset, 0))
         qpos = offset + _iota(x.shape[1])
         kpos = _iota(rows_cache.shape[1])
         out = op.attend_expanded(weights, q_nope, q_rope,
@@ -489,17 +504,20 @@ class StateEntry(EntryKind):
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         n = x.shape[0]                   # one token a slot: ``max_window``
         state, tails = entry
-        window = jnp.concatenate(
-            [tails[addr.rows].reshape(n, self.tail, self.channels),
-             op.conv_inputs(weights, x).astype(tails.dtype)], axis=1)
-        q, k, v = op.heads(op.convolve(weights, window))
+        with sub_scope("conv"):
+            window = jnp.concatenate(
+                [tails[addr.rows].reshape(n, self.tail, self.channels),
+                 op.conv_inputs(weights, x).astype(tails.dtype)], axis=1)
+            q, k, v = op.heads(op.convolve(weights, window))
         g, beta = op.gates(weights, x)
-        tails = tails.at[addr.rows].set(window[:, 1:].reshape(n, -1))
-        update = (gated_delta.gated_delta_decode
-                  if self.reads_in_place(op, entry, n, 1, 0)
-                  else gated_delta.gated_delta_step)
-        o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
-                          jnp.exp(g[:, 0]), beta[:, 0])
+        with sub_scope("write"):
+            tails = tails.at[addr.rows].set(window[:, 1:].reshape(n, -1))
+        with sub_scope("rule"):
+            update = (gated_delta.gated_delta_decode
+                      if self.reads_in_place(op, entry, n, 1, 0)
+                      else gated_delta.gated_delta_step)
+            o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
+                              jnp.exp(g[:, 0]), beta[:, 0])
         return op.finish(weights, x, o[:, None]), (state, tails)
 
     def prefill_path(self, bucket):
@@ -515,6 +533,7 @@ class StateEntry(EntryKind):
         out, state, tail = op.whole(weights, x, lengths)
         return out, self._put(entry, addr.rows, state, tail)
 
+    @sub_scope("write")
     def _put(self, entry, rows, state, tail):
         n = state.shape[0]
         lanes = jnp.moveaxis(state, 1, 2).reshape(n, self.key_dim, -1)
@@ -600,6 +619,7 @@ class SparseEntry(EntryKind):
         ok = live & (idx >= 0) & (idx < span * per)
         return jnp.where(ok, blk, NULL_BLOCK), idx % per
 
+    @sub_scope("write")
     def _write(self, entry, tables, pos, live, k, v):
         """``k``, ``v`` (N, W, Hkv, D) at positions ``pos`` (N, W)."""
         keys, values, kernels = entry
@@ -614,6 +634,7 @@ class SparseEntry(EntryKind):
         blk, off = self._where(tables, pos, True, arena.shape[2])
         return arena[blk[..., None], _iota(self.kv_heads), off[..., None]]
 
+    @sub_scope("write")
     def _write_kernels(self, entry, tables, first, pooled, keep):
         """``pooled`` (N, J, Hkv, D): kernels ``first + j`` of each
         request, written where ``keep`` (N, J)."""
@@ -650,7 +671,7 @@ class SparseEntry(EntryKind):
             entry, tables, begun // g.stride, pooled,
             (active & (begun >= 0) & (begun % g.stride == 0))[:, None])
         keys, values, _ = entry
-        with jax.named_scope("sparse_select"):
+        with sub_scope("select"):
             score = bsa.block_scores(bsa.kernel_scores(
                 qg, self._kernels(entry, tables), pos, g, op.scale),
                 pos, g)[:, :, 0]                      # (n, Hkv, mb)
@@ -677,7 +698,7 @@ class SparseEntry(EntryKind):
         # a slot below dense_len reads every block it has, up to dense_len
         # / block of them; a step none of whose slots is gathers topk
         wide = g.widest_read(mb)
-        with jax.named_scope("sparse_attend"):
+        with sub_scope("attend"):
             if wide == narrow:
                 o, ids = attend(narrow)
             else:
@@ -802,7 +823,8 @@ class DecayStateEntry(EntryKind):
         state = jnp.where((offsets > 0)[:, None, None, None],
                           entry[0][addr.rows], 0.0)
         out, state = op.run(weights, x, positions, state, lengths)
-        return out, (entry[0].at[addr.rows].set(state),)
+        with sub_scope("write"):
+            return out, (entry[0].at[addr.rows].set(state),)
 
     def whole(self, op, weights, x, positions):
         return op.run(weights, x, positions, op.empty_state(x.shape[0]))

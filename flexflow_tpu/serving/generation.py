@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ffconst import OpType
-from ..core.op import LowerCtx
+from ..core.op import LowerCtx, fixed_scope, op_scope
 from ..obs.trace import span
 from .cache_entry import kind_for
 from .kv_cache import NULL_BLOCK, Addresses, PagedKVPool
@@ -297,20 +297,23 @@ class _DecodeGraph:
         for op in self._cm.ops:
             ins = [acts[t.tensor_id] for t in op.layer.inputs]
             p = params.get(op.name, {})
-            if op.name in self._kinds:
-                outs = [attn(op, p, ins[0], positions)]
-            elif op.op_type is OpType.ROUTED_EXPERTS and experts is not None:
-                outs = [experts(op, p, ins[0])]
-            else:
-                outs = op.forward(ctx, ins, p)
+            with op_scope(op):
+                if op.name in self._kinds:
+                    outs = [attn(op, p, ins[0], positions)]
+                elif (op.op_type is OpType.ROUTED_EXPERTS
+                      and experts is not None):
+                    outs = [experts(op, p, ins[0])]
+                else:
+                    outs = op.forward(ctx, ins, p)
             for out, t in zip(outs, op.layer.outputs):
                 acts[t.tensor_id] = out
             if tail is not None and op is self._attn_ops[-1]:
                 if isinstance(tail, str):
                     return None
-                acts = {tid: jnp.take_along_axis(
-                    a, tail.reshape((-1, 1) + (1,) * (a.ndim - 2)), axis=1)
-                    for tid, a in acts.items()}
+                with fixed_scope("tail"):
+                    acts = {tid: jnp.take_along_axis(
+                        a, tail.reshape((-1, 1) + (1,) * (a.ndim - 2)),
+                        axis=1) for tid, a in acts.items()}
         logits = acts[self._cm.logits_tensor.tensor_id]
         return logits.astype(jnp.float32)
 
@@ -624,7 +627,8 @@ class PagedDecoder(_DecodeGraph):
         ((slots, vocab) float32 logits, new pool, the expert ids
         chosen, new counters, (slots,) int32 ids: each row's first
         maximum, what ``np.argmax`` of the fetched row gives)."""
-        tokens = jnp.where(take_prev, prev_ids, tokens)[:, None]
+        with fixed_scope("sample"):    # the greedy hand-over, both ends
+            tokens = jnp.where(take_prev, prev_ids, tokens)[:, None]
         positions = seq_lens[:, None]                           # (slots, 1)
         acts = self._inputs(tokens, positions)
         new_pool = dict(pool)
@@ -643,12 +647,14 @@ class PagedDecoder(_DecodeGraph):
             x2d = x.reshape(-1, x.shape[-1])
             ids, gates = op.route(p, x2d)
             routed[op.name] = ids
-            new_acc[op.name] = _count_up(
-                new_acc[op.name], _expert_counts(op, ids, active))
+            with fixed_scope("counters"):
+                new_acc[op.name] = _count_up(
+                    new_acc[op.name], _expert_counts(op, ids, active))
             return op.apply(p, x2d, ids, gates).reshape(x.shape)
 
         logits = self._forward_block(params, acts, attn, experts)[:, -1, :]
-        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with fixed_scope("sample"):
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits, new_pool, routed, new_acc, ids
 
     def _verify_step(self, params, tokens, pool, addr, seq_lens):
@@ -710,7 +716,8 @@ class PagedDecoder(_DecodeGraph):
 
         logits = self._forward_block(params, acts, attn,
                                      self._routing_kept(routed))
-        last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
+        with fixed_scope("tail"):
+            last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
         return last, new_pool, routed
 
     def _chunk_step(self, params, tokens, pool, addr, offsets, lengths, *,

@@ -286,43 +286,61 @@ def _buffers(text):
             for ln in lines]
 
 
-def test_train_step_holds_no_float32_vocabulary_array(monkeypatch, one_chip,
-                                                      no_compile_cache):
-    """The fit cell's step program at two layers (hidden 1024, 4 x 1024
-    tokens, vocabulary 50,257, bfloat16, Adam, the fused attention
-    kernels): sparse cross-entropy reads the head's logits where the head
-    wrote them. The logits exist once, as bfloat16, and no float32 array
-    of (tokens, vocabulary) is a buffer of the program — the log_softmax
-    path held three (the float32 copy, the log-probabilities, and the
-    scattered cotangent's reduction read them back)."""
+@pytest.fixture(scope="module")
+def train_step_text(one_chip):
+    """The optimised text of the fit cell's step program at two layers
+    (hidden 1024, 4 x 1024 tokens, vocabulary 50,257, bfloat16, Adam, the
+    fused attention kernels), compiled for the described chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     from flexflow_tpu import (AdamOptimizer, FFConfig, FFModel, LossType,
                               MetricsType, make_mesh)
     from flexflow_tpu.models.gpt import GPTConfig, build_gpt
 
-    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
     batch, seq, vocab = 4, 1024, 50257
-    ff = FFModel(FFConfig(batch_size=batch, seed=0, search_cache="off",
-                          ledger="off", compute_dtype="bfloat16",
-                          only_data_parallel=True, search_budget=0))
-    build_gpt(ff, batch, seq, GPTConfig(vocab_size=vocab, max_positions=seq,
-                                        hidden_size=1024, num_heads=16,
-                                        num_layers=2))
-    ff.compile(optimizer=AdamOptimizer(alpha=2e-4),
-               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-               metrics=[MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY],
-               mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
-    spec = ff.compiled.audit_exec[0]
-    assert spec.name == "train_step"
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=batch, seed=0,
+                                  search_cache="off", ledger="off",
+                                  compute_dtype="bfloat16",
+                                  only_data_parallel=True, search_budget=0))
+            build_gpt(ff, batch, seq, GPTConfig(
+                vocab_size=vocab, max_positions=seq, hidden_size=1024,
+                num_heads=16, num_layers=2))
+            ff.compile(optimizer=AdamOptimizer(alpha=2e-4),
+                       loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+                       metrics=[MetricsType.SPARSE_CATEGORICAL_CROSSENTROPY],
+                       mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]))
+            spec = ff.compiled.audit_exec[0]
+            assert spec.name == "train_step"
 
-    def on_chip(a):  # the optimizer's hyperparameters are plain floats
-        return (jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-                if hasattr(a, "shape") else a)
+            def on_chip(a):  # the optimizer's hyperparameters are plain floats
+                return (jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+                        if hasattr(a, "shape") else a)
 
-    seq_length, *args = spec.args
-    args = jax.tree_util.tree_map(on_chip, args)
-    args[-1] = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
-                                    sharding=one_chip)  # a label a position
-    text = spec.fn.lower(seq_length, *args).compile().as_text()
+            seq_length, *args = spec.args
+            args = jax.tree_util.tree_map(on_chip, args)
+            args[-1] = jax.ShapeDtypeStruct(
+                (batch, seq), jnp.int32, sharding=one_chip)  # a label a position
+            return ff, spec.fn.lower(seq_length, *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_train_step_holds_no_float32_vocabulary_array(train_step_text):
+    """Sparse cross-entropy reads the head's logits where the head wrote
+    them. The logits exist once, as bfloat16, and no float32 array
+    of (tokens, vocabulary) is a buffer of the program — the log_softmax
+    path held three (the float32 copy, the log-probabilities, and the
+    scattered cotangent's reduction read them back)."""
+    batch, seq, vocab = 4, 1024, 50257
+    _, text = train_step_text
     assert "flash_attention_fwd" in text
     made = []  # (what an instruction's output is, the instruction)
     for ln in _buffers(text):
@@ -338,3 +356,127 @@ def test_train_step_holds_no_float32_vocabulary_array(monkeypatch, one_chip,
     assert not producers(f"f32[{batch * seq},{vocab}]")
     assert len(producers(f"bf16[{batch},{seq},{vocab}]")) == 1
     assert not producers(f"bf16[{batch * seq},{vocab}]")
+
+
+# ---- the scopes survive the compiler -------------------------------------------
+
+def _entry_op_names(text):
+    """``{instruction: its op_name}`` over the computations that are not
+    a fusion's own (what a device trace has an event for)."""
+    out = {}
+    for ln in _buffers(text):
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", ln)
+        name = re.search(r'op_name="([^"]*)"', ln)
+        if m:
+            out[m.group(1)] = name.group(1) if name else ""
+    return out
+
+
+def test_train_step_fusions_keep_their_scopes(train_step_text):
+    """What XLA fuses keeps an owner: the optimised step's fusions and
+    kernels carry ``ff.`` scopes in their ``op_name``, every op of the
+    graph that has weights owns some instruction forward and backward,
+    and the loss and the update are there under their fixed scopes."""
+    from flexflow_tpu.core.op import parse_scope, scope_group
+
+    ff, text = train_step_text
+    names = _entry_op_names(text)
+    fusions = {k: v for k, v in names.items() if "fusion" in k}
+    owned = {k: parse_scope(v) for k, v in fusions.items()}
+    assert len(fusions) > 40
+    assert sum(o is not None for o in owned.values()) > 0.9 * len(fusions)
+    owners = {parse_scope(v) for v in names.values()} - {None}
+    for op in ff.compiled.ops:
+        if op.weight_specs():
+            phases = {ph for k, n, _, ph in owners if n == op.name}
+            assert phases == {"fwd", "bwd"}, (op, phases)
+    kinds = {k for k, n, _, _ in owners if n == ""}
+    assert {"loss", "optimizer"} <= kinds
+    kernels = {k: parse_scope(v) for k, v in names.items()
+               if "flash_attention" in k}
+    assert kernels and all(
+        o and scope_group(o[0]) == "attention" and o[2] == ("attend",)
+        for o in kernels.values()), kernels
+
+
+def test_hybrid_decode_step_while_is_a_state_ops_write(monkeypatch, one_chip,
+                                                       no_compile_cache):
+    """One linear layer of the hybrid configuration at its published
+    widths and its cell's 32 slots: the decode step's one ``while`` (the
+    scatter of the convolution tails, rows of 34,560 numbers) carries the
+    state op's ``write`` in its ``op_name``, and the state kernel its
+    ``rule``: what a device trace reads them by."""
+    import json
+    import os
+
+    from benchmark.families import olmo_hybrid as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.op import parse_scope
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-pp2.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=1,
+                  layer_types=config["layer_types"][:1])
+    slots, max_length = 32, 2048
+    ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                          ledger="off", search_cache="off",
+                          computation_mode=CompMode.INFERENCE))
+    family.build(ff, config, slots, max_length)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    dec = PagedDecoder(ff, max_length, decode_slots=slots, block_size=16,
+                       kv_dtype="bfloat16", calibrate=False)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def ints(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = dec._decode.lower(
+        jax.tree_util.tree_map(on_chip, dec._params_sds()), ints(slots),
+        jax.tree_util.tree_map(on_chip, dec.pool.kv),
+        Addresses(ints(slots, dec.max_blocks_per_request), ints(slots)),
+        ints(slots), {}, ints(slots),
+        ints(slots, dtype=jnp.bool_)).compile().as_text()
+    names = _entry_op_names(text)
+    (mixer,) = [op.name for op in dec._attn_ops]
+    whiles = [parse_scope(v) for k, v in names.items()
+              if k.startswith("while")]
+    assert whiles == [("GATED_DELTA_NET", mixer, ("write",), "fwd")]
+    kernels = [parse_scope(v) for k, v in names.items()
+               if k.startswith("gated_delta_decode")]
+    assert kernels == [("GATED_DELTA_NET", mixer, ("rule",), "fwd")]
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head", "decode"])
+def test_sparse_hybrid_programs_name_their_pieces(sparse_hybrid_programs,
+                                                  name):
+    """At the published widths the compiled programs keep the pieces a
+    metric sums: a chunk's ``sort`` lies under a sparse op's ``select``,
+    its loops under ``attend`` (the walk over the key spans), ``select``
+    (a few queries at a time) or a linear op's ``chunks``."""
+    from flexflow_tpu.core.op import parse_scope, scope_group
+
+    names = _entry_op_names(sparse_hybrid_programs[name][0])
+    owners = {k: parse_scope(v) for k, v in names.items()}
+    fusions = [o for k, o in owners.items() if "fusion" in k]
+    assert sum(o is not None for o in fusions) > 0.9 * len(fusions)
+    if name == "decode":
+        return
+    sorts = [o for k, o in owners.items() if k.startswith("sort")]
+    assert sorts and all(
+        o and o[0] == "BLOCK_SPARSE_ATTENTION" and o[2][-1] == "select"
+        for o in sorts), sorts
+    loops = {(scope_group(o[0]), o[2][-1]) for k, o in owners.items()
+             if k.startswith("while") and o and o[2]}
+    assert loops <= {("attention", "attend"), ("attention", "select"),
+                     ("state", "chunks")}
+    # the two layers end in the sparse one: a chunk that computes no head
+    # has no use for what it attends, and the compiler drops the walk
+    assert (("attention", "attend") in loops) == (name == "chunk_head")
