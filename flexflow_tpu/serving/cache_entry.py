@@ -71,6 +71,25 @@ def _put(arena, flat, rows):
         arena.shape)
 
 
+def _spread_rows(arena, rows, new):
+    """``new`` (N, width) written over the rows ``rows`` (N,) of a
+    per-request arena (R, width) where the arena lies: each row finds
+    the slot that names it through a one-hot mask of (slots, rows) and
+    takes that slot's values, and the donated arena keeps the rest, in
+    one elementwise pass. No scatter: N rows this wide scattered lower
+    to a sequential loop over the slots on the TPU, thirty times what
+    the arena's bytes need; a take of R rows does not. A live row is
+    named by at most one slot and its values are moved, not computed, so
+    the stepped rows are the scatter's bit for bit in any dtype, and a
+    slot's NaN stays in its own row (a one-hot PRODUCT over the slots is
+    a tenth of a millisecond a step faster at the hybrid cell's shapes
+    and gives up both: ``PERF.md``, PR 39). Row 0 is nobody's: a slot
+    that names it writes nothing."""
+    hot = (rows[:, None] == _iota(arena.shape[0])) & (rows[:, None] != 0)
+    return jnp.where(hot.any(0)[:, None],
+                     new.astype(arena.dtype)[jnp.argmax(hot, axis=0)], arena)
+
+
 def _prefill_slots(tables, lengths, pos, bs):
     """Where a group of prompts' rows go: row i's position p lands in
     block ``tables[i, p // bs]`` at offset ``p % bs``; padding positions
@@ -470,7 +489,14 @@ class StateEntry(EntryKind):
     whatever ``kv_dtype`` says (rounded each step it would drift for a
     request's whole life; ``stats()`` says so); the convolution's tail is
     stored as the per-token kinds' rows are. A state cannot be rolled
-    back, so a step takes one token a slot."""
+    back, so a step takes one token a slot. A step puts the slots' new
+    tails back through :func:`_spread_rows` (each arena row takes the
+    values of the slot that names it, one pass over the arena where it
+    lies) and not by a scatter: at the published widths a row is 34,560
+    numbers, and the TPU's compiler runs a scatter of 32 such rows as a
+    sequential loop of dynamic-update-slices, 2.5 ms of a 19 ms step
+    (the state itself the kernel steps in place; a prefill's one to a
+    few rows keep ``_put``'s scatter)."""
 
     heads: int
     key_dim: int
@@ -511,7 +537,8 @@ class StateEntry(EntryKind):
             q, k, v = op.heads(op.convolve(weights, window))
         g, beta = op.gates(weights, x)
         with sub_scope("write"):
-            tails = tails.at[addr.rows].set(window[:, 1:].reshape(n, -1))
+            tails = _spread_rows(tails, addr.rows,
+                                 window[:, 1:].reshape(n, -1))
         with sub_scope("rule"):
             update = (gated_delta.gated_delta_decode
                       if self.reads_in_place(op, entry, n, 1, 0)

@@ -199,6 +199,68 @@ def test_a_state_kind_answers_for_its_rows_bytes_names_and_limits(kv_dtype):
                     max_blocks_per_request=2, num_rows=1)
 
 
+_ROWS = {   # slot n writes arena row rows[n]; 0 is the null row (an idle slot)
+    "all_live_in_order": [1, 2, 3, 4, 5, 6],
+    "all_live_any_order": [5, 2, 6, 1, 4, 3],
+    "several_idle": [4, 0, 2, 0, 0, 6],
+    "one_live": [0, 0, 0, 3, 0, 0],
+    "none_live": [0, 0, 0, 0, 0, 0],
+}
+
+
+def _bits(a):
+    return np.array(jnp.asarray(a).astype(jnp.float32)).view(np.uint32)
+
+
+@pytest.mark.parametrize("case", list(_ROWS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_rows_spread_by_one_hot_equal_the_scatter_bit_for_bit(dtype, case):
+    """What a state kind's step writes its convolution tails with: each
+    arena row takes the values of the slot that names it, against
+    ``arena.at[rows].set(new)``: the stepped rows BIT for bit in both
+    storage dtypes (values of every magnitude a float holds, subnormals,
+    negative zeros and a slot of NaN among them: nothing is computed, so
+    a slot's NaN stays in its row), the rows nobody names as they were,
+    and row 0 never written, whatever the idle slots carry."""
+    rows = np.asarray(_ROWS[case], np.int32)
+    n, r, width = len(rows), 7, 384
+    rng = np.random.default_rng(len(case))
+
+    def draw(shape):
+        x = (rng.standard_normal(shape)
+             * 10.0 ** rng.integers(-44, 38, size=shape))
+        x[..., ::7] = -0.0
+        return jnp.asarray(x, jnp.float32).astype(dtype)
+
+    arena = draw((r, width)).at[0].set(7.0)
+    new = draw((n, width)).at[3].set(jnp.nan)   # slot 3: live in all but one
+    new = jnp.where((rows == 0)[:, None] & (jnp.arange(n) != 3)[:, None],
+                    jnp.asarray(3e38, dtype), new)
+    got = jax.jit(cache_entry._spread_rows, donate_argnums=0)(
+        jnp.array(arena), jnp.asarray(rows), new)
+    want = _bits(arena.at[rows].set(new))
+    want[0] = _bits(arena)[0]        # the scatter lets idle slots race here
+    assert got.dtype == arena.dtype and got.shape == arena.shape
+    np.testing.assert_array_equal(_bits(got), want)
+    untouched = sorted(set(range(r)) - set(rows.tolist()))
+    np.testing.assert_array_equal(_bits(got)[untouched],
+                                  _bits(arena)[untouched])
+    poisoned = {int(rows[3])} - {0}
+    clean = sorted(set(range(r)) - poisoned)
+    assert np.isfinite(np.asarray(got.astype(jnp.float32))[clean]).all()
+
+
+def test_the_spread_lowers_to_a_take_and_no_scatter():
+    """What the form is for: no ``scatter`` (which the TPU's compiler
+    runs as a sequential loop over the slots at rows this wide), no loop
+    and no product: a gather of the arena's rows."""
+    text = jax.jit(cache_entry._spread_rows).lower(
+        jnp.zeros((5, 384), jnp.bfloat16), jnp.zeros((4,), jnp.int32),
+        jnp.zeros((4, 384), jnp.bfloat16)).as_text()
+    assert "scatter" not in text and "while" not in text
+    assert "dot_general" not in text and "gather" in text
+
+
 def test_a_latent_row_has_no_int8_form():
     assert LatentEntry(24).int8_form is None
     assert PairEntry(4, 8).int8_form == Int8PairEntry(4, 8)

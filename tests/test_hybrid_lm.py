@@ -162,6 +162,74 @@ def test_generate_equals_the_dense_generator_and_slots_are_reused(model):
     assert kv["in_use"] == 0
 
 
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16"])
+def test_requests_in_slots_out_of_row_order_decode_as_alone(model, kv_dtype):
+    """Three requests whose arena rows (1, 2, 3 by admission) sit in the
+    slots in another order, an idle slot between them and one joining
+    late: every step's tails go back to the row their slot names, so each
+    request's logits are what it reads decoded alone in slot 1 of a fresh
+    decoder, to the bit (the same programs, the other slots idle)."""
+    ff, _ = model
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 96, size=n).astype(np.int32)
+               for n in (9, 14, 5)]
+    steps = 6
+
+    def decoder():
+        return PagedDecoder(ff, 64, decode_slots=5, block_size=8,
+                            prefill_buckets=[16], kv_dtype=kv_dtype,
+                            calibrate=False)
+
+    alone = []
+    for p in prompts:
+        rows, toks, _ = _serve(decoder(), p, steps)
+        alone.append((rows, toks))
+    dec = decoder()
+    tables = [dec.pool.try_admit(len(p) + steps + 1) for p in prompts]
+    assert dec.pool.rows_of(np.stack(tables)).tolist() == [1, 2, 3]
+    got = [[dec.prefill(p, t)] for p, t in zip(prompts, tables)]
+    slot_of = {0: 4, 1: 2, 2: 0}          # request -> slot; 1 and 3 idle
+    joins = {0: 0, 1: 2, 2: 0}            # request 1 waits two steps
+    for k in range(steps + 2):
+        tokens = np.zeros(5, np.int32)
+        tabs = np.zeros((5, dec.max_blocks_per_request), np.int32)
+        lens = np.zeros(5, np.int32)
+        live = [r for r in range(3) if joins[r] <= k < joins[r] + steps]
+        for r in live:
+            slot, j = slot_of[r], k - joins[r]
+            tokens[slot] = alone[r][1][len(prompts[r]) + j]
+            lens[slot], tabs[slot] = len(prompts[r]) + j, tables[r]
+        out = dec.decode(tokens, tabs, lens)
+        for r in live:
+            got[r].append(out[slot_of[r]])
+    for r in range(3):
+        np.testing.assert_array_equal(np.stack(got[r]), alone[r][0])
+    tails = np.asarray(dec.pool.kv["block0_mixer"][1].astype(jnp.float32))
+    assert np.isfinite(tails).all() and not tails[0].any()  # never written
+
+
+def test_two_requests_on_five_slots_equal_the_dense_generator(model):
+    """Through the scheduler with most slots idle for the whole session
+    (their tables name row 0): token for token the dense generator's."""
+    ff, _ = model
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(0, 96, (n,)).astype(np.int32), m)
+            for n, m in [(13, 9), (4, 12)]]
+    gen = Generator(ff, max_length=64, batch_size=1)
+    want = [gen.generate(p[None, :], m)[0] for p, m in reqs]
+    inst = GenerationInstance(ff, decode_slots=5, block_size=8,
+                              max_length=64, prefill_buckets=[16])
+    try:
+        got = [f.result(timeout=300) for f in
+               [inst.generate_async(p, m, temperature=0.0) for p, m in reqs]]
+        state = inst.stats()["kv"]["state"]
+    finally:
+        inst.stop()
+    for out, ref in zip(got, want):
+        np.testing.assert_array_equal(out, ref)
+    assert state["high_water"] == 2 and state["in_use"] == 0
+
+
 def test_a_slots_second_request_sees_nothing_of_the_first(model):
     ff, _ = model
     dec = PagedDecoder(ff, 128, decode_slots=3, block_size=8,
@@ -338,11 +406,16 @@ def test_unknown_layer_type_is_refused():
 # one-pass sparse cross-entropy (``runtime/loss.py``
 # ``sparse_log_likelihood``), which is meant to move them and nothing
 # else: the five serving programs kept their digests through it.
+# ``hybrid.decode`` was recorded again when ``StateEntry.step`` took to
+# writing its tails back row by row of the arena, each row taking the
+# values of the slot that names it (``cache_entry._spread_rows``), in
+# place of a scatter: the one program that change is meant to move; the
+# other six kept theirs.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
     "gpt.train": "b910d8faaa4dc59157d5baffb29bd2f5ec466eb49c9b8bed2e2fb14878d1376f",
-    "hybrid.decode": "4d24aefa5c08257e468bf6e0099721acafe48a58f593be9809c3c206eedcec7f",
+    "hybrid.decode": "1ccca11bf7d46d6b6847716a414a3e25a563e2874e54dbb59ea32f0f8053fd95",
     "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
     "latent_moe.prefill": "43e30c908af85f9f65457976171f1f762c7a454a0f807318b4c7e2a3e4a6dbd4",
     "latent_moe.train": "ec35820380d8c0377a8b07e79d3248e5c9af80e3156d4e7f49d29b7f78eb7b8f",

@@ -399,13 +399,22 @@ def test_train_step_fusions_keep_their_scopes(train_step_text):
         for o in kernels.values()), kernels
 
 
-def test_hybrid_decode_step_while_is_a_state_ops_write(monkeypatch, one_chip,
-                                                       no_compile_cache):
+@pytest.mark.parametrize("compute_dtype, kv_dtype, tails_dtype", [
+    ("bfloat16", "bfloat16", "bf16"),       # the documents cell's
+    ("float32", "float32", "f32"),          # ``serving_kv_dtype``'s default
+])
+def test_hybrid_decode_step_loops_over_no_slots(monkeypatch, one_chip,
+                                                no_compile_cache,
+                                                compute_dtype, kv_dtype,
+                                                tails_dtype):
     """One linear layer of the hybrid configuration at its published
-    widths and its cell's 32 slots: the decode step's one ``while`` (the
-    scatter of the convolution tails, rows of 34,560 numbers) carries the
-    state op's ``write`` in its ``op_name``, and the state kernel its
-    ``rule``: what a device trace reads them by."""
+    widths and its cell's 32 slots: the decode step holds no ``while``
+    and no ``dynamic-update-slice`` (a scatter of the convolution tails,
+    rows of 34,560 numbers, lowered to a sequential loop over the slots).
+    The tails are written by ONE fusion over the arena's 33 rows that
+    carries the state op's ``write`` in its ``op_name``, in place (the
+    arena's parameter is aliased to its output), and the state kernel
+    carries its ``rule``: what a device trace reads them by."""
     import json
     import os
 
@@ -424,13 +433,13 @@ def test_hybrid_decode_step_while_is_a_state_ops_write(monkeypatch, one_chip,
     config = dict(config, num_hidden_layers=1,
                   layer_types=config["layer_types"][:1])
     slots, max_length = 32, 2048
-    ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+    ff = FFModel(FFConfig(batch_size=slots, compute_dtype=compute_dtype,
                           ledger="off", search_cache="off",
                           computation_mode=CompMode.INFERENCE))
     family.build(ff, config, slots, max_length)
     ff.compile(optimizer=None, loss_type=None, metrics=[])
     dec = PagedDecoder(ff, max_length, decode_slots=slots, block_size=16,
-                       kv_dtype="bfloat16", calibrate=False)
+                       kv_dtype=kv_dtype, calibrate=False)
 
     def on_chip(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -438,17 +447,39 @@ def test_hybrid_decode_step_while_is_a_state_ops_write(monkeypatch, one_chip,
     def ints(*shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    text = dec._decode.lower(
-        jax.tree_util.tree_map(on_chip, dec._params_sds()), ints(slots),
-        jax.tree_util.tree_map(on_chip, dec.pool.kv),
-        Addresses(ints(slots, dec.max_blocks_per_request), ints(slots)),
-        ints(slots), {}, ints(slots),
-        ints(slots, dtype=jnp.bool_)).compile().as_text()
-    names = _entry_op_names(text)
+    args = (jax.tree_util.tree_map(on_chip, dec._params_sds()), ints(slots),
+            jax.tree_util.tree_map(on_chip, dec.pool.kv),
+            Addresses(ints(slots, dec.max_blocks_per_request), ints(slots)),
+            ints(slots), {}, ints(slots), ints(slots, dtype=jnp.bool_))
+    text = dec._decode.lower(*args).compile().as_text()
+    assert " while(" not in text
+    assert "dynamic-update-slice" not in text
+    assert " scatter(" not in text
     (mixer,) = [op.name for op in dec._attn_ops]
-    whiles = [parse_scope(v) for k, v in names.items()
-              if k.startswith("while")]
-    assert whiles == [("GATED_DELTA_NET", mixer, ("write",), "fwd")]
+    state, tails = dec.pool.kv[mixer]
+    arena = f"{tails_dtype}[{tails.shape[0]},{tails.shape[1]}]"
+    assert tails.shape == (slots + 1, 3 * 11520)
+    names = _entry_op_names(text)
+    # the entry's instructions that make a whole arena (the compiler may
+    # besides move the float32 one through fast memory and back, an async
+    # ``copy-done`` each way: the same bytes as a pass where it lies)
+    made = {}
+    for ln in _buffers(text):
+        m = re.match(rf"\s*(?:ROOT )?%?([\w.\-]+) = {re.escape(arena)}\S* "
+                     r"([a-z][\w\-]*)\(", ln)
+        if m and m.group(2) not in ("parameter", "bitcast", "copy-done",
+                                    "get-tuple-element"):
+            made[m.group(1)] = m.group(2)
+    assert list(made.values()) == ["fusion"], made
+    (writer,) = made
+    assert parse_scope(names[writer]) == (
+        "GATED_DELTA_NET", mixer, ("write",), "fwd")
+    # no copy of either arena: both donated parameters alias their outputs
+    leaves = jax.tree_util.tree_leaves(args)
+    pooled = [i for i, a in enumerate(leaves)
+              if a.shape in (state.shape, tails.shape)]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert sorted(int(i) for i in re.findall(r"\((\d+), ", alias)) == pooled
     kernels = [parse_scope(v) for k, v in names.items()
                if k.startswith("gated_delta_decode")]
     assert kernels == [("GATED_DELTA_NET", mixer, ("rule",), "fwd")]
