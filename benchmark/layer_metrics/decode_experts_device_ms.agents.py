@@ -1,0 +1,16 @@
+"""Exclusive device milliseconds per execution of the paged decode program
+(``jit__decode_step``) that lie under the ops of the type
+``ROUTED_EXPERTS`` (the router, the latent projections and the held
+experts' products; the shared expert is two ``LINEAR`` ops beside them),
+from the owner table of the traced window (``benchmark/owners.py``: an
+operation's duration less what is nested inside it, by the scope in its
+``op_name`` path). None where the profile holds no such scope. Layer:
+Expert layer."""
+
+from benchmark import owners
+
+PROGRAM = r"_decode_step"
+
+
+def read(run):
+    return owners.device_ms(run, PROGRAM, kinds=("ROUTED_EXPERTS",))

@@ -46,6 +46,7 @@ from .ops import (  # noqa: F401
     fused,
     gated_delta,
     lightning_attention,
+    mamba2,
     linear,
     moe_ops,
     norm,

@@ -81,14 +81,15 @@ FIXED_SCOPES = ("loss", "metrics", "optimizer", "sample", "tail", "counters")
 # the pieces of work inside an op that different changes aim at, the same
 # word in every entry kind (serving/cache_entry.py)
 SUB_SCOPES = ("project", "write", "attend", "select", "conv", "rule",
-              "chunks")
+              "chunks", "route", "latent", "experts")
 # the group a metric sums an op type under; a type not named is "other"
 OP_GROUPS: Dict[str, Tuple[OpType, ...]] = {
     # the types a serving program gives a pair, latent or sparse entry kind
     "attention": (OpType.MULTIHEAD_ATTENTION, OpType.LATENT_ATTENTION,
                   OpType.BLOCK_SPARSE_ATTENTION),
     # the types that keep a state a request
-    "state": (OpType.GATED_DELTA_NET, OpType.LIGHTNING_ATTENTION),
+    "state": (OpType.GATED_DELTA_NET, OpType.LIGHTNING_ATTENTION,
+              OpType.MAMBA2),
     "matmul": (OpType.LINEAR, OpType.GATED_MLP, OpType.EXPERT_LINEAR,
                OpType.ROUTED_EXPERTS),
 }

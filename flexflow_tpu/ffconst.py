@@ -48,6 +48,7 @@ class ActiMode(enum.Enum):
     TANH = 13
     GELU = 14
     SILU = 15
+    RELU2 = 16      # relu(x)^2 (no reference analog)
 
 
 class AggrMode(enum.Enum):
@@ -186,6 +187,10 @@ class OpType(enum.Enum):
     # linear attention with a fixed decay a head over a state of fixed
     # size a sequence, rotary positions (Lightning Attention)
     LIGHTNING_ATTENTION = "lightning_attention"
+    # a state-space mixer (Mamba-2): a scalar data-dependent decay a head
+    # over a state of fixed size a sequence, B and C shared by the heads
+    # of a group, a joint causal convolution, a gated grouped RMSNorm
+    MAMBA2 = "mamba2"
     # x -> (act(x W_gate) * (x W_up)) W_down
     GATED_MLP = "gated_mlp"
     # dropless top-k routing over n experts, of which this op holds a

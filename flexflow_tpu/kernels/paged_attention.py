@@ -25,6 +25,11 @@ from the arena where they lie:
   ``P @ V_chunk`` an ``(H_pad, H*D)`` accumulator whose diagonal blocks
   are the heads' outputs. No lane slice at a half tile, no per-head
   loop; the MXU has the headroom (the step is bound by the bytes);
+* grouped heads (``H`` query heads on ``Hkv`` key-value heads, the
+  arena's rows ``Hkv * D`` lanes wide, ``D`` whole lane tiles): the
+  ``H / Hkv`` query heads of a group are that many rows of the same score
+  matrix over the one key row, row h keeping its query in the lanes of
+  key-value head ``h // (H / Hkv)``;
 * scores, the running maximum and sum, and the weighted sum are float32;
   K, V and the probabilities fed to the MXU are in the arena's dtype,
   which is what the jnp path feeds it.
@@ -83,17 +88,18 @@ def _pages_per_chunk(block_size: int, max_blocks: int,
 
 
 def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
-                block_size: int, max_blocks: int, dtype) -> int:
+                block_size: int, max_blocks: int, dtype,
+                kv_heads: Optional[int] = None) -> int:
     """The kernel's VMEM working set as it is allocated: q and o whole
     (float32, double-buffered by the pipeline), both chunk buffers of K
     and V, the float32 accumulator, and the (rows, chunk) float32
     temporaries of one iteration (s, p, mask) beside the block-diagonal
     operand and its mask."""
-    hd = heads * head_dim
+    hd = (kv_heads or heads) * head_dim
     m = window * _round_up(heads, 16)
     chunk = _pages_per_chunk(block_size, max_blocks) * block_size
     item = jnp.dtype(dtype).itemsize
-    return (2 * 2 * 4 * rows * hd              # q, o
+    return (2 * 2 * 4 * rows * heads * head_dim  # q, o
             + 2 * 2 * chunk * hd * item        # K, V chunks, two buffers
             + 4 * m * hd * 3                   # acc, q_bd, its f32 source
             + 4 * m * 128 * 2                  # m, l columns (lane padded)
@@ -102,7 +108,8 @@ def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
 
 def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
     """Whether the kernel takes this call. ``q_shape``: (slots, W, H, D);
-    ``arena_shape``: (num_blocks, block_size, H*D). Refuses what Mosaic
+    ``arena_shape``: (num_blocks, block_size, Hkv*D), ``Hkv`` dividing
+    ``H`` (grouped heads: ``D`` then whole lane tiles). Refuses what Mosaic
     would: rows that do not fill whole 128-lane tiles, blocks that are
     not whole sublane tiles of the arena's dtype or do not divide a lane
     tile of tokens, dtypes other than float32 and bfloat16 (an int8
@@ -117,7 +124,10 @@ def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
     dtype = jnp.dtype(arena_dtype)
     if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
-    if hd != heads * head_dim or hd % 128:
+    if hd % head_dim or hd % 128:
+        return False
+    kv_heads = hd // head_dim
+    if heads % kv_heads or (kv_heads != heads and head_dim % 128):
         return False
     if block_size % _sublanes(dtype) or (128 % block_size
                                          and block_size % 128):
@@ -128,7 +138,7 @@ def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
         return False
     rows = _round_up(n * w, 8)
     return _vmem_bytes(rows, w, heads, head_dim, block_size, max_blocks,
-                       dtype) <= VMEM_BUDGET_BYTES
+                       dtype, kv_heads) <= VMEM_BUDGET_BYTES
 
 
 def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
@@ -136,9 +146,10 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
             o_ref,                           # output
             kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref,
             *, scale, window, heads, head_dim, block_size, max_blocks,
-            pages, slots):
+            pages, slots, kv_heads):
     b = pl.program_id(0)
-    hd = heads * head_dim
+    hd = kv_heads * head_dim
+    group = heads // kv_heads
     hpad = _round_up(heads, 16)
     chunk = pages * block_size
 
@@ -177,11 +188,26 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
     # and at the end the diagonal blocks of the accumulator
     r = jax.lax.broadcasted_iota(jnp.int32, (hpad, hd), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (hpad, hd), 1)
-    diag = (c >= r * head_dim) & (c < r * head_dim + head_dim)
-    q_bd = jnp.concatenate(
-        [jnp.where(diag, jnp.broadcast_to(
-            q_ref[pl.ds(b * window + w, 1), :], (hpad, hd)), 0.0)
-         for w in range(window)], axis=0).astype(kbuf.dtype)  # (M, HD)
+    if group == 1:
+        diag = (c >= r * head_dim) & (c < r * head_dim + head_dim)
+        q_bd = jnp.concatenate(
+            [jnp.where(diag, jnp.broadcast_to(
+                q_ref[pl.ds(b * window + w, 1), :], (hpad, hd)), 0.0)
+             for w in range(window)], axis=0).astype(kbuf.dtype)  # (M, HD)
+    else:
+        # q and o are (rows, H, D): row h of a group of hpad keeps head
+        # h's query in the lanes of its key-value head, whole lane tiles
+        diag = (c // head_dim == r // group) & (r < heads)
+
+        def spread(qh):               # (H, D) -> (hpad, Hkv D)
+            if hpad != heads:
+                qh = jnp.concatenate(
+                    [qh, jnp.zeros((hpad - heads, head_dim), qh.dtype)], 0)
+            return jnp.where(diag, jnp.concatenate([qh] * kv_heads, 1), 0.0)
+
+        q_bd = jnp.concatenate(
+            [spread(q_ref[b * window + w]) for w in range(window)],
+            axis=0).astype(kbuf.dtype)
     m_rows = window * hpad
     # the last position each row may see: seq_len + its window index
     row = jax.lax.broadcasted_iota(jnp.int32, (m_rows, chunk), 0)
@@ -224,9 +250,15 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
 
     out = acc_ref[...] / l_ref[...]                           # (M, HD)
     for w in range(window):
-        o_ref[pl.ds(b * window + w, 1), :] = jnp.sum(
-            jnp.where(diag, out[w * hpad:(w + 1) * hpad], 0.0),
-            axis=0, keepdims=True)
+        kept = jnp.where(diag, out[w * hpad:(w + 1) * hpad], 0.0)
+        if group == 1:
+            o_ref[pl.ds(b * window + w, 1), :] = jnp.sum(
+                kept, axis=0, keepdims=True)
+        else:
+            # a row's output lies in its key-value head's lanes alone
+            o_ref[b * window + w] = sum(
+                kept[:heads, j * head_dim:(j + 1) * head_dim]
+                for j in range(kv_heads))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
@@ -234,13 +266,18 @@ def _paged_attention(q, k_arena, v_arena, tables, seq_lens, *, scale, pages,
                      interpret):
     n, window, heads, head_dim = q.shape
     _, block_size, hd = k_arena.shape
+    kv_heads = hd // head_dim
     max_blocks = tables.shape[1]
     rows = _round_up(n * window, 8)
-    q2 = q.astype(jnp.float32).reshape(n * window, hd)
+    # a key head a query head: q and o are rows of H*D lanes; grouped:
+    # (rows, H, D), a head a sublane row
+    q_shape = ((rows, hd) if kv_heads == heads else (rows, heads, head_dim))
+    q2 = q.astype(jnp.float32).reshape((n * window,) + q_shape[1:])
     if rows != n * window:
-        q2 = jnp.pad(q2, ((0, rows - n * window), (0, 0)))
+        q2 = jnp.pad(q2, ((0, rows - n * window),) + ((0, 0),) *
+                     (q2.ndim - 1))
     m_rows = window * _round_up(heads, 16)
-    whole = pl.BlockSpec((rows, hd), lambda b, lens, tabs: (0, 0))
+    whole = pl.BlockSpec(q_shape, lambda b, lens, tabs: (0,) * len(q_shape))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
@@ -262,9 +299,9 @@ def _paged_attention(q, k_arena, v_arena, tables, seq_lens, *, scale, pages,
         functools.partial(
             _kernel, scale=scale, window=window, heads=heads,
             head_dim=head_dim, block_size=block_size,
-            max_blocks=max_blocks, pages=pages, slots=n),
+            max_blocks=max_blocks, pages=pages, slots=n, kv_heads=kv_heads),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(q_shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
@@ -283,7 +320,7 @@ def paged_attention_decode(q, k_arena, v_arena, tables, seq_lens,
     through its block table from the arenas in place.
 
     ``q``: (slots, W, H, D); ``k_arena``/``v_arena``: (num_blocks,
-    block_size, H*D), already holding the window's own rows; ``tables``:
+    block_size, Hkv*D), already holding the window's own rows; ``tables``:
     (slots, max_blocks) int32; ``seq_lens``: (slots,) int32, the tokens
     cached before the window. Row w of a slot sees positions
     ``0 .. seq_len + w``. ``pages_per_chunk`` (blocks a loop iteration;

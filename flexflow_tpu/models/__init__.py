@@ -16,6 +16,7 @@ from .gpt import build_gpt, GPTConfig
 from .latent_moe import build_latent_moe_lm, LatentMoEConfig
 from .hybrid import build_hybrid_lm, HybridLMConfig
 from .sparse_hybrid import build_sparse_hybrid_lm, SparseHybridConfig
+from .nemotron_h import build_nemotron_h_lm, NemotronHConfig
 
 
 def zoo_smoke_builders():
@@ -93,6 +94,14 @@ def zoo_smoke_builders():
             selection=dict(kernel=4, stride=2, block=4, window=4,
                            dense_len=16, init_blocks=1, topk=3)))
 
+    def nemotron_h(ff, bs):
+        build_nemotron_h_lm(ff, bs, 16, NemotronHConfig(
+            vocab_size=128, hidden_size=32, pattern="EM*", mamba_heads=4,
+            mamba_head_dim=8, state_size=8, n_groups=2, chunk_size=8,
+            num_heads=4, num_kv_heads=2, n_routed=8, experts_per_token=2,
+            routed_scale=2.0, latent_size=16, expert_width=24,
+            shared_width=48))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -109,4 +118,5 @@ def zoo_smoke_builders():
         "latent_moe": latent_moe,
         "hybrid": hybrid,
         "sparse_hybrid": sparse_hybrid,
+        "nemotron_h": nemotron_h,
     }

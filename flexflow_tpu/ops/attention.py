@@ -84,6 +84,12 @@ class MultiHeadAttention(Op):
         # qdim / num_heads)
         assert self.embed_dim % self.num_heads == 0
         self.head_dim = self.embed_dim // self.num_heads
+        # grouped heads: query head h reads key-value head
+        # h // (num_heads / num_kv_heads)
+        self.num_kv_heads = int(a.get("num_kv_heads") or self.num_heads)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(f"{self.num_heads} query heads are not "
+                             f"{self.num_kv_heads} equal groups")
         self.q_in = input_shapes[0].sizes[-1]
         self.k_in = input_shapes[1].sizes[-1]
         self.v_in = input_shapes[2].sizes[-1]
@@ -103,18 +109,18 @@ class MultiHeadAttention(Op):
     def weight_specs(self) -> List[WeightSpec]:
         dt = self.input_shapes[0].dtype
         init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
-        h, d = self.num_heads, self.head_dim
+        h, d, hkv = self.num_heads, self.head_dim, self.num_kv_heads
         specs = [
             WeightSpec("wq", (self.q_in, h, d), dt, init),
-            WeightSpec("wk", (self.k_in, h, d), dt, init),
-            WeightSpec("wv", (self.v_in, h, d), dt, init),
+            WeightSpec("wk", (self.k_in, hkv, d), dt, init),
+            WeightSpec("wv", (self.v_in, hkv, d), dt, init),
             WeightSpec("wo", (h, d, self.embed_dim), dt, init),
         ]
         if self.use_bias:
             specs += [
                 WeightSpec("bq", (h, d), dt, ZeroInitializer(), weight_decay=False),
-                WeightSpec("bk", (h, d), dt, ZeroInitializer(), weight_decay=False),
-                WeightSpec("bv", (h, d), dt, ZeroInitializer(), weight_decay=False),
+                WeightSpec("bk", (hkv, d), dt, ZeroInitializer(), weight_decay=False),
+                WeightSpec("bv", (hkv, d), dt, ZeroInitializer(), weight_decay=False),
                 WeightSpec("bo", (self.embed_dim,), dt, ZeroInitializer(), weight_decay=False),
             ]
         if self.qk_norm:
@@ -122,7 +128,7 @@ class MultiHeadAttention(Op):
                     or ConstantInitializer(1.0))
             specs += [
                 WeightSpec("q_norm", (h, d), dt, gain, weight_decay=False),
-                WeightSpec("k_norm", (h, d), dt, gain, weight_decay=False),
+                WeightSpec("k_norm", (hkv, d), dt, gain, weight_decay=False),
             ]
         return specs
 
@@ -133,8 +139,8 @@ class MultiHeadAttention(Op):
 
     @sub_scope("project")
     def project_qkv(self, weights, q_in, k_in, v_in):
-        """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries, keys and
-        values, biases added."""
+        """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries and the (B,
+        S, Hkv, D) keys and values, biases added."""
         qh = jnp.einsum("bse,ehd->bshd", q_in, weights["wq"])
         kh = jnp.einsum("bse,ehd->bshd", k_in, weights["wk"])
         vh = jnp.einsum("bse,ehd->bshd", v_in, weights["wv"])
@@ -163,6 +169,15 @@ class MultiHeadAttention(Op):
             out = out + weights["bo"]
         return out
 
+    def _all_heads(self, kh, vh):
+        """Grouped (B, S, Hkv, D) keys and values as every query head
+        reads them, (B, S, H, D): what a whole forward's attention takes
+        (serving's forms read the groups where they lie)."""
+        group = self.num_heads // self.num_kv_heads
+        if group == 1:
+            return kh, vh
+        return jnp.repeat(kh, group, axis=2), jnp.repeat(vh, group, axis=2)
+
     def _fused(self, ctx, inputs, weights):
         """The op through the fused kernels (kernels/flash_attention.py:
         no array with two sequence axes reaches HBM), or None where the
@@ -173,6 +188,8 @@ class MultiHeadAttention(Op):
 
         q_in, k_in, v_in = inputs
         h, d = self.num_heads, self.head_dim
+        if self.num_kv_heads != h:
+            return None               # the kernels take a key head a query head
         q_shape = q_in.shape[:2] + (h, d)
         k_shape = k_in.shape[:2] + (h, d)
         if not fa.engaged(q_shape[1], k_shape[1], d, self.causal, q_in.dtype):
@@ -235,7 +252,8 @@ class MultiHeadAttention(Op):
 
             sp = ulysses_attention if self.seq_mode == "a2a" else ring_attention
             path = "ulysses" if self.seq_mode == "a2a" else "ring"
-            qkv = self.project_qkv(weights, *inputs)
+            qh, kh, vh = self.project_qkv(weights, *inputs)
+            qkv = (qh,) + self._all_heads(kh, vh)
             with sub_scope("attend"):
                 ctxv = sp(*qkv, ctx.mesh, self.seq_axis, causal=self.causal,
                           scale=self.scale, dropout_rate=drop, rng=ctx.rng)
@@ -247,7 +265,8 @@ class MultiHeadAttention(Op):
             path = "flash"
             if out is None:
                 path = "xla"
-                qkv = self.project_qkv(weights, *inputs)
+                qh, kh, vh = self.project_qkv(weights, *inputs)
+                qkv = (qh,) + self._all_heads(kh, vh)
                 with sub_scope("attend"):
                     ctxv = single_device_attention(
                         *qkv, self.causal, self.scale, drop, ctx.rng)
@@ -266,7 +285,7 @@ class MultiHeadAttention(Op):
         ax = strategy.get("heads")
         if ax:
             deg = axis_sizes.get(ax, 1)
-            if deg > 1 and self.num_heads % deg == 0:
+            if deg > 1 and self.num_kv_heads % deg == 0:
                 for wn in ("wq", "wk", "wv"):
                     weight_shapes[wn] = weight_shapes[wn].partitioned(1, deg, ax)
                 weight_shapes["wo"] = weight_shapes["wo"].partitioned(0, deg, ax)
@@ -295,7 +314,8 @@ class MultiHeadAttention(Op):
     def flops(self) -> float:
         b, s = self.input_shapes[0].sizes[0], self.input_shapes[0].sizes[1]
         e, h, d = self.embed_dim, self.num_heads, self.head_dim
-        proj = 2.0 * b * s * e * h * d * 4  # q,k,v,o projections
+        # q and o over every head, k and v over the key-value heads
+        proj = 2.0 * b * s * e * d * 2 * (h + self.num_kv_heads)
         attn = 2.0 * b * h * s * s * d * 2  # logits + context
         return proj + attn
 

@@ -41,6 +41,8 @@ def apply_activation(x: jnp.ndarray, mode: ActiMode) -> jnp.ndarray:
         import jax.nn
 
         return jax.nn.silu(x)
+    if mode is ActiMode.RELU2:
+        return jnp.square(jnp.maximum(x, 0))
     raise ValueError(mode)
 
 

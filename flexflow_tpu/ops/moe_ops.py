@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax import shard_map
 
 from ..ffconst import ActiMode, DataType, OpType
-from ..core.op import Op, register_op
+from ..core.op import Op, register_op, sub_scope
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 
 
@@ -526,28 +526,46 @@ class Cache(Op):
         return [inputs[0]]
 
 
+# rows a matrix read once from HBM multiplies in the time of its read on
+# the chip this is measured on (a v5e: 197 TFLOP/s over 819 GB/s): up to
+# here the dense form's rows cost no more than the matrices' bytes
+RIDGE_ROWS = 240
+# of the rows of a call, the share one held expert may be named by before
+# the grouped form gives the call to the dense one
+CAPACITY_SHARE = 4
+
+
 @register_op
 class RoutedExperts(Op):
     """Dropless top-k routing over ``n_routed`` experts, of which this op
     holds ``experts_held = (first, count)`` (no reference analog; the
-    formulation of DeepSeek-V3 2024). Per token ``u``:
+    formulation of DeepSeek-V3 2024, and with ``latent`` the LatentMoE of
+    the Nemotron 3 line). Per token ``u``:
 
     * scores ``s = sigmoid(float32(u) W_router)``, in float32 whatever
       the activations' dtype;
+    * with ``selection_bias`` the choice is made by ``s + b`` (a learned
+      bias an expert; the weights below are still ``s``'s);
     * with ``n_group`` > 1 the experts are ``n_group`` groups of equal
-      size, a group scores the sum of its two highest ``s``, the
+      size, a group scores the sum of its two highest, the
       ``topk_group`` highest groups stay;
-    * ``T`` = the ``experts_per_token`` highest ``s`` among what stays;
+    * ``T`` = the ``experts_per_token`` highest among what stays;
       ``g_e = s_e / sum_{T} s`` (``norm_topk``) times ``routed_scale``;
-    * output ``sum_{e in T, e held} g_e MLP_e(u)``, a gated MLP of width
-      ``width`` each.
+    * with ``latent`` the experts read ``v = u W_dn`` (``latent`` wide)
+      and their weighted sum goes through ``W_up`` back to the model's
+      width; else ``v = u``;
+    * output ``sum_{e in T, e held} g_e MLP_e(v)``, each of width
+      ``width``: ``activation`` ``"silu_gated"``: ``(silu(v Wg) * (v
+      Wu)) Wd``; ``"relu2"``: ``relu(v Wu)^2 Wd``.
 
     It routes over ALL ``n_routed`` experts and computes only the pairs
     whose expert it holds, adding nothing for the others: the sum over
-    the holders of all shares is the whole layer's routed part. Nothing
-    is dropped, and an expert's weights are read once a call however few
-    rows it gets (:meth:`apply`). Weights: ``router`` (E, n_routed),
-    ``w_gate``/``w_up`` (count, E, width), ``w_down`` (count, width, E).
+    the holders of all shares is the whole layer's routed part (with
+    ``latent``, after ``W_up``). Nothing is dropped (:meth:`apply`).
+    Weights: ``router`` (E, n_routed), ``bias`` (n_routed,),
+    ``latent_down`` (E, latent), ``latent_up`` (latent, E), ``w_gate``
+    (gated only) and ``w_up`` (count, V, width), ``w_down`` (count,
+    width, V), V the width the experts read.
     """
 
     op_type = OpType.ROUTED_EXPERTS
@@ -564,11 +582,18 @@ class RoutedExperts(Op):
         self.scoring = a.get("scoring", "sigmoid")
         self.norm_topk = bool(a.get("norm_topk", True))
         self.routed_scale = float(a.get("routed_scale", 1.0))
+        self.selection_bias = bool(a.get("selection_bias", False))
+        self.gated = a.get("activation", "silu_gated") == "silu_gated"
+        self.latent = int(a.get("latent") or 0)
+        self.work_dim = self.latent or self.in_dim
         first, count = a.get("experts_held") or (0, self.n_routed)
         self.first, self.count = int(first), int(count)
         if self.scoring != "sigmoid":
             raise ValueError(f"scoring {self.scoring!r}: only sigmoid "
                              f"scores are built")
+        if a.get("activation", "silu_gated") not in ("silu_gated", "relu2"):
+            raise ValueError(f"activation {a['activation']!r} is neither "
+                             f"'silu_gated' nor 'relu2'")
         if self.n_routed % self.n_group:
             raise ValueError(f"{self.n_routed} experts are not {self.n_group}"
                              f" equal groups")
@@ -584,17 +609,28 @@ class RoutedExperts(Op):
 
     def weight_specs(self):
         from ..core.op import WeightSpec
-        from ..runtime.initializer import DefaultWeightInitializer
+        from ..runtime.initializer import (DefaultWeightInitializer,
+                                           ZeroInitializer)
 
         dt = self.input_shapes[0].dtype
         init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
-        e, w, c = self.in_dim, self.width, self.count
-        return [WeightSpec("router", (e, self.n_routed), dt, init),
-                WeightSpec("w_gate", (c, e, w), dt, init),
-                WeightSpec("w_up", (c, e, w), dt, init),
-                WeightSpec("w_down", (c, w, e), dt, init)]
+        e, v, w, c = self.in_dim, self.work_dim, self.width, self.count
+        specs = [WeightSpec("router", (e, self.n_routed), dt, init)]
+        if self.selection_bias:
+            specs.append(WeightSpec(
+                "bias", (self.n_routed,), dt,
+                self.attrs.get("bias_initializer") or ZeroInitializer(),
+                weight_decay=False))
+        if self.latent:
+            specs += [WeightSpec("latent_down", (e, v), dt, init),
+                      WeightSpec("latent_up", (v, e), dt, init)]
+        if self.gated:
+            specs.append(WeightSpec("w_gate", (c, v, w), dt, init))
+        return specs + [WeightSpec("w_up", (c, v, w), dt, init),
+                        WeightSpec("w_down", (c, w, v), dt, init)]
 
     # ---- the two halves (serving reads the first's ids) -------------------
+    @sub_scope("route")
     def route(self, weights, x2d, ids=None):
         """``x2d`` (T, E) -> expert ids (T, k) int32 and their weights
         (T, k) float32, over all ``n_routed`` experts. With ``ids`` given
@@ -606,12 +642,14 @@ class RoutedExperts(Op):
                          precision=jax.lax.Precision.HIGHEST)
         s = jax.nn.sigmoid(logits)
         choice = s
+        if self.selection_bias:
+            choice = s + weights["bias"].astype(jnp.float32)
         if ids is not None:
             ids = ids.astype(jnp.int32)
         elif self.n_group > 1 and self.topk_group < self.n_group:
             t = s.shape[0]
             per = self.n_routed // self.n_group
-            grouped = s.reshape(t, self.n_group, per)
+            grouped = choice.reshape(t, self.n_group, per)
             gscore = jax.lax.top_k(grouped, min(2, per))[0].sum(-1)
             _, gidx = jax.lax.top_k(gscore, self.topk_group)
             keep = jnp.zeros((t, self.n_group), bool).at[
@@ -631,27 +669,177 @@ class RoutedExperts(Op):
         return ((ids - self.first)[..., None]
                 == jnp.arange(self.count, dtype=jnp.int32))
 
-    def apply(self, weights, x2d, ids, gates):
-        """The held experts' part of the layer for the routing given:
-        (T, E) in the activations' dtype. Every token passes through
-        every held expert and is weighted by its gate, 0 where the token
-        did not take the expert: nothing is dropped, the shapes do not
-        depend on the routing, each matrix is read once a call, and the
-        weighted sum over the experts is part of the down product. That
-        is ``n_routed / k`` times the rows the routing names; for a
-        holder of a dozen experts at a decode step's few rows the
-        matrices' bytes decide, not the rows (PERF.md section 6, PR 27:
-        pairs sorted by expert and ``jax.lax.ragged_dot`` read 3,683
-        tokens/s where this reads 5,012)."""
+    def expert_form(self, rows: int) -> str:
+        """How :meth:`apply` multiplies ``rows`` tokens: ``"dense"``
+        (every token through every held expert) or ``"grouped"`` (the
+        pairs the routing names, an expert's rows side by side in a tile
+        of :meth:`capacity` rows, what overflows a tile in a few spill
+        tiles). A rule over what a trace sees, no
+        knob: up to ``RIDGE_ROWS`` rows the matrices' bytes decide and
+        the dense form stays (a decode step's slots); past them the
+        products do, and the grouped form multiplies a quarter of the
+        dense one's rows (a prefill's bucket). PERF.md section 6, PR 40,
+        has both forms measured at both."""
+        return "grouped" if rows > RIDGE_ROWS else "dense"
+
+    def capacity(self, rows: int) -> int:
+        """Rows of a held expert's tile in the grouped form: a quarter of
+        the call's, whole sublane tiles. The routing names an expert
+        ``rows x k / n_routed`` times on average (a twenty-fourth of the
+        rows at 22 of 512 and at 8 of 192), so a tile holds an expert six
+        times as full as the mean."""
+        return -(-rows // (CAPACITY_SHARE * 16)) * 16
+
+    @property
+    def spill_tiles(self) -> int:
+        """Tiles of the grouped form beside the held experts' own: what
+        takes the rows an expert is named by beyond its tile (a sixteenth
+        as many as the experts held, one at the least)."""
+        return max(1, self.count // 16)
+
+    def rows_computed(self, rows: int) -> int:
+        """Rows the held experts' products run over for ``rows`` tokens:
+        every token an expert in the dense form; a tile an expert and the
+        spill tiles in the grouped one (where they hold what overflows)."""
+        if self.expert_form(rows) == "dense":
+            return self.count * rows
+        return (self.count + self.spill_tiles) * self.capacity(rows)
+
+    def _apply_dense(self, weights, v, ids, gates):
+        """Every token passes through every held expert and is weighted
+        by its gate, 0 where the token did not take the expert: the
+        shapes do not depend on the routing, each matrix is read once a
+        call, and the weighted sum over the experts is part of the down
+        product. That is ``n_routed / k`` times the rows the routing
+        names; for a holder of a dozen experts at a decode step's few
+        rows the matrices' bytes decide, not the rows (PERF.md section 6,
+        PR 27: pairs sorted by expert and ``jax.lax.ragged_dot`` read
+        3,683 tokens/s where this reads 5,012)."""
         w = (self.held_hits(ids) * gates[..., None]).sum(1)     # (T, count)
-        g = jnp.einsum("te,cef->ctf", x2d, weights["w_gate"],
-                       preferred_element_type=jnp.float32)
-        u = jnp.einsum("te,cef->ctf", x2d, weights["w_up"],
-                       preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u * w.T[:, :, None]).astype(x2d.dtype)
+        def up(name):
+            return jnp.einsum("te,cef->ctf", v, weights[name],
+                              preferred_element_type=jnp.float32)
+
+        if self.gated:
+            g, u = up("w_gate"), up("w_up")
+            h = jax.nn.silu(g) * u
+        else:
+            h = jnp.square(jnp.maximum(up("w_up"), 0.0))
+        h = (h * w.T[:, :, None]).astype(v.dtype)
         return jnp.einsum("ctf,cfe->te", h, weights["w_down"],
                           preferred_element_type=jnp.float32
-                          ).astype(x2d.dtype)
+                          ).astype(v.dtype)
+
+    def _apply_grouped(self, weights, v, ids, gates):
+        """The pairs (token, pick) sorted by held expert, each expert's
+        first :meth:`capacity` rows gathered side by side into its tile,
+        what an expert is named by beyond its tile into ``spill_tiles``
+        more tiles (each with its expert's matrices gathered beside it),
+        one batched product a matrix over the tiles, and each token
+        taking its own pairs' rows back, weighted by its gates. The
+        shapes and the work do not depend on the routing, however uneven.
+        Nothing is dropped: where the spill tiles do not hold what
+        overflows either, the call is the dense form's (a ``lax.cond``,
+        so the result never depends on the form). ``jax.lax.ragged_dot``
+        over the sorted pairs (XLA's grouped matmul on the TPU) was built
+        first and read SLOWER than the dense form at every size: its
+        static row count is the pairs a token CAN name, and every group
+        costs it a 256-row tile (PERF.md section 6, PR 40)."""
+        t, k = ids.shape
+        cap, spill = self.capacity(t), self.spill_tiles
+        local = ids - self.first
+        held = (local >= 0) & (local < self.count)
+        key = jnp.where(held, local, self.count).reshape(-1)     # (T k,)
+        order = jnp.argsort(key)               # stable: a token's order
+        starts = jnp.searchsorted(key[order], jnp.arange(
+            self.count + 1, dtype=key.dtype))
+        sizes = starts[1:] - starts[:-1]
+        # tiles an expert needs beyond its own, and where its run of the
+        # spill tiles begins
+        extra = jnp.maximum(sizes - 1, 0) // cap
+        spill_ends = jnp.cumsum(extra)
+        spill_starts = spill_ends - extra
+
+        def tiles():
+            # spill tile j is chunk ``1 + j - spill_starts[e]`` of the
+            # expert e whose run holds j (a tile past the runs repeats the
+            # last expert's rows, which no token takes back)
+            j = jnp.arange(spill)
+            e_spill = jnp.minimum(jnp.searchsorted(spill_ends, j,
+                                                   side="right"),
+                                  self.count - 1)
+            first = jnp.concatenate([
+                starts[:-1],
+                starts[e_spill] + (1 + j - spill_starts[e_spill]) * cap])
+            # slot c of a tile is the pair at sorted place first + c; a
+            # slot past its expert's pairs holds some other pair's row
+            pair = order[jnp.minimum(first[:, None] + jnp.arange(cap),
+                                     t * k - 1)]       # (count + spill, cap)
+            rows = v[pair // k]
+
+            def mlp(rows, pick):
+                def up(name):
+                    return jnp.einsum("...cv,...vf->...cf", rows,
+                                      pick(weights[name]),
+                                      preferred_element_type=jnp.float32)
+
+                if self.gated:
+                    g, u = up("w_gate"), up("w_up")
+                    h = jax.nn.silu(g) * u
+                else:
+                    h = jnp.square(jnp.maximum(up("w_up"), 0.0))
+                return jnp.einsum("...cf,...fv->...cv", h.astype(v.dtype),
+                                  pick(weights["w_down"]),
+                                  preferred_element_type=jnp.float32)
+
+            def spilled(tile):
+                # a tile at a time, its expert's matrices sliced where
+                # they lie (a gather of eight experts' matrices reads all
+                # of them: 4 ms a call at 128 held)
+                rows, e = tile
+                return mlp(rows, lambda w: jax.lax.dynamic_index_in_dim(
+                    w, e, 0, keepdims=False))
+
+            y = jnp.concatenate([
+                mlp(rows[:self.count], lambda w: w),
+                jax.lax.map(spilled, (rows[self.count:], e_spill))])
+            y = y * gates.reshape(-1)[pair][..., None]
+            # a pair's slot: by its place among its expert's pairs, in the
+            # expert's own tile or in its run of the spill tiles
+            e = jnp.clip(local, 0, self.count - 1)
+            rank = jnp.argsort(order).reshape(t, k) - starts[e]
+            tile = jnp.where(rank < cap, e,
+                             self.count + spill_starts[e] + rank // cap - 1)
+            mine = y.reshape((self.count + spill) * cap, -1)[
+                jnp.where(held, tile * cap + rank % cap, 0)]
+            return jnp.where(held[..., None], mine, 0.0).sum(1).astype(
+                v.dtype)
+
+        return jax.lax.cond(spill_ends[-1] <= spill, tiles,
+                            lambda: self._apply_dense(weights, v, ids, gates))
+
+    def apply(self, weights, x2d, ids, gates):
+        """The held experts' part of the layer for the routing given:
+        (T, E) in the activations' dtype, by the form
+        :meth:`expert_form` names for these rows; with ``latent``,
+        between the projection down and the projection up."""
+        v = x2d
+        if self.latent:
+            with sub_scope("latent"):
+                v = jnp.dot(x2d, weights["latent_down"],
+                            preferred_element_type=jnp.float32
+                            ).astype(x2d.dtype)
+        with sub_scope("experts"):
+            form = (self._apply_grouped
+                    if self.expert_form(x2d.shape[0]) == "grouped"
+                    else self._apply_dense)
+            y = form(weights, v, ids, gates)
+        if self.latent:
+            with sub_scope("latent"):
+                y = jnp.dot(y, weights["latent_up"],
+                            preferred_element_type=jnp.float32
+                            ).astype(x2d.dtype)
+        return y
 
     def forward(self, ctx, inputs, weights):
         (x,) = inputs
@@ -663,6 +851,9 @@ class RoutedExperts(Op):
         t = 1
         for s in self.input_shapes[0].sizes[:-1]:
             t *= s
-        # what apply() computes: every token through every held expert
+        mats = 3.0 if self.gated else 2.0
+        # what apply() computes for these rows, by its form
         return (2.0 * t * self.in_dim * self.n_routed
-                + 6.0 * t * self.count * self.in_dim * self.width)
+                + 4.0 * t * self.in_dim * self.latent
+                + 2.0 * mats * self.rows_computed(t) * self.work_dim
+                * self.width)

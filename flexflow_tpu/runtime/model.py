@@ -355,17 +355,44 @@ class FFModel:
         return self._infer_and_add(OpType.LIGHTNING_ATTENTION,
                                    [input, positions], attrs, name)
 
+    def mamba2(self, input: Tensor, *, num_heads: int, head_dim: int,
+               state_size: int, n_groups: int = 1, conv_taps: int = 4,
+               chunk_size: int = 128, eps: float = 1e-5,
+               kernel_initializer=None, gain_initializer=None,
+               gate_initializer=None, name: Optional[str] = None) -> Tensor:
+        """A state-space mixer over a state of fixed size a sequence
+        (ops/mamba2.py Mamba2): ``num_heads`` heads of ``head_dim``, a
+        state of ``state_size`` a head channel, B and C shared by the
+        heads of each of ``n_groups`` groups, a causal depthwise
+        convolution of ``conv_taps`` taps before them."""
+        attrs = dict(
+            num_heads=int(num_heads), head_dim=int(head_dim),
+            state_size=int(state_size), n_groups=int(n_groups),
+            conv_taps=int(conv_taps), chunk_size=int(chunk_size),
+            eps=float(eps), kernel_initializer=kernel_initializer,
+            gain_initializer=gain_initializer,
+            gate_initializer=gate_initializer)
+        return self._infer_and_add(OpType.MAMBA2, [input], attrs, name)
+
     def routed_experts(self, input: Tensor, *, n_routed: int,
                        experts_per_token: int, width: int,
                        n_group: int = 1, topk_group: Optional[int] = None,
                        scoring: str = "sigmoid", norm_topk: bool = True,
                        routed_scale: float = 1.0,
                        experts_held: Optional[Tuple[int, int]] = None,
-                       kernel_initializer=None,
+                       selection_bias: bool = False,
+                       activation: str = "silu_gated",
+                       latent: Optional[int] = None,
+                       kernel_initializer=None, bias_initializer=None,
                        name: Optional[str] = None) -> Tensor:
         """Dropless top-k routed experts of which this op holds
         ``experts_held = (first, count)`` (default: all of them)
-        (ops/moe_ops.py RoutedExperts)."""
+        (ops/moe_ops.py RoutedExperts). ``selection_bias``: a learned
+        bias an expert, added to the scores in the choice only;
+        ``activation``: ``"silu_gated"`` (``silu(u Wg) * (u Wu)``) or
+        ``"relu2"`` (a plain MLP, ``relu(u W1)^2``); ``latent``: the
+        width the experts work in, between a projection down before them
+        and one up after their sum."""
         attrs = dict(
             n_routed=int(n_routed), experts_per_token=int(experts_per_token),
             width=int(width), n_group=int(n_group),
@@ -374,6 +401,15 @@ class FFModel:
             experts_held=(tuple(int(v) for v in experts_held)
                           if experts_held else None),
             kernel_initializer=kernel_initializer)
+        # stated only where they depart, so that a graph without them is
+        # the graph it was
+        if selection_bias:
+            attrs.update(selection_bias=True,
+                         bias_initializer=bias_initializer)
+        if activation != "silu_gated":
+            attrs["activation"] = activation
+        if latent:
+            attrs["latent"] = int(latent)
         return self._infer_and_add(OpType.ROUTED_EXPERTS, [input], attrs,
                                    name)
 
@@ -548,6 +584,7 @@ class FFModel:
         qk_norm: bool = False,
         norm_eps: float = 1e-6,
         gain_initializer=None,
+        num_kv_heads: Optional[int] = None,
     ) -> Tensor:
         """reference: FFModel::multihead_attention (model.h:542,
         src/ops/attention.cc — cuDNN multihead attention). ``causal`` is a
@@ -570,6 +607,10 @@ class FFModel:
         if qk_norm:
             attrs.update(qk_norm=True, norm_eps=float(norm_eps),
                          gain_initializer=gain_initializer)
+        if num_kv_heads and int(num_kv_heads) != int(num_heads):
+            # grouped heads: query head h reads key-value head
+            # h // (num_heads / num_kv_heads)
+            attrs["num_kv_heads"] = int(num_kv_heads)
         if strategy:
             attrs["strategy"] = strategy
         return self._infer_and_add(

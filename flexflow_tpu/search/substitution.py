@@ -107,7 +107,10 @@ def candidate_strategies(
             if in_dim % n == 0:
                 cands.append({"in": a})
     elif t is OpType.MULTIHEAD_ATTENTION and attr_ok:
-        heads = layer.attrs.get("num_heads", 0)
+        # the axis has to divide the key-value heads (grouped heads:
+        # fewer than the query heads, which they divide)
+        heads = (layer.attrs.get("num_kv_heads")
+                 or layer.attrs.get("num_heads", 0))
         for a in model_axes:
             if heads % axis_sizes[a] == 0:
                 cands.append({"heads": a})

@@ -38,6 +38,7 @@ from ..core.op import sub_scope
 from ..ffconst import OpType
 from ..kernels import gated_delta, latent_attention, paged_attention
 from ..ops import block_sparse_attention as bsa
+from ..ops import mamba2
 from ..ops.gated_delta import delta_rule_path
 from .kv_cache import NULL_BLOCK
 
@@ -58,6 +59,25 @@ def _weigh(scores, mask, v):
     form shares."""
     probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _attend(q, k, v, mask, scale):
+    """The masked softmax attention of (B, Sq, H, D) queries over (B, Sk,
+    Hkv, D) keys and values; ``mask()`` gives what broadcasts against (B,
+    1, Sq, Sk), made behind the scores (the order the programs' lowered
+    text has had). A key head a query head: :func:`_scores` and
+    :func:`_weigh`. Grouped heads: the ``H / Hkv`` query heads of a group
+    read the one key head where it lies, nothing is repeated."""
+    h, hkv = q.shape[2], k.shape[2]
+    if h == hkv:
+        scores = _scores(q, k, scale)
+        return _weigh(scores, mask(), v)
+    b, sq, _, d = q.shape
+    qg = q.reshape(b, sq, hkv, h // hkv, d)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
+    probs = jax.nn.softmax(jnp.where(mask()[:, :, None], scores, -1e30),
+                           axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(b, sq, h, d)
 
 
 def _put(arena, flat, rows):
@@ -216,10 +236,14 @@ class EntryKind:
 
 @dataclasses.dataclass(frozen=True)
 class PairEntry(EntryKind):
-    """Keys and values, ``heads * head_dim`` numbers each a token."""
+    """Keys and values, ``heads * head_dim`` numbers each a token;
+    ``heads`` are the key-value heads, of which ``query_heads`` query
+    heads read one each ``query_heads / heads`` (0: a key head a query
+    head)."""
 
     heads: int
     head_dim: int
+    query_heads: int = 0
     name = "pair"
 
     @classmethod
@@ -227,11 +251,19 @@ class PairEntry(EntryKind):
         if len({t.tensor_id for t in op.layer.inputs}) != 1 or not op.causal:
             raise ValueError(
                 f"{op.name}: generation needs causal SELF-attention")
+        if op.num_kv_heads != op.num_heads:
+            return cls(op.num_kv_heads, op.head_dim, op.num_heads)
         return cls(op.num_heads, op.head_dim)
 
     @property
     def int8_form(self):
-        return Int8PairEntry(self.heads, self.head_dim)
+        return Int8PairEntry(self.heads, self.head_dim, self.query_heads)
+
+    def stats(self):
+        if not self.query_heads:
+            return {"entry": self.name}
+        return {"entry": self.name, "kv_heads": self.heads,
+                "query_heads": self.query_heads}
 
     def arenas(self, num_blocks, block_size, dtype):
         a = jax.ShapeDtypeStruct(
@@ -258,13 +290,14 @@ class PairEntry(EntryKind):
 
     def reads_in_place(self, op, entry, slots, window, max_blocks):
         return paged_attention.supported(
-            (slots, window, self.heads, self.head_dim), entry[0].shape,
-            entry[0].dtype, max_blocks)
+            (slots, window, self.query_heads or self.heads, self.head_dim),
+            entry[0].shape, entry[0].dtype, max_blocks)
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         qh, kh, vh = op.project_qkv(weights, x, x, x)
         bs = entry[0].shape[1]
-        n, w, heads, hdim = qh.shape
+        n, w = qh.shape[:2]
+        heads, hdim = kh.shape[2:]
         tables = addr.tables
         mb = tables.shape[1]
         pos = seq_lens[:, None] + _iota(w)[None, :]                # (n, W)
@@ -286,18 +319,23 @@ class PairEntry(EntryKind):
                     scale=op.scale).astype(qh.dtype)
             else:
                 k, v = self.read(entry, tables)             # (n, L, H, D)
-                scores = _scores(qh, k, op.scale)
-                mask = _iota(k.shape[1])[None, None, :] <= pos[:, :, None]
-                ctxv = _weigh(scores, mask[:, None, :, :], v)
+                ctxv = _attend(
+                    qh, k, v, lambda: (_iota(k.shape[1])[None, None, :]
+                                       <= pos[:, :, None])[:, None, :, :],
+                    op.scale)
         return op.project_out(weights, ctxv), entry
 
     def whole(self, op, weights, x, positions):
         qh, kh, vh = op.project_qkv(weights, x, x, x)
-        with sub_scope("attend"):
-            scores = _scores(qh, kh, op.scale)
+        pos = None
+
+        def causal():
+            nonlocal pos
             pos = _iota(x.shape[1])
-            mask = pos[None, :] <= pos[:, None]
-            ctxv = _weigh(scores, mask[None, None, :, :], vh)
+            return (pos[None, :] <= pos[:, None])[None, None, :, :]
+
+        with sub_scope("attend"):
+            ctxv = _attend(qh, kh, vh, causal, op.scale)
         return op.project_out(weights, ctxv), (kh, vh), pos
 
     def dense_shapes(self, batch, max_length, dtype):
@@ -316,10 +354,11 @@ class PairEntry(EntryKind):
             vcache = jax.lax.dynamic_update_slice(vcache, vh,
                                                   (0, offset, 0, 0))
         with sub_scope("attend"):
-            scores = _scores(qh, kcache, op.scale)
-            qpos = offset + _iota(x.shape[1])
-            mask = _iota(kcache.shape[1])[None, :] <= qpos[:, None]
-            ctxv = _weigh(scores, mask[None, None, :, :], vcache)
+            ctxv = _attend(
+                qh, kcache, vcache,
+                lambda: (_iota(kcache.shape[1])[None, :]
+                         <= (offset + _iota(x.shape[1]))[:, None]
+                         )[None, None, :, :], op.scale)
         return op.project_out(weights, ctxv), (kcache, vcache)
 
 
@@ -864,6 +903,92 @@ class DecayStateEntry(EntryKind):
         return out, (state,)
 
 
+@dataclasses.dataclass(frozen=True)
+class SsmStateEntry(EntryKind):
+    """A state-space op's one row a REQUEST (``ops/mamba2.py``): the
+    float32 state of its heads, ``(H, P, N)``, and the last ``taps - 1``
+    inputs of its convolution, flat, in the rows the pool hands out to
+    every ``per_request`` kind. float32 whatever ``kv_dtype`` says, one
+    token a slot a step, and the tails back through :func:`_spread_rows`,
+    for :class:`StateEntry`'s reasons. A step updates the states where
+    they lie (``ssd_step_rows``: each row takes the inputs of the slot
+    that names it, one elementwise pass over the arena), so the decode
+    program gathers and scatters no state and holds no loop."""
+
+    heads: int
+    head_dim: int
+    state_size: int
+    tail: int          # positions of the convolution's inputs kept
+    channels: int
+    name = "ssm_state"
+    max_window = 1
+    per_request = True
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        return cls(op.num_heads, op.head_dim, op.state_size,
+                   op.conv_taps - 1, op.channels)
+
+    def arenas(self, rows, block_size, dtype):
+        return (jax.ShapeDtypeStruct(
+                    (rows, self.heads, self.head_dim, self.state_size),
+                    jnp.float32),
+                jax.ShapeDtypeStruct((rows, self.tail * self.channels),
+                                     dtype))
+
+    def stats(self):
+        return {"entry": self.name, "state_dtype": "float32"}
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        # the step's one form: the arena updated where it lies
+        return window == 1
+
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        n = x.shape[0]                   # one token a slot: ``max_window``
+        state, tails = entry
+        z, conv_in, dt = op.project(weights, x)
+        with sub_scope("conv"):
+            window = jnp.concatenate(
+                [tails[addr.rows].reshape(n, self.tail, self.channels),
+                 conv_in.astype(tails.dtype)], axis=1)
+            xs, bm, cm = op.split(op.convolve(weights, window))
+        with sub_scope("write"):
+            tails = _spread_rows(tails, addr.rows,
+                                 window[:, 1:].reshape(n, -1))
+        with sub_scope("rule"):
+            dt1 = dt[:, 0]
+            y, state = mamba2.ssd_step_rows(
+                state, addr.rows, xs[:, 0] * dt1[..., None],
+                jnp.exp(dt1 * op.decay_rate(weights)), bm[:, 0], cm[:, 0])
+        return op.finish(weights, z, xs, y[:, None]), (state, tails)
+
+    def prefill(self, op, weights, x, positions, entry, addr, lengths):
+        """The op's chunked whole-sequence form from an empty state: what
+        a prompt of its TRUE length leaves, whatever the bucket, written
+        over the request's row (padding rows over the null row)."""
+        out, state, tail = op.whole(weights, x, lengths)
+        with sub_scope("write"):
+            n = state.shape[0]
+            return out, (entry[0].at[addr.rows].set(state),
+                         entry[1].at[addr.rows].set(
+                             tail.reshape(n, -1).astype(entry[1].dtype)))
+
+    def whole(self, op, weights, x, positions):
+        out, state, tail = op.whole(weights, x)
+        return out, (state, tail), None
+
+    def dense_shapes(self, batch, max_length, dtype):
+        return (jax.ShapeDtypeStruct(
+                    (batch, self.heads, self.head_dim, self.state_size),
+                    jnp.float32),
+                jax.ShapeDtypeStruct((batch, self.tail, self.channels),
+                                     dtype))
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        out, state, tail = op.run(weights, x, *cache)
+        return out, (state, tail.astype(cache[1].dtype))
+
+
 # the kind of each op type that keeps something for a sequence: ``for_op``
 # as :meth:`EntryKind.for_op`
 KINDS: Dict[OpType, Callable[..., EntryKind]] = {
@@ -872,6 +997,7 @@ KINDS: Dict[OpType, Callable[..., EntryKind]] = {
     OpType.GATED_DELTA_NET: StateEntry.for_op,
     OpType.BLOCK_SPARSE_ATTENTION: SparseEntry.for_op,
     OpType.LIGHTNING_ATTENTION: DecayStateEntry.for_op,
+    OpType.MAMBA2: SsmStateEntry.for_op,
 }
 
 
@@ -890,5 +1016,5 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
 
 
 __all__ = ["DecayStateEntry", "EntryKind", "Int8PairEntry", "KINDS",
-           "LatentEntry", "PairEntry", "SparseEntry", "StateEntry", "kind_for",
-           "latent_row_lanes"]
+           "LatentEntry", "PairEntry", "SparseEntry", "SsmStateEntry",
+           "StateEntry", "kind_for", "latent_row_lanes"]

@@ -79,6 +79,13 @@ def _count_up(acc, add):
     return jnp.stack([low, acc[1] + (low < acc[0]).astype(jnp.uint32)])
 
 
+# the key under which a prompt program returns, beside the ids its ops
+# chose, the pairs (live token, pick) whose expert each routed-experts op
+# holds: (ops,) uint32, in the ops' order
+HELD_PAIRS = "#held_pairs"
+_count_up_jit = jax.jit(_count_up, donate_argnums=(0,))
+
+
 def sample_next_token(row_logits: np.ndarray, temperature: float,
                       rng: Optional[np.random.Generator]) -> int:
     """One host-side sampling decision for one request — THE sampling
@@ -556,6 +563,11 @@ class PagedDecoder(_DecodeGraph):
             op.name: jnp.zeros((2, 4 + op.count), jnp.uint32)
             for op in self._expert_ops}
         self._expert_acc_lock = threading.Lock()
+        # the same for the prompt programs: the pairs they named among the
+        # held experts (counted on the device, HELD_PAIRS) and, counted
+        # here from the shapes, the rows their experts' products ran over
+        self._prompt_acc = jnp.zeros((2, len(self._expert_ops)), jnp.uint32)
+        self._prompt_rows_computed = [0] * len(self._expert_ops)
         # the greedy ids the last decode step chose, (slots,) int32 on
         # the device: the next step's ``prev_ids``. Placed as the
         # program places what it returns (replicated over the model's
@@ -714,8 +726,12 @@ class PagedDecoder(_DecodeGraph):
             routed.update({op.name: ids for ids in picked})
             return out
 
-        logits = self._forward_block(params, acts, attn,
-                                     self._routing_kept(routed))
+        held: List[jax.Array] = []
+        logits = self._forward_block(
+            params, acts, attn, self._routing_kept(
+                routed, lambda: positions < lengths[:, None], held))
+        if held:
+            routed[HELD_PAIRS] = jnp.stack(held)
         with fixed_scope("tail"):
             last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
         return last, new_pool, routed
@@ -751,16 +767,36 @@ class PagedDecoder(_DecodeGraph):
         return (logits[:, 0] if head else None), new_pool, routed
 
     @staticmethod
-    def _routing_kept(routed: Dict[str, jax.Array]):
+    def _routing_kept(routed: Dict[str, jax.Array], live=None, held=None):
         """What a prompt program does with a routed-experts op: route,
-        keep the (rows, positions, k) expert ids in ``routed``, apply."""
+        keep the (rows, positions, k) expert ids in ``routed``, apply;
+        with ``held`` a list and ``live()`` giving (rows, positions)
+        bool, append the count of the live tokens' pairs whose expert the
+        op holds."""
         def experts(op, p, x):
             x2d = x.reshape(-1, x.shape[-1])
             ids, gates = op.route(p, x2d)
             routed[op.name] = ids.reshape(x.shape[:2] + (-1,))
+            if held is not None:
+                with fixed_scope("counters"):
+                    mine = (ids >= op.first) & (ids < op.first + op.count)
+                    held.append(jnp.sum(mine & live().reshape(-1, 1),
+                                        dtype=jnp.uint32))
             return op.apply(p, x2d, ids, gates).reshape(x.shape)
 
         return experts
+
+    def _count_prompt_rows(self, rows: int) -> None:
+        """After a prompt program over ``rows`` token rows: its held
+        pairs into the device-side count, its computed rows into the
+        host's."""
+        counts = self.last_routing.pop(HELD_PAIRS, None)
+        if counts is None:
+            return
+        with self._expert_acc_lock:
+            self._prompt_acc = _count_up_jit(self._prompt_acc, counts)
+            for i, op in enumerate(self._expert_ops):
+                self._prompt_rows_computed[i] += op.rows_computed(rows)
 
     def _new_pool(self, num_blocks: int) -> PagedKVPool:
         """A pool of the ops' entries stored as ``kv_dtype`` says, and
@@ -794,16 +830,29 @@ class PagedDecoder(_DecodeGraph):
         if not self._expert_ops:
             return {}
         with self._expert_acc_lock:
-            fetched = jax.device_get(self._expert_acc)
+            fetched, prompt = jax.device_get((self._expert_acc,
+                                              self._prompt_acc))
+            prompt_rows = list(self._prompt_rows_computed)
+        prompt = prompt.astype(np.uint64)
+        prompt = [int(v) for v in (prompt[1] << np.uint64(32)) | prompt[0]]
         out = {}
-        for op in self._expert_ops:
+        for i, op in enumerate(self._expert_ops):
             acc = fetched[op.name].astype(np.uint64)
             acc = [int(v) for v in (acc[1] << np.uint64(32)) | acc[0]]
             out[op.name] = {
                 "held": [op.first, op.count], "n_routed": op.n_routed,
                 "steps": acc[0], "pairs_routed": acc[1],
                 "pairs_held": acc[2], "idle_held_experts": acc[3],
-                "rows_per_held_expert": acc[4:]}
+                "rows_per_held_expert": acc[4:],
+                # how the held experts' products ran: the rows they went
+                # over (from the shapes, by ``op.expert_form``) beside
+                # the rows the routing named (``pairs_held``), a decode
+                # step's and the prompt programs'
+                "form_decode": op.expert_form(self.decode_slots),
+                "rows_computed": acc[0] * op.rows_computed(
+                    self.decode_slots),
+                "prompt_pairs_held": prompt[i],
+                "prompt_rows_computed": prompt_rows[i]}
         return out
 
     def _attention_path(self, window: int) -> str:
@@ -914,6 +963,7 @@ class PagedDecoder(_DecodeGraph):
             logits, self.pool.kv, self.last_routing = fn(
                 self._exec_params(), jnp.asarray(toks), self.pool.kv,
                 self._addresses(tabs), jnp.asarray(lengths))
+            self._count_prompt_rows(toks.size)
         return self._fetch(logits)[:len(arrs)]
 
     def _prefill_in_chunks(self, prompt, table) -> np.ndarray:

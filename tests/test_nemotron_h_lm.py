@@ -1,0 +1,476 @@
+"""State-space layers, latent experts held by share and grouped-head
+attention, at toy widths on the CPU: the program against the plain
+reference (``benchmark/reference/nemotron_h.py``, which imports nothing
+of the program): the ``M`` op's chunked form, its step and the
+token-by-token recurrence; prefill then decode through the pool against
+the reference's full forward; the expert layer's two forms against each
+other and the reference; the choice by ``s + b``; grouped heads through
+``PairEntry`` and the paged kernel; the four shares against the uncut
+layer. The programs compiled for the chip at the published widths are in
+tests/test_tpu_lowering.py."""
+
+import dataclasses
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.families import nemotron_h as family  # noqa: E402
+from benchmark.reference import nemotron_h as reference  # noqa: E402
+from flexflow_tpu import FFConfig, FFModel  # noqa: E402
+from flexflow_tpu.ffconst import CompMode, DataType  # noqa: E402
+from flexflow_tpu.kernels import paged_attention  # noqa: E402
+from flexflow_tpu.models import build_nemotron_h_lm  # noqa: E402
+from flexflow_tpu.ops import mamba2  # noqa: E402
+from flexflow_tpu.serving import cache_entry  # noqa: E402
+from flexflow_tpu.serving.generation import PagedDecoder  # noqa: E402
+
+with open(os.path.join(ROOT, "benchmark", "tests", "data", "configs",
+                       "nemotron-h-toy.json")) as _f:
+    TOY = json.load(_f)
+# the whole toy model: every expert held
+WHOLE = dict(TOY, n_routed_experts=16, expert_first=0)
+SEED = 2 ** 31 + 5
+MAX_LEN = 48
+
+
+def _program(config, seed=SEED, slots=3):
+    """The program's graph for ``config`` in float32 holding the
+    reference's seeded weights; returns (ff, weights)."""
+    cfg = dataclasses.replace(family.program_config(config),
+                              param_dtype=DataType.FLOAT, draw_weights=True)
+    ff = FFModel(FFConfig(batch_size=slots, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    build_nemotron_h_lm(ff, slots, MAX_LEN, cfg)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    weights = reference.init_weights(config, seed)
+    ff.compiled.params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32), family.to_program(weights, config))
+    ff.compiled.bump_params_version()
+    return ff, weights
+
+
+def _op(ff, name):
+    return next(op for op in ff.compiled.ops if op.name == name)
+
+
+def _pieces(config):
+    return reference._pieces(reference._key(config), "float32")
+
+
+def _layer(weights, i):
+    p = f"l{i}."
+    return {k[len(p):]: v for k, v in weights.items() if k.startswith(p)}
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return _program(TOY)
+
+
+# ---- the M op ----------------------------------------------------------------
+
+@pytest.mark.parametrize("length,bucket", [(5, 5), (19, 19), (19, 32),
+                                           (24, 24), (9, 16)])
+def test_mamba_chunked_form_step_and_recurrence_agree(toy, length, bucket):
+    """The chunked whole-sequence form (chunks of 8; lengths that are no
+    multiple of it; a bucket padded past the true length), the one-token
+    step carried from an empty state, and the reference's token-by-token
+    recurrence give the same outputs, and the first two the same state
+    and convolution tail at the prompt's TRUE length. float32 against
+    float32: 2e-5 of the outputs' range is summation order."""
+    ff, weights = toy
+    op = _op(ff, "block1_mixer")
+    w = ff.compiled.params["block1_mixer"]
+    x = jax.random.normal(jax.random.key(length), (1, bucket, 32))
+    # the reference's piece is the whole block: x + mixer(norm(x))
+    lw = _layer(weights, 1)
+    u = reference._rms(x, lw["norm"], 1e-5)
+    want = np.asarray(_pieces(TOY)["mamba"](x[:, :length], lw)
+                      - x[:, :length])
+    y, state, tail = op.whole(w, u, jnp.asarray([length], jnp.int32))
+    tol = 2e-5 * np.abs(want).max()
+    assert np.abs(np.asarray(y)[:, :length] - want).max() <= tol
+    st, tl = op.empty(1, u.dtype)
+    outs = []
+    for t in range(length):
+        o, st, tl = op.run(w, u[:, t:t + 1], st, tl)
+        outs.append(np.asarray(o)[0, 0])
+    assert np.abs(np.stack(outs) - want[0]).max() <= tol
+    assert np.abs(np.asarray(state - st)).max() <= 2e-5 * np.abs(st).max()
+    assert np.array_equal(np.asarray(tail), np.asarray(tl))
+
+
+def test_mamba_step_on_the_arena_rows_is_the_plain_step():
+    """``ssd_step_rows`` (the arena updated where it lies, each row
+    taking the inputs of the slot that names it) against ``ssd_step`` on
+    the gathered rows; the null row and the rows no slot names stay."""
+    rng = np.random.default_rng(0)
+    rows, n, h, p, s, g = 6, 3, 4, 8, 8, 2
+    arena = jnp.asarray(rng.normal(size=(rows, h, p, s)), jnp.float32)
+    slot_rows = jnp.asarray([4, 0, 2], jnp.int32)
+    u = jnp.asarray(rng.normal(size=(n, h, p)), jnp.float32)
+    decay = jnp.asarray(rng.uniform(0.5, 1.0, (n, h)), jnp.float32)
+    bm, cm = (jnp.asarray(rng.normal(size=(n, g, s)), jnp.float32)
+              for _ in range(2))
+    y, new = mamba2.ssd_step_rows(arena, slot_rows, u, decay, bm, cm)
+    want_y, want = mamba2.ssd_step(arena[slot_rows], u, decay, bm, cm)
+    for i, r in enumerate([4, 0, 2]):
+        if r == 0:
+            continue
+        assert np.allclose(y[i], want_y[i], atol=1e-5)
+        assert np.allclose(new[r], want[i], atol=1e-6)
+    for r in (0, 1, 3, 5):
+        assert np.array_equal(np.asarray(new[r]), np.asarray(arena[r]))
+
+
+# ---- the whole model through the pool ---------------------------------------
+
+def _paged_run(dec, names, prompt, steps, slot=0):
+    """Prefill then greedy decode steps in ``slot``; the logits of each
+    step, the token sequence, the routing per expert layer."""
+    n = len(prompt)
+    table = dec.pool.try_admit(n + steps + 1)
+    rows, toks = [dec.prefill(prompt, table)], list(prompt)
+    ids = [[np.asarray(dec.last_routing[nm])[0, :n]] for nm in names]
+    for k in range(steps):
+        toks.append(int(rows[-1].argmax()))
+        tokens = np.zeros(dec.decode_slots, np.int32)
+        tables = np.zeros((dec.decode_slots, dec.max_blocks_per_request),
+                          np.int32)
+        lens = np.zeros(dec.decode_slots, np.int32)
+        tokens[slot], lens[slot] = toks[-1], n + k
+        tables[slot, :len(table)] = table
+        rows.append(dec.decode(tokens, tables, lens)[slot])
+        for j, nm in enumerate(names):
+            ids[j].append(np.asarray(dec.last_routing[nm])[slot:slot + 1])
+    dec.pool.free(table)
+    return (np.stack(rows), np.asarray(toks, np.int32),
+            [np.concatenate(x) for x in ids])
+
+
+@pytest.mark.parametrize("config", [TOY, WHOLE], ids=["share", "whole"])
+def test_paged_prefill_and_decode_equal_the_references_forward(config):
+    """A prompt of 19 tokens in a bucket of 32 (two whole chunks of 8 and
+    a partial one, then padding), then decode steps through the pool (the
+    states stepped where they lie, the ``*`` layer's grouped heads through
+    its block table): the logits of the reference's cache-free forward
+    over the whole sequence, the reference taking the program's routing;
+    and in float32 the two route alike. 2e-4 of the logits' range:
+    float32 summation order over 5 layers."""
+    ff, weights = _program(config)
+    dec = PagedDecoder(ff, MAX_LEN, decode_slots=3, block_size=8,
+                       prefill_buckets=[16, 32])
+    names = family.expert_layer_names(config)
+    prompt = np.random.default_rng(3).integers(
+        0, config["vocab_size"], 19).astype(np.int32)
+    rows, toks, ids = _paged_run(dec, names, prompt, 4, slot=1)
+    logits, info = reference.forward_with_routing(
+        weights, jnp.asarray(toks[None]), config, "float32", routing=ids)
+    want = np.asarray(logits)[0, len(toks) - len(rows):]
+    assert np.abs(rows - want).max() <= 2e-4 * np.abs(want).max()
+    for got, layer in zip(ids, info):
+        assert np.array_equal(np.sort(got, -1),
+                              np.sort(np.asarray(layer["own_ids"]), -1))
+    kv = dec.pool.stats()
+    assert kv["entry"] == {"ssm_state": 2, "pair": 1}
+    assert kv["state_dtype"] == "float32"
+    assert (kv["kv_heads"], kv["query_heads"]) == (2, 4)
+    # a request's row: 2 M layers of a float32 state and a 3-position tail
+    assert kv["state"]["row_bytes"] == 2 * (4 * 8 * 8 * 4 + 3 * 64 * 4)
+    st = dec.expert_stats()
+    assert set(st) == set(names)
+    held = config["n_routed_experts"]
+    for rec in st.values():
+        assert rec["steps"] == 4 and rec["pairs_routed"] == 4 * 4
+        assert sum(rec["rows_per_held_expert"]) == rec["pairs_held"]
+        # the rows computed beside the rows named: a decode step's three
+        # slots through every held expert, the bucket's 32 rows likewise
+        assert rec["form_decode"] == "dense"
+        assert rec["rows_computed"] == 4 * 3 * held
+        assert rec["prompt_rows_computed"] == 32 * held
+        assert 0 <= rec["prompt_pairs_held"] <= 19 * 4
+        if held == 16:
+            assert rec["prompt_pairs_held"] == 19 * 4
+
+
+# ---- the expert layer --------------------------------------------------------
+
+def _expert_case(ff, weights, tokens=40):
+    op = _op(ff, "block0_mixer")
+    w = ff.compiled.params["block0_mixer"]
+    u = jax.random.normal(jax.random.key(1), (tokens, 32))
+    # experts 4-7 are held. Token 0 names none of them; every other token
+    # names one of 4, 5 and 7 (13 rows each: a tile of 16 holds them);
+    # expert 6 gets no row from anyone
+    ids = np.array([[(4, 5, 7)[t % 3], 8 + t % 4, 12 + t % 4, t % 4]
+                    for t in range(tokens)], np.int32)
+    ids[0] = [0, 1, 2, 3]
+    return op, w, u, jnp.asarray(ids)
+
+
+def test_expert_forms_agree_with_each_other_and_the_reference(toy):
+    """Every token through every held expert (``dense``) and the named
+    pairs gathered into a tile an expert (``grouped``) are the
+    reference's sum, for a routing in which one token names no held
+    expert and one held expert gets no row; and where one expert is named
+    more often than its tile holds, the rows beyond it go through a spill
+    tile, and beyond that the grouped form gives the dense form's result
+    (its fallback: nothing is dropped). 1e-5 of the
+    outputs' range: float32 summation order."""
+    ff, weights = toy
+    op, w, u, ids = _expert_case(ff, weights)
+    _, gates = op.route(w, u, ids)
+    v = u @ w["latent_down"]
+    counts = np.bincount(np.asarray(ids).ravel(), minlength=16)[4:8]
+    assert 0 < counts.max() <= op.capacity(40) == 16 and counts[2] == 0
+    dense = np.asarray(op._apply_dense(w, v, ids, gates))
+    grouped = np.asarray(op._apply_grouped(w, v, ids, gates))
+    # expert 5 named by 26 tokens: 10 rows spill into the one spill tile
+    spilled = np.array(ids)
+    spilled[1:27, 0] = 5
+    _, g2 = op.route(w, u, jnp.asarray(spilled))
+    d2 = np.asarray(op._apply_dense(w, v, jnp.asarray(spilled), g2))
+    s2 = np.asarray(op._apply_grouped(w, v, jnp.asarray(spilled), g2))
+    assert op.spill_tiles == 1 and np.abs(d2 - s2).max() <= 1e-5 * np.abs(
+        d2).max() and not np.array_equal(d2, s2)
+    # by all 40: more than the spill tile holds, the dense form's result
+    crowded = jnp.asarray(spilled).at[:, 0].set(5)
+    _, g3 = op.route(w, u, crowded)
+    assert np.array_equal(np.asarray(op._apply_grouped(w, v, crowded, g3)),
+                          np.asarray(op._apply_dense(w, v, crowded, g3)))
+    assert np.abs(dense[0]).max() == 0.0 and np.abs(grouped[0]).max() == 0.0
+    tol = 1e-5 * np.abs(dense).max()
+    assert np.abs(dense - grouped).max() <= tol
+    # the reference's layer less its residual and its shared expert, with
+    # the routed part's weights scaled up (N(0, 0.02) at these widths
+    # leaves it at 3e-6 beside a residual of 3, below float32's
+    # cancellation)
+    big = {"latent_down": "latent_down", "latent_up": "latent_up",
+           "experts.up": "w_up", "experts.down": "w_down"}
+    lw = dict(_layer(weights, 0), norm=jnp.ones(32))
+    lw.update({k: lw[k].astype(jnp.float32) * 8 for k in big})
+    w = dict(w, **{v: w[v] * 8 for v in big.values()})
+    x = u[None]                # the piece norms its input: gain 1 here
+    un = reference._rms(x, lw["norm"], 1e-5)[0]
+    ids_n, gates_n = op.route(w, un, ids)
+    s = jax.nn.sigmoid(un @ w["router"])
+    whole = np.asarray(_pieces(TOY)["expert_ffn"](x, lw, s, ids))[0]
+    shared = np.asarray(reference._relu2_mlp(
+        un, lw["shared.up"], lw["shared.down"], "float32"))
+    got = np.asarray(op.apply(w, un, ids_n, gates_n))
+    want = whole - np.asarray(x[0]) - shared
+    assert np.abs(want).max() > 0.1
+    assert np.abs(got - want).max() <= 2e-5 * np.abs(want).max()
+
+
+def test_the_form_is_a_rule_over_the_shapes_traced():
+    """``expert_form``: a decode step's rows keep the dense form, a
+    prefill's pass the crossover, at the new configuration's shapes and
+    at A.X-K1's; no knob."""
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import OpType
+    from flexflow_tpu.ops.moe_ops import RoutedExperts
+
+    def make(e, **attrs):
+        return RoutedExperts(
+            Layer(OpType.ROUTED_EXPERTS, "x", attrs=attrs),
+            [ParallelTensorShape.unpartitioned((1, 8, e), DataType.FLOAT)])
+
+    nemotron = make(4096, n_routed=512, experts_per_token=22, width=2688,
+                    experts_held=(0, 128), latent=1024, activation="relu2")
+    axk1 = make(7168, n_routed=192, experts_per_token=8, width=2048,
+                experts_held=(0, 12), n_group=8, topk_group=4)
+    for op in (nemotron, axk1):
+        assert op.expert_form(128) == "dense"
+        assert [op.expert_form(b) for b in (768, 1024)] == ["grouped"] * 2
+        assert op.expert_form(240) == "dense"
+    # a tile an expert, a quarter of the rows: six times the mean's pairs
+    assert nemotron.capacity(1024) == 256 and nemotron.capacity(600) == 160
+    assert nemotron.rows_computed(128) == 128 * 128
+    assert (nemotron.spill_tiles, axk1.spill_tiles) == (8, 1)
+    assert nemotron.rows_computed(1024) == (128 + 8) * 256
+    assert axk1.rows_computed(1024) == (12 + 1) * 256
+
+
+def test_the_choice_is_by_biased_scores_and_the_weights_by_plain(toy):
+    """With a bias that lifts the four lowest-scored experts over the
+    rest, the choice (``s + b``) is those four, not the four a choice by
+    ``s`` takes, and the weights are ``s``'s (normalised, times the
+    scale); the reference chooses alike."""
+    ff, weights = toy
+    op = _op(ff, "block0_mixer")
+    w = dict(ff.compiled.params["block0_mixer"])
+    u = jax.random.normal(jax.random.key(2), (6, 32))
+    s = np.asarray(jax.nn.sigmoid(u @ w["router"]))
+    plain = np.sort(np.argsort(-s, -1)[:, :4], -1)
+    ids0, _ = op.route(dict(w, bias=jnp.zeros(16)), u)
+    assert np.array_equal(np.sort(np.asarray(ids0), -1), plain)
+    lowest = np.argsort(s.mean(0))[:4]
+    bias = np.zeros(16, np.float32)
+    bias[lowest] = 2.0
+    ids, gates = op.route(dict(w, bias=jnp.asarray(bias)), u)
+    assert np.array_equal(np.sort(np.asarray(ids), -1),
+                          np.tile(np.sort(lowest), (6, 1)))
+    assert not np.array_equal(np.sort(np.asarray(ids), -1), plain)
+    picked = np.take_along_axis(s, np.asarray(ids), -1)
+    assert np.allclose(gates, 2.5 * picked / picked.sum(-1, keepdims=True),
+                       rtol=1e-5)
+    lw = dict(_layer(weights, 0), bias=jnp.asarray(bias),
+              norm=jnp.ones(32))
+    # the reference norms its input: hand it what norms to ``u``
+    _, own, choice = _pieces(TOY)["scores_of"](u[None] * 1.0, lw)
+    un = reference._rms(u, jnp.ones(32), 1e-5)
+    ids_n, _ = op.route(dict(w, bias=jnp.asarray(bias)), un)
+    assert np.array_equal(np.sort(np.asarray(own), -1),
+                          np.sort(np.asarray(ids_n), -1))
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """The share tied to the model: the routed parts of the four holders
+    (experts 0-3, 4-7, 8-11, 12-15; each after ``W_up``) and the shared
+    expert counted once are the uncut layer, in the reference and in the
+    program alike."""
+    weights = reference.init_weights(WHOLE, SEED)
+    lw = _layer(weights, 0)
+    # scaled up, so that the layer's output stands beside its residual
+    # (N(0, 0.02) at these widths leaves it under float32's cancellation)
+    lw.update({k: lw[k].astype(jnp.float32) * 8 for k in (
+        "latent_down", "latent_up", "experts.up", "experts.down",
+        "shared.up", "shared.down")})
+    x = jax.random.normal(jax.random.key(4), (1, 11, 32))
+    f = _pieces(WHOLE)
+    s, ids, _ = f["scores_of"](x, lw)
+    whole = np.asarray(f["expert_ffn"](x, lw, s, ids) - x)
+    un = reference._rms(x, lw["norm"], 1e-5)[0]
+    shared = np.asarray(reference._relu2_mlp(
+        un, lw["shared.up"], lw["shared.down"], "float32"))
+    parts, program_parts = [], []
+    for first in (0, 4, 8, 12):
+        cfg = dict(TOY, expert_first=first)
+        share = dict(lw, **{k: lw[k][first:first + 4]
+                            for k in ("experts.up", "experts.down")})
+        parts.append(np.asarray(_pieces(cfg)["expert_ffn"](x, share, s, ids)
+                                - x)[0] - shared)
+        ff, _ = _program(cfg)
+        op = _op(ff, "block0_mixer")
+        w = {k: share[v].astype(jnp.float32)
+             for k, v in family._EXPERTS.items()}
+        ids_p, gates_p = op.route(w, un)
+        assert np.array_equal(np.sort(np.asarray(ids_p), -1),
+                              np.sort(np.asarray(ids), -1))
+        program_parts.append(np.asarray(op.apply(w, un, ids_p, gates_p)))
+    assert min(np.abs(p).max() for p in program_parts) > 0.01
+    tol = 2e-5 * np.abs(whole).max()
+    assert np.abs(sum(parts) + shared - whole[0]).max() <= tol
+    assert np.abs(sum(program_parts) + shared - whole[0]).max() <= tol
+
+
+# ---- grouped heads -----------------------------------------------------------
+
+def test_grouped_heads_through_the_pair_entry(toy):
+    """The ``*`` layer (4 query heads on 2 key-value heads): the entry
+    keeps 2 heads' keys and values a token, and prefill then steps
+    through the block tables give the reference's attention over the
+    whole sequence."""
+    ff, weights = toy
+    op = _op(ff, "block4_mixer")
+    kind = cache_entry.kind_for(op, None, MAX_LEN)
+    assert isinstance(kind, cache_entry.PairEntry)
+    assert (kind.heads, kind.head_dim, kind.query_heads) == (2, 8, 4)
+    assert [a.shape for a in kind.arenas(5, 8, jnp.float32)] \
+        == [(5, 8, 16)] * 2
+    assert kind.int8_form.query_heads == 4
+    w = ff.compiled.params["block4_mixer"]
+    lw = dict(_layer(weights, 4), norm=jnp.ones(32))
+    x = jax.random.normal(jax.random.key(7), (1, 13, 32))
+    u = reference._rms(x, lw["norm"], 1e-5)
+    want = np.asarray(_pieces(TOY)["attention"](x, lw) - x)[0]
+    out, _, _ = kind.whole(op, w, u, None)
+    assert np.abs(np.asarray(out)[0] - want).max() <= 2e-5 * np.abs(
+        want).max()
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    entry = tuple(jnp.zeros(a.shape, a.dtype)
+                  for a in kind.arenas(5, 8, jnp.float32))
+    tables = jnp.asarray([[3, 1]], jnp.int32)
+    addr = Addresses(tables, None)
+    out, entry = kind.prefill(op, w, u[:, :9], None, entry, addr,
+                              jnp.asarray([9], jnp.int32))
+    assert np.abs(np.asarray(out)[0] - want[:9]).max() <= 2e-5 * np.abs(
+        want).max()
+    for t in range(9, 13):
+        out, entry = kind.step(op, w, u[:, t:t + 1], None, entry, addr,
+                               jnp.asarray([t], jnp.int32))
+        assert np.abs(np.asarray(out)[0, 0] - want[t]).max() \
+            <= 2e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("window", [1, 2])
+def test_paged_kernel_with_grouped_heads_equals_the_gather(monkeypatch,
+                                                           window):
+    """The paged decode kernel through the Pallas interpreter, 4 query
+    heads on 2 key-value heads of 128 (a head a whole lane tile): the
+    arena's rows are 256 lanes, the group's query heads are rows of one
+    score matrix; against the jnp gather over the same arenas, for slots
+    of different lengths and an idle slot."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    rng = np.random.default_rng(window)
+    n, h, hkv, d, bs, mb, nb = 3, 4, 2, 128, 16, 4, 9
+    q = jnp.asarray(rng.normal(size=(n, window, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(nb, bs, hkv * d)), jnp.float32)
+            for _ in range(2))
+    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [0, 0, 0, 0]],
+                         jnp.int32)
+    lens = jnp.asarray([37, 16, 0], jnp.int32)
+    assert paged_attention.supported(q.shape, k.shape, k.dtype, mb)
+    # a key head a query head at the same widths stays supported, grouped
+    # heads narrower than a lane tile are not
+    assert paged_attention.supported((n, 1, 2, d), k.shape, k.dtype, mb)
+    assert not paged_attention.supported((n, 1, 8, 64), (nb, bs, 4 * 64),
+                                         k.dtype, mb)
+    got = paged_attention.paged_attention_decode(q, k, v, tables, lens,
+                                                 scale=d ** -0.5)
+    kind = cache_entry.PairEntry(hkv, d, h)
+    kk, vv = kind.read((k, v), tables)
+    pos = lens[:, None] + jnp.arange(window)[None, :]
+    mask = jnp.arange(kk.shape[1])[None, None, :] <= pos[:, :, None]
+    want = cache_entry._attend(q, kk, vv, lambda: mask[:, None], d ** -0.5)
+    assert np.abs(np.asarray(got - want))[:2].max() <= 1e-5
+
+
+def test_the_state_kind_refuses_rollback_and_int8_by_name(toy):
+    """A state cannot be rolled back and has no int8 form: speculative
+    windows (so self-drafting) and an int8 pool refuse at construction,
+    naming the ops; and the decode step's two slots-in-flight (a waiting
+    request's row, the row nobody holds) stay as they were."""
+    from flexflow_tpu.serving import GenerationInstance
+
+    ff, _ = toy
+    with pytest.raises(
+            ValueError,
+            match=r"speculative verify over a ssm_state cache entry is not "
+                  r"built \(block1_mixer and 1 more\): serve this model "
+                  r"with spec_k=0"):
+        GenerationInstance(ff, decode_slots=2, block_size=8,
+                           max_length=MAX_LEN, spec_k=2, draft_ff=ff)
+    with pytest.raises(ValueError, match=r"block1_mixer: a ssm_state cache "
+                                         r"entry has no int8 form"):
+        PagedDecoder(ff, MAX_LEN, decode_slots=2, block_size=8,
+                     kv_dtype="int8")
+    kind = cache_entry.SsmStateEntry(4, 8, 8, 3, 64)
+    assert kind.max_window == 1 and kind.int8_form is None
+    assert kind.per_request and not kind.chunked
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        PagedDecoder(ff, MAX_LEN, decode_slots=2, block_size=8,
+                     prefill_chunk=16)

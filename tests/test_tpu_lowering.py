@@ -511,3 +511,138 @@ def test_sparse_hybrid_programs_name_their_pieces(sparse_hybrid_programs,
     # the two layers end in the sparse one: a chunk that computes no head
     # has no use for what it attends, and the compiler drops the walk
     assert (("attention", "attend") in loops) == (name == "chunk_head")
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    """The decode step and the widest prefill of one ``E``, one ``M`` and
+    the ``*`` layer at Nemotron-3-Super's published widths and its cell's
+    sizes (128 slots, contexts to 2,048, blocks of 16, 128 of 512 experts
+    held, a quarter of the vocabulary), compiled for the described chip:
+    {name: (the compiled text, its memory analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import nemotron_h as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3-super-ep4.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=3, hybrid_override_pattern="EM*")
+    slots, max_length = 128, 2048
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[])
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=16, kv_dtype="bfloat16",
+                               calibrate=False, prefill_buckets=[1024])
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            compiled = jax.jit(dec._prefill_step, donate_argnums=(2,)).lower(
+                params, ints(1, 1024), pool, Addresses(ints(1, mb), ints(1)),
+                ints(1)).compile()
+            out["prefill"] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
+    """The decode step of a state-space, a latent-expert and a
+    grouped-head layer holds no ``while``, no ``dynamic-update-slice``
+    and no scatter but the new token's keys and values (the states are
+    stepped where they lie, the tails spread over the arena's 129 rows);
+    its one Mosaic call is the
+    paged kernel over 2 key-value heads of 128 (``attention_path``
+    ``kernel``); the state arena (129 x 128 x 64 x 128 float32, 541 MB)
+    is made by ONE fusion, under the ``M`` op's ``rule``, and both of the
+    op's arenas alias their outputs: nothing beside the weights and the
+    pool but 64 MB."""
+    from flexflow_tpu.core.op import parse_scope
+
+    programs, dec = nemotron_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path == {"decode": "kernel"}
+    assert " while(" not in text
+    assert "dynamic-update-slice" not in text
+    # the only scatter is the ``*`` layer's: a token's keys and values
+    # into its block, as in every pair entry's step
+    for ln in text.splitlines():
+        if " scatter(" in ln:
+            assert "ff.MULTIHEAD_ATTENTION.block2_mixer/write" in ln, ln
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_attention_decode" in text
+    names = _entry_op_names(text)
+    made = {}
+    for ln in _buffers(text):       # a result, or one of a tuple of them
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([a-z][\w\-]*)\(", ln)
+        if (m and "f32[129,128,64,128]" in m.group(2)
+                and m.group(3) not in ("parameter", "bitcast", "copy-done",
+                                       "copy-start", "get-tuple-element",
+                                       "tuple")):
+            made[m.group(1)] = m.group(3)
+    assert list(made.values()) == ["fusion"], made
+    (writer,) = made
+    assert parse_scope(names[writer]) == (
+        "MAMBA2", "block1_mixer", ("rule",), "fwd")
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+
+
+def test_nemotron_programs_name_their_pieces(nemotron_programs):
+    """What the owner table reads: the expert op's ``route``, ``latent``
+    and ``experts`` and the state-space op's ``project``, ``conv``,
+    ``rule`` and ``write`` are in both programs' ``op_name`` paths; the
+    decode step multiplies every slot through every held expert (a
+    (128, 128, 2688) product), the prefill a tile of 256 rows an expert
+    (a quarter of its 1,024), with the dense form behind a conditional
+    for a routing that overflows a tile."""
+    from flexflow_tpu.core.op import parse_scope
+
+    programs, _ = nemotron_programs
+    for name in ("decode", "prefill"):
+        text = programs[name][0]
+        owners = {parse_scope(m) for m in re.findall(
+            r'op_name="([^"]+)"', text)} - {None}
+        subs = {(kind, sub) for kind, _, subs_, _ in owners for sub in subs_}
+        assert {("ROUTED_EXPERTS", "route"), ("ROUTED_EXPERTS", "latent"),
+                ("ROUTED_EXPERTS", "experts"), ("MAMBA2", "project"),
+                ("MAMBA2", "conv"), ("MAMBA2", "rule"),
+                ("MAMBA2", "write")} <= subs, (name, subs)
+    decode, prefill = programs["decode"][0], programs["prefill"][0]
+    assert "[128,128,2688]" in decode and " conditional(" not in decode
+    assert "[128,256,2688]" in prefill and " conditional(" in prefill
+    assert programs["prefill"][1].temp_size_in_bytes < 3 << 30
