@@ -26,11 +26,13 @@ same credit assignment.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 
 from ..ffconst import ActiMode, DataType, OpType
@@ -533,6 +535,36 @@ RIDGE_ROWS = 240
 # of the rows of a call, the share one held expert may be named by before
 # the grouped form gives the call to the dense one
 CAPACITY_SHARE = 4
+# the held experts' own matrices among a routed-experts op's weights
+EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def kernel_form(op, matrices, v, ids, gates):
+    """``op._apply_grouped`` as one kernel call
+    (``kernels/grouped_experts.py``): (the held experts' weighted sum,
+    the rows its products ran over, () uint32). ``matrices`` are the
+    op's ``EXPERT_MATRICES``."""
+    from ..kernels.grouped_experts import grouped_experts
+
+    return grouped_experts(v, ids, gates, matrices, first=op.first,
+                           gated=op.gated)
+
+
+def _kernel_form_fwd(op, *args):
+    return kernel_form(op, *args), args
+
+
+def _kernel_form_bwd(op, args, cotangents):
+    # nothing trains through the kernel yet: the jnp form's gradients
+    # (none for the ids, none from the count of rows)
+    matrices, v, ids, gates = args
+    grads = jax.vjp(lambda m, v, g: op._apply_grouped(m, v, ids, g),
+                    matrices, v, gates)[1](cotangents[0])
+    return grads[0], grads[1], np.zeros(ids.shape, jax.dtypes.float0), grads[2]
+
+
+kernel_form.defvjp(_kernel_form_fwd, _kernel_form_bwd)
 
 
 @register_op
@@ -669,22 +701,37 @@ class RoutedExperts(Op):
         return ((ids - self.first)[..., None]
                 == jnp.arange(self.count, dtype=jnp.int32))
 
-    def expert_form(self, rows: int) -> str:
-        """How :meth:`apply` multiplies ``rows`` tokens: ``"dense"``
-        (every token through every held expert) or ``"grouped"`` (the
-        pairs the routing names, an expert's rows side by side in a tile
-        of :meth:`capacity` rows, what overflows a tile in a few spill
-        tiles). A rule over what a trace sees, no
-        knob: up to ``RIDGE_ROWS`` rows the matrices' bytes decide and
-        the dense form stays (a decode step's slots); past them the
-        products do, and the grouped form multiplies a quarter of the
-        dense one's rows (a prefill's bucket). PERF.md section 6, PR 40,
-        has both forms measured at both."""
-        return "grouped" if rows > RIDGE_ROWS else "dense"
+    def expert_form(self, rows: int, dtype=None, mesh=None) -> str:
+        """How :meth:`apply` multiplies ``rows`` tokens of ``dtype`` (the
+        op's declared one where not given) in a program over ``mesh``:
+        ``"dense"`` (every token through every held expert), ``"kernel"``
+        (the pairs the routing names, in tiles the routing names:
+        ``kernels/grouped_experts.py``) or ``"grouped"`` (the same pairs
+        in jnp: an expert's rows side by side in a tile of
+        :meth:`capacity` rows, what overflows a tile in a few spill
+        tiles). A rule over what a trace sees, no knob: up to
+        ``RIDGE_ROWS`` rows the matrices' bytes decide and the dense form
+        stays (a decode step's slots); past them the products do (a
+        prefill's bucket), in the kernel where Pallas is on, the program
+        is one device's (the kernel has no ``shard_map`` composition:
+        ``kernels.use_pallas``) and its ``supported()`` takes the shapes,
+        else in jnp (the CPU, a mesh, float32 rows, widths of no whole
+        lane tiles, rows past its fast memory). PERF.md section 6, PR 40
+        and PR 41, has the forms measured."""
+        if rows <= RIDGE_ROWS:
+            return "dense"
+        from ..kernels.grouped_experts import supported
+
+        if dtype is None:
+            dtype = self.input_shapes[0].dtype.to_jnp()
+        return "kernel" if (mesh is None or mesh.size == 1) and supported(
+            rows, self.k, self.work_dim, self.width, self.count, self.gated,
+            dtype) else "grouped"
 
     def capacity(self, rows: int) -> int:
-        """Rows of a held expert's tile in the grouped form: a quarter of
-        the call's, whole sublane tiles. The routing names an expert
+        """Rows of a held expert's tile in the jnp grouped form (the
+        kernel has no capacity: its tiles follow the routing): a quarter
+        of the call's, whole sublane tiles. The routing names an expert
         ``rows x k / n_routed`` times on average (a twenty-fourth of the
         rows at 22 of 512 and at 8 of 192), so a tile holds an expert six
         times as full as the mean."""
@@ -692,16 +739,24 @@ class RoutedExperts(Op):
 
     @property
     def spill_tiles(self) -> int:
-        """Tiles of the grouped form beside the held experts' own: what
-        takes the rows an expert is named by beyond its tile (a sixteenth
-        as many as the experts held, one at the least)."""
+        """Tiles of the jnp grouped form beside the held experts' own
+        (the kernel has none): what takes the rows an expert is named by
+        beyond its tile (a sixteenth as many as the experts held, one at
+        the least)."""
         return max(1, self.count // 16)
 
-    def rows_computed(self, rows: int) -> int:
-        """Rows the held experts' products run over for ``rows`` tokens:
+    def rows_computed(self, rows: int, dtype=None,
+                      mesh=None) -> Optional[int]:
+        """Rows the held experts' products run over for ``rows`` tokens
+        by the form :meth:`expert_form` names, where the shapes say them:
         every token an expert in the dense form; a tile an expert and the
-        spill tiles in the grouped one (where they hold what overflows)."""
-        if self.expert_form(rows) == "dense":
+        spill tiles in the jnp grouped one (where they hold what
+        overflows). None for the kernel: its rows follow the routing and
+        are counted on the device (:meth:`apply`'s ``computed``)."""
+        form = self.expert_form(rows, dtype, mesh)
+        if form == "kernel":
+            return None
+        if form == "dense":
             return self.count * rows
         return (self.count + self.spill_tiles) * self.capacity(rows)
 
@@ -818,11 +873,15 @@ class RoutedExperts(Op):
         return jax.lax.cond(spill_ends[-1] <= spill, tiles,
                             lambda: self._apply_dense(weights, v, ids, gates))
 
-    def apply(self, weights, x2d, ids, gates):
+    def apply(self, weights, x2d, ids, gates, computed=None, mesh=None):
         """The held experts' part of the layer for the routing given:
         (T, E) in the activations' dtype, by the form
-        :meth:`expert_form` names for these rows; with ``latent``,
-        between the projection down and the projection up."""
+        :meth:`expert_form` names for these rows in a program over
+        ``mesh``; with ``latent``, between the projection down and the
+        projection up. With ``computed`` a list, the rows the products
+        ran over are appended to it, () uint32: counted on the device by
+        the kernel (real tiles x tile rows), :meth:`rows_computed` for
+        the jnp forms."""
         v = x2d
         if self.latent:
             with sub_scope("latent"):
@@ -830,10 +889,17 @@ class RoutedExperts(Op):
                             preferred_element_type=jnp.float32
                             ).astype(x2d.dtype)
         with sub_scope("experts"):
-            form = (self._apply_grouped
-                    if self.expert_form(x2d.shape[0]) == "grouped"
-                    else self._apply_dense)
-            y = form(weights, v, ids, gates)
+            form = self.expert_form(v.shape[0], v.dtype, mesh)
+            if form == "kernel":
+                y, rows = kernel_form(
+                    self, {name: weights[name] for name in EXPERT_MATRICES
+                           if name in weights}, v, ids, gates)
+            else:
+                y = getattr(self, f"_apply_{form}")(weights, v, ids, gates)
+                rows = jnp.uint32(self.rows_computed(v.shape[0], v.dtype,
+                                                     mesh))
+        if computed is not None:
+            computed.append(rows)
         if self.latent:
             with sub_scope("latent"):
                 y = jnp.dot(y, weights["latent_up"],
@@ -845,15 +911,23 @@ class RoutedExperts(Op):
         (x,) = inputs
         x2d = x.reshape(-1, x.shape[-1])
         ids, gates = self.route(weights, x2d)
-        return [self.apply(weights, x2d, ids, gates).reshape(x.shape)]
+        return [self.apply(weights, x2d, ids, gates,
+                           mesh=getattr(ctx, "mesh", None)).reshape(x.shape)]
 
     def flops(self) -> float:
         t = 1
         for s in self.input_shapes[0].sizes[:-1]:
             t *= s
         mats = 3.0 if self.gated else 2.0
-        # what apply() computes for these rows, by its form
+        # what apply() computes for these rows, by its form (the kernel's
+        # follow the routing: an even one's pairs, and half a tile an
+        # expert left empty)
+        rows = self.rows_computed(t)
+        if rows is None:
+            from ..kernels.grouped_experts import TILE_ROWS
+
+            rows = (t * self.k * self.count // self.n_routed
+                    + self.count * TILE_ROWS // 2)
         return (2.0 * t * self.in_dim * self.n_routed
                 + 4.0 * t * self.in_dim * self.latent
-                + 2.0 * mats * self.rows_computed(t) * self.work_dim
-                * self.width)
+                + 2.0 * mats * rows * self.work_dim * self.width)

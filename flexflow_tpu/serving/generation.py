@@ -72,16 +72,20 @@ def _expert_counts(op, ids, active):
 
 
 def _count_up(acc, add):
-    """``acc`` (2, n) uint32, low words over high words: a 64-bit count
-    in two words, so that a server that never restarts does not wrap
-    (1,024 pairs a step fill 32 bits in 4 M steps)."""
+    """``acc`` (2, ...) uint32, low words over high words (a decode
+    program's (2, 4 + count) an op, the prompt programs' (2, 2, ops)), and
+    ``add`` of the shape of one: a 64-bit count in two words, so that a
+    server that never restarts does not wrap (1,024 pairs a step fill 32
+    bits in 4 M steps)."""
     low = acc[0] + add
     return jnp.stack([low, acc[1] + (low < acc[0]).astype(jnp.uint32)])
 
 
 # the key under which a prompt program returns, beside the ids its ops
-# chose, the pairs (live token, pick) whose expert each routed-experts op
-# holds: (ops,) uint32, in the ops' order
+# chose, (2, ops) uint32 in the ops' order: the pairs (live token, pick)
+# whose expert each routed-experts op holds, over the rows its experts'
+# products ran over (``RoutedExperts.apply``'s ``computed``: the grouped
+# kernel counts them on the device, the jnp forms say them from the shapes)
 HELD_PAIRS = "#held_pairs"
 _count_up_jit = jax.jit(_count_up, donate_argnums=(0,))
 
@@ -299,6 +303,10 @@ class _DecodeGraph:
         head is computed for it alone (returns (B, 1, vocab))."""
         ctx = LowerCtx(mesh=None, training=False, aux_losses=[],
                        compute_dtype=None)
+        # (a routed-experts op picks its form by the model's mesh: its
+        # kernel is one device's)
+        on_mesh = LowerCtx(mesh=self._cm.mesh, training=False,
+                           aux_losses=[], compute_dtype=None)
         positions = (None if self._pos_id is None
                      else acts[self._pos_id.tensor_id])
         for op in self._cm.ops:
@@ -307,11 +315,12 @@ class _DecodeGraph:
             with op_scope(op):
                 if op.name in self._kinds:
                     outs = [attn(op, p, ins[0], positions)]
-                elif (op.op_type is OpType.ROUTED_EXPERTS
-                      and experts is not None):
+                elif op.op_type is not OpType.ROUTED_EXPERTS:
+                    outs = op.forward(ctx, ins, p)
+                elif experts is not None:
                     outs = [experts(op, p, ins[0])]
                 else:
-                    outs = op.forward(ctx, ins, p)
+                    outs = op.forward(on_mesh, ins, p)
             for out, t in zip(outs, op.layer.outputs):
                 acts[t.tensor_id] = out
             if tail is not None and op is self._attn_ops[-1]:
@@ -564,10 +573,10 @@ class PagedDecoder(_DecodeGraph):
             for op in self._expert_ops}
         self._expert_acc_lock = threading.Lock()
         # the same for the prompt programs: the pairs they named among the
-        # held experts (counted on the device, HELD_PAIRS) and, counted
-        # here from the shapes, the rows their experts' products ran over
-        self._prompt_acc = jnp.zeros((2, len(self._expert_ops)), jnp.uint32)
-        self._prompt_rows_computed = [0] * len(self._expert_ops)
+        # held experts over the rows their experts' products ran over
+        # (both counted on the device, HELD_PAIRS), two words each
+        self._prompt_acc = jnp.zeros((2, 2, len(self._expert_ops)),
+                                     jnp.uint32)
         # the greedy ids the last decode step chose, (slots,) int32 on
         # the device: the next step's ``prev_ids``. Placed as the
         # program places what it returns (replicated over the model's
@@ -662,7 +671,8 @@ class PagedDecoder(_DecodeGraph):
             with fixed_scope("counters"):
                 new_acc[op.name] = _count_up(
                     new_acc[op.name], _expert_counts(op, ids, active))
-            return op.apply(p, x2d, ids, gates).reshape(x.shape)
+            return op.apply(p, x2d, ids, gates,
+                            mesh=self._cm.mesh).reshape(x.shape)
 
         logits = self._forward_block(params, acts, attn, experts)[:, -1, :]
         with fixed_scope("sample"):
@@ -727,11 +737,14 @@ class PagedDecoder(_DecodeGraph):
             return out
 
         held: List[jax.Array] = []
+        computed: List[jax.Array] = []
         logits = self._forward_block(
             params, acts, attn, self._routing_kept(
-                routed, lambda: positions < lengths[:, None], held))
+                routed, lambda: positions < lengths[:, None], held,
+                computed))
         if held:
-            routed[HELD_PAIRS] = jnp.stack(held)
+            routed[HELD_PAIRS] = jnp.stack([jnp.stack(held),
+                                            jnp.stack(computed)])
         with fixed_scope("tail"):
             last = logits[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
         return last, new_pool, routed
@@ -766,13 +779,13 @@ class PagedDecoder(_DecodeGraph):
             tail=jnp.maximum(lengths - 1, 0) if head else "skip")
         return (logits[:, 0] if head else None), new_pool, routed
 
-    @staticmethod
-    def _routing_kept(routed: Dict[str, jax.Array], live=None, held=None):
+    def _routing_kept(self, routed: Dict[str, jax.Array], live=None,
+                      held=None, computed=None):
         """What a prompt program does with a routed-experts op: route,
         keep the (rows, positions, k) expert ids in ``routed``, apply;
         with ``held`` a list and ``live()`` giving (rows, positions)
         bool, append the count of the live tokens' pairs whose expert the
-        op holds."""
+        op holds, and to ``computed`` the rows its products ran over."""
         def experts(op, p, x):
             x2d = x.reshape(-1, x.shape[-1])
             ids, gates = op.route(p, x2d)
@@ -782,21 +795,19 @@ class PagedDecoder(_DecodeGraph):
                     mine = (ids >= op.first) & (ids < op.first + op.count)
                     held.append(jnp.sum(mine & live().reshape(-1, 1),
                                         dtype=jnp.uint32))
-            return op.apply(p, x2d, ids, gates).reshape(x.shape)
+            return op.apply(p, x2d, ids, gates, computed,
+                            self._cm.mesh).reshape(x.shape)
 
         return experts
 
-    def _count_prompt_rows(self, rows: int) -> None:
-        """After a prompt program over ``rows`` token rows: its held
-        pairs into the device-side count, its computed rows into the
-        host's."""
+    def _count_prompt_rows(self) -> None:
+        """After a prompt program: its held pairs and the rows its
+        experts computed into the device-side counts."""
         counts = self.last_routing.pop(HELD_PAIRS, None)
         if counts is None:
             return
         with self._expert_acc_lock:
             self._prompt_acc = _count_up_jit(self._prompt_acc, counts)
-            for i, op in enumerate(self._expert_ops):
-                self._prompt_rows_computed[i] += op.rows_computed(rows)
 
     def _new_pool(self, num_blocks: int) -> PagedKVPool:
         """A pool of the ops' entries stored as ``kv_dtype`` says, and
@@ -832,27 +843,35 @@ class PagedDecoder(_DecodeGraph):
         with self._expert_acc_lock:
             fetched, prompt = jax.device_get((self._expert_acc,
                                               self._prompt_acc))
-            prompt_rows = list(self._prompt_rows_computed)
         prompt = prompt.astype(np.uint64)
-        prompt = [int(v) for v in (prompt[1] << np.uint64(32)) | prompt[0]]
+        prompt = ((prompt[1] << np.uint64(32)) | prompt[0]).tolist()
+        dtype = self._compute_dtype()         # None: the graph's own
         out = {}
+        mesh = self._cm.mesh
         for i, op in enumerate(self._expert_ops):
             acc = fetched[op.name].astype(np.uint64)
             acc = [int(v) for v in (acc[1] << np.uint64(32)) | acc[0]]
+            # (None where a decode step's slots are past the ridge and
+            # take the kernel, which no decode program counts)
+            step_rows = op.rows_computed(self.decode_slots, dtype, mesh)
             out[op.name] = {
                 "held": [op.first, op.count], "n_routed": op.n_routed,
                 "steps": acc[0], "pairs_routed": acc[1],
                 "pairs_held": acc[2], "idle_held_experts": acc[3],
                 "rows_per_held_expert": acc[4:],
                 # how the held experts' products ran: the rows they went
-                # over (from the shapes, by ``op.expert_form``) beside
-                # the rows the routing named (``pairs_held``), a decode
-                # step's and the prompt programs'
-                "form_decode": op.expert_form(self.decode_slots),
-                "rows_computed": acc[0] * op.rows_computed(
-                    self.decode_slots),
-                "prompt_pairs_held": prompt[i],
-                "prompt_rows_computed": prompt_rows[i]}
+                # over (a decode step's from the shapes, the prompt
+                # programs' as those counted them) beside the rows the
+                # routing named (``pairs_held``), and by which form
+                # (``op.expert_form``; a prompt's at the widest bucket)
+                "form_decode": op.expert_form(self.decode_slots, dtype,
+                                              mesh),
+                "form_prefill": op.expert_form(self.prefill_buckets[-1],
+                                               dtype, mesh),
+                "rows_computed": (None if step_rows is None
+                                  else acc[0] * step_rows),
+                "prompt_pairs_held": prompt[0][i],
+                "prompt_rows_computed": prompt[1][i]}
         return out
 
     def _attention_path(self, window: int) -> str:
@@ -963,7 +982,7 @@ class PagedDecoder(_DecodeGraph):
             logits, self.pool.kv, self.last_routing = fn(
                 self._exec_params(), jnp.asarray(toks), self.pool.kv,
                 self._addresses(tabs), jnp.asarray(lengths))
-            self._count_prompt_rows(toks.size)
+            self._count_prompt_rows()
         return self._fetch(logits)[:len(arrs)]
 
     def _prefill_in_chunks(self, prompt, table) -> np.ndarray:
@@ -1118,7 +1137,8 @@ class PagedDecoder(_DecodeGraph):
         def experts(op, p, x):
             x2d = x.reshape(-1, x.shape[-1])
             ids, gates = op.route(p, x2d, jnp.asarray(routing[op.name]))
-            return op.apply(p, x2d, ids, gates).reshape(x.shape)
+            return op.apply(p, x2d, ids, gates,
+                            mesh=self._cm.mesh).reshape(x.shape)
 
         logits = self._forward_block(self._exec_params(), acts, attn,
                                      experts if routing else None)

@@ -415,14 +415,18 @@ def test_unknown_layer_type_is_refused():
 # live tokens' pairs whose expert its op holds (``generation.HELD_PAIRS``:
 # the rows named beside the rows computed); grouped heads, the experts'
 # new attributes, their grouped product (which these rows do not reach)
-# and the fourth per-request kind left the other six letter for letter.
+# and the fourth per-request kind left the other six letter for letter;
+# and once more when it took to returning, under the same key, the rows
+# its experts' products ran over beside them (counted on the device where
+# the grouped kernel runs; a (2, ops) array where it was (ops,)): again
+# the one program moved, every decode program among the six that stayed.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
     "gpt.train": "b910d8faaa4dc59157d5baffb29bd2f5ec466eb49c9b8bed2e2fb14878d1376f",
     "hybrid.decode": "1ccca11bf7d46d6b6847716a414a3e25a563e2874e54dbb59ea32f0f8053fd95",
     "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
-    "latent_moe.prefill": "5597b2392ab7be34f39105b80b5368dde1753819a36c1db7429fe44477073f9a",
+    "latent_moe.prefill": "83c9d597439ce9ec12a940c2520a62343025098d238f95a61f5b25c9f4423ff2",
     "latent_moe.train": "ec35820380d8c0377a8b07e79d3248e5c9af80e3156d4e7f49d29b7f78eb7b8f",
 }
 

@@ -4,7 +4,8 @@ reference (``benchmark/reference/nemotron_h.py``, which imports nothing
 of the program): the ``M`` op's chunked form, its step and the
 token-by-token recurrence; prefill then decode through the pool against
 the reference's full forward; the expert layer's two forms against each
-other and the reference; the choice by ``s + b``; grouped heads through
+other and the reference, and the grouped kernel (Pallas interpreter) against
+both; the choice by ``s + b``; grouped heads through
 ``PairEntry`` and the paged kernel; the four shares against the uncut
 layer. The programs compiled for the chip at the published widths are in
 tests/test_tpu_lowering.py."""
@@ -195,7 +196,7 @@ def test_paged_prefill_and_decode_equal_the_references_forward(config):
         assert sum(rec["rows_per_held_expert"]) == rec["pairs_held"]
         # the rows computed beside the rows named: a decode step's three
         # slots through every held expert, the bucket's 32 rows likewise
-        assert rec["form_decode"] == "dense"
+        assert rec["form_decode"] == rec["form_prefill"] == "dense"
         assert rec["rows_computed"] == 4 * 3 * held
         assert rec["prompt_rows_computed"] == 32 * held
         assert 0 <= rec["prompt_pairs_held"] <= 19 * 4
@@ -271,6 +272,228 @@ def test_expert_forms_agree_with_each_other_and_the_reference(toy):
     want = whole - np.asarray(x[0]) - shared
     assert np.abs(want).max() > 0.1
     assert np.abs(got - want).max() <= 2e-5 * np.abs(want).max()
+
+
+# ---- the grouped kernel (kernels/grouped_experts.py), interpreted ------------
+
+def _bf16_experts(activation, e=256, width=128, **attrs):
+    """A routed-experts op over bfloat16 rows at the smallest widths the
+    kernel takes (``work_dim`` 256), experts 4-9 of 16 held, top-4; with
+    seeded weights."""
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import OpType
+    from flexflow_tpu.ops.moe_ops import RoutedExperts
+
+    attrs = dict(dict(n_routed=16, experts_per_token=4, width=width,
+                      experts_held=(4, 6), activation=activation,
+                      routed_scale=2.5), **attrs)
+    op = RoutedExperts(
+        Layer(OpType.ROUTED_EXPERTS, "x", attrs=attrs),
+        [ParallelTensorShape.unpartitioned((1, 8, e), DataType.BFLOAT16)])
+    key = jax.random.key(3)
+    w = {ws.name: (0.08 * jax.random.normal(
+        jax.random.fold_in(key, i), ws.shape)).astype(jnp.bfloat16)
+        for i, ws in enumerate(op.weight_specs())}
+    return op, w
+
+
+def _routing(name, rows):
+    """(rows, 4) expert ids of 16, experts 4-9 held."""
+    t = np.arange(rows)
+    if name in ("uniform", "ragged_rows", "nan_row"):
+        ids = np.stack([(t + 3 * j) % 16 for j in range(4)], 1)
+    elif name == "one_expert_by_all":      # expert 5: 2-3 tiles of its own
+        ids = np.stack([np.full(rows, 5), t % 4, 10 + t % 6,
+                        np.full(rows, 4)], 1)
+    elif name == "held_experts_named_by_none":   # 6, 8 and 9 get no row
+        ids = np.stack([np.full(rows, 4), 5 + 2 * (t % 2), t % 4,
+                        10 + t % 6], 1)
+    else:                                   # no pair held at all
+        ids = np.stack([t % 4, 10 + t % 3, 13 + t % 3, (t + 1) % 4], 1)
+    return jnp.asarray(ids, jnp.int32)
+
+
+def _float32_experts(op, w, v, ids, gates):
+    """The held pairs' weighted sum in float32 numpy, a pair at a time."""
+    w = {k: np.asarray(a, np.float32) for k, a in w.items()}
+    v, ids, gates = (np.asarray(a) for a in (v.astype(jnp.float32), ids,
+                                             gates))
+    out = np.zeros(v.shape, np.float32)
+    for t, e in zip(*np.nonzero((ids >= op.first)
+                                & (ids < op.first + op.count))):
+        c = ids[t, e] - op.first
+        u = v[t] @ w["w_up"][c]
+        if op.gated:
+            g = v[t] @ w["w_gate"][c]
+            h = g / (1 + np.exp(-g)) * u
+        else:
+            h = np.maximum(u, 0) ** 2
+        out[t] += gates[t, e] * (h @ w["w_down"][c])
+    return out
+
+
+@pytest.mark.parametrize("routing", [
+    "uniform", "one_expert_by_all", "held_experts_named_by_none",
+    "no_pair_held", "ragged_rows", "nan_row"])
+@pytest.mark.parametrize("activation", ["relu2", "silu_gated"])
+def test_grouped_kernel_agrees_with_the_dense_form_and_float32(
+        monkeypatch, activation, routing):
+    """The kernel's sum over the held pairs is the dense form's and the
+    float32 reference's, to bfloat16's rounding of the terms (1 % of the
+    outputs' range), for a routing that spreads evenly, one whose every
+    token names one expert (tiles of its own, one after another), one in
+    which half the held experts get no row (no tile), one that names no
+    held expert (exactly 0), rows that fill no whole tile, and two
+    prompts in one call with a NaN in one token's row: that row alone is
+    NaN, and every other row is what it is without the NaN. The rows it
+    counts are the real tiles' (``ceil(n / 128)`` an expert)."""
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    op, w = _bf16_experts(activation)
+    rows = 200 if routing == "ragged_rows" else 256
+    v = jax.random.normal(jax.random.key(5), (rows, 256)).astype(jnp.bfloat16)
+    ids = _routing(routing, rows)
+    _, gates = op.route(w, v, ids)
+    assert kernel.supported(rows, 4, 256, 128, 6, op.gated, v.dtype)
+    got, counted = kernel.grouped_experts(v, ids, gates, w, first=op.first,
+                                          gated=op.gated)
+    assert got.dtype == v.dtype and got.shape == v.shape
+    got = np.asarray(got, np.float32)
+    load = np.bincount(np.asarray(ids).ravel(), minlength=16)[4:10]
+    assert int(counted) == 128 * int(np.ceil(load / 128).sum())
+    dense = np.asarray(op._apply_dense(w, v, ids, gates), np.float32)
+    want = _float32_experts(op, w, v, ids, gates)
+    if routing == "no_pair_held":
+        assert load.sum() == 0 and np.array_equal(got, np.zeros_like(got))
+        assert np.array_equal(dense, got)
+        return
+    scale = np.abs(want).max()
+    assert scale > 0.05
+    assert np.abs(got - want).max() <= 0.01 * scale
+    assert np.abs(got - dense).max() <= 0.01 * scale
+    if routing == "one_expert_by_all":
+        assert load[1] == rows and int(counted) >= 2 * 128
+    if routing == "held_experts_named_by_none":
+        assert (load[[2, 4, 5]] == 0).all()
+    if routing == "nan_row":
+        # token 2 of the second prompt (row 130) names held experts
+        assert load.sum() and ((np.asarray(ids)[130] >= 4)
+                               & (np.asarray(ids)[130] < 10)).any()
+        bad, _ = kernel.grouped_experts(
+            v.at[130, 7].set(jnp.nan), ids, gates, w, first=op.first,
+            gated=op.gated)
+        bad = np.asarray(bad, np.float32)
+        assert np.isnan(bad[130]).all()
+        assert np.array_equal(np.delete(bad, 130, 0), np.delete(got, 130, 0))
+
+
+def test_the_kernel_takes_the_cells_shapes_and_the_jnp_form_the_rest(
+        monkeypatch):
+    """``supported()`` from shapes alone: both routed cells' expert
+    layers at the three buckets (the Nemotron share's matrices whole, the
+    A.X-K1 share's cut along ``width``), not float32 rows, not a
+    ``work_dim`` of odd lane tiles, not A.X-K1's rows at 2,048 (its rows
+    and output alone are past the fast memory); without Pallas nothing.
+    What it refuses runs the jnp grouped form, and says so."""
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    nemotron = (22, 1024, 2688, 128, False, jnp.bfloat16)
+    axk1 = (8, 7168, 2048, 12, True, jnp.bfloat16)
+    for rows in (512, 768, 1024):
+        assert kernel.plan(rows, *nemotron) == 2688
+        assert kernel.plan(rows, *axk1) in (256, 512)
+        assert kernel.supported(rows, *nemotron)
+        assert kernel.supported(rows, *axk1)
+    assert kernel.plan(4096, *nemotron) and not kernel.plan(8192, *nemotron)
+    assert kernel.plan(1536, *axk1) == 128
+    assert kernel.plan(2048, *axk1) is None
+    assert kernel.plan(1024, *nemotron[:-1], jnp.float32) is None
+    assert kernel.plan(1024, 22, 1024 + 128, 2688, 128, False,
+                       jnp.bfloat16) is None
+    assert kernel.plan(1020, *nemotron) is None
+    # the op's rule: the kernel past the ridge where it fits, else jnp
+    op, w = _bf16_experts("relu2")
+    assert [op.expert_form(r) for r in (128, 240, 256)] == [
+        "dense", "dense", "kernel"]
+    assert op.expert_form(256, jnp.float32) == "grouped"
+    # a program over more than one device keeps the jnp form (the kernel
+    # has no shard_map composition); the rows the shapes say are those of
+    # the form that runs, and the kernel's are not the shapes' to say
+    devices = np.array(jax.devices())
+    assert op.expert_form(256, mesh=jax.sharding.Mesh(
+        devices[:1], ("x",))) == "kernel"
+    two = jax.sharding.Mesh(devices[:2], ("x",))
+    assert op.expert_form(256, mesh=two) == "grouped"
+    assert op.rows_computed(256) is None
+    assert op.rows_computed(256, mesh=two) == (6 + 1) * 64
+    assert op.rows_computed(256, jnp.float32) == (6 + 1) * 64
+    assert op.rows_computed(240) == 6 * 240
+    assert op.flops() == op.flops()       # a number where the kernel runs
+    narrow, wn = _bf16_experts("relu2", e=128)
+    assert narrow.expert_form(256) == "grouped"
+    v = jax.random.normal(jax.random.key(6), (256, 128)).astype(jnp.bfloat16)
+    ids = _routing("uniform", 256)
+    _, gates = narrow.route(wn, v, ids)
+    counted = []
+    got = narrow.apply(wn, v, ids, gates, counted)
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(
+        narrow._apply_grouped(wn, v, ids, gates), np.float32))
+    assert [int(c) for c in counted] == [narrow.rows_computed(256)]
+    counted = []
+    v = jax.random.normal(jax.random.key(6), (256, 256)).astype(jnp.bfloat16)
+    _, gates = op.route(w, v, ids)
+    op.apply(w, v, ids, gates, counted)
+    assert [int(c) for c in counted] == [6 * 128]
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    assert not kernel.supported(1024, *nemotron)
+    assert op.expert_form(256) == "grouped"
+    assert op.rows_computed(256) == (6 + 1) * 64
+
+
+@pytest.mark.parametrize("activation", ["relu2", "silu_gated"])
+def test_gradients_through_the_kernel_are_the_jnp_forms(monkeypatch,
+                                                        activation):
+    """``fit`` and the compiler's graph walk reach the kernel through
+    ``op.forward``: 256 bfloat16 rows differentiate (a bare Pallas call
+    with scalar prefetch has no JVP), and the gradients with respect to
+    the rows and to every weight, the router's among them, are the jnp
+    grouped form's; over a mesh of two devices the jnp form runs."""
+    from flexflow_tpu.core.op import LowerCtx
+
+    op, w = _bf16_experts(activation)
+    x = jax.random.normal(jax.random.key(8), (1, 256, 256)
+                          ).astype(jnp.bfloat16)
+    ctx = LowerCtx(mesh=None, training=True, aux_losses=[],
+                   compute_dtype=None)
+
+    def loss(w, x):
+        (y,) = op.forward(ctx, [x], w)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    assert op.expert_form(256) == "grouped"
+    want_loss, want = jax.value_and_grad(loss, (0, 1))(w, x)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert op.expert_form(256) == "kernel"
+    text = jax.make_jaxpr(jax.grad(loss, (0, 1)))(w, x).pretty_print()
+    assert "grouped_experts" in text
+    got_loss, got = jax.jit(jax.value_and_grad(loss, (0, 1)))(w, x)
+    assert abs(float(got_loss) - float(want_loss)) <= 0.01 * float(want_loss)
+    assert set(got[0]) == set(w) and float(want_loss) > 0
+    for g, h in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, h = (np.asarray(a, np.float32) for a in (g, h))
+        assert g.shape == h.shape and np.abs(h).max() > 0
+        # the forward values differ by bfloat16's rounding of the terms,
+        # and the loss's cotangent 2 y with them
+        assert np.abs(g - h).max() <= 0.02 * np.abs(h).max()
+    two = LowerCtx(mesh=jax.sharding.Mesh(np.array(jax.devices()[:2]),
+                                          ("x",)),
+                   training=True, aux_losses=[], compute_dtype=None)
+    assert "grouped_experts" not in jax.make_jaxpr(
+        lambda w, x: op.forward(two, [x], w))(w, x).pretty_print()
 
 
 def test_the_form_is_a_rule_over_the_shapes_traced():

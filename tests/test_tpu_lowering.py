@@ -1,5 +1,5 @@
-"""The fused attention and gated-delta-rule kernels compiled for a
-described (not attached) TPU v5e at real widths: what Mosaic refuses — a block shape off the
+"""The fused attention, gated-delta-rule and grouped-experts kernels
+compiled for a described (not attached) TPU v5e at real widths: what Mosaic refuses — a block shape off the
 tiling, an unsupported relayout, too much VMEM — fails here, on the CPU,
 before any chip time is spent. Nothing runs, so nothing here says
 anything about results or speed.
@@ -81,6 +81,62 @@ def test_flash_attention_compiles_for_v5e(monkeypatch, one_chip,
             assert name in text, (q_shape, name)
         (b, sq, h, _), skv = q_shape, k_shape[1]
         assert f"[{b},{h},{sq},{skv}]" not in text  # the scores of a head
+
+
+@pytest.mark.parametrize("bucket", [512, 768, 1024, "widest"])
+@pytest.mark.parametrize(
+    "cell, picks, work_dim, width, count, gated, widest", [
+        ("nemotron3-super-ep4", 22, 1024, 2688, 128, False, 4096),
+        ("axk1-ep16", 8, 7168, 2048, 12, True, 1536)])
+def test_grouped_experts_compiles_for_v5e(monkeypatch, one_chip,
+                                          no_compile_cache, cell, picks,
+                                          work_dim, width, count, gated,
+                                          widest, bucket):
+    """A prefill's held experts at both routed cells' widths, the three
+    buckets and the widest starting wave ``plan()`` takes (four prompts
+    of 1,024 for the Nemotron share, whose sorted pairs nearly fill the
+    scalar memory; two of 768 for the A.X-K1 share, whose rows and output
+    leave the fast memory room for a weight block of 128 columns and no
+    more): one Mosaic custom call by its name, one sort (the
+    pairs by held expert; no ``argsort`` pair, no stable sort), no
+    ``conditional``, no ``while``, no gather but the table's own
+    lookups, and no buffer a tile an expert or a row a pair: nothing
+    beside the arguments but the packed rows, the float32 output and the
+    table."""
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    if bucket == "widest":
+        bucket = widest
+        assert kernel.plan(2 * bucket, picks, work_dim, width, count, gated,
+                           jnp.bfloat16) is None
+    assert kernel.supported(bucket, picks, work_dim, width, count, gated,
+                            jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    weights = {"w_up": sds((count, work_dim, width)),
+               "w_down": sds((count, width, work_dim))}
+    if gated:
+        weights["w_gate"] = sds((count, work_dim, width))
+    compiled = jax.jit(lambda v, ids, gates, w: kernel.grouped_experts(
+        v, ids, gates, w, first=0, gated=gated)).lower(
+        sds((bucket, work_dim)), sds((bucket, picks), jnp.int32),
+        sds((bucket, picks), jnp.float32), weights).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "grouped_experts" in text
+    assert len(re.findall(r" sort\(", text)) == 1
+    for op in (" conditional(", " while("):
+        assert op not in text, (cell, op)
+    for ln in text.splitlines():      # the table's lookups: no row moves
+        if " gather(" in ln:
+            assert re.match(r"\s*(ROOT )?%?[\w.\-]+ = \w+\[\d+\]\{", ln), ln
+    assert f"[{bucket * picks},{work_dim}]" not in text
+    # the packed rows, the float32 output and its bfloat16 copy
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        8 * bucket * work_dim + (1 << 20))
 
 
 def test_gated_delta_decode_compiles_for_v5e(monkeypatch, one_chip,
@@ -527,6 +583,7 @@ def nemotron_programs(one_chip):
 
     from benchmark.families import nemotron_h as family
     from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
     from flexflow_tpu.ffconst import CompMode
     from flexflow_tpu.serving.generation import PagedDecoder
     from flexflow_tpu.serving.kv_cache import Addresses
@@ -547,7 +604,10 @@ def nemotron_programs(one_chip):
                                   ledger="off", search_cache="off",
                                   computation_mode=CompMode.INFERENCE))
             family.build(ff, config, slots, max_length)
-            ff.compile(optimizer=None, loss_type=None, metrics=[])
+            # one device's model, as on the chip (over the eight virtual
+            # CPU devices' mesh the experts keep their jnp form)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
             dec = PagedDecoder(ff, max_length, decode_slots=slots,
                                block_size=16, kv_dtype="bfloat16",
                                calibrate=False, prefill_buckets=[1024])
@@ -627,9 +687,11 @@ def test_nemotron_programs_name_their_pieces(nemotron_programs):
     and ``experts`` and the state-space op's ``project``, ``conv``,
     ``rule`` and ``write`` are in both programs' ``op_name`` paths; the
     decode step multiplies every slot through every held expert (a
-    (128, 128, 2688) product), the prefill a tile of 256 rows an expert
-    (a quarter of its 1,024), with the dense form behind a conditional
-    for a routing that overflows a tile."""
+    (128, 128, 2688) product); the prefill's held experts are ONE Mosaic
+    call an expert layer, under the op's ``experts`` (the owner table and
+    ``prefill_experts_device_ms.agents`` read it there), with no
+    ``conditional`` in the program and no ``while`` of the experts', no
+    dense form beside it and no float32 buffer a tile an expert."""
     from flexflow_tpu.core.op import parse_scope
 
     programs, _ = nemotron_programs
@@ -644,5 +706,19 @@ def test_nemotron_programs_name_their_pieces(nemotron_programs):
                 ("MAMBA2", "write")} <= subs, (name, subs)
     decode, prefill = programs["decode"][0], programs["prefill"][0]
     assert "[128,128,2688]" in decode and " conditional(" not in decode
-    assert "[128,256,2688]" in prefill and " conditional(" in prefill
+    assert " conditional(" not in prefill
+    # the only loop left is the state-space op's walk over its chunks
+    for ln in prefill.splitlines():
+        if " while(" in ln:
+            assert "ff.MAMBA2." in ln and "/rule/" in ln, ln
+    calls = [ln for ln in prefill.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "grouped_experts" in ln]
+    assert len(calls) == 1          # the fixture's pattern: one E layer
+    (scope,) = re.findall(r'op_name="([^"]+)"', calls[0])
+    assert parse_scope(scope)[:3] == ("ROUTED_EXPERTS", "block0_mixer",
+                                      ("experts",))
+    for gone in ("[128,256,2688]", "[136,256,2688]", "f32[128,1024,2688]",
+                 "f32[1024,128,2688]"):
+        assert gone not in prefill, gone
     assert programs["prefill"][1].temp_size_in_bytes < 3 << 30

@@ -1,20 +1,31 @@
-"""Where the grouped product over named pairs beats every token through
-every held expert: the table behind ``RoutedExperts.expert_form``
-(``ops/moe_ops.py``).
+"""The held experts' three forms side by side: the table behind
+``RoutedExperts.expert_form`` (``ops/moe_ops.py``) and
+``kernels/grouped_experts.py``.
 
     chiprun -- python tools/expert_forms_crossover.py
 
 On the chip only (it exits 2 anywhere else: a CPU timing is no speed).
 For each expert layer in ``LAYERS`` (the Nemotron-3-Super share: 128 of
 512 experts held, top-22, squared ReLU inside a 1024-wide latent; the
-A.X-K1 share: 12 of 192 held, top-8, gated SiLU at 7168) and each count of
-rows in ``ROWS`` (a decode step's 128 slots, the prefill buckets) it
-times ``RoutedExperts.apply`` both ways, bfloat16 weights and rows, a
-uniform random routing: the mean wall time of ``REPEATS`` calls behind
-one warm-up, each waited for (a call is milliseconds, the dispatch some
-tens of microseconds). It also says what ``expert_form`` chooses there
-and the largest difference of the two forms' outputs over their range.
-One JSON line a row on stdout, the table again under
+A.X-K1 share: 12 of 192 held, top-8, gated SiLU at 7168), each count of
+rows in ``ROWS`` (a decode step's 128 slots, the prefill buckets) and two
+routings (``uniform``: the op's own over random rows; ``uneven``: experts
+drawn by a log-normal popularity, ``UNEVEN_SIGMA``, which loads the
+busiest held expert some six times the mean, as the agents cell's random
+weights do) it times, bfloat16 weights and rows: every token through
+every held expert (``dense``), the jnp grouped form (``grouped``) and the
+Pallas kernel (``kernel``; None where ``supported()`` refuses the
+shapes), two ways behind one warm-up: ``<form>_ms``, the mean wall time of
+``REPEATS`` calls each waited for (PR 40's table: it holds, beside the
+device's time, the best part of a millisecond of dispatch and wake-up a
+call), and ``<form>_inflight_ms``, ``REPEATS`` calls dispatched one
+behind another and waited for once (the device's pace, which is what a
+program that holds the call pays: PERF.md section 6, PR 41, has it beside
+the traced time; the jnp grouped form's parts, timed once for PR 41's
+premise, are there too). It says what ``expert_form`` chooses, the rows
+the kernel counted, and the largest difference of the grouped forms'
+outputs from the dense one's over its range. One JSON line a row on
+stdout, the table again under
 ``chiprun_out/expert_forms_crossover.json``. Nothing reads that file:
 ``RIDGE_ROWS`` and ``CAPACITY_SHARE`` are edited by hand from it, and
 PERF.md section 6 keeps the table they were edited from.
@@ -33,6 +44,7 @@ if ROOT not in sys.path:
 
 ROWS = (128, 256, 512, 768, 1024)
 REPEATS = 10
+UNEVEN_SIGMA = 1.0
 LAYERS = {
     "nemotron3-super-ep4": (4096, dict(
         n_routed=512, experts_per_token=22, width=2688,
@@ -42,6 +54,17 @@ LAYERS = {
         n_routed=192, experts_per_token=8, width=2048,
         experts_held=(0, 12), n_group=8, topk_group=4, routed_scale=2.5)),
 }
+
+
+def uneven_ids(key, rows: int, n_routed: int, k: int):
+    """(rows, k) expert ids drawn without replacement by a log-normal
+    popularity an expert (Gumbel top-k)."""
+    import jax
+
+    pop, noise = jax.random.split(key)
+    logits = (UNEVEN_SIGMA * jax.random.normal(pop, (n_routed,))
+              + jax.random.gumbel(noise, (rows, n_routed)))
+    return jax.lax.top_k(logits, k)[1]
 
 
 def main() -> int:
@@ -56,7 +79,19 @@ def main() -> int:
     from flexflow_tpu.core.layer import Layer
     from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
     from flexflow_tpu.ffconst import DataType, OpType
+    from flexflow_tpu.kernels.grouped_experts import grouped_experts
     from flexflow_tpu.ops.moe_ops import RoutedExperts
+
+    def timed(fn, *args):
+        """(the result, ms a call each waited for, ms a call in flight)."""
+        out = jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(REPEATS):
+            jax.block_until_ready(fn(*args))
+        t1 = time.perf_counter()
+        jax.block_until_ready([fn(*args) for _ in range(REPEATS)])
+        return (out, 1e3 * (t1 - t0) / REPEATS,
+                1e3 * (time.perf_counter() - t1) / REPEATS)
 
     table = []
     for name, (e, attrs) in LAYERS.items():
@@ -70,37 +105,55 @@ def main() -> int:
             weights[ws.name] = (0.02 * jax.random.normal(
                 jax.random.fold_in(key, i), ws.shape, jnp.float32)
             ).astype(jnp.bfloat16)
+        forms = {"dense": jax.jit(op._apply_dense),
+                 "grouped": jax.jit(op._apply_grouped),
+                 "kernel": jax.jit(lambda w, v, ids, gates: grouped_experts(
+                     v, ids, gates, w, first=op.first, gated=op.gated))}
+        route = jax.jit(op.route)
         for rows in ROWS:
             x = jax.random.normal(jax.random.fold_in(key, rows), (rows, e),
                                   jnp.float32).astype(jnp.bfloat16)
-            ids, gates = jax.jit(op.route)(weights, x)
             v = x if not op.latent else jnp.dot(
                 x, weights["latent_down"]).astype(jnp.bfloat16)
-            row = {"layer": name, "rows": rows,
-                   "rule": op.expert_form(rows),
-                   "pairs_held": int(np.sum(
-                       (np.asarray(ids) >= op.first)
-                       & (np.asarray(ids) < op.first + op.count)))}
-            outs = {}
-            for form in ("dense", "grouped"):
-                fn = jax.jit(getattr(op, f"_apply_{form}"))
-                try:
-                    outs[form] = jax.block_until_ready(
-                        fn(weights, v, ids, gates))
-                    t0 = time.perf_counter()
-                    for _ in range(REPEATS):
-                        jax.block_until_ready(fn(weights, v, ids, gates))
-                    row[f"{form}_ms"] = 1e3 * (time.perf_counter()
-                                               - t0) / REPEATS
-                except Exception as err:  # noqa: BLE001 — e.g. no memory
-                    row[f"{form}_ms"] = None
-                    row[f"{form}_error"] = str(err).splitlines()[0][:200]
-            if len(outs) == 2:
-                a, b = (np.asarray(o, np.float32) for o in outs.values())
-                row["forms_differ_rel"] = float(
-                    np.abs(a - b).max() / max(np.abs(a).max(), 1e-30))
-            print(json.dumps(row), flush=True)
-            table.append(row)
+            for routing in ("uniform", "uneven"):
+                if routing == "uneven" and rows <= 256:
+                    continue
+                ids, gates = route(weights, x) if routing == "uniform" else (
+                    route(weights, x, uneven_ids(
+                        jax.random.fold_in(key, 7 * rows), rows,
+                        op.n_routed, op.k)))
+                load = np.bincount(np.asarray(ids).ravel(),
+                                   minlength=op.n_routed)[
+                    op.first:op.first + op.count]
+                row = {"layer": name, "rows": rows, "routing": routing,
+                       "rule": op.expert_form(rows),
+                       "pairs_held": int(load.sum()),
+                       "load_max_over_mean": float(
+                           load.max() / max(load.mean(), 1e-30))}
+                outs = {}
+                for form, fn in forms.items():
+                    if form == "kernel" and row["rule"] != "kernel":
+                        row["kernel_ms"] = None
+                        continue
+                    try:
+                        out, row[f"{form}_ms"], row[
+                            f"{form}_inflight_ms"] = timed(fn, weights, v,
+                                                           ids, gates)
+                        if form == "kernel":
+                            out, counted = out
+                            row["kernel_rows"] = int(counted)
+                        outs[form] = np.asarray(out, np.float32)
+                    except Exception as err:  # noqa: BLE001 — e.g. memory
+                        row[f"{form}_ms"] = None
+                        row[f"{form}_error"] = str(err).splitlines()[0][:200]
+                if "dense" in outs:
+                    scale = max(np.abs(outs["dense"]).max(), 1e-30)
+                    for form in ("grouped", "kernel"):
+                        if form in outs:
+                            row[f"{form}_differs_rel"] = float(np.abs(
+                                outs[form] - outs["dense"]).max() / scale)
+                print(json.dumps(row), flush=True)
+                table.append(row)
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "expert_forms_crossover.json"), "w") as f:
