@@ -81,7 +81,7 @@ FIXED_SCOPES = ("loss", "metrics", "optimizer", "sample", "tail", "counters")
 # the pieces of work inside an op that different changes aim at, the same
 # word in every entry kind (serving/cache_entry.py)
 SUB_SCOPES = ("project", "write", "attend", "select", "conv", "rule",
-              "chunks", "route", "latent", "experts")
+              "chunks", "route", "latent", "experts", "gate", "window")
 # the group a metric sums an op type under; a type not named is "other"
 OP_GROUPS: Dict[str, Tuple[OpType, ...]] = {
     # the types a serving program gives a pair, latent or sparse entry kind

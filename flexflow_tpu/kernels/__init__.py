@@ -14,7 +14,13 @@ that keep the working set in VMEM and feed the MXU directly:
   a slot's live blocks only, all heads of a chunk in one matrix product
   (serving/generation.py falls back to its jnp gather for what
   ``supported()`` refuses: int8 arenas, widths that are no whole lane
-  tiles, the CPU).
+  tiles, the CPU). A windowed layer's ring
+  (``serving/cache_entry.py`` ``WindowEntry``) is read by the same
+  kernel: its block table is made in the program from the slots' rows
+  and the lengths are clamped to the ring, so a step reads ``min(n,
+  window)`` rows a slot. No kernel reads a CHUNK's keys yet: a chunked
+  prefill's attention over plain keys and values is a walk over key
+  spans in jnp (``cache_entry._attend_spans``).
 * :mod:`gated_delta` — the gated delta rule of a linear-attention layer:
   the decode step over a pool's per-request states in place, and the
   whole-sequence form of a prefill as one kernel a layer, the state in

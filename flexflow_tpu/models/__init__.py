@@ -1,5 +1,12 @@
 """Model zoo: the reference's example workloads as builder-API definitions
-(reference: examples/cpp/* — SURVEY.md §2.8)."""
+(reference: examples/cpp/* — SURVEY.md §2.8), and the causal LMs serving
+drives: ``gpt``, ``latent_moe``, ``hybrid``, ``sparse_hybrid``,
+``nemotron_h`` and ``trinity`` (``build_trinity_lm``: windowed and full
+attention layers by a ``layer_types`` list, gated grouped heads with
+rotary positions in the windowed layers only, sandwich norms, leading
+dense layers then routed experts beside a shared one; a windowed layer
+keeps a ring of ``window`` rows a request in the paged pool:
+``serving/cache_entry.py`` ``WindowEntry``)."""
 
 from .mlp import build_mlp
 from .alexnet import build_alexnet
@@ -17,6 +24,7 @@ from .latent_moe import build_latent_moe_lm, LatentMoEConfig
 from .hybrid import build_hybrid_lm, HybridLMConfig
 from .sparse_hybrid import build_sparse_hybrid_lm, SparseHybridConfig
 from .nemotron_h import build_nemotron_h_lm, NemotronHConfig
+from .trinity import build_trinity_lm, TrinityConfig
 
 
 def zoo_smoke_builders():
@@ -102,6 +110,13 @@ def zoo_smoke_builders():
             routed_scale=2.0, latent_size=16, expert_width=24,
             shared_width=48))
 
+    def trinity(ff, bs):
+        build_trinity_lm(ff, bs, 32, TrinityConfig(
+            vocab_size=128, hidden_size=32, num_heads=4, num_kv_heads=2,
+            head_dim=16, window=16, num_dense=1, dense_width=64,
+            expert_width=16, n_routed=8, experts_per_token=2,
+            routed_scale=2.448))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -119,4 +134,5 @@ def zoo_smoke_builders():
         "hybrid": hybrid,
         "sparse_hybrid": sparse_hybrid,
         "nemotron_h": nemotron_h,
+        "trinity": trinity,
     }

@@ -81,9 +81,12 @@ class MultiHeadAttention(Op):
         self.dropout = float(a.get("dropout", 0.0))
         self.use_bias = bool(a.get("bias", True))
         # per-head projection sizes (reference: attention.cc qProjSize =
-        # qdim / num_heads)
-        assert self.embed_dim % self.num_heads == 0
-        self.head_dim = self.embed_dim // self.num_heads
+        # qdim / num_heads), or a ``head_dim`` of the op's own
+        if a.get("head_dim"):
+            self.head_dim = int(a["head_dim"])
+        else:
+            assert self.embed_dim % self.num_heads == 0
+            self.head_dim = self.embed_dim // self.num_heads
         # grouped heads: query head h reads key-value head
         # h // (num_heads / num_kv_heads)
         self.num_kv_heads = int(a.get("num_kv_heads") or self.num_heads)
@@ -95,9 +98,23 @@ class MultiHeadAttention(Op):
         self.v_in = input_shapes[2].sizes[-1]
         self.causal = bool(a.get("causal", False))
         # an RMSNorm with a gain over the whole projected q, and one over
-        # the whole projected k, before the heads are split
+        # the whole projected k, before the heads are split; ``"head"``:
+        # over each head's values, one gain of ``head_dim`` for all heads
         self.qk_norm = bool(a.get("qk_norm", False))
+        self.qk_norm_per_head = a.get("qk_norm") == "head"
         self.norm_eps = float(a.get("norm_eps", 1e-6))
+        # a key is seen from the ``window`` positions that end at the
+        # query's own (None: from every later one)
+        self.window = int(a["window"]) if a.get("window") else None
+        if self.window and not self.causal:
+            raise ValueError(f"{self.name}: a window is a causal op's")
+        # rotary positions over the whole head, by theta; the op then
+        # takes the graph's positions as its fourth input
+        self.rotary = float(a["rotary"]) if a.get("rotary") else None
+        self.inv_freq = (rotary_inv_freq(self.head_dim, self.rotary)
+                         if self.rotary else None)
+        # the attended values times sigmoid(x W_g), before W_o
+        self.gate = bool(a.get("gate", False))
         # set by propagate when the strategy sequence-shards this op
         self.seq_axis: str | None = None
         self.seq_mode: str = "ring"  # "ring" | "a2a" (Ulysses)
@@ -126,10 +143,15 @@ class MultiHeadAttention(Op):
         if self.qk_norm:
             gain = (self.attrs.get("gain_initializer")
                     or ConstantInitializer(1.0))
+            per = self.qk_norm_per_head
             specs += [
-                WeightSpec("q_norm", (h, d), dt, gain, weight_decay=False),
-                WeightSpec("k_norm", (hkv, d), dt, gain, weight_decay=False),
+                WeightSpec("q_norm", (d,) if per else (h, d), dt, gain,
+                           weight_decay=False),
+                WeightSpec("k_norm", (d,) if per else (hkv, d), dt, gain,
+                           weight_decay=False),
             ]
+        if self.gate:
+            specs.append(WeightSpec("wg", (self.q_in, h, d), dt, init))
         return specs
 
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
@@ -138,9 +160,10 @@ class MultiHeadAttention(Op):
         return 1.0 / math.sqrt(self.head_dim)
 
     @sub_scope("project")
-    def project_qkv(self, weights, q_in, k_in, v_in):
+    def project_qkv(self, weights, q_in, k_in, v_in, positions=None):
         """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries and the (B,
-        S, Hkv, D) keys and values, biases added."""
+        S, Hkv, D) keys and values, biases added, normed, and with
+        ``rotary`` rotated by ``positions`` (B, S)."""
         qh = jnp.einsum("bse,ehd->bshd", q_in, weights["wq"])
         kh = jnp.einsum("bse,ehd->bshd", k_in, weights["wk"])
         vh = jnp.einsum("bse,ehd->bshd", v_in, weights["wv"])
@@ -151,19 +174,34 @@ class MultiHeadAttention(Op):
         if self.qk_norm:
             qh = self._normed(qh, weights["q_norm"])
             kh = self._normed(kh, weights["k_norm"])
+        if self.rotary:
+            qh = apply_rotary(qh, positions, self.inv_freq)
+            kh = apply_rotary(kh, positions, self.inv_freq)
         return qh, kh, vh
 
     def _normed(self, x, gain):
         """RMSNorm over all heads' values of a position: ``x`` (..., H, D)
-        or packed (..., H D), ``gain`` (H, D)."""
+        or packed (..., H D), ``gain`` (H, D); per head: over each head's
+        D values of ``x`` (..., H, D), ``gain`` (D,)."""
         from .norm import rms_norm
 
+        if self.qk_norm_per_head:
+            return rms_norm(x, gain, self.norm_eps)
         flat = x.reshape(x.shape[:2] + (-1,))
         return rms_norm(flat, gain.reshape(-1), self.norm_eps).reshape(x.shape)
 
+    def project_out(self, weights, ctxv, x=None):
+        """The attended (B, S, H, D) values -> (B, S, E); with ``gate``
+        times ``sigmoid(x W_g)`` first, ``x`` (B, S, E) the op's input."""
+        if self.gate:
+            with sub_scope("gate"):
+                g = jnp.einsum("bse,ehd->bshd", x, weights["wg"])
+                ctxv = (ctxv * jax.nn.sigmoid(g.astype(jnp.float32))
+                        ).astype(x.dtype)
+        return self._project_out(weights, ctxv)
+
     @sub_scope("project")
-    def project_out(self, weights, ctxv):
-        """The attended (B, S, H, D) values -> (B, S, E)."""
+    def _project_out(self, weights, ctxv):
         out = jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
         if self.use_bias:
             out = out + weights["bo"]
@@ -186,10 +224,12 @@ class MultiHeadAttention(Op):
         mesh the kernels do not take (``fa.supported``)."""
         from ..kernels import flash_attention as fa
 
-        q_in, k_in, v_in = inputs
+        q_in, k_in, v_in = inputs[:3]
         h, d = self.num_heads, self.head_dim
         if self.num_kv_heads != h:
             return None               # the kernels take a key head a query head
+        if self.window or self.rotary or self.gate or self.qk_norm_per_head:
+            return None               # nor a band, positions or a gate
         q_shape = q_in.shape[:2] + (h, d)
         k_shape = k_in.shape[:2] + (h, d)
         if not fa.engaged(q_shape[1], k_shape[1], d, self.causal, q_in.dtype):
@@ -237,13 +277,24 @@ class MultiHeadAttention(Op):
             ctxv = fa.sharded_flash_attention(
                 qh, kh, vh, mesh, batch_ax, heads_ax, causal=self.causal,
                 scale=self.scale)
-        return self.project_out(weights, ctxv)
+        return self.project_out(weights, ctxv, q_in)
+
+    def sees(self, qpos, kpos):
+        """Whether a query at ``qpos`` sees a key at ``kpos`` (arrays that
+        broadcast): causal, and inside the window where the op has one."""
+        seen = kpos <= qpos
+        if self.window:
+            seen &= qpos - kpos < self.window
+        return seen
 
     def forward(self, ctx, inputs, weights):
         drop = self.dropout if (ctx.training and ctx.rng is not None) else 0.0
         from ..parallel.ring_attention import ring_attention, single_device_attention
 
         if self.seq_axis is not None and ctx.mesh is not None:
+            if self.window:
+                raise NotImplementedError(
+                    f"{self.name}: a windowed op is not sequence-sharded")
             # sequence parallelism: exact attention over seq-sharded q/k/v.
             # "ring": collective-permute ring over ICI; "a2a": Ulysses
             # all-to-all head resharding (no reference equivalent —
@@ -257,7 +308,7 @@ class MultiHeadAttention(Op):
             with sub_scope("attend"):
                 ctxv = sp(*qkv, ctx.mesh, self.seq_axis, causal=self.causal,
                           scale=self.scale, dropout_rate=drop, rng=ctx.rng)
-            out = self.project_out(weights, ctxv)
+            out = self.project_out(weights, ctxv, inputs[0])
         else:
             # attention dropout keeps the `xla` path: the kernels do not
             # implement it
@@ -269,8 +320,9 @@ class MultiHeadAttention(Op):
                 qkv = (qh,) + self._all_heads(kh, vh)
                 with sub_scope("attend"):
                     ctxv = single_device_attention(
-                        *qkv, self.causal, self.scale, drop, ctx.rng)
-                out = self.project_out(weights, ctxv)
+                        *qkv, self.causal, self.scale, drop, ctx.rng,
+                        self.window)
+                out = self.project_out(weights, ctxv, inputs[0])
         # which implementation this lowering took, counted once per trace:
         # the rule is over shapes, and a chip run has to be able to say
         # which one it timed
@@ -315,8 +367,12 @@ class MultiHeadAttention(Op):
         b, s = self.input_shapes[0].sizes[0], self.input_shapes[0].sizes[1]
         e, h, d = self.embed_dim, self.num_heads, self.head_dim
         # q and o over every head, k and v over the key-value heads
-        proj = 2.0 * b * s * e * d * 2 * (h + self.num_kv_heads)
-        attn = 2.0 * b * h * s * s * d * 2  # logits + context
+        proj = 2.0 * b * s * e * d * (
+            (3 if self.gate else 2) * h + 2 * self.num_kv_heads)
+        # logits + context over the keys a query may see: the square, or
+        # with a window its band
+        keys = min(s, self.window) if self.window else s
+        attn = 2.0 * b * h * s * keys * d * 2
         return proj + attn
 
 

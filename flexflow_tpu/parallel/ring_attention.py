@@ -67,13 +67,18 @@ def _block_attn(q, k, v, m_prev, l_prev, o_prev, mask, dropout_rate=0.0, rng=Non
 
 def single_device_attention(q, k, v, causal: bool, scale: float,
                             dropout_rate: float = 0.0,
-                            rng: Optional[jax.Array] = None):
+                            rng: Optional[jax.Array] = None,
+                            window: Optional[int] = None):
     """Plain scaled-dot-product attention (the n=1 path and the shared
-    implementation for the unsharded MultiHeadAttention lowering)."""
+    implementation for the unsharded MultiHeadAttention lowering). With a
+    ``window`` (a causal op's) a query sees the ``window`` keys that end
+    at its own."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
     if causal:
         Sq, Sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((Sq, Sk), bool))
+        if window:
+            mask &= ~jnp.tril(jnp.ones((Sq, Sk), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     p = _drop(p, dropout_rate, rng)

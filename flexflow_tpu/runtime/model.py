@@ -585,13 +585,25 @@ class FFModel:
         norm_eps: float = 1e-6,
         gain_initializer=None,
         num_kv_heads: Optional[int] = None,
+        head_dim: Optional[int] = None,
+        window: Optional[int] = None,
+        rotary: Optional[float] = None,
+        positions: Optional[Tensor] = None,
+        gate: bool = False,
     ) -> Tensor:
         """reference: FFModel::multihead_attention (model.h:542,
         src/ops/attention.cc — cuDNN multihead attention). ``causal`` is a
         TPU-native extension (the reference has no causal masking), as is
         ``qk_norm``: an RMSNorm with a learned gain over the whole
         projected q and over the whole projected k, before the heads are
-        split."""
+        split (``"head"``: over each head's values, one gain of
+        ``head_dim`` for all heads). Further extensions, each absent by
+        default: ``head_dim`` (a head's width where it is not ``embed_dim
+        / num_heads``), ``window`` (a query sees the ``window`` positions
+        that end at its own), ``rotary`` (theta: q and k are rotated by
+        ``positions``, the graph's int32 (B, S) input, over the whole
+        head), ``gate`` (the attended values times ``sigmoid(query
+        W_g)`` before the output projection)."""
         attrs = dict(
             embed_dim=embed_dim,
             num_heads=num_heads,
@@ -605,16 +617,29 @@ class FFModel:
             causal=causal,
         )
         if qk_norm:
-            attrs.update(qk_norm=True, norm_eps=float(norm_eps),
+            attrs.update(qk_norm="head" if qk_norm == "head" else True,
+                         norm_eps=float(norm_eps),
                          gain_initializer=gain_initializer)
         if num_kv_heads and int(num_kv_heads) != int(num_heads):
             # grouped heads: query head h reads key-value head
             # h // (num_heads / num_kv_heads)
             attrs["num_kv_heads"] = int(num_kv_heads)
+        if head_dim:
+            attrs["head_dim"] = int(head_dim)
+        if window:
+            attrs["window"] = int(window)
+        if gate:
+            attrs["gate"] = True
+        inputs = [query, key, value]
+        if rotary:
+            if positions is None:
+                raise ValueError("rotary positions need the positions input")
+            attrs["rotary"] = float(rotary)
+            inputs.append(positions)
         if strategy:
             attrs["strategy"] = strategy
         return self._infer_and_add(
-            OpType.MULTIHEAD_ATTENTION, [query, key, value], attrs, name
+            OpType.MULTIHEAD_ATTENTION, inputs, attrs, name
         )
 
     def slice_tensor(self, input: Tensor, items, name=None) -> Tensor:

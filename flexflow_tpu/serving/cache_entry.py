@@ -40,7 +40,7 @@ from ..kernels import gated_delta, latent_attention, paged_attention
 from ..ops import block_sparse_attention as bsa
 from ..ops import mamba2
 from ..ops.gated_delta import delta_rule_path
-from .kv_cache import NULL_BLOCK
+from .kv_cache import NULL_BLOCK, NULL_ROW
 
 
 def _iota(n):
@@ -78,6 +78,52 @@ def _attend(q, k, v, mask, scale):
     probs = jax.nn.softmax(jnp.where(mask()[:, :, None], scores, -1e30),
                            axis=-1)
     return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(b, sq, h, d)
+
+
+# keys one step of a chunk's attend scores (:func:`_attend_spans`): the
+# scores of a span are (heads, chunk, SPAN_TOKENS) float32, the only array
+# of two sequence axes a chunk's attention holds
+SPAN_TOKENS = 512
+# the position of a key that holds nothing: later than any query
+NOWHERE = 2 ** 30
+
+
+def _attend_spans(op, q, qpos, kv_heads, read, lo, hi):
+    """A chunk's (B, S, H, D) queries at positions ``qpos`` (B, S) over
+    the key spans ``lo .. hi - 1`` (int32 scalars, traced: the spans
+    outside them are not read at all): ``read(j)`` gives span j's (B, T,
+    Hkv, D) keys and values and the (B, T) positions they hold
+    (:data:`NOWHERE` for a row that holds nothing), and a query sees what
+    ``op.sees`` says. A running maximum and sum in float32, a span at a
+    time, so no (H, S, context) array is made; the ``H / Hkv`` query
+    heads of a group read the one key head where it lies. Returns (B, S,
+    H, D) in the queries' dtype."""
+    b, s, h, d = q.shape
+    g = h // kv_heads
+    qg = q.reshape(b, s, kv_heads, g, d)
+    f32 = jnp.float32
+
+    def body(j, carry):
+        m, l, acc = carry
+        k, v, kpos = read(j)
+        sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                        preferred_element_type=f32) * op.scale
+        seen = op.sees(qpos[:, :, None], kpos[:, None, :])[:, None, None]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(seen, sc, -1e30), axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(sc - m_new[..., None]), 0.0)
+        l = alpha * l + p.sum(-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bhgqk,bkhd->bhgqd", p.astype(v.dtype), v,
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    shape = (b, kv_heads, g, s)
+    _, l, acc = jax.lax.fori_loop(
+        lo, hi, body, (jnp.full(shape, -1e30, f32), jnp.zeros(shape, f32),
+                       jnp.zeros(shape + (d,), f32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]        # (B, Hkv, G, S, D)
+    return jnp.moveaxis(out, 3, 1).reshape(b, s, h, d).astype(q.dtype)
 
 
 def _put(arena, flat, rows):
@@ -204,6 +250,12 @@ class EntryKind:
         one that reads them all."""
         return None
 
+    def rows_read(self, length: int) -> Optional[int]:
+        """Rows of a request a step behind ``length`` cached tokens
+        reads, for a kind that keeps and reads at most a window of them;
+        None for one that keeps them all."""
+        return None
+
     def side_rows(self, length: int) -> int:
         """Rows a request of ``length`` cached tokens holds beside its
         row a token (pooled keys)."""
@@ -228,6 +280,10 @@ class EntryKind:
         if self.chunked:
             return self.chunk(op, weights, x, positions, entry, addr,
                               jnp.zeros_like(lengths), lengths)
+        return self._prefill_whole(op, weights, x, positions, entry, addr,
+                                   lengths)
+
+    def _prefill_whole(self, op, weights, x, positions, entry, addr, lengths):
         out, rows, pos = self.whole(op, weights, x, positions)
         flat = _prefill_slots(addr.tables, lengths, pos, entry[0].shape[1])
         return out, self.write(entry, flat.reshape(-1), *(
@@ -245,15 +301,22 @@ class PairEntry(EntryKind):
     head_dim: int
     query_heads: int = 0
     name = "pair"
+    chunked = True
 
     @classmethod
     def for_op(cls, op, positions_id, max_length):
-        if len({t.tensor_id for t in op.layer.inputs}) != 1 or not op.causal:
+        if (len({t.tensor_id for t in op.layer.inputs[:3]}) != 1
+                or not op.causal):
             raise ValueError(
                 f"{op.name}: generation needs causal SELF-attention")
-        if op.num_kv_heads != op.num_heads:
-            return cls(op.num_kv_heads, op.head_dim, op.num_heads)
-        return cls(op.num_heads, op.head_dim)
+        if op.rotary and op.layer.inputs[3].tensor_id != positions_id:
+            raise ValueError(f"{op.name}: rotary positions have to be the "
+                             f"graph's positions input")
+        grouped = op.num_kv_heads != op.num_heads
+        heads = (op.num_kv_heads, op.head_dim, op.num_heads if grouped else 0)
+        if op.window:
+            return WindowEntry(*heads, op.window)
+        return cls(*heads)
 
     @property
     def int8_form(self):
@@ -293,8 +356,53 @@ class PairEntry(EntryKind):
             (slots, window, self.query_heads or self.heads, self.head_dim),
             entry[0].shape, entry[0].dtype, max_blocks)
 
+    def prefill(self, op, weights, x, positions, entry, addr, lengths):
+        """A bucket's prompts whole (:meth:`whole`), their rows scattered
+        through the tables: a bucket is attended in one piece, as before
+        this kind had a :meth:`chunk`."""
+        return self._prefill_whole(op, weights, x, positions, entry, addr,
+                                   lengths)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        """The chunk's rows written through the tables, then each prompt's
+        blocks attended through its table a span at a time, as far as the
+        longest prompt of the group has got."""
+        qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
+        bs = entry[0].shape[1]
+        n, s = x.shape[:2]
+        heads, hdim = kh.shape[2:]
+        tables = addr.tables
+        mb = tables.shape[1]
+        pos = offsets[:, None] + _iota(s)[None, :]
+        live = _iota(s)[None, :] < lengths[:, None]
+        blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, mb - 1),
+                                  axis=1)
+        flat = jnp.where(live & (pos < mb * bs), blk * bs + pos % bs,
+                         NULL_BLOCK * bs)
+        entry = self.write(entry, flat.reshape(-1),
+                           kh.reshape(n * s, heads, hdim),
+                           vh.reshape(n * s, heads, hdim))
+        per = max(1, SPAN_TOKENS // bs)            # blocks a span
+        span = per * bs
+        padded = jnp.pad(tables, ((0, 0), (0, -mb % per)),
+                         constant_values=NULL_BLOCK)
+        keys, values = entry
+
+        def read(j):
+            blocks = jax.lax.dynamic_slice_in_dim(padded, j * per, per, 1)
+            rows = (n, span, heads, hdim)
+            return (keys[blocks].reshape(rows), values[blocks].reshape(rows),
+                    jnp.broadcast_to(j * span + _iota(span), (n, span)))
+
+        with sub_scope("attend"):
+            ctxv = _attend_spans(
+                op, qh, pos, heads, read, 0,
+                jnp.maximum((jnp.max(offsets + lengths) + span - 1) // span,
+                            1))
+        return op.project_out(weights, ctxv, x), entry
+
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
-        qh, kh, vh = op.project_qkv(weights, x, x, x)
+        qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
         bs = entry[0].shape[1]
         n, w = qh.shape[:2]
         heads, hdim = kh.shape[2:]
@@ -323,20 +431,21 @@ class PairEntry(EntryKind):
                     qh, k, v, lambda: (_iota(k.shape[1])[None, None, :]
                                        <= pos[:, :, None])[:, None, :, :],
                     op.scale)
-        return op.project_out(weights, ctxv), entry
+        return op.project_out(weights, ctxv, x), entry
 
     def whole(self, op, weights, x, positions):
-        qh, kh, vh = op.project_qkv(weights, x, x, x)
+        qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
         pos = None
 
         def causal():
             nonlocal pos
             pos = _iota(x.shape[1])
-            return (pos[None, :] <= pos[:, None])[None, None, :, :]
+            return op.sees(kpos=pos[None, :],
+                           qpos=pos[:, None])[None, None, :, :]
 
         with sub_scope("attend"):
             ctxv = _attend(qh, kh, vh, causal, op.scale)
-        return op.project_out(weights, ctxv), (kh, vh), pos
+        return op.project_out(weights, ctxv, x), (kh, vh), pos
 
     def dense_shapes(self, batch, max_length, dtype):
         a = jax.ShapeDtypeStruct(
@@ -344,7 +453,7 @@ class PairEntry(EntryKind):
         return (a, a)
 
     def dense_step(self, op, weights, x, positions, cache, offset):
-        qh, kh, vh = op.project_qkv(weights, x, x, x)
+        qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
         kcache, vcache = cache
         with sub_scope("write"):
             # dynamic_update_slice keeps the shape static; unwritten and
@@ -356,10 +465,10 @@ class PairEntry(EntryKind):
         with sub_scope("attend"):
             ctxv = _attend(
                 qh, kcache, vcache,
-                lambda: (_iota(kcache.shape[1])[None, :]
-                         <= (offset + _iota(x.shape[1]))[:, None]
-                         )[None, None, :, :], op.scale)
-        return op.project_out(weights, ctxv), (kcache, vcache)
+                lambda: op.sees(kpos=_iota(kcache.shape[1])[None, :],
+                                qpos=(offset + _iota(x.shape[1]))[:, None]
+                                )[None, None, :, :], op.scale)
+        return op.project_out(weights, ctxv, x), (kcache, vcache)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -372,6 +481,7 @@ class Int8PairEntry(PairEntry):
 
     name = "int8"
     int8_form = None
+    chunked = False        # a chunk's attend reads the arenas as they are
 
     def arenas(self, num_blocks, block_size, dtype):
         a = jax.ShapeDtypeStruct(
@@ -396,6 +506,135 @@ class Int8PairEntry(PairEntry):
         view = lambda a: self._view(a, tables)  # noqa: E731
         return (view(kq).astype(jnp.float32) * view(ks) + view(kz),
                 view(vq).astype(jnp.float32) * view(vs) + view(vz))
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowEntry(PairEntry):
+    """The pair of an op with a ``window``: a request keeps the last
+    ``window`` tokens' keys and values and no more, in a ring of its own.
+    A ``per_request`` kind whose arenas are rows of ``window /
+    block_size`` blocks in the pair layout: position p lies in block ``(p
+    // block_size) % ring`` of the request's row at offset ``p %
+    block_size``, i.e. at row ``p % window`` of its ring, where position
+    ``p - window`` lay before it. Keys are stored rotated, so where a row
+    lies is free; a query at position p sees exactly ``p - window + 1 ..
+    p``, which after its own row is written is all the ring holds. The
+    ring's block table is made inside the programs from ``addr.rows``, so
+    the step's kernel and the gather read it as they read a pair's, with
+    lengths clamped to the ring. A whole ring is reserved whatever the
+    request's length (``rows_read`` and the pool's bytes say what that
+    costs); a step takes one token a slot, and there is no int8 form."""
+
+    window: int = 0
+    name = "window"
+    max_window = 1
+    per_request = True
+    int8_form = None
+
+    def stats(self):
+        return dict(super().stats(), entry=self.name, window=self.window)
+
+    def rows_read(self, length):
+        return min(int(length) + 1, self.window)
+
+    def ring_blocks(self, block_size: int) -> int:
+        if self.window % block_size:
+            raise ValueError(
+                f"a window of {self.window} tokens is no whole blocks of "
+                f"{block_size}: the ring is made of the pool's blocks")
+        return self.window // block_size
+
+    def arenas(self, rows, block_size, dtype):
+        a = jax.ShapeDtypeStruct(
+            (rows * self.ring_blocks(block_size), block_size,
+             self.heads * self.head_dim), dtype)
+        return (a, a)
+
+    def _tables(self, entry, rows):
+        """The block table of each request's ring, (N, ring)."""
+        ring = self.ring_blocks(entry[0].shape[1])
+        return rows[:, None] * ring + _iota(ring)[None, :]
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return window == 1 and paged_attention.supported(
+            (slots, 1, self.query_heads or self.heads, self.head_dim),
+            entry[0].shape, entry[0].dtype,
+            self.ring_blocks(entry[0].shape[1]))
+
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
+        n = x.shape[0]                   # one token a slot: ``max_window``
+        w = self.window
+        # an idle slot's row is the null row: its ring takes the write
+        entry = self.write(entry, addr.rows * w + seq_lens % w,
+                           kh[:, 0], vh[:, 0])
+        tables = self._tables(entry, addr.rows)
+        held = jnp.minimum(seq_lens, w - 1)     # rows before the new one
+        with sub_scope("attend"), sub_scope("window"):
+            if self.reads_in_place(op, entry, n, 1, 0):
+                ctxv = paged_attention.paged_attention_decode(
+                    qh, entry[0], entry[1], tables, held,
+                    scale=op.scale).astype(qh.dtype)
+            else:
+                k, v = self.read(entry, tables)             # (n, W, H, D)
+                ctxv = _attend(
+                    qh, k, v, lambda: (_iota(w)[None, None, :]
+                                       <= held[:, None, None])[:, None, :, :],
+                    op.scale)
+        return op.project_out(weights, ctxv, x), entry
+
+    def prefill(self, op, weights, x, positions, entry, addr, lengths):
+        return self.chunk(op, weights, x, positions, entry, addr,
+                          jnp.zeros_like(lengths), lengths)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        """``[what the ring holds | the chunk]`` attended by absolute
+        position, the chunk written afterwards (it overwrites rows its own
+        early queries still read): of a chunk longer than the ring, the
+        last ``window`` live rows."""
+        qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
+        n, s = x.shape[:2]
+        heads, hdim = kh.shape[2:]
+        w = self.window
+        pos = offsets[:, None] + _iota(s)[None, :]
+        live = _iota(s)[None, :] < lengths[:, None]
+        # ring row r holds the last position before the chunk that is r
+        # modulo the window, if the request has got that far
+        before = offsets[:, None] - 1
+        held = before - jnp.mod(before - _iota(w)[None, :], w)
+        # (a request's ring is taken in the arena's own layout, rows of
+        # all heads side by side: splitting the heads first would copy
+        # the whole arena into another tiling)
+        rings = tuple(a.reshape(-1, w, heads * hdim)[addr.rows].reshape(
+            n, w, heads, hdim) for a in entry)
+        span = SPAN_TOKENS
+        pad = -(w + s) % span
+        keys, values = (jnp.pad(jnp.concatenate([ring, new.astype(ring.dtype)],
+                                                axis=1),
+                                ((0, 0), (0, pad), (0, 0), (0, 0)))
+                        for ring, new in zip(rings, (kh, vh)))
+        kpos = jnp.pad(jnp.concatenate(
+            [jnp.where(held >= 0, held, NOWHERE),
+             jnp.where(live, pos, NOWHERE)], axis=1), ((0, 0), (0, pad)),
+            constant_values=NOWHERE)
+
+        def read(j):
+            return tuple(jax.lax.dynamic_slice_in_dim(a, j * span, span, 1)
+                         for a in (keys, values, kpos))
+
+        with sub_scope("attend"), sub_scope("window"):
+            # a group of first chunks skips the ring, a short last chunk
+            # the spans past its length
+            ctxv = _attend_spans(
+                op, qh, pos, heads, read,
+                jnp.where(jnp.all(offsets == 0), w // span, 0),
+                (w + jnp.max(lengths) + span - 1) // span)
+        keep = live & (pos >= (offsets + lengths)[:, None] - w)
+        flat = jnp.where(keep, addr.rows[:, None], NULL_ROW) * w + pos % w
+        entry = self.write(entry, flat.reshape(-1),
+                           kh.reshape(n * s, heads, hdim),
+                           vh.reshape(n * s, heads, hdim))
+        return op.project_out(weights, ctxv, x), entry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1017,4 +1256,4 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
 
 __all__ = ["DecayStateEntry", "EntryKind", "Int8PairEntry", "KINDS",
            "LatentEntry", "PairEntry", "SparseEntry", "SsmStateEntry",
-           "StateEntry", "kind_for", "latent_row_lanes"]
+           "StateEntry", "WindowEntry", "kind_for", "latent_row_lanes"]

@@ -543,12 +543,6 @@ class PagedDecoder(_DecodeGraph):
         # from what the chunks before left (None: a whole prompt a bucket)
         self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
         if self.prefill_chunk:
-            whole = [name for name, k in self._kinds.items() if not k.chunked]
-            if whole:
-                raise ValueError(
-                    f"prefill_chunk: a {self._kinds[whole[0]].name} cache "
-                    f"entry prefills a prompt whole, it does not continue "
-                    f"from a chunk ({whole[0]} and {len(whole) - 1} more)")
             if self.prefill_chunk % self.block_size:
                 raise ValueError(
                     f"prefill_chunk {self.prefill_chunk} is not a multiple "
@@ -563,6 +557,16 @@ class PagedDecoder(_DecodeGraph):
                           + 1)
         self.kv_dtype = str(kv_dtype)
         self.pool = self._new_pool(int(num_blocks))
+        if self.prefill_chunk:
+            # (the kinds as the pool stores them: a pair takes chunks,
+            # its int8 form does not)
+            kinds = self.pool.kinds
+            whole = [name for name, k in kinds.items() if not k.chunked]
+            if whole:
+                raise ValueError(
+                    f"prefill_chunk: a {kinds[whole[0]].name} cache "
+                    f"entry prefills a prompt whole, it does not continue "
+                    f"from a chunk ({whole[0]} and {len(whole) - 1} more)")
         # one small accumulator for each routed-experts op (_count_up),
         # donated to the decode program beside the pool and returned by
         # it: counted on the device, fetched only by expert_stats(). The
@@ -740,7 +744,7 @@ class PagedDecoder(_DecodeGraph):
         computed: List[jax.Array] = []
         logits = self._forward_block(
             params, acts, attn, self._routing_kept(
-                routed, lambda: positions < lengths[:, None], held,
+                routed, lambda s: positions < lengths[:, None], held,
                 computed))
         if held:
             routed[HELD_PAIRS] = jnp.stack([jnp.stack(held),
@@ -774,17 +778,32 @@ class PagedDecoder(_DecodeGraph):
             routed.update({op.name: ids for ids in picked})
             return out
 
+        held: List[jax.Array] = []
+        computed: List[jax.Array] = []
         logits = self._forward_block(
-            params, acts, attn, self._routing_kept(routed),
+            # (behind the tail's cut a row is its last live position)
+            params, acts, attn, self._routing_kept(
+                routed, lambda s: (
+                    positions < (offsets + lengths)[:, None]
+                    if s == positions.shape[1]
+                    else jnp.ones((positions.shape[0], s), bool)), held,
+                computed),
             tail=jnp.maximum(lengths - 1, 0) if head else "skip")
+        if held:
+            # (a chunk that is not its prompt's last ends behind the last
+            # attention op: the expert layers after it did not run)
+            rest = [jnp.zeros((), jnp.uint32)] * (len(self._expert_ops)
+                                                  - len(held))
+            routed[HELD_PAIRS] = jnp.stack([jnp.stack(held + rest),
+                                            jnp.stack(computed + rest)])
         return (logits[:, 0] if head else None), new_pool, routed
 
     def _routing_kept(self, routed: Dict[str, jax.Array], live=None,
                       held=None, computed=None):
         """What a prompt program does with a routed-experts op: route,
         keep the (rows, positions, k) expert ids in ``routed``, apply;
-        with ``held`` a list and ``live()`` giving (rows, positions)
-        bool, append the count of the live tokens' pairs whose expert the
+        with ``held`` a list and ``live(positions)`` giving (rows,
+        positions) bool, append the count of the live tokens' pairs whose expert the
         op holds, and to ``computed`` the rows its products ran over."""
         def experts(op, p, x):
             x2d = x.reshape(-1, x.shape[-1])
@@ -793,7 +812,8 @@ class PagedDecoder(_DecodeGraph):
             if held is not None:
                 with fixed_scope("counters"):
                     mine = (ids >= op.first) & (ids < op.first + op.count)
-                    held.append(jnp.sum(mine & live().reshape(-1, 1),
+                    held.append(jnp.sum(mine
+                                        & live(x.shape[1]).reshape(-1, 1),
                                         dtype=jnp.uint32))
             return op.apply(p, x2d, ids, gates, computed,
                             self._cm.mesh).reshape(x.shape)
@@ -1028,6 +1048,7 @@ class PagedDecoder(_DecodeGraph):
                 self._exec_params(), jnp.asarray(toks), self.pool.kv,
                 self._addresses(tabs), jnp.asarray([offset], jnp.int32),
                 jnp.asarray([part.shape[0]], jnp.int32))
+            self._count_prompt_rows()
         return self._fetch(logits)[0] if last else None
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
@@ -1178,8 +1199,17 @@ class PagedDecoder(_DecodeGraph):
             # the experts' routing alone: a selecting attention op's
             # picks are its own business
             experts = [op.name for op in self._expert_ops]
-            routed = {k: [np.asarray(self.last_routing[k])[0, :prompt_len]]
-                      for k in experts}
+
+            def every_row(ids):
+                """(a chunked prompt's last layer routes its last
+                position alone, the head's: the rows before it are read
+                by nothing, and take its ids)"""
+                return np.concatenate(
+                    [np.repeat(ids[:1], prompt_len - len(ids), axis=0), ids])
+
+            routed = {k: [every_row(
+                np.asarray(self.last_routing[k])[0, :prompt_len])]
+                for k in experts}
             toks = np.zeros(self.decode_slots, np.int32)
             toks[0] = nxt
             tabs = np.full((self.decode_slots, self.max_blocks_per_request),

@@ -140,7 +140,10 @@ class _LoopClock:
         self.token_gap = Histogram()
         self.ahead = {"steps_ahead": 0, "steps_sync": 0, "rows_dropped": 0}
         # prompts prefilled in chunks: the chunks run, and their tokens
-        self.chunks = {"prefill_chunks": 0, "prefill_tokens": 0}
+        # (and the keys those tokens' queries saw in an op that keeps
+        # everything, and in one that keeps a window)
+        self.chunks = {"prefill_chunks": 0, "prefill_tokens": 0,
+                       "prefill_keys": 0, "prefill_keys_window": 0}
 
     def enter(self, phase: Optional[str]):
         """The thread is in ``phase`` from now on. Returns the boundary
@@ -168,10 +171,13 @@ class _LoopClock:
         with self._lock:
             self.ahead[key] += n
 
-    def count_chunk(self, tokens: int) -> None:
+    def count_chunk(self, tokens: int, keys: int = 0,
+                    window_keys: int = 0) -> None:
         with self._lock:
             self.chunks["prefill_chunks"] += 1
             self.chunks["prefill_tokens"] += tokens
+            self.chunks["prefill_keys"] += keys
+            self.chunks["prefill_keys_window"] += window_keys
 
     def snapshot(self) -> Dict:
         """``stats()["loop"]``; the phase that is open is charged up to
@@ -352,6 +358,13 @@ class ContinuousBatchingScheduler:
             (k for k in kinds if k.blocks_read(0) is not None), None)
         self._selected_read = 0
         self._selected_live = 0
+        # a kind that keeps and reads at most a window of a request's rows
+        # (its ops share one window): what the steps read of it, beside
+        # what ops that keep everything would, and what it reserves
+        self._windowed = next(
+            (k for k in kinds if k.rows_read(0) is not None), None)
+        self._window_rows = {"rows_read": 0, "rows_full": 0,
+                             "rows_reserved": 0}
         # requests admitted whose prompts are still being prefilled in
         # chunks, oldest first (the loop's thread alone touches it)
         self._prefilling: collections.deque = collections.deque()
@@ -742,7 +755,12 @@ class ContinuousBatchingScheduler:
             if not req.future.done():
                 req.future.set_exception(e)
             return
-        self._clock.count_chunk(n)
+        # a query at position p sees p + 1 keys, or a window's worth
+        seen = np.arange(at + 1, at + n + 1, dtype=np.int64)
+        self._clock.count_chunk(
+            n, int(seen.sum()),
+            0 if self._windowed is None
+            else int(np.minimum(seen, self._windowed.window).sum()))
         with self._mu:
             self._prefill_dispatches += 1
             if last:
@@ -969,6 +987,12 @@ class ContinuousBatchingScheduler:
                         self._selected_read += self._selecting.blocks_read(
                             req.seq_len)
                         self._selected_live += (req.seq_len + bs) // bs
+                    if self._windowed is not None:
+                        wr = self._window_rows
+                        wr["rows_read"] += self._windowed.rows_read(
+                            req.seq_len)
+                        wr["rows_full"] += req.seq_len + 1
+                        wr["rows_reserved"] += self._windowed.window
                 self._blocks_in_tables += len(active) * tables.shape[1]
                 self._rows_stepped += len(active) * self._state_ops
         return active, tokens, tables, seq_lens
@@ -1411,6 +1435,7 @@ class ContinuousBatchingScheduler:
             rows_stepped = self._rows_stepped
             selected = {"blocks_read": self._selected_read,
                         "blocks_live": self._selected_live}
+            window_rows = dict(self._window_rows)
             lengths = [r.seq_len for r in self._slots if r is not None]
         now = time.perf_counter()
         tps = (tokens / (now - t_start)
@@ -1426,6 +1451,17 @@ class ContinuousBatchingScheduler:
             kv["selected"] = selected
             kv["kernel_rows"] = sum(self._selecting.side_rows(n)
                                     for n in lengths)
+        if self._windowed is not None:
+            # one windowed op's rows over the decode steps' active slots
+            # (read, what an op that keeps everything would have read,
+            # the rings reserved), and the rows the requests in their
+            # slots hold now
+            kv["window"] = dict(
+                window_rows, rows=self._windowed.window,
+                ops=sum(k.rows_read(0) is not None
+                        for k in self.decoder.pool.kinds.values()),
+                rows_held=sum(min(n, self._windowed.window)
+                              for n in lengths))
         if "state" in kv:
             kv["state"]["rows_stepped"] = rows_stepped
             kv["state"]["prefill_path"] = self.decoder.prefill_path

@@ -420,14 +420,24 @@ def test_unknown_layer_type_is_refused():
 # its experts' products ran over beside them (counted on the device where
 # the grouped kernel runs; a (2, ops) array where it was (ops,)): again
 # the one program moved, every decode program among the six that stayed.
+# The three ``nemotron_h``, ``hybrid.prefill`` and ``sparse_hybrid``
+# digests were recorded on 8791b58, the commit before an attention op
+# took a window, rotary positions, a head size of its own, a per-head
+# norm and a gate, and before a pair took chunks: each of those is absent
+# by default, and every program of the older configurations stayed.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
     "gpt.train": "b910d8faaa4dc59157d5baffb29bd2f5ec466eb49c9b8bed2e2fb14878d1376f",
     "hybrid.decode": "1ccca11bf7d46d6b6847716a414a3e25a563e2874e54dbb59ea32f0f8053fd95",
+    "hybrid.prefill": "5dd6b2309d3ad4c5946d040785336ddaedeae8f84a69854a1c0fb4a3e549f238",
     "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
     "latent_moe.prefill": "83c9d597439ce9ec12a940c2520a62343025098d238f95a61f5b25c9f4423ff2",
     "latent_moe.train": "ec35820380d8c0377a8b07e79d3248e5c9af80e3156d4e7f49d29b7f78eb7b8f",
+    "nemotron_h.decode": "ed5434d26c53e5cf2063448a886f150ff3ed50f02420462142a94f76b141fc17",
+    "nemotron_h.prefill": "edd55bfd742698da21c788cb1971a1d66e73b3212926047075cc416371f70d1a",
+    "sparse_hybrid.decode": "83d1e616746ba634c9f0aca6072617b5fd0ba086364cd3849a57b2606898ce18",
+    "sparse_hybrid.prefill": "8af30f7980a5ae6c8d6bc03e7fc11a1bf8b0b63173f63adc8bc10cad4bb2f962",
 }
 
 
@@ -459,7 +469,9 @@ def _lowered(name: str) -> str:
                           computation_mode=CompMode.INFERENCE))
     build(ff, 2)
     ff.compile(optimizer=None, loss_type=None, metrics=[])
-    dec = PagedDecoder(ff, 32, decode_slots=2, block_size=8,
+    # (a sparse entry's pool takes its selection's block and no other)
+    dec = PagedDecoder(ff, 32, decode_slots=2,
+                       block_size=4 if model == "sparse_hybrid" else 8,
                        prefill_buckets=[16])
     lens = jax.ShapeDtypeStruct((2,), jnp.int32)
 

@@ -39,10 +39,13 @@ class _Op:
     v directly."""
     use_bias = False
     qk_norm = False
+    rotary = None
+    gate = False
     head_dim = HEAD_DIM
     scale = MultiHeadAttention.scale
     project_qkv = MultiHeadAttention.project_qkv
     project_out = MultiHeadAttention.project_out
+    _project_out = MultiHeadAttention._project_out
 
 
 def _case(heads, dtype, window, seed=0):

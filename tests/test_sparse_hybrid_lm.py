@@ -363,15 +363,16 @@ def test_the_kinds_refuse_what_they_do_not_build(model):
 
 
 def test_a_kind_that_prefills_whole_refuses_chunks():
-    from flexflow_tpu.models import GPTConfig, build_gpt
+    """A gated-delta-rule state is prefilled whole (a pair of keys and
+    values takes chunks since PR 42: ``tests/test_trinity_lm.py``)."""
+    from flexflow_tpu.models import zoo_smoke_builders
 
     ff = FFModel(FFConfig(batch_size=2, ledger="off",
                           computation_mode=CompMode.INFERENCE))
-    build_gpt(ff, 2, 16, GPTConfig(vocab_size=64, max_positions=64,
-                                   hidden_size=16, num_heads=2, num_layers=1))
+    zoo_smoke_builders()["hybrid"](ff, 2)
     ff.compile(optimizer=None, loss_type=None, metrics=[])
     with pytest.raises(ValueError, match="prefills a prompt whole"):
-        PagedDecoder(ff, 64, decode_slots=2, block_size=16, prefill_chunk=16)
+        PagedDecoder(ff, 16, decode_slots=2, block_size=16, prefill_chunk=16)
 
 
 def test_calibration_runs_over_the_three_arenas(model):

@@ -113,6 +113,10 @@ def test_spec_greedy_identical_per_zoo_causal_lm():
             # at construction (tests/test_hybrid_lm.py,
             # tests/test_nemotron_h_lm.py)
             continue
+        if any(layer.attrs.get("window") for layer in probe.layers):
+            # nor can a ring, whose rejected rows overwrote its oldest
+            # (tests/test_trinity_lm.py)
+            continue
         probe.compile(optimizer=None, loss_type=None, metrics=[])
         vocab = int(probe.compiled.logits_tensor.dims[-1])
         rng = np.random.default_rng(3)

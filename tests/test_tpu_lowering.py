@@ -722,3 +722,151 @@ def test_nemotron_programs_name_their_pieces(nemotron_programs):
                  "f32[1024,128,2688]"):
         assert gone not in prefill, gone
     assert programs["prefill"][1].temp_size_in_bytes < 3 << 30
+
+
+@pytest.fixture(scope="module")
+def trinity_programs(one_chip):
+    """The decode step and both chunk programs of one dense windowed, one
+    full and one windowed expert layer at Trinity-Large's published
+    widths and its cell's sizes (32 slots, contexts to 17,408, blocks of
+    64, chunks of 2,048, 32 of 256 experts held, an eighth of the
+    vocabulary), compiled for the described chip: {name: (the compiled
+    text, its memory analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import trinity as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-ep8.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=3, layer_types=[
+        "sliding_attention", "full_attention", "sliding_attention"])
+    slots, max_length, chunk = 32, 17408, 2048
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=64, kv_dtype="bfloat16",
+                               calibrate=False, prefill_chunk=chunk)
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, head in (("chunk", False), ("chunk_head", True)):
+                compiled = jax.jit(
+                    lambda *a, head=head: dec._chunk_step(*a, head=head),
+                    donate_argnums=(2,)).lower(
+                    params, ints(1, chunk), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1),
+                    ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+def test_trinity_decode_step_holds_no_while(trinity_programs):
+    """The decode step of windowed and full layers holds no ``while`` and
+    no ``conditional``; its Mosaic calls are the paged kernel, one an
+    attention layer, the windowed layers' over their rings (48 query
+    heads on 8 key-value heads of 128: ``attention_path`` ``kernel``);
+    the only scatters are the new token's keys and values; the pool (the
+    full layer's blocks and the rings) aliases its outputs."""
+    programs, dec = trinity_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path == {"decode": "kernel"}
+    assert " while(" not in text and " conditional(" not in text
+    for ln in text.splitlines():
+        if " scatter(" in ln:
+            assert re.search(r"ff\.MULTIHEAD_ATTENTION\.block\d_attn/write",
+                             ln), ln
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 3 and all("paged_attention_decode" in c
+                                   for c in calls)
+    # a ring is 64 blocks a row and 33 rows; the full layer's arena every
+    # slot's worst case
+    shapes = {name: a[0].shape for name, a in dec.pool.kv.items()}
+    assert shapes["block0_attn"] == shapes["block2_attn"] \
+        == (33 * 64, 64, 1024)
+    assert shapes["block1_attn"] == (32 * 272 + 1, 64, 1024)
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+    # every slot through every held expert: the dense form, 32 rows
+    assert "[32,32,3072]" in text
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head"])
+def test_trinity_chunk_programs_hold_no_square(trinity_programs, name):
+    """A chunk of 2,048 queries over up to 17,408 keys: no buffer of two
+    sequence axes wider than a span of 512 keys (48 x 2,048 x 512 float32
+    a span, 201 MB), the experts' products ONE Mosaic call an expert
+    layer that runs (the last layer's runs for the head's row alone and
+    keeps the dense form), and the pieces named: ``window`` inside
+    ``attend`` in the windowed layers, ``gate``, ``route``, ``experts``."""
+    from flexflow_tpu.core.op import parse_scope
+
+    text, mem = trinity_programs[0][name]
+    for ln in _buffers(text):
+        for shape in re.findall(r"\[([\d,]+)\]", ln.split(" = ")[1]
+                                .split("(")[0] if " = " in ln else ""):
+            dims = [int(d) for d in shape.split(",")]
+            wide = [d for d in dims if d >= 2048]
+            assert len(wide) < 2 or 3072 in dims or 6144 in dims \
+                or 12288 in dims or 25024 in dims, ln
+    owners = {parse_scope(m) for m in re.findall(r'op_name="([^"]+)"', text)
+              } - {None}
+    subs = {(kind, s) for kind, _, ss, _ in owners for s in ss}
+    assert {("MULTIHEAD_ATTENTION", "window"),
+            ("MULTIHEAD_ATTENTION", "attend"),
+            ("MULTIHEAD_ATTENTION", "gate"),
+            ("MULTIHEAD_ATTENTION", "project"),
+            ("MULTIHEAD_ATTENTION", "write"),
+            ("ROUTED_EXPERTS", "route"),
+            ("ROUTED_EXPERTS", "experts")} <= subs, subs
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "grouped_experts" in ln]
+    assert len(calls) == 1
+    assert mem.temp_size_in_bytes < 2 << 30
+    # a request's ring is gathered in the arena's own layout: nothing
+    # copies a whole arena (a ring arena is 33 x 4,096 x 1,024 numbers)
+    for ln in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", ln)
+        if m:
+            size = 1
+            for d in m.group(1).split(","):
+                size *= int(d)
+            assert size < 33 * 4096 * 1024, ln
