@@ -28,14 +28,18 @@ that keep the working set in VMEM and feed the MXU directly:
   the jnp forms, taken where ``supported()`` / ``chunks_supported()``
   refuse).
 * :mod:`grouped_experts` — the held experts of a routed layer over a
-  prefill's rows as one kernel: row tiles named by the routing (an
-  expert named by ``n`` pairs gets ``ceil(n / 128)``, one named by none
-  gets none), each expert's matrices read once where they lie, the
-  pairs' rows copied out of VMEM and their weighted results added back
-  into it row by row (``ops/moe_ops.py`` ``RoutedExperts.apply`` keeps
-  the jnp grouped form for what ``supported()`` refuses: float32 rows,
-  widths of no whole lane tiles, rows past the fast memory, the CPU;
-  and for a model over more than one device, and as the backward).
+  call's rows as one kernel: row tiles named by the routing (an expert
+  named by ``n`` pairs gets ``ceil(n / tile)``, one named by none gets
+  none and its matrices are not read), each named expert's matrices read
+  once where they lie, the pairs' rows copied out of VMEM and their
+  weighted results added back into it row by row. Tiles of 128 rows for
+  a prefill's bucket or chunk; for a call of fewer rows (a decode step
+  that names few of the experts it holds, the one row behind a head's
+  cut) tiles of the call's own rows, one an expert at the most
+  (``ops/moe_ops.py`` ``RoutedExperts.expert_form`` chooses; ``apply``
+  keeps the jnp forms for what ``supported()`` refuses: float32 rows,
+  widths of no whole lane tiles, rows past the fast memory, the CPU; and
+  for a model over more than one device, and as the backward).
 * :mod:`moe_kernels` — row gather / weighted row-gather-sum with
   scalar-prefetched indices, realizing the MoE dispatch/combine data
   movement (reference: src/ops/group_by.cu, aggregate.cu scatter kernels)

@@ -1,7 +1,7 @@
 """The grouped form of a routed-experts layer as one Pallas TPU kernel.
 
-``RoutedExperts`` (ops/moe_ops.py) computes, for the rows of a prefill,
-only the (token, pick) pairs whose expert it holds. Its jnp grouped form
+``RoutedExperts`` (ops/moe_ops.py) computes, for the rows of a call, only
+the (token, pick) pairs whose expert it holds. Its jnp grouped form
 sorts the pairs, gathers their rows into fixed tiles in HBM, multiplies
 the tiles, and gathers every pair's row back: the products are a third of
 its time (PERF.md section 6, PR 40 and PR 41). Here the routing names the
@@ -10,12 +10,22 @@ tiles and everything else happens where the rows are:
 * outside the kernel, small and in XLA (:func:`tile_table`): the pairs
   sorted by held expert, pairs of experts not held last; an expert named
   by ``n`` pairs gets ``ceil(n / tile)`` row tiles of its own, one after
-  another, an expert named by none gets none. A tile is (its expert, its
-  first sorted place, how many of its rows are real). The grid is static
-  at the worst case, ``ceil(pairs / tile) + count`` tiles; a tile past
-  the last real one repeats the block before it (no DMA) and multiplies
-  nothing (``pl.when``). No capacity, so nothing overflows and nothing is
-  dropped however uneven the routing;
+  another, an expert named by none gets none, and its matrices are not
+  read. A tile is (its expert, its first sorted place, how many of its
+  rows are real). The grid is static at the worst case
+  (:func:`grid_tiles`); a tile past the last real one repeats the block
+  before it (no DMA) and multiplies nothing (``pl.when``). No capacity,
+  so nothing overflows and nothing is dropped however uneven the
+  routing;
+* the tiles follow the call's rows (:func:`tile_rows`): 128 rows for a
+  prefill's bucket or chunk, ``ceil(pairs / 128) + count`` tiles; for a
+  call of no more rows than that (a decode step's slots, the one row
+  behind a head's cut) a tile of the call's own rows in whole bfloat16
+  sublane tiles of 16, which holds all of any expert's pairs since no
+  token names an expert twice: ``count`` tiles and no more, of which the
+  named experts' are real. That is what lets a step that names two in
+  five of the experts it holds read those alone (PERF.md section 6,
+  PR 43);
 * an expert's matrices are blocks of ``w_up`` / ``w_gate`` / ``w_down``
   where they lie, chosen by the tile's expert through the scalar-prefetch
   index map: consecutive tiles of one expert reuse the resident block,
@@ -35,9 +45,12 @@ tiles and everything else happens where the rows are:
   one elementwise pass over ``v``; unpacked on the tile, exactly).
 
 bfloat16 operands, float32 accumulation, the activation and the gate's
-weight in float32: the mathematics of ``RoutedExperts._apply_grouped``,
-which is this kernel's reference and takes what :func:`supported`
-refuses.
+weight in float32: the mathematics of ``RoutedExperts._apply_grouped``
+and ``_apply_dense``, which are this kernel's references and take what
+:func:`supported` refuses: rows other than bfloat16, widths of no whole
+lane tiles, 8 rows or more that are no whole sublane tiles (fewer are
+padded to one, by rows whose picks name no held expert), tables past the
+scalar memory, rows past the fast memory.
 """
 
 from __future__ import annotations
@@ -53,13 +66,32 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_mode
 from .moe_kernels import SMEM_BUDGET_BYTES
 
-# rows of a tile: one pass of the chip's 128 x 128 matrix unit; an expert
-# named by fewer multiplies a tile all the same
+# rows of a tile at the most: one pass of the chip's 128 x 128 matrix unit;
+# an expert named by fewer multiplies a tile all the same
 TILE_ROWS = 128
 # a v5e core has 128 MiB of VMEM; the call is given most of it, and plans
 # its blocks to a budget below that (the compiler's own temporaries)
 VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 VMEM_BUDGET_BYTES = 88 * 1024 * 1024
+# rows of a 32-bit sublane tile: what the packed rows and the output come in
+SUBLANES = 8
+
+
+def tile_rows(rows: int) -> int:
+    """Rows of a tile for a call of ``rows`` tokens: ``TILE_ROWS``, and
+    for a call of no more than that its own rows in whole bfloat16
+    sublane tiles of 16. No token names an expert twice, so such a tile
+    holds all the pairs of any one expert."""
+    return min(TILE_ROWS, -(-rows // (2 * SUBLANES)) * 2 * SUBLANES)
+
+
+def grid_tiles(rows: int, picks: int, count: int) -> int:
+    """The tiles a call is laid out for, the worst routing's: every
+    expert's last tile part empty, ``ceil(pairs / tile) + count``, and no
+    more than ``ceil(rows / tile)`` an expert, which a token names once
+    at the most: ``count`` tiles for a call of one tile's rows."""
+    tile = tile_rows(rows)
+    return min(-(-rows * picks // tile) + count, count * -(-rows // tile))
 
 
 def _vmem_bytes(rows: int, work_dim: int, cut: int, gated: bool) -> int:
@@ -69,10 +101,11 @@ def _vmem_bytes(rows: int, work_dim: int, cut: int, gated: bool) -> int:
     the float32 temporaries of one step (the up products, the activation,
     the down product)."""
     mats = 3 if gated else 2
+    tile = tile_rows(rows)
     return (2 * rows * work_dim + 4 * rows * work_dim
             + 2 * 2 * mats * work_dim * cut
-            + TILE_ROWS * work_dim * (2 + 2 + 4)
-            + 4 * TILE_ROWS * (mats * cut + work_dim))
+            + tile * work_dim * (2 + 2 + 4)
+            + 4 * tile * (mats * cut + work_dim))
 
 
 def plan(rows: int, picks: int, work_dim: int, width: int, count: int,
@@ -83,17 +116,19 @@ def plan(rows: int, picks: int, work_dim: int, width: int, count: int,
     them: rows other than
     bfloat16 (a row is copied as 32-bit pairs of columns), a ``work_dim``
     whose half is no whole lane tiles, a ``width`` of no whole lane tiles,
-    rows that are no whole sublane tiles, a routing whose sorted pairs and
-    tile table do not fit SMEM, or rows and output that leave VMEM no room
-    for a weight block of 128 columns. From shapes alone."""
+    8 rows or more that are no whole sublane tiles (fewer are padded to
+    one: :func:`grouped_experts`), a routing whose sorted pairs and tile
+    table do not fit SMEM, or rows and output that leave VMEM no room for
+    a weight block of 128 columns. From shapes alone."""
     if jnp.dtype(dtype) != jnp.dtype(jnp.bfloat16):
         return None
-    if work_dim % 256 or width % 128 or rows % 8 or rows < 8 or count < 1:
+    rows = max(rows, SUBLANES)
+    if work_dim % 256 or width % 128 or rows % SUBLANES or count < 1:
         return None
     pairs = rows * picks
     if (count + 1) << max(1, (pairs - 1).bit_length()) >= 1 << 31:
         return None                  # tile_table's sort key is one int32
-    if 4 * (2 * pairs + 3 * (-(-pairs // TILE_ROWS) + count) + 1) > (
+    if 4 * (2 * pairs + 3 * grid_tiles(rows, picks, count) + 1) > (
             SMEM_BUDGET_BYTES):
         return None
     lanes = width // 128
@@ -122,12 +157,14 @@ def tile_table(ids, first: int, count: int):
     * ``order`` (T k,) int32: the pairs' flat indices sorted by held
       expert (and by index within one: a token's own order), pairs of
       experts not held last;
-    * ``expert``, ``place``, ``real`` (tiles,) int32 for ``tiles =
-      ceil(T k / TILE_ROWS) + count``: a tile's held expert, the sorted place
-      of its first row, and how many of its rows are pairs (0 for a tile
-      past the last real one, which names the last real tile's expert);
+    * ``expert``, ``place``, ``real`` (tiles,) int32 for ``tiles =``
+      :func:`grid_tiles`, of :func:`tile_rows` rows each: a tile's held
+      expert, the sorted place of its first row, and how many of its rows
+      are pairs (0 for a tile past the last real one, which names the last
+      real tile's expert);
     * ``tiles_real`` () int32."""
     t, k = ids.shape
+    tile = tile_rows(t)
     local = ids - first
     held = (local >= 0) & (local < count)
     # one unstable sort of one operand, the key above the pair's index (the
@@ -144,19 +181,18 @@ def tile_table(ids, first: int, count: int):
         both >> bits, jnp.arange(count + 1, dtype=jnp.int32),
         method="compare_all").astype(jnp.int32)
     sizes = starts[1:] - starts[:-1]
-    per = -(-sizes // TILE_ROWS)                      # an expert's tiles
+    per = -(-sizes // tile)                           # an expert's tiles
     ends = jnp.cumsum(per)
     tiles_real = ends[-1]
-    j = jnp.arange(-(-(t * k) // TILE_ROWS) + count, dtype=jnp.int32)
+    j = jnp.arange(grid_tiles(t, k, count), dtype=jnp.int32)
     at = jnp.minimum(j, jnp.maximum(tiles_real - 1, 0))
     expert = jnp.minimum(
         jnp.searchsorted(ends, at, side="right", method="compare_all"),
         count - 1).astype(jnp.int32)
     chunk = at - (ends - per)[expert]              # which of its expert's
     real = jnp.where(j < tiles_real,
-                     jnp.clip(sizes[expert] - chunk * TILE_ROWS, 0,
-                              TILE_ROWS), 0)
-    return (order, expert, starts[expert] + chunk * TILE_ROWS,
+                     jnp.clip(sizes[expert] - chunk * tile, 0, tile), 0)
+    return (order, expert, starts[expert] + chunk * tile,
             real.astype(jnp.int32), tiles_real.astype(jnp.int32))
 
 
@@ -235,6 +271,7 @@ def _grouped_experts(v, ids, gates, w_gate, w_up, w_down, *, first, gated,
     count, _, width = w_up.shape
     cuts = width // cut
     half = work_dim // 2
+    tile = tile_rows(t)
     order, expert, place, real, tiles_real = tile_table(ids, first, count)
     bits = jax.lax.bitcast_convert_type(v, jnp.uint16).astype(jnp.uint32)
     packed = bits[:, :half] | (bits[:, half:] << 16)
@@ -255,9 +292,9 @@ def _grouped_experts(v, ids, gates, w_gate, w_up, w_down, *, first, gated,
         in_specs=([whole] + [up_spec] * (len(weights) - 1)
                   + [pl.BlockSpec((None, cut, work_dim), down_block)]),
         out_specs=whole,
-        scratch_shapes=[pltpu.VMEM((TILE_ROWS, half), jnp.uint32),
-                        pltpu.VMEM((TILE_ROWS, work_dim), v.dtype),
-                        pltpu.VMEM((TILE_ROWS, work_dim), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((tile, half), jnp.uint32),
+                        pltpu.VMEM((tile, work_dim), v.dtype),
+                        pltpu.VMEM((tile, work_dim), jnp.float32)])
     out = pl.pallas_call(
         functools.partial(_kernel, gated=gated, cuts=cuts),
         grid_spec=grid_spec,
@@ -269,7 +306,7 @@ def _grouped_experts(v, ids, gates, w_gate, w_up, w_down, *, first, gated,
         name="grouped_experts",
     )(expert, place, real, tiles_real.reshape(1), order // ids.shape[1],
       gates.astype(jnp.float32).reshape(-1)[order], packed, *weights)
-    return out.astype(v.dtype), (tiles_real * TILE_ROWS).astype(jnp.uint32)
+    return out.astype(v.dtype), (tiles_real * tile).astype(jnp.uint32)
 
 
 def grouped_experts(v, ids, gates, weights, *, first: int, gated: bool):
@@ -280,19 +317,29 @@ def grouped_experts(v, ids, gates, weights, *, first: int, gated: bool):
     (count, work_dim, width), ``w_down`` (count, width, work_dim) and,
     ``gated``, ``w_gate``, of the experts ``first .. first + count - 1``.
     Returns ((T, work_dim) in ``v``'s dtype, the rows the products ran
-    over: real tiles x tile rows, () uint32, counted here). Behind a
-    ``jit`` of its own: a program of many expert layers lowers it once.
-    Callers check :func:`supported` first."""
+    over: real tiles x tile rows, () uint32, counted here). Fewer rows
+    than a sublane tile's (the one row behind a head's cut) are padded to
+    it with rows whose picks name no held expert. Behind a ``jit`` of its
+    own: a program of many expert layers lowers it once. Callers check
+    :func:`supported` first."""
     w_up = weights["w_up"]
-    cut = plan(v.shape[0], ids.shape[1], v.shape[1], w_up.shape[2],
-               w_up.shape[0], gated, v.dtype)
+    t = v.shape[0]
+    cut = plan(t, ids.shape[1], v.shape[1], w_up.shape[2], w_up.shape[0],
+               gated, v.dtype)
     if cut is None:
         raise ValueError(f"grouped_experts cannot run rows {v.shape} "
                          f"{v.dtype} through experts {w_up.shape}")
-    return _grouped_experts(
+    short = max(SUBLANES - t, 0)
+    if short:
+        pad = ((0, short), (0, 0))
+        v, gates = jnp.pad(v, pad), jnp.pad(gates, pad)
+        ids = jnp.pad(ids, pad, constant_values=first - 1)
+    out, computed = _grouped_experts(
         v, ids, gates, weights.get("w_gate") if gated else None, w_up,
         weights["w_down"], first=first, gated=gated, cut=cut,
         interpret=pallas_mode() == "interpret")
+    return (out[:t] if short else out), computed
 
 
-__all__ = ["grouped_experts", "plan", "supported", "tile_table"]
+__all__ = ["grid_tiles", "grouped_experts", "plan", "supported",
+           "tile_rows", "tile_table"]

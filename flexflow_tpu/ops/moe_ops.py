@@ -532,6 +532,22 @@ class Cache(Op):
 # the chip this is measured on (a v5e: 197 TFLOP/s over 819 GB/s): up to
 # here the dense form's rows cost no more than the matrices' bytes
 RIDGE_ROWS = 240
+# under the ridge, the share of the held experts a call can name
+# (``RoutedExperts.named_share``) below which the kernel, which reads the
+# named experts' matrices alone, takes the call from the dense form, which
+# reads them all. Measured on a v5e at Trinity-Large's share (32 gated
+# experts of 3,072 x 3,072 held of 256, top-4; ``tools/
+# expert_forms_crossover.py trinity-large-ep8``, PERF.md section 6, PR 43):
+# a layer call in the dense form takes 2.47-2.58 ms at 1 to 128 rows
+# whatever is named (2.82 at 240), in the kernel 0.05 ms and 0.080 ms a
+# NAMED expert (0.87 ms for 10 of 32 at 32 rows, 1.85 for 23 at 64, 2.38
+# for 30 at 128): they cross where 30.5 of the 32 are named, a share of
+# 0.95. Placed a step under that, since the sweep is one expert shape and
+# a smaller expert pays more of its time a tile: at 0.87 (128 rows) the
+# kernel still leads by 4 % on an even routing and by 29 % on an uneven
+# one; at 0.996, a step of 128 slots of 8 picks over 192 or of 22 over
+# 512, the dense form stays
+NAMED_SHARE_KERNEL = 0.9
 # of the rows of a call, the share one held expert may be named by before
 # the grouped form gives the call to the dense one
 CAPACITY_SHARE = 4
@@ -701,6 +717,15 @@ class RoutedExperts(Op):
         return ((ids - self.first)[..., None]
                 == jnp.arange(self.count, dtype=jnp.int32))
 
+    def named_share(self, rows: int) -> float:
+        """The share of the held experts' matrices a call of ``rows``
+        tokens has to read, at the most: a token names a given expert
+        with probability ``k / n_routed``, so ``rows`` of them leave it
+        unnamed with ``(1 - k / n_routed) ** rows`` where the routing is
+        even (an uneven one names fewer). 39.6 % for 32 rows of 4 picks
+        over 256, 99.6 % for 128 of 8 over 192 or of 22 over 512."""
+        return 1.0 - (1.0 - self.k / self.n_routed) ** rows
+
     def expert_form(self, rows: int, dtype=None, mesh=None) -> str:
         """How :meth:`apply` multiplies ``rows`` tokens of ``dtype`` (the
         op's declared one where not given) in a program over ``mesh``:
@@ -709,24 +734,35 @@ class RoutedExperts(Op):
         ``kernels/grouped_experts.py``) or ``"grouped"`` (the same pairs
         in jnp: an expert's rows side by side in a tile of
         :meth:`capacity` rows, what overflows a tile in a few spill
-        tiles). A rule over what a trace sees, no knob: up to
-        ``RIDGE_ROWS`` rows the matrices' bytes decide and the dense form
-        stays (a decode step's slots); past them the products do (a
-        prefill's bucket), in the kernel where Pallas is on, the program
-        is one device's (the kernel has no ``shard_map`` composition:
-        ``kernels.use_pallas``) and its ``supported()`` takes the shapes,
-        else in jnp (the CPU, a mesh, float32 rows, widths of no whole
-        lane tiles, rows past its fast memory). PERF.md section 6, PR 40
-        and PR 41, has the forms measured."""
-        if rows <= RIDGE_ROWS:
+        tiles). A rule over what a trace sees, no knob. Up to
+        ``RIDGE_ROWS`` rows the matrices' bytes decide (a decode step's
+        slots, the one row behind a head's cut): the kernel reads the
+        matrices of the experts the routing names and the dense form
+        those of all it holds, so the kernel where the call can name
+        under ``NAMED_SHARE_KERNEL`` of them (:meth:`named_share`), else
+        the dense form, and never the jnp grouped one. Past the ridge the
+        products decide (a prefill's bucket): the kernel, else the jnp
+        grouped form. The kernel only where Pallas is on, the program is
+        one device's (the kernel has no ``shard_map`` composition:
+        ``kernels.use_pallas``) and its ``supported()`` takes the shapes
+        (not the CPU, a mesh, float32 rows, widths of no whole lane
+        tiles, rows past its fast memory). That holds for a training
+        call too: ``fit`` reaches :meth:`apply` through :meth:`forward`,
+        and few rows on one TPU device take ``kernel_form``, whose
+        backward is the jnp grouped form's. PERF.md section 6, PRs 40,
+        41 and 43, has the forms measured."""
+        few = rows <= RIDGE_ROWS
+        if few and self.named_share(rows) >= NAMED_SHARE_KERNEL:
             return "dense"
         from ..kernels.grouped_experts import supported
 
         if dtype is None:
             dtype = self.input_shapes[0].dtype.to_jnp()
-        return "kernel" if (mesh is None or mesh.size == 1) and supported(
-            rows, self.k, self.work_dim, self.width, self.count, self.gated,
-            dtype) else "grouped"
+        if (mesh is None or mesh.size == 1) and supported(
+                rows, self.k, self.work_dim, self.width, self.count,
+                self.gated, dtype):
+            return "kernel"
+        return "dense" if few else "grouped"
 
     def capacity(self, rows: int) -> int:
         """Rows of a held expert's tile in the jnp grouped form (the
@@ -752,7 +788,8 @@ class RoutedExperts(Op):
         every token an expert in the dense form; a tile an expert and the
         spill tiles in the jnp grouped one (where they hold what
         overflows). None for the kernel: its rows follow the routing and
-        are counted on the device (:meth:`apply`'s ``computed``)."""
+        are counted on the device (:meth:`apply`'s ``computed``, which a
+        prompt program and a decode step alike add to their counters)."""
         form = self.expert_form(rows, dtype, mesh)
         if form == "kernel":
             return None
@@ -766,10 +803,14 @@ class RoutedExperts(Op):
         shapes do not depend on the routing, each matrix is read once a
         call, and the weighted sum over the experts is part of the down
         product. That is ``n_routed / k`` times the rows the routing
-        names; for a holder of a dozen experts at a decode step's few
-        rows the matrices' bytes decide, not the rows (PERF.md section 6,
-        PR 27: pairs sorted by expert and ``jax.lax.ragged_dot`` read
-        3,683 tokens/s where this reads 5,012)."""
+        names; at a decode step's few rows the matrices' bytes decide,
+        not the rows, and where the step names nearly every held expert
+        (128 slots of 8 picks over 192) this form stands at those bytes
+        (PERF.md section 6, PR 27: pairs sorted by expert and
+        ``jax.lax.ragged_dot`` read 3,683 tokens/s where this reads
+        5,012). Where it names a few of them (32 slots of 4 picks over
+        256: two in five) this form reads the rest for nothing, and
+        :meth:`expert_form` gives the call to the kernel (PR 43)."""
         w = (self.held_hits(ids) * gates[..., None]).sum(1)     # (T, count)
         def up(name):
             return jnp.einsum("te,cef->ctf", v, weights[name],
@@ -920,14 +961,15 @@ class RoutedExperts(Op):
             t *= s
         mats = 3.0 if self.gated else 2.0
         # what apply() computes for these rows, by its form (the kernel's
-        # follow the routing: an even one's pairs, and half a tile an
-        # expert left empty)
+        # follow the routing: an even one's pairs, and half a tile left
+        # empty an expert it names)
         rows = self.rows_computed(t)
         if rows is None:
-            from ..kernels.grouped_experts import TILE_ROWS
+            from ..kernels.grouped_experts import tile_rows
 
             rows = (t * self.k * self.count // self.n_routed
-                    + self.count * TILE_ROWS // 2)
+                    + int(self.count * self.named_share(t))
+                    * tile_rows(t) // 2)
         return (2.0 * t * self.in_dim * self.n_routed
                 + 4.0 * t * self.in_dim * self.latent
                 + 2.0 * mats * rows * self.work_dim * self.width)

@@ -58,22 +58,24 @@ from .cache_entry import kind_for
 from .kv_cache import NULL_BLOCK, Addresses, PagedKVPool
 
 
-def _expert_counts(op, ids, active):
+def _expert_counts(op, ids, active, *computed):
     """What one decode step adds to an expert op's counters: ``[1, pairs
     routed, pairs held, held experts that got no row, rows of each held
-    expert ...]`` over the active slots' tokens. ``ids`` (T, k),
-    ``active`` (T,) bool."""
+    expert ...]`` over the active slots' tokens and, where the step's
+    experts ran as the kernel, the rows it ``computed`` () uint32 behind
+    them. ``ids`` (T, k), ``active`` (T,) bool."""
     hit = op.held_hits(ids) & active[:, None, None]
     rows = hit.sum((0, 1)).astype(jnp.uint32)                 # (count,)
     head = jnp.stack([jnp.uint32(1),
                       (active.sum() * ids.shape[1]).astype(jnp.uint32),
                       rows.sum(), (rows == 0).sum().astype(jnp.uint32)])
-    return jnp.concatenate([head, rows])
+    return jnp.concatenate([head, rows, *(c[None] for c in computed)])
 
 
 def _count_up(acc, add):
     """``acc`` (2, ...) uint32, low words over high words (a decode
-    program's (2, 4 + count) an op, the prompt programs' (2, 2, ops)), and
+    program's (2, 4 + count) an op, and one word more where its experts
+    run as the kernel; the prompt programs' (2, 2, ops)), and
     ``add`` of the shape of one: a 64-bit count in two words, so that a
     server that never restarts does not wrap (1,024 pairs a step fill 32
     bits in 4 M steps)."""
@@ -573,7 +575,9 @@ class PagedDecoder(_DecodeGraph):
         # lock covers the moment between a dispatch that donates them and
         # the assignment of what it returns.
         self._expert_acc: Dict[str, jax.Array] = {
-            op.name: jnp.zeros((2, 4 + op.count), jnp.uint32)
+            op.name: jnp.zeros(
+                (2, 4 + op.count + (self._decode_form(op) == "kernel")),
+                jnp.uint32)
             for op in self._expert_ops}
         self._expert_acc_lock = threading.Lock()
         # the same for the prompt programs: the pairs they named among the
@@ -672,11 +676,28 @@ class PagedDecoder(_DecodeGraph):
             x2d = x.reshape(-1, x.shape[-1])
             ids, gates = op.route(p, x2d)
             routed[op.name] = ids
-            with fixed_scope("counters"):
-                new_acc[op.name] = _count_up(
-                    new_acc[op.name], _expert_counts(op, ids, active))
-            return op.apply(p, x2d, ids, gates,
-                            mesh=self._cm.mesh).reshape(x.shape)
+            computed: List[jax.Array] = []
+
+            def count():
+                with fixed_scope("counters"):
+                    new_acc[op.name] = _count_up(
+                        new_acc[op.name],
+                        _expert_counts(op, ids, active, *computed))
+
+            if self._decode_form(op) == "kernel":
+                # the kernel reads the matrices of the experts its rows
+                # name: an idle slot's padding names none, and the rows
+                # the kernel says it ran go into the op's counters (so
+                # they are counted behind it; a dense step counts first,
+                # as it always did, and its program keeps its text)
+                y = op.apply(p, x2d,
+                             jnp.where(active[:, None], ids, op.first - 1),
+                             gates, computed, self._cm.mesh)
+                count()
+            else:
+                count()
+                y = op.apply(p, x2d, ids, gates, mesh=self._cm.mesh)
+            return y.reshape(x.shape)
 
         logits = self._forward_block(params, acts, attn, experts)[:, -1, :]
         with fixed_scope("sample"):
@@ -850,6 +871,12 @@ class PagedDecoder(_DecodeGraph):
         return Addresses(jnp.asarray(tables),
                          None if rows is None else jnp.asarray(rows))
 
+    def _decode_form(self, op) -> str:
+        """The form a decode step's slots take through a routed-experts
+        op's held experts (``op.expert_form``)."""
+        return op.expert_form(self.decode_slots, self._compute_dtype(),
+                              self._cm.mesh)
+
     def expert_stats(self) -> Dict[str, Dict]:
         """Per routed-experts op, counted on the device over the decode
         steps' active slots: ``steps``, ``pairs_routed`` (tokens x picks),
@@ -871,24 +898,23 @@ class PagedDecoder(_DecodeGraph):
         for i, op in enumerate(self._expert_ops):
             acc = fetched[op.name].astype(np.uint64)
             acc = [int(v) for v in (acc[1] << np.uint64(32)) | acc[0]]
-            # (None where a decode step's slots are past the ridge and
-            # take the kernel, which no decode program counts)
             step_rows = op.rows_computed(self.decode_slots, dtype, mesh)
             out[op.name] = {
                 "held": [op.first, op.count], "n_routed": op.n_routed,
                 "steps": acc[0], "pairs_routed": acc[1],
                 "pairs_held": acc[2], "idle_held_experts": acc[3],
-                "rows_per_held_expert": acc[4:],
+                "rows_per_held_expert": acc[4:4 + op.count],
                 # how the held experts' products ran: the rows they went
-                # over (a decode step's from the shapes, the prompt
-                # programs' as those counted them) beside the rows the
-                # routing named (``pairs_held``), and by which form
+                # over (a decode step's from the shapes, or as the step
+                # counted them where the kernel ran and the routing says
+                # them: the accumulator's last word; the prompt programs'
+                # as those counted them) beside the rows the routing
+                # named (``pairs_held``), and by which form
                 # (``op.expert_form``; a prompt's at the widest bucket)
-                "form_decode": op.expert_form(self.decode_slots, dtype,
-                                              mesh),
+                "form_decode": self._decode_form(op),
                 "form_prefill": op.expert_form(self.prefill_buckets[-1],
                                                dtype, mesh),
-                "rows_computed": (None if step_rows is None
+                "rows_computed": (acc[-1] if step_rows is None
                                   else acc[0] * step_rows),
                 "prompt_pairs_held": prompt[0][i],
                 "prompt_rows_computed": prompt[1][i]}

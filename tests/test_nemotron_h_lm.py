@@ -496,10 +496,9 @@ def test_gradients_through_the_kernel_are_the_jnp_forms(monkeypatch,
         lambda w, x: op.forward(two, [x], w))(w, x).pretty_print()
 
 
-def test_the_form_is_a_rule_over_the_shapes_traced():
-    """``expert_form``: a decode step's rows keep the dense form, a
-    prefill's pass the crossover, at the new configuration's shapes and
-    at A.X-K1's; no knob."""
+def _published_shares():
+    """The three routed configurations' expert layers at their published
+    shapes, bfloat16 rows: {name: op}."""
     from flexflow_tpu.core.layer import Layer
     from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
     from flexflow_tpu.ffconst import OpType
@@ -508,12 +507,25 @@ def test_the_form_is_a_rule_over_the_shapes_traced():
     def make(e, **attrs):
         return RoutedExperts(
             Layer(OpType.ROUTED_EXPERTS, "x", attrs=attrs),
-            [ParallelTensorShape.unpartitioned((1, 8, e), DataType.FLOAT)])
+            [ParallelTensorShape.unpartitioned((1, 8, e),
+                                               DataType.BFLOAT16)])
 
-    nemotron = make(4096, n_routed=512, experts_per_token=22, width=2688,
-                    experts_held=(0, 128), latent=1024, activation="relu2")
-    axk1 = make(7168, n_routed=192, experts_per_token=8, width=2048,
-                experts_held=(0, 12), n_group=8, topk_group=4)
+    return {
+        "nemotron": make(4096, n_routed=512, experts_per_token=22,
+                         width=2688, experts_held=(0, 128), latent=1024,
+                         activation="relu2"),
+        "axk1": make(7168, n_routed=192, experts_per_token=8, width=2048,
+                     experts_held=(0, 12), n_group=8, topk_group=4),
+        "trinity": make(3072, n_routed=256, experts_per_token=4, width=3072,
+                        experts_held=(0, 32))}
+
+
+def test_the_form_is_a_rule_over_the_shapes_traced():
+    """``expert_form``: a decode step's rows keep the dense form, a
+    prefill's pass the crossover, at the new configuration's shapes and
+    at A.X-K1's; no knob."""
+    shares = _published_shares()
+    nemotron, axk1 = shares["nemotron"], shares["axk1"]
     for op in (nemotron, axk1):
         assert op.expert_form(128) == "dense"
         assert [op.expert_form(b) for b in (768, 1024)] == ["grouped"] * 2
@@ -524,6 +536,51 @@ def test_the_form_is_a_rule_over_the_shapes_traced():
     assert (nemotron.spill_tiles, axk1.spill_tiles) == (8, 1)
     assert nemotron.rows_computed(1024) == (128 + 8) * 256
     assert axk1.rows_computed(1024) == (12 + 1) * 256
+
+
+@pytest.mark.parametrize("name, slots, share", [
+    ("trinity", 32, 0.396), ("nemotron", 128, 0.996), ("axk1", 128, 0.996)])
+def test_under_the_ridge_the_form_follows_the_share_of_experts_named(
+        monkeypatch, name, slots, share):
+    """Under the ridge the question is how many of the held experts'
+    matrices a call must read: ``named_share`` from ``rows``, ``k`` and
+    ``n_routed``, all static in a trace. At the three configurations'
+    published shapes and their cells' slots: Trinity's step names two in
+    five and takes the kernel where the kernel is supported (one device,
+    bfloat16 rows, Pallas on), the dense form on the CPU, under a mesh
+    and for float32 rows; Nemotron's and A.X-K1's steps name all but one
+    in 250 and keep the dense form at 128 and at 240 rows; no form under
+    the ridge is ever the jnp grouped one; and the rows computed are a
+    number from the shapes, or counted on the device."""
+    from flexflow_tpu.ops.moe_ops import NAMED_SHARE_KERNEL, RIDGE_ROWS
+
+    op = _published_shares()[name]
+    assert abs(op.named_share(slots) - share) < 0.0005
+    assert 0.4 < NAMED_SHARE_KERNEL < 0.99
+    two = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("x",))
+    one = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",))
+    for mode in ("off", "interpret"):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        want = "kernel" if (name, mode) == ("trinity", "interpret") \
+            else "dense"
+        assert op.expert_form(slots) == op.expert_form(slots, mesh=one) \
+            == want
+        assert op.expert_form(slots, mesh=two) == "dense"
+        assert op.expert_form(slots, jnp.float32) == "dense"
+        assert op.expert_form(240) == "dense"
+        # (a call that is no whole sublane tiles: the kernel refuses it)
+        assert op.expert_form(12) == "dense"
+        for rows in (1, 8, 16, 32, 64, 128, 200, RIDGE_ROWS):
+            for kw in ({}, {"mesh": two}, {"dtype": jnp.float32}):
+                form = op.expert_form(rows, **kw)
+                assert form in ("dense", "kernel"), (rows, kw)
+                computed = op.rows_computed(rows, **kw)
+                assert (computed is None) == (form == "kernel")
+                assert form == "kernel" or computed == op.count * rows
+        # the head's one row names 2-4 % of the held: the kernel's
+        assert op.expert_form(1) == (
+            "kernel" if mode == "interpret" else "dense")
+        assert op.flops() > 0
 
 
 def test_the_choice_is_by_biased_scores_and_the_weights_by_plain(toy):

@@ -83,26 +83,36 @@ def test_flash_attention_compiles_for_v5e(monkeypatch, one_chip,
         assert f"[{b},{h},{sq},{skv}]" not in text  # the scores of a head
 
 
-@pytest.mark.parametrize("bucket", [512, 768, 1024, "widest"])
-@pytest.mark.parametrize(
-    "cell, picks, work_dim, width, count, gated, widest", [
-        ("nemotron3-super-ep4", 22, 1024, 2688, 128, False, 4096),
-        ("axk1-ep16", 8, 7168, 2048, 12, True, 1536)])
+SHARES = {   # picks, work_dim, width, count, gated, the widest wave
+    "nemotron3-super-ep4": (22, 1024, 2688, 128, False, 4096),
+    "axk1-ep16": (8, 7168, 2048, 12, True, 1536),
+    "trinity-large-ep8": (4, 3072, 3072, 32, True, 2048)}
+
+
+@pytest.mark.parametrize("cell, bucket", [
+    (cell, bucket) for cell in ("nemotron3-super-ep4", "axk1-ep16")
+    for bucket in (512, 768, 1024, "widest")] + [
+    # a decode step's 32 slots, the one row behind a head's cut, a chunk
+    ("trinity-large-ep8", 32), ("trinity-large-ep8", 1),
+    ("trinity-large-ep8", 2048)])
 def test_grouped_experts_compiles_for_v5e(monkeypatch, one_chip,
-                                          no_compile_cache, cell, picks,
-                                          work_dim, width, count, gated,
-                                          widest, bucket):
+                                          no_compile_cache, cell, bucket):
     """A prefill's held experts at both routed cells' widths, the three
     buckets and the widest starting wave ``plan()`` takes (four prompts
     of 1,024 for the Nemotron share, whose sorted pairs nearly fill the
     scalar memory; two of 768 for the A.X-K1 share, whose rows and output
     leave the fast memory room for a weight block of 128 columns and no
-    more): one Mosaic custom call by its name, one sort (the
+    more), and the Trinity share's three calls (a decode step's 32 slots
+    in ONE tile of 32 rows an expert named, 32 tiles and no more, an
+    expert's matrices in two cuts; a head's one row padded to a sublane
+    tile; a chunk of 2,048 in tiles of 128): one Mosaic custom call by
+    its name, one sort (the
     pairs by held expert; no ``argsort`` pair, no stable sort), no
     ``conditional``, no ``while``, no gather but the table's own
     lookups, and no buffer a tile an expert or a row a pair: nothing
     beside the arguments but the packed rows, the float32 output and the
     table."""
+    picks, work_dim, width, count, gated, widest = SHARES[cell]
     from flexflow_tpu.kernels import grouped_experts as kernel
 
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
@@ -136,7 +146,18 @@ def test_grouped_experts_compiles_for_v5e(monkeypatch, one_chip,
     assert f"[{bucket * picks},{work_dim}]" not in text
     # the packed rows, the float32 output and its bfloat16 copy
     assert compiled.memory_analysis().temp_size_in_bytes <= (
-        8 * bucket * work_dim + (1 << 20))
+        8 * max(bucket, 8) * work_dim + (1 << 20))
+    if cell == "trinity-large-ep8":
+        # a call of one tile's rows: the tile is the call's, a tile an
+        # expert at the most, and the fast memory left takes wider cuts
+        rows = max(bucket, 8)
+        tile, tiles, cut = {8: (16, 32, 1536), 32: (32, 32, 1536),
+                            2048: (128, 96, 1024)}[rows]
+        assert kernel.tile_rows(rows) == tile
+        assert kernel.grid_tiles(rows, picks, count) == tiles
+        assert kernel.plan(bucket, picks, work_dim, width, count, gated,
+                           jnp.bfloat16) == cut
+        assert f"s32[{tiles}]" in text
 
 
 def test_gated_delta_decode_compiles_for_v5e(monkeypatch, one_chip,
@@ -802,9 +823,14 @@ def test_trinity_decode_step_holds_no_while(trinity_programs):
     """The decode step of windowed and full layers holds no ``while`` and
     no ``conditional``; its Mosaic calls are the paged kernel, one an
     attention layer, the windowed layers' over their rings (48 query
-    heads on 8 key-value heads of 128: ``attention_path`` ``kernel``);
-    the only scatters are the new token's keys and values; the pool (the
-    full layer's blocks and the rings) aliases its outputs."""
+    heads on 8 key-value heads of 128: ``attention_path`` ``kernel``),
+    and the grouped-experts kernel, one an expert layer: 32 slots of 4
+    picks over 256 name two in five of the 32 held, so the step reads
+    the named experts' matrices and not all (``expert_form``), and no
+    buffer holds every slot's row through every held expert; the
+    only scatters are the new token's keys and values; the pool (the
+    full layer's blocks and the rings) aliases its outputs; the op's
+    counters carry the word the kernel's rows are counted in."""
     programs, dec = trinity_programs
     text, mem = programs["decode"]
     assert dec.attention_path == {"decode": "kernel"}
@@ -815,8 +841,10 @@ def test_trinity_decode_step_holds_no_while(trinity_programs):
                              ln), ln
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(calls) == 3 and all("paged_attention_decode" in c
-                                   for c in calls)
+    assert len(calls) == 3 + 2
+    assert sum("paged_attention_decode" in c for c in calls) == 3
+    assert sum("grouped_experts" in c for c in calls) == 2
+    assert [a.shape for a in dec._expert_acc.values()] == [(2, 4 + 32 + 1)] * 2
     # a ring is 64 blocks a row and 33 rows; the full layer's arena every
     # slot's worst case
     shapes = {name: a[0].shape for name, a in dec.pool.kv.items()}
@@ -824,8 +852,9 @@ def test_trinity_decode_step_holds_no_while(trinity_programs):
         == (33 * 64, 64, 1024)
     assert shapes["block1_attn"] == (32 * 272 + 1, 64, 1024)
     assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
-    # every slot through every held expert: the dense form, 32 rows
-    assert "[32,32,3072]" in text
+    # no slot goes through every held expert: the dense form's 32 rows
+    # an expert are gone, and so is a tile of 128 rows
+    assert "[32,32,3072]" not in text and "[128,3072]" not in text
 
 
 @pytest.mark.parametrize("name", ["chunk", "chunk_head"])
@@ -833,9 +862,11 @@ def test_trinity_chunk_programs_hold_no_square(trinity_programs, name):
     """A chunk of 2,048 queries over up to 17,408 keys: no buffer of two
     sequence axes wider than a span of 512 keys (48 x 2,048 x 512 float32
     a span, 201 MB), the experts' products ONE Mosaic call an expert
-    layer that runs (the last layer's runs for the head's row alone and
-    keeps the dense form), and the pieces named: ``window`` inside
-    ``attend`` in the windowed layers, ``gate``, ``route``, ``experts``."""
+    layer that runs (the last layer's runs in the head's chunk only, for
+    the head's row alone, padded to a sublane tile: one call of its own,
+    and no row goes through every held expert), and the pieces named:
+    ``window`` inside ``attend`` in the windowed layers, ``gate``,
+    ``route``, ``experts``."""
     from flexflow_tpu.core.op import parse_scope
 
     text, mem = trinity_programs[0][name]
@@ -859,7 +890,10 @@ def test_trinity_chunk_programs_hold_no_square(trinity_programs, name):
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln
              and "grouped_experts" in ln]
-    assert len(calls) == 1
+    assert len(calls) == (2 if name == "chunk_head" else 1)
+    assert sum("f32[2048,3072]" in c for c in calls) == 1
+    assert sum("f32[8,3072]" in c for c in calls) == len(calls) - 1
+    assert "[32,1,3072]" not in text
     assert mem.temp_size_in_bytes < 2 << 30
     # a request's ring is gathered in the arena's own layout: nothing
     # copies a whole arena (a ring arena is 33 x 4,096 x 1,024 numbers)

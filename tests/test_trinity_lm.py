@@ -468,15 +468,230 @@ def test_the_zoo_preset_builds_and_serves():
 
 
 def test_the_grouped_kernel_plans_a_chunks_experts():
-    """What ``kernels/grouped_experts.plan`` does with a chunk of this
-    model at its published widths (2,048 tokens of 4 picks over 32 held
-    gated experts of 3,072 x 3,072): it takes them, an expert's matrices
-    cut into weight blocks of 1,024 columns; the one row of a prompt's
-    last layer behind the head's cut it refuses, and that row takes the
-    dense form."""
+    """What ``kernels/grouped_experts.plan`` does with the calls of this
+    model at its published widths (4 picks over 32 held gated experts of
+    3,072 x 3,072): a chunk's 2,048 tokens in tiles of 128 rows, an
+    expert's matrices cut into weight blocks of 1,024 columns; a decode
+    step's 32 slots in tiles of their own 32 rows, one an expert at the
+    most, whose smaller rows and output leave room for blocks of 1,536;
+    the one row of a prompt's last layer behind the head's cut as a
+    sublane tile's 8 (``grouped_experts`` pads it); 12 rows, which are no
+    whole sublane tiles, not at all."""
     from flexflow_tpu.kernels import grouped_experts
 
-    assert grouped_experts.plan(2048, 4, 3072, 3072, 32, True,
-                                jnp.bfloat16) == 1024
-    assert grouped_experts.plan(1, 4, 3072, 3072, 32, True,
-                                jnp.bfloat16) is None
+    share = (4, 3072, 3072, 32, True, jnp.bfloat16)
+    assert grouped_experts.plan(2048, *share) == 1024
+    assert grouped_experts.plan(32, *share) == 1536
+    assert grouped_experts.plan(1, *share) == grouped_experts.plan(
+        8, *share) == 1536
+    assert grouped_experts.plan(12, *share) is None
+    assert [grouped_experts.tile_rows(r) for r in (8, 16, 32, 40, 128, 2048)
+            ] == [16, 16, 32, 48, 128, 128]
+    assert [grouped_experts.grid_tiles(r, 4, 32) for r in (8, 32, 128, 2048)
+            ] == [32, 32, 32, 64 + 32]
+
+
+# ---- a call of few rows through the grouped kernel, interpreted --------------
+
+def _held_experts(e=256, width=128):
+    """A routed-experts op over bfloat16 rows at the smallest widths the
+    kernel takes, experts 64-95 of 256 held, top-4, gated: Trinity's
+    share at toy widths; with seeded weights."""
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import OpType
+    from flexflow_tpu.ops.moe_ops import RoutedExperts
+
+    op = RoutedExperts(
+        Layer(OpType.ROUTED_EXPERTS, "x", attrs=dict(
+            n_routed=256, experts_per_token=4, width=width,
+            experts_held=(64, 32), routed_scale=2.448)),
+        [ParallelTensorShape.unpartitioned((1, 8, e), DataType.BFLOAT16)])
+    key = jax.random.key(3)
+    w = {ws.name: (0.08 * jax.random.normal(
+        jax.random.fold_in(key, i), ws.shape)).astype(jnp.bfloat16)
+        for i, ws in enumerate(op.weight_specs())}
+    return op, w
+
+
+def _few_rows_routing(name, rows):
+    """(rows, 4) expert ids of 256, experts 64-95 held, no expert twice
+    in a row's picks."""
+    t = np.arange(rows)
+    if name == "no_pair_held":
+        ids = np.stack([t % 64, 96 + t, 130 + t, 200 + t % 50], 1)
+    elif name == "one_expert_by_all":       # expert 70: one tile, full
+        ids = np.stack([np.full(rows, 70), t % 64, 100 + t, 200 + t], 1)
+    else:                 # a held expert or two a row, most held get none
+        ids = np.stack([64 + (5 * t) % 32, 60 + (t % 8), 100 + t, 200 + t],
+                       1)
+    ids = jnp.asarray(ids, jnp.int32)
+    if name == "idle_rows_masked":          # every other row is padding
+        ids = jnp.where((t % 2 == 0)[:, None], ids, 64 - 1)
+    return ids
+
+
+@pytest.mark.parametrize("routing", ["spread", "no_pair_held",
+                                     "one_expert_by_all", "idle_rows_masked"])
+@pytest.mark.parametrize("rows", [8, 16, 32])
+def test_a_call_of_few_rows_through_the_kernel_is_the_dense_forms(
+        monkeypatch, rows, routing):
+    """A decode step's call (8, 16 and 32 rows of 4 picks over 32 held of
+    256) through the kernel is the dense form's sum to bfloat16's
+    rounding of the terms, for a routing that names a few held experts,
+    one that names none (exactly 0), one that puts every row on one
+    expert (one tile, full) and one whose idle rows' picks were masked
+    (they read nothing and get exactly 0); the rows it counts are a tile
+    of the call's rows (in whole 16s) an expert NAMED."""
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    op, w = _held_experts()
+    v = jax.random.normal(jax.random.key(rows), (rows, 256)
+                          ).astype(jnp.bfloat16)
+    ids = _few_rows_routing(routing, rows)
+    _, gates = op.route(w, v, jnp.maximum(ids, 0))
+    assert op.expert_form(rows) == "kernel"
+    counted = []
+    got = np.asarray(op.apply(w, v, ids, gates, counted), np.float32)
+    want = np.asarray(op._apply_dense(w, v, ids, gates), np.float32)
+    load = np.bincount(np.asarray(ids).ravel() + 1, minlength=257)[65:97]
+    assert [int(c) for c in counted] == [
+        int((load > 0).sum()) * kernel.tile_rows(rows)]
+    if routing == "no_pair_held":
+        assert load.sum() == 0 and not got.any() and not want.any()
+        return
+    scale = np.abs(want).max()
+    assert scale > 0.05 and np.abs(got - want).max() <= 0.01 * scale
+    if routing == "one_expert_by_all":
+        assert load[70 - 64] == rows
+    if routing == "idle_rows_masked":
+        assert not got[1::2].any() and got[0::2].any()
+        assert load.sum() <= 2 * (rows // 2)
+
+
+def test_one_row_is_padded_to_a_tile_and_a_nan_stays_in_its_row(monkeypatch):
+    """The one row behind a head's cut: padded to a sublane tile by rows
+    whose picks name no held expert, it comes back one row, the dense
+    form's. And of 16 rows, a NaN in one reaches no other: every other
+    row is what it is without the NaN."""
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    op, w = _held_experts()
+    v = jax.random.normal(jax.random.key(1), (16, 256)).astype(jnp.bfloat16)
+    ids = _few_rows_routing("spread", 16)
+    _, gates = op.route(w, v, ids)
+    assert op.expert_form(1) == "kernel"
+    counted = []
+    one = op.apply(w, v[3:4], ids[3:4], gates[3:4], counted)
+    assert one.shape == (1, 256) and int(counted[0]) == 16   # one expert
+    want = np.asarray(op._apply_dense(w, v, ids, gates), np.float32)
+    assert np.abs(np.asarray(one, np.float32) - want[3:4]).max() <= (
+        0.01 * np.abs(want).max())
+    good, _ = kernel.grouped_experts(v, ids, gates, w, first=64, gated=True)
+    bad, _ = kernel.grouped_experts(v.at[5, 7].set(jnp.nan), ids, gates, w,
+                                    first=64, gated=True)
+    good, bad = np.asarray(good, np.float32), np.asarray(bad, np.float32)
+    assert np.isnan(bad[5]).all()
+    assert np.array_equal(np.delete(bad, 5, 0), np.delete(good, 5, 0))
+
+
+def test_gradients_of_a_call_of_few_rows_are_the_dense_forms(monkeypatch):
+    """``fit`` reaches the kernel through ``op.forward``: 16 bfloat16
+    rows on one device take ``kernel_form``, whose backward is the jnp
+    grouped form's, and the gradients with respect to the rows and every
+    weight agree with those through the dense form."""
+    op, w = _held_experts()
+    x = jax.random.normal(jax.random.key(8), (1, 16, 256)
+                          ).astype(jnp.bfloat16)
+    ids = _few_rows_routing("spread", 16)
+    ctx = LowerCtx(mesh=None, training=True, aux_losses=[],
+                   compute_dtype=None)
+
+    def loss(w, x):
+        # (the router's own choice at random weights names few of the 32
+        # held: the routing is given, the weights are the router's)
+        x2d = x.reshape(-1, x.shape[-1])
+        _, gates = op.route(w, x2d, ids)
+        y = op.apply(w, x2d, ids, gates, mesh=ctx.mesh)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    assert op.expert_form(16) == "dense"
+    want_loss, want = jax.value_and_grad(loss, (0, 1))(w, x)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert op.expert_form(16) == "kernel"
+    assert "grouped_experts" in jax.make_jaxpr(
+        lambda w, x: op.forward(ctx, [x], w))(w, x).pretty_print()
+    assert "grouped_experts" in jax.make_jaxpr(jax.grad(loss, (0, 1)))(
+        w, x).pretty_print()
+    got_loss, got = jax.jit(jax.value_and_grad(loss, (0, 1)))(w, x)
+    assert float(want_loss) > 0
+    assert abs(float(got_loss) - float(want_loss)) <= 0.01 * float(want_loss)
+    for name in ("router", "w_gate", "w_up", "w_down"):
+        g, h = (np.asarray(a[0][name], np.float32) for a in (got, want))
+        assert np.abs(h).max() > 0, name
+        assert np.abs(g - h).max() <= 0.02 * np.abs(h).max(), name
+    g, h = (np.asarray(a[1], np.float32) for a in (got, want))
+    assert np.abs(g - h).max() <= 0.02 * np.abs(h).max()
+
+
+def test_decode_steps_through_the_kernel_count_their_rows(monkeypatch):
+    """A model whose decode step names a fifth of the experts it holds (8
+    slots of 2 picks over 64, 8 held) takes the kernel for its steps: the
+    logits are the dense form's to bfloat16's rounding, ``form_decode``
+    says so, ``rows_computed`` is the integer the steps counted on the
+    device (a tile of 16 rows an expert the ACTIVE slots named: seven
+    idle slots' padding names none), and the one row behind the head's
+    cut took the kernel too."""
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    config = dict(TOY, hidden_size=256, moe_intermediate_size=128,
+                  num_experts=8, expert_first=16,
+                  published=dict(TOY["published"], num_experts=64))
+    names = family.expert_layer_names(config)
+
+    def run(mode):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        ff = FFModel(FFConfig(batch_size=8, ledger="off", seed=7,
+                              compute_dtype="bfloat16",
+                              computation_mode=CompMode.INFERENCE))
+        build_trinity_lm(ff, 8, MAX_LEN, dataclasses.replace(
+            family.program_config(config), draw_weights=True))
+        ff.compile(optimizer=None, loss_type=None, metrics=[],
+                   mesh=make_mesh(devices=jax.devices()[:1]))
+        dec = PagedDecoder(ff, MAX_LEN, decode_slots=8, block_size=BLOCK,
+                           prefill_chunk=16, calibrate=False)
+        prompt = np.random.default_rng(4).integers(0, 96, 21).astype(
+            np.int32)
+        before = dec.expert_stats()
+        rows, toks, ids = _paged_run(dec, names, prompt, 3, slot=2)
+        return dec, rows, toks, ids, before, dec.expert_stats()
+
+    dec, rows, toks, ids, before, st = run("interpret")
+    assert {op.name: dec._decode_form(op) for op in dec._expert_ops} == {
+        nm: "kernel" for nm in names}
+    for j, nm in enumerate(names):
+        rec = st[nm]
+        assert rec["form_decode"] == "kernel" and rec["steps"] == 3
+        assert before[nm]["rows_computed"] == 0
+        assert len(rec["rows_per_held_expert"]) == 8
+        assert sum(rec["rows_per_held_expert"]) == rec["pairs_held"]
+        # the three steps' own picks (the last three rows of the routing)
+        named = sum(len({e for e in step if 16 <= e < 24})
+                    for step in ids[j][-3:])
+        assert isinstance(rec["rows_computed"], int)
+        assert rec["rows_computed"] == named * kernel.tile_rows(8)
+        assert rec["idle_held_experts"] == 3 * 8 - named
+    # the last layer's experts ran for the prompt's last row alone, padded
+    assert st[names[-1]]["prompt_rows_computed"] in (0, 16, 32)
+    dense, want, toks_dense, _, _, st_dense = run("off")
+    assert {r["form_decode"] for r in st_dense.values()} == {"dense"}
+    assert {r["rows_computed"] for r in st_dense.values()} == {3 * 8 * 8}
+    # (the prompt's logits whatever the steps chose; the steps' where the
+    # two sessions' greedy tokens agree, as they do at these seeds)
+    same = 1 + sum(np.cumprod(toks[21:] == toks_dense[21:]))
+    assert same >= 2
+    assert np.abs(rows - want)[:same].max() <= 0.03 * np.abs(want).max()

@@ -2,13 +2,18 @@
 ``RoutedExperts.expert_form`` (``ops/moe_ops.py``) and
 ``kernels/grouped_experts.py``.
 
-    chiprun -- python tools/expert_forms_crossover.py
+    chiprun -- python tools/expert_forms_crossover.py [layer ...]
 
 On the chip only (it exits 2 anywhere else: a CPU timing is no speed).
 For each expert layer in ``LAYERS`` (the Nemotron-3-Super share: 128 of
 512 experts held, top-22, squared ReLU inside a 1024-wide latent; the
-A.X-K1 share: 12 of 192 held, top-8, gated SiLU at 7168), each count of
-rows in ``ROWS`` (a decode step's 128 slots, the prefill buckets) and two
+A.X-K1 share: 12 of 192 held, top-8, gated SiLU at 7168; the
+Trinity-Large share: 32 of 256 held, top-4, gated SiLU at 3072; all of
+them, or those named), each count of its rows (a decode step's 128
+slots and the prefill buckets; for the Trinity share the calls under the
+ridge, from a head's one row over a step's 32 slots to 240, where the
+share of the held experts a call can name, ``named_share``, goes from 2
+to 98 %: what places ``NAMED_SHARE_KERNEL``) and two
 routings (``uniform``: the op's own over random rows; ``uneven``: experts
 drawn by a log-normal popularity, ``UNEVEN_SIGMA``, which loads the
 busiest held expert some six times the mean, as the agents cell's random
@@ -27,8 +32,9 @@ the kernel counted, and the largest difference of the grouped forms'
 outputs from the dense one's over its range. One JSON line a row on
 stdout, the table again under
 ``chiprun_out/expert_forms_crossover.json``. Nothing reads that file:
-``RIDGE_ROWS`` and ``CAPACITY_SHARE`` are edited by hand from it, and
-PERF.md section 6 keeps the table they were edited from.
+``RIDGE_ROWS``, ``NAMED_SHARE_KERNEL`` and ``CAPACITY_SHARE`` are edited
+by hand from it, and PERF.md section 6 keeps the tables they were edited
+from.
 """
 
 from __future__ import annotations
@@ -43,16 +49,21 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 ROWS = (128, 256, 512, 768, 1024)
+ROWS_FEW = (1, 8, 16, 32, 64, 128, 240)
 REPEATS = 10
 UNEVEN_SIGMA = 1.0
-LAYERS = {
+LAYERS = {   # the model's width, the op's attributes, the rows of a call
     "nemotron3-super-ep4": (4096, dict(
         n_routed=512, experts_per_token=22, width=2688,
         experts_held=(0, 128), latent=1024, activation="relu2",
-        selection_bias=True, routed_scale=5.0)),
+        selection_bias=True, routed_scale=5.0), ROWS),
     "axk1-ep16": (7168, dict(
         n_routed=192, experts_per_token=8, width=2048,
-        experts_held=(0, 12), n_group=8, topk_group=4, routed_scale=2.5)),
+        experts_held=(0, 12), n_group=8, topk_group=4, routed_scale=2.5),
+        ROWS),
+    "trinity-large-ep8": (3072, dict(
+        n_routed=256, experts_per_token=4, width=3072,
+        experts_held=(0, 32), routed_scale=2.448), ROWS_FEW),
 }
 
 
@@ -67,7 +78,7 @@ def uneven_ids(key, rows: int, n_routed: int, k: int):
     return jax.lax.top_k(logits, k)[1]
 
 
-def main() -> int:
+def main(layers=()) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -79,7 +90,8 @@ def main() -> int:
     from flexflow_tpu.core.layer import Layer
     from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
     from flexflow_tpu.ffconst import DataType, OpType
-    from flexflow_tpu.kernels.grouped_experts import grouped_experts
+    from flexflow_tpu.kernels.grouped_experts import (grouped_experts,
+                                                      supported)
     from flexflow_tpu.ops.moe_ops import RoutedExperts
 
     def timed(fn, *args):
@@ -94,7 +106,8 @@ def main() -> int:
                 1e3 * (time.perf_counter() - t1) / REPEATS)
 
     table = []
-    for name, (e, attrs) in LAYERS.items():
+    for name in layers or LAYERS:
+        e, attrs, rows_of = LAYERS[name]
         op = RoutedExperts(
             Layer(OpType.ROUTED_EXPERTS, "experts", attrs=attrs),
             [ParallelTensorShape.unpartitioned((1, 8, e),
@@ -110,13 +123,14 @@ def main() -> int:
                  "kernel": jax.jit(lambda w, v, ids, gates: grouped_experts(
                      v, ids, gates, w, first=op.first, gated=op.gated))}
         route = jax.jit(op.route)
-        for rows in ROWS:
+        for rows in rows_of:
             x = jax.random.normal(jax.random.fold_in(key, rows), (rows, e),
                                   jnp.float32).astype(jnp.bfloat16)
             v = x if not op.latent else jnp.dot(
                 x, weights["latent_down"]).astype(jnp.bfloat16)
             for routing in ("uniform", "uneven"):
-                if routing == "uneven" and rows <= 256:
+                if routing == "uneven" and rows <= 256 and (
+                        rows_of is not ROWS_FEW):
                     continue
                 ids, gates = route(weights, x) if routing == "uniform" else (
                     route(weights, x, uneven_ids(
@@ -127,12 +141,16 @@ def main() -> int:
                     op.first:op.first + op.count]
                 row = {"layer": name, "rows": rows, "routing": routing,
                        "rule": op.expert_form(rows),
+                       "named_share": op.named_share(rows),
+                       "experts_named": int((load > 0).sum()),
                        "pairs_held": int(load.sum()),
                        "load_max_over_mean": float(
                            load.max() / max(load.mean(), 1e-30))}
                 outs = {}
                 for form, fn in forms.items():
-                    if form == "kernel" and row["rule"] != "kernel":
+                    if form == "kernel" and not supported(
+                            rows, op.k, op.work_dim, op.width, op.count,
+                            op.gated, v.dtype):
                         row["kernel_ms"] = None
                         continue
                     try:
@@ -162,4 +180,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))
