@@ -18,9 +18,20 @@ that keep the working set in VMEM and feed the MXU directly:
   (``serving/cache_entry.py`` ``WindowEntry``) is read by the same
   kernel: its block table is made in the program from the slots' rows
   and the lengths are clamped to the ring, so a step reads ``min(n,
-  window)`` rows a slot. No kernel reads a CHUNK's keys yet: a chunked
-  prefill's attention over plain keys and values is a walk over key
-  spans in jnp (``cache_entry._attend_spans``).
+  window)`` rows a slot.
+* :mod:`chunk_attention` — a chunked prefill's attention over plain keys
+  and values: a chunk's queries over a windowed layer's ``[ring |
+  chunk]`` rows or a full layer's rows gathered through its table, by
+  absolute position (the positions are data: a ring is stored rotated),
+  the ``H / Hkv`` query heads of a group over the one key tile, a
+  running softmax in VMEM, and only the key blocks some query of a
+  block can see visited, from a table made in jnp and scalar-prefetched
+  (``serving/cache_entry.py`` ``PairEntry.chunk_path`` chooses; the walk
+  over key spans in jnp, ``cache_entry._attend_spans``, stays for what
+  ``supported()`` refuses: the CPU, heads of no whole lane tiles such as
+  GPT-2's 64, int8 pairs, a model over more than one device). It reads
+  a COPY of the table's rows; reading the table in place, as
+  ``paged_attention`` does, is not written.
 * :mod:`gated_delta` — the gated delta rule of a linear-attention layer:
   the decode step over a pool's per-request states in place, and the
   whole-sequence form of a prefill as one kernel a layer, the state in

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 
 from ..core.op import sub_scope
 from ..ffconst import OpType
-from ..kernels import gated_delta, latent_attention, paged_attention
+from ..kernels import (chunk_attention, gated_delta, latent_attention,
+                       paged_attention)
 from ..ops import block_sparse_attention as bsa
 from ..ops import mamba2
 from ..ops.gated_delta import delta_rule_path
@@ -80,12 +81,15 @@ def _attend(q, k, v, mask, scale):
     return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(b, sq, h, d)
 
 
-# keys one step of a chunk's attend scores (:func:`_attend_spans`): the
-# scores of a span are (heads, chunk, SPAN_TOKENS) float32, the only array
-# of two sequence axes a chunk's attention holds
+# keys one step of the WALK a chunk's attend takes where the kernel does
+# not (:func:`_attend_spans`: the CPU, head widths of no whole lane tiles,
+# a model over more than one device): the scores of a span are (heads,
+# chunk, SPAN_TOKENS) float32 in HBM, the only array of two sequence axes
+# a chunk's attention holds. Where ``kernels/chunk_attention.py`` takes
+# the chunk (:meth:`PairEntry.chunk_path`) no such array is made at all
 SPAN_TOKENS = 512
 # the position of a key that holds nothing: later than any query
-NOWHERE = 2 ** 30
+NOWHERE = chunk_attention.NOWHERE
 
 
 def _attend_spans(op, q, qpos, kv_heads, read, lo, hi):
@@ -124,6 +128,17 @@ def _attend_spans(op, q, qpos, kv_heads, read, lo, hi):
                        jnp.zeros(shape + (d,), f32)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]        # (B, Hkv, G, S, D)
     return jnp.moveaxis(out, 3, 1).reshape(b, s, h, d).astype(q.dtype)
+
+
+def _attend_kernel(op, q, qpos, keys, values, kpos):
+    """The same chunk through ``kernels/chunk_attention.py``: (B, S, H, D)
+    queries over (B, L, Hkv D) keys and values in the arena's row layout,
+    row r at position ``kpos[:, r]``. Returns (B, S, H, D)."""
+    b, s, h, d = q.shape
+    return chunk_attention.chunk_attention(
+        q.reshape(b, s, h * d), qpos, keys, values, kpos,
+        kv_heads=keys.shape[-1] // d, scale=op.scale,
+        window=op.window).reshape(q.shape)
 
 
 def _put(arena, flat, rows):
@@ -231,7 +246,8 @@ class EntryKind:
     (P,) multiples of the block size), BEHIND what the chunks before it
     left where ``addr`` says; a chunk at offset 0 starts from nothing.
     Only such kinds serve a prompt in chunks
-    (``PagedDecoder(prefill_chunk=...)``). ``step`` and ``chunk`` may
+    (``PagedDecoder(prefill_chunk=...)``); ``chunk_path`` says which form
+    its attention takes. ``step`` and ``chunk`` may
     return a third value, the ids of what the op chose to read (a
     selection of blocks), which the programs keep for whoever asks."""
 
@@ -265,6 +281,17 @@ class EntryKind:
         """How :meth:`prefill` computes a ``bucket`` of tokens, for a kind
         whose prefill has more than one form; None for one that has one."""
         return None
+
+    def chunk_path(self, entry, prompts: int, chunk: int, max_blocks: int,
+                   dtype) -> str:
+        """How :meth:`chunk` attends ``prompts`` chunks of ``chunk``
+        queries of ``dtype`` over ``entry``: ``"kernel"``
+        (``kernels/chunk_attention.py``) or ``"scan"`` (a walk in jnp)."""
+        return "scan"
+
+    def over(self, devices: int) -> "EntryKind":
+        """This kind in a model over ``devices`` devices."""
+        return self
 
     def token_bytes(self, dtype) -> int:
         """Bytes one token (one request, for a ``per_request`` kind) takes
@@ -300,6 +327,9 @@ class PairEntry(EntryKind):
     heads: int
     head_dim: int
     query_heads: int = 0
+    # devices the model's programs run over: the chunk's kernel has no
+    # ``shard_map`` composition and is one device's (``kernels.use_pallas``)
+    devices: int = dataclasses.field(default=1, kw_only=True, compare=False)
     name = "pair"
     chunked = True
 
@@ -321,6 +351,24 @@ class PairEntry(EntryKind):
     @property
     def int8_form(self):
         return Int8PairEntry(self.heads, self.head_dim, self.query_heads)
+
+    def over(self, devices):
+        return dataclasses.replace(self, devices=int(devices))
+
+    def _chunk_keys(self, entry, chunk: int, max_blocks: int) -> int:
+        """Key rows a chunk's queries are attended over."""
+        return max_blocks * entry[0].shape[1]
+
+    def chunk_path(self, entry, prompts, chunk, max_blocks, dtype):
+        """``"kernel"`` by what the program can see, no knob: a model
+        over one device and what ``chunk_attention.supported`` takes (a
+        TPU backend, heads of whole lane tiles, bfloat16 or float32 rows
+        of the queries' dtype)."""
+        rows = entry[0]
+        return "kernel" if self.devices == 1 and chunk_attention.supported(
+            (prompts, chunk, self.query_heads or self.heads, self.head_dim),
+            dtype, (prompts, self._chunk_keys(entry, chunk, max_blocks),
+                    rows.shape[-1]), rows.dtype) else "scan"
 
     def stats(self):
         if not self.query_heads:
@@ -365,8 +413,10 @@ class PairEntry(EntryKind):
 
     def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
         """The chunk's rows written through the tables, then each prompt's
-        blocks attended through its table a span at a time, as far as the
-        longest prompt of the group has got."""
+        blocks attended through its table: by the kernel over the table's
+        rows gathered once (:meth:`chunk_path`; the blocks past what the
+        longest prompt of the group has got to hold nothing and are not
+        visited), else a span at a time as far as that."""
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
         bs = entry[0].shape[1]
         n, s = x.shape[:2]
@@ -382,6 +432,14 @@ class PairEntry(EntryKind):
         entry = self.write(entry, flat.reshape(-1),
                            kh.reshape(n * s, heads, hdim),
                            vh.reshape(n * s, heads, hdim))
+        if self.chunk_path(entry, n, s, mb, qh.dtype) == "kernel":
+            with sub_scope("attend"):
+                at = _iota(mb * bs)[None, :]
+                ctxv = _attend_kernel(
+                    op, qh, pos, *(a[tables].reshape(n, mb * bs, -1)
+                                   for a in entry),
+                    jnp.where(at < (offsets + lengths)[:, None], at, NOWHERE))
+            return op.project_out(weights, ctxv, x), entry
         per = max(1, SPAN_TOKENS // bs)            # blocks a span
         span = per * bs
         padded = jnp.pad(tables, ((0, 0), (0, -mb % per)),
@@ -550,6 +608,9 @@ class WindowEntry(PairEntry):
              self.heads * self.head_dim), dtype)
         return (a, a)
 
+    def _chunk_keys(self, entry, chunk, max_blocks):
+        return self.window + chunk
+
     def _tables(self, entry, rows):
         """The block table of each request's ring, (N, ring)."""
         ring = self.ring_blocks(entry[0].shape[1])
@@ -605,30 +666,36 @@ class WindowEntry(PairEntry):
         # (a request's ring is taken in the arena's own layout, rows of
         # all heads side by side: splitting the heads first would copy
         # the whole arena into another tiling)
-        rings = tuple(a.reshape(-1, w, heads * hdim)[addr.rows].reshape(
-            n, w, heads, hdim) for a in entry)
-        span = SPAN_TOKENS
-        pad = -(w + s) % span
-        keys, values = (jnp.pad(jnp.concatenate([ring, new.astype(ring.dtype)],
-                                                axis=1),
-                                ((0, 0), (0, pad), (0, 0), (0, 0)))
-                        for ring, new in zip(rings, (kh, vh)))
-        kpos = jnp.pad(jnp.concatenate(
-            [jnp.where(held >= 0, held, NOWHERE),
-             jnp.where(live, pos, NOWHERE)], axis=1), ((0, 0), (0, pad)),
-            constant_values=NOWHERE)
-
-        def read(j):
-            return tuple(jax.lax.dynamic_slice_in_dim(a, j * span, span, 1)
-                         for a in (keys, values, kpos))
-
+        keys, values = (
+            jnp.concatenate([a.reshape(-1, w, heads * hdim)[addr.rows],
+                             new.reshape(n, s, -1).astype(a.dtype)], axis=1)
+            for a, new in zip(entry, (kh, vh)))
+        kpos = jnp.concatenate([jnp.where(held >= 0, held, NOWHERE),
+                                jnp.where(live, pos, NOWHERE)], axis=1)
         with sub_scope("attend"), sub_scope("window"):
-            # a group of first chunks skips the ring, a short last chunk
-            # the spans past its length
-            ctxv = _attend_spans(
-                op, qh, pos, heads, read,
-                jnp.where(jnp.all(offsets == 0), w // span, 0),
-                (w + jnp.max(lengths) + span - 1) // span)
+            # a group of first chunks' rings hold nothing, a short last
+            # chunk's rows past its length neither: the kernel visits
+            # none of their blocks, the walk skips their spans
+            if self.chunk_path(entry, n, s, 0, qh.dtype) == "kernel":
+                ctxv = _attend_kernel(op, qh, pos, keys, values, kpos)
+            else:
+                span = SPAN_TOKENS
+                pad = -(w + s) % span
+                keys, values = (
+                    jnp.pad(a, ((0, 0), (0, pad), (0, 0))).reshape(
+                        n, -1, heads, hdim) for a in (keys, values))
+                kpos = jnp.pad(kpos, ((0, 0), (0, pad)),
+                               constant_values=NOWHERE)
+
+                def read(j):
+                    return tuple(
+                        jax.lax.dynamic_slice_in_dim(a, j * span, span, 1)
+                        for a in (keys, values, kpos))
+
+                ctxv = _attend_spans(
+                    op, qh, pos, heads, read,
+                    jnp.where(jnp.all(offsets == 0), w // span, 0),
+                    (w + jnp.max(lengths) + span - 1) // span)
         keep = live & (pos >= (offsets + lengths)[:, None] - w)
         flat = jnp.where(keep, addr.rows[:, None], NULL_ROW) * w + pos % w
         entry = self.write(entry, flat.reshape(-1),

@@ -238,10 +238,13 @@ class _DecodeGraph:
         self._pos_id = (cm.input_tensors[1] if len(cm.input_tensors) > 1
                         else None)
         pos_tid = None if self._pos_id is None else self._pos_id.tensor_id
-        # what each attention op keeps for a token, chosen once
+        # what each attention op keeps for a token, chosen once (and told
+        # how many devices the model's programs run over)
         kinds = {op.name: kind_for(op, pos_tid, self.max_length)
                  for op in cm.ops}
-        self._kinds = {name: k for name, k in kinds.items() if k is not None}
+        devices = 1 if cm.mesh is None else cm.mesh.size
+        self._kinds = {name: k.over(devices) for name, k in kinds.items()
+                       if k is not None}
         self._attn_ops = [op for op in cm.ops if op.name in self._kinds]
         self._expert_ops = [op for op in cm.ops
                             if op.op_type is OpType.ROUTED_EXPERTS]
@@ -614,9 +617,12 @@ class PagedDecoder(_DecodeGraph):
         self._verify_fns: Dict[int, object] = {}
         # how each program's attention reads the pool, fixed when the
         # program is built: "kernel" (paged attention, in place) or
-        # "gather" (the jnp path); "verify" appears with its program
-        self.attention_path: Dict[str, str] = {
-            "decode": self._attention_path(1)}
+        # "gather" (the jnp path); "verify" appears with its program.
+        # "chunk": how a prompt's chunk is attended, "kernel" (every
+        # kind's chunk through kernels/chunk_attention.py) or "scan" (a
+        # walk in jnp); None where prompts are prefilled whole
+        self.attention_path: Dict[str, Optional[str]] = {
+            "decode": self._attention_path(1), "chunk": self._chunk_path()}
         self._prefill_fns: Dict[Tuple[int, int], object] = {}
         # how the prefill programs run the recurrence of the ops that
         # keep a state, fixed when a program is built, from the rule its
@@ -929,6 +935,17 @@ class PagedDecoder(_DecodeGraph):
                 op, self.pool.kv[op.name], self.decode_slots, window,
                 self.max_blocks_per_request)
             for op in self._attn_ops) else "gather"
+
+    def _chunk_path(self) -> Optional[str]:
+        """What a chunk program does with the pool as it is now."""
+        if not self.prefill_chunk:
+            return None
+        return "kernel" if all(
+            self.pool.kinds[op.name].chunk_path(
+                self.pool.kv[op.name], 1, self.prefill_chunk,
+                self.max_blocks_per_request,
+                self._compute_dtype() or jnp.float32) == "kernel"
+            for op in self._attn_ops) else "scan"
 
     def _prefill_path(self, bucket: int) -> Optional[str]:
         said = {kind.prefill_path(bucket) for kind in self.pool.kinds.values()}
@@ -1280,6 +1297,7 @@ class PagedDecoder(_DecodeGraph):
         self.kv_dtype = "float32"
         self.pool = self._new_pool(self.pool.num_blocks)  # concurrency: race-ok (calibration runs inside __init__, before the scheduler's thread or any stats() reader exists)
         self.attention_path["decode"] = self._attention_path(1)
+        self.attention_path["chunk"] = self._chunk_path()
 
 
 def build_draft_model(ff, spec: str):

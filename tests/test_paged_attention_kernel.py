@@ -184,7 +184,7 @@ def test_refused_entries_take_the_jnp_path(monkeypatch):
             dec = PagedDecoder(ff, max_length=32, decode_slots=2,
                                block_size=8, kv_dtype=kv_dtype,
                                kv_divergence_budget=10.0)
-            assert dec.attention_path == {"decode": "gather"}
+            assert dec.attention_path == {"decode": "gather", "chunk": None}
             table = dec.pool.try_admit(8)
             dec.prefill(prompt, table)
             tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
@@ -217,7 +217,7 @@ def test_decoder_reports_the_kernel_path(monkeypatch):
     for mode, path in (("interpret", "kernel"), ("off", "gather")):
         monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
         dec = PagedDecoder(ff, max_length=64, decode_slots=2, block_size=16)
-        assert dec.attention_path == {"decode": path}
+        assert dec.attention_path == {"decode": path, "chunk": None}
         table = dec.pool.try_admit(40)
         dec.prefill(prompt, table)
         tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
@@ -226,7 +226,8 @@ def test_decoder_reports_the_kernel_path(monkeypatch):
         step = dec.decode(np.array([7, 0], np.int32), tables, lens)[0]
         window = dec.verify(np.array([[7, 9, 11], [0, 0, 0]], np.int32),
                             tables, lens)[0]
-        assert dec.attention_path == {"decode": path, "verify": path}
+        assert dec.attention_path == {"decode": path, "chunk": None,
+                                      "verify": path}
         out[mode] = (step, window)
     for got, want in zip(out["interpret"], out["off"]):
         assert float(np.abs(got - want).max()) <= 1e-4 * float(

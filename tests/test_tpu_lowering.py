@@ -675,7 +675,7 @@ def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
 
     programs, dec = nemotron_programs
     text, mem = programs["decode"]
-    assert dec.attention_path == {"decode": "kernel"}
+    assert dec.attention_path == {"decode": "kernel", "chunk": None}
     assert " while(" not in text
     assert "dynamic-update-slice" not in text
     # the only scatter is the ``*`` layer's: a token's keys and values
@@ -833,7 +833,7 @@ def test_trinity_decode_step_holds_no_while(trinity_programs):
     counters carry the word the kernel's rows are counted in."""
     programs, dec = trinity_programs
     text, mem = programs["decode"]
-    assert dec.attention_path == {"decode": "kernel"}
+    assert dec.attention_path == {"decode": "kernel", "chunk": "kernel"}
     assert " while(" not in text and " conditional(" not in text
     for ln in text.splitlines():
         if " scatter(" in ln:
@@ -860,8 +860,10 @@ def test_trinity_decode_step_holds_no_while(trinity_programs):
 @pytest.mark.parametrize("name", ["chunk", "chunk_head"])
 def test_trinity_chunk_programs_hold_no_square(trinity_programs, name):
     """A chunk of 2,048 queries over up to 17,408 keys: no buffer of two
-    sequence axes wider than a span of 512 keys (48 x 2,048 x 512 float32
-    a span, 201 MB), the experts' products ONE Mosaic call an expert
+    sequence axes at all (the attention of each layer that attends is
+    ONE Mosaic call, ``chunk_attention``, its scores in VMEM; the span
+    walk it replaces wrote 48 x 2,048 x 512 float32 a span, 201 MB, and
+    its ``while`` is gone), the experts' products ONE Mosaic call an expert
     layer that runs (the last layer's runs in the head's chunk only, for
     the head's row alone, padded to a sublane tile: one call of its own,
     and no row goes through every held expert), and the pieces named:
@@ -887,9 +889,16 @@ def test_trinity_chunk_programs_hold_no_square(trinity_programs, name):
             ("MULTIHEAD_ATTENTION", "write"),
             ("ROUTED_EXPERTS", "route"),
             ("ROUTED_EXPERTS", "experts")} <= subs, subs
-    calls = [ln for ln in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln
-             and "grouped_experts" in ln]
+    mosaic = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    attends = [ln for ln in mosaic if "chunk_attention" in ln]
+    # (a chunk that is not its prompt's last ends behind the last
+    # attention op's write: nothing reads what that op would attend)
+    assert len(attends) == (3 if name == "chunk_head" else 2)
+    assert " while(" not in text
+    assert {parse_scope(re.search(r'op_name="([^"]+)"', ln).group(1))[2]
+            for ln in attends} == {("attend",), ("attend", "window")}
+    calls = [ln for ln in mosaic if "grouped_experts" in ln]
     assert len(calls) == (2 if name == "chunk_head" else 1)
     assert sum("f32[2048,3072]" in c for c in calls) == 1
     assert sum("f32[8,3072]" in c for c in calls) == len(calls) - 1

@@ -48,7 +48,7 @@ MAX_LEN = 96
 BLOCK = 8
 
 
-def _program(config, seed=SEED, slots=3, max_len=MAX_LEN):
+def _program(config, seed=SEED, slots=3, max_len=MAX_LEN, mesh=None):
     """The program's graph for ``config`` in float32 holding the
     reference's seeded weights; returns (ff, weights)."""
     cfg = dataclasses.replace(family.program_config(config),
@@ -56,7 +56,7 @@ def _program(config, seed=SEED, slots=3, max_len=MAX_LEN):
     ff = FFModel(FFConfig(batch_size=slots, ledger="off",
                           computation_mode=CompMode.INFERENCE))
     build_trinity_lm(ff, slots, max_len, cfg)
-    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    ff.compile(optimizer=None, loss_type=None, metrics=[], mesh=mesh)
     weights = reference.init_weights(config, seed)
     ff.compiled.params = jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32), family.to_program(weights, config))
@@ -80,6 +80,19 @@ def _layer(weights, i):
 @pytest.fixture(scope="module")
 def toy():
     return _program(TOY)
+
+
+# the toy at the width the chunk's kernel takes: heads of one lane tile
+LANE_TOY = dict(TOY, head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def lane_toy():
+    """The toy with heads of 128 in a model over ONE device (the tests'
+    eight virtual devices are a mesh the kernel does not take)."""
+    from flexflow_tpu.core.machine import make_mesh
+
+    return _program(LANE_TOY, mesh=make_mesh(devices=jax.devices()[:1]))
 
 
 @pytest.fixture()
@@ -204,22 +217,31 @@ def _against_reference(config, weights, rows, toks, ids):
                      # across the ring's wrap at 32
     (40, 8, 20),     # a chunk of one block; decode past another ring
 ], ids=["under", "at", "past", "padding-wraps", "block-chunks"])
+@pytest.mark.parametrize("form", ["scan", "kernel"])
 def test_chunked_prefill_and_decode_equal_the_references_forward(
-        toy, short_spans, n, chunk, steps):
+        request, monkeypatch, short_spans, form, n, chunk, steps):
     """A prompt prefilled in chunks (each behind what the chunks before
     left: the full layer through its block table, a windowed layer over
     ``[its ring | the chunk]``), then decode steps (the windowed layers
     read ``min(n, 16)`` rows of their rings): the LOGITS of the
     reference's cache-free forward over the whole sequence. 2e-4 of the
-    logits' range: float32 summation order."""
-    ff, weights = toy
+    logits' range: float32 summation order. ``scan``: the chunks' span
+    walk on the CPU; ``kernel``: the chunk programs through
+    ``kernels/chunk_attention.py`` under the interpreter, heads of 128
+    (tiles of the chunk's 8, 16 or 8 queries by 24, 32 or 24 keys)."""
+    if form == "kernel":
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    config = LANE_TOY if form == "kernel" else TOY
+    ff, weights = request.getfixturevalue(
+        "lane_toy" if form == "kernel" else "toy")
     dec = PagedDecoder(ff, MAX_LEN, decode_slots=3, block_size=BLOCK,
                        prefill_chunk=chunk, calibrate=False)
-    names = family.expert_layer_names(TOY)
+    assert dec.attention_path["chunk"] == form
+    names = family.expert_layer_names(config)
     prompt = np.random.default_rng(n).integers(
-        0, TOY["vocab_size"], n).astype(np.int32)
+        0, config["vocab_size"], n).astype(np.int32)
     rows, toks, ids = _paged_run(dec, names, prompt, steps, slot=1)
-    want = _against_reference(TOY, weights, rows, toks, ids)
+    want = _against_reference(config, weights, rows, toks, ids)
     assert np.abs(rows - want).max() <= 2e-4 * np.abs(want).max()
     kv = dec.pool.stats()
     assert kv["entry"] == {"window": 3, "pair": 1} and kv["window"] == 16
@@ -444,6 +466,81 @@ def test_the_scheduler_counts_the_windows_rows_and_the_chunks_keys(toy):
     assert set(moe) == set(family.expert_layer_names(TOY))
     assert 0 < moe["block1_experts"]["prompt_pairs_held"] <= 49 * 2
     assert moe["block3_experts"]["prompt_pairs_held"] <= 2 * 2
+
+
+@pytest.mark.parametrize("form", ["scan", "kernel"])
+def test_the_scheduler_says_how_a_chunk_is_attended(request, monkeypatch,
+                                                    form):
+    """``stats()["kv"]["attention_path"]["chunk"]``: ``"kernel"`` where
+    every kind's chunk goes through ``kernels/chunk_attention.py`` (the
+    toy with heads of 128 over one device, under the interpreter),
+    ``"scan"`` on the CPU, and the same tokens either way as the
+    cache-free generator's."""
+    from flexflow_tpu.serving.generation import Generator
+
+    if form == "kernel":
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    ff, _ = request.getfixturevalue("lane_toy" if form == "kernel" else "toy")
+    prompt = np.random.default_rng(3).integers(0, 96, 21).astype(np.int32)
+    inst = GenerationInstance(ff, decode_slots=3, block_size=BLOCK,
+                              max_length=MAX_LEN, prefill_chunk=16)
+    try:
+        out = inst.generate(prompt, max_new_tokens=4, temperature=0.0)
+        paths = inst.stats()["kv"]["attention_path"]
+    finally:
+        inst.stop()
+    assert paths["chunk"] == form
+    assert paths["decode"] == ("kernel" if form == "kernel" else "gather")
+    assert np.array_equal(out, Generator(ff, MAX_LEN, batch_size=1).generate(
+        prompt[None], 4)[0])
+
+
+def test_the_chunks_kernel_refuses_what_it_cannot_take(monkeypatch):
+    """``chunk_path`` is ``"kernel"`` for heads of 128 in float32 or
+    bfloat16 rows under the interpreter, and ``"scan"`` for GPT-2's head
+    width, an int8 pair, the CPU without the interpreter and a model
+    over more than one device; a decoder that takes no chunks says None.
+    The plain-attention model's chunk program lowers to the same text
+    with the interpreter on and with Pallas off: it keeps the walk."""
+    from flexflow_tpu.models import GPTConfig, build_gpt
+    from flexflow_tpu.serving.cache_entry import Int8PairEntry, PairEntry
+
+    def path(kind, dtype=jnp.float32, store=jnp.float32):
+        entry = tuple(jnp.zeros(a.shape, a.dtype)
+                      for a in kind.arenas(4, BLOCK, store))
+        return kind.chunk_path(entry, 1, 16, 12, dtype)
+
+    lanes = PairEntry(2, 128, 4)
+    assert path(lanes) == "scan"                       # the CPU
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    assert path(lanes) == "kernel"
+    assert path(lanes, jnp.bfloat16, jnp.bfloat16) == "kernel"
+    assert path(cache_entry.WindowEntry(2, 128, 4, 16)) == "kernel"
+    assert path(lanes, jnp.float32, jnp.bfloat16) == "scan"  # two dtypes
+    assert path(PairEntry(2, 64, 4)) == "scan"
+    assert path(Int8PairEntry(2, 128, 4)) == "scan"
+    assert path(lanes.over(8)) == "scan" and lanes.over(8) == lanes
+
+    ff = FFModel(FFConfig(batch_size=2, ledger="off", seed=3,
+                          computation_mode=CompMode.INFERENCE))
+    build_gpt(ff, 2, 64, GPTConfig(vocab_size=64, max_positions=64,
+                                   hidden_size=16, num_heads=2, num_layers=2))
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    texts = []
+    for mode in ("interpret", "off"):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        dec = PagedDecoder(ff, 64, decode_slots=2, block_size=8,
+                           calibrate=False, prefill_chunk=16)
+        assert dec.attention_path["chunk"] == "scan"
+        assert PagedDecoder(ff, 64, decode_slots=2, block_size=8,
+                            calibrate=False).attention_path["chunk"] is None
+        tabs = np.zeros((1, dec.max_blocks_per_request), np.int32)
+        texts.append(jax.jit(
+            lambda *a: dec._chunk_step(*a, head=True)).lower(
+            dec._exec_params(), jnp.zeros((1, 16), jnp.int32), dec.pool.kv,
+            dec._addresses(tabs), jnp.zeros((1,), jnp.int32),
+            jnp.full((1,), 16, jnp.int32)).as_text())
+    assert texts[0] == texts[1] and "while" in texts[0]
 
 
 def test_the_zoo_preset_builds_and_serves():
