@@ -33,6 +33,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.op import sub_scope
 from ..ffconst import OpType
@@ -267,15 +268,22 @@ class EntryKind:
         return None
 
     def rows_read(self, length: int) -> Optional[int]:
-        """Rows of a request a step behind ``length`` cached tokens
-        reads, for a kind that keeps and reads at most a window of them;
-        None for one that keeps them all."""
+        """Rows of a request a step behind ``length`` cached tokens (an
+        int, or an array of them) reads, for a kind that keeps and reads
+        at most a window of them; None for one that keeps them all."""
         return None
 
     def side_rows(self, length: int) -> int:
         """Rows a request of ``length`` cached tokens holds beside its
         row a token (pooled keys)."""
         return 0
+
+    def step_reads(self, lengths) -> Dict[str, Dict[str, int]]:
+        """What one step reads of ONE op of this kind, summed over slots
+        behind ``lengths`` (an int array) cached tokens, under the word
+        the pool's books keep it by; empty for a kind that reads all it
+        keeps."""
+        return {}
 
     def prefill_path(self, bucket: int) -> Optional[str]:
         """How :meth:`prefill` computes a ``bucket`` of tokens, for a kind
@@ -593,7 +601,12 @@ class WindowEntry(PairEntry):
         return dict(super().stats(), entry=self.name, window=self.window)
 
     def rows_read(self, length):
-        return min(int(length) + 1, self.window)
+        return np.minimum(np.asarray(length) + 1, self.window)
+
+    def step_reads(self, lengths):
+        return {"window": {"rows_read": int(self.rows_read(lengths).sum()),
+                           "rows_full": int((lengths + 1).sum()),
+                           "rows_reserved": len(lengths) * self.window}}
 
     def ring_blocks(self, block_size: int) -> int:
         if self.window % block_size:
@@ -975,6 +988,11 @@ class SparseEntry(EntryKind):
 
     def side_rows(self, length):
         return self.geom.kernels_in(length)
+
+    def step_reads(self, lengths):
+        return {"selected": {
+            "blocks_read": sum(self.blocks_read(int(n)) for n in lengths),
+            "blocks_live": int((lengths // self.geom.block + 1).sum())}}
 
     def reads_in_place(self, op, entry, slots, window, max_blocks):
         return False                    # no kernel yet: a step gathers

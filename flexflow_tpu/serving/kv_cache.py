@@ -61,6 +61,12 @@ table's request by its first block, which no other live request holds),
 :meth:`PagedKVPool.free` returns both, and the bytes count both. Inside
 the programs the two travel together as :class:`Addresses`.
 
+**The books.** What the decode steps read is the pool's to count, since
+the kinds, the geometry and ``stats()["kv"]`` are its own: the serving
+loop hands :meth:`PagedKVPool.count_step` a step's cached lengths and
+:meth:`PagedKVPool.chunk_keys` a chunk's span, and each kind says what a
+step behind such lengths reads of it (``step_reads``, ``rows_read``).
+
 **Quantized arenas** (``kv_dtype``): the pool can store its arenas in
 ``"bfloat16"`` (cast-in/cast-out) or ``"int8"``: each op's entry in its
 kind's int8 form (:class:`~flexflow_tpu.serving.cache_entry
@@ -216,6 +222,21 @@ class PagedKVPool:
         self._rows_high_water = 0
         self._mu = threading.Lock()
         self._high_water = 0
+        # running sums over the decode steps count_step was told of
+        self._steps = {"blocks_read": 0, "blocks_in_tables": 0,
+                       "rows_stepped": 0}
+        self._state_ops = sum(k.per_request for k in self.kinds.values())
+        # the ops whose steps read less than they keep, and the sums of
+        # what they read, by the word their kind's step_reads says
+        # ("selected": a selection of a request's blocks; "window": at
+        # most a window of its rows). One word's ops share one geometry,
+        # so the sums count the first
+        self._readers: Dict[str, List["EntryKind"]] = {}
+        self._reads: Dict[str, Dict[str, int]] = {}
+        for kind in self.kinds.values():
+            for word, zeros in kind.step_reads(np.zeros(0, np.int64)).items():
+                self._readers.setdefault(word, []).append(kind)
+                self._reads.setdefault(word, zeros)
         self._gauge()
 
     # ---- geometry ----------------------------------------------------------
@@ -321,11 +342,46 @@ class PagedKVPool:
         metrics_registry().gauge("serving.kv_blocks_in_use").set(
             self.in_use())
 
-    def stats(self) -> Dict:
-        """Session-level occupancy snapshot (ledger / bench / healthz)."""
+    # ---- the books --------------------------------------------------------
+    def count_step(self, lengths: np.ndarray) -> None:
+        """One decode step (or speculative round) whose active slots'
+        requests have ``lengths`` cached tokens: the blocks it reads of
+        the tables it is given (a slot's cached tokens and the row it
+        writes, whatever the kinds), a state row a slot and per-request
+        op, and what the kinds that read less than they keep say."""
+        n = np.asarray(lengths, np.int64)
+        live = int(((n + self.block_size) // self.block_size).sum())
+        reads = {word: kinds[0].step_reads(n)[word]
+                 for word, kinds in self._readers.items()}
+        with self._mu:
+            self._steps["blocks_read"] += live
+            self._steps["blocks_in_tables"] += \
+                n.size * self.max_blocks_per_request
+            self._steps["rows_stepped"] += n.size * self._state_ops
+            for word, counts in reads.items():
+                for key, v in counts.items():
+                    self._reads[word][key] += v
+
+    def chunk_keys(self, offset: int, tokens: int) -> Tuple[int, int]:
+        """The keys a chunk's queries see, at positions ``offset ..
+        offset + tokens - 1``: in an op that keeps everything (a query at
+        position p sees p + 1), and in a windowed one (a window's worth at
+        most; 0 in a pool that has none)."""
+        pos = np.arange(offset, offset + tokens, dtype=np.int64)
+        windowed = self._readers.get("window")
+        return (int((pos + 1).sum()),
+                int(windowed[0].rows_read(pos).sum()) if windowed else 0)
+
+    def stats(self, lengths=None) -> Dict:
+        """Session-level occupancy snapshot (ledger / bench / healthz).
+        Given the ``lengths`` cached of the requests in their slots (the
+        serving loop's call), also the books: what the counted steps read,
+        and what those requests hold now."""
         with self._mu:
             used = self.capacity_blocks - len(self._free)
             hw = self._high_water
+            steps = dict(self._steps)
+            reads = {word: dict(sums) for word, sums in self._reads.items()}
             state = {"state": {
                 "rows": self.num_rows,
                 "in_use": self.num_rows - 1 - len(self._free_rows),
@@ -333,7 +389,7 @@ class PagedKVPool:
                 # a request's row over all the ops that keep one, as stored
                 "row_bytes": pool_bytes(self.specs, 0, 0, self.kv_dtype,
                                         self.dtype, 1)}} if self.num_rows else {}
-        return {
+        out = {
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,
             "capacity_blocks": self.capacity_blocks,
@@ -349,6 +405,32 @@ class PagedKVPool:
             **self._entry_stats(),
             **state,
         }
+        if lengths is None:
+            return out
+        held = np.asarray(lengths, np.int64)
+        out["blocks_read"] = steps["blocks_read"]
+        out["blocks_in_tables"] = steps["blocks_in_tables"]
+        if "selected" in reads:
+            # what the steps of ONE op that selects blocks read, beside
+            # the live blocks of the slots they carried, and the pooled
+            # keys the requests in their slots hold now
+            kind = self._readers["selected"][0]
+            out["selected"] = reads["selected"]
+            out["kernel_rows"] = sum(kind.side_rows(int(n)) for n in held)
+        if "window" in reads:
+            # ONE windowed op's rows over the steps' active slots (read,
+            # what an op that keeps everything would have read, the rings
+            # reserved), and the rows the requests in their slots hold
+            # now. A wart kept: this dict takes the place of the kind's
+            # own word ``"window": <rows>`` (``rows`` carries the number),
+            # because the benchmark's readers take the dict by this name
+            kinds = self._readers["window"]
+            out["window"] = dict(
+                reads["window"], rows=kinds[0].window, ops=len(kinds),
+                rows_held=int(np.minimum(held, kinds[0].window).sum()))
+        if "state" in out:
+            out["state"]["rows_stepped"] = steps["rows_stepped"]
+        return out
 
     def _entry_stats(self) -> Dict:
         """One kind's own words where every op says the same; else each

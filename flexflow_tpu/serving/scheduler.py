@@ -345,26 +345,6 @@ class ContinuousBatchingScheduler:
                 kv_dtype=self.decoder.kv_dtype, calibrate=False)
             # a draft's rejected tokens are rolled back as the target's are
             self.draft.check_window(self.spec_k + 1)
-        # running sums over decode steps, read by stats()["kv"]
-        self._blocks_read = 0
-        self._blocks_in_tables = 0
-        # active slots x the ops that keep a state a request
-        self._rows_stepped = 0
-        kinds = list(self.decoder.pool.kinds.values())
-        self._state_ops = sum(k.per_request for k in kinds)
-        # a kind whose steps read a selection of a request's blocks (its
-        # ops share one geometry): what they read, beside the live blocks
-        self._selecting = next(
-            (k for k in kinds if k.blocks_read(0) is not None), None)
-        self._selected_read = 0
-        self._selected_live = 0
-        # a kind that keeps and reads at most a window of a request's rows
-        # (its ops share one window): what the steps read of it, beside
-        # what ops that keep everything would, and what it reserves
-        self._windowed = next(
-            (k for k in kinds if k.rows_read(0) is not None), None)
-        self._window_rows = {"rows_read": 0, "rows_full": 0,
-                             "rows_reserved": 0}
         # requests admitted whose prompts are still being prefilled in
         # chunks, oldest first (the loop's thread alone touches it)
         self._prefilling: collections.deque = collections.deque()
@@ -635,19 +615,13 @@ class ContinuousBatchingScheduler:
     def _admit(self, closed: bool) -> None:
         """Move queued requests into free decode slots: deadline-expired
         requests reject fast, pool-full requests wait (FIFO head keeps
-        its place), admitted requests prefill immediately. While decodes
-        are active at most ``max_prefills_per_step`` prompts are
-        prefilled per call, bounding the decode stall a prompt burst can
-        cause. With ``prefill_token_budget`` set the stall bound is
-        token-native instead: see :meth:`_admit_batched`."""
+        its place). A prompt is prefilled whole as it is admitted
+        (:meth:`_admit_whole`) or, with ``prefill_chunk``, a chunk a
+        pass from then on (:meth:`_admit_chunked`)."""
         with self._phase("admit") as ph:
-            if self.decoder.prefill_chunk:
-                n = self._admit_chunked(closed)
-            elif self.prefill_token_budget > 0:
-                n = self._admit_batched(closed)
-            else:
-                n = self._admit_single(closed)
-            ph.span.set(admitted=n)
+            admit = (self._admit_chunked if self.decoder.prefill_chunk
+                     else self._admit_whole)
+            ph.span.set(admitted=admit(closed))
         if self._prefilling:
             self._prefill_next_chunk()
 
@@ -745,22 +719,10 @@ class ContinuousBatchingScheduler:
                 logits = _DECODE_RETRY.call(self.decoder.prefill_chunk_at,
                                             req.prompt, req.table, at)
         except Exception as e:  # noqa: BLE001 — fail THIS request only
-            metrics_registry().counter("serving.errors").inc()
             self._prefilling.popleft()
-            with self._mu:
-                for i, r in enumerate(self._slots):
-                    if r is req:
-                        self._slots[i] = None
-            self.decoder.pool.free(req.table)
-            if not req.future.done():
-                req.future.set_exception(e)
+            self._fail_prefill([req], e)
             return
-        # a query at position p sees p + 1 keys, or a window's worth
-        seen = np.arange(at + 1, at + n + 1, dtype=np.int64)
-        self._clock.count_chunk(
-            n, int(seen.sum()),
-            0 if self._windowed is None
-            else int(np.minimum(seen, self._windowed.window).sum()))
+        self._clock.count_chunk(n, *self.decoder.pool.chunk_keys(at, n))
         with self._mu:
             self._prefill_dispatches += 1
             if last:
@@ -778,34 +740,47 @@ class ContinuousBatchingScheduler:
         with self._phase("sample", tokens=1):
             self._append_token(req, logits)
 
-    def _admit_single(self, closed: bool) -> int:
-        """One prefill dispatch a prompt; returns how many it admitted."""
-        reg = metrics_registry()
+    def _admit_whole(self, closed: bool) -> int:
+        """Admission where prompts are prefilled whole: each request
+        that finds a free slot and its worst case in the pool joins the
+        group of its prefill bucket, and a group is ONE dispatch
+        (:meth:`_prefill_group`), sent when it is full and, for what is
+        left, at the end of the pass in bucket order. A group holds
+        ``prefill_token_budget // bucket`` prompts, at least one: without
+        a budget every prompt is a group, prefilled before the next is
+        looked at. What a pass may admit while a slot decodes bounds the
+        stall a burst of prompts causes: ``max_prefills_per_step``
+        prompts prefilled or, under a budget, the budget in padded
+        tokens (and the pass's first prompt whatever its bucket).
+        Returns how many prompts it prefilled."""
         with self._mu:
             active = any(r is not None for r in self._slots)
             n_slots = len(self._slots)
-        budget = self.max_prefills_per_step if active else n_slots
-        admitted = 0
-        while admitted < budget:
+        budget = self.prefill_token_budget
+        bound = (self.max_prefills_per_step if active and not budget
+                 else n_slots)
+        groups: Dict[int, List] = {}  # bucket: [(slot, request)] not yet sent
+        admitted = spent = 0
+        while admitted < bound:
             req = self._pop_live(closed)
             if req is None:
-                return admitted
-            slot = self._reserve(req)
+                break
+            bucket = self.decoder.bucket_for(req.prompt.size)
+            if budget and active and spent and spent + bucket > budget:
+                with self._mu:
+                    self._queue.appendleft(req)
+                break
+            slot = self._reserve(
+                req, {slot for g in groups.values() for slot, _ in g})
             if slot is None:
-                return admitted
-            try:
-                self._prefill(req)
-            except Exception as e:  # noqa: BLE001 — fail THIS request only
-                reg.counter("serving.errors").inc()
-                self.decoder.pool.free(req.table)
-                if not req.future.done():
-                    req.future.set_exception(e)
-                continue
-            admitted += 1
-            if req.future.done():  # single-token request retired at prefill
-                continue
-            with self._mu:
-                self._slots[slot] = req
+                break
+            spent += bucket
+            group = groups.setdefault(bucket, [])
+            group.append((slot, req))
+            if len(group) == max(1, budget // bucket):
+                admitted += self._prefill_group(groups.pop(bucket), bucket)
+        for bucket in sorted(groups):
+            admitted += self._prefill_group(groups[bucket], bucket)
         return admitted
 
     def _observe_lat(self, phase: str, seconds: float) -> None:
@@ -815,79 +790,32 @@ class ContinuousBatchingScheduler:
         total[0] += 1  # hotpath: lock-ok (the caller holds _mu)
         total[1] += seconds  # hotpath: lock-ok (the caller holds _mu)
 
-    def _admit_batched(self, closed: bool) -> int:
-        """Token-budget admission: the same deadline/slot/pool gates as
-        the one-per-dispatch path, but admitted prompts are grouped by
-        prefill bucket and each group runs through ONE batched prefill
-        dispatch of at most ``floor(prefill_token_budget / bucket)``
-        prompts. While decodes are active, collection stops once the
-        group's padded prefill tokens would pass the budget — the
-        decode-stall bound is measured in tokens, which is what the
-        stall actually costs, instead of prompt count. Returns how many
-        prompts it sent to prefill."""
-        with self._mu:
-            active = any(r is not None for r in self._slots)
-            n_slots = len(self._slots)
-        batch: List = []  # (slot, req, bucket)
-        reserved: set = set()
-        spent = 0
-        while len(batch) < n_slots:
-            req = self._pop_live(closed)
-            if req is None:
-                break
-            bucket = self.decoder.bucket_for(req.prompt.size)
-            if active and batch and spent + bucket > \
-                    self.prefill_token_budget:
-                with self._mu:
-                    self._queue.appendleft(req)
-                break
-            slot = self._reserve(req, reserved)
-            if slot is None:
-                break
-            reserved.add(slot)
-            spent += bucket
-            batch.append((slot, req, bucket))
-        if not batch:
-            return 0
-        groups: Dict[int, List] = {}
-        for slot, req, bucket in batch:
-            groups.setdefault(bucket, []).append((slot, req))
-        for bucket in sorted(groups):
-            members = groups[bucket]
-            cap = max(1, self.prefill_token_budget // bucket)
-            for i in range(0, len(members), cap):
-                self._prefill_group(members[i:i + cap], bucket)
-        return len(batch)
-
-    def _prefill_group(self, members: List, bucket: int) -> None:
-        """ONE batched prefill dispatch for same-bucket requests; a
-        dispatch failure fails exactly the group's requests (their
-        blocks free), mirroring the single-prefill error contract."""
-        reg = metrics_registry()
+    def _prefill_group(self, members: List, bucket: int) -> int:
+        """ONE prefill dispatch for the same-bucket requests ``members``
+        ([(slot, request)], their tables reserved), each one's first
+        token sampled and the request seated in its slot (unless that
+        token was its last). A dispatch failure fails exactly the
+        group's requests. Returns how many it prefilled."""
         reqs = [r for _, r in members]
+        prompts = [r.prompt for r in reqs]
+        tables = [r.table for r in reqs]
         try:
             with self._phase(
                     "prefill", bucket=bucket,
                     request_ids=",".join(str(r.request_id) for r in reqs),
                     tokens=sum(int(r.prompt.size) for r in reqs)) as ph:
-                logits = _DECODE_RETRY.call(
-                    self.decoder.prefill_many,
-                    [r.prompt for r in reqs], [r.table for r in reqs])
+                logits = _DECODE_RETRY.call(self.decoder.prefill_many,
+                                            prompts, tables)
                 if self.draft is not None:
                     # prime the draft's arenas through the SAME block
                     # tables (its prefill logits are unused — the first
                     # generated token is sampled from the target,
                     # exactly like non-speculative serving)
-                    _DECODE_RETRY.call(
-                        self.draft.prefill_many,
-                        [r.prompt for r in reqs], [r.table for r in reqs])
+                    _DECODE_RETRY.call(self.draft.prefill_many, prompts,
+                                       tables)
         except Exception as e:  # noqa: BLE001 — fail the group only
-            reg.counter("serving.errors").inc()
-            for _, req in members:
-                self.decoder.pool.free(req.table)
-                if not req.future.done():
-                    req.future.set_exception(e)
-            return
+            self._fail_prefill(reqs, e)
+            return 0
         t0, t_done = ph.t0, ph.t1
         with self._mu:
             self._prefill_dispatches += 1
@@ -897,7 +825,8 @@ class ContinuousBatchingScheduler:
                 req.seq_len = req.prompt.size
                 req.rng = np.random.default_rng(req.seed)
                 self._observe_lat("prefill", t_done - t0)
-        reg.histogram("serving.prefill_s").observe(t_done - t0)
+        metrics_registry().histogram("serving.prefill_s").observe(
+            t_done - t0)
         with self._phase("sample", tokens=len(members)):
             for i, (slot, req) in enumerate(members):
                 self._append_token(req, logits[i])
@@ -905,30 +834,21 @@ class ContinuousBatchingScheduler:
                     continue
                 with self._mu:
                     self._slots[slot] = req
+        return len(members)
 
-    def _prefill(self, req: GenerationRequest) -> None:
-        with self._phase("prefill", request_id=req.request_id,
-                         bucket=self.decoder.bucket_for(req.prompt.size),
-                         tokens=int(req.prompt.size)) as ph:
-            logits = _DECODE_RETRY.call(self.decoder.prefill, req.prompt,
-                                        req.table)
-            if self.draft is not None:
-                # prime the draft's arenas through the SAME block table
-                # (its prefill logits are unused)
-                _DECODE_RETRY.call(self.draft.prefill, req.prompt,
-                                   req.table)
-        t0, t_done = ph.t0, ph.t1
-        with self._mu:
-            self._prefill_dispatches += 1
-            self._prefill_prompts += 1
-            req.t_prefill_done = t_done
-            req.seq_len = req.prompt.size
-            req.rng = np.random.default_rng(req.seed)
-            self._observe_lat("prefill", t_done - t0)
-        metrics_registry().histogram("serving.prefill_s").observe(
-            t_done - t0)
-        with self._phase("sample", tokens=1):
-            self._append_token(req, logits)
+    def _fail_prefill(self, reqs, e: Exception) -> None:
+        """A prefill dispatch failed: fail exactly its requests, their
+        blocks freed and their slots (a prompt prefilled in chunks sits
+        in its slot from admission) emptied."""
+        metrics_registry().counter("serving.errors").inc()
+        for req in reqs:
+            with self._mu:
+                for i, r in enumerate(self._slots):
+                    if r is req:
+                        self._slots[i] = None
+            self.decoder.pool.free(req.table)
+            if not req.future.done():
+                req.future.set_exception(e)
 
     # ---- decode ------------------------------------------------------------
     def _step_inputs(self):
@@ -969,10 +889,8 @@ class ContinuousBatchingScheduler:
                 return None
             n_slots = len(slots)
             tokens = np.zeros(n_slots, np.int32)
-            tables = np.zeros(
-                (n_slots, self.decoder.max_blocks_per_request), np.int32)
+            tables = np.zeros((n_slots, active[0][1].table.size), np.int32)
             seq_lens = np.zeros(n_slots, np.int32)
-            bs = self.decoder.block_size
             with self._mu:
                 for i, req in active:
                     tokens[i] = req.tokens[-1]
@@ -980,21 +898,7 @@ class ContinuousBatchingScheduler:
                     seq_lens[i] = req.seq_len
                     if req.decode_t0 is None:
                         req.decode_t0 = now
-                    # the share of its table the step reads: the blocks
-                    # of the slot's cached tokens and the row it writes
-                    self._blocks_read += (req.seq_len + bs) // bs
-                    if self._selecting is not None:
-                        self._selected_read += self._selecting.blocks_read(
-                            req.seq_len)
-                        self._selected_live += (req.seq_len + bs) // bs
-                    if self._windowed is not None:
-                        wr = self._window_rows
-                        wr["rows_read"] += self._windowed.rows_read(
-                            req.seq_len)
-                        wr["rows_full"] += req.seq_len + 1
-                        wr["rows_reserved"] += self._windowed.window
-                self._blocks_in_tables += len(active) * tables.shape[1]
-                self._rows_stepped += len(active) * self._state_ops
+            self.decoder.pool.count_step(seq_lens[[i for i, _ in active]])
         return active, tokens, tables, seq_lens
 
     def _dispatch(self, fn, *args):
@@ -1430,40 +1334,15 @@ class ContinuousBatchingScheduler:
             spec_proposed = self._spec_proposed
             spec_matched = self._spec_matched
             spec_emitted = self._spec_emitted
-            blocks_read = self._blocks_read
-            blocks_in_tables = self._blocks_in_tables
-            rows_stepped = self._rows_stepped
-            selected = {"blocks_read": self._selected_read,
-                        "blocks_live": self._selected_live}
-            window_rows = dict(self._window_rows)
             lengths = [r.seq_len for r in self._slots if r is not None]
         now = time.perf_counter()
         tps = (tokens / (now - t_start)
                if t_start is not None and now > t_start else 0.0)
-        kv = self.decoder.pool.stats()
+        # the pool's occupancy and its books of what the steps read; the
+        # decoder's own words beside them
+        kv = self.decoder.pool.stats(lengths)
         kv["attention_path"] = dict(self.decoder.attention_path)
-        kv["blocks_read"] = blocks_read
-        kv["blocks_in_tables"] = blocks_in_tables
-        if self._selecting is not None:
-            # what the steps of the ops that select blocks read, beside
-            # the live blocks of the slots they carried, and the pooled
-            # keys the requests in their slots hold now (one op's)
-            kv["selected"] = selected
-            kv["kernel_rows"] = sum(self._selecting.side_rows(n)
-                                    for n in lengths)
-        if self._windowed is not None:
-            # one windowed op's rows over the decode steps' active slots
-            # (read, what an op that keeps everything would have read,
-            # the rings reserved), and the rows the requests in their
-            # slots hold now
-            kv["window"] = dict(
-                window_rows, rows=self._windowed.window,
-                ops=sum(k.rows_read(0) is not None
-                        for k in self.decoder.pool.kinds.values()),
-                rows_held=sum(min(n, self._windowed.window)
-                              for n in lengths))
         if "state" in kv:
-            kv["state"]["rows_stepped"] = rows_stepped
             kv["state"]["prefill_path"] = self.decoder.prefill_path
         if self.decoder.kv_divergence is not None:
             kv["divergence"] = self.decoder.kv_divergence

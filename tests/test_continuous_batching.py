@@ -448,9 +448,7 @@ def test_deadline_expired_mid_flight_rejected_before_next_step(gpt):
     req = GenerationRequest(0, np.zeros(3, np.int32), 8, 0.0, 0, None,
                             deadline_s=0.01)
     req.table = sched.decoder.pool.try_admit(3 + 8)
-    sched._prefill(req)
-    with sched._mu:
-        sched._slots[0] = req
+    sched._prefill_group([(0, req)], sched.decoder.bucket_for(3))
     time.sleep(0.02)  # deadline passes mid-flight
     sched._decode_once()
     with pytest.raises(DeadlineExceeded, match="mid-decode"):
@@ -1142,6 +1140,41 @@ def test_failure_with_a_step_in_flight_fails_both_steps_once(
     assert st["completed"] == 1 and st["tokens"] >= 5
     assert sched.decoder.pool.in_use() == 0
     assert not sched._in_flight
+
+
+@pytest.mark.parametrize("how", ["whole", "chunked"])
+def test_a_prefill_that_fails_takes_its_request_alone(gpt, monkeypatch, how):
+    """The second prompt's prefill fails (a group of one; or its first
+    chunk, the request already in its slot): that request fails, its
+    blocks come back and its slot is empty; its neighbours are served."""
+    chunk = {"prefill_chunk": 8} if how == "chunked" else {}
+    sched = ContinuousBatchingScheduler(gpt, max_length=32, decode_slots=3,
+                                        block_size=8, **chunk)
+    name = "prefill_chunk_at" if chunk else "prefill_many"
+    real = getattr(sched.decoder, name)
+    calls = []
+
+    def prefill(*args):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("wedged device")
+        return real(*args)
+
+    monkeypatch.setattr(sched.decoder, name, prefill)
+    errors = metrics_registry().counter("serving.errors").value
+    futs = [sched.submit(np.arange(1, n + 1, dtype=np.int32), 6)
+            for n in (3, 4, 5)]
+    with pytest.raises(RuntimeError, match="wedged"):
+        futs[1].result(timeout=120)
+    assert [futs[i].result(timeout=120).shape for i in (0, 2)] == [(9,),
+                                                                   (11,)]
+    st = sched.stats()
+    with sched._mu:
+        slots = list(sched._slots)
+    sched.stop()
+    assert metrics_registry().counter("serving.errors").value == errors + 1
+    assert st["completed"] == 2 and st["prefill_prompts"] == 2
+    assert slots == [None] * 3 and sched.decoder.pool.in_use() == 0
 
 
 def test_worker_crash_with_a_step_in_flight_loses_no_token(gpt, monkeypatch):

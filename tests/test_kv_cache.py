@@ -173,3 +173,103 @@ def test_pool_bytes_has_a_term_a_token_and_a_term_a_request():
     assert pool_bytes(p.specs, 9, 4, "bfloat16", num_rows=5) \
         == p.memory_bytes()
     assert pool_bytes(p.specs, 9, 4, "bfloat16") == 9 * 4 * per_token
+
+
+# ------------------------------------------------ the books of what steps read
+def test_the_scheduler_names_no_entry_kind():
+    """What a step reads of each kind of entry is the pool's to count:
+    the serving loop, two layers above the kinds, names none of their
+    words, so a new kind costs the scheduler nothing."""
+    import inspect
+
+    from flexflow_tpu.serving import scheduler
+
+    src = inspect.getsource(scheduler)
+    for word in ("blocks_read", "rows_read", "side_rows", "per_request",
+                 "_selecting", "_windowed", "_state_ops", "pool.kinds"):
+        assert word not in src, word
+
+
+@pytest.mark.parametrize("model", ["gpt", "hybrid", "sparse_hybrid",
+                                   "latent_moe", "nemotron_h", "trinity"])
+def test_the_pool_keeps_the_books_of_what_the_steps_read(model):
+    """Each serving family of the zoo through a scripted session (two
+    prompts seated, four decode passes, nothing retired): ``stats()
+    ["kv"]``, which is ``PagedKVPool.stats(lengths)`` and the decoder's
+    words, holds every book to the lengths' arithmetic, each present
+    exactly where the pool has such a kind; ``stats()`` without lengths
+    holds none of them."""
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.models import zoo_smoke_builders
+    from flexflow_tpu.serving import cache_entry
+    from flexflow_tpu.serving.scheduler import (ContinuousBatchingScheduler,
+                                                GenerationRequest)
+
+    ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
+                          search_cache="off",
+                          computation_mode=CompMode.INFERENCE))
+    zoo_smoke_builders()[model](ff, 2)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    bs = 4 if model == "sparse_hybrid" else 8
+    sched = ContinuousBatchingScheduler(
+        ff, max_length=48, decode_slots=3, block_size=bs,
+        prefill_buckets=[16])
+    pool = sched.decoder.pool
+    prompts, passes = (5, 14), 4      # lengths 5..8 and 14..17 at the steps
+    for slot, n in enumerate(prompts):
+        req = GenerationRequest(slot, np.arange(1, n + 1, dtype=np.int32),
+                                20, 0.0, 0, None, None)
+        req.table = pool.try_admit(n + 20)
+        assert sched._prefill_group([(slot, req)], 16) == 1
+    for _ in range(passes):
+        sched._decode_once()
+    kv = sched.stats()["kv"]
+    sched._read_steps(keep=0)
+    sched.stop()
+
+    stepped = [n + k for n in prompts for k in range(passes)]
+    held = [n + passes for n in prompts]
+    kinds = list(pool.kinds.values())
+    assert kv["blocks_read"] == sum((x + bs) // bs for x in stepped)
+    assert kv["blocks_in_tables"] == len(stepped) * (48 // bs)
+    plain = pool.stats()
+    assert not {"blocks_read", "blocks_in_tables", "selected",
+                "kernel_rows"} & set(plain)
+    assert kv["in_use"] == plain["in_use"] == sum(
+        pool.blocks_for(n + 20) for n in prompts)
+
+    state_ops = sum(k.per_request for k in kinds)
+    assert ("state" in kv) == bool(state_ops) == (model not in (
+        "gpt", "latent_moe"))
+    if state_ops:
+        assert kv["state"]["rows_stepped"] == len(stepped) * state_ops
+        assert kv["state"]["in_use"] == 2
+        assert "prefill_path" in kv["state"]
+        assert set(plain["state"]) == {"rows", "in_use", "high_water",
+                                       "row_bytes"}
+
+    sparse = [k for k in kinds if isinstance(k, cache_entry.SparseEntry)]
+    assert ("selected" in kv) == ("kernel_rows" in kv) == bool(sparse) \
+        == (model == "sparse_hybrid")
+    if sparse:      # dense_len 16, topk 3 of blocks of 4; kernels 4 by 2
+        assert kv["selected"] == {
+            "blocks_read": sum(x // 4 + 1 if x < 16 else 3 for x in stepped),
+            "blocks_live": kv["blocks_read"]}
+        assert kv["kernel_rows"] == sum((x - 4 + 2) // 2 for x in held) > 0
+
+    windowed = [k for k in kinds if isinstance(k, cache_entry.WindowEntry)]
+    assert bool(windowed) == (model == "trinity")
+    if windowed:    # the books' dict in place of the kind's own word
+        assert plain["window"] == 16
+        assert kv["window"] == {
+            "rows_read": sum(min(x + 1, 16) for x in stepped),
+            "rows_full": sum(x + 1 for x in stepped),
+            "rows_reserved": 16 * len(stepped),
+            "rows": 16, "ops": len(windowed),
+            "rows_held": sum(min(x, 16) for x in held)}
+        assert pool.chunk_keys(8, 16) == (
+            sum(range(9, 25)), sum(min(p, 16) for p in range(9, 25)))
+    else:
+        assert "window" not in kv
+        assert pool.chunk_keys(8, 16) == (sum(range(9, 25)), 0)

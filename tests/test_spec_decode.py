@@ -224,14 +224,11 @@ def test_spec_deadline_mid_flight_rejected_before_next_round(gpt,
     doomed = GenerationRequest(0, np.zeros(3, np.int32), 8, 0.0, 0,
                                None, deadline_s=0.01)
     doomed.table = sched.decoder.pool.try_admit(3 + 8)
-    sched._prefill(doomed)
+    sched._prefill_group([(0, doomed)], sched.decoder.bucket_for(3))
     live = GenerationRequest(1, np.ones(3, np.int32), 4, 0.0, 0, None,
                              deadline_s=None)
     live.table = sched.decoder.pool.try_admit(3 + 4)
-    sched._prefill(live)
-    with sched._mu:
-        sched._slots[0] = doomed
-        sched._slots[1] = live
+    sched._prefill_group([(1, live)], sched.decoder.bucket_for(3))
     time.sleep(0.02)  # deadline passes mid-flight
     before = sched.decoder.pool.in_use()
     sched._decode_once()
