@@ -167,6 +167,24 @@ def scope_group(kind: str) -> str:
     return _GROUP_OF.get(kind, "other")
 
 
+def weights_of(op: "Op", params: Dict) -> Dict:
+    """``op``'s weights out of a parameter tree ``{op name: {weight name:
+    array}}``: its own, and what it borrows of another op's (a head tied
+    to the embedding): the same array, so a gradient through the tree is
+    the sum over both uses."""
+    own = params.get(op.name, {})
+    if not op.borrows:
+        return own
+    own = dict(own)
+    for name, (owner, theirs) in op.borrows.items():
+        if owner not in params:
+            raise ValueError(
+                f"{op.name} reads {owner}'s {theirs!r}, which this parameter "
+                f"tree does not hold (a pipeline stage of its own?)")
+        own[name] = params[owner][theirs]
+    return own
+
+
 class Op:
     """Base operator. Subclasses set ``op_type`` and implement the hooks."""
 
@@ -181,6 +199,11 @@ class Op:
         self.output_shapes: List[ParallelTensorShape] = []
         self.weight_shapes: Dict[str, ParallelTensorShape] = {}
         self.machine_view: Optional[MachineView] = None
+        # weights this op reads and does not own: {the name ``forward``
+        # finds it under: (the owner op's name, the owner's name for it)}.
+        # The parameter tree holds such a weight once, under its owner
+        # (:func:`weights_of` hands it over)
+        self.borrows: Dict[str, Tuple[str, str]] = {}
 
     # ---- shape rule -------------------------------------------------------
     def infer_output_shapes(self) -> List[Tuple[Tuple[int, ...], DataType]]:

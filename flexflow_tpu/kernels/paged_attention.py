@@ -26,10 +26,11 @@ from the arena where they lie:
   are the heads' outputs. No lane slice at a half tile, no per-head
   loop; the MXU has the headroom (the step is bound by the bytes);
 * grouped heads (``H`` query heads on ``Hkv`` key-value heads, the
-  arena's rows ``Hkv * D`` lanes wide, ``D`` whole lane tiles): the
-  ``H / Hkv`` query heads of a group are that many rows of the same score
-  matrix over the one key row, row h keeping its query in the lanes of
-  key-value head ``h // (H / Hkv)``;
+  arena's rows ``Hkv * D`` lanes wide, ``D`` whole lane tiles or half of
+  one, two key-value heads a tile): the ``H / Hkv`` query heads of a
+  group are that many rows of the same score matrix over the one key
+  row, row h keeping its query in the lanes of key-value head ``h // (H
+  / Hkv)``;
 * scores, the running maximum and sum, and the weighted sum are float32;
   K, V and the probabilities fed to the MXU are in the arena's dtype,
   which is what the jnp path feeds it.
@@ -109,7 +110,8 @@ def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
 def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
     """Whether the kernel takes this call. ``q_shape``: (slots, W, H, D);
     ``arena_shape``: (num_blocks, block_size, Hkv*D), ``Hkv`` dividing
-    ``H`` (grouped heads: ``D`` then whole lane tiles). Refuses what Mosaic
+    ``H`` (grouped heads: ``D`` then whole lane tiles, or 64, two heads a
+    tile: Granite's 32 on 8). Refuses what Mosaic
     would: rows that do not fill whole 128-lane tiles, blocks that are
     not whole sublane tiles of the arena's dtype or do not divide a lane
     tile of tokens, dtypes other than float32 and bfloat16 (an int8
@@ -127,7 +129,7 @@ def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
     if hd % head_dim or hd % 128:
         return False
     kv_heads = hd // head_dim
-    if heads % kv_heads or (kv_heads != heads and head_dim % 128):
+    if heads % kv_heads or (kv_heads != heads and head_dim % 64):
         return False
     if block_size % _sublanes(dtype) or (128 % block_size
                                          and block_size % 128):

@@ -1,12 +1,17 @@
 """Model zoo: the reference's example workloads as builder-API definitions
 (reference: examples/cpp/* — SURVEY.md §2.8), and the causal LMs serving
 drives: ``gpt``, ``latent_moe``, ``hybrid``, ``sparse_hybrid``,
-``nemotron_h`` and ``trinity`` (``build_trinity_lm``: windowed and full
+``nemotron_h``, ``trinity`` (``build_trinity_lm``: windowed and full
 attention layers by a ``layer_types`` list, gated grouped heads with
 rotary positions in the windowed layers only, sandwich norms, leading
 dense layers then routed experts beside a shared one; a windowed layer
 keeps a ring of ``window`` rows a request in the paged pool:
-``serving/cache_entry.py`` ``WindowEntry``)."""
+``serving/cache_entry.py`` ``WindowEntry``) and ``granite_hybrid``
+(``build_granite_hybrid_lm``: Mamba-2 and grouped-head attention mixers
+by a ``layer_types`` list, every block a mixer and a gated MLP behind
+pre-norms and scaled residuals, a softmax scale of the model's own, a
+head tied to the embedding; prompts may be prefilled in chunks through
+the states: ``SsmStateEntry.chunk``)."""
 
 from .mlp import build_mlp
 from .alexnet import build_alexnet
@@ -25,6 +30,7 @@ from .hybrid import build_hybrid_lm, HybridLMConfig
 from .sparse_hybrid import build_sparse_hybrid_lm, SparseHybridConfig
 from .nemotron_h import build_nemotron_h_lm, NemotronHConfig
 from .trinity import build_trinity_lm, TrinityConfig
+from .granite_hybrid import build_granite_hybrid_lm, GraniteHybridConfig
 
 
 def zoo_smoke_builders():
@@ -117,6 +123,13 @@ def zoo_smoke_builders():
             expert_width=16, n_routed=8, experts_per_token=2,
             routed_scale=2.448))
 
+    def granite_hybrid(ff, bs):
+        build_granite_hybrid_lm(ff, bs, 16, GraniteHybridConfig(
+            vocab_size=128, hidden_size=32,
+            layer_types=("mamba", "attention", "mamba"), mlp_width=64,
+            attention_multiplier=0.0625, mamba_heads=4, mamba_head_dim=16,
+            state_size=8, chunk_size=8, num_heads=4, num_kv_heads=2))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -135,4 +148,5 @@ def zoo_smoke_builders():
         "sparse_hybrid": sparse_hybrid,
         "nemotron_h": nemotron_h,
         "trinity": trinity,
+        "granite_hybrid": granite_hybrid,
     }

@@ -14,6 +14,11 @@ Head-dim partitioning (the reference's attribute parallelism on heads —
 substitution.cc:1763-1770 ``create_partition_attention_combine``) is
 strategy key ``{"heads": axis}``: weights shard on their head dim and GSPMD
 partitions the attention over heads.
+
+``MultiHeadAttention.scale`` is what the scores are multiplied by: ``1 /
+sqrt(head_dim)``, or the op's ``scale`` attribute where a model states
+its own (Granite's ``attention_multiplier``); the forward, every cache
+entry kind and both serving kernels read the property.
 """
 
 from __future__ import annotations
@@ -115,6 +120,9 @@ class MultiHeadAttention(Op):
                          if self.rotary else None)
         # the attended values times sigmoid(x W_g), before W_o
         self.gate = bool(a.get("gate", False))
+        # what the scores are multiplied by, where it is not
+        # 1 / sqrt(head_dim) (a model's own ``attention_multiplier``)
+        self._scale = float(a["scale"]) if a.get("scale") else None
         # set by propagate when the strategy sequence-shards this op
         self.seq_axis: str | None = None
         self.seq_mode: str = "ring"  # "ring" | "a2a" (Ulysses)
@@ -157,6 +165,8 @@ class MultiHeadAttention(Op):
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
     @property
     def scale(self) -> float:
+        if self._scale is not None:
+            return self._scale
         return 1.0 / math.sqrt(self.head_dim)
 
     @sub_scope("project")

@@ -14,7 +14,7 @@ in- or out-feature dim over the ``model`` mesh axis in :meth:`propagate`.
 
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
@@ -62,13 +62,18 @@ class Linear(Op):
         self.activation: ActiMode = layer.attrs.get("activation", ActiMode.NONE)
         self.use_bias: bool = layer.attrs.get("use_bias", True)
         self.in_dim: int = input_shapes[0].sizes[-1]
+        # a head tied to an embedding: no kernel of its own, it multiplies
+        # by the transposed (out_dim, in_dim) table of the op it names
+        self.tied_to: Optional[str] = layer.attrs.get("tied_to")
+        if self.tied_to:
+            self.borrows = {"tied": (self.tied_to, "weight")}
 
     def infer_output_shapes(self):
         sizes = self.input_shapes[0].sizes[:-1] + (self.out_dim,)
         return [(sizes, self.input_shapes[0].dtype)]
 
     def weight_specs(self) -> List[WeightSpec]:
-        specs = [
+        specs = [] if self.tied_to else [
             WeightSpec(
                 "kernel",
                 (self.in_dim, self.out_dim),
@@ -91,7 +96,13 @@ class Linear(Op):
 
     def forward(self, ctx: LowerCtx, inputs: Sequence[jnp.ndarray], weights):
         (x,) = inputs
-        y = jnp.dot(x, weights["kernel"], preferred_element_type=x.dtype)
+        if self.tied_to:
+            # contracted over the table's second axis where it lies: no
+            # transposed copy of it is made
+            y = jnp.einsum("...e,ve->...v", x, weights["tied"],
+                           preferred_element_type=x.dtype)
+        else:
+            y = jnp.dot(x, weights["kernel"], preferred_element_type=x.dtype)
         if self.use_bias:
             y = y + weights["bias"]
         return [apply_activation(y, self.activation)]
@@ -136,7 +147,9 @@ class Linear(Op):
                 kdims[0] = ParallelDim(self.in_dim, deg, in_axis)
 
         out_shape = ParallelTensorShape(tuple(out_dims + [out_feat]), in0.dtype)
-        weight_shapes = {
+        # (a tied head owns no kernel: the embedding's table is counted,
+        # sharded and synchronised once, under the embedding)
+        weight_shapes = {} if self.tied_to else {
             "kernel": ParallelTensorShape(tuple(kdims), in0.dtype),
         }
         if self.use_bias:

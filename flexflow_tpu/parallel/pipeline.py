@@ -67,7 +67,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.machine import DATA_AXIS, PIPE_AXIS, mesh_axis_sizes
-from ..core.op import LowerCtx, op_scope
+from ..core.op import LowerCtx, op_scope, weights_of
 from .schedule import (Action, PipelineSchedule, build_schedule,
                        check_schedule, schedule_summary)
 
@@ -377,7 +377,7 @@ class PipelinedModel:
                 ins = [acts[t.tensor_id] for t in op.layer.inputs]
                 with op_scope(op):
                     p = cast_op_params(cast, op,
-                                       chunk_params.get(op.name, {}), cdt)
+                                       weights_of(op, chunk_params), cdt)
                     outs = op.forward(ctx, ins, p)
                     for out, t in zip(outs, op.layer.outputs):
                         acts[t.tensor_id] = cast(out)

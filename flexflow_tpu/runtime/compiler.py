@@ -37,7 +37,8 @@ from ..ffconst import CompMode, DataType, LossType, MetricsType, OpType
 from ..config import FFConfig
 from ..core.layer import Layer
 from ..core.machine import DATA_AXIS, make_mesh, mesh_axis_sizes
-from ..core.op import LowerCtx, Op, create_op, fixed_scope, op_scope
+from ..core.op import (LowerCtx, Op, create_op, fixed_scope, op_scope,
+                       weights_of)
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 from ..core.tensor import Tensor
 from .loss import compute_loss, sparse_ce_from_logits
@@ -368,7 +369,7 @@ def _forward_graph(
         ins = [acts[t.tensor_id] for t in op.layer.inputs]
         ctx.rng = jax.random.fold_in(rng, oi) if rng is not None else None
         with op_scope(op):
-            p = cast_op_params(cast, op, params.get(op.name, {}),
+            p = cast_op_params(cast, op, weights_of(op, params),
                                compute_dtype)
             outs = op.forward(ctx, ins, p)
             for out, t, ps in zip(outs, op.layer.outputs, op.output_shapes):

@@ -160,10 +160,27 @@ class FFModel:
         kernel_regularizer=None,
         name: Optional[str] = None,
         strategy: Optional[Dict[str, str]] = None,
+        tied_to: Optional[str] = None,
     ) -> Tensor:
         """reference: FFModel::dense (model.h:487, src/ops/linear.cc).
         ``kernel_regularizer`` (keras/regularizers.py) adds a
-        differentiable penalty on the kernel to the training loss."""
+        differentiable penalty on the kernel to the training loss.
+        ``tied_to`` (a TPU-native extension) names an embedding layer of
+        ``out_dim`` entries as wide as ``input``: the layer then has no
+        kernel of its own and multiplies by that table transposed, which
+        the parameter tree holds once (a language model's tied head)."""
+        if tied_to is not None:
+            owner = next((l for l in self.layers if l.name == tied_to), None)
+            if owner is None or owner.op_type is not OpType.EMBEDDING:
+                raise ValueError(f"tied_to {tied_to!r}: no embedding layer "
+                                 f"of that name before this one")
+            table = (owner.attrs["num_entries"], owner.attrs["out_dim"])
+            if table != (out_dim, input.dims[-1]):
+                raise ValueError(
+                    f"tied_to {tied_to!r}: its table is {table}, this layer "
+                    f"needs {(out_dim, input.dims[-1])}")
+            if kernel_regularizer is not None:
+                raise ValueError("a tied layer has no kernel to regularize")
         attrs = dict(
             out_dim=out_dim,
             activation=activation,
@@ -172,6 +189,8 @@ class FFModel:
             bias_initializer=bias_initializer,
             kernel_regularizer=kernel_regularizer,
         )
+        if tied_to is not None:
+            attrs["tied_to"] = tied_to
         if strategy:
             attrs["strategy"] = strategy
         return self._infer_and_add(OpType.LINEAR, [input], attrs, name)
@@ -590,6 +609,7 @@ class FFModel:
         rotary: Optional[float] = None,
         positions: Optional[Tensor] = None,
         gate: bool = False,
+        scale: Optional[float] = None,
     ) -> Tensor:
         """reference: FFModel::multihead_attention (model.h:542,
         src/ops/attention.cc — cuDNN multihead attention). ``causal`` is a
@@ -603,7 +623,8 @@ class FFModel:
         that end at its own), ``rotary`` (theta: q and k are rotated by
         ``positions``, the graph's int32 (B, S) input, over the whole
         head), ``gate`` (the attended values times ``sigmoid(query
-        W_g)`` before the output projection)."""
+        W_g)`` before the output projection), ``scale`` (what the scores
+        are multiplied by where it is not ``1 / sqrt(head_dim)``)."""
         attrs = dict(
             embed_dim=embed_dim,
             num_heads=num_heads,
@@ -630,6 +651,8 @@ class FFModel:
             attrs["window"] = int(window)
         if gate:
             attrs["gate"] = True
+        if scale:
+            attrs["scale"] = float(scale)
         inputs = [query, key, value]
         if rotary:
             if positions is None:

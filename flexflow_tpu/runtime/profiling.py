@@ -222,7 +222,7 @@ def profile_ops(ffmodel, iters: int = 10, warmup: int = 2,
     cost-corpus collector (obs/costcorpus.py) both ride this."""
     import jax
 
-    from ..core.op import LowerCtx
+    from ..core.op import LowerCtx, weights_of
 
     cm = ffmodel.compiled
     assert cm is not None, "compile() first"
@@ -236,7 +236,7 @@ def profile_ops(ffmodel, iters: int = 10, warmup: int = 2,
     ctx = LowerCtx(mesh=cm.mesh, training=False, rng=None)
     for op in cm.ops:
         ins = [acts[t.tensor_id] for t in op.layer.inputs]
-        weights = cm.params.get(op.name, {})
+        weights = weights_of(op, cm.params)
 
         fwd = jax.jit(lambda ins, weights, _op=op: _op.forward(ctx, ins, weights))
         outs = fwd(ins, weights)  # compile + fill acts

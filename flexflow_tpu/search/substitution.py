@@ -104,7 +104,9 @@ def candidate_strategies(
             n = axis_sizes[a]
             if out_dim % n == 0:
                 cands.append({"out": a})
-            if in_dim % n == 0:
+            # (a tied layer has no kernel whose rows could be sharded:
+            # the table's sharding is its embedding's to choose)
+            if in_dim % n == 0 and not layer.attrs.get("tied_to"):
                 cands.append({"in": a})
     elif t is OpType.MULTIHEAD_ATTENTION and attr_ok:
         # the axis has to divide the key-value heads (grouped heads:

@@ -16,6 +16,13 @@ A kind keeps a row a TOKEN, addressed through the slots' block tables, or
 programs pass both as one :class:`~flexflow_tpu.serving.kv_cache
 .Addresses` and a kind takes the half that is its own.
 
+A kind that defines ``chunk`` serves a prompt in chunks, each behind what
+the chunks before left: the pair through its block table, the window over
+its ring, the sparse kind under its selection, the decay state and the
+Mamba-2 state (:class:`SsmStateEntry`: its state AND its convolution's
+tail) from the request's row. The gated-delta state, the latent row and
+the int8 pair prefill a prompt whole.
+
 An entry is the tuple of arrays its kind allocates, donated through the
 programs; the kind is static Python beside it. Every reader masks by
 position: a query at absolute position ``p`` sees keys at positions
@@ -1237,7 +1244,9 @@ class SsmStateEntry(EntryKind):
     for :class:`StateEntry`'s reasons. A step updates the states where
     they lie (``ssd_step_rows``: each row takes the inputs of the slot
     that names it, one elementwise pass over the arena), so the decode
-    program gathers and scatters no state and holds no loop."""
+    program gathers and scatters no state and holds no loop. A prompt in
+    a bucket is prefilled whole from zeros (:meth:`prefill`); a prompt in
+    chunks continues from the row (:meth:`chunk`)."""
 
     heads: int
     head_dim: int
@@ -1247,6 +1256,7 @@ class SsmStateEntry(EntryKind):
     name = "ssm_state"
     max_window = 1
     per_request = True
+    chunked = True
 
     @classmethod
     def for_op(cls, op, positions_id, max_length):
@@ -1291,11 +1301,30 @@ class SsmStateEntry(EntryKind):
         a prompt of its TRUE length leaves, whatever the bucket, written
         over the request's row (padding rows over the null row)."""
         out, state, tail = op.whole(weights, x, lengths)
-        with sub_scope("write"):
-            n = state.shape[0]
-            return out, (entry[0].at[addr.rows].set(state),
-                         entry[1].at[addr.rows].set(
-                             tail.reshape(n, -1).astype(entry[1].dtype)))
+        return out, self._put(entry, addr.rows, state, tail)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        """A row holds what the request before left: a first chunk starts
+        from zeros, not from it; a later one from the state and the tail
+        the chunk before wrote. What is written is what the chunk's TRUE
+        length leaves."""
+        n = x.shape[0]
+        later = offsets > 0
+        state = jnp.where(later[:, None, None, None], entry[0][addr.rows],
+                          0.0)
+        tail = jnp.where(later[:, None, None], entry[1][addr.rows].reshape(
+            n, self.tail, self.channels), 0)
+        out, state, tail = op.run(weights, x, state, tail, lengths)
+        return out, self._put(entry, addr.rows, state, tail)
+
+    @sub_scope("write")
+    def _put(self, entry, rows, state, tail):
+        """The prompts' states and tails over their rows (padding rows
+        over the null row)."""
+        n = state.shape[0]
+        return (entry[0].at[rows].set(state),
+                entry[1].at[rows].set(
+                    tail.reshape(n, -1).astype(entry[1].dtype)))
 
     def whole(self, op, weights, x, positions):
         out, state, tail = op.whole(weights, x)

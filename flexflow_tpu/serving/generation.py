@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ffconst import OpType
-from ..core.op import LowerCtx, fixed_scope, op_scope
+from ..core.op import LowerCtx, fixed_scope, op_scope, weights_of
 from ..obs.trace import span
 from .cache_entry import kind_for
 from .kv_cache import NULL_BLOCK, Addresses, PagedKVPool
@@ -316,7 +316,7 @@ class _DecodeGraph:
                      else acts[self._pos_id.tensor_id])
         for op in self._cm.ops:
             ins = [acts[t.tensor_id] for t in op.layer.inputs]
-            p = params.get(op.name, {})
+            p = weights_of(op, params)
             with op_scope(op):
                 if op.name in self._kinds:
                     outs = [attn(op, p, ins[0], positions)]

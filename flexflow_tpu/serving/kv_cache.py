@@ -63,9 +63,11 @@ the programs the two travel together as :class:`Addresses`.
 
 **The books.** What the decode steps read is the pool's to count, since
 the kinds, the geometry and ``stats()["kv"]`` are its own: the serving
-loop hands :meth:`PagedKVPool.count_step` a step's cached lengths and
-:meth:`PagedKVPool.chunk_keys` a chunk's span, and each kind says what a
-step behind such lengths reads of it (``step_reads``, ``rows_read``).
+loop hands :meth:`PagedKVPool.count_step` a step's cached lengths,
+:meth:`PagedKVPool.chunk_keys` a chunk's span and
+:meth:`PagedKVPool.count_chunk` its offset (the state rows it started
+from zeros or carried on), and each kind says what a step behind such
+lengths reads of it (``step_reads``, ``rows_read``).
 
 **Quantized arenas** (``kv_dtype``): the pool can store its arenas in
 ``"bfloat16"`` (cast-in/cast-out) or ``"int8"``: each op's entry in its
@@ -225,6 +227,10 @@ class PagedKVPool:
         # running sums over the decode steps count_step was told of
         self._steps = {"blocks_read": 0, "blocks_in_tables": 0,
                        "rows_stepped": 0}
+        # and over the prompt chunks count_chunk was told of: the state
+        # rows a chunk started from zeros (a prompt's first) and those it
+        # carried on from what the chunk before left
+        self._chunk_rows = {"rows_started": 0, "rows_carried": 0}
         self._state_ops = sum(k.per_request for k in self.kinds.values())
         # the ops whose steps read less than they keep, and the sums of
         # what they read, by the word their kind's step_reads says
@@ -362,6 +368,14 @@ class PagedKVPool:
                 for key, v in counts.items():
                     self._reads[word][key] += v
 
+    def count_chunk(self, offset: int) -> None:
+        """One chunk of a prompt at ``offset``: a state row a per-request
+        op, started from zeros at offset 0 and carried on past it."""
+        if self._state_ops:
+            with self._mu:
+                self._chunk_rows["rows_carried" if offset > 0
+                                 else "rows_started"] += self._state_ops
+
     def chunk_keys(self, offset: int, tokens: int) -> Tuple[int, int]:
         """The keys a chunk's queries see, at positions ``offset ..
         offset + tokens - 1``: in an op that keeps everything (a query at
@@ -380,7 +394,7 @@ class PagedKVPool:
         with self._mu:
             used = self.capacity_blocks - len(self._free)
             hw = self._high_water
-            steps = dict(self._steps)
+            steps = dict(self._steps, **self._chunk_rows)
             reads = {word: dict(sums) for word, sums in self._reads.items()}
             state = {"state": {
                 "rows": self.num_rows,
@@ -429,7 +443,8 @@ class PagedKVPool:
                 reads["window"], rows=kinds[0].window, ops=len(kinds),
                 rows_held=int(np.minimum(held, kinds[0].window).sum()))
         if "state" in out:
-            out["state"]["rows_stepped"] = steps["rows_stepped"]
+            out["state"].update({k: steps[k] for k in (
+                "rows_stepped", "rows_started", "rows_carried")})
         return out
 
     def _entry_stats(self) -> Dict:

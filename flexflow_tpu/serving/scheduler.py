@@ -723,6 +723,7 @@ class ContinuousBatchingScheduler:
             self._fail_prefill([req], e)
             return
         self._clock.count_chunk(n, *self.decoder.pool.chunk_keys(at, n))
+        self.decoder.pool.count_chunk(at)
         with self._mu:
             self._prefill_dispatches += 1
             if last:

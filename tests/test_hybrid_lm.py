@@ -425,9 +425,17 @@ def test_unknown_layer_type_is_refused():
 # took a window, rotary positions, a head size of its own, a per-head
 # norm and a gate, and before a pair took chunks: each of those is absent
 # by default, and every program of the older configurations stayed.
+# The two ``granite_hybrid`` digests were recorded with the builder (PR
+# 46); the other twelve kept theirs through the attention op's ``scale``,
+# the weight two ops read (``core/op.py`` ``weights_of``) and the Mamba
+# kind's ``chunk`` (its bucketed ``prefill`` and its ``step`` are what
+# they were: ``nemotron_h.decode`` and ``nemotron_h.prefill`` letter for
+# letter).
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
+    "granite_hybrid.decode": "a8ee0bc62339eebb4e39199a1c1e4fc8a79645d5c35101572d0202d2bd1d179e",
+    "granite_hybrid.prefill": "d120db5c5134d9da85549cb0c853891519f8b715e23bea6205c93ed3ff22e654",
     "gpt.train": "b910d8faaa4dc59157d5baffb29bd2f5ec466eb49c9b8bed2e2fb14878d1376f",
     "hybrid.decode": "1ccca11bf7d46d6b6847716a414a3e25a563e2874e54dbb59ea32f0f8053fd95",
     "hybrid.prefill": "5dd6b2309d3ad4c5946d040785336ddaedeae8f84a69854a1c0fb4a3e549f238",

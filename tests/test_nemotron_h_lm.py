@@ -697,16 +697,22 @@ def test_grouped_heads_through_the_pair_entry(toy):
 
 
 @pytest.mark.parametrize("window", [1, 2])
+@pytest.mark.parametrize("h,hkv,d", [(4, 2, 128), (8, 2, 64), (32, 8, 64)],
+                         ids=["lane-tile", "two-a-tile", "granite"])
 def test_paged_kernel_with_grouped_heads_equals_the_gather(monkeypatch,
-                                                           window):
-    """The paged decode kernel through the Pallas interpreter, 4 query
-    heads on 2 key-value heads of 128 (a head a whole lane tile): the
-    arena's rows are 256 lanes, the group's query heads are rows of one
-    score matrix; against the jnp gather over the same arenas, for slots
-    of different lengths and an idle slot."""
+                                                           window, h, hkv, d):
+    """The paged decode kernel through the Pallas interpreter with grouped
+    heads: 4 query heads on 2 key-value heads of 128 (a head a whole lane
+    tile: the arena's rows are 256 lanes), and heads of 64, two key-value
+    heads a lane tile (8 on 2; Granite's 32 on 8, rows of 512 lanes, with
+    a softmax scale of its own): the group's query heads are rows of one
+    score matrix, each keeping its query in its key-value head's lanes;
+    against the jnp gather over the same arenas, for slots of different
+    lengths and an idle slot."""
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
     rng = np.random.default_rng(window)
-    n, h, hkv, d, bs, mb, nb = 3, 4, 2, 128, 16, 4, 9
+    n, bs, mb, nb = 3, 16, 4, 9
+    scale = 0.015625 if h == 32 else d ** -0.5
     q = jnp.asarray(rng.normal(size=(n, window, h, d)), jnp.float32)
     k, v = (jnp.asarray(rng.normal(size=(nb, bs, hkv * d)), jnp.float32)
             for _ in range(2))
@@ -714,18 +720,21 @@ def test_paged_kernel_with_grouped_heads_equals_the_gather(monkeypatch,
                          jnp.int32)
     lens = jnp.asarray([37, 16, 0], jnp.int32)
     assert paged_attention.supported(q.shape, k.shape, k.dtype, mb)
-    # a key head a query head at the same widths stays supported, grouped
-    # heads narrower than a lane tile are not
-    assert paged_attention.supported((n, 1, 2, d), k.shape, k.dtype, mb)
-    assert not paged_attention.supported((n, 1, 8, 64), (nb, bs, 4 * 64),
+    # a key head a query head at the same widths stays supported; grouped
+    # heads narrower than half a lane tile, or rows that do not fill
+    # whole lane tiles, are not
+    assert paged_attention.supported((n, 1, hkv, d), k.shape, k.dtype, mb)
+    assert not paged_attention.supported((n, 1, 8, 32), (nb, bs, 4 * 32),
+                                         k.dtype, mb)
+    assert not paged_attention.supported((n, 1, 6, 64), (nb, bs, 3 * 64),
                                          k.dtype, mb)
     got = paged_attention.paged_attention_decode(q, k, v, tables, lens,
-                                                 scale=d ** -0.5)
+                                                 scale=scale)
     kind = cache_entry.PairEntry(hkv, d, h)
     kk, vv = kind.read((k, v), tables)
     pos = lens[:, None] + jnp.arange(window)[None, :]
     mask = jnp.arange(kk.shape[1])[None, None, :] <= pos[:, :, None]
-    want = cache_entry._attend(q, kk, vv, lambda: mask[:, None], d ** -0.5)
+    want = cache_entry._attend(q, kk, vv, lambda: mask[:, None], scale)
     assert np.abs(np.asarray(got - want))[:2].max() <= 1e-5
 
 
@@ -750,7 +759,15 @@ def test_the_state_kind_refuses_rollback_and_int8_by_name(toy):
                      kv_dtype="int8")
     kind = cache_entry.SsmStateEntry(4, 8, 8, 3, 64)
     assert kind.max_window == 1 and kind.int8_form is None
-    assert kind.per_request and not kind.chunked
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        PagedDecoder(ff, MAX_LEN, decode_slots=2, block_size=8,
-                     prefill_chunk=16)
+    # (it takes chunks since ``SsmStateEntry.chunk``: a prompt in chunks
+    # of 16 leaves the logits a bucket of 32 leaves)
+    assert kind.per_request and kind.chunked
+    prompt = np.random.default_rng(5).integers(
+        0, TOY["vocab_size"], 27).astype(np.int32)
+    outs = []
+    for kw in (dict(prefill_buckets=[32]), dict(prefill_chunk=16)):
+        dec = PagedDecoder(ff, MAX_LEN, decode_slots=2, block_size=8,
+                           calibrate=False, **kw)
+        table = dec.pool.try_admit(28)
+        outs.append(dec.prefill(prompt, table))
+    assert np.abs(outs[0] - outs[1]).max() <= 2e-4 * np.abs(outs[0]).max()

@@ -42,6 +42,7 @@ class _Op:
     rotary = None
     gate = False
     head_dim = HEAD_DIM
+    _scale = None                      # no scale of the model's own
     scale = MultiHeadAttention.scale
     project_qkv = MultiHeadAttention.project_qkv
     project_out = MultiHeadAttention.project_out

@@ -913,3 +913,216 @@ def test_trinity_chunk_programs_hold_no_square(trinity_programs, name):
             for d in m.group(1).split(","):
                 size *= int(d)
             assert size < 33 * 4096 * 1024, ln
+
+
+# ---- Granite 4.0-H: states through chunks, a tied head, scaled residuals ------
+
+def _granite_programs(one_chip, **overrides):
+    """The decode step and both chunk programs of two Mamba-2 blocks, an
+    attention block and a Mamba-2 block (the published layers 3 to 6) at
+    Granite 4.0-H Micro's published widths and its cell's sizes (48
+    slots, contexts to 9,216, blocks of 64, chunks of 2,048, the whole
+    vocabulary), compiled for the described chip: {name: (the compiled
+    text, its memory analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import granite_hybrid as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=4,
+                  layer_types=config["layer_types"][3:7], **overrides)
+    assert config["layer_types"] == ["mamba", "mamba", "attention", "mamba"]
+    slots, max_length, chunk = 48, 9216, 2048
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=64, kv_dtype="bfloat16",
+                               calibrate=False, prefill_chunk=chunk)
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, head in (("chunk", False), ("chunk_head", True)):
+                if overrides:
+                    continue
+                compiled = jax.jit(
+                    lambda *a, head=head: dec._chunk_step(*a, head=head),
+                    donate_argnums=(2,)).lower(
+                    params, ints(1, chunk), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1),
+                    ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+@pytest.fixture(scope="module")
+def granite_programs(one_chip):
+    return _granite_programs(one_chip)
+
+
+def test_granite_decode_step_holds_no_while_and_moves_no_state(
+        granite_programs):
+    """The decode step of Mamba-2 and attention blocks holds no ``while``,
+    no ``conditional`` and no ``dynamic-update-slice``; no gather or
+    scatter touches a state (three arenas of 49 x 64 x 64 x 128 float32,
+    103 MB each, each made by ONE fusion under its op's ``rule`` and
+    aliased to its output); the only scatter is the new token's keys and
+    values; the attention layer reads its blocks in place by the paged
+    kernel, 32 query heads on 8 key-value heads of 64, two of them a lane
+    tile (``attention_path`` ``kernel``: ONE Mosaic call, and no copy of
+    every slot's table: the gather it replaces made 48 x 144 x 64 x 512
+    bfloat16 twice, 0.9 GB, and was 36 of the step's 60 ms on the chip);
+    the tied head is ONE product that reads the embedding's table where
+    it lies: no buffer of the table's size is made, transposed or not."""
+    from flexflow_tpu.core.op import parse_scope
+
+    programs, dec = granite_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path == {"decode": "kernel", "chunk": "scan"}
+    assert " while(" not in text and " conditional(" not in text
+    assert "dynamic-update-slice" not in text
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "paged_attention_decode" in calls[0]
+    assert "ff.MULTIHEAD_ATTENTION.block2_mixer/attend" in calls[0]
+    state = "f32[49,64,64,128]"
+    for ln in _buffers(text):
+        head = ln.split(" = ", 1)[1]
+        if " gather(" in ln or " scatter(" in ln:
+            assert "[48,64,64,128]" not in head.split("(")[0] \
+                and state not in head, ln
+        if " scatter(" in ln:
+            assert "ff.MULTIHEAD_ATTENTION.block2_mixer/write" in ln, ln
+    names = _entry_op_names(text)
+    made = {}
+    for ln in _buffers(text):
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([a-z][\w\-]*)\(", ln)
+        # (the compiler may bring an arena into fast memory ahead of its
+        # fusion, in slices it joins by a bitcast: a copy of the
+        # scheduler's, once through, not a gather of the program's)
+        if (m and state in m.group(2)
+                and m.group(3) not in ("parameter", "bitcast", "copy-done",
+                                       "copy-start", "slice-start",
+                                       "slice-done", "get-tuple-element",
+                                       "tuple")
+                and "ConcatBitcast" not in ln):
+            made[m.group(1)] = m.group(3)
+    assert sorted(made.values()) == ["fusion"] * 3, made
+    assert {parse_scope(names[w])[:3] for w in made} == {
+        ("MAMBA2", f"block{i}_mixer", ("rule",)) for i in (0, 1, 3)}
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+    # the table: the parameter, and nothing else of its size at all
+    for ln in _buffers(text):
+        if "[100352,2048]" in ln or "[2048,100352]" in ln:
+            assert " parameter(" in ln, ln
+    heads = [ln for ln in _buffers(text) if "ff.LINEAR.lm_head" in ln
+             and "dot_general" in ln]
+    assert len(heads) == 1 and "embed" in heads[0].split("fusion(")[1]
+    assert "lm_head" not in dec._params_sds()
+    # no slot's table is copied, and little else is held
+    assert "[48,144,64,512]" not in text and "[48,9216," not in text
+    assert mem.temp_size_in_bytes < 128 << 20
+
+
+def test_granite_multipliers_cost_no_pass_of_their_own(one_chip,
+                                                       granite_programs):
+    """The embedding's multiplier, the residuals' 0.22 and the logits'
+    divisor are ``SCALAR_MULTIPLY`` ops of the graph: the compiler fuses
+    each into a neighbour, so the decode step with them holds no more
+    device operations (what a trace has an event for) than the same
+    model with all three at 1, where the builder adds no such op."""
+    with_them = granite_programs[0]["decode"][0]
+    without, dec = _granite_programs(one_chip, embedding_multiplier=1,
+                                     residual_multiplier=1.0,
+                                     logits_scaling=1)
+    assert not [op for op in dec._cm.ops if "scale" in op.name]
+    assert [op.name for op in granite_programs[1]._cm.ops
+            if op.name.endswith("_scale")] == [
+        "embed_scale", *(f"block{i}_{p}_scale" for i in range(4)
+                         for p in ("mixer", "mlp")), "logits_scale"]
+
+    def kernels(text):
+        return sum(bool(re.search(r" (fusion|convolution|custom-call|"
+                                  r"gather|scatter|copy|dot)\(", ln))
+                   for ln in _buffers(text))
+
+    assert kernels(with_them) <= kernels(without["decode"][0])
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head"])
+def test_granite_chunk_programs_carry_the_states_under_their_scopes(
+        granite_programs, name):
+    """A chunk of 2,048 tokens behind the states: the Mamba ops' pieces
+    are named (``project``, ``conv``, ``rule``, ``write``: the owner table
+    tells a chunk's scan from its projections), the attention op's
+    ``attend`` and ``write``; the only loops are the scan's walk over its
+    blocks of 256 and the attention's over its key spans; no buffer holds
+    the chunk's queries against the whole context, and none a row of the
+    vocabulary for every token (the head is computed for the last row
+    alone: ``chunk`` holds no product with the table at all)."""
+    from flexflow_tpu.core.op import parse_scope
+
+    text, mem = granite_programs[0][name]
+    owners = {parse_scope(m) for m in re.findall(r'op_name="([^"]+)"', text)
+              } - {None}
+    subs = {(kind, s) for kind, _, ss, _ in owners for s in ss}
+    assert {("MAMBA2", "project"), ("MAMBA2", "conv"), ("MAMBA2", "rule"),
+            ("MAMBA2", "write"), ("MULTIHEAD_ATTENTION", "project"),
+            ("MULTIHEAD_ATTENTION", "write"),
+            ("MULTIHEAD_ATTENTION", "attend")} <= subs, subs
+    for ln in text.splitlines():
+        if " while(" in ln:
+            assert ("ff.MAMBA2." in ln and "/rule/" in ln) or (
+                "ff.MULTIHEAD_ATTENTION." in ln and "/attend/" in ln), ln
+    for ln in _buffers(text):
+        for shape in re.findall(r"\[([\d,]+)\]", ln.split(" = ")[1]
+                                .split("(")[0] if " = " in ln else ""):
+            dims = [int(d) for d in shape.split(",")]
+            # (the hidden size is the chunk's length: a square of 2,048
+            # says nothing here; the context's length does)
+            assert not (2048 in dims and 9216 in dims), ln
+            assert not (2048 in dims and 100352 in dims) \
+                or " parameter(" in ln, ln
+    has_head = any("ff.LINEAR.lm_head" in ln for ln in _buffers(text))
+    assert has_head == (name == "chunk_head")
+    assert mem.alias_size_in_bytes >= granite_programs[1].pool.memory_bytes()
+    assert mem.temp_size_in_bytes < 1 << 30
