@@ -565,6 +565,63 @@ def check_gated_delta(slots: int, heads: int, key_dim: int, value_dim: int,
     return err
 
 
+# Kernel and jnp form are float32 elementwise work and one sum over the
+# state's axis; they differ by that sum's order and by which state ``y``
+# is read from (the kernel: the new one; the jnp form: the old one and
+# ``u (B . C)``).
+SSD_STEP_RANGE_TOL = 1e-5
+
+
+def check_ssd_step(slots: int, heads: int, head_dim: int, state: int,
+                   groups: int, mosaic: bool) -> float:
+    """The Mamba-2 decode-step kernel against its jnp form
+    (``ops/mamba2.py`` ``ssd_step_rows``) over the same arena of states:
+    every slot on a row of its own, in no order, but two idle ones on the
+    null row, decays in (0.5, 1). Returns the largest error of the live
+    slots' outputs and new states, each as a share of its reference's
+    largest magnitude; the null row and the rows no slot names must come
+    back bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels import ssd_step
+    from flexflow_tpu.ops import mamba2
+
+    rows_n = slots + 2
+    shape = (rows_n, state, heads * head_dim)
+    _require(ssd_step.supported(slots, heads, head_dim, state, groups, shape,
+                                jnp.float32),
+             f"ssd_step.supported() refuses {slots} slots over {shape}")
+    rng = np.random.default_rng(0)
+    arena = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    rows = rng.permutation(np.arange(1, rows_n))[:slots].astype(np.int32)
+    rows[[1, slots - 1]] = 0
+    args = tuple(map(jnp.asarray, (
+        rows, rng.normal(size=(slots, heads, head_dim)).astype(np.float32),
+        rng.uniform(0.5, 1.0, size=(slots, heads)).astype(np.float32),
+        rng.normal(size=(slots, groups, state)).astype(np.float32),
+        rng.normal(size=(slots, groups, state)).astype(np.float32))))
+    got_fn = jax.jit(ssd_step.ssd_step_decode)
+    if mosaic:
+        _assert_mosaic(got_fn, arena, *args)
+    y, new = got_fn(arena, *args)
+    y_ref, new_ref = jax.jit(mamba2.ssd_step_rows)(arena, *args)
+    live = rows != 0
+    held = rows[live]
+    untouched = np.setdiff1d(np.arange(rows_n), held)
+    _require(np.array_equal(np.asarray(new)[untouched],
+                            np.asarray(arena)[untouched]),
+             "ssd step: the null row or a row no slot names was written")
+    err = 0.0
+    for a, r in ((np.asarray(y)[live], np.asarray(y_ref)[live]),
+                 (np.asarray(new)[held], np.asarray(new_ref)[held])):
+        err = max(err, float(np.max(np.abs(a - r)) / np.max(np.abs(r))))
+    _require(err <= SSD_STEP_RANGE_TOL,
+             f"ssd step ({slots} slots, {shape}): max error {err:.2e} of "
+             f"range > {SSD_STEP_RANGE_TOL}")
+    return err
+
+
 # The whole-sequence kernel's products are float32 at float32 contract
 # precision, as its jnp form's are at `highest`; they differ by the order
 # of sums and by how each inverts a chunk's unit-lower system (doubling
@@ -650,6 +707,14 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
     e = check_gated_delta_chunks(*((1536, 30) if mosaic else (150, 6)),
                                  96, 192, mosaic)
     errs.update({f"gated_delta_chunks_{k}": f"{v:.1e}" for k, v in e.items()})
+    # Mamba-2 states at the retrieval and the agents cells' widths (64
+    # heads in one group, 128 in 8; rows of 2.1 and 4.2 MB) and slots; a
+    # toy of each shape class under the interpreter
+    for name, shape in (("granite", (48, 64, 64, 128, 1) if mosaic
+                         else (4, 4, 32, 16, 1)),
+                        ("nemotron", (128, 128, 64, 128, 8) if mosaic
+                         else (4, 8, 32, 16, 2))):
+        errs[f"ssd_step_{name}"] = "%.1e" % check_ssd_step(*shape, mosaic)
     return ph.report(interpret=not mosaic, flash_shape=shape,
                      moe_shape=sizes.moe, **errs)
 
@@ -716,6 +781,84 @@ def serve_hybrid() -> Dict[str, str]:
     _require(set(paths.values()) == {"kernel"}
              or jax.default_backend() != "tpu",
              f"the hybrid's programs took {paths}")
+    return paths
+
+
+SSM_TOY = dict(slots=2, vocab=512, max_length=96)
+
+
+def ssm_toy_families() -> Dict[str, tuple]:
+    """A small model of each family that keeps Mamba-2 states: a Mamba-2
+    and an attention block at widths both kinds' kernels take (Granite: 4
+    heads of 64 in one group, a prompt prefilled in chunks through the
+    state; Nemotron-H: 8 heads of 32 in 2 groups, prefilled in a bucket).
+    {name: (the builder, its configuration, how ``GenerationInstance``
+    prefills)}; ``tests/test_ssd_step_kernel.py`` serves the same two."""
+    from flexflow_tpu.models import (GraniteHybridConfig, NemotronHConfig,
+                                     build_granite_hybrid_lm,
+                                     build_nemotron_h_lm)
+
+    common = dict(vocab_size=SSM_TOY["vocab"], hidden_size=256,
+                  state_size=16, num_heads=2, num_kv_heads=1, chunk_size=16)
+    return {
+        "granite": (build_granite_hybrid_lm, GraniteHybridConfig(
+            layer_types=("mamba", "attention"), mamba_heads=4,
+            mamba_head_dim=64, mlp_width=512, **common),
+            dict(prefill_chunk=32)),
+        "nemotron": (build_nemotron_h_lm, NemotronHConfig(
+            pattern="M*", mamba_heads=8, mamba_head_dim=32, n_groups=2,
+            **common),
+            dict(prefill_buckets=[48, SSM_TOY["max_length"]])),
+    }
+
+
+def serve_ssm() -> Dict[str, str]:
+    """:func:`ssm_toy_families` through ``GenerationInstance``: two greedy
+    requests over two slots. Returns which form each model's decode step
+    took for its states (the counters ``ssm_step.path.*``): on the chip
+    it must be the kernel, and the step read both caches in place."""
+    import jax
+
+    from flexflow_tpu import FFModel
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.obs.metrics import metrics_registry
+    from flexflow_tpu.serving import GenerationInstance
+
+    slots, vocab, max_length = (SSM_TOY[k] for k in
+                                ("slots", "vocab", "max_length"))
+    families = ssm_toy_families()
+    reg = metrics_registry()
+    paths = {}
+    for name, (build, cfg, how) in families.items():
+        before = _path_counts(reg, "ssm_step", ("kernel", "rows"))
+        ff = FFModel(_ff_config(batch_size=slots,
+                                computation_mode=CompMode.INFERENCE))
+        build(ff, slots, max_length, cfg)
+        ff.compile(optimizer=None, loss_type=None, metrics=[])
+        inst = GenerationInstance(ff, decode_slots=slots, block_size=16,
+                                  max_length=max_length, kv_dtype=KV_DTYPE,
+                                  **how)
+        try:
+            rng = np.random.default_rng(2)
+            reqs = [(rng.integers(0, vocab, n).astype(np.int32), m)
+                    for n, m in ((40, 5), (20, 4))]
+            futures = [inst.generate_async(p, m, temperature=0.0)
+                       for p, m in reqs]
+            for (prompt, m), fut in zip(reqs, futures):
+                out = fut.result(timeout=900)
+                _require(out.shape == (prompt.size + m,)
+                         and np.array_equal(out[:prompt.size], prompt),
+                         f"{name} request of {prompt.size}+{m} tokens "
+                         f"returned {out.shape}")
+            decode = inst.stats()["kv"]["attention_path"]["decode"]
+        finally:
+            inst.stop()
+        took = "+".join(_paths_taken(reg, "ssm_step", before))
+        _require((took, decode) == ("kernel", "kernel")
+                 or jax.default_backend() != "tpu",
+                 f"{name}'s decode step took ssm_step.path.{took} and read "
+                 f"its caches by {decode}")
+        paths[f"{name}_ssm_step_path"] = took
     return paths
 
 
@@ -820,7 +963,7 @@ def phase_serve(sizes: SmokeSizes) -> Dict:
     finally:
         inst.stop()
     return ph.report(
-        **serve_hybrid(),
+        **serve_hybrid(), **serve_ssm(),
         requests=sizes.requests, tokens=st["tokens"],
         decode_steps=st["decode_steps"],
         prefill_dispatches=st["prefill_dispatches"],

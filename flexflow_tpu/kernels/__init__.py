@@ -38,6 +38,16 @@ that keep the working set in VMEM and feed the MXU directly:
   VMEM from the first chunk to the last (``ops/gated_delta.py`` keeps
   the jnp forms, taken where ``supported()`` / ``chunks_supported()``
   refuse).
+* :mod:`ssd_step` — the Mamba-2 decode step over a pool's per-request
+  states in place: the live slots' rows of a ``(rows, N, H P)`` arena by
+  scalar prefetch, each read once, stepped and written back through the
+  aliased arena, rows no slot names left alone and idle slots at no
+  traffic (``serving/cache_entry.py`` ``SsmStateEntry.step`` chooses;
+  ``ops/mamba2.py`` ``ssd_step_rows``, one elementwise pass over the
+  whole arena in jnp, stays for what ``supported()`` refuses: the CPU, a
+  state of no whole sublane tile, channels of no whole lane tiles, a row
+  past the fast memory). The whole-sequence form a prefill or a chunk
+  runs is jnp (``chunked_ssd``); no kernel is written for it.
 * :mod:`grouped_experts` — the held experts of a routed layer over a
   call's rows as one kernel: row tiles named by the routing (an expert
   named by ``n`` pairs gets ``ceil(n / tile)``, one named by none gets

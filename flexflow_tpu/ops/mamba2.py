@@ -33,9 +33,13 @@ at a time: within a chunk the masked products ``(C B^T * L) (dt xs)`` with
 chunks the state is carried by ``lax.scan``. :func:`ssd_step_rows` takes
 one token a slot on the rows of an arena where they lie: the slots'
 inputs are spread over the rows and the arena is updated elementwise, so
-that no state is gathered or scattered and the step holds no loop. The
-projections around them are in the activations' dtype with float32
-accumulation.
+that no state is gathered or scattered and the step holds no loop. It
+is the CPU's form and the reference of the form the chip runs,
+``kernels/ssd_step.py`` ``ssd_step_decode``: the same step as one Pallas
+kernel over the live rows alone (``serving/cache_entry.py``
+``SsmStateEntry.step`` takes the kernel where its ``supported()``
+agrees). The projections around them are in the activations' dtype
+with float32 accumulation.
 """
 
 from __future__ import annotations
@@ -117,27 +121,33 @@ def ssd_step(state, u, decay, bm, cm):
 
 
 def ssd_step_rows(arena, rows, u, decay, bm, cm):
-    """:func:`ssd_step` on the rows of an arena, in place: ``arena`` (R, H,
-    P, S) holds a state a row, slot n steps row ``rows[n]`` (row 0 is
-    nobody's: a slot that names it steps nothing and reads what lies
-    there). Each row takes the inputs of the slot that names it and the
-    arena is updated elementwise where it lies: one read and one write of
-    the states, no gather or scatter of them. ``y`` is read from the
-    state before the update (``S_t C = a S_{t-1} C + u (B . C)``), so the
-    pass that writes the new state is the pass that reads the old.
-    Returns (y (N, H, P), the new arena)."""
-    per = arena.shape[1] // bm.shape[1]
+    """:func:`ssd_step` on the rows of an arena, in place: ``arena`` (R, S,
+    H P) holds a state a row as the pool stores it, the state's axis
+    before the heads' channels (``kernels/ssd_step.py``), and slot n steps
+    row ``rows[n]`` (row 0 is nobody's: a slot that names it steps
+    nothing and reads what lies there). Each row takes the inputs of the
+    slot that names it and the arena is updated elementwise where it
+    lies: one read and one write of the states, no gather or scatter of
+    them. ``y`` is read from the state before the update (``S_t C = a
+    S_{t-1} C + u (B . C)``), so the pass that writes the new state is
+    the pass that reads the old. Returns (y (N, H, P), the new arena)."""
+    n, h, p = u.shape
+    per = h // bm.shape[1]
     hot = ((rows[:, None] == jax.lax.iota(jnp.int32, arena.shape[0]))
            & (rows[:, None] != 0))                               # (N, R)
     who = jnp.argmax(hot, axis=0)
     ur, ar, br, cr = (v[who] for v in (u, decay, bm, cm))
-    bh, ch = (jnp.repeat(v, per, axis=1) for v in (br, cr))     # (R, H, S)
-    y = (ar[..., None] * jnp.einsum("rhps,rhs->rhp", arena, ch, precision=_HI)
-         + ur * jnp.sum(bh * ch, -1)[..., None])
-    arena = jnp.where(hot.any(0)[:, None, None, None],
-                      ar[..., None, None] * arena
-                      + ur[..., None] * bh[:, :, None, :], arena)
-    return y[rows], arena
+
+    def lanes(v):          # a value a head, or a group, along the channels
+        return jnp.repeat(v, h * p // v.shape[-1], axis=-1)
+
+    ul, al = ur.reshape(-1, h * p), lanes(ar)                    # (R, H P)
+    bl, cl = (lanes(jnp.swapaxes(v, 1, 2)) for v in (br, cr))   # (R, S, H P)
+    y = (al * jnp.sum(arena * cl, axis=1)
+         + ul * lanes(jnp.sum(br * cr, -1)))
+    arena = jnp.where(hot.any(0)[:, None, None],
+                      al[:, None] * arena + ul[:, None] * bl, arena)
+    return y[rows].reshape(n, h, p), arena
 
 
 @register_op

@@ -45,7 +45,8 @@ import numpy as np
 from ..core.op import sub_scope
 from ..ffconst import OpType
 from ..kernels import (chunk_attention, gated_delta, latent_attention,
-                       paged_attention)
+                       paged_attention, ssd_step)
+from ..obs.metrics import metrics_registry
 from ..ops import block_sparse_attention as bsa
 from ..ops import mamba2
 from ..ops.gated_delta import delta_rule_path
@@ -1237,16 +1238,24 @@ class DecayStateEntry(EntryKind):
 @dataclasses.dataclass(frozen=True)
 class SsmStateEntry(EntryKind):
     """A state-space op's one row a REQUEST (``ops/mamba2.py``): the
-    float32 state of its heads, ``(H, P, N)``, and the last ``taps - 1``
-    inputs of its convolution, flat, in the rows the pool hands out to
-    every ``per_request`` kind. float32 whatever ``kv_dtype`` says, one
-    token a slot a step, and the tails back through :func:`_spread_rows`,
-    for :class:`StateEntry`'s reasons. A step updates the states where
-    they lie (``ssd_step_rows``: each row takes the inputs of the slot
-    that names it, one elementwise pass over the arena), so the decode
-    program gathers and scatters no state and holds no loop. A prompt in
-    a bucket is prefilled whole from zeros (:meth:`prefill`); a prompt in
-    chunks continues from the row (:meth:`chunk`)."""
+    float32 state of its heads, ``(N, H P)`` with the state's axis on the
+    sublanes and the heads' channels side by side on the lanes
+    (``kernels/ssd_step.py``: the step's sum over ``N`` runs down
+    sublanes), and the last ``taps - 1`` inputs of its convolution, flat,
+    in the rows the pool hands out to every ``per_request`` kind. float32
+    whatever ``kv_dtype`` says, one token a slot a step, and the tails
+    back through :func:`_spread_rows`, for :class:`StateEntry`'s reasons.
+    A step updates the states where they lie, so the decode program
+    gathers and scatters no state and holds no loop: on the chip by the
+    ``ssd_step_decode`` kernel, one call a layer that moves the live
+    slots' rows and no other (:meth:`reads_in_place`; the counter
+    ``ssm_step.path.kernel``); elsewhere by ``ssd_step_rows``, one
+    elementwise pass over the arena in which each row takes the inputs of
+    the slot that names it (``ssm_step.path.rows``: the CPU, the kernel's
+    reference). A prompt in a bucket is prefilled whole from zeros
+    (:meth:`prefill`); a prompt in chunks continues from the row
+    (:meth:`chunk`); both turn the op's ``(H, P, N)`` state a request
+    into the row's layout and back (:meth:`_put`, :meth:`_rows`)."""
 
     heads: int
     head_dim: int
@@ -1265,7 +1274,7 @@ class SsmStateEntry(EntryKind):
 
     def arenas(self, rows, block_size, dtype):
         return (jax.ShapeDtypeStruct(
-                    (rows, self.heads, self.head_dim, self.state_size),
+                    (rows, self.state_size, self.heads * self.head_dim),
                     jnp.float32),
                 jax.ShapeDtypeStruct((rows, self.tail * self.channels),
                                      dtype))
@@ -1274,8 +1283,9 @@ class SsmStateEntry(EntryKind):
         return {"entry": self.name, "state_dtype": "float32"}
 
     def reads_in_place(self, op, entry, slots, window, max_blocks):
-        # the step's one form: the arena updated where it lies
-        return window == 1
+        return window == 1 and ssd_step.supported(
+            slots, self.heads, self.head_dim, self.state_size, op.n_groups,
+            entry[0].shape, entry[0].dtype)
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         n = x.shape[0]                   # one token a slot: ``max_window``
@@ -1291,7 +1301,13 @@ class SsmStateEntry(EntryKind):
                                  window[:, 1:].reshape(n, -1))
         with sub_scope("rule"):
             dt1 = dt[:, 0]
-            y, state = mamba2.ssd_step_rows(
+            update, path = ((ssd_step.ssd_step_decode, "kernel")
+                            if self.reads_in_place(op, entry, n, 1, 0)
+                            else (mamba2.ssd_step_rows, "rows"))
+            # which form this lowering took, counted once a trace (as
+            # ``attention.path.*``): a chip run has to be able to say
+            metrics_registry().counter(f"ssm_step.path.{path}").inc()
+            y, state = update(
                 state, addr.rows, xs[:, 0] * dt1[..., None],
                 jnp.exp(dt1 * op.decay_rate(weights)), bm[:, 0], cm[:, 0])
         return op.finish(weights, z, xs, y[:, None]), (state, tails)
@@ -1310,8 +1326,8 @@ class SsmStateEntry(EntryKind):
         length leaves."""
         n = x.shape[0]
         later = offsets > 0
-        state = jnp.where(later[:, None, None, None], entry[0][addr.rows],
-                          0.0)
+        state = jnp.where(later[:, None, None, None],
+                          self._rows(entry[0], addr.rows), 0.0)
         tail = jnp.where(later[:, None, None], entry[1][addr.rows].reshape(
             n, self.tail, self.channels), 0)
         out, state, tail = op.run(weights, x, state, tail, lengths)
@@ -1319,12 +1335,19 @@ class SsmStateEntry(EntryKind):
 
     @sub_scope("write")
     def _put(self, entry, rows, state, tail):
-        """The prompts' states and tails over their rows (padding rows
-        over the null row)."""
+        """The prompts' states (N, H, P, S) and tails over their rows
+        (padding rows over the null row)."""
         n = state.shape[0]
-        return (entry[0].at[rows].set(state),
+        lanes = jnp.swapaxes(state.reshape(n, -1, self.state_size), 1, 2)
+        return (entry[0].at[rows].set(lanes),
                 entry[1].at[rows].set(
                     tail.reshape(n, -1).astype(entry[1].dtype)))
+
+    def _rows(self, arena, rows):
+        """:meth:`_put` backwards: the states of ``rows`` as the op takes
+        them, (N, H, P, S)."""
+        return jnp.swapaxes(arena[rows], 1, 2).reshape(
+            len(rows), self.heads, self.head_dim, self.state_size)
 
     def whole(self, op, weights, x, positions):
         out, state, tail = op.whole(weights, x)

@@ -430,20 +430,27 @@ def test_unknown_layer_type_is_refused():
 # the weight two ops read (``core/op.py`` ``weights_of``) and the Mamba
 # kind's ``chunk`` (its bucketed ``prefill`` and its ``step`` are what
 # they were: ``nemotron_h.decode`` and ``nemotron_h.prefill`` letter for
-# letter).
+# letter). The four ``granite_hybrid`` and ``nemotron_h`` digests were
+# recorded again when the Mamba kind took to storing a state's row as
+# ``(N, H P)``, the layout the ``ssd_step_decode`` kernel steps in place
+# (PR 47: ``SsmStateEntry.arenas``, ``_put``, ``_rows`` and
+# ``ssd_step_rows`` are the places that know it): the four programs that
+# hold such an arena, and no other; on the CPU the kernel's
+# ``supported()`` refuses and the step is ``ssd_step_rows`` as before,
+# over the new rows. The other ten kept theirs letter for letter.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
-    "granite_hybrid.decode": "a8ee0bc62339eebb4e39199a1c1e4fc8a79645d5c35101572d0202d2bd1d179e",
-    "granite_hybrid.prefill": "d120db5c5134d9da85549cb0c853891519f8b715e23bea6205c93ed3ff22e654",
+    "granite_hybrid.decode": "e53be8f475abf4dbb3c3f6ad8519f758191cbaffeb423b024159374e1cb795f7",
+    "granite_hybrid.prefill": "b67646167b8a5d6226b90af276e2ce9befad6e662d067becd50ca1f6ec370113",
     "gpt.train": "b910d8faaa4dc59157d5baffb29bd2f5ec466eb49c9b8bed2e2fb14878d1376f",
     "hybrid.decode": "1ccca11bf7d46d6b6847716a414a3e25a563e2874e54dbb59ea32f0f8053fd95",
     "hybrid.prefill": "5dd6b2309d3ad4c5946d040785336ddaedeae8f84a69854a1c0fb4a3e549f238",
     "latent_moe.decode": "0ccd617d2dc93b7d2aa65670f4a282beb5ccbe9dcf7d6a790ce075fbefe4fe6f",
     "latent_moe.prefill": "83c9d597439ce9ec12a940c2520a62343025098d238f95a61f5b25c9f4423ff2",
     "latent_moe.train": "ec35820380d8c0377a8b07e79d3248e5c9af80e3156d4e7f49d29b7f78eb7b8f",
-    "nemotron_h.decode": "ed5434d26c53e5cf2063448a886f150ff3ed50f02420462142a94f76b141fc17",
-    "nemotron_h.prefill": "edd55bfd742698da21c788cb1971a1d66e73b3212926047075cc416371f70d1a",
+    "nemotron_h.decode": "c088dc5b6ecee8626ce1f3d6d5394f5dea08fa1388186a8df0f927f26762c5a1",
+    "nemotron_h.prefill": "aa21ea5bac064e9bc3ea1d29c00124399c95aa1a77105e0d62b0f4c13fe161d5",
     "sparse_hybrid.decode": "83d1e616746ba634c9f0aca6072617b5fd0ba086364cd3849a57b2606898ce18",
     "sparse_hybrid.prefill": "8af30f7980a5ae6c8d6bc03e7fc11a1bf8b0b63173f63adc8bc10cad4bb2f962",
 }

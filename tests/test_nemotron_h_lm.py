@@ -114,22 +114,28 @@ def test_mamba_chunked_form_step_and_recurrence_agree(toy, length, bucket):
 def test_mamba_step_on_the_arena_rows_is_the_plain_step():
     """``ssd_step_rows`` (the arena updated where it lies, each row
     taking the inputs of the slot that names it) against ``ssd_step`` on
-    the gathered rows; the null row and the rows no slot names stay."""
+    the gathered rows; the null row and the rows no slot names stay. A
+    row is stored as the pool stores it, ``(S, H P)``."""
     rng = np.random.default_rng(0)
     rows, n, h, p, s, g = 6, 3, 4, 8, 8, 2
-    arena = jnp.asarray(rng.normal(size=(rows, h, p, s)), jnp.float32)
+    arena = jnp.asarray(rng.normal(size=(rows, s, h * p)), jnp.float32)
+
+    def states(a):                       # rows (.., S, H P) -> (.., H, P, S)
+        return jnp.moveaxis(a.reshape(a.shape[:-1] + (h, p)), -3, -1)
+
     slot_rows = jnp.asarray([4, 0, 2], jnp.int32)
     u = jnp.asarray(rng.normal(size=(n, h, p)), jnp.float32)
     decay = jnp.asarray(rng.uniform(0.5, 1.0, (n, h)), jnp.float32)
     bm, cm = (jnp.asarray(rng.normal(size=(n, g, s)), jnp.float32)
               for _ in range(2))
     y, new = mamba2.ssd_step_rows(arena, slot_rows, u, decay, bm, cm)
-    want_y, want = mamba2.ssd_step(arena[slot_rows], u, decay, bm, cm)
+    want_y, want = mamba2.ssd_step(states(arena[slot_rows]), u, decay, bm,
+                                   cm)
     for i, r in enumerate([4, 0, 2]):
         if r == 0:
             continue
         assert np.allclose(y[i], want_y[i], atol=1e-5)
-        assert np.allclose(new[r], want[i], atol=1e-6)
+        assert np.allclose(states(new[r]), want[i], atol=1e-6)
     for r in (0, 1, 3, 5):
         assert np.array_equal(np.asarray(new[r]), np.asarray(arena[r]))
 

@@ -192,6 +192,37 @@ def test_gated_delta_decode_compiles_for_v5e(monkeypatch, one_chip,
     assert mem.temp_size_in_bytes < arena_bytes // 8
 
 
+@pytest.mark.parametrize("cell, n, rows, h, g", [
+    ("granite-4.0-h-micro.serve-rag", 48, 49, 64, 1),
+    ("nemotron3-super-ep4.serve-agents", 128, 129, 128, 8)])
+def test_ssd_step_decode_compiles_for_v5e(monkeypatch, one_chip,
+                                          no_compile_cache, cell, n, rows, h,
+                                          g):
+    """The Mamba-2 state-update kernel at the two cells' widths (heads of
+    64 over a state of 128: rows of 2.1 and 4.2 MB, whole in the fast
+    memory twice in and twice out): one Mosaic custom call, the arena
+    aliased through it, nothing of its size beside it."""
+    from flexflow_tpu.kernels import ssd_step
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    p, s = 64, 128
+    assert ssd_step.supported(n, h, p, s, g, (rows, s, h * p), jnp.float32)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(ssd_step.ssd_step_decode, donate_argnums=(0,)).lower(
+        sds((rows, s, h * p)), sds((n,), jnp.int32), sds((n, h, p)),
+        sds((n, h)), sds((n, g, s)), sds((n, g, s))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssd_step_decode" in text
+    mem = compiled.memory_analysis()
+    arena_bytes = rows * s * h * p * 4
+    assert mem.alias_size_in_bytes >= arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes // 8
+
+
 def test_gated_delta_prefill_compiles_for_v5e(monkeypatch, one_chip,
                                               no_compile_cache):
     """One linear layer's prefill (``GatedDeltaNet.whole``: projections,
@@ -667,10 +698,11 @@ def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
     stepped where they lie, the tails spread over the arena's 129 rows);
     its one Mosaic call is the
     paged kernel over 2 key-value heads of 128 (``attention_path``
-    ``kernel``); the state arena (129 x 128 x 64 x 128 float32, 541 MB)
-    is made by ONE fusion, under the ``M`` op's ``rule``, and both of the
-    op's arenas alias their outputs: nothing beside the weights and the
-    pool but 64 MB."""
+    ``kernel``) beside the ``M`` op's one ``ssd_step_decode`` call; the
+    state arena (129 x 128 x 8,192 float32, 541 MB) is made by that
+    call alone, under the op's ``rule``, and both of the op's arenas
+    alias their outputs: nothing beside the weights and the pool but
+    64 MB."""
     from flexflow_tpu.core.op import parse_scope
 
     programs, dec = nemotron_programs
@@ -683,19 +715,19 @@ def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
     for ln in text.splitlines():
         if " scatter(" in ln:
             assert "ff.MULTIHEAD_ATTENTION.block2_mixer/write" in ln, ln
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "paged_attention_decode" in text
     names = _entry_op_names(text)
     made = {}
     for ln in _buffers(text):       # a result, or one of a tuple of them
         m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
                      r"([a-z][\w\-]*)\(", ln)
-        if (m and "f32[129,128,64,128]" in m.group(2)
-                and m.group(3) not in ("parameter", "bitcast", "copy-done",
-                                       "copy-start", "get-tuple-element",
+        if (m and "f32[129,128,8192]" in m.group(2)
+                and m.group(3) not in ("parameter", "get-tuple-element",
                                        "tuple")):
+            assert "ssd_step_decode" in ln, ln
             made[m.group(1)] = m.group(3)
-    assert list(made.values()) == ["fusion"], made
+    assert list(made.values()) == ["custom-call"], made
     (writer,) = made
     assert parse_scope(names[writer]) == (
         "MAMBA2", "block1_mixer", ("rule",), "fwd")
@@ -1002,16 +1034,21 @@ def test_granite_decode_step_holds_no_while_and_moves_no_state(
         granite_programs):
     """The decode step of Mamba-2 and attention blocks holds no ``while``,
     no ``conditional`` and no ``dynamic-update-slice``; no gather or
-    scatter touches a state (three arenas of 49 x 64 x 64 x 128 float32,
-    103 MB each, each made by ONE fusion under its op's ``rule`` and
-    aliased to its output); the only scatter is the new token's keys and
-    values; the attention layer reads its blocks in place by the paged
-    kernel, 32 query heads on 8 key-value heads of 64, two of them a lane
-    tile (``attention_path`` ``kernel``: ONE Mosaic call, and no copy of
-    every slot's table: the gather it replaces made 48 x 144 x 64 x 512
-    bfloat16 twice, 0.9 GB, and was 36 of the step's 60 ms on the chip);
-    the tied head is ONE product that reads the embedding's table where
-    it lies: no buffer of the table's size is made, transposed or not."""
+    scatter touches a state (three arenas of 49 x 128 x 4,096 float32,
+    103 MB each), each stepped by ONE ``ssd_step_decode`` kernel call
+    under its op's ``rule`` with the arena aliased in and out, and no
+    array of an arena's shape is made besides (the fusion this replaced
+    was staged by the compiler: each arena copied into fast memory in
+    four slices and back out by a copy of its own, which carry no
+    scope); the slots' order is sorted ONCE for the three layers; the
+    only scatter is the new token's keys and values; the attention layer
+    reads its blocks in place by the paged kernel, 32 query heads on 8
+    key-value heads of 64, two of them a lane tile (``attention_path``
+    ``kernel``, and no copy of every slot's table: the gather it
+    replaces made 48 x 144 x 64 x 512 bfloat16 twice, 0.9 GB, and was 36
+    of the step's 60 ms on the chip); the tied head is ONE product that
+    reads the embedding's table where it lies: no buffer of the table's
+    size is made, transposed or not."""
     from flexflow_tpu.core.op import parse_scope
 
     programs, dec = granite_programs
@@ -1021,34 +1058,39 @@ def test_granite_decode_step_holds_no_while_and_moves_no_state(
     assert "dynamic-update-slice" not in text
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(calls) == 1 and "paged_attention_decode" in calls[0]
-    assert "ff.MULTIHEAD_ATTENTION.block2_mixer/attend" in calls[0]
-    state = "f32[49,64,64,128]"
+    paged = [ln for ln in calls if "paged_attention_decode" in ln]
+    assert len(paged) == 1 and len(calls) == 4
+    assert "ff.MULTIHEAD_ATTENTION.block2_mixer/attend" in paged[0]
+    state = "f32[49,128,4096]"
     for ln in _buffers(text):
         head = ln.split(" = ", 1)[1]
         if " gather(" in ln or " scatter(" in ln:
-            assert "[48,64,64,128]" not in head.split("(")[0] \
+            assert "[48,128,4096]" not in head.split("(")[0] \
                 and state not in head, ln
         if " scatter(" in ln:
             assert "ff.MULTIHEAD_ATTENTION.block2_mixer/write" in ln, ln
+    assert sum(" sort(" in ln for ln in _buffers(text)) == 1
     names = _entry_op_names(text)
     made = {}
     for ln in _buffers(text):
         m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
                      r"([a-z][\w\-]*)\(", ln)
-        # (the compiler may bring an arena into fast memory ahead of its
-        # fusion, in slices it joins by a bitcast: a copy of the
-        # scheduler's, once through, not a gather of the program's)
         if (m and state in m.group(2)
-                and m.group(3) not in ("parameter", "bitcast", "copy-done",
-                                       "copy-start", "slice-start",
-                                       "slice-done", "get-tuple-element",
-                                       "tuple")
-                and "ConcatBitcast" not in ln):
+                and m.group(3) not in ("parameter", "get-tuple-element",
+                                       "tuple")):
+            assert "ssd_step_decode" in ln, ln
             made[m.group(1)] = m.group(3)
-    assert sorted(made.values()) == ["fusion"] * 3, made
+    assert sorted(made.values()) == ["custom-call"] * 3, made
     assert {parse_scope(names[w])[:3] for w in made} == {
         ("MAMBA2", f"block{i}_mixer", ("rule",)) for i in (0, 1, 3)}
+    for ln in calls:
+        if "ssd_step_decode" in ln:     # the arena in is the arena out
+            operands = re.search(r"custom-call\((.*?)\), custom_call_target",
+                                 ln).group(1).split(", ")
+            aliased = int(re.search(
+                r"output_to_operand_aliasing=\{\{0\}: \((\d+), \{\}\)\}",
+                ln).group(1))
+            assert re.search(r"%pool__block\d_mixer___0_", operands[aliased])
     assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
     # the table: the parameter, and nothing else of its size at all
     for ln in _buffers(text):

@@ -57,6 +57,14 @@ def test_latent_attention_compiled(slots, max_blocks):
                                       "bfloat16", mosaic=True)
 
 
+@pytest.mark.parametrize("slots,heads,groups", [(48, 64, 1), (128, 128, 8)])
+def test_ssd_step_compiled(slots, heads, groups):
+    """The retrieval cell's Mamba-2 states (48 slots, 64 heads of 64 in
+    one group, a state of 128: rows of 2.1 MB) and the agents cell's (128
+    slots, 128 heads in 8 groups: rows of 4.2 MB)."""
+    chip_smoke.check_ssd_step(slots, heads, 64, 128, groups, mosaic=True)
+
+
 def test_flash_autotune_on_chip(monkeypatch):
     """Compiled-mode autotune at the fit cell's shape: what training
     runs (bfloat16, causal, forward and backward) through every block
