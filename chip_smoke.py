@@ -277,7 +277,8 @@ def _assert_mosaic(jitted, *args) -> None:
 
 def check_flash_attention(shape, causal: bool, dtype: str,
                           mosaic: bool) -> Dict[str, float]:
-    """Flash attention (forward, dq, dk/dv) against
+    """Flash attention (forward, and the backward as the one kernel
+    every shape checked here is given) against
     ``single_device_attention`` in float32 at ``highest`` precision, on
     the same inputs. Returns each output's largest error as a share of
     its reference's largest magnitude."""
@@ -285,6 +286,7 @@ def check_flash_attention(shape, causal: bool, dtype: str,
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.flash_attention import flash_attention, supported
+    from flexflow_tpu.obs.metrics import metrics_registry
     from flexflow_tpu.parallel.ring_attention import single_device_attention
 
     _require(supported(shape, shape, causal, dtype),
@@ -305,9 +307,14 @@ def check_flash_attention(shape, causal: bool, dtype: str,
 
     got_fn = jax.jit(jax.value_and_grad(kernel_loss, argnums=(0, 1, 2),
                                         has_aux=True))
+    reg = metrics_registry()
+    before = _path_counts(reg, "attention", ("fused", "split"), "backward")
     if mosaic:
         _assert_mosaic(got_fn, q, k, v)
     (_, out), grads = got_fn(q, k, v)
+    took = _paths_taken(reg, "attention", before, "backward")
+    _require(took == ["fused"],
+             f"flash backward at {shape} took the {'+'.join(took)!r} form")
     with jax.default_matmul_precision("highest"):
         (_, out_ref), grads_ref = jax.jit(jax.value_and_grad(
             ref_loss, argnums=(0, 1, 2), has_aux=True))(
@@ -1004,14 +1011,16 @@ def _train_data(sizes: SmokeSizes, n: int):
     return tok[:, :-1].copy(), pos, tok[:, 1:].copy()
 
 
-def _path_counts(reg, family: str, names) -> Dict[str, int]:
-    """The ``<family>.path.<name>`` counters: which implementation a
+def _path_counts(reg, family: str, names,
+                 kind: str = "path") -> Dict[str, int]:
+    """The ``<family>.<kind>.<name>`` counters: which implementation a
     lowering took, counted once per trace."""
-    return {n: reg.counter(f"{family}.path.{n}").value for n in names}
+    return {n: reg.counter(f"{family}.{kind}.{n}").value for n in names}
 
 
-def _paths_taken(reg, family: str, before: Dict[str, int]) -> List[str]:
-    now = _path_counts(reg, family, before)
+def _paths_taken(reg, family: str, before: Dict[str, int],
+                 kind: str = "path") -> List[str]:
+    now = _path_counts(reg, family, before, kind)
     return sorted(n for n, v0 in before.items() if now[n] > v0)
 
 
@@ -1035,6 +1044,7 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
                           ("flash", "xla", "ring", "ulysses"))
     forms0 = _path_counts(reg, "loss",
                           ("one_pass", "log_softmax", "probabilities"))
+    backward0 = _path_counts(reg, "attention", ("fused", "split"), "backward")
 
     ff = FFModel(_ff_config(batch_size=batch, epochs=2,
                             only_data_parallel=plan != "searched",
@@ -1052,6 +1062,7 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
     losses = [pm.sparse_cce_loss / max(1, pm.train_all) for pm in history]
     paths = _paths_taken(reg, "attention", paths0)
     forms = _paths_taken(reg, "loss", forms0)
+    backward = _paths_taken(reg, "attention", backward0, "backward")
     # no silent fall-back: on the chip, at this shape, the step's
     # attention is the fused kernels (no (S, S) array in HBM), taken by
     # the shapes alone — main() pops every variable that could force it
@@ -1059,6 +1070,11 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
              f"the train step's attention took the {'+'.join(paths)!r} "
              f"path at sequence {sizes.seq}, {sizes.heads} heads of "
              f"{sizes.hidden // sizes.heads}")
+    # and its backward is one kernel: a sequence of this length leaves
+    # the whole dQ of a lane tile room in fast memory
+    _require(backward == ["fused"] or paths != ["flash"],
+             f"the train step's attention backward took the "
+             f"{'+'.join(backward)!r} form at sequence {sizes.seq}")
     # sparse labels on raw logits: the loss reads the head's logits where
     # the head wrote them, by the loss type alone
     _require(forms == ["one_pass"],
@@ -1109,7 +1125,7 @@ def phase_train(sizes: SmokeSizes, plan: str, batch_per_device: int) -> Dict:
         seq=sizes.seq, params=gpt_param_count(sizes),
         estimated_bytes=train_bytes_estimate(sizes, batch_per_device),
         steps=2 * sizes.steps_per_epoch, attention_path="+".join(paths),
-        loss_path="+".join(forms),
+        attention_backward="+".join(backward), loss_path="+".join(forms),
         loss_epoch0=round(losses[0], 4), loss_epoch1=round(losses[1], 4),
         epoch_wall_s=[e["wall_s"] for e in epochs],
         compiles_by_epoch=[e["compiles"] for e in epochs], **facts)

@@ -22,18 +22,31 @@ broadcasts along sublanes, and a reduction over keys is elementwise
 over vregs with one short sublane reduce at its end: no cross-lane
 reduction and no (block_q, 1) column anywhere (a first version with
 queries on sublanes spent more on those than on its products: PERF.md
-section 6, PR 31). The three kernels, by their ``pallas_call`` names:
+section 6, PR 31). Two kernels a step of training, by their
+``pallas_call`` names, or three where the sequence is too long for the
+backward to be one (:func:`backward_form`):
 
 * ``flash_attention_fwd`` — grid (B, H/G, query blocks, key blocks), the
   key axis innermost; m, l and the transposed output accumulator
   (W, block_q) live in VMEM scratch across a query block's key blocks
   (the running softmax), so VMEM does not grow with the sequence. The
   accumulator is transposed back once a query block.
-* ``flash_attention_dq`` — the same grid; dQ^T accumulates over key
-  blocks.
-* ``flash_attention_dkv`` — grid (B, H/G, key blocks, query blocks), the
-  query axis innermost; dK and dV accumulate over query blocks, already
-  the right way round.
+* ``flash_attention_bwd`` — grid (B, H/G, key blocks, query blocks), the
+  query axis innermost. A tile's scores, exponentials and dS are made
+  once and enter five products: dK and dV accumulate over a key
+  block's query blocks, already the right way round, and dQ^T over the
+  key blocks, which are the OUTER block axis: a (batch, lane tile)'s
+  whole dQ^T stays in VMEM scratch, a (W, block_q) panel a query block,
+  and each panel is transposed into the resident (Sq, W) output block
+  at the last key block. That accumulator is the one thing that grows
+  with the sequence (12 bytes a query and lane, counted with its output
+  block), so the form is read from the shape: where it does not fit the
+  VMEM budget the backward is the two kernels below, which rebuild
+  each tile twice (seven products for five) and hold nothing whole.
+* ``flash_attention_dq`` — the forward's grid; dQ^T accumulates over a
+  query block's key blocks.
+* ``flash_attention_dkv`` — the fused kernel's grid and body without
+  dQ.
 
 Precision: MXU operands in the dtype the op was given, float32
 accumulation; scores, row maximum, sum, log-sum-exp and
@@ -271,15 +284,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc,
-                *, scale, causal, d, block_q, block_k):
-    kk, i, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+                dk_ref, dv_ref, *rest, scale, causal, d, block_q, block_k):
+    """dK and dV of a key block over its query blocks. Given a dQ output
+    and its accumulator as well (``flash_attention_bwd``), the tile's dS
+    makes dQ too: a (batch, lane tile)'s whole dQ^T is held in VMEM, a
+    ``(width, block_q)`` panel a query block, across the key blocks."""
+    kk, i = pl.program_id(2), pl.program_id(3)
+    nk, nq = pl.num_programs(2), pl.num_programs(3)
     lanes = _head_masks(q_ref.shape[-1], d, 1)
+    dq_ref, dk_acc, dv_acc, dq_acc = (rest if len(rest) == 4
+                                      else (None, *rest, None))
 
     @pl.when(i == 0)
     def _():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    if dq_ref is not None:
+        @pl.when(kk == 0)
+        def _():
+            dq_acc[i] = jnp.zeros(dq_acc.shape[1:], jnp.float32)
 
     def tile(masked, ks, qs):
         q, g, k, v = q_ref[0, qs], g_ref[0, qs], k_ref[0, ks], v_ref[0, ks]
@@ -287,9 +311,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         if masked:
             keep = _keep(_size(ks), _size(qs), i * block_q + qs.start,
                          kk * block_k + ks.start)
-        dk = dv = None
+        dk = dv = dq = None
         for c, mine in enumerate(lanes):
-            st = _dot(_only(mine, k), q_scaled, _NT)       # (keys, queries)
+            kc = _only(mine, k)
+            st = _dot(kc, q_scaled, _NT)                   # (keys, queries)
             if post != 1.0:
                 st = st * post
             if masked:
@@ -302,8 +327,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             # head 0's product fills the tile; each later head takes its lanes
             dv = xv if dv is None else jnp.where(mine, xv, dv)
             dk = xk if dk is None else jnp.where(mine, xk, dk)
+            if dq_ref is not None:
+                x = _dot(kc, dst.astype(k.dtype), _TN)     # (width, queries)
+                dq = x if dq is None else dq + x           # this head's rows
         dv_acc[ks] += dv
         dk_acc[ks] += dk
+        if dq_ref is not None:
+            dq_acc[i, :, qs] += dq
 
     _causal_tiles(causal, i * block_q, kk * block_k, block_q, block_k, tile)
 
@@ -312,14 +342,25 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+    if dq_ref is not None:
+        @pl.when(kk == nk - 1)
+        def _():
+            at = pl.multiple_of(i * block_q, block_q)
+            dq_ref[0, pl.ds(at, block_q)] = (
+                dq_acc[i] * scale).T.astype(dq_ref.dtype)
+
 
 # -- the calls ----------------------------------------------------------------
 
-# batch, lane tile and the outer block axis are independent; the inner
-# block axis carries the accumulators
-_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-    vmem_limit_bytes=VMEM_LIMIT_BYTES)
+# batch and lane tile are independent; the inner block axis carries the
+# accumulators, and in the fused backward the outer one carries dQ's
+def _compiler_params(outer: str = "parallel"):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", outer, "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+_COMPILER_PARAMS = _compiler_params()
 
 
 def _last_key_block(i, block_q: int, block_k: int, nk: int):
@@ -394,10 +435,11 @@ def _forward(q, k, v, *, heads, causal, scale, block_q, block_k, interpret):
     )(q, k, v)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
+@functools.partial(jax.jit, static_argnames=_STATIC + ("fused",))
 def _backward(q, k, v, out, lse, g_out, *, heads, causal, scale, block_q,
-              block_k, interpret):
-    """-> (dq, dk, dv)."""
+              block_k, interpret, fused):
+    """-> (dq, dk, dv): one kernel (``fused``: :func:`backward_form`) or
+    two."""
     b, sq, f = q.shape
     skv, d = k.shape[1], f // heads
     width = _tile_width(heads, d)
@@ -408,6 +450,35 @@ def _backward(q, k, v, out, lse, g_out, *, heads, causal, scale, block_q,
     delta = delta.transpose(0, 2, 1)[:, :, None, :]
     kw = dict(scale=scale, causal=causal, d=d, block_q=block_q,
               block_k=block_k)
+    operands = (q, k, v, g_out, lse, delta)
+    dq_shape = jax.ShapeDtypeStruct((b, sq, f), q.dtype)
+    qspec, kspec, sspec = _specs(causal, width, g, block_q, block_k, nq, nk,
+                                 queries_inner=True)
+    acc = pltpu.VMEM((block_k, width), jnp.float32)
+    out_specs, scratch = [kspec, kspec], [acc, acc]
+    out_shape = [jax.ShapeDtypeStruct((b, skv, f), k.dtype),
+                 jax.ShapeDtypeStruct((b, skv, f), v.dtype)]
+    if fused:
+        # dQ whole: its block stays where it is across both block axes
+        # and goes to HBM once a (batch, lane tile)
+        out_specs.append(pl.BlockSpec((1, sq, width),
+                                      lambda b, j, kk, i: (b, 0, j)))
+        out_shape.append(dq_shape)
+        scratch.append(pltpu.VMEM((nq, width, block_q), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(_dkv_kernel, **kw),
+        grid=(b, f // width, nk, nq),
+        in_specs=[qspec, kspec, kspec, qspec, sspec, sspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params("arbitrary" if fused else "parallel"),
+        interpret=interpret,
+        name="flash_attention_bwd" if fused else "flash_attention_dkv",
+    )(*operands)
+    if fused:
+        dk, dv, dq = outs
+        return dq, dk, dv
     qspec, kspec, sspec = _specs(causal, width, g, block_q, block_k, nq, nk,
                                  queries_inner=False)
     dq = pl.pallas_call(
@@ -415,28 +486,13 @@ def _backward(q, k, v, out, lse, g_out, *, heads, causal, scale, block_q,
         grid=(b, f // width, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, sspec, sspec],
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, f), q.dtype),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((width, block_q), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_attention_dq",
-    )(q, k, v, g_out, lse, delta)
-    qspec, kspec, sspec = _specs(causal, width, g, block_q, block_k, nq, nk,
-                                 queries_inner=True)
-    acc = pltpu.VMEM((block_k, width), jnp.float32)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kw),
-        grid=(b, f // width, nk, nq),
-        in_specs=[qspec, kspec, kspec, qspec, sspec, sspec],
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((b, skv, f), k.dtype),
-                   jax.ShapeDtypeStruct((b, skv, f), v.dtype)],
-        scratch_shapes=[acc, acc],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-        name="flash_attention_dkv",
-    )(q, k, v, g_out, lse, delta)
-    return dq, dk, dv
+    )(*operands)
+    return (dq, *outs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -450,7 +506,13 @@ def _flash_fwd(q, k, v, static):
 
 
 def _flash_bwd(static, res, g_out):
-    return _backward(*res, g_out, **dict(static))
+    from ..obs.metrics import metrics_registry
+
+    kw = dict(static)
+    form = backward_form(res[0].shape, kw["heads"], kw["block_q"],
+                         kw["block_k"])
+    metrics_registry().counter(f"attention.backward.{form}").inc()
+    return _backward(*res, g_out, fused=form == "fused", **kw)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -471,21 +533,39 @@ def _pick_block(s: int) -> Optional[int]:
     return None
 
 
-def _vmem_bytes(block_q: int, block_k: int, width: int, d: int) -> int:
-    """Largest VMEM working set of the three kernels for float32 inputs
-    (the widest), counted the way it is allocated: every input and
-    output block twice (the Pallas pipeline double-buffers them), the
-    float32 accumulators, the statistics rows (a sublane tile each), the
-    zeroed copies of K and V a head takes, and the (block_k, block_q)
-    float32 temporaries of one tile (s, p, dp, ds, the mask) for each
-    head of the lane tile. It does not grow with the sequence."""
+def _vmem_bytes(block_q: int, block_k: int, width: int, d: int,
+                whole_dq: int = 0) -> int:
+    """Largest VMEM working set of the kernels for float32 inputs (the
+    widest), counted the way it is allocated: every input and output
+    block twice (the Pallas pipeline double-buffers them), the float32
+    accumulators, the statistics rows (a sublane tile each), the zeroed
+    copies of K and V a head takes, and the (block_k, block_q) float32
+    temporaries of one tile (s, p, dp, ds, the mask) for each head of
+    the lane tile. None of that grows with the sequence. The fused
+    backward holds the dQ of ``whole_dq`` queries besides: its float32
+    accumulator and, twice, the output block it is written to."""
     w = -(-width // LANES) * LANES
     blocks = 2 * 4 * w * (3 * block_q + 4 * block_k)    # q, g, dq; k, v, dk, dv
     acc = 4 * w * (block_q + 2 * block_k)
     stats = 4 * 8 * block_q * (2 * 2 + 2) * (width // d)
     copies = 4 * w * 4 * max(block_q, block_k)
     tiles = 4 * 5 * (width // d) * block_q * block_k
-    return blocks + acc + stats + copies + tiles
+    dq = 4 * w * whole_dq * (1 + 2)                     # scratch, out twice
+    return blocks + acc + stats + copies + tiles + dq
+
+
+def backward_form(q_shape, heads: int, block_q: int, block_k: int) -> str:
+    """Which backward a packed ``(B, Sq, H*D)`` query takes, read from
+    its shape: ``"fused"``, one kernel that makes a tile's dS once for
+    dQ, dK and dV, where the whole dQ of a (batch, lane tile) fits the
+    VMEM budget beside the tiles (float32 at 128 lanes: some 20k
+    queries); ``"split"``, the dQ kernel and the dK/dV kernel, whose
+    working sets do not grow with the sequence, beyond that."""
+    _, sq, f = q_shape
+    d = f // heads
+    fits = _vmem_bytes(block_q, block_k, _tile_width(heads, d), d,
+                       whole_dq=sq) <= VMEM_BUDGET_BYTES
+    return "fused" if fits else "split"
 
 
 def supported(q_shape, k_shape, causal: bool = False, dtype=None) -> bool:
@@ -635,10 +715,12 @@ def autotune(shape=(4, 1024, 16, 64),
     passes one after the other (each on the last one's gradients, so
     none can be dropped), which keeps the host's dispatch out of a
     short kernel's time. Returns ``{"blocks": {(bq, bk): seconds a
-    pass}, "best": (bq, bk), "xla_s": seconds, "xla_ratio": xla_s /
-    best}``. It measures and decides nothing: :func:`engaged` is a rule
-    over shapes, and its constants are edited from tables this function
-    produced on the chip (tools/flash_crossover.py)."""
+    pass}, "backward": {(bq, bk): the form :func:`backward_form` gave
+    the pass that was timed}, "best": (bq, bk), "xla_s": seconds,
+    "xla_ratio": xla_s / best}``. It measures and decides nothing:
+    :func:`engaged` is a rule over shapes, and its constants are edited
+    from tables this function produced on the chip
+    (tools/flash_crossover.py)."""
     import time
 
     import numpy as np
@@ -691,5 +773,7 @@ def autotune(shape=(4, 1024, 16, 64),
     xla_s = median_time(both_passes(
         lambda q, k, v: single_device_attention(q, k, v, causal, scale)))
     best = min(blocks, key=blocks.get) if blocks else None
-    return {"blocks": blocks, "best": best, "xla_s": xla_s,
+    forms = {blk: backward_form((shape[0], s, h * d), h, *blk)
+             for blk in blocks}
+    return {"blocks": blocks, "backward": forms, "best": best, "xla_s": xla_s,
             "xla_ratio": (round(xla_s / blocks[best], 4) if blocks else None)}

@@ -45,6 +45,7 @@ def test_phases_run_to_completion_at_toy_size(monkeypatch, tmp_path):
     assert records[1]["granite_ssm_step_path"] == "kernel"
     assert records[1]["nemotron_ssm_step_path"] == "kernel"
     assert records[2]["devices"] == 8 and records[2]["attention_path"] == "flash"
+    assert records[2]["attention_backward"] == "fused"
     assert records[2]["loss_path"] == records[3]["loss_path"] == "one_pass"
     assert records[3]["compiles_by_epoch"][1] == 0
     assert os.path.isdir(tmp_path / "ledger")  # nothing under the cwd

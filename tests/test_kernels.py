@@ -6,6 +6,8 @@ here each Pallas kernel is validated against the framework's own jnp
 formulation, in the Pallas interpreter on the hermetic CPU platform.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -15,6 +17,27 @@ import pytest
 @pytest.fixture(autouse=True)
 def _interpret(monkeypatch):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+
+
+FORMS = ("fused", "split")
+
+
+@pytest.fixture
+def backward_form(request, monkeypatch):
+    """Pin the backward of fused attention to the form the test is
+    parametrised with (``indirect``), whatever the shape's rule would
+    answer, and check afterwards that every lowering counted it."""
+    from flexflow_tpu.kernels import flash_attention as fa
+    from flexflow_tpu.obs.metrics import metrics_registry
+
+    form = request.param
+    monkeypatch.setattr(fa, "backward_form", lambda *a, **kw: form)
+    reg = metrics_registry()
+    before = {f: reg.counter(f"attention.backward.{f}").value for f in FORMS}
+    yield form
+    took = {f for f in FORMS
+            if reg.counter(f"attention.backward.{f}").value > before[f]}
+    assert took == {form}
 
 
 def _qkv(b=2, s=128, h=2, d=8, seed=0):
@@ -36,8 +59,9 @@ def test_flash_attention_forward(causal):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads(causal):
+def test_flash_attention_grads(causal, backward_form):
     from flexflow_tpu.kernels.flash_attention import flash_attention
     from flexflow_tpu.parallel.ring_attention import single_device_attention
 
@@ -76,6 +100,28 @@ def test_sharded_flash_attention_matches_reference(causal):
     want = single_device_attention(q, k, v, causal, scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
+def test_sharded_flash_attention_grads(backward_form):
+    """Differentiated through ``shard_map``: each device's block takes
+    the backward its own (per-device) shape is given, one kernel or
+    two, and the gradients equal the unsharded reference's."""
+    from jax.sharding import Mesh
+    from flexflow_tpu.kernels.flash_attention import sharded_flash_attention
+    from flexflow_tpu.parallel.ring_attention import single_device_attention
+
+    q, k, v = _qkv(b=2, s=64, h=4, d=8, seed=6)
+    scale = q.shape[-1] ** -0.5
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    got = jax.grad(lambda q, k, v: jnp.sum(sharded_flash_attention(
+        q, k, v, mesh, "data", "model", causal=True, scale=scale) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: jnp.sum(single_device_attention(
+        q, k, v, True, scale) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
 
 
 def test_attention_op_uses_sharded_kernel_on_mesh(monkeypatch):
@@ -270,12 +316,14 @@ def _attention_errors(q_shape, k_shape, causal, dtype, block_q, block_k):
                                        (jnp.bfloat16, 2e-2)])
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("heads,d", [(2, 64), (1, 128)])
-def test_flash_blocked_kernels_match_reference(heads, d, causal, dtype, tol):
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
+def test_flash_blocked_kernels_match_reference(heads, d, causal, dtype, tol,
+                                               backward_form):
     """The key-blocked kernels, forward and all three gradients, over
     more than one key block and more than one query block: two heads of
     64 side by side in a lane tile and one head of 128, causal and not,
     float32 operands and bfloat16 ones (float32 statistics either
-    way)."""
+    way), the backward as one kernel and as two."""
     shape = (1, 256, heads, d)
     errs = _attention_errors(shape, shape, causal, dtype, 64, 128)
     assert max(errs.values()) <= tol, errs
@@ -283,7 +331,9 @@ def test_flash_blocked_kernels_match_reference(heads, d, causal, dtype, tol):
 
 @pytest.mark.parametrize("sq,skv", [(256, 128), (128, 256), (192, 64)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_blocked_kernels_unequal_sequences(sq, skv, causal):
+@pytest.mark.parametrize("backward_form", FORMS, indirect=True)
+def test_flash_blocked_kernels_unequal_sequences(sq, skv, causal,
+                                                 backward_form):
     """sq != skv, both ways round: the causal mask is
     ``single_device_attention``'s top-left ``tril``, so a long query's
     late rows see every key and a long key's late blocks are never read
@@ -291,6 +341,75 @@ def test_flash_blocked_kernels_unequal_sequences(sq, skv, causal):
     errs = _attention_errors((2, sq, 4, 32), (2, skv, 4, 32), causal,
                              jnp.float32, 64, 64)
     assert max(errs.values()) <= 1e-5, errs
+
+
+@pytest.mark.parametrize("dtype,tol,same", [(jnp.float32, 1e-5, 1e-6),
+                                            (jnp.bfloat16, 2e-2, 8e-3)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads,d", [(2, 64), (1, 128)])
+def test_flash_backward_forms_agree(monkeypatch, heads, d, causal, dtype,
+                                    tol, same):
+    """Three key blocks against three query blocks of 128, so that the
+    fused kernel's dQ accumulates across key blocks with a causal tile
+    skipped between them (query block 0 sees key block 0 alone, block 2
+    all three) and the aligned diagonal tile runs as three quarters:
+    each form within the reference's tolerance, and the two within a
+    rounding of each other (the same products, another order of
+    sums)."""
+    from flexflow_tpu.kernels import flash_attention as fa
+    from flexflow_tpu.parallel.ring_attention import single_device_attention
+
+    shape = (2, 384, heads, d)
+    rng = np.random.default_rng(5)
+    q, k, v, w = (jnp.asarray(rng.normal(size=shape), dtype)
+                  for _ in range(4))
+    scale = d ** -0.5
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    grads = {}
+    for form in FORMS:
+        monkeypatch.setattr(fa, "backward_form", lambda *a, _f=form: _f)
+        grads[form] = jax.grad(loss(functools.partial(
+            fa.flash_attention, causal=causal, scale=scale, block_q=128,
+            block_k=128)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: single_device_attention(
+        q, k, v, causal, scale)), argnums=(0, 1, 2))(
+            *(a.astype(jnp.float32) for a in (q, k, v)))
+    for name, a, b, r in zip(("dq", "dk", "dv"), grads["fused"],
+                             grads["split"], want):
+        a, b, r = (np.asarray(x.astype(jnp.float32)) for x in (a, b, r))
+        top = np.max(np.abs(r))
+        assert np.max(np.abs(a - r)) / top <= tol, (name, "fused")
+        assert np.max(np.abs(b - r)) / top <= tol, (name, "split")
+        assert np.max(np.abs(a - b)) / top <= same, name
+
+
+@pytest.mark.parametrize("q_shape,heads,want", [
+    ((4, 1024, 16 * 64), 16, "fused"),         # the fit cell
+    ((1, 8192, 8 * 128), 8, "fused"),
+    ((2, 16384, 2 * 64), 2, "fused"),
+    ((1, 32768, 2 * 64), 2, "split"),          # dQ whole: 50 MB at 128 lanes
+    ((1, 65536, 8 * 128), 8, "split"),
+])
+def test_flash_backward_form_follows_the_shape(q_shape, heads, want):
+    """Which backward runs is read from the shape: one kernel while the
+    whole dQ of a (batch, lane tile) fits the VMEM budget beside the
+    tiles, two beyond; ``_vmem_bytes`` counts what the whole dQ adds
+    (its float32 accumulator, and the output block twice, 12 bytes a
+    query and lane) and nothing else changes with the sequence."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    assert fa.backward_form(q_shape, heads, 512, 512) == want
+    d = q_shape[2] // heads
+    width, sq = fa._tile_width(heads, d), q_shape[1]
+    base = fa._vmem_bytes(512, 512, width, d)
+    assert fa._vmem_bytes(512, 512, width, d, whole_dq=sq) - base == (
+        12 * width * sq)
+    assert (base + 12 * width * sq <= fa.VMEM_BUDGET_BYTES) == (
+        want == "fused")
 
 
 def _two_sequence_axes(closed_jaxpr, seq: int):
@@ -371,6 +490,8 @@ def test_flash_autotune_mechanics(monkeypatch):
     assert set(r["blocks"]) == {(16, 16), (32, 64)}   # 48 does not tile 64
     assert all(t > 0 for t in r["blocks"].values())
     assert r["best"] == min(r["blocks"], key=r["blocks"].get)
+    # beside each timing, the form of the backward it ran
+    assert r["backward"] == {blk: "fused" for blk in r["blocks"]}
     assert [fa.engaged(s, s, 8) for s in (64, 1024)] == before
     assert not hasattr(fa, "load_tune_cache")          # no file, no cache
 

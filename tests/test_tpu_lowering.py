@@ -46,25 +46,30 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-CASES = [
-    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.bfloat16, True),  # the fit cell
-    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.float32, True),
-    ((1, 2048, 8, 128), (1, 2048, 8, 128), jnp.bfloat16, True),
-    ((2, 256, 20, 64), (2, 768, 20, 64), jnp.bfloat16, False),
+FUSED = ("flash_attention_fwd", "flash_attention_bwd")
+SPLIT = ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv")
+CASES = [   # the last column: the backward's kernels, by the shape's rule
+    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.bfloat16, True, FUSED),  # fit
+    ((4, 1024, 16, 64), (4, 1024, 16, 64), jnp.float32, True, FUSED),
+    ((1, 2048, 8, 128), (1, 2048, 8, 128), jnp.bfloat16, True, FUSED),
+    ((2, 256, 20, 64), (2, 768, 20, 64), jnp.bfloat16, False, FUSED),
+    # a whole dQ of 32k queries passes the VMEM budget: two kernels
+    ((1, 32768, 2, 64), (1, 32768, 2, 64), jnp.bfloat16, True, SPLIT),
 ]
 
 
 def test_flash_attention_compiles_for_v5e(monkeypatch, one_chip,
                                           no_compile_cache):
-    """Forward and backward at the blocks the shapes choose: three
-    Mosaic custom calls, by their names, and no (S, S) buffer in the
-    program. One test over all the cases, so that one process (the one
+    """Forward and backward at the blocks the shapes choose: two
+    Mosaic custom calls by their names, the backward one kernel, or
+    three where the shape's rule (``backward_form``) sends dQ to a
+    kernel of its own, and no (S, S) buffer in the program. One test over all the cases, so that one process (the one
     that holds the TPU's library) compiles them all, whatever the
     number of workers."""
     from flexflow_tpu.kernels import flash_attention as fa
 
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
-    for q_shape, k_shape, dtype, causal in CASES:
+    for q_shape, k_shape, dtype, causal, names in CASES:
         assert fa.supported(q_shape, k_shape, causal, dtype)
         q = jax.ShapeDtypeStruct(q_shape, dtype, sharding=one_chip)
         k = jax.ShapeDtypeStruct(k_shape, dtype, sharding=one_chip)
@@ -75,9 +80,9 @@ def test_flash_attention_compiles_for_v5e(monkeypatch, one_chip,
 
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, k, k).compile().as_text()
-        assert text.count('custom_call_target="tpu_custom_call"') == 3
-        for name in ("flash_attention_fwd", "flash_attention_dq",
-                     "flash_attention_dkv"):
+        assert text.count('custom_call_target="tpu_custom_call"') == len(
+            names), q_shape
+        for name in names:
             assert name in text, (q_shape, name)
         (b, sq, h, _), skv = q_shape, k_shape[1]
         assert f"[{b},{h},{sq},{skv}]" not in text  # the scores of a head

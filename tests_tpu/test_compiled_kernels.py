@@ -29,6 +29,8 @@ import chip_smoke
      ((1, 1024, 8, 128), True), ((1, 2048, 20, 64), False)],
 )
 def test_flash_attention_compiled(shape, causal, dtype):
+    """Forward and the one-kernel backward through Mosaic (the check
+    requires the counter ``attention.backward.fused``)."""
     chip_smoke.check_flash_attention(shape, causal, dtype, mosaic=True)
 
 
@@ -70,7 +72,8 @@ def test_flash_autotune_on_chip(monkeypatch):
     runs (bfloat16, causal, forward and backward) through every block
     size that tiles it and through the `xla` path; under forced compiled
     mode a Mosaic refusal of any candidate raises instead of being
-    skipped. The rule takes the kernels at this shape, and they win."""
+    skipped. The rule takes the kernels at this shape, the backward as
+    one kernel, and they win."""
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels import flash_attention as fa
@@ -81,5 +84,7 @@ def test_flash_autotune_on_chip(monkeypatch):
                     candidates=((512, 512), (256, 256), (128, 128)))
     print("flash autotune:",
           {k: round(v * 1e3, 3) for k, v in r["blocks"].items()},
+          "backward:", r["backward"],
           "xla ms:", round(r["xla_s"] * 1e3, 3), "ratio:", r["xla_ratio"])
     assert len(r["blocks"]) == 3 and r["xla_ratio"] > 1.0
+    assert set(r["backward"].values()) == {"fused"}

@@ -9,8 +9,10 @@ For each sequence S in {128, 256, 512, 1024, 2048} and head size d in
 {64, 128}, at batch x heads 64, causal, bfloat16, it times the forward
 and backward passes together (``flash_attention.autotune``: eight
 passes a program, the median of three windows) through the kernels at
-each block size that tiles S, and through ``single_device_attention``,
-the `xla` path. One JSON line a shape on stdout, the table again under
+each block size that tiles S (``backward`` says which form of the
+backward each timing ran: ``fused``, one kernel, or ``split``, two), and
+through ``single_device_attention``, the `xla` path. One JSON line a
+shape on stdout, the table again under
 ``chiprun_out/flash_crossover.json``. Nothing reads that file: the
 rule's constants (``MIN_SEQ``, ``BLOCKS``) are edited by hand from it,
 and PERF.md section 6 keeps the table they were edited from.
@@ -68,6 +70,8 @@ def main(argv=None) -> int:
                "device_kind": jax.devices()[0].device_kind,
                "kernel_ms": {f"{bq}x{bk}": round(t * 1e3, 4)
                              for (bq, bk), t in r["blocks"].items()},
+               "backward": {f"{bq}x{bk}": form
+                            for (bq, bk), form in r["backward"].items()},
                "best": list(r["best"]) if r["best"] else None,
                "xla_ms": round(r["xla_s"] * 1e3, 4),
                "xla_over_kernel": r["xla_ratio"],
