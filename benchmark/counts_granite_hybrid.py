@@ -135,3 +135,21 @@ def chunk_flops(config: Dict, tokens: float, keys: float) -> float:
     return (2.0 * tokens * layer_matrix_params(config)
             + scan_flops(config, tokens)
             + z["a"] * 4.0 * z["heads"] * z["d"] * keys)
+
+
+def state_step_flops_per_row(config: Dict) -> int:
+    """Operations of one state's update and read-out: per number the
+    decay (1), ``dt x B^T`` (2) and ``S C`` (2)."""
+    return state_bytes(config) // 4 * 5
+
+
+def state_step_least_s(config: Dict, state_rows: float,
+                       peaks: Dict[str, float]) -> float:
+    """The least time the state updates of ``state_rows`` (slot, layer)
+    pairs could take: the states' bytes in and out over the HBM peak, or
+    their operations over the chip's peak, whichever is larger (the
+    bytes, by two orders)."""
+    return max(state_rows * 2 * state_bytes(config)
+               / peaks["hbm_bytes_per_s"],
+               state_rows * state_step_flops_per_row(config)
+               / peaks["bf16_flops_per_s"])

@@ -101,3 +101,29 @@ def expert_layer_names(config: Dict):
     return [f"block{i}_experts"
             for i in range(int(config["first_k_dense_replace"]),
                            int(config["num_hidden_layers"]))]
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: every matrix read once in bfloat16 (of
+    the held experts only the share that got a row: the window's
+    ``stats()["moe"]``) and every live latent row once in every layer
+    (the live tokens counted low from the window's ``blocks_read``),
+    ``counts_latent_moe.decode_bytes_per_step``, over the HBM peak."""
+    from benchmark import counts_latent_moe, routed_window
+
+    hit = routed_window.expert_hit_share(run)
+    live = routed_window.live_tokens_per_step(run)
+    if hit is None or live is None:
+        return None
+    return (counts_latent_moe.decode_bytes_per_step(run["config"], live,
+                                                    hit)
+            / run["peaks"]["hbm_bytes_per_s"])

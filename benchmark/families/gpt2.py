@@ -119,3 +119,33 @@ def grad_sample_names(config: Dict):
             "ln_1.g", "attn.c_attn.w", "attn.c_attn.b", "attn.c_proj.w",
             "ln_2.b", "mlp.c_fc.w", "mlp.c_fc.b", "mlp.c_proj.w")]
     return names
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: ``counts.decode_bytes_per_step`` over the
+    HBM peak. The live tokens are counted low, so that the share is a
+    true lower bound: each active slot is credited its request's prompt
+    alone (the mean prompt of the jobs sent), not the answer tokens it
+    has cached by then; active slots are the window's
+    ``slot_occupancy``."""
+    from benchmark import counts, serving
+
+    f = run["facts"]
+    s0, s1 = f.get("stats0"), f.get("stats1")
+    if not s0 or not s1 or not f.get("prompt_lens"):
+        return None
+    active = serving.decode_tokens_per_step(s0, s1)
+    if active is None:
+        return None
+    prompt = sum(f["prompt_lens"]) / len(f["prompt_lens"])
+    return (counts.decode_bytes_per_step(run["config"], active * prompt)
+            / run["peaks"]["hbm_bytes_per_s"])

@@ -91,3 +91,71 @@ def to_program(weights: Dict, config: Dict) -> Dict[str, Dict]:
             {k: w[p + v] for k, v in _MAMBA.items()} if kind == "mamba"
             else {k: w[p + k] for k in ("wq", "wk", "wv", "wo")})
     return out
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: every matrix read once in bfloat16 with
+    the embedding once (as the head), every stepped state once in and
+    once out (the window's ``rows_stepped`` a step) and every live
+    token's keys and values once (the live tokens counted low from the
+    window's ``blocks_read``),
+    ``counts_granite_hybrid.decode_bytes_per_step``, over the HBM peak."""
+    from benchmark import (counts_granite_hybrid, routed_window,
+                           state_window)
+
+    live = routed_window.live_tokens_per_step(run)
+    rows = state_window.rows_per_step(run)
+    if live is None or rows is None:
+        return None
+    return (counts_granite_hybrid.decode_bytes_per_step(
+        run["config"], live, rows) / run["peaks"]["hbm_bytes_per_s"])
+
+
+def cache_bytes(run: Dict):
+    """``state_bytes_share``: ``(state, rest)``, the window's
+    ``rows_stepped`` times a state's float32 bytes, in and out, beside its
+    ``blocks_read`` times a block's keys and values over the four
+    attention layers (``counts_granite_hybrid``)."""
+    from benchmark import counts_granite_hybrid, state_window
+
+    return state_window.cache_bytes(run, counts_granite_hybrid)
+
+
+def state_step_least_s(run: Dict):
+    """``mamba_state_roofline``: the stepped states' bytes once in and
+    once out over the HBM peak,
+    ``counts_granite_hybrid.state_step_least_s`` of the window's
+    ``rows_stepped`` a step."""
+    from benchmark import counts_granite_hybrid, state_window
+
+    rows = state_window.rows_per_step(run)
+    if rows is None:
+        return None
+    return counts_granite_hybrid.state_step_least_s(run["config"], rows,
+                                                    run["peaks"])
+
+
+def chunk_least_s(run: Dict):
+    """``prefill_chunk_mfu``: every layer's matrices once a live token
+    (the window's ``prefill_tokens``: padding counts for nothing), the
+    scan's products by the published blocked form, and the scores and
+    weighted sums of the keys each query sees (``prefill_keys``) in the
+    four attention layers, ``counts_granite_hybrid.chunk_flops`` over the
+    window's chunks, over the bfloat16 peak."""
+    from benchmark import counts_granite_hybrid, plain_chunked
+
+    n = plain_chunked.chunks(run)
+    if n is None:
+        return None
+    return (counts_granite_hybrid.chunk_flops(
+        run["config"], n["tokens"], n["keys"]) / n["chunks"]
+        / run["peaks"]["bf16_flops_per_s"])

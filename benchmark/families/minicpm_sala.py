@@ -104,3 +104,63 @@ def to_program(weights: Dict, config: Dict) -> Dict[str, Dict]:
         out[f"block{i}_mlp"] = {k: w[p + "mlp." + k]
                                 for k in ("gate", "up", "down")}
     return out
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: every matrix read once in bfloat16, the
+    state of every active slot and linear layer once in and once out in
+    float32 (the window's ``rows_stepped``), the blocks the sparse layers'
+    steps selected (``selected.blocks_read``) and every live request's
+    pooled keys once (counted low from ``selected.blocks_live``),
+    ``counts_sala.decode_bytes_per_step``, over the HBM peak."""
+    from benchmark import counts_sala, selected_window
+
+    step = selected_window.per_step(run)
+    if step is None:
+        return None
+    return (counts_sala.decode_bytes_per_step(
+        run["config"], step["state_rows"], step["selected"],
+        step["live_tokens"]) / run["peaks"]["hbm_bytes_per_s"])
+
+
+def cache_bytes(run: Dict):
+    """``state_bytes_share``: ``(state, rest)``, the window's
+    ``rows_stepped`` times a state's float32 bytes, in and out, beside the
+    selected blocks' keys and values over the sparse layers and the live
+    requests' pooled keys (``counts_sala``)."""
+    from benchmark import counts_sala, selected_window
+
+    step = selected_window.per_step(run)
+    if step is None:
+        return None
+    cfg = run["config"]
+    state = step["state_rows"] * 2 * counts_sala.state_bytes(cfg)
+    rest = (counts_sala.decode_bytes_per_step(
+        cfg, 0, step["selected"], step["live_tokens"])
+        - counts_sala.matrix_params(cfg) * 2)
+    return state, rest
+
+
+def chunk_least_s(run: Dict):
+    """``prefill_chunk_mfu``: every layer's matrices once a live token
+    (the window's ``prefill_tokens`` over its ``prefill_chunks``: a
+    prompt's last chunk is padded, and padding counts for nothing) and
+    the linear layers' state products, the sparse layers' attention left
+    out (the counters prove no context), ``counts_sala.chunk_flops``,
+    over the bfloat16 peak."""
+    from benchmark import counts_sala, selected_window
+
+    n = selected_window.chunks(run)
+    if n is None:
+        return None
+    return (counts_sala.chunk_flops(run["config"], n["tokens"] / n["chunks"])
+            / run["peaks"]["bf16_flops_per_s"])

@@ -109,3 +109,54 @@ def expert_layer_names(config: Dict):
     return [f"block{i}_mixer"
             for i, kind in enumerate(str(config["hybrid_override_pattern"]))
             if kind == "E"]
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: every matrix read once in bfloat16 (of
+    the held experts only the share that got a row: the window's
+    ``stats()["moe"]``), every stepped state once in and once out (the
+    window's ``rows_stepped`` a step) and every live token's keys and
+    values once (the live tokens counted low from the window's
+    ``blocks_read``), ``counts_nemotron_h.decode_bytes_per_step``, over
+    the HBM peak."""
+    from benchmark import counts_nemotron_h, routed_window, state_window
+
+    hit = routed_window.expert_hit_share(run)
+    live = routed_window.live_tokens_per_step(run)
+    rows = state_window.rows_per_step(run)
+    if hit is None or live is None or rows is None:
+        return None
+    return (counts_nemotron_h.decode_bytes_per_step(
+        run["config"], live, rows, hit) / run["peaks"]["hbm_bytes_per_s"])
+
+
+def cache_bytes(run: Dict):
+    """``state_bytes_share``: ``(state, rest)``, the window's
+    ``rows_stepped`` times a state's float32 bytes, in and out, beside its
+    ``blocks_read`` times a block's keys and values over the ``*`` layers
+    (``counts_nemotron_h``)."""
+    from benchmark import counts_nemotron_h, state_window
+
+    return state_window.cache_bytes(run, counts_nemotron_h)
+
+
+def state_step_least_s(run: Dict):
+    """``mamba_state_roofline``: the stepped states' bytes once in and
+    once out over the HBM peak, ``counts_nemotron_h.state_step_least_s``
+    of the window's ``rows_stepped`` a step."""
+    from benchmark import counts_nemotron_h, state_window
+
+    rows = state_window.rows_per_step(run)
+    if rows is None:
+        return None
+    return counts_nemotron_h.state_step_least_s(run["config"], rows,
+                                                run["peaks"])

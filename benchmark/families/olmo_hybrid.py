@@ -85,3 +85,39 @@ def to_program(weights: Dict, config: Dict) -> Dict[str, Dict]:
         out[f"block{i}_mlp"] = {k: w[p + "mlp." + k]
                                 for k in ("gate", "up", "down")}
     return out
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: every matrix read once in bfloat16, the
+    state of every active slot and linear layer once in and once out at
+    its unpadded float32 bytes (the window's ``rows_stepped``), every live
+    token's keys and values once (counted low from the window's
+    ``blocks_read``), ``counts_hybrid.decode_bytes_per_step``, over the
+    HBM peak."""
+    from benchmark import counts_hybrid, routed_window, state_window
+
+    rows = state_window.rows_per_step(run)
+    live = routed_window.live_tokens_per_step(run)
+    if rows is None or live is None:
+        return None
+    return (counts_hybrid.decode_bytes_per_step(run["config"], live, rows)
+            / run["peaks"]["hbm_bytes_per_s"])
+
+
+def cache_bytes(run: Dict):
+    """``state_bytes_share``: ``(state, rest)``, the window's
+    ``rows_stepped`` times a state's float32 bytes, in and out, beside its
+    ``blocks_read`` times a block's keys and values over the full layers
+    (``counts_hybrid``)."""
+    from benchmark import counts_hybrid, state_window
+
+    return state_window.cache_bytes(run, counts_hybrid)

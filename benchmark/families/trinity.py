@@ -105,3 +105,50 @@ def expert_layer_names(config: Dict):
     return [f"block{i}_experts"
             for i in range(int(config["num_dense_layers"]),
                            int(config["num_hidden_layers"]))]
+
+
+# ---- what the readers ask of a family ------------------------------------------
+# ``run["family"]`` is this module (``benchmark/run.py``). A reader of a
+# quantity that several families share takes from here what differs between
+# them: which ``counts*.py`` the shapes are counted by, and which of the
+# window's counters feed it. A function answers None where the window holds
+# no such counters; a family that has no such quantity leaves the function
+# out, and the reader then reports nothing.
+
+
+def decode_step_least_s(run: Dict):
+    """``decode_step_roofline``: every matrix read once in bfloat16 (of
+    the held experts only the share that got a row: the window's
+    ``stats()["moe"]``) and every visible row's keys and values once (a
+    windowed layer's ``min(length + 1, window)`` rows a slot, the full
+    layer's all: the window's ``stats()["kv"]["window"]``),
+    ``counts_trinity.decode_bytes_per_step``, over the HBM peak."""
+    from benchmark import counts_trinity, routed_chunked, routed_window
+
+    hit = routed_window.expert_hit_share(run)
+    rows = routed_chunked.window_rows(run)
+    if hit is None or rows is None:
+        return None
+    return (counts_trinity.decode_bytes_per_step(
+        run["config"], rows["rows_read"] / rows["steps"],
+        rows["rows_full"] / rows["steps"], hit)
+        / run["peaks"]["hbm_bytes_per_s"])
+
+
+def chunk_least_s(run: Dict):
+    """``prefill_chunk_mfu``: every fixed matrix once a live token (the
+    window's ``prefill_tokens``: padding counts for nothing), the held
+    experts' matrices once a pair the routing named among them
+    (``prompt_pairs_held``), and the scores and weighted sums of the keys
+    each query sees (``prefill_keys`` in the full layer,
+    ``prefill_keys_window`` in a windowed one),
+    ``counts_trinity.chunk_flops`` over the window's chunks, over the
+    bfloat16 peak."""
+    from benchmark import counts_trinity, routed_chunked
+
+    n = routed_chunked.chunks(run)
+    if n is None:
+        return None
+    return (counts_trinity.chunk_flops(
+        run["config"], n["tokens"], n["pairs_held"], n["keys_full"],
+        n["keys_window"]) / n["chunks"] / run["peaks"]["bf16_flops_per_s"])

@@ -1,0 +1,15 @@
+"""Exclusive device milliseconds per execution of a prefill chunk program
+(``jit__chunk_step`` and ``jit__chunk_step_head``) that lie under the
+sub-scope ``select`` of the group ``attention`` (the blocks' scores and
+their ``top_k``), from the owner table of the traced window
+(``benchmark/owners.py``: an operation's duration less what is nested
+inside it, by the scope in its ``op_name`` path). None where the profile
+holds no such scope. Layer: Kernels."""
+
+from benchmark import owners
+
+PROGRAM = r"_chunk_step"
+
+
+def read(run):
+    return owners.device_ms(run, PROGRAM, group="attention", subs=("select",))

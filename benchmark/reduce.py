@@ -272,6 +272,24 @@ def program_time(reduced: Dict, pattern: str) -> Optional[Dict[str, float]]:
             "device_s": sum(r["device_s"] for r in hit)}
 
 
+def least_share(run: Dict, program: str, asks: str) -> Optional[float]:
+    """The least time one execution of ``program`` could take, as the
+    run's family states it (its function ``asks``, seconds), over the
+    program's measured device time an execution, in %; None untraced,
+    without peaks, where no such program ran whole inside the window, or
+    where the family does not say."""
+    if run["trace"] is None or run["peaks"] is None:
+        return None
+    t = program_time(run["trace"], program)
+    ask = getattr(run["family"], asks, None)
+    if t is None or ask is None:
+        return None
+    least_s = ask(run)
+    if least_s is None:
+        return None
+    return 100.0 * least_s / (t["device_s"] / t["count"])
+
+
 def describe(trace: Dict, sample: int = 4) -> str:
     """Planes, lines and a few events of each: look at a trace by hand
     before trusting code against it."""

@@ -120,6 +120,7 @@ def run_cell(layout, workload: str, seed: int, seconds: float, trace: bool,
             trace_reduce.find_xplane(ctx.profiler.out_dir)))
     dev = device.describe(ctx.devices)
     run = {"cell": cell, "config": ctx.config, "mix": ctx.mix,
+           "family": ctx.family,  # what a shared reader asks its counts of
            "facts": ctx.facts, "trace": reduced, "setup_s": setup_s,
            "end_to_end": out["end_to_end"], "chips": ctx.chips,
            "peaks": (counts.peaks_for(dev["kind"])

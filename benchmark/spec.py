@@ -43,15 +43,31 @@ class Layout:
 
     def family(self, name: str):
         """``families/<name>.py``: builds the program's graph from a
-        configuration and names its reference."""
+        configuration, names its reference, and answers what the shared
+        readers ask of a family (its counts of a step's bytes, a chunk's
+        operations, ...)."""
         return self._module("families", name)
 
     def reference(self, name: str):
         return self._module("reference", name)
 
     def reader(self, metric: str):
-        """``layer_metrics/<metric>.py``: ``read(run) -> number | None``."""
-        return self._module("layer_metrics", metric)
+        """``layer_metrics/<metric>.py``: ``read(run) -> number | None``,
+        one reader a quantity, for every cell its entry lists. What
+        differs by family it asks of ``run["family"]``, by the name its
+        ``ASKS`` states. An entry that has to stand apart from its
+        quantity's, because it moves another end-to-end metric or belongs
+        to another layer there, is named ``<quantity>.<what sets it
+        apart>`` and read by the quantity's file, unless it has one of
+        its own."""
+        return self._module("layer_metrics", self.quantity(metric))
+
+    def quantity(self, metric: str) -> str:
+        """The quantity whose file reads the entry ``metric``."""
+        own = os.path.join(self.base, "layer_metrics", metric + ".py")
+        if not os.path.isfile(own) and "." in metric:
+            return metric.rpartition(".")[0]
+        return metric
 
     def mix(self, name: str) -> Dict:
         path = os.path.join(self.base, "traffic", name + ".json")

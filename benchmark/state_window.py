@@ -36,6 +36,21 @@ def rows_per_step(run: Dict) -> Optional[float]:
     return rows_stepped(run) / steps if steps > 0 else None
 
 
+def cache_bytes(run: Dict, counts) -> Optional[tuple]:
+    """``(state, kv)``: the bytes the window's decode steps moved of
+    states (``rows_stepped`` times a state's bytes, in and out) and of
+    keys and values (``blocks_read`` times a block's), by the family's
+    ``counts`` module (its ``state_bytes``, ``kv_bytes_per_token``)."""
+    rows = rows_stepped(run)
+    blocks = blocks_read(run)
+    if rows is None or blocks is None:
+        return None
+    state = rows * 2 * counts.state_bytes(run["config"])
+    kv = (blocks * run["facts"]["stats1"]["kv"]["block_size"]
+          * counts.kv_bytes_per_token(run["config"]))
+    return state, kv
+
+
 def blocks_read(run: Dict) -> Optional[int]:
     ends = _ends(run)
     if ends is None:

@@ -121,7 +121,7 @@ def layout(tmp_path_factory):
         if m["name"] == "serve_tokens_per_s":
             m["workloads"].append(TOY_CELL)
     for m in bench["per_layer"]:
-        if m["name"].endswith(".reasoning"):
+        if CELL in m.get("workloads", ()):  # the toy joins what CELL reads
             m["workloads"].append(TOY_CELL)
     with open(path, "w") as f:
         json.dump(bench, f)
@@ -158,8 +158,9 @@ def test_readers_read_the_programs_counters(layout, result):
     assert "serve_check" in result["facts"]
     from benchmark import routed_window
 
-    run = {"trace": None, "peaks": None,
-           "config": layout.cell(TOY_CELL)["config"]}
+    cfg = layout.cell(TOY_CELL)["config"]
+    run = {"trace": None, "peaks": None, "config": cfg,
+           "family": layout.family(cfg["family"])}
     st = {"moe": {"block1_experts": {
         "held": [2, 4], "steps": 10, "idle_held_experts": 10,
         "rows_per_held_expert": [10, 0, 20, 10]}},
@@ -171,22 +172,20 @@ def test_readers_read_the_programs_counters(layout, result):
         "decode_steps": 0, "tokens": 0, "prefill_prompts": 0,
         "kv": {"blocks_read": 0, "blocks_in_tables": 0, "block_size": 8}}
     run["facts"] = {"stats0": zero, "stats1": st}
-    assert layout.reader("expert_rows_per_step.reasoning").read(run) == 1.0
-    assert layout.reader("expert_load_max_over_mean.reasoning").read(run) \
-        == 2.0
-    assert layout.reader("latent_blocks_read_share.reasoning").read(run) \
-        == 75.0
+    assert layout.reader("expert_rows_per_step").read(run) == 1.0
+    assert layout.reader("expert_load_max_over_mean").read(run) == 2.0
+    assert layout.reader("kv_blocks_read_share").read(run) == 75.0
     assert routed_window.expert_hit_share(run) == 0.75
     # 90 blocks over 30 slot-steps: each slot holds more than 2 blocks
     assert routed_window.live_tokens_per_step(run) == (90 - 30) * 8 / 10
     # a program without the counters: nothing, and no error
     run["facts"] = {"stats0": {}, "stats1": {}}
-    for name in ("expert_rows_per_step.reasoning",
-                 "expert_load_max_over_mean.reasoning",
-                 "latent_blocks_read_share.reasoning",
-                 "decode_step_roofline.reasoning",
-                 "latent_attention_roofline.reasoning",
-                 "decode_step_device_ms.reasoning"):
+    for name in ("expert_rows_per_step",
+                 "expert_load_max_over_mean",
+                 "kv_blocks_read_share",
+                 "decode_step_roofline",
+                 "latent_attention_roofline",
+                 "decode_step_device_ms"):
         assert layout.reader(name).read(run) is None
 
 

@@ -23,13 +23,22 @@ CELL = "granite-4.0-h-micro.serve-rag"
 TOY_CELL = "granite-toy.serve-rag-toy"
 SEED = 2 ** 31 + 77
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# this PR's per-layer entries, by name: not by where ``per_layer`` ends.
-# Eight, where the issue named twenty-three: ``per_layer`` may hold 128
-# and held 120 (the other fifteen are PERF.md section 7's)
-ENTRIES = {name + ".rag" for name in (
+# the cell's per-layer quantities, by name: not by where ``per_layer``
+# ends. PR 46 shipped eight of the twenty-three its issue named (the
+# list held 120 of the 128 it may); PR 49 folded the list and the cell
+# joined the other fifteen
+SHIPPED = {
     "decode_step_device_ms", "decode_step_roofline", "decode_mamba_device_ms",
-    "decode_attention_device_ms", "prefill_chunk_device_ms",
-    "prefill_chunk_mfu", "chunk_scan_mfu", "state_rows_carried_share")}
+    "decode_full_attention_device_ms", "prefill_chunk_device_ms",
+    "prefill_chunk_mfu", "chunk_scan_mfu", "state_rows_carried_share"}
+JOINED = {
+    "decode_matmul_device_ms", "device_idle_share", "idle_no_span_share",
+    "device_owned_share", "state_bytes_share", "kv_blocks_read_share",
+    "slot_occupancy", "loop_step_wall_ms", "loop_step_wall_max_ms",
+    "loop_host_ms", "loop_fetch_ms", "prefill_chunk_window_share",
+    "mamba_state_roofline", "chunk_mamba_device_ms",
+    "chunk_attention_device_ms"}
+ENTRIES = SHIPPED | JOINED
 
 
 def _config():
@@ -177,26 +186,19 @@ def test_the_new_entries_by_name():
     assert [m["name"] for m in cell["end_to_end"]] \
         == ["serve_tokens_per_s", "setup_s"]
     mine = {m["name"]: m for m in bench["per_layer"]
-            if m["name"].endswith(".rag")}
-    assert set(mine) == ENTRIES and len(ENTRIES) == 8
-    assert len(bench["per_layer"]) <= 128
+            if CELL in m.get("workloads", ())}
+    # the cell reports these and no other quantity of a list
+    assert set(mine) == ENTRIES and len(ENTRIES) == 8 + 15
+    assert len(bench["per_layer"]) <= 64
     assert {m["name"] for m in cell["per_layer"]} \
         == ENTRIES | {"compile_request_s", "cache_misses_warm"}
     for m in mine.values():
-        assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
         assert LAYOUT.reader(m["name"]).read is not None
     for name in ("decode_step_roofline", "prefill_chunk_mfu",
-                 "chunk_scan_mfu"):
-        assert mine[name + ".rag"]["unit"] == "%"
-        assert mine[name + ".rag"]["layer"] == "Kernels"
-    layers = {m["layer"] for m in bench["per_layer"]
-              if not m["name"].endswith(".rag")}
-    assert {m["layer"] for m in mine.values()} <= layers
-    # no other cell's metric lists this one
-    for m in bench["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["name"] in ENTRIES
+                 "chunk_scan_mfu", "mamba_state_roofline"):
+        assert mine[name]["unit"] == "%"
+        assert mine[name]["layer"] == "Kernels"
     serve = next(m for m in bench["end_to_end"]
                  if m["name"] == "serve_tokens_per_s")
     # (by name, not by place: the next cell is appended behind this one)
@@ -261,8 +263,9 @@ def test_readers_read_the_programs_counters(layout):
     """The per-layer readers that need no trace, on hand-made readings of
     ``stats()`` at a window's two ends; and nothing, without an error,
     from a program that lacks the counters (the parent commit's)."""
-    run = {"trace": None, "peaks": None,
-           "config": layout.cell(TOY_CELL)["config"]}
+    cfg = layout.cell(TOY_CELL)["config"]
+    run = {"trace": None, "peaks": None, "config": cfg,
+           "family": layout.family(cfg["family"])}
 
     def stats(k, state=True):
         kv = {"blocks_read": 90 * k, "blocks_in_tables": 300 * k,
@@ -279,9 +282,15 @@ def test_readers_read_the_programs_counters(layout):
     run["facts"] = {"stats0": stats(0), "stats1": stats(1)}
 
     def read(name):
-        return layout.reader(name + ".rag").read(run)
+        return layout.reader(name).read(run)
 
     assert read("state_rows_carried_share") == 60.0
+    # 90 states in and out beside 90 blocks of 8 tokens' keys and values
+    state = 90 * 2 * counts.state_bytes(cfg)
+    kv = 90 * 8 * counts.kv_bytes_per_token(cfg)
+    assert read("state_bytes_share") == pytest.approx(
+        100 * state / (state + kv))
+    assert read("kv_blocks_read_share") == 30.0
     from benchmark import plain_chunked
 
     assert plain_chunked.chunks(run) == {"chunks": 10, "tokens": 120,
@@ -289,7 +298,10 @@ def test_readers_read_the_programs_counters(layout):
     # the traced ones read nothing without a trace
     for name in ("decode_step_roofline", "decode_mamba_device_ms",
                  "prefill_chunk_mfu", "chunk_scan_mfu",
-                 "decode_attention_device_ms", "prefill_chunk_device_ms"):
+                 "decode_full_attention_device_ms", "prefill_chunk_device_ms",
+                 "mamba_state_roofline", "chunk_mamba_device_ms",
+                 "chunk_attention_device_ms", "decode_matmul_device_ms",
+                 "prefill_chunk_window_share", "device_idle_share"):
         assert read(name) is None
     # the parent's counters: a state without the chunks' rows
     run["facts"] = {"stats0": stats(0), "stats1": stats(1)}

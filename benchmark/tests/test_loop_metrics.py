@@ -17,9 +17,8 @@ from benchmark.tests import toy
 SEED = 2 ** 31 + 79
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-LOOP_METRICS = ["loop_step_wall_ms.offline", "loop_host_ms.offline",
-                "loop_fetch_ms.offline", "loop_prefill_share.offline",
-                "loop_step_wall_max_ms.offline"]
+LOOP_METRICS = ["loop_step_wall_ms", "loop_host_ms", "loop_fetch_ms",
+                "loop_prefill_share", "loop_step_wall_max_ms"]
 
 
 @pytest.fixture(scope="module")
@@ -77,13 +76,13 @@ def test_loop_readers_agree_with_each_other_and_the_window(layout, closed):
     assert abs(w["steps"] - f["decode_steps_in_window"]) <= 1
     # the phases telescope: steps times the step's wall time is the window
     # (the two readings of stats() are taken at its two ends)
-    assert read["loop_step_wall_ms.offline"] * w["steps"] / 1e3 == \
+    assert read["loop_step_wall_ms"] * w["steps"] / 1e3 == \
         pytest.approx(f["window_s"], rel=0.02)
     assert w["phase_s"]["wait"] < 0.02 * f["window_s"]  # a closed loop
-    parts = (read["loop_host_ms.offline"] + read["loop_fetch_ms.offline"]
+    parts = (read["loop_host_ms"] + read["loop_fetch_ms"]
              + 1e3 * w["phase_s"]["prefill"] / w["steps"])
-    assert parts == pytest.approx(read["loop_step_wall_ms.offline"])
-    assert 0 <= read["loop_prefill_share.offline"] < 100
+    assert parts == pytest.approx(read["loop_step_wall_ms"])
+    assert 0 <= read["loop_prefill_share"] < 100
     # the longest step is no shorter than the mean one, and a bucket's
     # bound overstates by less than a quarter of a doubling
     rows = loop.bucket_rows(closed, "step_wall")
@@ -91,13 +90,13 @@ def test_loop_readers_agree_with_each_other_and_the_window(layout, closed):
     s0 = f["stats0"]["loop"]["step_wall"]
     s1 = f["stats1"]["loop"]["step_wall"]
     mean_ms = 1e3 * (s1["sum"] - s0.get("sum", 0.0)) / w["steps"]
-    assert read["loop_step_wall_max_ms.offline"] >= mean_ms
-    assert read["loop_step_wall_max_ms.offline"] <= \
+    assert read["loop_step_wall_max_ms"] >= mean_ms
+    assert read["loop_step_wall_max_ms"] <= \
         1e3 * s1["max"] * 2 ** 0.25 * 1.001
 
 
 def test_token_gap_reader_on_the_open_loop_kinds_stats(layout, opened):
-    reader = layout.reader("token_gap_p95_ms.chat")
+    reader = layout.reader("token_gap_p95_ms")
     value = reader.read(opened)
     assert isinstance(value, float) and value > 0
     rows = loop.bucket_rows(opened, "token_gap")
@@ -124,7 +123,7 @@ def test_bucket_percentile_is_the_bound_of_the_bucket_that_holds_it():
 
 
 def test_idle_no_span_share_on_a_small_reduction(layout):
-    reader = layout.reader("idle_no_span_share.offline")
+    reader = layout.reader("idle_no_span_share")
     assert reader.read({"facts": {}, "trace": None}) is None
     # the ledger's PR 24 reading: 0.155 s of 0.166 s idle under no span
     tr = {"window_s": 2.95, "busy_s": 2.784,
@@ -167,13 +166,11 @@ def test_idle_gaps_take_the_programs_span_names_from_a_trace():
 def test_the_six_entries_are_in_the_benchmark_and_name_their_readers():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    tail = bench["per_layer"][-6:]  # appended, nothing else moved
-    assert [m["name"] for m in tail] == LOOP_METRICS + [
-        "idle_no_span_share.offline"]
-    for m in tail:
-        assert m["workloads"] == ["gpt2-large.serve-offline"]
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    for name in LOOP_METRICS + ["idle_no_span_share"]:
+        m = entries[name]  # by name: wherever the list holds it
+        assert "gpt2-large.serve-offline" in m["workloads"]
         assert m["moves"] == "serve_tokens_per_s" and m["better"] == "lower"
         assert os.path.exists(os.path.join(
-            ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
-    assert not any(m["name"] == "token_gap_p95_ms.chat"
-                   for m in bench["per_layer"])  # held back with its cell
+            ROOT, "benchmark", "layer_metrics", name + ".py"))
+    assert "token_gap_p95_ms" not in entries  # held back with its cell

@@ -24,7 +24,8 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 READERS = ("decode_step_device_ms", "decode_step_roofline",
            "prefill_chunk_device_ms", "prefill_chunk_mfu",
            "sparse_blocks_read_share", "state_bytes_share",
-           "loop_prefill_share", "slot_occupancy", "loop_step_wall_ms",
+           "prefill_chunk_window_share", "slot_occupancy",
+           "loop_step_wall_ms",
            "loop_step_wall_max_ms", "loop_host_ms", "loop_fetch_ms",
            "device_idle_share", "idle_no_span_share")
 
@@ -140,13 +141,13 @@ def test_the_mix_is_the_issues_table_and_fits_the_model():
     assert {m["name"] for m in cell["end_to_end"]} == {"serve_tokens_per_s",
                                                        "setup_s"}
     names = {m["name"] for m in cell["per_layer"]}
-    assert names >= {f"{r}.longdocs" for r in READERS}
+    assert names >= set(READERS)
     assert names >= {"compile_request_s", "cache_misses_warm"}
-    # this cell's entries in the order the issue lists them (by name, not
-    # by where the list ends: a later PR appends behind them)
-    mine = [m["name"] for m in LAYOUT.bench["per_layer"]
-            if m["name"].endswith(".longdocs")]
-    assert mine == [f"{r}.longdocs" for r in READERS]
+    # this cell's entries by name, wherever the list holds them: an entry
+    # is a quantity, and this cell is one of those that report it
+    entries = {m["name"]: m for m in LAYOUT.bench["per_layer"]}
+    for r in READERS:
+        assert CELL in entries[r]["workloads"], r
     assert CELL in [w["name"] for w in LAYOUT.bench["workloads"]]
 
 
@@ -206,7 +207,7 @@ def layout(tmp_path_factory):
         if m["name"] == "serve_tokens_per_s":
             m["workloads"].append(TOY_CELL)
     for m in bench["per_layer"]:
-        if m["name"].endswith(".longdocs"):
+        if CELL in m.get("workloads", ()):  # the toy joins what CELL reads
             m["workloads"].append(TOY_CELL)
     with open(path, "w") as f:
         json.dump(bench, f)
@@ -277,11 +278,12 @@ def test_every_new_reader_on_recorded_readings(layout):
              "idle_share": 0.25, "window_s": 0.4, "busy_s": 0.3,
              "idle_gaps": [["(no span)", 0.025], ["serving.loop.fetch", 0.05]]}
     run = {"trace": trace, "peaks": peaks, "config": cfg,
+           "family": layout.family(cfg["family"]),
            "facts": {"stats0": _stats(0, 0, 0, 0, 0, 0, 0),
                      "stats1": _stats(10, 40, 160, 240, 80, 5, 1)}}
 
     def read(name):
-        return layout.reader(f"{name}.longdocs").read(run)
+        return layout.reader(name).read(run)
 
     state = 4 * 8 * 8 * 4                    # a toy state: H D D float32
     block = 16 * 2 * 2 * 8 * 2               # k and v, 2 heads of 8, bf16
@@ -299,7 +301,8 @@ def test_every_new_reader_on_recorded_readings(layout):
     assert read("sparse_blocks_read_share") == pytest.approx(100 * 160 / 240)
     assert read("state_bytes_share") == pytest.approx(
         100 * 8 * 2 * state / (8 * 2 * state + rest))
-    assert read("loop_prefill_share") == pytest.approx(100 * 0.1 / 0.4)
+    assert read("prefill_chunk_window_share") == pytest.approx(
+        100 * 0.1 / 0.4)
     assert read("slot_occupancy") == pytest.approx(100.0)
     assert read("loop_step_wall_ms") == pytest.approx(350.0)
     assert read("loop_step_wall_max_ms") == pytest.approx(500.0)
@@ -315,21 +318,21 @@ def test_every_new_reader_on_recorded_readings(layout):
                  "sparse_blocks_read_share", "state_bytes_share"):
         assert read(name) is None
     assert read("slot_occupancy") == pytest.approx(100.0)
-    # another cell's run (the hybrid cell's configuration and counters)
-    other = dict(run, config=LAYOUT.cell(
-        "olmo-hybrid-pp2.serve-documents")["config"])
+    # another family's run (the hybrid cell's configuration, family and
+    # programs): a family that counts no chunk, a trace that holds none
+    hybrid = LAYOUT.cell("olmo-hybrid-pp2.serve-documents")["config"]
+    other = dict(run, config=hybrid, family=layout.family(hybrid["family"]))
     other["trace"] = {**trace, "programs": {
         "jit__decode_step": {"count": 10, "device_s": 0.05},
         "jit__prefill_step": {"count": 2, "device_s": 0.03}}}
-    for name in ("decode_step_roofline", "prefill_chunk_mfu",
-                 "prefill_chunk_device_ms", "sparse_blocks_read_share",
-                 "state_bytes_share", "loop_prefill_share"):
-        assert layout.reader(f"{name}.longdocs").read(other) is None
+    for name in ("prefill_chunk_mfu", "prefill_chunk_device_ms",
+                 "sparse_blocks_read_share", "prefill_chunk_window_share"):
+        assert layout.reader(name).read(other) is None
     # an untraced run: the trace's readers say nothing
     run["trace"] = None
     for name in ("decode_step_device_ms", "prefill_chunk_device_ms",
                  "decode_step_roofline", "prefill_chunk_mfu",
-                 "loop_prefill_share", "device_idle_share",
+                 "prefill_chunk_window_share", "device_idle_share",
                  "idle_no_span_share"):
         assert read(name) is None
     run["facts"] = {"stats0": {}, "stats1": {}}

@@ -21,18 +21,19 @@ CELL = "nemotron3-super-ep4.serve-agents"
 TOY_CELL = "nemotron-h-toy.serve-agents-toy"
 SEED = 2 ** 31 + 77
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# this PR's per-layer entries, by name: not by where ``per_layer`` ends
+# the cell's per-layer quantities (PR 40's nineteen, under PR 49's
+# names), by name: not by where ``per_layer`` ends
 ENTRIES = {
-    "decode_step_device_ms.agents", "decode_step_roofline.agents",
-    "prefill_device_ms.agents", "decode_experts_device_ms.agents",
-    "prefill_experts_device_ms.agents", "decode_mamba_device_ms.agents",
-    "mamba_state_roofline.agents", "expert_rows_per_step.agents",
-    "expert_load_max_over_mean.agents",
-    "expert_rows_computed_over_named.agents", "state_bytes_share.agents",
-    "slot_occupancy.agents", "loop_step_wall_ms.agents",
-    "loop_host_ms.agents", "loop_fetch_ms.agents",
-    "loop_prefill_share.agents", "device_idle_share.agents",
-    "idle_no_span_share.agents", "device_owned_share.agents"}
+    "decode_step_device_ms", "decode_step_roofline",
+    "prefill_device_ms", "decode_experts_device_ms",
+    "prefill_experts_device_ms", "decode_mamba_device_ms",
+    "mamba_state_roofline", "expert_rows_per_step",
+    "expert_load_max_over_mean",
+    "expert_rows_computed_over_named", "state_bytes_share",
+    "slot_occupancy", "loop_step_wall_ms",
+    "loop_host_ms", "loop_fetch_ms",
+    "loop_prefill_share", "device_idle_share",
+    "idle_no_span_share", "device_owned_share"}
 
 
 def _config():
@@ -163,19 +164,15 @@ def test_the_new_entries_by_name():
     assert [m["name"] for m in cell["end_to_end"]] \
         == ["serve_tokens_per_s", "setup_s"]
     mine = {m["name"]: m for m in bench["per_layer"]
-            if m["name"].endswith(".agents")}
-    assert set(mine) == ENTRIES
+            if CELL in m.get("workloads", ())}
+    # the cell reports these and no other quantity of a list
+    assert set(mine) == ENTRIES and len(ENTRIES) == 19
     assert {m["name"] for m in cell["per_layer"]} >= ENTRIES
     for m in mine.values():
-        assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
         assert LAYOUT.reader(m["name"]).read is not None
-    assert mine["decode_step_roofline.agents"]["unit"] == "%"
-    assert mine["mamba_state_roofline.agents"]["unit"] == "%"
-    # no other cell's metric lists this one
-    for m in bench["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["name"] in ENTRIES
+    assert mine["decode_step_roofline"]["unit"] == "%"
+    assert mine["mamba_state_roofline"]["unit"] == "%"
 
 
 # ---- the toy cell on the CPU -------------------------------------------------
@@ -233,8 +230,9 @@ def test_readers_read_the_programs_counters(layout, result):
     """The per-layer readers that need no trace, on hand-made readings of
     ``stats()`` at a window's two ends; and nothing, without an error,
     from a program that lacks the counters (the parent commit's)."""
-    run = {"trace": None, "peaks": None,
-           "config": layout.cell(TOY_CELL)["config"]}
+    cfg = layout.cell(TOY_CELL)["config"]
+    run = {"trace": None, "peaks": None, "config": cfg,
+           "family": layout.family(cfg["family"])}
 
     def moe(steps, idle, rows, computed, held, p_computed, p_held):
         return {"block0_mixer": {
@@ -252,15 +250,15 @@ def test_readers_read_the_programs_counters(layout, result):
             "kv": {"blocks_read": 0, "block_size": 8,
                    "state": {"rows_stepped": 0}}}
     run["facts"] = {"stats0": zero, "stats1": st}
-    assert layout.reader("expert_rows_per_step.agents").read(run) == 1.0
-    assert layout.reader("expert_load_max_over_mean.agents").read(run) == 2.0
+    assert layout.reader("expert_rows_per_step").read(run) == 1.0
+    assert layout.reader("expert_load_max_over_mean").read(run) == 2.0
     # (120 + 64) rows computed over (40 + 24) rows named
     assert layout.reader(
-        "expert_rows_computed_over_named.agents").read(run) == 184 / 64
+        "expert_rows_computed_over_named").read(run) == 184 / 64
     cfg = run["config"]
     state = 60 * 2 * counts.state_bytes(cfg)
     kv = 90 * 8 * counts.kv_bytes_per_token(cfg)
-    assert layout.reader("state_bytes_share.agents").read(run) \
+    assert layout.reader("state_bytes_share").read(run) \
         == pytest.approx(100 * state / (state + kv))
     # a program without the counters: nothing, and no error
     run["facts"] = {"stats0": {}, "stats1": {}}
@@ -274,7 +272,7 @@ def test_readers_read_the_programs_counters(layout, result):
         "rows_per_held_expert": [1, 1, 1, 1], "pairs_held": 4}}}
     run["facts"] = {"stats0": old, "stats1": old}
     assert layout.reader(
-        "expert_rows_computed_over_named.agents").read(run) is None
+        "expert_rows_computed_over_named").read(run) is None
 
 
 def test_the_comparison_passes_the_program_and_refuses_the_control(layout):

@@ -271,45 +271,54 @@ def test_a_profile_recorded_on_the_chip(tmp_path):
 
 # ---- the entries and their readers ----------------------------------------------
 
-OWNER_ENTRIES = [
-    "device_owned_share.train", "attention_device_ms.train",
-    "matmul_device_ms.train", "optimizer_device_ms.train",
-    "device_owned_share.offline", "decode_attention_device_ms.offline",
-    "decode_matmul_device_ms.offline", "device_owned_share.reasoning",
-    "decode_attention_device_ms.reasoning",
-    "decode_matmul_device_ms.reasoning", "device_owned_share.documents",
-    "decode_matmul_device_ms.documents",
-    "decode_state_write_device_ms.documents", "device_owned_share.longdocs",
-    "chunk_select_device_ms.longdocs", "chunk_attend_device_ms.longdocs"]
-COUNTER_ENTRIES = ["kv_blocks_read_share.offline", "loop_ahead_share.offline",
-                   "loop_ahead_share.reasoning"]
+# PR 28's nineteen entries as PR 49 folded them: a quantity an entry, and
+# the cells that report it (by name: no place in ``per_layer`` is pinned)
+OWNER_ENTRIES = {
+    "device_owned_share.train": ["gpt2-medium.fit-1024"],
+    "attention_device_ms": ["gpt2-medium.fit-1024"],
+    "matmul_device_ms": ["gpt2-medium.fit-1024"],
+    "optimizer_device_ms": ["gpt2-medium.fit-1024"],
+    "device_owned_share": [
+        "gpt2-large.serve-offline", "axk1-ep16.serve-reasoning",
+        "olmo-hybrid-pp2.serve-documents", "minicpm-sala-pp2.serve-longdocs"],
+    "decode_attention_device_ms": ["gpt2-large.serve-offline",
+                                   "axk1-ep16.serve-reasoning"],
+    "decode_matmul_device_ms": ["gpt2-large.serve-offline",
+                                "olmo-hybrid-pp2.serve-documents"],
+    "decode_matmul_device_ms.reasoning": ["axk1-ep16.serve-reasoning"],
+    "decode_state_write_device_ms": ["olmo-hybrid-pp2.serve-documents"],
+    "chunk_select_device_ms": ["minicpm-sala-pp2.serve-longdocs"],
+    "chunk_attend_device_ms": ["minicpm-sala-pp2.serve-longdocs"]}
+COUNTER_ENTRIES = {
+    "kv_blocks_read_share": ["gpt2-large.serve-offline"],
+    "loop_ahead_share": ["gpt2-large.serve-offline",
+                         "axk1-ep16.serve-reasoning"]}
+ENTRIES = {**OWNER_ENTRIES, **COUNTER_ENTRIES}
 
 
 def test_the_nineteen_entries_are_in_the_benchmark_by_name():
     layout = Layout()
     entries = {m["name"]: m for m in layout.bench["per_layer"]}
-    cells = {w["name"] for w in layout.bench["workloads"]}
     layers = {m["layer"] for m in layout.bench["per_layer"]
-              if m["name"] not in OWNER_ENTRIES + COUNTER_ENTRIES}
-    for name in OWNER_ENTRIES + COUNTER_ENTRIES:
+              if m["name"] not in ENTRIES}
+    assert sum(len(cells) for cells in ENTRIES.values()) == 19
+    for name, cells in ENTRIES.items():
         m = entries[name]
-        assert len(m["workloads"]) == 1 and m["workloads"][0] in cells
+        assert set(cells) <= set(m["workloads"]), name
         assert m["layer"] in layers          # a layer the benchmark names
-        cell = layout.cell(m["workloads"][0])
-        assert m["moves"] == cell["end_to_end"][0]["name"]
-        assert name in [e["name"] for e in cell["per_layer"]]
+        for workload in cells:
+            cell = layout.cell(workload)
+            assert m["moves"] == cell["end_to_end"][0]["name"]
+            assert name in [e["name"] for e in cell["per_layer"]]
         assert callable(layout.reader(name).read)
     for name in OWNER_ENTRIES:
         assert entries[name]["source"] == "device_trace"
         share = name.startswith("device_owned_share")
         assert (entries[name]["unit"], entries[name]["better"]) == (
             ("%", "higher") if share else ("ms", "lower"))
-    order = [m["name"] for m in layout.bench["per_layer"]]
-    assert order[order.index(OWNER_ENTRIES[0]):] == \
-        OWNER_ENTRIES + COUNTER_ENTRIES
 
 
-@pytest.mark.parametrize("name", OWNER_ENTRIES + COUNTER_ENTRIES)
+@pytest.mark.parametrize("name", sorted(ENTRIES))
 def test_a_reader_reports_nothing_without_a_trace_or_the_counters(name):
     read = Layout().reader(name).read
     assert read({"trace": None, "facts": {}, "cell": {
@@ -324,15 +333,15 @@ def test_the_owner_readers_read_the_table():
     run = {"trace": {}, "facts": {}, "_owners": O.owner_table(HAND)}
     assert layout.reader("device_owned_share.train").read(run) == \
         pytest.approx(100 * (1 - 70 / 320))
-    assert layout.reader("optimizer_device_ms.train").read(run) == \
+    assert layout.reader("optimizer_device_ms").read(run) == \
         pytest.approx(40e-6)
-    assert layout.reader("matmul_device_ms.train").read(run) == \
+    assert layout.reader("matmul_device_ms").read(run) == \
         pytest.approx(50e-6)
-    assert layout.reader("decode_attention_device_ms.offline").read(run) == \
+    assert layout.reader("decode_attention_device_ms").read(run) == \
         pytest.approx(50e-6)
-    assert layout.reader("decode_state_write_device_ms.documents").read(
+    assert layout.reader("decode_state_write_device_ms").read(
         run) is None                       # the decode program has no state
-    assert layout.reader("chunk_select_device_ms.longdocs").read(run) is None
+    assert layout.reader("chunk_select_device_ms").read(run) is None
 
 
 def test_the_counter_readers_take_the_windows_deltas():
@@ -344,10 +353,9 @@ def test_the_counter_readers_take_the_windows_deltas():
                         "loop": {"steps": 110, "phase_s": {},
                                  "ahead": {"steps_ahead": 107}}}}
     run = {"trace": None, "facts": facts}
-    assert layout.reader("kv_blocks_read_share.offline").read(run) == \
+    assert layout.reader("kv_blocks_read_share").read(run) == \
         pytest.approx(45.0)
-    for cell in ("offline", "reasoning"):
-        assert layout.reader(f"loop_ahead_share.{cell}").read(run) == \
-            pytest.approx(99.0)
+    assert layout.reader("loop_ahead_share").read(run) == \
+        pytest.approx(99.0)
     del facts["stats0"]["loop"]["ahead"], facts["stats1"]["loop"]["ahead"]
-    assert layout.reader("loop_ahead_share.offline").read(run) is None
+    assert layout.reader("loop_ahead_share").read(run) is None

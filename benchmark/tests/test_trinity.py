@@ -23,8 +23,9 @@ CELL = "trinity-large-ep8.serve-mixedlengths"
 TOY_CELL = "trinity-toy.serve-mixedlengths-toy"
 SEED = 2 ** 31 + 77
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# this PR's per-layer entries, by name: not by where ``per_layer`` ends
-ENTRIES = {name + ".mixedlengths" for name in (
+# the cell's per-layer quantities (PR 42's twenty-five, under PR 49's
+# names), by name: not by where ``per_layer`` ends
+ENTRIES = set((
     "decode_step_device_ms", "decode_step_roofline",
     "prefill_chunk_device_ms", "prefill_chunk_mfu",
     "window_attention_roofline", "decode_window_attention_device_ms",
@@ -35,7 +36,7 @@ ENTRIES = {name + ".mixedlengths" for name in (
     "kv_blocks_read_share", "expert_rows_per_step",
     "expert_load_max_over_mean", "expert_rows_computed_over_named",
     "slot_occupancy", "loop_step_wall_ms", "loop_step_wall_max_ms",
-    "loop_host_ms", "loop_fetch_ms", "loop_prefill_share")}
+    "loop_host_ms", "loop_fetch_ms", "prefill_chunk_window_share"))
 
 
 def _config():
@@ -203,23 +204,19 @@ def test_the_new_entries_by_name():
     assert [m["name"] for m in cell["end_to_end"]] \
         == ["serve_tokens_per_s", "setup_s"]
     mine = {m["name"]: m for m in bench["per_layer"]
-            if m["name"].endswith(".mixedlengths")}
+            if CELL in m.get("workloads", ())}
+    # the cell reports these and no other quantity of a list
     assert set(mine) == ENTRIES and len(ENTRIES) == 25
     assert {m["name"] for m in cell["per_layer"]} >= ENTRIES
     for m in mine.values():
-        assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
         assert LAYOUT.reader(m["name"]).read is not None
     for name in ("decode_step_roofline", "prefill_chunk_mfu",
                  "window_attention_roofline"):
-        assert mine[name + ".mixedlengths"]["unit"] == "%"
-    # no other cell's metric lists this one
-    for m in bench["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["name"] in ENTRIES
+        assert mine[name]["unit"] == "%"
     serve = next(m for m in bench["end_to_end"]
                  if m["name"] == "serve_tokens_per_s")
-    assert serve["workloads"][-1] == CELL
+    assert CELL in serve["workloads"]
     for entry in (cell["workload"], cell["config_entry"]):
         assert len(entry["why"]) <= 200
 
@@ -283,8 +280,9 @@ def test_readers_read_the_programs_counters(layout):
     """The per-layer readers that need no trace, on hand-made readings of
     ``stats()`` at a window's two ends; and nothing, without an error,
     from a program that lacks the counters (the parent commit's)."""
-    run = {"trace": None, "peaks": None,
-           "config": layout.cell(TOY_CELL)["config"]}
+    cfg = layout.cell(TOY_CELL)["config"]
+    run = {"trace": None, "peaks": None, "config": cfg,
+           "family": layout.family(cfg["family"])}
 
     def moe(steps, idle, rows, computed, held, p_computed, p_held):
         return {"block1_experts": {
@@ -310,7 +308,7 @@ def test_readers_read_the_programs_counters(layout):
     run["facts"] = {"stats0": stats(0), "stats1": stats(1)}
 
     def read(name):
-        return layout.reader(name + ".mixedlengths").read(run)
+        return layout.reader(name).read(run)
 
     assert read("window_rows_read_share") == 40.0
     assert read("window_rows_reserved_over_used") == 1.6
