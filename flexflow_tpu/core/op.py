@@ -81,12 +81,14 @@ FIXED_SCOPES = ("loss", "metrics", "optimizer", "sample", "tail", "counters")
 # the pieces of work inside an op that different changes aim at, the same
 # word in every entry kind (serving/cache_entry.py)
 SUB_SCOPES = ("project", "write", "attend", "select", "conv", "rule",
-              "chunks", "route", "latent", "experts", "gate", "window")
+              "chunks", "route", "latent", "experts", "gate", "window",
+              "mix", "out")
 # the group a metric sums an op type under; a type not named is "other"
 OP_GROUPS: Dict[str, Tuple[OpType, ...]] = {
     # the types a serving program gives a pair, latent or sparse entry kind
     "attention": (OpType.MULTIHEAD_ATTENTION, OpType.LATENT_ATTENTION,
-                  OpType.BLOCK_SPARSE_ATTENTION),
+                  OpType.BLOCK_SPARSE_ATTENTION,
+                  OpType.COMPRESSED_CONV_ATTENTION),
     # the types that keep a state a request
     "state": (OpType.GATED_DELTA_NET, OpType.LIGHTNING_ATTENTION,
               OpType.MAMBA2),

@@ -147,6 +147,9 @@ class OpType(enum.Enum):
     BATCHNORM = "batch_norm"
     LAYERNORM = "layer_norm"
     RMS_NORM = "rms_norm"
+    # x -> scale * x + shift, two learned vectors over the last axis (a
+    # model's learned residual scaling)
+    SCALE_SHIFT = "scale_shift"
     CONCAT = "concat"
     SPLIT = "split"
     EMBEDDING = "embedding"
@@ -187,6 +190,11 @@ class OpType(enum.Enum):
     # linear attention with a fixed decay a head over a state of fixed
     # size a sequence, rotary positions (Lightning Attention)
     LIGHTNING_ATTENTION = "lightning_attention"
+    # causal grouped-head attention whose queries and keys are mixed along
+    # the sequence by two short causal convolutions, whose values take
+    # half of each head from the token before, and whose positions rotate
+    # part of a head (compressed convolutional attention)
+    COMPRESSED_CONV_ATTENTION = "compressed_conv_attention"
     # a state-space mixer (Mamba-2): a scalar data-dependent decay a head
     # over a state of fixed size a sequence, B and C shared by the heads
     # of a group, a joint causal convolution, a gated grouped RMSNorm

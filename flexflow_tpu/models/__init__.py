@@ -11,7 +11,11 @@ keeps a ring of ``window`` rows a request in the paged pool:
 by a ``layer_types`` list, every block a mixer and a gated MLP behind
 pre-norms and scaled residuals, a softmax scale of the model's own, a
 head tied to the embedding; prompts may be prefilled in chunks through
-the states: ``SsmStateEntry.chunk``)."""
+the states: ``SsmStateEntry.chunk``) and ``zaya`` (``build_zaya_lm``:
+every layer compressed convolutional attention and top-1 routed experts
+under an MLP router whose state runs down the layers, learned residual
+scalings, a tied head; a layer keeps a pair a token AND a row a request:
+``CcaEntry``)."""
 
 from .mlp import build_mlp
 from .alexnet import build_alexnet
@@ -31,6 +35,7 @@ from .sparse_hybrid import build_sparse_hybrid_lm, SparseHybridConfig
 from .nemotron_h import build_nemotron_h_lm, NemotronHConfig
 from .trinity import build_trinity_lm, TrinityConfig
 from .granite_hybrid import build_granite_hybrid_lm, GraniteHybridConfig
+from .zaya import build_zaya_lm, ZayaConfig
 
 
 def zoo_smoke_builders():
@@ -130,6 +135,12 @@ def zoo_smoke_builders():
             attention_multiplier=0.0625, mamba_heads=4, mamba_head_dim=16,
             state_size=8, chunk_size=8, num_heads=4, num_kv_heads=2))
 
+    def zaya(ff, bs):
+        build_zaya_lm(ff, bs, 16, ZayaConfig(
+            vocab_size=128, hidden_size=32, num_layers=2, num_heads=4,
+            num_kv_heads=2, head_dim=16, n_routed=4, expert_width=16,
+            router_width=8))
+
     return {
         "mlp": mlp,
         "alexnet": alexnet,
@@ -149,4 +160,5 @@ def zoo_smoke_builders():
         "nemotron_h": nemotron_h,
         "trinity": trinity,
         "granite_hybrid": granite_hybrid,
+        "zaya": zaya,
     }

@@ -423,18 +423,206 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rotary(x, positions, inv_freq):
+def apply_rotary(x, positions, inv_freq, rotary_dim=None):
     """Rotate the pairs ``(x[i], x[i + d/2])`` of the last axis by
     ``positions * inv_freq[i]``. ``x``: (..., S, [H,] d) with
-    ``positions`` (..., S); float32 inside, the input's dtype out."""
+    ``positions`` (..., S); float32 inside, the input's dtype out. With
+    ``rotary_dim`` the first ``rotary_dim`` values of the last axis are
+    rotated (their own halves paired) and the rest pass as they are."""
     ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     if x.ndim == ang.ndim + 1:                   # a heads axis before d
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
+    rest = []
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        xf, rest = xf[..., :rotary_dim], [xf[..., rotary_dim:]]
     a, b = jnp.split(xf, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin] + rest,
                            axis=-1).astype(x.dtype)
+
+
+@register_op
+class CompressedConvAttention(Op):
+    """Causal grouped-head self-attention whose queries and keys are mixed
+    along the sequence before they meet (compressed convolutional
+    attention, Zyphra 2025; no reference analog). ``H`` query heads and
+    ``G`` key-value heads of ``d``, ``H / G`` query heads a key-value
+    head; per token ``u`` (zeros stand before position 0):
+
+    1. :meth:`project`: ``z = [u W_q ; u W_k]``, ``(H + G) d`` wide;
+    2. :meth:`mix`, over a window of the ``taps0 + taps1 - 2`` rows before
+       the tokens and the tokens: a depthwise causal convolution of
+       ``taps0`` taps and a bias, then one of ``taps1`` taps grouped by
+       head (a ``d x d`` matrix a tap and head) and a bias; to the result
+       the mean of each query head's and its key head's UNMIXED values is
+       added (for a key head, the mean over its query heads of those
+       means); each head is scaled to the length ``sqrt(d)``, a key head
+       times ``exp(temp)`` besides (one learned temperature a key head);
+       the first ``rotary_dim`` values of each head are rotated by the
+       positions;
+    3. :meth:`values`: a value head's first half is ``u W_v1``'s, its
+       second half ``W_v2``'s of the token BEFORE;
+    4. causal attention ``softmax(q k / sqrt(d)) v`` and :meth:`out`.
+
+    What a cache keeps: keys (rotated) and values a token, and a request
+    the last ``tail`` rows of ``z`` and the last token's ``u W_v2``
+    (serving/cache_entry.py ``CcaEntry``). Inputs: the activations (B, S,
+    E) and the graph's int32 positions (B, S)."""
+
+    op_type = OpType.COMPRESSED_CONV_ATTENTION
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        a = self.attrs
+        self.embed_dim: int = input_shapes[0].sizes[-1]
+        self.num_heads = int(a["num_heads"])
+        self.num_kv_heads = int(a["num_kv_heads"])
+        self.head_dim = int(a["head_dim"])
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(f"{self.num_heads} query heads are not "
+                             f"{self.num_kv_heads} equal groups")
+        if self.head_dim % 2:
+            raise ValueError("a value head is two halves")
+        self.taps = (int(a.get("taps0", 2)), int(a.get("taps1", 2)))
+        if min(self.taps) < 1:
+            raise ValueError(f"taps {self.taps}: a convolution has a tap")
+        # rows of z before a token that its mixed q and k read
+        self.tail = self.taps[0] + self.taps[1] - 2
+        self.channels = (self.num_heads + self.num_kv_heads) * self.head_dim
+        self.rotary_dim = int(a.get("rotary_dim") or self.head_dim)
+        self.inv_freq = rotary_inv_freq(self.rotary_dim,
+                                        float(a.get("rotary", 10000.0)))
+        self.scale = 1.0 / math.sqrt(self.head_dim)
+        self.causal, self.window = True, None
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self) -> List[WeightSpec]:
+        dt = self.input_shapes[0].dtype
+        init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        gain = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
+        zero = self.attrs.get("bias_initializer") or ZeroInitializer()
+        e, h, g, d = (self.embed_dim, self.num_heads, self.num_kv_heads,
+                      self.head_dim)
+        t0, t1 = self.taps
+        return [
+            WeightSpec("wq", (e, h, d), dt, init),
+            WeightSpec("wk", (e, g, d), dt, init),
+            WeightSpec("wv1", (e, g, d // 2), dt, init),
+            WeightSpec("wv2", (e, g, d // 2), dt, init),
+            WeightSpec("wo", (h, d, e), dt, init),
+            WeightSpec("conv0", (t0, self.channels), dt, gain),
+            WeightSpec("conv0_b", (self.channels,), dt, zero,
+                       weight_decay=False),
+            WeightSpec("conv1", (h + g, t1, d, d), dt, init),
+            WeightSpec("conv1_b", (h + g, d), dt, zero, weight_decay=False),
+            WeightSpec("temp", (g,), dt, zero, weight_decay=False),
+        ]
+
+    # ---- the pieces serving composes (serving/cache_entry.py) -------------
+    @sub_scope("project")
+    def project(self, weights, u):
+        """``u`` (B, S, E) -> ``z`` (B, S, (H + G) d): the queries' heads,
+        then the keys', before any mixing."""
+        b, s, _ = u.shape
+        q = jnp.einsum("bse,ehd->bshd", u, weights["wq"])
+        k = jnp.einsum("bse,ehd->bshd", u, weights["wk"])
+        return jnp.concatenate([q, k], axis=2).reshape(b, s, self.channels)
+
+    @sub_scope("mix")
+    def mix(self, weights, window, positions):
+        """``window`` (B, tail + S, channels): the ``tail`` rows of ``z``
+        before the S tokens at ``positions`` (B, S), then theirs; a row
+        before position 0 holds zeros, and so does what the first
+        convolution would make of it. Returns the (B, S, H, d) queries and
+        (B, S, G, d) keys as they are attended, float32 inside, the
+        window's dtype out."""
+        f32 = jnp.float32
+        h, g, d = self.num_heads, self.num_kv_heads, self.head_dim
+        t0, t1 = self.taps
+        b, s = positions.shape
+        z = window.astype(f32)
+        n1 = s + t1 - 1                      # rows of the first one's output
+        w0 = weights["conv0"].astype(f32)
+        z1 = sum(w0[j] * z[:, j:j + n1] for j in range(t0)) \
+            + weights["conv0_b"].astype(f32)
+        # its row i stands at position positions[:, 0] - (t1 - 1) + i
+        at = positions[:, :1] - (t1 - 1) + jax.lax.iota(jnp.int32, n1)[None]
+        z1 = jnp.where((at >= 0)[..., None], z1, 0.0).reshape(
+            b, n1, h + g, d)
+        w1 = weights["conv1"].astype(f32)
+        z2 = sum(jnp.einsum("bshi,hio->bsho", z1[:, j:j + s], w1[:, j],
+                            precision=jax.lax.Precision.HIGHEST)
+                 for j in range(t1)) + weights["conv1_b"].astype(f32)
+        raw = z[:, self.tail:].reshape(b, s, h + g, d)
+        q_raw = raw[:, :, :h].reshape(b, s, g, h // g, d)
+        m_q = 0.5 * (q_raw + raw[:, :, h:, None])
+        q = z2[:, :, :h] + m_q.reshape(b, s, h, d)
+        k = z2[:, :, h:] + m_q.mean(3)
+
+        def unit(x):
+            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                     + 1e-12) * math.sqrt(d)
+
+        q = unit(q)
+        k = unit(k) * jnp.exp(weights["temp"].astype(f32))[:, None]
+        q = apply_rotary(q, positions, self.inv_freq, self.rotary_dim)
+        k = apply_rotary(k, positions, self.inv_freq, self.rotary_dim)
+        return q.astype(window.dtype), k.astype(window.dtype)
+
+    @sub_scope("project")
+    def values(self, weights, u, prev):
+        """``u`` (B, S, E) and ``prev`` (B, G d/2), the token before the
+        first one's ``u W_v2`` (zeros before position 0) -> the (B, S, G,
+        d) values and every token's own ``u W_v2`` (B, S, G d/2)."""
+        b, s, _ = u.shape
+        g, half = self.num_kv_heads, self.head_dim // 2
+        now = jnp.einsum("bse,egd->bsgd", u, weights["wv1"])
+        own = jnp.einsum("bse,egd->bsgd", u, weights["wv2"])
+        before = jnp.concatenate(
+            [prev.reshape(b, 1, g, half).astype(own.dtype), own[:, :-1]],
+            axis=1)
+        return (jnp.concatenate([now, before], axis=-1),
+                own.reshape(b, s, g * half))
+
+    @sub_scope("out")
+    def out(self, weights, ctxv):
+        return jnp.einsum("bqhd,hde->bqe", ctxv, weights["wo"])
+
+    def sees(self, qpos, kpos):
+        return kpos <= qpos
+
+    def whole(self, weights, u, positions):
+        """Whole sequences from nothing: the (B, S, H, d) queries and the
+        (B, S, G, d) keys and values as they are attended."""
+        z = self.project(weights, u)
+        q, k = self.mix(weights, jnp.pad(z, ((0, 0), (self.tail, 0), (0, 0))),
+                        positions)
+        v, _ = self.values(weights, u, jnp.zeros(
+            (u.shape[0], self.num_kv_heads * self.head_dim // 2), u.dtype))
+        return q, k, v
+
+    def forward(self, ctx, inputs, weights):
+        from ..parallel.ring_attention import single_device_attention
+
+        u, positions = inputs
+        q, k, v = self.whole(weights, u, positions)
+        group = self.num_heads // self.num_kv_heads
+        with sub_scope("attend"):
+            ctxv = single_device_attention(
+                q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+                True, self.scale, 0.0, None, None)
+        return [self.out(weights, ctxv)]
+
+    def flops(self) -> float:
+        b, s = self.input_shapes[0].sizes[:2]
+        e, h, g, d = (self.embed_dim, self.num_heads, self.num_kv_heads,
+                      self.head_dim)
+        proj = 2.0 * b * s * e * d * (2 * h + 2 * g)
+        conv = 2.0 * b * s * (h + g) * self.taps[1] * d * d
+        return proj + conv + 4.0 * b * h * s * s * d
 
 
 @register_op

@@ -590,8 +590,15 @@ class RoutedExperts(Op):
     formulation of DeepSeek-V3 2024, and with ``latent`` the LatentMoE of
     the Nemotron 3 line). Per token ``u``:
 
-    * scores ``s = sigmoid(float32(u) W_router)``, in float32 whatever
-      the activations' dtype;
+    * scores ``s = sigmoid(float32(u) W_router)`` (``scoring``
+      ``"softmax"``: the softmax over all experts), in float32 whatever
+      the activations' dtype; with ``router="mlp"`` the router's logits
+      are an MLP's over a state ``r`` of ``router_width`` numbers a
+      token: ``r = u W_dn + b_dn + depth_scale * r_prev`` (``r_prev`` the
+      state of the routed layer before, the op's second input; a model's
+      first such layer has neither it nor ``depth_scale``), logits
+      ``gelu(gelu(rms(r) W_1 + b_1) W_2 + b_2) W_router`` (exact GELU),
+      all float32, and ``r`` is the op's second output;
     * with ``selection_bias`` the choice is made by ``s + b`` (a learned
       bias an expert; the weights below are still ``s``'s);
     * with ``n_group`` > 1 the experts are ``n_group`` groups of equal
@@ -610,7 +617,10 @@ class RoutedExperts(Op):
     whose expert it holds, adding nothing for the others: the sum over
     the holders of all shares is the whole layer's routed part (with
     ``latent``, after ``W_up``). Nothing is dropped (:meth:`apply`).
-    Weights: ``router`` (E, n_routed), ``bias`` (n_routed,),
+    Weights: ``router`` (E, n_routed) (``"mlp"``: (router_width,
+    n_routed), behind ``router_down`` (E, router_width), ``router_b``,
+    ``depth_scale``, ``router_norm``, ``router_w1``, ``router_b1``,
+    ``router_w2``, ``router_b2``), ``bias`` (n_routed,),
     ``latent_down`` (E, latent), ``latent_up`` (latent, E), ``w_gate``
     (gated only) and ``w_up`` (count, V, width), ``w_down`` (count,
     width, V), V the width the experts read.
@@ -636,9 +646,21 @@ class RoutedExperts(Op):
         self.work_dim = self.latent or self.in_dim
         first, count = a.get("experts_held") or (0, self.n_routed)
         self.first, self.count = int(first), int(count)
-        if self.scoring != "sigmoid":
-            raise ValueError(f"scoring {self.scoring!r}: only sigmoid "
-                             f"scores are built")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r} is neither "
+                             f"'sigmoid' nor 'softmax'")
+        self.router = a.get("router", "linear")
+        if self.router not in ("linear", "mlp"):
+            raise ValueError(f"router {self.router!r} is neither 'linear' "
+                             f"nor 'mlp'")
+        self.router_width = int(a.get("router_width") or 0)
+        self.router_eps = float(a.get("router_eps", 1e-5))
+        # an MLP router reads the state of the routed layer before (the
+        # second input) and hands its own on (the second output)
+        self.takes_state = len(input_shapes) > 1
+        if self.takes_state and self.router != "mlp":
+            raise ValueError(f"{self.name}: only an MLP router takes a "
+                             f"router state")
         if a.get("activation", "silu_gated") not in ("silu_gated", "relu2"):
             raise ValueError(f"activation {a['activation']!r} is neither "
                              f"'silu_gated' nor 'relu2'")
@@ -653,7 +675,35 @@ class RoutedExperts(Op):
             raise ValueError("fewer experts stay than a token takes")
 
     def infer_output_shapes(self):
-        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+        x = self.input_shapes[0]
+        outs = [(x.sizes, x.dtype)]
+        if self.router == "mlp":
+            outs.append((x.sizes[:-1] + (self.router_width,), DataType.FLOAT))
+        return outs
+
+    def _router_specs(self, dt, init):
+        from ..core.op import WeightSpec
+        from ..runtime.initializer import ConstantInitializer, ZeroInitializer
+
+        if self.router != "mlp":
+            return [WeightSpec("router", (self.in_dim, self.n_routed), dt,
+                               init)]
+        r = self.router_width
+        one = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
+        zero = self.attrs.get("bias_initializer") or ZeroInitializer()
+
+        def vector(name, how):
+            return WeightSpec(name, (r,), dt, how, weight_decay=False)
+
+        return ([WeightSpec("router_down", (self.in_dim, r), dt, init),
+                 vector("router_b", zero)]
+                + ([vector("depth_scale", one)] if self.takes_state else [])
+                + [vector("router_norm", one),
+                   WeightSpec("router_w1", (r, r), dt, init),
+                   vector("router_b1", zero),
+                   WeightSpec("router_w2", (r, r), dt, init),
+                   vector("router_b2", zero),
+                   WeightSpec("router", (r, self.n_routed), dt, init)])
 
     def weight_specs(self):
         from ..core.op import WeightSpec
@@ -663,7 +713,7 @@ class RoutedExperts(Op):
         dt = self.input_shapes[0].dtype
         init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
         e, v, w, c = self.in_dim, self.work_dim, self.width, self.count
-        specs = [WeightSpec("router", (e, self.n_routed), dt, init)]
+        specs = self._router_specs(dt, init)
         if self.selection_bias:
             specs.append(WeightSpec(
                 "bias", (self.n_routed,), dt,
@@ -678,17 +728,43 @@ class RoutedExperts(Op):
                         WeightSpec("w_down", (c, w, v), dt, init)]
 
     # ---- the two halves (serving reads the first's ids) -------------------
+    def _router_logits(self, weights, x2d, prev):
+        """The router's (T, n_routed) float32 logits and, from an MLP
+        router, its (T, router_width) float32 state."""
+        f32 = jnp.float32
+
+        def mm(a, name):
+            return jnp.dot(a, weights[name].astype(f32),
+                           precision=jax.lax.Precision.HIGHEST)
+
+        x = x2d.astype(f32)
+        if self.router != "mlp":
+            return mm(x, "router"), None
+        from .norm import rms_norm
+
+        r = mm(x, "router_down") + weights["router_b"].astype(f32)
+        if self.takes_state:
+            r = r + weights["depth_scale"].astype(f32) * prev.reshape(
+                r.shape).astype(f32)
+        h = rms_norm(r, weights["router_norm"], self.router_eps)
+        for i in ("1", "2"):
+            h = jax.nn.gelu(mm(h, "router_w" + i)
+                            + weights["router_b" + i].astype(f32),
+                            approximate=False)
+        return mm(h, "router"), r
+
     @sub_scope("route")
-    def route(self, weights, x2d, ids=None):
-        """``x2d`` (T, E) -> expert ids (T, k) int32 and their weights
-        (T, k) float32, over all ``n_routed`` experts. With ``ids`` given
-        the selection is skipped: those experts are taken, weighted by
-        this op's own scores of them (a comparison that has to follow
+    def route(self, weights, x2d, ids=None, prev=None):
+        """``x2d`` (T, E) -> expert ids (T, k) int32, their weights (T,
+        k) float32, over all ``n_routed`` experts, and the router's state
+        (T, router_width) float32 (None from a router that keeps none;
+        ``prev`` is the state of the routed layer before). With ``ids``
+        given the selection is skipped: those experts are taken, weighted
+        by this op's own scores of them (a comparison that has to follow
         another program's routing)."""
-        logits = jnp.dot(x2d.astype(jnp.float32),
-                         weights["router"].astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        s = jax.nn.sigmoid(logits)
+        logits, state = self._router_logits(weights, x2d, prev)
+        s = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
         choice = s
         if self.selection_bias:
             choice = s + weights["bias"].astype(jnp.float32)
@@ -709,7 +785,7 @@ class RoutedExperts(Op):
         g = jnp.take_along_axis(s, ids, axis=-1)
         if self.norm_topk:
             g = g / (g.sum(-1, keepdims=True) + 1e-20)
-        return ids.astype(jnp.int32), g * self.routed_scale
+        return ids.astype(jnp.int32), g * self.routed_scale, state
 
     def held_hits(self, ids):
         """``ids`` (T, k) -> (T, k, count) bool: which held expert, if
@@ -949,11 +1025,20 @@ class RoutedExperts(Op):
         return y
 
     def forward(self, ctx, inputs, weights):
-        (x,) = inputs
+        x, *prev = inputs
         x2d = x.reshape(-1, x.shape[-1])
-        ids, gates = self.route(weights, x2d)
-        return [self.apply(weights, x2d, ids, gates,
-                           mesh=getattr(ctx, "mesh", None)).reshape(x.shape)]
+        ids, gates, state = self.route(weights, x2d, None, *prev)
+        return self.outputs(x, self.apply(
+            weights, x2d, ids, gates, mesh=getattr(ctx, "mesh", None)), state)
+
+    def outputs(self, x, y, state):
+        """The op's outputs, shaped as its input ``x`` (..., E) is: the
+        held experts' part ``y`` (T, E) and, from an MLP router, its
+        ``state`` (T, router_width)."""
+        outs = [y.reshape(x.shape)]
+        if state is not None:
+            outs.append(state.reshape(x.shape[:-1] + (-1,)))
+        return outs
 
     def flops(self) -> float:
         t = 1
@@ -970,6 +1055,9 @@ class RoutedExperts(Op):
             rows = (t * self.k * self.count // self.n_routed
                     + int(self.count * self.named_share(t))
                     * tile_rows(t) // 2)
-        return (2.0 * t * self.in_dim * self.n_routed
+        r = self.router_width
+        router = (self.in_dim * self.n_routed if self.router != "mlp"
+                  else self.in_dim * r + 2 * r * r + r * self.n_routed)
+        return (2.0 * t * router
                 + 4.0 * t * self.in_dim * self.latent
                 + 2.0 * mats * rows * self.work_dim * self.width)

@@ -90,6 +90,39 @@ class RMSNorm(Op):
         return 4.0 * n
 
 
+@register_op
+class ScaleShift(Op):
+    """``scale * x + shift``, two learned vectors over the last axis (a
+    learned residual scaling; no reference analog), in float32 whatever
+    the activations' dtype; the output keeps the input's."""
+
+    op_type = OpType.SCALE_SHIFT
+
+    def infer_output_shapes(self):
+        return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
+
+    def weight_specs(self):
+        n, dt = self.input_shapes[0].sizes[-1], self.input_shapes[0].dtype
+        return [WeightSpec("scale", (n,), dt,
+                           self.attrs.get("kernel_initializer")
+                           or ConstantInitializer(1.0), weight_decay=False),
+                WeightSpec("shift", (n,), dt,
+                           self.attrs.get("bias_initializer")
+                           or ZeroInitializer(), weight_decay=False)]
+
+    def forward(self, ctx, inputs, weights):
+        (x,) = inputs
+        f32 = jnp.float32
+        return [(x.astype(f32) * weights["scale"].astype(f32)
+                 + weights["shift"].astype(f32)).astype(x.dtype)]
+
+    def flops(self) -> float:
+        n = 1
+        for s in self.input_shapes[0].sizes:
+            n *= s
+        return 2.0 * n
+
+
 def rms_norm(x, scale, eps: float):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)

@@ -277,6 +277,16 @@ class FFModel:
             OpType.RMS_NORM, [input],
             dict(eps=float(eps), kernel_initializer=kernel_initializer), name)
 
+    def scale_shift(self, input: Tensor, kernel_initializer=None,
+                    bias_initializer=None,
+                    name: Optional[str] = None) -> Tensor:
+        """``scale * x + shift``, two learned vectors over the last axis
+        (ops/norm.py ScaleShift)."""
+        return self._infer_and_add(
+            OpType.SCALE_SHIFT, [input],
+            dict(kernel_initializer=kernel_initializer,
+                 bias_initializer=bias_initializer), name)
+
     def gated_mlp(self, input: Tensor, width: int,
                   activation: ActiMode = ActiMode.SILU,
                   kernel_initializer=None,
@@ -312,6 +322,31 @@ class FFModel:
             eps=float(eps), kernel_initializer=kernel_initializer,
             gain_initializer=gain_initializer)
         return self._infer_and_add(OpType.LATENT_ATTENTION,
+                                   [input, positions], attrs, name)
+
+    def compressed_conv_attention(self, input: Tensor, positions: Tensor, *,
+                                  num_heads: int, num_kv_heads: int,
+                                  head_dim: int, taps0: int = 2,
+                                  taps1: int = 2, rotary: float = 10000.0,
+                                  rotary_dim: Optional[int] = None,
+                                  kernel_initializer=None,
+                                  gain_initializer=None,
+                                  bias_initializer=None,
+                                  name: Optional[str] = None) -> Tensor:
+        """Causal grouped-head self-attention whose queries and keys pass
+        two causal convolutions of ``taps0`` (depthwise) and ``taps1``
+        (grouped by head) taps, whose values take half of each head from
+        the token before, and whose first ``rotary_dim`` values a head
+        are rotated by ``positions``, the graph's int32 (B, S) input
+        (ops/attention.py CompressedConvAttention)."""
+        attrs = dict(num_heads=int(num_heads), num_kv_heads=int(num_kv_heads),
+                     head_dim=int(head_dim), taps0=int(taps0),
+                     taps1=int(taps1), rotary=float(rotary),
+                     rotary_dim=int(rotary_dim or head_dim),
+                     kernel_initializer=kernel_initializer,
+                     gain_initializer=gain_initializer,
+                     bias_initializer=bias_initializer)
+        return self._infer_and_add(OpType.COMPRESSED_CONV_ATTENTION,
                                    [input, positions], attrs, name)
 
     def gated_delta_net(self, input: Tensor, *, num_heads: int, key_dim: int,
@@ -402,16 +437,26 @@ class FFModel:
                        selection_bias: bool = False,
                        activation: str = "silu_gated",
                        latent: Optional[int] = None,
+                       router: str = "linear",
+                       router_width: Optional[int] = None,
+                       router_state: Optional[Tensor] = None,
+                       router_eps: float = 1e-5,
                        kernel_initializer=None, bias_initializer=None,
-                       name: Optional[str] = None) -> Tensor:
+                       gain_initializer=None,
+                       name: Optional[str] = None):
         """Dropless top-k routed experts of which this op holds
         ``experts_held = (first, count)`` (default: all of them)
-        (ops/moe_ops.py RoutedExperts). ``selection_bias``: a learned
+        (ops/moe_ops.py RoutedExperts). ``scoring``: ``"sigmoid"`` or
+        ``"softmax"`` over all experts; ``selection_bias``: a learned
         bias an expert, added to the scores in the choice only;
         ``activation``: ``"silu_gated"`` (``silu(u Wg) * (u Wu)``) or
         ``"relu2"`` (a plain MLP, ``relu(u W1)^2``); ``latent``: the
         width the experts work in, between a projection down before them
-        and one up after their sum."""
+        and one up after their sum. ``router="mlp"``: the scores come
+        from an MLP over a state of ``router_width`` numbers a token,
+        which takes the state of the layer before (``router_state``, that
+        op's second output; None in a model's first such layer) and hands
+        its own on: the builder then returns ``[output, state]``."""
         attrs = dict(
             n_routed=int(n_routed), experts_per_token=int(experts_per_token),
             width=int(width), n_group=int(n_group),
@@ -429,7 +474,15 @@ class FFModel:
             attrs["activation"] = activation
         if latent:
             attrs["latent"] = int(latent)
-        return self._infer_and_add(OpType.ROUTED_EXPERTS, [input], attrs,
+        inputs = [input]
+        if router != "linear":
+            attrs.update(router=router, router_width=int(router_width),
+                         router_eps=float(router_eps),
+                         gain_initializer=gain_initializer,
+                         bias_initializer=bias_initializer)
+            if router_state is not None:
+                inputs.append(router_state)
+        return self._infer_and_add(OpType.ROUTED_EXPERTS, inputs, attrs,
                                    name)
 
     # ---- elementwise --------------------------------------------------- #

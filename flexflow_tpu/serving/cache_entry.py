@@ -309,12 +309,30 @@ class EntryKind:
         """This kind in a model over ``devices`` devices."""
         return self
 
+    def request_arenas(self, rows, dtype) -> Tuple:
+        """What a kind of a row a token keeps a REQUEST besides: arenas
+        of ``rows`` rows, which the pool allocates behind :meth:`arenas`'
+        own in the op's entry and addresses by the slots' rows. None, for
+        most kinds."""
+        return ()
+
+    @property
+    def keeps_row(self) -> bool:
+        """Whether a request holds a row of this kind's arenas: all of
+        them (``per_request``) or some (:meth:`request_arenas`)."""
+        return self.per_request or bool(self.request_arenas(1, jnp.float32))
+
     def token_bytes(self, dtype) -> int:
         """Bytes one token (one request, for a ``per_request`` kind) takes
         in one op's arenas stored as ``dtype``: plain arithmetic on
         :meth:`arenas`, nothing is allocated."""
         return sum(math.prod(a.shape) * a.dtype.itemsize
                    for a in self.arenas(1, 1, dtype))
+
+    def request_bytes(self, dtype) -> int:
+        """Bytes one request takes in :meth:`request_arenas`."""
+        return sum(math.prod(a.shape) * a.dtype.itemsize
+                   for a in self.request_arenas(1, dtype))
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """:meth:`whole`, and the rows scattered through each prompt's
@@ -434,8 +452,16 @@ class PairEntry(EntryKind):
         longest prompt of the group has got to hold nothing and are not
         visited), else a span at a time as far as that."""
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
+        ctxv, entry = self._chunk_rows(op, qh, kh, vh, entry, addr, offsets,
+                                       lengths)
+        return op.project_out(weights, ctxv, x), entry
+
+    def _chunk_rows(self, op, qh, kh, vh, entry, addr, offsets, lengths):
+        """:meth:`chunk` behind the projections: the chunk's (P, S, Hkv,
+        D) keys and values written, its (P, S, H, D) queries attended;
+        returns (the attended values, the entry)."""
         bs = entry[0].shape[1]
-        n, s = x.shape[:2]
+        n, s = qh.shape[:2]
         heads, hdim = kh.shape[2:]
         tables = addr.tables
         mb = tables.shape[1]
@@ -455,7 +481,7 @@ class PairEntry(EntryKind):
                     op, qh, pos, *(a[tables].reshape(n, mb * bs, -1)
                                    for a in entry),
                     jnp.where(at < (offsets + lengths)[:, None], at, NOWHERE))
-            return op.project_out(weights, ctxv, x), entry
+            return ctxv, entry
         per = max(1, SPAN_TOKENS // bs)            # blocks a span
         span = per * bs
         padded = jnp.pad(tables, ((0, 0), (0, -mb % per)),
@@ -473,10 +499,17 @@ class PairEntry(EntryKind):
                 op, qh, pos, heads, read, 0,
                 jnp.maximum((jnp.max(offsets + lengths) + span - 1) // span,
                             1))
-        return op.project_out(weights, ctxv, x), entry
+        return ctxv, entry
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
+        ctxv, entry = self._step_rows(op, qh, kh, vh, entry, addr, seq_lens)
+        return op.project_out(weights, ctxv, x), entry
+
+    def _step_rows(self, op, qh, kh, vh, entry, addr, seq_lens):
+        """:meth:`step` behind the projections: the W new (N, W, Hkv, D)
+        keys and values written through the tables, the (N, W, H, D)
+        queries attended; returns (the attended values, the entry)."""
         bs = entry[0].shape[1]
         n, w = qh.shape[:2]
         heads, hdim = kh.shape[2:]
@@ -505,7 +538,7 @@ class PairEntry(EntryKind):
                     qh, k, v, lambda: (_iota(k.shape[1])[None, None, :]
                                        <= pos[:, :, None])[:, None, :, :],
                     op.scale)
-        return op.project_out(weights, ctxv, x), entry
+        return ctxv, entry
 
     def whole(self, op, weights, x, positions):
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
@@ -528,6 +561,13 @@ class PairEntry(EntryKind):
 
     def dense_step(self, op, weights, x, positions, cache, offset):
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
+        ctxv, cache = self._dense_rows(op, qh, kh, vh, cache, offset)
+        return op.project_out(weights, ctxv, x), cache
+
+    def _dense_rows(self, op, qh, kh, vh, cache, offset):
+        """:meth:`dense_step` behind the projections: the block's keys
+        and values written at ``offset``, its queries attended; returns
+        (the attended values, the cache)."""
         kcache, vcache = cache
         with sub_scope("write"):
             # dynamic_update_slice keeps the shape static; unwritten and
@@ -540,9 +580,9 @@ class PairEntry(EntryKind):
             ctxv = _attend(
                 qh, kcache, vcache,
                 lambda: op.sees(kpos=_iota(kcache.shape[1])[None, :],
-                                qpos=(offset + _iota(x.shape[1]))[:, None]
+                                qpos=(offset + _iota(qh.shape[1]))[:, None]
                                 )[None, None, :, :], op.scale)
-        return op.project_out(weights, ctxv, x), (kcache, vcache)
+        return ctxv, (kcache, vcache)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -723,6 +763,125 @@ class WindowEntry(PairEntry):
                            kh.reshape(n * s, heads, hdim),
                            vh.reshape(n * s, heads, hdim))
         return op.project_out(weights, ctxv, x), entry
+
+
+@dataclasses.dataclass(frozen=True)
+class CcaEntry(PairEntry):
+    """The entry of a compressed convolutional attention op
+    (``ops/attention.py`` ``CompressedConvAttention``): the pair a TOKEN,
+    keys (rotated) and values in the pair's arenas and layout, read by
+    the pair's kernels, AND a row a REQUEST (:meth:`request_arenas`): the
+    last ``tail`` rows of ``z``, what the op's two convolutions read
+    before a token, flat, and the last token's ``u W_v2``, the half of
+    the next token's values that comes from it; both stored as the pair
+    is. An entry is ``(keys, values, tails, prevs)``. A step takes one
+    token a slot and puts the slots' rows back through
+    :func:`_spread_rows`, for :class:`StateEntry`'s reasons; a prompt's
+    first chunk starts from zeros, whatever the row held, a later one
+    from what the chunk before wrote; what is written is what the
+    chunk's TRUE length leaves. No int8 form."""
+
+    tail: int = 0
+    channels: int = 0
+    name = "cca"
+    max_window = 1
+    int8_form = None
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        if op.layer.inputs[1].tensor_id != positions_id:
+            raise ValueError(f"{op.name}: its rotary positions have to be "
+                             f"the graph's positions input")
+        return cls(op.num_kv_heads, op.head_dim, op.num_heads,
+                   tail=op.tail, channels=op.channels)
+
+    def request_arenas(self, rows, dtype):
+        return (jax.ShapeDtypeStruct((rows, self.tail * self.channels),
+                                     dtype),
+                jax.ShapeDtypeStruct((rows, self.heads * self.head_dim // 2),
+                                     dtype))
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return window == 1 and super().reads_in_place(
+            op, entry[:2], slots, window, max_blocks)
+
+    def _mixed(self, op, weights, x, positions, tail, prev):
+        """The op's queries, keys and values for ``x`` (N, S, E) behind a
+        request's ``tail`` (N, tail, channels) and ``prev`` (N, .), and
+        what the tokens leave of both: the window's rows of ``z`` (N, tail
+        + S, channels) and each token's own ``u W_v2`` (N, S, .)."""
+        z = op.project(weights, x)
+        window = jnp.concatenate([tail.astype(z.dtype), z], axis=1)
+        qh, kh = op.mix(weights, window, positions)
+        vh, own = op.values(weights, x, prev)
+        return qh, kh, vh, window, own
+
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        n = x.shape[0]                   # one token a slot: ``max_window``
+        tails, prevs = entry[2:]
+        with sub_scope("mix"):
+            tail = tails[addr.rows].reshape(n, self.tail, self.channels)
+            prev = prevs[addr.rows]
+        qh, kh, vh, window, own = self._mixed(op, weights, x, positions,
+                                              tail, prev)
+        with sub_scope("write"):
+            rows = (_spread_rows(tails, addr.rows,
+                                 window[:, 1:].reshape(n, -1)),
+                    _spread_rows(prevs, addr.rows, own[:, 0]))
+        ctxv, pair = self._step_rows(op, qh, kh, vh, entry[:2], addr,
+                                     seq_lens)
+        return op.out(weights, ctxv), pair + rows
+
+    def prefill(self, op, weights, x, positions, entry, addr, lengths):
+        return self.chunk(op, weights, x, positions, entry, addr,
+                          jnp.zeros_like(lengths), lengths)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        n = x.shape[0]
+        tails, prevs = entry[2:]
+        later = offsets > 0
+        with sub_scope("mix"):
+            tail = jnp.where(later[:, None, None], tails[addr.rows].reshape(
+                n, self.tail, self.channels), 0)
+            prev = jnp.where(later[:, None], prevs[addr.rows], 0)
+        qh, kh, vh, window, own = self._mixed(op, weights, x, positions,
+                                              tail, prev)
+        with sub_scope("write"):
+            # the last ``tail`` rows of z behind the chunk's TRUE length,
+            # and its last live token's half value
+            at = lengths[:, None] + _iota(self.tail)[None, :]
+            left = jnp.take_along_axis(window, at[:, :, None], axis=1)
+            last = jnp.take_along_axis(
+                own, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
+            rows = (tails.at[addr.rows].set(
+                        left.reshape(n, -1).astype(tails.dtype)),
+                    prevs.at[addr.rows].set(last[:, 0].astype(prevs.dtype)))
+        ctxv, pair = self._chunk_rows(op, qh, kh, vh, entry[:2], addr,
+                                      offsets, lengths)
+        return op.out(weights, ctxv), pair + rows
+
+    def whole(self, op, weights, x, positions):
+        qh, kh, vh = op.whole(weights, x, positions)
+        pos = _iota(x.shape[1])
+        with sub_scope("attend"):
+            ctxv = _attend(qh, kh, vh, lambda: (
+                pos[None, :] <= pos[:, None])[None, None, :, :], op.scale)
+        return op.out(weights, ctxv), (kh, vh), pos
+
+    def dense_shapes(self, batch, max_length, dtype):
+        return super().dense_shapes(batch, max_length, dtype) + (
+            jax.ShapeDtypeStruct((batch, self.tail, self.channels), dtype),
+            jax.ShapeDtypeStruct((batch, self.heads * self.head_dim // 2),
+                                 dtype))
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        tail, prev = cache[2:]
+        qh, kh, vh, window, own = self._mixed(op, weights, x, positions,
+                                              tail, prev)
+        ctxv, pair = self._dense_rows(op, qh, kh, vh, cache[:2], offset)
+        return op.out(weights, ctxv), pair + (
+            window[:, window.shape[1] - self.tail:].astype(tail.dtype),
+            own[:, -1].astype(prev.dtype))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1369,6 +1528,7 @@ class SsmStateEntry(EntryKind):
 # as :meth:`EntryKind.for_op`
 KINDS: Dict[OpType, Callable[..., EntryKind]] = {
     OpType.MULTIHEAD_ATTENTION: PairEntry.for_op,
+    OpType.COMPRESSED_CONV_ATTENTION: CcaEntry.for_op,
     OpType.LATENT_ATTENTION: LatentEntry.for_op,
     OpType.GATED_DELTA_NET: StateEntry.for_op,
     OpType.BLOCK_SPARSE_ATTENTION: SparseEntry.for_op,
@@ -1391,6 +1551,6 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
     return None
 
 
-__all__ = ["DecayStateEntry", "EntryKind", "Int8PairEntry", "KINDS",
+__all__ = ["CcaEntry", "DecayStateEntry", "EntryKind", "Int8PairEntry", "KINDS",
            "LatentEntry", "PairEntry", "SparseEntry", "SsmStateEntry",
            "StateEntry", "WindowEntry", "kind_for", "latent_row_lanes"]

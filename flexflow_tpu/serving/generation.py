@@ -296,7 +296,9 @@ class _DecodeGraph:
         """Walk the op graph over the activations in ``acts``; ``attn``
         handles each attention op, given ``(op, weights, x, positions)``
         (it calls the op's entry kind in the program's cache layout) and
-        ``experts``, where given, each routed-experts op (the paged
+        ``experts``, where given, each routed-experts op, given ``(op,
+        weights, x)`` and, behind another whose router hands a state on,
+        that state; it returns the op's outputs, a list (the paged
         programs keep the routing they chose). Returns the (B, S, vocab)
         float32 logits.
 
@@ -323,7 +325,7 @@ class _DecodeGraph:
                 elif op.op_type is not OpType.ROUTED_EXPERTS:
                     outs = op.forward(ctx, ins, p)
                 elif experts is not None:
-                    outs = [experts(op, p, ins[0])]
+                    outs = experts(op, p, *ins)
                 else:
                     outs = op.forward(on_mesh, ins, p)
             for out, t in zip(outs, op.layer.outputs):
@@ -678,9 +680,9 @@ class PagedDecoder(_DecodeGraph):
             routed.update({op.name: ids for ids in picked})
             return out
 
-        def experts(op, p, x):
+        def experts(op, p, x, *prev):
             x2d = x.reshape(-1, x.shape[-1])
-            ids, gates = op.route(p, x2d)
+            ids, gates, state = op.route(p, x2d, None, *prev)
             routed[op.name] = ids
             computed: List[jax.Array] = []
 
@@ -703,7 +705,7 @@ class PagedDecoder(_DecodeGraph):
             else:
                 count()
                 y = op.apply(p, x2d, ids, gates, mesh=self._cm.mesh)
-            return y.reshape(x.shape)
+            return op.outputs(x, y, state)
 
         logits = self._forward_block(params, acts, attn, experts)[:, -1, :]
         with fixed_scope("sample"):
@@ -832,9 +834,9 @@ class PagedDecoder(_DecodeGraph):
         with ``held`` a list and ``live(positions)`` giving (rows,
         positions) bool, append the count of the live tokens' pairs whose expert the
         op holds, and to ``computed`` the rows its products ran over."""
-        def experts(op, p, x):
+        def experts(op, p, x, *prev):
             x2d = x.reshape(-1, x.shape[-1])
-            ids, gates = op.route(p, x2d)
+            ids, gates, state = op.route(p, x2d, None, *prev)
             routed[op.name] = ids.reshape(x.shape[:2] + (-1,))
             if held is not None:
                 with fixed_scope("counters"):
@@ -842,8 +844,8 @@ class PagedDecoder(_DecodeGraph):
                     held.append(jnp.sum(mine
                                         & live(x.shape[1]).reshape(-1, 1),
                                         dtype=jnp.uint32))
-            return op.apply(p, x2d, ids, gates, computed,
-                            self._cm.mesh).reshape(x.shape)
+            return op.outputs(x, op.apply(p, x2d, ids, gates, computed,
+                                          self._cm.mesh), state)
 
         return experts
 
@@ -1198,11 +1200,12 @@ class PagedDecoder(_DecodeGraph):
         def attn(op, p, x, pos):
             return self._kinds[op.name].whole(op, p, x, pos)[0]
 
-        def experts(op, p, x):
+        def experts(op, p, x, *prev):
             x2d = x.reshape(-1, x.shape[-1])
-            ids, gates = op.route(p, x2d, jnp.asarray(routing[op.name]))
-            return op.apply(p, x2d, ids, gates,
-                            mesh=self._cm.mesh).reshape(x.shape)
+            ids, gates, state = op.route(
+                p, x2d, jnp.asarray(routing[op.name]), *prev)
+            return op.outputs(x, op.apply(p, x2d, ids, gates,
+                                          mesh=self._cm.mesh), state)
 
         logits = self._forward_block(self._exec_params(), acts, attn,
                                      experts if routing else None)

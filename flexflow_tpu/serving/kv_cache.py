@@ -59,7 +59,11 @@ a request's blocks AND a row, or neither; the block table stays the
 request's one handle (:meth:`PagedKVPool.rows_of` finds the row of a
 table's request by its first block, which no other live request holds),
 :meth:`PagedKVPool.free` returns both, and the bytes count both. Inside
-the programs the two travel together as :class:`Addresses`.
+the programs the two travel together as :class:`Addresses`. A kind may
+keep both in one layer (``request_arenas``: compressed convolutional
+attention's pair a token and, a request, its convolutions' tail and the
+last token's half value): its paged arenas get ``num_blocks`` blocks, the
+others ``num_rows`` rows, and its bytes count under both terms.
 
 **The books.** What the decode steps read is the pool's to count, since
 the kinds, the geometry and ``stats()["kv"]`` are its own: the serving
@@ -149,6 +153,7 @@ def pool_bytes(specs, num_blocks: int, block_size: int,
             per_row += kind.token_bytes(store)
         else:
             per_tok += kind.token_bytes(store)
+            per_row += kind.request_bytes(store)
     return (int(num_blocks) * int(block_size) * per_tok
             + int(num_rows) * per_row)
 
@@ -201,7 +206,7 @@ class PagedKVPool:
                   for name, spec in self.specs.items()}
         # rows of each per-request arena, the null row among them; 0 in a
         # pool none of whose kinds keeps a state
-        has_state = any(kind.per_request for kind, _ in stored.values())
+        has_state = any(kind.keeps_row for kind, _ in stored.values())
         self.num_rows = int(num_rows) if has_state else 0
         if has_state and self.num_rows < 2:
             raise ValueError(f"num_rows {num_rows} < 2: row 0 is the "
@@ -213,7 +218,8 @@ class PagedKVPool:
             self.kv[name] = tuple(
                 jnp.zeros(a.shape, a.dtype) for a in kind.arenas(
                     self.num_rows if kind.per_request else self.num_blocks,
-                    self.block_size, store))
+                    self.block_size, store)
+                + kind.request_arenas(self.num_rows, store))
         # LIFO free list: freshly freed blocks are reused first (their
         # stale contents are masked by position either way)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
@@ -231,7 +237,7 @@ class PagedKVPool:
         # rows a chunk started from zeros (a prompt's first) and those it
         # carried on from what the chunk before left
         self._chunk_rows = {"rows_started": 0, "rows_carried": 0}
-        self._state_ops = sum(k.per_request for k in self.kinds.values())
+        self._state_ops = sum(k.keeps_row for k in self.kinds.values())
         # the ops whose steps read less than they keep, and the sums of
         # what they read, by the word their kind's step_reads says
         # ("selected": a selection of a request's blocks; "window": at

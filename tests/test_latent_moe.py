@@ -210,7 +210,7 @@ def test_dropless_under_imbalance():
     router[:, 4:6] = 1.0
     params["router"] = jnp.asarray(router)
     op, held = _expert_op((4, 1), params)                 # holds 4 alone
-    ids, gates = op.route(held, x.reshape(-1, 32))
+    ids, gates, _ = op.route(held, x.reshape(-1, 32))
     assert np.array_equal(np.sort(np.asarray(ids), -1),
                           np.tile([4, 5], (12, 1)))
     got = np.asarray(op.forward(None, [x], held)[0]).reshape(12, 32)
@@ -446,13 +446,13 @@ def test_expert_counters_live_outside_the_pool_and_read_beside_a_step():
     assert rec["steps"] == 12 and rec["pairs_routed"] == 12 * 2
 
 
-def test_only_sigmoid_scores_are_built():
-    with pytest.raises(ValueError, match="sigmoid"):
+def test_only_sigmoid_and_softmax_scores_are_built():
+    with pytest.raises(ValueError, match="neither 'sigmoid' nor 'softmax'"):
         ff = FFModel(FFConfig(batch_size=2, ledger="off",
                               computation_mode=CompMode.INFERENCE))
         x = ff.create_tensor((2, 4, 32), DataType.FLOAT)
         ff.routed_experts(x, n_routed=8, experts_per_token=2, width=16,
-                          scoring="softmax")
+                          scoring="tanh")
         ff.compile(optimizer=None, loss_type=None, metrics=[])
 
 
@@ -495,7 +495,7 @@ def test_kv_calibration_follows_the_paged_programs_routing():
     x = jnp.asarray(np.random.default_rng(0).normal(size=(5, 32)),
                     jnp.float32)
     p = ff.compiled.params[names[0]]
-    ids, gates = op.route(p, x)
-    same_ids, same_gates = op.route(p, x, ids)
+    ids, gates, _ = op.route(p, x)
+    same_ids, same_gates, _ = op.route(p, x, ids)
     assert np.array_equal(ids, same_ids)
     assert np.allclose(gates, same_gates)

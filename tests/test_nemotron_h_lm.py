@@ -236,7 +236,7 @@ def test_expert_forms_agree_with_each_other_and_the_reference(toy):
     outputs' range: float32 summation order."""
     ff, weights = toy
     op, w, u, ids = _expert_case(ff, weights)
-    _, gates = op.route(w, u, ids)
+    _, gates, _ = op.route(w, u, ids)
     v = u @ w["latent_down"]
     counts = np.bincount(np.asarray(ids).ravel(), minlength=16)[4:8]
     assert 0 < counts.max() <= op.capacity(40) == 16 and counts[2] == 0
@@ -245,14 +245,14 @@ def test_expert_forms_agree_with_each_other_and_the_reference(toy):
     # expert 5 named by 26 tokens: 10 rows spill into the one spill tile
     spilled = np.array(ids)
     spilled[1:27, 0] = 5
-    _, g2 = op.route(w, u, jnp.asarray(spilled))
+    _, g2, _ = op.route(w, u, jnp.asarray(spilled))
     d2 = np.asarray(op._apply_dense(w, v, jnp.asarray(spilled), g2))
     s2 = np.asarray(op._apply_grouped(w, v, jnp.asarray(spilled), g2))
     assert op.spill_tiles == 1 and np.abs(d2 - s2).max() <= 1e-5 * np.abs(
         d2).max() and not np.array_equal(d2, s2)
     # by all 40: more than the spill tile holds, the dense form's result
     crowded = jnp.asarray(spilled).at[:, 0].set(5)
-    _, g3 = op.route(w, u, crowded)
+    _, g3, _ = op.route(w, u, crowded)
     assert np.array_equal(np.asarray(op._apply_grouped(w, v, crowded, g3)),
                           np.asarray(op._apply_dense(w, v, crowded, g3)))
     assert np.abs(dense[0]).max() == 0.0 and np.abs(grouped[0]).max() == 0.0
@@ -269,7 +269,7 @@ def test_expert_forms_agree_with_each_other_and_the_reference(toy):
     w = dict(w, **{v: w[v] * 8 for v in big.values()})
     x = u[None]                # the piece norms its input: gain 1 here
     un = reference._rms(x, lw["norm"], 1e-5)[0]
-    ids_n, gates_n = op.route(w, un, ids)
+    ids_n, gates_n, _ = op.route(w, un, ids)
     s = jax.nn.sigmoid(un @ w["router"])
     whole = np.asarray(_pieces(TOY)["expert_ffn"](x, lw, s, ids))[0]
     shared = np.asarray(reference._relu2_mlp(
@@ -361,7 +361,7 @@ def test_grouped_kernel_agrees_with_the_dense_form_and_float32(
     rows = 200 if routing == "ragged_rows" else 256
     v = jax.random.normal(jax.random.key(5), (rows, 256)).astype(jnp.bfloat16)
     ids = _routing(routing, rows)
-    _, gates = op.route(w, v, ids)
+    _, gates, _ = op.route(w, v, ids)
     assert kernel.supported(rows, 4, 256, 128, 6, op.gated, v.dtype)
     got, counted = kernel.grouped_experts(v, ids, gates, w, first=op.first,
                                           gated=op.gated)
@@ -442,7 +442,7 @@ def test_the_kernel_takes_the_cells_shapes_and_the_jnp_form_the_rest(
     assert narrow.expert_form(256) == "grouped"
     v = jax.random.normal(jax.random.key(6), (256, 128)).astype(jnp.bfloat16)
     ids = _routing("uniform", 256)
-    _, gates = narrow.route(wn, v, ids)
+    _, gates, _ = narrow.route(wn, v, ids)
     counted = []
     got = narrow.apply(wn, v, ids, gates, counted)
     assert np.array_equal(np.asarray(got, np.float32), np.asarray(
@@ -450,7 +450,7 @@ def test_the_kernel_takes_the_cells_shapes_and_the_jnp_form_the_rest(
     assert [int(c) for c in counted] == [narrow.rows_computed(256)]
     counted = []
     v = jax.random.normal(jax.random.key(6), (256, 256)).astype(jnp.bfloat16)
-    _, gates = op.route(w, v, ids)
+    _, gates, _ = op.route(w, v, ids)
     op.apply(w, v, ids, gates, counted)
     assert [int(c) for c in counted] == [6 * 128]
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
@@ -600,12 +600,12 @@ def test_the_choice_is_by_biased_scores_and_the_weights_by_plain(toy):
     u = jax.random.normal(jax.random.key(2), (6, 32))
     s = np.asarray(jax.nn.sigmoid(u @ w["router"]))
     plain = np.sort(np.argsort(-s, -1)[:, :4], -1)
-    ids0, _ = op.route(dict(w, bias=jnp.zeros(16)), u)
+    ids0, _, _ = op.route(dict(w, bias=jnp.zeros(16)), u)
     assert np.array_equal(np.sort(np.asarray(ids0), -1), plain)
     lowest = np.argsort(s.mean(0))[:4]
     bias = np.zeros(16, np.float32)
     bias[lowest] = 2.0
-    ids, gates = op.route(dict(w, bias=jnp.asarray(bias)), u)
+    ids, gates, _ = op.route(dict(w, bias=jnp.asarray(bias)), u)
     assert np.array_equal(np.sort(np.asarray(ids), -1),
                           np.tile(np.sort(lowest), (6, 1)))
     assert not np.array_equal(np.sort(np.asarray(ids), -1), plain)
@@ -617,7 +617,7 @@ def test_the_choice_is_by_biased_scores_and_the_weights_by_plain(toy):
     # the reference norms its input: hand it what norms to ``u``
     _, own, choice = _pieces(TOY)["scores_of"](u[None] * 1.0, lw)
     un = reference._rms(u, jnp.ones(32), 1e-5)
-    ids_n, _ = op.route(dict(w, bias=jnp.asarray(bias)), un)
+    ids_n, _, _ = op.route(dict(w, bias=jnp.asarray(bias)), un)
     assert np.array_equal(np.sort(np.asarray(own), -1),
                           np.sort(np.asarray(ids_n), -1))
 
@@ -652,7 +652,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         op = _op(ff, "block0_mixer")
         w = {k: share[v].astype(jnp.float32)
              for k, v in family._EXPERTS.items()}
-        ids_p, gates_p = op.route(w, un)
+        ids_p, gates_p, _ = op.route(w, un)
         assert np.array_equal(np.sort(np.asarray(ids_p), -1),
                               np.sort(np.asarray(ids), -1))
         program_parts.append(np.asarray(op.apply(w, un, ids_p, gates_p)))

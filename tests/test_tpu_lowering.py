@@ -1173,3 +1173,137 @@ def test_granite_chunk_programs_carry_the_states_under_their_scopes(
     assert has_head == (name == "chunk_head")
     assert mem.alias_size_in_bytes >= granite_programs[1].pool.memory_bytes()
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+# ---- ZAYA1: a pair a token and a tail a request in one layer, an MLP router ----
+
+@pytest.fixture(scope="module")
+def zaya_programs(one_chip):
+    """The decode step and both chunk programs of three layers at
+    ZAYA1-8B's published widths and its cell's sizes (48 slots, contexts
+    to 4,608, blocks of 64, chunks of 2,048, all 16 experts, the whole
+    vocabulary), compiled for the described chip: {name: (the compiled
+    text, its memory analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import zaya as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "zaya1-8b-pp2.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=3, layer_types=["hybrid"] * 3)
+    slots, max_length, chunk = 48, 4608, 2048
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=64, kv_dtype="bfloat16",
+                               calibrate=False, prefill_chunk=chunk)
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, head in (("chunk", False), ("chunk_head", True)):
+                compiled = jax.jit(
+                    lambda *a, head=head: dec._chunk_step(*a, head=head),
+                    donate_argnums=(2,)).lower(
+                    params, ints(1, chunk), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1),
+                    ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+def test_zaya_decode_step_reads_the_pair_by_the_kernel(zaya_programs):
+    """The decode step of three CCA and top-1 expert layers holds no
+    ``while`` and no ``conditional``; its Mosaic calls are the paged
+    kernel, one a layer (8 query heads on 2 key-value heads of 128:
+    ``attention_path`` ``kernel``); 48 slots of one pick over 16 name
+    nearly every expert, so the experts run in the dense form
+    (``expert_form``); the only scatters are the new token's keys and
+    values (the tails and half values go back through ``_spread_rows``);
+    the pool, pairs and rows, aliases its outputs."""
+    programs, dec = zaya_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path == {"decode": "kernel", "chunk": "kernel"}
+    assert " while(" not in text and " conditional(" not in text
+    for ln in text.splitlines():
+        if " scatter(" in ln:
+            assert re.search(
+                r"ff\.COMPRESSED_CONV_ATTENTION\.block\d_attn/write", ln), ln
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 3
+    assert all("paged_attention_decode" in c for c in calls)
+    shapes = {name: [a.shape for a in entry]
+              for name, entry in dec.pool.kv.items()}
+    assert shapes["block0_attn"] == shapes["block2_attn"] == [
+        (48 * 72 + 1, 64, 256)] * 2 + [(49, 2 * 1280), (49, 128)]
+    assert dec.pool.memory_bytes() == 3 * (
+        (48 * 72 + 1) * 64 * 1024 + 49 * 5376)
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head"])
+def test_zaya_chunk_programs_name_their_pieces(zaya_programs, name):
+    """A chunk of 2,048 queries behind up to 4,608 keys: each layer that
+    attends is ONE Mosaic call, ``chunk_attention``, no ``while``, and
+    the pieces are named: ``project``, ``mix``, ``write``, ``attend``,
+    ``out`` in the attention op, ``route`` and ``experts`` in the expert
+    op; the head is computed in the head's chunk alone."""
+    from flexflow_tpu.core.op import parse_scope
+
+    text, mem = zaya_programs[0][name]
+    owners = {parse_scope(m) for m in re.findall(r'op_name="([^"]+)"', text)
+              } - {None}
+    subs = {(kind, s) for kind, _, ss, _ in owners for s in ss}
+    cca = "COMPRESSED_CONV_ATTENTION"
+    assert {(cca, "project"), (cca, "mix"), (cca, "write"), (cca, "attend"),
+            (cca, "out"), ("ROUTED_EXPERTS", "route"),
+            ("ROUTED_EXPERTS", "experts")} <= subs, subs
+    mosaic = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    attends = [ln for ln in mosaic if "chunk_attention" in ln]
+    # (a chunk that is not its prompt's last ends behind the last
+    # attention op's write: nothing reads what that op would attend)
+    assert len(attends) == (3 if name == "chunk_head" else 2)
+    assert " while(" not in text
+    has_head = any("ff.LINEAR.lm_head" in ln for ln in _buffers(text))
+    assert has_head == (name == "chunk_head")
+    assert mem.alias_size_in_bytes >= zaya_programs[1].pool.memory_bytes()
+    assert mem.temp_size_in_bytes < 2 << 30

@@ -382,7 +382,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
              "w_gate": share["experts.gate"], "w_up": share["experts.up"],
              "w_down": share["experts.down"]}
         w = {k: v.astype(jnp.float32) for k, v in w.items()}
-        ids_p, gates_p = op.route(w, m)
+        ids_p, gates_p, _ = op.route(w, m)
         assert np.array_equal(np.sort(np.asarray(ids_p), -1),
                               np.sort(np.asarray(ids), -1))
         program_parts.append(np.asarray(op.apply(w, m, ids_p, gates_p)))
@@ -647,7 +647,7 @@ def test_a_call_of_few_rows_through_the_kernel_is_the_dense_forms(
     v = jax.random.normal(jax.random.key(rows), (rows, 256)
                           ).astype(jnp.bfloat16)
     ids = _few_rows_routing(routing, rows)
-    _, gates = op.route(w, v, jnp.maximum(ids, 0))
+    _, gates, _ = op.route(w, v, jnp.maximum(ids, 0))
     assert op.expert_form(rows) == "kernel"
     counted = []
     got = np.asarray(op.apply(w, v, ids, gates, counted), np.float32)
@@ -678,7 +678,7 @@ def test_one_row_is_padded_to_a_tile_and_a_nan_stays_in_its_row(monkeypatch):
     op, w = _held_experts()
     v = jax.random.normal(jax.random.key(1), (16, 256)).astype(jnp.bfloat16)
     ids = _few_rows_routing("spread", 16)
-    _, gates = op.route(w, v, ids)
+    _, gates, _ = op.route(w, v, ids)
     assert op.expert_form(1) == "kernel"
     counted = []
     one = op.apply(w, v[3:4], ids[3:4], gates[3:4], counted)
@@ -710,7 +710,7 @@ def test_gradients_of_a_call_of_few_rows_are_the_dense_forms(monkeypatch):
         # (the router's own choice at random weights names few of the 32
         # held: the routing is given, the weights are the router's)
         x2d = x.reshape(-1, x.shape[-1])
-        _, gates = op.route(w, x2d, ids)
+        _, gates, _ = op.route(w, x2d, ids)
         y = op.apply(w, x2d, ids, gates, mesh=ctx.mesh)
         return jnp.sum(jnp.square(y.astype(jnp.float32)))
 
