@@ -394,19 +394,23 @@ def check_moe_kernels(moe, mosaic: bool) -> Dict[str, float]:
 
 def check_paged_attention(slots: int, heads: int, head_dim: int,
                           block_size: int, max_blocks: int, dtype: str,
-                          mosaic: bool, window: int = 1) -> float:
+                          mosaic: bool, window: int = 1,
+                          kv_heads: Optional[int] = None) -> float:
     """The paged-attention decode kernel against the gather-and-softmax
     it replaces, in float32 at ``highest`` precision over the same
     arenas: slots of ragged lengths (one inactive, one full), tables
-    over shuffled blocks, garbage in the null block. Returns the largest
-    error as a share of the reference's largest magnitude."""
+    over shuffled blocks, garbage in the null block; ``heads`` query
+    heads on ``kv_heads`` key-value heads (None: a key head a query
+    head). Returns the largest error as a share of the reference's
+    largest magnitude."""
     import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.paged_attention import (paged_attention_decode,
                                                       supported)
 
-    hd = heads * head_dim
+    kv_heads = kv_heads or heads
+    hd = kv_heads * head_dim
     nb = slots * max_blocks + 1
     q_shape = (slots, window, heads, head_dim)
     _require(supported(q_shape, (nb, block_size, hd), dtype, max_blocks),
@@ -430,13 +434,16 @@ def check_paged_attention(slots: int, heads: int, head_dim: int,
     got = np.asarray(got_fn(q, k, v, tables, lens), np.float32)
 
     def reference(q, k, v):
-        kk, vv = (a[tables].reshape(slots, length, heads, head_dim)
+        # a group of query heads g reads its key-value head k
+        kk, vv = (a[tables].reshape(slots, length, kv_heads, head_dim)
                   for a in (k, v))
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * head_dim ** -0.5
+        qg = q.reshape(slots, window, kv_heads, heads // kv_heads, head_dim)
+        s = jnp.einsum("bqkgd,blkd->bkgql", qg, kk) * head_dim ** -0.5
         pos = lens[:, None] + jnp.arange(window)[None, :]
         seen = jnp.arange(length)[None, None, :] <= pos[:, :, None]
-        p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+        p = jax.nn.softmax(jnp.where(seen[:, None, None], s, -jnp.inf),
+                           axis=-1)
+        return jnp.einsum("bkgql,blkd->bqkgd", p, vv).reshape(q.shape)
 
     with jax.default_matmul_precision("highest"):
         want = np.asarray(jax.jit(reference)(
@@ -701,6 +708,15 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
             errs[f"paged_w{window}"] = "%.1e" % check_paged_attention(
                 4, sizes.heads, sizes.hidden // sizes.heads, 16,
                 sizes.max_length // 16, KV_DTYPE, mosaic, window)
+    # grouped heads at the chunk their rows' bytes ask: the chains cell's
+    # pool (8 on 2 of 128, 16 pages of 64 an iteration) and the retrieval
+    # cell's (32 on 8 of 64, 8 pages); 3 slots of 6 blocks under the
+    # interpreter
+    for name, heads, kv_heads, head_dim, table in (
+            ("chains", 8, 2, 128, 72), ("retrieval", 32, 8, 64, 144)):
+        errs[f"paged_{name}"] = "%.1e" % check_paged_attention(
+            48 if mosaic else 3, heads, head_dim, 64, table if mosaic else 6,
+            KV_DTYPE, mosaic, kv_heads=kv_heads)
     # a latent cache's rows at the benchmark's widths (64 heads over rows
     # of 512 + 64, padded to 640 lanes), beside paged_attention_decode
     errs["latent"] = "%.1e" % check_latent_attention(

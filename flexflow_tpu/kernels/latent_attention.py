@@ -53,8 +53,8 @@ CHUNK_TOKENS = 512
 
 
 def _pages_per_chunk(block_size: int, max_blocks: int) -> int:
-    return paged_attention._pages_per_chunk(block_size, max_blocks,
-                                            CHUNK_TOKENS)
+    return paged_attention._pages_for_tokens(block_size, max_blocks,
+                                             CHUNK_TOKENS)
 
 
 def _vmem_bytes(heads: int, row: int, out_width: int, block_size: int,

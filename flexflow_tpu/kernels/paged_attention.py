@@ -17,7 +17,12 @@ from the arena where they lie:
   (one grid step) the kernel walks ``ceil((seq_len + W) / block_size)``
   blocks of its table and no more, ``pages_per_chunk`` blocks a loop
   iteration, with the next chunk's DMAs (the next slot's first chunk
-  after a slot's last) in flight behind the current chunk's math;
+  after a slot's last) in flight behind the current chunk's math. A
+  chunk follows the rows' BYTES (``_pages_per_chunk``): an iteration
+  costs a DMA's round trip whatever it brings, so narrow rows (two
+  key-value heads of 128: 512 B) get as many pages as keep a MiB of K
+  and V in flight, 1,024 tokens, and rows of 2 KB and wider the 256
+  tokens they always had;
 * all heads of a chunk are scored by ONE matrix product: the slot's
   query row is spread into a block-diagonal ``(H_pad, H*D)`` operand
   (row h keeps head h's 64 lanes, zeros elsewhere), so
@@ -63,10 +68,28 @@ from . import pallas_mode
 from .flash_attention import NEG_INF, VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES, _NT, _dot
 from .moe_kernels import SMEM_BUDGET_BYTES
 
-# tokens a loop iteration scores: a multiple of the 128 lanes the score
-# matrix has them on; 256 measured best of 128/256/512 on the v5e at the
-# benchmark's shapes (PERF.md section 6, PR 26)
+# What a loop iteration brings is what is in flight behind the math of the
+# iteration before, and an iteration costs a DMA's round trip whatever it
+# brings: 0.65 us on the v5e with the kernel alone, 1.1 us inside a decode
+# step, in which the HBM's 819 GB/s deliver 0.5 to 0.9 MB. So a chunk is
+# as many pages as bring K and V together to CHUNK_BYTES (PERF.md section
+# 6, PR 51: the kernel alone at five cells' shapes, the pages a chunk side
+# by side, tools/paged_chunk_sweep.py: rows of 512 B 157 us a layer at
+# 256 tokens, 113 at 1,024, 133 at 2,048; rows of 1 KB 571 at 256, 481 at
+# 512, 479 at 1,024) ...
+CHUNK_BYTES = 1 << 20
+# ... never fewer than this many tokens, a multiple of the 128 lanes the
+# score matrix has them on: rows of 2 KB and wider gain nothing past the
+# chunk that measured best of 128/256/512 at GPT-2 large's rows (PR 26;
+# PR 51's table: 1,068 us at 256 and at 512 tokens of 2 KB rows, 62 and
+# 64 us at 2.5 KB) ...
 CHUNK_TOKENS = 256
+# ... and never more than this many pages unless CHUNK_TOKENS need more:
+# the longest unrolled run of DMA starts and waits. Blocks of 16 tokens
+# reach it at 256 tokens, and rows of 512 B there are 8 KB DMAs, some 38 ns
+# each whatever the chunk (642 us a layer at 16 pages, 590 at 32, 624 at
+# 64, against 169 us of bytes): the chunk is not that shape's handle
+MAX_PAGES = 16
 # rows of the block-diagonal query operand: W windows of H_pad heads
 MAX_QUERY_ROWS = 256
 
@@ -79,13 +102,33 @@ def _sublanes(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize     # 8 for f32, 16 for bf16
 
 
-def _pages_per_chunk(block_size: int, max_blocks: int,
-                     chunk_tokens: int = CHUNK_TOKENS) -> int:
-    per = max(1, chunk_tokens // block_size)
-    # no chunk wider than a table: the smallest multiple of a lane tile
-    # of tokens that covers it
+def _pages_for_tokens(block_size: int, max_blocks: int,
+                      chunk_tokens: int) -> int:
+    """Pages of a chunk of about ``chunk_tokens``: whole lane tiles of
+    tokens, and no wider than the smallest such that covers a table."""
     lane_pages = max(1, 128 // block_size)
+    per = max(lane_pages, chunk_tokens // block_size // lane_pages
+              * lane_pages)
     return min(per, _round_up(max_blocks, lane_pages))
+
+
+def _pages_per_chunk(block_size: int, max_blocks: int,
+                     row_bytes: int) -> int:
+    """Pages a loop iteration brings of arenas whose rows (one token's K,
+    or its V) are ``row_bytes`` wide: see ``CHUNK_BYTES``."""
+    wanted = CHUNK_BYTES // (2 * row_bytes * block_size)
+    least = max(1, CHUNK_TOKENS // block_size)
+    return _pages_for_tokens(block_size, max_blocks,
+                             block_size * max(least, min(wanted, MAX_PAGES)))
+
+
+def chunk_tokens(arena_shape, arena_dtype, max_blocks: int) -> int:
+    """Tokens a loop iteration scores over arenas of ``arena_shape``
+    (num_blocks, block_size, Hkv*D): what a decoder's ``attention_path``
+    says of its decode kernel."""
+    _, block_size, hd = arena_shape
+    return block_size * _pages_per_chunk(
+        block_size, max_blocks, hd * jnp.dtype(arena_dtype).itemsize)
 
 
 def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
@@ -98,8 +141,8 @@ def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
     operand and its mask."""
     hd = (kv_heads or heads) * head_dim
     m = window * _round_up(heads, 16)
-    chunk = _pages_per_chunk(block_size, max_blocks) * block_size
     item = jnp.dtype(dtype).itemsize
+    chunk = _pages_per_chunk(block_size, max_blocks, hd * item) * block_size
     return (2 * 2 * 4 * rows * heads * head_dim  # q, o
             + 2 * 2 * chunk * hd * item        # K, V chunks, two buffers
             + 4 * m * hd * 3                   # acc, q_bd, its f32 source
@@ -333,7 +376,8 @@ def paged_attention_decode(q, k_arena, v_arena, tables, seq_lens,
     block_size = k_arena.shape[1]
     scale = float(scale) if scale is not None else head_dim ** -0.5
     pages = (int(pages_per_chunk) if pages_per_chunk
-             else _pages_per_chunk(block_size, tables.shape[1]))
+             else _pages_per_chunk(block_size, tables.shape[1],
+                                   k_arena.shape[2] * k_arena.dtype.itemsize))
     if (pages * block_size) % 128:
         raise ValueError(f"a chunk of {pages} blocks of {block_size} "
                          f"tokens is no multiple of 128 lanes")

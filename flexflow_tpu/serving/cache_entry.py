@@ -224,7 +224,9 @@ class EntryKind:
       ``jax.ShapeDtypeStruct`` each, of ``n`` blocks (of ``n`` rows, for a
       ``per_request`` kind);
     * ``reads_in_place(op, entry, slots, window, max_blocks)``: whether a
-      ``window``-token step reads ``entry`` by a kernel, in place;
+      ``window``-token step reads ``entry`` by a kernel, in place, and
+      ``decode_chunk_tokens(entry, max_blocks)``: the tokens one loop
+      iteration of that kernel brings (None: it walks no chunks);
     * ``step(op, weights, x, positions, entry, addr, seq_lens)``: W new
       tokens a slot at positions ``seq_lens .. seq_lens + W - 1``, their
       rows written through the tables (an idle slot's, and positions past
@@ -268,6 +270,9 @@ class EntryKind:
 
     def stats(self) -> Dict:
         return {"entry": self.name}
+
+    def decode_chunk_tokens(self, entry, max_blocks: int) -> Optional[int]:
+        return None
 
     def blocks_read(self, length: int) -> Optional[int]:
         """Blocks of a request's table a step behind ``length`` cached
@@ -437,6 +442,10 @@ class PairEntry(EntryKind):
         return paged_attention.supported(
             (slots, window, self.query_heads or self.heads, self.head_dim),
             entry[0].shape, entry[0].dtype, max_blocks)
+
+    def decode_chunk_tokens(self, entry, max_blocks):
+        return paged_attention.chunk_tokens(entry[0].shape, entry[0].dtype,
+                                            max_blocks)
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """A bucket's prompts whole (:meth:`whole`), their rows scattered
@@ -683,6 +692,10 @@ class WindowEntry(PairEntry):
             entry[0].shape, entry[0].dtype,
             self.ring_blocks(entry[0].shape[1]))
 
+    def decode_chunk_tokens(self, entry, max_blocks):
+        return super().decode_chunk_tokens(
+            entry, self.ring_blocks(entry[0].shape[1]))
+
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
         n = x.shape[0]                   # one token a slot: ``max_window``
@@ -927,6 +940,11 @@ class LatentEntry(EntryKind):
         return window == 1 and latent_attention.supported(
             (slots, op.num_heads, arena.shape[-1]), arena.shape,
             arena.dtype, max_blocks, op.kv_rank)
+
+    def decode_chunk_tokens(self, entry, max_blocks):
+        block_size = entry[0].shape[1]
+        return block_size * latent_attention._pages_per_chunk(block_size,
+                                                              max_blocks)
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         """The absorbed form: per head the query over a row's lanes is

@@ -622,9 +622,12 @@ class PagedDecoder(_DecodeGraph):
         # "gather" (the jnp path); "verify" appears with its program.
         # "chunk": how a prompt's chunk is attended, "kernel" (every
         # kind's chunk through kernels/chunk_attention.py) or "scan" (a
-        # walk in jnp); None where prompts are prefilled whole
-        self.attention_path: Dict[str, Optional[str]] = {
-            "decode": self._attention_path(1), "chunk": self._chunk_path()}
+        # walk in jnp); None where prompts are prefilled whole.
+        # "decode_chunk_tokens": the tokens a loop iteration of the decode
+        # kernel brings (the narrowest of the ops' kernels; None where
+        # the step gathers)
+        self.attention_path: Dict[str, Union[None, str, int]] = {}
+        self._set_attention_path()
         self._prefill_fns: Dict[Tuple[int, int], object] = {}
         # how the prefill programs run the recurrence of the ops that
         # keep a state, fixed when a program is built, from the rule its
@@ -937,6 +940,17 @@ class PagedDecoder(_DecodeGraph):
                 op, self.pool.kv[op.name], self.decode_slots, window,
                 self.max_blocks_per_request)
             for op in self._attn_ops) else "gather"
+
+    def _set_attention_path(self) -> None:
+        """The decode and chunk programs' entries of ``attention_path``,
+        from the pool as it is now."""
+        decode = self._attention_path(1)
+        chunks = [self.pool.kinds[op.name].decode_chunk_tokens(
+            self.pool.kv[op.name], self.max_blocks_per_request)
+            for op in self._attn_ops] if decode == "kernel" else []
+        self.attention_path.update(
+            decode=decode, chunk=self._chunk_path(),
+            decode_chunk_tokens=min((c for c in chunks if c), default=None))
 
     def _chunk_path(self) -> Optional[str]:
         """What a chunk program does with the pool as it is now."""
@@ -1299,8 +1313,7 @@ class PagedDecoder(_DecodeGraph):
               file=sys.stderr)
         self.kv_dtype = "float32"
         self.pool = self._new_pool(self.pool.num_blocks)  # concurrency: race-ok (calibration runs inside __init__, before the scheduler's thread or any stats() reader exists)
-        self.attention_path["decode"] = self._attention_path(1)
-        self.attention_path["chunk"] = self._chunk_path()
+        self._set_attention_path()
 
 
 def build_draft_model(ff, spec: str):

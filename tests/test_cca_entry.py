@@ -101,7 +101,8 @@ def test_chunked_prefill_and_decode_equal_the_references_forward(
     summation order."""
     ff, weights = toy
     dec = _decoder(ff, prefill_chunk=chunk)
-    assert dec.attention_path == {"decode": "gather", "chunk": "scan"}
+    assert dec.attention_path == {"decode": "gather", "chunk": "scan",
+                                  "decode_chunk_tokens": None}
     rows, toks = _paged_run(dec, _prompt(n), steps, slot=1)
     want = _reference_rows(weights, toks, len(rows))
     assert np.abs(rows - want).max() <= 2e-4 * np.abs(want).max()

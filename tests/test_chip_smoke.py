@@ -37,6 +37,7 @@ def test_phases_run_to_completion_at_toy_size(monkeypatch, tmp_path):
         "kernels", "serve", "train[dp]", "train[searched]"]
     assert records[0]["interpret"] is True
     assert float(records[0]["gated_delta_chunks_o"]) < 1e-4
+    assert float(records[0]["paged_chains"]) <= chip_smoke.PAGED_RANGE_TOL
     # the small hybrid's prefills took the whole-sequence kernel
     assert records[1]["hybrid_prefill_path"] == "kernel"
     assert records[1]["hybrid_attention_path"] == "kernel"

@@ -280,7 +280,8 @@ def test_stats_kv_counts_the_share_of_its_tables_a_step_reads(gpt):
     sched.generate(prompt, 6)   # one slot active: 5 decode steps
     kv = sched.stats()["kv"]
     sched.stop()
-    assert kv["attention_path"] == {"decode": "gather", "chunk": None}
+    assert kv["attention_path"] == {"decode": "gather", "chunk": None,
+                                    "decode_chunk_tokens": None}
     # seq_len 5..9 plus the row the step writes: one block at 5..7 (6..8
     # tokens), two at 8..9 (9..10 tokens); a table spans 32 / 8 = 4
     assert kv["blocks_read"] == 3 * 1 + 2 * 2

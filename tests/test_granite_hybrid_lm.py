@@ -250,7 +250,8 @@ def test_chunked_prefill_and_decode_equal_the_references_forward(
     2e-4 of the logits' range: float32 summation order."""
     ff, weights = toy
     dec = _decoder(ff, prefill_chunk=chunk)
-    assert dec.attention_path == {"decode": "gather", "chunk": "scan"}
+    assert dec.attention_path == {"decode": "gather", "chunk": "scan",
+                                  "decode_chunk_tokens": None}
     prompt = np.random.default_rng(n).integers(
         0, TOY["vocab_size"], n).astype(np.int32)
     rows, toks = _paged_run(dec, prompt, steps, slot=1)
@@ -452,4 +453,5 @@ def test_the_zoo_preset_builds_and_serves():
         inst.stop()
     assert np.array_equal(got, want)
     assert kv["entry"] == {"ssm_state": 2, "pair": 1}
-    assert kv["attention_path"] == {"decode": "gather", "chunk": "scan"}
+    assert kv["attention_path"] == {"decode": "gather", "chunk": "scan",
+                                    "decode_chunk_tokens": None}

@@ -10,9 +10,9 @@ import pytest
 
 import jax.numpy as jnp
 
-from flexflow_tpu.kernels import paged_attention
+from flexflow_tpu.kernels import latent_attention, paged_attention
 from flexflow_tpu.ops.attention import MultiHeadAttention
-from flexflow_tpu.serving.cache_entry import PairEntry
+from flexflow_tpu.serving.cache_entry import PairEntry, _attend
 from flexflow_tpu.serving.kv_cache import NULL_BLOCK, Addresses
 
 HEAD_DIM, BLOCK, MAX_BLOCKS = 64, 16, 20
@@ -123,26 +123,149 @@ def test_kernel_matches_jnp_path(monkeypatch, heads, dtype, window):
                               np.asarray(b, np.float32))
 
 
-def test_kernel_reads_live_blocks_only(monkeypatch):
-    """A block past a slot's last live one is never fetched: NaN there
-    (which no mask could hide from a matrix product) changes nothing."""
+def _wide_case(dtype, seed=0):
+    """The chains cell's layout cut small: 8 query heads on 2 key-value
+    heads of 128 (rows of 256 lanes), blocks of 64, tables of three of
+    the chunks THE RULE gives this width and dtype, over shuffled blocks;
+    the null block and every row past a slot's own hold large finite
+    garbage. Lengths (the step's own row counted): a slot that ends in a
+    part-full chunk, an idle one between two live ones (the next slot's
+    first chunk is fetched behind it), one that spans exactly one chunk,
+    one exactly three, and one a row into its second."""
+    heads, kv_heads, head_dim, block = 8, 2, 128, 64
+    hd = kv_heads * head_dim
+    chunk = paged_attention.chunk_tokens((1, block, hd), dtype, 10 ** 6)
+    max_blocks = 3 * chunk // block
+    rng = np.random.default_rng(seed)
+    lens = np.array([chunk + chunk // 3, 0, chunk - 1, 3 * chunk - 1,
+                     chunk], np.int32)
+    n = lens.size
+    nb = n * max_blocks + 1
+    k = np.full((nb, block, hd), HUGE, np.float32)
+    v = np.full((nb, block, hd), -HUGE, np.float32)
+    tables = np.full((n, max_blocks), NULL_BLOCK, np.int32)
+    perm = rng.permutation(np.arange(1, nb)).reshape(n, max_blocks)
+    for i, length in enumerate(lens):
+        if length == 0:
+            continue
+        tables[i] = perm[i]
+        pos = np.arange(length + 1)        # cached, and the step's own
+        for arena in (k, v):
+            arena[tables[i, pos // block], pos % block] = rng.normal(
+                size=(pos.size, hd))
+    q = rng.normal(size=(n, 1, heads, head_dim)).astype(np.float32)
+    return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
+            jnp.asarray(v, dtype), jnp.asarray(tables), jnp.asarray(lens),
+            chunk)
+
+
+@pytest.mark.parametrize("dtype,chunk_tokens", [("float32", 512),
+                                                ("bfloat16", 1024)])
+def test_kernel_matches_jnp_path_at_two_wide_heads(monkeypatch, dtype,
+                                                   chunk_tokens):
+    """Rows of two key-value heads of 128 get the chunk their bytes ask
+    (a MiB of K and V: 1,024 tokens of bfloat16, 512 of float32), and
+    with it the kernel's output is the gather's within the dtype's
+    tolerance for slots that end part-way into a chunk, on a chunk's
+    last row, a row past it, and behind an idle slot; the garbage never
+    shows."""
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
-    heads = 16
-    x, (k, v), tables, lens = _case(heads, "float32", 1)
-    q = x.reshape(x.shape[0], 1, heads, HEAD_DIM)
-    # chunks of 128 tokens: the long slots walk two and three of them
+    q, k, v, tables, lens, chunk = _wide_case(dtype)
+    assert chunk == chunk_tokens
+    assert paged_attention.supported(q.shape, k.shape, k.dtype,
+                                     tables.shape[1])
+    got = np.asarray(paged_attention.paged_attention_decode(
+        q, k, v, tables, lens))
+    kk, vv = PairEntry(2, 128, 8).read((k, v), tables)
+    seen = jnp.arange(kk.shape[1])[None, None, :] <= lens[:, None, None]
+    want = np.asarray(_attend(q, kk, vv, lambda: seen[:, None], 128 ** -0.5),
+                      np.float32)
+    assert np.isfinite(got).all()
+    active = np.asarray(lens) > 0
+    # the gather path rounds its output to the arena's dtype
+    got = np.asarray(jnp.asarray(got, dtype), np.float32)
+    worst = float(np.abs(got[active] - want[active]).max())
+    bound = TOLERANCE[dtype] * float(np.abs(want[active]).max())
+    assert worst <= bound, f"off by {worst:.3e} > {bound:.3e}"
+    assert float(np.abs(got[active]).max()) < 10.0, "garbage leaked"
+
+
+@pytest.mark.parametrize("shape", ["gpt2", "two-wide-heads"])
+def test_kernel_reads_live_blocks_only(monkeypatch, shape):
+    """A block past a slot's last live one is never fetched: NaN there
+    (which no mask could hide from a matrix product) changes nothing; at
+    GPT-2's heads in chunks of 128 tokens, and at two key-value heads of
+    128 in the chunk the rule gives them, 512 tokens."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    if shape == "gpt2":
+        heads, block = 16, BLOCK
+        x, (k, v), tables, lens = _case(heads, "float32", 1)
+        q = x.reshape(x.shape[0], 1, heads, HEAD_DIM)
+        # chunks of 128 tokens: the long slots walk two and three of them
+        pages = 8
+    else:
+        q, k, v, tables, lens, _ = _wide_case("float32")
+        block, pages = 64, None
     clean = np.asarray(paged_attention.paged_attention_decode(
-        q, k, v, tables, lens, pages_per_chunk=8))
+        q, k, v, tables, lens, pages_per_chunk=pages))
     live = {NULL_BLOCK}
     for row, length in zip(np.asarray(tables), np.asarray(lens)):
-        live.update(row[:math.ceil((length + 1) / BLOCK)].tolist())
+        live.update(row[:math.ceil((length + 1) / block)].tolist())
     dead = np.array(sorted(set(range(k.shape[0])) - live))
     assert dead.size > 0
     k = k.at[dead].set(jnp.nan)
     v = v.at[dead].set(jnp.nan)
     dirty = np.asarray(paged_attention.paged_attention_decode(
-        q, k, v, tables, lens, pages_per_chunk=8))
+        q, k, v, tables, lens, pages_per_chunk=pages))
     assert np.array_equal(clean, dirty)
+
+
+# (slots, query heads, key-value heads, head width, block, table), the
+# arenas bfloat16: each serving cell's pool as its traffic file and
+# configuration make it, and the pages and tokens of a chunk there. The
+# first five are what every PR before 51 ran (256 tokens): a change to the
+# rule that moves one of them moves a cell the change was not made for.
+CELL_CHUNKS = {
+    "offline": ((16, 20, 20, 64, 16, 64), 16, 256),
+    "documents": ((32, 30, 30, 128, 16, 128), 16, 256),
+    "mixedlengths-ring": ((32, 48, 8, 128, 64, 64), 4, 256),
+    "mixedlengths-full": ((32, 48, 8, 128, 64, 272), 4, 256),
+    "agents": ((128, 32, 2, 128, 16, 128), 16, 256),
+    "retrieval": ((48, 32, 8, 64, 64, 144), 8, 512),
+    "chains": ((48, 8, 2, 128, 64, 72), 16, 1024),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_CHUNKS) + ["reasoning"])
+def test_chunk_follows_the_row_bytes(monkeypatch, cell):
+    """The pages and tokens a loop iteration brings at each serving
+    cell's shape: K and V together a MiB where 16 pages hold it, never
+    under 256 tokens; ``supported()`` takes every one, and reckons the
+    working set with the chunk the rule gives (both buffers of K and V
+    and the iteration's float32 temporaries). The latent kernel's chunk,
+    which borrows the helper, is the 512 tokens it was."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    if cell == "reasoning":
+        assert latent_attention._pages_per_chunk(16, 256) == 32
+        return
+    (slots, heads, kv_heads, head_dim, block, table), pages, tokens = \
+        CELL_CHUNKS[cell]
+    hd = kv_heads * head_dim
+    arena = (slots * table + 1, block, hd)
+    assert paged_attention._pages_per_chunk(block, table, 2 * hd) == pages
+    assert paged_attention.chunk_tokens(arena, "bfloat16", table) == tokens
+    assert pages * block == tokens and tokens % 128 == 0
+    # float32 rows are twice the bytes: half the tokens, from 256 up
+    assert paged_attention.chunk_tokens(arena, "float32", table) == max(
+        256, tokens // 2)
+    assert paged_attention.supported((slots, 1, heads, head_dim), arena,
+                                     "bfloat16", table)
+    m = -(-heads // 16) * 16
+    reckoned = paged_attention._vmem_bytes(
+        -(-slots // 8) * 8, 1, heads, head_dim, block, table, "bfloat16",
+        kv_heads)
+    assert (2 * 2 * tokens * hd * 2 + 4 * 3 * m * tokens <= reckoned
+            <= paged_attention.VMEM_BUDGET_BYTES)
 
 
 @pytest.mark.parametrize("why,heads,head_dim,block,dtype,window", [
@@ -185,7 +308,8 @@ def test_refused_entries_take_the_jnp_path(monkeypatch):
             dec = PagedDecoder(ff, max_length=32, decode_slots=2,
                                block_size=8, kv_dtype=kv_dtype,
                                kv_divergence_budget=10.0)
-            assert dec.attention_path == {"decode": "gather", "chunk": None}
+            assert dec.attention_path == {"decode": "gather", "chunk": None,
+                                          "decode_chunk_tokens": None}
             table = dec.pool.try_admit(8)
             dec.prefill(prompt, table)
             tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
@@ -218,7 +342,10 @@ def test_decoder_reports_the_kernel_path(monkeypatch):
     for mode, path in (("interpret", "kernel"), ("off", "gather")):
         monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
         dec = PagedDecoder(ff, max_length=64, decode_slots=2, block_size=16)
-        assert dec.attention_path == {"decode": path, "chunk": None}
+        # a table of 4 blocks of 16: a lane tile of tokens covers it
+        chunk = {"kernel": 128, "gather": None}[path]
+        assert dec.attention_path == {"decode": path, "chunk": None,
+                                      "decode_chunk_tokens": chunk}
         table = dec.pool.try_admit(40)
         dec.prefill(prompt, table)
         tables = np.zeros((2, dec.max_blocks_per_request), np.int32)
@@ -228,6 +355,7 @@ def test_decoder_reports_the_kernel_path(monkeypatch):
         window = dec.verify(np.array([[7, 9, 11], [0, 0, 0]], np.int32),
                             tables, lens)[0]
         assert dec.attention_path == {"decode": path, "chunk": None,
+                                      "decode_chunk_tokens": chunk,
                                       "verify": path}
         out[mode] = (step, window)
     for got, want in zip(out["interpret"], out["off"]):

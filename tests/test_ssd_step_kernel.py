@@ -132,7 +132,12 @@ def test_a_served_model_steps_its_states_by_the_kernel(monkeypatch, family):
             futures = [inst.generate_async(p, m, temperature=0.0)
                        for p, m in reqs]
             outs[mode] = [f.result(timeout=600) for f in futures]
-            assert inst.stats()["kv"]["attention_path"]["decode"] == decode
+            said = inst.stats()["kv"]["attention_path"]
+            assert said["decode"] == decode
+            # a table of 6 blocks of 16 is covered by a lane tile of
+            # tokens: the paged kernel's chunk, where it is the kernel
+            assert said["decode_chunk_tokens"] == (
+                128 if decode == "kernel" else None)
         finally:
             inst.stop()
         took = {p for p, v in before.items()

@@ -712,7 +712,8 @@ def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
 
     programs, dec = nemotron_programs
     text, mem = programs["decode"]
-    assert dec.attention_path == {"decode": "kernel", "chunk": None}
+    assert dec.attention_path == {"decode": "kernel", "chunk": None,
+                                  "decode_chunk_tokens": 256}
     assert " while(" not in text
     assert "dynamic-update-slice" not in text
     # the only scatter is the ``*`` layer's: a token's keys and values
@@ -870,7 +871,8 @@ def test_trinity_decode_step_holds_no_while(trinity_programs):
     counters carry the word the kernel's rows are counted in."""
     programs, dec = trinity_programs
     text, mem = programs["decode"]
-    assert dec.attention_path == {"decode": "kernel", "chunk": "kernel"}
+    assert dec.attention_path == {"decode": "kernel", "chunk": "kernel",
+                                  "decode_chunk_tokens": 256}
     assert " while(" not in text and " conditional(" not in text
     for ln in text.splitlines():
         if " scatter(" in ln:
@@ -1058,7 +1060,8 @@ def test_granite_decode_step_holds_no_while_and_moves_no_state(
 
     programs, dec = granite_programs
     text, mem = programs["decode"]
-    assert dec.attention_path == {"decode": "kernel", "chunk": "scan"}
+    assert dec.attention_path == {"decode": "kernel", "chunk": "scan",
+                                  "decode_chunk_tokens": 512}
     assert " while(" not in text and " conditional(" not in text
     assert "dynamic-update-slice" not in text
     calls = [ln for ln in text.splitlines()
@@ -1260,7 +1263,8 @@ def test_zaya_decode_step_reads_the_pair_by_the_kernel(zaya_programs):
     the pool, pairs and rows, aliases its outputs."""
     programs, dec = zaya_programs
     text, mem = programs["decode"]
-    assert dec.attention_path == {"decode": "kernel", "chunk": "kernel"}
+    assert dec.attention_path == {"decode": "kernel", "chunk": "kernel",
+                                  "decode_chunk_tokens": 1024}
     assert " while(" not in text and " conditional(" not in text
     for ln in text.splitlines():
         if " scatter(" in ln:

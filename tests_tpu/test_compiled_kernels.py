@@ -50,6 +50,20 @@ def test_paged_attention_compiled(heads, window):
                                      mosaic=True, window=window)
 
 
+@pytest.mark.parametrize("heads,kv_heads,head_dim,max_blocks",
+                         [(8, 2, 128, 72), (32, 8, 64, 144)],
+                         ids=["chains", "retrieval"])
+def test_paged_attention_grouped_compiled(heads, kv_heads, head_dim,
+                                          max_blocks):
+    """Grouped heads at the chunk their rows' bytes ask: the chains
+    cell's pool (48 slots, 8 query heads on 2 key-value heads of 128,
+    tables of 72 blocks of 64 tokens, bfloat16: 16 pages an iteration)
+    and the retrieval cell's (32 on 8 of 64, tables of 144: 8 pages)."""
+    chip_smoke.check_paged_attention(48, heads, head_dim, 64, max_blocks,
+                                     "bfloat16", mosaic=True,
+                                     kv_heads=kv_heads)
+
+
 @pytest.mark.parametrize("slots,max_blocks", [(128, 256), (4, 64)])
 def test_latent_attention_compiled(slots, max_blocks):
     """The reasoning cell's pool (128 slots of 256 blocks of 16 tokens,
