@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -41,6 +42,8 @@ from ..core.op import (LowerCtx, Op, create_op, fixed_scope, op_scope,
                        weights_of)
 from ..core.parallel_tensor import ParallelDim, ParallelTensorShape
 from ..core.tensor import Tensor
+from ..obs.metrics import metrics_registry
+from ..obs.trace import span
 from .loss import compute_loss, sparse_ce_from_logits
 from .metrics import compute_batch_metrics
 from .initializer import DeclaredInitializer
@@ -442,7 +445,12 @@ def compile_model(
         pshapes[label_tensor.tensor_id] = lab_ps
         label_sharding = _named_sharding(mesh, lab_ps)
 
-    params, param_shardings, wd_mask = init_params(ops, mesh, config.seed)
+    _t0_init = time.perf_counter()
+    with span("compile.init_params", cat="compile"):
+        params, param_shardings, wd_mask = init_params(
+            ops, mesh, config.seed)
+    metrics_registry().counter("setup.init_params_s").inc(
+        time.perf_counter() - _t0_init)
     opt_state = optimizer.init_state(params) if optimizer is not None else None
 
     # ---- ZeRO-1: shard optimizer state over the data axis -----------------

@@ -1051,6 +1051,10 @@ class FFModel:
         # on, like the tracer; a bad port value raises here)
         _cfg_obs_server(self.config)
         _t0_compile = time.perf_counter()
+        # the whole of compile, entered and left by hand as fit.step is
+        # (the body is long and an exception ends the compile)
+        _compile_span = span("compile", cat="compile")
+        _compile_span.__enter__()
         if optimizer is not None:
             self.optimizer = optimizer
         elif self.optimizer is None:
@@ -1186,6 +1190,7 @@ class FFModel:
                     f"idle", severity="warning")
                 if vmode == "warn":
                     print(f"[pcg] {f.format()}", flush=True)
+        _t0_lower = time.perf_counter()
         with span("compile.lower", cat="compile",
                   n_layers=len(compile_layers)):
             try:
@@ -1220,6 +1225,8 @@ class FFModel:
                         print(f"[pcg] {f.format()}", file=sys.stderr,
                               flush=True)
                 raise
+        metrics_registry().counter("setup.lower_s").inc(
+            time.perf_counter() - _t0_lower)
         self.pipelined = None
         if pipeline is not None:
             from ..parallel.pipeline import make_pipelined_model
@@ -1293,7 +1300,7 @@ class FFModel:
                 len(self.audit_report.errors))
             reg.counter("audit.warnings").inc(
                 len(self.audit_report.warnings))
-            reg.histogram("audit.wall_time_s").observe(_dt_audit)
+            reg.counter("setup.audit_s").inc(_dt_audit)
             self.audit_report.handle(amode)
         # --- executable telemetry (obs/exec_telemetry.py): what XLA
         # itself reports about each compiled step program — flops, bytes
@@ -1336,11 +1343,10 @@ class FFModel:
         # silent-skip regression (the except-all guard) fails loudly
         self._playoff_record = None
         _dt_compile = time.perf_counter() - _t0_compile
-        tracer().complete(
-            "compile", _t0_compile, _dt_compile,
-            cat="compile",
-            args={"n_ops": len(self.compiled.ops),
-                  "pipelined": self.pipelined is not None})
+        _compile_span.set(n_ops=len(self.compiled.ops),
+                          pipelined=self.pipelined is not None)
+        _compile_span.__exit__(None, None, None)
+        metrics_registry().counter("setup.model_compile_s").inc(_dt_compile)
         # durable telemetry: one ledger record per compile — machine
         # fingerprint, knobs, search/cache outcome, audit summary, exec
         # telemetry (obs/ledger.py; config.ledger="off" disables)

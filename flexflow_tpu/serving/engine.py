@@ -49,7 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs.metrics import metrics_registry
-from ..obs.trace import VIRTUAL_TID_BASE, tracer
+from ..obs.trace import VIRTUAL_TID_BASE, span, tracer
 from ..obs.watchdog import watch as _wd_watch
 from ..runtime.faults import InjectedFault, TransientFault
 from ..runtime.faults import fire as _fault_fire
@@ -252,6 +252,10 @@ class GenerationInstance:
     def __init__(self, ff, name: str = "lm", **scheduler_kw):
         if ff.compiled is None:
             raise ValueError("compile() the FFModel before serving it")
+        # the whole construction, entered and left by hand
+        _t0_build = time.perf_counter()
+        _build_span = span("serving.build", cat="serving", instance=name)
+        _build_span.__enter__()
         from ..obs.server import configure_obs_server
         from ..obs.watchdog import configure_watchdog
         from ..runtime.faults import configure_faults
@@ -304,6 +308,9 @@ class GenerationInstance:
         self._ff = ff
         self.scheduler = ContinuousBatchingScheduler(ff, name=name,
                                                      **defaults)
+        _build_span.__exit__(None, None, None)
+        metrics_registry().counter("setup.instance_build_s").inc(
+            time.perf_counter() - _t0_build)
 
     @property
     def decoder(self):

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 import weakref
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -53,6 +54,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..ffconst import OpType
 from ..core.op import LowerCtx, fixed_scope, op_scope, weights_of
+from ..obs.metrics import metrics_registry
 from ..obs.trace import span
 from .cache_entry import kind_for
 from .kv_cache import NULL_BLOCK, Addresses, PagedKVPool
@@ -650,7 +652,12 @@ class PagedDecoder(_DecodeGraph):
         self.kv_quant_report = None
         self._maybe_audit()
         if self.kv_dtype != "float32" and calibrate:
-            self._calibrate_kv_quant(kv_divergence_budget)
+            _t0_calibrate = time.perf_counter()
+            with span("serving.build.calibrate", cat="serving",
+                      kv_dtype=self.kv_dtype):
+                self._calibrate_kv_quant(kv_divergence_budget)
+            metrics_registry().counter("setup.calibration_s").inc(
+                time.perf_counter() - _t0_calibrate)
 
     # ---- compiled programs -------------------------------------------------
     def _decode_step(self, params, tokens, pool, addr, seq_lens,

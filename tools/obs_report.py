@@ -30,6 +30,13 @@ Exercises the full observability surface end to end — the CI smoke for
      "watchdog": {"enabled": true, "sources_seen": [...], "dumps": 0},
      "exit": 0}
 
+``metrics`` holds, among the rest, how set-up went (always on,
+``utils/compile_cache.py``): the compiler's sums ``jax.compiles``,
+``jax.compile_s``, ``jax.cache_hits``, ``jax.cache_misses``,
+``jax.trace_s``, ``jax.mlir_s``, ``jax.cache_read_s`` and the phases
+``setup.model_compile_s``, ``setup.lower_s``, ``setup.init_params_s``,
+``setup.audit_s``, ``setup.instance_build_s``, ``setup.calibration_s``.
+
 Exit status 1 when the trace fails validation, the divergence block is
 missing, the attribution phase table is absent or fails to reconcile
 with the measured step time, the serving/fit counters did not populate,
