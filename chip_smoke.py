@@ -636,6 +636,82 @@ def check_ssd_step(slots: int, heads: int, head_dim: int, state: int,
     return err
 
 
+# Both arms of a counted call multiply bfloat16 rows by bfloat16 matrices
+# with float32 sums and round the activation to bfloat16 before the down
+# product: against the same product in float32 each carries those roundings
+# (2^-9 of a term), as a share of the largest output some 3e-3 measured.
+COUNTED_EXPERTS_RANGE_TOL = 2e-2
+
+
+def check_counted_experts(rows: int, count: int, width: int, named: int,
+                          mosaic: bool) -> Dict[str, float]:
+    """A decode step's held experts where the shapes say dense and the
+    step counts (``RoutedExperts._apply_counted``): ``rows`` tokens of one
+    pick over ``count`` gated experts of ``width`` x ``width``, all held,
+    bfloat16. A routing that names ``named`` of them (under the op's
+    ``kernel_limit``) must take the kernel and say so, one that names all
+    must take the dense form, and each arm's sum is compared with the
+    same product in float32 at ``highest`` precision. The one program
+    holds one conditional, with the Mosaic call in one arm. Returns each
+    arm's largest error as a share of its reference's largest
+    magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import DataType, OpType
+    from flexflow_tpu.kernels.grouped_experts import tile_rows
+    from flexflow_tpu.ops.moe_ops import RoutedExperts
+
+    op = RoutedExperts(
+        Layer(OpType.ROUTED_EXPERTS, "experts", attrs=dict(
+            n_routed=count, experts_per_token=1, width=width,
+            scoring="softmax", norm_topk=False)),
+        [ParallelTensorShape.unpartitioned((1, rows, width),
+                                           DataType.BFLOAT16)])
+    _require(op.expert_form(rows, active=True) == "counted",
+             f"{rows} rows of one pick over {count} experts of {width} are "
+             f"{op.expert_form(rows, active=True)!r}, not counted")
+    _require(named <= op.kernel_limit() < count,
+             f"{named} named is past the limit {op.kernel_limit()}")
+    key = jax.random.key(0)
+    w = {ws.name: (0.05 * jax.random.normal(
+        jax.random.fold_in(key, i), ws.shape)).astype(jnp.bfloat16)
+        for i, ws in enumerate(op.weight_specs())}
+    v = jax.random.normal(jax.random.fold_in(key, 99), (rows, width)
+                          ).astype(jnp.bfloat16)
+    got_fn = jax.jit(op._apply_counted)
+    f32 = {k: a.astype(jnp.float32) for k, a in w.items()}
+    errs = {}
+    for arm, n in (("kernel", named), ("dense", count)):
+        ids = jnp.asarray((np.arange(rows) % n)[:, None], jnp.int32)
+        _, gates, _ = op.route(w, v, ids)
+        if mosaic and arm == "kernel":
+            text = got_fn.lower(w, v, ids, gates).compile().as_text()
+            _require(text.count(" conditional(") == 1,
+                     "a counted call holds one conditional")
+            _require(text.count("tpu_custom_call") >= 1
+                     and "grouped_experts" in text,
+                     "kernel did not lower to a Mosaic tpu_custom_call")
+        y, computed, took = got_fn(w, v, ids, gates)
+        _require(int(took) == (arm == "kernel"),
+                 f"{n} of {count} named took the wrong arm ({int(took)})")
+        _require(int(computed) == (n * tile_rows(rows) if arm == "kernel"
+                                   else count * rows),
+                 f"{arm}: {int(computed)} rows computed")
+        with jax.default_matmul_precision("highest"):
+            ref = np.asarray(op._apply_dense(f32, v.astype(jnp.float32),
+                                             ids, gates))
+        err = float(np.abs(np.asarray(y, np.float32) - ref).max()
+                    / np.abs(ref).max())
+        _require(err <= COUNTED_EXPERTS_RANGE_TOL,
+                 f"counted experts, {arm} arm: max error {err:.2e} of range "
+                 f"> {COUNTED_EXPERTS_RANGE_TOL}")
+        errs[arm] = err
+    return errs
+
+
 # The whole-sequence kernel's products are float32 at float32 contract
 # precision, as its jnp form's are at `highest`; they differ by the order
 # of sums and by how each inverts a chunk's unit-lower system (doubling
@@ -738,6 +814,11 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
                         ("nemotron", (128, 128, 64, 128, 8) if mosaic
                          else (4, 8, 32, 16, 2))):
         errs[f"ssd_step_{name}"] = "%.1e" % check_ssd_step(*shape, mosaic)
+    # a decode step's held experts at the chains cell's share (48 slots of
+    # one pick over 16 experts of 2,048 x 2,048, 6 named), both arms of
+    # the counted call; 256-wide experts under the interpreter
+    e = check_counted_experts(48, 16, 2048 if mosaic else 256, 6, mosaic)
+    errs.update({f"counted_experts_{k}": f"{v:.1e}" for k, v in e.items()})
     return ph.report(interpret=not mosaic, flash_shape=shape,
                      moe_shape=sizes.moe, **errs)
 

@@ -546,13 +546,29 @@ RIDGE_ROWS = 240
 # a smaller expert pays more of its time a tile: at 0.87 (128 rows) the
 # kernel still leads by 4 % on an even routing and by 29 % on an uneven
 # one; at 0.996, a step of 128 slots of 8 picks over 192 or of 22 over
-# 512, the dense form stays
+# 512, the dense form stays. That is the cut a PROGRAM is made by, from
+# what its rows CAN name at an even routing; a decode step whose estimate
+# is past it is cut again on the device, a step at a time, by what its live
+# rows DO name, against the same share of the count held
+# (``RoutedExperts.kernel_limit``). Measured there with the named count
+# forced, at four shapes (the same tool, PERF.md section 6, PR 54): a
+# named expert's matrices at 751-760 GB/s behind 0.04-0.08 ms a call, all
+# the held ones' in the dense form at 723-741 GB/s, which cross at 15.3 of
+# 16 experts of 2,048 x 2,048 (48 rows), 125.6 of 128 of 1,024 x 2,688
+# (128 rows), 11.7 of 12 of 7,168 x 2,048 (128 rows) and 32 of 32 of
+# 3,072 x 3,072 (32 rows): every one past 0.95, so 0.9 is on the dense
+# side of them all, and at 0.9 the kernel leads by 8 to 10 %
 NAMED_SHARE_KERNEL = 0.9
 # of the rows of a call, the share one held expert may be named by before
 # the grouped form gives the call to the dense one
 CAPACITY_SHARE = 4
 # the held experts' own matrices among a routed-experts op's weights
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+def _expert_matrices(weights):
+    return {name: weights[name] for name in EXPERT_MATRICES
+            if name in weights}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -802,12 +818,23 @@ class RoutedExperts(Op):
         over 256, 99.6 % for 128 of 8 over 192 or of 22 over 512."""
         return 1.0 - (1.0 - self.k / self.n_routed) ** rows
 
-    def expert_form(self, rows: int, dtype=None, mesh=None) -> str:
+    def kernel_limit(self) -> int:
+        """The most held experts a counted call may name and still be the
+        kernel's: ``NAMED_SHARE_KERNEL`` of those held, the share a
+        program is cut by applied to what a call does name: 14 of 16,
+        115 of 128, 10 of 12."""
+        return int(NAMED_SHARE_KERNEL * self.count)
+
+    def expert_form(self, rows: int, dtype=None, mesh=None,
+                    active: bool = False) -> str:
         """How :meth:`apply` multiplies ``rows`` tokens of ``dtype`` (the
         op's declared one where not given) in a program over ``mesh``:
         ``"dense"`` (every token through every held expert), ``"kernel"``
         (the pairs the routing names, in tiles the routing names:
-        ``kernels/grouped_experts.py``) or ``"grouped"`` (the same pairs
+        ``kernels/grouped_experts.py``), ``"counted"`` (one of those two
+        a CALL, chosen on the device by the count of held experts the
+        call's live rows name: :meth:`_apply_counted`) or ``"grouped"``
+        (the same pairs
         in jnp: an expert's rows side by side in a tile of
         :meth:`capacity` rows, what overflows a tile in a few spill
         tiles). A rule over what a trace sees, no knob. Up to
@@ -815,20 +842,29 @@ class RoutedExperts(Op):
         slots, the one row behind a head's cut): the kernel reads the
         matrices of the experts the routing names and the dense form
         those of all it holds, so the kernel where the call can name
-        under ``NAMED_SHARE_KERNEL`` of them (:meth:`named_share`), else
-        the dense form, and never the jnp grouped one. Past the ridge the
+        under ``NAMED_SHARE_KERNEL`` of them at an even routing
+        (:meth:`named_share`). Past that estimate the dense form, unless
+        the caller says which of its rows are live (``active``: a decode
+        step, whose idle slots name nothing and whose router may be
+        uneven, neither of which the estimate knows): such a call counts
+        what it names and is the kernel's up to :meth:`kernel_limit`
+        experts, the dense form's beyond. Never the jnp grouped form
+        under the ridge. Past the ridge the
         products decide (a prefill's bucket): the kernel, else the jnp
         grouped form. The kernel only where Pallas is on, the program is
         one device's (the kernel has no ``shard_map`` composition:
         ``kernels.use_pallas``) and its ``supported()`` takes the shapes
         (not the CPU, a mesh, float32 rows, widths of no whole lane
-        tiles, rows past its fast memory). That holds for a training
+        tiles, rows past its fast memory); where it is not, a call that
+        would be counted is dense. That holds for a training
         call too: ``fit`` reaches :meth:`apply` through :meth:`forward`,
         and few rows on one TPU device take ``kernel_form``, whose
         backward is the jnp grouped form's. PERF.md section 6, PRs 40,
-        41 and 43, has the forms measured."""
+        41, 43 and 54, has the forms measured."""
         few = rows <= RIDGE_ROWS
-        if few and self.named_share(rows) >= NAMED_SHARE_KERNEL:
+        estimate_dense = few and (
+            self.named_share(rows) >= NAMED_SHARE_KERNEL)
+        if estimate_dense and not active:
             return "dense"
         from ..kernels.grouped_experts import supported
 
@@ -837,7 +873,7 @@ class RoutedExperts(Op):
         if (mesh is None or mesh.size == 1) and supported(
                 rows, self.k, self.work_dim, self.width, self.count,
                 self.gated, dtype):
-            return "kernel"
+            return "counted" if estimate_dense else "kernel"
         return "dense" if few else "grouped"
 
     def capacity(self, rows: int) -> int:
@@ -857,17 +893,18 @@ class RoutedExperts(Op):
         the least)."""
         return max(1, self.count // 16)
 
-    def rows_computed(self, rows: int, dtype=None,
-                      mesh=None) -> Optional[int]:
+    def rows_computed(self, rows: int, dtype=None, mesh=None,
+                      active: bool = False) -> Optional[int]:
         """Rows the held experts' products run over for ``rows`` tokens
         by the form :meth:`expert_form` names, where the shapes say them:
         every token an expert in the dense form; a tile an expert and the
         spill tiles in the jnp grouped one (where they hold what
-        overflows). None for the kernel: its rows follow the routing and
+        overflows). None for the kernel and for a counted call: their
+        rows follow the routing and
         are counted on the device (:meth:`apply`'s ``computed``, which a
         prompt program and a decode step alike add to their counters)."""
-        form = self.expert_form(rows, dtype, mesh)
-        if form == "kernel":
+        form = self.expert_form(rows, dtype, mesh, active)
+        if form in ("kernel", "counted"):
             return None
         if form == "dense":
             return self.count * rows
@@ -886,7 +923,9 @@ class RoutedExperts(Op):
         ``jax.lax.ragged_dot`` read 3,683 tokens/s where this reads
         5,012). Where it names a few of them (32 slots of 4 picks over
         256: two in five) this form reads the rest for nothing, and
-        :meth:`expert_form` gives the call to the kernel (PR 43)."""
+        :meth:`expert_form` gives the call to the kernel: by what its
+        rows can name (PR 43) or, a decode step, by what its live rows
+        do (:meth:`_apply_counted`, PR 54)."""
         w = (self.held_hits(ids) * gates[..., None]).sum(1)     # (T, count)
         def up(name):
             return jnp.einsum("te,cef->ctf", v, weights[name],
@@ -990,15 +1029,39 @@ class RoutedExperts(Op):
         return jax.lax.cond(spill_ends[-1] <= spill, tiles,
                             lambda: self._apply_dense(weights, v, ids, gates))
 
-    def apply(self, weights, x2d, ids, gates, computed=None, mesh=None):
+    def _apply_counted(self, weights, v, ids, gates):
+        """One of two forms, chosen by the call itself: it counts the
+        held experts its pairs name (:meth:`held_hits`, any over the
+        pairs: a few thousand bools beside the matrices), and up to
+        :meth:`kernel_limit` of them the kernel reads those experts'
+        matrices alone, beyond it the dense form reads all. A
+        ``lax.cond`` over the same ``ids`` and ``gates``: a row's sum is
+        over the same pairs either way, to the rounding the two forms
+        differ by. Returns (the sum, the rows the products ran over, 1
+        where the kernel ran else 0), the last two () uint32."""
+        few = self.held_hits(ids).any((0, 1)).sum() <= self.kernel_limit()
+        matrices = _expert_matrices(weights)
+        y, rows = jax.lax.cond(
+            few, lambda: kernel_form(self, matrices, v, ids, gates),
+            lambda: (self._apply_dense(matrices, v, ids, gates),
+                     jnp.uint32(self.count * v.shape[0])))
+        return y, rows, few.astype(jnp.uint32)
+
+    def apply(self, weights, x2d, ids, gates, computed=None, mesh=None,
+              active=None):
         """The held experts' part of the layer for the routing given:
         (T, E) in the activations' dtype, by the form
         :meth:`expert_form` names for these rows in a program over
         ``mesh``; with ``latent``, between the projection down and the
-        projection up. With ``computed`` a list, the rows the products
+        projection up. ``active`` (T,) bool comes from a caller that
+        knows which rows are live (a decode step's slots): where the form
+        reads by the routing (the kernel, a counted call) a row that is
+        not names no expert, and what it gets back is read by no one.
+        With ``computed`` a list, the rows the products
         ran over are appended to it, () uint32: counted on the device by
         the kernel (real tiles x tile rows), :meth:`rows_computed` for
-        the jnp forms."""
+        the jnp forms; a counted call appends behind them 1 where it was
+        the kernel's and 0 where the dense form's."""
         v = x2d
         if self.latent:
             with sub_scope("latent"):
@@ -1006,17 +1069,21 @@ class RoutedExperts(Op):
                             preferred_element_type=jnp.float32
                             ).astype(x2d.dtype)
         with sub_scope("experts"):
-            form = self.expert_form(v.shape[0], v.dtype, mesh)
-            if form == "kernel":
-                y, rows = kernel_form(
-                    self, {name: weights[name] for name in EXPERT_MATRICES
-                           if name in weights}, v, ids, gates)
+            live = active is not None
+            form = self.expert_form(v.shape[0], v.dtype, mesh, live)
+            if live and form in ("kernel", "counted"):
+                ids = jnp.where(active[:, None], ids, self.first - 1)
+            if form == "counted":
+                y, *rows = self._apply_counted(weights, v, ids, gates)
+            elif form == "kernel":
+                y, *rows = kernel_form(self, _expert_matrices(weights), v,
+                                       ids, gates)
             else:
                 y = getattr(self, f"_apply_{form}")(weights, v, ids, gates)
-                rows = jnp.uint32(self.rows_computed(v.shape[0], v.dtype,
-                                                     mesh))
+                rows = [jnp.uint32(self.rows_computed(v.shape[0], v.dtype,
+                                                      mesh, live))]
         if computed is not None:
-            computed.append(rows)
+            computed += rows
         if self.latent:
             with sub_scope("latent"):
                 y = jnp.dot(y, weights["latent_up"],

@@ -64,8 +64,10 @@ def _expert_counts(op, ids, active, *computed):
     """What one decode step adds to an expert op's counters: ``[1, pairs
     routed, pairs held, held experts that got no row, rows of each held
     expert ...]`` over the active slots' tokens and, where the step's
-    experts ran as the kernel, the rows it ``computed`` () uint32 behind
-    them. ``ids`` (T, k), ``active`` (T,) bool."""
+    experts read by the routing, what ``RoutedExperts.apply`` ``computed``
+    behind them, () uint32 each: the rows the kernel ran over and, from
+    a step that chose its form by its count, 1 where it chose the kernel.
+    ``ids`` (T, k), ``active`` (T,) bool."""
     hit = op.held_hits(ids) & active[:, None, None]
     rows = hit.sum((0, 1)).astype(jnp.uint32)                 # (count,)
     head = jnp.stack([jnp.uint32(1),
@@ -76,8 +78,9 @@ def _expert_counts(op, ids, active, *computed):
 
 def _count_up(acc, add):
     """``acc`` (2, ...) uint32, low words over high words (a decode
-    program's (2, 4 + count) an op, and one word more where its experts
-    run as the kernel; the prompt programs' (2, 2, ops)), and
+    program's (2, 4 + count) an op, one word more where its experts
+    run as the kernel and two where a step chooses; the prompt programs'
+    (2, 2, ops)), and
     ``add`` of the shape of one: a 64-bit count in two words, so that a
     server that never restarts does not wrap (1,024 pairs a step fill 32
     bits in 4 M steps)."""
@@ -583,8 +586,8 @@ class PagedDecoder(_DecodeGraph):
         # the assignment of what it returns.
         self._expert_acc: Dict[str, jax.Array] = {
             op.name: jnp.zeros(
-                (2, 4 + op.count + (self._decode_form(op) == "kernel")),
-                jnp.uint32)
+                (2, 4 + op.count + {"kernel": 1, "counted": 2}.get(
+                    self._decode_form(op), 0)), jnp.uint32)
             for op in self._expert_ops}
         self._expert_acc_lock = threading.Lock()
         # the same for the prompt programs: the pairs they named among the
@@ -702,15 +705,17 @@ class PagedDecoder(_DecodeGraph):
                         new_acc[op.name],
                         _expert_counts(op, ids, active, *computed))
 
-            if self._decode_form(op) == "kernel":
+            if self._decode_form(op) in ("kernel", "counted"):
                 # the kernel reads the matrices of the experts its rows
-                # name: an idle slot's padding names none, and the rows
-                # the kernel says it ran go into the op's counters (so
-                # they are counted behind it; a dense step counts first,
-                # as it always did, and its program keeps its text)
-                y = op.apply(p, x2d,
-                             jnp.where(active[:, None], ids, op.first - 1),
-                             gates, computed, self._cm.mesh)
+                # name, and a counted step is the kernel's or the dense
+                # form's by how many they name: an idle slot's padding
+                # names none (``active``), and the rows the kernel says
+                # it ran, and whether a counted step ran it, go into the
+                # op's counters (so they are counted behind it; a dense
+                # step counts first, as it always did, and its program
+                # keeps its text)
+                y = op.apply(p, x2d, ids, gates, computed, self._cm.mesh,
+                             active)
                 count()
             else:
                 count()
@@ -891,16 +896,18 @@ class PagedDecoder(_DecodeGraph):
 
     def _decode_form(self, op) -> str:
         """The form a decode step's slots take through a routed-experts
-        op's held experts (``op.expert_form``)."""
+        op's held experts (``op.expert_form``, of a call that says which
+        of its rows are live)."""
         return op.expert_form(self.decode_slots, self._compute_dtype(),
-                              self._cm.mesh)
+                              self._cm.mesh, active=True)
 
     def expert_stats(self) -> Dict[str, Dict]:
         """Per routed-experts op, counted on the device over the decode
         steps' active slots: ``steps``, ``pairs_routed`` (tokens x picks),
         ``pairs_held`` (those whose expert this op holds),
         ``idle_held_experts`` (held experts that got no row, summed over
-        steps), ``rows_per_held_expert`` (count,). One fetch of a few
+        steps), ``rows_per_held_expert`` (count,), ``kernel_steps`` (the
+        steps whose experts ran as the kernel). One fetch of a few
         hundred bytes, which waits for a decode step in flight; {} for a
         graph with no such op."""
         if not self._expert_ops:
@@ -916,7 +923,9 @@ class PagedDecoder(_DecodeGraph):
         for i, op in enumerate(self._expert_ops):
             acc = fetched[op.name].astype(np.uint64)
             acc = [int(v) for v in (acc[1] << np.uint64(32)) | acc[0]]
-            step_rows = op.rows_computed(self.decode_slots, dtype, mesh)
+            form = self._decode_form(op)
+            step_rows = op.rows_computed(self.decode_slots, dtype, mesh,
+                                         active=True)
             out[op.name] = {
                 "held": [op.first, op.count], "n_routed": op.n_routed,
                 "steps": acc[0], "pairs_routed": acc[1],
@@ -925,15 +934,19 @@ class PagedDecoder(_DecodeGraph):
                 # how the held experts' products ran: the rows they went
                 # over (a decode step's from the shapes, or as the step
                 # counted them where the kernel ran and the routing says
-                # them: the accumulator's last word; the prompt programs'
-                # as those counted them) beside the rows the routing
-                # named (``pairs_held``), and by which form
-                # (``op.expert_form``; a prompt's at the widest bucket)
-                "form_decode": self._decode_form(op),
+                # them: the word behind the experts' rows; the prompt
+                # programs' as those counted them) beside the rows the
+                # routing named (``pairs_held``), and by which form
+                # (``op.expert_form``; a prompt's at the widest bucket);
+                # of a decode program whose steps choose ("counted"), how
+                # many chose the kernel: the accumulator's last word
+                "form_decode": form,
                 "form_prefill": op.expert_form(self.prefill_buckets[-1],
                                                dtype, mesh),
-                "rows_computed": (acc[-1] if step_rows is None
+                "rows_computed": (acc[4 + op.count] if step_rows is None
                                   else acc[0] * step_rows),
+                "kernel_steps": (acc[-1] if form == "counted"
+                                 else acc[0] * (form == "kernel")),
                 "prompt_pairs_held": prompt[0][i],
                 "prompt_rows_computed": prompt[1][i]}
         return out

@@ -555,7 +555,12 @@ def test_under_the_ridge_the_form_follows_the_share_of_experts_named(
     five and takes the kernel where the kernel is supported (one device,
     bfloat16 rows, Pallas on), the dense form on the CPU, under a mesh
     and for float32 rows; Nemotron's and A.X-K1's steps name all but one
-    in 250 and keep the dense form at 128 and at 240 rows; no form under
+    in 250 and keep the dense form at 128 and at 240 rows, unless the
+    call says which of its rows are live (``active``: a decode step):
+    then, where the kernel is supported, the call is ``counted`` (it
+    counts the held experts its live rows name and takes the kernel up
+    to 0.9 of them: 115 of 128, 10 of 12), and a call the shapes give to
+    the kernel stays the kernel's; no form under
     the ridge is ever the jnp grouped one; and the rows computed are a
     number from the shapes, or counted on the device."""
     from flexflow_tpu.ops.moe_ops import NAMED_SHARE_KERNEL, RIDGE_ROWS
@@ -574,6 +579,16 @@ def test_under_the_ridge_the_form_follows_the_share_of_experts_named(
         assert op.expert_form(slots, mesh=two) == "dense"
         assert op.expert_form(slots, jnp.float32) == "dense"
         assert op.expert_form(240) == "dense"
+        live = {"dense": "counted"}.get(want, want) if mode == "interpret" \
+            else "dense"
+        assert op.expert_form(slots, active=True) == op.expert_form(
+            slots, mesh=one, active=True) == live
+        assert op.expert_form(slots, mesh=two, active=True) == "dense"
+        assert op.expert_form(slots, jnp.float32, active=True) == "dense"
+        assert (op.rows_computed(slots, active=True) is None) == (
+            live != "dense")
+        assert op.kernel_limit() == int(NAMED_SHARE_KERNEL * op.count) \
+            == {"trinity": 28, "nemotron": 115, "axk1": 10}[name]
         # (a call that is no whole sublane tiles: the kernel refuses it)
         assert op.expert_form(12) == "dense"
         for rows in (1, 8, 16, 32, 64, 128, 200, RIDGE_ROWS):

@@ -701,9 +701,10 @@ def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
     grouped-head layer holds no ``while``, no ``dynamic-update-slice``
     and no scatter but the new token's keys and values (the states are
     stepped where they lie, the tails spread over the arena's 129 rows);
-    its one Mosaic call is the
+    its Mosaic calls are the
     paged kernel over 2 key-value heads of 128 (``attention_path``
-    ``kernel``) beside the ``M`` op's one ``ssd_step_decode`` call; the
+    ``kernel``), the ``M`` op's one ``ssd_step_decode`` call and, in the
+    one arm of the ``E`` op's ``conditional``, ``grouped_experts``; the
     state arena (129 x 128 x 8,192 float32, 541 MB) is made by that
     call alone, under the op's ``rule``, and both of the op's arenas
     alias their outputs: nothing beside the weights and the pool but
@@ -721,7 +722,7 @@ def test_nemotron_decode_step_loops_over_no_slots(nemotron_programs):
     for ln in text.splitlines():
         if " scatter(" in ln:
             assert "ff.MULTIHEAD_ATTENTION.block2_mixer/write" in ln, ln
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert "paged_attention_decode" in text
     names = _entry_op_names(text)
     made = {}
@@ -745,15 +746,20 @@ def test_nemotron_programs_name_their_pieces(nemotron_programs):
     """What the owner table reads: the expert op's ``route``, ``latent``
     and ``experts`` and the state-space op's ``project``, ``conv``,
     ``rule`` and ``write`` are in both programs' ``op_name`` paths; the
-    decode step multiplies every slot through every held expert (a
-    (128, 128, 2688) product); the prefill's held experts are ONE Mosaic
+    decode step's held experts are counted (``expert_form``: 128 slots
+    of 22 picks over 512 can name every one of the 128 held, so the step
+    counts those its live rows do name): ONE ``conditional`` an expert
+    layer, whose one arm multiplies every slot through every held expert
+    (a (128, 128, 2688) product) and whose other is the kernel, both
+    under the op's ``experts``, and the op's counters carry the two words
+    behind the experts' rows; the prefill's held experts are ONE Mosaic
     call an expert layer, under the op's ``experts`` (the owner table and
     ``prefill_experts_device_ms.agents`` read it there), with no
     ``conditional`` in the program and no ``while`` of the experts', no
     dense form beside it and no float32 buffer a tile an expert."""
     from flexflow_tpu.core.op import parse_scope
 
-    programs, _ = nemotron_programs
+    programs, dec = nemotron_programs
     for name in ("decode", "prefill"):
         text = programs[name][0]
         owners = {parse_scope(m) for m in re.findall(
@@ -764,7 +770,14 @@ def test_nemotron_programs_name_their_pieces(nemotron_programs):
                 ("MAMBA2", "conv"), ("MAMBA2", "rule"),
                 ("MAMBA2", "write")} <= subs, (name, subs)
     decode, prefill = programs["decode"][0], programs["prefill"][0]
-    assert "[128,128,2688]" in decode and " conditional(" not in decode
+    assert "[128,128,2688]" in decode
+    assert decode.count(" conditional(") == 1
+    (call,) = [ln for ln in decode.splitlines() if "grouped_experts" in ln
+               and 'custom_call_target="tpu_custom_call"' in ln]
+    assert "/experts/" in call and "f32[128,1024]" in call
+    (op,) = dec._expert_ops
+    assert 1 <= op.kernel_limit() < 128
+    assert dec._expert_acc[op.name].shape == (2, 4 + 128 + 2)
     assert " conditional(" not in prefill
     # the only loop left is the state-space op's walk over its chunks
     for ln in prefill.splitlines():
@@ -1254,26 +1267,41 @@ def zaya_programs(one_chip):
 
 def test_zaya_decode_step_reads_the_pair_by_the_kernel(zaya_programs):
     """The decode step of three CCA and top-1 expert layers holds no
-    ``while`` and no ``conditional``; its Mosaic calls are the paged
+    ``while``; its Mosaic calls are the paged
     kernel, one a layer (8 query heads on 2 key-value heads of 128:
-    ``attention_path`` ``kernel``); 48 slots of one pick over 16 name
-    nearly every expert, so the experts run in the dense form
-    (``expert_form``); the only scatters are the new token's keys and
+    ``attention_path`` ``kernel``), and the grouped-experts kernel, one
+    an expert layer, in the one arm of that layer's ``conditional``: 48
+    slots of one pick over 16 CAN name nearly every expert, so the step
+    counts the experts its live rows DO name (``expert_form``
+    ``counted``) and reads those alone up to 14 of the 16, all of them
+    in the dense form beyond; the matrices are copied for neither arm;
+    the op's counters carry the kernel's rows and the steps that took
+    it; the only scatters are the new token's keys and
     values (the tails and half values go back through ``_spread_rows``);
     the pool, pairs and rows, aliases its outputs."""
     programs, dec = zaya_programs
     text, mem = programs["decode"]
     assert dec.attention_path == {"decode": "kernel", "chunk": "kernel",
                                   "decode_chunk_tokens": 1024}
-    assert " while(" not in text and " conditional(" not in text
+    assert " while(" not in text
+    conds = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert [re.search(r"ff\.ROUTED_EXPERTS\.(block\d)_experts/experts/",
+                      ln).group(1) for ln in conds] == [
+        "block0", "block1", "block2"]
     for ln in text.splitlines():
         if " scatter(" in ln:
             assert re.search(
                 r"ff\.COMPRESSED_CONV_ATTENTION\.block\d_attn/write", ln), ln
+        assert not re.search(r"= bf16\[16,2048,2048\]\S* copy\(", ln), ln
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(calls) == 3
-    assert all("paged_attention_decode" in c for c in calls)
+    assert len(calls) == 3 + 3
+    assert sum("paged_attention_decode" in c for c in calls) == 3
+    assert sum("grouped_experts" in c and "/experts/" in c
+               for c in calls) == 3
+    assert {op.kernel_limit() for op in dec._expert_ops} == {14}
+    assert [a.shape for a in dec._expert_acc.values()] == [
+        (2, 4 + 16 + 2)] * 3
     shapes = {name: [a.shape for a in entry]
               for name, entry in dec.pool.kv.items()}
     assert shapes["block0_attn"] == shapes["block2_attn"] == [

@@ -773,6 +773,7 @@ def test_decode_steps_through_the_kernel_count_their_rows(monkeypatch):
     for j, nm in enumerate(names):
         rec = st[nm]
         assert rec["form_decode"] == "kernel" and rec["steps"] == 3
+        assert rec["kernel_steps"] == 3
         assert before[nm]["rows_computed"] == 0
         assert len(rec["rows_per_held_expert"]) == 8
         assert sum(rec["rows_per_held_expert"]) == rec["pairs_held"]
@@ -787,8 +788,166 @@ def test_decode_steps_through_the_kernel_count_their_rows(monkeypatch):
     dense, want, toks_dense, _, _, st_dense = run("off")
     assert {r["form_decode"] for r in st_dense.values()} == {"dense"}
     assert {r["rows_computed"] for r in st_dense.values()} == {3 * 8 * 8}
+    assert {r["kernel_steps"] for r in st_dense.values()} == {0}
     # (the prompt's logits whatever the steps chose; the steps' where the
     # two sessions' greedy tokens agree, as they do at these seeds)
+    same = 1 + sum(np.cumprod(toks[21:] == toks_dense[21:]))
+    assert same >= 2
+    assert np.abs(rows - want)[:same].max() <= 0.03 * np.abs(want).max()
+
+
+# ---- a decode step that counts what it names (PR 54) --------------------------
+
+def _all_held(count=16, e=256, width=128):
+    """A routed-experts op that holds all it routes over, one pick a
+    token, gated, bfloat16, at the smallest widths the kernel takes:
+    ZAYA1's share at toy widths; with seeded weights."""
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import OpType
+    from flexflow_tpu.ops.moe_ops import RoutedExperts
+
+    op = RoutedExperts(
+        Layer(OpType.ROUTED_EXPERTS, "x", attrs=dict(
+            n_routed=count, experts_per_token=1, width=width,
+            scoring="softmax", norm_topk=False)),
+        [ParallelTensorShape.unpartitioned((1, 8, e), DataType.BFLOAT16)])
+    key = jax.random.key(5)
+    w = {ws.name: (0.08 * jax.random.normal(
+        jax.random.fold_in(key, i), ws.shape)).astype(jnp.bfloat16)
+        for i, ws in enumerate(op.weight_specs())}
+    return op, w
+
+
+@pytest.mark.parametrize("live", ["all_rows", "every_other_row",
+                                  "an_expert_named_by_idle_rows_alone"])
+@pytest.mark.parametrize("named", [1, 6, 14, 15, 16])
+def test_a_counted_call_takes_the_form_its_live_rows_name(
+        monkeypatch, named, live):
+    """48 rows of one pick over 16 held CAN name 95 % of them, so the
+    shapes say dense; a call that says which rows are live counts the
+    experts those name and is the kernel's up to 14 of the 16
+    (``kernel_limit``), the dense form's beyond: the sum is
+    ``_apply_dense``'s over the live rows' picks, bit for bit where the
+    dense arm ran and to bfloat16's rounding of the terms where the
+    kernel did; ``computed`` is a tile of 48 rows an expert NAMED in the
+    one arm and every row through every expert in the other, and behind
+    it which arm ran; an idle row names nothing and gets exactly 0, and
+    an expert whose only namers are idle has no tile and does not count
+    (15 picked, 14 named: the kernel's)."""
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    op, w = _all_held()
+    rows = 48
+    assert op.kernel_limit() == 14
+    assert op.expert_form(rows) == "dense"
+    assert op.expert_form(rows, active=True) == "counted"
+    assert op.rows_computed(rows) == 16 * rows
+    assert op.rows_computed(rows, active=True) is None
+    assert op.expert_form(rows, jnp.float32, active=True) == "dense"
+    t = np.arange(rows)
+    if live == "all_rows":
+        active, ids, want_named = np.ones(rows, bool), t % named, named
+    elif live == "every_other_row":
+        # the idle rows' padding picks expert 15, as a token 0 might
+        active = t % 2 == 0
+        ids, want_named = np.where(active, (t // 2) % named, 15), named
+    else:
+        # the last expert picked is picked by idle rows alone
+        active = t % named != named - 1 if named > 1 else np.zeros(rows, bool)
+        ids, want_named = t % named, named - 1
+    v = jax.random.normal(jax.random.key(named), (rows, 256)
+                          ).astype(jnp.bfloat16)
+    ids = jnp.asarray(ids[:, None], jnp.int32)
+    _, gates, _ = op.route(w, v, ids)
+    counted = []
+    got = np.asarray(op.apply(w, v, ids, gates, counted,
+                              active=jnp.asarray(active)), np.float32)
+    masked = jnp.where(jnp.asarray(active)[:, None], ids, -1)
+    want = np.asarray(op._apply_dense(w, v, masked, gates), np.float32)
+    took_kernel = want_named <= 14
+    assert [int(c) for c in counted] == [
+        want_named * kernel.tile_rows(rows) if took_kernel else 16 * rows,
+        int(took_kernel)]
+    assert not got[~active].any() and not want[~active].any()
+    if not active.any():
+        return
+    scale = np.abs(want).max()
+    assert scale > 0.05 and got[active].any()
+    if took_kernel:
+        assert np.abs(got - want).max() <= 0.01 * scale
+    else:
+        assert np.array_equal(got, want)
+
+
+def test_decode_steps_count_the_steps_that_took_the_kernel(monkeypatch):
+    """A model that holds all 8 experts it routes over, 16 slots of 2
+    picks: the slots CAN name 99 % of them, so the decode program's
+    experts are ``counted``. A step of one live slot names 2 and takes
+    the kernel; a step of sixteen live slots names all 8, past the limit
+    of 7, and takes the dense form: ``kernel_steps`` counts the first
+    kind, layer by layer as the steps' own routing says, under ``steps``;
+    ``rows_computed`` is a tile of 16 rows an expert named in the one and
+    16 rows through 8 experts in the other; the logits and greedy tokens
+    are those of the same model with the kernel off."""
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.kernels import grouped_experts as kernel
+
+    config = dict(WHOLE, hidden_size=256, moe_intermediate_size=128)
+    names = family.expert_layer_names(config)
+    slots = 16
+    prompt = np.random.default_rng(4).integers(0, 96, 21).astype(np.int32)
+    crowd = np.random.default_rng(5).integers(0, 96, slots).astype(np.int32)
+
+    def run(mode):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        ff = FFModel(FFConfig(batch_size=slots, ledger="off", seed=7,
+                              compute_dtype="bfloat16",
+                              computation_mode=CompMode.INFERENCE))
+        build_trinity_lm(ff, slots, MAX_LEN, dataclasses.replace(
+            family.program_config(config), draw_weights=True))
+        ff.compile(optimizer=None, loss_type=None, metrics=[],
+                   mesh=make_mesh(devices=jax.devices()[:1]))
+        dec = PagedDecoder(ff, MAX_LEN, decode_slots=slots, block_size=BLOCK,
+                           prefill_chunk=16, calibrate=False)
+        # three steps of one live slot
+        rows, toks, _ = _paged_run(dec, names, prompt, 3, slot=2)
+        alone = dec.expert_stats()
+        # and two of sixteen: every slot a request of one token
+        tables = [dec.pool.try_admit(4) for _ in range(slots)]
+        table = np.zeros((slots, dec.max_blocks_per_request), np.int32)
+        for s, tb in enumerate(tables):
+            table[s, :len(tb)] = tb
+        named = {nm: [] for nm in names}
+        for k in range(2):
+            dec.decode(crowd + k, table, np.full(slots, k, np.int32))
+            for nm in names:
+                named[nm].append(len(set(
+                    np.asarray(dec.last_routing[nm]).ravel().tolist())))
+        return dec, rows, toks, alone, dec.expert_stats(), named
+
+    dec, rows, toks, alone, both, named = run("interpret")
+    assert {dec._decode_form(op) for op in dec._expert_ops} == {"counted"}
+    assert {op.kernel_limit() for op in dec._expert_ops} == {7}
+    tile = kernel.tile_rows(slots)
+    for nm in names:
+        assert alone[nm]["form_decode"] == "counted"
+        assert alone[nm]["steps"] == alone[nm]["kernel_steps"] == 3
+        assert alone[nm]["rows_computed"] == tile * (
+            3 * 8 - alone[nm]["idle_held_experts"])
+        assert both[nm]["steps"] == 5
+        crowded = [n for n in named[nm]]
+        assert both[nm]["kernel_steps"] == 3 + sum(n <= 7 for n in crowded)
+        assert both[nm]["rows_computed"] - alone[nm]["rows_computed"] == sum(
+            n * tile if n <= 7 else 8 * slots for n in crowded)
+    # both arms were reached: some layer's crowded step named all eight
+    assert any(n == 8 for nm in names for n in named[nm])
+    assert all(r["kernel_steps"] <= r["steps"] for r in both.values())
+    _, want, toks_dense, _, dense, _ = run("off")
+    assert {r["form_decode"] for r in dense.values()} == {"dense"}
+    assert {r["kernel_steps"] for r in dense.values()} == {0}
+    assert {r["rows_computed"] for r in dense.values()} == {5 * 8 * slots}
     same = 1 + sum(np.cumprod(toks[21:] == toks_dense[21:]))
     assert same >= 2
     assert np.abs(rows - want)[:same].max() <= 0.03 * np.abs(want).max()

@@ -81,6 +81,15 @@ def test_ssd_step_compiled(slots, heads, groups):
     chip_smoke.check_ssd_step(slots, heads, 64, 128, groups, mosaic=True)
 
 
+def test_counted_experts_compiled():
+    """The chains cell's expert layer at a decode step (48 slots of one
+    pick over 16 experts of 2,048 x 2,048, bfloat16): the shapes say
+    dense, the step counts; 6 named take the kernel, 16 the dense form,
+    both against the float32 product, one ``conditional`` in the
+    program with the Mosaic call in its one arm."""
+    chip_smoke.check_counted_experts(48, 16, 2048, 6, mosaic=True)
+
+
 def test_flash_autotune_on_chip(monkeypatch):
     """Compiled-mode autotune at the fit cell's shape: what training
     runs (bfloat16, causal, forward and backward) through every block
