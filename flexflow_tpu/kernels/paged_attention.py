@@ -36,6 +36,16 @@ from the arena where they lie:
   group are that many rows of the same score matrix over the one key
   row, row h keeping its query in the lanes of key-value head ``h // (H
   / Hkv)``;
+* a key head need not be a value head's width (``Hkv * Dv`` lanes a row
+  of V, ``Dv`` whole lane tiles or 64), nor whole lane tiles itself: a
+  key head of ``128 a + 64`` numbers (192: one and a half tiles) is
+  stored SPLIT (:func:`key_parts`, :func:`split_heads`: every head's
+  first ``128 a`` numbers, then every head's last 64), so that each
+  head's slices start where a 64-wide head's do; the query's two slices
+  are spread the same way and the one product sums both parts;
+* a sink a query head (``sink`` (H,)) is the running softmax's initial
+  state, ``m = s_h, l = 1, acc = 0`` in place of ``-inf, 0, 0``: one
+  more column that carries no value, and no column at all;
 * scores, the running maximum and sum, and the weighted sum are float32;
   K, V and the probabilities fed to the MXU are in the arena's dtype,
   which is what the jnp path feeds it.
@@ -102,6 +112,43 @@ def _sublanes(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize     # 8 for f32, 16 for bf16
 
 
+def key_parts(head_dim: int):
+    """The widths a key head's numbers are stored as: itself, or for a
+    head of ``128 a + 64`` numbers past one tile its first ``128 a`` and
+    its last 64, the layout :func:`split_heads` makes."""
+    if head_dim > 128 and head_dim % 128 == 64:
+        return (head_dim - 64, 64)
+    return (head_dim,)
+
+
+def split_heads(x, heads: int):
+    """(..., heads * D) rows of heads side by side -> the same rows as an
+    arena keeps them: as they are, or, where :func:`key_parts` splits a
+    head, every head's first part then every head's last."""
+    d = x.shape[-1] // heads
+    parts = key_parts(d)
+    if len(parts) == 1:
+        return x
+    xh = x.reshape(x.shape[:-1] + (heads, d))
+    return jnp.concatenate(
+        [xh[..., :parts[0]].reshape(x.shape[:-1] + (-1,)),
+         xh[..., parts[0]:].reshape(x.shape[:-1] + (-1,))], axis=-1)
+
+
+def join_heads(x, heads: int):
+    """:func:`split_heads` undone."""
+    d = x.shape[-1] // heads
+    parts = key_parts(d)
+    if len(parts) == 1:
+        return x
+    edge = heads * parts[0]
+    lead = x.shape[:-1]
+    return jnp.concatenate(
+        [x[..., :edge].reshape(lead + (heads, parts[0])),
+         x[..., edge:].reshape(lead + (heads, parts[1]))],
+        axis=-1).reshape(lead + (heads * d,))
+
+
 def _pages_for_tokens(block_size: int, max_blocks: int,
                       chunk_tokens: int) -> int:
     """Pages of a chunk of about ``chunk_tokens``: whole lane tiles of
@@ -115,46 +162,58 @@ def _pages_for_tokens(block_size: int, max_blocks: int,
 def _pages_per_chunk(block_size: int, max_blocks: int,
                      row_bytes: int) -> int:
     """Pages a loop iteration brings of arenas whose rows (one token's K,
-    or its V) are ``row_bytes`` wide: see ``CHUNK_BYTES``."""
+    or its V; their mean where they differ) are ``row_bytes`` wide: see
+    ``CHUNK_BYTES``."""
     wanted = CHUNK_BYTES // (2 * row_bytes * block_size)
     least = max(1, CHUNK_TOKENS // block_size)
     return _pages_for_tokens(block_size, max_blocks,
                              block_size * max(least, min(wanted, MAX_PAGES)))
 
 
-def chunk_tokens(arena_shape, arena_dtype, max_blocks: int) -> int:
+def chunk_tokens(arena_shape, arena_dtype, max_blocks: int,
+                 value_lanes: Optional[int] = None) -> int:
     """Tokens a loop iteration scores over arenas of ``arena_shape``
-    (num_blocks, block_size, Hkv*D): what a decoder's ``attention_path``
-    says of its decode kernel."""
+    (num_blocks, block_size, Hkv*D) (the values' rows ``value_lanes``
+    wide where they are not the keys'): what a decoder's
+    ``attention_path`` says of its decode kernel."""
     _, block_size, hd = arena_shape
     return block_size * _pages_per_chunk(
-        block_size, max_blocks, hd * jnp.dtype(arena_dtype).itemsize)
+        block_size, max_blocks,
+        (hd + (value_lanes or hd)) * jnp.dtype(arena_dtype).itemsize // 2)
 
 
 def _vmem_bytes(rows: int, window: int, heads: int, head_dim: int,
                 block_size: int, max_blocks: int, dtype,
-                kv_heads: Optional[int] = None) -> int:
+                kv_heads: Optional[int] = None,
+                value_lanes: Optional[int] = None) -> int:
     """The kernel's VMEM working set as it is allocated: q and o whole
     (float32, double-buffered by the pipeline), both chunk buffers of K
     and V, the float32 accumulator, and the (rows, chunk) float32
     temporaries of one iteration (s, p, mask) beside the block-diagonal
     operand and its mask."""
     hd = (kv_heads or heads) * head_dim
+    vd = value_lanes or hd
     m = window * _round_up(heads, 16)
     item = jnp.dtype(dtype).itemsize
-    chunk = _pages_per_chunk(block_size, max_blocks, hd * item) * block_size
-    return (2 * 2 * 4 * rows * heads * head_dim  # q, o
-            + 2 * 2 * chunk * hd * item        # K, V chunks, two buffers
-            + 4 * m * hd * 3                   # acc, q_bd, its f32 source
+    chunk = _pages_per_chunk(block_size, max_blocks,
+                             (hd + vd) * item // 2) * block_size
+    return (2 * 4 * rows * heads * (hd + vd) // (kv_heads or heads)  # q, o
+            + 2 * chunk * (hd + vd) * item     # K, V chunks, two buffers
+            + 4 * m * (2 * hd + vd)            # q_bd, its f32 source, acc
             + 4 * m * 128 * 2                  # m, l columns (lane padded)
             + 4 * 3 * m * chunk)               # s, p, mask
 
 
-def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
+def supported(q_shape, arena_shape, arena_dtype, max_blocks: int,
+              value_lanes: Optional[int] = None) -> bool:
     """Whether the kernel takes this call. ``q_shape``: (slots, W, H, D);
     ``arena_shape``: (num_blocks, block_size, Hkv*D), ``Hkv`` dividing
     ``H`` (grouped heads: ``D`` then whole lane tiles, or 64, two heads a
-    tile: Granite's 32 on 8). Refuses what Mosaic
+    tile: Granite's 32 on 8; or ``128 a + 64``, stored split:
+    :func:`key_parts`); ``value_lanes``: ``Hkv * Dv``, the width of V's
+    rows where it is not K's (grouped heads only: a key head a query
+    head keeps q and o in one layout, of one width, unsplit). Refuses
+    what Mosaic
     would: rows that do not fill whole 128-lane tiles, blocks that are
     not whole sublane tiles of the arena's dtype or do not divide a lane
     tile of tokens, dtypes other than float32 and bfloat16 (an int8
@@ -169,10 +228,17 @@ def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
     dtype = jnp.dtype(arena_dtype)
     if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
-    if hd % head_dim or hd % 128:
+    vd = value_lanes or hd
+    if hd % head_dim or hd % 128 or vd % 128:
         return False
     kv_heads = hd // head_dim
-    if heads % kv_heads or (kv_heads != heads and head_dim % 64):
+    if heads % kv_heads or vd % kv_heads:
+        return False
+    parts = key_parts(head_dim)
+    if kv_heads != heads:
+        if any(p % 64 for p in parts) or (vd // kv_heads) % 64:
+            return False
+    elif vd != hd or len(parts) > 1:
         return False
     if block_size % _sublanes(dtype) or (128 % block_size
                                          and block_size % 128):
@@ -183,17 +249,21 @@ def supported(q_shape, arena_shape, arena_dtype, max_blocks: int) -> bool:
         return False
     rows = _round_up(n * w, 8)
     return _vmem_bytes(rows, w, heads, head_dim, block_size, max_blocks,
-                       dtype, kv_heads) <= VMEM_BUDGET_BYTES
+                       dtype, kv_heads, vd) <= VMEM_BUDGET_BYTES
 
 
 def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
-            q_ref, k_hbm, v_hbm,             # inputs
-            o_ref,                           # output
-            kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref,
-            *, scale, window, heads, head_dim, block_size, max_blocks,
-            pages, slots, kv_heads):
+            q_ref, k_hbm, v_hbm, *rest,      # inputs (a sink's column last)
+            scale, window, heads, head_dim, block_size, max_blocks,
+            pages, slots, kv_heads, v_dim, sink):
+    sink_ref = rest[0] if sink else None
+    (o_ref,                                  # output
+     kbuf, vbuf, sems, cur_ref, m_ref, l_ref, acc_ref) = rest[sink:]
     b = pl.program_id(0)
     hd = kv_heads * head_dim
+    parts = key_parts(head_dim)
+    # (the values' diagonal is the keys' unless their widths differ)
+    apart = v_dim != head_dim or len(parts) > 1
     group = heads // kv_heads
     hpad = _round_up(heads, 16)
     chunk = pages * block_size
@@ -242,13 +312,25 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
     else:
         # q and o are (rows, H, D): row h of a group of hpad keeps head
         # h's query in the lanes of its key-value head, whole lane tiles
-        diag = (c // head_dim == r // group) & (r < heads)
+        if len(parts) == 1:
+            diag = (c // head_dim == r // group) & (r < heads)
+        else:
+            # the split layout: all heads' first parts, then their last
+            edge = kv_heads * parts[0]
+            diag = (jnp.where(c < edge, c // parts[0],
+                              (c - edge) // parts[1]) == r // group) & (
+                r < heads)
 
         def spread(qh):               # (H, D) -> (hpad, Hkv D)
             if hpad != heads:
                 qh = jnp.concatenate(
                     [qh, jnp.zeros((hpad - heads, head_dim), qh.dtype)], 0)
-            return jnp.where(diag, jnp.concatenate([qh] * kv_heads, 1), 0.0)
+            if len(parts) == 1:
+                cols = [qh] * kv_heads
+            else:
+                cols = ([qh[:, :parts[0]]] * kv_heads
+                        + [qh[:, parts[0]:]] * kv_heads)
+            return jnp.where(diag, jnp.concatenate(cols, 1), 0.0)
 
         q_bd = jnp.concatenate(
             [spread(q_ref[b * window + w]) for w in range(window)],
@@ -261,8 +343,14 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
         limit = limit + (row >= w * hpad).astype(jnp.int32)
     col = jax.lax.broadcasted_iota(jnp.int32, (m_rows, chunk), 1)
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if sink:
+        # one more column of the softmax that carries no value: where
+        # the running maximum and sum start
+        m_ref[...] = jnp.concatenate([sink_ref[...]] * window, axis=0)
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     n_chunks = (live_blocks(b) + pages - 1) // pages          # >= 1
 
@@ -278,7 +366,7 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
 
         copies(b, i, cur, wait=True)
         k = kbuf[cur].reshape(chunk, hd)
-        v = vbuf[cur].reshape(chunk, hd)
+        v = vbuf[cur].reshape(chunk, kv_heads * v_dim)
         s = _dot(q_bd, k, _NT) * scale                        # (M, chunk)
         s = jnp.where(i * chunk + col <= limit, s, NEG_INF)
         m_prev = m_ref[...]
@@ -294,6 +382,10 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
     jax.lax.fori_loop(0, n_chunks, body, None)
 
     out = acc_ref[...] / l_ref[...]                           # (M, HD)
+    if apart:
+        rv = jax.lax.broadcasted_iota(jnp.int32, (hpad, kv_heads * v_dim), 0)
+        cv = jax.lax.broadcasted_iota(jnp.int32, (hpad, kv_heads * v_dim), 1)
+        diag = (cv // v_dim == rv // group) & (rv < heads)
     for w in range(window):
         kept = jnp.where(diag, out[w * hpad:(w + 1) * hpad], 0.0)
         if group == 1:
@@ -302,16 +394,18 @@ def _kernel(lens_ref, tables_ref,            # scalar prefetch (SMEM)
         else:
             # a row's output lies in its key-value head's lanes alone
             o_ref[b * window + w] = sum(
-                kept[:heads, j * head_dim:(j + 1) * head_dim]
+                kept[:heads, j * v_dim:(j + 1) * v_dim]
                 for j in range(kv_heads))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
-def _paged_attention(q, k_arena, v_arena, tables, seq_lens, *, scale, pages,
-                     interpret):
+def _paged_attention(q, k_arena, v_arena, tables, seq_lens, sink=None, *,
+                     scale, pages, interpret):
     n, window, heads, head_dim = q.shape
     _, block_size, hd = k_arena.shape
     kv_heads = hd // head_dim
+    vd = v_arena.shape[2]
+    v_dim = vd // kv_heads
     max_blocks = tables.shape[1]
     rows = _round_up(n * window, 8)
     # a key head a query head: q and o are rows of H*D lanes; grouped:
@@ -321,69 +415,87 @@ def _paged_attention(q, k_arena, v_arena, tables, seq_lens, *, scale, pages,
     if rows != n * window:
         q2 = jnp.pad(q2, ((0, rows - n * window),) + ((0, 0),) *
                      (q2.ndim - 1))
-    m_rows = window * _round_up(heads, 16)
+    hpad = _round_up(heads, 16)
+    m_rows = window * hpad
     whole = pl.BlockSpec(q_shape, lambda b, lens, tabs: (0,) * len(q_shape))
+    o_shape = q_shape if v_dim == head_dim else (rows, heads, v_dim)
+    whole_o = whole if v_dim == head_dim else pl.BlockSpec(
+        o_shape, lambda b, lens, tabs: (0, 0, 0))
+    more_in, more = [], []
+    if sink is not None:
+        # the sinks as a column of the score matrix's rows (a padding
+        # row's is 0: its output is dropped)
+        more_in = [pl.BlockSpec((hpad, 1), lambda b, lens, tabs: (0, 0))]
+        more = [jnp.pad(sink.astype(jnp.float32),
+                        (0, hpad - heads)).reshape(hpad, 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
         in_specs=[whole,
                   pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=whole,
+                  pl.BlockSpec(memory_space=pl.ANY)] + more_in,
+        out_specs=whole_o,
         scratch_shapes=[
             pltpu.VMEM((2, pages, block_size, hd), k_arena.dtype),
-            pltpu.VMEM((2, pages, block_size, hd), v_arena.dtype),
+            pltpu.VMEM((2, pages, block_size, vd), v_arena.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((m_rows, 1), jnp.float32),
             pltpu.VMEM((m_rows, 1), jnp.float32),
-            pltpu.VMEM((m_rows, hd), jnp.float32),
+            pltpu.VMEM((m_rows, vd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=scale, window=window, heads=heads,
             head_dim=head_dim, block_size=block_size,
-            max_blocks=max_blocks, pages=pages, slots=n, kv_heads=kv_heads),
+            max_blocks=max_blocks, pages=pages, slots=n, kv_heads=kv_heads,
+            v_dim=v_dim, sink=sink is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_shape, jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(o_shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="paged_attention_decode",
     )(seq_lens.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1),
-      q2, k_arena, v_arena)
-    return out[:n * window].reshape(n, window, heads, head_dim)
+      q2, k_arena, v_arena, *more)
+    return out[:n * window].reshape(n, window, heads, v_dim)
 
 
 def paged_attention_decode(q, k_arena, v_arena, tables, seq_lens,
                            scale: Optional[float] = None,
-                           pages_per_chunk: Optional[int] = None
+                           pages_per_chunk: Optional[int] = None,
+                           sink: Optional[jax.Array] = None
                            ) -> jax.Array:
     """Attention of W new tokens a slot over the slot's cached K/V, read
     through its block table from the arenas in place.
 
     ``q``: (slots, W, H, D); ``k_arena``/``v_arena``: (num_blocks,
-    block_size, Hkv*D), already holding the window's own rows; ``tables``:
+    block_size, Hkv*D) and (.., Hkv*Dv), K's rows as :func:`split_heads`
+    lays them, already holding the window's own rows; ``sink``: (H,) or
+    None; ``tables``:
     (slots, max_blocks) int32; ``seq_lens``: (slots,) int32, the tokens
     cached before the window. Row w of a slot sees positions
     ``0 .. seq_len + w``. ``pages_per_chunk`` (blocks a loop iteration;
     times ``block_size`` a multiple of 128) is for tests and tuning.
-    Returns (slots, W, H, D) float32. Callers check :func:`supported`
+    Returns (slots, W, H, Dv) float32. Callers check :func:`supported`
     first."""
     head_dim = q.shape[-1]
     block_size = k_arena.shape[1]
     scale = float(scale) if scale is not None else head_dim ** -0.5
     pages = (int(pages_per_chunk) if pages_per_chunk
-             else _pages_per_chunk(block_size, tables.shape[1],
-                                   k_arena.shape[2] * k_arena.dtype.itemsize))
+             else _pages_per_chunk(
+                 block_size, tables.shape[1],
+                 (k_arena.shape[2] + v_arena.shape[2])
+                 * k_arena.dtype.itemsize // 2))
     if (pages * block_size) % 128:
         raise ValueError(f"a chunk of {pages} blocks of {block_size} "
                          f"tokens is no multiple of 128 lanes")
-    return _paged_attention(q, k_arena, v_arena, tables, seq_lens,
+    return _paged_attention(q, k_arena, v_arena, tables, seq_lens, sink,
                             scale=scale, pages=pages,
                             interpret=pallas_mode() == "interpret")
 
 
-__all__ = ["paged_attention_decode", "supported"]
+__all__ = ["join_heads", "key_parts", "paged_attention_decode",
+           "split_heads", "supported"]

@@ -23,6 +23,21 @@ API, so the graph compiles, is priced by the search and the simulator,
 and drives ``serving.GenerationInstance``: a full layer keeps a (k, v)
 pair a token in the paged pool, a windowed layer a ring of ``window``
 rows a request beside it (serving/cache_entry.py ``WindowEntry``).
+
+The same builder takes the Xiaomi MiMo-V2 (``mimo_v2``) line, which is
+this skeleton (layers of two kinds by a list, a dense MLP in the first
+layers and sigmoid experts with a selection bias after them) with other
+answers to what a configuration can say, each a field whose default is
+Trinity's: two norms a layer and not four (``sandwich_norms``), no norm
+over a head and no gate (``qk_norm``, ``gate``), key-value heads a kind
+of layer (``num_kv_heads_sliding``), values narrower than keys
+(``v_head_dim``), rotary over the first ``rotary_dim`` numbers of a head
+in BOTH kinds with a base each (``rope_theta_full``), a learned sink a
+head in the kinds ``sink_layers`` names, values scaled
+(``value_scale``), no shared expert (``n_shared`` 0) and an embedding as
+it is (``scale_embedding``). One builder and not a sibling file: what
+differs is configuration, and ROADMAP D17 counts a file a configuration
+as debt.
 """
 
 from __future__ import annotations
@@ -58,6 +73,16 @@ class TrinityConfig:
     n_shared: int = 1
     experts_held: Optional[Tuple[int, int]] = None
     scale_embedding: bool = True
+    # what the MiMo line answers otherwise (the module's docstring)
+    sandwich_norms: bool = True
+    qk_norm: bool = True
+    gate: bool = True
+    num_kv_heads_sliding: Optional[int] = None   # None: ``num_kv_heads``
+    v_head_dim: Optional[int] = None             # None: ``head_dim``
+    rotary_dim: Optional[int] = None             # None: the whole head
+    rope_theta_full: Optional[float] = None      # None: no positions there
+    sink_layers: Tuple[str, ...] = ()
+    value_scale: Optional[float] = None
     param_dtype: DataType = DataType.FLOAT
     draw_weights: bool = True
 
@@ -85,16 +110,24 @@ def build_trinity_lm(ff, batch_size: int, seq_length: int,
             name=f"block{i}_{what}")
         u = norm(h, "norm_in")
         sliding = kind == SLIDING
+        theta = cfg.rope_theta if sliding else cfg.rope_theta_full
         attn = ff.multihead_attention(
             u, u, u, cfg.hidden_size, cfg.num_heads, bias=False, causal=True,
-            num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
-            qk_norm="head", norm_eps=cfg.rms_eps, gate=True,
+            num_kv_heads=(cfg.num_kv_heads_sliding if sliding
+                          and cfg.num_kv_heads_sliding else cfg.num_kv_heads),
+            head_dim=cfg.head_dim,
+            qk_norm="head" if cfg.qk_norm else False, norm_eps=cfg.rms_eps,
+            gate=cfg.gate,
             window=cfg.window if sliding else None,
-            rotary=cfg.rope_theta if sliding else None,
-            positions=positions if sliding else None,
+            rotary=theta, positions=positions if theta else None,
+            v_head_dim=cfg.v_head_dim, rotary_dim=cfg.rotary_dim,
+            sinks=kind in cfg.sink_layers, sink_initializer=init,
+            value_scale=cfg.value_scale,
             kernel_initializer=init, gain_initializer=init,
             name=f"block{i}_attn")
-        h = ff.add(h, norm(attn, "norm_post_attn"), name=f"block{i}_res1")
+        if cfg.sandwich_norms:
+            attn = norm(attn, "norm_post_attn")
+        h = ff.add(h, attn, name=f"block{i}_res1")
         m = norm(h, "norm_pre_mlp")
         if i < cfg.num_dense:
             f = ff.gated_mlp(m, cfg.dense_width, kernel_initializer=init,
@@ -113,7 +146,9 @@ def build_trinity_lm(ff, batch_size: int, seq_length: int,
                     m, cfg.n_shared * cfg.expert_width,
                     kernel_initializer=init, name=f"block{i}_shared")
                 f = ff.add(f, shared, name=f"block{i}_ffn")
-        h = ff.add(h, norm(f, "norm_post_mlp"), name=f"block{i}_res2")
+        if cfg.sandwich_norms:
+            f = norm(f, "norm_post_mlp")
+        h = ff.add(h, f, name=f"block{i}_res2")
     h = ff.rms_norm(h, eps=cfg.rms_eps, kernel_initializer=init,
                     name="norm_f")
     logits = ff.dense(h, cfg.vocab_size, use_bias=False,

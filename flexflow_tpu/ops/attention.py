@@ -19,6 +19,16 @@ partitions the attention over heads.
 sqrt(head_dim)``, or the op's ``scale`` attribute where a model states
 its own (Granite's ``attention_multiplier``); the forward, every cache
 entry kind and both serving kernels read the property.
+
+Four attributes that are absent for most models (MiMo's layers state
+all four): ``v_head_dim``, a value head's width where it is not the key
+head's (q and k are ``(H, head_dim)``, v and the attended values ``(H,
+v_head_dim)``, ``wo`` ``(H, v_head_dim, E)``); ``rotary_dim``, the
+first numbers of a head that ``rotary`` rotates (the rest pass);
+``sinks``, a learned scalar a query head that is one more column of the
+softmax and carries no value (``o = sum_j exp(a_j - m) v_j / (exp(s_h -
+m) + sum_j exp(a_j - m))``); ``value_scale``, what the projected values
+are multiplied by.
 """
 
 from __future__ import annotations
@@ -116,8 +126,20 @@ class MultiHeadAttention(Op):
         # rotary positions over the whole head, by theta; the op then
         # takes the graph's positions as its fourth input
         self.rotary = float(a["rotary"]) if a.get("rotary") else None
-        self.inv_freq = (rotary_inv_freq(self.head_dim, self.rotary)
+        # ... or over the first ``rotary_dim`` numbers of it
+        self.rotary_dim = (int(a["rotary_dim"]) if a.get("rotary_dim")
+                           else None)
+        self.inv_freq = (rotary_inv_freq(self.rotary_dim or self.head_dim,
+                                         self.rotary)
                          if self.rotary else None)
+        # a value head's width, where it is not a key head's
+        self.v_head_dim = int(a.get("v_head_dim") or self.head_dim)
+        # a learned scalar a query head: one more column of the softmax
+        # that carries no value
+        self.sinks = bool(a.get("sinks", False))
+        # what the projected values are multiplied by
+        self.value_scale = (float(a["value_scale"])
+                            if a.get("value_scale") else None)
         # the attended values times sigmoid(x W_g), before W_o
         self.gate = bool(a.get("gate", False))
         # what the scores are multiplied by, where it is not
@@ -135,17 +157,18 @@ class MultiHeadAttention(Op):
         dt = self.input_shapes[0].dtype
         init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
         h, d, hkv = self.num_heads, self.head_dim, self.num_kv_heads
+        dv = self.v_head_dim
         specs = [
             WeightSpec("wq", (self.q_in, h, d), dt, init),
             WeightSpec("wk", (self.k_in, hkv, d), dt, init),
-            WeightSpec("wv", (self.v_in, hkv, d), dt, init),
-            WeightSpec("wo", (h, d, self.embed_dim), dt, init),
+            WeightSpec("wv", (self.v_in, hkv, dv), dt, init),
+            WeightSpec("wo", (h, dv, self.embed_dim), dt, init),
         ]
         if self.use_bias:
             specs += [
                 WeightSpec("bq", (h, d), dt, ZeroInitializer(), weight_decay=False),
                 WeightSpec("bk", (hkv, d), dt, ZeroInitializer(), weight_decay=False),
-                WeightSpec("bv", (hkv, d), dt, ZeroInitializer(), weight_decay=False),
+                WeightSpec("bv", (hkv, dv), dt, ZeroInitializer(), weight_decay=False),
                 WeightSpec("bo", (self.embed_dim,), dt, ZeroInitializer(), weight_decay=False),
             ]
         if self.qk_norm:
@@ -159,7 +182,12 @@ class MultiHeadAttention(Op):
                            weight_decay=False),
             ]
         if self.gate:
-            specs.append(WeightSpec("wg", (self.q_in, h, d), dt, init))
+            specs.append(WeightSpec("wg", (self.q_in, h, dv), dt, init))
+        if self.sinks:
+            specs.append(WeightSpec(
+                "sinks", (h,), dt,
+                self.attrs.get("sink_initializer") or ZeroInitializer(),
+                weight_decay=False))
         return specs
 
     # ---- the pieces serving composes (serving/cache_entry.py) -------------
@@ -171,9 +199,10 @@ class MultiHeadAttention(Op):
 
     @sub_scope("project")
     def project_qkv(self, weights, q_in, k_in, v_in, positions=None):
-        """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries and the (B,
-        S, Hkv, D) keys and values, biases added, normed, and with
-        ``rotary`` rotated by ``positions`` (B, S)."""
+        """(B, S, E) x (E, H, D) -> the (B, S, H, D) queries, the (B, S,
+        Hkv, D) keys and the (B, S, Hkv, Dv) values, biases added, normed,
+        with ``rotary`` rotated by ``positions`` (B, S) and the values
+        times ``value_scale``."""
         qh = jnp.einsum("bse,ehd->bshd", q_in, weights["wq"])
         kh = jnp.einsum("bse,ehd->bshd", k_in, weights["wk"])
         vh = jnp.einsum("bse,ehd->bshd", v_in, weights["wv"])
@@ -185,9 +214,15 @@ class MultiHeadAttention(Op):
             qh = self._normed(qh, weights["q_norm"])
             kh = self._normed(kh, weights["k_norm"])
         if self.rotary:
-            qh = apply_rotary(qh, positions, self.inv_freq)
-            kh = apply_rotary(kh, positions, self.inv_freq)
+            qh = apply_rotary(qh, positions, self.inv_freq, self.rotary_dim)
+            kh = apply_rotary(kh, positions, self.inv_freq, self.rotary_dim)
+        if self.value_scale is not None:
+            vh = vh * jnp.asarray(self.value_scale, vh.dtype)
         return qh, kh, vh
+
+    def sink(self, weights):
+        """The (H,) sinks, or None for an op that has none."""
+        return weights["sinks"] if self.sinks else None
 
     def _normed(self, x, gain):
         """RMSNorm over all heads' values of a position: ``x`` (..., H, D)
@@ -240,6 +275,9 @@ class MultiHeadAttention(Op):
             return None               # the kernels take a key head a query head
         if self.window or self.rotary or self.gate or self.qk_norm_per_head:
             return None               # nor a band, positions or a gate
+        if (self.sinks or self.v_head_dim != d
+                or self.value_scale is not None):
+            return None               # nor a sink, nor two widths a head
         q_shape = q_in.shape[:2] + (h, d)
         k_shape = k_in.shape[:2] + (h, d)
         if not fa.engaged(q_shape[1], k_shape[1], d, self.causal, q_in.dtype):
@@ -302,9 +340,10 @@ class MultiHeadAttention(Op):
         from ..parallel.ring_attention import ring_attention, single_device_attention
 
         if self.seq_axis is not None and ctx.mesh is not None:
-            if self.window:
+            if self.window or self.sinks:
                 raise NotImplementedError(
-                    f"{self.name}: a windowed op is not sequence-sharded")
+                    f"{self.name}: an op with a window or sinks is not "
+                    f"sequence-sharded")
             # sequence parallelism: exact attention over seq-sharded q/k/v.
             # "ring": collective-permute ring over ICI; "a2a": Ulysses
             # all-to-all head resharding (no reference equivalent —
@@ -331,7 +370,7 @@ class MultiHeadAttention(Op):
                 with sub_scope("attend"):
                     ctxv = single_device_attention(
                         *qkv, self.causal, self.scale, drop, ctx.rng,
-                        self.window)
+                        self.window, self.sink(weights))
                 out = self.project_out(weights, ctxv, inputs[0])
         # which implementation this lowering took, counted once per trace:
         # the rule is over shapes, and a chip run has to be able to say
@@ -351,7 +390,7 @@ class MultiHeadAttention(Op):
                 for wn in ("wq", "wk", "wv"):
                     weight_shapes[wn] = weight_shapes[wn].partitioned(1, deg, ax)
                 weight_shapes["wo"] = weight_shapes["wo"].partitioned(0, deg, ax)
-                for bn in ("bq", "bk", "bv", "q_norm", "k_norm"):
+                for bn in ("bq", "bk", "bv", "q_norm", "k_norm", "sinks"):
                     if bn in weight_shapes:
                         weight_shapes[bn] = weight_shapes[bn].partitioned(0, deg, ax)
         sax = strategy.get("seq")
@@ -376,13 +415,16 @@ class MultiHeadAttention(Op):
     def flops(self) -> float:
         b, s = self.input_shapes[0].sizes[0], self.input_shapes[0].sizes[1]
         e, h, d = self.embed_dim, self.num_heads, self.head_dim
-        # q and o over every head, k and v over the key-value heads
-        proj = 2.0 * b * s * e * d * (
-            (3 if self.gate else 2) * h + 2 * self.num_kv_heads)
+        dv = self.v_head_dim
+        # q and o (and the gate) over every head, k and v over the
+        # key-value heads; keys of d, values of dv
+        proj = 2.0 * b * s * e * (
+            h * (d + (2 if self.gate else 1) * dv)
+            + self.num_kv_heads * (d + dv))
         # logits + context over the keys a query may see: the square, or
         # with a window its band
         keys = min(s, self.window) if self.window else s
-        attn = 2.0 * b * h * s * keys * d * 2
+        attn = 2.0 * b * h * s * keys * (d + dv)
         return proj + attn
 
 

@@ -65,14 +65,28 @@ def _block_attn(q, k, v, m_prev, l_prev, o_prev, mask, dropout_rate=0.0, rng=Non
     return m_new, l_new, o_new
 
 
+def sink_softmax(scores, sink=None):
+    """The softmax over the last axis of (masked) ``scores`` (B, heads...,
+    Sq, Sk); with ``sink`` (shaped as the heads axes) over one more
+    column, ``sink``'s, which carries no value and is dropped."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    col = jnp.broadcast_to(sink.astype(scores.dtype)[None, ..., None, None],
+                           scores.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([scores, col], axis=-1),
+                          axis=-1)[..., :-1]
+
+
 def single_device_attention(q, k, v, causal: bool, scale: float,
                             dropout_rate: float = 0.0,
                             rng: Optional[jax.Array] = None,
-                            window: Optional[int] = None):
+                            window: Optional[int] = None,
+                            sink: Optional[jax.Array] = None):
     """Plain scaled-dot-product attention (the n=1 path and the shared
     implementation for the unsharded MultiHeadAttention lowering). With a
     ``window`` (a causal op's) a query sees the ``window`` keys that end
-    at its own."""
+    at its own. ``sink`` (H,): one more column of each head's softmax,
+    which carries no value. The values' width may differ from the keys'."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
     if causal:
         Sq, Sk = s.shape[-2], s.shape[-1]
@@ -80,8 +94,7 @@ def single_device_attention(q, k, v, causal: bool, scale: float,
         if window:
             mask &= ~jnp.tril(jnp.ones((Sq, Sk), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    p = _drop(p, dropout_rate, rng)
+    p = _drop(sink_softmax(s, sink), dropout_rate, rng)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 

@@ -663,6 +663,11 @@ class FFModel:
         positions: Optional[Tensor] = None,
         gate: bool = False,
         scale: Optional[float] = None,
+        v_head_dim: Optional[int] = None,
+        rotary_dim: Optional[int] = None,
+        sinks: bool = False,
+        sink_initializer=None,
+        value_scale: Optional[float] = None,
     ) -> Tensor:
         """reference: FFModel::multihead_attention (model.h:542,
         src/ops/attention.cc — cuDNN multihead attention). ``causal`` is a
@@ -677,7 +682,13 @@ class FFModel:
         ``positions``, the graph's int32 (B, S) input, over the whole
         head), ``gate`` (the attended values times ``sigmoid(query
         W_g)`` before the output projection), ``scale`` (what the scores
-        are multiplied by where it is not ``1 / sqrt(head_dim)``)."""
+        are multiplied by where it is not ``1 / sqrt(head_dim)``),
+        ``v_head_dim`` (a value head's width where it is not the key
+        head's), ``rotary_dim`` (the first numbers of a head that
+        ``rotary`` rotates, where it is not the whole head), ``sinks`` (a
+        learned scalar a query head, one more column of the softmax that
+        carries no value), ``value_scale`` (what the projected values are
+        multiplied by)."""
         attrs = dict(
             embed_dim=embed_dim,
             num_heads=num_heads,
@@ -706,11 +717,20 @@ class FFModel:
             attrs["gate"] = True
         if scale:
             attrs["scale"] = float(scale)
+        if v_head_dim and int(v_head_dim) != int(
+                head_dim or embed_dim // num_heads):
+            attrs["v_head_dim"] = int(v_head_dim)
+        if sinks:
+            attrs.update(sinks=True, sink_initializer=sink_initializer)
+        if value_scale:
+            attrs["value_scale"] = float(value_scale)
         inputs = [query, key, value]
         if rotary:
             if positions is None:
                 raise ValueError("rotary positions need the positions input")
             attrs["rotary"] = float(rotary)
+            if rotary_dim:
+                attrs["rotary_dim"] = int(rotary_dim)
             inputs.append(positions)
         if strategy:
             attrs["strategy"] = strategy

@@ -50,6 +50,7 @@ from ..obs.metrics import metrics_registry
 from ..ops import block_sparse_attention as bsa
 from ..ops import mamba2
 from ..ops.gated_delta import delta_rule_path
+from ..parallel.ring_attention import sink_softmax
 from .kv_cache import NULL_BLOCK, NULL_ROW
 
 
@@ -62,32 +63,34 @@ def _scores(q, k, scale):
     return jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
 
 
-def _weigh(scores, mask, v):
+def _weigh(scores, mask, v, sink=None):
     """The masked softmax of ``scores`` over the (B, Sk, H, D) values;
     ``mask`` broadcasts against (B, H, Sq, Sk), True where a query sees a
     key. With :func:`_scores`, the one copy of the attend every (k, v)
     form shares."""
-    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    probs = sink_softmax(jnp.where(mask, scores, -1e30), sink)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _attend(q, k, v, mask, scale):
+def _attend(q, k, v, mask, scale, sink=None):
     """The masked softmax attention of (B, Sq, H, D) queries over (B, Sk,
-    Hkv, D) keys and values; ``mask()`` gives what broadcasts against (B,
-    1, Sq, Sk), made behind the scores (the order the programs' lowered
-    text has had). A key head a query head: :func:`_scores` and
-    :func:`_weigh`. Grouped heads: the ``H / Hkv`` query heads of a group
-    read the one key head where it lies, nothing is repeated."""
+    Hkv, D) keys and (B, Sk, Hkv, Dv) values; ``mask()`` gives what
+    broadcasts against (B, 1, Sq, Sk), made behind the scores (the order
+    the programs' lowered text has had). A key head a query head:
+    :func:`_scores` and :func:`_weigh`. Grouped heads: the ``H / Hkv``
+    query heads of a group read the one key head where it lies, nothing
+    is repeated. ``sink`` (H,): one more column of each head's softmax."""
     h, hkv = q.shape[2], k.shape[2]
     if h == hkv:
         scores = _scores(q, k, scale)
-        return _weigh(scores, mask(), v)
+        return _weigh(scores, mask(), v, sink)
     b, sq, _, d = q.shape
     qg = q.reshape(b, sq, hkv, h // hkv, d)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
-    probs = jax.nn.softmax(jnp.where(mask()[:, :, None], scores, -1e30),
-                           axis=-1)
-    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(b, sq, h, d)
+    probs = sink_softmax(jnp.where(mask()[:, :, None], scores, -1e30),
+                     None if sink is None else sink.reshape(hkv, h // hkv))
+    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(
+        b, sq, h, v.shape[-1])
 
 
 # keys one step of the WALK a chunk's attend takes where the kernel does
@@ -101,7 +104,7 @@ SPAN_TOKENS = 512
 NOWHERE = chunk_attention.NOWHERE
 
 
-def _attend_spans(op, q, qpos, kv_heads, read, lo, hi):
+def _attend_spans(op, q, qpos, kv_heads, read, lo, hi, sink=None, dv=None):
     """A chunk's (B, S, H, D) queries at positions ``qpos`` (B, S) over
     the key spans ``lo .. hi - 1`` (int32 scalars, traced: the spans
     outside them are not read at all): ``read(j)`` gives span j's (B, T,
@@ -109,9 +112,13 @@ def _attend_spans(op, q, qpos, kv_heads, read, lo, hi):
     (:data:`NOWHERE` for a row that holds nothing), and a query sees what
     ``op.sees`` says. A running maximum and sum in float32, a span at a
     time, so no (H, S, context) array is made; the ``H / Hkv`` query
-    heads of a group read the one key head where it lies. Returns (B, S,
-    H, D) in the queries' dtype."""
+    heads of a group read the one key head where it lies. The values are
+    ``dv`` wide where that is not the keys' width; ``sink`` (H,) is where
+    each head's running maximum and sum start (``m = s_h, l = 1``: one
+    more column of the softmax that carries no value). Returns (B, S, H,
+    Dv) in the queries' dtype."""
     b, s, h, d = q.shape
+    dv = dv or d
     g = h // kv_heads
     qg = q.reshape(b, s, kv_heads, g, d)
     f32 = jnp.float32
@@ -132,22 +139,27 @@ def _attend_spans(op, q, qpos, kv_heads, read, lo, hi):
         return m_new, l, acc
 
     shape = (b, kv_heads, g, s)
+    if sink is None:
+        start = (jnp.full(shape, -1e30, f32), jnp.zeros(shape, f32))
+    else:
+        start = (jnp.broadcast_to(sink.astype(f32).reshape(
+            kv_heads, g)[None, :, :, None], shape), jnp.ones(shape, f32))
     _, l, acc = jax.lax.fori_loop(
-        lo, hi, body, (jnp.full(shape, -1e30, f32), jnp.zeros(shape, f32),
-                       jnp.zeros(shape + (d,), f32)))
+        lo, hi, body, start + (jnp.zeros(shape + (dv,), f32),))
     out = acc / jnp.maximum(l, 1e-30)[..., None]        # (B, Hkv, G, S, D)
-    return jnp.moveaxis(out, 3, 1).reshape(b, s, h, d).astype(q.dtype)
+    return jnp.moveaxis(out, 3, 1).reshape(b, s, h, dv).astype(q.dtype)
 
 
-def _attend_kernel(op, q, qpos, keys, values, kpos):
+def _attend_kernel(op, q, qpos, keys, values, kpos, sink=None):
     """The same chunk through ``kernels/chunk_attention.py``: (B, S, H, D)
-    queries over (B, L, Hkv D) keys and values in the arena's row layout,
-    row r at position ``kpos[:, r]``. Returns (B, S, H, D)."""
+    queries over (B, L, Hkv D) keys and (B, L, Hkv Dv) values in the
+    arena's row layout, row r at position ``kpos[:, r]``. Returns (B, S,
+    H, Dv)."""
     b, s, h, d = q.shape
     return chunk_attention.chunk_attention(
         q.reshape(b, s, h * d), qpos, keys, values, kpos,
         kv_heads=keys.shape[-1] // d, scale=op.scale,
-        window=op.window).reshape(q.shape)
+        window=op.window, sink=sink).reshape(b, s, h, -1)
 
 
 def _put(arena, flat, rows):
@@ -358,14 +370,22 @@ class EntryKind:
 
 @dataclasses.dataclass(frozen=True)
 class PairEntry(EntryKind):
-    """Keys and values, ``heads * head_dim`` numbers each a token;
-    ``heads`` are the key-value heads, of which ``query_heads`` query
-    heads read one each ``query_heads / heads`` (0: a key head a query
-    head)."""
+    """Keys and values, ``heads * head_dim`` numbers each a token (the
+    values ``heads * value_dim`` where ``value_dim`` is not 0: two arenas
+    of two widths); ``heads`` are the key-value heads, of which
+    ``query_heads`` query heads read one each ``query_heads / heads`` (0:
+    a key head a query head). Keys lie in their arena as
+    ``paged_attention.split_heads`` lays them (a head of one and a half
+    lane tiles in two parts; every other width as it is), which the
+    kernels read and the jnp forms undo. ``sink``: the op has a learned
+    sink a query head, which every form's softmax takes from the op's
+    weights. The int8 form is for equal widths."""
 
     heads: int
     head_dim: int
     query_heads: int = 0
+    value_dim: int = dataclasses.field(default=0, kw_only=True)
+    sink: bool = dataclasses.field(default=False, kw_only=True)
     # devices the model's programs run over: the chunk's kernel has no
     # ``shard_map`` composition and is one device's (``kernels.use_pallas``)
     devices: int = dataclasses.field(default=1, kw_only=True, compare=False)
@@ -383,13 +403,23 @@ class PairEntry(EntryKind):
                              f"graph's positions input")
         grouped = op.num_kv_heads != op.num_heads
         heads = (op.num_kv_heads, op.head_dim, op.num_heads if grouped else 0)
+        more = dict(
+            value_dim=op.v_head_dim if op.v_head_dim != op.head_dim else 0,
+            sink=op.sinks)
         if op.window:
-            return WindowEntry(*heads, op.window)
-        return cls(*heads)
+            return WindowEntry(*heads, op.window, **more)
+        return cls(*heads, **more)
+
+    @property
+    def v_dim(self) -> int:
+        return self.value_dim or self.head_dim
 
     @property
     def int8_form(self):
-        return Int8PairEntry(self.heads, self.head_dim, self.query_heads)
+        if self.value_dim:
+            return None            # quantized heads are of one width
+        return Int8PairEntry(self.heads, self.head_dim, self.query_heads,
+                             sink=self.sink)
 
     def over(self, devices):
         return dataclasses.replace(self, devices=int(devices))
@@ -407,45 +437,58 @@ class PairEntry(EntryKind):
         return "kernel" if self.devices == 1 and chunk_attention.supported(
             (prompts, chunk, self.query_heads or self.heads, self.head_dim),
             dtype, (prompts, self._chunk_keys(entry, chunk, max_blocks),
-                    rows.shape[-1]), rows.dtype) else "scan"
+                    rows.shape[-1]), rows.dtype,
+            entry[1].shape[-1]) else "scan"
 
     def stats(self):
-        if not self.query_heads:
-            return {"entry": self.name}
-        return {"entry": self.name, "kv_heads": self.heads,
-                "query_heads": self.query_heads}
+        out = {"entry": self.name}
+        if self.query_heads:
+            out.update(kv_heads=self.heads, query_heads=self.query_heads)
+        if self.value_dim or self.sink:
+            out.update(kv_heads=self.heads, key_dim=self.head_dim,
+                       value_dim=self.v_dim, sink=self.sink)
+        return out
 
     def arenas(self, num_blocks, block_size, dtype):
-        a = jax.ShapeDtypeStruct(
-            (num_blocks, block_size, self.heads * self.head_dim), dtype)
-        return (a, a)
+        return tuple(jax.ShapeDtypeStruct(
+            (num_blocks, block_size, self.heads * d), dtype)
+            for d in (self.head_dim, self.v_dim))
 
     @sub_scope("write")
     def write(self, entry, flat, kh, vh):
-        """T new (T, H, D) keys and values at flat token slots (T,)."""
+        """T new (T, H, D) keys and (T, H, Dv) values at flat token slots
+        (T,)."""
         t = kh.shape[0]
         k, v = entry
-        return (_put(k, flat, kh.reshape(t, -1)),
+        return (_put(k, flat, self._key_rows(kh.reshape(t, -1))),
                 _put(v, flat, vh.reshape(t, -1)))
+
+    def _key_rows(self, rows):
+        """(..., H D) keys, heads side by side, as their arena lays them."""
+        return paged_attention.split_heads(rows, self.heads)
 
     def read(self, entry, tables):
         """Each slot's logical (max_blocks * block_size, H, D) keys and
         values, gathered through its table: what the kernel is checked
         against, and what runs where it does not."""
-        return tuple(self._view(a, tables) for a in entry)
+        return (self._view(entry[0], tables, keys=True),) + tuple(
+            self._view(a, tables) for a in entry[1:])
 
-    def _view(self, arena, tables):
-        return arena[tables].reshape(tables.shape[0], -1, self.heads,
-                                     arena.shape[-1] // self.heads)
+    def _view(self, arena, tables, keys=False):
+        rows = arena[tables]
+        if keys:
+            rows = paged_attention.join_heads(rows, self.heads)
+        return rows.reshape(tables.shape[0], -1, self.heads,
+                            arena.shape[-1] // self.heads)
 
     def reads_in_place(self, op, entry, slots, window, max_blocks):
         return paged_attention.supported(
             (slots, window, self.query_heads or self.heads, self.head_dim),
-            entry[0].shape, entry[0].dtype, max_blocks)
+            entry[0].shape, entry[0].dtype, max_blocks, entry[1].shape[-1])
 
     def decode_chunk_tokens(self, entry, max_blocks):
         return paged_attention.chunk_tokens(entry[0].shape, entry[0].dtype,
-                                            max_blocks)
+                                            max_blocks, entry[1].shape[-1])
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """A bucket's prompts whole (:meth:`whole`), their rows scattered
@@ -462,10 +505,11 @@ class PairEntry(EntryKind):
         visited), else a span at a time as far as that."""
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
         ctxv, entry = self._chunk_rows(op, qh, kh, vh, entry, addr, offsets,
-                                       lengths)
+                                       lengths, op.sink(weights))
         return op.project_out(weights, ctxv, x), entry
 
-    def _chunk_rows(self, op, qh, kh, vh, entry, addr, offsets, lengths):
+    def _chunk_rows(self, op, qh, kh, vh, entry, addr, offsets, lengths,
+                    sink=None):
         """:meth:`chunk` behind the projections: the chunk's (P, S, Hkv,
         D) keys and values written, its (P, S, H, D) queries attended;
         returns (the attended values, the entry)."""
@@ -482,40 +526,40 @@ class PairEntry(EntryKind):
                          NULL_BLOCK * bs)
         entry = self.write(entry, flat.reshape(-1),
                            kh.reshape(n * s, heads, hdim),
-                           vh.reshape(n * s, heads, hdim))
+                           vh.reshape(n * s, heads, -1))
         if self.chunk_path(entry, n, s, mb, qh.dtype) == "kernel":
             with sub_scope("attend"):
                 at = _iota(mb * bs)[None, :]
                 ctxv = _attend_kernel(
                     op, qh, pos, *(a[tables].reshape(n, mb * bs, -1)
                                    for a in entry),
-                    jnp.where(at < (offsets + lengths)[:, None], at, NOWHERE))
+                    jnp.where(at < (offsets + lengths)[:, None], at, NOWHERE),
+                    sink)
             return ctxv, entry
         per = max(1, SPAN_TOKENS // bs)            # blocks a span
         span = per * bs
         padded = jnp.pad(tables, ((0, 0), (0, -mb % per)),
                          constant_values=NULL_BLOCK)
-        keys, values = entry
 
         def read(j):
             blocks = jax.lax.dynamic_slice_in_dim(padded, j * per, per, 1)
-            rows = (n, span, heads, hdim)
-            return (keys[blocks].reshape(rows), values[blocks].reshape(rows),
-                    jnp.broadcast_to(j * span + _iota(span), (n, span)))
+            return self.read(entry, blocks) + (
+                jnp.broadcast_to(j * span + _iota(span), (n, span)),)
 
         with sub_scope("attend"):
             ctxv = _attend_spans(
                 op, qh, pos, heads, read, 0,
                 jnp.maximum((jnp.max(offsets + lengths) + span - 1) // span,
-                            1))
+                            1), sink, self.v_dim)
         return ctxv, entry
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
-        ctxv, entry = self._step_rows(op, qh, kh, vh, entry, addr, seq_lens)
+        ctxv, entry = self._step_rows(op, qh, kh, vh, entry, addr, seq_lens,
+                                      op.sink(weights))
         return op.project_out(weights, ctxv, x), entry
 
-    def _step_rows(self, op, qh, kh, vh, entry, addr, seq_lens):
+    def _step_rows(self, op, qh, kh, vh, entry, addr, seq_lens, sink=None):
         """:meth:`step` behind the projections: the W new (N, W, Hkv, D)
         keys and values written through the tables, the (N, W, H, D)
         queries attended; returns (the attended values, the entry)."""
@@ -533,20 +577,20 @@ class PairEntry(EntryKind):
                          NULL_BLOCK * bs)
         entry = self.write(entry, flat.reshape(-1),
                            kh.reshape(n * w, heads, hdim),
-                           vh.reshape(n * w, heads, hdim))
+                           vh.reshape(n * w, heads, -1))
         with sub_scope("attend"):
             if self.reads_in_place(op, entry, n, w, mb):
                 # the kernel walks each slot's live blocks in the arena
                 # itself
                 ctxv = paged_attention.paged_attention_decode(
                     qh, entry[0], entry[1], tables, seq_lens,
-                    scale=op.scale).astype(qh.dtype)
+                    scale=op.scale, sink=sink).astype(qh.dtype)
             else:
                 k, v = self.read(entry, tables)             # (n, L, H, D)
                 ctxv = _attend(
                     qh, k, v, lambda: (_iota(k.shape[1])[None, None, :]
                                        <= pos[:, :, None])[:, None, :, :],
-                    op.scale)
+                    op.scale, sink)
         return ctxv, entry
 
     def whole(self, op, weights, x, positions):
@@ -560,20 +604,21 @@ class PairEntry(EntryKind):
                            qpos=pos[:, None])[None, None, :, :]
 
         with sub_scope("attend"):
-            ctxv = _attend(qh, kh, vh, causal, op.scale)
+            ctxv = _attend(qh, kh, vh, causal, op.scale, op.sink(weights))
         return op.project_out(weights, ctxv, x), (kh, vh), pos
 
     def dense_shapes(self, batch, max_length, dtype):
-        a = jax.ShapeDtypeStruct(
-            (batch, max_length, self.heads, self.head_dim), dtype)
-        return (a, a)
+        return tuple(jax.ShapeDtypeStruct(
+            (batch, max_length, self.heads, d), dtype)
+            for d in (self.head_dim, self.v_dim))
 
     def dense_step(self, op, weights, x, positions, cache, offset):
         qh, kh, vh = op.project_qkv(weights, x, x, x, positions)
-        ctxv, cache = self._dense_rows(op, qh, kh, vh, cache, offset)
+        ctxv, cache = self._dense_rows(op, qh, kh, vh, cache, offset,
+                                       op.sink(weights))
         return op.project_out(weights, ctxv, x), cache
 
-    def _dense_rows(self, op, qh, kh, vh, cache, offset):
+    def _dense_rows(self, op, qh, kh, vh, cache, offset, sink=None):
         """:meth:`dense_step` behind the projections: the block's keys
         and values written at ``offset``, its queries attended; returns
         (the attended values, the cache)."""
@@ -590,7 +635,7 @@ class PairEntry(EntryKind):
                 qh, kcache, vcache,
                 lambda: op.sees(kpos=_iota(kcache.shape[1])[None, :],
                                 qpos=(offset + _iota(qh.shape[1]))[:, None]
-                                )[None, None, :, :], op.scale)
+                                )[None, None, :, :], op.scale, sink)
         return ctxv, (kcache, vcache)
 
 
@@ -673,10 +718,8 @@ class WindowEntry(PairEntry):
         return self.window // block_size
 
     def arenas(self, rows, block_size, dtype):
-        a = jax.ShapeDtypeStruct(
-            (rows * self.ring_blocks(block_size), block_size,
-             self.heads * self.head_dim), dtype)
-        return (a, a)
+        return super().arenas(rows * self.ring_blocks(block_size),
+                              block_size, dtype)
 
     def _chunk_keys(self, entry, chunk, max_blocks):
         return self.window + chunk
@@ -690,7 +733,7 @@ class WindowEntry(PairEntry):
         return window == 1 and paged_attention.supported(
             (slots, 1, self.query_heads or self.heads, self.head_dim),
             entry[0].shape, entry[0].dtype,
-            self.ring_blocks(entry[0].shape[1]))
+            self.ring_blocks(entry[0].shape[1]), entry[1].shape[-1])
 
     def decode_chunk_tokens(self, entry, max_blocks):
         return super().decode_chunk_tokens(
@@ -705,17 +748,18 @@ class WindowEntry(PairEntry):
                            kh[:, 0], vh[:, 0])
         tables = self._tables(entry, addr.rows)
         held = jnp.minimum(seq_lens, w - 1)     # rows before the new one
+        sink = op.sink(weights)
         with sub_scope("attend"), sub_scope("window"):
             if self.reads_in_place(op, entry, n, 1, 0):
                 ctxv = paged_attention.paged_attention_decode(
                     qh, entry[0], entry[1], tables, held,
-                    scale=op.scale).astype(qh.dtype)
+                    scale=op.scale, sink=sink).astype(qh.dtype)
             else:
                 k, v = self.read(entry, tables)             # (n, W, H, D)
                 ctxv = _attend(
                     qh, k, v, lambda: (_iota(w)[None, None, :]
                                        <= held[:, None, None])[:, None, :, :],
-                    op.scale)
+                    op.scale, sink)
         return op.project_out(weights, ctxv, x), entry
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
@@ -741,23 +785,27 @@ class WindowEntry(PairEntry):
         # all heads side by side: splitting the heads first would copy
         # the whole arena into another tiling)
         keys, values = (
-            jnp.concatenate([a.reshape(-1, w, heads * hdim)[addr.rows],
-                             new.reshape(n, s, -1).astype(a.dtype)], axis=1)
-            for a, new in zip(entry, (kh, vh)))
+            jnp.concatenate([a.reshape(-1, w, a.shape[-1])[addr.rows],
+                             new.astype(a.dtype)], axis=1)
+            for a, new in zip(entry, (self._key_rows(kh.reshape(n, s, -1)),
+                                      vh.reshape(n, s, -1))))
         kpos = jnp.concatenate([jnp.where(held >= 0, held, NOWHERE),
                                 jnp.where(live, pos, NOWHERE)], axis=1)
+        sink = op.sink(weights)
         with sub_scope("attend"), sub_scope("window"):
             # a group of first chunks' rings hold nothing, a short last
             # chunk's rows past its length neither: the kernel visits
             # none of their blocks, the walk skips their spans
             if self.chunk_path(entry, n, s, 0, qh.dtype) == "kernel":
-                ctxv = _attend_kernel(op, qh, pos, keys, values, kpos)
+                ctxv = _attend_kernel(op, qh, pos, keys, values, kpos, sink)
             else:
                 span = SPAN_TOKENS
                 pad = -(w + s) % span
                 keys, values = (
                     jnp.pad(a, ((0, 0), (0, pad), (0, 0))).reshape(
-                        n, -1, heads, hdim) for a in (keys, values))
+                        n, -1, heads, a.shape[-1] // heads)
+                    for a in (paged_attention.join_heads(keys, heads),
+                              values))
                 kpos = jnp.pad(kpos, ((0, 0), (0, pad)),
                                constant_values=NOWHERE)
 
@@ -769,12 +817,13 @@ class WindowEntry(PairEntry):
                 ctxv = _attend_spans(
                     op, qh, pos, heads, read,
                     jnp.where(jnp.all(offsets == 0), w // span, 0),
-                    (w + jnp.max(lengths) + span - 1) // span)
+                    (w + jnp.max(lengths) + span - 1) // span, sink,
+                    self.v_dim)
         keep = live & (pos >= (offsets + lengths)[:, None] - w)
         flat = jnp.where(keep, addr.rows[:, None], NULL_ROW) * w + pos % w
         entry = self.write(entry, flat.reshape(-1),
                            kh.reshape(n * s, heads, hdim),
-                           vh.reshape(n * s, heads, hdim))
+                           vh.reshape(n * s, heads, -1))
         return op.project_out(weights, ctxv, x), entry
 
 

@@ -632,6 +632,7 @@ class PagedDecoder(_DecodeGraph):
         # kernel brings (the narrowest of the ops' kernels; None where
         # the step gathers)
         self.attention_path: Dict[str, Union[None, str, int]] = {}
+        self.attention_path_by_entry: Dict[str, Dict] = {}
         self._set_attention_path()
         self._prefill_fns: Dict[Tuple[int, int], object] = {}
         # how the prefill programs run the recurrence of the ops that
@@ -963,25 +964,35 @@ class PagedDecoder(_DecodeGraph):
 
     def _set_attention_path(self) -> None:
         """The decode and chunk programs' entries of ``attention_path``,
-        from the pool as it is now."""
-        decode = self._attention_path(1)
+        from the pool as it is now: ``"kernel"`` where every op's entry,
+        of whatever kind, takes it; and the same by kind of entry
+        (``attention_path_by_entry``: a model's full layers beside its
+        windowed ones), so that a chip run says what it timed in each."""
+        by: Dict[str, Dict[str, Optional[str]]] = {}
+        for op in self._attn_ops:
+            kind, entry = self.pool.kinds[op.name], self.pool.kv[op.name]
+            said = by.setdefault(kind.name, {
+                "decode": "kernel",
+                "chunk": "kernel" if self.prefill_chunk else None})
+            if not kind.reads_in_place(op, entry, self.decode_slots, 1,
+                                       self.max_blocks_per_request):
+                said["decode"] = "gather"
+            if self.prefill_chunk and kind.chunk_path(
+                    entry, 1, self.prefill_chunk,
+                    self.max_blocks_per_request,
+                    self._compute_dtype() or jnp.float32) != "kernel":
+                said["chunk"] = "scan"
+        self.attention_path_by_entry = by
+        decode = ("gather" if any(s["decode"] == "gather"
+                                  for s in by.values()) else "kernel")
+        chunk = ("scan" if any(s["chunk"] == "scan" for s in by.values())
+                 else "kernel") if self.prefill_chunk else None
         chunks = [self.pool.kinds[op.name].decode_chunk_tokens(
             self.pool.kv[op.name], self.max_blocks_per_request)
             for op in self._attn_ops] if decode == "kernel" else []
         self.attention_path.update(
-            decode=decode, chunk=self._chunk_path(),
+            decode=decode, chunk=chunk,
             decode_chunk_tokens=min((c for c in chunks if c), default=None))
-
-    def _chunk_path(self) -> Optional[str]:
-        """What a chunk program does with the pool as it is now."""
-        if not self.prefill_chunk:
-            return None
-        return "kernel" if all(
-            self.pool.kinds[op.name].chunk_path(
-                self.pool.kv[op.name], 1, self.prefill_chunk,
-                self.max_blocks_per_request,
-                self._compute_dtype() or jnp.float32) == "kernel"
-            for op in self._attn_ops) else "scan"
 
     def _prefill_path(self, bucket: int) -> Optional[str]:
         said = {kind.prefill_path(bucket) for kind in self.pool.kinds.values()}

@@ -465,6 +465,12 @@ class PagedKVPool:
             out.update(s)
         out["entry"] = {name: sum(s["entry"] == name for s in said)
                         for name in dict.fromkeys(s["entry"] for s in said)}
+        # where two kinds say one word differently (the key-value heads of
+        # a model's full and windowed layers), each kind's own words
+        words = {s["entry"]: {k: v for k, v in s.items() if k != "entry"}
+                 for s in said}
+        if any(out[k] != v for w in words.values() for k, v in w.items()):
+            out["by_entry"] = words
         return out
 
 

@@ -143,7 +143,10 @@ class _LoopClock:
         # (and the keys those tokens' queries saw in an op that keeps
         # everything, and in one that keeps a window)
         self.chunks = {"prefill_chunks": 0, "prefill_tokens": 0,
-                       "prefill_keys": 0, "prefill_keys_window": 0}
+                       "prefill_keys": 0, "prefill_keys_window": 0,
+                       # ... and of the chunks that were their prompt's
+                       # last, the only ones whose last layer attends
+                       "prefill_tokens_last": 0, "prefill_keys_last": 0}
 
     def enter(self, phase: Optional[str]):
         """The thread is in ``phase`` from now on. Returns the boundary
@@ -172,12 +175,15 @@ class _LoopClock:
             self.ahead[key] += n
 
     def count_chunk(self, tokens: int, keys: int = 0,
-                    window_keys: int = 0) -> None:
+                    window_keys: int = 0, last: bool = False) -> None:
         with self._lock:
             self.chunks["prefill_chunks"] += 1
             self.chunks["prefill_tokens"] += tokens
             self.chunks["prefill_keys"] += keys
             self.chunks["prefill_keys_window"] += window_keys
+            if last:
+                self.chunks["prefill_tokens_last"] += tokens
+                self.chunks["prefill_keys_last"] += keys
 
     def snapshot(self) -> Dict:
         """``stats()["loop"]``; the phase that is open is charged up to
@@ -722,7 +728,8 @@ class ContinuousBatchingScheduler:
             self._prefilling.popleft()
             self._fail_prefill([req], e)
             return
-        self._clock.count_chunk(n, *self.decoder.pool.chunk_keys(at, n))
+        self._clock.count_chunk(n, *self.decoder.pool.chunk_keys(at, n),
+                                last=last)
         self.decoder.pool.count_chunk(at)
         with self._mu:
             self._prefill_dispatches += 1
@@ -1343,6 +1350,9 @@ class ContinuousBatchingScheduler:
         # decoder's own words beside them
         kv = self.decoder.pool.stats(lengths)
         kv["attention_path"] = dict(self.decoder.attention_path)
+        kv["attention_path_by_entry"] = {
+            k: dict(v) for k, v in
+            self.decoder.attention_path_by_entry.items()}
         if "state" in kv:
             kv["state"]["prefill_path"] = self.decoder.prefill_path
         if self.decoder.kv_divergence is not None:

@@ -38,7 +38,8 @@ def test_the_share_is_the_windows_delta_over_the_expert_layers(
     assert reader.read({"facts": {}}) is None
 
 
-def test_the_entry_lists_the_four_routed_cells():
+def test_the_entry_lists_the_routed_cells():
+    """PR 54's four, and since PR 55 the MiMo cell, appended."""
     (entry,) = [m for m in Layout().bench["per_layer"]
                 if m["name"] == "decode_experts_kernel_share"]
     assert entry == {
@@ -48,4 +49,5 @@ def test_the_entry_lists_the_four_routed_cells():
         "workloads": ["axk1-ep16.serve-reasoning",
                       "nemotron3-super-ep4.serve-agents",
                       "trinity-large-ep8.serve-mixedlengths",
-                      "zaya1-8b-pp2.serve-chains"]}
+                      "zaya1-8b-pp2.serve-chains",
+                      "mimo-v2.5-ep16.serve-codebases"]}

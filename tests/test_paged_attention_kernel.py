@@ -41,10 +41,13 @@ class _Op:
     qk_norm = False
     rotary = None
     gate = False
+    sinks = False
+    value_scale = None
     head_dim = HEAD_DIM
     _scale = None                      # no scale of the model's own
     scale = MultiHeadAttention.scale
     project_qkv = MultiHeadAttention.project_qkv
+    sink = MultiHeadAttention.sink
     project_out = MultiHeadAttention.project_out
     _project_out = MultiHeadAttention._project_out
 

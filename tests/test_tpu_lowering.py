@@ -1339,3 +1339,148 @@ def test_zaya_chunk_programs_name_their_pieces(zaya_programs, name):
     assert has_head == (name == "chunk_head")
     assert mem.alias_size_in_bytes >= zaya_programs[1].pool.memory_bytes()
     assert mem.temp_size_in_bytes < 2 << 30
+
+
+# ---- MiMo-V2.5: keys of 192 beside values of 128, a sink, two head counts -----
+
+@pytest.fixture(scope="module")
+def mimo_programs(one_chip):
+    """The decode step and both chunk programs of one dense full layer,
+    one windowed and one full expert layer at MiMo-V2.5's published
+    widths and its cell's sizes (32 slots, contexts to 33,792, blocks of
+    64, chunks of 2,048, 16 of 256 experts held, an eighth of the
+    vocabulary), compiled for the described chip: {name: (the compiled
+    text, its memory analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import mimo as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2.5-ep16.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=3, hybrid_layer_pattern=[0, 1, 0],
+                  moe_layer_freq=[0, 1, 1])
+    slots, max_length, chunk = 32, 33792, 2048
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=64, kv_dtype="bfloat16",
+                               calibrate=False, prefill_chunk=chunk)
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, head in (("chunk", False), ("chunk_head", True)):
+                compiled = jax.jit(
+                    lambda *a, head=head: dec._chunk_step(*a, head=head),
+                    donate_argnums=(2,)).lower(
+                    params, ints(1, chunk), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1),
+                    ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+def test_mimo_decode_step_reads_both_kinds_by_the_kernel(mimo_programs):
+    """The decode step of full layers (64 query heads on 4 key-value heads,
+    16 a group) and a windowed one (64 on 8, a ring of two blocks behind a
+    sink) at keys of 192 beside values of 128: no ``while``, no
+    ``conditional``; the paged kernel once an attention layer, both kinds
+    (``attention_path`` says ``kernel`` of each); the arenas are two
+    widths, the keys' rows 192 a head and not 256: nothing is padded in
+    HBM; the only scatters are the new token's keys and values; the pool
+    aliases its outputs."""
+    programs, dec = mimo_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path == {
+        "decode": "kernel", "chunk": "kernel", "decode_chunk_tokens": 128}
+    assert dec.attention_path_by_entry == {
+        "pair": {"decode": "kernel", "chunk": "kernel"},
+        "window": {"decode": "kernel", "chunk": "kernel"}}
+    assert " while(" not in text and " conditional(" not in text
+    for ln in text.splitlines():
+        if " scatter(" in ln:
+            assert re.search(r"ff\.MULTIHEAD_ATTENTION\.block\d_attn/write",
+                             ln), ln
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum("paged_attention_decode" in c for c in calls) == 3
+    assert sum("grouped_experts" in c for c in calls) == 2
+    shapes = {name: tuple(a.shape for a in e)
+              for name, e in dec.pool.kv.items()}
+    assert shapes["block0_attn"] == shapes["block2_attn"] == (
+        (32 * 528 + 1, 64, 4 * 192), (32 * 528 + 1, 64, 4 * 128))
+    assert shapes["block1_attn"] == ((33 * 2, 64, 8 * 192),
+                                     (33 * 2, 64, 8 * 128))
+    # 5,120 B a token in the paged pool at the cell's two full layers
+    assert dec.pool.kinds["block0_attn"].token_bytes(jnp.bfloat16) == 2560
+    assert dec.pool.kinds["block1_attn"].token_bytes(jnp.bfloat16) \
+        == 128 * 8 * 320 * 2
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head"])
+def test_mimo_chunk_programs_hold_no_square(mimo_programs, name):
+    """A chunk of 2,048 queries over up to 33,792 keys: the attention of
+    each layer that attends is ONE Mosaic call, ``chunk_attention``, its
+    scores in VMEM (no ``while``: the span walk is not taken at a key
+    width of one and a half lane tiles), named ``attend`` in a full layer
+    and ``window`` inside ``attend`` in the windowed one."""
+    from flexflow_tpu.core.op import parse_scope
+
+    text, mem = mimo_programs[0][name]
+    mosaic = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    attends = [ln for ln in mosaic if "chunk_attention" in ln]
+    # (a chunk that is not its prompt's last ends behind the last
+    # attention op's write: nothing reads what that op would attend)
+    assert len(attends) == (3 if name == "chunk_head" else 2)
+    assert " while(" not in text
+    assert {parse_scope(re.search(r'op_name="([^"]+)"', ln).group(1))[2]
+            for ln in attends} == {("attend",), ("attend", "window")}
+    owners = {parse_scope(m) for m in re.findall(r'op_name="([^"]+)"', text)
+              } - {None}
+    subs = {(kind, s) for kind, _, ss, _ in owners for s in ss}
+    assert {("MULTIHEAD_ATTENTION", "window"),
+            ("MULTIHEAD_ATTENTION", "attend"),
+            ("MULTIHEAD_ATTENTION", "project"),
+            ("MULTIHEAD_ATTENTION", "write"),
+            ("ROUTED_EXPERTS", "route"),
+            ("ROUTED_EXPERTS", "experts")} <= subs, subs
+    assert mem.temp_size_in_bytes < 2 << 30
