@@ -463,9 +463,9 @@ def check_latent_attention(slots: int, heads: int, rank: int, rope: int,
     """The latent-attention decode kernel against the gather-and-softmax
     it replaces, in float32 at ``highest`` precision over the same arena
     of rows ``[c | k_rope | 0...]``: slots of ragged lengths (one
-    inactive, one full), tables over shuffled blocks, garbage in the null
-    block. Returns the largest error as a share of the reference's
-    largest magnitude."""
+    inactive, one full), tables over shuffled blocks and over stretches
+    of neighbours, garbage in the null block. Returns the largest error
+    as a share of the reference's largest magnitude."""
     import jax
     import jax.numpy as jnp
 
@@ -484,8 +484,17 @@ def check_latent_attention(slots: int, heads: int, rank: int, rope: int,
     length = max_blocks * block_size
     lens = rng.integers(1, length - 1, size=slots).astype(np.int32)
     lens[0], lens[-1] = 0, length - 1
-    tables = rng.permutation(np.arange(1, nb)).astype(np.int32).reshape(
-        slots, max_blocks)
+    tables = np.arange(1, nb, dtype=np.int32).reshape(slots, max_blocks)
+    for i, blocks in enumerate(tables):
+        if i % 2:
+            blocks[:] = rng.permutation(blocks)
+            continue
+        # stretches of 3 to 40 neighbours ascending, which the kernel
+        # fetches a group by one copy, seams apart
+        cuts = np.cumsum(rng.integers(3, 41, size=max_blocks // 3))
+        stretches = np.split(blocks.copy(), cuts[cuts < max_blocks])
+        blocks[:] = np.concatenate(
+            [stretches[j] for j in rng.permutation(len(stretches))])
     tables[0] = 0
     pad = np.zeros((1, 1, row), np.float32)
     pad[..., :rank + rope] = 1.0          # the arena's padding lanes are 0

@@ -238,7 +238,9 @@ class EntryKind:
     * ``reads_in_place(op, entry, slots, window, max_blocks)``: whether a
       ``window``-token step reads ``entry`` by a kernel, in place, and
       ``decode_chunk_tokens(entry, max_blocks)``: the tokens one loop
-      iteration of that kernel brings (None: it walks no chunks);
+      iteration of that kernel brings (None: it walks no chunks), and
+      ``fetch_run_blocks(entry, max_blocks)``: the neighbouring blocks
+      one copy of it brings;
     * ``step(op, weights, x, positions, entry, addr, seq_lens)``: W new
       tokens a slot at positions ``seq_lens .. seq_lens + W - 1``, their
       rows written through the tables (an idle slot's, and positions past
@@ -285,6 +287,11 @@ class EntryKind:
 
     def decode_chunk_tokens(self, entry, max_blocks: int) -> Optional[int]:
         return None
+
+    def fetch_run_blocks(self, entry, max_blocks: int) -> int:
+        """Table entries ONE copy of the kind's decode kernel brings where
+        they are neighbours ascending in the arena; 1: a copy a block."""
+        return 1
 
     def blocks_read(self, length: int) -> Optional[int]:
         """Blocks of a request's table a step behind ``length`` cached
@@ -994,6 +1001,10 @@ class LatentEntry(EntryKind):
         block_size = entry[0].shape[1]
         return block_size * latent_attention._pages_per_chunk(block_size,
                                                               max_blocks)
+
+    def fetch_run_blocks(self, entry, max_blocks):
+        return latent_attention.run_blocks(entry[0].shape, entry[0].dtype,
+                                           max_blocks)
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         """The absorbed form: per head the query over a row's lanes is

@@ -158,6 +158,18 @@ def pool_bytes(specs, num_blocks: int, block_size: int,
             + int(num_rows) * per_row)
 
 
+def run_groups(blocks, run: int) -> Tuple[int, int]:
+    """Of a table's entries ``blocks``: its whole groups of ``run``
+    entries, and those of them that are neighbours ascending in the
+    arena, which ONE copy fetches."""
+    whole = len(blocks) // run
+    if run == 1 or not whole:
+        return whole, whole
+    groups = np.asarray(blocks[:whole * run], np.int64).reshape(whole, run)
+    return whole, int((groups == groups[:, :1] + np.arange(run))
+                      .all(axis=1).sum())
+
+
 class PagedKVPool:
     """Block pool + allocator for one model's attention ops.
 
@@ -221,8 +233,19 @@ class PagedKVPool:
                     self.block_size, store)
                 + kind.request_arenas(self.num_rows, store))
         # LIFO free list: freshly freed blocks are reused first (their
-        # stale contents are masked by position either way)
+        # stale contents are masked by position either way). A freed table
+        # goes back reversed, so that what pops next comes in the order the
+        # table had it: a request's table is a few ascending stretches of
+        # earlier tables, which a kernel that fetches neighbours by ONE
+        # copy reads as runs (kernels/latent_attention.py)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        # the table entries ONE such copy brings (1: a copy a block), and
+        # of the tables handed out their whole groups of so many entries
+        # and those that are neighbours ascending
+        self._run_blocks = max(
+            kind.fetch_run_blocks(self.kv[name], self.max_blocks_per_request)
+            for name, kind in self.kinds.items())
+        self._fetch_runs = {"groups": 0, "groups_run": 0}
         # the same for rows (a prefill overwrites the whole row), and the
         # row each live request holds, by the request's first block
         self._free_rows: List[int] = list(range(self.num_rows - 1, 0, -1))
@@ -306,6 +329,9 @@ class PagedKVPool:
                                           and not self._free_rows):
                 return None
             blocks = [self._free.pop() for _ in range(need)]
+            whole, as_one = run_groups(blocks, self._run_blocks)
+            self._fetch_runs["groups"] += whole
+            self._fetch_runs["groups_run"] += as_one
             used = self.capacity_blocks - len(self._free)
             if used > self._high_water:
                 self._high_water = used
@@ -333,7 +359,7 @@ class PagedKVPool:
                         f"holds no state row")
                 self._row_of_block[blocks[0]] = NULL_ROW
                 self._free_rows.append(row)
-            self._free.extend(blocks)
+            self._free.extend(reversed(blocks))
             if len(self._free) > self.capacity_blocks:
                 raise RuntimeError(
                     f"double free: {len(self._free)} free blocks > "
@@ -401,6 +427,7 @@ class PagedKVPool:
             used = self.capacity_blocks - len(self._free)
             hw = self._high_water
             steps = dict(self._steps, **self._chunk_rows)
+            fetch_runs = dict(self._fetch_runs, run_blocks=self._run_blocks)
             reads = {word: dict(sums) for word, sums in self._reads.items()}
             state = {"state": {
                 "rows": self.num_rows,
@@ -418,6 +445,9 @@ class PagedKVPool:
             "high_water": hw,
             "memory_bytes": int(self.memory_bytes()),
             "kv_dtype": self.kv_dtype,
+            # of the tables handed out so far: their whole groups of
+            # ``run_blocks`` entries, and those ONE copy fetches
+            "fetch_runs": fetch_runs,
             # what an op keeps, in its kind's words: "pair" (k, v),
             # "int8" (values and sidecars), "latent" (one row, its width
             # as cached and as the arena pads it) or "state" (a row a
@@ -475,4 +505,4 @@ class PagedKVPool:
 
 
 __all__ = ["Addresses", "KV_DTYPES", "NULL_BLOCK", "NULL_ROW", "PagedKVPool",
-           "KVPoolExhausted", "pool_bytes", "stored_as"]
+           "KVPoolExhausted", "pool_bytes", "run_groups", "stored_as"]

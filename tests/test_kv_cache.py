@@ -9,7 +9,8 @@ import jax.numpy as jnp
 from flexflow_tpu.obs.metrics import metrics_registry
 from flexflow_tpu.serving.cache_entry import PairEntry, StateEntry
 from flexflow_tpu.serving.errors import KVPoolExhausted, ShedError
-from flexflow_tpu.serving.kv_cache import NULL_BLOCK, PagedKVPool
+from flexflow_tpu.serving.kv_cache import (NULL_BLOCK, PagedKVPool,
+                                           run_groups)
 
 
 def _pool(num_blocks=9, block_size=4, max_blocks=4, **kw):
@@ -104,6 +105,186 @@ def test_double_free_is_loud():
     p.free(t)
     with pytest.raises(RuntimeError, match="double free"):
         p.free(t)
+
+
+# ---- tables made of ascending stretches ------------------------------------------
+
+@pytest.mark.parametrize("frees, asks, want", [
+    # the same need again: the same table
+    ([0], [16], [[1, 2, 3, 4]]),
+    # less, then the rest: one freed table's stretch carried on
+    ([0], [8, 8], [[1, 2], [3, 4]]),
+    # across a seam: the table freed last first, then the one before it
+    ([0, 1], [28], [[5, 6, 7, 1, 2, 3, 4]]),
+    ([1, 0], [20], [[1, 2, 3, 4, 5]]),
+])
+def test_a_freed_table_comes_out_ascending_again(frees, asks, want):
+    """Blocks go back so that the next tables pop a freed table's blocks
+    in the order the table had them: ascending stretches, seams between
+    them, never a stretch backwards."""
+    p = _pool(max_blocks=8)
+    tables = [p.try_admit(16), p.try_admit(12)]
+    assert list(tables[0][:4]) == [1, 2, 3, 4]
+    assert list(tables[1][:3]) == [5, 6, 7]
+    for i in frees:
+        p.free(tables[i])
+    for ask, blocks in zip(asks, want):
+        t = p.try_admit(ask)
+        assert [int(b) for b in t if b != NULL_BLOCK] == blocks
+    # pairs of (k, v): a copy a block, every entry a group of its own
+    handed = 7 + sum(len(b) for b in want)
+    assert p.stats()["fetch_runs"] == {"groups": handed, "run_blocks": 1,
+                                       "groups_run": handed}
+
+
+def _latent_pool(**kw):
+    from flexflow_tpu.serving.cache_entry import LatentEntry
+
+    # rows of 576 in 640 lanes, blocks of 16, bfloat16: the reasoning
+    # cell's 20 KB a block, four of them a copy
+    return PagedKVPool({"attn": LatentEntry(576)}, num_blocks=65,
+                       block_size=16, max_blocks_per_request=32,
+                       kv_dtype="bfloat16", **kw)
+
+
+def test_fetch_runs_counts_what_a_table_holds():
+    """Whole groups of ``run_blocks`` entries and those that are
+    neighbours ascending, of a hand-made table and of the allocator's
+    own: a seam between two freed tables' stretches breaks the group it
+    falls in and no other."""
+    p = _latent_pool()
+    assert p.stats()["fetch_runs"] == {"groups": 0, "groups_run": 0,
+                                       "run_blocks": 4}
+    assert run_groups([1, 2, 3, 4,  9, 10, 11, 12,  5, 6, 8, 7,
+                       20, 19, 18, 17,  13, 14, 15, 17,  30, 31, 32],
+                      4) == (5, 2)
+    assert run_groups([3, 2, 1], 1) == (3, 3) and run_groups([1, 2], 4) \
+        == (0, 0)
+    t1 = p.try_admit(10 * 16)        # two groups, and 9 10 of a third
+    assert list(t1[:10]) == list(range(1, 11))
+    t2 = p.try_admit(6 * 16)         # a group, and 15 16
+    assert list(t2[:6]) == list(range(11, 17))
+    assert p.in_use() == 16 and p.stats()["in_use"] == 16
+    s = p.stats()["fetch_runs"]
+    assert (s["groups"], s["groups_run"]) == (2 + 1, 2 + 1)
+    p.free(t1)
+    p.free(t2)
+    assert p.in_use() == 0
+    # the table freed last first, each in the order it had: the seam
+    # 16 | 1 lies in the second group, the seam 10 | 17 in t4's only one
+    t3 = p.try_admit(13 * 16)
+    assert list(t3[:13]) == [11, 12, 13, 14, 15, 16, 1, 2, 3, 4, 5, 6, 7]
+    t4 = p.try_admit(7 * 16)
+    assert list(t4[:7]) == [8, 9, 10, 17, 18, 19, 20]
+    s = p.stats()["fetch_runs"]
+    assert (s["groups"], s["groups_run"]) == (3 + 3 + 1, 3 + 2 + 0)
+
+
+def _second_generation(pool, rng, free):
+    """The reasoning mix in small (``benchmark/traffic/
+    serve-reasoning.json``: prompts of 512-1,024 tokens, answers of
+    1,024-3,072, tables reserved whole, a closed loop with a client a
+    slot, two jobs a slot): the share of whole groups that are runs in
+    the tables admitted after the first free."""
+    import heapq
+
+    slots = (pool.num_blocks - 1) // pool.max_blocks_per_request
+    prompts = rng.integers(512, 1025, size=2 * slots)
+    answers = rng.integers(1024, 3073, size=2 * slots)
+    held, done = {}, []
+    for job in range(slots):
+        held[job] = pool.try_admit(int(prompts[job] + answers[job]))
+        heapq.heappush(done, (int(answers[job]), job))
+    first = dict(pool.stats()["fetch_runs"])
+    assert first["groups_run"] == first["groups"] > 0
+    for job in range(slots, 2 * slots):
+        now, finished = heapq.heappop(done)
+        free(pool, held.pop(finished))
+        held[job] = pool.try_admit(int(prompts[job] + answers[job]))
+        heapq.heappush(done, (now + int(answers[job]), job))
+    last = pool.stats()["fetch_runs"]
+    return ((last["groups_run"] - first["groups_run"])
+            / (last["groups"] - first["groups"]))
+
+
+def test_the_second_generation_of_tables_is_runs_still():
+    """What the reversed free is for: the tables a slot's second request
+    is handed are ascending stretches of the tables freed before it, and
+    all but the groups at their seams come by one copy. Handed back in
+    table order, as the pool did until PR 56 (a table freed backwards
+    here), the free list pops them descending and next to no group is a
+    run."""
+    def pool():
+        from flexflow_tpu.serving.cache_entry import LatentEntry
+
+        return PagedKVPool({"attn": LatentEntry(576)},
+                           num_blocks=8 * 256 + 1, block_size=16,
+                           max_blocks_per_request=256, kv_dtype="bfloat16")
+
+    as_it_is = _second_generation(pool(), np.random.default_rng(56),
+                                  lambda p, t: p.free(t))
+    table_order = _second_generation(pool(), np.random.default_rng(56),
+                                     lambda p, t: p.free(t[::-1].copy()))
+    assert as_it_is > 0.9, as_it_is
+    assert table_order < 0.5, table_order
+
+
+def test_a_full_pool_and_exhaustion_are_as_before():
+    p = _latent_pool()
+    a, b, c = (p.try_admit(6 * 16) for _ in range(3))
+    assert [list(t[:6]) for t in (a, b, c)] == [
+        list(range(1, 7)), list(range(7, 13)), list(range(13, 19))]
+    with pytest.raises(KVPoolExhausted, match="max_blocks_per_request"):
+        p.try_admit(33 * 16)
+    big = p.try_admit(32 * 16)
+    assert big is not None and list(big[:32]) == list(range(19, 51)) \
+        and p.in_use() == 50
+    assert p.try_admit(15 * 16) is None          # 14 left: wait
+    last = p.try_admit(14 * 16)
+    assert list(last[:14]) == list(range(51, 65))
+    assert p.in_use() == p.capacity_blocks and p.try_admit(16) is None
+    s = p.stats()["fetch_runs"]
+    # b and c begin off a multiple of four in the arena: a run is
+    # neighbours ascending from wherever the table's group begins
+    assert (s["groups"], s["groups_run"]) == (3 + 8 + 3, 3 + 8 + 3)
+    for table in (a, b, c, big, last):
+        p.free(table)
+    assert p.in_use() == 0
+    with pytest.raises(RuntimeError, match="double free"):
+        p.free(a)
+    assert p.stats()["fetch_runs"]["run_blocks"] == 4
+
+
+@pytest.mark.parametrize("before, after, want", [
+    # every group a run: what one copy brings is the whole group
+    ({"groups": 10, "groups_run": 10}, {"groups": 30, "groups_run": 30}, 4.0),
+    # none: a copy a block
+    ({"groups": 0, "groups_run": 0}, {"groups": 8, "groups_run": 0}, 1.0),
+    # 18 of 20 groups: 80 blocks by 18 + 8 copies
+    ({"groups": 5, "groups_run": 5}, {"groups": 25, "groups_run": 23},
+     80 / 26),
+    # a window that admitted nothing, and a program without the counter
+    ({"groups": 5, "groups_run": 5}, {"groups": 5, "groups_run": 5}, None),
+    (None, None, None),
+])
+def test_blocks_per_fetch_reads_the_windows_tables(before, after, want):
+    """The benchmark's reader over the counter's deltas (the one the
+    reasoning cell reports as ``kv_blocks_per_fetch``)."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "kv_blocks_per_fetch", os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+            "kv_blocks_per_fetch.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    kv = lambda runs: {"kv": dict(  # noqa: E731
+        {"block_size": 16},
+        **({"fetch_runs": dict(runs, run_blocks=4)} if runs else {}))}
+    got = reader.read({"facts": {"stats0": kv(before), "stats1": kv(after)}})
+    assert got == want if want is None else got == pytest.approx(want)
+    assert reader.read({"facts": {}}) is None
 
 
 # ---- per-request rows beside the blocks ------------------------------------------

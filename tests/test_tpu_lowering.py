@@ -228,6 +228,34 @@ def test_ssd_step_decode_compiles_for_v5e(monkeypatch, one_chip,
     assert mem.temp_size_in_bytes < arena_bytes // 8
 
 
+@pytest.mark.parametrize("block, table, run", [(16, 256, 4), (64, 64, 1)])
+def test_latent_attention_decode_compiles_for_v5e(monkeypatch, one_chip,
+                                                  no_compile_cache, block,
+                                                  table, run):
+    """The latent decode kernel at the reasoning cell's shape (128 slots,
+    64 heads over rows of 640 bfloat16 lanes, tables of 4,096 tokens): in
+    blocks of 16 a group of 4 neighbours comes by one copy of a slice of
+    the arena, in blocks of 64 every block by its own; one Mosaic custom
+    call under the name the roofline's reader finds it by."""
+    from flexflow_tpu.kernels import latent_attention as la
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    n, heads, row, width = 128, 64, 640, 512
+    arena = (n * table + 1, block, row)
+    assert la.supported((n, heads, row), arena, jnp.bfloat16, table, width)
+    assert la.run_blocks(arena, jnp.bfloat16, table) == run
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda q, a, t, l: la.latent_attention_decode(
+        q, a, t, l, scale=0.04, out_width=width)).lower(
+        sds((n, heads, row)), sds(arena), sds((n, table), jnp.int32),
+        sds((n,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "latent_attention_decode" in text
+
+
 def test_gated_delta_prefill_compiles_for_v5e(monkeypatch, one_chip,
                                               no_compile_cache):
     """One linear layer's prefill (``GatedDeltaNet.whole``: projections,

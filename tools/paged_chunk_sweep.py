@@ -56,10 +56,12 @@ CASES = {
 }
 
 
-def kernel_device_us(trace_dir: str) -> list:
-    """Device durations (us) of the kernel's events: an event is named by
-    its instruction's whole text, so one that only READS the kernel's
-    output names it too; the kernel's own starts with its name."""
+def kernel_device_us(trace_dir: str,
+                     kernel: str = "paged_attention_decode") -> list:
+    """Device durations (us) of the events of the kernel of that name: an
+    event is named by its instruction's whole text, so one that only
+    READS the kernel's output names it too; the kernel's own starts with
+    its name."""
     from jax.profiler import ProfileData
 
     found = sorted(glob.glob(os.path.join(
@@ -72,8 +74,7 @@ def kernel_device_us(trace_dir: str) -> list:
             if line.name != "XLA Ops":
                 continue
             out.extend(ev.duration_ns / 1e3 for ev in line.events
-                       if ev.name.lstrip("%").startswith(
-                           "paged_attention_decode"))
+                       if ev.name.lstrip("%").startswith(kernel))
     return out
 
 
