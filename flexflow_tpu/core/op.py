@@ -90,8 +90,8 @@ OP_GROUPS: Dict[str, Tuple[OpType, ...]] = {
                   OpType.BLOCK_SPARSE_ATTENTION,
                   OpType.COMPRESSED_CONV_ATTENTION),
     # the types that keep a state a request
-    "state": (OpType.GATED_DELTA_NET, OpType.LIGHTNING_ATTENTION,
-              OpType.MAMBA2),
+    "state": (OpType.GATED_DELTA_NET, OpType.KIMI_DELTA_ATTENTION,
+              OpType.LIGHTNING_ATTENTION, OpType.MAMBA2),
     "matmul": (OpType.LINEAR, OpType.GATED_MLP, OpType.EXPERT_LINEAR,
                OpType.ROUTED_EXPERTS),
 }

@@ -183,6 +183,9 @@ class OpType(enum.Enum):
     # linear attention by the gated delta rule: a state of fixed size a
     # sequence, a short causal convolution before it (Gated DeltaNet)
     GATED_DELTA_NET = "gated_delta_net"
+    # the gated delta rule with a decay a key CHANNEL behind a sigmoid
+    # bounded below, and one output gate a head (Kimi Delta Attention)
+    KIMI_DELTA_ATTENTION = "kimi_delta_attention"
     # causal attention with grouped key-value heads that, past a context
     # length, reads only the key blocks a score over mean-pooled keys
     # selects (InfLLM v2), and an output gate

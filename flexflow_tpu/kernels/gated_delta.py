@@ -121,9 +121,13 @@ def supported(slots: int, heads: int, key_dim: int, value_dim: int,
     return 4 * 4 * key_dim * heads * value_dim <= VMEM_BUDGET_BYTES
 
 
-def _kernel(rows_ref, qt_ref, kt_ref, vab_ref, s_ref, s_out, o_ref,
-            *, heads, value_dim, group):
+def _kernel(rows_ref, qt_ref, kt_ref, vab_ref, *rest, heads, value_dim,
+            group, channel):
     del rows_ref                      # the index maps read it
+    # a decay a key channel comes as the keys do, (d_k, H), one more
+    # operand before the state
+    at_ref = rest[0] if channel else None
+    s_ref, s_out, o_ref = rest[-3:]
     dk = s_ref.shape[0]
     width = group * value_dim         # lanes of a group of heads
     lane = jax.lax.broadcasted_iota(jnp.int32, (dk, LANES), 1)
@@ -146,6 +150,8 @@ def _kernel(rows_ref, qt_ref, kt_ref, vab_ref, s_ref, s_out, o_ref,
             at = pl.ds(grp * width + tile * LANES, LANES)
             kx = spread(kt, first, tile)
             v, alpha, beta = (vab_ref[j:j + 1, at] for j in range(3))
+            if channel:               # the row's own factor down the keys
+                alpha = spread(at_ref[...], first, tile)
             s = s_ref[:, at] * alpha
             u = beta * (v - jnp.sum(s * kx, axis=0, keepdims=True))
             s = s + kx * u
@@ -155,8 +161,9 @@ def _kernel(rows_ref, qt_ref, kt_ref, vab_ref, s_ref, s_out, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "interpret"))
-def _gated_delta(arena, rows, qt, kt, vab, *, heads, interpret):
+def _gated_delta(arena, rows, qt, kt, vab, at=None, *, heads, interpret):
     n, dk, _ = qt.shape
+    decays = () if at is None else (at,)
     width = arena.shape[-1]
     value_dim = width // heads
     per_slot = lambda *tail: pl.BlockSpec(  # noqa: E731
@@ -166,42 +173,50 @@ def _gated_delta(arena, rows, qt, kt, vab, *, heads, interpret):
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[per_slot(dk, heads), per_slot(dk, heads),
-                  per_slot(3, width), row],
+                  per_slot(3, width)]
+        + [per_slot(dk, heads) for _ in decays] + [row],
         out_specs=[row, per_slot(1, width)],
     )
     return pl.pallas_call(
         functools.partial(_kernel, heads=heads, value_dim=value_dim,
-                          group=_group(value_dim)),
+                          group=_group(value_dim), channel=bool(decays)),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(arena.shape, arena.dtype),
                    jax.ShapeDtypeStruct((n, 1, width), jnp.float32)],
-        # operand 4 (after the prefetched rows): the arena, in place
-        input_output_aliases={4: 0},
+        # the last operand (the fifth, after the prefetched rows, or the
+        # sixth behind a decay a channel): the arena, in place
+        input_output_aliases={4 + len(decays): 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="gated_delta_decode",
-    )(rows.astype(jnp.int32), qt, kt, vab, arena)
+    )(rows.astype(jnp.int32), qt, kt, vab, *decays, arena)
 
 
 def gated_delta_decode(arena, rows, q, k, v, alpha, beta):
     """One token a slot through the states in ``arena`` (rows, d_k, H
     d_v) float32, donated. ``rows`` (N,) int32 each slot's arena row;
     ``q``, ``k`` (N, H, d_k), ``v`` (N, H, d_v), ``alpha``, ``beta`` (N,
-    H), float32. Returns (o (N, H, d_v) float32, the arena with those
-    rows updated). Callers check :func:`supported` first. The kernel's
-    call is jitted on its own, so that the layers of a model trace it
-    once."""
+    H), float32; ``alpha`` (N, H, d_k) is a decay a key channel: the
+    state's row ``d`` of head ``h`` is multiplied by ``alpha[n, h, d]``.
+    Returns (o (N, H, d_v) float32, the arena with those rows updated).
+    Callers check :func:`supported` first. The kernel's call is jitted on
+    its own, so that the layers of a model trace it once."""
     n, heads, _ = q.shape
     value_dim = v.shape[-1]
     f32 = jnp.float32
     lanes = lambda a: jnp.repeat(a.astype(f32), value_dim, axis=-1)  # noqa: E731
-    vab = jnp.stack([v.astype(f32).reshape(n, -1), lanes(alpha),
-                     lanes(beta)], axis=1)                    # (N, 3, H d_v)
+    keys = lambda a: a.astype(f32).transpose(0, 2, 1)  # noqa: E731
+    # a decay a channel rides with the keys, (N, d_k, H) behind ``vab``,
+    # whose second row is then not read
+    channel = alpha.ndim == 3
+    vab = jnp.stack([v.astype(f32).reshape(n, -1),
+                     lanes(beta if channel else alpha), lanes(beta)],
+                    axis=1)                                   # (N, 3, H d_v)
     arena, o = _gated_delta(
-        arena, rows, q.astype(f32).transpose(0, 2, 1),
-        k.astype(f32).transpose(0, 2, 1), vab, heads=heads,
+        arena, rows, keys(q), keys(k), vab,
+        *((keys(alpha),) if channel else ()), heads=heads,
         interpret=pallas_mode() == "interpret")
     return o.reshape(n, heads, value_dim), arena
 
@@ -531,7 +546,10 @@ def delta_rule_step(state, q, k, v, alpha, beta):
     state)."""
     n, h, dk = q.shape
     hi = jax.lax.Precision.HIGHEST
-    st = state.reshape(n, dk, h, -1) * alpha[:, None, :, None]
+    # ``alpha`` (N, H): one decay a head; (N, H, d_k): one a key channel
+    st = state.reshape(n, dk, h, -1)
+    st = st * (alpha[:, None, :, None] if alpha.ndim == 2
+               else alpha.transpose(0, 2, 1)[..., None])
     u = beta[..., None] * (v - jnp.einsum("ndhv,nhd->nhv", st, k,
                                           precision=hi))
     st = st + k.transpose(0, 2, 1)[..., None] * u[:, None]

@@ -13,6 +13,14 @@ One builder serves the whole model and one holder's share of it:
 attention, router and dense layers are whole on every holder), and
 ``vocab_size`` is whatever slice of the vocabulary the holder keeps.
 
+A layer takes its mixer and its feed-forward by its PUBLISHED index
+``first_layer + i`` (a pipeline stage holds a run of layers from
+``first_layer``): ``layer_types`` names each held layer's mixer, ``"latent"``
+(every layer, by default) or ``"kda"`` (Kimi Delta Attention,
+``ops/gated_delta.py``: the hybrids of the Ling 3.0 and Kimi Linear line,
+some linear layers to one latent layer), and the first ``first_dense``
+published layers have the dense MLP.
+
 Built on the builder API, so the graph compiles, is priced by the search
 and the simulator, and drives ``serving.GenerationInstance`` (a paged
 latent cache, one row a token). ``param_dtype`` is the dtype the graph's
@@ -38,7 +46,7 @@ class LatentMoEConfig:
     hidden_size: int = 512
     num_layers: int = 4
     num_heads: int = 8
-    q_lora_rank: int = 192
+    q_lora_rank: Optional[int] = 192   # None: queries projected in one step
     kv_lora_rank: int = 64
     qk_nope_head_dim: int = 32
     qk_rope_head_dim: int = 16
@@ -58,6 +66,15 @@ class LatentMoEConfig:
     routed_scale: float = 1.0
     n_shared: int = 1
     experts_held: Optional[Tuple[int, int]] = None
+    selection_bias: bool = False       # the experts are chosen by s + b
+    # each held layer's mixer, "latent" or "kda"; None: every layer latent
+    layer_types: Optional[Tuple[str, ...]] = None
+    first_layer: int = 0               # the published index of layer 0
+    output_gate: Optional[str] = None  # "head": one sigmoid gate a head
+    rope_interleaved: bool = False
+    kda_head_dim: int = 128            # keys and values of a KDA head
+    kda_conv_taps: int = 4
+    kda_lower_bound: float = -5.0
     param_dtype: DataType = DataType.FLOAT
     draw_weights: bool = True
 
@@ -73,22 +90,40 @@ def build_latent_moe_lm(ff, batch_size: int, seq_length: int,
     h = ff.embedding(tokens, cfg.vocab_size, cfg.hidden_size,
                      dtype=cfg.param_dtype, kernel_initializer=init,
                      name="embed")
+    # (absent where the selection has no bias: an older graph's
+    # attributes are what they were)
+    bias = (dict(selection_bias=True, bias_initializer=init)
+            if cfg.selection_bias else {})
+    types = cfg.layer_types or ("latent",) * cfg.num_layers
+    if len(types) != cfg.num_layers or set(types) - {"latent", "kda"}:
+        raise ValueError(f"layer_types {types} for {cfg.num_layers} layers "
+                         f"of 'latent' or 'kda'")
     for i in range(cfg.num_layers):
         n1 = ff.rms_norm(h, eps=cfg.rms_eps, kernel_initializer=init,
                          name=f"block{i}_norm1")
-        attn = ff.latent_attention(
-            n1, positions, num_heads=cfg.num_heads,
-            q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
-            qk_nope_head_dim=cfg.qk_nope_head_dim,
-            qk_rope_head_dim=cfg.qk_rope_head_dim,
-            v_head_dim=cfg.v_head_dim, max_positions=cfg.max_positions,
-            rope_theta=cfg.rope_theta, rope_scaling=cfg.rope_scaling,
-            eps=cfg.rms_eps, kernel_initializer=init, gain_initializer=init,
-            name=f"block{i}_attn")
+        if types[i] == "kda":
+            attn = ff.kimi_delta_attention(
+                n1, num_heads=cfg.num_heads, key_dim=cfg.kda_head_dim,
+                value_dim=cfg.kda_head_dim, conv_taps=cfg.kda_conv_taps,
+                lower_bound=cfg.kda_lower_bound, eps=cfg.rms_eps,
+                kernel_initializer=init, gain_initializer=init,
+                gate_initializer=init, name=f"block{i}_attn")
+        else:
+            attn = ff.latent_attention(
+                n1, positions, num_heads=cfg.num_heads,
+                q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+                qk_nope_head_dim=cfg.qk_nope_head_dim,
+                qk_rope_head_dim=cfg.qk_rope_head_dim,
+                v_head_dim=cfg.v_head_dim, max_positions=cfg.max_positions,
+                rope_theta=cfg.rope_theta, rope_scaling=cfg.rope_scaling,
+                eps=cfg.rms_eps, output_gate=cfg.output_gate,
+                rope_interleaved=cfg.rope_interleaved,
+                kernel_initializer=init, gain_initializer=init,
+                name=f"block{i}_attn")
         h = ff.add(h, attn, name=f"block{i}_res1")
         n2 = ff.rms_norm(h, eps=cfg.rms_eps, kernel_initializer=init,
                          name=f"block{i}_norm2")
-        if i < cfg.first_dense:
+        if cfg.first_layer + i < cfg.first_dense:
             m = ff.gated_mlp(n2, cfg.dense_width, kernel_initializer=init,
                              name=f"block{i}_mlp")
         else:
@@ -99,7 +134,7 @@ def build_latent_moe_lm(ff, batch_size: int, seq_length: int,
                 topk_group=cfg.topk_group, scoring=cfg.scoring,
                 norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
                 experts_held=cfg.experts_held, kernel_initializer=init,
-                name=f"block{i}_experts")
+                **bias, name=f"block{i}_experts")
             if cfg.n_shared:
                 shared = ff.gated_mlp(
                     n2, cfg.n_shared * cfg.expert_width,

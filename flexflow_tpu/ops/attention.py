@@ -33,8 +33,9 @@ are multiplied by.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -465,17 +466,28 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rotary(x, positions, inv_freq, rotary_dim=None):
+def apply_rotary(x, positions, inv_freq, rotary_dim=None,
+                 interleaved=False):
     """Rotate the pairs ``(x[i], x[i + d/2])`` of the last axis by
     ``positions * inv_freq[i]``. ``x``: (..., S, [H,] d) with
     ``positions`` (..., S); float32 inside, the input's dtype out. With
     ``rotary_dim`` the first ``rotary_dim`` values of the last axis are
-    rotated (their own halves paired) and the rest pass as they are."""
+    rotated (their own halves paired) and the rest pass as they are.
+    ``interleaved``: the pairs are ``(x[2i], x[2i + 1])``, each rotated
+    where it lies."""
     ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     if x.ndim == ang.ndim + 1:                   # a heads axis before d
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
+    if interleaved:
+        if rotary_dim is not None:
+            raise ValueError("interleaved pairs over part of a head are "
+                             "not built")
+        pairs = xf.reshape(xf.shape[:-1] + (xf.shape[-1] // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     rest = []
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         xf, rest = xf[..., :rotary_dim], [xf[..., rotary_dim:]]
@@ -676,7 +688,12 @@ class LatentAttention(Op):
     * ``[ckv | kr] = x W_kva``; ``c = norm(ckv)``; one ``k_rope =
       rope(kr)`` for all heads, ``q_rope = rope(q_rope)``;
     * ``[k_nope | v] = c W_kvb`` per head; scores ``(q_nope . k_nope +
-      q_rope . k_rope) * scale``, causal softmax, ``sum p v``, ``W_o``.
+      q_rope . k_rope) * scale``, causal softmax, ``sum p v``, ``W_o``;
+    * ``q_lora_rank=None``: ``[q_nope | q_rope] = x W_q`` direct, no
+      low-rank step and no norm; ``output_gate="head"``: each head's
+      ``sum p v`` times ``sigmoid(x w_gate,h)`` before ``W_o`` (``wg`` is
+      (E, H)); ``rope_interleaved``: the rotary pairs are ``(2i, 2i +
+      1)``.
 
     What a cache has to keep of a token is the row ``[c | k_rope]``
     (:meth:`queries_and_rows`), not keys and values:
@@ -693,7 +710,14 @@ class LatentAttention(Op):
         a = self.attrs
         self.embed_dim: int = input_shapes[0].sizes[-1]
         self.num_heads: int = int(a["num_heads"])
-        self.q_rank: int = int(a["q_lora_rank"])
+        # None: the queries are projected in one step
+        self.q_rank: Optional[int] = (None if a.get("q_lora_rank") is None
+                                      else int(a["q_lora_rank"]))
+        self.output_gate = a.get("output_gate")
+        if self.output_gate not in (None, "head"):
+            raise ValueError(f"output_gate {self.output_gate!r} is neither "
+                             f"None nor 'head'")
+        self.rope_interleaved = bool(a.get("rope_interleaved", False))
         self.kv_rank: int = int(a["kv_lora_rank"])
         self.nope_dim: int = int(a["qk_nope_head_dim"])
         self.rope_dim: int = int(a["qk_rope_head_dim"])
@@ -721,18 +745,21 @@ class LatentAttention(Op):
         init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
         gain = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
         e, h = self.embed_dim, self.num_heads
-        return [
+        qk = h * (self.nope_dim + self.rope_dim)
+        queries = [WeightSpec("wq", (e, qk), dt, init)] \
+            if self.q_rank is None else [
             WeightSpec("wq_a", (e, self.q_rank), dt, init),
             WeightSpec("q_norm", (self.q_rank,), dt, gain, weight_decay=False),
-            WeightSpec("wq_b", (self.q_rank,
-                                h * (self.nope_dim + self.rope_dim)), dt, init),
+            WeightSpec("wq_b", (self.q_rank, qk), dt, init)]
+        gate = [WeightSpec("wg", (e, h), dt, init)] if self.output_gate \
+            else []
+        return queries + [
             WeightSpec("wkv_a", (e, self.kv_rank + self.rope_dim), dt, init),
             WeightSpec("kv_norm", (self.kv_rank,), dt, gain,
                        weight_decay=False),
             WeightSpec("wkv_b", (self.kv_rank,
                                  h * (self.nope_dim + self.v_dim)), dt, init),
-            WeightSpec("wo", (h * self.v_dim, e), dt, init),
-        ]
+        ] + gate + [WeightSpec("wo", (h * self.v_dim, e), dt, init)]
 
     # ---- the pieces serving composes ------------------------------------
     @sub_scope("project")
@@ -744,16 +771,32 @@ class LatentAttention(Op):
 
         b, s, _ = x.shape
         h = self.num_heads
-        cq = rms_norm(_mm(x, weights["wq_a"]), weights["q_norm"], self.eps)
-        q = _mm(cq, weights["wq_b"]).reshape(
-            b, s, h, self.nope_dim + self.rope_dim)
+        if self.q_rank is None:
+            q = _mm(x, weights["wq"])
+        else:
+            cq = rms_norm(_mm(x, weights["wq_a"]), weights["q_norm"],
+                          self.eps)
+            q = _mm(cq, weights["wq_b"])
+        q = q.reshape(b, s, h, self.nope_dim + self.rope_dim)
         q_nope, q_rope = q[..., :self.nope_dim], q[..., self.nope_dim:]
         kva = _mm(x, weights["wkv_a"])
         c = rms_norm(kva[..., :self.kv_rank], weights["kv_norm"], self.eps)
-        k_rope = apply_rotary(kva[..., self.kv_rank:], positions,
-                              self.inv_freq)
-        q_rope = apply_rotary(q_rope, positions, self.inv_freq)
+        turn = functools.partial(apply_rotary, positions=positions,
+                                 inv_freq=self.inv_freq,
+                                 interleaved=self.rope_interleaved)
+        k_rope = turn(kva[..., self.kv_rank:])
+        q_rope = turn(q_rope)
         return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+
+    def gated(self, weights, x, o):
+        """The heads' outputs ``o`` (B, S, H, v) as ``W_o`` takes them:
+        times ``sigmoid(x w_gate)``, one a head, where the op has an
+        output gate; as they are where it has none."""
+        if not self.output_gate:
+            return o
+        with sub_scope("gate"):
+            z = jnp.dot(x, weights["wg"], preferred_element_type=jnp.float32)
+            return (o * jax.nn.sigmoid(z)[..., None]).astype(o.dtype)
 
     def kvb_heads(self, weights):
         """``W_kvb`` as (kv_rank, H, nope + v): its key part is
@@ -762,10 +805,11 @@ class LatentAttention(Op):
                                         self.nope_dim + self.v_dim)
 
     @sub_scope("attend")
-    def attend_expanded(self, weights, q_nope, q_rope, rows, mask):
+    def attend_expanded(self, weights, q_nope, q_rope, rows, mask, x=None):
         """Attention in the expanded form over the rows given (queries
         (B, Sq, H, ·), rows (B, Sk, width), ``mask`` (Sq, Sk) or (B, Sq,
-        Sk) True where a query sees a key); returns (B, Sq, E)."""
+        Sk) True where a query sees a key; ``x`` (B, Sq, E) the op's
+        input, which an output gate reads); returns (B, Sq, E)."""
         c, k_rope = rows[..., :self.kv_rank], rows[..., self.kv_rank:]
         kv = jnp.einsum("bkc,chd->bkhd", c, self.kvb_heads(weights),
                         preferred_element_type=jnp.float32).astype(c.dtype)
@@ -780,6 +824,7 @@ class LatentAttention(Op):
         ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
                           preferred_element_type=jnp.float32).astype(v.dtype)
         b, sq = ctxv.shape[:2]
+        ctxv = self.gated(weights, x, ctxv)
         return _mm(ctxv.reshape(b, sq, self.num_heads * self.v_dim),
                    weights["wo"])
 
@@ -789,14 +834,15 @@ class LatentAttention(Op):
         s = x.shape[1]
         pos = jax.lax.iota(jnp.int32, s)
         return [self.attend_expanded(weights, q_nope, q_rope, rows,
-                                     pos[None, :] <= pos[:, None])]
+                                     pos[None, :] <= pos[:, None], x)]
 
     def flops(self) -> float:
         b, s = self.input_shapes[0].sizes[:2]
         e, h = self.embed_dim, self.num_heads
         qk = self.nope_dim + self.rope_dim
         proj = 2.0 * b * s * (
-            e * self.q_rank + self.q_rank * h * qk
+            (e * h * qk if self.q_rank is None
+             else e * self.q_rank + self.q_rank * h * qk)
             + e * (self.kv_rank + self.rope_dim)
             + self.kv_rank * h * (self.nope_dim + self.v_dim)
             + h * self.v_dim * e)

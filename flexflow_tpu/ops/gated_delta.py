@@ -86,17 +86,7 @@ def chunked_delta_rule(q, k, v, g, beta, state):
     A position with ``g = 0`` and ``beta = 0`` leaves the state as it was.
     Returns (o (B, S, H, d_v), the state after position S - 1)."""
     b, s, h, dk = q.shape
-    pad = -s % CHUNK
-    if pad:
-        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
-                                    (a.ndim - 2)) for a in (q, k, v, g, beta))
-    n = (s + pad) // CHUNK
-
-    def chunks(a):                    # (B, n C, H, ...) -> (n, B, H, C, ...)
-        a = a.reshape((b, n, CHUNK) + a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
-
-    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    q, k, v, g, beta = _in_chunks(q, k, v, g, beta)
     gc = jnp.cumsum(g, axis=-1)                              # (n, B, H, C)
     idx = jax.lax.iota(jnp.int32, CHUNK)
     lower = idx[:, None] >= idx[None, :]
@@ -117,7 +107,31 @@ def chunked_delta_rule(q, k, v, g, beta, state):
     q_in = since[..., None] * q                              # against S_0
     k_out = jnp.exp(gc[..., -1:] - gc)[..., None] * k        # up to the end
     a_end = since[..., -1][..., None, None]
+    return _carry_chunks(state, (uv, w, qk, q_in, k_out, a_end), b, s, h)
 
+
+def _in_chunks(*arrays):
+    """Each of ``arrays`` (B, S, H, ...) padded with zeros to whole chunks
+    and cut into them: (n, B, H, CHUNK, ...)."""
+    b, s = arrays[0].shape[:2]
+    pad = -s % CHUNK
+    if pad:
+        arrays = tuple(jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                               (a.ndim - 2)) for a in arrays)
+    n = (s + pad) // CHUNK
+
+    def chunks(a):                    # (B, n C, H, ...) -> (n, B, H, C, ...)
+        a = a.reshape((b, n, CHUNK) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    return tuple(map(chunks, arrays))
+
+
+def _carry_chunks(state, chunks, b, s, h):
+    """The state carried over the chunks (the leading axis of each of
+    ``chunks`` = (uv, w, qk, q_in, k_out, a_end), what a chunk needs that
+    does not involve the incoming state); returns (o (B, S, H, d_v), the
+    state after the last)."""
     def step(st, xs):
         uv_c, w_c, qk_c, q_c, k_c, a_c = xs
         u = uv_c - jnp.einsum("...td,...dv->...tv", w_c, st, precision=_HI)
@@ -127,9 +141,71 @@ def chunked_delta_rule(q, k, v, g, beta, state):
                                    precision=_HI)
         return st, o
 
-    state, o = jax.lax.scan(step, state, (uv, w, qk, q_in, k_out, a_end))
+    state, o = jax.lax.scan(step, state, chunks)
+    n = o.shape[0]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)            # (B, n, C, H, dv)
     return o.reshape(b, n * CHUNK, h, -1)[:, :s], state
+
+
+SUB = 16  # tokens a sub-chunk of the per-channel form (see there)
+
+
+def chunked_channel_rule(q, k, v, g, beta, state):
+    """:func:`chunked_delta_rule` with a decay a key CHANNEL: ``g`` = log
+    alpha is (B, S, H, d_k) and the state's row ``d`` decays by
+    ``alpha_t[d]``::
+
+        S' = diag(alpha_t) S_{t-1};  S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+
+    One decay can no longer be pulled out of a chunk's products: the
+    pair's ``sum_d k_t[d] k_i[d] exp(G_t[d] - G_i[d])`` (``G`` the log-decay
+    summed from the chunk's start) factorised as ``(k_t exp G_t) . (k_i
+    exp -G_i)`` overflows float32 once ``-G_i`` passes 88, which a gate
+    bounded below by -5 a token does after 17 tokens. So the products are
+    taken against the start of the ROW's sub-chunk of ``SUB`` tokens: row
+    ``t`` of sub-chunk ``a`` is ``k_t exp(G_t - G^a)`` (at most 1) and
+    column ``i`` is ``k_i exp(G^a - G_i)``, at most 1 for a column of an
+    earlier sub-chunk and at most ``exp(SUB * 5) = e^80`` within ``a``
+    itself; columns behind ``a`` are never read and their exponent is
+    struck before it is taken. Safe for ``g >= -88 / SUB = -5.5``
+    everywhere; everything else of the chunk (the unit-lower system, what
+    meets the incoming state, the carry) is the scalar form's with the
+    decay since the chunk's start a vector where it was a number."""
+    b, s, h, dk = q.shape
+    nsub = CHUNK // SUB
+    q, k, v, g, beta = _in_chunks(q, k, v, g, beta)
+    gc = jnp.cumsum(g, axis=-2)                          # (n, B, H, C, d_k)
+    idx = jax.lax.iota(jnp.int32, CHUNK)
+    # G^a: the sum before sub-chunk a's first token, (.., nsub, 1, d_k)
+    lead = gc.shape[:-2]
+    starts = jnp.concatenate(
+        [jnp.zeros(lead + (1, dk), gc.dtype), gc[..., SUB - 1:-1:SUB, :]],
+        axis=-2)[..., :, None, :]
+    rows = jnp.exp(gc.reshape(lead + (nsub, SUB, dk)) - starts)
+    # a column is read by the rows of its own sub-chunk and of later ones
+    seen = (idx[None, :] < (jax.lax.iota(jnp.int32, nsub)[:, None] + 1) * SUB)
+    cols = k[..., None, :, :] * jnp.exp(jnp.where(
+        seen[:, :, None], starts - gc[..., None, :, :], 0.0))
+    # (.., nsub, C, d_k)
+
+    def pairs(a):                     # sum_d a_t[d] k_i[d] exp(G_t - G_i)[d]
+        a = a.reshape(lead + (nsub, SUB, dk)) * rows
+        return jnp.einsum("...atd,...aid->...ati", a, cols,
+                          precision=_HI).reshape(lead + (CHUNK, CHUNK))
+
+    low = beta[..., None] * jnp.where(idx[:, None] > idx[None, :], pairs(k),
+                                      0.0)
+    inv = _unit_lower_inverse(low)
+    since = jnp.exp(gc)               # the decay since the chunk's start
+    uv = jnp.einsum("...ti,...id->...td", inv, beta[..., None] * v,
+                    precision=_HI)
+    w = jnp.einsum("...ti,...id->...td", inv, beta[..., None] * since * k,
+                   precision=_HI)
+    qk = jnp.where(idx[:, None] >= idx[None, :], pairs(q), 0.0)
+    q_in = since * q                                         # against S_0
+    k_out = jnp.exp(gc[..., -1:, :] - gc) * k                # up to the end
+    a_end = since[..., -1, :][..., None]                     # (.., d_k, 1)
+    return _carry_chunks(state, (uv, w, qk, q_in, k_out, a_end), b, s, h)
 
 
 UNIT_EPS = 1e-6  # under the root of a head's L2 norm of q and of k
@@ -151,8 +227,9 @@ def scan_rule(eps: float, q, k, v, g, beta, state, gain):
     wrote them; unit q and k a head, :func:`chunked_delta_rule`, then
     RMSNorm over each head's d_v times ``gain`` (d_v,). Returns (y (B, S,
     H d_v) float32, the state after the sequence)."""
-    b, s, h = g.shape
-    o, state = chunked_delta_rule(
+    b, s, h = g.shape[:3]
+    rule = chunked_delta_rule if g.ndim == 3 else chunked_channel_rule
+    o, state = rule(
         *unit_heads(*(a.reshape(b, s, h, -1) for a in (q, k, v))), g, beta,
         state)
     return rms_norm(o, gain, eps).reshape(v.shape), state
@@ -179,11 +256,12 @@ fused_rule.defvjp(_fused_fwd, _fused_bwd)
 
 
 def delta_rule_path(seq: int, heads: int, key_dim: int, value_dim: int,
-                    dtype=jnp.float32) -> str:
+                    dtype=jnp.float32, channel_decay: bool = False) -> str:
     """How a layer computes its recurrence over these shapes:
     ``"kernel"`` (:func:`fused_rule`) or ``"scan"`` (:func:`scan_rule`).
-    A rule over what a trace sees, the backend among it; no knob."""
-    return "kernel" if kernel.chunks_supported(
+    A rule over what a trace sees, the backend among it; no knob. The
+    kernel is the scalar decay's: a decay a channel takes the jnp form."""
+    return "kernel" if not channel_decay and kernel.chunks_supported(
         seq, heads, key_dim, value_dim, dtype) else "scan"
 
 
@@ -205,6 +283,7 @@ class GatedDeltaNet(Op):
         self.conv_taps = int(a.get("conv_taps", 4))
         self.neg_eigval = bool(a.get("allow_neg_eigval", False))
         self.eps = float(a.get("eps", 1e-6))
+        self.channel_decay = False     # one decay a head and a step
         self.qk_width = self.num_heads * self.key_dim
         self.v_width = self.num_heads * self.value_dim
         self.channels = 2 * self.qk_width + self.v_width
@@ -317,11 +396,13 @@ class GatedDeltaNet(Op):
             # by the path the shapes choose; the kernel's gradients are
             # the jnp form's
             rule = (fused_rule if delta_rule_path(
-                s, self.num_heads, self.key_dim, self.value_dim, q.dtype)
-                == "kernel" else scan_rule)
-            y, state = rule(self.eps, q, k, v, jnp.where(live, g, 0.0),
-                            jnp.where(live, beta, 0.0), state,
-                            weights["norm"])
+                s, self.num_heads, self.key_dim, self.value_dim, q.dtype,
+                self.channel_decay) == "kernel" else scan_rule)
+            y, state = rule(
+                self.eps, q, k, v,
+                jnp.where(live[..., None] if self.channel_decay else live,
+                          g, 0.0),
+                jnp.where(live, beta, 0.0), state, weights["norm"])
         with sub_scope("conv"):
             # window position p is block position p - (K - 1): the K - 1
             # inputs before position ``length`` start at ``length``
@@ -352,3 +433,98 @@ class GatedDeltaNet(Op):
         chunk = 2.0 * b * s * h * (CHUNK * (2 * dk + 2 * (dk + dv))
                                    + 3 * dk * dv)
         return proj + chunk + 2.0 * b * s * self.conv_taps * self.channels
+
+
+@register_op
+class KimiDeltaAttention(GatedDeltaNet):
+    """Kimi Delta Attention (Kimi Linear, 2025; the linear layers of the
+    Ling 3.0 line): :class:`GatedDeltaNet` with a decay a key CHANNEL.
+    What differs from it, per token:
+
+    * ``g_t = lower * sigmoid(exp(A_log_h) * (x_t W_f + dt_bias))``, (H,
+      d_k) wide: ``W_f`` (E, H d_k) full-rank, ``A_log`` one a head,
+      ``dt_bias`` one a channel, ``lower`` < 0 the gate's bound, so
+      ``alpha_t = exp(g_t)`` lies in ``(e^lower, 1)`` (the safe gate of the
+      public kernels); the state's row ``d`` decays by ``alpha_t[d]``:
+      ``S' = diag(alpha_t) S_{t-1}``, then the delta rule as before;
+    * ``beta_t = sigmoid(x_t W_b)``, never doubled;
+    * out: ``concat_h(RMSNorm_{d_v}(o_t,h) * sigmoid(x_t w_gate,h)) W_o``:
+      ONE gate a head (``wg`` is (E, H)), a sigmoid.
+
+    The convolution, the unit q and k, the state's shape and what a
+    request keeps are :class:`GatedDeltaNet`'s, so serving stores it as
+    that op's :class:`~flexflow_tpu.serving.cache_entry.StateEntry`. The
+    whole-sequence form is :func:`chunked_channel_rule` (jnp; no kernel
+    yet), one token ``kernels/gated_delta.py``'s step with a ``(d_k,)``
+    decay a head."""
+
+    op_type = OpType.KIMI_DELTA_ATTENTION
+
+    def __init__(self, layer, input_shapes):
+        super().__init__(layer, input_shapes)
+        self.lower_bound = float(self.attrs.get("lower_bound", -5.0))
+        if not -88.0 / SUB <= self.lower_bound < 0:
+            raise ValueError(
+                f"{self.name}: a decay bounded by {self.lower_bound} a "
+                f"token leaves what a sub-chunk of {SUB} tokens can hold "
+                f"in float32 (no lower than {-88.0 / SUB})")
+        if self.neg_eigval:
+            raise ValueError(f"{self.name}: beta is never doubled here")
+        self.channel_decay = True
+
+    def weight_specs(self) -> List[WeightSpec]:
+        dt = self.input_shapes[0].dtype
+        init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        gain = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
+        gate = self.attrs.get("gate_initializer") or ZeroInitializer()
+        e, h = self.embed_dim, self.num_heads
+        return [
+            WeightSpec("wq", (e, self.qk_width), dt, init),
+            WeightSpec("wk", (e, self.qk_width), dt, init),
+            WeightSpec("wv", (e, self.v_width), dt, init),
+            WeightSpec("wf", (e, self.qk_width), dt, init),
+            WeightSpec("wb", (e, h), dt, init),
+            WeightSpec("wg", (e, h), dt, init),
+            WeightSpec("conv", (self.conv_taps, self.channels), dt, init),
+            WeightSpec("a_log", (h,), dt, gate, weight_decay=False),
+            WeightSpec("dt_bias", (self.qk_width,), dt, gate,
+                       weight_decay=False),
+            WeightSpec("norm", (self.value_dim,), dt, gain,
+                       weight_decay=False),
+            WeightSpec("wo", (self.v_width, e), dt, init),
+        ]
+
+    @sub_scope("gate")
+    def gates(self, weights, x):
+        """(B, S, E) -> ``g`` = log alpha (B, S, H, d_k) and beta (B, S,
+        H), float32."""
+        f32 = jnp.float32
+        b, s, _ = x.shape
+        h, dk = self.num_heads, self.key_dim
+        f = jnp.dot(x, weights["wf"], preferred_element_type=f32)
+        bl = jnp.dot(x, weights["wb"], preferred_element_type=f32)
+        a = jnp.exp(weights["a_log"].astype(f32))[:, None]
+        g = self.lower_bound * jax.nn.sigmoid(
+            a * (f.reshape(b, s, h, dk)
+                 + weights["dt_bias"].astype(f32).reshape(h, dk)))
+        return g, jax.nn.sigmoid(bl)
+
+    @sub_scope("out")
+    def finish(self, weights, x, o, normed=False):
+        """The recurrence's (B, S, H, d_v) float32 outputs -> (B, S, E):
+        RMSNorm over ``d_v`` a head (``normed``: made already), times the
+        head's ``sigmoid(x w_gate)``, through ``W_o``."""
+        b, s = o.shape[:2]
+        z = jnp.dot(x, weights["wg"], preferred_element_type=jnp.float32)
+        y = o if normed else rms_norm(o, weights["norm"], self.eps)
+        y = y.reshape(b, s, self.num_heads, self.value_dim) \
+            * jax.nn.sigmoid(z)[..., None]
+        return _mm(y.reshape(b, s, self.v_width).astype(x.dtype),
+                   weights["wo"])
+
+    def flops(self) -> float:
+        b, s = self.input_shapes[0].sizes[:2]
+        e, h = self.embed_dim, self.num_heads
+        # the decay's full-rank projection in the place of W_g's and W_a's
+        return (super().flops()
+                + 2.0 * b * s * e * (self.qk_width - self.v_width))

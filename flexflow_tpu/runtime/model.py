@@ -299,20 +299,25 @@ class FFModel:
                  kernel_initializer=kernel_initializer), name)
 
     def latent_attention(self, input: Tensor, positions: Tensor, *,
-                         num_heads: int, q_lora_rank: int, kv_lora_rank: int,
+                         num_heads: int, q_lora_rank: Optional[int],
+                         kv_lora_rank: int,
                          qk_nope_head_dim: int, qk_rope_head_dim: int,
                          v_head_dim: int, max_positions: int,
                          rope_theta: float = 10000.0,
                          rope_scaling: Optional[Dict[str, Any]] = None,
-                         eps: float = 1e-6, kernel_initializer=None,
-                         gain_initializer=None,
+                         eps: float = 1e-6, output_gate: Optional[str] = None,
+                         rope_interleaved: bool = False,
+                         kernel_initializer=None, gain_initializer=None,
                          name: Optional[str] = None) -> Tensor:
         """Causal self-attention over a low-rank latent with rotary
         positions (ops/attention.py LatentAttention). ``positions`` is
         the graph's int32 positions input; ``rope_scaling`` an optional
-        YaRN dict."""
+        YaRN dict; ``q_lora_rank=None`` projects the queries in one step;
+        ``output_gate="head"`` gates each head's output by a sigmoid;
+        ``rope_interleaved`` turns the pairs ``(2i, 2i + 1)``."""
         attrs = dict(
-            num_heads=int(num_heads), q_lora_rank=int(q_lora_rank),
+            num_heads=int(num_heads),
+            q_lora_rank=None if q_lora_rank is None else int(q_lora_rank),
             kv_lora_rank=int(kv_lora_rank),
             qk_nope_head_dim=int(qk_nope_head_dim),
             qk_rope_head_dim=int(qk_rope_head_dim),
@@ -321,6 +326,12 @@ class FFModel:
             rope_scaling=dict(rope_scaling) if rope_scaling else None,
             eps=float(eps), kernel_initializer=kernel_initializer,
             gain_initializer=gain_initializer)
+        # (absent where they are the default's: an older graph's
+        # attributes are what they were)
+        if output_gate is not None:
+            attrs["output_gate"] = str(output_gate)
+        if rope_interleaved:
+            attrs["rope_interleaved"] = True
         return self._infer_and_add(OpType.LATENT_ATTENTION,
                                    [input, positions], attrs, name)
 
@@ -368,6 +379,26 @@ class FFModel:
             gate_initializer=gate_initializer)
         return self._infer_and_add(OpType.GATED_DELTA_NET, [input], attrs,
                                    name)
+
+    def kimi_delta_attention(self, input: Tensor, *, num_heads: int,
+                             key_dim: int, value_dim: int,
+                             conv_taps: int = 4, lower_bound: float = -5.0,
+                             eps: float = 1e-6, kernel_initializer=None,
+                             gain_initializer=None, gate_initializer=None,
+                             name: Optional[str] = None) -> Tensor:
+        """Linear attention by the delta rule with a decay a key channel,
+        ``exp(lower_bound * sigmoid(.))``, and one sigmoid output gate a
+        head (ops/gated_delta.py KimiDeltaAttention); the state a
+        sequence keeps is :meth:`gated_delta_net`'s."""
+        attrs = dict(
+            num_heads=int(num_heads), key_dim=int(key_dim),
+            value_dim=int(value_dim), conv_taps=int(conv_taps),
+            lower_bound=float(lower_bound), eps=float(eps),
+            kernel_initializer=kernel_initializer,
+            gain_initializer=gain_initializer,
+            gate_initializer=gate_initializer)
+        return self._infer_and_add(OpType.KIMI_DELTA_ATTENTION, [input],
+                                   attrs, name)
 
     def block_sparse_attention(self, input: Tensor, *, num_heads: int,
                                num_kv_heads: int, head_dim: int,

@@ -1052,6 +1052,8 @@ class LatentEntry(EntryKind):
             o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
                            wkvb[..., op.nope_dim:],
                            preferred_element_type=jnp.float32).astype(x.dtype)
+            if op.output_gate:
+                o = op.gated(weights, x, o[:, None])[:, 0]
             out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim),
                           weights["wo"],
                           preferred_element_type=jnp.float32).astype(x.dtype)
@@ -1063,7 +1065,7 @@ class LatentEntry(EntryKind):
         q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
         pos = _iota(x.shape[1])
         out = op.attend_expanded(weights, q_nope, q_rope, rows,
-                                 pos[None, :] <= pos[:, None])
+                                 pos[None, :] <= pos[:, None], x)
         return out, (rows,), pos
 
     def dense_shapes(self, batch, max_length, dtype):
@@ -1079,7 +1081,7 @@ class LatentEntry(EntryKind):
         kpos = _iota(rows_cache.shape[1])
         out = op.attend_expanded(weights, q_nope, q_rope,
                                  rows_cache.astype(x.dtype),
-                                 kpos[None, :] <= qpos[:, None])
+                                 kpos[None, :] <= qpos[:, None], x)
         return out, (rows_cache,)
 
 
@@ -1106,6 +1108,7 @@ class StateEntry(EntryKind):
     value_dim: int
     tail: int          # positions of the convolution's inputs kept
     channels: int
+    channel_decay: bool = False   # a decay a key channel (KimiDeltaAttention)
     name = "state"
     max_window = 1
     per_request = True
@@ -1113,7 +1116,7 @@ class StateEntry(EntryKind):
     @classmethod
     def for_op(cls, op, positions_id, max_length):
         return cls(op.num_heads, op.key_dim, op.value_dim, op.conv_taps - 1,
-                   op.channels)
+                   op.channels, op.channel_decay)
 
     def arenas(self, rows, block_size, dtype):
         return (jax.ShapeDtypeStruct(
@@ -1154,7 +1157,8 @@ class StateEntry(EntryKind):
         """``"kernel"`` (the fused whole-sequence kernel) or ``"scan"``
         (the jnp form), by the rule the op's lowering asks."""
         return delta_rule_path(bucket, self.heads, self.key_dim,
-                               self.value_dim)
+                               self.value_dim,
+                               channel_decay=self.channel_decay)
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """The op's chunked whole-sequence form from an empty state: what
@@ -1609,6 +1613,7 @@ KINDS: Dict[OpType, Callable[..., EntryKind]] = {
     OpType.COMPRESSED_CONV_ATTENTION: CcaEntry.for_op,
     OpType.LATENT_ATTENTION: LatentEntry.for_op,
     OpType.GATED_DELTA_NET: StateEntry.for_op,
+    OpType.KIMI_DELTA_ATTENTION: StateEntry.for_op,
     OpType.BLOCK_SPARSE_ATTENTION: SparseEntry.for_op,
     OpType.LIGHTNING_ATTENTION: DecayStateEntry.for_op,
     OpType.MAMBA2: SsmStateEntry.for_op,

@@ -39,7 +39,8 @@ def test_the_share_is_the_windows_delta_over_the_expert_layers(
 
 
 def test_the_entry_lists_the_routed_cells():
-    """PR 54's four, and since PR 55 the MiMo cell, appended."""
+    """PR 54's four, since PR 55 the MiMo cell and since PR 58 the Ling
+    cell, appended."""
     (entry,) = [m for m in Layout().bench["per_layer"]
                 if m["name"] == "decode_experts_kernel_share"]
     assert entry == {
@@ -50,4 +51,5 @@ def test_the_entry_lists_the_routed_cells():
                       "nemotron3-super-ep4.serve-agents",
                       "trinity-large-ep8.serve-mixedlengths",
                       "zaya1-8b-pp2.serve-chains",
-                      "mimo-v2.5-ep16.serve-codebases"]}
+                      "mimo-v2.5-ep16.serve-codebases",
+                      "ling-3.0-flash-ep8.serve-longanswers"]}

@@ -197,6 +197,35 @@ def test_gated_delta_decode_compiles_for_v5e(monkeypatch, one_chip,
     assert mem.temp_size_in_bytes < arena_bytes // 8
 
 
+def test_channel_decay_decode_compiles_for_v5e(monkeypatch, one_chip,
+                                               no_compile_cache):
+    """The same kernel with a decay a key channel at Ling-3.0-flash's KDA
+    widths and its cell's slots (32 heads of 128 by 128, one head a lane
+    tile; 256 slots over 257 rows of 2 MB): one Mosaic custom call, the
+    arena aliased through it, the ``(N, d_k, H)`` decays one more operand
+    beside the keys."""
+    from flexflow_tpu.kernels import gated_delta as gd
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    n, rows, h, dk, dv = 256, 257, 32, 128, 128
+    assert gd.supported(n, h, dk, dv, (rows, dk, h * dv), jnp.float32)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(gd.gated_delta_decode, donate_argnums=(0,)).lower(
+        sds((rows, dk, h * dv)), sds((n,), jnp.int32), sds((n, h, dk)),
+        sds((n, h, dk)), sds((n, h, dv)), sds((n, h, dk)),
+        sds((n, h))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gated_delta_decode" in text
+    mem = compiled.memory_analysis()
+    arena_bytes = rows * dk * h * dv * 4
+    assert mem.alias_size_in_bytes >= arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes // 8
+
+
 @pytest.mark.parametrize("cell, n, rows, h, g", [
     ("granite-4.0-h-micro.serve-rag", 48, 49, 64, 1),
     ("nemotron3-super-ep4.serve-agents", 128, 129, 128, 8)])
@@ -1512,3 +1541,134 @@ def test_mimo_chunk_programs_hold_no_square(mimo_programs, name):
             ("ROUTED_EXPERTS", "route"),
             ("ROUTED_EXPERTS", "experts")} <= subs, subs
     assert mem.temp_size_in_bytes < 2 << 30
+
+
+@pytest.fixture(scope="module")
+def ling_programs(one_chip):
+    """The decode step and the widest prefill of two KDA layers and the
+    latent layer (published layers 3, 4, 5, all with experts) at
+    Ling-3.0-flash's published widths and its cell's sizes (256 slots,
+    contexts to 4,096, blocks of 16, 64 of 512 experts held, an eighth of
+    the vocabulary), compiled for the described chip: {name: (the compiled
+    text, its memory analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import ling as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ling-3.0-flash-ep8.json")) as f:
+        config = json.load(f)
+    config = dict(config, first_layer=3, num_hidden_layers=3)
+    slots, max_length = 256, 4096
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=16, kv_dtype="bfloat16",
+                               calibrate=False, prefill_buckets=[1024])
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            compiled = jax.jit(dec._prefill_step, donate_argnums=(2,)).lower(
+                params, ints(1, 1024), pool, Addresses(ints(1, mb), ints(1)),
+                ints(1)).compile()
+            out["prefill"] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+def test_ling_decode_step_steps_both_kinds_by_their_kernels(ling_programs):
+    """The decode step of two KDA layers and a latent layer at 256 slots:
+    both kinds read in place (``attention_path`` ``kernel``): a
+    ``gated_delta_decode`` call a KDA layer, under the op's ``rule``, each
+    the only maker of its state arena (257 x 128 x 4,096 float32, 539 MB;
+    aliased through), ONE ``latent_attention_decode`` at 32 heads, and the
+    grouped experts' kernel an expert layer with no ``conditional`` (256
+    rows are past the count at which a step's form is the kernel's
+    whatever it names); no loop over the slots, and nothing beside the
+    weights and the pool but 256 MB (136 when written)."""
+    from flexflow_tpu.core.op import parse_scope
+
+    programs, dec = ling_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path["decode"] == "kernel"
+    assert dec.attention_path_by_entry == {
+        "state": {"decode": "kernel", "chunk": None},
+        "latent": {"decode": "kernel", "chunk": None}}
+    assert " while(" not in text
+    assert " conditional(" not in text
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum("gated_delta_decode" in ln for ln in calls) == 2
+    assert sum("latent_attention_decode" in ln for ln in calls) == 1
+    assert sum("grouped_experts" in ln for ln in calls) == 3
+    for ln in calls:
+        if "gated_delta_decode" in ln:
+            (scope,) = re.findall(r'op_name="([^"]+)"', ln)
+            assert parse_scope(scope)[0] == "KIMI_DELTA_ATTENTION"
+            assert parse_scope(scope)[2] == ("rule",)
+            assert "f32[257,128,4096]" in ln
+    owners = {parse_scope(m) for m in re.findall(r'op_name="([^"]+)"', text)
+              } - {None}
+    subs = {(kind, sub) for kind, _, subs_, _ in owners for sub in subs_}
+    assert {("KIMI_DELTA_ATTENTION", "project"),
+            ("KIMI_DELTA_ATTENTION", "conv"),
+            ("KIMI_DELTA_ATTENTION", "gate"),
+            ("KIMI_DELTA_ATTENTION", "rule"),
+            ("KIMI_DELTA_ATTENTION", "write"),
+            ("KIMI_DELTA_ATTENTION", "out"),
+            ("LATENT_ATTENTION", "attend"), ("LATENT_ATTENTION", "gate"),
+            ("ROUTED_EXPERTS", "route"),
+            ("ROUTED_EXPERTS", "experts")} <= subs, subs
+    assert mem.temp_size_in_bytes < 256 << 20
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+
+
+def test_ling_prefill_runs_the_channel_rule_in_jnp(ling_programs):
+    """The widest prefill: the per-channel rule is the jnp form (no
+    whole-sequence kernel for a decay a channel yet; the only loops are
+    the KDA ops' walks over their chunks and the system's rows, under
+    ``rule``), the experts the grouped kernel, and its temporaries stay
+    under 3 GB beside a pool that fills the chip."""
+    programs, dec = ling_programs
+    text, mem = programs["prefill"]
+    assert "gated_delta_chunks" not in text
+    for ln in text.splitlines():
+        if " while(" in ln:
+            assert "ff.KIMI_DELTA_ATTENTION." in ln and "/rule/" in ln, ln
+    assert mem.temp_size_in_bytes < 3 << 30
