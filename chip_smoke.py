@@ -645,6 +645,67 @@ def check_ssd_step(slots: int, heads: int, head_dim: int, state: int,
     return err
 
 
+# The kernel and the jnp lines sum the same four float32 products in the
+# same order; the compiler may still contract a product and a sum into one
+# rounding in one and not in the other.
+STATE_TAILS_RANGE_TOL = 1e-6
+
+
+def check_state_tails(slots: int, channels: int, mosaic: bool) -> float:
+    """A delta-rule step's tails through the kernel
+    (``StateEntry._tails_arena``, ``kernels/gated_delta.py`` ``tails_step``:
+    flat on the lanes, one pass over the arena in arena order) against
+    the slot-order lines it replaced (a window of ``(n, taps, channels)``
+    through ``GatedDeltaNet.convolve``'s sum, ``_spread_rows`` back) over
+    the same bfloat16 arena: every slot on a row of its own, in no order,
+    but two idle ones on the null row. The stepped arena must come back
+    bit for bit, the null row with it; returns the largest error of the
+    live slots' convolved rows as a share of the reference's largest
+    magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels import gated_delta as gd
+    from flexflow_tpu.serving.cache_entry import StateEntry, _spread_rows
+
+    taps, bf, f32 = 4, jnp.bfloat16, jnp.float32
+    rows_n, c = slots + 1, channels
+    _require(gd.tails_supported((rows_n, (taps - 1) * c), bf, c),
+             f"gated_delta.tails_supported() refuses {rows_n} rows of "
+             f"{taps - 1} x {c}")
+    rng = np.random.default_rng(0)
+    rows = rng.permutation(np.arange(1, rows_n)).astype(np.int32)
+    rows[[1, slots - 1]] = 0
+    w = jnp.asarray(rng.normal(size=(taps, c)), bf)
+    args = (jnp.asarray(rng.normal(size=(rows_n, (taps - 1) * c)), bf),
+            jnp.asarray(rows), jnp.asarray(rng.normal(size=(slots, c)), bf))
+
+    def slot_order(tails, rows, inputs):
+        window = jnp.concatenate(
+            [tails[rows].reshape(slots, taps - 1, c), inputs[:, None]], 1)
+        acc = sum(w.astype(f32)[j] * window.astype(f32)[:, j:j + 1]
+                  for j in range(taps))
+        return jax.nn.silu(acc), _spread_rows(
+            tails, rows, window[:, 1:].reshape(slots, -1))
+
+    got_fn = jax.jit(StateEntry._tails_arena)
+    if mosaic:
+        _assert_mosaic(got_fn, *args, w)
+    u, new = got_fn(*args, w)
+    u_ref, new_ref = jax.jit(slot_order)(*args)
+    _require(np.array_equal(np.asarray(new.astype(f32)),
+                            np.asarray(new_ref.astype(f32))),
+             f"state tails ({slots} slots, {c} channels): the kernel left "
+             "other tails than the slot-order lines")
+    live = rows != 0
+    err = float(np.max(np.abs(np.asarray(u) - np.asarray(u_ref))[live])
+                / np.max(np.abs(np.asarray(u_ref)[live])))
+    _require(err <= STATE_TAILS_RANGE_TOL,
+             f"state tails ({slots} slots, {c} channels): max error "
+             f"{err:.2e} of range > {STATE_TAILS_RANGE_TOL}")
+    return err
+
+
 # Both arms of a counted call multiply bfloat16 rows by bfloat16 matrices
 # with float32 sums and round the activation to bfloat16 before the down
 # product: against the same product in float32 each carries those roundings
@@ -823,6 +884,13 @@ def phase_kernels(sizes: SmokeSizes, batch: int) -> Dict:
                         ("nemotron", (128, 128, 64, 128, 8) if mosaic
                          else (4, 8, 32, 16, 2))):
         errs[f"ssd_step_{name}"] = "%.1e" % check_ssd_step(*shape, mosaic)
+    # a delta-rule step's tails at the long-answers and the documents
+    # cells' shapes (256 slots of 3 x 12,288, 32 of 3 x 11,520): the
+    # kernel against the slot-order lines; toys under the interpreter
+    for name, shape in (("ling", (256, 12288) if mosaic else (6, 128)),
+                        ("olmo", (32, 11520) if mosaic else (20, 384))):
+        errs[f"state_tails_{name}"] = "%.1e" % check_state_tails(*shape,
+                                                                 mosaic)
     # a decode step's held experts at the chains cell's share (48 slots of
     # one pick over 16 experts of 2,048 x 2,048, 6 named), both arms of
     # the counted call; 256-wide experts under the interpreter
@@ -855,7 +923,8 @@ def serve_hybrid() -> Dict[str, str]:
     kinds' kernels take) through ``GenerationInstance``: three greedy
     requests over two slots and three prefill buckets. Returns how its
     programs ran: on the chip the prefills must take the whole-sequence
-    kernel and the decode step read both caches in place."""
+    kernel and the decode step read both caches in place and step its
+    convolution tails by theirs."""
     import jax
 
     from flexflow_tpu import FFModel
@@ -889,8 +958,11 @@ def serve_hybrid() -> Dict[str, str]:
         kv = inst.stats()["kv"]
     finally:
         inst.stop()
+    # (the step's convolution tails among them: 512 channels, taps of
+    # whole lane tiles)
     paths = {"hybrid_prefill_path": kv["state"]["prefill_path"],
-             "hybrid_attention_path": kv["attention_path"]["decode"]}
+             "hybrid_attention_path": kv["attention_path"]["decode"],
+             "hybrid_tails_path": kv["tails_path"]}
     _require(set(paths.values()) == {"kernel"}
              or jax.default_backend() != "tpu",
              f"the hybrid's programs took {paths}")

@@ -1,5 +1,6 @@
 """The gated delta rule as Pallas TPU kernels: the decode step (every
-active slot's state read once, updated, and written back in place) and,
+active slot's state read once, updated, and written back in place), the
+step of the convolution's kept inputs beside it (:func:`tails_step`) and,
 further down, the whole-sequence form a prefill runs.
 
 A gated-delta-rule op (ops/gated_delta.py ``GatedDeltaNet``) keeps, for a
@@ -219,6 +220,93 @@ def gated_delta_decode(arena, rows, q, k, v, alpha, beta):
         *((keys(alpha),) if channel else ()), heads=heads,
         interpret=pallas_mode() == "interpret")
     return o.reshape(n, heads, value_dim), arena
+
+
+# ---- a step's convolution tails: one pass over the arena in arena order -----
+
+# lanes of a row the body takes at a time (a run of whole lane tiles that
+# divides the channels): four taps and their float32 sum in registers
+TAILS_LANES = 512
+
+
+def tails_supported(arena_shape, arena_dtype, channels: int) -> bool:
+    """Whether :func:`tails_step` takes this arena: ``(rows, tail *
+    channels)`` of 2- or 4-byte numbers, taps of whole lane tiles, and one
+    sublane tile of rows (in and out, twice each for the pipeline) with
+    its inputs and its float32 results within the VMEM budget. No floor
+    on the rows: at the documents cell's 33 the kernel is 25 us a layer
+    where the jnp lines are 57 (``tools/state_tails_forms.py``,
+    ``PERF.md`` section 6, PR 59)."""
+    if pallas_mode() is None or channels % LANES:
+        return False
+    if len(arena_shape) != 2 or arena_shape[1] % channels:
+        return False
+    item = jnp.dtype(arena_dtype).itemsize
+    if item not in (2, 4):
+        return False
+    block = (32 // item) * (4 * arena_shape[1] * item
+                            + 2 * channels * (item + 4))
+    return block <= VMEM_BUDGET_BYTES
+
+
+def _tails_kernel(live_ref, x_ref, w_ref, t_ref, t_out, u_ref, *, taps):
+    c = x_ref.shape[1]
+    lanes = max(n for n in range(LANES, TAILS_LANES + 1, LANES) if c % n == 0)
+    live = live_ref[...] != 0                                 # (rows, 1)
+    for at in range(0, c, lanes):
+        cut = [t_ref[:, j * c + at:j * c + at + lanes]
+               for j in range(taps - 1)] + [x_ref[:, at:at + lanes]]
+        # ``GatedDeltaNet.convolve``'s sum: float32 products from tap 0
+        acc = sum(w_ref[j:j + 1, at:at + lanes] * t.astype(jnp.float32)
+                  for j, t in enumerate(cut))
+        u_ref[:, at:at + lanes] = acc * jax.nn.sigmoid(acc)
+        for j in range(taps - 1):
+            t_out[:, j * c + at:j * c + at + lanes] = jnp.where(
+                live, cut[j + 1], cut[j])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _tails_step(tails, live, x_r, w, *, interpret):
+    r, width = tails.shape
+    taps, c = w.shape
+    rb = 32 // tails.dtype.itemsize                   # one sublane tile
+    by_rows = lambda cols: pl.BlockSpec((rb, cols), lambda i: (i, 0))  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_tails_kernel, taps=taps),
+        grid=(pl.cdiv(r, rb),),
+        in_specs=[by_rows(1), by_rows(c),
+                  pl.BlockSpec((taps, c), lambda i: (0, 0)), by_rows(width)],
+        out_specs=[by_rows(width), by_rows(c)],
+        out_shape=[jax.ShapeDtypeStruct(tails.shape, tails.dtype),
+                   jax.ShapeDtypeStruct((r, c), jnp.float32)],
+        input_output_aliases={3: 0},                  # the arena, in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="state_tails_step",
+    )(live.astype(jnp.int32)[:, None], x_r, w.astype(jnp.float32), tails)
+
+
+def tails_step(tails, live, x_r, w):
+    """One position's convolution a row over a request's kept inputs
+    ("tails") where they lie: ``tails`` (R, (K - 1) C), donated, a row's
+    last ``K - 1`` inputs oldest first side by side on the lanes, so that
+    tap ``j`` is the lanes ``[j C, (j + 1) C)``: whole lane tiles, a slice
+    that moves nothing; ``x_r`` (R, C) each ROW's new inputs; ``live``
+    (R,) whether a slot names the row; ``w`` (K, C) the taps' weights.
+    Returns (``silu`` of the K float32 products summed from tap 0, (R, C)
+    float32; the arena with every live row shifted by one tap behind its
+    inputs, the others as they were). A grid step takes one sublane tile
+    of rows at their whole width, which under the arena's tiling is one
+    contiguous copy each way: NOT a slot a grid step with the row's
+    address prefetched, as :func:`gated_delta_decode` has it: one row of
+    a 2-D bfloat16 arena is ``width / 128`` pieces of 256 bytes. Callers
+    check :func:`tails_supported` first; the call is jitted on its own,
+    so that a model's layers trace it once."""
+    tails, u = _tails_step(tails, live, x_r.astype(tails.dtype), w,
+                           interpret=pallas_mode() == "interpret")
+    return u, tails
 
 
 # ---- the whole-sequence form: a chunk of tokens a grid step -----------------

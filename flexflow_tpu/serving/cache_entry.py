@@ -192,6 +192,15 @@ def _spread_rows(arena, rows, new):
                      new.astype(arena.dtype)[jnp.argmax(hot, axis=0)], arena)
 
 
+def _named_by(num_rows: int, rows):
+    """Of a per-request arena's ``num_rows`` rows, which of the slots
+    ``rows`` (N,) names each (0 where none does) and whether one does:
+    :func:`_spread_rows`' one-hot mask of (slots, rows). Row 0 is
+    nobody's."""
+    hot = (rows[:, None] == _iota(num_rows)) & (rows[:, None] != 0)
+    return jnp.argmax(hot, axis=0), hot.any(0)
+
+
 def _prefill_slots(tables, lengths, pos, bs):
     """Where a group of prompts' rows go: row i's position p lands in
     block ``tables[i, p // bs]`` at offset ``p % bs``; padding positions
@@ -1094,14 +1103,26 @@ class StateEntry(EntryKind):
     whatever ``kv_dtype`` says (rounded each step it would drift for a
     request's whole life; ``stats()`` says so); the convolution's tail is
     stored as the per-token kinds' rows are. A state cannot be rolled
-    back, so a step takes one token a slot. A step puts the slots' new
-    tails back through :func:`_spread_rows` (each arena row takes the
-    values of the slot that names it, one pass over the arena where it
-    lies) and not by a scatter: at the published widths a row is 34,560
-    numbers, and the TPU's compiler runs a scatter of 32 such rows as a
-    sequential loop of dynamic-update-slices, 2.5 ms of a 19 ms step
-    (the state itself the kernel steps in place; a prefill's one to a
-    few rows keep ``_put``'s scatter)."""
+    back, so a step takes one token a slot. A step takes, convolves and
+    puts back the tails flat as they lie, in ONE pass over the arena's
+    rows in arena order (:meth:`_tails_arena`, ``gated_delta.tails_step``:
+    each row takes the inputs of the slot that names it; tap ``j`` of a
+    row is the lanes ``[j C, (j + 1) C)``, whole lane tiles where ``C =
+    channels`` is a multiple of 128), neither by a gather of the slots'
+    rows cut into ``(n, taps, channels)`` (a relayout of the whole take
+    each way: 4 of a 26 ms step at 256 slots of 36,864 numbers,
+    ``PERF.md``, PR 59) nor by a scatter (at widths like these the TPU's
+    compiler runs a scatter of 32 rows as a sequential loop of
+    dynamic-update-slices, 2.5 ms of a 19 ms step). Where that kernel
+    refuses (a width of no whole lane tiles; the CPU) the slot-order
+    lines and :func:`_spread_rows` run, which are also its reference
+    (``tails_path`` and the counters ``state_tails.path.kernel`` /
+    ``.rows`` say which). The state itself the state kernel steps in
+    place; a prefill's one to a few rows keep ``_put``'s scatter. Row 0,
+    the null row that idle slots name, is nobody's: the tails' pass never
+    writes it (an idle slot convolves its taps behind zeros), the state
+    kernel and a prefill's padding rows do, and nothing reads it as
+    zeros."""
 
     heads: int
     key_dim: int
@@ -1126,25 +1147,47 @@ class StateEntry(EntryKind):
                                      dtype))
 
     def stats(self):
-        return {"entry": self.name, "state_dtype": "float32"}
+        return {"entry": self.name, "state_dtype": "float32",
+                "tails_path": self.tails_path()}
 
     def reads_in_place(self, op, entry, slots, window, max_blocks):
         return window == 1 and gated_delta.supported(
             slots, self.heads, self.key_dim, self.value_dim, entry[0].shape,
             entry[0].dtype)
 
+    def tails_path(self, dtype=jnp.bfloat16) -> str:
+        """How a step takes its tails out of an arena of ``dtype``:
+        ``"kernel"`` (``gated_delta.tails_step``: flat on the lanes, one
+        pass over the arena's rows in arena order) where a tap is whole
+        lane tiles and the kernels run, else ``"rows"`` (the slots' rows
+        gathered and cut into taps, put back through
+        :func:`_spread_rows`)."""
+        return "kernel" if gated_delta.tails_supported(
+            (2, self.tail * self.channels), dtype, self.channels) else "rows"
+
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         n = x.shape[0]                   # one token a slot: ``max_window``
         state, tails = entry
+        path = self.tails_path(tails.dtype)
+        # which form this lowering took, counted once a trace (as
+        # ``ssm_step.path.*``): a chip run has to be able to say
+        metrics_registry().counter(f"state_tails.path.{path}").inc()
         with sub_scope("conv"):
-            window = jnp.concatenate(
-                [tails[addr.rows].reshape(n, self.tail, self.channels),
-                 op.conv_inputs(weights, x).astype(tails.dtype)], axis=1)
-            q, k, v = op.heads(op.convolve(weights, window))
+            if path == "kernel":
+                u, tails = self._tails_arena(
+                    tails, addr.rows, op.conv_inputs(weights, x)[:, 0],
+                    weights["conv"])
+            else:
+                window = jnp.concatenate(
+                    [tails[addr.rows].reshape(n, self.tail, self.channels),
+                     op.conv_inputs(weights, x).astype(tails.dtype)], axis=1)
+                u = op.convolve(weights, window)
+            q, k, v = op.heads(u)
         g, beta = op.gates(weights, x)
-        with sub_scope("write"):
-            tails = _spread_rows(tails, addr.rows,
-                                 window[:, 1:].reshape(n, -1))
+        if path == "rows":
+            with sub_scope("write"):
+                tails = _spread_rows(tails, addr.rows,
+                                     window[:, 1:].reshape(n, -1))
         with sub_scope("rule"):
             update = (gated_delta.gated_delta_decode
                       if self.reads_in_place(op, entry, n, 1, 0)
@@ -1152,6 +1195,21 @@ class StateEntry(EntryKind):
             o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
                               jnp.exp(g[:, 0]), beta[:, 0])
         return op.finish(weights, x, o[:, None]), (state, tails)
+
+    @staticmethod
+    def _tails_arena(tails, rows, inputs, w):
+        """The slots' convolved rows (N, 1, channels) float32 and the arena
+        stepped, in arena order: row r takes the inputs of the slot that
+        names it (a row nobody names takes zeros and is left as it was),
+        ``tails_step`` convolves every row behind its own taps and shifts
+        the live ones where they lie, and the slots take their rows'
+        results. Nothing is cut into ``(n, taps, channels)`` and no row
+        ``tail * channels`` wide is gathered: two takes of rows
+        ``channels`` wide. A live row's new tail is moved, not computed."""
+        slot_of, live = _named_by(tails.shape[0], rows)
+        x_r = jnp.where(live[:, None], inputs[slot_of], 0)
+        u_r, tails = gated_delta.tails_step(tails, live, x_r, w)
+        return u_r[rows][:, None], tails
 
     def prefill_path(self, bucket):
         """``"kernel"`` (the fused whole-sequence kernel) or ``"scan"``

@@ -261,6 +261,142 @@ def test_the_spread_lowers_to_a_take_and_no_scatter():
     assert "dot_general" not in text and "gather" in text
 
 
+# ---- a delta-rule op's tails, flat on the lanes in arena order ---------------
+
+_WIDTHS = {128: (4, 8, 16), 384: (4, 32, 32), 96: (4, 8, 8)}  # channels: H, d_k, d_v
+
+
+def _delta_op(channels, dtype="float32"):
+    """A gated-delta-rule op ``channels`` wide, its kind, drawn weights."""
+    from flexflow_tpu.ffconst import DataType
+
+    h, dk, dv = _WIDTHS[channels]
+    ff = FFModel(FFConfig(batch_size=6, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    x = ff.create_tensor((6, 1, 24), DataType.FLOAT, name="x")
+    ff.gated_delta_net(x, num_heads=h, key_dim=dk, value_dim=dv, name="gdn")
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    op = [o for o in ff.compiled.ops if o.name == "gdn"][0]
+    assert op.channels == channels
+    rng = np.random.default_rng(channels)
+    w = {k: jnp.asarray(rng.normal(size=v.shape) * (0.3 if v.ndim > 1
+                                                    else 1.0), dtype)
+         for k, v in ff.compiled.params["gdn"].items()}
+    return op, StateEntry.for_op(op, None, 32), w
+
+
+def _tails_case(channels, dtype, rows):
+    """An arena of 7 rows, six slots' inputs (slot 3's NaN), row 0 sevens."""
+    rng = np.random.default_rng(channels + len(rows))
+    tails = jnp.asarray(rng.normal(size=(7, 3 * channels)), dtype)
+    tails = tails.at[0].set(7.0)
+    inputs = jnp.asarray(rng.normal(size=(len(rows), channels)), dtype)
+    return tails, inputs.at[3].set(jnp.nan), jnp.asarray(rows, jnp.int32)
+
+
+def _tails_in_slot_order(op, w, tails, rows, inputs):
+    """The lines a step held up to PR 58 and holds where the kernel
+    refuses: the slots' rows cut into taps, ``convolve`` over the window
+    ``(n, taps, channels)``, ``_spread_rows`` on the way back."""
+    n, c = inputs.shape
+    window = jnp.concatenate([tails[rows].reshape(n, 3, c),
+                              inputs[:, None]], axis=1)
+    return (op.convolve(w, window),
+            cache_entry._spread_rows(tails, rows,
+                                     window[:, 1:].reshape(n, -1)))
+
+
+@pytest.mark.parametrize("case", list(_ROWS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("channels", [128, 384])
+def test_tails_stepped_in_arena_order_are_the_spreads_bit_for_bit(
+        monkeypatch, channels, dtype, case):
+    """``StateEntry._tails_arena`` (the ``tails_step`` kernel, interpreted)
+    against the slot-order lines it replaced: the whole arena BIT for bit
+    (every live row's new tail moved into place, the rows nobody names
+    and row 0 as they were), slot 3's NaN in its own row and nowhere
+    else, and the live slots' convolved rows the same float32 products
+    in the same order."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    op, kind, w = _delta_op(channels, dtype)
+    assert kind.tails_path(dtype) == "kernel"
+    tails, inputs, rows = _tails_case(channels, dtype, _ROWS[case])
+    u, got = jax.jit(kind._tails_arena, donate_argnums=0)(
+        jnp.array(tails), rows, inputs, w["conv"])
+    u_ref, want = jax.jit(_tails_in_slot_order, static_argnums=0)(
+        op, w, tails, rows, inputs)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_array_equal(_bits(got)[0], _bits(tails)[0])
+    live = np.asarray(rows) != 0
+    assert u.shape == u_ref.shape == (6, 1, channels)
+    assert u.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(u)[live], np.asarray(u_ref)[live],
+                               rtol=1e-6, atol=1e-6)
+    clean = live & (np.arange(6) != 3)
+    assert np.isfinite(np.asarray(u)[clean]).all()
+    # an idle slot convolves the null row's taps behind zeros, whatever
+    # any slot carries
+    assert np.isfinite(np.asarray(u)[~live]).all()
+    poisoned = {int(rows[3])} - {0}
+    assert np.isfinite(np.asarray(got.astype(jnp.float32))[
+        sorted(set(range(7)) - poisoned)]).all()
+
+
+@pytest.mark.parametrize("channels, mode, path", [
+    (128, "interpret", "kernel"), (384, "interpret", "kernel"),
+    (96, "interpret", "rows"), (128, "off", "rows")])
+def test_a_states_step_takes_its_tails_by_the_width_and_counts_it(
+        monkeypatch, channels, mode, path):
+    """``StateEntry.step`` whole: where a tap is whole lane tiles and the
+    kernels run, the arena-order pass, counted ``state_tails.path.kernel``,
+    its program with no gather or scatter of ``tail * channels``-wide rows
+    and no ``(n, taps, channels)`` or ``(n, tail, channels)`` array; a
+    width of 96, or the kernels off, takes the old lines (``.rows``),
+    which hold all three. Both leave the outputs and the arenas the old
+    lines leave."""
+    from flexflow_tpu.obs.metrics import metrics_registry
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    op, kind, w = _delta_op(channels)
+    tails, _, rows = _tails_case(channels, "float32", _ROWS["several_idle"])
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(6, 1, 24)), jnp.float32)
+    state = jnp.asarray(rng.normal(size=(7, op.key_dim,
+                                         op.num_heads * op.value_dim)),
+                        jnp.float32)
+    addr = Addresses(jnp.zeros((6, 2), jnp.int32), rows)
+
+    def step(state, tails):
+        return kind.step(op, w, x, None, (state, tails), addr, None)
+
+    # (a function of its own: a second trace of ``step`` would be the first)
+    y_ref, (state_ref, tails_ref) = jax.jit(lambda *a: step(*a))(state, tails)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    assert kind.tails_path(tails.dtype) == kind.stats()["tails_path"] == path
+    reg = metrics_registry()
+    before = {p: reg.counter(f"state_tails.path.{p}").value
+              for p in ("kernel", "rows")}
+    lowered = jax.jit(step).lower(state, tails)
+    assert {p for p, v in before.items()
+            if reg.counter(f"state_tails.path.{p}").value > v} == {path}
+    text = lowered.as_text()
+    wide = [line for line in text.splitlines()
+            if ("gather" in line or "scatter" in line)
+            and f"x{3 * channels}x" in line]
+    cut = [f"x{taps}x{channels}x" in text for taps in (3, 4)]
+    assert (not wide and not any(cut)) if path == "kernel" \
+        else (wide and all(cut))
+    y, (new_state, new_tails) = lowered.compile()(state, tails)
+    np.testing.assert_array_equal(_bits(new_tails), _bits(tails_ref))
+    live = np.asarray(rows) != 0
+    np.testing.assert_allclose(np.asarray(y)[live], np.asarray(y_ref)[live],
+                               rtol=1e-5, atol=1e-6)
+    held = np.asarray(rows)[live]
+    np.testing.assert_allclose(np.asarray(new_state)[held],
+                               np.asarray(state_ref)[held],
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_a_latent_row_has_no_int8_form():
     assert LatentEntry(24).int8_form is None
     assert PairEntry(4, 8).int8_form == Int8PairEntry(4, 8)

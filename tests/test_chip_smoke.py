@@ -41,8 +41,12 @@ def test_phases_run_to_completion_at_toy_size(monkeypatch, tmp_path):
     # the small hybrid's prefills took the whole-sequence kernel
     assert records[1]["hybrid_prefill_path"] == "kernel"
     assert records[1]["hybrid_attention_path"] == "kernel"
+    assert records[1]["hybrid_tails_path"] == "kernel"
     # both Mamba-2 families stepped their states by the kernel
     assert float(records[0]["ssd_step_nemotron"]) < 1e-5
+    # a delta-rule step's tails: the kernel against slot order
+    assert float(records[0]["state_tails_ling"]) <= \
+        chip_smoke.STATE_TAILS_RANGE_TOL
     assert records[1]["granite_ssm_step_path"] == "kernel"
     assert records[1]["nemotron_ssm_step_path"] == "kernel"
     assert records[2]["devices"] == 8 and records[2]["attention_path"] == "flash"

@@ -124,6 +124,48 @@ def test_op_shapes_weights_and_flops():
     assert out.shape == (2, 12, E) and bool(jnp.isfinite(out).all())
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("heads, dk, dv", [(4, 8, 16), (4, 32, 32),
+                                           (4, 8, 8)])
+def test_tails_step_is_convolve_over_the_rows_windows(monkeypatch, heads,
+                                                      dk, dv, dtype):
+    """``tails_step`` (interpreted), one position a row over FLAT tails
+    (taps side by side on the lanes: 128 and 384 channels; 96 it refuses)
+    of 19 rows, more than two sublane tiles and no whole number of them,
+    against ``convolve`` over the window the serving step used to build,
+    ``(n, taps, channels)``: the same float32 products summed in the same
+    order, so the same numbers; a live row's tail shifted behind its
+    inputs bit for bit, the others as they were."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    ff = FFModel(FFConfig(batch_size=2, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    x = ff.create_tensor((2, 12, E), DataType.FLOAT, name="x")
+    ff.gated_delta_net(x, num_heads=heads, key_dim=dk, value_dim=dv,
+                       name="gdn")
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    op = [o for o in ff.compiled.ops if o.name == "gdn"][0]
+    c, r = op.channels, 19
+    assert gd.tails_supported((r, 3 * c), dtype, c) == (c % 128 == 0)
+    assert not gd.tails_supported((r, 3 * c), jnp.int8, c)
+    if c % 128:
+        return
+    rng = np.random.default_rng(c)
+    w = jnp.asarray(rng.normal(size=(4, c)), dtype)
+    tails = jnp.asarray(rng.normal(size=(r, 3 * c)), dtype)
+    new = jnp.asarray(rng.normal(size=(r, c)), dtype)
+    live = jnp.asarray(rng.integers(0, 2, size=r).astype(bool))
+    window = jnp.concatenate([tails.reshape(r, 3, c), new[:, None]], axis=1)
+    want = jax.jit(op.convolve)({"conv": w}, window)[:, 0]
+    got, stepped = jax.jit(gd.tails_step)(tails, live, new, w)
+    assert got.shape == (r, c) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert stepped.dtype == tails.dtype
+    np.testing.assert_array_equal(
+        np.asarray(stepped.astype(jnp.float32)),
+        np.asarray(jnp.where(live[:, None], window[:, 1:].reshape(r, -1),
+                             tails).astype(jnp.float32)))
+
+
 def test_padded_prefill_leaves_the_true_lengths_state_and_tail():
     """A prompt of 70 in a bucket of 150, beside one of 150: each row's
     state and convolution tail are those of its own length, and its

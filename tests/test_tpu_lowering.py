@@ -226,6 +226,38 @@ def test_channel_decay_decode_compiles_for_v5e(monkeypatch, one_chip,
     assert mem.temp_size_in_bytes < arena_bytes // 8
 
 
+@pytest.mark.parametrize("cell, rows, channels", [
+    ("ling-3.0-flash-ep8.serve-longanswers", 257, 12288),
+    ("olmo-hybrid-pp2.serve-documents", 33, 11520)])
+def test_state_tails_step_compiles_for_v5e(monkeypatch, one_chip,
+                                           no_compile_cache, cell, rows,
+                                           channels):
+    """The tails' kernel at both cells' arenas (257 rows of 3 x 12,288
+    bfloat16, 33 of 3 x 11,520: 17 and 3 grid steps of one sublane tile of
+    rows, the last hanging over the arena's edge): one Mosaic custom
+    call, the arena aliased through it, nothing beside it on the device
+    but what it is given and the float32 rows it returns."""
+    from flexflow_tpu.kernels import gated_delta as gd
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    bf = jnp.bfloat16
+    assert gd.tails_supported((rows, 3 * channels), bf, channels)
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(gd.tails_step, donate_argnums=(0,)).lower(
+        sds((rows, 3 * channels)), sds((rows,), jnp.bool_),
+        sds((rows, channels)), sds((4, channels))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "state_tails_step" in text
+    mem = compiled.memory_analysis()
+    arena_bytes = rows * 3 * channels * 2
+    assert mem.alias_size_in_bytes >= arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes // 8
+
+
 @pytest.mark.parametrize("cell, n, rows, h, g", [
     ("granite-4.0-h-micro.serve-rag", 48, 49, 64, 1),
     ("nemotron3-super-ep4.serve-agents", 128, 129, 128, 8)])
@@ -581,10 +613,12 @@ def test_hybrid_decode_step_loops_over_no_slots(monkeypatch, one_chip,
     widths and its cell's 32 slots: the decode step holds no ``while``
     and no ``dynamic-update-slice`` (a scatter of the convolution tails,
     rows of 34,560 numbers, lowered to a sequential loop over the slots).
-    The tails are written by ONE fusion over the arena's 33 rows that
-    carries the state op's ``write`` in its ``op_name``, in place (the
-    arena's parameter is aliased to its output), and the state kernel
-    carries its ``rule``: what a device trace reads them by."""
+    The tails are stepped by ONE kernel over the arena's 33 rows
+    (``state_tails_step``, PR 59; a fusion under ``write`` before it) that
+    carries the state op's ``conv`` in its ``op_name``, in place (the
+    arena's parameter is aliased to its output), and no gather in the
+    program yields rows of 34,560; the state kernel carries its ``rule``:
+    what a device trace reads them by."""
     import json
     import os
 
@@ -640,10 +674,13 @@ def test_hybrid_decode_step_loops_over_no_slots(monkeypatch, one_chip,
         if m and m.group(2) not in ("parameter", "bitcast", "copy-done",
                                     "get-tuple-element"):
             made[m.group(1)] = m.group(2)
-    assert list(made.values()) == ["fusion"], made
-    (writer,) = made
+    assert made == {}, made
+    # ... but the kernel's call, whose result is a tuple (the arena, the
+    # convolved rows)
+    (writer,) = [k for k in names if k.startswith("state_tails_step")]
     assert parse_scope(names[writer]) == (
-        "GATED_DELTA_NET", mixer, ("write",), "fwd")
+        "GATED_DELTA_NET", mixer, ("conv",), "fwd")
+    assert not re.search(rf"\[\d+,{tails.shape[1]}\]\S* gather\(", text)
     # no copy of either arena: both donated parameters alias their outputs
     leaves = jax.tree_util.tree_leaves(args)
     pooled = [i for i, a in enumerate(leaves)
@@ -1617,7 +1654,10 @@ def test_ling_decode_step_steps_both_kinds_by_their_kernels(ling_programs):
     both kinds read in place (``attention_path`` ``kernel``): a
     ``gated_delta_decode`` call a KDA layer, under the op's ``rule``, each
     the only maker of its state arena (257 x 128 x 4,096 float32, 539 MB;
-    aliased through), ONE ``latent_attention_decode`` at 32 heads, and the
+    aliased through), a ``state_tails_step`` call a KDA layer under its
+    ``conv`` (since PR 59 the step has no ``write`` of tails: the kernel
+    shifts them where they lie), ONE ``latent_attention_decode`` at 32
+    heads, and the
     grouped experts' kernel an expert layer with no ``conditional`` (256
     rows are past the count at which a step's form is the kernel's
     whatever it names); no loop over the slots, and nothing beside the
@@ -1635,6 +1675,7 @@ def test_ling_decode_step_steps_both_kinds_by_their_kernels(ling_programs):
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert sum("gated_delta_decode" in ln for ln in calls) == 2
+    assert sum("state_tails_step" in ln for ln in calls) == 2
     assert sum("latent_attention_decode" in ln for ln in calls) == 1
     assert sum("grouped_experts" in ln for ln in calls) == 3
     for ln in calls:
@@ -1650,7 +1691,6 @@ def test_ling_decode_step_steps_both_kinds_by_their_kernels(ling_programs):
             ("KIMI_DELTA_ATTENTION", "conv"),
             ("KIMI_DELTA_ATTENTION", "gate"),
             ("KIMI_DELTA_ATTENTION", "rule"),
-            ("KIMI_DELTA_ATTENTION", "write"),
             ("KIMI_DELTA_ATTENTION", "out"),
             ("LATENT_ATTENTION", "attend"), ("LATENT_ATTENTION", "gate"),
             ("ROUTED_EXPERTS", "route"),

@@ -81,6 +81,15 @@ def test_ssd_step_compiled(slots, heads, groups):
     chip_smoke.check_ssd_step(slots, heads, 64, 128, groups, mosaic=True)
 
 
+@pytest.mark.parametrize("slots,channels", [(256, 12288), (32, 11520)])
+def test_state_tails_compiled(slots, channels):
+    """A delta-rule step's tails through the kernel against the
+    slot-order lines, at the long-answers cell's shape (256 slots, rows of
+    3 x 12,288 bfloat16) and the documents cell's (32 slots, 3 x
+    11,520)."""
+    chip_smoke.check_state_tails(slots, channels, mosaic=True)
+
+
 def test_counted_experts_compiled():
     """The chains cell's expert layer at a decode step (48 slots of one
     pick over 16 experts of 2,048 x 2,048, bfloat16): the shapes say
