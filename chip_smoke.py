@@ -653,10 +653,10 @@ STATE_TAILS_RANGE_TOL = 1e-6
 
 def check_state_tails(slots: int, channels: int, mosaic: bool) -> float:
     """A delta-rule step's tails through the kernel
-    (``StateEntry._tails_arena``, ``kernels/gated_delta.py`` ``tails_step``:
+    (``ConvTail.step_arena``, ``kernels/gated_delta.py`` ``tails_step``:
     flat on the lanes, one pass over the arena in arena order) against
     the slot-order lines it replaced (a window of ``(n, taps, channels)``
-    through ``GatedDeltaNet.convolve``'s sum, ``_spread_rows`` back) over
+    through ``GatedDeltaNet.convolve``'s sum, ``spread_rows`` back) over
     the same bfloat16 arena: every slot on a row of its own, in no order,
     but two idle ones on the null row. The stepped arena must come back
     bit for bit, the null row with it; returns the largest error of the
@@ -666,7 +666,8 @@ def check_state_tails(slots: int, channels: int, mosaic: bool) -> float:
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels import gated_delta as gd
-    from flexflow_tpu.serving.cache_entry import StateEntry, _spread_rows
+    from flexflow_tpu.ops.rows import spread_rows
+    from flexflow_tpu.serving.cache_entry import ConvTail
 
     taps, bf, f32 = 4, jnp.bfloat16, jnp.float32
     rows_n, c = slots + 1, channels
@@ -685,10 +686,10 @@ def check_state_tails(slots: int, channels: int, mosaic: bool) -> float:
             [tails[rows].reshape(slots, taps - 1, c), inputs[:, None]], 1)
         acc = sum(w.astype(f32)[j] * window.astype(f32)[:, j:j + 1]
                   for j in range(taps))
-        return jax.nn.silu(acc), _spread_rows(
+        return jax.nn.silu(acc), spread_rows(
             tails, rows, window[:, 1:].reshape(slots, -1))
 
-    got_fn = jax.jit(StateEntry._tails_arena)
+    got_fn = jax.jit(ConvTail.step_arena)
     if mosaic:
         _assert_mosaic(got_fn, *args, w)
     u, new = got_fn(*args, w)

@@ -40,6 +40,7 @@ from ..ffconst import OpType
 from ..runtime.initializer import ConstantInitializer, DefaultWeightInitializer
 from .attention import _mm, apply_rotary, rotary_inv_freq
 from .norm import rms_norm
+from .rows import named_by
 
 CHUNK = 128  # tokens a step of the block form's scan
 _HI = jax.lax.Precision.HIGHEST
@@ -110,20 +111,22 @@ def decay_step(state, q, k, v, lam):
 def decay_step_rows(arena, rows, q, k, v, lam):
     """:func:`decay_step` on the rows of an arena, in place: ``arena``
     (R, H, D, Dv) holds a state a row, slot n steps row ``rows[n]`` (row
-    0 is nobody's: a slot that names it steps nothing and reads zeros).
-    The slots' q, k and v are spread over the rows by a one-hot product
-    and the arena is updated elementwise, so that no state is gathered
-    or scattered (a gather of rows of 2 MB lowers to a loop over the
-    slots). Returns (o (N, H, Dv), the new arena)."""
-    hot = ((rows[:, None] == jax.lax.iota(jnp.int32, arena.shape[0]))
-           & (rows[:, None] != 0)).astype(jnp.float32)          # (N, R)
-    qr, kr, vr = (jnp.einsum("nr,nhd->rhd", hot, a, precision=_HI)
-                  for a in (q, k, v))
-    stepped = (hot.sum(0) > 0)[:, None, None, None]
-    arena = jnp.where(stepped, lam[None, :, None, None] * arena
+    0 is nobody's: a slot that names it steps nothing and reads zeros,
+    a row nobody names taking a query of zeros). Each row TAKES the q, k
+    and v of the slot that names it (``ops/rows.py`` ``named_by``: moved,
+    not multiplied in, so one slot's NaN stays in its own row) and the
+    arena is updated elementwise under ``live``, so that no state is
+    gathered or scattered (a gather of rows of 2 MB lowers to a loop
+    over the slots); the slots take their rows' outputs back. Returns (o
+    (N, H, Dv), the new arena)."""
+    slot_of, live = named_by(arena.shape[0], rows)
+    qr = jnp.where(live[:, None, None], q[slot_of], 0.0)
+    kr, vr = k[slot_of], v[slot_of]
+    arena = jnp.where(live[:, None, None, None],
+                      lam[None, :, None, None] * arena
                       + kr[..., :, None] * vr[..., None, :], arena)
     o = jnp.einsum("rhd,rhdv->rhv", qr, arena, precision=_HI)
-    return jnp.einsum("nr,rhv->nhv", hot, o, precision=_HI), arena
+    return o[rows], arena
 
 
 @register_op

@@ -54,6 +54,7 @@ from ..ffconst import OpType
 from ..runtime.initializer import (ConstantInitializer,
                                    DefaultWeightInitializer, ZeroInitializer)
 from .attention import _mm
+from .rows import named
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -126,15 +127,17 @@ def ssd_step_rows(arena, rows, u, decay, bm, cm):
     before the heads' channels (``kernels/ssd_step.py``), and slot n steps
     row ``rows[n]`` (row 0 is nobody's: a slot that names it steps
     nothing and reads what lies there). Each row takes the inputs of the
-    slot that names it and the arena is updated elementwise where it
-    lies: one read and one write of the states, no gather or scatter of
-    them. ``y`` is read from the state before the update (``S_t C = a
-    S_{t-1} C + u (B . C)``), so the pass that writes the new state is
-    the pass that reads the old. Returns (y (N, H, P), the new arena)."""
+    slot that names it (``ops/rows.py``; by the mask itself: ``named_by``
+    would reduce it to ``live`` before the takes, and the programs'
+    recorded text has it behind them) and the arena is updated
+    elementwise where it lies: one read and one write of the states, no
+    gather or scatter of them. ``y`` is read from the state before the
+    update (``S_t C = a S_{t-1} C + u (B . C)``), so the pass that writes
+    the new state is the pass that reads the old. Returns (y (N, H, P),
+    the new arena)."""
     n, h, p = u.shape
     per = h // bm.shape[1]
-    hot = ((rows[:, None] == jax.lax.iota(jnp.int32, arena.shape[0]))
-           & (rows[:, None] != 0))                               # (N, R)
+    hot = named(arena.shape[0], rows)                            # (N, R)
     who = jnp.argmax(hot, axis=0)
     ur, ar, br, cr = (v[who] for v in (u, decay, bm, cm))
 

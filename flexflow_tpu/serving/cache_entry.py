@@ -11,10 +11,12 @@ allocates what a kind describes and counts its bytes, the programs
 limits. A new kind is a class and a line in :data:`KINDS`; those three
 modules do not change.
 
-A kind keeps a row a TOKEN, addressed through the slots' block tables, or
-(``per_request``) a row a REQUEST, addressed by the slots' rows; the
-programs pass both as one :class:`~flexflow_tpu.serving.kv_cache
-.Addresses` and a kind takes the half that is its own.
+A kind keeps a row a TOKEN (``arenas``), addressed through the slots'
+block tables, or a row a REQUEST (``request_arenas``), addressed by the
+slots' rows, or both; the programs pass both addresses as one
+:class:`~flexflow_tpu.serving.kv_cache.Addresses`. What a step hands
+between its slots and an arena's rows goes through ``ops/rows.py``, and a
+request's convolution tail is a :class:`ConvTail`, whichever kind holds it.
 
 A kind that defines ``chunk`` serves a prompt in chunks, each behind what
 the chunks before left: the pair through its block table, the window over
@@ -50,6 +52,7 @@ from ..obs.metrics import metrics_registry
 from ..ops import block_sparse_attention as bsa
 from ..ops import mamba2
 from ..ops.gated_delta import delta_rule_path
+from ..ops.rows import named_by, spread_rows
 from ..parallel.ring_attention import sink_softmax
 from .kv_cache import NULL_BLOCK, NULL_ROW
 
@@ -173,34 +176,6 @@ def _put(arena, flat, rows):
         arena.shape)
 
 
-def _spread_rows(arena, rows, new):
-    """``new`` (N, width) written over the rows ``rows`` (N,) of a
-    per-request arena (R, width) where the arena lies: each row finds
-    the slot that names it through a one-hot mask of (slots, rows) and
-    takes that slot's values, and the donated arena keeps the rest, in
-    one elementwise pass. No scatter: N rows this wide scattered lower
-    to a sequential loop over the slots on the TPU, thirty times what
-    the arena's bytes need; a take of R rows does not. A live row is
-    named by at most one slot and its values are moved, not computed, so
-    the stepped rows are the scatter's bit for bit in any dtype, and a
-    slot's NaN stays in its own row (a one-hot PRODUCT over the slots is
-    a tenth of a millisecond a step faster at the hybrid cell's shapes
-    and gives up both: ``PERF.md``, PR 39). Row 0 is nobody's: a slot
-    that names it writes nothing."""
-    hot = (rows[:, None] == _iota(arena.shape[0])) & (rows[:, None] != 0)
-    return jnp.where(hot.any(0)[:, None],
-                     new.astype(arena.dtype)[jnp.argmax(hot, axis=0)], arena)
-
-
-def _named_by(num_rows: int, rows):
-    """Of a per-request arena's ``num_rows`` rows, which of the slots
-    ``rows`` (N,) names each (0 where none does) and whether one does:
-    :func:`_spread_rows`' one-hot mask of (slots, rows). Row 0 is
-    nobody's."""
-    hot = (rows[:, None] == _iota(num_rows)) & (rows[:, None] != 0)
-    return jnp.argmax(hot, axis=0), hot.any(0)
-
-
 def _prefill_slots(tables, lengths, pos, bs):
     """Where a group of prompts' rows go: row i's position p lands in
     block ``tables[i, p // bs]`` at offset ``p % bs``; padding positions
@@ -230,6 +205,89 @@ def latent_row_lanes(width: int) -> int:
     return -(-int(width) // 128) * 128
 
 
+@dataclasses.dataclass(frozen=True)
+class ConvTail:
+    """The last ``tail`` inputs of a layer's causal convolution, ``channels``
+    wide, kept a REQUEST: one arena ``(rows, tail * channels)``, a row's
+    inputs oldest first side by side on the lanes, stored as the per-token
+    kinds' rows are. The kinds that hold one (``conv_tail``) write none of
+    this themselves. A step has two forms: in slot order (:meth:`take`,
+    the new inputs :meth:`behind`, the op's own convolution,
+    :meth:`slide`) and in arena order (:meth:`step_arena`, where
+    :meth:`path` says ``"kernel"``); the first is the second's reference,
+    and the CPU's."""
+
+    tail: int          # positions of the convolution's inputs kept
+    channels: int
+
+    def paged(self, rows, dtype):
+        return jax.ShapeDtypeStruct((rows, self.tail * self.channels), dtype)
+
+    def dense(self, batch, dtype):
+        """The dense form's array: a tail a sequence, uncut."""
+        return jax.ShapeDtypeStruct((batch, self.tail, self.channels), dtype)
+
+    def path(self, dtype=jnp.bfloat16) -> str:
+        """``"kernel"`` where :meth:`step_arena` takes an arena of ``dtype``
+        (a tap of whole lane tiles, the kernels running), else ``"rows"``."""
+        return "kernel" if gated_delta.tails_supported(
+            (2, self.tail * self.channels), dtype, self.channels) else "rows"
+
+    def take(self, arena, rows, later=None):
+        """The tails of ``rows``, (N, tail, channels); with ``later`` (N,)
+        bool, zeros where it is false: a prompt's first chunk starts from
+        nothing, not from what the request before left in the row."""
+        if later is None:
+            return arena[rows].reshape(len(rows), self.tail, self.channels)
+        # (the mask first, then the take: the order the programs have had)
+        return jnp.where(later[:, None, None], arena[rows].reshape(
+            len(rows), self.tail, self.channels), 0)
+
+    @staticmethod
+    def behind(taken, new):
+        """The window to convolve: ``new`` (N, S, channels) behind them."""
+        return jnp.concatenate([taken, new.astype(taken.dtype)], axis=1)
+
+    @sub_scope("write")
+    def slide(self, arena, rows, window):
+        """A step's ``window`` (N, tail + 1, channels) less its oldest
+        position, back over the slots' rows (row 0 is nobody's)."""
+        return spread_rows(arena, rows, window[:, 1:].reshape(len(rows), -1))
+
+    @staticmethod
+    def step_arena(arena, rows, inputs, w):
+        """The slots' convolved rows (N, 1, channels) float32 and the arena
+        stepped, in arena order: row r takes the inputs of the slot that
+        names it (a row nobody names takes zeros and is left as it was),
+        ``gated_delta.tails_step`` convolves every row behind its own
+        taps (tap ``j`` is the lanes ``[j C, (j + 1) C)``, whole lane
+        tiles where ``C = channels`` is a multiple of 128) and shifts the
+        live ones where they lie, and the slots take their rows' results:
+        two takes of rows ``channels`` wide, neither a gather of the
+        slots' rows cut into ``(n, taps, channels)`` (a relayout of the
+        whole take each way: 4 of a 26 ms step at 256 slots of 36,864
+        numbers, ``PERF.md``, PR 59) nor a scatter (32 rows this wide run
+        as a sequential loop of dynamic-update-slices, 2.5 ms of a 19 ms
+        step). A live row's new tail is moved, not computed."""
+        slot_of, live = named_by(arena.shape[0], rows)
+        x_r = jnp.where(live[:, None], inputs[slot_of], 0)
+        u_r, arena = gated_delta.tails_step(arena, live, x_r, w)
+        return u_r[rows][:, None], arena
+
+    @staticmethod
+    def put(arena, rows, tail):
+        """The prompts' tails (N, tail, channels) over their rows (padding
+        rows over the null row; one to a few rows keep the scatter)."""
+        return arena.at[rows].set(
+            tail.reshape(len(rows), -1).astype(arena.dtype))
+
+    def left(self, window, lengths):
+        """Of a chunk's ``window`` (N, tail + S, channels), the ``tail``
+        positions that end at each prompt's TRUE length ``lengths``."""
+        at = lengths[:, None] + _iota(self.tail)[None, :]
+        return jnp.take_along_axis(window, at[:, :, None], axis=1)
+
+
 class EntryKind:
     """What one op keeps for one token (or one request), and everything
     that depends on it. ``op`` is the op, ``weights`` its parameters,
@@ -241,9 +299,11 @@ class EntryKind:
     * ``for_op(op, positions_id, max_length)`` (a classmethod): the kind
       of ``op`` in a graph whose positions input has that tensor id,
       decoded up to ``max_length``; raises what the op cannot serve;
-    * ``arenas(n, block_size, dtype)``: one op's arenas, a
-      ``jax.ShapeDtypeStruct`` each, of ``n`` blocks (of ``n`` rows, for a
-      ``per_request`` kind);
+    * ``arenas(num_blocks, block_size, dtype)``: what one op keeps a
+      TOKEN, a ``jax.ShapeDtypeStruct`` each, of ``num_blocks`` blocks,
+      and ``request_arenas(rows, block_size, dtype)``: what it keeps a
+      REQUEST, of ``rows`` rows; either may be ``()``, and an entry is
+      the first's arrays, then the second's;
     * ``reads_in_place(op, entry, slots, window, max_blocks)``: whether a
       ``window``-token step reads ``entry`` by a kernel, in place, and
       ``decode_chunk_tokens(entry, max_blocks)``: the tokens one loop
@@ -254,8 +314,8 @@ class EntryKind:
       tokens a slot at positions ``seq_lens .. seq_lens + W - 1``, their
       rows written through the tables (an idle slot's, and positions past
       a table's span, into the null block), then each slot's cache
-      attended through its table; a per-request kind updates each slot's
-      row (an idle slot's is the null row); returns (out, entry);
+      attended through its table; a kind of a row a request updates each
+      slot's row (an idle slot's is the null row); returns (out, entry);
     * ``prefill(op, weights, x, positions, entry, addr, lengths)``: a
       group of prompts padded to one bucket, of true ``lengths``, from
       nothing: what they leave is written where ``addr`` says (padding
@@ -288,11 +348,13 @@ class EntryKind:
     name = ""                          # what stats()["kv"]["entry"] says
     max_window: Optional[int] = None   # new tokens a slot a step; None: any
     int8_form: Optional["EntryKind"] = None
-    per_request = False                # a row a request, not a row a token
     chunked = False                    # defines ``chunk``
 
     def stats(self) -> Dict:
         return {"entry": self.name}
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks) -> bool:
+        return False                    # no kernel: a step gathers
 
     def decode_chunk_tokens(self, entry, max_blocks: int) -> Optional[int]:
         return None
@@ -342,30 +404,32 @@ class EntryKind:
         """This kind in a model over ``devices`` devices."""
         return self
 
-    def request_arenas(self, rows, dtype) -> Tuple:
-        """What a kind of a row a token keeps a REQUEST besides: arenas
-        of ``rows`` rows, which the pool allocates behind :meth:`arenas`'
-        own in the op's entry and addresses by the slots' rows. None, for
-        most kinds."""
+    def arenas(self, num_blocks, block_size, dtype) -> Tuple:
+        return ()                       # nothing a token
+
+    def request_arenas(self, rows, block_size, dtype) -> Tuple:
+        """What the kind keeps a REQUEST: arenas of ``rows`` rows, which
+        the pool allocates behind :meth:`arenas`' own in the op's entry
+        and addresses by the slots' rows (``block_size`` is the pool's,
+        for a kind whose row is made of its blocks). None, for most
+        kinds."""
         return ()
 
     @property
     def keeps_row(self) -> bool:
-        """Whether a request holds a row of this kind's arenas: all of
-        them (``per_request``) or some (:meth:`request_arenas`)."""
-        return self.per_request or bool(self.request_arenas(1, jnp.float32))
+        """Whether a request holds a row of any of this kind's arenas."""
+        return bool(self.request_arenas(1, 1, jnp.float32))
 
     def token_bytes(self, dtype) -> int:
-        """Bytes one token (one request, for a ``per_request`` kind) takes
-        in one op's arenas stored as ``dtype``: plain arithmetic on
-        :meth:`arenas`, nothing is allocated."""
+        """Bytes one token takes in one op's arenas stored as ``dtype``:
+        plain arithmetic on :meth:`arenas`, nothing is allocated."""
         return sum(math.prod(a.shape) * a.dtype.itemsize
                    for a in self.arenas(1, 1, dtype))
 
     def request_bytes(self, dtype) -> int:
         """Bytes one request takes in :meth:`request_arenas`."""
         return sum(math.prod(a.shape) * a.dtype.itemsize
-                   for a in self.request_arenas(1, dtype))
+                   for a in self.request_arenas(1, 1, dtype))
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """:meth:`whole`, and the rows scattered through each prompt's
@@ -696,8 +760,8 @@ class Int8PairEntry(PairEntry):
 class WindowEntry(PairEntry):
     """The pair of an op with a ``window``: a request keeps the last
     ``window`` tokens' keys and values and no more, in a ring of its own.
-    A ``per_request`` kind whose arenas are rows of ``window /
-    block_size`` blocks in the pair layout: position p lies in block ``(p
+    It keeps nothing a token: its arenas are a REQUEST's, rows of ``window
+    / block_size`` blocks in the pair layout: position p lies in block ``(p
     // block_size) % ring`` of the request's row at offset ``p %
     block_size``, i.e. at row ``p % window`` of its ring, where position
     ``p - window`` lay before it. Keys are stored rotated, so where a row
@@ -712,7 +776,6 @@ class WindowEntry(PairEntry):
     window: int = 0
     name = "window"
     max_window = 1
-    per_request = True
     int8_form = None
 
     def stats(self):
@@ -733,7 +796,9 @@ class WindowEntry(PairEntry):
                 f"{block_size}: the ring is made of the pool's blocks")
         return self.window // block_size
 
-    def arenas(self, rows, block_size, dtype):
+    arenas = EntryKind.arenas            # nothing a token: a ring a request
+
+    def request_arenas(self, rows, block_size, dtype):
         return super().arenas(rows * self.ring_blocks(block_size),
                               block_size, dtype)
 
@@ -778,9 +843,7 @@ class WindowEntry(PairEntry):
                     op.scale, sink)
         return op.project_out(weights, ctxv, x), entry
 
-    def prefill(self, op, weights, x, positions, entry, addr, lengths):
-        return self.chunk(op, weights, x, positions, entry, addr,
-                          jnp.zeros_like(lengths), lengths)
+    prefill = EntryKind.prefill          # a first chunk, not a whole bucket
 
     def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
         """``[what the ring holds | the chunk]`` attended by absolute
@@ -852,12 +915,13 @@ class CcaEntry(PairEntry):
     last ``tail`` rows of ``z``, what the op's two convolutions read
     before a token, flat, and the last token's ``u W_v2``, the half of
     the next token's values that comes from it; both stored as the pair
-    is. An entry is ``(keys, values, tails, prevs)``. A step takes one
-    token a slot and puts the slots' rows back through
-    :func:`_spread_rows`, for :class:`StateEntry`'s reasons; a prompt's
-    first chunk starts from zeros, whatever the row held, a later one
-    from what the chunk before wrote; what is written is what the
-    chunk's TRUE length leaves. No int8 form."""
+    is. An entry is ``(keys, values, tails, prevs)``. The tails are a
+    :class:`ConvTail`'s, taken, slid and put by it; the half values go
+    the same ways beside them (a step takes one token a slot and puts
+    the slots' rows back through ``spread_rows``; a prompt's first chunk
+    starts from zeros, whatever the row held, a later one from what the
+    chunk before wrote; what is written is what the chunk's TRUE length
+    leaves). No int8 form."""
 
     tail: int = 0
     channels: int = 0
@@ -873,9 +937,12 @@ class CcaEntry(PairEntry):
         return cls(op.num_kv_heads, op.head_dim, op.num_heads,
                    tail=op.tail, channels=op.channels)
 
-    def request_arenas(self, rows, dtype):
-        return (jax.ShapeDtypeStruct((rows, self.tail * self.channels),
-                                     dtype),
+    @property
+    def conv_tail(self) -> ConvTail:
+        return ConvTail(self.tail, self.channels)
+
+    def request_arenas(self, rows, block_size, dtype):
+        return (self.conv_tail.paged(rows, dtype),
                 jax.ShapeDtypeStruct((rows, self.heads * self.head_dim // 2),
                                      dtype))
 
@@ -895,44 +962,36 @@ class CcaEntry(PairEntry):
         return qh, kh, vh, window, own
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
-        n = x.shape[0]                   # one token a slot: ``max_window``
-        tails, prevs = entry[2:]
+        tails, prevs = entry[2:]         # one token a slot: ``max_window``
         with sub_scope("mix"):
-            tail = tails[addr.rows].reshape(n, self.tail, self.channels)
+            tail = self.conv_tail.take(tails, addr.rows)
             prev = prevs[addr.rows]
         qh, kh, vh, window, own = self._mixed(op, weights, x, positions,
                                               tail, prev)
+        tails = self.conv_tail.slide(tails, addr.rows, window)
         with sub_scope("write"):
-            rows = (_spread_rows(tails, addr.rows,
-                                 window[:, 1:].reshape(n, -1)),
-                    _spread_rows(prevs, addr.rows, own[:, 0]))
+            prevs = spread_rows(prevs, addr.rows, own[:, 0])
         ctxv, pair = self._step_rows(op, qh, kh, vh, entry[:2], addr,
                                      seq_lens)
-        return op.out(weights, ctxv), pair + rows
+        return op.out(weights, ctxv), pair + (tails, prevs)
 
-    def prefill(self, op, weights, x, positions, entry, addr, lengths):
-        return self.chunk(op, weights, x, positions, entry, addr,
-                          jnp.zeros_like(lengths), lengths)
+    prefill = EntryKind.prefill          # a first chunk, not a whole bucket
 
     def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
-        n = x.shape[0]
         tails, prevs = entry[2:]
         later = offsets > 0
         with sub_scope("mix"):
-            tail = jnp.where(later[:, None, None], tails[addr.rows].reshape(
-                n, self.tail, self.channels), 0)
+            tail = self.conv_tail.take(tails, addr.rows, later)
             prev = jnp.where(later[:, None], prevs[addr.rows], 0)
         qh, kh, vh, window, own = self._mixed(op, weights, x, positions,
                                               tail, prev)
         with sub_scope("write"):
             # the last ``tail`` rows of z behind the chunk's TRUE length,
             # and its last live token's half value
-            at = lengths[:, None] + _iota(self.tail)[None, :]
-            left = jnp.take_along_axis(window, at[:, :, None], axis=1)
+            left = self.conv_tail.left(window, lengths)
             last = jnp.take_along_axis(
                 own, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
-            rows = (tails.at[addr.rows].set(
-                        left.reshape(n, -1).astype(tails.dtype)),
+            rows = (self.conv_tail.put(tails, addr.rows, left),
                     prevs.at[addr.rows].set(last[:, 0].astype(prevs.dtype)))
         ctxv, pair = self._chunk_rows(op, qh, kh, vh, entry[:2], addr,
                                       offsets, lengths)
@@ -948,7 +1007,7 @@ class CcaEntry(PairEntry):
 
     def dense_shapes(self, batch, max_length, dtype):
         return super().dense_shapes(batch, max_length, dtype) + (
-            jax.ShapeDtypeStruct((batch, self.tail, self.channels), dtype),
+            self.conv_tail.dense(batch, dtype),
             jax.ShapeDtypeStruct((batch, self.heads * self.head_dim // 2),
                                  dtype))
 
@@ -1094,129 +1153,38 @@ class LatentEntry(EntryKind):
         return out, (rows_cache,)
 
 
-@dataclasses.dataclass(frozen=True)
-class StateEntry(EntryKind):
-    """A gated-delta-rule op's one row a REQUEST: the float32 state of
-    its heads, ``(d_k, H d_v)`` with the heads side by side on the lanes
-    (``kernels/gated_delta.py``: the tiles pad nothing), and the last
-    ``taps - 1`` inputs of its convolution, flat. The state is float32
-    whatever ``kv_dtype`` says (rounded each step it would drift for a
-    request's whole life; ``stats()`` says so); the convolution's tail is
-    stored as the per-token kinds' rows are. A state cannot be rolled
-    back, so a step takes one token a slot. A step takes, convolves and
-    puts back the tails flat as they lie, in ONE pass over the arena's
-    rows in arena order (:meth:`_tails_arena`, ``gated_delta.tails_step``:
-    each row takes the inputs of the slot that names it; tap ``j`` of a
-    row is the lanes ``[j C, (j + 1) C)``, whole lane tiles where ``C =
-    channels`` is a multiple of 128), neither by a gather of the slots'
-    rows cut into ``(n, taps, channels)`` (a relayout of the whole take
-    each way: 4 of a 26 ms step at 256 slots of 36,864 numbers,
-    ``PERF.md``, PR 59) nor by a scatter (at widths like these the TPU's
-    compiler runs a scatter of 32 rows as a sequential loop of
-    dynamic-update-slices, 2.5 ms of a 19 ms step). Where that kernel
-    refuses (a width of no whole lane tiles; the CPU) the slot-order
-    lines and :func:`_spread_rows` run, which are also its reference
-    (``tails_path`` and the counters ``state_tails.path.kernel`` /
-    ``.rows`` say which). The state itself the state kernel steps in
-    place; a prefill's one to a few rows keep ``_put``'s scatter. Row 0,
-    the null row that idle slots name, is nobody's: the tails' pass never
-    writes it (an idle slot convolves its taps behind zeros), the state
-    kernel and a prefill's padding rows do, and nothing reads it as
-    zeros."""
+class StateKind(EntryKind):
+    """A kind that keeps a recurrent state a REQUEST and nothing a token:
+    float32 whatever ``kv_dtype`` says (rounded each step it would drift
+    for a request's whole life; ``stats()`` says so), one token a slot a
+    step (a state cannot be rolled back). Nothing reads row 0, the null
+    row that idle slots name, as zeros."""
 
-    heads: int
-    key_dim: int
-    value_dim: int
-    tail: int          # positions of the convolution's inputs kept
-    channels: int
-    channel_decay: bool = False   # a decay a key channel (KimiDeltaAttention)
-    name = "state"
     max_window = 1
-    per_request = True
-
-    @classmethod
-    def for_op(cls, op, positions_id, max_length):
-        return cls(op.num_heads, op.key_dim, op.value_dim, op.conv_taps - 1,
-                   op.channels, op.channel_decay)
-
-    def arenas(self, rows, block_size, dtype):
-        return (jax.ShapeDtypeStruct(
-                    (rows, self.key_dim, self.heads * self.value_dim),
-                    jnp.float32),
-                jax.ShapeDtypeStruct((rows, self.tail * self.channels),
-                                     dtype))
 
     def stats(self):
-        return {"entry": self.name, "state_dtype": "float32",
-                "tails_path": self.tails_path()}
+        return {"entry": self.name, "state_dtype": "float32"}
 
-    def reads_in_place(self, op, entry, slots, window, max_blocks):
-        return window == 1 and gated_delta.supported(
-            slots, self.heads, self.key_dim, self.value_dim, entry[0].shape,
-            entry[0].dtype)
 
-    def tails_path(self, dtype=jnp.bfloat16) -> str:
-        """How a step takes its tails out of an arena of ``dtype``:
-        ``"kernel"`` (``gated_delta.tails_step``: flat on the lanes, one
-        pass over the arena's rows in arena order) where a tap is whole
-        lane tiles and the kernels run, else ``"rows"`` (the slots' rows
-        gathered and cut into taps, put back through
-        :func:`_spread_rows`)."""
-        return "kernel" if gated_delta.tails_supported(
-            (2, self.tail * self.channels), dtype, self.channels) else "rows"
+class TailedStateKind(StateKind):
+    """A :class:`StateKind` whose op reads its inputs through a short
+    causal convolution: a request's row is the state, in the layout the
+    kind's step kernel takes (``row_shape``; the op's own is
+    ``state_shape``, ``_to_row`` between them), and a :class:`ConvTail`.
+    A kind says its two shapes, ``_to_row`` and its ``step``."""
 
-    def step(self, op, weights, x, positions, entry, addr, seq_lens):
-        n = x.shape[0]                   # one token a slot: ``max_window``
-        state, tails = entry
-        path = self.tails_path(tails.dtype)
-        # which form this lowering took, counted once a trace (as
-        # ``ssm_step.path.*``): a chip run has to be able to say
-        metrics_registry().counter(f"state_tails.path.{path}").inc()
-        with sub_scope("conv"):
-            if path == "kernel":
-                u, tails = self._tails_arena(
-                    tails, addr.rows, op.conv_inputs(weights, x)[:, 0],
-                    weights["conv"])
-            else:
-                window = jnp.concatenate(
-                    [tails[addr.rows].reshape(n, self.tail, self.channels),
-                     op.conv_inputs(weights, x).astype(tails.dtype)], axis=1)
-                u = op.convolve(weights, window)
-            q, k, v = op.heads(u)
-        g, beta = op.gates(weights, x)
-        if path == "rows":
-            with sub_scope("write"):
-                tails = _spread_rows(tails, addr.rows,
-                                     window[:, 1:].reshape(n, -1))
-        with sub_scope("rule"):
-            update = (gated_delta.gated_delta_decode
-                      if self.reads_in_place(op, entry, n, 1, 0)
-                      else gated_delta.gated_delta_step)
-            o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
-                              jnp.exp(g[:, 0]), beta[:, 0])
-        return op.finish(weights, x, o[:, None]), (state, tails)
+    @property
+    def conv_tail(self) -> ConvTail:
+        return ConvTail(self.tail, self.channels)
 
-    @staticmethod
-    def _tails_arena(tails, rows, inputs, w):
-        """The slots' convolved rows (N, 1, channels) float32 and the arena
-        stepped, in arena order: row r takes the inputs of the slot that
-        names it (a row nobody names takes zeros and is left as it was),
-        ``tails_step`` convolves every row behind its own taps and shifts
-        the live ones where they lie, and the slots take their rows'
-        results. Nothing is cut into ``(n, taps, channels)`` and no row
-        ``tail * channels`` wide is gathered: two takes of rows
-        ``channels`` wide. A live row's new tail is moved, not computed."""
-        slot_of, live = _named_by(tails.shape[0], rows)
-        x_r = jnp.where(live[:, None], inputs[slot_of], 0)
-        u_r, tails = gated_delta.tails_step(tails, live, x_r, w)
-        return u_r[rows][:, None], tails
+    def request_arenas(self, rows, block_size, dtype):
+        return (jax.ShapeDtypeStruct((rows,) + self.row_shape, jnp.float32),
+                self.conv_tail.paged(rows, dtype))
 
-    def prefill_path(self, bucket):
-        """``"kernel"`` (the fused whole-sequence kernel) or ``"scan"``
-        (the jnp form), by the rule the op's lowering asks."""
-        return delta_rule_path(bucket, self.heads, self.key_dim,
-                               self.value_dim,
-                               channel_decay=self.channel_decay)
+    def dense_shapes(self, batch, max_length, dtype):
+        return (jax.ShapeDtypeStruct((batch,) + self.state_shape,
+                                     jnp.float32),
+                self.conv_tail.dense(batch, dtype))
 
     def prefill(self, op, weights, x, positions, entry, addr, lengths):
         """The op's chunked whole-sequence form from an empty state: what
@@ -1227,26 +1195,109 @@ class StateEntry(EntryKind):
 
     @sub_scope("write")
     def _put(self, entry, rows, state, tail):
-        n = state.shape[0]
-        lanes = jnp.moveaxis(state, 1, 2).reshape(n, self.key_dim, -1)
-        return (entry[0].at[rows].set(lanes),
-                entry[1].at[rows].set(
-                    tail.reshape(n, -1).astype(entry[1].dtype)))
+        """The prompts' states, as the op gives them, and tails over
+        their rows (padding rows over the null row)."""
+        return (entry[0].at[rows].set(self._to_row(state)),
+                self.conv_tail.put(entry[1], rows, tail))
 
     def whole(self, op, weights, x, positions):
         out, state, tail = op.whole(weights, x)
         return out, (state, tail), None
 
-    def dense_shapes(self, batch, max_length, dtype):
-        return (jax.ShapeDtypeStruct(
-                    (batch, self.heads, self.key_dim, self.value_dim),
-                    jnp.float32),
-                jax.ShapeDtypeStruct((batch, self.tail, self.channels),
-                                     dtype))
-
     def dense_step(self, op, weights, x, positions, cache, offset):
         out, state, tail = op.run(weights, x, *cache)
         return out, (state, tail.astype(cache[1].dtype))
+
+
+@dataclasses.dataclass(frozen=True)
+class StateEntry(TailedStateKind):
+    """A gated-delta-rule op's one row a REQUEST: the float32 state of
+    its heads, ``(d_k, H d_v)`` with the heads side by side on the lanes
+    (``kernels/gated_delta.py``: the tiles pad nothing), and the last
+    ``taps - 1`` inputs of its convolution (:class:`ConvTail`). A step
+    takes, convolves and puts back the tails flat as they lie, in ONE
+    pass over the arena's rows in arena order (``ConvTail.step_arena``);
+    where that kernel refuses (a width of no whole lane tiles; the CPU)
+    the tail's slot-order form runs, which is also its reference
+    (``tails_path`` and the counters ``state_tails.path.kernel`` /
+    ``.rows`` say which). The state itself the state kernel steps in
+    place. Of row 0 the tails' pass writes nothing (an idle slot
+    convolves its taps behind zeros); the state kernel and a prefill's
+    padding rows do."""
+
+    heads: int
+    key_dim: int
+    value_dim: int
+    tail: int          # positions of the convolution's inputs kept
+    channels: int
+    channel_decay: bool = False   # a decay a key channel (KimiDeltaAttention)
+    name = "state"
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        return cls(op.num_heads, op.key_dim, op.value_dim, op.conv_taps - 1,
+                   op.channels, op.channel_decay)
+
+    @property
+    def row_shape(self):
+        return (self.key_dim, self.heads * self.value_dim)
+
+    @property
+    def state_shape(self):
+        return (self.heads, self.key_dim, self.value_dim)
+
+    def _to_row(self, state):
+        return jnp.moveaxis(state, 1, 2).reshape(
+            state.shape[0], self.key_dim, -1)
+
+    def stats(self):
+        return dict(super().stats(), tails_path=self.tails_path())
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return window == 1 and gated_delta.supported(
+            slots, self.heads, self.key_dim, self.value_dim, entry[0].shape,
+            entry[0].dtype)
+
+    def tails_path(self, dtype=jnp.bfloat16) -> str:
+        """How a step takes its tails out of an arena of ``dtype``:
+        ``"kernel"`` (arena order) or ``"rows"`` (slot order)."""
+        return self.conv_tail.path(dtype)
+
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        n = x.shape[0]                   # one token a slot: ``max_window``
+        state, tails = entry
+        tail = self.conv_tail
+        path = tail.path(tails.dtype)
+        # which form this lowering took, counted once a trace (as
+        # ``ssm_step.path.*``): a chip run has to be able to say
+        metrics_registry().counter(f"state_tails.path.{path}").inc()
+        with sub_scope("conv"):
+            if path == "kernel":
+                u, tails = tail.step_arena(
+                    tails, addr.rows, op.conv_inputs(weights, x)[:, 0],
+                    weights["conv"])
+            else:
+                window = tail.behind(tail.take(tails, addr.rows),
+                                     op.conv_inputs(weights, x))
+                u = op.convolve(weights, window)
+            q, k, v = op.heads(u)
+        g, beta = op.gates(weights, x)
+        if path == "rows":
+            tails = tail.slide(tails, addr.rows, window)
+        with sub_scope("rule"):
+            update = (gated_delta.gated_delta_decode
+                      if self.reads_in_place(op, entry, n, 1, 0)
+                      else gated_delta.gated_delta_step)
+            o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
+                              jnp.exp(g[:, 0]), beta[:, 0])
+        return op.finish(weights, x, o[:, None]), (state, tails)
+
+    def prefill_path(self, bucket):
+        """``"kernel"`` (the fused whole-sequence kernel) or ``"scan"``
+        (the jnp form), by the rule the op's lowering asks."""
+        return delta_rule_path(bucket, self.heads, self.key_dim,
+                               self.value_dim,
+                               channel_decay=self.channel_decay)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1300,9 +1351,6 @@ class SparseEntry(EntryKind):
         return {"selected": {
             "blocks_read": sum(self.blocks_read(int(n)) for n in lengths),
             "blocks_live": int((lengths // self.geom.block + 1).sum())}}
-
-    def reads_in_place(self, op, entry, slots, window, max_blocks):
-        return False                    # no kernel yet: a step gathers
 
     # ---- addressing -------------------------------------------------------
     @staticmethod
@@ -1480,17 +1528,13 @@ class SparseEntry(EntryKind):
 
 
 @dataclasses.dataclass(frozen=True)
-class DecayStateEntry(EntryKind):
+class DecayStateEntry(StateKind):
     """A lightning-attention op's one row a REQUEST: the float32 state of
-    its heads, ``(H, D, D)``, in the rows the pool hands out to every
-    ``per_request`` kind. float32 whatever ``kv_dtype`` says and one
-    token a slot a step, for :class:`StateEntry`'s reasons."""
+    its heads, ``(H, D, D)``, and no convolution."""
 
     heads: int
     head_dim: int
     name = "decay_state"
-    max_window = 1
-    per_request = True
     chunked = True
 
     @classmethod
@@ -1500,15 +1544,9 @@ class DecayStateEntry(EntryKind):
                              f"the graph's positions input")
         return cls(op.num_heads, op.head_dim)
 
-    def arenas(self, rows, block_size, dtype):
+    def request_arenas(self, rows, block_size, dtype):
         return (jax.ShapeDtypeStruct(
             (rows, self.heads, self.head_dim, self.head_dim), jnp.float32),)
-
-    def stats(self):
-        return {"entry": self.name, "state_dtype": "float32"}
-
-    def reads_in_place(self, op, entry, slots, window, max_blocks):
-        return False
 
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         out, arena = op.step(weights, x, positions, entry[0], addr.rows)
@@ -1527,7 +1565,7 @@ class DecayStateEntry(EntryKind):
         return op.run(weights, x, positions, op.empty_state(x.shape[0]))
 
     def dense_shapes(self, batch, max_length, dtype):
-        return self.arenas(batch, 0, dtype)
+        return self.request_arenas(batch, 0, dtype)
 
     def dense_step(self, op, weights, x, positions, cache, offset):
         out, state = op.run(weights, x, positions, cache[0])
@@ -1535,26 +1573,24 @@ class DecayStateEntry(EntryKind):
 
 
 @dataclasses.dataclass(frozen=True)
-class SsmStateEntry(EntryKind):
+class SsmStateEntry(TailedStateKind):
     """A state-space op's one row a REQUEST (``ops/mamba2.py``): the
     float32 state of its heads, ``(N, H P)`` with the state's axis on the
     sublanes and the heads' channels side by side on the lanes
     (``kernels/ssd_step.py``: the step's sum over ``N`` runs down
-    sublanes), and the last ``taps - 1`` inputs of its convolution, flat,
-    in the rows the pool hands out to every ``per_request`` kind. float32
-    whatever ``kv_dtype`` says, one token a slot a step, and the tails
-    back through :func:`_spread_rows`, for :class:`StateEntry`'s reasons.
-    A step updates the states where they lie, so the decode program
-    gathers and scatters no state and holds no loop: on the chip by the
-    ``ssd_step_decode`` kernel, one call a layer that moves the live
-    slots' rows and no other (:meth:`reads_in_place`; the counter
-    ``ssm_step.path.kernel``); elsewhere by ``ssd_step_rows``, one
-    elementwise pass over the arena in which each row takes the inputs of
-    the slot that names it (``ssm_step.path.rows``: the CPU, the kernel's
-    reference). A prompt in a bucket is prefilled whole from zeros
-    (:meth:`prefill`); a prompt in chunks continues from the row
-    (:meth:`chunk`); both turn the op's ``(H, P, N)`` state a request
-    into the row's layout and back (:meth:`_put`, :meth:`_rows`)."""
+    sublanes), and the last ``taps - 1`` inputs of its convolution
+    (:class:`ConvTail`, stepped in slot order). A step updates the states
+    where they lie, so the decode program gathers and scatters no state
+    and holds no loop: on the chip by the ``ssd_step_decode`` kernel, one
+    call a layer that moves the live slots' rows and no other
+    (:meth:`reads_in_place`; the counter ``ssm_step.path.kernel``);
+    elsewhere by ``ssd_step_rows``, one elementwise pass over the arena
+    in which each row takes the inputs of the slot that names it
+    (``ssm_step.path.rows``: the CPU, the kernel's reference). A prompt
+    in a bucket is prefilled whole from zeros (:meth:`prefill`); a prompt
+    in chunks continues from the row (:meth:`chunk`); both turn the op's
+    ``(H, P, N)`` state a request into the row's layout and back
+    (:meth:`_to_row`, :meth:`_rows`)."""
 
     heads: int
     head_dim: int
@@ -1562,8 +1598,6 @@ class SsmStateEntry(EntryKind):
     tail: int          # positions of the convolution's inputs kept
     channels: int
     name = "ssm_state"
-    max_window = 1
-    per_request = True
     chunked = True
 
     @classmethod
@@ -1571,15 +1605,23 @@ class SsmStateEntry(EntryKind):
         return cls(op.num_heads, op.head_dim, op.state_size,
                    op.conv_taps - 1, op.channels)
 
-    def arenas(self, rows, block_size, dtype):
-        return (jax.ShapeDtypeStruct(
-                    (rows, self.state_size, self.heads * self.head_dim),
-                    jnp.float32),
-                jax.ShapeDtypeStruct((rows, self.tail * self.channels),
-                                     dtype))
+    @property
+    def row_shape(self):
+        return (self.state_size, self.heads * self.head_dim)
 
-    def stats(self):
-        return {"entry": self.name, "state_dtype": "float32"}
+    @property
+    def state_shape(self):
+        return (self.heads, self.head_dim, self.state_size)
+
+    def _to_row(self, state):
+        return jnp.swapaxes(
+            state.reshape(state.shape[0], -1, self.state_size), 1, 2)
+
+    def _rows(self, arena, rows):
+        """:meth:`_to_row` backwards: the states of ``rows`` as the op
+        takes them, (N, H, P, S)."""
+        return jnp.swapaxes(arena[rows], 1, 2).reshape(
+            len(rows), self.heads, self.head_dim, self.state_size)
 
     def reads_in_place(self, op, entry, slots, window, max_blocks):
         return window == 1 and ssd_step.supported(
@@ -1589,15 +1631,12 @@ class SsmStateEntry(EntryKind):
     def step(self, op, weights, x, positions, entry, addr, seq_lens):
         n = x.shape[0]                   # one token a slot: ``max_window``
         state, tails = entry
+        tail = self.conv_tail
         z, conv_in, dt = op.project(weights, x)
         with sub_scope("conv"):
-            window = jnp.concatenate(
-                [tails[addr.rows].reshape(n, self.tail, self.channels),
-                 conv_in.astype(tails.dtype)], axis=1)
+            window = tail.behind(tail.take(tails, addr.rows), conv_in)
             xs, bm, cm = op.split(op.convolve(weights, window))
-        with sub_scope("write"):
-            tails = _spread_rows(tails, addr.rows,
-                                 window[:, 1:].reshape(n, -1))
+        tails = tail.slide(tails, addr.rows, window)
         with sub_scope("rule"):
             dt1 = dt[:, 0]
             update, path = ((ssd_step.ssd_step_decode, "kernel")
@@ -1611,57 +1650,17 @@ class SsmStateEntry(EntryKind):
                 jnp.exp(dt1 * op.decay_rate(weights)), bm[:, 0], cm[:, 0])
         return op.finish(weights, z, xs, y[:, None]), (state, tails)
 
-    def prefill(self, op, weights, x, positions, entry, addr, lengths):
-        """The op's chunked whole-sequence form from an empty state: what
-        a prompt of its TRUE length leaves, whatever the bucket, written
-        over the request's row (padding rows over the null row)."""
-        out, state, tail = op.whole(weights, x, lengths)
-        return out, self._put(entry, addr.rows, state, tail)
-
     def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
         """A row holds what the request before left: a first chunk starts
         from zeros, not from it; a later one from the state and the tail
         the chunk before wrote. What is written is what the chunk's TRUE
         length leaves."""
-        n = x.shape[0]
         later = offsets > 0
         state = jnp.where(later[:, None, None, None],
                           self._rows(entry[0], addr.rows), 0.0)
-        tail = jnp.where(later[:, None, None], entry[1][addr.rows].reshape(
-            n, self.tail, self.channels), 0)
+        tail = self.conv_tail.take(entry[1], addr.rows, later)
         out, state, tail = op.run(weights, x, state, tail, lengths)
         return out, self._put(entry, addr.rows, state, tail)
-
-    @sub_scope("write")
-    def _put(self, entry, rows, state, tail):
-        """The prompts' states (N, H, P, S) and tails over their rows
-        (padding rows over the null row)."""
-        n = state.shape[0]
-        lanes = jnp.swapaxes(state.reshape(n, -1, self.state_size), 1, 2)
-        return (entry[0].at[rows].set(lanes),
-                entry[1].at[rows].set(
-                    tail.reshape(n, -1).astype(entry[1].dtype)))
-
-    def _rows(self, arena, rows):
-        """:meth:`_put` backwards: the states of ``rows`` as the op takes
-        them, (N, H, P, S)."""
-        return jnp.swapaxes(arena[rows], 1, 2).reshape(
-            len(rows), self.heads, self.head_dim, self.state_size)
-
-    def whole(self, op, weights, x, positions):
-        out, state, tail = op.whole(weights, x)
-        return out, (state, tail), None
-
-    def dense_shapes(self, batch, max_length, dtype):
-        return (jax.ShapeDtypeStruct(
-                    (batch, self.heads, self.head_dim, self.state_size),
-                    jnp.float32),
-                jax.ShapeDtypeStruct((batch, self.tail, self.channels),
-                                     dtype))
-
-    def dense_step(self, op, weights, x, positions, cache, offset):
-        out, state, tail = op.run(weights, x, *cache)
-        return out, (state, tail.astype(cache[1].dtype))
 
 
 # the kind of each op type that keeps something for a sequence: ``for_op``
@@ -1692,6 +1691,7 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
     return None
 
 
-__all__ = ["CcaEntry", "DecayStateEntry", "EntryKind", "Int8PairEntry", "KINDS",
-           "LatentEntry", "PairEntry", "SparseEntry", "SsmStateEntry",
-           "StateEntry", "WindowEntry", "kind_for", "latent_row_lanes"]
+__all__ = ["CcaEntry", "ConvTail", "DecayStateEntry", "EntryKind",
+           "Int8PairEntry", "KINDS", "LatentEntry", "PairEntry", "SparseEntry",
+           "SsmStateEntry", "StateEntry", "StateKind", "TailedStateKind",
+           "WindowEntry", "kind_for", "latent_row_lanes"]

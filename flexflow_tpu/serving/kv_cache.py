@@ -51,19 +51,20 @@ arena, 72 arenas, 3.0 GB.
 
 **Per-request entries.** A layer may keep, instead of a row a token, a
 state of fixed size a request (a gated-delta-rule op:
-:class:`~flexflow_tpu.serving.cache_entry.StateEntry`). Such a kind says
-``per_request``, and the pool gives its arenas ``num_rows`` rows in place
-of ``num_blocks`` blocks: row 0 is the **null row**, what idle slots and a
-prefill's padding rows name, as block 0 is for tokens. Admission reserves
+:class:`~flexflow_tpu.serving.cache_entry.StateEntry`). A kind says what
+it keeps a token in ``arenas`` and what it keeps a request in
+``request_arenas``, and the pool gives the first ``num_blocks`` blocks
+and the second ``num_rows`` rows: row 0 is the **null row**, what idle
+slots and a prefill's padding rows name, as block 0 is for tokens.
+Admission reserves
 a request's blocks AND a row, or neither; the block table stays the
 request's one handle (:meth:`PagedKVPool.rows_of` finds the row of a
 table's request by its first block, which no other live request holds),
 :meth:`PagedKVPool.free` returns both, and the bytes count both. Inside
 the programs the two travel together as :class:`Addresses`. A kind may
-keep both in one layer (``request_arenas``: compressed convolutional
-attention's pair a token and, a request, its convolutions' tail and the
-last token's half value): its paged arenas get ``num_blocks`` blocks, the
-others ``num_rows`` rows, and its bytes count under both terms.
+keep both in one layer (compressed convolutional attention's pair a
+token and, a request, its convolutions' tail and the last token's half
+value): its bytes count under both terms.
 
 **The books.** What the decode steps read is the pool's to count, since
 the kinds, the geometry and ``stats()["kv"]`` are its own: the serving
@@ -149,11 +150,8 @@ def pool_bytes(specs, num_blocks: int, block_size: int,
     per_tok = per_row = 0
     for name, spec in dict(specs).items():
         kind, store = stored_as(name, spec, kv_dtype, dtype)
-        if kind.per_request:
-            per_row += kind.token_bytes(store)
-        else:
-            per_tok += kind.token_bytes(store)
-            per_row += kind.request_bytes(store)
+        per_tok += kind.token_bytes(store)
+        per_row += kind.request_bytes(store)
     return (int(num_blocks) * int(block_size) * per_tok
             + int(num_rows) * per_row)
 
@@ -216,11 +214,11 @@ class PagedKVPool:
         self.kinds: Dict[str, "EntryKind"] = {}
         stored = {name: stored_as(name, spec, kv_dtype, dtype)
                   for name, spec in self.specs.items()}
-        # rows of each per-request arena, the null row among them; 0 in a
-        # pool none of whose kinds keeps a state
-        has_state = any(kind.keeps_row for kind, _ in stored.values())
-        self.num_rows = int(num_rows) if has_state else 0
-        if has_state and self.num_rows < 2:
+        # the ops that keep a row a request, and the rows of each of their
+        # arenas, the null row among them; 0 in a pool that has no such op
+        self._state_ops = sum(kind.keeps_row for kind, _ in stored.values())
+        self.num_rows = int(num_rows) if self._state_ops else 0
+        if self._state_ops and self.num_rows < 2:
             raise ValueError(f"num_rows {num_rows} < 2: row 0 is the "
                              f"reserved null row, so a pool of per-request "
                              f"entries needs at least one more")
@@ -228,10 +226,9 @@ class PagedKVPool:
         for name, (kind, store) in stored.items():
             self.kinds[name] = kind
             self.kv[name] = tuple(
-                jnp.zeros(a.shape, a.dtype) for a in kind.arenas(
-                    self.num_rows if kind.per_request else self.num_blocks,
-                    self.block_size, store)
-                + kind.request_arenas(self.num_rows, store))
+                jnp.zeros(a.shape, a.dtype) for a in
+                kind.arenas(self.num_blocks, self.block_size, store)
+                + kind.request_arenas(self.num_rows, self.block_size, store))
         # LIFO free list: freshly freed blocks are reused first (their
         # stale contents are masked by position either way). A freed table
         # goes back reversed, so that what pops next comes in the order the
@@ -260,7 +257,6 @@ class PagedKVPool:
         # rows a chunk started from zeros (a prompt's first) and those it
         # carried on from what the chunk before left
         self._chunk_rows = {"rows_started": 0, "rows_carried": 0}
-        self._state_ops = sum(k.keeps_row for k in self.kinds.values())
         # the ops whose steps read less than they keep, and the sums of
         # what they read, by the word their kind's step_reads says
         # ("selected": a selection of a request's blocks; "window": at

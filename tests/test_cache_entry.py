@@ -18,6 +18,7 @@ from flexflow_tpu.models import (GPTConfig, LatentMoEConfig, build_gpt,
 from flexflow_tpu.serving import (ContinuousBatchingScheduler,
                                   GenerationInstance, Generator,
                                   PagedDecoder, PagedKVPool)
+from flexflow_tpu.ops import rows as rows_ops
 from flexflow_tpu.serving import cache_entry
 from flexflow_tpu.serving.cache_entry import (Int8PairEntry, LatentEntry,
                                               PairEntry, StateEntry)
@@ -176,7 +177,8 @@ def test_a_state_kind_answers_for_its_rows_bytes_names_and_limits(kv_dtype):
     assert pool.kv["a"][0].shape == (NB, BS, 32)
     held = sum(a.nbytes for entry in pool.kv.values() for a in entry)
     row = 8 * 64 * 4 + 3 * 128 * jnp.dtype(store).itemsize
-    assert state.token_bytes(store) == row and state.per_request
+    assert state.request_bytes(store) == row and state.keeps_row
+    assert state.token_bytes(store) == 0 == len(state.arenas(NB, BS, store))
     assert pool.memory_bytes() == held == (
         NB * BS * pair.token_bytes(store) + 5 * 2 * row)
     assert held == serving_kv_pool_bytes(pool.specs, NB, BS, kv_dtype,
@@ -199,6 +201,97 @@ def test_a_state_kind_answers_for_its_rows_bytes_names_and_limits(kv_dtype):
                     max_blocks_per_request=2, num_rows=1)
 
 
+# ---- what a token and a request weigh, kind by kind, pinned ---------------------
+
+def _nine_kinds():
+    from flexflow_tpu.ops.block_sparse_attention import Selection
+    from flexflow_tpu.serving.cache_entry import (CcaEntry, DecayStateEntry,
+                                                  SparseEntry, SsmStateEntry,
+                                                  WindowEntry)
+
+    return {
+        "pair": (PairEntry(4, 8, 8), "bfloat16"),
+        "int8": (PairEntry(4, 8), "int8"),
+        "window": (WindowEntry(2, 8, 4, 32, value_dim=4, sink=True),
+                   "bfloat16"),
+        "cca": (CcaEntry(2, 16, 8, tail=2, channels=96), "bfloat16"),
+        "latent": (LatentEntry(24), "bfloat16"),
+        "state": (StateEntry(4, 8, 16, 3, 128), "bfloat16"),
+        "sparse": (SparseEntry(2, 8, Selection(**GEOM)), "bfloat16"),
+        "decay_state": (DecayStateEntry(4, 8), "bfloat16"),
+        "ssm_state": (SsmStateEntry(4, 8, 16, 3, 96), "bfloat16"),
+    }
+
+
+# a pool of two ops of the kind, 9 blocks of 16 tokens and 5 rows: the
+# bytes of its token term and of its row term (``pool_bytes``), ``token_bytes
+# + request_bytes`` of one op, the op's entry (token arenas, then request
+# arenas) and a request's ``row_bytes``; recorded on 3d52808, the commit
+# before ``per_request`` went: a kind says ``arenas`` for what it keeps a
+# token and ``request_arenas`` for what it keeps a request, and none of
+# these numbers knows
+_PINNED = {
+    "pair": (36864, 0, 128, [((9, 16, 32), "bfloat16")] * 2, None),
+    "int8": (36864, 0, 128, [((9, 16, 32), "int8")] * 2
+             + [((9, 16, 4), "float32")] * 4, None),
+    "window": (0, 15360, 1536, [((10, 16, 16), "bfloat16"),
+                                ((10, 16, 8), "bfloat16")], 3072),
+    "cca": (36864, 4160, 544, [((9, 16, 32), "bfloat16")] * 2
+            + [((5, 192), "bfloat16"), ((5, 16), "bfloat16")], 832),
+    "latent": (73728, 0, 256, [((9, 16, 128), "bfloat16")], None),
+    "state": (0, 28160, 2816, [((5, 8, 64), "float32"),
+                               ((5, 384), "bfloat16")], 5632),
+    "sparse": (20736, 0, 72, [((9, 2, 16, 8), "bfloat16")] * 2
+               + [((36, 16), "bfloat16")], None),
+    "decay_state": (0, 10240, 1024, [((5, 4, 8, 8), "float32")], 2048),
+    "ssm_state": (0, 26240, 2624, [((5, 16, 32), "float32"),
+                                   ((5, 288), "bfloat16")], 5248),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PINNED))
+def test_what_a_token_and_a_request_weigh_is_what_it_was(name):
+    """The pool's arithmetic and its books for each of the nine kinds, at
+    one toy shape, against the numbers of the commit before the kinds
+    took to declaring a request's arenas one way: ``pool_bytes``' two
+    terms, the bytes of one token and one request of one op, the entry's
+    arrays in their order, ``stats()["entry"]`` and ``stats()["state"]``
+    after one admission, one step of two slots and two chunks."""
+    from flexflow_tpu.serving.kv_cache import pool_bytes
+
+    kind, kv_dtype = _nine_kinds()[name]
+    by_token, by_row, one, arenas, row_bytes = _PINNED[name]
+    pool = PagedKVPool({"a": kind, "b": kind}, num_blocks=9, block_size=16,
+                       max_blocks_per_request=4, kv_dtype=kv_dtype,
+                       num_rows=5)
+    store = jnp.bfloat16 if kv_dtype == "bfloat16" else jnp.float32
+    stored = pool.kinds["a"]
+    assert pool_bytes(pool.specs, 9, 16, kv_dtype, jnp.float32, 0) == by_token
+    assert pool_bytes(pool.specs, 0, 0, kv_dtype, jnp.float32, 5) == by_row
+    assert stored.token_bytes(store) + stored.request_bytes(store) == one
+    assert bool(stored.request_arenas(2, 16, store)) == stored.keeps_row \
+        == (row_bytes is not None)
+    for entry in pool.kv.values():
+        assert [(a.shape, str(a.dtype)) for a in entry] == arenas
+    assert pool.memory_bytes() == by_token + by_row == sum(
+        a.nbytes for entry in pool.kv.values() for a in entry)
+    table = pool.try_admit(40)
+    pool.count_step(np.asarray([40, 3]))
+    pool.count_chunk(0)
+    pool.count_chunk(16)
+    st = pool.stats(np.asarray([40]))
+    assert st["entry"] == name
+    if row_bytes is None:
+        assert "state" not in st and pool.rows_of(table[None]) is None
+        return
+    assert st["state"] == {
+        "rows": 5, "in_use": 1, "high_water": 1, "row_bytes": row_bytes,
+        "rows_stepped": 4, "rows_started": 2, "rows_carried": 2}
+    assert pool.rows_of(table[None]).tolist() == [1]
+    pool.free(table)
+    assert pool.stats()["state"]["in_use"] == 0
+
+
 _ROWS = {   # slot n writes arena row rows[n]; 0 is the null row (an idle slot)
     "all_live_in_order": [1, 2, 3, 4, 5, 6],
     "all_live_any_order": [5, 2, 6, 1, 4, 3],
@@ -212,19 +305,93 @@ def _bits(a):
     return np.array(jnp.asarray(a).astype(jnp.float32)).view(np.uint32)
 
 
+def _odd_values(rng, shape):
+    """Normal draws with a subnormal and a negative zero among them."""
+    x = np.asarray(rng.standard_normal(shape), np.float32)
+    flat = x.reshape(-1)
+    flat[1::11], flat[5::13] = 1e-40, -0.0
+    return x
+
+
+def _decay_rows_case(rows, rng):
+    """``decay_step_rows`` over an arena (7, H, D, D), and the same on
+    the slots' rows gathered (``decay_step``) with a scatter back."""
+    from flexflow_tpu.ops.lightning_attention import (decay_step,
+                                                      decay_step_rows)
+
+    n, h, d = len(rows), 2, 8
+    arena = jnp.asarray(_odd_values(rng, (7, h, d, d))).at[1].set(0.0)
+    q, k, v = (jnp.asarray(_odd_values(rng, (n, h, d))).at[3].set(jnp.nan)
+               for _ in range(3))
+    lam = jnp.asarray([0.9, 0.5], jnp.float32)
+    o, got = jax.jit(decay_step_rows)(arena, rows, q, k, v, lam)
+    o_ref, new = jax.jit(decay_step)(arena[rows], q, k, v, lam)
+    return arena, got, arena.at[rows].set(new), o, o_ref
+
+
+def _ssd_rows_case(rows, rng):
+    """``ssd_step_rows`` over an arena (7, S, H P) in the pool's layout,
+    and ``ssd_step`` on the slots' rows gathered, turned to the op's (H,
+    P, S) and back, with a scatter back."""
+    from flexflow_tpu.ops.mamba2 import ssd_step, ssd_step_rows
+
+    n, h, p, size, g = len(rows), 4, 8, 8, 2
+    arena = jnp.asarray(_odd_values(rng, (7, size, h * p))).at[1].set(0.0)
+    u = jnp.asarray(_odd_values(rng, (n, h, p))).at[3].set(jnp.nan)
+    decay = jnp.asarray(rng.uniform(0.2, 1.0, (n, h)), jnp.float32)
+    bm, cm = (jnp.asarray(_odd_values(rng, (n, g, size))) for _ in range(2))
+    y, got = jax.jit(ssd_step_rows)(arena, rows, u, decay, bm, cm)
+
+    def gathered(arena):
+        state = jnp.swapaxes(arena[rows], 1, 2).reshape(n, h, p, size)
+        y, state = ssd_step(state, u, decay, bm, cm)
+        return y, jnp.swapaxes(state.reshape(n, h * p, size), 1, 2)
+
+    y_ref, new = jax.jit(gathered)(arena)
+    return arena, got, arena.at[rows].set(new), y, y_ref
+
+
+_ROW_STEPS = {"decay": _decay_rows_case, "ssd": _ssd_rows_case}
+
+
 @pytest.mark.parametrize("case", list(_ROWS))
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_rows_spread_by_one_hot_equal_the_scatter_bit_for_bit(dtype, case):
+@pytest.mark.parametrize("form", ["bfloat16", "float32", "decay", "ssd"])
+def test_rows_spread_by_one_hot_equal_the_scatter_bit_for_bit(form, case):
     """What a state kind's step writes its convolution tails with: each
     arena row takes the values of the slot that names it, against
     ``arena.at[rows].set(new)``: the stepped rows BIT for bit in both
     storage dtypes (values of every magnitude a float holds, subnormals,
     negative zeros and a slot of NaN among them: nothing is computed, so
     a slot's NaN stays in its row), the rows nobody names as they were,
-    and row 0 never written, whatever the idle slots carry."""
+    and row 0 never written, whatever the idle slots carry. The two row
+    steps that take their slots' inputs through the same hand-over
+    (``decay``: ``decay_step_rows``; ``ssd``: ``ssd_step_rows``) are held
+    to the step on the gathered rows and a scatter back in the same
+    words, and their live slots' outputs to that step's: slot 3's NaN in
+    its own row and its own output, and nowhere else."""
     rows = np.asarray(_ROWS[case], np.int32)
     n, r, width = len(rows), 7, 384
     rng = np.random.default_rng(len(case))
+    poisoned = {int(rows[3])} - {0}
+    clean = sorted(set(range(r)) - poisoned)
+    untouched = sorted(set(range(r)) - set(rows.tolist()))
+    if form in _ROW_STEPS:
+        arena, got, scattered, o, o_ref = _ROW_STEPS[form](
+            jnp.asarray(rows), rng)
+        want = _bits(scattered)
+        want[0] = _bits(arena)[0]
+        np.testing.assert_array_equal(_bits(got), want)
+        np.testing.assert_array_equal(_bits(got)[untouched],
+                                      _bits(arena)[untouched])
+        assert np.isfinite(np.asarray(got)[clean]).all()
+        live = (rows != 0) & (np.arange(n) != 3)
+        np.testing.assert_allclose(np.asarray(o)[live],
+                                   np.asarray(o_ref)[live], rtol=1e-5,
+                                   atol=1e-6)
+        assert np.isnan(np.asarray(o)[3]).any() == bool(poisoned)
+        assert np.isfinite(np.asarray(o)[np.arange(n) != 3]).all()
+        return
+    dtype = form
 
     def draw(shape):
         x = (rng.standard_normal(shape)
@@ -236,29 +403,135 @@ def test_rows_spread_by_one_hot_equal_the_scatter_bit_for_bit(dtype, case):
     new = draw((n, width)).at[3].set(jnp.nan)   # slot 3: live in all but one
     new = jnp.where((rows == 0)[:, None] & (jnp.arange(n) != 3)[:, None],
                     jnp.asarray(3e38, dtype), new)
-    got = jax.jit(cache_entry._spread_rows, donate_argnums=0)(
+    got = jax.jit(rows_ops.spread_rows, donate_argnums=0)(
         jnp.array(arena), jnp.asarray(rows), new)
     want = _bits(arena.at[rows].set(new))
     want[0] = _bits(arena)[0]        # the scatter lets idle slots race here
     assert got.dtype == arena.dtype and got.shape == arena.shape
     np.testing.assert_array_equal(_bits(got), want)
-    untouched = sorted(set(range(r)) - set(rows.tolist()))
     np.testing.assert_array_equal(_bits(got)[untouched],
                                   _bits(arena)[untouched])
-    poisoned = {int(rows[3])} - {0}
-    clean = sorted(set(range(r)) - poisoned)
     assert np.isfinite(np.asarray(got.astype(jnp.float32))[clean]).all()
+
+
+# the zoo's toy of each kind that keeps a row a request, and its pool's blocks
+_ROW_KEEPERS = {"state": ("hybrid", 8), "decay_state": ("sparse_hybrid", 4),
+                "ssm_state": ("granite_hybrid", 8), "cca": ("zaya", 8)}
+
+
+@pytest.mark.parametrize("kind_name", sorted(_ROW_KEEPERS))
+def test_a_nan_in_one_requests_row_reaches_no_other_requests_logits(
+        kind_name):
+    """Two requests decode side by side, a third slot idle; the row the
+    second holds in every per-request arena is made NaN: the first's
+    logits over three decode steps are, bit for bit, what they are
+    without it, and the second's are NaN. Each kind hands its slots'
+    values to its rows through ``ops/rows.py``, a take: a product over
+    the slots (``decay_state`` up to PR 59) made every live row NaN."""
+    from flexflow_tpu.models import zoo_smoke_builders
+
+    model, block = _ROW_KEEPERS[kind_name]
+    ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
+                          computation_mode=CompMode.INFERENCE))
+    zoo_smoke_builders()[model](ff, 2)
+    ff.compile(optimizer=None, loss_type=None, metrics=[])
+    rng = np.random.default_rng(3)
+    a, b = (rng.integers(0, 128, n).astype(np.int32) for n in (11, 9))
+
+    def run(poison):
+        dec = PagedDecoder(ff, 32, decode_slots=3, block_size=block,
+                           prefill_buckets=[16], calibrate=False)
+        assert kind_name in {k.name for k in dec.pool.kinds.values()}
+        ta, tb = dec.pool.try_admit(24), dec.pool.try_admit(24)
+        first = dec.prefill(a, ta)
+        dec.prefill(b, tb)
+        if poison:
+            row = int(dec.pool.rows_of(tb[None])[0])
+            for name, kind in dec.pool.kinds.items():
+                held = len(kind.arenas(2, block, jnp.float32))
+                dec.pool.kv[name] = dec.pool.kv[name][:held] + tuple(
+                    arena.at[row].set(jnp.nan)
+                    for arena in dec.pool.kv[name][held:])
+        rows = [first]
+        for k in range(3):
+            tokens = np.zeros(3, np.int32)
+            tables = np.zeros((3, dec.max_blocks_per_request), np.int32)
+            lens = np.zeros(3, np.int32)
+            tokens[0], lens[0], tables[0] = int(rows[-1].argmax()), 11 + k, ta
+            tokens[1], lens[1], tables[1] = 1, 9 + k, tb
+            out = dec.decode(tokens, tables, lens)
+            rows.append(out[0])
+            assert np.isnan(out[1]).any() == poison
+        return np.stack(rows)
+
+    clean, poisoned = run(False), run(True)
+    assert np.isfinite(poisoned).all()
+    assert np.array_equal(clean, poisoned)
 
 
 def test_the_spread_lowers_to_a_take_and_no_scatter():
     """What the form is for: no ``scatter`` (which the TPU's compiler
     runs as a sequential loop over the slots at rows this wide), no loop
     and no product: a gather of the arena's rows."""
-    text = jax.jit(cache_entry._spread_rows).lower(
+    text = jax.jit(rows_ops.spread_rows).lower(
         jnp.zeros((5, 384), jnp.bfloat16), jnp.zeros((4,), jnp.int32),
         jnp.zeros((4, 384), jnp.bfloat16)).as_text()
     assert "scatter" not in text and "while" not in text
     assert "dot_general" not in text and "gather" in text
+
+
+# ---- a request's convolution tail, one object whichever kind holds it -----------
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("channels", [128, 96])     # whole lane tiles, and not
+def test_a_conv_tail_takes_slides_puts_and_leaves_what_the_lines_did(
+        channels, dtype):
+    """``ConvTail`` alone, against the lines the three kinds each held:
+    its two shapes; ``take`` (the slots' rows cut into taps, zeros for a
+    first chunk and nothing else); ``behind`` and ``slide`` (the window
+    less its oldest position over the slots' rows: a scatter's bits, row
+    0 and the rows nobody names as they were); ``put`` (a prompt's tail
+    over its row, in the arena's dtype); ``left`` (the taps that end at a
+    chunk's TRUE length, whatever lies behind it)."""
+    from flexflow_tpu.serving.cache_entry import ConvTail
+
+    tail = ConvTail(3, channels)
+    assert tail.paged(7, dtype).shape == (7, 3 * channels)
+    assert tail.dense(2, dtype).shape == (2, 3, channels)
+    assert tail.paged(7, dtype).dtype == tail.dense(2, dtype).dtype == dtype
+    rng = np.random.default_rng(channels)
+    arena = jnp.asarray(rng.normal(size=(7, 3 * channels)), dtype)
+    rows = jnp.asarray([4, 0, 2, 6], jnp.int32)
+    taken = tail.take(arena, rows)
+    np.testing.assert_array_equal(
+        _bits(taken), _bits(arena[rows].reshape(4, 3, channels)))
+    later = jnp.asarray([True, False, False, True])
+    first = tail.take(arena, rows, later)
+    np.testing.assert_array_equal(_bits(first)[[0, 3]],
+                                  _bits(taken)[[0, 3]])
+    assert not np.asarray(first.astype(jnp.float32))[[1, 2]].any()
+    new = jnp.asarray(rng.normal(size=(4, 1, channels)), jnp.float32)
+    window = tail.behind(taken, new)
+    assert window.shape == (4, 4, channels) and window.dtype == arena.dtype
+    slid = jax.jit(tail.slide, donate_argnums=0)(jnp.array(arena), rows,
+                                                  window)
+    want = _bits(arena.at[rows].set(window[:, 1:].reshape(4, -1)))
+    want[0] = _bits(arena)[0]
+    np.testing.assert_array_equal(_bits(slid), want)
+    np.testing.assert_array_equal(_bits(slid)[[1, 3, 5]],
+                                  _bits(arena)[[1, 3, 5]])
+    prompt = jnp.asarray(rng.normal(size=(2, 3, channels)), jnp.float32)
+    put = tail.put(arena, jnp.asarray([5, 1]), prompt)
+    assert put.dtype == arena.dtype
+    np.testing.assert_array_equal(
+        _bits(put)[[5, 1]], _bits(prompt.astype(dtype).reshape(2, -1)))
+    np.testing.assert_array_equal(_bits(put)[[0, 2, 3, 4, 6]],
+                                  _bits(arena)[[0, 2, 3, 4, 6]])
+    chunk = jnp.asarray(rng.normal(size=(2, 3 + 8, channels)), dtype)
+    left = tail.left(chunk, jnp.asarray([8, 5]))
+    np.testing.assert_array_equal(_bits(left[0]), _bits(chunk[0, 8:11]))
+    np.testing.assert_array_equal(_bits(left[1]), _bits(chunk[1, 5:8]))
+    assert tail.path(dtype) == "rows"                 # the CPU: no kernel
 
 
 # ---- a delta-rule op's tails, flat on the lanes in arena order ---------------
@@ -297,12 +570,12 @@ def _tails_case(channels, dtype, rows):
 def _tails_in_slot_order(op, w, tails, rows, inputs):
     """The lines a step held up to PR 58 and holds where the kernel
     refuses: the slots' rows cut into taps, ``convolve`` over the window
-    ``(n, taps, channels)``, ``_spread_rows`` on the way back."""
+    ``(n, taps, channels)``, ``spread_rows`` on the way back."""
     n, c = inputs.shape
     window = jnp.concatenate([tails[rows].reshape(n, 3, c),
                               inputs[:, None]], axis=1)
     return (op.convolve(w, window),
-            cache_entry._spread_rows(tails, rows,
+            rows_ops.spread_rows(tails, rows,
                                      window[:, 1:].reshape(n, -1)))
 
 
@@ -311,7 +584,7 @@ def _tails_in_slot_order(op, w, tails, rows, inputs):
 @pytest.mark.parametrize("channels", [128, 384])
 def test_tails_stepped_in_arena_order_are_the_spreads_bit_for_bit(
         monkeypatch, channels, dtype, case):
-    """``StateEntry._tails_arena`` (the ``tails_step`` kernel, interpreted)
+    """``ConvTail.step_arena`` (the ``tails_step`` kernel, interpreted)
     against the slot-order lines it replaced: the whole arena BIT for bit
     (every live row's new tail moved into place, the rows nobody names
     and row 0 as they were), slot 3's NaN in its own row and nowhere
@@ -321,7 +594,7 @@ def test_tails_stepped_in_arena_order_are_the_spreads_bit_for_bit(
     op, kind, w = _delta_op(channels, dtype)
     assert kind.tails_path(dtype) == "kernel"
     tails, inputs, rows = _tails_case(channels, dtype, _ROWS[case])
-    u, got = jax.jit(kind._tails_arena, donate_argnums=0)(
+    u, got = jax.jit(kind.conv_tail.step_arena, donate_argnums=0)(
         jnp.array(tails), rows, inputs, w["conv"])
     u_ref, want = jax.jit(_tails_in_slot_order, static_argnums=0)(
         op, w, tails, rows, inputs)
@@ -465,7 +738,7 @@ def test_the_sparse_and_decay_kinds_answer_for_their_arenas_and_limits(
     held = sum(a.nbytes for entry in pool.kv.values() for a in entry)
     item = jnp.dtype(store).itemsize
     assert sparse.token_bytes(store) == (2 * 16 + 16 // 4) * item
-    assert decay.token_bytes(store) == 4 * 8 * 8 * 4 and decay.per_request
+    assert decay.request_bytes(store) == 4 * 8 * 8 * 4 and decay.keeps_row
     assert pool.memory_bytes() == held == serving_kv_pool_bytes(
         pool.specs, NB, 16, kv_dtype, num_rows=4)
     st = pool.stats()

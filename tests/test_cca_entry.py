@@ -206,7 +206,7 @@ def test_the_kind_has_no_int8_form_and_one_token_a_step(toy):
         _decoder(ff, kv_dtype="int8")
     kind = _decoder(ff).pool.kinds[LAYERS[0]]
     assert isinstance(kind, CcaEntry) and kind.max_window == 1
-    assert kind.chunked and not kind.per_request and kind.keeps_row
+    assert kind.chunked and kind.arenas(2, 8, jnp.float32) and kind.keeps_row
 
 
 # ---- the pool: a row a token and a row a request, arena by arena --------------------
@@ -224,9 +224,9 @@ def test_a_token_and_a_request_weigh_what_the_arithmetic_says():
     assert CCA.token_bytes(bf16) == 1024 == PAIR.token_bytes(bf16)
     assert CCA.request_bytes(bf16) == (2 * 1280 + 128) * 2 == 5376
     assert PAIR.request_bytes(bf16) == 0 and not PAIR.keeps_row
-    assert SSM.request_bytes(bf16) == 0 and SSM.keeps_row   # all of it a row
+    assert SSM.token_bytes(bf16) == 0 and SSM.keeps_row     # all of it a row
     assert [a.shape for a in CCA.arenas(5, 64, bf16)] == [(5, 64, 256)] * 2
-    assert [a.shape for a in CCA.request_arenas(7, bf16)] == [(7, 2560),
+    assert [a.shape for a in CCA.request_arenas(7, 64, bf16)] == [(7, 2560),
                                                               (7, 128)]
     specs = {f"l{i}": CCA for i in range(20)}
     # the cell's pool: 48 worst-case slots of 4,608 tokens, and 49 rows

@@ -100,7 +100,7 @@ def test_paged_decode_bit_identical_per_zoo_causal_lm():
                            block_size=8)
         # a step over a per-request state advances it: that program run
         # twice is two steps, not one step twice
-        repeats = not any(k.per_request for k in dec.pool.kinds.values())
+        repeats = not any(k.keeps_row for k in dec.pool.kinds.values())
         rng = np.random.default_rng(1)
         prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
                    for n in (3, 6, 2, 5)]

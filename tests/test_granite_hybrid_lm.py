@@ -331,7 +331,7 @@ def test_a_nan_in_one_requests_state_reaches_no_other(toy, short_spans):
         if poison:
             row = int(dec.pool.rows_of(tb[None])[0])
             for name, kind in dec.pool.kinds.items():
-                where = np.asarray([row]) if kind.per_request \
+                where = np.asarray([row]) if kind.keeps_row \
                     else tb[tb != 0]
                 dec.pool.kv[name] = tuple(
                     arena.at[where].set(jnp.nan)

@@ -408,7 +408,7 @@ def test_unknown_layer_type_is_refused():
 # else: the five serving programs kept their digests through it.
 # ``hybrid.decode`` was recorded again when ``StateEntry.step`` took to
 # writing its tails back row by row of the arena, each row taking the
-# values of the slot that names it (``cache_entry._spread_rows``), in
+# values of the slot that names it (``spread_rows``, now ``ops/rows.py``'s), in
 # place of a scatter: the one program that change is meant to move; the
 # other six kept theirs. ``latent_moe.prefill`` was recorded again when a
 # prompt program took to counting, beside the expert ids it keeps, the
@@ -438,6 +438,14 @@ def test_unknown_layer_type_is_refused():
 # hold such an arena, and no other; on the CPU the kernel's
 # ``supported()`` refuses and the step is ``ssd_step_rows`` as before,
 # over the new rows. The other ten kept theirs letter for letter.
+# ``sparse_hybrid.decode`` was recorded again when ``decay_step_rows``
+# took to TAKING each row's q, k and v from the slot that names it
+# (``ops/rows.py`` ``named_by``, as ``ssd_step_rows`` does) where it
+# multiplied them in by a one-hot product over the slots, which made one
+# request's NaN every live request's (PR 60, ROADMAP D19): the one
+# program that is meant to move. The hand-over's one owner, the one
+# ``ConvTail`` and the kinds' one way to declare a request's arenas left
+# the other thirteen letter for letter.
 RECORDED = {
     "gpt.decode": "3aafb0f57e8d64295ce268b7d45e62c31463373b34e36d3e30ea9871343f04a7",
     "gpt.prefill": "1878e51f7de936c6f1c483b255b386023f2cd48c08326fa40f65e2199d075cb3",
@@ -451,7 +459,7 @@ RECORDED = {
     "latent_moe.train": "ec35820380d8c0377a8b07e79d3248e5c9af80e3156d4e7f49d29b7f78eb7b8f",
     "nemotron_h.decode": "c088dc5b6ecee8626ce1f3d6d5394f5dea08fa1388186a8df0f927f26762c5a1",
     "nemotron_h.prefill": "aa21ea5bac064e9bc3ea1d29c00124399c95aa1a77105e0d62b0f4c13fe161d5",
-    "sparse_hybrid.decode": "83d1e616746ba634c9f0aca6072617b5fd0ba086364cd3849a57b2606898ce18",
+    "sparse_hybrid.decode": "80df1ca5e53b4103e5843bd02083672a838377c71953d5a1e2a7fc90798a354b",
     "sparse_hybrid.prefill": "8af30f7980a5ae6c8d6bc03e7fc11a1bf8b0b63173f63adc8bc10cad4bb2f962",
 }
 

@@ -366,7 +366,7 @@ def test_the_scheduler_names_no_entry_kind():
     from flexflow_tpu.serving import scheduler
 
     src = inspect.getsource(scheduler)
-    for word in ("blocks_read", "rows_read", "side_rows", "per_request",
+    for word in ("blocks_read", "rows_read", "side_rows", "keeps_row",
                  "_selecting", "_windowed", "_state_ops", "pool.kinds"):
         assert word not in src, word
 
@@ -420,7 +420,7 @@ def test_the_pool_keeps_the_books_of_what_the_steps_read(model):
     assert kv["in_use"] == plain["in_use"] == sum(
         pool.blocks_for(n + 20) for n in prompts)
 
-    state_ops = sum(k.per_request for k in kinds)
+    state_ops = sum(k.keeps_row for k in kinds)
     assert ("state" in kv) == bool(state_ops) == (model not in (
         "gpt", "latent_moe"))
     if state_ops:

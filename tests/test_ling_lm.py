@@ -369,7 +369,7 @@ def test_two_kinds_share_one_pool_and_its_bytes_are_their_sum(toy):
     state = kinds["block0_attn"]
     assert state == StateEntry(2, 64, 64, 3, 3 * 2 * 64, True)
     assert kinds["block1_attn"] == LatentEntry(32 + 8)
-    per_request = 4 * state.token_bytes(jnp.float32)
+    per_request = 4 * state.request_bytes(jnp.float32)
     assert per_request == 4 * (2 * 64 * 64 * 4 + 3 * 384 * 4)
     per_token = 2 * kinds["block1_attn"].token_bytes(jnp.float32)
     assert per_token == 2 * 128 * 4          # 40 numbers on 128 lanes

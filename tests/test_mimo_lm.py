@@ -593,7 +593,7 @@ def test_the_pool_keeps_two_widths_and_says_so(toy):
     k, v = dec.pool.kv["block1_attn"]
     assert k.shape[1:] == (BLOCK, 4 * 48) and v.shape[1:] == (BLOCK, 4 * 32)
     assert pair.token_bytes(jnp.float32) == 2 * 80 * 4
-    assert ring.token_bytes(jnp.float32) == 16 * 4 * 80 * 4
+    assert ring.request_bytes(jnp.float32) == 16 * 4 * 80 * 4
     # 10 blocks of 8 tokens in two full layers, 4 rings in two windowed
     assert pool_bytes(kinds, 10, BLOCK, "float32", jnp.float32, 4) == (
         10 * BLOCK * 2 * (2 * 80 * 4) + 4 * 2 * (16 * 4 * 80 * 4))

@@ -782,7 +782,7 @@ def test_the_state_kind_refuses_rollback_and_int8_by_name(toy):
     assert kind.max_window == 1 and kind.int8_form is None
     # (it takes chunks since ``SsmStateEntry.chunk``: a prompt in chunks
     # of 16 leaves the logits a bucket of 32 leaves)
-    assert kind.per_request and kind.chunked
+    assert kind.keeps_row and kind.chunked
     prompt = np.random.default_rng(5).integers(
         0, TOY["vocab_size"], 27).astype(np.int32)
     outs = []

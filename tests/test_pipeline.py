@@ -105,121 +105,29 @@ def test_pipeline_matches_single_device_training():
     assert abs(h_pp[-1].accuracy - h_sd[-1].accuracy) <= 0.15
 
 
-_PERF_SCRIPT = r"""
-import os
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-    " --xla_force_host_platform_device_count=8"
-import jax
-jax.config.update("jax_platforms", "cpu")
-import time
-import numpy as np
-import jax.numpy as jnp
-from flexflow_tpu import FFConfig, FFModel, LossType
-from flexflow_tpu.parallel.pipeline import PipelineConfig
-from flexflow_tpu.runtime.optimizer import SGDOptimizer
-
-H, L, bs, M = 1024, 8, 256, 2
-rng = np.random.default_rng(0)
-x = rng.normal(size=(bs, H)).astype(np.float32)
-y = rng.integers(0, 8, size=(bs, 1)).astype(np.int32)
-
-
-def build(ff):
-    t = ff.create_tensor((bs, H), name="input")
-    for i in range(L):
-        t = ff.dense(t, H, name=f"fc{i}")
-        t = ff.relu(t, name=f"a{i}")
-    t = ff.dense(t, 8, name="head")
-    return ff.softmax(t, name="probs")
-
-
-def run(pipelined, iters=8):
-    ff = FFModel(FFConfig(
-        batch_size=bs, seed=0,
-        mesh_shape={"pipe": 2, "data": 4} if pipelined else {"data": 8}))
-    build(ff)
-    kw = dict(pipeline=PipelineConfig(num_stages=2, num_microbatches=M)) \
-        if pipelined else {}
-    ff.compile(optimizer=SGDOptimizer(lr=0.01),
-               loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
-               metrics=[], **kw)
-    key = jax.random.key(0)
-    if pipelined:
-        pm = ff.pipelined
-        for _ in range(2):
-            pm.train_step(key, [jnp.asarray(x)], jnp.asarray(y))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            parts, aux = pm.train_step(key, [jnp.asarray(x)],
-                                       jnp.asarray(y), sync=False)
-        _ = sum(float(p) for p in parts)  # fence once at the end
-        return (time.perf_counter() - t0) / iters
-    cm = ff.compiled
-    xb = jax.device_put(x, cm.input_shardings[0])
-    yb = jax.device_put(y, cm.label_sharding)
-    p, o = cm.params, cm.opt_state
-    for _ in range(2):
-        p, o, loss, _ = cm.train_step(p, o, key, xb, yb)
-    float(loss)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        p, o, loss, _ = cm.train_step(p, o, key, xb, yb)
-    float(loss)  # fences the dependency chain
-    return (time.perf_counter() - t0) / iters
-
-
-tp, tn = run(True), run(False)
-print(f"RESULT {tp} {tn}", flush=True)
-"""
-
-
-def test_pipeline_step_overhead_bounded():
-    """Performance-real criterion: on a compute-dominated model the
-    steady-state pipelined step stays within 1.3x of the non-pipelined
-    step on the 8-device CPU mesh (the compiled-per-stage engine; the old
-    eager engine measured ~4x). Steady-state = closed loop without
-    per-step host sync, so adjacent steps overlap across the GPipe bubble
-    — fencing every step would measure the bubble, which back-to-back
-    training amortizes.
-
-    At this compute-dominated size the pipelined path is typically FASTER
-    than 8-way DP (each stage all-reduces only its own weights over half
-    the devices), so 1.3x has wide margin.
-
-    Measured in a FRESH subprocess: accumulated in-process suite state
-    (dozens of compiled executables, thread pools) skews host-driven
-    dispatch timing. A load spike can only cause a false failure, never a
-    false pass, so any of 3 attempts meeting the bound proves the
-    engine."""
-    import os
-    import subprocess
-    import sys
-
-    ratios = []
-    for _ in range(3):
-        proc = subprocess.run(
-            [sys.executable, "-c", _PERF_SCRIPT],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            timeout=600,
-            env={**os.environ,
-                 "PYTHONPATH": os.pathsep.join(filter(None, [
-                     os.path.dirname(os.path.dirname(
-                         os.path.abspath(__file__))),
-                     os.environ.get("PYTHONPATH")]))},
-        )
-        line = next((l for l in proc.stdout.splitlines()
-                     if l.startswith("RESULT")), None)
-        assert proc.returncode == 0 and line is not None, (
-            f"perf subprocess failed rc={proc.returncode}\n"
-            f"stdout: {proc.stdout[-500:]}\nstderr: {proc.stderr[-1500:]}")
-        tp, tn = (float(v) for v in line.split()[1:])
-        ratios.append(tp / tn)
-        if tp <= 1.3 * tn:
-            return
-    raise AssertionError(
-        f"pipelined/non-pipelined step ratios {[f'{r:.2f}' for r in ratios]} "
-        f"all exceed 1.3x")
+@pytest.mark.parametrize("microbatches", [2, 4])
+def test_pipeline_step_dispatches_counted(microbatches):
+    """What a pipelined step costs the host, as a count that repeats: the
+    host engine dispatches once a forward, a backward and an accumulation
+    a stage and microbatch and once an update a stage, (3 S - 1) M in all
+    for S stages of GPipe over M microbatches; the compiled engine
+    dispatches ONE schedule program behind its input placements, whatever
+    M. Read from the engines' own ``step_dispatches``; no clock (the
+    wall-clock ratio of two CPU runs this replaces measured the box, not
+    the engine: ROADMAP D2)."""
+    stages = 2
+    host, _, _ = _train_variant("gpipe", engine="host",
+                                mesh_shape={"pipe": stages}, steps=1,
+                                num_microbatches=microbatches)
+    assert host.pipelined.engine_name == "host"
+    assert host.pipelined.step_dispatches \
+        == (3 * stages - 1) * microbatches
+    compiled, _, _ = _train_variant("gpipe", engine="auto",
+                                    mesh_shape={"pipe": stages}, steps=1,
+                                    num_microbatches=microbatches)
+    assert compiled.pipelined.engine_name == "compiled"
+    # the tokens' and the labels' placements, then the one program
+    assert compiled.pipelined.step_dispatches == 3
 
 
 def test_pipeline_forward_only():

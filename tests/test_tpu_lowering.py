@@ -289,6 +289,47 @@ def test_ssd_step_decode_compiles_for_v5e(monkeypatch, one_chip,
     assert mem.temp_size_in_bytes < arena_bytes // 8
 
 
+def test_decay_step_rows_compiles_for_v5e(one_chip, no_compile_cache):
+    """The long-documents cell's decay step at its real widths
+    (MiniCPM-SALA's 32 heads of 128 by 128 and ``serve-longdocs``' 16
+    slots, read from the cell's files: 17 rows of 2 MB): each row TAKES
+    its slot's q, k and v (``ops/rows.py`` ``named_by``) where a one-hot
+    product over the slots multiplied them in, so no product of two
+    (slots, rows) arrays is left, the states are stepped where they lie
+    (no ``while``: a gather of rows of 2 MB lowers to a sequential loop
+    over the slots; no scatter), and the donated arena goes in and comes
+    out as one buffer."""
+    import json
+    import os
+
+    from flexflow_tpu.ops.lightning_attention import decay_step_rows
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(root, "configs", "minicpm-sala-pp2.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "traffic", "serve-longdocs.json")) as f:
+        n = json.load(f)["decode_slots"]
+    h, d = config["lightning_nh"], config["lightning_head_dim"]
+    assert (n, h, d) == (16, 32, 128)
+    rows = n + 1
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(decay_step_rows, donate_argnums=(0,)).lower(
+        sds((rows, h, d, d)), sds((n,), jnp.int32), sds((n, h, d)),
+        sds((n, h, d)), sds((n, h, d)), sds((h,))).compile()
+    text = compiled.as_text()
+    assert " while(" not in text and "scatter" not in text
+    # the one product left is a row's query against its own state
+    assert f"f32[{n},{rows}]" not in text and f"f32[{rows},{n}]" not in text
+    mem = compiled.memory_analysis()
+    arena_bytes = rows * h * d * d * 4
+    assert mem.alias_size_in_bytes >= arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes // 8
+
+
 @pytest.mark.parametrize("block, table, run", [(16, 256, 4), (64, 64, 1)])
 def test_latent_attention_decode_compiles_for_v5e(monkeypatch, one_chip,
                                                   no_compile_cache, block,
@@ -1371,7 +1412,7 @@ def test_zaya_decode_step_reads_the_pair_by_the_kernel(zaya_programs):
     in the dense form beyond; the matrices are copied for neither arm;
     the op's counters carry the kernel's rows and the steps that took
     it; the only scatters are the new token's keys and
-    values (the tails and half values go back through ``_spread_rows``);
+    values (the tails and half values go back through ``spread_rows``);
     the pool, pairs and rows, aliases its outputs."""
     programs, dec = zaya_programs
     text, mem = programs["decode"]
@@ -1544,7 +1585,7 @@ def test_mimo_decode_step_reads_both_kinds_by_the_kernel(mimo_programs):
                                      (33 * 2, 64, 8 * 128))
     # 5,120 B a token in the paged pool at the cell's two full layers
     assert dec.pool.kinds["block0_attn"].token_bytes(jnp.bfloat16) == 2560
-    assert dec.pool.kinds["block1_attn"].token_bytes(jnp.bfloat16) \
+    assert dec.pool.kinds["block1_attn"].request_bytes(jnp.bfloat16) \
         == 128 * 8 * 320 * 2
     assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
 

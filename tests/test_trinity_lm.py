@@ -287,7 +287,7 @@ def test_a_nan_in_one_requests_rows_reaches_no_other(toy, short_spans):
             row = int(dec.pool.rows_of(tb[None])[0])
             for name, kind in dec.pool.kinds.items():
                 entry = dec.pool.kv[name]
-                if kind.per_request:
+                if kind.keeps_row:
                     ring = 16 // BLOCK
                     where = np.arange(row * ring, (row + 1) * ring)
                 else:
@@ -395,7 +395,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
 # ---- the pool and the counters -----------------------------------------------
 
 def test_a_windowed_layer_reserves_a_ring_and_the_pools_bytes_say_so(toy):
-    """The windowed kind: a ``per_request`` arena of ``window /
+    """The windowed kind: a request's arenas of ``window /
     block_size`` blocks a row in the pair layout, one step a slot, no
     int8 form; the pool's bytes are the full layer's blocks and a ring a
     row and windowed layer."""
@@ -406,7 +406,7 @@ def test_a_windowed_layer_reserves_a_ring_and_the_pools_bytes_say_so(toy):
     win, full = kinds["block0_attn"], kinds["block2_attn"]
     assert isinstance(win, cache_entry.WindowEntry) and win.window == 16
     assert type(full) is cache_entry.PairEntry
-    assert win.per_request and win.chunked and full.chunked
+    assert win.keeps_row and win.chunked and full.chunked
     assert win.max_window == 1 and win.int8_form is None
     assert [win.rows_read(n) for n in (0, 7, 15, 16, 90)] \
         == [1, 8, 16, 16, 16]
@@ -416,7 +416,7 @@ def test_a_windowed_layer_reserves_a_ring_and_the_pools_bytes_say_so(toy):
     blocks = 3 * (MAX_LEN // BLOCK) + 1
     assert dec.pool.kv["block2_attn"][0].shape == (blocks, BLOCK, 16)
     ring = 2 * 16 * 16 * 4
-    assert win.token_bytes(jnp.float32) == ring
+    assert win.request_bytes(jnp.float32) == ring
     assert dec.pool.memory_bytes() == 3 * 4 * ring + blocks * BLOCK * 2 * 64
     assert dec.pool.stats()["state"]["row_bytes"] == 3 * ring
     with pytest.raises(ValueError, match="no whole blocks"):
@@ -507,7 +507,8 @@ def test_the_chunks_kernel_refuses_what_it_cannot_take(monkeypatch):
 
     def path(kind, dtype=jnp.float32, store=jnp.float32):
         entry = tuple(jnp.zeros(a.shape, a.dtype)
-                      for a in kind.arenas(4, BLOCK, store))
+                      for a in kind.arenas(4, BLOCK, store)
+                      + kind.request_arenas(4, BLOCK, store))
         return kind.chunk_path(entry, 1, 16, 12, dtype)
 
     lanes = PairEntry(2, 128, 4)

@@ -11,7 +11,7 @@ to run them, so the wall floors near 0.2 ms a layer):
 
 * ``i``   the lines ``StateEntry.step`` held up to PR 58: a gather of
   the slots' rows, a reshape to ``(n, tail, channels)``, the window
-  ``(n, taps, channels)``, ``convolve``, ``_spread_rows`` on the way back;
+  ``(n, taps, channels)``, ``convolve``, ``spread_rows`` on the way back;
 * ``ii``  the same in slot order with the taps left on the lanes: tap j
   of a flat row is the lanes ``[j C, (j + 1) C)``;
 * ``iii`` lanes-flat in ARENA order: every arena row takes the inputs of
@@ -48,8 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from flexflow_tpu.ops.gated_delta import unit_heads
-from flexflow_tpu.serving.cache_entry import (StateEntry, _named_by,
-                                              _spread_rows)
+from flexflow_tpu.ops.rows import named_by, spread_rows
+from flexflow_tpu.serving.cache_entry import ConvTail
 
 # (arena rows, slots, heads, key_dim, value_dim): channels = 2 H d_k + H d_v
 SHAPES = {"ling-3.0-flash-ep8": (257, 256, 32, 128, 128),
@@ -70,7 +70,7 @@ def form_i(tails, rows, x, w):
     window = jnp.concatenate(
         [tails[rows].reshape(n, TAPS - 1, c), x[:, None]], axis=1)
     u = silu_sum(w[:, None, None], [window[:, j:j + 1] for j in range(TAPS)])
-    return u[:, 0], _spread_rows(tails, rows, window[:, 1:].reshape(n, -1))
+    return u[:, 0], spread_rows(tails, rows, window[:, 1:].reshape(n, -1))
 
 
 def form_ii(tails, rows, x, w):
@@ -78,12 +78,12 @@ def form_ii(tails, rows, x, w):
     t = tails[rows]
     u = silu_sum(w[:, None], [t[:, j * c:(j + 1) * c]
                               for j in range(TAPS - 1)] + [x])
-    return u, _spread_rows(tails, rows, jnp.concatenate([t[:, c:], x], -1))
+    return u, spread_rows(tails, rows, jnp.concatenate([t[:, c:], x], -1))
 
 
 def form_iii(tails, rows, x, w):
     c = x.shape[1]
-    slot_of, live = _named_by(tails.shape[0], rows)
+    slot_of, live = named_by(tails.shape[0], rows)
     x_r = jnp.where(live[:, None], x[slot_of], 0)    # nobody's row: zeros
     u_r = silu_sum(w[:, None], [tails[:, j * c:(j + 1) * c]
                                 for j in range(TAPS - 1)] + [x_r])
@@ -93,7 +93,7 @@ def form_iii(tails, rows, x, w):
 
 
 def form_iv(tails, rows, x, w):
-    u, tails = StateEntry._tails_arena(tails, rows, x, w)
+    u, tails = ConvTail.step_arena(tails, rows, x, w)
     return u[:, 0], tails
 
 
