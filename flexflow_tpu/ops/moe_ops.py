@@ -559,11 +559,103 @@ RIDGE_ROWS = 240
 # 3,072 x 3,072 (32 rows): every one past 0.95, so 0.9 is on the dense
 # side of them all, and at 0.9 the kernel leads by 8 to 10 %
 NAMED_SHARE_KERNEL = 0.9
+# a router's pick of k among n: k passes of a first maximum against one sort
+# of the row (``k_largest``). A pass reads the row once, a bitonic sort of n
+# takes ``b (b + 1) / 2`` stages over it, ``b = ceil(log2 n)``, whatever k
+# is: passes where ``SELECT_PASS_STAGES`` x k is no more than the stages.
+# Measured on a v5e (``tools/route_select_forms.py``, PERF.md section 6, PR
+# 62: a pick alone behind a sigmoid, the device's busy time a call). At 256
+# rows the sort takes 0.0221 ms of 512 whatever k from 6 up (0.0092 at k =
+# 4, 0.0057 at 2: under 6 ``lax.top_k`` is no full sort here), 0.0101 of
+# 256, 0.0093 of 192, 0.0050 of 128, 0.0022 of 64, 0.0009 of 8; the passes
+# 0.0105 ms for 8 of 512 (0.0177 for 12, 0.0260 for 16, 0.0407 for 22: a
+# pass costs more the more there are), 0.0072 for 8 of 256, 0.0040 for 8 of
+# 192, 0.0012 for 2 of 64. They cross at k = 1 to 2 of 8, 2 to 4 of 16, 4
+# to 6 of 32, 8 of 64, 6 to 8 of 128, 16 to 22 of 192, 8 to 12 of 256 and
+# 12 to 16 of 512, the same at 2,048 rows to one step of k: 2.3 to 6 stages
+# a pass. At 3.5 the rule is on the measured side of every pick the six
+# routed configurations make, decode rows | prompt rows, passes | sort in
+# us: 8 of 512 10.5 | 22.1 and 36.5 | 85.6, 8 of 192 2.6 | 5.2 and 12.1 |
+# 33.1, 4 of 256 2.1 | 4.3 and 18.6 | 71.9, 8 of 256 3.6 | 4.3 and 46.0 |
+# 71.9, 1 of 16 0.9 | 1.4 and 1.3 | 18.6, a group's best 2 of 64 3.0 | 14.2
+# and 9.3 | 53.6, of 24 1.4 | 4.1 and 4.5 | 25.9; the sort for 22 of 512,
+# 20.8 | 11.8 and 152.1 | 85.9, and for a grouped router's 4 groups of 8,
+# 1.2 | 1.0 and 1.4 | 1.2. On the wrong side, none of them asked for: 8 of
+# 128 (6.8 | 5.0) and 12 and 16 of 192 (6.2 and 8.8 | 9.4). What the
+# grouped routers paid was less the sorting than its shape, the best 2 of
+# each group sorted over THREE axes and the kept groups scattered: a
+# layer's whole ``route`` at Ling's 256 rows takes 0.253 ms as it stood,
+# 0.063 with the same three sorts over rows and a compare for the scatter,
+# 0.038 as this rule cuts it (0.274, 0.236, 0.126 at 1,024 rows)
+SELECT_PASS_STAGES = 3.5
 # of the rows of a call, the share one held expert may be named by before
 # the grouped form gives the call to the dense one
 CAPACITY_SHARE = 4
 # the held experts' own matrices among a routed-experts op's weights
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+def select_form(k: int, n: int) -> str:
+    """How :func:`k_largest` picks ``k`` of ``n``: ``"passes"`` or
+    ``"sort"``, by the two numbers alone (``SELECT_PASS_STAGES``)."""
+    bits = max(1, (n - 1).bit_length())
+    return ("passes" if SELECT_PASS_STAGES * k <= bits * (bits + 1) / 2
+            else "sort")
+
+
+def _order_keys(bits):
+    """int32 bit patterns of float32 numbers <-> int32 keys that compare as
+    the numbers do in the total order a sort puts them in (-0.0 under 0.0,
+    a NaN past +inf). Its own inverse: the sign bit stays."""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def k_largest(x, k: int, form: Optional[str] = None):
+    """The ``k`` largest of the last axis of a float32 ``x``: their
+    values ``(..., k)`` and int32 indices, descending, the lower index
+    first among equals: ``jax.lax.top_k``'s answer to the bit, NaN, -inf
+    and signed zeros included, in the form :func:`select_form` names
+    (``form`` says it in its place, for tests and the sweep).
+    ``"passes"``: k times the first maximum of the row, one variadic
+    reduction over (key, index) a pass, the picked entry then struck by
+    its INDEX (its key the least there is and its index past every
+    other), so that a row of equal entries, of NaN or of -inf, still
+    yields k distinct ids; the values are the keys the reductions
+    returned, so nothing is gathered, sorted or scattered. A selection:
+    no gradient passes through either form (``route`` takes its weights
+    from the scores at the ids)."""
+    if x.dtype != jnp.float32:
+        raise TypeError(f"k_largest orders float32 bits, not {x.dtype}")
+    x = jax.lax.stop_gradient(x)
+    n = x.shape[-1]
+    if (form or select_form(k, n)) == "sort":
+        # as rows: over three axes the chip's compiler sorts several times
+        # slower (``SELECT_PASS_STAGES``: Ling's ``route`` 0.253 | 0.063 ms)
+        values, ids = jax.lax.top_k(x.reshape(-1, n), k)
+        lead = x.shape[:-1] + (k,)
+        return values.reshape(lead), ids.reshape(lead)
+    lowest, past = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+
+    def first_max(a, b):
+        (ak, ai), (bk, bi) = a, b
+        first = (ak > bk) | ((ak == bk) & (ai < bi))
+        return jnp.where(first, ak, bk), jnp.where(first, ai, bi)
+
+    keys = _order_keys(jax.lax.bitcast_convert_type(x, jnp.int32))
+    index = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    values, ids = [], []
+    for _ in range(k):
+        key, idx = jax.lax.reduce(
+            (keys, index), (np.int32(lowest), np.int32(past)), first_max,
+            (x.ndim - 1,))
+        values.append(key)
+        ids.append(idx)
+        struck = index == idx[..., None]
+        keys, index = (jnp.where(struck, lowest, keys),
+                       jnp.where(struck, past, index))
+    values = jax.lax.bitcast_convert_type(
+        _order_keys(jnp.stack(values, -1)), jnp.float32)
+    return values, jnp.stack(ids, -1)
 
 
 def _expert_matrices(weights):
@@ -790,18 +882,26 @@ class RoutedExperts(Op):
             t = s.shape[0]
             per = self.n_routed // self.n_group
             grouped = choice.reshape(t, self.n_group, per)
-            gscore = jax.lax.top_k(grouped, min(2, per))[0].sum(-1)
-            _, gidx = jax.lax.top_k(gscore, self.topk_group)
-            keep = jnp.zeros((t, self.n_group), bool).at[
-                jnp.arange(t)[:, None], gidx].set(True)
+            gscore = k_largest(grouped, min(2, per))[0].sum(-1)
+            _, gidx = k_largest(gscore, self.topk_group)
+            keep = (gidx[:, :, None] == jnp.arange(
+                self.n_group, dtype=jnp.int32)).any(1)
             choice = jnp.where(keep[:, :, None], grouped, -1.0).reshape(
                 t, self.n_routed)
         if ids is None:
-            _, ids = jax.lax.top_k(choice, self.k)
+            _, ids = k_largest(choice, self.k)
         g = jnp.take_along_axis(s, ids, axis=-1)
         if self.norm_topk:
             g = g / (g.sum(-1, keepdims=True) + 1e-20)
         return ids.astype(jnp.int32), g * self.routed_scale, state
+
+    def select_form(self) -> str:
+        """How :meth:`route` picks its ``k`` of ``n_routed``
+        (:func:`select_form`: ``"passes"`` or ``"sort"``; a grouped
+        router's two smaller picks, the best 2 of a group and
+        ``topk_group`` of the groups, follow the same rule at their own
+        sizes). By the op's two numbers alone, whatever the rows."""
+        return select_form(self.k, self.n_routed)
 
     def held_hits(self, ids):
         """``ids`` (T, k) -> (T, k, count) bool: which held expert, if

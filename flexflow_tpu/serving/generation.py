@@ -944,6 +944,11 @@ class PagedDecoder(_DecodeGraph):
                 "form_decode": form,
                 "form_prefill": op.expert_form(self.prefill_buckets[-1],
                                                dtype, mesh),
+                # how the router picked its experts in those programs
+                # (``op.select_form``: "passes" or "sort"; its rule reads
+                # the op's k and n_routed and no rows, so one word twice)
+                "select_decode": op.select_form(),
+                "select_prefill": op.select_form(),
                 "rows_computed": (acc[4 + op.count] if step_rows is None
                                   else acc[0] * step_rows),
                 "kernel_steps": (acc[-1] if form == "counted"

@@ -1753,3 +1753,20 @@ def test_ling_prefill_runs_the_channel_rule_in_jnp(ling_programs):
         if " while(" in ln:
             assert "ff.KIMI_DELTA_ATTENTION." in ln and "/rule/" in ln, ln
     assert mem.temp_size_in_bytes < 3 << 30
+
+
+@pytest.mark.parametrize("name,rows", [("decode", 256), ("prefill", 1024)])
+def test_ling_routers_sort_their_groups_and_nothing_else(ling_programs,
+                                                         name, rows):
+    """Since PR 62 a router picks by passes where ``select_form`` says so:
+    of the three picks of Ling's ``route`` (the best 2 of each group of
+    64, 4 groups of 8, 8 experts of 512) the compiled programs sort the
+    groups' eight scores alone, once an expert layer, and scatter nothing
+    (the kept groups are a compare)."""
+    routed = [ln for ln in ling_programs[0][name][0].splitlines()
+              if "/route/" in ln]
+    sorts = [ln for ln in routed if " sort(" in ln]
+    assert len(sorts) == 3, sorts
+    for ln in sorts:
+        assert f"f32[{rows},8]" in ln and "512]" not in ln, ln
+    assert not [ln for ln in routed if " scatter(" in ln]
