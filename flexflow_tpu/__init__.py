@@ -54,6 +54,7 @@ from .ops import (  # noqa: F401
     recurrent,
     reduce,
     softmax,
+    stream_mix,
     structural,
 )
 

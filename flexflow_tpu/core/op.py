@@ -94,6 +94,8 @@ OP_GROUPS: Dict[str, Tuple[OpType, ...]] = {
               OpType.LIGHTNING_ATTENTION, OpType.MAMBA2),
     "matmul": (OpType.LINEAR, OpType.GATED_MLP, OpType.EXPERT_LINEAR,
                OpType.ROUTED_EXPERTS),
+    # a residual path of several streams, read and written around a sublayer
+    "stream": (OpType.STREAM_MIX,),
 }
 _GROUP_OF = {t.name: g for g, types in OP_GROUPS.items() for t in types}
 

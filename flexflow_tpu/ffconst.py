@@ -204,6 +204,10 @@ class OpType(enum.Enum):
     MAMBA2 = "mamba2"
     # x -> (act(x W_gate) * (x W_up)) W_down
     GATED_MLP = "gated_mlp"
+    # a residual path of n streams (hyper-connections): the streams spread
+    # from one, read into a sublayer's input, written back behind it under
+    # a mixing matrix, and summed at the end
+    STREAM_MIX = "stream_mix"
     # dropless top-k routing over n experts, of which this op holds a
     # contiguous share and computes only those
     ROUTED_EXPERTS = "routed_experts"

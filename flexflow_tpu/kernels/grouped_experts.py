@@ -197,7 +197,7 @@ def tile_table(ids, first: int, count: int):
 
 
 def _kernel(expert_ref, place_ref, real_ref, tiles_ref, token_ref, gate_ref,
-            packed_ref, *refs, gated: bool, cuts: int):
+            packed_ref, *refs, gated: bool, cuts: int, limit=None):
     if gated:
         w_gate_ref, w_up_ref, w_down_ref, out_ref, rows_u32, rows, res = refs
     else:
@@ -236,7 +236,10 @@ def _kernel(expert_ref, place_ref, real_ref, tiles_ref, token_ref, gate_ref,
             return jnp.dot(x, ref[...], preferred_element_type=jnp.float32)
 
         if gated:
-            h = jax.nn.silu(up(w_gate_ref)) * up(w_up_ref)
+            g, u = up(w_gate_ref), up(w_up_ref)
+            if limit is not None:      # compiled out where there is none
+                g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+            h = jax.nn.silu(g) * u
         else:
             h = jnp.square(jnp.maximum(up(w_up_ref), 0.0))
         y = jnp.dot(h.astype(x.dtype), w_down_ref[...],
@@ -264,9 +267,9 @@ def _kernel(expert_ref, place_ref, real_ref, tiles_ref, token_ref, gate_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "first", "gated", "cut", "interpret"))
+    "first", "gated", "cut", "interpret", "limit"))
 def _grouped_experts(v, ids, gates, w_gate, w_up, w_down, *, first, gated,
-                     cut, interpret):
+                     cut, interpret, limit=None):
     t, work_dim = v.shape
     count, _, width = w_up.shape
     cuts = width // cut
@@ -296,7 +299,7 @@ def _grouped_experts(v, ids, gates, w_gate, w_up, w_down, *, first, gated,
                         pltpu.VMEM((tile, work_dim), v.dtype),
                         pltpu.VMEM((tile, work_dim), jnp.float32)])
     out = pl.pallas_call(
-        functools.partial(_kernel, gated=gated, cuts=cuts),
+        functools.partial(_kernel, gated=gated, cuts=cuts, limit=limit),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, work_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -309,8 +312,11 @@ def _grouped_experts(v, ids, gates, w_gate, w_up, w_down, *, first, gated,
     return out.astype(v.dtype), (tiles_real * tile).astype(jnp.uint32)
 
 
-def grouped_experts(v, ids, gates, weights, *, first: int, gated: bool):
-    """The held experts' weighted sum for the routing given.
+def grouped_experts(v, ids, gates, weights, *, first: int, gated: bool,
+                    limit: Optional[float] = None):
+    """The held experts' weighted sum for the routing given; ``limit``
+    (``gated`` only): the gate cut at it from above and the up-projection
+    to ``[-limit, limit]`` before the product.
 
     ``v`` (T, work_dim) bfloat16; ``ids`` (T, k) int32 over all routed
     experts and ``gates`` (T, k) float32; ``weights`` the op's ``w_up``
@@ -337,7 +343,7 @@ def grouped_experts(v, ids, gates, weights, *, first: int, gated: bool):
     out, computed = _grouped_experts(
         v, ids, gates, weights.get("w_gate") if gated else None, w_up,
         weights["w_down"], first=first, gated=gated, cut=cut,
-        interpret=pallas_mode() == "interpret")
+        interpret=pallas_mode() == "interpret", limit=limit)
     return (out[:t] if short else out), computed
 
 

@@ -33,6 +33,7 @@ are multiplied by.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import List, Optional
@@ -679,6 +680,99 @@ class CompressedConvAttention(Op):
         return proj + conv + 4.0 * b * h * s * s * d
 
 
+@dataclasses.dataclass(frozen=True)
+class Indexer:
+    """A latent-attention op's learned indexer (DeepSeek-V3.2's sparse
+    attention, with keys pooled over positions): ``heads`` query heads of
+    ``dim`` against ONE key head, rotary over the first ``rope_dim``
+    dimensions of both (interleaved pairs, base ``theta``); keys are kept
+    as the MEAN of each complete pool of ``pool`` positions; a query at
+    position ``t`` always reads the pool that holds ``t`` and, of the
+    pools before it, the ``picks`` with the highest ``sum_j w_j relu(q_j .
+    K_p)``: ``topk`` rows in all. The selection comes as ids
+    (:meth:`picked`: serving gathers by them) or as a mask (:meth:`taken`:
+    the plain whole-sequence form attends under it)."""
+
+    heads: int
+    dim: int
+    rope_dim: int
+    pool: int
+    topk: int
+    theta: float = 10000.0
+    eps: float = 1e-6          # under the root of the key's LayerNorm
+
+    def __post_init__(self):
+        if (self.topk % self.pool or self.topk < 2 * self.pool
+                or self.rope_dim % 2 or self.rope_dim > self.dim):
+            raise ValueError(f"an indexer of {self}")
+
+    @property
+    def picks(self) -> int:
+        """Pools a query takes by their scores, beside the one it is in."""
+        return self.topk // self.pool - 1
+
+    @property
+    def inv_freq(self):
+        return rotary_inv_freq(self.rope_dim, self.theta)
+
+    def turn(self, x, positions):
+        """Rotary over the first ``rope_dim`` of the last axis."""
+        r = self.rope_dim
+        return jnp.concatenate(
+            [apply_rotary(x[..., :r], positions, self.inv_freq,
+                          interleaved=True), x[..., r:]], axis=-1)
+
+    def pooled(self, keys):
+        """(B, S, dim) keys, S whole pools -> (B, S / pool, dim): each
+        pool's mean, float32 inside."""
+        b, s, d = keys.shape
+        return keys.astype(jnp.float32).reshape(
+            b, s // self.pool, self.pool, d).mean(2).astype(keys.dtype)
+
+    def scores(self, q, w, pooled, qpos):
+        """``q`` (B, Sq, heads, dim), ``w`` (B, Sq, heads) float32,
+        ``pooled`` (B, P, dim), ``qpos`` (B, Sq) -> (B, Sq, P) float32:
+        ``sum_j w_j relu(q_j . K_p)`` where pool p lies wholly before the
+        query's own pool, else -inf."""
+        dots = jnp.einsum("bqhd,bpd->bqhp", q, pooled,
+                          preferred_element_type=jnp.float32)
+        sc = jnp.einsum("bqhp,bqh->bqp", jnp.maximum(dots, 0.0), w)
+        before = (jax.lax.iota(jnp.int32, pooled.shape[1])[None, None, :]
+                  < (qpos // self.pool)[..., None])
+        return jnp.where(before, sc, -jnp.inf)
+
+    def picked(self, scores):
+        """``scores`` (B, Sq, P) -> the pools a query takes by their
+        scores, (B, Sq, count) int32, ``count = min(picks, P)``, highest
+        first, -1 where it has fewer before it; ties go to the lower pool
+        (``lax.top_k``'s order; as rows: over three axes the chip's
+        compiler sorts several times slower)."""
+        count = min(self.picks, scores.shape[-1])
+        vals, ids = jax.lax.top_k(
+            scores.reshape(-1, scores.shape[-1]), count)
+        lead = scores.shape[:-1] + (count,)
+        return jnp.where(vals > -jnp.inf, ids.astype(jnp.int32),
+                         -1).reshape(lead)
+
+    def taken(self, scores):
+        """The same selection as a mask, (B, Sq, P) bool (the plain form's:
+        a compare of every pick with every pool)."""
+        at = jax.lax.iota(jnp.int32, scores.shape[-1])
+        return (self.picked(scores)[..., None] == at).any(-2)
+
+    def sees(self, taken, qpos, kpos):
+        """``taken`` (B, Sq, P) -> (B, Sq, Sk) bool: whether the query at
+        ``qpos`` (B, Sq) reads the key at ``kpos`` (B, Sk): in a pool it
+        took or in its own, and not after it."""
+        kp = kpos // self.pool
+        picked = jnp.take_along_axis(
+            taken, jnp.broadcast_to(
+                jnp.clip(kp, 0, taken.shape[-1] - 1)[:, None, :],
+                taken.shape[:2] + kp.shape[-1:]), axis=-1)
+        own = kp[:, None, :] == (qpos // self.pool)[..., None]
+        return (picked | own) & (kpos[:, None, :] <= qpos[..., None])
+
+
 @register_op
 class LatentAttention(Op):
     """Causal self-attention over a low-rank latent (multi-head latent
@@ -693,7 +787,12 @@ class LatentAttention(Op):
       low-rank step and no norm; ``output_gate="head"``: each head's
       ``sum p v`` times ``sigmoid(x w_gate,h)`` before ``W_o`` (``wg`` is
       (E, H)); ``rope_interleaved``: the rotary pairs are ``(2i, 2i +
-      1)``.
+      1)``; ``qk_rope_head_dim=0``: no rotary part at all, the row is
+      the latent alone;
+    * ``indexer`` (:class:`Indexer`; needs a query rank): ``q_I = cq
+      W_qI`` per indexer head, ``k_I = layer_norm(x W_kI)`` one head,
+      ``w = (x W_w) heads^-1/2 dim^-1/2``, both rotated; a query reads
+      only the rows of the pools it takes (:meth:`Indexer.sees`).
 
     What a cache has to keep of a token is the row ``[c | k_rope]``
     (:meth:`queries_and_rows`), not keys and values:
@@ -736,6 +835,13 @@ class LatentAttention(Op):
         # the row a cache keeps of a token
         self.row_width = self.kv_rank + self.rope_dim
         self.causal = True
+        ix = a.get("indexer") or None
+        self.indexer: Optional[Indexer] = None if ix is None else Indexer(
+            **{k: (float(v) if k in ("theta", "eps") else int(v))
+               for k, v in ix.items()})
+        if self.indexer is not None and self.q_rank is None:
+            raise ValueError(f"{self.name}: an indexer reads the queries' "
+                             f"low-rank step; q_lora_rank is None")
 
     def infer_output_shapes(self):
         return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
@@ -753,6 +859,17 @@ class LatentAttention(Op):
             WeightSpec("wq_b", (self.q_rank, qk), dt, init)]
         gate = [WeightSpec("wg", (e, h), dt, init)] if self.output_gate \
             else []
+        ix = self.indexer
+        if ix is not None:
+            zero = self.attrs.get("bias_initializer") or ZeroInitializer()
+            gate = gate + [
+                WeightSpec("wq_i", (self.q_rank, ix.heads * ix.dim), dt, init),
+                WeightSpec("wk_i", (e, ix.dim), dt, init),
+                WeightSpec("k_norm_i", (ix.dim,), dt, gain,
+                           weight_decay=False),
+                WeightSpec("k_bias_i", (ix.dim,), dt, zero,
+                           weight_decay=False),
+                WeightSpec("ww_i", (e, ix.heads), dt, init)]
         return queries + [
             WeightSpec("wkv_a", (e, self.kv_rank + self.rope_dim), dt, init),
             WeightSpec("kv_norm", (self.kv_rank,), dt, gain,
@@ -763,14 +880,16 @@ class LatentAttention(Op):
 
     # ---- the pieces serving composes ------------------------------------
     @sub_scope("project")
-    def queries_and_rows(self, weights, x, positions):
+    def queries_and_rows(self, weights, x, positions, with_cq=False):
         """``x`` (B, S, E), ``positions`` (B, S) -> ``q_nope`` (B, S, H,
         nope), ``q_rope`` (B, S, H, rope) rotated, and the latent rows
-        (B, S, kv_rank + rope): ``[c | k_rope]``."""
+        (B, S, kv_rank + rope): ``[c | k_rope]``; ``with_cq``: also the
+        queries' normed low-rank step (B, S, q_rank)."""
         from .norm import rms_norm
 
         b, s, _ = x.shape
         h = self.num_heads
+        cq = None
         if self.q_rank is None:
             q = _mm(x, weights["wq"])
         else:
@@ -784,9 +903,35 @@ class LatentAttention(Op):
         turn = functools.partial(apply_rotary, positions=positions,
                                  inv_freq=self.inv_freq,
                                  interleaved=self.rope_interleaved)
+        if not self.rope_dim:          # no rotary part: the latent alone
+            return (q_nope, q_rope, c) + ((cq,) if with_cq else ())
         k_rope = turn(kva[..., self.kv_rank:])
         q_rope = turn(q_rope)
-        return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+        out = q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1)
+        return out + ((cq,) if with_cq else ())
+
+    @sub_scope("select")
+    def index(self, weights, x, cq, positions):
+        """The indexer's side of a token: ``q_I`` (B, S, heads, dim)
+        rotated, ``w`` (B, S, heads) float32, and its key ``k_I`` (B, S,
+        dim), normed and rotated, in the activations' dtype."""
+        ix = self.indexer
+        b, s, _ = x.shape
+        f32 = jnp.float32
+        q = ix.turn(_mm(cq, weights["wq_i"]).reshape(b, s, ix.heads, ix.dim),
+                    positions)
+        k = jnp.dot(x, weights["wk_i"], preferred_element_type=f32)
+        k = k - k.mean(-1, keepdims=True)
+        k = (k * jax.lax.rsqrt(jnp.mean(k * k, -1, keepdims=True) + ix.eps)
+             * weights["k_norm_i"].astype(f32)
+             + weights["k_bias_i"].astype(f32))
+        k = ix.turn(k, positions).astype(x.dtype)
+        w = jnp.dot(x, weights["ww_i"], preferred_element_type=f32) \
+            * (ix.heads * ix.dim) ** -0.5
+        return q, w, k
+
+    def sees(self, qpos, kpos):
+        return kpos <= qpos
 
     def gated(self, weights, x, o):
         """The heads' outputs ``o`` (B, S, H, v) as ``W_o`` takes them:
@@ -814,11 +959,12 @@ class LatentAttention(Op):
         kv = jnp.einsum("bkc,chd->bkhd", c, self.kvb_heads(weights),
                         preferred_element_type=jnp.float32).astype(c.dtype)
         k_nope, v = kv[..., :self.nope_dim], kv[..., self.nope_dim:]
-        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
-                               preferred_element_type=jnp.float32)
-                  ) * self.scale
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                            preferred_element_type=jnp.float32)
+        if self.rope_dim:
+            scores = scores + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                                         preferred_element_type=jnp.float32)
+        scores = scores * self.scale
         mask = mask[None, None] if mask.ndim == 2 else mask[:, None]
         probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
         ctxv = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
@@ -828,8 +974,41 @@ class LatentAttention(Op):
         return _mm(ctxv.reshape(b, sq, self.num_heads * self.v_dim),
                    weights["wo"])
 
+    def selected(self, weights, x, positions, before=None, offset=0):
+        """The op under its indexer over whole sequences, every query's
+        selection as a mask over the keys (the plain form: serving's
+        kinds read the taken rows alone): ``x`` (B, S, E) at ``positions``
+        (B, S) = ``offset ..``, behind ``before`` = (rows (B, L, width),
+        index keys (B, L, dim)) that hold positions ``0 .. offset - 1``
+        (None: nothing, L = S), L whole pools. Returns (out (B, S, E),
+        (rows, index keys) with the block written at ``offset``, the pools
+        each query took by their scores, (B, S, L / pool) bool)."""
+        ix = self.indexer
+        q_nope, q_rope, rows, cq = self.queries_and_rows(
+            weights, x, positions, with_cq=True)
+        qi, w, ki = self.index(weights, x, cq, positions)
+        if before is None:
+            pad = ((0, 0), (0, -x.shape[1] % ix.pool), (0, 0))
+            rows, ki = jnp.pad(rows, pad), jnp.pad(ki, pad)
+        else:
+            with sub_scope("write"):
+                rows, ki = (jax.lax.dynamic_update_slice(
+                    old, new.astype(old.dtype), (0, offset, 0))
+                    for old, new in zip(before, (rows, ki)))
+        with sub_scope("select"):
+            taken = ix.taken(ix.scores(qi, w, ix.pooled(ki), positions))
+            kpos = jnp.broadcast_to(
+                jax.lax.iota(jnp.int32, rows.shape[1])[None],
+                rows.shape[:2])
+            mask = ix.sees(taken, positions, kpos)
+        out = self.attend_expanded(weights, q_nope, q_rope,
+                                   rows.astype(x.dtype), mask, x)
+        return out, (rows, ki), taken
+
     def forward(self, ctx, inputs, weights):
         x, positions = inputs
+        if self.indexer is not None:
+            return [self.selected(weights, x, positions)[0]]
         q_nope, q_rope, rows = self.queries_and_rows(weights, x, positions)
         s = x.shape[1]
         pos = jax.lax.iota(jnp.int32, s)
@@ -846,7 +1025,16 @@ class LatentAttention(Op):
             + e * (self.kv_rank + self.rope_dim)
             + self.kv_rank * h * (self.nope_dim + self.v_dim)
             + h * self.v_dim * e)
-        return proj + 2.0 * b * h * s * s * (qk + self.v_dim)
+        ix = self.indexer
+        if ix is None:
+            return proj + 2.0 * b * h * s * s * (qk + self.v_dim)
+        # the indexer's projections, its scores over the pooled keys, and
+        # the attention over at most ``topk`` rows a query
+        index = 2.0 * b * s * (self.q_rank * ix.heads * ix.dim
+                               + e * (ix.dim + ix.heads)
+                               + ix.heads * ix.dim * (s // ix.pool) / 2)
+        return (proj + index
+                + 2.0 * b * h * s * min(s, ix.topk) * (qk + self.v_dim))
 
 
 def _mm(x, w):

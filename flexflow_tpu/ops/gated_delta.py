@@ -449,7 +449,12 @@ class KimiDeltaAttention(GatedDeltaNet):
       ``S' = diag(alpha_t) S_{t-1}``, then the delta rule as before;
     * ``beta_t = sigmoid(x_t W_b)``, never doubled;
     * out: ``concat_h(RMSNorm_{d_v}(o_t,h) * sigmoid(x_t w_gate,h)) W_o``:
-      ONE gate a head (``wg`` is (E, H)), a sigmoid.
+      ONE gate a head (``wg`` is (E, H)), a sigmoid;
+    * ``decay_rank``: the decay's projection goes through that rank, ``x
+      W_fa W_fb`` (``wf_a`` (E, rank), ``wf_b`` (rank, H d_k)) in ``wf``'s
+      place; ``output_gate="channel"``: a gate a value CHANNEL through
+      ``gate_rank``, ``sigmoid(x W_ga W_gb)`` (``wg_a`` (E, rank), ``wg_b``
+      (rank, H d_v)) in ``wg``'s place (the Kimi Linear form).
 
     The convolution, the unit q and k, the state's shape and what a
     request keeps are :class:`GatedDeltaNet`'s, so serving stores it as
@@ -471,6 +476,16 @@ class KimiDeltaAttention(GatedDeltaNet):
         if self.neg_eigval:
             raise ValueError(f"{self.name}: beta is never doubled here")
         self.channel_decay = True
+        self.decay_rank = int(self.attrs.get("decay_rank") or 0)
+        self.output_gate = self.attrs.get("output_gate", "head")
+        if self.output_gate not in ("head", "channel"):
+            raise ValueError(f"{self.name}: output_gate "
+                             f"{self.output_gate!r} is neither 'head' nor "
+                             f"'channel'")
+        self.gate_rank = int(self.attrs.get("gate_rank") or 0)
+        if (self.output_gate == "channel") != bool(self.gate_rank):
+            raise ValueError(f"{self.name}: a gate a channel goes through "
+                             f"a gate_rank, a gate a head through none")
 
     def weight_specs(self) -> List[WeightSpec]:
         dt = self.input_shapes[0].dtype
@@ -478,13 +493,21 @@ class KimiDeltaAttention(GatedDeltaNet):
         gain = self.attrs.get("gain_initializer") or ConstantInitializer(1.0)
         gate = self.attrs.get("gate_initializer") or ZeroInitializer()
         e, h = self.embed_dim, self.num_heads
+        decay = [WeightSpec("wf", (e, self.qk_width), dt, init)] \
+            if not self.decay_rank else [
+            WeightSpec("wf_a", (e, self.decay_rank), dt, init),
+            WeightSpec("wf_b", (self.decay_rank, self.qk_width), dt, init)]
+        gate_out = [WeightSpec("wg", (e, h), dt, init)] \
+            if not self.gate_rank else [
+            WeightSpec("wg_a", (e, self.gate_rank), dt, init),
+            WeightSpec("wg_b", (self.gate_rank, self.v_width), dt, init)]
         return [
             WeightSpec("wq", (e, self.qk_width), dt, init),
             WeightSpec("wk", (e, self.qk_width), dt, init),
             WeightSpec("wv", (e, self.v_width), dt, init),
-            WeightSpec("wf", (e, self.qk_width), dt, init),
+            *decay,
             WeightSpec("wb", (e, h), dt, init),
-            WeightSpec("wg", (e, h), dt, init),
+            *gate_out,
             WeightSpec("conv", (self.conv_taps, self.channels), dt, init),
             WeightSpec("a_log", (h,), dt, gate, weight_decay=False),
             WeightSpec("dt_bias", (self.qk_width,), dt, gate,
@@ -501,7 +524,11 @@ class KimiDeltaAttention(GatedDeltaNet):
         f32 = jnp.float32
         b, s, _ = x.shape
         h, dk = self.num_heads, self.key_dim
-        f = jnp.dot(x, weights["wf"], preferred_element_type=f32)
+        if self.decay_rank:
+            f = jnp.dot(_mm(x, weights["wf_a"]), weights["wf_b"],
+                        preferred_element_type=f32)
+        else:
+            f = jnp.dot(x, weights["wf"], preferred_element_type=f32)
         bl = jnp.dot(x, weights["wb"], preferred_element_type=f32)
         a = jnp.exp(weights["a_log"].astype(f32))[:, None]
         g = self.lower_bound * jax.nn.sigmoid(
@@ -515,8 +542,13 @@ class KimiDeltaAttention(GatedDeltaNet):
         RMSNorm over ``d_v`` a head (``normed``: made already), times the
         head's ``sigmoid(x w_gate)``, through ``W_o``."""
         b, s = o.shape[:2]
-        z = jnp.dot(x, weights["wg"], preferred_element_type=jnp.float32)
         y = o if normed else rms_norm(o, weights["norm"], self.eps)
+        if self.gate_rank:
+            z = jnp.dot(_mm(x, weights["wg_a"]), weights["wg_b"],
+                        preferred_element_type=jnp.float32)
+            y = y.reshape(b, s, self.v_width) * jax.nn.sigmoid(z)
+            return _mm(y.astype(x.dtype), weights["wo"])
+        z = jnp.dot(x, weights["wg"], preferred_element_type=jnp.float32)
         y = y.reshape(b, s, self.num_heads, self.value_dim) \
             * jax.nn.sigmoid(z)[..., None]
         return _mm(y.reshape(b, s, self.v_width).astype(x.dtype),
@@ -525,6 +557,10 @@ class KimiDeltaAttention(GatedDeltaNet):
     def flops(self) -> float:
         b, s = self.input_shapes[0].sizes[:2]
         e, h = self.embed_dim, self.num_heads
-        # the decay's full-rank projection in the place of W_g's and W_a's
+        # the decay's projection (full-rank, or through its rank) and the
+        # output gate's in the place of W_g's and W_a's
+        r, gr = self.decay_rank, self.gate_rank
+        decay = r * (e + self.qk_width) if r else e * self.qk_width
+        gate = gr * (e + self.v_width) if gr else e * h
         return (super().flops()
-                + 2.0 * b * s * e * (self.qk_width - self.v_width))
+                + 2.0 * b * s * (decay + gate - e * (self.v_width + h)))

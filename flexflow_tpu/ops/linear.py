@@ -182,6 +182,9 @@ class GatedMLP(Op):
         self.activation: ActiMode = layer.attrs.get("activation",
                                                     ActiMode.SILU)
         self.in_dim: int = input_shapes[0].sizes[-1]
+        # the clamp before the product (None: none)
+        self.limit = (None if layer.attrs.get("limit") is None
+                      else float(layer.attrs["limit"]))
 
     def infer_output_shapes(self):
         return [(self.input_shapes[0].sizes, self.input_shapes[0].dtype)]
@@ -196,7 +199,7 @@ class GatedMLP(Op):
     def forward(self, ctx: LowerCtx, inputs, weights):
         (x,) = inputs
         return [gated_mlp(x, weights["gate"], weights["up"], weights["down"],
-                          self.activation)]
+                          self.activation, self.limit)]
 
     def flops(self) -> float:
         batch = 1
@@ -209,8 +212,11 @@ class GatedMLP(Op):
         return [(0, last, "gate", 0), (0, last, "up", 0)]
 
 
-def gated_mlp(x, gate, up, down, activation: ActiMode = ActiMode.SILU):
+def gated_mlp(x, gate, up, down, activation: ActiMode = ActiMode.SILU,
+              limit=None):
     g = jnp.dot(x, gate, preferred_element_type=jnp.float32)
     u = jnp.dot(x, up, preferred_element_type=jnp.float32)
+    if limit is not None:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
     h = (apply_activation(g, activation) * u).astype(x.dtype)
     return jnp.dot(h, down, preferred_element_type=jnp.float32).astype(x.dtype)

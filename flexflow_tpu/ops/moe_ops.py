@@ -672,7 +672,7 @@ def kernel_form(op, matrices, v, ids, gates):
     from ..kernels.grouped_experts import grouped_experts
 
     return grouped_experts(v, ids, gates, matrices, first=op.first,
-                           gated=op.gated)
+                           gated=op.gated, limit=op.limit)
 
 
 def _kernel_form_fwd(op, *args):
@@ -750,6 +750,11 @@ class RoutedExperts(Op):
         self.routed_scale = float(a.get("routed_scale", 1.0))
         self.selection_bias = bool(a.get("selection_bias", False))
         self.gated = a.get("activation", "silu_gated") == "silu_gated"
+        # the clamp before a gated expert's product (None: none)
+        self.limit = None if a.get("limit") is None else float(a["limit"])
+        if self.limit is not None and not self.gated:
+            raise ValueError(f"{layer.name}: a limit clamps a gated "
+                             f"expert's gate and up-projection")
         self.latent = int(a.get("latent") or 0)
         self.work_dim = self.latent or self.in_dim
         first, count = a.get("experts_held") or (0, self.n_routed)
@@ -1010,6 +1015,14 @@ class RoutedExperts(Op):
             return self.count * rows
         return (self.count + self.spill_tiles) * self.capacity(rows)
 
+    def _gated(self, g, u):
+        """``silu(g) * u``, behind the clamp where the op has a ``limit``
+        (``g`` cut at it from above, ``u`` to ``[-limit, limit]``)."""
+        if self.limit is not None:
+            g = jnp.minimum(g, self.limit)
+            u = jnp.clip(u, -self.limit, self.limit)
+        return jax.nn.silu(g) * u
+
     def _apply_dense(self, weights, v, ids, gates):
         """Every token passes through every held expert and is weighted
         by its gate, 0 where the token did not take the expert: the
@@ -1032,8 +1045,7 @@ class RoutedExperts(Op):
                               preferred_element_type=jnp.float32)
 
         if self.gated:
-            g, u = up("w_gate"), up("w_up")
-            h = jax.nn.silu(g) * u
+            h = self._gated(up("w_gate"), up("w_up"))
         else:
             h = jnp.square(jnp.maximum(up("w_up"), 0.0))
         h = (h * w.T[:, :, None]).astype(v.dtype)
@@ -1095,8 +1107,7 @@ class RoutedExperts(Op):
                                       preferred_element_type=jnp.float32)
 
                 if self.gated:
-                    g, u = up("w_gate"), up("w_up")
-                    h = jax.nn.silu(g) * u
+                    h = self._gated(up("w_gate"), up("w_up"))
                 else:
                     h = jnp.square(jnp.maximum(up("w_up"), 0.0))
                 return jnp.einsum("...cf,...fv->...cv", h.astype(v.dtype),

@@ -289,14 +289,53 @@ class FFModel:
 
     def gated_mlp(self, input: Tensor, width: int,
                   activation: ActiMode = ActiMode.SILU,
-                  kernel_initializer=None,
+                  kernel_initializer=None, limit: Optional[float] = None,
                   name: Optional[str] = None) -> Tensor:
         """``(act(x W_gate) * (x W_up)) W_down`` (ops/linear.py
-        GatedMLP)."""
+        GatedMLP); with ``limit`` the gate is cut at ``limit`` from above
+        and the up-projection to ``[-limit, limit]`` before the product."""
+        attrs = dict(width=int(width), activation=activation,
+                     kernel_initializer=kernel_initializer)
+        if limit is not None:           # absent: the graph it was
+            attrs["limit"] = float(limit)
+        return self._infer_and_add(OpType.GATED_MLP, [input], attrs, name)
+
+    def _stream_mix(self, part: str, inputs, streams: int, name, **more):
         return self._infer_and_add(
-            OpType.GATED_MLP, [input],
-            dict(width=int(width), activation=activation,
-                 kernel_initializer=kernel_initializer), name)
+            OpType.STREAM_MIX, inputs,
+            dict(part=part, streams=int(streams), **more), name)
+
+    def stream_spread(self, input: Tensor, streams: int,
+                      name: Optional[str] = None) -> Tensor:
+        """``(B, S, d)`` copied into ``streams`` residual streams, ``(B,
+        S, streams d)`` (ops/stream_mix.py)."""
+        return self._stream_mix("spread", [input], streams, name)
+
+    def stream_mix_pre(self, streams_in: Tensor, streams: int, *,
+                       sinkhorn_iters: int = 20, eps: float = 1e-6,
+                       norm_eps: float = 1e-6, kernel_initializer=None,
+                       scale_initializer=None, bias_initializer=None,
+                       name: Optional[str] = None) -> List[Tensor]:
+        """The streams -> ``[u, coefficients]``: a sublayer's input and
+        what :meth:`stream_mix_post` writes its output back under."""
+        return self._stream_mix(
+            "pre", [streams_in], streams, name,
+            sinkhorn_iters=int(sinkhorn_iters), eps=float(eps),
+            norm_eps=float(norm_eps), kernel_initializer=kernel_initializer,
+            scale_initializer=scale_initializer,
+            bias_initializer=bias_initializer)
+
+    def stream_mix_post(self, streams_in: Tensor, y: Tensor, coefs: Tensor,
+                        streams: int, name: Optional[str] = None) -> Tensor:
+        """(streams, the sublayer's output, :meth:`stream_mix_pre`'s
+        coefficients) -> the streams behind the sublayer."""
+        return self._stream_mix("post", [streams_in, y, coefs], streams,
+                                name)
+
+    def stream_sum(self, streams_in: Tensor, streams: int,
+                   name: Optional[str] = None) -> Tensor:
+        """The streams summed, ``(B, S, d)``."""
+        return self._stream_mix("sum", [streams_in], streams, name)
 
     def latent_attention(self, input: Tensor, positions: Tensor, *,
                          num_heads: int, q_lora_rank: Optional[int],
@@ -307,14 +346,19 @@ class FFModel:
                          rope_scaling: Optional[Dict[str, Any]] = None,
                          eps: float = 1e-6, output_gate: Optional[str] = None,
                          rope_interleaved: bool = False,
+                         indexer: Optional[Dict[str, Any]] = None,
                          kernel_initializer=None, gain_initializer=None,
+                         bias_initializer=None,
                          name: Optional[str] = None) -> Tensor:
         """Causal self-attention over a low-rank latent with rotary
         positions (ops/attention.py LatentAttention). ``positions`` is
         the graph's int32 positions input; ``rope_scaling`` an optional
         YaRN dict; ``q_lora_rank=None`` projects the queries in one step;
         ``output_gate="head"`` gates each head's output by a sigmoid;
-        ``rope_interleaved`` turns the pairs ``(2i, 2i + 1)``."""
+        ``rope_interleaved`` turns the pairs ``(2i, 2i + 1)``; ``indexer``
+        (``heads``, ``dim``, ``rope_dim``, ``pool``, ``topk``, ``theta``):
+        a learned indexer picks the pools of ``pool`` rows a query reads,
+        ``topk`` rows in all."""
         attrs = dict(
             num_heads=int(num_heads),
             q_lora_rank=None if q_lora_rank is None else int(q_lora_rank),
@@ -332,6 +376,9 @@ class FFModel:
             attrs["output_gate"] = str(output_gate)
         if rope_interleaved:
             attrs["rope_interleaved"] = True
+        if indexer:
+            attrs.update(indexer=dict(indexer),
+                         bias_initializer=bias_initializer)
         return self._infer_and_add(OpType.LATENT_ATTENTION,
                                    [input, positions], attrs, name)
 
@@ -385,11 +432,16 @@ class FFModel:
                              conv_taps: int = 4, lower_bound: float = -5.0,
                              eps: float = 1e-6, kernel_initializer=None,
                              gain_initializer=None, gate_initializer=None,
+                             decay_rank: Optional[int] = None,
+                             output_gate: str = "head",
+                             gate_rank: Optional[int] = None,
                              name: Optional[str] = None) -> Tensor:
         """Linear attention by the delta rule with a decay a key channel,
         ``exp(lower_bound * sigmoid(.))``, and one sigmoid output gate a
         head (ops/gated_delta.py KimiDeltaAttention); the state a
-        sequence keeps is :meth:`gated_delta_net`'s."""
+        sequence keeps is :meth:`gated_delta_net`'s. ``decay_rank``: the
+        decay's projection through that rank; ``output_gate="channel"``:
+        a gate a value channel, through ``gate_rank``."""
         attrs = dict(
             num_heads=int(num_heads), key_dim=int(key_dim),
             value_dim=int(value_dim), conv_taps=int(conv_taps),
@@ -397,6 +449,12 @@ class FFModel:
             kernel_initializer=kernel_initializer,
             gain_initializer=gain_initializer,
             gate_initializer=gate_initializer)
+        # (absent where they are the default's)
+        if decay_rank:
+            attrs["decay_rank"] = int(decay_rank)
+        if output_gate != "head":
+            attrs.update(output_gate=str(output_gate),
+                         gate_rank=int(gate_rank))
         return self._infer_and_add(OpType.KIMI_DELTA_ATTENTION, [input],
                                    attrs, name)
 
@@ -472,6 +530,7 @@ class FFModel:
                        router_width: Optional[int] = None,
                        router_state: Optional[Tensor] = None,
                        router_eps: float = 1e-5,
+                       limit: Optional[float] = None,
                        kernel_initializer=None, bias_initializer=None,
                        gain_initializer=None,
                        name: Optional[str] = None):
@@ -505,6 +564,8 @@ class FFModel:
             attrs["activation"] = activation
         if latent:
             attrs["latent"] = int(latent)
+        if limit is not None:
+            attrs["limit"] = float(limit)
         inputs = [input]
         if router != "linear":
             attrs.update(router=router, router_width=int(router_width),

@@ -21,9 +21,11 @@ request's convolution tail is a :class:`ConvTail`, whichever kind holds it.
 A kind that defines ``chunk`` serves a prompt in chunks, each behind what
 the chunks before left: the pair through its block table, the window over
 its ring, the sparse kind under its selection, the decay state and the
-Mamba-2 state (:class:`SsmStateEntry`: its state AND its convolution's
-tail) from the request's row. The gated-delta state, the latent row and
-the int8 pair prefill a prompt whole.
+Mamba-2 state (:class:`SsmStateEntry`) and the gated-delta state
+(:class:`StateEntry`), each its state AND its convolution's tail, from the
+request's row, the latent row under an indexer's selection
+(:class:`SparseLatentEntry`). The plain latent row and the int8 pair
+prefill a prompt whole.
 
 An entry is the tuple of arrays its kind allocates, donated through the
 programs; the kind is static Python beside it. Every reader masks by
@@ -51,6 +53,7 @@ from ..kernels import (chunk_attention, gated_delta, latent_attention,
 from ..obs.metrics import metrics_registry
 from ..ops import block_sparse_attention as bsa
 from ..ops import mamba2
+from ..ops.attention import Indexer
 from ..ops.gated_delta import delta_rule_path
 from ..ops.rows import named_by, spread_rows
 from ..parallel.ring_attention import sink_softmax
@@ -349,6 +352,9 @@ class EntryKind:
     max_window: Optional[int] = None   # new tokens a slot a step; None: any
     int8_form: Optional["EntryKind"] = None
     chunked = False                    # defines ``chunk``
+    # its step has ONE form, which reads nothing by a kernel: it says
+    # "gather" of itself and has no say in whether a program fell back
+    one_form = False
 
     def stats(self) -> Dict:
         return {"entry": self.name}
@@ -386,6 +392,11 @@ class EntryKind:
         behind ``lengths`` (an int array) cached tokens, under the word
         the pool's books keep it by; empty for a kind that reads all it
         keeps."""
+        return {}
+
+    def chunk_reads(self, offset: int, tokens: int) -> Dict[str, Dict[str, int]]:
+        """:meth:`step_reads` for one prompt's chunk of ``tokens`` tokens
+        at ``offset``, under the same words."""
         return {}
 
     def prefill_path(self, bucket: int) -> Optional[str]:
@@ -1085,21 +1096,11 @@ class LatentEntry(EntryKind):
         tables = addr.tables
         mb = tables.shape[1]
         q_nope, q_rope, rows = op.queries_and_rows(weights, x, positions)
-        blk = jnp.take_along_axis(
-            tables, jnp.clip(seq_lens[:, None] // bs, 0, mb - 1), axis=1)[:, 0]
-        flat = jnp.where(seq_lens < mb * bs, blk * bs + seq_lens % bs,
-                         NULL_BLOCK * bs)
-        entry = self.write(entry, flat, rows[:, 0])
+        entry = self.write(entry, self._step_slots(tables, seq_lens, bs),
+                           rows[:, 0])
         arena = entry[0]
-        with sub_scope("project"):
-            wkvb = op.kvb_heads(weights)              # (rank, H, nope + v)
-            q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0],
-                               wkvb[..., :op.nope_dim],
-                               preferred_element_type=jnp.float32)
-            q_full = jnp.concatenate(
-                [q_lat.astype(arena.dtype), q_rope[:, 0].astype(arena.dtype),
-                 jnp.zeros((n, op.num_heads, lanes - op.row_width),
-                           arena.dtype)], axis=-1)    # (n, H, lanes)
+        q_full, wkvb = self._absorbed(op, weights, q_nope, q_rope, lanes,
+                                      arena.dtype)
         with sub_scope("attend"):
             if self.reads_in_place(op, entry, n, w, mb):
                 ctxv = latent_attention.latent_attention_decode(
@@ -1107,25 +1108,66 @@ class LatentEntry(EntryKind):
                     out_width=op.kv_rank)
             else:
                 view = arena[tables].reshape(n, mb * bs, lanes)  # (n, L, ·)
-                scores = jnp.einsum(
-                    "nhr,nlr->nhl", q_full, view,
-                    preferred_element_type=jnp.float32) * op.scale
-                mask = _iota(mb * bs)[None, :] <= seq_lens[:, None]
-                probs = jax.nn.softmax(
-                    jnp.where(mask[:, None, :], scores, -1e30), axis=-1)
-                ctxv = jnp.einsum("nhl,nlc->nhc", probs.astype(arena.dtype),
-                                  view[..., :op.kv_rank],
-                                  preferred_element_type=jnp.float32)
+                ctxv = self._attend_rows(
+                    op, q_full, view,
+                    lambda: _iota(mb * bs)[None, :] <= seq_lens[:, None])
+        return self._unabsorbed(op, weights, x, wkvb, ctxv), entry
+
+    @staticmethod
+    def _step_slots(tables, seq_lens, bs):
+        """The flat token slot a step's one new row a slot goes to (past
+        the table's span: the null block)."""
+        mb = tables.shape[1]
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(seq_lens[:, None] // bs, 0, mb - 1), axis=1)[:, 0]
+        return jnp.where(seq_lens < mb * bs, blk * bs + seq_lens % bs,
+                         NULL_BLOCK * bs)
+
+    @staticmethod
+    def _absorbed(op, weights, q_nope, q_rope, lanes: int, dtype):
+        """A step's queries over a row's ``lanes`` in the arena's
+        ``dtype``, (n, H, lanes), and ``W_kvb`` by heads."""
+        n = q_nope.shape[0]
+        with sub_scope("project"):
+            wkvb = op.kvb_heads(weights)              # (rank, H, nope + v)
+            q_lat = jnp.einsum("nhd,chd->nhc", q_nope[:, 0],
+                               wkvb[..., :op.nope_dim],
+                               preferred_element_type=jnp.float32)
+            q_full = jnp.concatenate(
+                [q_lat.astype(dtype), q_rope[:, 0].astype(dtype),
+                 jnp.zeros((n, op.num_heads, lanes - op.row_width),
+                           dtype)], axis=-1)          # (n, H, lanes)
+        return q_full, wkvb
+
+    @staticmethod
+    def _attend_rows(op, q_full, view, mask):
+        """The absorbed queries over each slot's rows ``view`` (n, L,
+        lanes) where ``mask()`` (n, L), made behind the scores (the order
+        the programs' lowered text has had): the weighted sums of the
+        rows' latent part, (n, H, rank) float32."""
+        scores = jnp.einsum(
+            "nhr,nlr->nhl", q_full, view,
+            preferred_element_type=jnp.float32) * op.scale
+        probs = jax.nn.softmax(
+            jnp.where(mask()[:, None, :], scores, -1e30), axis=-1)
+        return jnp.einsum("nhl,nlc->nhc", probs.astype(view.dtype),
+                          view[..., :op.kv_rank],
+                          preferred_element_type=jnp.float32)
+
+    @staticmethod
+    def _unabsorbed(op, weights, x, wkvb, ctxv):
+        """The rows' weighted sums through the value half of ``W_kvb``,
+        the output gate and ``W_o``: (n, 1, E)."""
+        n = x.shape[0]
         with sub_scope("project"):
             o = jnp.einsum("nhc,chd->nhd", ctxv.astype(x.dtype),
                            wkvb[..., op.nope_dim:],
                            preferred_element_type=jnp.float32).astype(x.dtype)
             if op.output_gate:
                 o = op.gated(weights, x, o[:, None])[:, 0]
-            out = jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim),
-                          weights["wo"],
-                          preferred_element_type=jnp.float32).astype(x.dtype)
-        return out, entry
+            return jnp.dot(o.reshape(n, 1, op.num_heads * op.v_dim),
+                           weights["wo"],
+                           preferred_element_type=jnp.float32).astype(x.dtype)
 
     def whole(self, op, weights, x, positions):
         """The expanded form: keys and values up-projected from the
@@ -1151,6 +1193,349 @@ class LatentEntry(EntryKind):
                                  rows_cache.astype(x.dtype),
                                  kpos[None, :] <= qpos[:, None], x)
         return out, (rows_cache,)
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseLatentEntry(LatentEntry):
+    """A latent-attention op under a learned indexer
+    (:class:`~flexflow_tpu.ops.attention.Indexer`). What it keeps: the
+    latent row a token, a POOL of ``pool`` rows side by side on the lanes
+    (``(blocks, block_size / pool, pool * lanes)``: what a query reads is
+    whole pools, and a pool is then ONE row of the arena's flat view, so
+    that the taken pools are gathered where they lie, 4 KB a slice); ONE
+    pooled index key a pool under the same block ids (``(blocks,
+    block_size / pool, dim)``: pool i of a request lies in its block ``i
+    // per_block``, the second granularity :class:`SparseEntry` has for
+    its kernels); and a REQUEST's open pool: the float32 sum of the at most
+    ``pool - 1`` keys whose pool is not whole yet (all a mean needs of
+    them; the open pool is the query's own and is always read, so no
+    score is taken against it).
+
+    A step puts its row into its pool, adds its key to the open pool and
+    closes it with the ``pool``-th, scores the slot's whole pools through
+    its table, takes ``picks`` of them and gathers them and its own for
+    the absorbed form. A chunk (at an offset of whole blocks, so of whole
+    pools) writes its pools and the keys of those it closes, scores its
+    queries against all the request's pools and, a tile of queries at a
+    time, gathers each query's taken pools and attends them in the
+    absorbed form: its work does not grow with the context. A prompt's
+    FIRST chunk, while it ends inside the dense regime, takes every pool
+    there is: it walks its few key spans causally, as a pair's chunk does
+    (``chunk_reads`` counts the rows either form's products ran over
+    beside the rows taken). The step has this one form: nothing here reads
+    by a kernel (``one_form``), so the kind has no say in whether a decode
+    step "fell back" from one. Both programs keep the pools each query
+    took by their scores, (rows, positions, picks) int32, -1 for a pick a
+    query has no pool for."""
+
+    index: Optional[Indexer] = None
+    name = "sparse_latent"
+    chunked = True
+    one_form = True
+
+    @classmethod
+    def for_op(cls, op, positions_id, max_length):
+        if op.rope_dim or op.output_gate:
+            raise ValueError(f"{op.name}: an indexer over rows with a "
+                             f"rotary part or an output gate is not built")
+        return cls(LatentEntry.for_op(op, positions_id, max_length).row_width,
+                   op.indexer)
+
+    @property
+    def lanes(self) -> int:
+        return latent_row_lanes(self.row_width)
+
+    def arenas(self, num_blocks, block_size, dtype):
+        ix = self.index
+        if block_size % ix.pool:
+            raise ValueError(f"a block of {block_size} tokens is not whole "
+                             f"pools of {ix.pool}")
+        per = block_size // ix.pool
+        return (jax.ShapeDtypeStruct((num_blocks, per, ix.pool * self.lanes),
+                                     dtype),
+                jax.ShapeDtypeStruct((num_blocks, per, ix.dim), dtype))
+
+    def request_arenas(self, rows, block_size, dtype):
+        return (jax.ShapeDtypeStruct((rows, self.index.dim), jnp.float32),)
+
+    def token_bytes(self, dtype) -> int:
+        item = jnp.dtype(dtype).itemsize
+        return self.lanes * item + self.index.dim * item // self.index.pool
+
+    def stats(self):
+        ix = self.index
+        return dict(super().stats(), index_pool=ix.pool, index_topk=ix.topk)
+
+    def reads_in_place(self, op, entry, slots, window, max_blocks):
+        return False
+
+    def decode_chunk_tokens(self, entry, max_blocks):
+        return None
+
+    def fetch_run_blocks(self, entry, max_blocks):
+        return 1
+
+    # ---- the books ----------------------------------------------------------
+    def dense_through(self) -> int:
+        """Cached tokens up to which every pool before a query is taken."""
+        return (self.index.picks + 1) * self.index.pool
+
+    def rows_taken(self, length):
+        """Rows the query behind ``length`` cached tokens (an int or an
+        array) reads: its picks' and its own pool's up to itself."""
+        ix = self.index
+        return (np.minimum(length // ix.pool, ix.picks) * ix.pool
+                + length % ix.pool + 1)
+
+    def step_reads(self, lengths):
+        ix = self.index
+        pools = lengths // ix.pool
+        return {"index": {
+            "pools_scored": int(pools.sum()),
+            "pools_taken": int((np.minimum(pools, ix.picks) + 1).sum()),
+            "rows_read": int(self.rows_taken(lengths).sum()),
+            "rows_live": int((lengths + 1).sum()),
+            "dense_steps": int(bool(len(lengths))
+                               and bool((pools <= ix.picks).all())),
+            "rows_taken": 0, "rows_attended": 0}}
+
+    def chunk_reads(self, offset, tokens):
+        pos = np.arange(offset, offset + tokens, dtype=np.int64)
+        end = offset + tokens
+        # the rows a query's products run over: a dense chunk walks whole
+        # key spans, any other gathers its budget whatever it masks of it
+        each = (-(-end // SPAN_TOKENS) * SPAN_TOKENS
+                if end <= self.dense_through() else self.index.topk)
+        return {"index": {"rows_taken": int(self.rows_taken(pos).sum()),
+                          "rows_attended": int(tokens * each)}}
+
+    # ---- addressing -----------------------------------------------------------
+    def _pool_slots(self, tables, idx, keep, per: int):
+        """The flat slot of pools ``idx`` (N, J) of the requests of
+        ``tables`` in either arena's flat view; the null block's first
+        where not ``keep``."""
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(idx // per, 0, tables.shape[1] - 1), axis=1)
+        return jnp.where(keep & (idx >= 0) & (idx < tables.shape[1] * per),
+                         blk * per + idx % per, NULL_BLOCK * per)
+
+    @staticmethod
+    def _flat(arena):
+        """An arena by pools, ``(blocks * per_block, width)``: a reshape
+        that moves nothing."""
+        return arena.reshape((-1,) + arena.shape[2:])
+
+    @sub_scope("write")
+    def _put_pools(self, arena, slots, pools):
+        return self._flat(arena).at[slots.reshape(-1)].set(
+            pools.reshape((-1,) + arena.shape[2:]).astype(
+                arena.dtype)).reshape(arena.shape)
+
+    def _taken_pools(self, arena, tables, pools, qpos):
+        """The pools ``pools`` (N, J; -1: none) of the requests of
+        ``tables``, a pool a slice of the arena's flat view, (N, J, pool *
+        lanes), and whether the query at ``qpos`` (N,) reads each row of
+        them, (N, J, pool)."""
+        ix = self.index
+        runs = self._flat(arena)[self._pool_slots(
+            tables, pools, pools >= 0, arena.shape[1])]
+        kpos = pools[..., None] * ix.pool + _iota(ix.pool)
+        return runs, (pools >= 0)[..., None] & (kpos <= qpos[:, None, None])
+
+    def _attend_pools(self, op, q, runs, seen):
+        """Absorbed queries ``q`` (N, H, lanes) over their taken pools
+        ``runs`` (N, J, pool * lanes) where ``seen`` (N, J, pool): the
+        weighted sums of the rows' latent part, (N, H, rank) float32. A
+        pool's rows are taken as the lane groups they lie in (whole lane
+        tiles), each its own product: cutting the pools into rows would
+        copy all that was gathered."""
+        pool, lanes = self.index.pool, self.lanes
+        rows = [runs[..., r * lanes:r * lanes + op.kv_rank]
+                for r in range(pool)]                     # (N, J, rank) each
+        scores = jnp.stack(
+            [jnp.einsum("nhc,njc->nhj", q[..., :op.kv_rank], row,
+                        preferred_element_type=jnp.float32)
+             for row in rows], axis=-1) * op.scale        # (N, H, J, pool)
+        shape = scores.shape
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, None], scores, -1e30).reshape(shape[:2] + (-1,)),
+            axis=-1).reshape(shape).astype(runs.dtype)
+        return sum(jnp.einsum("nhj,njc->nhc", probs[..., r], row,
+                              preferred_element_type=jnp.float32)
+                   for r, row in enumerate(rows))
+
+    def _every_pool(self, pos, count: int):
+        """The picks of queries at ``pos`` (..., S) in the dense regime:
+        every pool before their own, in order, (..., S, count)."""
+        nth = _iota(count)
+        return jnp.where(nth < (pos // self.index.pool)[..., None], nth, -1)
+
+    # ---- the programs' forms ----------------------------------------------
+    def step(self, op, weights, x, positions, entry, addr, seq_lens):
+        ix = self.index
+        n = x.shape[0]                   # one token a slot: ``max_window``
+        arena, keys, opened = entry
+        per, lanes = arena.shape[1], self.lanes
+        tables = addr.tables
+        mb = tables.shape[1]
+        q_nope, q_rope, rows, cq = op.queries_and_rows(weights, x, positions,
+                                                       with_cq=True)
+        qi, wi, ki = op.index(weights, x, cq, positions)
+        own = (seq_lens // ix.pool)[:, None]
+        slot = self._pool_slots(tables, own, True, per)
+        # the row into its place in its pool (the pool read, one lane group
+        # of it replaced, put back)
+        at = seq_lens % ix.pool
+        with sub_scope("write"):
+            row = jnp.pad(rows[:, 0], ((0, 0), (0, lanes - rows.shape[-1])))
+            mine = (_iota(ix.pool * lanes)[None, :] // lanes) == at[:, None]
+            pool_row = jnp.where(mine, jnp.tile(row, (1, ix.pool)).astype(
+                arena.dtype), self._flat(arena)[slot[:, 0]])
+        arena = self._put_pools(arena, slot, pool_row)
+        # the open pool takes the key; the pool's last key closes it
+        with sub_scope("write"):
+            total = jnp.where((at == 0)[:, None], 0.0,
+                              opened[addr.rows]) + ki[:, 0].astype(jnp.float32)
+            opened = spread_rows(opened, addr.rows, total)
+        keys = self._put_pools(
+            keys, self._pool_slots(tables, own, (at == ix.pool - 1)[:, None],
+                                   per), total / ix.pool)
+        q_full, wkvb = self._absorbed(op, weights, q_nope, q_rope, lanes,
+                                      arena.dtype)
+        with sub_scope("select"):
+            pooled = keys[tables].reshape(n, mb * per, ix.dim)
+            ids = ix.picked(ix.scores(qi, wi, pooled, seq_lens[:, None]))
+        with sub_scope("attend"):
+            ctxv = self._attend_pools(op, q_full, *self._taken_pools(
+                arena, tables, jnp.concatenate([ids[:, 0], own], axis=1),
+                seq_lens))
+        return (self._unabsorbed(op, weights, x, wkvb, ctxv),
+                (arena, keys, opened), ids)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        ix = self.index
+        n, s, _ = x.shape
+        arena, keys, opened = entry
+        per, lanes = arena.shape[1], self.lanes
+        bs = per * ix.pool
+        tables = addr.tables
+        mb = tables.shape[1]
+        q_nope, q_rope, rows, cq = op.queries_and_rows(weights, x, positions,
+                                                       with_cq=True)
+        qi, wi, ki = op.index(weights, x, cq, positions)
+        pos = offsets[:, None] + _iota(s)[None, :]
+        live = _iota(s)[None, :] < lengths[:, None]
+        # the chunk's pools (it starts at a whole pool): those that hold a
+        # live token are written whole (a pool's rows past the prompt's
+        # end are written again, each by its own step, before any query
+        # can read them); the keys of those that end inside it; and what
+        # the one its true length leaves open holds so far
+        idx = (offsets // ix.pool)[:, None] + _iota(s // ix.pool)[None, :]
+        begun = idx * ix.pool < (offsets + lengths)[:, None]
+        whole = (idx + 1) * ix.pool <= (offsets + lengths)[:, None]
+        arena = self._put_pools(
+            arena, self._pool_slots(tables, idx, begun, per),
+            jnp.pad(rows, ((0, 0), (0, 0), (0, lanes - rows.shape[-1]))))
+        sums = jnp.where(live[..., None], ki.astype(jnp.float32), 0.0).reshape(
+            n, s // ix.pool, ix.pool, ix.dim).sum(2)
+        keys = self._put_pools(
+            keys, self._pool_slots(tables, idx, whole, per), sums / ix.pool)
+        with sub_scope("write"):
+            last = jnp.take_along_axis(
+                sums, jnp.clip(lengths // ix.pool, 0,
+                               s // ix.pool - 1)[:, None, None], axis=1)[:, 0]
+            opened = opened.at[addr.rows].set(
+                jnp.where((lengths % ix.pool > 0)[:, None], last, 0.0))
+        count = min(ix.picks, mb * per)
+        wkvb = op.kvb_heads(weights)
+        qb = 128 if s % 128 == 0 else s
+
+        def parts(a):                  # (N, S, ...) -> (S / qb, N, qb, ...)
+            return jnp.moveaxis(a.reshape((n, s // qb, qb) + a.shape[2:]),
+                                1, 0)
+
+        def joined(a):                 # and back
+            return jnp.moveaxis(a, 0, 1).reshape((n, s) + a.shape[3:])
+
+        def sparse():
+            """A tile of queries at a time (their scores are (queries,
+            heads, pools) float32, their taken rows (queries, topk, lanes)):
+            score, pick, gather, attend in the absorbed form."""
+            with sub_scope("select"):
+                pooled = keys[tables].reshape(n, mb * per, ix.dim)
+            with sub_scope("project"):
+                q_lat = jnp.einsum(
+                    "bshd,chd->bshc", q_nope, wkvb[..., :op.nope_dim],
+                    preferred_element_type=jnp.float32).astype(arena.dtype)
+
+            def tile(part):
+                qi_t, wi_t, pos_t, q_t = part
+                with sub_scope("select"):
+                    ids = ix.picked(ix.scores(qi_t, wi_t, pooled, pos_t))
+                with sub_scope("attend"):
+                    pools = jnp.concatenate(
+                        [ids, (pos_t // ix.pool)[..., None]], axis=-1)
+                    ctxv = self._attend_pools(
+                        op, q_t.reshape((n * qb,) + q_t.shape[2:]),
+                        *self._taken_pools(
+                            arena, jnp.repeat(tables, qb, axis=0),
+                            pools.reshape(n * qb, -1), pos_t.reshape(-1)))
+                return (ctxv.astype(x.dtype).reshape(
+                    (n, qb) + ctxv.shape[1:]), ids)
+
+            ctxv, ids = jax.lax.map(tile, (parts(qi), parts(wi), parts(pos),
+                                           parts(q_lat)))
+            with sub_scope("project"):
+                o = jnp.einsum("bshc,chd->bshd", joined(ctxv),
+                               wkvb[..., op.nope_dim:],
+                               preferred_element_type=jnp.float32)
+            return o.astype(x.dtype), joined(ids)
+
+        def dense():
+            """Every pool before a query is taken: the causal walk over
+            the chunk's few key spans, keys and values expanded a span."""
+            span_blocks = max(1, SPAN_TOKENS // bs)
+            span = span_blocks * bs
+            padded = jnp.pad(tables, ((0, 0), (0, -mb % span_blocks)),
+                             constant_values=NULL_BLOCK)
+
+            def read(j):
+                blocks = jax.lax.dynamic_slice_in_dim(
+                    padded, j * span_blocks, span_blocks, axis=1)
+                c = arena[blocks].reshape(n, span, lanes)[..., :op.kv_rank]
+                kv = jnp.einsum(
+                    "bkc,chd->bkhd", c, wkvb,
+                    preferred_element_type=jnp.float32).astype(c.dtype)
+                kpos = jnp.broadcast_to(j * span + _iota(span)[None],
+                                        (n, span))
+                return kv[..., :op.nope_dim], kv[..., op.nope_dim:], kpos
+
+            with sub_scope("attend"):
+                hi = (jnp.max(offsets + lengths) + span - 1) // span
+                o = _attend_spans(op, q_nope, pos, op.num_heads, read, 0,
+                                  jnp.maximum(hi, 1), dv=op.v_dim)
+            return o, self._every_pool(pos, count)
+
+        o, ids = jax.lax.cond(
+            jnp.max(offsets + lengths) <= self.dense_through(), dense, sparse)
+        with sub_scope("project"):
+            out = jnp.dot(o.reshape(n, s, op.num_heads * op.v_dim),
+                          weights["wo"],
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+        return out, (arena, keys, opened), ids
+
+    def whole(self, op, weights, x, positions):
+        return op.selected(weights, x, positions)
+
+    def dense_shapes(self, batch, max_length, dtype):
+        ix = self.index
+        length = -(-max_length // ix.pool) * ix.pool
+        return (jax.ShapeDtypeStruct((batch, length, self.row_width), dtype),
+                jax.ShapeDtypeStruct((batch, length, ix.dim), dtype))
+
+    def dense_step(self, op, weights, x, positions, cache, offset):
+        out, cache, _ = op.selected(weights, x, positions, cache, offset)
+        return out, cache
 
 
 class StateKind(EntryKind):
@@ -1232,6 +1617,7 @@ class StateEntry(TailedStateKind):
     channels: int
     channel_decay: bool = False   # a decay a key channel (KimiDeltaAttention)
     name = "state"
+    chunked = True
 
     @classmethod
     def for_op(cls, op, positions_id, max_length):
@@ -1249,6 +1635,12 @@ class StateEntry(TailedStateKind):
     def _to_row(self, state):
         return jnp.moveaxis(state, 1, 2).reshape(
             state.shape[0], self.key_dim, -1)
+
+    def _rows(self, arena, rows):
+        """:meth:`_to_row` backwards: the states of ``rows`` as the op
+        takes them, (N, H, d_k, d_v)."""
+        return jnp.moveaxis(arena[rows].reshape(
+            len(rows), self.key_dim, self.heads, self.value_dim), 2, 1)
 
     def stats(self):
         return dict(super().stats(), tails_path=self.tails_path())
@@ -1291,6 +1683,19 @@ class StateEntry(TailedStateKind):
             o, state = update(state, addr.rows, q[:, 0], k[:, 0], v[:, 0],
                               jnp.exp(g[:, 0]), beta[:, 0])
         return op.finish(weights, x, o[:, None]), (state, tails)
+
+    def chunk(self, op, weights, x, positions, entry, addr, offsets, lengths):
+        """:meth:`SsmStateEntry.chunk`'s contract: a first chunk starts
+        from zeros, a later one from the state and the tail the chunk
+        before wrote, through the op's own ``run`` (the bucket prefill's
+        form), and what is written is what the chunk's TRUE length
+        leaves."""
+        later = offsets > 0
+        state = jnp.where(later[:, None, None, None],
+                          self._rows(entry[0], addr.rows), 0.0)
+        tail = self.conv_tail.take(entry[1], addr.rows, later)
+        out, state, tail = op.run(weights, x, state, tail, lengths)
+        return out, self._put(entry, addr.rows, state, tail)
 
     def prefill_path(self, bucket):
         """``"kernel"`` (the fused whole-sequence kernel) or ``"scan"``
@@ -1668,7 +2073,9 @@ class SsmStateEntry(TailedStateKind):
 KINDS: Dict[OpType, Callable[..., EntryKind]] = {
     OpType.MULTIHEAD_ATTENTION: PairEntry.for_op,
     OpType.COMPRESSED_CONV_ATTENTION: CcaEntry.for_op,
-    OpType.LATENT_ATTENTION: LatentEntry.for_op,
+    OpType.LATENT_ATTENTION: lambda op, *a: (
+        LatentEntry if op.indexer is None else SparseLatentEntry).for_op(
+            op, *a),
     OpType.GATED_DELTA_NET: StateEntry.for_op,
     OpType.KIMI_DELTA_ATTENTION: StateEntry.for_op,
     OpType.BLOCK_SPARSE_ATTENTION: SparseEntry.for_op,
@@ -1693,5 +2100,6 @@ def kind_for(op, positions_id: int, max_length: int) -> Optional[EntryKind]:
 
 __all__ = ["CcaEntry", "ConvTail", "DecayStateEntry", "EntryKind",
            "Int8PairEntry", "KINDS", "LatentEntry", "PairEntry", "SparseEntry",
+           "SparseLatentEntry",
            "SsmStateEntry", "StateEntry", "StateKind", "TailedStateKind",
            "WindowEntry", "kind_for", "latent_row_lanes"]

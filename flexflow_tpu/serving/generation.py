@@ -959,13 +959,15 @@ class PagedDecoder(_DecodeGraph):
 
     def _attention_path(self, window: int) -> str:
         """What a W-token step does with the pool as it is now: "kernel"
-        where every op's entry, of whatever kind, is read in place, else
-        "gather"."""
+        where every op's entry, of whatever kind that has a kernel to read
+        it by (a kind of ``one_form`` has none to fall back from), is read
+        in place, else "gather"."""
         return "kernel" if all(
             self.pool.kinds[op.name].reads_in_place(
                 op, self.pool.kv[op.name], self.decode_slots, window,
                 self.max_blocks_per_request)
-            for op in self._attn_ops) else "gather"
+            for op in self._attn_ops
+            if not self.pool.kinds[op.name].one_form) else "gather"
 
     def _set_attention_path(self) -> None:
         """The decode and chunk programs' entries of ``attention_path``,
@@ -988,8 +990,12 @@ class PagedDecoder(_DecodeGraph):
                     self._compute_dtype() or jnp.float32) != "kernel":
                 said["chunk"] = "scan"
         self.attention_path_by_entry = by
+        # (of the whole program: the kinds that have a kernel to fall back
+        # from; a kind of one form says "gather" of itself above)
+        one_form = {k.name for k in self.pool.kinds.values() if k.one_form}
         decode = ("gather" if any(s["decode"] == "gather"
-                                  for s in by.values()) else "kernel")
+                                  for name, s in by.items()
+                                  if name not in one_form) else "kernel")
         chunk = ("scan" if any(s["chunk"] == "scan" for s in by.values())
                  else "kernel") if self.prefill_chunk else None
         chunks = [self.pool.kinds[op.name].decode_chunk_tokens(

@@ -260,8 +260,8 @@ class PagedKVPool:
         # the ops whose steps read less than they keep, and the sums of
         # what they read, by the word their kind's step_reads says
         # ("selected": a selection of a request's blocks; "window": at
-        # most a window of its rows). One word's ops share one geometry,
-        # so the sums count the first
+        # most a window of its rows; "index": an indexer's pools of rows).
+        # One word's ops share one geometry, so the sums count the first
         self._readers: Dict[str, List["EntryKind"]] = {}
         self._reads: Dict[str, Dict[str, int]] = {}
         for kind in self.kinds.values():
@@ -396,13 +396,20 @@ class PagedKVPool:
                 for key, v in counts.items():
                     self._reads[word][key] += v
 
-    def count_chunk(self, offset: int) -> None:
-        """One chunk of a prompt at ``offset``: a state row a per-request
-        op, started from zeros at offset 0 and carried on past it."""
-        if self._state_ops:
-            with self._mu:
+    def count_chunk(self, offset: int, tokens: int = 0) -> None:
+        """One chunk of a prompt, ``tokens`` tokens at ``offset``: a state
+        row a per-request op, started from zeros at offset 0 and carried on
+        past it, and what the kinds that read less than they keep say of
+        it (``chunk_reads``)."""
+        reads = {word: kinds[0].chunk_reads(offset, tokens).get(word, {})
+                 for word, kinds in self._readers.items()}
+        with self._mu:
+            if self._state_ops:
                 self._chunk_rows["rows_carried" if offset > 0
                                  else "rows_started"] += self._state_ops
+            for word, counts in reads.items():
+                for key, v in counts.items():
+                    self._reads[word][key] += v
 
     def chunk_keys(self, offset: int, tokens: int) -> Tuple[int, int]:
         """The keys a chunk's queries see, at positions ``offset ..
@@ -463,6 +470,12 @@ class PagedKVPool:
             kind = self._readers["selected"][0]
             out["selected"] = reads["selected"]
             out["kernel_rows"] = sum(kind.side_rows(int(n)) for n in held)
+        if "index" in reads:
+            # ONE indexed op's steps over their active slots: the pools
+            # scored and taken, the rows read beside the rows live, the
+            # steps all of whose slots were dense; and its chunks: the
+            # rows their queries took beside those their products ran over
+            out["index"] = reads["index"]
         if "window" in reads:
             # ONE windowed op's rows over the steps' active slots (read,
             # what an op that keeps everything would have read, the rings

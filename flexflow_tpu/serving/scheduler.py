@@ -730,7 +730,7 @@ class ContinuousBatchingScheduler:
             return
         self._clock.count_chunk(n, *self.decoder.pool.chunk_keys(at, n),
                                 last=last)
-        self.decoder.pool.count_chunk(at)
+        self.decoder.pool.count_chunk(at, n)
         with self._mu:
             self._prefill_dispatches += 1
             if last:

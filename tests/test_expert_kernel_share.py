@@ -39,8 +39,8 @@ def test_the_share_is_the_windows_delta_over_the_expert_layers(
 
 
 def test_the_entry_lists_the_routed_cells():
-    """PR 54's four, since PR 55 the MiMo cell and since PR 58 the Ling
-    cell, appended."""
+    """PR 54's four, since PR 55 the MiMo cell, since PR 58 the Ling cell
+    and since PR 63 the GLM cell, appended."""
     (entry,) = [m for m in Layout().bench["per_layer"]
                 if m["name"] == "decode_experts_kernel_share"]
     assert entry == {
@@ -52,4 +52,5 @@ def test_the_entry_lists_the_routed_cells():
                       "trinity-large-ep8.serve-mixedlengths",
                       "zaya1-8b-pp2.serve-chains",
                       "mimo-v2.5-ep16.serve-codebases",
-                      "ling-3.0-flash-ep8.serve-longanswers"]}
+                      "ling-3.0-flash-ep8.serve-longanswers",
+                      "glm-5.3-flash-ep8.serve-repositories"]}

@@ -356,19 +356,22 @@ def test_a_nan_in_one_requests_state_reaches_no_other(toy, short_spans):
     assert np.array_equal(clean, poisoned)
 
 
-def test_the_gated_delta_kind_still_prefills_whole():
-    """``StateEntry`` (the gated-delta rule's) defines no ``chunk``: a
-    model that keeps one refuses ``prefill_chunk`` at construction, by
-    name; the Mamba kind and the pair say they take it."""
+def test_the_gated_delta_kind_takes_chunks_too():
+    """Until PR 63 ``StateEntry`` (the gated-delta rule's) defined no
+    ``chunk`` and a model that keeps one refused ``prefill_chunk`` at
+    construction; now it says it takes it, as the Mamba kind and the pair
+    do (``tests/test_glm_lm.py`` holds its chunk to the bucket prefill's
+    rows), and the hybrid toy builds its chunk programs."""
     ff = FFModel(FFConfig(batch_size=2, seed=0, ledger="off",
                           computation_mode=CompMode.INFERENCE))
     zoo_smoke_builders()["hybrid"](ff, 2)
     ff.compile(optimizer=None, loss_type=None, metrics=[])
-    with pytest.raises(ValueError, match=r"prefill_chunk: a state cache "
-                                         r"entry prefills a prompt whole"):
-        PagedDecoder(ff, 32, decode_slots=2, block_size=8, prefill_chunk=16)
-    assert not cache_entry.StateEntry.chunked
+    dec = PagedDecoder(ff, 32, decode_slots=2, block_size=8,
+                       prefill_chunk=16, calibrate=False)
+    assert dec.prefill_chunk == 16
+    assert cache_entry.StateEntry.chunked
     assert cache_entry.SsmStateEntry.chunked and cache_entry.PairEntry.chunked
+    assert not cache_entry.LatentEntry.chunked
 
 
 # ---- the reference's own two forms -------------------------------------------

@@ -384,7 +384,9 @@ def test_what_the_kinds_do_not_define_refuses_by_name(toy):
     with pytest.raises(ValueError, match="no int8 form"):
         PagedDecoder(ff, MAX_LEN, decode_slots=3, block_size=8,
                      kv_dtype="int8")
-    with pytest.raises(ValueError, match="state cache entry prefills a "
+    # (the state kind takes chunks since PR 63; the plain latent row does
+    # not)
+    with pytest.raises(ValueError, match="latent cache entry prefills a "
                                          "prompt whole"):
         PagedDecoder(ff, MAX_LEN, decode_slots=3, block_size=8,
                      prefill_chunk=16)

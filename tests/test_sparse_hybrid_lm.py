@@ -363,16 +363,24 @@ def test_the_kinds_refuse_what_they_do_not_build(model):
 
 
 def test_a_kind_that_prefills_whole_refuses_chunks():
-    """A gated-delta-rule state is prefilled whole (a pair of keys and
-    values takes chunks since PR 42: ``tests/test_trinity_lm.py``)."""
+    """A plain latent row is prefilled whole (a pair of keys and values
+    takes chunks since PR 42: ``tests/test_trinity_lm.py``; a gated-delta
+    state since PR 63, so the hybrid toy no longer refuses)."""
     from flexflow_tpu.models import zoo_smoke_builders
 
-    ff = FFModel(FFConfig(batch_size=2, ledger="off",
-                          computation_mode=CompMode.INFERENCE))
-    zoo_smoke_builders()["hybrid"](ff, 2)
-    ff.compile(optimizer=None, loss_type=None, metrics=[])
-    with pytest.raises(ValueError, match="prefills a prompt whole"):
-        PagedDecoder(ff, 16, decode_slots=2, block_size=16, prefill_chunk=16)
+    for name, refuses in (("latent_moe", True), ("hybrid", False)):
+        ff = FFModel(FFConfig(batch_size=2, ledger="off",
+                              computation_mode=CompMode.INFERENCE))
+        zoo_smoke_builders()[name](ff, 2)
+        ff.compile(optimizer=None, loss_type=None, metrics=[])
+        if not refuses:
+            PagedDecoder(ff, 16, decode_slots=2, block_size=16,
+                         prefill_chunk=16, calibrate=False)
+            continue
+        with pytest.raises(ValueError, match="latent cache entry prefills "
+                                             "a prompt whole"):
+            PagedDecoder(ff, 16, decode_slots=2, block_size=16,
+                         prefill_chunk=16)
 
 
 def test_calibration_runs_over_the_three_arenas(model):

@@ -1770,3 +1770,152 @@ def test_ling_routers_sort_their_groups_and_nothing_else(ling_programs,
     for ln in sorts:
         assert f"f32[{rows},8]" in ln and "512]" not in ln, ln
     assert not [ln for ln in routed if " scatter(" in ln]
+
+
+@pytest.fixture(scope="module")
+def glm_programs(one_chip):
+    """The decode step and both chunk programs of a KDA layer with the
+    dense MLP and the sparse latent layer with experts (published layers 2
+    and 3) at GLM-5.3-Flash's published widths and its cell's sizes (32
+    slots, contexts to 66,560, blocks of 64, chunks of 2,048, 36 of 288
+    experts held, an eighth of the vocabulary, four residual streams),
+    compiled for the described chip over a pool of two slots' blocks (the
+    tables are the cell's): {name: (the compiled text, its memory
+    analysis)}, and the decoder."""
+    import json
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.families import glm as family
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.core.machine import make_mesh
+    from flexflow_tpu.ffconst import CompMode
+    from flexflow_tpu.serving.generation import PagedDecoder
+    from flexflow_tpu.serving.kv_cache import Addresses
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5.3-flash-ep8.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=2,
+                  **{k: config[k][:2] for k in (
+                      "layer_types", "mlp_layer_types", "indexer_types")})
+    slots, max_length, chunk = 32, 66560, 2048
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+            ff = FFModel(FFConfig(batch_size=slots, compute_dtype="bfloat16",
+                                  ledger="off", search_cache="off",
+                                  computation_mode=CompMode.INFERENCE))
+            family.build(ff, config, slots, max_length)
+            ff.compile(optimizer=None, loss_type=None, metrics=[],
+                       mesh=make_mesh(devices=jax.devices()[:1]))
+            dec = PagedDecoder(ff, max_length, decode_slots=slots,
+                               block_size=64, kv_dtype="bfloat16",
+                               calibrate=False, prefill_chunk=chunk,
+                               num_blocks=2 * 1040 + 1)
+
+            def on_chip(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+
+            def ints(*shape, dtype=jnp.int32):
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+            params = jax.tree_util.tree_map(on_chip, dec._params_sds())
+            pool = jax.tree_util.tree_map(on_chip, dec.pool.kv)
+            acc = jax.tree_util.tree_map(on_chip, dec._expert_acc)
+            mb = dec.max_blocks_per_request
+            out = {}
+            compiled = dec._decode.lower(
+                params, ints(slots), pool,
+                Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
+                ints(slots), ints(slots, dtype=jnp.bool_)).compile()
+            out["decode"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, head in (("chunk", False), ("chunk_head", True)):
+                compiled = jax.jit(
+                    lambda *a, head=head: dec._chunk_step(*a, head=head),
+                    donate_argnums=(2,)).lower(
+                    params, ints(1, chunk), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1),
+                    ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out, dec
+
+
+def test_glm_decode_step_selects_gathers_and_keeps_its_kernels(glm_programs):
+    """The decode step of a KDA layer and the sparse latent layer at 32
+    slots behind four residual streams: the state kind by its kernels
+    (``gated_delta_decode`` under ``rule``, ``state_tails_step`` under
+    ``conv``: the program's verdict is ``kernel``, the sparse latent kind
+    has one form and says ``gather`` of itself), the indexer's scores and
+    top-k under ``select`` and the gather of 512 pools of four rows, a
+    slice a pool, under ``attend``, the experts' kernel, the stream mixes
+    under ``mix``; no ``conditional``, no array of every slot's whole
+    context, no copy of the arena, and nothing beside the weights and the
+    pool but half a gigabyte."""
+    from flexflow_tpu.core.op import parse_scope
+
+    programs, dec = glm_programs
+    text, mem = programs["decode"]
+    assert dec.attention_path["decode"] == "kernel"
+    assert dec.attention_path_by_entry == {
+        "state": {"decode": "kernel", "chunk": "scan"},
+        "sparse_latent": {"decode": "gather", "chunk": "scan"}}
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum("gated_delta_decode" in ln for ln in calls) == 1
+    assert sum("state_tails_step" in ln for ln in calls) == 1
+    assert sum("latent_attention_decode" in ln for ln in calls) == 0
+    assert sum("grouped_experts" in ln for ln in calls) == 1
+    assert " conditional(" not in text
+    owners = {parse_scope(m) for m in re.findall(r'op_name="([^"]+)"', text)
+              } - {None}
+    subs = {(kind, sub) for kind, _, subs_, _ in owners for sub in subs_}
+    assert {("KIMI_DELTA_ATTENTION", "rule"), ("KIMI_DELTA_ATTENTION", "conv"),
+            ("KIMI_DELTA_ATTENTION", "gate"), ("KIMI_DELTA_ATTENTION", "out"),
+            ("LATENT_ATTENTION", "project"), ("LATENT_ATTENTION", "select"),
+            ("LATENT_ATTENTION", "attend"), ("LATENT_ATTENTION", "write"),
+            ("STREAM_MIX", "mix"), ("ROUTED_EXPERTS", "route"),
+            ("ROUTED_EXPERTS", "experts")} <= subs, subs
+    # the arena by pools, as it lies: never copied into another layout
+    assert not re.search(r"bf16\[2081,16,2048\]\{(?!2,1,0)", text)
+    assert "bf16[32,66560,512]" not in text
+    assert mem.temp_size_in_bytes < 512 << 20
+    assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_head"])
+def test_glm_chunk_programs_carry_state_and_selection(glm_programs, name):
+    """A chunk of 2,048 tokens: the KDA layer continues from the request's
+    state and tails by the per-channel rule in jnp (no whole-sequence
+    kernel for a decay a channel), the sparse latent layer scores its
+    queries, picks and gathers their taken rows a tile of 128 queries at a
+    time under ONE ``conditional`` (a first chunk inside the dense regime
+    walks its key spans causally instead); every loop lies under one of
+    those ops; the arena lies by pools and is never copied into another
+    layout (over rows a token a gather of whole pools made the compiler do
+    that, 2.2 GB a call); no (heads,
+    queries, context) array and no (queries, heads, pools) one of the
+    whole chunk is made, and the temporaries stay under 1.5 GB beside a
+    pool that fills the chip."""
+    programs, dec = glm_programs
+    text, mem = programs[name]
+    assert "gated_delta_chunks" not in text
+    for ln in text.splitlines():
+        if " while(" in ln:
+            assert ("ff.KIMI_DELTA_ATTENTION." in ln and "/rule/" in ln) or (
+                "ff.LATENT_ATTENTION." in ln) or (
+                "ff.ROUTED_EXPERTS." in ln), ln
+    assert text.count(" conditional(") >= 1
+    assert not re.search(r"bf16\[2081,16,2048\]\{(?!2,1,0)", text)
+    assert "f32[1,64,2048,66560]" not in text
+    assert "f32[1,2048,32,16640]" not in text
+    assert mem.temp_size_in_bytes < 1536 << 20
