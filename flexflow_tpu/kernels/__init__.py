@@ -34,10 +34,10 @@ that keep the working set in VMEM and feed the MXU directly:
   ``paged_attention`` does, is not written.
 * :mod:`gated_delta` — the gated delta rule of a linear-attention layer:
   the decode step over a pool's per-request states in place, and the
-  whole-sequence form of a prefill as one kernel a layer, the state in
-  VMEM from the first chunk to the last (``ops/gated_delta.py`` keeps
-  the jnp forms, taken where ``supported()`` / ``chunks_supported()``
-  refuse).
+  whole-sequence form of a prefill or a chunk as one kernel a layer, the
+  state in VMEM from the first chunk to the last, with a decay a head or
+  a decay a key channel (``ops/gated_delta.py`` keeps the jnp forms,
+  taken where ``supported()`` / ``chunks_supported()`` refuse).
 * :mod:`ssd_step` — the Mamba-2 decode step over a pool's per-request
   states in place: the live slots' rows of a ``(rows, N, H P)`` arena by
   scalar prefetch, each read once, stepped and written back through the

@@ -74,6 +74,41 @@ between chunks the state is carried. The kernel:
 * computes in float32 throughout, every product at float32 contract
   precision (``highest``): Mosaic's default for float32 operands is bf16
   passes.
+
+The same call takes a decay a key CHANNEL (``g`` (B, S, H, d_k):
+``ops/gated_delta.py`` ``KimiDeltaAttention``, ``chunked_channel_rule``
+its jnp form; the Pallas call is then named ``channel_delta_chunks``).
+The grid, the state scratch, the system, its inverse and the carry are
+the same code; what differs is how a chunk's ``k k^T`` and ``q k^T``
+under the decay are made, since one decay can no longer be pulled out
+of the products: ``sum_d k_t[d] k_i[d] exp(G_t[d] - G_i[d])`` (``G`` the
+log-decay summed from the chunk's start) factorised as ``(k_t exp G_t) .
+(k_i exp -G_i)`` leaves float32 once ``-G_i`` passes 88, which a gate
+bounded below by -5 a token does after 17 tokens. So the chunk's 128
+rows go in SUB-chunks of ``SUB`` = 16 tokens (:func:`_channel_decays`):
+
+* what comes in beside q, k and v is the log-decay summed from each
+  sub-chunk's start, (B, S, H d_k) as the keys lie (XLA sums it before
+  the call, 16 rows at a time): never a sum over the whole chunk, whose
+  differences would round where a sub-chunk's do not;
+* rows of sub-chunk ``a`` are taken against its start, ``k_t exp(G_t -
+  G^a)``, at most 1; the columns they meet are ``k_i exp(G^a - G_i)``: at
+  most ``e^80`` inside ``a``, at most 1 before it, zeros behind it (never
+  read). In VMEM that is ONE (rows, d_k) exponential a sign: a column of
+  an earlier sub-chunk is taken against its own sub-chunk's END and
+  carried on to ``G^a`` by a (1, d_k) factor a pair of sub-chunks, each
+  a product of whole sub-chunks' decays (at most 1, so they underflow
+  where the pair's decay does and never overflow);
+* eight products of (2 SUB, d_k) rows, a sub-chunk's keys above its
+  queries, against their (rows, d_k) columns stand where the scalar form
+  has one of (2 rows, d_k): the same operations in eight independent
+  pieces, stacked down the sublanes into the same two (rows, rows)
+  tiles;
+* the decay since the chunk's start is a (rows, d_k) array where it was
+  a column, the keys up to the chunk's end likewise, and the whole
+  chunk's decay scales the state's ROWS: its (1, d_k) row goes through
+  the diagonal of a (d_k, d_k) tile and a lane sum to lie down the
+  sublanes.
 """
 
 from __future__ import annotations
@@ -317,10 +352,19 @@ def tails_step(tails, live, x_r, w):
 ROWS = 128
 # sequences from which the kernel beats the jnp scan on a v5e: one chunk
 # of the scan's (64 tokens) or less is one short loop there and one
-# padded chunk of ROWS here (PERF.md section 6, PR 33: the table)
+# padded chunk of ROWS here (PERF.md section 6, PR 33: the table). With a
+# decay a key channel the cut is the same (PR 64, ``tools/
+# delta_rule_crossover.py --decay channel``: from 128 tokens the kernel is
+# 2.2 to 4.6 times the faster; at 64 it reads 0.077 | 0.192 ms against
+# the scan's 0.109 | 0.230 at 32 | 64 heads of 128, within a third either
+# way, and nothing was read below, where no cell's program lies)
 MIN_SEQ = 65
 # the body unrolls a group of heads; past this many no group is built
 MAX_GROUP = 8
+# tokens a sub-chunk of the form with a decay a key CHANNEL: a chunk's
+# products are taken against the start of the row's sub-chunk, and a gate
+# bounded below by -88 / SUB a token (-5.5) keeps exp(SUB |g|) in float32
+SUB = 16
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -329,43 +373,65 @@ def _whole_tiles(width: int) -> int:
     return LANES // math.gcd(width, LANES)
 
 
-def _chunk_group(key_dim: int, value_dim: int) -> int:
+# heads a grid step takes side by side where fewer would fill whole lane
+# tiles: a head is a chain of dependent products, and the matrix unit
+# waits between one chain's stages (PR 33: four at 96 and 192; PR 64, a
+# decay a channel, ``tools/delta_rule_crossover.py --decay channel``: 4.86,
+# 3.17, 2.81 ms a layer at 1, 2 and 4 with 64 heads of 128 and 2,048
+# tokens, 2.33, 1.56, 1.38 with 32 heads and two rows of 1,024; 8 read
+# 2.24 and 1.38, at twice the body to trace and compile a program)
+CHAINS = 4
+
+
+def _chunk_group(key_dim: int, value_dim: int, heads: int) -> int:
     """Heads a grid step takes: the fewest whose keys AND values fill
-    whole lane tiles of the (B, S, H d) operands (4 at 96 and 192)."""
-    return math.lcm(_whole_tiles(key_dim), _whole_tiles(value_dim))
+    whole lane tiles of the (B, S, H d) operands (4 at 96 and 192), and
+    as many such sets as make ``CHAINS`` heads, no more than the op
+    has."""
+    whole = math.lcm(_whole_tiles(key_dim), _whole_tiles(value_dim))
+    return whole * max(1, min(CHAINS // whole, -(-heads // whole)))
 
 
-def _chunks_vmem_bytes(group: int, key_dim: int, value_dim: int) -> int:
+def _chunks_vmem_bytes(group: int, key_dim: int, value_dim: int,
+                       channel: bool = False) -> int:
     """The working set of a grid step: q, k, v in and o out and the
     chunk's constant tiles twice (the pipeline's two buffers), the
     states in, out and in the scratch, and each head's (ROWS, ROWS) and
     (2 ROWS, lanes) temporaries (the heads go stage by stage, so all of
-    them live at once), all float32."""
+    them live at once), all float32. A decay a ``channel`` comes in as
+    the keys do and makes ten (ROWS, keys) arrays more a head (the
+    decays of rows and columns, the keys and queries under them, one
+    sub-chunk's columns)."""
     kp, vp = _round_up(key_dim, LANES), _round_up(value_dim, LANES)
     blocks = 2 * ROWS * group * (2 * key_dim + 2 * value_dim)
     masks = 2 * (ROWS.bit_length() + 1) * ROWS * ROWS
     states = group * (4 * key_dim * value_dim + kp * vp)
     temps = group * (8 * ROWS * ROWS + 6 * ROWS * (kp + vp))
+    if channel:
+        blocks += 2 * ROWS * group * key_dim
+        temps += group * 10 * ROWS * kp
     return 4 * (blocks + masks + states + temps)
 
 
 def chunks_supported(seq: int, heads: int, key_dim: int, value_dim: int,
-                     dtype) -> bool:
+                     dtype, channel: bool = False) -> bool:
     """Whether :func:`gated_delta_chunks` takes these shapes: more than
     one chunk of the jnp scan's, float32, keys that fill whole sublane
     tiles of a state, heads that group into whole lane tiles (values in
     whole groups: a tile two heads share is written by both), a group
-    the body can unroll, a working set within the VMEM budget."""
+    the body can unroll, a working set within the VMEM budget;
+    ``channel``: for a decay a key channel."""
     if pallas_mode() is None or seq < MIN_SEQ:
         return False
     if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
         return False
     if key_dim % 8 or heads % _whole_tiles(value_dim):
         return False
-    group = _chunk_group(key_dim, value_dim)
+    group = _chunk_group(key_dim, value_dim, heads)
     if group > MAX_GROUP:
         return False
-    return _chunks_vmem_bytes(group, key_dim, value_dim) <= VMEM_BUDGET_BYTES
+    return _chunks_vmem_bytes(group, key_dim, value_dim,
+                              channel) <= VMEM_BUDGET_BYTES
 
 
 def _mm(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -422,6 +488,21 @@ def _tile_masks(rows: int):
     return jnp.stack(masks).astype(jnp.float32)
 
 
+def _cut(x, lo: int, hi: int):
+    """Rows ``[lo, hi)`` of ``x`` (rows, lanes) by lax's own slice: the
+    operation ``x[lo:hi]`` traces to, at a third of the time to trace it
+    (a kernel's body is traced anew for every program that holds it, and
+    this one slices some hundreds of times)."""
+    if hi - lo == x.shape[0]:
+        return x
+    return jax.lax.slice(x, (lo, 0), (hi, x.shape[1]))
+
+
+def _stack(parts):
+    """``parts`` one under the other: ``jnp.concatenate``'s operation."""
+    return jax.lax.concatenate(parts, 0)
+
+
 def _unit_lower_inverses(lows, m_ref):
     """``(I + low)^-1`` for each strictly lower-triangular tile of
     ``lows``, by block substitution in doubling blocks: with ``T`` the
@@ -445,25 +526,89 @@ def _unit_lower_inverses(lows, m_ref):
                     for low, inv in zip(lows, invs)]
             continue
         later = range(s, rows, 2 * s)
-        gather = lambda x: jnp.concatenate(  # noqa: E731
-            [x[p:p + s] for p in later], axis=0)
+        gather = lambda x: _stack([_cut(x, p, p + s) for p in later])  # noqa: E731
         zeros = jnp.zeros((s, rows), jnp.float32)
         through = [_mm(gather(low * joins), inv)          # (rows / 2, rows)
                    for low, inv in zip(lows, invs)]
-        change = [_mm(gather(inv), jnp.concatenate(
+        change = [_mm(gather(inv), _stack(
             [part for n in range(len(later))
-             for part in (zeros, thr[n * s:(n + 1) * s])], axis=0))
+             for part in (zeros, _cut(thr, n * s, (n + 1) * s))]))
             for inv, thr in zip(invs, through)]
-        invs = [jnp.concatenate(
+        invs = [_stack(
             [part for n, p in enumerate(later)
-             for part in (inv[p - s:p],
-                          inv[p:p + s] - chg[n * s:(n + 1) * s])], axis=0)
+             for part in (_cut(inv, p - s, p),
+                          _cut(inv, p, p + s) - _cut(chg, n * s, (n + 1) * s))])
             for inv, chg in zip(invs, change)]
     return invs
 
 
+def _channel_decays(log, k, q):
+    """What a chunk with a decay a key CHANNEL makes of its decay before
+    any product, for a whole group of heads at once (it is elementwise
+    along the lanes, so the heads go side by side, ``lanes`` = the
+    group's padded keys: a body a head would be traced and lowered a
+    head, four times the operations for the same work). ``log`` (rows,
+    lanes): the log-decay summed from the start of each row's SUB-chunk
+    of ``SUB`` tokens (at least ``-88``: the gate's bound); ``k``, ``q``
+    (rows, lanes). With ``G`` the log-decay summed from the chunk's
+    start and ``G^a`` its value before sub-chunk ``a``, returns
+
+    * ``lhs``: for each sub-chunk ``a`` its keys' rows above its queries'
+      (2 SUB, lanes), each times ``exp(G_t - G^a)``, at most 1;
+    * ``cols``: for each ``a`` the keys as columns (rows, lanes), ``k_i
+      exp(G^a - G_i)``: at most 1 in the sub-chunks before ``a``, at most
+      ``exp(SUB |g|)`` inside it, zeros behind it (never read);
+    * ``since`` (rows, lanes), ``exp(G_t)``; ``out`` (rows, lanes), ``k_i
+      exp(G_end - G_i)``; ``carry`` (1, lanes), ``exp(G_end)``.
+
+    ONE exponential of (rows, lanes) a sign: a column of an earlier
+    sub-chunk is taken against its own sub-chunk's END and carried to
+    ``G^a`` by a (1, lanes) factor a pair of sub-chunks, each a product
+    of whole sub-chunks' decays."""
+    rows, lanes = log.shape
+    nsub = rows // SUB
+    cut = lambda x, b: _cut(x, b * SUB, (b + 1) * SUB)  # noqa: E731
+    over = lambda x: jax.lax.broadcast_in_dim(  # noqa: E731
+        x, (SUB, lanes), (0, 1))
+    down = jnp.exp(log)               # exp(G_t - G^a), a the row's own
+    up = k * jnp.exp(-log)            # k_i exp(G^a - G_i), a the column's own
+    # a whole sub-chunk's decay, and between[a][c] = exp(G^a - G^c), c <= a
+    whole = [jnp.exp(_cut(log, (b + 1) * SUB - 1, (b + 1) * SUB))
+             for b in range(nsub)]
+    between = []
+    for a in range(nsub + 1):
+        run = [None] * (a + 1)
+        for c in range(a - 1, -1, -1):
+            run[c] = whole[c] if run[c + 1] is None else run[c + 1] * whole[c]
+        between.append(run)
+    # a column against its sub-chunk's end, then carried on to G^a
+    ended = [cut(up, b) * over(whole[b]) for b in range(nsub)]
+    carried = lambda a, b: (  # noqa: E731
+        ended[b] if a == b + 1 else ended[b] * over(between[a][b + 1]))
+    zeros = jnp.zeros((SUB, lanes), jnp.float32)
+    cols = [_stack([carried(a, b) for b in range(a)] + [cut(up, a)]
+                  + [zeros] * (nsub - a - 1)) for a in range(nsub)]
+    out = _stack([carried(nsub, b) for b in range(nsub)])
+    kd, qd = k * down, q * down
+    lhs = [_stack([cut(kd, a), cut(qd, a)]) for a in range(nsub)]
+    since = down * _stack([jnp.ones((SUB, lanes), jnp.float32)]
+                         + [over(between[a][0]) for a in range(1, nsub)])
+    return lhs, cols, since, out, between[nsub][0]
+
+
 def _chunks_kernel(q_ref, k_ref, v_ref, gb_ref, gt_ref, m_ref, s0_ref, *rest,
-                   group, key_dim, value_dim, unit_eps, norm_eps):
+                   group, key_dim, value_dim, unit_eps, norm_eps,
+                   channel=False):
+    """One chunk of a group of heads. The decay is a number a head and
+    token (``gb_ref`` (rows, 2 group): the log-decay summed from the
+    chunk's start beside beta; ``gt_ref`` (group, rows): the sums along
+    the lanes) or, ``channel``, a number a key channel (``gb_ref`` (rows,
+    group keys): the log-decay summed from each SUB-chunk's start, as
+    the keys lie; ``gt_ref`` (rows, group): beta). What differs between
+    the two is how the chunk's ``k k^T`` and ``q k^T`` under the decay
+    are made and what the decay since the chunk's start is, a column or
+    a (rows, keys) array; the system, its inverse and the carry are one
+    code."""
     gain_ref = rest[0] if norm_eps is not None else None
     o_ref, s_out, s_scr = rest[-3:]
     c = pl.program_id(2)
@@ -487,19 +632,42 @@ def _chunks_kernel(q_ref, k_ref, v_ref, gb_ref, gt_ref, m_ref, s0_ref, *rest,
             jnp.sum(a * a, axis=1, keepdims=True) + unit_eps)
         qs = [unit(q) * key_dim ** -0.5 for q in qs]
         ks = [unit(k) for k in ks]
-    # gc: the cumulative log-decay from the chunk's start, down the
-    # sublanes and along the lanes; beta down the sublanes
-    gcs = [gb_ref[:, j:j + 1] for j in heads]                     # (rows, 1)
-    betas = [gb_ref[:, group + j:group + j + 1] for j in heads]
-    # decay[t, i] = prod_{i < j <= t} alpha_j, for i <= t (0 above)
-    decays = [jnp.exp(jnp.minimum(gc - gt_ref[j:j + 1, :], 0.0)) * m_ref[0]
-              for j, gc in zip(heads, gcs)]
-    kqs = [_mm(jnp.concatenate([k, q], axis=0), k, _NT)       # (2 rows, rows)
-           for k, q in zip(ks, qs)]
-    invs = _unit_lower_inverses(
-        [beta * kq[:rows] * decay * m_ref[1]
-         for beta, kq, decay in zip(betas, kqs, decays)], m_ref)
-    sinces = [jnp.exp(gc) for gc in gcs]    # the decay since the chunk's start
+    if channel:
+        betas = [gt_ref[:, j:j + 1] for j in heads]               # (rows, 1)
+        # the group's heads side by side on the lanes, and a head's back
+        wide = lambda xs: jax.lax.concatenate(xs, 1)  # noqa: E731
+        head = lambda x, j: jax.lax.slice_in_dim(  # noqa: E731
+            x, j * kp, (j + 1) * kp, axis=1)
+        lhs, cols, since, out, carry = _channel_decays(
+            wide([_take(gb_ref, j * key_dim, key_dim) for j in heads]),
+            wide(ks), wide(qs))
+        sinces, outs, carries = ([head(x, j) for j in heads]
+                                 for x in (since, out, carry))
+        # a sub-chunk's rows against every column it reads: (2 SUB, rows),
+        # its keys' pairs above its queries'
+        pairs = [[_mm(head(rows_a, j), head(cols_a, j), _NT)
+                  for rows_a, cols_a in zip(lhs, cols)] for j in heads]
+        part = lambda pair, at: _stack(  # noqa: E731
+            [_cut(p, at, at + SUB) for p in pair])
+        lows = [beta * part(pair, 0) * m_ref[1]
+                for beta, pair in zip(betas, pairs)]
+        qks = [part(pair, SUB) * m_ref[0] for pair in pairs]
+    else:
+        # gc: the cumulative log-decay from the chunk's start, down the
+        # sublanes and along the lanes; beta down the sublanes
+        gcs = [gb_ref[:, j:j + 1] for j in heads]                 # (rows, 1)
+        betas = [gb_ref[:, group + j:group + j + 1] for j in heads]
+        # decay[t, i] = prod_{i < j <= t} alpha_j, for i <= t (0 above)
+        decays = [jnp.exp(jnp.minimum(gc - gt_ref[j:j + 1, :], 0.0))
+                  * m_ref[0] for j, gc in zip(heads, gcs)]
+        kqs = [_mm(jnp.concatenate([k, q], axis=0), k, _NT)   # (2 rows, rows)
+               for k, q in zip(ks, qs)]
+        lows = [beta * kq[:rows] * decay * m_ref[1]
+                for beta, kq, decay in zip(betas, kqs, decays)]
+    invs = _unit_lower_inverses(lows, m_ref)
+    if not channel:
+        # the decay since the chunk's start
+        sinces = [jnp.exp(gc) for gc in gcs]
     # u_t = uv_t - w_t S_0: what position t adds to the state as k_t u_t^T
     wus = [_mm(inv, jnp.concatenate([(beta * since) * k, beta * v], axis=1))
            for inv, beta, since, k, v in zip(invs, betas, sinces, ks, vs)]
@@ -507,18 +675,30 @@ def _chunks_kernel(q_ref, k_ref, v_ref, gb_ref, gt_ref, m_ref, s0_ref, *rest,
     againsts = [_mm(jnp.concatenate([wu[:, :kp], since * q], axis=0), state)
                 for wu, since, q, state in zip(wus, sinces, qs, states)]
     us = [wu[:, kp:] - against[:rows] for wu, against in zip(wus, againsts)]
-    # the whole chunk's log-decay, (1, 1): summed out of the lanes' form
-    # (a slice of the sublanes' form sits on sublane 7, and Mosaic
-    # broadcasts along one of sublanes and lanes at a time)
-    last = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) == rows - 1
-    ends = [jnp.sum(jnp.where(last, gt_ref[j:j + 1, :], 0.0), axis=1,
-                    keepdims=True) for j in heads]
-    throughs = [_mm(jnp.concatenate(
-        [kq[rows:] * decay, (jnp.exp(end - gc) * k).T], axis=0), u)
-        for kq, decay, end, gc, k, u in zip(kqs, decays, ends, gcs, ks, us)]
+    if channel:
+        throughs = [_mm(jnp.concatenate([qk, out.T], axis=0), u)
+                    for qk, out, u in zip(qks, outs, us)]
+        # the whole chunk's decay down the state's sublanes: the (1, kp)
+        # row through the diagonal, summed along the lanes
+        diagonal = (jax.lax.broadcasted_iota(jnp.int32, (kp, kp), 0)
+                    == jax.lax.broadcasted_iota(jnp.int32, (kp, kp), 1))
+        keeps = [jnp.sum(jnp.where(diagonal, carry, 0.0), axis=1,
+                         keepdims=True) for carry in carries]
+    else:
+        # the whole chunk's log-decay, (1, 1): summed out of the lanes'
+        # form (a slice of the sublanes' form sits on sublane 7, and
+        # Mosaic broadcasts along one of sublanes and lanes at a time)
+        last = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) == rows - 1
+        ends = [jnp.sum(jnp.where(last, gt_ref[j:j + 1, :], 0.0), axis=1,
+                        keepdims=True) for j in heads]
+        throughs = [_mm(jnp.concatenate(
+            [kq[rows:] * decay, (jnp.exp(end - gc) * k).T], axis=0), u)
+            for kq, decay, end, gc, k, u in zip(kqs, decays, ends, gcs, ks,
+                                                us)]
     tiles = [None] * (group * value_dim // LANES)
     for j in heads:
-        s_scr[j] = jnp.exp(ends[j]) * states[j] + throughs[j][rows:]
+        keep = keeps[j] if channel else jnp.exp(ends[j])
+        s_scr[j] = keep * states[j] + throughs[j][rows:]
         o = againsts[j][rows:] + throughs[j][:rows]
         if norm_eps is not None:  # GatedDeltaNet.finish: RMSNorm a head
             o = o * jax.lax.rsqrt(jnp.sum(o * o, axis=1, keepdims=True)
@@ -539,40 +719,56 @@ def _gated_delta_chunks(q, k, v, g, beta, state, gain=None, *, heads, rows,
                         unit_eps=None, norm_eps=None, interpret):
     b, s, _ = q.shape
     key_dim, value_dim = q.shape[-1] // heads, v.shape[-1] // heads
-    group = _chunk_group(key_dim, value_dim)
+    channel = g.shape[-1] != heads    # (B, S, H d_k), flat as the keys are
+    group = _chunk_group(key_dim, value_dim, heads)
     groups = -(-heads // group)
     pad = -s % rows
     if pad:
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
                             for a in (q, k, v, g, beta))
     n = (s + pad) // rows
-    # the log-decay summed from each chunk's start: (B, S, H), the one
-    # thing of a chunk made before the call (its (rows, rows) decay, its
-    # system and the system's inverse are made in VMEM)
-    gc = jnp.cumsum(g.reshape(b, n, rows, heads), axis=2)
 
     def grouped(a):               # (B, n, rows, H) -> (B, groups, n, rows, group)
         a = jnp.pad(a, ((0, 0),) * 3 + ((0, groups * group - heads),))
         return a.reshape(b, n, rows, groups, group).transpose(0, 3, 1, 2, 4)
 
-    gc = grouped(gc)
     lanes = lambda d: pl.BlockSpec(  # noqa: E731
         (None, rows, group * d), lambda bi, gi, ci: (bi, ci, gi))
     gates = lambda *blk: pl.BlockSpec(  # noqa: E731
         (None, None, None) + blk, lambda bi, gi, ci: (bi, gi, ci, 0, 0))
+    if channel:
+        # the log-decay summed from each SUB-chunk's start, as the keys
+        # lie: what a chunk's decays are made of in VMEM (never a sum over
+        # the whole chunk, whose differences would round where a
+        # sub-chunk's do not)
+        gc = jnp.cumsum(g.reshape(b, -1, SUB, heads * key_dim),
+                        axis=2).reshape(g.shape)
+    else:
+        # the log-decay summed from each chunk's start: (B, S, H), the
+        # one thing of a chunk made before the call (its (rows, rows)
+        # decay, its system and the system's inverse are made in VMEM)
+        gc = grouped(jnp.cumsum(g.reshape(b, n, rows, heads), axis=2))
     masks = _tile_masks(rows)
     states = pl.BlockSpec((None, group, key_dim, value_dim),
                           lambda bi, gi, ci: (bi, gi, 0, 0))
     vp = _round_up(value_dim, LANES)
     normed = () if norm_eps is None else (jnp.pad(
         gain.astype(jnp.float32), (0, vp - value_dim)).reshape(1, vp),)
+    beta = grouped(beta.reshape(b, n, rows, heads))
+    if channel:
+        decay = (gc, beta)
+        decay_specs = [lanes(key_dim), gates(rows, group)]
+    else:
+        decay = (jnp.concatenate([gc, beta], axis=-1),
+                 gc.transpose(0, 1, 2, 4, 3))
+        decay_specs = [gates(rows, 2 * group), gates(group, rows)]
     o, state = pl.pallas_call(
         functools.partial(_chunks_kernel, group=group, key_dim=key_dim,
                           value_dim=value_dim, unit_eps=unit_eps,
-                          norm_eps=norm_eps),
+                          norm_eps=norm_eps, channel=channel),
         grid=(b, groups, n),
         in_specs=[lanes(key_dim), lanes(key_dim), lanes(value_dim),
-                  gates(rows, 2 * group), gates(group, rows),
+                  *decay_specs,
                   pl.BlockSpec(masks.shape, lambda bi, gi, ci: (0, 0, 0)),
                   states] + [pl.BlockSpec((1, vp), lambda bi, gi, ci: (0, 0))
                              for _ in normed],
@@ -585,10 +781,8 @@ def _gated_delta_chunks(q, k, v, g, beta, state, gain=None, *, heads, rows,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="gated_delta_chunks",
-    )(q, k, v, jnp.concatenate(
-        [gc, grouped(beta.reshape(b, n, rows, heads))], axis=-1),
-      gc.transpose(0, 1, 2, 4, 3), masks, state, *normed)
+        name="channel_delta_chunks" if channel else "gated_delta_chunks",
+    )(q, k, v, *decay, masks, state, *normed)
     return o[:, :s], state
 
 
@@ -605,6 +799,11 @@ def gated_delta_chunks(q, k, v, g, beta, state, *, unit_eps=None, norm=None):
     back in v's form. The chunk's decay, its unit-lower system and the
     system's inverse exist only in VMEM.
 
+    ``g`` (B, S, H, d_k) is a decay a key CHANNEL
+    (``chunked_channel_rule``'s): the same call and grid, the Pallas
+    call named ``channel_delta_chunks``; no lower than ``-88 / SUB`` a
+    token, which the op checks of its gate's bound.
+
     Two things the op does around the recurrence reduce over a head's
     lanes, which XLA does by transposing the whole (B, S, H d) array
     and back; on a head's tile in VMEM they are a lane sum. ``unit_eps``:
@@ -616,12 +815,12 @@ def gated_delta_chunks(q, k, v, g, beta, state, *, unit_eps=None, norm=None):
 
     Callers check :func:`chunks_supported` first. The call is jitted on
     its own, so that the layers of a model trace it once a shape."""
-    b, s, heads = g.shape
+    b, s, heads = g.shape[:3]
     f32 = jnp.float32
     flat = lambda a: a.astype(f32).reshape(b, s, -1)  # noqa: E731
     gain, norm_eps = (None, None) if norm is None else norm
     o, state = _gated_delta_chunks(
-        flat(q), flat(k), flat(v), g.astype(f32), beta.astype(f32),
+        flat(q), flat(k), flat(v), flat(g), beta.astype(f32),
         state.astype(f32), gain, heads=heads, rows=ROWS, unit_eps=unit_eps,
         norm_eps=norm_eps, interpret=pallas_mode() == "interpret")
     return o.reshape(v.shape), state

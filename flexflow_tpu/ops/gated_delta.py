@@ -35,7 +35,9 @@ carried by ``lax.scan``. A layer runs it, between the norms on either
 side of it, by the path the shapes choose: :func:`fused_rule`
 (``kernels/gated_delta.py`` ``gated_delta_chunks``, one kernel a call)
 where :func:`delta_rule_path` says ``"kernel"``, else :func:`scan_rule`,
-the jnp form, which stays as the kernel's reference.
+the jnp form, which stays as the kernel's reference; both take a decay
+that is a number a head (:func:`chunked_delta_rule`) or one a key
+channel (:func:`chunked_channel_rule`, :class:`KimiDeltaAttention`).
 ``kernels/gated_delta.py`` also takes one token (``delta_rule_step`` in
 jnp, ``gated_delta_decode`` the kernel). All are float32 with products
 at ``highest``; the projections around them are in the activations'
@@ -147,7 +149,7 @@ def _carry_chunks(state, chunks, b, s, h):
     return o.reshape(b, n * CHUNK, h, -1)[:, :s], state
 
 
-SUB = 16  # tokens a sub-chunk of the per-channel form (see there)
+SUB = kernel.SUB  # tokens a sub-chunk of the per-channel form (see there)
 
 
 def chunked_channel_rule(q, k, v, g, beta, state):
@@ -156,6 +158,9 @@ def chunked_channel_rule(q, k, v, g, beta, state):
     ``alpha_t[d]``::
 
         S' = diag(alpha_t) S_{t-1};  S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+
+    (the reference of the kernel's form with such a decay, and what
+    runs where :func:`delta_rule_path` says ``"scan"``.)
 
     One decay can no longer be pulled out of a chunk's products: the
     pair's ``sum_d k_t[d] k_i[d] exp(G_t[d] - G_i[d])`` (``G`` the log-decay
@@ -259,10 +264,10 @@ def delta_rule_path(seq: int, heads: int, key_dim: int, value_dim: int,
                     dtype=jnp.float32, channel_decay: bool = False) -> str:
     """How a layer computes its recurrence over these shapes:
     ``"kernel"`` (:func:`fused_rule`) or ``"scan"`` (:func:`scan_rule`).
-    A rule over what a trace sees, the backend among it; no knob. The
-    kernel is the scalar decay's: a decay a channel takes the jnp form."""
-    return "kernel" if not channel_decay and kernel.chunks_supported(
-        seq, heads, key_dim, value_dim, dtype) else "scan"
+    A rule over what a trace sees, the backend among it; no knob. One
+    kernel takes both decays, a number a head or one a key channel."""
+    return "kernel" if kernel.chunks_supported(
+        seq, heads, key_dim, value_dim, dtype, channel_decay) else "scan"
 
 
 @register_op
@@ -459,9 +464,12 @@ class KimiDeltaAttention(GatedDeltaNet):
     The convolution, the unit q and k, the state's shape and what a
     request keeps are :class:`GatedDeltaNet`'s, so serving stores it as
     that op's :class:`~flexflow_tpu.serving.cache_entry.StateEntry`. The
-    whole-sequence form is :func:`chunked_channel_rule` (jnp; no kernel
-    yet), one token ``kernels/gated_delta.py``'s step with a ``(d_k,)``
-    decay a head."""
+    whole-sequence form is ``kernels/gated_delta.py``
+    ``gated_delta_chunks`` with a ``(d_k,)`` decay a head and token (the
+    Pallas call ``channel_delta_chunks``) where :func:`delta_rule_path`
+    says ``"kernel"``, else :func:`chunked_channel_rule`, its jnp form
+    and reference; one token that module's step with a ``(d_k,)`` decay
+    a head."""
 
     op_type = OpType.KIMI_DELTA_ATTENTION
 

@@ -1688,6 +1688,8 @@ class StateEntry(TailedStateKind):
         """:meth:`SsmStateEntry.chunk`'s contract: a first chunk starts
         from zeros, a later one from the state and the tail the chunk
         before wrote, through the op's own ``run`` (the bucket prefill's
+        form: the whole-sequence kernel behind the incoming state where
+        :meth:`chunk_path` says ``"kernel"``, whichever the decay's
         form), and what is written is what the chunk's TRUE length
         leaves."""
         later = offsets > 0
@@ -1703,6 +1705,12 @@ class StateEntry(TailedStateKind):
         return delta_rule_path(bucket, self.heads, self.key_dim,
                                self.value_dim,
                                channel_decay=self.channel_decay)
+
+    def chunk_path(self, entry, prompts, chunk, max_blocks, dtype):
+        """A chunk goes through the op's ``run`` as a bucket does, so by
+        the same rule at the chunk's length (the recurrence is float32
+        whatever ``dtype`` the model computes in)."""
+        return self.prefill_path(chunk)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -374,6 +374,139 @@ def test_chunks_kernel_carries_its_state_into_the_next_call(monkeypatch):
     assert np.abs(end - end2).max() < 2e-5 * np.abs(end).max()
 
 
+# ---- the whole-sequence kernel with a decay a key channel ---------------------
+
+def _channel_draw(rng, b, s, h, dk, dv, g_lo=-5.0, g_hi=0.0):
+    """Unit q (scaled) and k, gates uniform in ``(g_lo, g_hi)`` a key
+    channel (the published bound -5), beta in (0, 1), a state that is
+    not zero."""
+    q, k, v = (rng.normal(size=(b, s, h, d)).astype(np.float32)
+               for d in (dk, dk, dv))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * dk ** 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = rng.uniform(g_lo, g_hi, size=(b, s, h, dk)).astype(np.float32)
+    beta = rng.uniform(0, 1, size=(b, s, h)).astype(np.float32)
+    state = rng.normal(size=(b, h, dk, dv)).astype(np.float32)
+    return tuple(map(jnp.asarray, (q, k, v, g, beta, state)))
+
+
+def _channel_whole(rng, b, s, h, dk, dv, **gates):
+    args = _channel_draw(rng, b, s, h, dk, dv, **gates)
+    assert gd.chunks_supported(s, h, dk, dv, jnp.float32, True)
+    return (gd.gated_delta_chunks(*args),
+            op_mod.chunked_channel_rule(*args))
+
+
+def _channel_ragged(rng):
+    """Two rows of 300 positions, the first stopped inside its second
+    chunk as ``KimiDeltaAttention.run`` stops it (``g = 0``, ``beta = 0``
+    past its length): its state is what its 150 tokens alone leave."""
+    q, k, v, g, beta, state = _channel_draw(rng, 2, 300, 2, 64, 128)
+    live = (np.arange(300)[None, :] < np.array([150, 300])[:, None])
+    g = jnp.where(live[..., None, None], g, 0.0)
+    beta = jnp.where(live[..., None], beta, 0.0)
+    got = gd.gated_delta_chunks(q, k, v, g, beta, state)
+    want = op_mod.chunked_channel_rule(q, k, v, g, beta, state)
+    alone = op_mod.chunked_channel_rule(
+        *(a[:1, :150] for a in (q, k, v, g, beta)), state[:1])
+    assert np.abs(got[1][0] - alone[1][0]).max() \
+        < 2e-5 * np.abs(alone[1]).max()
+    return got, want
+
+
+def _channel_in_two_calls(rng):
+    """One call, and two with the state handed over at a position no
+    chunk and no sub-chunk ends at."""
+    *args, state = _channel_draw(rng, 1, 300, 2, 64, 128)
+    whole = gd.gated_delta_chunks(*args, state)
+    cut = 137
+    first, mid = gd.gated_delta_chunks(*(a[:, :cut] for a in args), state)
+    second, end = gd.gated_delta_chunks(*(a[:, cut:] for a in args), mid)
+    return (jnp.concatenate([first, second], axis=1), end), whole
+
+
+def _channel_fused(rng):
+    """``fused_rule`` against ``scan_rule``: q and k as the convolution
+    wrote them, flat, made unit a head in the kernel, and o
+    RMS-normalised a head times the gain on its way out."""
+    b, s, h, dk, dv = 2, 150, 2, 128, 128
+    q, k, v, g, beta, state = _channel_draw(rng, b, s, h, dk, dv)
+    q, k = 3.0 * q, 0.3 * k * rng.uniform(0.5, 2.0, size=(b, s, h, 1))
+    flat = [jnp.asarray(a, jnp.float32).reshape(b, s, -1) for a in (q, k, v)]
+    rest = (g, beta, state, jnp.asarray(
+        rng.uniform(0.5, 1.5, size=(dv,)).astype(np.float32)))
+    assert op_mod.delta_rule_path(s, h, dk, dv, channel_decay=True) == "kernel"
+    got = op_mod.fused_rule(1e-6, *flat, *rest)
+    assert got[0].shape == (b, s, h * dv)
+    return got, op_mod.scan_rule(1e-6, *flat, *rest)
+
+
+# each: rng -> ((o, state) of the kernel, (o, state) it is held to)
+CHANNEL_CASES = {
+    "behind-a-state": lambda rng: _channel_whole(rng, 1, 200, 2, 128, 128),
+    "gates-at-the-bound-for-whole-chunks": lambda rng: _channel_whole(
+        rng, 1, 256, 2, 128, 128, g_lo=-5.0, g_hi=-5.0),
+    "gates-near-one": lambda rng: _channel_whole(
+        rng, 1, 200, 2, 128, 128, g_lo=-1e-2, g_hi=-1e-4),
+    "no-multiple-of-rows-and-a-row-stopped-inside-a-chunk": _channel_ragged,
+    "in-one-call-and-in-two": _channel_in_two_calls,
+    "64-heads-of-128-by-128-one-chunk": lambda rng: _channel_whole(
+        rng, 1, 70, 64, 128, 128),
+    "32-heads-of-128-by-128-two-rows": lambda rng: _channel_whole(
+        rng, 2, 130, 32, 128, 128),
+    "pairs-of-heads-a-tile": lambda rng: _channel_whole(
+        rng, 1, 150, 4, 64, 64),
+    "a-group-of-four-and-a-half": lambda rng: _channel_whole(
+        rng, 1, 150, 6, 96, 192),
+    "fused-rule-makes-the-norms": _channel_fused,
+}
+
+
+@pytest.mark.parametrize("case", CHANNEL_CASES)
+def test_channel_chunks_kernel_interpreted_is_the_channel_rule(
+        monkeypatch, case):
+    """The whole-sequence kernel with a decay a key CHANNEL (the Pallas
+    call ``channel_delta_chunks``) against ``chunked_channel_rule``,
+    outputs and outgoing state, over the range of each: behind a state,
+    with every gate at the published bound for whole chunks (the
+    overflow the sub-chunks exist for: nothing but finite numbers), with
+    rows that stop inside a chunk, handed over between two calls, at both
+    cells' heads and widths, and with the norms made on the head's
+    tile."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    (got, end), (want, want_end) = CHANNEL_CASES[case](
+        np.random.default_rng(len(case)))
+    assert got.shape == want.shape and end.shape == want_end.shape
+    assert np.isfinite(got).all() and np.isfinite(end).all()
+    assert np.abs(got - want).max() < 2e-5 * np.abs(want).max()
+    assert np.abs(end - want_end).max() < 2e-5 * np.abs(want_end).max()
+
+
+def test_channel_chunks_kernel_is_the_token_loop(monkeypatch):
+    """Against the recurrence token by token in float64, the state's row
+    ``d`` decayed by ``alpha_t[d]``: the kernel is no further from it
+    than the jnp form (whose chunks are half as long)."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    rng = np.random.default_rng(64)
+    q, k, v, g, beta, state = _channel_draw(rng, 1, 200, 2, 64, 128,
+                                            g_lo=-3.0)
+    st = np.asarray(state, np.float64)
+    want = np.zeros(v.shape)
+    for t in range(q.shape[1]):
+        st = np.exp(np.asarray(g[:, t], np.float64))[..., None] * st
+        r = v[:, t] - np.einsum("bhdv,bhd->bhv", st, k[:, t])
+        st = st + (np.asarray(beta[:, t])[..., None, None]
+                   * np.asarray(k[:, t])[..., None] * r[:, :, None, :])
+        want[:, t] = np.einsum("bhdv,bhd->bhv", st, q[:, t])
+    got, end = gd.gated_delta_chunks(q, k, v, g, beta, state)
+    scan, _ = op_mod.chunked_channel_rule(q, k, v, g, beta, state)
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() < 2e-5 * scale
+    assert np.abs(end - st).max() < 2e-5 * np.abs(st).max()
+    assert np.abs(got - want).max() < max(4 * np.abs(scan - want).max(),
+                                          2e-6 * scale)
+
+
 def _wide_op(batch=2, seq=150):
     """An op at widths the whole-sequence kernel takes (4 heads of 32 and
     64: one group), with gates in the published range."""
@@ -462,6 +595,10 @@ def test_chunks_kernel_takes_by_shape(monkeypatch, why):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
     assert gd.chunks_supported(*args) is takes
     assert op_mod.delta_rule_path(*args) == ("kernel" if takes else "scan")
+    # a decay a key channel: the same shapes, by the same rule
+    assert gd.chunks_supported(*args, True) is takes
+    assert op_mod.delta_rule_path(*args, channel_decay=True) == (
+        "kernel" if takes else "scan")
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
     assert not gd.chunks_supported(*args)
     monkeypatch.delenv("FLEXFLOW_TPU_PALLAS")

@@ -411,6 +411,43 @@ def test_gated_delta_prefill_compiles_for_v5e(monkeypatch, one_chip,
     assert text.count("call @_gated_delta_chunks(") == 12
 
 
+def test_scalar_rule_traces_to_the_program_it_was(monkeypatch):
+    """``GatedDeltaNet.whole`` at the documents cell's 1,536 bucket traces
+    to the program it traced to on 62fe4b3, the commit before the
+    whole-sequence kernel took a decay a key channel (sha256 of
+    ``jax.make_jaxpr``'s text: the projections, the ``pallas_call`` with
+    its kernel's body, grid, block shapes and index maps; source locations
+    struck, as ``tests/test_mimo_lm.py`` keeps for the attention kernels):
+    the per-channel form is a branch the scalar form never enters. A
+    digest that moves with a change to the kernel's body is recorded
+    anew, with the documents cell's numbers on the chip beside it."""
+    import hashlib
+
+    from flexflow_tpu.core.layer import Layer
+    from flexflow_tpu.core.parallel_tensor import ParallelTensorShape
+    from flexflow_tpu.ffconst import DataType, OpType
+    from flexflow_tpu.ops import gated_delta as op_mod
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    e, h, dk, dv = 3840, 30, 96, 192
+    layer = Layer(OpType.GATED_DELTA_NET, "gdn", attrs=dict(
+        num_heads=h, key_dim=dk, value_dim=dv, conv_taps=4,
+        allow_neg_eigval=True))
+    op = op_mod.GatedDeltaNet(layer, [ParallelTensorShape.unpartitioned(
+        (1, 1536, e), DataType.FLOAT)])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    traced = jax.make_jaxpr(op.whole)(
+        {ws.name: sds(ws.shape) for ws in op.weight_specs()},
+        sds((1, 1536, e)), sds((1,), jnp.int32))
+    text = re.sub(r" at [^\s]+:\d+", "", str(traced))
+    assert "name=gated_delta_chunks" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "c567fcda8f46fef10e3b7148cba5a203d29526505fd41e69642d1491a14f0f97")
+
+
 @pytest.fixture(scope="module")
 def sparse_hybrid_programs(one_chip):
     """The decode step and both chunk programs of a sparse and a linear
@@ -802,7 +839,7 @@ def nemotron_programs(one_chip):
                        mesh=make_mesh(devices=jax.devices()[:1]))
             dec = PagedDecoder(ff, max_length, decode_slots=slots,
                                block_size=16, kv_dtype="bfloat16",
-                               calibrate=False, prefill_buckets=[1024])
+                               calibrate=False, prefill_buckets=[768, 1024])
 
             def on_chip(a):
                 return jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -821,10 +858,12 @@ def nemotron_programs(one_chip):
                 Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
                 ints(slots), ints(slots, dtype=jnp.bool_)).compile()
             out["decode"] = (compiled.as_text(), compiled.memory_analysis())
-            compiled = jax.jit(dec._prefill_step, donate_argnums=(2,)).lower(
-                params, ints(1, 1024), pool, Addresses(ints(1, mb), ints(1)),
-                ints(1)).compile()
-            out["prefill"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, bucket in (("prefill", 1024), ("prefill_768", 768)):
+                compiled = jax.jit(
+                    dec._prefill_step, donate_argnums=(2,)).lower(
+                    params, ints(1, bucket), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
@@ -1623,7 +1662,8 @@ def test_mimo_chunk_programs_hold_no_square(mimo_programs, name):
 
 @pytest.fixture(scope="module")
 def ling_programs(one_chip):
-    """The decode step and the widest prefill of two KDA layers and the
+    """The decode step and the two wider prefills (buckets of 1,024 and
+    768) of two KDA layers and the
     latent layer (published layers 3, 4, 5, all with experts) at
     Ling-3.0-flash's published widths and its cell's sizes (256 slots,
     contexts to 4,096, blocks of 16, 64 of 512 experts held, an eighth of
@@ -1661,7 +1701,7 @@ def ling_programs(one_chip):
                        mesh=make_mesh(devices=jax.devices()[:1]))
             dec = PagedDecoder(ff, max_length, decode_slots=slots,
                                block_size=16, kv_dtype="bfloat16",
-                               calibrate=False, prefill_buckets=[1024])
+                               calibrate=False, prefill_buckets=[768, 1024])
 
             def on_chip(a):
                 return jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -1680,10 +1720,12 @@ def ling_programs(one_chip):
                 Addresses(ints(slots, mb), ints(slots)), ints(slots), acc,
                 ints(slots), ints(slots, dtype=jnp.bool_)).compile()
             out["decode"] = (compiled.as_text(), compiled.memory_analysis())
-            compiled = jax.jit(dec._prefill_step, donate_argnums=(2,)).lower(
-                params, ints(1, 1024), pool, Addresses(ints(1, mb), ints(1)),
-                ints(1)).compile()
-            out["prefill"] = (compiled.as_text(), compiled.memory_analysis())
+            for name, bucket in (("prefill", 1024), ("prefill_768", 768)):
+                compiled = jax.jit(
+                    dec._prefill_step, donate_argnums=(2,)).lower(
+                    params, ints(1, bucket), pool,
+                    Addresses(ints(1, mb), ints(1)), ints(1)).compile()
+                out[name] = (compiled.as_text(), compiled.memory_analysis())
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
@@ -1740,18 +1782,41 @@ def test_ling_decode_step_steps_both_kinds_by_their_kernels(ling_programs):
     assert mem.alias_size_in_bytes >= dec.pool.memory_bytes()
 
 
-def test_ling_prefill_runs_the_channel_rule_in_jnp(ling_programs):
-    """The widest prefill: the per-channel rule is the jnp form (no
-    whole-sequence kernel for a decay a channel yet; the only loops are
-    the KDA ops' walks over their chunks and the system's rows, under
-    ``rule``), the experts the grouped kernel, and its temporaries stay
-    under 3 GB beside a pool that fills the chip."""
+def _rule_kernels(text):
+    """The Mosaic calls of the whole-sequence rule with a decay a key
+    channel in a compiled program, each under a KDA op's ``rule``."""
+    from flexflow_tpu.core.op import parse_scope
+
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "channel_delta_chunks" in ln]
+    for ln in calls:
+        (scope,) = re.findall(r'op_name="([^"]+)"', ln)
+        assert parse_scope(scope)[0] == "KIMI_DELTA_ATTENTION"
+        assert parse_scope(scope)[2] == ("rule",)
+    return calls
+
+
+@pytest.mark.parametrize("name", ["prefill", "prefill_768"])
+def test_ling_prefill_runs_the_channel_rule_as_one_kernel(
+        monkeypatch, ling_programs, name):
+    """A prefill of 1,024 and of 768 tokens: the per-channel rule is ONE
+    ``channel_delta_chunks`` call a KDA layer, under the op's ``rule``
+    (since PR 64; the jnp form's walks over its chunks and its system's
+    rows were the program's only loops), no ``while`` anywhere, the
+    name of no other reader's kernel (``gated_delta_decode``) in it, the
+    experts the grouped kernel, and its temporaries stay under 3 GB
+    beside a pool that fills the chip. The kind says so
+    (``prefill_path``), which ``stats()["kv"]["state"]`` hands on."""
     programs, dec = ling_programs
-    text, mem = programs["prefill"]
-    assert "gated_delta_chunks" not in text
-    for ln in text.splitlines():
-        if " while(" in ln:
-            assert "ff.KIMI_DELTA_ATTENTION." in ln and "/rule/" in ln, ln
+    text, mem = programs[name]
+    assert len(_rule_kernels(text)) == 2
+    assert "gated_delta_decode" not in text
+    assert " while(" not in text
+    assert dec.prefill_path == "kernel"
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "compiled")
+    assert {kind.prefill_path(bucket) for kind in dec.pool.kinds.values()
+            for bucket in (512, 768, 1024)} == {"kernel", None}
     assert mem.temp_size_in_bytes < 3 << 30
 
 
@@ -1867,7 +1932,7 @@ def test_glm_decode_step_selects_gathers_and_keeps_its_kernels(glm_programs):
     text, mem = programs["decode"]
     assert dec.attention_path["decode"] == "kernel"
     assert dec.attention_path_by_entry == {
-        "state": {"decode": "kernel", "chunk": "scan"},
+        "state": {"decode": "kernel", "chunk": "kernel"},
         "sparse_latent": {"decode": "gather", "chunk": "scan"}}
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
@@ -1895,8 +1960,9 @@ def test_glm_decode_step_selects_gathers_and_keeps_its_kernels(glm_programs):
 @pytest.mark.parametrize("name", ["chunk", "chunk_head"])
 def test_glm_chunk_programs_carry_state_and_selection(glm_programs, name):
     """A chunk of 2,048 tokens: the KDA layer continues from the request's
-    state and tails by the per-channel rule in jnp (no whole-sequence
-    kernel for a decay a channel), the sparse latent layer scores its
+    state and tails by the per-channel rule as ONE ``channel_delta_chunks``
+    call under its ``rule`` (since PR 64: no ``while`` of the op's is
+    left), the sparse latent layer scores its
     queries, picks and gathers their taken rows a tile of 128 queries at a
     time under ONE ``conditional`` (a first chunk inside the dense regime
     walks its key spans causally instead); every loop lies under one of
@@ -1908,11 +1974,11 @@ def test_glm_chunk_programs_carry_state_and_selection(glm_programs, name):
     pool that fills the chip."""
     programs, dec = glm_programs
     text, mem = programs[name]
-    assert "gated_delta_chunks" not in text
+    assert len(_rule_kernels(text)) == 1
+    assert "gated_delta_decode" not in text
     for ln in text.splitlines():
         if " while(" in ln:
-            assert ("ff.KIMI_DELTA_ATTENTION." in ln and "/rule/" in ln) or (
-                "ff.LATENT_ATTENTION." in ln) or (
+            assert ("ff.LATENT_ATTENTION." in ln) or (
                 "ff.ROUTED_EXPERTS." in ln), ln
     assert text.count(" conditional(") >= 1
     assert not re.search(r"bf16\[2081,16,2048\]\{(?!2,1,0)", text)
